@@ -21,7 +21,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from _bench_util import enable_persistent_cache  # noqa: E402
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 V5E_PEAK_TFLOPS = 197.0
 SEQ = 1024
@@ -73,7 +73,7 @@ def run_config(mbs, gas, remat_policy):
 
 
 def main():
-    enable_persistent_cache()
+    enable_compile_cache()
     out_path = os.path.join(os.path.dirname(__file__),
                             "large_model_results.json")
     result = {"model": "GPT-2 Large-class 774M (36L x 1280 x 20h, seq 1024)",

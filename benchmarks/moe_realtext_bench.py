@@ -51,9 +51,9 @@ def main():
 
     import jax
 
-    from _bench_util import enable_persistent_cache
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_persistent_cache()
+    enable_compile_cache()
 
     import jax.numpy as jnp
 

@@ -44,9 +44,9 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from _bench_util import enable_persistent_cache
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_persistent_cache()  # before the first compile
+    enable_compile_cache()  # before the first compile
 
     import deepspeed_tpu as ds
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel

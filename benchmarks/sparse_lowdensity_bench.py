@@ -33,7 +33,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(__file__))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from _bench_util import enable_persistent_cache  # noqa: E402
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 from attn_bench import timed  # noqa: E402
 
 
@@ -172,7 +172,7 @@ def model_rows(seq=8192, block=512):
 
 
 def main():
-    enable_persistent_cache()
+    enable_compile_cache()
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "sparse_lowdensity_results.json")
     out = {"kernel": [], "model": []}

@@ -1,8 +1,9 @@
 """Accelerator selection (≅ reference ``accelerator/real_accelerator.py:45``).
 
 Selection order: ``DSTPU_ACCELERATOR`` env override, else the platform of
-``jax.devices()`` (tpu → TpuAccelerator, gpu → GpuAccelerator, otherwise
-CpuAccelerator).
+``jax.devices()`` (tpu → TpuAccelerator, gpu → GpuAccelerator, cpu →
+CpuAccelerator). Any other platform raises: reporting host RAM as an
+unknown accelerator's HBM would hide the device.
 """
 
 from __future__ import annotations
@@ -72,8 +73,11 @@ def get_accelerator() -> Accelerator:
     if name is None:
         import jax
 
-        platform = jax.default_backend()
-        name = platform if platform in _ACCELERATORS else "cpu"
+        name = jax.default_backend()
+    if name not in _ACCELERATORS:
+        raise RuntimeError(
+            f"no accelerator class for platform {name!r}; expected one of "
+            f"{sorted(_ACCELERATORS)}")
     _accelerator = _ACCELERATORS[name]()
     return _accelerator
 

@@ -23,6 +23,26 @@ from typing import Dict, List, Optional, Sequence
 from ..utils.logging import logger
 
 
+def require_one_process_per_host(num_local_procs: int,
+                                 env: Dict[str, str]) -> None:
+    """A chip belongs to one process, and every JAX process claims all
+    of its host's chips: N local workers on an accelerator host are N
+    claims on the same chips, and all but the first fail or hang. More
+    than one local worker is therefore only for the forced-host-device
+    CPU mesh, which the workers' environment must select itself
+    (``JAX_PLATFORMS=cpu``). The launcher never touches JAX — it would
+    hold the chips its workers need — so the environment is all it can
+    go by."""
+    if num_local_procs > 1 and \
+            env.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise RuntimeError(
+            f"{num_local_procs} worker processes requested on this host, "
+            f"but one process drives all local chips: pass one slot per "
+            f"host (--num_gpus 1) and let that process see every chip. "
+            f"Several local workers are only supported with "
+            f"JAX_PLATFORMS=cpu (forced host devices).")
+
+
 class WorkerSpec:
     """What to run for each local worker (≅ torch-elastic WorkerSpec)."""
 
@@ -85,6 +105,8 @@ class DSElasticAgent:
 
     def _start_workers(self) -> None:
         self._procs = []
+        require_one_process_per_host(self.spec.local_world_size,
+                                     self._worker_env(0))
         for local_rank in range(self.spec.local_world_size):
             p = subprocess.Popen(self.spec.entrypoint,
                                  env=self._worker_env(local_rank))

@@ -7,10 +7,13 @@ set, installs a sigkill handler that tears the whole local group down when
 any rank dies (:313), and routes to the elastic agent when
 ``--enable_elastic_training``.
 
-TPU process model: normally ONE process per host drives all local chips
+TPU process model: ONE process per host drives all local chips
 (``jax.distributed.initialize`` + every local device visible), so the world
-info maps hosts → process slots rather than GPU ids. Per-chip processes are
-still expressible (slots > 1) for CPU-mesh testing.
+info maps hosts → process slots rather than GPU ids. More than one slot per
+host is accepted only with ``JAX_PLATFORMS=cpu`` (CPU-mesh testing); on an
+accelerator host it is an error (``require_one_process_per_host``), since
+every process would claim every chip. This process initialises no JAX
+backend, so it holds no chip itself.
 """
 
 from __future__ import annotations
@@ -26,6 +29,8 @@ import time
 from collections import defaultdict
 from typing import Dict, List
 
+from ..elasticity.elastic_agent import (DSElasticAgent, WorkerSpec,
+                                        require_one_process_per_host)
 from ..utils.logging import logger
 
 PID_FILE_BASEPATH = "/tmp"
@@ -77,9 +82,9 @@ def main(args=None):
         global_rank_offset += len(world_info[node_list[i]])
     world_size = sum(len(s) for s in world_info.values())
 
-    if args.enable_elastic_training:
-        from ..elasticity.elastic_agent import DSElasticAgent, WorkerSpec
+    require_one_process_per_host(num_local_procs, os.environ)
 
+    if args.enable_elastic_training:
         spec = WorkerSpec(
             entrypoint=[sys.executable, "-u", args.user_script] +
             args.user_args,

@@ -26,6 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import backend
 from ..parallel.mesh import MODEL_AXIS
 
 
@@ -65,8 +66,8 @@ class GPT2Config:
     # reference's fused transformer-block kernel). True | False | "auto".
     # The parameter tree is identical either way. "auto" currently
     # resolves to OFF: the round-5 flagship A/B measured the fused kernel
-    # at 0.91x XLA's composition (40.9k -> 37.3k tok/s at 350M/seq1024 —
-    # benchmarks/model_bench_results.json; XLA's matmul pipelining +
+    # at 0.91x XLA's composition (40.9k -> 37.3k tok/s at 350M/seq1024,
+    # rounds 1-5 runtime, ROUND5_NOTES.md; XLA's matmul pipelining +
     # multi-output fusions beat hand fusion at these shapes). Kept as an
     # explicit option and parity-tested; does not compose with model
     # parallelism (the Pallas call is not GSPMD-partitionable)
@@ -208,8 +209,7 @@ class CausalSelfAttention(nn.Module):
 
             scfg = cfg.sparse_attention
             layout = np.asarray(scfg.make_layout(T))
-            if supports_pallas(scfg.block, T) and \
-                    jax.default_backend() == "tpu":
+            if supports_pallas(scfg.block, T) and backend.on_tpu():
                 y = block_sparse_flash_attention(
                     q, k, v, layout, scfg.block, causal=True)
             else:

@@ -41,6 +41,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import backend
 
 
 @dataclasses.dataclass(frozen=True)
@@ -286,7 +287,7 @@ class CachedAttention(nn.Module):
             return False
         if cfg.decode_kernel == "on":
             return True
-        return jax.default_backend() == "tpu"
+        return backend.on_tpu()
 
     def _paged_decode_step(self, q, k, v, kv_cache, positions,
                            deterministic):

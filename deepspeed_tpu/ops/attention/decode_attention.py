@@ -39,7 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import LANES, NEG_INF, SUBLANES, _interpret
+from .. import backend
+from .flash_attention import LANES, NEG_INF, SUBLANES
 
 DEFAULT_BLOCK_S = 1024
 LONG_CACHE_BLOCK_S = 4096  # >= 8k caches: grid overhead, not bandwidth,
@@ -227,6 +228,23 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         is ever materialized.
     Returns (B, H, D) in q's dtype.
     """
+    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32),
+                               (q.shape[0],))
+    kernel = functools.partial(_decode_attention_local, scale=scale,
+                               block_s=block_s)
+    B, H = backend.BATCH, backend.HEADS
+    return backend.shard_kernel(
+        kernel, (B, H, None),
+        q=(q, (B, H, None)), k_cache=(k_cache, (B, H, None, None)),
+        v_cache=(v_cache, (B, H, None, None)), lengths=(lengths, (B,)),
+        alibi_slopes=(alibi_slopes, (H,)), k_scale=(k_scale, (B, H, None)),
+        v_scale=(v_scale, (B, H, None)))
+
+
+def _decode_attention_local(q, k_cache, v_cache, lengths, *, scale,
+                            alibi_slopes, k_scale, v_scale, block_s):
+    """:func:`decode_attention` on the sequences and heads one device
+    holds."""
     B, H, D = q.shape
     _, KV, Dc, S = k_cache.shape
     assert H % KV == 0, f"H={H} not a multiple of KV={KV}"
@@ -250,7 +268,6 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         q = q.astype(k_cache.dtype)
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     if alibi_slopes is None:
         slopes = jnp.zeros((H,), jnp.float32)
         alibi = False
@@ -319,6 +336,6 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, 1, D), q.dtype),
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(*operands)
     return out.reshape(B, H, D).astype(out_dtype)

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import backend
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -41,10 +43,6 @@ ATTN_SAVE_NAMES = ("flash_out", "flash_lse")
 # blocks satisfy the (8, 128) tiling rule; stats scratch is lane-width.
 SUBLANES = 8
 LANES = 128
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -168,7 +166,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, block_q: int, block_k: in
                 jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
                 jax.ShapeDtypeStruct((bh, SUBLANES, seq_q), jnp.float32),
             ],
-            interpret=_interpret(),
+            interpret=backend.pallas_interpret(),
         )(q, k, v)
         return out, lse
 
@@ -199,7 +197,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, block_q: int, block_k: in
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(q, k, v)
     return out, lse
 
@@ -333,7 +331,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal: bool, scale: float, block_q: in
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(q, k, v, do, lse, delta)
 
     grid_k = (bh, seq_k // block_k, seq_q // block_q)
@@ -368,7 +366,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal: bool, scale: float, block_q: in
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -436,9 +434,7 @@ def use_flash_by_default(seq: int) -> bool:
     4k-8k, BASELINE.md crossover table); below that XLA wins. Off-TPU
     (interpret mode) it is only for tests. Shapes whose auto blocks would
     degenerate (seq with a tiny power-of-two factor) stay on XLA."""
-    import jax
-
-    return jax.default_backend() == "tpu" and seq >= 1024 \
+    return backend.on_tpu() and seq >= 1024 \
         and min(auto_block_sizes(seq)) >= 128
 
 
@@ -469,13 +465,20 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     block_q = auto_q if block_q is None else block_q
     block_k = auto_k if block_k is None else block_k
 
-    # (B, T, H, D) → (B*H, T, D)
-    def to_bh(x, T):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, T, d)
+    def kernel(q, k, v):
+        b, _, h, _ = q.shape        # this shard's sequences and heads
 
-    out = _flash_attention(to_bh(q, t), to_bh(k, s), to_bh(v, s), causal, scale,
-                           block_q, block_k)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+        # (B, T, H, D) → (B*H, T, D)
+        def to_bh(x, T):
+            return x.transpose(0, 2, 1, 3).reshape(b * h, T, d)
+
+        out = _flash_attention(to_bh(q, t), to_bh(k, s), to_bh(v, s), causal,
+                               scale, block_q, block_k)
+        return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+    dims = (backend.BATCH, None, backend.HEADS, None)
+    return backend.shard_kernel(kernel, dims, q=(q, dims), k=(k, dims),
+                                v=(v, dims))
 
 
 def mha_reference(q, k, v, causal: bool = True, scale: Optional[float] = None):

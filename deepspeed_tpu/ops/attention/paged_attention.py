@@ -16,10 +16,16 @@ op-for-op the dense decode kernel's
 same online-softmax update order, same masking, same scratch shapes) with
 the position block pinned to ONE PAGE. A single-token call is therefore
 bitwise-identical to ``decode_attention(q, dense_k, dense_v, lengths,
-block_s=page_size)`` on the gathered dense view — in interpret mode on
-CPU and natively on TPU — which is what lets the serving tests pin the
-paged-kernel arm against the dense path exactly (TransformerConfig's
-``decode_block`` pins the oracle's block granule to the page size).
+block_s=page_size)`` on the gathered dense view in interpret mode on
+the CPU, which is what lets the serving tests pin the paged-kernel arm
+against the dense path exactly (TransformerConfig's ``decode_block``
+pins the oracle's block granule to the page size). On the TPU that twin
+exists only for pages of at least 128 positions: the dense kernel puts
+``block_s`` on the lane axis and Mosaic rejects a 64-wide block there,
+so at the default page size (64) the pinned oracle does not lower and
+the arms are compared within a bf16 tolerance instead (chip_smoke.py:
+max |delta logit| 0.041 at logit scale 5.0 on a v5e, PR 21). Bitwise
+equality on the chip at page size 128 has not been tried.
 
 Garbage is masked by length, never by table lookups: dead grid steps
 (pages past a slot's live length) clamp their index map to the slot's
@@ -45,7 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import LANES, NEG_INF, SUBLANES, _interpret
+from .. import backend
+from .flash_attention import LANES, NEG_INF, SUBLANES
 
 __all__ = ["paged_decode_attention", "MAX_QUERY_ROWS"]
 
@@ -151,6 +158,26 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         dequantization scales for a quantized page pool.
     Returns (B, T, H, D) in q's dtype.
     """
+    starts = jnp.broadcast_to(jnp.asarray(starts, jnp.int32),
+                              (q.shape[0],))
+    kernel = functools.partial(_paged_decode_attention_local, scale=scale)
+    B, H = backend.BATCH, backend.HEADS
+    # the page pool has no batch dim: every device holds every page of
+    # its KV heads, and its slots' rows of the table
+    return backend.shard_kernel(
+        kernel, (B, None, H, None),
+        q=(q, (B, None, H, None)), k_pages=(k_pages, (None, H, None, None)),
+        v_pages=(v_pages, (None, H, None, None)), table=(table, (B, None)),
+        starts=(starts, (B,)), alibi_slopes=(alibi_slopes, (H,)),
+        k_scale_pages=(k_scale_pages, (None, H, None)),
+        v_scale_pages=(v_scale_pages, (None, H, None)))
+
+
+def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, *,
+                                  scale, alibi_slopes, k_scale_pages,
+                                  v_scale_pages):
+    """:func:`paged_decode_attention` on the slots and heads one device
+    holds."""
     B, T, H, D = q.shape
     P, KV, Dc, ps = k_pages.shape
     maxP = table.shape[1]
@@ -175,7 +202,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         q = q.astype(k_pages.dtype)
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    starts = jnp.broadcast_to(jnp.asarray(starts, jnp.int32), (B,))
     if alibi_slopes is None:
         slopes = jnp.zeros((H,), jnp.float32)
         alibi = False
@@ -248,7 +274,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, SUBLANES, D), q.dtype),
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(*operands)
     out = out.reshape(B, H, SUBLANES, D)[:, :, :T]
     return out.transpose(0, 2, 1, 3).astype(out_dtype)

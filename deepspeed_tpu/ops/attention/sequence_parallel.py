@@ -32,6 +32,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ...parallel.mesh import DATA_AXIS, EXPERT_AXIS, SEQ_AXIS, get_mesh
@@ -160,15 +161,6 @@ def ulysses_attention_local(q, k, v, *, axis_name: str = SEQ_AXIS,
 # ---------------------------------------------------------------------------
 # shard_map wrappers taking GLOBAL arrays
 # ---------------------------------------------------------------------------
-def _shard_map():
-    try:
-        from jax import shard_map  # jax >= 0.8
-        return shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-
-
 def _seq_specs(batch_axes, axis_name, head_axes):
     return P(batch_axes, axis_name, head_axes, None)
 
@@ -183,8 +175,8 @@ def ring_attention(q, k, v, *, mesh=None, axis_name: str = SEQ_AXIS,
     spec = _seq_specs(batch_axes, axis_name, head_axes)
     fn = functools.partial(ring_attention_local, axis_name=axis_name,
                           causal=causal, scale=scale)
-    return _shard_map()(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec)(q, k, v)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec)(q, k, v)
 
 
 def ulysses_attention(q, k, v, *, mesh=None, axis_name: str = SEQ_AXIS,
@@ -196,8 +188,8 @@ def ulysses_attention(q, k, v, *, mesh=None, axis_name: str = SEQ_AXIS,
     spec = _seq_specs(batch_axes, axis_name, head_axes)
     fn = functools.partial(ulysses_attention_local, axis_name=axis_name,
                           causal=causal, scale=scale, attn_fn=attn_fn)
-    return _shard_map()(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec)(q, k, v)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec)(q, k, v)
 
 
 class DistributedAttention:

@@ -7,7 +7,10 @@ The reference JIT-compiles torch CUDA extensions per op at first use
 ctypes — no pybind11/torch toolchain. Pallas kernels need no building.
 
 ``OpBuilder.load()`` compiles on first use into ``_build/`` next to this
-file (keyed by source mtime) and returns a ``ctypes.CDLL``. Failures mark
+file and returns a ``ctypes.CDLL``. The object's file name carries a hash of
+its sources, its flags and the CPU it was built for (``-march=native``), so
+a binary left behind by other sources or another machine is never loaded —
+it simply has another name. Failures mark
 the builder incompatible (``is_compatible()`` → False) so callers can fall
 back to pure-numpy paths — the analog of the reference's compatibility
 probes.
@@ -16,7 +19,9 @@ probes.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 from typing import List, Optional
 
@@ -24,6 +29,21 @@ from ...utils.logging import logger
 
 _CSRC = os.path.join(os.path.dirname(__file__), "..", "csrc")
 _BUILD = os.path.join(os.path.dirname(__file__), "..", "_build")
+
+
+def _machine_tag() -> str:
+    """Architecture plus the CPU feature flags ``-march=native`` compiles
+    for (x86 ``flags`` / arm ``Features`` line of /proc/cpuinfo)."""
+    features = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    features = line.strip()
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()}|{features}"
 
 
 class OpBuilder:
@@ -36,22 +56,32 @@ class OpBuilder:
     def absolute_sources(self) -> List[str]:
         return [os.path.normpath(os.path.join(_CSRC, s)) for s in self.SOURCES]
 
-    def so_path(self) -> str:
-        return os.path.join(_BUILD, f"{self.NAME}.so")
+    def _compile_flags(self) -> List[str]:
+        return (["-O3", "-shared", "-fPIC", "-std=c++17", "-fopenmp",
+                 "-march=native"] + self.EXTRA_FLAGS)
 
-    def _stale(self) -> bool:
-        so = self.so_path()
-        if not os.path.exists(so):
-            return True
-        so_mtime = os.path.getmtime(so)
-        return any(os.path.getmtime(s) > so_mtime for s in self.absolute_sources())
+    def build_key(self) -> str:
+        """Hash of everything the built object depends on: source bytes,
+        compile flags, and — because of ``-march=native`` — this CPU."""
+        h = hashlib.sha256()
+        for src in self.absolute_sources():
+            with open(src, "rb") as f:
+                h.update(f.read())
+        h.update(" ".join(self._compile_flags()).encode())
+        h.update(_machine_tag().encode())
+        return h.hexdigest()[:16]
+
+    def so_path(self) -> str:
+        return os.path.join(_BUILD, f"{self.NAME}-{self.build_key()}.so")
 
     def build(self) -> str:
         os.makedirs(_BUILD, exist_ok=True)
         so = self.so_path()
-        cmd = (["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-fopenmp",
-                "-march=native"] + self.EXTRA_FLAGS
-               + self.absolute_sources() + ["-o", so])
+        # build beside the target and rename: a concurrent loader never
+        # sees a half-written object under the keyed name
+        tmp = f"{so}.{os.getpid()}.tmp"
+        cmd = (["g++"] + self._compile_flags()
+               + self.absolute_sources() + ["-o", tmp])
         try:
             subprocess.run(cmd, check=True, capture_output=True, text=True)
         except (subprocess.CalledProcessError, FileNotFoundError) as e:
@@ -63,6 +93,7 @@ class OpBuilder:
             except Exception:
                 raise RuntimeError(
                     f"building native op {self.NAME} failed:\n{stderr}") from e
+        os.replace(tmp, so)
         return so
 
     def is_compatible(self) -> bool:
@@ -77,10 +108,11 @@ class OpBuilder:
             return OpBuilder._cache[self.NAME]
         if os.environ.get("DS_SKIP_NATIVE_BUILD"):
             raise RuntimeError("native builds disabled by DS_SKIP_NATIVE_BUILD")
-        if self._stale():
+        so = self.so_path()
+        if not os.path.exists(so):
             logger.info(f"building native op {self.NAME} ...")
             self.build()
-        lib = ctypes.CDLL(self.so_path())
+        lib = ctypes.CDLL(so)
         self._declare(lib)
         OpBuilder._cache[self.NAME] = lib
         return lib

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import backend
+
 DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 512
 DEFAULT_BLOCK_K = 1024
@@ -46,10 +48,6 @@ DEFAULT_BLOCK_K = 1024
 # for Mosaic's own staging. Shapes whose tile plan exceeds this run the
 # jnp reference instead of failing to compile at serve time.
 VMEM_BUDGET_BYTES = 10 * 1024 * 1024
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _plan_vmem_bytes(bm: int, bk: int, bn: int) -> int:
@@ -185,7 +183,7 @@ def int8_matmul(x: jnp.ndarray, w: jnp.ndarray, scales: jnp.ndarray,
     """
     forced = interpret is True
     if interpret is None:
-        if _interpret():
+        if backend.pallas_interpret():
             return int8_matmul_reference(x, w, scales, out_dtype)
         interpret = False
     K, N = w.shape

@@ -17,9 +17,9 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
+from .. import backend
 from .int8_matmul import int8_matmul, int8_matmul_reference
 
 LANE = 128
@@ -58,7 +58,7 @@ class QuantDense(nn.Module):
         else:
             y = int8_matmul(x, kernel, scale, out_dtype=self.dtype,
                             interpret=(True if self.kernel_mode == "on" and
-                                       jax.default_backend() != "tpu"
+                                       backend.pallas_interpret()
                                        else None))
         if n_pad != self.features:
             y = y[..., :self.features]

@@ -40,7 +40,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..attention.flash_attention import LANES, NEG_INF, SUBLANES, _interpret
+from .. import backend
+from ..attention.flash_attention import LANES, NEG_INF, SUBLANES
 
 MIN_KERNEL_BLOCK = 128
 
@@ -156,7 +157,7 @@ def _sparse_fwd(q, k, v, idx, cnt, *, scale: float, causal: bool, block: int,
             jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, SUBLANES, seq), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(idx, cnt, q, k, v)
     return out, lse
 
@@ -290,7 +291,7 @@ def _sparse_bwd(q, k, v, out, lse, do, idx, cnt, idx_t, cnt_t, *,
                           block=block, num_heads=num_heads),
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(idx, cnt, q, k, v, do, lse, delta)
 
     dkv_spec = pltpu.PrefetchScalarGridSpec(
@@ -333,7 +334,7 @@ def _sparse_bwd(q, k, v, out, lse, do, idx, cnt, idx_t, cnt_t, *,
             jax.ShapeDtypeStruct((bh, seq, d), k.dtype),
             jax.ShapeDtypeStruct((bh, seq, d), v.dtype),
         ],
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(idx_t, cnt_t, q, k, v, do, lse, delta)
     return dq, dk, dv
 

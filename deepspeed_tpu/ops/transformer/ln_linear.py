@@ -38,15 +38,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import backend
+
 SUBLANES = 8
 # checkpoint_name tags (see ops/attention/flash_attention.py ATTN_SAVE_NAMES):
 # saving (y, stats) lets the "dots" remat policy skip re-running the fused
 # forward kernel in the backward pass
 LN_SAVE_NAMES = ("ln_linear_out", "ln_linear_stats")
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _fwd_kernel(x_ref, g_ref, b_ref, w_ref, bias_ref, y_ref, mean_ref,
@@ -147,7 +145,7 @@ def _ln_linear_fwd_impl(x, gamma, beta, w, bias, *, eps, block_m, block_n):
             jax.ShapeDtypeStruct((SUBLANES, m), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_m, c), x.dtype)],
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(x, gamma.reshape(1, c), beta.reshape(1, c), w, bias.reshape(1, n))
     return y, mean, rstd
 
@@ -187,7 +185,7 @@ def _ln_linear_bwd_impl(x, gamma, mean, rstd, w, dy, *, block_m, block_n):
             jax.ShapeDtypeStruct((m // block_m * SUBLANES, c), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_m, c), jnp.float32)],
-        interpret=_interpret(),
+        interpret=backend.pallas_interpret(),
     )(dy, w, x, gamma.reshape(1, c), mean, rstd)
     return dx, dg_parts, db_parts
 

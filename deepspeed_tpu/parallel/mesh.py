@@ -135,12 +135,16 @@ def build_mesh(config: Optional[MeshConfig] = None,
         dims = (config.pipe, config.data // mics_shard_size,
                 mics_shard_size, config.expert, config.seq, config.model)
         axes = MICS_MESH_AXES
-    try:
+    devices = list(devices)
+    if devices[0].platform == "cpu":
+        # forced host devices have no torus to respect
+        device_array = np.asarray(devices).reshape(dims)
+    else:
+        # on an accelerator a mesh that cannot be laid onto the
+        # interconnect is an error, never a silent plain reshape
         from jax.experimental import mesh_utils
 
-        device_array = mesh_utils.create_device_mesh(dims, devices=list(devices))
-    except Exception:  # non-TPU platforms (CPU test meshes) lack torus metadata
-        device_array = np.asarray(list(devices)).reshape(dims)
+        device_array = mesh_utils.create_device_mesh(dims, devices=devices)
     return Mesh(device_array, axes)
 
 

@@ -50,23 +50,12 @@ import jax.flatten_util
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ....parallel import mesh as mesh_mod
 from ...comm.compressed import compressed_allreduce
 
 LANES = 128
-
-
-def _supports_auto_axes() -> bool:
-    """jax >= 0.9 shard_map takes ``axis_names`` (the set of MANUAL axes;
-    every other mesh axis stays GSPMD-auto) — what lets the exchange be
-    manual over ``data`` while TP sharding constraints keep working."""
-    import inspect
-    return "axis_names" in inspect.signature(shard_map).parameters
 
 
 def is_enabled(config, mesh) -> bool:
@@ -87,11 +76,6 @@ def check_supported(engine) -> None:
     if mesh_mod.get_sequence_parallel_world_size() > 1:
         raise ValueError("comm_backend_name=compressed does not compose "
                          "with sequence parallelism (sp=1); dp x tp only")
-    if engine.mp_world_size != 1 and not _supports_auto_axes():
-        raise ValueError("comm_backend_name=compressed with model "
-                         "parallelism needs jax.shard_map axis_names "
-                         "support (jax >= 0.9); this jax is older — "
-                         "run with mp=1")
     if engine.dp_world_size < 2:
         raise ValueError("comm_backend_name=compressed needs dp_world > 1 "
                          "(single rank has no wire to compress)")
@@ -380,19 +364,14 @@ def build_train_step(engine):
         metric_specs = spec_like(
             {"loss": 0, "overflow": 0, "grad_norm": 0, "lr": 0,
              "loss_scale": 0, "comm_bytes": 0}, rep)
-        # jax >= 0.8 renamed check_rep → check_vma; disable either way (the
-        # replicated outputs are made identical by the exchange itself)
-        import inspect
-        kw = {"check_vma": False} \
-            if "check_vma" in inspect.signature(shard_map).parameters \
-            else {"check_rep": False}
-        if _supports_auto_axes():
-            # manual over data only; model (TP) stays a GSPMD auto axis
-            kw["axis_names"] = frozenset({axis})
+        # check_vma off: the replicated outputs are made identical by the
+        # exchange itself. Manual over ``data`` only (axis_names); model
+        # (TP) stays a GSPMD auto axis so its sharding constraints hold
         fn = shard_map(
             local_step, mesh=mesh,
             in_specs=(state_specs, onebit_specs, bspecs),
-            out_specs=(state_specs, onebit_specs, metric_specs), **kw)
+            out_specs=(state_specs, onebit_specs, metric_specs),
+            axis_names=frozenset({axis}), check_vma=False)
         new_state, new_onebit, metrics = fn(state, onebit, stacked_batch)
         new_state["onebit"] = new_onebit
         new_state["params"] = jax.lax.with_sharding_constraint(

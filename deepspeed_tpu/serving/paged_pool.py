@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import backend
 from .prefix_cache import PrefixCache
 from .slot_pool import SlotPool
 
@@ -764,7 +765,7 @@ class PagedKVPool(SlotPool):
             return False
         if self.kernel == "on":
             return True
-        return jax.default_backend() == "tpu"
+        return backend.on_tpu()
 
     def run_decode(self, engine: Any, tokens, pos):
         """One masked decode step for every slot over paged storage;

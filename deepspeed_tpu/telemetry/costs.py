@@ -48,20 +48,22 @@ try:  # pragma: no cover - jax is always present in this repo
 except Exception:  # pragma: no cover
     jax = None
 
-# (peak_flops, peak_bytes_per_s) by device-kind substring, first match
-# wins. Dense bf16 peaks; HBM bandwidth from public TPU system specs.
-_DEVICE_PEAKS: Tuple[Tuple[str, Tuple[float, float]], ...] = (
-    ("v6e", (918e12, 1640e9)),
-    ("v5p", (459e12, 2765e9)),
-    ("v5e", (197e12, 819e9)),
-    ("v5lite", (197e12, 819e9)),
-    ("v4", (275e12, 1228e9)),
-    ("v3", (123e12, 900e9)),
-    ("v2", (46e12, 700e9)),
-)
-# CPU (and unknown devices) get nominal figures so MFU stays a nonzero,
-# host-comparable ratio; gates on it are warn-only off-TPU.
-_NOMINAL_PEAKS = (1e12, 1e11)
+# (peak_flops, peak_bytes_per_s) keyed by the exact ``device_kind`` JAX
+# reports (the strings of ``jax.experimental.mesh_utils``). Dense bf16
+# peaks and HBM bandwidth from the public Cloud TPU system specs; a
+# v5e reports itself as "TPU v5 lite" (chip_smoke.py prints the string).
+_DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v2": (46e12, 700e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+}
+# the CPU has no published peak: a nominal figure keeps MFU a nonzero,
+# host-comparable ratio in the tests; it is never a device metric
+_NOMINAL_CPU_PEAKS = (1e12, 1e11)
 
 _MISSING = object()
 
@@ -79,17 +81,21 @@ def _mesh_device_count() -> int:
 
 
 def resolve_peaks(device=None) -> Tuple[float, float]:
-    """(peak_flops, peak_bytes_per_s) for the first local device."""
-    kind = ""
-    try:
-        dev = device if device is not None else jax.devices()[0]
-        kind = str(getattr(dev, "device_kind", "")).lower()
-    except Exception:
-        pass
-    for key, peaks in _DEVICE_PEAKS:
-        if key in kind:
-            return peaks
-    return _NOMINAL_PEAKS
+    """(peak_flops, peak_bytes_per_s) for ``device`` (default: the first
+    local device). The CPU resolves to a nominal figure; an accelerator
+    whose ``device_kind`` is not in the table raises — an invented peak
+    would make every utilization gauge on it wrong by its ratio."""
+    dev = device if device is not None else jax.devices()[0]
+    if dev.platform == "cpu":
+        return _NOMINAL_CPU_PEAKS
+    kind = str(dev.device_kind)
+    if kind not in _DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peak for device_kind {kind!r} (platform "
+            f"{dev.platform!r}); add it to telemetry/costs.py "
+            f"_DEVICE_PEAKS with its source — known: "
+            f"{sorted(_DEVICE_PEAKS)}")
+    return _DEVICE_PEAKS[kind]
 
 
 def _abstract(x: Any) -> Any:

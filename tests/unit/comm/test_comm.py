@@ -75,7 +75,7 @@ def shmap_mesh():
 
 
 def _shmap(mesh, fn, *args, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs))(*args)

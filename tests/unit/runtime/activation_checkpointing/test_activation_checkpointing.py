@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import deepspeed_tpu
 from deepspeed_tpu.runtime.activation_checkpointing import checkpointing as ckpt

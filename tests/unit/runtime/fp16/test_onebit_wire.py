@@ -203,11 +203,7 @@ def test_wire_composes_with_tensor_parallelism(stage):
     from deepspeed_tpu.parallel import initialize_mesh
     from deepspeed_tpu.parallel import mesh as mesh_mod
     from deepspeed_tpu.models.transformer_lm import transformer_sharding_rules
-    from deepspeed_tpu.runtime.fp16.onebit import wire
     from deepspeed_tpu.runtime.zero.policy import ShardingRules
-
-    if not wire._supports_auto_axes():
-        pytest.skip("shard_map axis_names (jax >= 0.9) required for tp>1")
 
     batches = _batches(10, seed=7)
 

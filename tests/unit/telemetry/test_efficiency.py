@@ -299,6 +299,24 @@ def test_cost_model_peaks_scale_with_mesh_device_count():
     assert four.summary()["num_devices"] == 4
 
 
+def test_resolve_peaks_knows_the_chip_and_invents_nothing():
+    """The v5e reports itself as "TPU v5 lite" (chip_smoke.py, PR 21): it
+    must resolve to the published 197 TFLOP/s / 819 GB/s, and an
+    accelerator that is not in the table is an error, never a made-up
+    peak. Only the CPU gets a nominal figure (for these tests)."""
+    from types import SimpleNamespace
+
+    from deepspeed_tpu.telemetry.costs import resolve_peaks
+
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert resolve_peaks(v5e) == (197e12, 819e9)
+    unknown = SimpleNamespace(platform="tpu", device_kind="TPU v9 mega")
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        resolve_peaks(unknown)
+    cpu = SimpleNamespace(platform="cpu", device_kind="cpu")
+    assert resolve_peaks(cpu) == resolve_peaks()   # tests run on the CPU
+
+
 def test_cost_model_autodetects_global_mesh():
     """num_devices=None resolves against the installed global mesh at
     construction (1 with no mesh — the single-chip default)."""
