@@ -8,7 +8,11 @@ Public API parity with ``deepspeed/__init__.py``: :func:`initialize` (:58),
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Union
+import time as _time
+
+_IMPORT_T0_NS = _time.perf_counter_ns()   # closed as ``setup/import`` below
+
+from typing import Any, Dict, Optional, Union  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -22,6 +26,7 @@ from .runtime.engine import DeepSpeedEngine  # noqa: F401
 from .utils.logging import log_dist, logger  # noqa: F401
 from .comm.comm import init_distributed  # noqa: F401  (≅ reference
 # deepspeed.init_distributed, deepspeed/__init__.py:303 re-export)
+from .telemetry import default_tracer as _default_tracer
 
 
 def initialize(args=None,
@@ -54,6 +59,16 @@ def initialize(args=None,
         config = getattr(args, "deepspeed_config", None)
     if config is None:
         raise ValueError("DeepSpeed requires --deepspeed_config or config=")
+    with _default_tracer().span("setup/build", entry="initialize"):
+        engine = _build_engine(model, model_parameters, training_data,
+                               lr_scheduler, collate_fn, config, loss_fn,
+                               sharding_rules, mesh)
+    return engine, engine.optimizer_def, engine.training_dataloader, engine.lr_scheduler
+
+
+def _build_engine(model, model_parameters, training_data, lr_scheduler,
+                  collate_fn, config, loss_fn, sharding_rules, mesh):
+    """The engine ``initialize`` returns, by the kind of model and config."""
 
     # PipelineModule → PipelineEngine dispatch (reference __init__.py:151-189)
     try:
@@ -96,7 +111,7 @@ def initialize(args=None,
                                  training_data=training_data,
                                  lr_scheduler=lr_scheduler, collate_fn=collate_fn,
                                  mesh=mesh)
-    return engine, engine.optimizer_def, engine.training_dataloader, engine.lr_scheduler
+    return engine
 
 
 def init_inference(model: Any = None, config: Union[str, Dict, None] = None, **kwargs):
@@ -124,7 +139,10 @@ def init_serving(model: Any = None, config: Union[str, Dict, None] = None,
     ``top_k``, ``top_p``, ``seed``, ``monitor``, ``spec_decode``,
     ``prefill_chunk`` and ``prefill_token_budget`` (stall-free chunked
     admission; 0 disables), the telemetry keys ``tracer`` (a
-    :class:`telemetry.Tracer`, or ``True`` for a default-capacity one),
+    :class:`telemetry.Tracer` of the server's own, or ``True`` for a
+    default-capacity one; given none the server records into the
+    process-wide ``telemetry.default_tracer()``, which is on, and
+    ``Tracer(enabled=False)`` silences the ring),
     ``registry``, ``strict_recompile`` (raise at the step boundary on
     any post-warmup recompile) and ``timeline_capacity``, which pass
     through to ServingEngine, plus
@@ -209,9 +227,13 @@ def init_serving(model: Any = None, config: Union[str, Dict, None] = None,
                   "fault_injector", "paged_kv", "overlap", "cost_model",
                   "slo", "flight_recorder", "dump_dir", "priority", "clock")
     serve_kwargs = {k: kwargs.pop(k) for k in serve_keys if k in kwargs}
-    engine = init_inference(model=model, config=config, **kwargs)
-    return ServingEngine(engine, num_slots=num_slots,
-                         max_queue_depth=max_queue_depth, **serve_kwargs)
+    tracer = _default_tracer()
+    with tracer.span("setup/build", entry="init_serving"):
+        engine = init_inference(model=model, config=config, **kwargs)
+        with tracer.span("setup/init_serving", slots=num_slots):
+            return ServingEngine(engine, num_slots=num_slots,
+                                 max_queue_depth=max_queue_depth,
+                                 **serve_kwargs)
 
 
 def add_config_arguments(parser):
@@ -227,3 +249,7 @@ def add_config_arguments(parser):
     group.add_argument("--deepscale_config", default=None, type=str,
                        help="Deprecated alias of --deepspeed_config")
     return parser
+
+
+_default_tracer().complete("setup/import", _IMPORT_T0_NS,
+                           _time.perf_counter_ns() - _IMPORT_T0_NS)

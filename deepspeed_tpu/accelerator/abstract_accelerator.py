@@ -102,9 +102,9 @@ class Accelerator(abc.ABC):
 
     # --- tracing ranges (NVTX analog; surfaced to jax profiler) ---
     def range_push(self, msg: str):
-        import jax.profiler
+        from ..telemetry import default_tracer
 
-        tc = jax.profiler.TraceAnnotation(msg)
+        tc = default_tracer().span(msg)
         tc.__enter__()
         self._range_stack = getattr(self, "_range_stack", [])
         self._range_stack.append(tc)

@@ -334,6 +334,7 @@ def _decode_attention_local(q, k_cache, v_cache, lengths, *, scale,
     )
     out = pl.pallas_call(
         kernel,
+        name="dense_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, 1, D), q.dtype),
         interpret=backend.pallas_interpret(),

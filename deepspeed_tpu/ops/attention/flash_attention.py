@@ -147,6 +147,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, block_q: int, block_k: in
         # single KV step: no online stats needed (see _fwd_single_kernel)
         out, lse = pl.pallas_call(
             functools.partial(_fwd_single_kernel, scale=scale, causal=causal),
+            name="flash_fwd",
             grid=(bh, seq_q // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
@@ -173,6 +174,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, block_q: int, block_k: in
     grid = (bh, seq_q // block_q, seq_k // block_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal),
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -312,6 +314,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal: bool, scale: float, block_q: in
     grid_q = (bh, seq_q // block_q, seq_k // block_k)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal),
+        name="flash_bwd_dq",
         grid=grid_q,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -337,6 +340,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal: bool, scale: float, block_q: in
     grid_k = (bh, seq_k // block_k, seq_q // block_q)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal),
+        name="flash_bwd_dkv",
         grid=grid_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0),

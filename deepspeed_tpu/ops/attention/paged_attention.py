@@ -272,6 +272,7 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, SUBLANES, D), q.dtype),
         interpret=backend.pallas_interpret(),

@@ -198,10 +198,11 @@ class DeepSpeedEngine:
         self.tput_timer = ThroughputTimer(
             batch_size=self.train_batch_size(), steps_per_output=self.steps_per_print())
         self.monitor = MonitorMaster(self._config.monitor_config)
-        # off by default; assign an enabled telemetry.Tracer to record
-        # train_batch phase spans (export via engine.tracer.export(path))
-        from ..telemetry import Tracer
-        self.tracer = Tracer(enabled=False)
+        # the process-wide tracer, on: train_batch's phase spans and the
+        # set-up spans (export via engine.tracer.export(path)); assign a
+        # telemetry.Tracer(enabled=False) to silence the ring
+        from ..telemetry import default_tracer
+        self.tracer = default_tracer()
         cl = self._config.comms_logger
         dist.configure(enabled=cl.enabled, prof_all=cl.prof_all, prof_ops=cl.prof_ops,
                        verbose=cl.verbose, debug=cl.debug)
@@ -387,6 +388,13 @@ class DeepSpeedEngine:
     # state / sharding construction
     # ------------------------------------------------------------------
     def _build_state(self, params_host) -> None:
+        with self.tracer.span("setup/build_state") as span:
+            self._place_state(params_host)
+            span.set(parameters=self._num_params, bytes_placed=sum(
+                getattr(x, "nbytes", 0)
+                for x in jax.tree_util.tree_leaves(self.state)))
+
+    def _place_state(self, params_host) -> None:
         mesh = self.mesh
         policy = self.policy
 
@@ -642,7 +650,9 @@ class DeepSpeedEngine:
         def fused_train_batch(state, stacked_batch):
             """One global step: grads over gas micro-batches + update."""
             loss, grads_sum, denom = grads_fn(state, stacked_batch)
-            new_state, metrics = update_from_grads(state, grads_sum, denom)
+            with jax.named_scope("optimizer"):
+                new_state, metrics = update_from_grads(state, grads_sum,
+                                                       denom)
             metrics["loss"] = loss
             return new_state, metrics
 
@@ -701,10 +711,12 @@ class DeepSpeedEngine:
             def body(carry, mb):
                 acc, loss_sum, r = carry
                 r, sub = jax.random.split(r)
-                loss, grads = micro_grads(params, mb, sub, scale)
-                acc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(jnp.float32), acc, grads)
-                acc = constrain_grads(acc, params)
+                with jax.named_scope("forward_backward"):
+                    loss, grads = micro_grads(params, mb, sub, scale)
+                with jax.named_scope("accumulate"):
+                    acc = jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(jnp.float32), acc, grads)
+                    acc = constrain_grads(acc, params)
                 return (acc, loss_sum + loss, r), None
 
             (grads_sum, loss_sum, _), _ = jax.lax.scan(
@@ -763,21 +775,24 @@ class DeepSpeedEngine:
         assert (data_iter is None) != (batch is None), \
             "pass exactly one of data_iter / batch"
         source = data_iter if data_iter is not None else batch
-        stacked = self._stack_micro_batches(source)
-        stacked = self._apply_curriculum(stacked)
-        if self.state is None:
-            first = jax.tree_util.tree_map(lambda x: x[0], stacked)
-            self._build_state(self._init_params_from_batch(first))
+        tracer = self.tracer
+        with tracer.span("train/step", step=self.global_steps,
+                         micro_batches=self.gradient_accumulation_steps()):
+            with tracer.span("train/stack_batch"):
+                stacked = self._stack_micro_batches(source)
+                stacked = self._apply_curriculum(stacked)
+            if self.state is None:
+                first = jax.tree_util.tree_map(lambda x: x[0], stacked)
+                self._build_state(self._init_params_from_batch(first))
 
-        if self._config.check_rank_consistency:
-            self._check_rank_consistency(stacked)
-        self._maybe_profile_flops(stacked)
-        self.timers(TRAIN_BATCH_TIMER).start()
-        self.tput_timer.start()
-        with self.tracer.span("train/step", step=self.global_steps):
+            if self._config.check_rank_consistency:
+                self._check_rank_consistency(stacked)
+            self._maybe_profile_flops(stacked)
+            self.timers(TRAIN_BATCH_TIMER).start()
+            self.tput_timer.start()
             if self._param_offload is not None:
                 # streamed path: feed host micro batches (gas-major)
-                with self.tracer.span("train/offload_stream"):
+                with tracer.span("train/offload_stream"):
                     micros = [jax.tree_util.tree_map(
                         lambda x, i=i: np.asarray(x[i]), stacked)
                         for i in range(self.gradient_accumulation_steps())]
@@ -785,24 +800,26 @@ class DeepSpeedEngine:
                 self.state["step"] = self.state["step"] + 1
                 self.state["opt_step"] = self.state["opt_step"] + 1
             elif self._offload_enabled:
-                with self.tracer.span("train/fwd_bwd"):
+                with tracer.span("train/fwd_bwd"):
                     self.state, grads_dev, metrics = self._jit_offload_grads(
                         self.state, stacked)
-                with self.tracer.span("train/host_opt_step"):
+                with tracer.span("train/host_opt_step"):
                     self._host_optimizer_step(grads_dev, metrics)
             else:
-                with self.tracer.span("train/fwd_bwd_opt"):
+                with tracer.span("train/dispatch"):
                     self.state, metrics = self._jit_train_batch(
                         self.state, stacked)
             loss = metrics["loss"]
             self.global_steps += 1
             self.global_samples += self.train_batch_size()
             self.micro_steps += self.gradient_accumulation_steps()
-            # block on the step's outputs so the recorded wall time is
-            # compute, not async dispatch (see utils/timer.py)
-            self.tput_timer.stop(global_step=True, block_on=loss)
-            self.timers(TRAIN_BATCH_TIMER).stop(block_on=loss)
-        self._after_step(metrics)
+            with tracer.span("train/sync"):
+                # block on the step's outputs so the recorded wall time
+                # is compute, not async dispatch (see utils/timer.py)
+                self.tput_timer.stop(global_step=True, block_on=loss)
+                self.timers(TRAIN_BATCH_TIMER).stop(block_on=loss)
+            with tracer.span("train/after_step"):
+                self._after_step(metrics)
         return loss
 
     def _check_rank_consistency(self, stacked) -> None:

@@ -87,9 +87,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..telemetry import (FlightRecorder, MetricsRegistry, ProgramCostModel,
                          RecompileAfterWarmupError, RecompileWatchdog,
-                         SLOTracker, TimelineStore, Tracer)
+                         SLOTracker, TimelineStore, Tracer, default_tracer)
 from ..utils.logging import log_dist
-from ..utils.timer import SynchronizedWallClockTimer
 from .metrics import ServingMetrics
 from .paged_pool import PagedKVPool, PagePoolExhausted
 from .request import FinishReason, RejectReason, Request, RequestState
@@ -270,12 +269,12 @@ class ServingEngine:
         self._priority = getattr(self.scheduler, "config", None) \
             if priority else None
         # -- telemetry -------------------------------------------------
-        # the tracer defaults to DISABLED: span() then costs one branch
-        # + a shared null span, keeping the instrumented hot path within
-        # the 2% overhead budget when nobody is tracing
+        # given none, the server records into the process-wide tracer,
+        # which is ON (~2 us a span, a dozen spans a step); an explicit
+        # Tracer(enabled=False) silences the ring
         if tracer is True:
             tracer = Tracer()
-        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
+        self.tracer = tracer if tracer is not None else default_tracer()
         self.registry = registry if registry is not None else MetricsRegistry()
         self.step_id = 0                 # monotonic scheduler-step counter
         self.timelines = TimelineStore(capacity=timeline_capacity,
@@ -323,7 +322,13 @@ class ServingEngine:
         self.dump_dir = dump_dir
         self._tokens_emitted = 0        # lifetime tokens (all paths)
         self._tokens_prev = 0           # snapshot for per-step deltas
-        self._telemetry_ns = 0          # step-boundary instrumentation
+        self._after_step_ns = 0         # total of serving/after_step
+        # the step in flight, from its spans: when it opened, host
+        # nanoseconds by phase, and the programs it dispatched (the
+        # serving/step span's attributes at its close)
+        self._step_t0_ns = 0
+        self._phase_ns: dict = {}
+        self._dispatched: dict = {}
         # fleet identity: assigned by ReplicaRouter at join time, stamped
         # onto every timeline event so cross-replica journeys stitch
         self.replica_id: Optional[int] = None
@@ -489,7 +494,6 @@ class ServingEngine:
         # dispatch time and replayed — in dispatch order — after the one
         # blocking fetch in _drain_deferred at the end of step()
         self._deferred: List[Any] = []
-        self.timers = SynchronizedWallClockTimer()
         self._next_id = 0
         self._ensure_watch()
         log_dist(f"ServingEngine: slots={num_slots} policy={policy} "
@@ -651,12 +655,13 @@ class ServingEngine:
 
     @property
     def telemetry_overhead_s(self) -> float:
-        """Host seconds spent in the ISSUE-8 instrumentation: the
-        self-timed step-boundary block plus the cost model's per-call
-        accounting and the SLO tracker's observe/on_step work (one-time
-        AOT harvests are excluded — they are warmup, reported
-        separately in ``costs.summary()['harvest_s']``)."""
-        total = self._telemetry_ns / 1e9
+        """Host seconds spent in the instrumentation: the total of the
+        ``serving/after_step`` spans (paging gauges, SLO / cost-model /
+        flight-recorder bookkeeping, the recompile gate) plus the cost
+        model's per-call accounting and the SLO tracker's observe/on_step
+        work (one-time AOT harvests are excluded — they are warmup,
+        reported separately in ``costs.summary()['harvest_s']``)."""
+        total = self._after_step_ns / 1e9
         if self.costs is not None:
             total += self.costs.overhead_s
         if self.slo is not None:
@@ -666,16 +671,11 @@ class ServingEngine:
     def _telemetry_step(self, wall: float, running_at_entry: int,
                         granted: List[Request],
                         finished: List[Request]) -> None:
-        """Step-boundary efficiency/SLO/flight-recorder bookkeeping,
-        self-timed so benches can report instrumentation overhead_pct
-        honestly instead of diffing noisy wall clocks."""
+        """Step-boundary efficiency/SLO/flight-recorder bookkeeping
+        (timed by the ``serving/after_step`` span it runs under)."""
         costs, slo, rec = self.costs, self.slo, self.recorder
         if costs is None and slo is None and rec is None:
             return
-        t0 = time.perf_counter_ns()
-        # the SLO tracker self-times its own methods; subtract its delta
-        # from this envelope so telemetry_overhead_s never double-counts
-        slo_ns0 = slo.overhead_ns if slo is not None else 0
         tokens = self._tokens_emitted - self._tokens_prev
         self._tokens_prev = self._tokens_emitted
         if slo is not None:
@@ -688,13 +688,9 @@ class ServingEngine:
                 costs.reconcile_kv(self.pool, monitor=self.metrics.monitor,
                                    step=self.step_id, tracer=self.tracer)
         if rec is not None:
-            rec.record(self._step_record(wall, granted, finished))
-        spent = time.perf_counter_ns() - t0
-        if slo is not None:
-            spent -= slo.overhead_ns - slo_ns0
-        self._telemetry_ns += spent
+            rec.record(self._step_record(granted, finished))
 
-    def _step_record(self, wall: float, granted: List[Request],
+    def _step_record(self, granted: List[Request],
                      finished: List[Request]) -> dict:
         rec = {
             "step_id": self.step_id,
@@ -703,7 +699,11 @@ class ServingEngine:
             # replica's ring on this axis, not the per-replica step_id
             "t": self._now(),
             "replica": self.replica_id,
-            "wall_ms": wall * 1e3,
+            # where the step went, from its spans: the time since
+            # serving/step opened, and the phases closed so far
+            "wall_ms": (time.perf_counter_ns() - self._step_t0_ns) / 1e6,
+            "phases_ms": {k: v / 1e6 for k, v in self._phase_ns.items()},
+            "dispatched": dict(self._dispatched),
             "live": len(self._slot_req),
             "pending": self.scheduler.pending,
             "prefilling": len(self._prefill_queue),
@@ -798,7 +798,7 @@ class ServingEngine:
             self.costs.reset_totals()
         if self.slo is not None:
             self.slo.reset()
-        self._telemetry_ns = 0
+        self._after_step_ns = 0
         self.step_wall_s = 0.0
         self._tokens_prev = self._tokens_emitted
 
@@ -943,7 +943,11 @@ class ServingEngine:
         producer keeps ``_jit_cur_scatter`` at one executable per width
         no matter what layout GSPMD picked for the sampler output."""
         if callable(self._pool_sharding):
-            sh = self._pool_sharding("index", np.asarray(arr))
+            # the resolver reads the shape alone, so it gets the device
+            # array itself: np.asarray(arr) would fetch the tokens, a
+            # blocking sync in the middle of the step's dispatches
+            sh = self._pool_sharding(
+                "index", arr if hasattr(arr, "shape") else np.asarray(arr))
         else:
             sh = self._rep_sharding()
         return jax.device_put(arr, sh)
@@ -973,18 +977,29 @@ class ServingEngine:
     def _drain_deferred(self, *, sync: bool = True) -> None:
         """The step's single device sync: block on every deferred array
         at once, then replay the queued host bookkeeping in dispatch
-        order. ``serving/step_fetch`` times exactly the blocking wait."""
+        order. ``serving/sync`` times exactly the blocking wait."""
         if not self._deferred:
             return
         pending, self._deferred = self._deferred, []
         bundle = [a for arrays, _ in pending for a in arrays]
+        phases = self._phase_ns
         if sync:
-            timer = self.timers("serving/step_fetch")
-            timer.start()
-            # graftlint: allow[hot-loop-host-sync] -- the step's ONE deliberate sync: every deferred token/flag fetch collapses onto this block
-            timer.stop(block_on=bundle)
-        for arrays, callback in pending:
-            callback(*[np.asarray(a) for a in arrays])
+            with self.tracer.span("serving/sync", arrays=len(bundle)) as sp:
+                # the step's ONE deliberate sync: every deferred
+                # token/flag fetch collapses onto this block
+                jax.block_until_ready(bundle)
+            phases["sync"] = phases.get("sync", 0) + sp.dur_ns
+        with self.tracer.span("serving/replay", callbacks=len(pending)) as sp:
+            for arrays, callback in pending:
+                callback(*[np.asarray(a) for a in arrays])
+        phases["replay"] = phases.get("replay", 0) + sp.dur_ns
+
+    def _note_admit(self, rows: int, padded_tokens: int) -> None:
+        """An admission program of this step: requests seated, and the
+        tokens it computes (rows x bucket width, padding included)."""
+        d = self._dispatched
+        d["admit"] = d.get("admit", 0) + rows
+        d["admit_tokens"] = d.get("admit_tokens", 0) + padded_tokens
 
     @staticmethod
     def _bucket(n: int, cap: int) -> int:
@@ -1011,6 +1026,7 @@ class ServingEngine:
             ids[0, :T] = seed
             running_before = self._running_count()
             req.admit_time = self._now()
+            self._note_admit(1, width)
             with self.tracer.span("serving/admit", rid=req.request_id,
                                   tokens=T, width=width):
                 logits, pre_cache = eng._jit_prefill_at(
@@ -1277,6 +1293,7 @@ class ServingEngine:
                 lengths[i] = T
                 req.admit_time = self._now()
             t0 = self._now()
+            self._note_admit(n, nB * width)
             with self.tracer.span("serving/prefill_batch", n=n, width=width,
                                   batch=nB):
                 logits, pre_cache = eng._jit_prefill_at(
@@ -1357,6 +1374,7 @@ class ServingEngine:
             # the dispatch (allocating / CoW-forking under pressure may
             # preempt a victim — host work, so it happens outside jit)
             self._ensure_pages(slot, pos, pos + L)
+        self._dispatched["chunk"] = L
         with self.tracer.span("serving/prefill_chunk", rid=req.request_id,
                               pos=pos, len=L):
             if self._paged:
@@ -1779,17 +1797,23 @@ class ServingEngine:
         tracer = self.tracer
         t_step = self._now()
         running_at_entry = self._running_count()
-        with tracer.span("serving/step", step=self.step_id):
+        tokens_at_entry = self._tokens_emitted
+        self._dispatched = {}
+        phases = self._phase_ns = {}
+        with tracer.span("serving/step", step=self.step_id) as sp_step:
+            self._step_t0_ns = sp_step.t0_ns
             # boundary work first, outside the abort scope: expiring a
             # deadline or walking the load ladder touches no device
             # state, so a failure here must not FAIL innocent requests
-            self._expire_deadlines(finished)
-            self._update_load_state()
-            self._auto_preempt()
-            self._burn_preempt()
+            with tracer.span("serving/boundary") as sp:
+                self._expire_deadlines(finished)
+                self._update_load_state()
+                self._auto_preempt()
+                self._burn_preempt()
+            phases["boundary"] = sp.dur_ns
             tracer.counter("serving/occupancy", live=self.live_count,
                            pending=self.scheduler.pending)
-            with tracer.span("serving/grant"):
+            with tracer.span("serving/grant") as sp:
                 page_budget = self._grant_page_budget() if self._paged \
                     else None
                 page_cost = self._page_cost if self._paged else None
@@ -1807,6 +1831,8 @@ class ServingEngine:
                     granted = self.scheduler.grant(
                         self.pool.free_count, self.live_count,
                         page_budget=page_budget, page_cost=page_cost)
+            phases["grant"] = sp.dur_ns
+            t_granted = sp.t0_ns + sp.dur_ns
             try:
                 decoded = False
                 if self._overlap and self._running_count() \
@@ -1849,6 +1875,36 @@ class ServingEngine:
             except Exception:
                 self._abort_step(granted)
                 raise
+            # the SLO tracker times its own methods: its part of the
+            # after-step is taken out again, so telemetry_overhead_s
+            # (which adds slo.overhead_s) never counts it twice
+            slo_ns0 = self.slo.overhead_ns if self.slo is not None else 0
+            with tracer.span("serving/after_step") as sp:
+                # everything between grant and here that was neither the
+                # sync nor the replay: the dispatches and their host work
+                phases["dispatch"] = sp.t0_ns - t_granted \
+                    - phases.get("sync", 0) - phases.get("replay", 0)
+                wall = self._after_step(t_step, running_at_entry, granted,
+                                        finished)
+            self._after_step_ns += sp.dur_ns
+            if self.slo is not None:
+                self._after_step_ns -= self.slo.overhead_ns - slo_ns0
+            sp_step.set(tokens=self._tokens_emitted - tokens_at_entry,
+                        **self._dispatched)
+        if running_at_entry:
+            # a running request waited through this WHOLE step for its
+            # next token — the user-visible inter-token gap, admission
+            # work included (what stall-free admission bounds)
+            self.metrics.record_step_gap(wall)
+        return finished
+
+    def _after_step(self, t_step: float, running_at_entry: int,
+                    granted: List[Request],
+                    finished: List[Request]) -> float:
+        """The serial host work after the step's sync and replay: paging
+        gauges, telemetry, the recompile gate. Returns the step's wall on
+        the injected clock."""
+        tracer = self.tracer
         if self._paged:
             # per-step paging gauges (Prometheus export + dashboards):
             # occupancy and sharing level of the page pool
@@ -1867,9 +1923,6 @@ class ServingEngine:
         wall = self._now() - t_step
         self.step_wall_s += wall
         self._telemetry_step(wall, running_at_entry, granted, finished)
-        # drain serving/step_fetch (the single-sync wait) into
-        # timer/*_ms histograms alongside the rest of the step metrics
-        self.timers.publish(self.registry)
         # strict-mode recompile gate sits at the step boundary: raising
         # mid-step would trigger _abort_step and FAIL innocent in-flight
         # requests, when the state is actually perfectly consistent
@@ -1886,12 +1939,7 @@ class ServingEngine:
             self.metrics.record_step_overrun(wall, self.step_wall_budget_ms)
             tracer.instant("serving/step_overrun", wall_ms=wall * 1e3,
                            budget_ms=self.step_wall_budget_ms)
-        if running_at_entry:
-            # a running request waited through this WHOLE step for its
-            # next token — the user-visible inter-token gap, admission
-            # work included (what stall-free admission bounds)
-            self.metrics.record_step_gap(wall)
-        return finished
+        return wall
 
     def _effective_prefill_budget(self) -> Optional[int]:
         """The step's prefill token budget after degradation: PRESSURED
@@ -1978,6 +2026,7 @@ class ServingEngine:
         # the previous step's sampled tokens to round-trip the host
         tokens = self._cur_dev[:, None]
         pos = jnp.asarray(self.pool.positions())
+        self._dispatched["decode"] = len(running)
         with self.tracer.span("serving/decode", live=len(running)):
             if self._paged:
                 logits = self.pool.run_decode(eng, tokens, pos)
@@ -2083,6 +2132,7 @@ class ServingEngine:
         tokens = jnp.concatenate(
             [self._cur_dev[:, None], jnp.asarray(draft)], axis=1)
         self._rng, sub = jax.random.split(self._rng)
+        self._dispatched["decode"] = self._running_count()
         with self.tracer.span("serving/verify_k", k=K):
             if self._paged:
                 out_dev, n_emit_dev = self.pool.run_verify(

@@ -511,13 +511,14 @@ class PagedKVPool(SlotPool):
         program; src/dst are traced scalars so one compile covers every
         page pair."""
         out = dict(cs)
-        for key in ("k", "v", "k_scale", "v_scale"):
-            if key not in cs:
-                continue
-            leaf = cs[key]
-            page = jax.lax.dynamic_slice_in_dim(leaf, src, 1, 1)
-            out[key] = jax.lax.dynamic_update_slice_in_dim(leaf, page,
-                                                           dst, 1)
+        with jax.named_scope("copy"):
+            for key in ("k", "v", "k_scale", "v_scale"):
+                if key not in cs:
+                    continue
+                leaf = cs[key]
+                page = jax.lax.dynamic_slice_in_dim(leaf, src, 1, 1)
+                out[key] = jax.lax.dynamic_update_slice_in_dim(leaf, page,
+                                                               dst, 1)
         return out
 
     @staticmethod
@@ -530,9 +531,10 @@ class PagedKVPool(SlotPool):
         drops the write on the other side, so ONE compile covers every
         transfer size — the same trick the admission scatter uses.
         Runs on the SOURCE pool's devices."""
-        return {key: jnp.take(src_cs[key], src_ids, axis=1, mode="clip")
-                for key in ("k", "v", "k_scale", "v_scale")
-                if key in src_cs}
+        with jax.named_scope("gather"):
+            return {key: jnp.take(src_cs[key], src_ids, axis=1, mode="clip")
+                    for key in ("k", "v", "k_scale", "v_scale")
+                    if key in src_cs}
 
     @staticmethod
     def _scatter_pages_body(dst_cs: dict, block: dict, dst_ids):
@@ -541,11 +543,12 @@ class PagedKVPool(SlotPool):
         program. Runs on the DESTINATION pool's devices — the block
         arrived via :meth:`_land_block`."""
         out = dict(dst_cs)
-        for key in ("k", "v", "k_scale", "v_scale"):
-            if key not in dst_cs:
-                continue
-            out[key] = dst_cs[key].at[:, dst_ids].set(
-                block[key].astype(dst_cs[key].dtype), mode="drop")
+        with jax.named_scope("scatter"):
+            for key in ("k", "v", "k_scale", "v_scale"):
+                if key not in dst_cs:
+                    continue
+                out[key] = dst_cs[key].at[:, dst_ids].set(
+                    block[key].astype(dst_cs[key].dtype), mode="drop")
         return out
 
     def _scatter_cols(self, pool: dict, dense: dict, tables, positions):
@@ -554,6 +557,10 @@ class PagedKVPool(SlotPool):
         into the page pool through per-row page ``tables`` ((B,
         max_pages_per_slot)). Out-of-range positions and sentinel table
         entries scatter with ``mode="drop"`` — they touch nothing."""
+        with jax.named_scope("scatter"):
+            return self._scatter_cols_body(pool, dense, tables, positions)
+
+    def _scatter_cols_body(self, pool: dict, dense: dict, tables, positions):
         ps = self.page_size
         maxP = self.pages_per_slot
         sent = self.num_pages
@@ -626,7 +633,8 @@ class PagedKVPool(SlotPool):
         scatter = self._scatter_cols
 
         def dense_cache(cs):
-            dense = spec.dense_from_pages(cs, cs["table"])
+            with jax.named_scope("gather"):
+                dense = spec.dense_from_pages(cs, cs["table"])
             dense["index"] = cs["index"]
             return {"cache_store": dense}
 
@@ -659,7 +667,8 @@ class PagedKVPool(SlotPool):
             # window-masked chunk, scatter back only the chunk window
             vals = {k: v for k, v in cs.items()
                     if k not in ("index", "table")}
-            dense = spec.dense_from_pages(vals, row_table[None])
+            with jax.named_scope("gather"):
+                dense = spec.dense_from_pages(vals, row_table[None])
             dense["index"] = start[None]
             out, vars_ = module.apply(
                 {"params": dequant(params),

@@ -89,7 +89,7 @@ import numpy as np
 from ..telemetry.fleet import FleetTelemetry
 from ..telemetry.registry import MetricsRegistry
 from ..telemetry.slo import QuantileDigest
-from ..telemetry.tracer import Tracer, export_merged
+from ..telemetry.tracer import Tracer, default_tracer, export_merged
 from ..telemetry.watchdog import RecompileAfterWarmupError
 from .engine import ServingEngine
 from .request import FinishReason, Request, RequestState
@@ -165,9 +165,13 @@ class ReplicaRouter:
         # -- fleet observability (ISSUE 20) ----------------------------
         # the router's OWN tracer: dispatch/transfer spans, failover
         # and scale-event instants — one extra process lane in the
-        # merged Perfetto export. Disabled by default like the engine's.
-        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
-        self.tracer.process_name = "router"
+        # merged Perfetto export. Given none, the router records into the
+        # process-wide tracer like the engine (and names no lane of it).
+        if tracer is None:
+            tracer = default_tracer()
+        else:
+            tracer.process_name = "router"
+        self.tracer = tracer
         self.dump_dir = dump_dir
         # request journeys: jid -> {request_id, hops, homes, terminal},
         # a bounded log — the fleet post-mortem's dispatch record and
@@ -202,8 +206,9 @@ class ReplicaRouter:
         merged export)."""
         rep.replica_id = i
         rep.timelines.replica_id = i
-        rep.tracer.process_name = \
-            f"replica{i}:{getattr(rep, 'role', 'both')}"
+        if rep.tracer is not default_tracer():
+            rep.tracer.process_name = \
+                f"replica{i}:{getattr(rep, 'role', 'both')}"
 
     def _collect_metrics(self) -> None:
         """Registry collector (runs at every snapshot/scrape): copy the

@@ -5,12 +5,12 @@ This package is the TPU-idiomatic analogue of the reference
 DeepSpeed's observability stack, mapped feature-for-feature:
 
 * reference ``utils/timer.py`` (SynchronizedWallClockTimer) →
-  :class:`Tracer` spans. The reference synchronizes CUDA before
-  reading the clock; here the analogous hazard is JAX *async
-  dispatch* — a host-side timer around a jitted call measures
-  dispatch, not compute. Spans record honest host time; for compute
-  time, pass the step outputs to ``Timer.stop(block_on=...)``
-  (see ``utils/timer.py``) which ``block_until_ready``-s them first.
+  :class:`Tracer` spans, the one way the program times host work. The
+  reference synchronizes CUDA before reading the clock; here the
+  analogous hazard is JAX *async dispatch* — a host-side timer around
+  a jitted call measures dispatch, not compute. Spans record honest
+  host time; the device's time is the ``serving/sync`` /
+  ``train/sync`` span around the step's ``block_until_ready``.
 * reference ``monitor/`` (TensorBoard/WandB/csv scalar sinks) →
   :class:`MetricsRegistry` publishing ``(tag, value, step)`` events
   through the same ``MonitorMaster`` fan-out, plus the new machine-
@@ -66,6 +66,19 @@ DeepSpeed's observability stack, mapped feature-for-feature:
   turns the tests' "zero recompiles under churn" invariant into a
   runtime guarantee.
 
+What is on by default: :func:`default_tracer`, the process-wide
+:class:`Tracer`, enabled. Both engines record their step phases, request
+events and set-up (``setup/import``, ``setup/build``, one
+``setup/compile`` per compile) into it when no ``tracer=`` is passed; a
+span costs ~2.5 us. What a profiler session adds: every span is also a
+``jax.profiler.TraceAnnotation``, so under ``jax.profiler.start_trace``
+(xprof, ``chip_smoke.py``, the benchmark's ``--trace 1``) the same spans
+lie in the xplane's ``/host:CPU`` plane on the profiler's clock, beside
+the device's operations. Export, any time::
+
+    from deepspeed_tpu.telemetry import default_tracer
+    default_tracer().export("/tmp/trace.json")   # ui.perfetto.dev
+
 Quick start::
 
     from deepspeed_tpu.telemetry import Tracer
@@ -76,14 +89,14 @@ Quick start::
 
 Serving integration (all knobs on ``ds.init_serving``)::
 
-    srv = ds.init_serving(engine, tracer=Tracer(),
-                          strict_recompile=True)
-    srv.end_warmup()            # after warmup traffic
+    srv = ds.init_serving(engine, tracer=Tracer(),   # a ring of its own;
+                          strict_recompile=True)     # Tracer(enabled=False)
+    srv.end_warmup()            # after warmup traffic   silences the ring
     srv.timeline(request_id)    # per-request lifecycle events
     srv.publish_telemetry()     # registry -> monitor sinks
 """
 
-from .tracer import Tracer, export_merged, merge_chrome
+from .tracer import Tracer, default_tracer, export_merged, merge_chrome
 from .registry import Counter, Gauge, Histogram, MetricsRegistry
 from .timeline import TimelineStore
 from .watchdog import (RecompileAfterWarmupError, RecompileWatchdog,
@@ -99,6 +112,7 @@ from .fleet import (FleetTelemetry, FLEET_POST_MORTEM_KEYS,
 
 __all__ = [
     "Tracer",
+    "default_tracer",
     "merge_chrome",
     "export_merged",
     "FleetTelemetry",

@@ -1,14 +1,32 @@
 """Low-overhead span tracer with Chrome trace-event / Perfetto export.
 
-The tracer records *host-side* spans into a bounded, lock-protected
-ring buffer. It is deliberately dumb: every event is a small dict, the
-clock is ``time.perf_counter_ns`` (monotonic, ns resolution), and
+``Tracer.span`` is the ONE way the program times host work, and every
+span has three sinks:
+
+* a ``jax.profiler.TraceAnnotation`` opened under it, so whenever a
+  profiler session runs (an xprof capture, ``chip_smoke.py``, the
+  benchmark's ``--trace 1``) the span lies in the xplane's ``/host:CPU``
+  plane on the profiler's clock, beside the device's operations. With no
+  session the annotation costs ~0.4 us;
+* an event in a bounded, lock-protected ring buffer on
+  ``time.perf_counter_ns`` (monotonic, ns resolution): the clock of
+  ``Request.*_time``, of :class:`TimelineStore` and of a harness that
+  reads ``time.perf_counter``. Each event notes under ``profiled``
+  whether a profiler session was active, so a reader can pick the
+  traced stretch;
+* Chrome trace-event / Perfetto export of that ring.
+
+The tracer is deliberately dumb: every event is a small dict and
 nesting is never tracked explicitly — Chrome's trace viewer infers
 nesting of complete ("X") events from ts/dur containment per thread
 track, so a span stack on the host would only add overhead.
 
-Disabled tracers hand out a shared null span so instrumented hot paths
-pay one attribute load + one method call when tracing is off.
+:func:`default_tracer` is the process-wide tracer, ENABLED: engines use
+it when no ``tracer=`` is passed. An explicit ``Tracer(enabled=False)``
+silences the ring (its spans still time themselves and still open the
+annotation). Events whose name starts with ``setup/`` (import, build,
+one per compile) are kept outside the ring, so a long run's wrap-around
+never loses how the process started.
 
 Event kinds emitted (Chrome trace-event ``ph`` codes):
 
@@ -41,35 +59,36 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 
-class _NullSpan:
-    """No-op span returned by a disabled tracer; shared singleton."""
+try:  # pragma: no cover - jax is always present in this repo
+    from jax.profiler import TraceAnnotation as _Annotation
+except Exception:  # pragma: no cover
+    _Annotation = None
 
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **attrs):
-        return self
+# events whose name starts with this are kept outside the ring
+SETUP_PREFIX = "setup/"
+_SETUP_CAPACITY = 4096
 
 
-_NULL_SPAN = _NullSpan()
+def profiler_active() -> bool:
+    """Whether a profiler session is recording TraceAnnotations now."""
+    return _Annotation is not None and bool(_Annotation.is_enabled())
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager timing a block: opens a profiler annotation,
+    and records one complete ("X") event on exit if the tracer's ring
+    is on. ``t0_ns`` / ``dur_ns`` stay readable after the block."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "t0_ns", "dur_ns", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Optional[Dict[str, Any]]):
         self._tracer = tracer
         self.name = name
         self.args = args
-        self._t0 = 0
+        self.t0_ns = 0
+        self.dur_ns = 0
+        self._ann = None
 
     def set(self, **attrs):
         """Attach attributes to the span (visible in the trace viewer)."""
@@ -79,20 +98,26 @@ class _Span:
         return self
 
     def __enter__(self):
-        self._t0 = time.perf_counter_ns()
+        if _Annotation is not None:
+            self._ann = _Annotation(self.name)
+            self._ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        now = time.perf_counter_ns()
-        args = self.args
-        if exc_type is not None:
-            args = dict(args) if args else {}
-            args["error"] = exc_type.__name__
-        self._tracer._record({
-            "name": self.name, "ph": "X", "ts": self._t0,
-            "dur": now - self._t0,
-            "tid": threading.get_ident(), "args": args,
-        })
+        self.dur_ns = time.perf_counter_ns() - self.t0_ns
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._tracer.enabled:
+            args = self.args
+            if exc_type is not None:
+                args = dict(args) if args else {}
+                args["error"] = exc_type.__name__
+            self._tracer._record({
+                "name": self.name, "ph": "X", "ts": self.t0_ns,
+                "dur": self.dur_ns,
+                "tid": threading.get_ident(), "args": args,
+            })
         return False
 
 
@@ -114,6 +139,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._buf: List[Dict[str, Any]] = []
         self._pos = 0  # next overwrite index once the buffer is full
+        self._setup: List[Dict[str, Any]] = []  # setup/* events, kept
         self.events_total = 0
         # wall-clock anchor so exports can be correlated across files
         self.epoch_ns = time.perf_counter_ns()
@@ -125,7 +151,12 @@ class Tracer:
     def _record(self, ev: Dict[str, Any]) -> None:
         if not self.enabled:
             return
+        ev["profiled"] = profiler_active()
         with self._lock:
+            if ev["name"].startswith(SETUP_PREFIX):
+                if len(self._setup) < _SETUP_CAPACITY:
+                    self._setup.append(ev)
+                    return
             if len(self._buf) < self.capacity:
                 self._buf.append(ev)
             else:
@@ -135,9 +166,16 @@ class Tracer:
 
     def span(self, name: str, **args):
         """Context manager timing a block: ``with tracer.span("x"): ...``"""
-        if not self.enabled:
-            return _NULL_SPAN
         return _Span(self, name, args or None)
+
+    def complete(self, name: str, t0_ns: int, dur_ns: int, **args) -> None:
+        """Record a span that is already over (its start and length on
+        ``perf_counter_ns``): work a listener hears of only at its end,
+        such as a compile, or that began before this module existed,
+        such as the package's import."""
+        self._record({"name": name, "ph": "X", "ts": int(t0_ns),
+                      "dur": int(dur_ns), "tid": threading.get_ident(),
+                      "args": args or None})
 
     def trace(self, name: Optional[str] = None):
         """Decorator form of :meth:`span`."""
@@ -146,8 +184,6 @@ class Tracer:
 
             @functools.wraps(fn)
             def wrapper(*a, **kw):
-                if not self.enabled:
-                    return fn(*a, **kw)
                 with self.span(label):
                     return fn(*a, **kw)
             return wrapper
@@ -207,14 +243,16 @@ class Tracer:
         return max(0, self.events_total - self.capacity)
 
     def events(self) -> List[Dict[str, Any]]:
-        """Snapshot of buffered events, oldest first."""
+        """Snapshot of the kept ``setup/*`` events, then of the buffered
+        events, each oldest first."""
         with self._lock:
-            return self._buf[self._pos:] + self._buf[:self._pos]
+            return self._setup + self._buf[self._pos:] + self._buf[:self._pos]
 
     def clear(self) -> None:
         with self._lock:
             self._buf = []
             self._pos = 0
+            self._setup = []
             self.events_total = 0
 
     def to_chrome(self) -> Dict[str, Any]:
@@ -261,6 +299,15 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(trace, f)
         return len(trace["traceEvents"])
+
+
+_DEFAULT_TRACER = Tracer()
+
+
+def default_tracer() -> Tracer:
+    """The process-wide tracer, enabled: what an engine records into when
+    it is given no ``tracer=``."""
+    return _DEFAULT_TRACER
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,12 @@ This module promotes that into production telemetry:
 
 * a process-global ``jax.monitoring`` duration listener counts every
   backend compile (``backend_compiles``, unattributed — JAX fires it
-  for any program in the process);
+  for any program in the process) and leaves one ``setup/compile`` span
+  per compile in the process-wide tracer: the program's name (the
+  ``_WatchedJit``'s where the compile fell inside one, else JAX's own),
+  the abstract signature where known, the seconds of each stage
+  (tracing, lowering, the backend compile, which holds the retrieval
+  from the persistent cache) and whether that cache hit;
 * :class:`_WatchedJit` proxies wrap the named jitted entry points
   (``InferenceEngine._jit_*``, ``SlotPool._admit*_jit``); a call during
   which the global compile counter advanced is attributed a recompile
@@ -198,12 +203,21 @@ class _WatchedJit:
         _ensure_listener()
 
     def __call__(self, *args, **kwargs):
+        global _in_watched_call
         if self._recording:
             self._manifest.add(manifest_signature(args, kwargs))
         start = _compile_events
-        out = self._fn(*args, **kwargs)
+        # compiles of this call wait here for the name and the signature
+        held, _in_watched_call = _in_watched_call, []
+        try:
+            out = self._fn(*args, **kwargs)
+        finally:
+            compiles, _in_watched_call = _in_watched_call, held
+            sig = abstract_signature(args, kwargs) if compiles else None
+            for rec in compiles:
+                _emit_compile(rec, self._name, sig)
         if _compile_events > start and self._watchers:
-            sig = abstract_signature(args, kwargs)
+            sig = sig or abstract_signature(args, kwargs)
             for w in list(self._watchers):
                 w.record(self._name, sig)
         if self._cost_models:
@@ -246,14 +260,57 @@ def suppress_compile_events():
         _suppress_compiles -= 1
 
 
+# the stages of one compile, in the order JAX reports them; the backend
+# compile closes it (a hit in the persistent cache is retrieved inside it)
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+# seconds of the stages heard since the last backend compile. A stage
+# overwrites: tracing a program traces the jits inside it first, and the
+# outermost, whose time holds theirs, reports last
+_stages_heard: Dict[str, float] = {}
+# compile records of the _WatchedJit call in flight (None outside one)
+_in_watched_call: Optional[List[Dict[str, Any]]] = None
+
+
+def _emit_compile(rec: Dict[str, Any], program: Optional[str],
+                  signature: Optional[str]) -> None:
+    """One ``setup/compile`` span in the process-wide tracer."""
+    from .tracer import default_tracer
+
+    stages = rec["stages"]
+    dur_ns = int(sum(v for k, v in stages.items()
+                     if k != "cache_retrieval_s") * 1e9)
+    default_tracer().complete(
+        "setup/compile", rec["end_ns"] - dur_ns, dur_ns,
+        program=program or rec["fun"], fun=rec["fun"], signature=signature,
+        cache="hit" if "cache_retrieval_s" in stages else "miss", **stages)
+
+
 def _on_event_duration(event: str, duration: float, **kw) -> None:
     global _compile_events
-    if "backend_compile" in event:
-        if _suppress_compiles:
-            return
-        _compile_events += 1
-        for w in list(_active_watchdogs):
-            w._record_backend_compile(event, duration)
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    _stages_heard[stage] = float(duration)
+    if stage != "backend_s":
+        return
+    stages = dict(_stages_heard)
+    _stages_heard.clear()
+    if _suppress_compiles:
+        return
+    _compile_events += 1
+    for w in list(_active_watchdogs):
+        w._record_backend_compile(event, duration)
+    rec = {"fun": kw.get("fun_name"), "end_ns": time.perf_counter_ns(),
+           "stages": stages}
+    if _in_watched_call is not None:
+        _in_watched_call.append(rec)
+    else:
+        _emit_compile(rec, None, None)
 
 
 def _ensure_listener() -> None:
@@ -264,6 +321,11 @@ def _ensure_listener() -> None:
         _jax_monitoring.register_event_duration_secs_listener(
             _on_event_duration)
         _listener_registered = True
+
+
+# registered with the package, not with the first watchdog: a trainer has
+# no watchdog, and its compiles are set-up time all the same
+_ensure_listener()
 
 
 # ----------------------------------------------------------------------

@@ -1,11 +1,12 @@
 """Profiler range annotation.
 
 Capability parity with reference ``deepspeed/utils/nvtx.py:9
-instrument_w_nvtx`` — wraps a function in a named profiler range. On TPU
-the range shows up in xprof/perfetto traces via
-``jax.profiler.TraceAnnotation`` and inside compiled programs via
-``jax.named_scope`` (which also names HLO ops for the flops profiler's
-per-module attribution).
+instrument_w_nvtx`` — wraps a function in a named range. The range is a
+span of the process-wide tracer (``telemetry.default_tracer().span``: an
+event in its ring, and a ``jax.profiler.TraceAnnotation`` that a running
+profiler session shows in xprof/perfetto). Code traced inside it also
+runs under ``jax.named_scope``, which names the HLO ops for the flops
+profiler's per-module attribution.
 """
 
 from __future__ import annotations
@@ -16,13 +17,11 @@ import functools
 
 def instrument_w_nvtx(func):
     """Decorator: execute ``func`` inside a named trace range."""
-    import jax
-
     name = getattr(func, "__qualname__", getattr(func, "__name__", "fn"))
 
     @functools.wraps(func)
     def wrapped(*args, **kwargs):
-        with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
+        with trace_range(name):
             return func(*args, **kwargs)
 
     return wrapped
@@ -44,11 +43,13 @@ def range_pop() -> None:
 
 @contextlib.contextmanager
 def trace_range(name: str):
-    """with trace_range("phase"): ... — xprof-visible range that is ALSO a
-    jax.named_scope, so ops traced inside attribute to this name in the
-    flops profiler's per-module tree (same visibility as
+    """with trace_range("phase"): ... — a span of the process-wide tracer
+    that is ALSO a jax.named_scope, so ops traced inside attribute to this
+    name in the flops profiler's per-module tree (same visibility as
     ``instrument_w_nvtx``)."""
     import jax
 
-    with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
+    from ..telemetry import default_tracer
+
+    with default_tracer().span(name), jax.named_scope(name):
         yield

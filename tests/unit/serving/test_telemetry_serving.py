@@ -14,7 +14,7 @@ import pytest
 import deepspeed_tpu as ds
 from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import RequestState, ServingEngine
-from deepspeed_tpu.telemetry import RecompileAfterWarmupError, Tracer
+from deepspeed_tpu.telemetry import default_tracer, RecompileAfterWarmupError, Tracer
 
 TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
             dtype=jnp.float32)
@@ -179,7 +179,9 @@ def test_set_tracer_enables_post_hoc_tracing(stack):
     srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
                max_new_tokens=2)
     srv.run_until_drained(max_steps=20)
-    assert srv.tracer.events_total == 0  # off by default
+    # given no tracer the server records into the process-wide one, on
+    assert srv.tracer is default_tracer() and srv.tracer.enabled
+    assert any(e["name"] == "serving/step" for e in srv.tracer.events())
 
     tr = Tracer()
     srv.set_tracer(tr)
