@@ -296,9 +296,10 @@ class CachedAttention(nn.Module):
         entries drop — the ``_scatter_cols`` discipline, applied at the
         source) and attend via the fused paged kernel. The value bytes
         written and the attention math match the dense path exactly
-        (same quantize/pack pipeline, kernel compute copied op-for-op
-        from the dense decode kernel), which is what keeps paged-kernel
-        greedy output bitwise-identical to the dense oracle."""
+        (same quantize/pack pipeline; for each head the kernel folds one
+        page at a time in table order, op-for-op the dense decode kernel
+        at a block of one page), which is what keeps paged-kernel greedy
+        output bitwise-identical to the dense oracle."""
         cfg = self.config
         B, T, H, D = q.shape
         kv_packed = kv_cache_spec(cfg)[2]

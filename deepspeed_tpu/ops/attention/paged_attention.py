@@ -2,42 +2,76 @@
 
 The vLLM PagedAttention insight, aimed at this repo's hottest serving op:
 the kernel reads the :class:`~deepspeed_tpu.serving.paged_pool.PagedKVPool`
-page table IN PLACE instead of gathering pages into a dense per-slot view
-first. The dense round-trip (``KVCacheSpec.dense_from_pages`` gather →
-dense attention → ``_scatter_cols`` writeback) materializes O(slots ×
-max_seq_len) K/V every step; here the page table rides scalar prefetch
-(SMEM) and the K/V BlockSpec index maps resolve ``table[slot, j]`` per
-grid step, so HBM traffic is one DMA per LIVE page — the pool's physical
-pages are the only cache bytes ever read.
+pages IN PLACE instead of gathering them into a dense per-slot view first
+(``KVCacheSpec.dense_from_pages`` gather → dense attention →
+``_scatter_cols`` writeback materializes O(slots × max_seq_len) K/V every
+step).
 
-Parity contract (the "dense oracle" discipline): the per-step compute is
-op-for-op the dense decode kernel's
+**Grid.** One grid step is one LIVE page of one slot with every KV head
+of the device in it. The wrapper turns ``(starts, table)`` into a work
+list (:func:`live_pages`: slot, table entry and physical page of each
+step, slot by slot in table order), hands it to the kernel by scalar
+prefetch (SMEM) and sizes the grid by the list's length, which is known
+only on the device: ``grid = (KV // kv_group, total)`` with a dynamic
+second axis. Pages past a slot's live length are no step and no DMA. A
+slot's live length is bounded by its row's leading mapped entries as well
+as by its ``start``: the pool advances every slot's index on every decode
+step, so a freed slot's ``start`` counts on towards the capacity while
+its row is all sentinel, and such a slot is one masked step (every output
+block is written), not ``pages_per_slot`` of them. Compiled shapes depend
+on static shapes alone: the live lengths ride scalar prefetch.
+
+**Blocks.** K and V blocks are ``(1, kv_group, Dc, page_size)``: a page
+of one layer is contiguous over its heads, so all of them arrive in one
+DMA (16 heads of 128 × 64 bf16: 256 KB, 512 KB in VMEM because a 64-wide
+page fills half of each 128-lane tile — in HBM too). The query and output
+blocks carry the ``kv_group * rep`` heads of the slot, the scratch
+(``acc``, ``m``, ``l``) has a head axis, and the per-head fold runs inside
+the step (a static loop; GQA's ``rep`` query heads of a KV head share its
+page). :func:`plan_grid` picks ``kv_group`` from the shapes against a fixed
+VMEM budget (:data:`VMEM_BUDGET_BYTES`, half the v5e's default scoped
+limit): every KV head when one page of each fits, else the largest divisor
+of ``KV`` that does, and the head-group axis comes back into the grid.
+No option selects any of this.
+
+**What was measured** (v5e, stand-alone at the served shape B=64, H=KV=16,
+D=128, page 64, 32 table entries a slot, 256 pages; chip runs of PR 24):
+the former grid ``(B, H, pages_per_slot)`` = 32,768 steps took 6.6 ms a
+call with 30 slots live at 100-600 tokens and 6.1 ms with 2 slots at
+~1,400, i.e. ~0.2 us an empty step; every head in one step over ``(B,
+pages_per_slot)`` 0.82 / 0.55 ms; this grid 0.48 / 0.26 ms, ~2.4 us a
+live page. Several page operands a step (the table row cut in blocks) were
+slower with every operand added (3: 1.02 / 0.65 ms, 8: 1.15 / 0.65 ms), and
+Mosaic refuses the kernel's own ``make_async_copy`` of a 64-wide page
+("Slice shape along dimension 3 must be aligned to tiling (128)"), so
+neither is here.
+
+Parity contract (the "dense oracle" discipline): for each head the
+per-page fold is op-for-op the dense decode kernel's
 (:func:`~deepspeed_tpu.ops.attention.decode_attention._decode_kernel` —
-same online-softmax update order, same masking, same scratch shapes) with
-the position block pinned to ONE PAGE. A single-token call is therefore
-bitwise-identical to ``decode_attention(q, dense_k, dense_v, lengths,
-block_s=page_size)`` on the gathered dense view in interpret mode on
-the CPU, which is what lets the serving tests pin the paged-kernel arm
-against the dense path exactly (TransformerConfig's ``decode_block``
-pins the oracle's block granule to the page size). On the TPU that twin
-exists only for pages of at least 128 positions: the dense kernel puts
+same online-softmax update order, same masking) with the position block
+pinned to ONE PAGE, one page at a time in table order. Each row of a call
+is therefore bitwise-identical to ``decode_attention(q, dense_k, dense_v,
+lengths, block_s=page_size)`` on the gathered dense view in interpret mode
+on the CPU, which is what lets the serving tests pin the paged-kernel arm
+against the dense path exactly (TransformerConfig's ``decode_block`` pins
+the oracle's block granule to the page size). On the TPU that twin exists
+only for pages of at least 128 positions: the dense kernel puts
 ``block_s`` on the lane axis and Mosaic rejects a 64-wide block there,
 so at the default page size (64) the pinned oracle does not lower and
 the arms are compared within a bf16 tolerance instead (chip_smoke.py:
 max |delta logit| 0.041 at logit scale 5.0 on a v5e, PR 21). Bitwise
 equality on the chip at page size 128 has not been tried.
 
-Garbage is masked by length, never by table lookups: dead grid steps
-(pages past a slot's live length) clamp their index map to the slot's
-LAST LIVE page — consecutive identical block indices elide the DMA
-(Pallas revisiting rule), so bandwidth tracks the live length — and
-sentinel table entries (``num_pages`` = unmapped) clip to a real page
-exactly like the dense gather's ``mode="clip"``; both reads are masked
-to ``NEG_INF`` before the softmax, so their values never reach the
-output. Supports 1..SUBLANES query rows per slot (plain decode T=1;
-speculative verify T=K+1) with per-row causal masking, GQA, ALiBi, and
-the int8/int32-packed quantized cache tiers (scales paged alongside,
-folded into the score/probability rows like the dense kernel).
+Garbage is masked by length, never by table lookups: sentinel table
+entries (``num_pages`` = unmapped) clip to a real page exactly like the
+dense gather's ``mode="clip"``, and stale columns past the live length
+inside the last live page are masked to ``NEG_INF`` before the softmax,
+so their values never reach the output. Supports 1..SUBLANES query rows
+per slot (plain decode T=1; speculative verify T=K+1) with per-row causal
+masking, GQA, ALiBi, and the int8/int32-packed quantized cache tiers
+(scales paged alongside, folded into the score/probability rows like the
+dense kernel).
 """
 
 from __future__ import annotations
@@ -54,44 +88,136 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import backend
 from .flash_attention import LANES, NEG_INF, SUBLANES
 
-__all__ = ["paged_decode_attention", "MAX_QUERY_ROWS"]
+__all__ = ["paged_decode_attention", "plan_grid", "live_pages",
+           "MAX_QUERY_ROWS"]
 
 # one kernel serves decode (T=1) and speculative verify (T=K+1): query
 # rows live on the SUBLANES axis of the score tile, so the row budget is
 # the sublane count — pools fall back to the dense composition beyond it
 MAX_QUERY_ROWS = SUBLANES
 
+# what one grid step may hold in VMEM: half of the v5e's default scoped
+# limit (16 MiB), so the compiler's own temporaries fit beside it and no
+# call needs a raised ``vmem_limit_bytes``
+VMEM_BUDGET_BYTES = 8 * 2 ** 20
 
-def _paged_kernel(start_ref, slope_ref, table_ref, q_ref, k_ref, v_ref,
-                  o_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                  page_size: int, num_rows: int, alibi: bool,
-                  compute_dtype=None, k_scale_ref=None, v_scale_ref=None,
-                  packed: bool = False):
-    # start_ref/slope_ref/table_ref are scalar-prefetch SMEM arrays:
-    # (B,), (H,) and (B, pages_per_slot). The compute below mirrors
+
+def plan_grid(B: int, H: int, KV: int, D: int, Dc: int, page_size: int,
+              pages_per_slot: int, kv_dtype, q_dtype, quantized: bool):
+    """``(kv_group, pages_per_step, grid)`` for one call, from its static
+    shapes alone.
+
+    One grid step holds one page of ``kv_group`` KV heads (K and V, and
+    the scale rows of a quantized pool, each double-buffered by the
+    pipeline) beside the query and output blocks of the group's heads
+    and the fp32 accumulator and softmax statistics. ``kv_group`` is all
+    of ``KV`` when that fits :data:`VMEM_BUDGET_BYTES`, else the largest
+    divisor of ``KV`` that does — the head-group axis then comes back
+    into the grid. ``pages_per_step`` is 1: on the v5e every further
+    page operand of a step cost more than the grid step it saved (3
+    pages a step: 1.02 ms against 0.82 ms a call at the served shape,
+    chip run of PR 24). ``grid`` is ``(KV // kv_group, B *
+    pages_per_slot)``, the second a bound: the call runs one step for
+    each entry of :func:`live_pages`, not for each table entry."""
+    rep = H // KV
+
+    def step_bytes(kv_group: int) -> int:
+        heads = kv_group * rep
+        page = 2 * _vmem_tile_bytes(Dc, page_size, kv_dtype)      # K and V
+        if quantized:
+            page += 2 * _vmem_tile_bytes(1, page_size, jnp.float32)
+        blocks = 2 * heads * _vmem_tile_bytes(SUBLANES, D, q_dtype)
+        scratch = heads * (_vmem_tile_bytes(SUBLANES, D, jnp.float32)
+                           + 2 * _vmem_tile_bytes(SUBLANES, LANES,
+                                                  jnp.float32))
+        return 2 * (kv_group * page + blocks) + scratch
+
+    kv_group = max((g for g in range(1, KV + 1) if KV % g == 0
+                    and step_bytes(g) <= VMEM_BUDGET_BYTES), default=1)
+    return kv_group, 1, (KV // kv_group, B * pages_per_slot)
+
+
+def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
+    """Bytes a (rows, cols) array takes in VMEM: lanes pad to 128 and
+    sublanes to a whole tile (8 rows of 32 bits, 16 of bf16, 32 of
+    int8) — a 64-wide page fills half of every tile it touches."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = SUBLANES * (4 // itemsize)
+    return (-(-rows // sublanes) * sublanes * -(-cols // LANES) * LANES
+            * itemsize)
+
+
+def live_pages(starts: jax.Array, table: jax.Array, num_rows: int,
+               page_size: int, num_pages: int):
+    """The call's work list: ``(slot_of, entry_of, page_of, live, total)``.
+
+    Step ``w < total`` folds table entry ``entry_of[w]`` of slot
+    ``slot_of[w]``, physical page ``page_of[w]``, slot by slot in table
+    order. Slot ``b`` has ``live[b]`` steps: the entries its rows can
+    see, ``ceil((start + num_rows) / page_size)``, but no more than the
+    row's leading MAPPED entries — a freed slot's row is all sentinel
+    while its ``start`` keeps counting (the pool advances every slot's
+    index each decode step), and what is not mapped is not cached — and
+    at least one, so that every output block is written (an empty slot
+    is one masked step on a clipped page). The lists are as long as the
+    table (``B * pages_per_slot``); entries from ``total`` on are in
+    range and never run."""
+    B, pages_per_slot = table.shape
+    # index of the row's first sentinel, pages_per_slot if it has none
+    mapped = jnp.argmin(jnp.pad(table < num_pages, ((0, 0), (0, 1))),
+                        axis=1).astype(jnp.int32)
+    live = jnp.clip(
+        jnp.minimum((starts + num_rows + page_size - 1) // page_size, mapped),
+        1, pages_per_slot)
+    ends = jnp.cumsum(live)
+    w = jnp.arange(B * pages_per_slot, dtype=jnp.int32)
+    slot_of = jnp.minimum(
+        jnp.searchsorted(ends, w, side="right", method="compare_all"),
+        B - 1).astype(jnp.int32)
+    entry_of = jnp.clip(w - (ends - live)[slot_of], 0, pages_per_slot - 1)
+    # sentinel ids clip to a real page, like the dense gather's "clip"
+    page_of = jnp.minimum(table[slot_of, entry_of], num_pages - 1)
+    return slot_of, entry_of, page_of, live, ends[-1]
+
+
+def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
+                  slope_ref, q_ref, k_ref, v_ref, *refs, scale: float,
+                  rep: int, alibi: bool, quantized: bool, packed: bool,
+                  compute_dtype):
+    # the first six are scalar-prefetch SMEM arrays: the work list of
+    # live_pages (page_ref is read by the index maps only), (B,) starts
+    # and (H,) slopes. One grid step is one live page of one slot for
+    # one group of KV heads. For each head the fold mirrors
     # decode_attention._decode_kernel line for line (the bitwise-parity
-    # contract in the module docstring); the ONLY differences are where
-    # K/V blocks come from (page-indexed index maps, not contiguous
-    # offsets) and that query rows 0..num_rows-1 carry their own causal
+    # contract in the module docstring); the differences are where K/V
+    # blocks come from and that each query row carries its own causal
     # limit (row t sees cache positions <= start + t).
-    j = pl.program_id(2)
-    num_p = pl.num_programs(2)
-    start = start_ref[pl.program_id(0)]
-    slope = slope_ref[pl.program_id(1)]
-    block_start = j * page_size
+    if quantized:
+        k_scale_ref, v_scale_ref, *refs = refs
+    o_ref, acc_ref, m_ref, l_ref = refs
+    g, w = pl.program_id(0), pl.program_id(1)
+    _, kv_group, _, page_size = k_ref.shape
+    heads = kv_group * rep
+    entry = entry_ref[w]
+    slot = slot_ref[w]
+    start = start_ref[slot]
+    block_start = entry * page_size
 
-    @pl.when(j == 0)
+    @pl.when(entry == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(block_start < start + num_rows)
-    def _compute():
-        q = q_ref[0]                                      # (SUBLANES, D)
-        k = k_ref[0, 0]                                   # (Dc, page_size)
-        v = v_ref[0, 0]
-        if k_scale_ref is not None:
+    pos = block_start + jax.lax.broadcasted_iota(
+        jnp.int32, (SUBLANES, page_size), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, page_size), 0)
+    for h in range(heads):
+        c = h // rep                      # GQA: rep query heads a KV head
+        q = q_ref[0, h]                                   # (SUBLANES, D)
+        k = k_ref[0, c]                                   # (Dc, page_size)
+        v = v_ref[0, c]
+        if quantized:
             if packed:
                 k = pltpu.bitcast(k, jnp.int8).astype(compute_dtype)
                 v = pltpu.bitcast(v, jnp.int8).astype(compute_dtype)
@@ -100,32 +226,35 @@ def _paged_kernel(start_ref, slope_ref, table_ref, q_ref, k_ref, v_ref,
                 v = v.astype(compute_dtype)
         s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if k_scale_ref is not None:
-            s = s * k_scale_ref[0, 0]                     # (1, page) scale
-        pos = block_start + jax.lax.broadcasted_iota(
-            jnp.int32, (SUBLANES, page_size), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, page_size), 0)
+        if quantized:
+            s = s * k_scale_ref[0, c]                     # (1, page) scale
         if alibi:
-            # row t's query sits at absolute position start + t
-            s = s + slope * (pos - (start + row)).astype(jnp.float32)
+            # the dense kernel's bias for the query at ``start``, then
+            # row t's own offset: slope * (pos - (start + t)). Row 0
+            # subtracts an exact zero, which keeps a T=1 call bitwise
+            # equal to the dense kernel's scalar expression
+            slope = slope_ref[g * heads + h]
+            s = s + slope * (pos - start).astype(jnp.float32) \
+                - slope * row.astype(jnp.float32)
         s = jnp.where(pos <= start + row, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
+        m_prev = m_ref[h, :, :1]
+        l_prev = l_ref[h, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=1, keepdims=True), l_ref.shape)
-        if v_scale_ref is not None:
-            p = p * v_scale_ref[0, 0]                     # (1, page) scale
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        l_ref[h] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
+            l_ref.shape[1:])
+        if quantized:
+            p = p * v_scale_ref[0, c]                     # (1, page) scale
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
 
-    @pl.when(j == num_p - 1)
+    @pl.when(entry == live_ref[slot] - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
+        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
@@ -150,7 +279,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         (Dc = D // 4) when scales are given.
       table: (B, pages_per_slot) int32 page table; ``P`` is the
         unmapped sentinel (clipped to a real page, masked by length —
-        the dense gather's ``mode="clip"`` discipline).
+        the dense gather's ``mode="clip"`` discipline). A slot attends
+        over its row's leading mapped entries at most: a row that maps
+        nothing is an empty slot, whatever its ``starts`` says.
       starts: (B,) int32 cache length BEFORE this step's tokens (the
         slot pool's ``index`` mirror at dispatch).
       alibi_slopes: optional (H,) ALiBi slopes.
@@ -212,70 +343,53 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, *,
 
     # query rows ride the SUBLANES axis: pad T up to the full sublane
     # tile (dead rows compute with a wider causal window and are sliced
-    # off — never all-masked, so no NaN risk) and fold heads into the
-    # leading grid axis like the dense kernel's q3
+    # off — never all-masked, so no NaN risk)
     q4 = q.transpose(0, 2, 1, 3)                          # (B, H, T, D)
     if T < SUBLANES:
         q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, SUBLANES - T), (0, 0)))
-    q3 = q4.reshape(B * H, SUBLANES, D)
 
-    grid = (B, H, maxP)
+    kv_group, _, (groups, _) = plan_grid(
+        B, H, KV, D, Dc, ps, maxP, k_pages.dtype, q.dtype, quantized)
+    heads = kv_group * rep
+    slot_of, entry_of, page_of, live, total = live_pages(
+        starts, table, T, ps, P)
 
-    def kv_index(b, h, j, start_ref, slope_ref, table_ref):
-        # clamp dead steps to the slot's last LIVE page (consecutive
-        # identical indices elide the DMA — bandwidth tracks the live
-        # length), then clip sentinel entries into range (masked reads)
-        last_live = jnp.maximum(
-            (start_ref[b] + T + ps - 1) // ps - 1, 0)
-        pid = table_ref[b, jnp.minimum(j, last_live)]
-        return (jnp.minimum(pid, P - 1), h // rep, 0, 0)
+    head_block = pl.BlockSpec(
+        (1, heads, SUBLANES, D),
+        lambda g, w, slot_ref, *_: (slot_ref[w], g, 0, 0))
 
-    in_specs = [
-        pl.BlockSpec((1, SUBLANES, D), lambda b, h, j, *_: (b * H + h, 0, 0)),
-        pl.BlockSpec((1, 1, Dc, ps), kv_index),
-        pl.BlockSpec((1, 1, Dc, ps), kv_index),
-    ]
-    operands = [starts, slopes, table, q3, k_pages, v_pages]
+    def page_index(g, w, slot_ref, entry_ref, page_ref, *_):
+        return (page_ref[w], g, 0, 0)
+
+    pools = [k_pages, v_pages]
+    blocks = [(1, kv_group, Dc, ps)] * 2
     if quantized:
-        # scales ride as (P, KV, 1, page_size) so the (1, 1, 1, ps)
-        # block lands on LANES, matching s/p (same trick as the dense
+        # scales ride as (P, KV, 1, page_size) so a head's (1, ps) row
+        # lands on LANES, matching s/p (same trick as the dense
         # kernel's (B, KV, 1, S) reshape)
-        in_specs += [pl.BlockSpec((1, 1, 1, ps), kv_index),
-                     pl.BlockSpec((1, 1, 1, ps), kv_index)]
-        operands += [
-            k_scale_pages.astype(jnp.float32).reshape(P, KV, 1, ps),
-            v_scale_pages.astype(jnp.float32).reshape(P, KV, 1, ps)]
-
-        def kernel(start_ref, slope_ref, table_ref, q_ref, k_ref, v_ref,
-                   ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref):
-            _paged_kernel(start_ref, slope_ref, table_ref, q_ref, k_ref,
-                          v_ref, o_ref, acc_ref, m_ref, l_ref, scale=scale,
-                          page_size=ps, num_rows=T, alibi=alibi,
-                          compute_dtype=compute_dtype,
-                          k_scale_ref=ks_ref, v_scale_ref=vs_ref,
-                          packed=packed)
-    else:
-        kernel = functools.partial(_paged_kernel, scale=scale, page_size=ps,
-                                   num_rows=T, alibi=alibi)
-
+        pools += [k_scale_pages.astype(jnp.float32).reshape(P, KV, 1, ps),
+                  v_scale_pages.astype(jnp.float32).reshape(P, KV, 1, ps)]
+        blocks += [(1, kv_group, 1, ps)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, SUBLANES, D),
-                               lambda b, h, j, *_: (b * H + h, 0, 0)),
+        num_scalar_prefetch=6,
+        grid=(groups, total),
+        in_specs=[head_block] + [pl.BlockSpec(block, page_index)
+                                 for block in blocks],
+        out_specs=head_block,
         scratch_shapes=[
-            pltpu.VMEM((SUBLANES, D), jnp.float32),
-            pltpu.VMEM((SUBLANES, LANES), jnp.float32),
-            pltpu.VMEM((SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM((heads, SUBLANES, D), jnp.float32),
+            pltpu.VMEM((heads, SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM((heads, SUBLANES, LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_kernel, scale=scale, rep=rep, alibi=alibi,
+                          quantized=quantized, packed=packed,
+                          compute_dtype=compute_dtype),
         name="paged_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, SUBLANES, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, SUBLANES, D), q.dtype),
         interpret=backend.pallas_interpret(),
-    )(*operands)
-    out = out.reshape(B, H, SUBLANES, D)[:, :, :T]
+    )(slot_of, entry_of, page_of, live, starts, slopes, q4, *pools)
+    out = out[:, :, :T]
     return out.transpose(0, 2, 1, 3).astype(out_dtype)

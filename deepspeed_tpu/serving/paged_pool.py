@@ -695,10 +695,12 @@ class PagedKVPool(SlotPool):
         # Same jit signatures as the dense compositions above, but the
         # model step runs ``decode_paged``: column writes scatter through
         # the page table at the source and the Pallas kernel reads pages
-        # in place — the dense (L, B, KV, cd, S) scratch view is never
-        # built. Greedy decode output is bitwise-identical (the kernel's
-        # per-page online-softmax blocking matches decode_attention at
-        # block_s=page_size; see ops/attention/paged_attention.py).
+        # in place, one grid step for each live page of a slot with every
+        # head in it — the dense (L, B, KV, cd, S) scratch view is never
+        # built. Greedy decode output is bitwise-identical (for each head
+        # the kernel folds one page at a time in table order, which is
+        # decode_attention at block_s=page_size; see
+        # ops/attention/paged_attention.py).
         if self.kernel_active \
                 and getattr(module, "decode_paged", None) is not None:
             from ..ops.attention.paged_attention import MAX_QUERY_ROWS

@@ -11,11 +11,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.ops.attention.decode_attention import decode_attention
+from deepspeed_tpu.ops.attention import paged_attention
+from deepspeed_tpu.ops.attention.decode_attention import (
+    decode_attention,
+    pack_int8_sublanes,
+)
 from deepspeed_tpu.ops.attention.flash_attention import SUBLANES
 from deepspeed_tpu.ops.attention.paged_attention import (
     MAX_QUERY_ROWS,
+    live_pages,
     paged_decode_attention,
+    plan_grid,
 )
 
 
@@ -161,6 +167,12 @@ def test_row_budget_is_enforced():
 
 
 def test_alibi_matches_dense_oracle():
+    """Bitwise for the T=1 row: the paged kernel builds the bias as the
+    dense kernel does for the query at ``start`` (a scalar query
+    position) and subtracts each row's own offset, an exact zero on
+    row 0. Writing it as ``slope * (pos - (start + row))`` made the CPU
+    contract the two kernels' multiply-adds differently (351 of 512
+    elements, 3.0e-7)."""
     rng = np.random.default_rng(4)
     B, H, KV, D, S, ps = 2, 4, 4, 64, 128, 32
     dense_k, dense_v, k_pages, v_pages, table = _make_paged(
@@ -182,10 +194,6 @@ def test_alibi_matches_dense_oracle():
 def test_quantized_pages_match_dense_oracle(packed):
     """int8 (and int32-packed) page pools with per-column scales: bitwise
     against the dense quantized kernel on the gathered view."""
-    from deepspeed_tpu.ops.attention.decode_attention import (
-        pack_int8_sublanes,
-    )
-
     rng = np.random.default_rng(5)
     B, H, KV, D, S, ps = 2, 4, 2, 64, 128, 32
     pages_per_slot = S // ps
@@ -198,9 +206,7 @@ def test_quantized_pages_match_dense_oracle(packed):
     table = perm.reshape(B, pages_per_slot).astype(np.int32)
 
     def gather(pages):
-        # (B, KV, ..., S) dense view through the table
-        return np.concatenate([pages[table[:, j]]
-                               for j in range(pages_per_slot)], axis=-1)
+        return _gather(pages, table)
 
     starts = np.asarray([S - 3, ps + 7], np.int32)
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
@@ -232,3 +238,205 @@ def test_jit_and_eager_agree():
     np.testing.assert_array_equal(
         np.asarray(jax.jit(paged_decode_attention)(*args)),
         np.asarray(paged_decode_attention(*args)))
+
+
+# ---------------------------------------------------------------------------
+# the grid follows the live pages (ISSUE 24)
+# ---------------------------------------------------------------------------
+def _gather(pages, table):
+    """(B, KV, ..., S) dense view through the table; sentinel entries
+    clip to the last physical page like the pool's dense gather."""
+    table = np.minimum(table, pages.shape[0] - 1)
+    return np.concatenate([pages[table[:, j]]
+                           for j in range(table.shape[1])], axis=-1)
+
+
+# the served shape (Pythia-1.4B: 16 heads of 128, pages of 64) cut down
+# only in slots and pages; 5 table entries a slot
+_SERVED = dict(H=16, KV=16, D=128, ps=64, per_slot=5, P=12)
+_SLOT_CASES = {
+    # starts, table rows (12 = the unmapped sentinel)
+    "start_0": ([0, 200], [[3, 12, 12, 12, 12], [7, 1, 9, 4, 12]]),
+    "all_sentinel": ([0, 0, 70], [[12] * 5, [12] * 5, [2, 5, 12, 12, 12]]),
+    "ends_on_page_edge": ([127, 63], [[0, 8, 12, 12, 12], [6, 12, 12, 12, 12]]),
+    "one_past_page_edge": ([128, 64], [[0, 8, 4, 12, 12], [6, 10, 12, 12, 12]]),
+    "full_row": ([319, 5], [[11, 10, 9, 8, 7], [0, 12, 12, 12, 12]]),
+    "shared_prefix": ([150, 170, 131], [[1, 2, 3, 12, 12], [1, 2, 6, 12, 12],
+                                        [1, 2, 9, 12, 12]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SLOT_CASES))
+def test_served_shape_slots_match_dense_oracle(case):
+    """Every head of a page in one grid step, one step a live page: dead
+    slots, sentinel rows, page edges, a full table row and shared pages,
+    each bitwise against the dense kernel on the gathered view."""
+    starts, table = _SLOT_CASES[case]
+    starts, table = np.asarray(starts, np.int32), np.asarray(table, np.int32)
+    H, KV, D, ps, P = (_SERVED[k] for k in ("H", "KV", "D", "ps", "P"))
+    rng = np.random.default_rng(24)
+    k_pages = rng.standard_normal((P, KV, D, ps)).astype(np.float32)
+    v_pages = rng.standard_normal((P, KV, D, ps)).astype(np.float32)
+    q = jnp.asarray(rng.standard_normal((len(starts), 1, H, D)), jnp.float32)
+    out = paged_decode_attention(q, jnp.asarray(k_pages),
+                                 jnp.asarray(v_pages), jnp.asarray(table),
+                                 jnp.asarray(starts))
+    oracle = decode_attention(q[:, 0], jnp.asarray(_gather(k_pages, table)),
+                              jnp.asarray(_gather(v_pages, table)),
+                              jnp.asarray(starts + 1), block_s=ps)
+    np.testing.assert_array_equal(np.asarray(out[:, 0]), np.asarray(oracle))
+
+
+@pytest.mark.parametrize("tier", ["bf16", "int8", "int32-packed"])
+@pytest.mark.parametrize("T", [1, 3, 8])
+def test_rows_and_cache_tiers_match_dense_oracle(T, tier):
+    """Row t of a T-row call is the dense kernel's answer for a query at
+    ``start + t`` (the call's columns are already in the pages), bitwise,
+    for each K/V tier."""
+    rng = np.random.default_rng(7)
+    B, H, KV, D, ps, per_slot = 2, 4, 2, 128, 64, 3
+    P = B * per_slot + 1
+    table = rng.permutation(P)[:B * per_slot].reshape(B, per_slot) \
+        .astype(np.int32)
+    starts = np.asarray([ps - 2, 2 * ps + 5], np.int32)   # rows cross a page
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.bfloat16)
+    scales = {}
+    if tier == "bf16":
+        k_pages = jnp.asarray(rng.standard_normal((P, KV, D, ps)),
+                              jnp.bfloat16)
+        v_pages = jnp.asarray(rng.standard_normal((P, KV, D, ps)),
+                              jnp.bfloat16)
+        dense_k, dense_v = (jnp.asarray(_gather(np.asarray(x), table))
+                            for x in (k_pages, v_pages))
+    else:
+        k8 = rng.integers(-127, 128, (P, KV, D, ps)).astype(np.int8)
+        v8 = rng.integers(-127, 128, (P, KV, D, ps)).astype(np.int8)
+        ks = rng.uniform(0.01, 0.1, (P, KV, ps)).astype(np.float32)
+        vs = rng.uniform(0.01, 0.1, (P, KV, ps)).astype(np.float32)
+        k_pages, v_pages = jnp.asarray(k8), jnp.asarray(v8)
+        dense_k = jnp.asarray(_gather(k8, table))
+        dense_v = jnp.asarray(_gather(v8, table))
+        if tier == "int32-packed":
+            k_pages, v_pages, dense_k, dense_v = (
+                pack_int8_sublanes(x)
+                for x in (k_pages, v_pages, dense_k, dense_v))
+        scales = dict(k_scale_pages=jnp.asarray(ks),
+                      v_scale_pages=jnp.asarray(vs))
+        dense_scales = dict(k_scale=jnp.asarray(_gather(ks, table)),
+                            v_scale=jnp.asarray(_gather(vs, table)))
+    out = paged_decode_attention(q, k_pages, v_pages, jnp.asarray(table),
+                                 jnp.asarray(starts), **scales)
+    assert out.shape == (B, T, H, D) and out.dtype == q.dtype
+    for t in range(T):
+        oracle = decode_attention(
+            q[:, t], dense_k, dense_v, jnp.asarray(starts + t + 1),
+            block_s=ps, **(dense_scales if scales else {}))
+        np.testing.assert_array_equal(
+            np.asarray(out[:, t].astype(jnp.float32)),
+            np.asarray(oracle.astype(jnp.float32)), err_msg=f"row {t}")
+
+
+def test_heads_that_do_not_fit_vmem_split_into_groups(monkeypatch):
+    """A budget one page of every head does not fit: kv_group falls to a
+    divisor of KV, the head-group axis comes back into the grid, and the
+    answer is the same bit for bit (GQA, so a group carries rep heads)."""
+    rng = np.random.default_rng(8)
+    B, H, KV, D, S, ps = 2, 8, 4, 64, 128, 32
+    dense_k, dense_v, k_pages, v_pages, table = _make_paged(
+        rng, B, KV, D, S, ps)
+    starts = np.asarray([S - 3, ps + 1], np.int32)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
+    args = (q, jnp.asarray(k_pages), jnp.asarray(v_pages),
+            jnp.asarray(table), jnp.asarray(starts))
+    shape = (B, H, KV, D, D, ps, S // ps, jnp.float32, jnp.float32, False)
+    assert plan_grid(*shape)[0] == KV
+    whole = paged_decode_attention(*args)
+    monkeypatch.setattr(paged_attention, "VMEM_BUDGET_BYTES", 400 * 1024)
+    kv_group, _, (groups, _) = plan_grid(*shape)
+    assert (kv_group, groups) == (2, 2)
+    split = paged_decode_attention(*args)
+    np.testing.assert_array_equal(np.asarray(split), np.asarray(whole))
+    oracle = decode_attention(q[:, 0], jnp.asarray(dense_k),
+                              jnp.asarray(dense_v), jnp.asarray(starts + 1),
+                              block_s=ps)
+    np.testing.assert_array_equal(np.asarray(split[:, 0]),
+                                  np.asarray(oracle))
+
+
+def test_grid_follows_live_pages_not_table_entries():
+    """The mechanism itself, so that a return to a per-head or per-entry
+    grid fails here: at the served shape a grid step holds every head of
+    a page, and the call runs one step for each live (slot, page)."""
+    B, H, KV, D, ps, per_slot, P = 64, 16, 16, 128, 64, 32, 256
+    for dtype, quantized, Dc in ((jnp.bfloat16, False, D),
+                                 (jnp.int8, True, D),
+                                 (jnp.int32, True, D // 4)):
+        kv_group, pages_per_step, grid = plan_grid(
+            B, H, KV, D, Dc, ps, per_slot, dtype, jnp.bfloat16, quantized)
+        assert kv_group == KV
+        assert int(np.prod(grid)) <= B * -(-per_slot // pages_per_step)
+    # a much wider model: the heads of one page no longer fit
+    kv_group, _, grid = plan_grid(4, 64, 64, 256, 256, 128, 8, jnp.bfloat16,
+                                  jnp.bfloat16, False)
+    assert kv_group < 64 and 64 % kv_group == 0 and grid[0] == 64 // kv_group
+
+    # 30 slots decoding at 100-600 tokens, the chat cell. The rest are
+    # freed: rows all sentinel, and a ``start`` that kept counting (the
+    # pool advances every slot's index each decode step)
+    rng = np.random.default_rng(0)
+    starts = rng.integers(0, per_slot * ps, (B,)).astype(np.int32)
+    table = np.full((B, per_slot), P, np.int32)
+    free = iter(rng.permutation(P))
+    live = np.ones((B,), np.int64)
+    for b in rng.choice(B, 30, replace=False):
+        starts[b] = rng.integers(100, 601)
+        live[b] = (starts[b] + 1 + ps - 1) // ps
+        for j in range(live[b]):
+            table[b, j] = next(free)
+    slot_of, entry_of, page_of, live_of, total = (
+        np.asarray(x) for x in live_pages(jnp.asarray(starts),
+                                          jnp.asarray(table), 1, ps, P))
+    np.testing.assert_array_equal(live_of, live)
+    assert total == live.sum() < B * per_slot // 8
+    want = [(b, j) for b in range(B) for j in range(live[b])]
+    assert list(zip(slot_of[:total], entry_of[:total])) == want
+    np.testing.assert_array_equal(
+        page_of[:total], [min(table[b, j], P - 1) for b, j in want])
+    # past the end the lists stay in range (never run, but prefetchable)
+    assert slot_of.max() < B and entry_of.max() < per_slot \
+        and page_of.max() < P
+    # a slot whose rows overflow its table row stops at the row's end
+    _, entry_of, _, _, total = live_pages(
+        jnp.asarray([per_slot * ps + 5], jnp.int32),
+        jnp.zeros((1, per_slot), jnp.int32), 8, ps, P)
+    assert int(total) == per_slot and int(entry_of[per_slot - 1]) \
+        == per_slot - 1
+
+
+def test_freed_slots_with_stale_starts_cost_one_step_and_change_nothing():
+    """A freed slot's ``start`` is whatever the pool's index counted up
+    to. Its row is unmapped, so it is one step, and the live slots'
+    answers do not depend on it."""
+    rng = np.random.default_rng(9)
+    B, H, KV, D, S, ps = 3, 4, 2, 64, 128, 32
+    dense_k, dense_v, k_pages, v_pages, table = _make_paged(
+        rng, B, KV, D, S, ps)
+    P = k_pages.shape[0]
+    table[1] = P                                   # slot 1 was freed
+    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
+    outs = []
+    for stale in (0, S - 1):
+        starts = np.asarray([ps + 3, stale, S - 2], np.int32)
+        *_, live, total = live_pages(jnp.asarray(starts), jnp.asarray(table),
+                                     1, ps, P)
+        assert live.tolist() == [2, 1, 4] and int(total) == 7
+        outs.append(np.asarray(paged_decode_attention(
+            q, jnp.asarray(k_pages), jnp.asarray(v_pages),
+            jnp.asarray(table), jnp.asarray(starts))))
+        assert np.isfinite(outs[-1]).all()
+    np.testing.assert_array_equal(outs[0][[0, 2]], outs[1][[0, 2]])
+    oracle = decode_attention(q[:, 0], jnp.asarray(dense_k),
+                              jnp.asarray(dense_v), jnp.asarray(starts + 1),
+                              block_s=ps)
+    np.testing.assert_array_equal(outs[1][[0, 2], 0],
+                                  np.asarray(oracle)[[0, 2]])
