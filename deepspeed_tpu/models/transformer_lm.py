@@ -289,12 +289,21 @@ class CachedAttention(nn.Module):
             return True
         return backend.on_tpu()
 
-    def _paged_decode_step(self, q, k, v, kv_cache, positions,
-                           deterministic):
-        """Decode/verify step over PAGED storage: write this step's K/V
-        columns straight into the page pool through the table (sentinel
-        entries drop — the ``_scatter_cols`` discipline, applied at the
-        source) and attend via the fused paged kernel. The value bytes
+    def _paged_decode_step(self, q, k, v, kv_cache):
+        """Decode/verify step over PAGED storage. ``kv_cache`` holds the
+        pool's STACKED leaves whole ((L, P, KV, cache_d, lanes), no
+        batch axis) with ``layer``, ``start`` and ``table``: this step's
+        K/V columns go into this layer's pages through the table
+        (``paged_write``: a Pallas call that takes the whole leaf,
+        rewrites the pages it names and returns the leaf aliased;
+        sentinel entries and positions out of range are not in its work
+        list, so they touch nothing) and the fused paged kernel attends
+        over the same leaf at the same layer. No slice, re-layout or
+        copy of a leaf is made on the way: the XLA scatter this replaced
+        (``buf.at[pages, :, :, offs].set`` on one layer's slice, on the
+        first and the minor dimension of a positions-minor page) cost
+        five passes over a 67 MB slice a layer with the slicing around
+        it, 74 % of a busy chip (ledger, PR 24). The value bytes
         written and the attention math match the dense path exactly
         (same quantize/pack pipeline; for each head the kernel folds one
         page at a time in table order, op-for-op the dense decode kernel
@@ -306,6 +315,7 @@ class CachedAttention(nn.Module):
         from ..ops.attention.paged_attention import (
             MAX_QUERY_ROWS,
             paged_decode_attention,
+            paged_write_columns,
         )
 
         assert T <= MAX_QUERY_ROWS, \
@@ -316,20 +326,16 @@ class CachedAttention(nn.Module):
         assert jnp.ndim(start) == 1, \
             "paged decode is slot-pooled: start must be (B,)"
         table = kv_cache["table"]                  # (B, pages_per_slot)
-        P = kv_cache["k"].shape[0]
-        ps = kv_cache["k"].shape[-1]
-        maxP = table.shape[1]
+        layer = kv_cache["layer"]
+        page_size = cfg.max_seq_len // table.shape[1]
         new_cache = {key: val for key, val in kv_cache.items()
-                     if key not in ("start", "table")}
+                     if key not in ("start", "table", "layer")}
 
-        # column writes through the table (mode="drop" for sentinels)
-        pos_w = positions.astype(jnp.int32)               # (B, T) absolute
-        pidx = pos_w // ps
-        valid = (pos_w >= 0) & (pos_w < maxP * ps)
-        pages = jnp.take_along_axis(table, jnp.clip(pidx, 0, maxP - 1),
-                                    axis=1)
-        pages = jnp.where(valid, pages, P)
-        offs = pos_w % ps
+        def write(key, cols):
+            new_cache[key] = paged_write_columns(
+                kv_cache[key], layer, cols, table, start,
+                page_size=page_size)
+
         k_rows = k.astype(cfg.dtype).transpose(0, 2, 1, 3)  # (B, KV, T, D)
         v_rows = v.astype(cfg.dtype).transpose(0, 2, 1, 3)
         scales = {}
@@ -341,29 +347,23 @@ class CachedAttention(nn.Module):
 
             k_rows, k_sc = quantize_kv_rows(k_rows)       # scales (B,KV,T)
             v_rows, v_sc = quantize_kv_rows(v_rows)
-            for key, sc in (("k_scale", k_sc), ("v_scale", v_sc)):
-                buf = kv_cache[key]                       # (P, KV, ps)
-                new_cache[key] = buf.at[pages, :, offs].set(
-                    sc.transpose(0, 2, 1).astype(buf.dtype), mode="drop")
+            write("k_scale", k_sc)
+            write("v_scale", v_sc)
             scales = dict(k_scale_pages=new_cache["k_scale"],
                           v_scale_pages=new_cache["v_scale"])
         k_cols = k_rows.transpose(0, 1, 3, 2)             # (B, KV, D, T)
         v_cols = v_rows.transpose(0, 1, 3, 2)
         if kv_packed:
-            from ..ops.attention.decode_attention import pack_int8_sublanes
-
             k_cols = pack_int8_sublanes(k_cols)           # (B, KV, D//4, T)
             v_cols = pack_int8_sublanes(v_cols)
-        for key, cols in (("k", k_cols), ("v", v_cols)):
-            buf = kv_cache[key]                           # (P, KV, cd, ps)
-            vals = cols.transpose(0, 3, 1, 2)             # (B, T, KV, cd)
-            new_cache[key] = buf.at[pages, :, :, offs].set(
-                vals.astype(buf.dtype), mode="drop")
+        write("k", k_cols)
+        write("v", v_cols)
 
         slopes = alibi_slopes(H) if cfg.pos_emb == "alibi" else None
         y = paged_decode_attention(
             q.astype(cfg.dtype), new_cache["k"], new_cache["v"], table,
-            start, alibi_slopes=slopes, **scales)
+            start, layer=layer, page_size=page_size, alibi_slopes=slopes,
+            **scales)
         y = y.astype(cfg.dtype).reshape(B, T, H * D)
         o_proj = _dense(cfg, self.config.n_embd, use_bias=cfg.qkv_bias,
                         name="o_proj")
@@ -384,13 +384,13 @@ class CachedAttention(nn.Module):
 
         kv_packed = kv_cache_spec(cfg)[2]
         if decode:
-            # This layer's KV-cache slice arrives as an ARGUMENT (dict
-            # with k/v [+ scales] and the shared ``start``) and the
-            # updated slice is RETURNED — the stacked cache rides the
-            # layer scan's carry with per-layer dynamic-update-slices,
-            # the one pattern XLA reliably keeps in place at any size.
-            # (The previous design — per-layer flax cache variables,
-            # nn.scan variable_axes — lowers to a scan whose xs/ys pair
+            # This layer's KV cache arrives as an ARGUMENT (dict with
+            # k/v [+ scales] and the shared ``start``) and the updated
+            # one is RETURNED: the stacked cache rides the layer scan's
+            # carry (_ScanBlock), as one layer's slice for the
+            # contiguous cache and whole for a page pool. (The previous
+            # design — per-layer flax cache variables, nn.scan
+            # variable_axes — lowers to a scan whose xs/ys pair
             # double-buffers the quantized cache above ~100 MB:
             # BASELINE.md round-5 capacity section.)
             assert kv_cache is not None, "decode needs the kv_cache slice"
@@ -411,14 +411,14 @@ class CachedAttention(nn.Module):
             k = apply_rotary(k, positions, rotary_dim=rd, theta=cfg.rope_theta)
 
         if decode and kv_cache is not None and "table" in kv_cache:
-            # Paged decode: this layer's K/V live in the PAGE POOL
-            # ((P, KV, cache_d, page_size), no batch axis) and both the
-            # column writes and the attention read resolve positions
-            # through the per-slot page table — no dense per-slot view is
-            # ever materialized (the gather→attend→scatter round-trip the
-            # fused kernel eliminates; ops/attention/paged_attention.py).
-            return self._paged_decode_step(q, k, v, kv_cache, positions,
-                                           deterministic)
+            # Paged decode: K/V live in the PAGE POOL ((L, P, KV,
+            # cache_d, lanes), no batch axis, every layer in one
+            # leaf) and both the column writes and the attention read
+            # resolve (layer, position) through the per-slot page table
+            # inside Pallas calls — no dense per-slot view and no slice
+            # of the leaf is ever materialized
+            # (ops/attention/paged_attention.py).
+            return self._paged_decode_step(q, k, v, kv_cache)
 
         kv_scales = None  # set on the quantized-cache einsum fallback
         # "fresh" attention = causal over the just-computed k/v. True for
@@ -637,13 +637,25 @@ class TransformerBlock(nn.Module):
 
 
 class _ScanBlock(nn.Module):
-    """One scanned layer. The carry is ``(x, cache, start, layer_idx)``:
-    the STACKED (L-leading) KV cache rides the carry and each layer
-    dynamic-slices its own entry and dynamic-update-slices it back — the
-    carry-DUS pattern XLA keeps in place at any size, unlike scanned
-    cache VARIABLES whose xs/ys accumulator pair double-buffers the
-    quantized cache above ~100 MB (BASELINE.md round-5 capacity
-    section)."""
+    """One scanned layer. The carry is ``(x, cache, start, layer_idx)``
+    and the STACKED (L-leading) KV cache rides it. Which of two access
+    patterns a layer uses is read off the carry's contents:
+
+    - the contiguous cache (``generate()``, the ``SlotPool``): each
+      layer dynamic-slices its own (B, KV, cd, S) entry and
+      dynamic-update-slices it back. Scanned cache VARIABLES double-
+      buffer the quantized cache above ~100 MB through their xs/ys pair
+      (BASELINE.md round-5 capacity section); the carry-DUS of a
+      batch-major dense row did not.
+    - a page pool (``"table"`` in the carry): the stacked leaves and the
+      layer counter go to the block whole and come back whole; the
+      block reads and writes them through Pallas calls that index
+      (layer, page) and alias the leaf. Carry-DUS is NOT kept in place
+      here: with a scatter and a custom call between the slice and the
+      update, XLA made one pass over the 67 MB slice for the
+      dynamic-slice and one for the dynamic-update-slice of every layer
+      and leaf, 0.96 s of a 2.81 s busy trace beside the scatter's own
+      re-layouts (serve-pythia-1b4-chat, ledger, PR 24)."""
 
     config: TransformerConfig
 
@@ -658,19 +670,21 @@ class _ScanBlock(nn.Module):
         if cache is None:
             x, _ = block(x, decode, deterministic, None, block_hint)
             return (x, None, start, li), None
-        # "table" is the POOL-WIDE page table (slots, pages_per_slot) —
-        # shared by every layer, so it rides the slice whole and is never
-        # written back (the paged branch returns k/v pages only)
+        if "table" in cache:
+            # "table" is the POOL-WIDE page table (slots, pages_per_slot),
+            # shared by every layer and never written
+            x, leaves = block(x, decode, deterministic,
+                              dict(cache, start=start, layer=li),
+                              block_hint)
+            return (x, dict(leaves, table=cache["table"]), start,
+                    li + 1), None
         kv_slice = {key: jax.lax.dynamic_index_in_dim(val, li, 0,
                                                       keepdims=False)
-                    for key, val in cache.items() if key != "table"}
+                    for key, val in cache.items()}
         kv_slice["start"] = start
-        if "table" in cache:
-            kv_slice["table"] = cache["table"]
         x, new_slice = block(x, decode, deterministic, kv_slice, block_hint)
-        cache = {key: (val if key == "table"
-                       else jax.lax.dynamic_update_slice_in_dim(
-                           val, new_slice[key][None], li, 0))
+        cache = {key: jax.lax.dynamic_update_slice_in_dim(
+                     val, new_slice[key][None], li, 0)
                  for key, val in cache.items()}
         return (x, cache, start, li + 1), None
 
@@ -707,6 +721,25 @@ def kv_cache_spec(cfg: TransformerConfig):
     if cfg.kv_cache_quant:
         return jnp.int8, D, False
     return cfg.dtype, D, False
+
+
+def page_lanes(page_size: int) -> int:
+    """The minor dimension a page of ``page_size`` columns is stored
+    with: whole 128-lane tiles. A Mosaic kernel takes its operands
+    row-major, where a 64-wide page fills half of each tile anyway; the
+    TPU client, left to store a ``(..., 128, 64)`` leaf as it likes,
+    puts the head dim minor instead, and XLA then wraps every kernel
+    call in a copy of the whole leaf to row-major and back (``copy.107
+    / .110`` of the decode program before PR 27; four copies of the
+    stacked leaf a step, 8.5 ms each, once the kernels took it whole:
+    chip runs of PR 27). A leaf whose minor dimension IS the lane tile
+    has one layout everybody agrees on, at the bytes the row-major
+    layout takes in any case: twice a page's at ``page_size`` 64, none
+    extra at 128. (Pinning the layout of a 64-wide leaf with
+    ``jax.experimental.layout`` works within one process and breaks the
+    persistent compile cache: a deserialized executable reports default
+    layouts for its row-major operands; chip run of PR 27.)"""
+    return -(-page_size // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -760,18 +793,21 @@ class KVCacheSpec:
     def paged_cache(self, num_pages: int, page_size: int) -> dict:
         """Zeroed PAGE-POOL k/v arrays: the positions axis is split into
         ``num_pages`` physical pages of ``page_size`` columns each, with
-        NO batch axis — k/v (L, P, KV, cache_d, page_size) [+ scales
-        (L, P, KV, page_size)]. A per-slot page table maps logical
-        positions to pages; :meth:`dense_from_pages` reassembles the
+        NO batch axis — k/v (L, P, KV, cache_d, lanes) [+ scales
+        (L, P, KV, lanes)], ``lanes = page_lanes(page_size)``: a page's
+        columns stand in its first ``page_size`` lanes and the rest is
+        never read. A per-slot page table maps logical positions to
+        pages; :meth:`dense_from_pages` reassembles the
         ``stacked_cache`` layout the attention kernels consume. Same
         dtype/packing tiers as the contiguous container (int8/packed
         cache columns page exactly like full-precision ones)."""
+        lanes = page_lanes(page_size)
         shape = (self.n_layer, num_pages, self.kv_heads, self.cache_d,
-                 page_size)
+                 lanes)
         cache = {"k": jnp.zeros(shape, self.dtype),
                  "v": jnp.zeros(shape, self.dtype)}
         if self.quantized:
-            sshape = (self.n_layer, num_pages, self.kv_heads, page_size)
+            sshape = (self.n_layer, num_pages, self.kv_heads, lanes)
             cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
             cache["v_scale"] = jnp.zeros(sshape, jnp.float32)
         return cache
@@ -790,20 +826,21 @@ class KVCacheSpec:
         slots free). ``table`` rows must span exactly
         ``max_seq_len // page_size`` pages."""
         B, max_pages = table.shape
+        ps = self.max_seq_len // max_pages
         flat = table.reshape(-1)
         out = {}
         for key in ("k", "v"):
-            leaf = paged[key]                       # (L, P, KV, cd, ps)
-            L, _, KV, cd, ps = leaf.shape
-            g = jnp.take(leaf, flat, axis=1, mode="clip")
+            leaf = paged[key]                       # (L, P, KV, cd, lanes)
+            L, _, KV, cd, _ = leaf.shape
+            g = jnp.take(leaf, flat, axis=1, mode="clip")[..., :ps]
             g = g.reshape(L, B, max_pages, KV, cd, ps)
             out[key] = g.transpose(0, 1, 3, 4, 2, 5).reshape(
                 L, B, KV, cd, max_pages * ps)
         if self.quantized:
             for key in ("k_scale", "v_scale"):
-                leaf = paged[key]                   # (L, P, KV, ps)
-                L, _, KV, ps = leaf.shape
-                g = jnp.take(leaf, flat, axis=1, mode="clip")
+                leaf = paged[key]                   # (L, P, KV, lanes)
+                L, _, KV, _ = leaf.shape
+                g = jnp.take(leaf, flat, axis=1, mode="clip")[..., :ps]
                 g = g.reshape(L, B, max_pages, KV, ps)
                 out[key] = g.transpose(0, 1, 3, 2, 4).reshape(
                     L, B, KV, max_pages * ps)
@@ -829,7 +866,10 @@ def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:
 class _CacheStore(nn.Module):
     """Owns the STACKED (n_layer-leading) KV-cache arrays as top-level
     flax variables in the ``cache`` collection. The stack rides the
-    layer scan's CARRY (see _ScanBlock) rather than scanned per-layer
+    layer scan's CARRY (see _ScanBlock: sliced and updated per layer
+    for the contiguous cache, whole for a page pool, where per-layer
+    slicing measured two passes over the slice a layer) rather than
+    scanned per-layer
     variables; this module is only the flax-variable home that keeps the
     engine-facing contract (prefill/decode with ``mutable=["cache"]``,
     cache an opaque pytree) unchanged. Call once to READ (returns the
@@ -1035,11 +1075,11 @@ class TransformerLM(nn.Module):
         """Fused paged-kernel decode step: like :meth:`decode`, but the
         provided ``cache`` collection holds the PAGE POOL arrays
         (``KVCacheSpec.paged_cache`` layout — k/v (L, P, KV, cache_d,
-        page_size), no batch axis) and ``table`` is the (B,
+        lanes), no batch axis) and ``table`` is the (B,
         pages_per_slot) int32 page table (sentinel = num_pages). Column
-        writes scatter through the table and attention reads pages in
-        place inside the fused kernel — no dense per-slot view is ever
-        materialized. ``start_pos`` must be the per-slot (B,) cache
+        writes go through the table and attention reads pages in place,
+        both inside Pallas calls on the whole leaf — no dense per-slot
+        view and no slice of a leaf is ever materialized. ``start_pos`` must be the per-slot (B,) cache
         lengths; handles 1 <= T <= MAX_QUERY_ROWS query rows (plain
         decode and speculative verify). Call with ``mutable=["cache"]``;
         greedy output is bitwise-identical to the dense-oracle

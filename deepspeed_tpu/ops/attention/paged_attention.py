@@ -1,11 +1,33 @@
-"""Fused paged-attention decode as a Pallas TPU kernel.
+"""The page pool's two access points as Pallas TPU kernels: the fused
+paged-attention decode (``paged_decode``) and the column write
+(``paged_write``).
 
 The vLLM PagedAttention insight, aimed at this repo's hottest serving op:
-the kernel reads the :class:`~deepspeed_tpu.serving.paged_pool.PagedKVPool`
-pages IN PLACE instead of gathering them into a dense per-slot view first
-(``KVCacheSpec.dense_from_pages`` gather → dense attention →
-``_scatter_cols`` writeback materializes O(slots × max_seq_len) K/V every
-step).
+the kernels read and write the
+:class:`~deepspeed_tpu.serving.paged_pool.PagedKVPool` pages IN PLACE.
+Both take the pool's STACKED leaf ``(L, P, KV, Dc, page_size)`` whole and
+find their block by ``(layer, page)`` from scalar prefetch; the write
+returns the leaf through ``input_output_aliases``. No program of a
+serving step slices, re-lays-out or copies a leaf (the gather → dense
+attention → scatter composition materialized O(slots × max_seq_len) K/V
+every step; the per-layer slice → XLA scatter → kernel → update-slice
+that followed it made five passes over a 67 MB slice a layer, 74 % of a
+busy chip: serve-pythia-1b4-chat, ledger, PR 24).
+
+**The leaf's minor dimension.** A Mosaic operand is row-major. The TPU
+client's own choice for a ``(..., 128, 64)`` bf16 leaf is the head dim
+minor (no lane padded), and XLA then wraps every kernel call in a copy
+of the whole operand to row-major and back. The pool therefore stores a
+page in whole 128-lane tiles (``page_lanes``: a 64-wide page in the
+first 64 lanes of 128, which is what its row-major layout takes in HBM
+as in VMEM anyway), a shape with one layout everybody agrees on, and
+the kernels are told the ``page_size`` beside the leaf: with that, the
+compiled decode, chunk and admission programs hold no operation over a
+leaf but the custom calls (temporaries 2 MB, 0.8 GB and 0 at the served
+size, compiled for a described v5e); without it, four copies of the
+stacked leaf a step at 8.5 ms each (chip run of PR 27). A leaf whose
+minor dimension is the page size itself is taken too (tests, pages of
+128).
 
 **Grid.** One grid step is one LIVE page of one slot with every KV head
 of the device in it. The wrapper turns ``(starts, table)`` into a work
@@ -63,6 +85,30 @@ the arms are compared within a bf16 tolerance instead (chip_smoke.py:
 max |delta logit| 0.041 at logit scale 5.0 on a v5e, PR 21). Bitwise
 equality on the chip at page size 128 has not been tried.
 
+**The write** (:func:`paged_write`, PR 27). One grid step is one page of
+one layer for one group of KV heads: the ``(1, 1, kv_group, Dc,
+page_size)`` block comes in, the columns named by the step replace its
+lanes ``[lo, hi)`` and the block goes back to the same place. The new
+columns arrive positions-minor in windows of ``lcm(page_size, 128)``
+lanes (a lane block has to be a multiple of 128) and are rotated to
+their offset in the page inside the kernel; Mosaic rotates 32-bit lanes
+only, so bf16 and int8 rows ride bit-cast to words, which moves whole
+columns all the same and keeps every bit (NaN payloads, signed zeros:
+the write is data movement, no arithmetic). The work list
+(:func:`run_work`: page, source row, window, rotation, ``lo``, ``hi`` of
+each step) rides scalar prefetch and the grid is as long as the list:
+what the XLA scatter's ``mode="drop"`` dropped (a sentinel page, a
+position out of range) is NOT in the list, so it touches nothing. Two
+steps may name one page only if they follow each other (the block is
+then still in VMEM and the second goes on from it). Three shapes of
+write share the kernel: a step's 1-8 columns a slot into one layer
+(:func:`paged_write_columns`, the model's decode and verify), a run of a
+dense cache's columns into every layer (:func:`paged_write_runs`: the
+chunk's window, whole prefilled rows, the kernel-off compositions), and
+the fp32 scale leaves ``(L, P, KV, page_size)`` of the quantized tiers,
+which go through the same call with the heads in the stored dim's place
+(less code than keeping a scatter for them).
+
 Garbage is masked by length, never by table lookups: sentinel table
 entries (``num_pages`` = unmapped) clip to a real page exactly like the
 dense gather's ``mode="clip"``, and stale columns past the live length
@@ -88,7 +134,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import backend
 from .flash_attention import LANES, NEG_INF, SUBLANES
 
-__all__ = ["paged_decode_attention", "plan_grid", "live_pages",
+__all__ = ["paged_decode_attention", "paged_write_columns",
+           "paged_write_runs", "plan_grid", "plan_write", "live_pages",
            "MAX_QUERY_ROWS"]
 
 # one kernel serves decode (T=1) and speculative verify (T=K+1): query
@@ -181,13 +228,15 @@ def live_pages(starts: jax.Array, table: jax.Array, num_rows: int,
 
 
 def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
-                  slope_ref, q_ref, k_ref, v_ref, *refs, scale: float,
-                  rep: int, alibi: bool, quantized: bool, packed: bool,
-                  compute_dtype):
-    # the first six are scalar-prefetch SMEM arrays: the work list of
-    # live_pages (page_ref is read by the index maps only), (B,) starts
-    # and (H,) slopes. One grid step is one live page of one slot for
-    # one group of KV heads. For each head the fold mirrors
+                  slope_ref, layer_ref, q_ref, k_ref, v_ref, *refs,
+                  page_size: int, scale: float, rep: int, alibi: bool,
+                  quantized: bool, packed: bool, compute_dtype):
+    # the first seven are scalar-prefetch SMEM arrays: the work list of
+    # live_pages, (B,) starts, (H,) slopes and the (1,) layer of the
+    # stacked leaf (page_ref and layer_ref are read by the index maps
+    # only). One grid step is one live page of one slot for one group of
+    # KV heads: the K/V blocks are (1, 1, kv_group, Dc, lanes), the page
+    # in their first page_size lanes. For each head the fold mirrors
     # decode_attention._decode_kernel line for line (the bitwise-parity
     # contract in the module docstring); the differences are where K/V
     # blocks come from and that each query row carries its own causal
@@ -196,7 +245,7 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
         k_scale_ref, v_scale_ref, *refs = refs
     o_ref, acc_ref, m_ref, l_ref = refs
     g, w = pl.program_id(0), pl.program_id(1)
-    _, kv_group, _, page_size = k_ref.shape
+    kv_group = k_ref.shape[2]
     heads = kv_group * rep
     entry = entry_ref[w]
     slot = slot_ref[w]
@@ -215,8 +264,8 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
     for h in range(heads):
         c = h // rep                      # GQA: rep query heads a KV head
         q = q_ref[0, h]                                   # (SUBLANES, D)
-        k = k_ref[0, c]                                   # (Dc, page_size)
-        v = v_ref[0, c]
+        k = k_ref[0, 0, c][:, :page_size]                 # (Dc, page_size)
+        v = v_ref[0, 0, c][:, :page_size]
         if quantized:
             if packed:
                 k = pltpu.bitcast(k, jnp.int8).astype(compute_dtype)
@@ -227,7 +276,7 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
         s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if quantized:
-            s = s * k_scale_ref[0, c]                     # (1, page) scale
+            s = s * k_scale_ref[0, 0, c][:, :page_size]    # (1, page)
         if alibi:
             # the dense kernel's bias for the query at ``start``, then
             # row t's own offset: slope * (pos - (start + t)). Row 0
@@ -246,7 +295,7 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
             alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
             l_ref.shape[1:])
         if quantized:
-            p = p * v_scale_ref[0, c]                     # (1, page) scale
+            p = p * v_scale_ref[0, 0, c][:, :page_size]    # (1, page)
         acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -260,7 +309,8 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, table: jax.Array,
-                           starts: jax.Array, *,
+                           starts: jax.Array, *, layer=None,
+                           page_size: Optional[int] = None,
                            scale: Optional[float] = None,
                            alibi_slopes: Optional[jax.Array] = None,
                            k_scale_pages: Optional[jax.Array] = None,
@@ -274,9 +324,11 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         Row ``t`` of slot ``b`` attends cache positions
         ``[0, starts[b] + t]`` (its own column included — the caller has
         already written this step's T columns into the pages).
-      k_pages/v_pages: (P, KV, Dc, page_size) ONE layer's physical page
-        pool, H % KV == 0 (GQA). May be int8, or int32-packed
-        (Dc = D // 4) when scales are given.
+      k_pages/v_pages: (L, P, KV, Dc, lanes) the pool's STACKED leaf,
+        read at ``layer`` through the index map: no slice of it is ever
+        made. H % KV == 0 (GQA). May be int8, or int32-packed
+        (Dc = D // 4) when scales are given. One layer's
+        (P, KV, Dc, lanes) pool is taken as a stack of one.
       table: (B, pages_per_slot) int32 page table; ``P`` is the
         unmapped sentinel (clipped to a real page, masked by length —
         the dense gather's ``mode="clip"`` discipline). A slot attends
@@ -284,33 +336,50 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         nothing is an empty slot, whatever its ``starts`` says.
       starts: (B,) int32 cache length BEFORE this step's tokens (the
         slot pool's ``index`` mirror at dispatch).
+      layer: int32 scalar, the layer of the stacked leaf to read
+        (traced: the layer scan's counter). ``None`` reads layer 0.
+      page_size: positions a page holds, in its first lanes; ``None``
+        when the leaf's minor dimension is the page size itself.
       alibi_slopes: optional (H,) ALiBi slopes.
-      k_scale_pages/v_scale_pages: (P, KV, page_size) fp32 per-column
+      k_scale_pages/v_scale_pages: (L, P, KV, lanes) fp32 per-column
         dequantization scales for a quantized page pool.
     Returns (B, T, H, D) in q's dtype.
     """
     starts = jnp.broadcast_to(jnp.asarray(starts, jnp.int32),
                               (q.shape[0],))
-    kernel = functools.partial(_paged_decode_attention_local, scale=scale)
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scale_pages is not None:
+            k_scale_pages, v_scale_pages = (k_scale_pages[None],
+                                            v_scale_pages[None])
+    layer = jnp.zeros((1,), jnp.int32) if layer is None \
+        else jnp.asarray(layer, jnp.int32).reshape(1)
+    kernel = functools.partial(
+        _paged_decode_attention_local, scale=scale,
+        page_size=k_pages.shape[-1] if page_size is None else page_size)
     B, H = backend.BATCH, backend.HEADS
     # the page pool has no batch dim: every device holds every page of
     # its KV heads, and its slots' rows of the table
     return backend.shard_kernel(
         kernel, (B, None, H, None),
-        q=(q, (B, None, H, None)), k_pages=(k_pages, (None, H, None, None)),
-        v_pages=(v_pages, (None, H, None, None)), table=(table, (B, None)),
-        starts=(starts, (B,)), alibi_slopes=(alibi_slopes, (H,)),
-        k_scale_pages=(k_scale_pages, (None, H, None)),
-        v_scale_pages=(v_scale_pages, (None, H, None)))
+        q=(q, (B, None, H, None)),
+        k_pages=(k_pages, (None, None, H, None, None)),
+        v_pages=(v_pages, (None, None, H, None, None)),
+        table=(table, (B, None)), starts=(starts, (B,)),
+        layer=(layer, (None,)), alibi_slopes=(alibi_slopes, (H,)),
+        k_scale_pages=(k_scale_pages, (None, None, H, None)),
+        v_scale_pages=(v_scale_pages, (None, None, H, None)))
 
 
-def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, *,
-                                  scale, alibi_slopes, k_scale_pages,
-                                  v_scale_pages):
+def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
+                                  *, scale, page_size, alibi_slopes,
+                                  k_scale_pages, v_scale_pages):
     """:func:`paged_decode_attention` on the slots and heads one device
     holds."""
     B, T, H, D = q.shape
-    P, KV, Dc, ps = k_pages.shape
+    L, P, KV, Dc, lanes = k_pages.shape
+    ps = page_size
+    assert ps <= lanes, f"page of {ps} in {lanes} lanes"
     maxP = table.shape[1]
     assert H % KV == 0, f"H={H} not a multiple of KV={KV}"
     assert 1 <= T <= MAX_QUERY_ROWS, \
@@ -349,7 +418,7 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, *,
         q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, SUBLANES - T), (0, 0)))
 
     kv_group, _, (groups, _) = plan_grid(
-        B, H, KV, D, Dc, ps, maxP, k_pages.dtype, q.dtype, quantized)
+        B, H, KV, D, Dc, lanes, maxP, k_pages.dtype, q.dtype, quantized)
     heads = kv_group * rep
     slot_of, entry_of, page_of, live, total = live_pages(
         starts, table, T, ps, P)
@@ -358,20 +427,22 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, *,
         (1, heads, SUBLANES, D),
         lambda g, w, slot_ref, *_: (slot_ref[w], g, 0, 0))
 
-    def page_index(g, w, slot_ref, entry_ref, page_ref, *_):
-        return (page_ref[w], g, 0, 0)
+    def page_index(g, w, slot_ref, entry_ref, page_ref, live_ref,
+                   start_ref, slope_ref, layer_ref):
+        return (layer_ref[0], page_ref[w], g, 0, 0)
 
     pools = [k_pages, v_pages]
-    blocks = [(1, kv_group, Dc, ps)] * 2
+    blocks = [(1, 1, kv_group, Dc, lanes)] * 2
     if quantized:
-        # scales ride as (P, KV, 1, page_size) so a head's (1, ps) row
+        # scales ride as (L, P, KV, 1, lanes) so a head's (1, ps) row
         # lands on LANES, matching s/p (same trick as the dense
         # kernel's (B, KV, 1, S) reshape)
-        pools += [k_scale_pages.astype(jnp.float32).reshape(P, KV, 1, ps),
-                  v_scale_pages.astype(jnp.float32).reshape(P, KV, 1, ps)]
-        blocks += [(1, kv_group, 1, ps)] * 2
+        pools += [
+            k_scale_pages.astype(jnp.float32).reshape(L, P, KV, 1, lanes),
+            v_scale_pages.astype(jnp.float32).reshape(L, P, KV, 1, lanes)]
+        blocks += [(1, 1, kv_group, 1, lanes)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(groups, total),
         in_specs=[head_block] + [pl.BlockSpec(block, page_index)
                                  for block in blocks],
@@ -383,13 +454,248 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, *,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, rep=rep, alibi=alibi,
+        functools.partial(_paged_kernel, page_size=ps, scale=scale, rep=rep,
+                          alibi=alibi,
                           quantized=quantized, packed=packed,
                           compute_dtype=compute_dtype),
         name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, SUBLANES, D), q.dtype),
         interpret=backend.pallas_interpret(),
-    )(slot_of, entry_of, page_of, live, starts, slopes, q4, *pools)
+    )(slot_of, entry_of, page_of, live, starts, slopes, layer, q4, *pools)
     out = out[:, :, :T]
     return out.transpose(0, 2, 1, 3).astype(out_dtype)
+
+
+# ---------------------------------------------------------------------------
+# the write: columns into the stacked leaf, in place
+# ---------------------------------------------------------------------------
+def plan_write(KV: int, Dc: int, page_size: int, lanes: int, dtype):
+    """``(kv_group, window)`` of a :func:`paged_write` call, from its
+    static shapes alone. ``window`` is the width of the source block: a
+    lane block has to be a multiple of 128 (or the whole axis), so a
+    64-wide page is cut out of a 128-wide window of the source.
+    ``kv_group`` follows :data:`VMEM_BUDGET_BYTES` like the read's: one
+    page block in and one out, and the source window, each
+    double-buffered."""
+    window = math.lcm(page_size, LANES)
+
+    def step_bytes(kv_group: int) -> int:
+        return 2 * kv_group * (2 * _vmem_tile_bytes(Dc, lanes, dtype)
+                               + _vmem_tile_bytes(Dc, window, dtype))
+
+    kv_group = max((g for g in range(1, KV + 1) if KV % g == 0
+                    and step_bytes(g) <= VMEM_BUDGET_BYTES), default=1)
+    return kv_group, window
+
+
+def _write_kernel(layer_ref, page_ref, row_ref, win_ref, shift_ref, lo_ref,
+                  hi_ref, leaf_ref, src_ref, out_ref):
+    # scalar prefetch: the (1,) first layer and the work list. One grid
+    # step is one page of one layer for one group of KV heads: the page
+    # comes in, the source window is rotated so that its columns stand
+    # at their offsets in the page, lanes [lo, hi) are taken from it and
+    # the page goes back. A page that the step before also wrote is
+    # still in VMEM (same block index: neither fetched again nor
+    # written back in between), so the step goes on from the output
+    # block and not from the stale input block.
+    w = pl.program_id(2)
+    lanes = out_ref.shape[-1]
+    again = jnp.logical_and(
+        w > 0, page_ref[jnp.maximum(w - 1, 0)] == page_ref[w])
+
+    @pl.when(jnp.logical_not(again))
+    def _fetch():
+        out_ref[...] = leaf_ref[...]
+
+    lo, hi, shift = lo_ref[w], hi_ref[w], shift_ref[w]
+    narrow = out_ref.dtype.itemsize < 4
+    for c in range(out_ref.shape[2]):
+        cols = src_ref[0, 0, c]                           # (Dc, window)
+        if narrow:
+            # Mosaic rotates 32-bit lanes only ("Rotate with non-32-bit
+            # data" is refused): bf16 / int8 rows ride packed in words,
+            # which moves whole columns all the same
+            cols = pltpu.bitcast(cols, jnp.int32)
+        cols = pltpu.roll(cols, shift, axis=1)[:, :lanes]
+        if narrow:
+            cols = pltpu.bitcast(cols, out_ref.dtype)
+        lane = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 1)
+        out_ref[0, 0, c] = jnp.where((lane >= lo) & (lane < hi), cols,
+                                     out_ref[0, 0, c])
+
+
+def paged_write(leaf: jax.Array, src: jax.Array, layer, work,
+                page_size: int) -> jax.Array:
+    """Write columns into the pool's stacked leaf IN PLACE and return it.
+
+    Args:
+      leaf: (L, P, KV, Dc, lanes), aliased to the result: the call
+        touches the pages of its work list and nothing else.
+      src: (Ls, R, KV, Dc, W) the new columns, positions-minor; ``W`` a
+        multiple of :func:`plan_write`'s window. Layers
+        ``layer .. layer + Ls - 1`` of the leaf are written from
+        ``src[0] .. src[Ls - 1]`` with the same work list.
+      layer: int32 scalar (traced), the first layer written.
+      work: ``(page, row, win, shift, lo, hi, total)``, the first six
+        int32 lists of one length, ``total`` how many of them run. Step
+        ``w`` replaces columns ``[lo, hi)`` of page ``page[w]`` by window
+        ``win[w]`` of ``src[:, row[w]]`` rotated right by ``shift[w]``
+        lanes. A page may stand in two steps only if they follow each
+        other. What is to be dropped (a sentinel page, a position out
+        of range) is not in the list: see :func:`run_work`.
+      page_size: positions a page holds, in the first of its lanes.
+    """
+    page_of, row_of, win_of, shift_of, lo_of, hi_of, total = work
+    L, P, KV, Dc, lanes = leaf.shape
+    Ls = src.shape[0]
+    kv_group, window = plan_write(KV, Dc, page_size, lanes, leaf.dtype)
+    assert src.shape[2:4] == (KV, Dc) and src.shape[4] % window == 0, \
+        (src.shape, leaf.shape, window)
+
+    def page_index(l, g, w, layer_ref, page_ref, *_):
+        return (layer_ref[0] + l, page_ref[w], g, 0, 0)
+
+    def src_index(l, g, w, layer_ref, page_ref, row_ref, win_ref, *_):
+        return (l, row_ref[w], g, 0, win_ref[w])
+
+    page_block = pl.BlockSpec((1, 1, kv_group, Dc, lanes), page_index)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(Ls, KV // kv_group, total),
+        in_specs=[page_block,
+                  pl.BlockSpec((1, 1, kv_group, Dc, window), src_index)],
+        out_specs=page_block,
+    )
+    return pl.pallas_call(
+        _write_kernel,
+        name="paged_write",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
+        input_output_aliases={7: 0},
+        interpret=backend.pallas_interpret(),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), page_of, row_of, win_of,
+      shift_of, lo_of, hi_of, leaf, src.astype(leaf.dtype))
+
+
+def run_work(table: jax.Array, first: jax.Array, count: int, src_col,
+             page_size: int, num_pages: int, window: int):
+    """The work list of writing positions ``[first[r], first[r] + count)``
+    of every row ``r`` through its table row: one step for each page
+    that such a run touches, row by row in table order.
+
+    ``src_col(r, pos)`` is the lane of the source at which row ``r``
+    keeps position ``pos``. Dropped, and so not in the list: positions
+    under 0 or past the table row's end, and entries that hold the
+    sentinel ``num_pages`` ("drop" stays "touches nothing")."""
+    R, maxP = table.shape
+    ps = page_size
+    first = jnp.asarray(first, jnp.int32)
+    # entries a run of `count` columns can touch, wherever it starts
+    n_e = min((count + ps - 2) // ps + 1, maxP)
+    begin = jnp.clip(first, 0, maxP * ps)                  # (R,)
+    end = jnp.clip(first + count, 0, maxP * ps)
+    entry = begin[:, None] // ps + jnp.arange(n_e, dtype=jnp.int32)[None]
+    lo_pos = jnp.maximum(entry * ps, begin[:, None])       # (R, n_e)
+    hi_pos = jnp.minimum((entry + 1) * ps, end[:, None])
+    page = jnp.take_along_axis(jnp.asarray(table, jnp.int32),
+                               jnp.minimum(entry, maxP - 1), axis=1)
+    keep = (hi_pos > lo_pos) & (page >= 0) & (page < num_pages)
+    row = jnp.broadcast_to(jnp.arange(R, dtype=jnp.int32)[:, None],
+                           entry.shape)
+    lo = lo_pos - entry * ps
+    col = src_col(row, lo_pos)                 # source lane of column lo
+    work = (jnp.clip(page, 0, num_pages - 1), row, col // window,
+            (lo - col) % window, lo, hi_pos - entry * ps)
+    # the kept steps first, in order; what follows them never runs
+    order = jnp.argsort(jnp.logical_not(keep).reshape(-1), stable=True)
+    return tuple(jnp.where(keep, x, 0).reshape(-1)[order]
+                 .astype(jnp.int32) for x in work) \
+        + (jnp.sum(keep, dtype=jnp.int32),)
+
+
+def paged_write_columns(leaf: jax.Array, layer, cols: jax.Array,
+                        table: jax.Array, starts: jax.Array, *,
+                        page_size: Optional[int] = None) -> jax.Array:
+    """One decode or verify step's new columns into ONE layer of the
+    stacked leaf, in place: ``cols`` (B, KV, Dc, T) goes to positions
+    ``starts[b] .. starts[b] + T - 1`` of slot ``b`` through ``table``
+    (B, pages_per_slot). A scale leaf (L, P, KV, lanes) takes
+    (B, KV, T). ``page_size`` as in :func:`paged_decode_attention`.
+    Returns the leaf."""
+    H = backend.HEADS
+    tail = (None,) * (leaf.ndim - 3)
+    # the pool has no batch dim and is whole on every device of a batch
+    # axis: each of them writes every slot's columns
+    return backend.shard_kernel(
+        functools.partial(
+            _paged_write_columns_local,
+            page_size=leaf.shape[-1] if page_size is None else page_size),
+        (None, None, H) + tail,
+        leaf=(leaf, (None, None, H) + tail),
+        layer=(jnp.asarray(layer, jnp.int32).reshape(1), (None,)),
+        cols=(cols, (None, H) + tail),
+        table=(table, (None, None)),
+        starts=(jnp.asarray(starts, jnp.int32), (None,)))
+
+
+def _paged_write_columns_local(leaf, layer, cols, table, starts, *,
+                               page_size):
+    scale_leaf = leaf.ndim == 4
+    if scale_leaf:            # (L, P, KV, lanes): the heads take Dc's place
+        leaf, cols = leaf[:, :, None], cols[:, None]
+    L, P, KV, Dc, lanes = leaf.shape
+    B, T = cols.shape[0], cols.shape[-1]
+    _, window = plan_write(KV, Dc, page_size, lanes, leaf.dtype)
+    # slot b's columns at lanes [b * stride, b * stride + T) of one
+    # positions-minor row: a power of two, so that no slot's columns
+    # straddle two windows
+    stride = 1 << (T - 1).bit_length()
+    width = -(-B * stride // window) * window
+    src = jnp.pad(cols, ((0, 0),) * 3 + ((0, stride - T),))
+    src = src.transpose(1, 2, 0, 3).reshape(KV, Dc, B * stride)
+    src = jnp.pad(src, ((0, 0), (0, 0), (0, width - B * stride)))
+    work = run_work(table, starts, T,
+                    lambda row, pos: row * stride + pos - starts[:, None],
+                    page_size, P, window)
+    # every slot is row 0 of the source: its lanes tell them apart
+    work = (work[0], jnp.zeros_like(work[1])) + work[2:]
+    out = paged_write(leaf, src[None, None], layer[0], work, page_size)
+    return out[:, :, 0] if scale_leaf else out
+
+
+def paged_write_runs(leaf: jax.Array, dense: jax.Array, table: jax.Array,
+                     first: jax.Array, count: int, *,
+                     page_size: Optional[int] = None) -> jax.Array:
+    """Runs of a dense positions-minor cache into EVERY layer of the
+    stacked leaf, in place: positions ``[first[r], first[r] + count)`` of
+    ``dense`` (L, R, KV, Dc, S) row ``r`` go through ``table``
+    (R, pages_per_slot) to the pages (a chunk's window, a verify step's
+    columns, whole prefilled rows at ``first = 0, count = S``). A scale
+    leaf (L, P, KV, lanes) takes (L, R, KV, S). Returns the leaf."""
+    H = backend.HEADS
+    tail = (None,) * (leaf.ndim - 3)
+    return backend.shard_kernel(
+        functools.partial(
+            _paged_write_runs_local, count=count,
+            page_size=leaf.shape[-1] if page_size is None else page_size),
+        (None, None, H) + tail,
+        leaf=(leaf, (None, None, H) + tail),
+        dense=(dense, (None, None, H) + tail),
+        table=(table, (None, None)),
+        first=(jnp.asarray(first, jnp.int32), (None,)))
+
+
+def _paged_write_runs_local(leaf, dense, table, first, *, count, page_size):
+    scale_leaf = leaf.ndim == 4
+    if scale_leaf:
+        leaf, dense = leaf[:, :, None], dense[:, :, None]
+    L, P, KV, Dc, lanes = leaf.shape
+    _, window = plan_write(KV, Dc, page_size, lanes, leaf.dtype)
+    S = dense.shape[-1]
+    if S % window:            # a cache shorter than one window (tests)
+        dense = jnp.pad(dense, ((0, 0),) * 4 + ((0, -S % window),))
+    work = run_work(table, first, min(count, S), lambda row, pos: pos,
+                    page_size, P, window)
+    out = paged_write(leaf, dense, 0, work, page_size)
+    return out[:, :, 0] if scale_leaf else out

@@ -1028,11 +1028,14 @@ class ServingEngine:
             req.admit_time = self._now()
             self._note_admit(1, width)
             with self.tracer.span("serving/admit", rid=req.request_id,
-                                  tokens=T, width=width):
+                                  tokens=T, width=width) as sp:
                 logits, pre_cache = eng._jit_prefill_at(
                     eng.params, jnp.asarray(ids),
                     jnp.asarray(T - 1, jnp.int32))
                 self.pool.admit(pre_cache, slot, T)
+                if self._paged:
+                    sp.set(pool_writes=self.pool.pages_touched(
+                        slot, 0, self.pool.capacity))
                 with self.tracer.span("serving/sample"):
                     # dispatch only; the host value arrives at the
                     # end-of-step fetch
@@ -1295,10 +1298,13 @@ class ServingEngine:
             t0 = self._now()
             self._note_admit(n, nB * width)
             with self.tracer.span("serving/prefill_batch", n=n, width=width,
-                                  batch=nB):
+                                  batch=nB) as sp:
                 logits, pre_cache = eng._jit_prefill_at(
                     eng.params, jnp.asarray(ids), jnp.asarray(last_pos))
                 self.pool.admit_rows(pre_cache, slots, lengths)
+                if self._paged:
+                    sp.set(pool_writes=self.pool.pages_touched(
+                        slots[:n], np.zeros((n,)), self.pool.capacity))
                 with self.tracer.span("serving/sample"):
                     # dispatch only; host values arrive at the
                     # end-of-step fetch
@@ -1376,10 +1382,11 @@ class ServingEngine:
             self._ensure_pages(slot, pos, pos + L)
         self._dispatched["chunk"] = L
         with self.tracer.span("serving/prefill_chunk", rid=req.request_id,
-                              pos=pos, len=L):
+                              pos=pos, len=L) as sp:
             if self._paged:
                 logits = self.pool.run_prefill_chunk(
                     self.engine, ids, slot, pos, L, L - 1)
+                sp.set(pool_writes=self.pool.pages_touched(slot, pos, C))
             else:
                 logits, cache = self.engine.prefill_chunk(
                     self.pool.cache, ids, slot, pos, L, L - 1)
@@ -2027,9 +2034,14 @@ class ServingEngine:
         tokens = self._cur_dev[:, None]
         pos = jnp.asarray(self.pool.positions())
         self._dispatched["decode"] = len(running)
-        with self.tracer.span("serving/decode", live=len(running)):
+        with self.tracer.span("serving/decode", live=len(running)) as sp:
             if self._paged:
                 logits = self.pool.run_decode(eng, tokens, pos)
+                # counted after the dispatch, from a mirror the dispatch
+                # does not move: every slot's row is in the program's
+                # work list, the live ones map a page at their index
+                sp.set(pool_writes=self.pool.pages_touched(
+                    np.arange(self.pool.num_slots), self.pool.starts, 1))
             else:
                 logits, cache = eng._jit_decode(eng.params, self.pool.cache,
                                                 tokens, pos)
@@ -2133,7 +2145,7 @@ class ServingEngine:
             [self._cur_dev[:, None], jnp.asarray(draft)], axis=1)
         self._rng, sub = jax.random.split(self._rng)
         self._dispatched["decode"] = self._running_count()
-        with self.tracer.span("serving/verify_k", k=K):
+        with self.tracer.span("serving/verify_k", k=K) as sp:
             if self._paged:
                 out_dev, n_emit_dev = self.pool.run_verify(
                     eng, tokens,
@@ -2141,6 +2153,9 @@ class ServingEngine:
                     jnp.asarray(draft_len), sub,
                     jnp.asarray(self.temperature, jnp.float32),
                     self._greedy, int(self.top_k), float(self.top_p))
+                sp.set(pool_writes=self.pool.pages_touched(
+                    np.arange(self.pool.num_slots), self.pool.starts,
+                    K + 1))
             else:
                 cache, out_dev, n_emit_dev = eng.verify_k(
                     self.pool.cache, tokens,
@@ -2340,10 +2355,16 @@ class ServingEngine:
             if r.slot is not None:
                 errors.append(f"queued req {r.request_id} still holds "
                               f"slot {r.slot}")
-        if np.any(self.pool.starts < 0) or \
-                np.any(self.pool.starts > self.pool.capacity):
-            errors.append(f"cache starts out of [0, {self.pool.capacity}]: "
-                          f"{self.pool.starts.tolist()}")
+        # seated slots only: a free slot's start is whatever the decode
+        # program's uniform +1 counted it up to since it was last seated
+        # (SlotPool.positions clamps it), past the capacity after 2,048
+        # idle steps — 25 s of serving at 12 ms a step
+        seated = sorted(s for s in self._slot_req
+                        if 0 <= s < self.pool.num_slots)
+        starts = self.pool.starts[seated]
+        if np.any(starts < 0) or np.any(starts > self.pool.capacity):
+            errors.append(f"cache starts of seated slots {seated} out of "
+                          f"[0, {self.pool.capacity}]: {starts.tolist()}")
         for r in (self._handoff_ready or ()):
             # a parked handoff must still be a live seat HERE — anything
             # else means a retire/transfer path forgot to purge it

@@ -22,21 +22,32 @@ Terminology map (for readers coming from the reference systems):
 Shape discipline is identical to the contiguous
 :class:`~deepspeed_tpu.serving.slot_pool.SlotPool`: physical storage is
 ONE statically-shaped pytree — k/v ``(L, num_pages, KV, cache_d,
-page_size)`` — and every jitted entry (decode, ``verify_k``,
+lanes)``, a page in the first ``page_size`` of its lanes — and every
+jitted entry (decode, ``verify_k``,
 ``prefill_chunk``, batched admission) is a gather → existing traced
 attention program → scatter composition:
 :meth:`KVCacheSpec.dense_from_pages` reassembles the dense ``(L, B, KV,
 cache_d, max_seq_len)`` view the compiled attention already consumes
 (so the math — and greedy output — is BITWISE identical to the
 contiguous pool), and only the columns the step actually wrote are
-scattered back by page id. Page churn, prefix hits, CoW forks and
+written back by page id. Page churn, prefix hits, CoW forks and
 preempt/resume are all data movement inside the same buffers: zero
 post-warmup recompiles, watchdog-enforced. The transient dense view is
 scratch the compiler can schedule; the *persistent* HBM footprint is
-the page pool — which is the served-requests-per-GB lever. (A fused
-Pallas paged-attention kernel that skips the dense rematerialization is
-the natural follow-up; the pool/table/refcount contract here is
-layout-compatible with it.)
+the page pool — which is the served-requests-per-GB lever. With the
+kernel active, decode and verify skip the dense view: the model reads
+pages in place (``decode_paged``).
+
+ONE write path leads into the pool (PR 27): ``paged_write``
+(``ops/attention/paged_attention.py``), a Pallas call that takes a
+stacked leaf whole, rewrites the pages its work list names and returns
+the leaf aliased — from the model's decode step for its own columns,
+and from :meth:`PagedKVPool._write_runs` for the chunk's window,
+admitted rows and the kernel-off compositions. A page is stored in
+whole 128-lane tiles (``models.transformer_lm.page_lanes``: k/v
+``(L, num_pages, KV, cache_d, lanes)``), the one shape whose device
+layout XLA and a Mosaic operand agree on; no program of a serving step
+passes over a whole leaf.
 
 Composition with the int8 packed cache (BASELINE.md): the page pool
 allocates through the same module-declared ``KVCacheSpec``, so
@@ -48,9 +59,9 @@ rather than replacing it.
 Sentinel convention: table entry ``num_pages`` means "unmapped". The
 gather reads sentinel entries with a clip-mode take (arbitrary real
 page — harmless, a slot's mapped region always covers its live
-``[0, index)`` columns and attention masks the rest), and the scatter
-drops sentinel writes (``mode="drop"``), so a dead or padding row can
-never touch a real page.
+``[0, index)`` columns and attention masks the rest), and a write
+leaves sentinel entries out of its work list, so a dead or padding row
+can never touch a real page.
 """
 
 from __future__ import annotations
@@ -157,13 +168,21 @@ class PagedKVPool(SlotPool):
     # ------------------------------------------------------------------
     def _fresh_cache(self):
         """Zeroed page pool + sentinel table, committed like the dense
-        pool (see SlotPool._fresh_cache for why commitment matters)."""
-        cs = self.spec.paged_cache(self.num_pages, self.page_size)
-        cs["index"] = jnp.zeros((self.num_slots,), jnp.int32)
-        cs["table"] = jnp.full((self.num_slots, self.pages_per_slot),
-                               self.num_pages, jnp.int32)
-        if self._sharding is not None:
-            cs = {k: self._place_leaf(k, v) for k, v in cs.items()}
+        pool (see SlotPool._fresh_cache for why commitment matters).
+        The page leaves are BORN in their placement: made whole on the
+        default device first and moved, a pool sharded over four chips
+        stood twice on chip 0 while it was built (1.5 GB short of
+        loading there, chip run of PR 27)."""
+        shapes = jax.eval_shape(
+            lambda: self.spec.paged_cache(self.num_pages, self.page_size))
+        cs = {key: jnp.zeros(leaf.shape, leaf.dtype,
+                             device=self._leaf_sharding(key, leaf))
+              for key, leaf in shapes.items()}
+        cs["index"] = self._place_leaf(
+            "index", jnp.zeros((self.num_slots,), jnp.int32))
+        cs["table"] = self._place_leaf(
+            "table", jnp.full((self.num_slots, self.pages_per_slot),
+                              self.num_pages, jnp.int32))
         return {"cache_store": cs}
 
     def _table_from_mirror(self):
@@ -551,57 +570,53 @@ class PagedKVPool(SlotPool):
                     block[key].astype(dst_cs[key].dtype), mode="drop")
         return out
 
-    def _scatter_cols(self, pool: dict, dense: dict, tables, positions):
-        """Traced: write the dense view's columns at ``positions``
-        ((B, W) absolute positions, aligned with the dense batch) back
-        into the page pool through per-row page ``tables`` ((B,
-        max_pages_per_slot)). Out-of-range positions and sentinel table
-        entries scatter with ``mode="drop"`` — they touch nothing."""
-        with jax.named_scope("scatter"):
-            return self._scatter_cols_body(pool, dense, tables, positions)
+    def pages_touched(self, slots, first, count: int) -> int:
+        """How many pages a write of positions ``[first[i], first[i] +
+        count)`` of ``slots[i]`` touches, from the host's mirror of the
+        table: the length of the write's work list for one leaf of one
+        layer (``pool_writes`` on the dispatch spans). Unmapped entries
+        and positions out of range are dropped there and not counted
+        here."""
+        slots = np.atleast_1d(np.asarray(slots, np.int64))
+        first = np.atleast_1d(np.asarray(first, np.int64))
+        lo = np.clip(first, 0, self.capacity)[:, None]
+        hi = np.clip(first + count, 0, self.capacity)[:, None]
+        entry = np.arange(self.pages_per_slot)[None, :] * self.page_size
+        spanned = (entry < hi) & (entry + self.page_size > lo)
+        return int(np.sum(spanned
+                          & (self.table[slots] != self.num_pages)))
 
-    def _scatter_cols_body(self, pool: dict, dense: dict, tables, positions):
-        ps = self.page_size
-        maxP = self.pages_per_slot
-        sent = self.num_pages
-        pidx = positions // ps
-        valid = (positions >= 0) & (positions < maxP * ps)
-        pages = jnp.take_along_axis(tables, jnp.clip(pidx, 0, maxP - 1),
-                                    axis=1)
-        pages = jnp.where(valid, pages, sent)
-        offs = positions % ps
+    def _write_runs(self, pool: dict, dense: dict, tables, first,
+                    count: int):
+        """Traced: write positions ``[first[r], first[r] + count)`` of
+        every row ``r`` of the dense view ((L, B, KV, cd, S), aligned
+        with ``tables`` (B, max_pages_per_slot)) into the page pool —
+        the ONE write path into it beside the model's own column write,
+        and the same Pallas call (``paged_write``): it takes each
+        stacked leaf whole, rewrites the pages its work list names and
+        returns the leaf aliased. Positions out of range and sentinel
+        table entries are not in the list — they touch nothing."""
+        from ..ops.attention.paged_attention import paged_write_runs
+
         out = dict(pool)
-        for key in ("k", "v"):
-            leaf = dense[key]                     # (L, B, KV, cd, S)
-            vals = jnp.take_along_axis(
-                leaf, positions[None, :, None, None, :], axis=4,
-                mode="clip")
-            vals = vals.transpose(1, 4, 0, 2, 3)  # (B, W, L, KV, cd)
-            out[key] = pool[key].at[:, pages, :, :, offs].set(
-                vals.astype(pool[key].dtype), mode="drop")
-        for key in ("k_scale", "v_scale"):
-            if key not in pool:
-                continue
-            leaf = dense[key]                     # (L, B, KV, S)
-            vals = jnp.take_along_axis(
-                leaf, positions[None, :, None, :], axis=3, mode="clip")
-            vals = vals.transpose(1, 3, 0, 2)     # (B, W, L, KV)
-            out[key] = pool[key].at[:, pages, :, offs].set(
-                vals.astype(pool[key].dtype), mode="drop")
+        with jax.named_scope("scatter"):
+            for key in ("k", "v", "k_scale", "v_scale"):
+                if key in pool:
+                    out[key] = paged_write_runs(
+                        pool[key], dense[key], tables, first, count,
+                        page_size=self.page_size)
         return out
 
     def _paged_admit_rows(self, pool: dict, pre: dict, rows_tables,
                           slots, lengths):
-        """Batched paged admission: scatter every column of the (full-
+        """Batched paged admission: write every column of the (full-
         capacity) prefill cache through host-passed per-row tables.
         Padding rows are ALL-sentinel tables (not just a sentinel slot
         id — indexing the device table with a clamped sentinel slot
-        would alias a real slot's pages), so their writes drop."""
-        S = self.capacity
+        would alias a real slot's pages), so they write nothing."""
         nB = rows_tables.shape[0]
-        pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :],
-                               (nB, S))
-        out = self._scatter_cols(pool, pre, rows_tables, pos)
+        out = self._write_runs(pool, pre, rows_tables,
+                               jnp.zeros((nB,), jnp.int32), self.capacity)
         out["index"] = pool["index"].at[slots].set(
             jnp.asarray(lengths, jnp.int32), mode="drop")
         out["table"] = pool["table"]
@@ -630,7 +645,7 @@ class PagedKVPool(SlotPool):
         module = getattr(engine, "_serve_module", None) or engine.module
         dequant = engine._dequant
         chunk_gen = getattr(module, "prefill_chunk", None)
-        scatter = self._scatter_cols
+        write_runs = self._write_runs
 
         def dense_cache(cs):
             with jax.named_scope("gather"):
@@ -641,8 +656,8 @@ class PagedKVPool(SlotPool):
         def paged_decode(params, cs, token, pos):
             logits, new = decode_fn(params, dense_cache(cs), token, pos)
             ncs = new["cache_store"]
-            W = cs["index"][:, None]          # one column written per row
-            out = scatter(cs, ncs, cs["table"], W)
+            # one column written per row
+            out = write_runs(cs, ncs, cs["table"], cs["index"], 1)
             out["index"] = ncs["index"]
             out["table"] = cs["table"]
             return logits, out
@@ -653,10 +668,9 @@ class PagedKVPool(SlotPool):
                 params, dense_cache(cs), tokens, pos, draft, draft_len,
                 rng, temperature, greedy, top_k, top_p)
             ncs = new["cache_store"]
-            K1 = tokens.shape[1]              # K+1 columns written per row
-            W = cs["index"][:, None] + \
-                jnp.arange(K1, dtype=jnp.int32)[None, :]
-            out = scatter(cs, ncs, cs["table"], W)
+            # K+1 columns written per row
+            out = write_runs(cs, ncs, cs["table"], cs["index"],
+                             tokens.shape[1])
             out["index"] = ncs["index"]
             out["table"] = cs["table"]
             return out, out_tok, n_emit
@@ -676,10 +690,8 @@ class PagedKVPool(SlotPool):
                 ids, start[None], last_idx, method=chunk_gen,
                 mutable=["cache"])
             new = vars_["cache"]["cache_store"]
-            C = ids.shape[1]
-            W = start[None, None] + \
-                jnp.arange(C, dtype=jnp.int32)[None, :]       # (1, C)
-            outcs = scatter(cs, new, row_table[None], W)
+            outcs = write_runs(cs, new, row_table[None], start[None],
+                               ids.shape[1])
             outcs["index"] = cs["index"].at[slot].set(
                 start + jnp.asarray(length, jnp.int32), mode="drop")
             outcs["table"] = cs["table"]
@@ -693,14 +705,14 @@ class PagedKVPool(SlotPool):
 
         # -- fused paged-attention kernel entries (ISSUE 13) -----------
         # Same jit signatures as the dense compositions above, but the
-        # model step runs ``decode_paged``: column writes scatter through
-        # the page table at the source and the Pallas kernel reads pages
-        # in place, one grid step for each live page of a slot with every
-        # head in it — the dense (L, B, KV, cd, S) scratch view is never
-        # built. Greedy decode output is bitwise-identical (for each head
-        # the kernel folds one page at a time in table order, which is
-        # decode_attention at block_s=page_size; see
-        # ops/attention/paged_attention.py).
+        # model step runs ``decode_paged``: column writes go through the
+        # page table at the source (``paged_write``) and the Pallas kernel
+        # reads pages in place, one grid step for each live page of a slot
+        # with every head in it — the dense (L, B, KV, cd, S) scratch view
+        # is never built, and no slice of a leaf either. Greedy decode
+        # output is bitwise-identical (for each head the kernel folds one
+        # page at a time in table order, which is decode_attention at
+        # block_s=page_size; see ops/attention/paged_attention.py).
         if self.kernel_active \
                 and getattr(module, "decode_paged", None) is not None:
             from ..ops.attention.paged_attention import MAX_QUERY_ROWS

@@ -67,12 +67,15 @@ class SlotPool:
         self._admit_rows_jit = jax.jit(self._admit_rows, donate_argnums=(0,))
 
     # ------------------------------------------------------------------
+    def _leaf_sharding(self, key: str, leaf):
+        """Where cache leaf ``key`` goes (``None``: wherever JAX puts
+        it); the resolver reads ``leaf``'s shape alone."""
+        return self._sharding(key, leaf) if callable(self._sharding) \
+            else self._sharding
+
     def _place_leaf(self, key: str, leaf):
         """Commit one cache leaf to its sharding (see ``__init__``)."""
-        if self._sharding is None:
-            return leaf
-        sh = self._sharding(key, leaf) if callable(self._sharding) \
-            else self._sharding
+        sh = self._leaf_sharding(key, leaf)
         return leaf if sh is None else jax.device_put(leaf, sh)
 
     def _fresh_cache(self) -> Dict[str, Any]:

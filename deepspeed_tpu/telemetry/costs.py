@@ -378,8 +378,10 @@ def kv_hbm_report(pool) -> Dict[str, float]:
 
     Predicted comes from ``KVCacheSpec`` math alone (never from array
     shapes): per-token bytes x capacity tokens, where capacity is
-    ``num_pages x page_size`` for the paged pool and
-    ``num_slots x max_seq_len`` for contiguous rows. Actual sums
+    ``num_pages x page_size`` for the paged pool (stored in whole
+    128-lane tiles, ``page_lanes``: a 64-wide page takes the bytes of
+    128 columns) and ``num_slots x max_seq_len`` for contiguous rows.
+    Actual sums
     ``.nbytes`` over the pool's k/v (+ scale) device leaves — the
     ``index``/``table`` bookkeeping arrays are not KV storage and are
     excluded from both sides, so a healthy pool reports drift 0.0.
@@ -391,12 +393,15 @@ def kv_hbm_report(pool) -> Dict[str, float]:
         per_token += spec.n_layer * spec.kv_heads * 2 * 4  # f32 scales
     paged = hasattr(pool, "num_pages")
     if paged:
+        from ..models.transformer_lm import page_lanes
+
         tokens = pool.num_pages * pool.page_size
-        page_bytes = per_token * pool.page_size
+        page_bytes = per_token * page_lanes(pool.page_size)
+        predicted = float(page_bytes * pool.num_pages)
     else:
         tokens = pool.num_slots * spec.max_seq_len
         page_bytes = 0.0
-    predicted = float(per_token * tokens)
+        predicted = float(per_token * tokens)
     cs = pool.cache.get("cache_store", {})
     actual = 0.0
     for leaf_name in _KV_LEAVES:

@@ -94,6 +94,197 @@ def test_paged_attention_lowers_for_tpu(compiled_kernels, page_size, rows,
         lambda *a: paged_decode_attention(*a, **kwargs), *args) >= 1
 
 
+@pytest.mark.parametrize("page_size", [64, 128])
+@pytest.mark.parametrize("tier", ["bf16", "int8", "packed", "scale"])
+def test_paged_write_lowers_for_tpu(compiled_kernels, page_size, tier):
+    """The pool's write kernel, both of its callers: a step's columns into
+    one layer, runs of a dense cache into every layer."""
+    from deepspeed_tpu.ops.attention.paged_attention import (
+        paged_write_columns, paged_write_runs)
+
+    L, P, KV, B, per_slot = 2, 8, 2, 2, 4
+    dtype, Dc = {"bf16": (jnp.bfloat16, 128), "int8": (jnp.int8, 128),
+                 "packed": (jnp.int32, 32), "scale": (jnp.float32, None)}[tier]
+    mid = (KV,) if Dc is None else (KV, Dc)
+    leaf = jnp.zeros((L, P) + mid + (page_size,), dtype)
+    table = jnp.arange(B * per_slot, dtype=jnp.int32).reshape(B, per_slot)
+    starts = jnp.asarray([page_size - 2, 5], jnp.int32)
+    cols = jnp.zeros((B,) + mid + (5,), dtype)
+    assert _tpu_custom_calls(paged_write_columns, leaf,
+                             jnp.asarray(1, jnp.int32), cols, table,
+                             starts) == 1
+    dense = jnp.zeros((L, B) + mid + (per_slot * page_size,), dtype)
+    assert _tpu_custom_calls(
+        lambda *a: paged_write_runs(*a, 64), leaf, dense, table,
+        starts) == 1
+
+
+@pytest.fixture(scope="module")
+def described_v5e():
+    """One chip of a v5e that is described and not attached: the TPU's own
+    compiler, no device. Made inside a fixture, never at import (only one
+    process may hold libtpu; see the on-chip-measurement guide)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                               # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_pool_kernels_compile_for_a_described_v5e_at_the_served_shape(
+        compiled_kernels, described_v5e):
+    """Mosaic itself, which the lowering above does not reach: it refused
+    the write's first form ("Rotate with non-32-bit data": bf16 and int8
+    columns are rotated as 32-bit words since), and a block that breaks the
+    tiling or the VMEM limit fails only here. Pythia-1.4B's pool (24 layers,
+    256 pages of 64 in 128 lanes, 16 heads of 128, 64 slots), each K/V
+    tier; and XLA around it: stored in whole lane tiles the leaf goes in
+    and comes out in one buffer, stored 64 wide it is copied whole to
+    row-major and back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from deepspeed_tpu.models.transformer_lm import page_lanes
+    from deepspeed_tpu.ops.attention.paged_attention import (
+        paged_decode_attention, paged_write_columns, paged_write_runs)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=described_v5e)
+
+    L, P, KV, ps, B, per_slot = 24, 256, 16, 64, 64, 32
+    lanes = page_lanes(ps)
+    table, starts = shape((B, per_slot), jnp.int32), shape((B,), jnp.int32)
+    layer = shape((), jnp.int32)
+
+    def write(leaf, cols):
+        return jax.jit(lambda *a: paged_write_columns(*a, page_size=ps),
+                       donate_argnums=0).lower(leaf, layer, cols, table,
+                                               starts).compile()
+
+    # a compile for a described chip cannot be read back from the
+    # persistent cache and warns when it tries
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for dtype, Dc in ((jnp.bfloat16, 128), (jnp.int8, 128),
+                          (jnp.int32, 32)):
+            leaf = shape((L, P, KV, Dc, lanes), dtype)
+            compiled = write(leaf, shape((B, KV, Dc, 8), dtype))
+            text = compiled.as_text()
+            assert "input_output_alias={ {}: (0, {}, may-alias) }" in text
+            assert text.count("tpu_custom_call") >= 1
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
+            jax.jit(lambda *a: paged_write_runs(*a, 64, page_size=ps),
+                    donate_argnums=0).lower(
+                leaf, shape((L, 1, KV, Dc, per_slot * ps), dtype),
+                shape((1, per_slot), jnp.int32),
+                shape((1,), jnp.int32)).compile()
+            scales = {} if dtype == jnp.bfloat16 else dict(
+                k_scale_pages=shape((L, P, KV, lanes), jnp.float32),
+                v_scale_pages=shape((L, P, KV, lanes), jnp.float32))
+            jax.jit(lambda q, k, v, t, s, li, **kw: paged_decode_attention(
+                q, k, v, t, s, layer=li, page_size=ps, **kw)).lower(
+                shape((B, 1, 16, 128), jnp.bfloat16), leaf, leaf, table,
+                starts, layer, **scales).compile()
+        # why the lanes: the same call on a 64-wide bf16 leaf
+        narrow = write(shape((L, P, KV, 128, ps), jnp.bfloat16),
+                       shape((B, KV, 128, 8), jnp.bfloat16))
+        assert narrow.memory_analysis().temp_size_in_bytes > 2 ** 30
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+# the lowered programs of a small paged server: what touches a pool leaf
+_LEAF_OPS = ("dynamic_slice", "dynamic_update_slice", "scatter", "transpose",
+             "gather", "pad", "concatenate", "convert", "select")
+
+
+def _ops_on(lowered, shapes):
+    """Names of the StableHLO operations, at any depth, that take or give
+    a value of one of ``shapes`` (MLIR tensor types)."""
+    found = []
+
+    def walk(op):
+        types = [str(v.type) for v in list(op.operands) + list(op.results)]
+        if any(t in shapes for t in types):
+            found.append(op.name)
+        for region in op.regions:
+            for block in region:
+                for child in block:
+                    walk(child.operation)
+
+    walk(lowered.compiler_ir(dialect="stablehlo").operation)
+    return found
+
+
+def test_no_program_of_a_serving_step_passes_over_a_pool_leaf(
+        compiled_kernels):
+    """``kernel_decode``, ``paged_chunk`` and the admission program take the
+    stacked K and V leaves and hand them on through custom calls alone: no
+    slice, update-slice, scatter or transpose of a leaf (one layer's or
+    the stacked one). The chunk program's ``dense_from_pages`` gather of
+    one slot's row stays (ROADMAP S2) and is the one reader allowed."""
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
+                                                     transformer_config)
+    from deepspeed_tpu.serving.paged_pool import PagedKVPool
+
+    cfg = transformer_config("gpt-neox", vocab_size=128, max_seq_len=256,
+                             n_embd=256, n_layer=2, n_head=2,
+                             dtype=jnp.bfloat16)
+    model = TransformerLM(cfg)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32),
+                           method=model.logits)["params"])
+    params = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, jnp.bfloat16), params)
+    engine = ds.init_inference(model=model, model_parameters=params,
+                               config={"dtype": "bf16"})
+    engine._ensure_params(jnp.zeros((1, 2), jnp.int32))
+    slots, pages, ps = 2, 6, 64
+    pool = PagedKVPool(model.kv_cache_spec(), slots, num_pages=pages,
+                       page_size=ps, kernel="on")
+    pool.bind_engine(engine)
+    cs = pool.cache["cache_store"]
+    lanes = cs["k"].shape[-1]
+    assert cs["k"].shape == (2, pages, 2, 128, lanes) and lanes == 128
+    leaves = {f"tensor<2x{pages}x2x128x{lanes}xbf16>",
+              f"tensor<{pages}x2x128x{lanes}xbf16>",
+              f"tensor<1x{pages}x2x128x{lanes}xbf16>"}
+    i32 = jnp.int32
+    pre = dict(model.kv_cache_spec().stacked_cache(2))
+    programs = {
+        "kernel_decode": (pool._paged_decode_kernel_jit, (
+            engine.params, cs, jnp.zeros((slots, 1), i32),
+            jnp.zeros((slots,), i32))),
+        "paged_chunk": (pool._paged_chunk_jit, (
+            engine.params, cs, jnp.zeros((1, 64), i32),
+            jnp.zeros((pool.pages_per_slot,), i32), jnp.asarray(0, i32),
+            jnp.asarray(64, i32), jnp.asarray(64, i32),
+            jnp.asarray(63, i32))),
+        "_paged_admit_rows": (pool._admit_rows_jit, (
+            cs, pre, jnp.zeros((2, pool.pages_per_slot), i32),
+            jnp.zeros((2,), i32), jnp.zeros((2,), i32))),
+    }
+    for name, (jitted, args) in programs.items():
+        lowered = jitted.trace(*args).lower(lowering_platforms=("tpu",))
+        text = lowered.as_text()
+        assert "paged_write" in text, name
+        ops = [op for op in _ops_on(lowered, leaves)
+               if op.split(".")[-1] in _LEAF_OPS]
+        allowed = {"stablehlo.gather"} if name == "paged_chunk" else set()
+        assert set(ops) <= allowed, (name, ops)
+    # and the decode program reads through the kernel, on the same leaf
+    lowered = pool._paged_decode_kernel_jit.trace(
+        *programs["kernel_decode"][1]).lower(lowering_platforms=("tpu",))
+    assert "paged_decode" in lowered.as_text()
+
+
 def test_dense_decode_lowers_for_tpu(compiled_kernels):
     from deepspeed_tpu.ops.attention.decode_attention import decode_attention
 

@@ -440,3 +440,258 @@ def test_freed_slots_with_stale_starts_cost_one_step_and_change_nothing():
                               block_s=ps)
     np.testing.assert_array_equal(outs[1][[0, 2], 0],
                                   np.asarray(oracle)[[0, 2]])
+
+
+# ---------------------------------------------------------------------------
+# the write: columns into the stacked leaf, in place (ISSUE 27)
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.ops.attention.paged_attention import (  # noqa: E402
+    paged_write_columns,
+    paged_write_runs,
+    plan_write,
+)
+
+# dtype and stored head dim of a 128-wide head in each K/V tier
+_WRITE_TIERS = {"bf16": (jnp.bfloat16, 128), "int8": (jnp.int8, 128),
+                "int32-packed": (jnp.int32, 32)}
+
+
+def _values(rng, shape, dtype):
+    if dtype == jnp.int32:                   # four packed int8: any word
+        return jnp.asarray(rng.integers(-2 ** 31, 2 ** 31, shape), dtype)
+    if dtype == jnp.int8:
+        return jnp.asarray(rng.integers(-127, 128, shape), dtype)
+    return jnp.asarray(rng.standard_normal(shape), dtype)
+
+
+def _scatter_columns(leaf, layer, cols, table, starts):
+    """What the model's XLA scatter did: ``buf.at[pages, :, :, offs]
+    .set(mode="drop")`` on one layer, sentinels and positions out of
+    range dropped."""
+    P, ps = leaf.shape[1], leaf.shape[-1]
+    maxP, T = table.shape[1], cols.shape[-1]
+    pos = starts[:, None] + np.arange(T)[None]
+    valid = (pos >= 0) & (pos < maxP * ps)
+    pages = np.where(valid, np.take_along_axis(
+        table, np.clip(pos // ps, 0, maxP - 1), axis=1), P)
+    if leaf.ndim == 4:                                    # a scale leaf
+        return leaf.at[layer, pages, :, pos % ps].set(
+            cols.transpose(0, 2, 1), mode="drop")
+    return leaf.at[layer, pages, :, :, pos % ps].set(
+        cols.transpose(0, 3, 1, 2), mode="drop")
+
+
+def _scatter_runs(leaf, dense, tables, positions):
+    """What ``_scatter_cols_body`` did on the stacked leaf."""
+    P, ps = leaf.shape[1], leaf.shape[-1]
+    maxP = tables.shape[1]
+    valid = (positions >= 0) & (positions < maxP * ps)
+    pages = np.where(valid, np.take_along_axis(
+        tables, np.clip(positions // ps, 0, maxP - 1), axis=1), P)
+    pos = jnp.asarray(positions)
+    if leaf.ndim == 4:
+        vals = jnp.take_along_axis(dense, pos[None, :, None, :], axis=3,
+                                   mode="clip").transpose(1, 3, 0, 2)
+        return leaf.at[:, pages, :, positions % ps].set(vals, mode="drop")
+    vals = jnp.take_along_axis(dense, pos[None, :, None, None, :], axis=4,
+                               mode="clip").transpose(1, 4, 0, 2, 3)
+    return leaf.at[:, pages, :, :, positions % ps].set(vals, mode="drop")
+
+
+def _same(a, b):
+    np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                  np.asarray(b.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("tier", sorted(_WRITE_TIERS))
+@pytest.mark.parametrize("T", [1, 4, 8])
+def test_write_columns_matches_the_scatter_it_replaced(T, tier):
+    """A decode (T=1) or verify (T=4, 8) step's columns: a run inside a
+    page, one that crosses into the next page, one whose next page is
+    unmapped (that part is dropped), a freed slot with a stale start
+    and a start past the table's end; the whole stacked leaf bitwise
+    equal to the XLA scatter's, so every other layer is untouched."""
+    dtype, Dc = _WRITE_TIERS[tier]
+    rng = np.random.default_rng(27)
+    L, P, KV, ps, maxP = 3, 9, 2, 64, 3
+    leaf = _values(rng, (L, P, KV, Dc, ps), dtype)
+    table = np.asarray([[4, 2, P], [7, 0, 8], [1, P, P], [P, P, P],
+                        [3, 5, 6]], np.int32)
+    starts = np.asarray([10, ps - 2, ps - 3, 77, maxP * ps - 1], np.int32)
+    cols = _values(rng, (len(starts), KV, Dc, T), dtype)
+    out = jax.jit(paged_write_columns)(leaf, jnp.asarray(1, jnp.int32),
+                                       cols, jnp.asarray(table),
+                                       jnp.asarray(starts))
+    _same(out, _scatter_columns(leaf, 1, cols, table, starts))
+    _same(out[0], leaf[0])
+    _same(out[2], leaf[2])
+    assert not np.array_equal(np.asarray(out[1].astype(jnp.float32)),
+                              np.asarray(leaf[1].astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("tier", sorted(_WRITE_TIERS))
+@pytest.mark.parametrize("first", [64, 64 + 37])
+def test_write_chunk_matches_the_scatter_it_replaced(first, tier):
+    """``paged_chunk``'s window, 64 columns of one slot's dense row in
+    every layer: a whole page at an aligned start, parts of two pages
+    at an unaligned one."""
+    dtype, Dc = _WRITE_TIERS[tier]
+    rng = np.random.default_rng(28)
+    L, P, KV, ps, maxP = 2, 6, 2, 64, 4
+    leaf = _values(rng, (L, P, KV, Dc, ps), dtype)
+    dense = _values(rng, (L, 1, KV, Dc, maxP * ps), dtype)
+    table = np.asarray([[5, 1, 3, P]], np.int32)
+    out = jax.jit(paged_write_runs, static_argnums=4)(
+        leaf, dense, jnp.asarray(table), jnp.asarray([first], jnp.int32), 64)
+    positions = first + np.arange(64)[None]
+    _same(out, _scatter_runs(leaf, dense, table, positions))
+    touched = {int(table[0, p // ps]) for p in positions[0]}
+    for page in set(range(P)) - touched:
+        _same(out[:, page], leaf[:, page])
+
+
+@pytest.mark.parametrize("tier", sorted(_WRITE_TIERS))
+def test_write_admitted_rows_matches_the_scatter_it_replaced(tier):
+    """``_paged_admit_rows``: every column of a prefill cache through
+    per-row tables; what a row does not map (and a padding row maps
+    nothing) is dropped."""
+    dtype, Dc = _WRITE_TIERS[tier]
+    rng = np.random.default_rng(29)
+    L, P, KV, ps, maxP = 2, 7, 2, 64, 3
+    leaf = _values(rng, (L, P, KV, Dc, ps), dtype)
+    dense = _values(rng, (L, 3, KV, Dc, maxP * ps), dtype)
+    tables = np.asarray([[6, 2, P], [P, P, P], [0, P, P]], np.int32)
+    out = jax.jit(paged_write_runs, static_argnums=4)(
+        leaf, dense, jnp.asarray(tables), jnp.zeros((3,), jnp.int32),
+        maxP * ps)
+    positions = np.broadcast_to(np.arange(maxP * ps)[None], (3, maxP * ps))
+    _same(out, _scatter_runs(leaf, dense, tables, positions))
+    for page in (1, 3, 4, 5):
+        _same(out[:, page], leaf[:, page])
+
+
+@pytest.mark.parametrize("tier", sorted(_WRITE_TIERS))
+def test_write_that_maps_nothing_touches_nothing(tier):
+    """Sentinel pages and positions out of range are not in the work
+    list: a call whose every write is dropped runs no step and hands
+    the leaf back as it was, bit for bit."""
+    dtype, Dc = _WRITE_TIERS[tier]
+    rng = np.random.default_rng(30)
+    L, P, KV, ps, maxP = 2, 4, 2, 64, 2
+    leaf = _values(rng, (L, P, KV, Dc, ps), dtype)
+    cols = _values(rng, (3, KV, Dc, 4), dtype)
+    # a freed slot, a start past the row's end, a negative start whose
+    # four columns all lie under position 0
+    table = jnp.asarray([[P, P], [0, 1], [2, 3]], jnp.int32)
+    starts = jnp.asarray([5, maxP * ps, -4], jnp.int32)
+    _same(paged_write_columns(leaf, 0, cols, table, starts), leaf)
+    dense = _values(rng, (L, 2, KV, Dc, maxP * ps), dtype)
+    _same(paged_write_runs(leaf, dense, jnp.full((2, maxP), P, jnp.int32),
+                           jnp.zeros((2,), jnp.int32), maxP * ps), leaf)
+    _same(paged_write_runs(leaf, dense, table[1:],
+                           jnp.asarray([maxP * ps, maxP * ps + 9]), 64),
+          leaf)
+
+
+def test_write_leaves_a_shared_prefix_page_alone():
+    """Two slots map the same read-only prefix page and write their own
+    next page: the shared page keeps every bit, each slot's column is
+    its own."""
+    rng = np.random.default_rng(31)
+    L, P, KV, Dc, ps = 2, 5, 2, 128, 64
+    leaf = _values(rng, (L, P, KV, Dc, ps), jnp.bfloat16)
+    table = np.asarray([[1, 3, P], [1, 4, P]], np.int32)
+    starts = np.asarray([ps + 9, ps + 20], np.int32)
+    cols = _values(rng, (2, KV, Dc, 1), jnp.bfloat16)
+    out = paged_write_columns(leaf, 0, cols, jnp.asarray(table),
+                              jnp.asarray(starts))
+    _same(out, _scatter_columns(leaf, 0, cols, table, starts))
+    _same(out[:, 1], leaf[:, 1])
+    _same(out[0, 3, :, :, 9], cols[0, :, :, 0])
+    _same(out[0, 4, :, :, 20], cols[1, :, :, 0])
+
+
+@pytest.mark.parametrize("T", [1, 8])
+def test_write_scale_leaves_through_the_same_kernel(T):
+    """The (L, P, KV, page_size) scale leaves of the quantized tiers go
+    through ``paged_write`` with the heads in the stored-dim's place."""
+    rng = np.random.default_rng(32)
+    L, P, KV, ps, maxP = 2, 6, 4, 64, 2
+    leaf = jnp.asarray(rng.uniform(0.01, 0.1, (L, P, KV, ps)), jnp.float32)
+    table = np.asarray([[2, 5], [P, P], [0, 3]], np.int32)
+    starts = np.asarray([ps - 3, 7, 12], np.int32)
+    cols = jnp.asarray(rng.uniform(0.01, 0.1, (3, KV, T)), jnp.float32)
+    out = paged_write_columns(leaf, 1, cols, jnp.asarray(table),
+                              jnp.asarray(starts))
+    _same(out, _scatter_columns(leaf, 1, cols, table, starts))
+    dense = jnp.asarray(rng.uniform(0.01, 0.1, (L, 3, KV, maxP * ps)),
+                        jnp.float32)
+    out = paged_write_runs(leaf, dense, jnp.asarray(table),
+                           jnp.asarray(starts), T)
+    _same(out, _scatter_runs(leaf, dense, table,
+                             starts[:, None] + np.arange(T)[None]))
+
+
+def test_write_group_follows_the_vmem_budget(monkeypatch):
+    """``plan_write`` keeps every KV head in one step at the served
+    shape and splits the heads where a page of each does not fit; the
+    split call writes the same bits."""
+    assert plan_write(16, 128, 64, 128, jnp.bfloat16) == (16, 128)
+    assert plan_write(16, 32, 128, 128, jnp.int32) == (16, 128)
+    rng = np.random.default_rng(33)
+    L, P, KV, Dc, ps = 2, 4, 4, 128, 64
+    leaf = _values(rng, (L, P, KV, Dc, ps), jnp.bfloat16)
+    cols = _values(rng, (2, KV, Dc, 2), jnp.bfloat16)
+    args = (leaf, 1, cols, jnp.asarray([[3, 0], [2, 4]], jnp.int32),
+            jnp.asarray([ps - 1, 5], jnp.int32))
+    whole = paged_write_columns(*args)
+    monkeypatch.setattr(paged_attention, "VMEM_BUDGET_BYTES", 400 * 1024)
+    assert plan_write(KV, Dc, ps, ps, jnp.bfloat16)[0] == 2
+    _same(paged_write_columns(*args), whole)
+
+
+@pytest.mark.parametrize("tier", sorted(_WRITE_TIERS))
+def test_pages_stored_in_whole_lane_tiles_read_and_write_the_same(tier):
+    """The pool stores a 64-wide page in the first 64 of 128 lanes
+    (``page_lanes``) and tells the kernels the page size: the write
+    touches those lanes alone and the read answers bit for bit what it
+    answers on the unpadded leaf."""
+    from deepspeed_tpu.models.transformer_lm import page_lanes
+
+    dtype, Dc = _WRITE_TIERS[tier]
+    rng = np.random.default_rng(34)
+    L, P, KV, ps, maxP, H, D = 2, 7, 2, 64, 3, 4, 128
+    lanes = page_lanes(ps)
+    assert (lanes, page_lanes(128), page_lanes(8)) == (128, 128, 128)
+    leaf = _values(rng, (L, P, KV, Dc, ps), dtype)
+    junk = _values(rng, (L, P, KV, Dc, lanes - ps), dtype)
+    wide = jnp.concatenate([leaf, junk], axis=-1)
+    table = np.asarray([[4, 2, P], [6, 0, 5]], np.int32)
+    starts = np.asarray([ps - 2, ps + 9], np.int32)
+    cols = _values(rng, (2, KV, Dc, 4), dtype)
+    args = (jnp.asarray(1, jnp.int32), cols, jnp.asarray(table),
+            jnp.asarray(starts))
+    out = paged_write_columns(wide, *args, page_size=ps)
+    _same(out[..., :ps], paged_write_columns(leaf, *args))
+    _same(out[..., ps:], junk)
+    dense = _values(rng, (L, 2, KV, Dc, maxP * ps), dtype)
+    out = paged_write_runs(wide, dense, jnp.asarray(table),
+                           jnp.asarray(starts), 64, page_size=ps)
+    _same(out[..., :ps], paged_write_runs(leaf, dense, jnp.asarray(table),
+                                          jnp.asarray(starts), 64))
+    _same(out[..., ps:], junk)
+
+    q = jnp.asarray(rng.standard_normal((2, 1, H, D)), jnp.bfloat16)
+    scales, wide_scales = {}, {}
+    if tier != "bf16":
+        sc = jnp.asarray(rng.uniform(0.01, 0.1, (L, P, KV, ps)), jnp.float32)
+        scales = dict(k_scale_pages=sc, v_scale_pages=sc)
+        wide_sc = jnp.pad(sc, ((0, 0),) * 3 + ((0, lanes - ps),),
+                          constant_values=7.0)
+        wide_scales = dict(k_scale_pages=wide_sc, v_scale_pages=wide_sc)
+    narrow = paged_decode_attention(q, leaf, leaf, jnp.asarray(table),
+                                    jnp.asarray(starts), layer=1, **scales)
+    padded = paged_decode_attention(q, wide, wide, jnp.asarray(table),
+                                    jnp.asarray(starts), layer=1,
+                                    page_size=ps, **wide_scales)
+    _same(padded, narrow)

@@ -52,6 +52,36 @@ def test_tokens_bitwise_match_generate(stack):
                                       err_msg=f"req {req.request_id}")
 
 
+@pytest.mark.parametrize("paged", [False, {"page_size": 8, "kernel": "on"}])
+def test_a_slot_left_idle_past_the_capacity_breaks_no_invariant(stack, paged):
+    """Every decode step advances every slot's index, free ones too: a
+    slot nobody was seated in for more steps than the cache has columns
+    counts past the capacity (``positions()`` clamps it). The audit is
+    about seated slots; the answers stay what ``generate()`` gives. On
+    the chip a chat server reached this after 25 s once a step took 12
+    ms (chip run of PR 27)."""
+    _, _, engine = stack
+    rng = np.random.default_rng(11)
+    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
+                        paged_kv=paged)
+    for _ in range(3):                    # one at a time: slot 1 never used
+        prompt = rng.integers(0, 64, size=6).astype(np.int32)
+        req = srv.submit(prompt, max_new_tokens=30)
+        srv.run_until_drained(max_steps=100)
+        srv.check_invariants()
+        np.testing.assert_array_equal(
+            req.tokens(), engine.generate(prompt[None], max_new_tokens=30)[0])
+    assert srv.pool.starts.max() > srv.pool.capacity
+    # and a seated slot out of range is still caught
+    srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
+               max_new_tokens=4)
+    srv.step()
+    (slot,) = srv._slot_req
+    srv.pool.starts[slot] = srv.pool.capacity + 1
+    with pytest.raises(Exception, match="seated slots"):
+        srv.check_invariants()
+
+
 def test_staggered_admission_and_slot_reuse(stack):
     """A request submitted while all slots are busy waits QUEUED, then is
     admitted into the retired request's slot; timing stamps are ordered."""

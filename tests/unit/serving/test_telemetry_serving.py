@@ -172,6 +172,42 @@ def test_traced_run_exports_step_spans_and_request_lanes(stack, tmp_path):
     assert steps == sorted(steps) and steps[0] >= 1
 
 
+@pytest.mark.parametrize("kernel", ["on", "off"])
+def test_dispatch_spans_count_the_pages_they_write(stack, kernel):
+    """``pool_writes`` on the decode, chunk and admission dispatch spans:
+    the pages of the call's write list for one leaf of one layer, as the
+    host's table mirror knows them. A decode step writes one page for
+    each slot that maps one at its index; a 16-token chunk over pages of
+    8 writes two or, from an unaligned start, three; an admission writes
+    the pages its prompt fills."""
+    _, _, engine = stack
+    rng = np.random.default_rng(72)
+    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
+                        tracer=Tracer(), prefill_chunk=16,
+                        paged_kv={"page_size": 8, "kernel": kernel,
+                                  "prefix_cache": False})
+    srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
+               max_new_tokens=4)
+    srv.submit(rng.integers(0, 64, size=40).astype(np.int32),
+               max_new_tokens=3)
+    srv.run_until_drained(max_steps=50)
+    srv.check_invariants()
+    events = [e for e in srv.tracer.events() if e["ph"] == "X"]
+
+    def writes(name):
+        return [(e["args"], e["args"]["pool_writes"]) for e in events
+                if e["name"] == name]
+
+    decodes = writes("serving/decode")
+    assert decodes and all(0 <= n <= 2 for _, n in decodes)
+    assert any(n == args["live"] for args, n in decodes)
+    chunks = writes("serving/prefill_chunk")
+    assert [args["pos"] for args, _ in chunks] == [0, 16, 32]
+    assert [n for _, n in chunks] == [2, 2, 1]     # 40 tokens: five pages
+    admits = writes("serving/admit") + writes("serving/prefill_batch")
+    assert [n for _, n in admits] == [1]           # 5 tokens: one page
+
+
 def test_set_tracer_enables_post_hoc_tracing(stack):
     _, _, engine = stack
     rng = np.random.default_rng(73)
