@@ -6,7 +6,7 @@ Drives both normal entry points once, on the TPU, through the public API
 benchmark uses, with random weights from a seed:
 
 * **train** — ``ds.initialize`` + ``engine.train_batch`` on the flagship of
-  ``bench.py``: GPT-2 350M (1024 x 24 x 16 heads, vocab 50257, seq 1024),
+  rounds 1-5: GPT-2 350M (1024 x 24 x 16 heads, vocab 50257, seq 1024),
   bf16, ZeRO-2, Adam, ``remat_policy="dots"``, micro-batch 10 (gas cut to 2).
   A few steps on one repeated batch: first loss near ln(vocab), every loss
   finite, last below first; the compiled step must contain the Pallas flash
@@ -247,7 +247,7 @@ def phase_train(rehearsal: bool, plant: bool) -> dict:
         raise RuntimeError("planted failure in the train phase")
     size = TRAIN_REHEARSAL if rehearsal else TRAIN_CHIP
     n_dev = device["count"]
-    # one chip: ZeRO-2 as bench.py builds the flagship; several chips:
+    # one chip: ZeRO-2, the flagship's own stage; several chips:
     # ZeRO-3 over the default all-data mesh, where sharding is the point
     stage = 3 if n_dev > 1 else 2
     cfg = GPT2Config(vocab_size=size["vocab_size"], n_positions=size["seq"],

@@ -135,7 +135,7 @@ def init_serving(model: Any = None, config: Union[str, Dict, None] = None,
 
     Knobs split into two scopes. **Server-global** (fixed at construction,
     shared by every request — they shape the compiled programs): the
-    serving-only keys ``policy``, ``do_sample``, ``temperature``,
+    serving-only keys ``do_sample``, ``temperature``,
     ``top_k``, ``top_p``, ``seed``, ``monitor``, ``spec_decode``,
     ``prefill_chunk`` and ``prefill_token_budget`` (stall-free chunked
     admission; 0 disables), the telemetry keys ``tracer`` (a
@@ -144,7 +144,7 @@ def init_serving(model: Any = None, config: Union[str, Dict, None] = None,
     process-wide ``telemetry.default_tracer()``, which is on, and
     ``Tracer(enabled=False)`` silences the ring),
     ``registry``, ``strict_recompile`` (raise at the step boundary on
-    any post-warmup recompile) and ``timeline_capacity``, which pass
+    any post-warmup recompile), which pass
     through to ServingEngine, plus
     ``num_slots`` / ``max_queue_depth``. **Per-request** (ride on each ``submit()``):
     ``max_new_tokens`` and ``eos_token_id`` — nothing else varies per
@@ -215,17 +215,17 @@ def init_serving(model: Any = None, config: Union[str, Dict, None] = None,
     clock). Per-request ``priority`` / ``tenant`` ride on ``submit()``.
     The HTTP/SSE server wraps the returned engine:
     ``serving.ServingFrontend(srv, port=...)``."""
+    import inspect
+
     from .serving.engine import ServingEngine
 
-    serve_keys = ("policy", "do_sample", "temperature", "top_k", "top_p",
-                  "seed", "monitor", "spec_decode", "prefill_chunk",
-                  "prefill_token_budget", "tracer", "registry",
-                  "strict_recompile", "timeline_capacity",
-                  "deadline_default_ms", "step_wall_budget_ms",
-                  "guard_numerics", "degradation",
-                  "preempt_queue_threshold", "preempt_min_run_steps",
-                  "fault_injector", "paged_kv", "overlap", "cost_model",
-                  "slo", "flight_recorder", "dump_dir", "priority", "clock")
+    # every option of the server's constructor but those this function
+    # passes itself, in the constructor's order (a set's order would
+    # differ from one process to the next); whatever is left configures
+    # the inference engine
+    options = inspect.signature(ServingEngine.__init__).parameters
+    serve_keys = [k for k in options if k not in (
+        "self", "engine", "num_slots", "max_queue_depth")]
     serve_kwargs = {k: kwargs.pop(k) for k in serve_keys if k in kwargs}
     tracer = _default_tracer()
     with tracer.span("setup/build", entry="init_serving"):

@@ -1,6 +1,6 @@
 """Command-line front end for graftlint (see ``bin/graftlint``).
 
-Exit codes mirror ``check_regression.py``: 0 = gate passes, 1 =
+Exit codes: 0 = gate passes, 1 =
 unsuppressed errors above ``--max-errors``, 2 = unusable invocation
 (bad path, bad baseline file) — a typo can never pass silently.
 """
@@ -95,7 +95,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "warmup signatures")
     ap.add_argument("--manifest", metavar="FILE",
                     help="signatures.json warmup manifest exported by "
-                         "`bench.py --signatures` — re-enumerates under "
+                         "`ServingEngine.export_signatures` — re-enumerates under "
                          "the manifest's recorded configs and fails on "
                          "any static/runtime divergence (implies "
                          "--check)")

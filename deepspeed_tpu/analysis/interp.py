@@ -1487,10 +1487,14 @@ def run_drivers(interp: Interp) -> None:
 
 
 def default_check_envs() -> List[dict]:
-    """The representative configs a bare ``--check`` proves finite:
-    the CI bench rows' serving arms (serving-stall stall-free + serial,
-    kv-paging paged + dense).  ``--manifest`` runs replace these with
-    the configs recorded in the manifest itself."""
+    """The representative configs a bare ``--check`` proves finite, at
+    sizes small enough to enumerate. One stands for the server the
+    benchmark's cells run (``perf/configs/pythia-1.4b-paged.json``); the
+    others are options of ``ServingEngine`` no ``perf/configs/`` file
+    turns on, kept finite for the tests and ``chip_smoke.py`` that do.
+    Hand-kept: deriving them from ``perf/configs/*.json`` is ROADMAP D7.
+    ``--manifest`` runs replace these with the configs recorded in the
+    manifest itself."""
     common = dict(top_k=0, top_p=1.0, greedy=False, temperature=1.0,
                   spec_k=0, guard_numerics=False)
     stall = dict(num_slots=8, capacity=1024, prefill_chunk=256,
@@ -1502,20 +1506,32 @@ def default_check_envs() -> List[dict]:
                   num_pages=32, pages_per_slot=8, use_prefix=True,
                   vocab_size=512, max_seed_len=160, **common)
     return [
+        # no perf/configs/ server: the contiguous SlotPool (the
+        # constructor's default), chunked stall-free admission
         dict(stall, stall_free=True),
+        # no perf/configs/ server: the same with prefill_chunk=0,
+        # serial admission
         dict(stall, stall_free=False, prefill_chunk=0,
              prefill_token_budget=0),
+        # no perf/configs/ server: the paged pool's dense composition
+        # (paged_kv.kernel "off"): the oracle arm of chip_smoke.py's
+        # serve phase and what the paged serving tests run
         dict(paging, stall_free=True),
+        # no perf/configs/ server: the contiguous pool again at the
+        # paged envs' capacity, four slots
         dict(paging, paged=False, page_size=0, num_pages=0,
              pages_per_slot=0, num_slots=4, use_prefix=False,
              stall_free=True),
-        # the serving-decode bench row's fused-kernel arm: same paged
-        # config, decode/verify dispatch through the Pallas kernel jits
+        # perf/configs/pythia-1.4b-paged.json (both serving cells; 64
+        # slots and 256 pages of 64 there): paged pool, prefix cache,
+        # stall-free admission, decode/verify through the Pallas kernel
+        # jits (kernel "auto" resolves to on, on the TPU)
         dict(paging, stall_free=True, paged_kernel="on",
              paged_kernel_active=True),
-        # the serving-tp bench row's sharded arm: a (data, model) mesh
-        # changes ONLY array placements, never a traced shape, so its
-        # enumerated signature set must be identical to the dense env's
+        # no perf/configs/ server: chip_smoke.py's four-chip serve phase
+        # and test_tp_serving.py. A (data, model) mesh changes ONLY
+        # array placements, never a traced shape, so its enumerated
+        # signature set must be identical to the dense env's
         # (mesh_data/mesh_model ride along in _signature_env for config
         # identity; the drivers ignore unknown keys)
         dict(stall, stall_free=True, mesh_data=4, mesh_model=2),
@@ -1570,7 +1586,7 @@ def enumerate_signatures(env: dict, root: str,
 def enumerate_union(envs: Iterable[dict], root: str,
                     project: Optional[ProjectIndex] = None) -> EnumResult:
     """Union of :func:`enumerate_signatures` across configs — the shape
-    a bench row's manifest has (several arms share one engine)."""
+    a merged manifest has (several servers sharing one engine)."""
     project = project or ProjectIndex(root)
     programs: Dict[str, set] = {}
     findings: List[Finding] = []

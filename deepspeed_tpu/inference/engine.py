@@ -164,7 +164,7 @@ class InferenceEngine:
     # matmul's operand read (≅ the reference's int8 inference tier,
     # csrc/quantization + weight_quantizer.py). Where weights are read once
     # per dispatch (per-step decode, prefill) this halves weight HBM
-    # traffic (~1.5x measured, BASELINE.md); inside the whole-loop decode
+    # traffic (~1.5x, PERF.md §8 lead); inside the whole-loop decode
     # scan XLA hoists the dequant, so the win there is at-rest/transport
     # footprint, not bandwidth.
     # ------------------------------------------------------------------
@@ -277,7 +277,7 @@ class InferenceEngine:
 
         # generation-only prefill: last-position logits (the full
         # (B, T, V) fp32 prompt logits are the largest prefill buffer
-        # and bound the servable batch at long context — BASELINE.md)
+        # and bound the servable batch at long context)
         prefill_gen = getattr(module, "prefill_last", None)
 
         def prefill_last_fn(params, input_ids):
@@ -568,7 +568,7 @@ class InferenceEngine:
         # Cache avals from a shape-only prefill: the decode-program compile
         # happens BEFORE any cache buffer lives. The allocated KV capacity
         # comes from the module's DECLARED kv_cache_spec when it has one
-        # (the allocation contract — ADVICE r5; the serving slot pool
+        # (the allocation contract; the serving slot pool
         # consumes the same spec), falling back to the last dim of ndim>=4
         # cache leaves (positions-minor layout) only for foreign modules
         # that declare nothing. Steps past capacity would write out of
@@ -594,7 +594,7 @@ class InferenceEngine:
         # decode at these shapes is grid-overhead bound, not dead-row
         # bound (the index-map clamp already elides dead-block DMA), so
         # fewer, larger grid steps win even when the last live block is
-        # mostly dead (BASELINE.md round-5 KV e2e section). Callers with
+        # mostly dead (PERF.md §8, the block_hint lead). Callers with
         # measured wins at their own shapes can drive
         # module.decode(block_hint=...) directly.
         decode_exec = None

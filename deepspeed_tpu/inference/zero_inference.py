@@ -391,7 +391,7 @@ class ZeroInferenceEngine:
     def score_logits(self, logits, input_ids) -> np.ndarray:
         """The scoring tail over already-computed logits (one jitted
         program + the readback). Split out so callers that must control
-        readback ordering (see benchmarks/zero_inference_bench.py) reuse
+        readback ordering (to time the pass without the fetch) reuse
         the shipped tail instead of re-deriving it."""
         ids = jnp.asarray(input_ids, jnp.int32)
         if not hasattr(self, "_jit_score_tail"):

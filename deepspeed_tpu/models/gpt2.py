@@ -46,7 +46,7 @@ class GPT2Config:
     # jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     remat_policy: str = "full"
     # Pallas flash kernel: True | False | "auto" (on-TPU when seq >= the
-    # measured crossover — BASELINE.md; off elsewhere)
+    # crossover in use_flash_by_default; off elsewhere)
     use_flash_attention: Any = "auto"
     # sequence/context parallelism over the `seq` mesh axis:
     # None | "ring" (ppermute KV rotation) | "ulysses" (all-to-all head swap)
@@ -67,7 +67,7 @@ class GPT2Config:
     # The parameter tree is identical either way. "auto" currently
     # resolves to OFF: the round-5 flagship A/B measured the fused kernel
     # at 0.91x XLA's composition (40.9k -> 37.3k tok/s at 350M/seq1024,
-    # rounds 1-5 runtime, ROUND5_NOTES.md; XLA's matmul pipelining +
+    # rounds 1-5 runtime, PERF.md §8; XLA's matmul pipelining +
     # multi-output fusions beat hand fusion at these shapes). Kept as an
     # explicit option and parity-tested; does not compose with model
     # parallelism (the Pallas call is not GSPMD-partitionable)
@@ -77,7 +77,7 @@ class GPT2Config:
     # (ops/transformer/chunked_xent.py). Measured ~free at the flagship
     # (-0.3%) and lets previously-OOM configs compile (350M mbs16, 774M
     # dots_plain) — but did NOT unlock a better operating point at
-    # either size (BASELINE.md 774M section). 0 = dense loss.
+    # either size (PERF.md §8). 0 = dense loss.
     loss_chunk: int = 0
 
 

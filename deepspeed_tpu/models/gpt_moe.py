@@ -45,7 +45,7 @@ class GPTMoEConfig:
     use_rts: bool = True
     # "auto" (einsum for k=1, index for k>=2 — the measured per-k policy),
     # "index" (scatter/gather), or "einsum" (the reference's dense one-hot
-    # dispatch) — see moe/layer.py and BASELINE.md round-5 MoE rows
+    # dispatch) — see moe/layer.py and the MoE lead of PERF.md §8
     moe_dispatch_mode: str = "auto"
     # PR-MoE residual blend (arXiv:2201.05596): dense expert + learned
     # per-token coefficient alongside each MoE block

@@ -87,7 +87,7 @@ class TransformerConfig:
     # against Mosaic's (4,1)-packed s8 layout-conversion copies (the
     # round-4/5 capacity killer; the positions-minor layout + carry-DUS
     # scan fixed the measured cases, and packed/plain now measure equal —
-    # BASELINE.md round-5 capacity ladder). Only meaningful with
+    # PERF.md §8). Only meaningful with
     # kv_cache_quant; requires head_dim % 4 == 0. Tri-state: None (auto,
     # the default) packs when head_dim allows and warns once when it
     # can't; True requires a packable head_dim (raises otherwise);
@@ -392,7 +392,7 @@ class CachedAttention(nn.Module):
             # design — per-layer flax cache variables, nn.scan
             # variable_axes — lowers to a scan whose xs/ys pair
             # double-buffers the quantized cache above ~100 MB:
-            # BASELINE.md round-5 capacity section.)
+            # PERF.md §8, the carry-DUS lead.)
             assert kv_cache is not None, "decode needs the kv_cache slice"
             # ``start`` is scalar () for batch-uniform decode (generate),
             # or (B,) for slot-pooled decode where every sequence sits at
@@ -552,8 +552,7 @@ class CachedAttention(nn.Module):
 
         scale = 1.0 / math.sqrt(D)
         # int8 cache: the s8->f32 cast does NOT fuse into the dot on TPU
-        # (measured: full fp32 cache copies, BASELINE.md round-5 KV
-        # section), so the quantized path casts to the compute dtype
+        # (rounds 1-5: full fp32 cache copies appeared), so the quantized path casts to the compute dtype
         # instead — int8 is exact in bf16, the copy is half the bytes,
         # and the dot still accumulates in f32. The per-row scales apply
         # to the (B,H,T,S) score/probability tensors.
@@ -645,7 +644,7 @@ class _ScanBlock(nn.Module):
       layer dynamic-slices its own (B, KV, cd, S) entry and
       dynamic-update-slices it back. Scanned cache VARIABLES double-
       buffer the quantized cache above ~100 MB through their xs/ys pair
-      (BASELINE.md round-5 capacity section); the carry-DUS of a
+      (PERF.md §8, the carry-DUS lead); the carry-DUS of a
       batch-major dense row did not.
     - a page pool (``"table"`` in the carry): the stacked leaves and the
       layer counter go to the block whole and come back whole; the
@@ -746,7 +745,7 @@ def page_lanes(page_size: int) -> int:
 class KVCacheSpec:
     """Module-declared KV-cache allocation contract: everything an engine
     needs to size, allocate and bound a cache WITHOUT inferring layout
-    from pytree leaf shapes (ADVICE r5). ``stacked_cache``/``layer_cache``
+    from pytree leaf shapes. ``stacked_cache``/``layer_cache``
     build zeroed containers in the exact layout CachedAttention reads and
     writes; the serving slot pool allocates through this (batch dim =
     slots) and ``InferenceEngine.generate`` takes ``max_seq_len`` as the
@@ -1008,8 +1007,8 @@ class TransformerLM(nn.Module):
         only the LAST position onto the vocabulary, returning (B, 1, V)
         logits. Sampling uses only the last position, and the full
         (B, T, V) fp32 logits are the largest prefill allocation
-        (~0.8 GB at B=8/T=512/V=50k — measured as the binding constraint
-        on the 32k serving row, BASELINE.md); scoring callers keep
+        (~0.8 GB at B=8/T=512/V=50k, what bound the batch of a 32k-context
+        server in rounds 1-5); scoring callers keep
         ``prefill``.
 
         ``last_pos`` (scalar or (B,) int32, optional) selects WHICH
@@ -1065,7 +1064,7 @@ class TransformerLM(nn.Module):
         granule — an explicit expert option; engine.generate keeps the
         allocation-based default after a budget-derived hint measured
         net-negative (grid overhead dominates dead-row reads;
-        BASELINE.md round-5 KV e2e section)."""
+        PERF.md §8, the block_hint lead)."""
         B, T = input_ids.shape
         off = start_pos[:, None] if jnp.ndim(start_pos) == 1 else start_pos
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))

@@ -74,7 +74,7 @@ class MoE(nn.Module):
     # a dense expert-shaped MLP runs alongside the MoE and the two outputs
     # are blended by a learned per-token softmax coefficient
     use_residual: bool = False
-    # "auto" (default, measured policy — BASELINE.md round-5 MoE rows):
+    # "auto" (default; the per-k choice is the MoE lead of PERF.md §8):
     # "einsum" for k=1 (the dense one-hot dispatch is a bf16 MXU matmul
     # and beats the scatter at top-1 capacity) UNLESS the dense form's
     # (S,E,C) tensor would exceed ``auto_index_threshold`` elements

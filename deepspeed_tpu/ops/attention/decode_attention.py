@@ -44,8 +44,8 @@ from .flash_attention import LANES, NEG_INF, SUBLANES
 
 DEFAULT_BLOCK_S = 1024
 LONG_CACHE_BLOCK_S = 4096  # >= 8k caches: grid overhead, not bandwidth,
-# bounds the 1024 block — the kv_int8_bench block sweep measures 4096
-# fastest for both bf16 and int8 at 16k (BASELINE.md round-5 KV section);
+# bounds the 1024 block — a block sweep of rounds 1-5 had 4096
+# fastest for both bf16 and int8 at 16k (PERF.md §8);
 # short live lengths only pay one partially-dead block (the index-map
 # clamp elides the rest), a sub-ms cost
 
@@ -57,8 +57,8 @@ def preferred_block_for(live_len: int) -> int:
     budget (every arm 5-15% slower at live 1536/4352 in an 8k cache):
     the index-map clamp already elides dead-block DMA, so decode at
     these shapes is grid-overhead bound and fewer, larger grid steps
-    win even when the last live block is mostly dead (BASELINE.md
-    round-5 KV e2e section). engine.generate therefore keeps the
+    win even when the last live block is mostly dead (PERF.md §8,
+    the block_hint lead). engine.generate therefore keeps the
     allocation-based block; this helper + the ``block_hint`` plumbing
     remain for callers with measured wins at their own shapes."""
     return LONG_CACHE_BLOCK_S if live_len >= 8192 else DEFAULT_BLOCK_S
@@ -96,8 +96,8 @@ def pack_int8_sublanes(x8: jax.Array) -> jax.Array:
     Why: Mosaic stores int8 arrays in a (4, 1)-packed tiled layout; when
     an int8 KV cache rides a ``lax.scan``/while-loop carry, a
     layout-conversion copy defeats XLA's in-place buffer aliasing and the
-    decode program double-buffers the cache (measured: BASELINE.md
-    round-5 "capacity ladder" section — the 485 MB-over OOM at int8 B=4).
+    decode program double-buffers the cache (rounds 1-5: 485 MB over
+    at int8 B=4; PERF.md §8).
     int32 carries use the native (8, 128) tiling and alias in place, so
     the same bytes in an int32 container restore O(cache) memory.
 

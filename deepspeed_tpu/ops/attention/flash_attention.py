@@ -413,8 +413,8 @@ _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
 def auto_block_sizes(seq: int) -> "tuple[int, int]":
-    """(block_q, block_k) tuned on v5e with bf16 MXU operands (round-5
-    sweep, benchmarks/flash1k_sweep_results.json + the r2 crossover table):
+    """(block_q, block_k) tuned on v5e with bf16 MXU operands (rounds 1-5
+    sweeps, PERF.md §8):
     512x1024 wins at 1024-4096; the biggest tiles win at >=8192. Each block
     is shrunk (halved) until it divides ``seq`` — the kernel requires exact
     tiling, and an odd seq must not crash the auto path."""
@@ -434,8 +434,8 @@ def auto_block_sizes(seq: int) -> "tuple[int, int]":
 def use_flash_by_default(seq: int) -> bool:
     """Shape-based auto-selection: with bf16 MXU operands (round 5) the
     Pallas kernel beats XLA's fused attention from seq 1024 up on TPU
-    (1.55x @1k, 1.33x @2k — benchmarks/flash1k_sweep_results.json; 2x+ at
-    4k-8k, BASELINE.md crossover table); below that XLA wins. Off-TPU
+    (1.55x @1k, 1.33x @2k, 1.57x @4k, 1.91x @8k: rounds 1-5 runtime, a lead,
+    PERF.md §8; the train cells run it at 1k); below that XLA wins. Off-TPU
     (interpret mode) it is only for tests. Shapes whose auto blocks would
     degenerate (seq with a tiny power-of-two factor) stay on XLA."""
     return backend.on_tpu() and seq >= 1024 \

@@ -19,8 +19,8 @@ Quantization is per-OUTPUT-channel (scale per column of W): the scale
 multiply then applies to the f32 accumulator at flush time — one VPU
 convert per weight element instead of a convert+scale+round-trip through
 f32 — which is what makes the kernel beat the bf16 matmul instead of
-merely matching it (measured 1.15-2.2x at decode shapes,
-benchmarks/int8_bench_results.json).
+merely matching it (1.15-2.2x at decode shapes in rounds 1-5, PERF.md §8:
+a lead, no cell runs it).
 
 Layout: x (..., K) float, w int8 (K, N), scales f32 (1, N) or (N,).
 K on sublanes, N on lanes; blocks over K and N must be 128-multiples (or

@@ -5,7 +5,7 @@ logits tensor is (B, T, V) — at GPT-2 Large scale (mbs 2, T 1024,
 V 50257) that is ~400 MB fp32 PER COPY, and the forward + softmax +
 backward chain holds several copies, adding GBs of peak HBM. This is
 what kept the 774M single-chip row on full remat: selective ("dots")
-remat missed fitting by ~0.6 GB (BASELINE.md 774M section).
+remat missed fitting by ~0.6 GB (rounds 1-5; PERF.md §8).
 
 This module computes the same masked mean cross-entropy WITHOUT ever
 materializing the full logits: positions stream through in chunks of

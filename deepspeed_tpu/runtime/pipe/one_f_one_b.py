@@ -33,8 +33,9 @@ _exec_schedule``) as ONE compiled SPMD loop:
 
 Total ticks = M + 2(S−1): M steady-state ticks are fully utilized (one F
 and one B each, both valid); the 2(S−1) ramp ticks carry masked work — the
-pipeline bubble. See BASELINE.md for the measured bubble/memory tradeoff vs
-the GPipe executor (kept as ``pipeline.schedule = "gpipe"``).
+pipeline bubble: (2S−2)/(M+2S−2) of the ticks against GPipe's (S−1)/(M+S−1),
+for ≤ 2S−1 live activations a stage against M+S−1 (PERF.md §8; the GPipe
+executor is kept as ``pipeline.schedule = "gpipe"``).
 """
 
 from __future__ import annotations

@@ -767,7 +767,7 @@ class ParamOffloadRunner:
         self._jit_head_bwd = jax.jit(
             head_bwd, out_shardings=(rep, res_rep, self._data_sh))
         # loss-only head for evaluation: no value_and_grad over the
-        # resident tree (ADVICE r4: eval_loss must not pay the head
+        # resident tree (eval_loss must not pay the head
         # backward + gradient buffers)
         self._jit_head_loss = jax.jit(head_loss, out_shardings=rep)
 
@@ -956,7 +956,7 @@ class ParamOffloadRunner:
             t_bwd += time.perf_counter() - tb0
 
         # ---- finalize: norm, clip, host Adam, store update ------------
-        # Layer-streamed (round 5, VERDICT r4 next-#4): resident leaves go
+        # Layer-streamed: resident leaves go
         # through the pipelined whole-leaf step; the stacked trunk updates
         # one LAYER at a time (per-row Adam via step_rows, write_layer
         # writeback, grad rows freed as they land) — the full new param

@@ -129,8 +129,8 @@ class TransformerLMAdapter(StreamedModelAdapter):
 
 
 class GPT2Adapter(StreamedModelAdapter):
-    """``models/gpt2.GPT2LMHeadModel`` — round-5 generalization target
-    (VERDICT r4 next-#3). Resident: wte, wpe, ln_f; streamed: the scanned
+    """``models/gpt2.GPT2LMHeadModel``, the second model family the
+    streamed engine takes. Resident: wte, wpe, ln_f; streamed: the scanned
     blocks. The embed/head reuse the model's own flax submodules so the
     numerics (including Embed.attend's dtype promotion) match
     ``GPT2LMHeadModel.logits`` exactly."""

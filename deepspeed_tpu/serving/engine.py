@@ -72,7 +72,7 @@ without ever changing a compiled shape:
   tokens from the same dispatch are kept;
 * a seeded :class:`~deepspeed_tpu.serving.resilience.FaultInjector`
   threads deterministic failures through five named points for the
-  chaos suite and ``bench.py serving-chaos``.
+  chaos suite (``tests/unit/serving/test_resilience.py``).
 """
 
 from __future__ import annotations
@@ -131,7 +131,7 @@ class ServingEngine:
     """
 
     def __init__(self, engine: Any, num_slots: int = 4,
-                 max_queue_depth: int = 64, policy: str = "continuous",
+                 max_queue_depth: int = 64,
                  do_sample: bool = False,
                  temperature: Optional[float] = None,
                  top_k: Optional[int] = None, top_p: Optional[float] = None,
@@ -142,7 +142,6 @@ class ServingEngine:
                  tracer: Optional[Any] = None,
                  registry: Optional[Any] = None,
                  strict_recompile: bool = False,
-                 timeline_capacity: int = 4096,
                  deadline_default_ms: Optional[float] = None,
                  step_wall_budget_ms: Optional[float] = None,
                  guard_numerics: bool = False,
@@ -247,8 +246,7 @@ class ServingEngine:
                 # never clamp into another request's live columns.
                 sched_capacity = self.pool.capacity - sc.k
         sched_kw = dict(
-            max_queue_depth=max_queue_depth, policy=policy,
-            capacity=sched_capacity,
+            max_queue_depth=max_queue_depth, capacity=sched_capacity,
             # page-denominated admission (oversubscription makes row
             # capacity a fiction): reject what the whole pool could
             # never hold; spec decode's k-past-the-index verify writes
@@ -277,8 +275,7 @@ class ServingEngine:
         self.tracer = tracer if tracer is not None else default_tracer()
         self.registry = registry if registry is not None else MetricsRegistry()
         self.step_id = 0                 # monotonic scheduler-step counter
-        self.timelines = TimelineStore(capacity=timeline_capacity,
-                                       tracer=self.tracer)
+        self.timelines = TimelineStore(tracer=self.tracer)
         self.watchdog = RecompileWatchdog(
             registry=self.registry, tracer=self.tracer, monitor=monitor,
             strict=strict_recompile, step_fn=lambda: self.step_id)
@@ -383,7 +380,7 @@ class ServingEngine:
         # chunk width is a latency knob, not a correctness contract.
         while chunk > 1 and self.pool.capacity % chunk != 0:
             chunk //= 2
-        self._stall_free = (chunk > 0 and policy == "continuous" and
+        self._stall_free = (chunk > 0 and
                             getattr(engine, "_jit_prefill_chunk", None)
                             is not None)
         self.prefill_chunk = chunk if self._stall_free else 0
@@ -496,7 +493,7 @@ class ServingEngine:
         self._deferred: List[Any] = []
         self._next_id = 0
         self._ensure_watch()
-        log_dist(f"ServingEngine: slots={num_slots} policy={policy} "
+        log_dist(f"ServingEngine: slots={num_slots} "
                  f"capacity={self.pool.capacity} "
                  f"max_queue_depth={max_queue_depth} "
                  f"admission={'stall-free chunk=%d budget=%d' % (self.prefill_chunk, self.prefill_token_budget) if self._stall_free else 'serial'}",
@@ -583,8 +580,8 @@ class ServingEngine:
         ``{"version": 1, "configs": [env...], "programs": {name:
         [sorted sigs]}}``.
 
-        ``merge=True`` unions with an existing file — bench rows run
-        several serving arms against one shared inference engine, so
+        ``merge=True`` unions with an existing file — a caller may run
+        several servers against one shared inference engine, so
         the shared engine jits see every arm's traffic and the manifest
         is only meaningful as the union.  ``extra`` adds workload keys
         the config alone cannot know (vocab size, prompt-length sweep
@@ -614,7 +611,7 @@ class ServingEngine:
 
     def set_tracer(self, tracer) -> None:
         """Swap the tracer in post-construction (e.g. a traced replay on
-        an already-warmed server in ``bench.py --trace``)."""
+        an already-warmed server)."""
         self.tracer = tracer
         self.timelines.tracer = tracer
         self.watchdog.tracer = tracer
@@ -1830,13 +1827,13 @@ class ServingEngine:
                     # within budget
                     spent = self.prefill_chunk if self._prefill_queue else 0
                     granted = self.scheduler.grant(
-                        self.pool.free_count, self.live_count,
+                        self.pool.free_count,
                         token_budget=self._effective_prefill_budget(),
                         cost=self._admission_cost, spent=spent,
                         page_budget=page_budget, page_cost=page_cost)
                 else:
                     granted = self.scheduler.grant(
-                        self.pool.free_count, self.live_count,
+                        self.pool.free_count,
                         page_budget=page_budget, page_cost=page_cost)
             phases["grant"] = sp.dur_ns
             t_granted = sp.t0_ns + sp.dur_ns

@@ -171,13 +171,13 @@ class PriorityScheduler(FIFOScheduler):
     """
 
     def __init__(self, num_slots: int, max_queue_depth: int = 64,
-                 policy: str = "continuous", capacity: Optional[int] = None,
+                 capacity: Optional[int] = None,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None, page_headroom: int = 0,
                  priority=True,
                  clock: Optional[Callable[[], float]] = None):
         super().__init__(num_slots, max_queue_depth=max_queue_depth,
-                         policy=policy, capacity=capacity,
+                         capacity=capacity,
                          page_size=page_size, num_pages=num_pages,
                          page_headroom=page_headroom)
         self.config = PriorityConfig.resolve(priority)
@@ -283,7 +283,7 @@ class PriorityScheduler(FIFOScheduler):
                     break
         return best
 
-    def grant(self, free_slots: int, live_slots: int,
+    def grant(self, free_slots: int,
               token_budget: Optional[int] = None,
               cost=None, spent: int = 0,
               page_budget: Optional[int] = None,
@@ -308,8 +308,6 @@ class PriorityScheduler(FIFOScheduler):
         lowest class IS the highest-ranked waiter, so every class
         eventually progresses (no starvation livelock; pinned).
         """
-        if self.policy == "gang" and live_slots > 0:
-            return []
         if not self.queue or free_slots <= 0:
             return []
         by_rank: Dict[int, List[Request]] = {}

@@ -72,7 +72,7 @@ class ServingMetrics:
         self.stall_time: float = 0.0
         # paged-KV prefix cache (admission-time trie lookups): hit
         # tokens are seed tokens whose prefill was SKIPPED by mapping
-        # cached pages — the TTFT lever the paging bench row measures
+        # cached pages — the TTFT lever of the prefix cache
         self.prefix_lookups: int = 0
         self.prefix_hits: int = 0
         self.prefix_hit_tokens: int = 0

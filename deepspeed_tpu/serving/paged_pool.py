@@ -49,7 +49,7 @@ whole 128-lane tiles (``models.transformer_lm.page_lanes``: k/v
 layout XLA and a Mosaic operand agree on; no program of a serving step
 passes over a whole leaf.
 
-Composition with the int8 packed cache (BASELINE.md): the page pool
+Composition with the int8 packed cache: the page pool
 allocates through the same module-declared ``KVCacheSpec``, so
 quantized (int8, or int32-packed with ``cache_d = head_dim // 4``)
 columns page exactly like full-precision ones, with per-column scales

@@ -13,8 +13,7 @@ built as four coupled pieces the engine hooks into:
 * graceful degradation — the HEALTHY/PRESSURED/OVERLOADED load-state
   machine (:mod:`.degradation`);
 * deterministic fault injection — seeded, schedulable failures at
-  named engine points, for the chaos suite and the
-  ``bench.py serving-chaos`` row (:mod:`.faults`).
+  named engine points, for the chaos suite (:mod:`.faults`).
 """
 
 from .degradation import (DegradationConfig, LoadState,  # noqa: F401

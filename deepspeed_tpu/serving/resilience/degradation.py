@@ -105,8 +105,8 @@ class LoadStateMachine:
         self.cfg = cfg
         self.state = LoadState.HEALTHY
         self._calm = 0
-        # (step, old, new) history — the chaos bench reports it and the
-        # tests assert the ladder was actually walked
+        # (step, old, new) history — the tests assert the ladder was
+        # actually walked
         self.transitions: list = []
 
     # ------------------------------------------------------------------

@@ -65,7 +65,7 @@ class FaultInjector:
     Two firing modes compose per point:
 
     * ``schedule={point: [call ordinals]}`` — fire on exactly those
-      1-based calls of the point (the chaos bench's fixed schedule);
+      1-based calls of the point (the chaos tests' fixed schedules);
     * ``rates={point: p}`` — fire each call with probability ``p`` from
       the point's own seeded stream (soak testing).
 

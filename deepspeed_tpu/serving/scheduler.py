@@ -1,19 +1,12 @@
-"""Admission queue + slot-grant policy for continuous batching.
+"""Admission queue + slot grants for continuous batching.
 
 The scheduler is deliberately host-only and device-free: it owns the
 FIFO queue, enforces admission control (bounded queue depth,
 prompt-fits-in-capacity) and decides WHICH queued requests get a slot
-this step. Two policies:
-
-* ``"continuous"`` — iteration-level scheduling (Orca; PAPERS.md):
-  every step, any free slot is immediately refilled from the queue.
-  Retirements and admissions interleave with decode, so slots never
-  idle while work is queued.
-* ``"gang"`` — the static-batch discipline ``generate()`` imposes,
-  expressed in the same machinery: admit only when the pool is fully
-  drained, then seat a whole batch at once. This is the baseline arm of
-  the serving benchmark — same engine, same kernels, only the admission
-  policy differs — so the bench row isolates the scheduling win.
+this step: iteration-level scheduling (Orca; PAPERS.md) — every step,
+any free slot is immediately refilled from the queue. Retirements and
+admissions interleave with decode, so slots never idle while work is
+queued.
 
 Under the serving engine's stall-free mode, ``grant`` additionally
 enforces a per-step prefill TOKEN BUDGET (Sarathi-style): admission
@@ -30,22 +23,16 @@ from typing import Deque, List, Optional, Tuple
 
 from .request import RejectReason, Request, RequestState
 
-POLICIES = ("continuous", "gang")
-
 
 class FIFOScheduler:
-    """Bounded FIFO admission queue with a pluggable slot-grant policy."""
+    """Bounded FIFO admission queue that refills free slots every step."""
 
     def __init__(self, num_slots: int, max_queue_depth: int = 64,
-                 policy: str = "continuous", capacity: Optional[int] = None,
+                 capacity: Optional[int] = None,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None, page_headroom: int = 0):
-        if policy not in POLICIES:
-            raise ValueError(f"unknown policy {policy!r}; expected one of "
-                             f"{POLICIES}")
         self.num_slots = num_slots
         self.max_queue_depth = max_queue_depth
-        self.policy = policy
         self.capacity = capacity
         # paged-KV admission accounting: with a PagedKVPool the real
         # admission currency is PAGES, not rows — ``capacity`` alone
@@ -141,7 +128,7 @@ class FIFOScheduler:
                 r for r in self.queue if not r.expired(now))
         return expired
 
-    def grant(self, free_slots: int, live_slots: int,
+    def grant(self, free_slots: int,
               token_budget: Optional[int] = None,
               cost=None, spent: int = 0,
               page_budget: Optional[int] = None,
@@ -175,8 +162,6 @@ class FIFOScheduler:
         engine's job, not an overshoot's: pressure preemption frees
         victims' pages, and the submit-time footprint check guarantees
         the head fits an otherwise-empty pool."""
-        if self.policy == "gang" and live_slots > 0:
-            return []  # batch-synchronous: wait for the whole gang to drain
         granted: List[Request] = []
         remaining = None if token_budget is None else token_budget - spent
         pages_left = page_budget

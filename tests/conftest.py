@@ -62,8 +62,8 @@ def tp_mesh():
 
     This only works because of two environment settings made at the TOP
     of this conftest, before JAX initializes a backend — repeat them in
-    any subprocess (bench arms, ``check_regression`` reruns) BEFORE its
-    local ``import jax``:
+    any subprocess (CLI tools, multi-process tests) BEFORE its local
+    ``import jax``:
 
     * ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` splits the
       host CPU into 8 virtual XLA devices. It is read once at backend

@@ -1,4 +1,4 @@
-"""Real-text convergence across the ZeRO/offload matrix (VERDICT r3 #4).
+"""Real-text convergence across the ZeRO/offload matrix.
 
 The reference's model-level e2e suite trains real Megatron GPT-2 on real
 corpora and compares loss curves against baselines

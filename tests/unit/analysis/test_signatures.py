@@ -97,7 +97,7 @@ def test_default_envs_enumerate_finitely():
         assert progs.get(name), f"missing program {name}"
     # the stall-free row's admission set: singleton width buckets
     # 16..256 plus every (rows x width) group the 1024-token budget
-    # allows — 19 exactly (the hand-derived count the bench sweeps)
+    # allows — 19 exactly (the hand-derived count)
     pre = [s for s in progs["InferenceEngine._jit_prefill_at"]
            if "int32[1," in s]
     assert any("int32[1,16]" in s for s in pre)

@@ -240,7 +240,7 @@ class TestResourceManager:
         assert n.reserve(1) == [0]
 
     def test_end_to_end_real_experiments(self, tmp_path):
-        """VERDICT done-criterion: an end-to-end tune over a toy model with
+        """The done-criterion: an end-to-end tune over a toy model with
         REAL measured metrics — each experiment is a subprocess run of the
         user script; throughput comes from the engine's profile window."""
         from deepspeed_tpu.autotuning import ResourceManager

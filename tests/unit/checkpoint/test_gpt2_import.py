@@ -1,7 +1,7 @@
 """Real-checkpoint GPT-2 migration: a reference-format (Megatron-DeepSpeed)
 checkpoint, TP-sharded with torch, imports into the flax GPT-2 and produces
 IDENTICAL logits whether read from tp=2 shards or the unsharded original —
-the VERDICT done-criterion for AutoTP/state-dict-factory validation
+the done-criterion for AutoTP/state-dict-factory validation
 (reference module_inject/auto_tp.py:13, runtime/state_dict_factory.py:190).
 """
 

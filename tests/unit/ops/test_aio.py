@@ -103,8 +103,8 @@ def test_validate_grid(tmp_path):
 
 def test_sweep_structure_and_sanity(tmp_path):
     """The sweep produces measured bandwidths per config. The async>sync
-    claim itself is recorded from a full-size run in BASELINE.md (buffered
-    ~3x, O_DIRECT ~2x); a strict >1x assertion here would be a timing race
+    claim itself comes from a full-size run of rounds 1-5 (buffered ~3x,
+    O_DIRECT ~2x: a lead, PERF.md §8); a strict >1x assertion here would be a timing race
     on small files / loaded CI hosts, so only sanity is asserted."""
     out = sweep(file_mb=64, dir=str(tmp_path),
                 block_sizes=(1 << 20, 8 << 20), threads=(2, 4))

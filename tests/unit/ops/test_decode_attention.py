@@ -81,7 +81,7 @@ def test_scalar_length_broadcasts():
 
 
 def test_pick_block_s():
-    assert pick_block_s(2048) == 1024  # tuned default (attn_bench r3 sweep)
+    assert pick_block_s(2048) == 1024  # tuned default (PERF.md §8)
     assert pick_block_s(512) == 512
     assert pick_block_s(192) == 64
     assert pick_block_s(100) == 4   # 100 = 4 * 25

@@ -98,7 +98,7 @@ def test_quant_dense_matches_dense():
 def test_auto_mode_off_tpu_uses_reference(monkeypatch):
     """kernel_mode='auto' / interpret=None must route to the jnp
     reference on non-TPU backends — interpret-mode Pallas is orders of
-    magnitude slower (ADVICE r3 medium)."""
+    magnitude slower."""
     import importlib
 
     # the package re-exports the function under the same name; importlib

@@ -94,7 +94,7 @@ def test_leading_dims_flattened():
 
 def test_model_level_fused_block_is_drop_in():
     """Fused and unfused GPT-2 blocks: identical param trees, matching
-    loss and grads (the A/B the flagship bench toggles)."""
+    loss and grads (the A/B ``fused_ln_linear`` toggles)."""
     import jax.tree_util as jtu
 
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel

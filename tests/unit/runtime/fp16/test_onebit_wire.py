@@ -1,4 +1,4 @@
-"""1-bit Adam compressed exchange ON the wire (VERDICT r2 next #4, r3 #6/#9).
+"""1-bit Adam compressed exchange ON the wire.
 
 Four planes, all on the virtual 8-device mesh:
   * volume accounting — metrics["comm_bytes"] must drop ~30x when the

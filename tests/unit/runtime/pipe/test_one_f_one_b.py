@@ -64,7 +64,7 @@ def test_1f1b_matches_gpipe_loss_and_params():
 
 
 def test_1f1b_matches_gpipe_gas_2x_stages():
-    """VERDICT done-criterion: parity at gas >= 2 x stages."""
+    """The done-criterion: parity at gas >= 2 x stages."""
     l_g, _ = _train("gpipe", steps=1, gas=8, stages=4)
     l_f, _ = _train("1f1b", steps=1, gas=8, stages=4)
     np.testing.assert_allclose(l_f, l_g, rtol=1e-5, atol=1e-5)

@@ -210,7 +210,7 @@ def _run_gpt2(zero, steps=4, gas=2, dropout=0.0, seed=1234):
 
 
 def test_param_offload_gpt2_matches_resident_offload():
-    """Round-5 generalization (VERDICT r4 next-#3): GPT2LMHeadModel streams
+    """The second model family: GPT2LMHeadModel streams
     through the same runner via the adapter registry, trajectory pinned to
     the resident optimizer-offload engine."""
     base, _ = _run_gpt2({"stage": 0, "offload_optimizer": {"device": "cpu"}})
@@ -249,7 +249,7 @@ def test_param_offload_dropout_transformer_lm():
 
 
 def test_param_offload_nvme_bounded_finalize(tmp_path):
-    """VERDICT r4 next-#4: the layer-streamed finalize must not
+    """The layer-streamed finalize must not
     materialize the full new param tree — transient host allocations during
     step() stay O(layer) as depth grows. Measured with tracemalloc around
     one global step: the finalize-phase peak delta for a 2x-deeper model

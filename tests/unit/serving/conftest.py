@@ -1,0 +1,46 @@
+"""What the serving tests share: one tiny engine, and one way to build a
+server on either K/V pool.
+
+Every serving cell of ``BENCHMARK.json`` runs ``PagedKVPool``; the
+constructor's default is the contiguous ``SlotPool``. A test that builds
+a server takes the ``pool`` fixture and calls :func:`make_server`, so
+each behaviour is checked on both. ``paged`` is the dense
+gather/scatter composition (``kernel: "off"``): the kernel's parity is
+``test_paged_kernel.py``'s and ``ops/test_paged_attention.py``'s job,
+and interpret mode would cost minutes here."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
+from deepspeed_tpu.serving import ServingEngine
+
+TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
+            dtype=jnp.float32)
+
+POOLS = {"contiguous": False, "paged": {"kernel": "off"}}
+
+
+@pytest.fixture(scope="module")
+def stack():
+    """(model, params, engine) of the tiny LM, built once a module."""
+    cfg = TransformerConfig(**TINY)
+    model = TransformerLM(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
+    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
+                        method=model.logits)["params"]
+    engine = ds.init_inference(model=model, model_parameters=params,
+                               config={"dtype": "float32"})
+    return model, params, engine
+
+
+@pytest.fixture(params=sorted(POOLS))
+def pool(request):
+    return request.param
+
+
+def make_server(engine, pool, **kw):
+    """A ``ServingEngine`` over ``engine`` on the named pool."""
+    return ServingEngine(engine, paged_kv=POOLS[pool], **kw)

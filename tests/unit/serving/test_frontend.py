@@ -81,13 +81,13 @@ class TestFIFOHeadLiveness:
         s = FIFOScheduler(num_slots=2)
         ok, _ = s.submit(_req(0, plen=32))
         assert ok
-        got = s.grant(2, 0, token_budget=4, cost=lambda r: 100)
+        got = s.grant(2, token_budget=4, cost=lambda r: 100)
         assert [r.request_id for r in got] == [0]
 
     def test_head_blocked_when_prefill_already_committed(self):
         s = FIFOScheduler(num_slots=2)
         s.submit(_req(0, plen=32))
-        assert s.grant(2, 0, token_budget=4, cost=lambda r: 100,
+        assert s.grant(2, token_budget=4, cost=lambda r: 100,
                        spent=1) == []
         assert s.pending == 1  # still queued, granted next idle step
 
@@ -98,7 +98,7 @@ class TestFIFOHeadLiveness:
         s.submit(a)
         s.submit(b)
         assert s.head() is a
-        assert s.grant(1, 0)[0] is a
+        assert s.grant(1)[0] is a
         assert s.head() is b
 
 
@@ -111,7 +111,7 @@ class TestPriorityGrant:
         s.submit(_req(0, cls="batch"))
         s.submit(_req(1, cls="standard"))
         s.submit(_req(2, cls="interactive"))
-        got = [r.request_id for r in s.grant(2, 0)]
+        got = [r.request_id for r in s.grant(2)]
         assert got == [2, 1]      # rank order beats arrival order
         assert s.head().request_id == 0
 
@@ -123,7 +123,7 @@ class TestPriorityGrant:
         assert s.head().request_id == 1
         assert s.head_within(0).request_id == 1
         # nothing at-or-above rank 0 once interactive drains
-        s.grant(2, 0)
+        s.grant(2)
         assert s.head_within(0) is None
         assert s.head_within(2).request_id == 0
 
@@ -136,7 +136,7 @@ class TestPriorityGrant:
         # budget 20, cost 10 each, equal shares -> ONE grant per class:
         # a high-class flood cannot eat the whole step's prefill budget
         got = [r.request_id for r in
-               s.grant(4, 0, token_budget=20, cost=lambda r: 10)]
+               s.grant(4, token_budget=20, cost=lambda r: 10)]
         assert got == [0, 10]
 
     def test_shares_weight_the_split(self):
@@ -150,7 +150,7 @@ class TestPriorityGrant:
         s.submit(_req(11, cls="batch"))
         # budget 40 -> slices 30/10 at cost 10: three interactive, one batch
         got = [r.request_id for r in
-               s.grant(5, 0, token_budget=40, cost=lambda r: 10)]
+               s.grant(5, token_budget=40, cost=lambda r: 10)]
         assert got == [0, 1, 2, 10]
 
     def test_leftover_budget_is_work_conserving(self):
@@ -162,7 +162,7 @@ class TestPriorityGrant:
         # the third batch request rides the global leftover (pass 2)
         cost = {0: 2, 10: 3, 11: 3, 12: 3}
         got = [r.request_id for r in
-               s.grant(8, 0, token_budget=12,
+               s.grant(8, token_budget=12,
                        cost=lambda r: cost[r.request_id])]
         assert got == [0, 10, 11, 12]
         assert s.pending == 0
@@ -172,7 +172,7 @@ class TestPriorityGrant:
         s.submit(_req(0, cls="interactive", plen=32))
         s.submit(_req(1, cls="batch"))
         got = [r.request_id for r in
-               s.grant(2, 0, token_budget=4, cost=lambda r: 100)]
+               s.grant(2, token_budget=4, cost=lambda r: 100)]
         # the overshoot grants exactly the head — it must NOT also be
         # re-spent on lower classes (budget already blown)
         assert got == [0]
@@ -184,13 +184,13 @@ class TestPriorityGrant:
         # the head-liveness overshoot
         s = PriorityScheduler(num_slots=2)
         s.submit(_req(0, cls="batch", plen=32))
-        got = s.grant(2, 0, token_budget=1, cost=lambda r: 100)
+        got = s.grant(2, token_budget=1, cost=lambda r: 100)
         assert [r.request_id for r in got] == [0]
 
     def test_overshoot_suppressed_after_committed_work(self):
         s = PriorityScheduler(num_slots=2)
         s.submit(_req(0, cls="batch", plen=32))
-        assert s.grant(2, 0, token_budget=1, cost=lambda r: 100,
+        assert s.grant(2, token_budget=1, cost=lambda r: 100,
                        spent=1) == []
 
     def test_page_budget_strict_and_global(self):
@@ -201,15 +201,9 @@ class TestPriorityGrant:
         # the interactive head does not fit 2 pages -> the WHOLE grant
         # stops; letting batch take pages the blocked head needs would
         # invert priority under memory pressure
-        assert s.grant(4, 0, page_budget=2,
+        assert s.grant(4, page_budget=2,
                        page_cost=lambda r: pages[r.request_id]) == []
         assert s.pending == 2
-
-    def test_gang_policy_still_respected(self):
-        s = PriorityScheduler(num_slots=2, policy="gang")
-        s.submit(_req(0, cls="interactive"))
-        assert s.grant(2, live_slots=1) == []
-        assert [r.request_id for r in s.grant(2, live_slots=0)] == [0]
 
     def test_base_requeue_and_expire_paths_still_work(self):
         clock = FakeClock()
@@ -277,7 +271,7 @@ class TestPriorityAdmission:
         # re-admits immediately — only requests that actually joined the
         # queue consume rate (without the refund the bucket would be
         # empty here and this would be RATE_LIMITED)
-        s.grant(2, 0)
+        s.grant(2)
         assert s.submit(_req(2, plen=10, mnt=10))[0]
 
     def test_tenant_queue_quota(self):

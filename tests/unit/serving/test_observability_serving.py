@@ -7,32 +7,15 @@ collector must surface tracer/sink/recorder counters in Prometheus."""
 
 import json
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
-from deepspeed_tpu.serving import InvariantViolation, ServingEngine
+from deepspeed_tpu.serving import InvariantViolation
 from deepspeed_tpu.serving.resilience import FaultInjector
 from deepspeed_tpu.telemetry.flight_recorder import (POST_MORTEM_KEYS,
                                                      SCHEMA_VERSION)
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
+from .conftest import make_server
 
 
 def _prompts(rng, n, lo=5, hi=12):
@@ -40,12 +23,12 @@ def _prompts(rng, n, lo=5, hi=12):
             .astype(np.int32) for _ in range(n)]
 
 
-def test_postmortem_on_planted_invariant_violation(stack, tmp_path):
+def test_postmortem_on_planted_invariant_violation(stack, pool, tmp_path):
     _, _, engine = stack
     rng = np.random.default_rng(71)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        fault_injector=FaultInjector(seed=0),
-                        dump_dir=str(tmp_path))
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      fault_injector=FaultInjector(seed=0),
+                      dump_dir=str(tmp_path))
     srv.faults.load_schedule({"state_corruption": [1]})
     for p in _prompts(rng, 2):
         srv.submit(p, max_new_tokens=4)
@@ -74,10 +57,10 @@ def test_postmortem_on_planted_invariant_violation(stack, tmp_path):
     assert srv.recorder.dump_count == 1
 
 
-def test_debug_dump_serves_postmortem_payload_live(stack):
+def test_debug_dump_serves_postmortem_payload_live(stack, pool):
     _, _, engine = stack
     rng = np.random.default_rng(73)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8, slo=True)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8, slo=True)
     for p in _prompts(rng, 3):
         srv.submit(p, max_new_tokens=8)
     for _ in range(2):
@@ -96,14 +79,14 @@ def test_debug_dump_serves_postmortem_payload_live(stack):
     assert srv.recorder.dump_count == 0
 
 
-def test_cost_model_never_perturbs_outputs(stack):
+def test_cost_model_never_perturbs_outputs(stack, pool):
     _, _, engine = stack
     rng = np.random.default_rng(79)
     prompts = _prompts(rng, 6)
 
     def run(cost_model):
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                            cost_model=cost_model)
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                          cost_model=cost_model)
         reqs = [srv.submit(p, max_new_tokens=5) for p in prompts]
         srv.run_until_drained(max_steps=200)
         return [list(r.output_tokens) for r in reqs]
@@ -111,11 +94,11 @@ def test_cost_model_never_perturbs_outputs(stack):
     assert run(False) == run(True)  # greedy serving is bit-identical
 
 
-def test_cost_model_harvests_and_reconciles(stack):
+def test_cost_model_harvests_and_reconciles(stack, pool):
     _, _, engine = stack
     rng = np.random.default_rng(83)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        cost_model=True)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      cost_model=True)
     for p in _prompts(rng, 4):
         srv.submit(p, max_new_tokens=4)
     srv.run_until_drained(max_steps=200)
@@ -130,12 +113,12 @@ def test_cost_model_harvests_and_reconciles(stack):
     assert 0.0 <= eff["overhead_pct"]
 
 
-def test_slo_counts_deadline_expiry_against_goodput(stack):
+def test_slo_counts_deadline_expiry_against_goodput(stack, pool):
     _, _, engine = stack
     rng = np.random.default_rng(89)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        slo={"ttft_ms": 60_000.0, "gap_ms": 60_000.0,
-                             "window_steps": 8})
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      slo={"ttft_ms": 60_000.0, "gap_ms": 60_000.0,
+                           "window_steps": 8})
     good = [srv.submit(p, max_new_tokens=3) for p in _prompts(rng, 3)]
     srv.run_until_drained(max_steps=100)
     assert srv.slo.goodput() == 1.0
@@ -153,7 +136,7 @@ def test_slo_counts_deadline_expiry_against_goodput(stack):
     assert eff["alert_state"] in ("ok", "warn", "page")
 
 
-def test_prometheus_exposes_telemetry_health(stack):
+def test_prometheus_exposes_telemetry_health(stack, pool):
     _, _, engine = stack
 
     class _Sink:
@@ -164,8 +147,8 @@ def test_prometheus_exposes_telemetry_health(stack):
             pass
 
     rng = np.random.default_rng(97)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        tracer=True, monitor=_Sink())
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      tracer=True, monitor=_Sink())
     for p in _prompts(rng, 2):
         srv.submit(p, max_new_tokens=3)
     srv.run_until_drained(max_steps=100)

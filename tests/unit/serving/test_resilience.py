@@ -8,15 +8,11 @@ bitwise what it would have been without the preemption."""
 
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import (FIFOScheduler, FinishReason, RejectReason,
-                                   Request, RequestState, ServingEngine)
+                                   Request, RequestState)
 from deepspeed_tpu.serving.metrics import ServingMetrics
 from deepspeed_tpu.serving.resilience import (DegradationConfig,
                                               FaultInjector, InjectedFault,
@@ -24,20 +20,7 @@ from deepspeed_tpu.serving.resilience import (DegradationConfig,
 from deepspeed_tpu.serving.resilience.degradation import LoadStateMachine
 from deepspeed_tpu.serving.resilience.preemption import select_victims
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
+from .conftest import make_server
 
 
 def _prompts(rng, n, lo=5, hi=12):
@@ -256,10 +239,10 @@ class TestLoadStateMachine:
 # deadlines
 # ---------------------------------------------------------------------------
 class TestDeadlines:
-    def test_queued_request_expires_before_costing_prefill(self, stack):
+    def test_queued_request_expires_before_costing_prefill(self, stack, pool):
         _, _, engine = stack
         rng = np.random.default_rng(0)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
         req = srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
                          max_new_tokens=8, deadline_ms=1.0)
         time.sleep(0.01)
@@ -270,10 +253,10 @@ class TestDeadlines:
         assert srv.stats()["deadline_expired"] == 1
         _assert_clean(srv)
 
-    def test_seated_request_retires_via_rollback_path(self, stack):
+    def test_seated_request_retires_via_rollback_path(self, stack, pool):
         _, _, engine = stack
         rng = np.random.default_rng(1)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
         req = srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
                          max_new_tokens=32, deadline_ms=60_000.0)
         srv.step()
@@ -288,9 +271,9 @@ class TestDeadlines:
         assert len(req.output_tokens) == got   # partial output preserved
         _assert_clean(srv)
 
-    def test_engine_default_ttl_applies(self, stack):
+    def test_engine_default_ttl_applies(self, stack, pool):
         _, _, engine = stack
-        srv = ServingEngine(engine, num_slots=2, deadline_default_ms=500.0)
+        srv = make_server(engine, pool, num_slots=2, deadline_default_ms=500.0)
         req = srv.submit(np.arange(5, dtype=np.int32), max_new_tokens=2)
         assert req.deadline_ms == 500.0 and req.deadline_time is not None
         srv.run_until_drained(max_steps=30)
@@ -301,7 +284,7 @@ class TestDeadlines:
 # preemption
 # ---------------------------------------------------------------------------
 class TestPreemption:
-    def test_preempted_output_bitwise_identical(self, stack):
+    def test_preempted_output_bitwise_identical(self, stack, pool):
         """The headline resume guarantee: preempt mid-generation, resume
         through re-prefill, and the greedy token stream is EXACTLY what
         an unpreempted run produces."""
@@ -311,7 +294,7 @@ class TestPreemption:
         budget = 12
         expected = engine.generate(prompt[None], max_new_tokens=budget)[0]
 
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
         req = srv.submit(prompt, max_new_tokens=budget)
         for _ in range(4):
             srv.step()
@@ -331,10 +314,10 @@ class TestPreemption:
         assert srv.stats()["preempted"] == 1
         _assert_clean(srv)
 
-    def test_preempt_requeues_front_of_line(self, stack):
+    def test_preempt_requeues_front_of_line(self, stack, pool):
         _, _, engine = stack
         rng = np.random.default_rng(3)
-        srv = ServingEngine(engine, num_slots=1, max_queue_depth=8)
+        srv = make_server(engine, pool, num_slots=1, max_queue_depth=8)
         victim = srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
                             max_new_tokens=16)
         waiter = srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
@@ -347,13 +330,13 @@ class TestPreemption:
         assert [r.request_id for r in srv.scheduler.queue] == \
             [victim.request_id, waiter.request_id]
 
-    def test_preempt_unknown_id_raises(self, stack):
+    def test_preempt_unknown_id_raises(self, stack, pool):
         _, _, engine = stack
-        srv = ServingEngine(engine, num_slots=2)
+        srv = make_server(engine, pool, num_slots=2)
         with pytest.raises(ValueError, match="not seated"):
             srv.preempt(12345)
 
-    def test_auto_preemption_under_pressure_still_exact(self, stack):
+    def test_auto_preemption_under_pressure_still_exact(self, stack, pool):
         """Queue pressure past the threshold triggers automatic victim
         eviction (requeued at the TAIL — time-slicing, not a swap
         livelock) and every request still finishes with bitwise-exact
@@ -361,9 +344,9 @@ class TestPreemption:
         _, _, engine = stack
         rng = np.random.default_rng(4)
         prompts = _prompts(rng, 6)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=16,
-                            preempt_queue_threshold=2,
-                            preempt_min_run_steps=2)
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
+                          preempt_queue_threshold=2,
+                          preempt_min_run_steps=2)
         reqs = [srv.submit(p, max_new_tokens=6) for p in prompts]
         srv.run_until_drained(max_steps=400)
         assert srv.stats()["preempted"] >= 1
@@ -373,14 +356,14 @@ class TestPreemption:
             np.testing.assert_array_equal(req.tokens(), expected)
         _assert_clean(srv)
 
-    def test_preempt_mid_chunked_prefill(self, stack):
+    def test_preempt_mid_chunked_prefill(self, stack, pool):
         """A PREFILLING victim restarts its chunk walk from zero on
         resume; output parity still holds."""
         _, _, engine = stack
         rng = np.random.default_rng(5)
         prompt = rng.integers(0, 64, size=40).astype(np.int32)
-        srv = ServingEngine(engine, num_slots=2, prefill_chunk=16,
-                            prefill_token_budget=16)
+        srv = make_server(engine, pool, num_slots=2, prefill_chunk=16,
+                          prefill_token_budget=16)
         req = srv.submit(prompt, max_new_tokens=6)
         srv.step()
         assert req.state is RequestState.PREFILLING
@@ -397,14 +380,14 @@ class TestPreemption:
 # graceful degradation
 # ---------------------------------------------------------------------------
 class TestDegradation:
-    def test_ladder_walks_and_sheds_with_retry_after(self, stack):
+    def test_ladder_walks_and_sheds_with_retry_after(self, stack, pool):
         _, _, engine = stack
         rng = np.random.default_rng(6)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=32,
-                            degradation={"queue_pressured": 2,
-                                         "queue_overloaded": 4,
-                                         "cooldown_steps": 2,
-                                         "retry_after_s": 0.25})
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=32,
+                          degradation={"queue_pressured": 2,
+                                       "queue_overloaded": 4,
+                                       "cooldown_steps": 2,
+                                       "retry_after_s": 0.25})
         reqs = [srv.submit(p, max_new_tokens=4) for p in _prompts(rng, 6)]
         srv.step()   # boundary sees queue depth >= 4 -> OVERLOADED
         assert srv._load.state is LoadState.OVERLOADED
@@ -421,30 +404,30 @@ class TestDegradation:
             assert r.state is RequestState.FINISHED
         _assert_clean(srv)
 
-    def test_pressure_shrinks_prefill_budget(self, stack):
+    def test_pressure_shrinks_prefill_budget(self, stack, pool):
         _, _, engine = stack
-        srv = ServingEngine(engine, num_slots=2, prefill_chunk=16,
-                            prefill_token_budget=64,
-                            degradation={"queue_pressured": 1,
-                                         "queue_overloaded": 8})
+        srv = make_server(engine, pool, num_slots=2, prefill_chunk=16,
+                          prefill_token_budget=64,
+                          degradation={"queue_pressured": 1,
+                                       "queue_overloaded": 8})
         assert srv._effective_prefill_budget() == 64
         srv._load.state = LoadState.PRESSURED
         assert srv._effective_prefill_budget() == 32
         srv._load.state = LoadState.OVERLOADED
         assert srv._effective_prefill_budget() == 16   # one chunk
 
-    def test_overload_suspends_spec_drafting(self, stack):
+    def test_overload_suspends_spec_drafting(self, stack, pool):
         """OVERLOADED pushes zero-length drafts through the SAME verify
         program — throughput degrades, shapes (and greedy output) do
         not."""
         _, _, engine = stack
         rng = np.random.default_rng(7)
         prompts = _prompts(rng, 4)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=16,
-                            spec_decode={"drafter": "ngram", "k": 4},
-                            degradation={"queue_pressured": 1,
-                                         "queue_overloaded": 2,
-                                         "cooldown_steps": 64})
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
+                          spec_decode={"drafter": "ngram", "k": 4},
+                          degradation={"queue_pressured": 1,
+                                       "queue_overloaded": 2,
+                                       "cooldown_steps": 64})
         reqs = [srv.submit(p, max_new_tokens=5) for p in prompts]
         srv.run_until_drained(max_steps=200)
         assert srv._load.state is not LoadState.HEALTHY  # ladder engaged
@@ -459,13 +442,13 @@ class TestDegradation:
 # chaos: every injection point, invariants after each
 # ---------------------------------------------------------------------------
 class TestChaos:
-    def test_admit_oom_rolls_back_and_recovers(self, stack):
+    def test_admit_oom_rolls_back_and_recovers(self, stack, pool):
         _, _, engine = stack
         rng = np.random.default_rng(8)
         prompts = _prompts(rng, 3)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                            fault_injector=FaultInjector(
-                                seed=0, schedule={"admit_oom": [1]}))
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                          fault_injector=FaultInjector(
+                              seed=0, schedule={"admit_oom": [1]}))
         reqs = [srv.submit(p, max_new_tokens=4) for p in prompts]
         with pytest.raises(InjectedFault):
             srv.step()
@@ -479,16 +462,16 @@ class TestChaos:
             np.testing.assert_array_equal(req.tokens(), expected)
         _assert_clean(srv)
 
-    def test_admit_oom_with_spec_decode_enabled(self, stack):
+    def test_admit_oom_with_spec_decode_enabled(self, stack, pool):
         # satellite: the admission failure path must also be exception-
         # safe when speculative decoding is configured
         _, _, engine = stack
         rng = np.random.default_rng(9)
         prompts = _prompts(rng, 3)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                            spec_decode={"drafter": "ngram", "k": 4},
-                            fault_injector=FaultInjector(
-                                seed=0, schedule={"admit_oom": [1]}))
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                          spec_decode={"drafter": "ngram", "k": 4},
+                          fault_injector=FaultInjector(
+                              seed=0, schedule={"admit_oom": [1]}))
         reqs = [srv.submit(p, max_new_tokens=4) for p in prompts]
         with pytest.raises(InjectedFault):
             srv.step()
@@ -499,16 +482,16 @@ class TestChaos:
             assert r.state is RequestState.FINISHED
         _assert_clean(srv)
 
-    def test_drafter_failure_aborts_cleanly(self, stack):
+    def test_drafter_failure_aborts_cleanly(self, stack, pool):
         # satellite: drafter raises mid-step with spec decode enabled —
         # running requests FAIL with a reason, nothing leaks, and the
         # server keeps serving afterwards
         _, _, engine = stack
         rng = np.random.default_rng(10)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                            spec_decode={"drafter": "ngram", "k": 4},
-                            fault_injector=FaultInjector(
-                                seed=0, schedule={"drafter_error": [1]}))
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                          spec_decode={"drafter": "ngram", "k": 4},
+                          fault_injector=FaultInjector(
+                              seed=0, schedule={"drafter_error": [1]}))
         reqs = [srv.submit(p, max_new_tokens=8) for p in _prompts(rng, 2)]
         with pytest.raises(InjectedFault):
             srv.run_until_drained(max_steps=50)
@@ -525,14 +508,14 @@ class TestChaos:
         assert again.state is RequestState.FINISHED
         _assert_clean(srv)
 
-    def test_nan_logits_fails_only_poisoned_slot(self, stack):
+    def test_nan_logits_fails_only_poisoned_slot(self, stack, pool):
         _, _, engine = stack
         rng = np.random.default_rng(11)
         prompts = _prompts(rng, 3)
-        srv = ServingEngine(engine, num_slots=3, max_queue_depth=8,
-                            guard_numerics=True,
-                            fault_injector=FaultInjector(
-                                seed=0, schedule={"nan_logits": [2]}))
+        srv = make_server(engine, pool, num_slots=3, max_queue_depth=8,
+                          guard_numerics=True,
+                          fault_injector=FaultInjector(
+                              seed=0, schedule={"nan_logits": [2]}))
         reqs = [srv.submit(p, max_new_tokens=8) for p in prompts]
         srv.run_until_drained(max_steps=100)
         failed = [r for r in reqs if r.state is RequestState.FAILED]
@@ -547,12 +530,12 @@ class TestChaos:
             np.testing.assert_array_equal(r.tokens(), expected)
         _assert_clean(srv)
 
-    def test_step_host_error_aborts_without_leaks(self, stack):
+    def test_step_host_error_aborts_without_leaks(self, stack, pool):
         _, _, engine = stack
         rng = np.random.default_rng(12)
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                            fault_injector=FaultInjector(
-                                seed=0, schedule={"step_host_error": [2]}))
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                          fault_injector=FaultInjector(
+                              seed=0, schedule={"step_host_error": [2]}))
         reqs = [srv.submit(p, max_new_tokens=8) for p in _prompts(rng, 2)]
         with pytest.raises(InjectedFault):
             srv.run_until_drained(max_steps=50)
@@ -563,7 +546,7 @@ class TestChaos:
             assert r.finish_reason is FinishReason.ERROR
         _assert_clean(srv)
 
-    def test_chaos_zero_postwarmup_recompiles(self, stack):
+    def test_chaos_zero_postwarmup_recompiles(self, stack, pool):
         """End-to-end invariant: injected faults (including the NaN
         poisoning, which round-trips logits through the host) must not
         change the compiled program set, and every request still ends
@@ -571,8 +554,8 @@ class TestChaos:
         _, _, engine = stack
         rng = np.random.default_rng(14)
         fi = FaultInjector(seed=0)   # empty schedule through warmup
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=16,
-                            guard_numerics=True, fault_injector=fi)
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
+                          guard_numerics=True, fault_injector=fi)
         for count in (1, 2):         # cover single + batched admission
             for _ in range(count):
                 srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
@@ -596,13 +579,13 @@ class TestChaos:
             assert r.finish_reason is not None
         _assert_clean(srv)
 
-    def test_slow_dispatch_trips_step_wall_watchdog(self, stack):
+    def test_slow_dispatch_trips_step_wall_watchdog(self, stack, pool):
         _, _, engine = stack
         rng = np.random.default_rng(13)
-        srv = ServingEngine(engine, num_slots=2, step_wall_budget_ms=0.001,
-                            fault_injector=FaultInjector(
-                                seed=0, schedule={"slow_dispatch": [1]},
-                                slow_ms=5.0))
+        srv = make_server(engine, pool, num_slots=2, step_wall_budget_ms=0.001,
+                          fault_injector=FaultInjector(
+                              seed=0, schedule={"slow_dispatch": [1]},
+                              slow_ms=5.0))
         req = srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
                          max_new_tokens=2)
         srv.run_until_drained(max_steps=20)
@@ -615,9 +598,9 @@ class TestChaos:
 # stall guard
 # ---------------------------------------------------------------------------
 class TestStallGuard:
-    def test_livelock_raises_with_dump(self, stack):
+    def test_livelock_raises_with_dump(self, stack, pool):
         _, _, engine = stack
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
         req = srv.submit(np.arange(6, dtype=np.int32), max_new_tokens=4)
         # sever the scheduler: queued work that can never be granted is
         # exactly the livelock signature the guard exists to catch
@@ -629,10 +612,10 @@ class TestStallGuard:
         assert dump[0]["state"] == "queued"
         assert "no progress" in str(ei.value)
 
-    def test_max_steps_break_still_returns(self, stack):
+    def test_max_steps_break_still_returns(self, stack, pool):
         # the pre-existing contract: max_steps caps work WITHOUT raising
         _, _, engine = stack
-        srv = ServingEngine(engine, num_slots=2)
+        srv = make_server(engine, pool, num_slots=2)
         srv.submit(np.arange(6, dtype=np.int32), max_new_tokens=50)
         out = srv.run_until_drained(max_steps=3)
         assert isinstance(out, list)

@@ -4,32 +4,16 @@ whole-batch ``generate()``, slot reuse never recompiles the decode step,
 staggered arrivals admit/retire correctly, and admission control sheds
 load with a reason instead of raising."""
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import FIFOScheduler, RequestState, ServingEngine
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
+from .conftest import POOLS, make_server
 
 
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
-
-
-def test_tokens_bitwise_match_generate(stack):
+def test_tokens_bitwise_match_generate(stack, pool):
     """Continuous batching through 2 slots (forcing multi-wave slot reuse)
     must produce EXACTLY the tokens static-batch generate() produces per
     prompt — scheduling policy can never change model output (greedy)."""
@@ -39,7 +23,7 @@ def test_tokens_bitwise_match_generate(stack):
     budgets = [6, 4, 8, 3, 7, 5]
     prompts = [rng.integers(0, 64, size=n).astype(np.int32) for n in lengths]
 
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
     reqs = [srv.submit(p, max_new_tokens=b) for p, b in zip(prompts, budgets)]
     finished = srv.run_until_drained(max_steps=200)
 
@@ -82,12 +66,12 @@ def test_a_slot_left_idle_past_the_capacity_breaks_no_invariant(stack, paged):
         srv.check_invariants()
 
 
-def test_staggered_admission_and_slot_reuse(stack):
+def test_staggered_admission_and_slot_reuse(stack, pool):
     """A request submitted while all slots are busy waits QUEUED, then is
     admitted into the retired request's slot; timing stamps are ordered."""
     _, _, engine = stack
     rng = np.random.default_rng(3)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
     r1 = srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
                     max_new_tokens=2)
     r2 = srv.submit(rng.integers(0, 64, size=10).astype(np.int32),
@@ -112,13 +96,13 @@ def test_staggered_admission_and_slot_reuse(stack):
         assert len(r.output_tokens) == r.max_new_tokens
 
 
-def test_slot_reuse_does_not_recompile(stack):
+def test_slot_reuse_does_not_recompile(stack, pool):
     """Retire/admit churn across waves must keep the jitted decode and
     prefill caches at a FIXED number of compiled programs — dead slots are
     masked padding, not shape changes."""
     _, _, engine = stack
     rng = np.random.default_rng(5)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=16)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=16)
     # wave A: compile everything once — 3 requests over 2 slots so both
     # admission batch buckets (nB=2 full step, nB=1 single refill) warm up
     for _ in range(3):
@@ -138,10 +122,10 @@ def test_slot_reuse_does_not_recompile(stack):
     assert srv.watchdog.recompiles == 0
 
 
-def test_admission_control_rejects_with_reason(stack):
+def test_admission_control_rejects_with_reason(stack, pool):
     _, _, engine = stack
     rng = np.random.default_rng(11)
-    srv = ServingEngine(engine, num_slots=1, max_queue_depth=2)
+    srv = make_server(engine, pool, num_slots=1, max_queue_depth=2)
 
     ok = [srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
                      max_new_tokens=2) for _ in range(2)]
@@ -167,7 +151,7 @@ def test_admission_control_rejects_with_reason(stack):
     assert stats["rejected"] == {"queue_full": 1, "prompt_too_long": 1}
 
 
-def test_eos_retires_early(stack):
+def test_eos_retires_early(stack, pool):
     """With eos_token_id set, a slot retires the moment greedy emits it —
     and the emitted prefix still matches generate()'s."""
     _, _, engine = stack
@@ -178,7 +162,7 @@ def test_eos_retires_early(stack):
     eos = int(gen[2])  # greedy will deterministically reach this token
     first = int(np.argmax(gen == eos))
 
-    srv = ServingEngine(engine, num_slots=1, max_queue_depth=2)
+    srv = make_server(engine, pool, num_slots=1, max_queue_depth=2)
     req = srv.submit(prompt, max_new_tokens=8, eos_token_id=eos)
     srv.run_until_drained(max_steps=50)
     assert req.finish_reason == "eos"
@@ -186,37 +170,8 @@ def test_eos_retires_early(stack):
     np.testing.assert_array_equal(req.output_tokens, gen[:first + 1])
 
 
-def test_gang_policy_is_batch_synchronous(stack):
-    """The bench baseline arm: gang admission refuses to backfill free
-    slots until the WHOLE wave has drained (the generate() discipline)."""
-    _, _, engine = stack
-    rng = np.random.default_rng(17)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        policy="gang")
-    r1 = srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
-                    max_new_tokens=2)
-    r2 = srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
-                    max_new_tokens=6)
-    r3 = srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
-                    max_new_tokens=2)
-    srv.step()  # wave 1 admitted (r1, r2); r1 finishes (budget 2)
-    assert r1.state == RequestState.FINISHED
-    while srv.live_count:  # r3 must NOT be admitted while r2 runs
-        assert r3.state == RequestState.QUEUED
-        srv.step()
-    srv.run_until_drained(max_steps=50)
-    assert r3.state == RequestState.FINISHED
-    # and the policy changed nothing about the tokens
-    expected = engine.generate(np.asarray(r3.prompt)[None],
-                               max_new_tokens=2)[0]
-    np.testing.assert_array_equal(r3.tokens(), expected)
-
-
 def test_scheduler_unit():
-    sched = FIFOScheduler(num_slots=2, max_queue_depth=2, policy="continuous",
-                          capacity=32)
-    with pytest.raises(ValueError, match="policy"):
-        FIFOScheduler(2, 2, policy="nope", capacity=32)
+    sched = FIFOScheduler(num_slots=2, max_queue_depth=2, capacity=32)
 
     class R:  # minimal stand-in (the admission surface of Request:
         # capacity charges seed + REMAINING budget, see Scheduler.submit)
@@ -232,19 +187,21 @@ def test_scheduler_unit():
     sched.submit(R(4, 4))
     ok, reason = sched.submit(R(4, 4))
     assert not ok and reason == "queue_full"
-    assert len(sched.grant(free_slots=2, live_slots=0)) == 2
+    assert len(sched.grant(free_slots=2)) == 2
     assert sched.pending == 0
 
 
-def test_init_serving_wrapper(stack):
-    """ds.init_serving splits serving knobs from inference knobs."""
+def test_init_serving_wrapper(stack, pool):
+    """ds.init_serving splits serving knobs from inference knobs: every
+    option of ServingEngine's constructor goes to the server."""
     model, params, _ = stack
     srv = ds.init_serving(model, config={"dtype": "float32"},
                           model_parameters=params, num_slots=2,
-                          max_queue_depth=4, policy="gang", seed=3)
+                          max_queue_depth=4, seed=3, role="both",
+                          paged_kv=POOLS[pool])
     assert isinstance(srv, ServingEngine)
-    assert srv.scheduler.policy == "gang"
-    assert srv.pool.num_slots == 2
+    assert srv.pool.num_slots == 2 and srv.role == "both"
+    assert srv._paged == (pool == "paged")
     req = srv.submit(np.arange(5, dtype=np.int32), max_new_tokens=2)
     srv.run_until_drained(max_steps=20)
     assert req.state == RequestState.FINISHED
@@ -265,13 +222,13 @@ def test_release_double_free_guard(stack):
         pool.release(7)
 
 
-def test_midstep_decode_exception_never_leaks_slots(stack):
+def test_midstep_decode_exception_never_leaks_slots(stack, pool):
     """An engine exception mid-decode must FAIL the running requests
     (their donated KV state is unrecoverable), keep queued requests
     queued, return every slot, and leave the server usable."""
     _, _, engine = stack
     rng = np.random.default_rng(41)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
     r1 = srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
                     max_new_tokens=6)
     r2 = srv.submit(rng.integers(0, 64, size=9).astype(np.int32),
@@ -281,14 +238,18 @@ def test_midstep_decode_exception_never_leaks_slots(stack):
     srv.step()
     assert r1.state == r2.state == RequestState.RUNNING
 
-    orig = engine._jit_decode
-    engine._jit_decode = lambda *a, **k: (_ for _ in ()).throw(
-        RuntimeError("injected decode failure"))
+    # the decode program the server dispatches: the engine's on the
+    # contiguous pool, the pool's own dense composition on the paged one
+    owner, name = (srv.pool, "_paged_decode_jit") if srv._paged \
+        else (engine, "_jit_decode")
+    orig = getattr(owner, name)
+    setattr(owner, name, lambda *a, **k: (_ for _ in ()).throw(
+        RuntimeError("injected decode failure")))
     try:
         with pytest.raises(RuntimeError, match="injected"):
             srv.step()
     finally:
-        engine._jit_decode = orig
+        setattr(owner, name, orig)
 
     assert srv.live_count == 0 and srv.pool.free_count == 2
     for r in (r1, r2):
@@ -304,13 +265,13 @@ def test_midstep_decode_exception_never_leaks_slots(stack):
     assert srv.stats()["failed"] == 2
 
 
-def test_admit_exception_requeues_request(stack):
+def test_admit_exception_requeues_request(stack, pool):
     """A prefill exception during admission rolls the request back to
     QUEUED (front of queue, state scrubbed) instead of leaking its slot
     or failing it — it lost nothing but time."""
     _, _, engine = stack
     rng = np.random.default_rng(43)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
     prompt = rng.integers(0, 64, size=6).astype(np.int32)
     r1 = srv.submit(prompt, max_new_tokens=3)
 
@@ -345,14 +306,15 @@ class _FakeMonitor:
         self.events.extend(events)
 
 
-def test_rejection_paths_end_to_end_with_metrics(stack):
+def test_rejection_paths_end_to_end_with_metrics(stack, pool):
     """queue_full / prompt_too_long shedding: the request never consumes
     a slot, the reason lands in stats() AND as a monitor event, and the
     accepted workload is unaffected."""
     _, _, engine = stack
     rng = np.random.default_rng(47)
     mon = _FakeMonitor()
-    srv = ServingEngine(engine, num_slots=1, max_queue_depth=1, monitor=mon)
+    srv = make_server(engine, pool, num_slots=1, max_queue_depth=1,
+                      monitor=mon)
 
     ok = srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
                     max_new_tokens=2)
@@ -378,10 +340,10 @@ def test_rejection_paths_end_to_end_with_metrics(stack):
     assert "serving/ttft_ms" in [t for t, _, _ in mon.events]
 
 
-def test_metrics_snapshot_fields(stack):
+def test_metrics_snapshot_fields(stack, pool):
     _, _, engine = stack
     rng = np.random.default_rng(19)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8)
     for _ in range(3):
         srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
                    max_new_tokens=3)

@@ -5,31 +5,14 @@ slot churn still never recompiles, rollback math keeps the KV state
 machine consistent through eos/budget truncation, and the config block
 validates its knobs up front."""
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
-from deepspeed_tpu.serving import (RequestState, ServingEngine, SlotPool,
-                                   SpecDecodeConfig)
+from deepspeed_tpu.serving import RequestState, SlotPool, SpecDecodeConfig
 from deepspeed_tpu.serving.spec_decode import NGramDrafter, make_drafter
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
+from .conftest import make_server
 
 
 def _spec(k=4, **kw):
@@ -52,7 +35,7 @@ def _mixed_prompts(rng, n):
 
 
 # ---------------------------------------------------------------- parity
-def test_greedy_parity_multiwave_staggered(stack):
+def test_greedy_parity_multiwave_staggered(stack, pool):
     """The acceptance bar: n-gram-drafted speculative decode through 2
     slots (multi-wave slot reuse) with STAGGERED arrivals emits exactly
     the tokens the spec-off server — and generate() — emits."""
@@ -62,8 +45,8 @@ def test_greedy_parity_multiwave_staggered(stack):
     budgets = [int(b) for b in rng.integers(4, 24, size=7)]
 
     def run(spec):
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=16,
-                            spec_decode=spec)
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
+                          spec_decode=spec)
         reqs = []
         for p, b in zip(prompts, budgets):   # staggered: one per step
             reqs.append(srv.submit(p, max_new_tokens=b))
@@ -86,7 +69,7 @@ def test_greedy_parity_multiwave_staggered(stack):
     assert s["decode_steps"] < sum(budgets)  # fewer steps than tokens
 
 
-def test_eos_mid_accepted_chunk(stack):
+def test_eos_mid_accepted_chunk(stack, pool):
     """EOS emitted INSIDE an accepted draft chunk truncates consumption,
     retires the slot that step, and still matches generate()'s prefix."""
     _, _, engine = stack
@@ -97,23 +80,23 @@ def test_eos_mid_accepted_chunk(stack):
     eos = int(gen[3])
     first = int(np.argmax(gen == eos))
 
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=4,
-                        spec_decode=_spec(k=5))
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=4,
+                      spec_decode=_spec(k=5))
     req = srv.submit(prompt, max_new_tokens=12, eos_token_id=eos)
     srv.run_until_drained(max_steps=50)
     assert req.finish_reason == "eos"
     np.testing.assert_array_equal(req.output_tokens, gen[:first + 1])
 
 
-def test_do_sample_spec_smoke(stack):
+def test_do_sample_spec_smoke(stack, pool):
     """Lossless rejection sampling path: runs, respects budgets, emits
     in-vocab tokens. (Distributional identity is the verify program's
     math; this guards the plumbing.)"""
     _, _, engine = stack
     rng = np.random.default_rng(29)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        do_sample=True, temperature=1.0, seed=5,
-                        spec_decode=_spec(k=3))
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      do_sample=True, temperature=1.0, seed=5,
+                      spec_decode=_spec(k=3))
     reqs = [srv.submit(p, max_new_tokens=6)
             for p in _mixed_prompts(rng, 4)]
     srv.run_until_drained(max_steps=100)
@@ -124,7 +107,7 @@ def test_do_sample_spec_smoke(stack):
 
 
 # ------------------------------------------------------- shape discipline
-def test_spec_churn_does_not_recompile(stack):
+def test_spec_churn_does_not_recompile(stack, pool):
     """Slot retire/admit churn with speculation on keeps the verify jit
     (and decode/prefill jits) at a fixed program count — draft_len
     masking absorbs every live/dead/non-speculating combination."""
@@ -132,21 +115,25 @@ def test_spec_churn_does_not_recompile(stack):
     rng = np.random.default_rng(31)
 
     def wave(n):
-        srv = ServingEngine(engine, num_slots=2, max_queue_depth=16,
-                            spec_decode=_spec(k=4))
+        srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
+                          spec_decode=_spec(k=4))
         for p in _mixed_prompts(rng, n):
             srv.submit(p, max_new_tokens=5)
         srv.run_until_drained(max_steps=200)
         return srv
 
-    wave(2)  # compile: prefill buckets, verify, decode
-    n_verify = engine._jit_verify_k._cache_size()
-    n_decode = engine._jit_decode._cache_size()
-    n_prefill = engine._jit_prefill_at._cache_size()
+    def programs(srv):
+        # what a spec-decode step dispatches: the engine's jits on the
+        # contiguous pool; on the paged one the pool's own, built anew
+        # with every pool, so two servers' COUNTS are what compares
+        jits = (srv.pool._paged_verify_jit, srv.pool._paged_decode_jit,
+                srv.pool._admit_rows_jit) if srv._paged else \
+            (engine._jit_verify_k, engine._jit_decode)
+        return [j._cache_size() for j in jits + (engine._jit_prefill_at,)]
+
+    counts = programs(wave(2))  # compile: prefill buckets, verify, decode
     srv = wave(6)  # multi-wave churn through the same shapes
-    assert engine._jit_verify_k._cache_size() == n_verify
-    assert engine._jit_decode._cache_size() == n_decode
-    assert engine._jit_prefill_at._cache_size() == n_prefill
+    assert programs(srv) == counts and counts[0] >= 1
     # the watchdog pins the same invariant at runtime: a warmed server
     # sees zero attributed compiles through another churn wave
     srv.end_warmup()
@@ -156,16 +143,16 @@ def test_spec_churn_does_not_recompile(stack):
     assert srv.watchdog.recompiles == 0
 
 
-def test_capacity_margin_tightens_admission(stack):
+def test_capacity_margin_tightens_admission(stack, pool):
     """With spec on, admission reserves k positions of verify headroom:
     a request that fits the raw capacity but not capacity - k is shed
     as prompt_too_long instead of corrupting a neighbour's live KV."""
     _, _, engine = stack
     prompt = np.zeros((40,), np.int32)  # 40 + 20 = 60 <= 64 but > 64 - 6
-    off = ServingEngine(engine, num_slots=2, max_queue_depth=4)
+    off = make_server(engine, pool, num_slots=2, max_queue_depth=4)
     assert off.submit(prompt, max_new_tokens=20).state == RequestState.QUEUED
-    on = ServingEngine(engine, num_slots=2, max_queue_depth=4,
-                       spec_decode=_spec(k=6))
+    on = make_server(engine, pool, num_slots=2, max_queue_depth=4,
+                     spec_decode=_spec(k=6))
     r = on.submit(prompt, max_new_tokens=20)
     assert r.state == RequestState.REJECTED
     assert r.reject_reason == "prompt_too_long"
@@ -195,7 +182,7 @@ def test_ngram_drafter_unit():
     assert counts[0] == 0
 
 
-def test_small_model_drafter_self_speculation(stack):
+def test_small_model_drafter_self_speculation(stack, pool):
     """Drafting with the TARGET model itself (the degenerate two-model
     setup) must keep exact parity — and accept nearly everything, since
     the draft IS the target's greedy continuation."""
@@ -205,9 +192,9 @@ def test_small_model_drafter_self_speculation(stack):
     rng = np.random.default_rng(37)
     prompts = _mixed_prompts(rng, 4)
 
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        spec_decode={"drafter": "model", "k": 4,
-                                     "draft_engine": draft_eng})
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      spec_decode={"drafter": "model", "k": 4,
+                                   "draft_engine": draft_eng})
     reqs = [srv.submit(p, max_new_tokens=10) for p in prompts]
     srv.run_until_drained(max_steps=100)
     for r, p in zip(reqs, prompts):

@@ -5,36 +5,19 @@ chunk/batch programs never recompile on churn, long prompts stop
 stalling live decode slots, and capacity exhaustion retires with
 ``"length_cap"`` instead of silently clamping cache writes."""
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
-from deepspeed_tpu.serving import RequestState, ServingEngine
+from deepspeed_tpu.serving import RequestState
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
+from .conftest import make_server
 
 
 def _prompts(rng, lengths):
     return [rng.integers(1, 64, size=n).astype(np.int32) for n in lengths]
 
 
-def test_chunked_prefill_parity_with_generate(stack):
+def test_chunked_prefill_parity_with_generate(stack, pool):
     """Prompts longer than the chunk width stream in chunk by chunk; the
     resulting greedy tokens must bitwise-match whole-prompt generate()."""
     _, _, engine = stack
@@ -42,8 +25,8 @@ def test_chunked_prefill_parity_with_generate(stack):
     lengths = [40, 33, 17]          # 3 chunks, 3 chunks (odd tail), 2 chunks
     budgets = [6, 5, 4]
     prompts = _prompts(rng, lengths)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        prefill_chunk=16)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      prefill_chunk=16)
     assert srv._stall_free and srv.prefill_chunk == 16
     reqs = [srv.submit(p, max_new_tokens=b) for p, b in zip(prompts, budgets)]
     srv.run_until_drained(max_steps=300)
@@ -54,7 +37,7 @@ def test_chunked_prefill_parity_with_generate(stack):
                                       err_msg=f"req {req.request_id}")
 
 
-def test_bucket_boundary_prompt_lengths(stack):
+def test_bucket_boundary_prompt_lengths(stack, pool):
     """Power-of-two bucket edges (15/16/17, 31/32/33) and a prompt that
     exactly fills its slot with its budget (60 + 4 = capacity 64) must
     all admit, finish, and match generate() bitwise."""
@@ -63,8 +46,8 @@ def test_bucket_boundary_prompt_lengths(stack):
     lengths = [15, 16, 17, 31, 32, 33, 60]
     budgets = [3, 3, 3, 3, 3, 3, 4]
     prompts = _prompts(rng, lengths)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        prefill_chunk=16)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      prefill_chunk=16)
     reqs = [srv.submit(p, max_new_tokens=b) for p, b in zip(prompts, budgets)]
     srv.run_until_drained(max_steps=400)
     for req, prompt, budget in zip(reqs, prompts, budgets):
@@ -75,14 +58,14 @@ def test_bucket_boundary_prompt_lengths(stack):
                                       err_msg=f"len {req.prompt_len}")
 
 
-def test_long_prompt_does_not_stall_running_slot(stack):
+def test_long_prompt_does_not_stall_running_slot(stack, pool):
     """THE stall-free property: while a long prompt is PREFILLING chunk
     by chunk, an already-running request keeps emitting one token per
     step — admission no longer monopolizes whole steps."""
     _, _, engine = stack
     rng = np.random.default_rng(31)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        prefill_chunk=16)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      prefill_chunk=16)
     short = srv.submit(rng.integers(1, 64, size=6).astype(np.int32),
                        max_new_tokens=20)
     srv.step()
@@ -120,19 +103,19 @@ class _FakeMonitor:
         self.events.extend(events)
 
 
-def test_length_cap_retires_full_slot(stack):
+def test_length_cap_retires_full_slot(stack, pool):
     """A slot whose cache row fills to max_seq_len retires with
     ``"length_cap"`` (plus its monitor event) instead of silently
     clamp-overwriting the last column forever."""
     _, _, engine = stack
     rng = np.random.default_rng(37)
     mon = _FakeMonitor()
-    srv = ServingEngine(engine, num_slots=1, max_queue_depth=4,
-                        prefill_chunk=16, monitor=mon)
+    srv = make_server(engine, pool, num_slots=1, max_queue_depth=4,
+                      prefill_chunk=16, monitor=mon)
     # normal admission control forbids prompt+budget > capacity, which is
     # exactly what makes the cap unreachable; disable it to exercise the
-    # engine-side safety net behind it
-    srv.scheduler.capacity = None
+    # engine-side safety net behind it (rows, and on the paged pool pages)
+    srv.scheduler.capacity = srv.scheduler.num_pages = None
     req = srv.submit(rng.integers(1, 64, size=60).astype(np.int32),
                      max_new_tokens=10)
     srv.run_until_drained(max_steps=100)
@@ -144,15 +127,15 @@ def test_length_cap_retires_full_slot(stack):
     assert "serving/finished/length_cap" in [t for t, _, _ in mon.events]
 
 
-def test_spec_decode_skips_prefilling_slots(stack):
+def test_spec_decode_skips_prefilling_slots(stack, pool):
     """Speculative decoding + chunked admission: verify steps must not
     advance (or corrupt) half-prefilled rows — outputs stay bitwise
     equal to generate() for both the running and the chunked request."""
     _, _, engine = stack
     rng = np.random.default_rng(41)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        prefill_chunk=16, spec_decode={"drafter": "ngram",
-                                                       "k": 4})
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      prefill_chunk=16, spec_decode={"drafter": "ngram",
+                                                     "k": 4})
     short = srv.submit(rng.integers(1, 64, size=9).astype(np.int32),
                        max_new_tokens=12)
     long = srv.submit(rng.integers(1, 64, size=44).astype(np.int32),
@@ -166,13 +149,13 @@ def test_spec_decode_skips_prefilling_slots(stack):
                                       err_msg=f"req {req.request_id}")
 
 
-def test_batched_admission_is_one_dispatch(stack):
+def test_batched_admission_is_one_dispatch(stack, pool):
     """Same-bucket waiting prompts admit through ONE prefill dispatch and
     ONE multi-row scatter, not one dispatch per prompt."""
     _, _, engine = stack
     rng = np.random.default_rng(43)
-    srv = ServingEngine(engine, num_slots=4, max_queue_depth=8,
-                        prefill_chunk=16, prefill_token_budget=64)
+    srv = make_server(engine, pool, num_slots=4, max_queue_depth=8,
+                      prefill_chunk=16, prefill_token_budget=64)
     reqs = [srv.submit(p, max_new_tokens=3)
             for p in _prompts(rng, [5, 9, 12])]
 
@@ -199,14 +182,14 @@ def test_batched_admission_is_one_dispatch(stack):
         np.testing.assert_array_equal(req.tokens(), expected)
 
 
-def test_token_budget_bounds_admission(stack):
+def test_token_budget_bounds_admission(stack, pool):
     """The per-step token budget defers admissions past the budget and an
     in-flight chunk blocks new grants entirely — but the FIFO head is
     never starved (liveness overshoot when nothing else was spent)."""
     _, _, engine = stack
     rng = np.random.default_rng(47)
-    srv = ServingEngine(engine, num_slots=4, max_queue_depth=8,
-                        prefill_chunk=16, prefill_token_budget=16)
+    srv = make_server(engine, pool, num_slots=4, max_queue_depth=8,
+                      prefill_chunk=16, prefill_token_budget=16)
     a = srv.submit(rng.integers(1, 64, size=6).astype(np.int32),
                    max_new_tokens=8)
     b = srv.submit(rng.integers(1, 64, size=6).astype(np.int32),
@@ -234,15 +217,15 @@ def test_token_budget_bounds_admission(stack):
         np.testing.assert_array_equal(req.tokens(), expected)
 
 
-def test_no_recompile_across_chunked_and_batched_churn(stack):
+def test_no_recompile_across_chunked_and_batched_churn(stack, pool):
     """Extended churn coverage: after one warmup wave that touches every
     program (batched admission at nB=1/2, the chunk program, decode),
     further waves of NEW lengths/offsets/slots must not add a single
     compiled program."""
     _, _, engine = stack
     rng = np.random.default_rng(53)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=16,
-                        prefill_chunk=16)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
+                      prefill_chunk=16)
     # warmup: two shorts together (nB=2), a straggler short (nB=1 refill),
     # and a long prompt (chunk program at several offsets)
     for n, b in [(6, 3), (9, 3), (7, 3), (40, 3)]:
@@ -266,30 +249,28 @@ def test_no_recompile_across_chunked_and_batched_churn(stack):
     assert srv.watchdog.recompiles == 0
 
 
-def test_config_validation_and_fallbacks(stack):
+def test_config_validation_and_fallbacks(stack, pool):
     """Knob validation: chunk auto-halves until it divides capacity,
-    budget below the chunk raises, chunk=0 or gang policy falls back to
-    serial admission."""
+    budget below the chunk raises, chunk=0 falls back to serial
+    admission."""
     _, _, engine = stack
-    srv = ServingEngine(engine, num_slots=1, prefill_chunk=48)
+    srv = make_server(engine, pool, num_slots=1, prefill_chunk=48)
     assert srv._stall_free
     assert srv.pool.capacity % srv.prefill_chunk == 0
     with pytest.raises(ValueError, match="prefill_token_budget"):
-        ServingEngine(engine, num_slots=1, prefill_chunk=32,
-                      prefill_token_budget=16)
+        make_server(engine, pool, num_slots=1, prefill_chunk=32,
+                    prefill_token_budget=16)
     with pytest.raises(ValueError, match="prefill_chunk"):
-        ServingEngine(engine, num_slots=1, prefill_chunk=-1)
-    off = ServingEngine(engine, num_slots=1, prefill_chunk=0)
+        make_server(engine, pool, num_slots=1, prefill_chunk=-1)
+    off = make_server(engine, pool, num_slots=1, prefill_chunk=0)
     assert not off._stall_free and off.prefill_token_budget is None
-    gang = ServingEngine(engine, num_slots=1, policy="gang")
-    assert not gang._stall_free
 
 
-def test_metrics_prefill_decode_split(stack):
+def test_metrics_prefill_decode_split(stack, pool):
     _, _, engine = stack
     rng = np.random.default_rng(59)
-    srv = ServingEngine(engine, num_slots=2, max_queue_depth=8,
-                        prefill_chunk=16)
+    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
+                      prefill_chunk=16)
     for n in (6, 10, 40):
         srv.submit(rng.integers(1, 64, size=n).astype(np.int32),
                    max_new_tokens=4)

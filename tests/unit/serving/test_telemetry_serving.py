@@ -6,30 +6,12 @@ recompile watchdog must read zero across warmed churn."""
 
 import json
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import RequestState, ServingEngine
 from deepspeed_tpu.telemetry import default_tracer, RecompileAfterWarmupError, Tracer
-
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
 
 
 class _FakeMonitor:
@@ -296,7 +278,8 @@ def test_watchdog_zero_after_warmup_and_strict_raise(stack):
 
 def test_tracer_overhead_is_bounded(stack):
     """Tracing 50 steps of a drained server must not blow up step cost —
-    a loose 2x smoke bound (the bench gates the real <2% number)."""
+    a loose 2x smoke bound (the ring's real cost is a chip number: PERF.md
+    §6, PR 23)."""
     import time
 
     _, _, engine = stack
@@ -348,7 +331,7 @@ def test_warmup_manifest_records_then_freezes(stack):
 def test_export_signatures_merges_by_union(stack, tmp_path):
     # watchdog proxies are shared per ENGINE (attach is idempotent), so
     # a merged union of distinct warmup sets needs two engines — exactly
-    # the bench shape, where every arm exports into one signatures.json
+    # the shape of several servers exporting into one signatures.json
     model, params, engine = stack
     rng = np.random.default_rng(29)
     path = str(tmp_path / "signatures.json")
