@@ -178,7 +178,9 @@ class SmallModelDrafter(Drafter):
                                             jnp.asarray(pos))
             cur = self._argmax(logits)
             toks.append(cur)
-            pos += 1
+            # a new array, not ``+=``: the CPU backend may still be reading
+            # the buffer ``jnp.asarray(pos)`` aliases when this line runs
+            pos = pos + 1
         tokens = np.stack([np.asarray(t) for t in toks], axis=1)
         counts = np.where(lens > 0, k, 0).astype(np.int32)
         return tokens.astype(np.int32), counts

@@ -46,19 +46,23 @@ def _rand(shape, dtype=jnp.bfloat16, seed=0):
         np.random.default_rng(seed).standard_normal(shape), dtype)
 
 
-@pytest.mark.parametrize("head_dim", [64, 128])
-def test_flash_forward_and_backward_lower_for_tpu(compiled_kernels, head_dim):
+@pytest.mark.parametrize("seq,head_dim,causal", [
+    (1024, 64, True), (1024, 128, True), (2048, 128, True), (1024, 64, False)])
+def test_flash_forward_and_backward_lower_for_tpu(compiled_kernels, seq,
+                                                  head_dim, causal):
     from deepspeed_tpu.ops.attention.flash_attention import flash_attention
 
-    q = _rand((1, 1024, 2, head_dim))
-    fwd = _tpu_custom_calls(flash_attention, q, q, q)
-    assert fwd >= 1
+    q = _rand((1, seq, 2, head_dim))
+    fwd = _tpu_custom_calls(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal), q, q, q)
+    assert fwd == 1
 
     def loss(q, k, v):
-        return flash_attention(q, k, v).astype(jnp.float32).sum()
+        return flash_attention(q, k, v, causal=causal).astype(
+            jnp.float32).sum()
 
     both = _tpu_custom_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    assert both > fwd      # the backward kernels are custom calls too
+    assert both == 3       # flash_fwd, flash_bwd_dq, flash_bwd_dkv
 
 
 def _paged_operands(page_size, rows=1, quant=None):
