@@ -263,10 +263,12 @@ WATCHED_MODELS = {
     "_jit_copy_page": lambda args, kw, env: Tree(COMMITTED, "pool"),
     "_jit_gather_pages": lambda args, kw, env: Tree(COMMITTED, "pool"),
     "_jit_scatter_pages": lambda args, kw, env: Tree(COMMITTED, "pool"),
+    # (logits, pool, what a routed FFN counted: None for a dense model)
     "_paged_decode_jit": lambda args, kw, env: Tup(
-        [_logits(_batch_of(args[2]), env), Tree(COMMITTED, "pool")]),
+        [_logits(_batch_of(args[2]), env), Tree(COMMITTED, "pool"),
+         Scalar(None)]),
     "_paged_chunk_jit": lambda args, kw, env: Tup(
-        [_logits(Known(1), env), Tree(COMMITTED, "pool")]),
+        [_logits(Known(1), env), Tree(COMMITTED, "pool"), Scalar(None)]),
     "_paged_verify_jit": lambda args, kw, env: Tup(
         [Tree(COMMITTED, "pool"),
          Arr((_batch_of(args[2]),
@@ -278,7 +280,8 @@ WATCHED_MODELS = {
     # fused paged-attention kernel arms: same caller-visible contract as
     # the dense compositions they replace
     "_paged_decode_kernel_jit": lambda args, kw, env: Tup(
-        [_logits(_batch_of(args[2]), env), Tree(COMMITTED, "pool")]),
+        [_logits(_batch_of(args[2]), env), Tree(COMMITTED, "pool"),
+         Scalar(None)]),
     "_paged_verify_kernel_jit": lambda args, kw, env: Tup(
         [Tree(COMMITTED, "pool"),
          Arr((_batch_of(args[2]),

@@ -22,7 +22,21 @@ llama          rotary    rmsnorm    swiglu    no biases, untied head, GQA
 opt            learned   layernorm  relu      tied head
 bloom          alibi     layernorm  gelu      embedding layernorm
 megatron-gpt   learned   layernorm  gelu
+mellum         rotary    rmsnorm    routed    ``head_dim`` a field, GQA,
+                                              window and full layers
+                                              (``layer_types``, rotary by
+                                              type), top-k of E experts
 =============  ========  =========  ========  ===================
+
+Layer kinds that differ (``layer_types``: ``sliding_attention`` or
+``full_attention``, each with its own rotary table) run inside the ONE
+layer scan: a layer reads its kind off the scan's counter, so the mask
+and the table are selected, not branched on. The routed FFN
+(``n_experts > 0``) is ``deepspeed_tpu/moe/routed_ffn.py``; its stacked
+expert leaves ``(layers, E, C, F)`` are parameters of the model, not of
+the scanned block, and reach every layer whole (a scanned leaf would be
+sliced, that is copied, a layer). Nothing of this is reached by a
+configuration with ``n_experts == 0`` and no ``layer_types``.
 
 KV-cache decoding uses the flax ``cache`` variable collection: ``prefill``
 writes the prompt's K/V at positions [0, T), ``decode`` appends one position
@@ -40,6 +54,7 @@ from typing import Any, Optional, Tuple, Union
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import backend
 
@@ -100,10 +115,70 @@ class TransformerConfig:
     loss_chunk: int = 0                 # streaming cross-entropy: >0 computes
     # the LM loss in T-chunks of this size without materializing the
     # (B, T, V) logits (ops/transformer/chunked_xent.py); 0 = dense loss
+    head_size: Optional[int] = None     # per-head width where it is not
+    # n_embd // n_head (read it as ``head_dim``)
+    ffn_dim: Optional[int] = None       # FFN width where it is not
+    # mlp_ratio * n_embd; with experts, the width of ONE expert
+    layer_types: Optional[Tuple[str, ...]] = None   # per layer,
+    # "sliding_attention" | "full_attention"; None: every layer full
+    sliding_window: Optional[int] = None    # a sliding layer's query i sees
+    # key j iff 0 <= i - j < sliding_window
+    rope_parameters: Optional[tuple] = None     # rotary by layer type, as
+    # frozen by transformer_config from {"<layer type>": {"rope_type":
+    # "default" | "yarn", "rope_theta", "factor",
+    # "original_max_position_embeddings", "beta_fast", "beta_slow",
+    # "attention_factor"}}; None: rope_theta for every layer
+    n_experts: int = 0                  # > 0: the FFN of every layer is
+    # routed (deepspeed_tpu/moe/routed_ffn.py), ffn_dim an expert's width
+    experts_per_token: int = 0
+    norm_topk_prob: bool = True         # renormalise the chosen experts'
+    # router probabilities to sum to 1
+
+    def __post_init__(self):
+        if self.layer_types is not None:
+            kinds = set(self.layer_types) - {"sliding_attention",
+                                             "full_attention"}
+            if kinds or len(self.layer_types) != self.n_layer:
+                raise ValueError(
+                    f"layer_types names n_layer={self.n_layer} layers as "
+                    f"sliding_attention | full_attention; got "
+                    f"{len(self.layer_types)} entries, unknown {sorted(kinds)}")
+            if "sliding_attention" in self.layer_types \
+                    and not self.sliding_window:
+                raise ValueError("sliding_attention layers need "
+                                 "sliding_window")
+            if self.pos_emb not in ("rotary", "none"):
+                raise ValueError(
+                    f"layer_types composes with rotary or no positions, "
+                    f"not pos_emb={self.pos_emb!r}")
+        if self.n_experts:
+            if not 0 < self.experts_per_token <= self.n_experts:
+                raise ValueError(
+                    f"experts_per_token={self.experts_per_token} of "
+                    f"n_experts={self.n_experts}")
+            if self.int8_weights:
+                raise ValueError(
+                    "int8_weights does not reach the routed FFN's expert "
+                    "leaves (ops/quantization quantizes Dense kernels); "
+                    "serve the routed model in bf16")
+            if self.activation != "swiglu" or self.mlp_bias:
+                raise ValueError("the routed FFN is gated silu without "
+                                 "bias (activation='swiglu', mlp_bias=False)")
+        if (self.layer_types is not None or self.n_experts) \
+                and self.kv_cache_quant:
+            raise ValueError(
+                "kv_cache_quant does not compose with layer_types or a "
+                "routed FFN yet: the window group's pages and the window "
+                "mask exist for the full-precision tier only (ROADMAP.md, "
+                "Reach)")
 
     @property
     def head_dim(self) -> int:
-        return self.n_embd // self.n_head
+        return self.head_size or self.n_embd // self.n_head
+
+    @property
+    def ffn_width(self) -> int:
+        return self.ffn_dim or int(self.mlp_ratio * self.n_embd)
 
     @property
     def kv_heads(self) -> int:
@@ -125,7 +200,21 @@ FAMILY_PRESETS = {
     "bloom": dict(pos_emb="alibi", norm="layernorm", activation="gelu",
                   embed_layernorm=True),
     "megatron-gpt": dict(pos_emb="learned", norm="layernorm", activation="gelu"),
+    # Mellum 2 (JetBrains): llama's block with head_dim, FFN width, layer
+    # kinds, rotary by kind and the routed FFN given by the caller
+    "mellum": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
+                   qkv_bias=False, mlp_bias=False, tie_word_embeddings=False,
+                   layer_norm_epsilon=1e-6),
 }
+
+
+def _freeze(value):
+    """JSON-shaped ``value`` as something a frozen dataclass can hash."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, _freeze(v)) for k, v in value.items()))
+    if isinstance(value, (list, tuple)):
+        return tuple(_freeze(v) for v in value)
+    return value
 
 
 def transformer_config(family: str, **overrides) -> TransformerConfig:
@@ -133,6 +222,8 @@ def transformer_config(family: str, **overrides) -> TransformerConfig:
     reference module_inject/replace_policy.py)."""
     if family not in FAMILY_PRESETS:
         raise ValueError(f"unknown family {family!r}; know {sorted(FAMILY_PRESETS)}")
+    overrides = {k: _freeze(v) if k in ("layer_types", "rope_parameters")
+                 else v for k, v in overrides.items()}
     return TransformerConfig(**{**FAMILY_PRESETS[family], **overrides})
 
 
@@ -212,6 +303,67 @@ def apply_rotary(x, positions, *, rotary_dim: int, theta: float):
     return jnp.concatenate([out.astype(x.dtype), rest], axis=-1)
 
 
+def rope_inv_freq(rotary_dim: int, rope: dict):
+    """``(inv_freq (rotary_dim // 2,), factor)`` of one ``rope_parameters``
+    section: ``default`` is ``theta ** (-2i / d)`` with factor 1; ``yarn``
+    is the static YaRN of the ``transformers`` library (``truncate`` at its
+    default): frequencies under ``low`` keep ``f_i``, over ``high`` take
+    ``f_i / s``, a linear ramp between, and cos and sin are multiplied by
+    ``attention_factor`` (``0.1 ln s + 1`` where the section gives none)."""
+    d = rotary_dim
+    theta = float(rope.get("rope_theta", 10000.0))
+    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return f.astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: know default | yarn")
+    s = float(rope["factor"])
+    L0 = float(rope["original_max_position_embeddings"])
+
+    def corr(beta):
+        return d * math.log(L0 / (2 * math.pi * beta)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(float(rope.get("beta_fast", 32.0)))), 0)
+    high = min(math.ceil(corr(float(rope.get("beta_slow", 1.0)))), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    factor = rope.get("attention_factor")
+    if factor is None:
+        factor = 0.1 * math.log(s) + 1.0
+    return (f / s * ramp + f * (1 - ramp)).astype(np.float32), float(factor)
+
+
+def layer_rope_tables(cfg: "TransformerConfig"):
+    """Per layer of a ``layer_types`` configuration: ``(inv_freq (L, rd/2),
+    factor (L,), window (L,) bool)`` as constants the scanned layer indexes
+    by the scan's counter."""
+    rd = int(cfg.rotary_pct * cfg.head_dim) // 2 * 2
+    sections = {k: dict(v) for k, v in (cfg.rope_parameters or ())}
+    rows, factors = [], []
+    for kind in cfg.layer_types:
+        inv, factor = rope_inv_freq(
+            rd, sections.get(kind, {"rope_theta": cfg.rope_theta}))
+        rows.append(inv)
+        factors.append(factor)
+    return (np.stack(rows), np.asarray(factors, np.float32),
+            np.asarray([k == "sliding_attention" for k in cfg.layer_types]))
+
+
+def apply_rotary_table(x, positions, inv_freq, factor, rotary_dim: int):
+    """:func:`apply_rotary` with the frequencies given (a layer's row of
+    :func:`layer_rope_tables`) and cos, sin scaled by ``factor``."""
+    rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    angles = jnp.concatenate([angles, angles], axis=-1)[:, :, None, :]
+    cos, sin = jnp.cos(angles) * factor, jnp.sin(angles) * factor
+    rot32 = rot.astype(jnp.float32)
+    out = rot32 * cos + _rotate_half(rot32) * sin
+    return jnp.concatenate([out.astype(x.dtype), rest], axis=-1)
+
+
 def alibi_slopes(n_head: int) -> jnp.ndarray:
     """Per-head ALiBi slopes (Press et al.), matching the reference's alibi
     computation used for bloom (csrc attention alibi path)."""
@@ -253,7 +405,9 @@ class CachedAttention(nn.Module):
         equivalent — those stay on the einsum path (forcing raises)."""
         cfg = self.config
         use = cfg.use_flash_attention
-        if use is False or use == "off":
+        if use is False or use == "off" or cfg.layer_types is not None:
+            # (a window inside the flash kernels is not written yet: layer
+            # kinds take the masked einsum in the full-context forward)
             return False
         alibi_ok = cfg.pos_emb != "alibi"
         drop_ok = cfg.dropout == 0 or deterministic
@@ -279,8 +433,8 @@ class CachedAttention(nn.Module):
         from ..ops.attention.decode_attention import pick_block_s
 
         cfg = self.config
-        if cfg.decode_kernel == "off":
-            return False
+        if cfg.decode_kernel == "off" or cfg.layer_types is not None:
+            return False    # (the dense decode kernel knows no window)
         if cfg.dropout > 0 and not deterministic:
             return False
         if pick_block_s(cache_len) < 8:
@@ -325,6 +479,8 @@ class CachedAttention(nn.Module):
         start = kv_cache["start"]
         assert jnp.ndim(start) == 1, \
             "paged decode is slot-pooled: start must be (B,)"
+        if kv_cache_groups(cfg) is not None:
+            return self._grouped_paged_step(q, k, v, kv_cache)
         table = kv_cache["table"]                  # (B, pages_per_slot)
         layer = kv_cache["layer"]
         page_size = cfg.max_seq_len // table.shape[1]
@@ -369,10 +525,56 @@ class CachedAttention(nn.Module):
                         name="o_proj")
         return o_proj(y), new_cache
 
+    def _grouped_paged_step(self, q, k, v, kv_cache):
+        """:meth:`_paged_decode_step` over a pool of layer GROUPS
+        (:func:`kv_cache_groups`): each group has its own stacked leaf
+        and table (``k`` / ``table`` for the full layers, ``k_win`` /
+        ``table_win`` for the window layers). The layer's kind is a
+        traced value inside the scan, so the step makes the write and the
+        read of EVERY group and gives the groups the layer is not in an
+        empty work list (``active``: a grid of no step, a leaf returned
+        as it came); a ``lax.cond`` over the leaves would copy the
+        branch's pass-through operands."""
+        from ..ops.attention.paged_attention import (
+            paged_decode_attention,
+            paged_write_columns,
+        )
+
+        cfg = self.config
+        B, T, H, D = q.shape
+        start, layer = kv_cache["start"], kv_cache["layer"]
+        new_cache = {key: val for key, val in kv_cache.items()
+                     if key not in ("start", "layer")
+                     and not key.startswith("table")}
+        k_cols = k.astype(cfg.dtype).transpose(0, 2, 3, 1)    # (B, KV, D, T)
+        v_cols = v.astype(cfg.dtype).transpose(0, 2, 3, 1)
+        y = None
+        for suffix, layers, window in kv_cache_groups(cfg):
+            place = np.full((cfg.n_layer,), -1, np.int32)
+            place[list(layers)] = np.arange(len(layers))
+            index = jnp.asarray(place)[layer]    # the layer within its group
+            active = index >= 0
+            table = kv_cache["table" + suffix]
+            page_size = cfg.max_seq_len // table.shape[1]
+            for key, cols in (("k", k_cols), ("v", v_cols)):
+                new_cache[key + suffix] = paged_write_columns(
+                    kv_cache[key + suffix], jnp.maximum(index, 0), cols,
+                    table, start, page_size=page_size, active=active)
+            y_g = paged_decode_attention(
+                q.astype(cfg.dtype), new_cache["k" + suffix],
+                new_cache["v" + suffix], table, start,
+                layer=jnp.maximum(index, 0), page_size=page_size,
+                window=window or None, active=active)
+            y = y_g if y is None else jnp.where(active, y_g, y)
+        y = y.astype(cfg.dtype).reshape(B, T, H * D)
+        o_proj = _dense(cfg, cfg.n_embd, use_bias=cfg.qkv_bias,
+                        name="o_proj")
+        return o_proj(y), new_cache
+
     @nn.compact
     def __call__(self, x, *, decode: Union[bool, str] = False,
                  deterministic: bool = True, kv_cache=None,
-                 block_hint=None):
+                 block_hint=None, layer=None):
         cfg = self.config
         B, T, C = x.shape
         H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -405,10 +607,22 @@ class CachedAttention(nn.Module):
             start = jnp.zeros((), jnp.int32)
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
 
+        is_window = None    # traced: this layer is a sliding-window layer
+        if cfg.layer_types is not None:
+            inv_freq, factor, windows = layer_rope_tables(cfg)
+            is_window = jnp.asarray(windows)[layer]
         if cfg.pos_emb == "rotary":
             rd = int(cfg.rotary_pct * D) // 2 * 2
-            q = apply_rotary(q, positions, rotary_dim=rd, theta=cfg.rope_theta)
-            k = apply_rotary(k, positions, rotary_dim=rd, theta=cfg.rope_theta)
+            if cfg.layer_types is not None:
+                rope = (jnp.asarray(inv_freq)[layer],
+                        jnp.asarray(factor)[layer], rd)
+                q = apply_rotary_table(q, positions, *rope)
+                k = apply_rotary_table(k, positions, *rope)
+            else:
+                q = apply_rotary(q, positions, rotary_dim=rd,
+                                 theta=cfg.rope_theta)
+                k = apply_rotary(k, positions, rotary_dim=rd,
+                                 theta=cfg.rope_theta)
 
         if decode and kv_cache is not None and "table" in kv_cache:
             # Paged decode: K/V live in the PAGE POOL ((L, P, KV,
@@ -521,6 +735,14 @@ class CachedAttention(nn.Module):
                 else:
                     mask = (jnp.arange(S)[None, :]
                             <= (start + jnp.arange(T))[:, None])
+                if is_window is not None:
+                    # a sliding layer's row at position p sees keys in
+                    # (p - sliding_window, p]
+                    qpos = (start[:, None] if per_slot else start) \
+                        + jnp.arange(T)
+                    mask = mask & jnp.logical_or(
+                        ~is_window, jnp.arange(S) > qpos[..., None]
+                        - cfg.sliding_window)
         if fresh:
             if self._use_flash(T, deterministic):
                 # fused Pallas flash attention for the full-context forward
@@ -541,6 +763,10 @@ class CachedAttention(nn.Module):
             v_all = v.transpose(0, 2, 1, 3)
             S = T
             mask = jnp.tril(jnp.ones((T, T), dtype=bool))
+            if is_window is not None:
+                mask = mask & jnp.logical_or(
+                    ~is_window, jnp.arange(T)[None, :]
+                    > jnp.arange(T)[:, None] - cfg.sliding_window)
 
         if KV != H:
             rep = H // KV
@@ -600,7 +826,7 @@ class TransformerMLP(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
         cfg = self.config
-        hidden = int(cfg.mlp_ratio * cfg.n_embd)
+        hidden = cfg.ffn_width
         if cfg.activation == "swiglu":
             # llama sizing: 2/3 * 4d rounded — callers control via mlp_ratio
             gate = _dense(cfg, hidden, use_bias=cfg.mlp_bias, name="gate_proj")(x)
@@ -622,17 +848,31 @@ class TransformerBlock(nn.Module):
     @nn.compact
     def __call__(self, x, decode: Union[bool, str] = False,
                  deterministic: bool = True, kv_cache=None,
-                 block_hint=None):
+                 block_hint=None, layer=None, experts=None):
         cfg = self.config
         a, new_cache = CachedAttention(cfg, name="attn")(
             _norm(cfg, "ln_1")(x), decode=decode, deterministic=deterministic,
-            kv_cache=kv_cache, block_hint=block_hint)
+            kv_cache=kv_cache, block_hint=block_hint, layer=layer)
+        stats = ()      # a routed FFN's counts follow (x, cache)
+
+        def mlp(h):
+            if not cfg.n_experts:
+                return TransformerMLP(cfg, name="mlp")(h, deterministic)
+            from ..moe.routed_ffn import RoutedFFN
+
+            nonlocal stats
+            m, layer_stats = RoutedFFN(
+                cfg.n_experts, cfg.experts_per_token, cfg.norm_topk_prob,
+                name="mlp")(h, experts, layer)
+            stats = (layer_stats,)
+            return m
+
         if cfg.parallel_residual:
-            m = TransformerMLP(cfg, name="mlp")(_norm(cfg, "ln_2")(x), deterministic)
-            return x + a + m, new_cache
-        x = x + a
-        m = TransformerMLP(cfg, name="mlp")(_norm(cfg, "ln_2")(x), deterministic)
-        return x + m, new_cache
+            x = x + a + mlp(_norm(cfg, "ln_2")(x))
+        else:
+            x = x + a
+            x = x + mlp(_norm(cfg, "ln_2")(x))
+        return (x, new_cache, *stats)
 
 
 class _ScanBlock(nn.Module):
@@ -659,33 +899,44 @@ class _ScanBlock(nn.Module):
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, carry, decode, deterministic, block_hint):
+    def __call__(self, carry, decode, deterministic, block_hint,
+                 experts=None):
         x, cache, start, li = carry
+        cfg = self.config
         cls = TransformerBlock
-        if self.config.remat:
+        if cfg.remat:
             cls = nn.remat(cls, prevent_cse=False,
                            static_argnums=(2, 3, 5))
-        block = cls(self.config, name="block")
+        block = cls(cfg, name="block")
+        # the scan's counter and the model's expert leaves reach a block
+        # only where its configuration reads them
+        more = (li, experts) if (cfg.layer_types is not None
+                                 or cfg.n_experts) else ()
+        # a layer's output beside the carry: what its routed FFN counted
+        # (stacked over the layers by the scan), nothing for a dense FFN
         if cache is None:
-            x, _ = block(x, decode, deterministic, None, block_hint)
-            return (x, None, start, li), None
+            x, _, *stats = block(x, decode, deterministic, None, block_hint,
+                                 *more)
+            return (x, None, start, li + 1 if more else li), tuple(stats)
         if "table" in cache:
-            # "table" is the POOL-WIDE page table (slots, pages_per_slot),
-            # shared by every layer and never written
-            x, leaves = block(x, decode, deterministic,
-                              dict(cache, start=start, layer=li),
-                              block_hint)
-            return (x, dict(leaves, table=cache["table"]), start,
-                    li + 1), None
+            # the "table*" entries are the POOL-WIDE page tables (slots,
+            # pages_per_slot), one a layer group, shared by the group's
+            # layers and never written
+            tables = {key: val for key, val in cache.items()
+                      if key.startswith("table")}
+            x, leaves, *stats = block(x, decode, deterministic,
+                                      dict(cache, start=start, layer=li),
+                                      block_hint, *more)
+            return (x, dict(leaves, **tables), start, li + 1), tuple(stats)
         kv_slice = {key: jax.lax.dynamic_index_in_dim(val, li, 0,
                                                       keepdims=False)
                     for key, val in cache.items()}
         kv_slice["start"] = start
-        x, new_slice = block(x, decode, deterministic, kv_slice, block_hint)
+        x, new_slice, *stats = block(x, decode, deterministic, kv_slice,
+                                     block_hint, *more)
         cache = {key: jax.lax.dynamic_update_slice_in_dim(
-                     val, new_slice[key][None], li, 0)
-                 for key, val in cache.items()}
-        return (x, cache, start, li + 1), None
+            val, new_slice[key][None], li, 0) for key, val in cache.items()}
+        return (x, cache, start, li + 1), tuple(stats)
 
 
 _PACK_DISABLED_WARNED: set = set()
@@ -720,6 +971,23 @@ def kv_cache_spec(cfg: TransformerConfig):
     if cfg.kv_cache_quant:
         return jnp.int8, D, False
     return cfg.dtype, D, False
+
+
+def kv_cache_groups(cfg: TransformerConfig):
+    """The layer groups of the cache, or None for the single group every
+    model had before ``layer_types``: ``((suffix, layers, window), ...)``,
+    ``""`` the full-attention layers (every position kept) and ``"_win"``
+    the sliding layers (the last ``window`` positions visible). A page
+    pool keeps one stacked leaf (``k<suffix>``, ``v<suffix>``) and one
+    page table (``table<suffix>``) a group."""
+    if cfg.layer_types is None \
+            or "sliding_attention" not in cfg.layer_types:
+        return None
+    by_kind = {kind: tuple(i for i, k in enumerate(cfg.layer_types)
+                           if k == kind)
+               for kind in ("full_attention", "sliding_attention")}
+    return (("", by_kind["full_attention"], 0),
+            ("_win", by_kind["sliding_attention"], int(cfg.sliding_window)))
 
 
 def page_lanes(page_size: int) -> int:
@@ -759,6 +1027,9 @@ class KVCacheSpec:
     max_seq_len: int
     quantized: bool
     packed: bool
+    groups: Optional[tuple] = None     # kv_cache_groups(cfg): the layers
+    # of each group of a page pool; the contiguous containers below keep
+    # every layer at full length (a window layer's old columns are masked)
 
     def layer_cache(self, batch_size: int) -> dict:
         """Zeroed single-layer k/v dict: (B, KV, cache_d, S) [+ scales]."""
@@ -789,7 +1060,8 @@ class KVCacheSpec:
         return cache
 
     # -- paged KV (PagedAttention-style block pool) --------------------
-    def paged_cache(self, num_pages: int, page_size: int) -> dict:
+    def paged_cache(self, num_pages: int, page_size: int,
+                    window_pages: Optional[int] = None) -> dict:
         """Zeroed PAGE-POOL k/v arrays: the positions axis is split into
         ``num_pages`` physical pages of ``page_size`` columns each, with
         NO batch axis — k/v (L, P, KV, cache_d, lanes) [+ scales
@@ -801,6 +1073,15 @@ class KVCacheSpec:
         dtype/packing tiers as the contiguous container (int8/packed
         cache columns page exactly like full-precision ones)."""
         lanes = page_lanes(page_size)
+        if self.groups is not None:
+            # one stacked leaf a group: ``num_pages`` pages for the full
+            # layers, ``window_pages`` for the window layers
+            return {key + suffix: jnp.zeros(
+                        (len(layers), pages, self.kv_heads, self.cache_d,
+                         lanes), self.dtype)
+                    for (suffix, layers, _), pages in zip(
+                        self.groups, (num_pages, window_pages))
+                    for key in ("k", "v")}
         shape = (self.n_layer, num_pages, self.kv_heads, self.cache_d,
                  lanes)
         cache = {"k": jnp.zeros(shape, self.dtype),
@@ -823,7 +1104,25 @@ class KVCacheSpec:
         always covers its live ``[0, index)`` columns and attention
         masks everything beyond (the same alive-masking that makes dead
         slots free). ``table`` rows must span exactly
-        ``max_seq_len // page_size`` pages."""
+        ``max_seq_len // page_size`` pages.
+
+        With layer ``groups``, ``paged`` holds a leaf and ``table`` (a
+        dict) a table a group; each group is gathered through its own
+        table and the layers come back in model order, so the dense
+        programs see the one ``(L, ...)`` stack they always saw. A window
+        group's recycled entries are sentinels like any other: what they
+        read is behind the window mask."""
+        if self.groups is not None:
+            single = dataclasses.replace(self, groups=None)
+            parts = [single.dense_from_pages(
+                        {key: paged[key + suffix] for key in ("k", "v")},
+                        table["table" + suffix])
+                     for suffix, _, _ in self.groups]
+            order = np.argsort(np.concatenate(
+                [np.asarray(layers, np.int64)
+                 for _, layers, _ in self.groups]))
+            return {key: jnp.concatenate([p[key] for p in parts])[order]
+                    for key in ("k", "v")}
         B, max_pages = table.shape
         ps = self.max_seq_len // max_pages
         flat = table.reshape(-1)
@@ -851,7 +1150,8 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
     return KVCacheSpec(n_layer=cfg.n_layer, kv_heads=cfg.kv_heads,
                        head_dim=cfg.head_dim, cache_d=cache_d,
                        dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
-                       quantized=cfg.kv_cache_quant, packed=packed)
+                       quantized=cfg.kv_cache_quant, packed=packed,
+                       groups=kv_cache_groups(cfg))
 
 
 def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:
@@ -878,7 +1178,8 @@ class _CacheStore(nn.Module):
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, batch_size, new_values=None, new_index=None):
+    def __call__(self, batch_size, new_values=None, new_index=None,
+                 paged=False):
         cfg = self.config
         L, KV = cfg.n_layer, cfg.kv_heads
         cache_dtype, cache_d, _ = kv_cache_spec(cfg)
@@ -886,6 +1187,15 @@ class _CacheStore(nn.Module):
         ck = self.variable("cache", "k", jnp.zeros, shape, cache_dtype)
         cv = self.variable("cache", "v", jnp.zeros, shape, cache_dtype)
         values = {"k": ck.value, "v": cv.value}
+        if paged and kv_cache_groups(cfg) is not None:
+            # a page pool of layer groups hands in a second pair of
+            # leaves (always provided: the initializer never runs)
+            for key in ("k_win", "v_win"):
+                var = self.variable("cache", key, jnp.zeros, (0,),
+                                    cache_dtype)
+                values[key] = var.value
+                if new_values is not None:
+                    var.value = new_values[key]
         if cfg.kv_cache_quant:
             sshape = (L, batch_size, KV, cfg.max_seq_len)
             cks = self.variable("cache", "k_scale", jnp.zeros, sshape,
@@ -934,9 +1244,15 @@ class TransformerLM(nn.Module):
             variable_axes={"params": 0},
             split_rngs={"params": True, "dropout": True},
             length=cfg.n_layer,
-            in_axes=(nn.broadcast, nn.broadcast, nn.broadcast),
+            in_axes=(nn.broadcast,) * (4 if cfg.n_experts else 3),
             metadata_params={nn.PARTITION_NAME: "layers"},
         )(cfg, name="blocks")
+        if cfg.n_experts:
+            from ..moe.routed_ffn import ExpertLeaves
+
+            self.experts = ExpertLeaves(cfg.n_layer, cfg.n_experts,
+                                        cfg.n_embd, cfg.ffn_width,
+                                        name="experts")
         self.cache_store = _CacheStore(cfg, name="cache_store")
         self.ln_f = _norm(cfg, "ln_f")
         if not cfg.tie_word_embeddings:
@@ -954,27 +1270,42 @@ class TransformerLM(nn.Module):
             x = x + self.embed_pos(positions)
         if cfg.embed_layernorm:
             x = self.embed_ln(x)
+        # the stacked expert leaves go to every layer whole, as a
+        # broadcast argument of the scan (cast once, outside it)
+        more = (jax.tree_util.tree_map(lambda w: w.astype(cfg.dtype),
+                                       self.experts()),) \
+            if cfg.n_experts else ()
         if decode:
-            cache, start = self.cache_store(B)
-            if paged_table is not None:
+            paged = paged_table is not None
+            cache, start = self.cache_store(B, paged=paged)
+            if paged:
                 # paged-kernel decode: the cache_store variables hold the
                 # PAGE POOL (L, P, KV, cd, page_size) — provided-cache
-                # shapes pass through — and the shared page table joins
-                # the carry so every layer resolves positions through it
-                # (stripped before writeback; see _ScanBlock)
-                cache = dict(cache, table=paged_table)
+                # shapes pass through — and the shared page table (one a
+                # layer group, as a dict) joins the carry so every layer
+                # resolves positions through it (stripped before
+                # writeback; see _ScanBlock)
+                cache = dict(cache, **(paged_table if isinstance(
+                    paged_table, dict) else {"table": paged_table}))
             carry = (x, cache, start, jnp.zeros((), jnp.int32))
-            (x, cache, _, _), _ = self.blocks(carry, decode, deterministic,
-                                              block_hint)
-            if paged_table is not None:
-                cache = {key: val for key, val in cache.items()
-                         if key != "table"}
-            self.cache_store(B, new_values=cache, new_index=start + T)
+            (x, cache, _, _), stats = self.blocks(
+                carry, decode, deterministic, block_hint, *more)
+            if stats and self.is_mutable_collection("stats"):
+                # a caller that asks for the "stats" collection gets what
+                # the routed FFN counted in this call, over its layers
+                from ..moe.routed_ffn import call_stats
+
+                self.sow("stats", "moe", call_stats(stats[0], cfg.n_experts),
+                         init_fn=lambda: None, reduce_fn=lambda _, new: new)
+            cache = {key: val for key, val in cache.items()
+                     if not key.startswith("table")}
+            self.cache_store(B, new_values=cache, new_index=start + T,
+                             paged=paged)
         else:
             carry = (x, None, jnp.zeros((), jnp.int32),
                      jnp.zeros((), jnp.int32))
             (x, _, _, _), _ = self.blocks(carry, decode, deterministic,
-                                          block_hint)
+                                          block_hint, *more)
         x = self.ln_f(x)
         if not head:
             return x  # pre-projection hidden states (streaming loss path)

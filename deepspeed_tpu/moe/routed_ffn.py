@@ -1,0 +1,261 @@
+"""Routed feed-forward that drops no token: the sparse FFN of a *served*
+``TransformerLM`` (``TransformerConfig.n_experts > 0``).
+
+Beside the capacity layer (``sharded_moe.py``: the reference's training
+gate, ``k`` in {1, 2}, a capacity that drops what overflows) this is the
+form today's sparse decoders publish: a float32 softmax router, the ``k``
+largest of ``E`` experts a token (``k`` = 8 of 64 for the configuration the
+benchmark serves), optionally renormalised over the chosen
+(``norm_topk_prob``), every chosen expert computed, nothing dropped::
+
+    p = softmax(h @ Wr)            float32
+    S = top_k(p, k);  w_e = p_e / sum_{e' in S} p_e'      (or p_e)
+    y = sum_{e in S} w_e * down_e( silu(gate_e h) * up_e h )
+
+**Rows grouped by expert.** The ``N * k`` (token, expert) assignments are
+sorted by expert and laid out in tiles of :data:`ROW_TILE` rows, each
+group padded to whole tiles, so a tile belongs to ONE expert
+(:func:`group_rows`). The bound on tiles is static (``ceil(N k / tile) +
+E``); how many run is known on the device only and sizes the grid.
+
+**Expert products are Pallas calls that read the stacked leaf in place.**
+``moe_gate_up`` and ``moe_down`` (``pallas_call(name=...)``; the v5e trace
+carries no operation metadata, so the name is what a trace can attribute)
+take the expert leaves WHOLE, ``(layers, E, C, F)`` and ``(layers, E, F,
+C)``, and find a tile's block by ``(layer, expert)`` on scalar prefetch:
+no slice of a leaf is made (one layer's experts are 0.79 GB at the served
+size; XLA cannot fuse a dynamic-slice into a custom call, so a sliced
+layer is a copy of it), and an expert no row chose is never read. Tiles of
+one expert follow each other, so its block is fetched once. The blocks
+hold a whole ``(C, F)`` matrix (4.1 MB at 2304 x 896 bf16, two matrices,
+double-buffered: 16.5 MB), which is why the calls raise
+``vmem_limit_bytes`` over the 16 MiB default.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..ops import backend
+
+__all__ = ["route", "group_rows", "routed_ffn", "routed_ffn_reference",
+           "RowGroups", "ROW_TILE", "RoutedFFN", "ExpertLeaves"]
+
+# rows of one grid step: one bf16 sublane tile. A tile is bound by its
+# expert's weights (an MXU pass costs the same for 16 rows as for 128), so
+# a larger tile would only pad more
+ROW_TILE = 16
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+
+def route(h: jax.Array, router: jax.Array, k: int, norm_topk_prob: bool
+          ) -> Tuple[jax.Array, jax.Array]:
+    """``(weights (N, k) float32, experts (N, k) int32)`` of ``h`` (N, C)
+    under the router matrix (C, E): softmax in float32, the ``k`` largest,
+    renormalised over the chosen when ``norm_topk_prob``."""
+    logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    w, e = jax.lax.top_k(p, k)
+    if norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, e.astype(jnp.int32)
+
+
+class RowGroups(NamedTuple):
+    """Where each assignment stands once rows are grouped by expert."""
+
+    row_of: jax.Array       # (N, k) row of the tiled layout of each assignment
+    token_of: jax.Array     # (rows,) token a row holds (padding rows: 0)
+    tile_expert: jax.Array  # (tiles,) expert of each tile
+    num_tiles: jax.Array    # () tiles that hold a row
+    counts: jax.Array       # (E,) assignments an expert
+
+
+def group_rows(experts: jax.Array, num_experts: int, tile: int = ROW_TILE
+               ) -> RowGroups:
+    """Lay the ``N * k`` assignments out expert by expert, each expert's
+    rows padded to whole tiles of ``tile``. ``ceil(N k / tile) + E`` tiles
+    bound the layout whatever the router does (one expert taking every
+    token fills ``N k / tile`` of them; every expert taking one row, ``E``)."""
+    N, k = experts.shape
+    A = N * k
+    flat = experts.reshape(A)
+    tiles = -(-A // tile) + num_experts
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    counts = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    padded = -(-counts // tile) * tile
+    ends_p = jnp.cumsum(padded)
+    begin, begin_p = jnp.cumsum(counts) - counts, ends_p - padded
+    sorted_e = flat[order]
+    rank = jnp.arange(A, dtype=jnp.int32)
+    row_sorted = begin_p[sorted_e] + rank - begin[sorted_e]
+    row_of = jnp.zeros((A,), jnp.int32).at[order].set(row_sorted)
+    token_of = jnp.zeros((tiles * tile,), jnp.int32).at[row_sorted].set(
+        order // k)
+    first_row = jnp.arange(tiles, dtype=jnp.int32) * tile
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends_p, first_row, side="right"),
+        num_experts - 1).astype(jnp.int32)
+    return RowGroups(row_of.reshape(N, k), token_of, tile_expert,
+                     (ends_p[-1] // tile).astype(jnp.int32), counts)
+
+
+def _gate_up_kernel(layer_ref, expert_ref, x_ref, gate_ref, up_ref, h_ref):
+    x = x_ref[...]
+    g = jnp.dot(x, gate_ref[0, 0], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, up_ref[0, 0], preferred_element_type=jnp.float32)
+    h_ref[...] = (jax.nn.silu(g) * u).astype(h_ref.dtype)
+
+
+def _down_kernel(layer_ref, expert_ref, h_ref, down_ref, y_ref):
+    y_ref[...] = jnp.dot(h_ref[...], down_ref[0, 0],
+                         preferred_element_type=jnp.float32
+                         ).astype(y_ref.dtype)
+
+
+def _expert_call(kernel, name: str, rows: jax.Array, leaves, layer,
+                 groups: RowGroups, out_width: int, out_dtype, tile: int):
+    """One product over the tiled rows: tile ``w`` of ``rows`` against
+    block ``(layer, tile_expert[w])`` of each stacked leaf."""
+
+    def row_index(w, layer_ref, expert_ref):
+        return (w, 0)
+
+    def leaf_index(w, layer_ref, expert_ref):
+        return (layer_ref[0], expert_ref[w], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(groups.num_tiles,),
+        in_specs=[pl.BlockSpec((tile, rows.shape[1]), row_index)]
+        + [pl.BlockSpec((1, 1) + leaf.shape[2:], leaf_index)
+           for leaf in leaves],
+        out_specs=pl.BlockSpec((tile, out_width), row_index),
+    )
+    return pl.pallas_call(
+        kernel, name=name, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows.shape[0], out_width),
+                                       out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=backend.pallas_interpret(),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), groups.tile_expert, rows,
+      *leaves)
+
+
+def routed_ffn(h: jax.Array, router: jax.Array, gate: jax.Array,
+               up: jax.Array, down: jax.Array, layer, *, k: int,
+               norm_topk_prob: bool, tile: int = ROW_TILE):
+    """``(y (N, C), stats (3,) int32)``: the routed FFN of ``h`` (N, C)
+    with layer ``layer`` (traced) of the stacked expert leaves ``gate`` /
+    ``up`` (L, E, C, F) and ``down`` (L, E, F, C). ``stats`` =
+    (assignments, experts that a row chose, rows of the fullest expert)."""
+    E = gate.shape[1]
+    w, experts = route(h, router, k, norm_topk_prob)
+    groups = group_rows(experts, E, tile)
+    dtype = gate.dtype
+    x = h.astype(dtype)[groups.token_of]                    # (rows, C)
+    act = _expert_call(_gate_up_kernel, "moe_gate_up", x, (gate, up), layer,
+                       groups, gate.shape[3], dtype, tile)
+    y = _expert_call(_down_kernel, "moe_down", act, (down,), layer, groups,
+                     down.shape[3], jnp.float32, tile)
+    # each token gathers the rows of its k choices: no scatter, one order
+    y = jnp.sum(y[groups.row_of] * w[..., None], axis=1)
+    stats = jnp.stack([jnp.asarray(experts.size, jnp.int32),
+                       jnp.sum(groups.counts > 0, dtype=jnp.int32),
+                       jnp.max(groups.counts)])
+    return y.astype(h.dtype), stats
+
+
+#: what :func:`call_stats` holds, in order
+CALL_STATS = ("assignments", "experts_touched", "layer_calls", "load_max",
+              "load_max_over_mean")
+
+
+def call_stats(layer_stats: jax.Array, n_experts: int) -> jax.Array:
+    """One model call's counts from its layers' ``stats`` (L, 3), as
+    float32 in the order of :data:`CALL_STATS`: assignments and experts
+    touched summed over the layers, the layers, the rows of the fullest
+    expert of any layer, and those rows over a layer's mean rows an
+    expert (1 is perfectly even)."""
+    assignments, touched = jnp.sum(layer_stats[:, :2], axis=0)
+    load_max = jnp.max(layer_stats[:, 2])
+    calls = layer_stats.shape[0]
+    mean = jnp.maximum(assignments, 1) / (calls * n_experts)
+    return jnp.stack([assignments, touched, calls, load_max,
+                      load_max / mean]).astype(jnp.float32)
+
+
+def routed_ffn_reference(h, router, gate, up, down, *, k: int,
+                         norm_topk_prob: bool):
+    """The same layer as a plain sum over experts (every expert computed
+    for every token, the unchosen weighted 0): what the tests hold the
+    kernels to. ``gate`` / ``up`` (E, C, F), ``down`` (E, F, C)."""
+    w, experts = route(h, router, k, norm_topk_prob)
+    E = gate.shape[0]
+    dense_w = jnp.zeros((h.shape[0], E), jnp.float32).at[
+        jnp.arange(h.shape[0])[:, None], experts].add(w)
+    hp = functools.partial(jnp.einsum,
+                           precision=jax.lax.Precision.HIGHEST)
+    h32 = h.astype(jnp.float32)
+    act = jax.nn.silu(hp("nc,ecf->enf", h32, gate.astype(jnp.float32))) \
+        * hp("nc,ecf->enf", h32, up.astype(jnp.float32))
+    y = hp("enf,efc->enc", act, down.astype(jnp.float32))
+    return jnp.einsum("enc,ne->nc", y, dense_w).astype(h.dtype)
+
+
+def _expert_init(key, shape, dtype=jnp.float32):
+    """LeCun normal over the contracted dimension (the last but one), as
+    ``nn.Dense`` draws a kernel; layers and experts are batch dimensions."""
+    return jax.nn.initializers.lecun_normal(
+        in_axis=-2, out_axis=-1, batch_axis=(0, 1))(key, shape, dtype)
+
+
+class ExpertLeaves(nn.Module):
+    """The stacked expert weights of a model, ``gate_proj`` / ``up_proj``
+    (layers, E, C, F) and ``down_proj`` (layers, E, F, C): parameters of
+    the MODEL, beside the scanned blocks, because a parameter of the
+    scanned block reaches a layer as a slice of its stack, and a slice
+    handed to a custom call is a copy."""
+
+    n_layer: int
+    n_experts: int
+    n_embd: int
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        L, E, C, F = self.n_layer, self.n_experts, self.n_embd, self.width
+        return {"gate_proj": self.param("gate_proj", _expert_init,
+                                        (L, E, C, F)),
+                "up_proj": self.param("up_proj", _expert_init, (L, E, C, F)),
+                "down_proj": self.param("down_proj", _expert_init,
+                                        (L, E, F, C))}
+
+
+class RoutedFFN(nn.Module):
+    """One layer's routed FFN inside the block: owns the router matrix
+    (C, E), takes the model's expert leaves and the layer's index."""
+
+    n_experts: int
+    experts_per_token: int
+    norm_topk_prob: bool
+
+    @nn.compact
+    def __call__(self, x, experts, layer):
+        B, T, C = x.shape
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (C, self.n_experts))
+        y, stats = routed_ffn(
+            x.reshape(B * T, C), router, experts["gate_proj"],
+            experts["up_proj"], experts["down_proj"], layer,
+            k=self.experts_per_token, norm_topk_prob=self.norm_topk_prob)
+        return y.reshape(B, T, C), stats

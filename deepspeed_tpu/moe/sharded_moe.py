@@ -204,7 +204,11 @@ def gate_decisions(logits: jnp.ndarray, k: int = 1,
     if k == 2:
         return top2_decisions(logits, capacity_factor, min_capacity,
                               drop_tokens, rng)
-    raise ValueError(f"top-{k} gating unsupported (reference supports k=1,2)")
+    raise ValueError(
+        f"top-{k} gating unsupported by the capacity gate (the "
+        f"reference's training layer: k = 1, 2); top-k routing that "
+        f"drops no token is deepspeed_tpu/moe/routed_ffn.py "
+        f"(TransformerConfig.n_experts)")
 
 
 def _densify(dec: GateDecisions, num_experts: int, dtype
@@ -318,7 +322,11 @@ def gate_and_dispatch(tokens: jnp.ndarray, gate_logits: jnp.ndarray, k: int = 1,
         aux, combine, dispatch, _ = top2gating(
             gate_logits, capacity_factor, min_capacity, drop_tokens, rng)
     else:
-        raise ValueError(f"top-{k} gating unsupported (reference supports k=1,2)")
+        raise ValueError(
+            f"top-{k} gating unsupported by the capacity gate (the "
+            f"reference's training layer: k = 1, 2); top-k routing that "
+            f"drops no token is deepspeed_tpu/moe/routed_ffn.py "
+            f"(TransformerConfig.n_experts)")
     dispatched = jnp.einsum("sec,sm->ecm", dispatch.astype(tokens.dtype), tokens)
     return aux, dispatched, combine
 

@@ -47,10 +47,12 @@ on static shapes alone: the live lengths ride scalar prefetch.
 of one layer is contiguous over its heads, so all of them arrive in one
 DMA (16 heads of 128 × 64 bf16: 256 KB, 512 KB in VMEM because a 64-wide
 page fills half of each 128-lane tile — in HBM too). The query and output
-blocks carry the ``kv_group * rep`` heads of the slot, the scratch
-(``acc``, ``m``, ``l``) has a head axis, and the per-head fold runs inside
-the step (a static loop; GQA's ``rep`` query heads of a KV head share its
-page). :func:`plan_grid` picks ``kv_group`` from the shapes against a fixed
+blocks carry the ``kv_group`` KV heads of the slot with the rows of each
+one's ``rep`` query heads stacked (``rep * 8`` rows: GQA's query heads of
+a KV head share its page, so they are one operand of one product), the
+scratch (``acc``, ``m``, ``l``) has the same KV-head axis, and the fold
+runs once a KV head inside the step (a static loop; with ``rep`` 1 a KV
+head is a query head). :func:`plan_grid` picks ``kv_group`` from the shapes against a fixed
 VMEM budget (:data:`VMEM_BUDGET_BYTES`, half the v5e's default scoped
 limit): every KV head when one page of each fits, else the largest divisor
 of ``KV`` that does, and the head-group axis comes back into the grid.
@@ -195,8 +197,10 @@ def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
 
 
 def live_pages(starts: jax.Array, table: jax.Array, num_rows: int,
-               page_size: int, num_pages: int):
-    """The call's work list: ``(slot_of, entry_of, page_of, live, total)``.
+               page_size: int, num_pages: int,
+               window: Optional[int] = None):
+    """The call's work list: ``(slot_of, entry_of, page_of, live, total)``
+    and, with a ``window``, each slot's first entry after ``live``.
 
     Step ``w < total`` folds table entry ``entry_of[w]`` of slot
     ``slot_of[w]``, physical page ``page_of[w]``, slot by slot in table
@@ -208,8 +212,17 @@ def live_pages(starts: jax.Array, table: jax.Array, num_rows: int,
     at least one, so that every output block is written (an empty slot
     is one masked step on a clipped page). The lists are as long as the
     table (``B * pages_per_slot``); entries from ``total`` on are in
-    range and never run."""
+    range and never run.
+
+    With a ``window`` (a group of sliding layers: query ``p`` sees keys in
+    ``(p - window, p]``) a slot's steps begin at the entry that holds
+    ``start - window + 1``, the oldest key its first row sees, and a row's
+    MAPPED entries are those up to its last mapped one: what lies before
+    the window has been recycled and is a sentinel in the table."""
     B, pages_per_slot = table.shape
+    if window is not None:
+        return _window_pages(starts, table, num_rows, page_size, num_pages,
+                             window)
     # index of the row's first sentinel, pages_per_slot if it has none
     mapped = jnp.argmin(jnp.pad(table < num_pages, ((0, 0), (0, 1))),
                         axis=1).astype(jnp.int32)
@@ -227,10 +240,34 @@ def live_pages(starts: jax.Array, table: jax.Array, num_rows: int,
     return slot_of, entry_of, page_of, live, ends[-1]
 
 
+def _window_pages(starts, table, num_rows: int, page_size: int,
+                  num_pages: int, window: int):
+    """:func:`live_pages` for a group of sliding layers."""
+    B, pages_per_slot = table.shape
+    entries = jnp.arange(pages_per_slot, dtype=jnp.int32)
+    # one past the row's last mapped entry, 0 where it maps nothing
+    mapped = jnp.max(jnp.where(table < num_pages, entries + 1, 0), axis=1)
+    first = jnp.clip((starts - window + 1) // page_size, 0,
+                     pages_per_slot - 1).astype(jnp.int32)
+    last = jnp.minimum((starts + num_rows + page_size - 1) // page_size,
+                       mapped)
+    live = jnp.clip(last - first, 1, pages_per_slot)
+    ends = jnp.cumsum(live)
+    w = jnp.arange(B * pages_per_slot, dtype=jnp.int32)
+    slot_of = jnp.minimum(
+        jnp.searchsorted(ends, w, side="right", method="compare_all"),
+        B - 1).astype(jnp.int32)
+    entry_of = jnp.clip(first[slot_of] + w - (ends - live)[slot_of], 0,
+                        pages_per_slot - 1)
+    page_of = jnp.minimum(table[slot_of, entry_of], num_pages - 1)
+    return slot_of, entry_of, page_of, live, ends[-1], first
+
+
 def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
-                  slope_ref, layer_ref, q_ref, k_ref, v_ref, *refs,
+                  slope_ref, layer_ref, *refs,
                   page_size: int, scale: float, rep: int, alibi: bool,
-                  quantized: bool, packed: bool, compute_dtype):
+                  quantized: bool, packed: bool, compute_dtype,
+                  window: Optional[int] = None):
     # the first seven are scalar-prefetch SMEM arrays: the work list of
     # live_pages, (B,) starts, (H,) slopes and the (1,) layer of the
     # stacked leaf (page_ref and layer_ref are read by the index maps
@@ -240,30 +277,46 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
     # decode_attention._decode_kernel line for line (the bitwise-parity
     # contract in the module docstring); the differences are where K/V
     # blocks come from and that each query row carries its own causal
-    # limit (row t sees cache positions <= start + t).
+    # limit (row t sees cache positions <= start + t). A group of
+    # sliding layers brings an eighth list, each slot's first entry, and
+    # masks what lies ``window`` or more behind a row.
+    first_ref = None
+    if window is not None:
+        first_ref, *refs = refs
+    q_ref, k_ref, v_ref, *refs = refs
     if quantized:
         k_scale_ref, v_scale_ref, *refs = refs
     o_ref, acc_ref, m_ref, l_ref = refs
     g, w = pl.program_id(0), pl.program_id(1)
     kv_group = k_ref.shape[2]
-    heads = kv_group * rep
+    rows = rep * SUBLANES
     entry = entry_ref[w]
     slot = slot_ref[w]
     start = start_ref[slot]
     block_start = entry * page_size
 
-    @pl.when(entry == 0)
+    first = 0 if first_ref is None else first_ref[slot]
+
+    @pl.when(entry == first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
+    # GQA: the rep query heads of a KV head share its page, so their
+    # SUBLANES rows each stand in ONE (rep * SUBLANES, D) operand and the
+    # fold runs once a KV head (a fold a query head, 8 rows each, was 32
+    # small products a step at 32 / 4 heads and cost a decode token twice
+    # a prefill token: PERF.md section 6, PR 30). Row r is query row
+    # r % SUBLANES of head r // SUBLANES; with rep == 1 this is the fold
+    # it always was.
     pos = block_start + jax.lax.broadcasted_iota(
-        jnp.int32, (SUBLANES, page_size), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, page_size), 0)
-    for h in range(heads):
-        c = h // rep                      # GQA: rep query heads a KV head
-        q = q_ref[0, h]                                   # (SUBLANES, D)
+        jnp.int32, (rows, page_size), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
+    if rep > 1:
+        head_of, row = row // SUBLANES, row % SUBLANES
+    for c in range(kv_group):
+        q = q_ref[0, c]                                   # (rows, D)
         k = k_ref[0, 0, c][:, :page_size]                 # (Dc, page_size)
         v = v_ref[0, 0, c][:, :page_size]
         if quantized:
@@ -282,26 +335,37 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
             # row t's own offset: slope * (pos - (start + t)). Row 0
             # subtracts an exact zero, which keeps a T=1 call bitwise
             # equal to the dense kernel's scalar expression
-            slope = slope_ref[g * heads + h]
+            slope = slope_ref[(g * kv_group + c) * rep]
+            for j in range(1, rep):
+                slope = jnp.where(
+                    head_of == j, slope_ref[(g * kv_group + c) * rep + j],
+                    slope)
             s = s + slope * (pos - start).astype(jnp.float32) \
                 - slope * row.astype(jnp.float32)
-        s = jnp.where(pos <= start + row, s, NEG_INF)
-        m_prev = m_ref[h, :, :1]
-        l_prev = l_ref[h, :, :1]
+        seen = pos <= start + row
+        if window is not None:
+            seen = jnp.logical_and(seen, pos > start + row - window)
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_ref[c, :, :1]
+        l_prev = l_ref[c, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[h] = jnp.broadcast_to(
+        l_ref[c] = jnp.broadcast_to(
             alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
             l_ref.shape[1:])
         if quantized:
             p = p * v_scale_ref[0, 0, c][:, :page_size]    # (1, page)
-        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+        acc_ref[c] = acc_ref[c] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        m_ref[c] = jnp.broadcast_to(m_new, m_ref.shape[1:])
 
-    @pl.when(entry == live_ref[slot] - 1)
+    last = live_ref[slot] - 1
+    if first_ref is not None:
+        last = first + last
+
+    @pl.when(entry == last)
     def _finish():
         l = jnp.maximum(l_ref[:, :, :1], 1e-30)
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -314,8 +378,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            scale: Optional[float] = None,
                            alibi_slopes: Optional[jax.Array] = None,
                            k_scale_pages: Optional[jax.Array] = None,
-                           v_scale_pages: Optional[jax.Array] = None
-                           ) -> jax.Array:
+                           v_scale_pages: Optional[jax.Array] = None,
+                           window: Optional[int] = None,
+                           active=None) -> jax.Array:
     """Cached attention over paged K/V: softmax(q·K^T + bias) · V with
     K/V resolved through a per-slot page table inside the kernel.
 
@@ -343,6 +408,12 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
       alibi_slopes: optional (H,) ALiBi slopes.
       k_scale_pages/v_scale_pages: (L, P, KV, lanes) fp32 per-column
         dequantization scales for a quantized page pool.
+      window: the pool is a group of sliding layers' (static): row ``t``
+        sees positions ``(starts[b] + t - window, starts[b] + t]`` only,
+        and the slot's steps begin at the page that holds the oldest of
+        them (``table`` holds sentinels before it).
+      active: traced bool; False runs a grid of NO step (the layer is not
+        of this pool's group) and the result is not to be read.
     Returns (B, T, H, D) in q's dtype.
     """
     starts = jnp.broadcast_to(jnp.asarray(starts, jnp.int32),
@@ -355,8 +426,11 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     layer = jnp.zeros((1,), jnp.int32) if layer is None \
         else jnp.asarray(layer, jnp.int32).reshape(1)
     kernel = functools.partial(
-        _paged_decode_attention_local, scale=scale,
+        _paged_decode_attention_local, scale=scale, window=window,
         page_size=k_pages.shape[-1] if page_size is None else page_size)
+    if active is not None:
+        kernel = functools.partial(kernel,
+                                   active=jnp.asarray(active, jnp.bool_))
     B, H = backend.BATCH, backend.HEADS
     # the page pool has no batch dim: every device holds every page of
     # its KV heads, and its slots' rows of the table
@@ -373,7 +447,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
 
 def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
                                   *, scale, page_size, alibi_slopes,
-                                  k_scale_pages, v_scale_pages):
+                                  k_scale_pages, v_scale_pages, window=None,
+                                  active=None):
     """:func:`paged_decode_attention` on the slots and heads one device
     holds."""
     B, T, H, D = q.shape
@@ -417,18 +492,23 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
     if T < SUBLANES:
         q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, SUBLANES - T), (0, 0)))
 
+    # a KV head's rep query heads, SUBLANES rows each, as one operand
+    rows = rep * SUBLANES
+    q4 = q4.reshape(B, KV, rows, D)
+
     kv_group, _, (groups, _) = plan_grid(
         B, H, KV, D, Dc, lanes, maxP, k_pages.dtype, q.dtype, quantized)
-    heads = kv_group * rep
-    slot_of, entry_of, page_of, live, total = live_pages(
-        starts, table, T, ps, P)
+    slot_of, entry_of, page_of, live, total, *first = live_pages(
+        starts, table, T, ps, P, window)
+    if active is not None:
+        total = jnp.where(active, total, 0)
 
     head_block = pl.BlockSpec(
-        (1, heads, SUBLANES, D),
+        (1, kv_group, rows, D),
         lambda g, w, slot_ref, *_: (slot_ref[w], g, 0, 0))
 
     def page_index(g, w, slot_ref, entry_ref, page_ref, live_ref,
-                   start_ref, slope_ref, layer_ref):
+                   start_ref, slope_ref, layer_ref, *_):
         return (layer_ref[0], page_ref[w], g, 0, 0)
 
     pools = [k_pages, v_pages]
@@ -442,28 +522,29 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
             v_scale_pages.astype(jnp.float32).reshape(L, P, KV, 1, lanes)]
         blocks += [(1, 1, kv_group, 1, lanes)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=7 + len(first),
         grid=(groups, total),
         in_specs=[head_block] + [pl.BlockSpec(block, page_index)
                                  for block in blocks],
         out_specs=head_block,
         scratch_shapes=[
-            pltpu.VMEM((heads, SUBLANES, D), jnp.float32),
-            pltpu.VMEM((heads, SUBLANES, LANES), jnp.float32),
-            pltpu.VMEM((heads, SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM((kv_group, rows, D), jnp.float32),
+            pltpu.VMEM((kv_group, rows, LANES), jnp.float32),
+            pltpu.VMEM((kv_group, rows, LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page_size=ps, scale=scale, rep=rep,
                           alibi=alibi,
                           quantized=quantized, packed=packed,
-                          compute_dtype=compute_dtype),
+                          compute_dtype=compute_dtype, window=window),
         name="paged_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, SUBLANES, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, rows, D), q.dtype),
         interpret=backend.pallas_interpret(),
-    )(slot_of, entry_of, page_of, live, starts, slopes, layer, q4, *pools)
-    out = out[:, :, :T]
+    )(slot_of, entry_of, page_of, live, starts, slopes, layer, *first, q4,
+      *pools)
+    out = out.reshape(B, H, SUBLANES, D)[:, :, :T]
     return out.transpose(0, 2, 1, 3).astype(out_dtype)
 
 
@@ -616,21 +697,27 @@ def run_work(table: jax.Array, first: jax.Array, count: int, src_col,
 
 def paged_write_columns(leaf: jax.Array, layer, cols: jax.Array,
                         table: jax.Array, starts: jax.Array, *,
-                        page_size: Optional[int] = None) -> jax.Array:
+                        page_size: Optional[int] = None,
+                        active=None) -> jax.Array:
     """One decode or verify step's new columns into ONE layer of the
     stacked leaf, in place: ``cols`` (B, KV, Dc, T) goes to positions
     ``starts[b] .. starts[b] + T - 1`` of slot ``b`` through ``table``
     (B, pages_per_slot). A scale leaf (L, P, KV, lanes) takes
-    (B, KV, T). ``page_size`` as in :func:`paged_decode_attention`.
-    Returns the leaf."""
+    (B, KV, T). ``page_size`` as in :func:`paged_decode_attention`;
+    ``active`` (traced bool) False makes the work list empty: the leaf
+    comes back as it went in. Returns the leaf."""
     H = backend.HEADS
     tail = (None,) * (leaf.ndim - 3)
+    local = functools.partial(
+        _paged_write_columns_local,
+        page_size=leaf.shape[-1] if page_size is None else page_size)
+    if active is not None:
+        local = functools.partial(local,
+                                  active=jnp.asarray(active, jnp.bool_))
     # the pool has no batch dim and is whole on every device of a batch
     # axis: each of them writes every slot's columns
     return backend.shard_kernel(
-        functools.partial(
-            _paged_write_columns_local,
-            page_size=leaf.shape[-1] if page_size is None else page_size),
+        local,
         (None, None, H) + tail,
         leaf=(leaf, (None, None, H) + tail),
         layer=(jnp.asarray(layer, jnp.int32).reshape(1), (None,)),
@@ -640,7 +727,7 @@ def paged_write_columns(leaf: jax.Array, layer, cols: jax.Array,
 
 
 def _paged_write_columns_local(leaf, layer, cols, table, starts, *,
-                               page_size):
+                               page_size, active=None):
     scale_leaf = leaf.ndim == 4
     if scale_leaf:            # (L, P, KV, lanes): the heads take Dc's place
         leaf, cols = leaf[:, :, None], cols[:, None]
@@ -660,6 +747,8 @@ def _paged_write_columns_local(leaf, layer, cols, table, starts, *,
                     page_size, P, window)
     # every slot is row 0 of the source: its lanes tell them apart
     work = (work[0], jnp.zeros_like(work[1])) + work[2:]
+    if active is not None:
+        work = work[:-1] + (jnp.where(active, work[-1], 0),)
     out = paged_write(leaf, src[None, None], layer[0], work, page_size)
     return out[:, :, 0] if scale_leaf else out
 

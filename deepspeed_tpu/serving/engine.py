@@ -227,7 +227,39 @@ class ServingEngine:
                                     prefix_cache=use_prefix,
                                     kernel=paged_kernel)
         else:
+            # (a model with sliding-window layers keeps full-length rows
+            # here: what left a window is masked, not freed)
             self.pool = SlotPool(spec, num_slots, sharding=rep)
+        # what a window page group or a routed FFN does not compose with
+        # yet refuses here, by mechanism (ROADMAP.md, Reach)
+        grouped = getattr(spec, "groups", None) is not None
+        routed = bool(getattr(getattr(
+            getattr(engine, "_serve_module", None) or engine.module,
+            "config", None), "n_experts", 0))
+        if grouped or routed:
+            what = "sliding-window layers" if grouped else "a routed FFN"
+            if spec_decode:
+                raise ValueError(
+                    f"spec_decode does not compose with {what} yet: "
+                    f"verify_k's rollback would have to un-recycle window "
+                    f"pages, and the drafter has no routed FFN")
+            mesh = getattr(engine, "mesh", None)
+            if mesh is not None and mesh.shape.get("model", 1) > 1:
+                raise ValueError(
+                    f"tensor-parallel serving does not compose with "
+                    f"{what} yet: the expert leaves have no placement on "
+                    f"the expert axis when served and the window group's "
+                    f"leaves none on the model axis")
+            if grouped and paged_kv and prefill_chunk > spec.groups[1][2]:
+                raise ValueError(
+                    f"prefill_chunk ({prefill_chunk}) is wider than "
+                    f"sliding_window ({spec.groups[1][2]}): a chunk's rows "
+                    f"read the pages behind it while its own are mapped, more "
+                    f"than the ring a slot is granted")
+            if role != "both":
+                raise ValueError(
+                    f"prefill/decode roles do not compose with {what} "
+                    f"yet: a handoff would have to ship the window ring")
         self._paged = isinstance(self.pool, PagedKVPool)
         self._spec = None
         self._drafter = None
@@ -991,6 +1023,26 @@ class ServingEngine:
                 callback(*[np.asarray(a) for a in arrays])
         phases["replay"] = phases.get("replay", 0) + sp.dur_ns
 
+    def _note_moe_stats(self) -> None:
+        """What the routed FFN counted in this step's programs (each
+        hands back ``moe.routed_ffn.call_stats``; the arrays are outputs
+        of programs the step's sync has waited for): registry counters,
+        and attributes of the ``serving/step`` span."""
+        from ..moe.routed_ffn import CALL_STATS
+
+        calls, self.pool.moe_stats = \
+            np.stack([np.asarray(s) for s in self.pool.moe_stats]), []
+        step = {name: float(col.max() if name.startswith("load_max")
+                            else col.sum())
+                for name, col in zip(CALL_STATS, calls.T)}
+        reg = self.registry
+        for name in ("assignments", "experts_touched", "layer_calls"):
+            reg.counter(f"serving/moe_{name}").inc(step[name])
+            step[name] = int(step[name])
+        reg.gauge("serving/moe_load_max").set(step["load_max"])
+        self._dispatched.update(
+            {f"moe_{name}": val for name, val in step.items()})
+
     def _note_admit(self, rows: int, padded_tokens: int) -> None:
         """An admission program of this step: requests seated, and the
         tokens it computes (rows x bucket width, padding included)."""
@@ -1128,6 +1180,20 @@ class ServingEngine:
         """Pages the grant may promise this step: free now, plus what
         trie eviction could reclaim without preempting anyone."""
         return self.pool.free_page_count + self.pool.evictable_page_count()
+
+    def _grantable_slots(self) -> int:
+        """Slots the grant may fill this step. Pages are counted by
+        group: the budget above is the full group's; of the window group
+        a request never holds more than one ring (``sliding_window /
+        page_size + 1`` pages, one more while an unaligned chunk is
+        written), so it admits as many requests as it has whole rings
+        free."""
+        free = self.pool.free_count
+        ring = getattr(self.pool, "ring", None)
+        if ring is not None:
+            free = min(free, ring.free_count
+                       // (-(-ring.window // ring.page_size) + 2))
+        return free
 
     def _ensure_pages(self, slot: int, start: int, end: int) -> None:
         """ensure_writable with the pressure valve: on PagePoolExhausted
@@ -1827,13 +1893,13 @@ class ServingEngine:
                     # within budget
                     spent = self.prefill_chunk if self._prefill_queue else 0
                     granted = self.scheduler.grant(
-                        self.pool.free_count,
+                        self._grantable_slots(),
                         token_budget=self._effective_prefill_budget(),
                         cost=self._admission_cost, spent=spent,
                         page_budget=page_budget, page_cost=page_cost)
                 else:
                     granted = self.scheduler.grant(
-                        self.pool.free_count,
+                        self._grantable_slots(),
                         page_budget=page_budget, page_cost=page_cost)
             phases["grant"] = sp.dur_ns
             t_granted = sp.t0_ns + sp.dur_ns
@@ -1920,6 +1986,16 @@ class ServingEngine:
             self.registry.gauge("paging/refcounted_pages").set(float(shared))
             tracer.counter("paging/pages", free=free,
                            in_use=self.pool.num_pages - free, shared=shared)
+            if self.pool.ring is not None:
+                ring = self.pool.ring
+                self.registry.gauge("paging/pages_mapped_full").set(
+                    float(self.pool.num_pages - free))
+                self.registry.gauge("paging/pages_mapped_window").set(
+                    float(ring.mapped_count))
+                self._dispatched.update(window_pages=ring.mapped_count,
+                                        window_pages_total=ring.num_pages)
+            if self.pool.moe_stats:
+                self._note_moe_stats()
         if self.faults is not None and self.faults.fires("state_corruption"):
             # chaos: corrupt our own slot bookkeeping at the boundary so
             # check_invariants + the flight recorder face REAL damage

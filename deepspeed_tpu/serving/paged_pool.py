@@ -4,7 +4,9 @@ copy-on-write prefix sharing over the serving slot pool.
 Terminology map (for readers coming from the reference systems):
 
 * **vLLM PagedAttention** — our *page* is vLLM's KV *block*
-  (``page_size`` token columns of K/V across every layer); the
+  (``page_size`` token columns of K/V across every layer of ONE layer
+  group: every layer, for a model whose layers all keep every position;
+  see "Layer groups" below); the
   ``(num_slots, max_pages_per_slot)`` int32 *page table* is vLLM's
   per-sequence block table; the free-page heap is the block allocator;
   ``num_pages < num_slots * max_pages_per_slot`` is oversubscription —
@@ -56,7 +58,27 @@ columns page exactly like full-precision ones, with per-column scales
 paged alongside — paging multiplies with the 4x packed-footprint win
 rather than replacing it.
 
-Sentinel convention: table entry ``num_pages`` means "unmapped". The
+Layer groups (PR 30): a model that mixes full-attention and
+sliding-window layers (``KVCacheSpec.groups``) keeps TWO kinds of cache
+in one pool, each with its own stacked leaf, page table and free heap.
+The full group (``k`` / ``v`` / ``table``, ``num_pages``) is everything
+above, unchanged. The window group (``k_win`` / ``v_win`` /
+``table_win``, :class:`WindowRing`, a whole ring a slot) keeps a slot's
+last ``sliding_window`` positions only: its table has the same LOGICAL
+shape (an entry a ``page_size`` positions of the slot), but an entry
+whose positions have all left the window is unmapped and its page goes
+back to the group's free heap in the step that moves past it
+(:meth:`PagedKVPool.ensure_writable`), so a slot maps at most
+``sliding_window / page_size + 1`` pages (one more while a chunk that is
+not page-aligned is written). The kernels take a group's leaf and table
+and, for the window group, start at the first visible entry and mask
+positions ``<= i - sliding_window`` inside it. A model without layer
+kinds has exactly the single group it always had. What a ring does not
+compose with yet refuses at construction: the prefix cache (what a hit
+means for pages that were recycled), cross-pool page transfer.
+
+Sentinel convention: table entry ``num_pages`` means "unmapped" (the
+window group's: its own number of pages). The
 gather reads sentinel entries with a clip-mode take (arbitrary real
 page — harmless, a slot's mapped region always covers its live
 ``[0, index)`` columns and attention masks the rest), and a write
@@ -83,6 +105,109 @@ class PagePoolExhausted(RuntimeError):
     victim (freeing its pages) and retry, or fail the allocation."""
 
 
+class WindowRing:
+    """Host bookkeeping of the window group's pages: a free heap, a
+    ``(num_slots, pages_per_slot)`` table of LOGICAL entries of which each
+    slot maps only those its window still reaches, and how many were
+    recycled. A page has one owner: nothing shares a window page."""
+
+    def __init__(self, num_slots: int, pages_per_slot: int, page_size: int,
+                 window: int, num_pages: int):
+        self.page_size, self.window = page_size, int(window)
+        self.num_pages = int(num_pages)
+        self.pages_per_slot = pages_per_slot
+        self.table = np.full((num_slots, pages_per_slot), self.num_pages,
+                             np.int32)
+        # entries under a slot's floor left its window at its last write
+        self.floor = np.zeros((num_slots,), np.int64)
+        self.recycled = 0
+        self.reset()
+
+    def reset(self) -> None:
+        self._free = list(range(self.num_pages))
+        heapq.heapify(self._free)
+        self.table[:] = self.num_pages
+        self.floor[:] = 0
+
+    @property
+    def free_count(self) -> int:
+        return len(self._free)
+
+    @property
+    def mapped_count(self) -> int:
+        return self.num_pages - len(self._free)
+
+    def unmap_slot(self, slot: int) -> None:
+        for pid in self.table[slot][self.table[slot] != self.num_pages]:
+            heapq.heappush(self._free, int(pid))
+        self.table[slot, :] = self.num_pages
+        self.floor[slot] = 0
+
+    def make_writable(self, slot: int, start: int, end: int) -> bool:
+        """Before a step writes positions ``[start, end)`` of ``slot``:
+        entries whose every position lies ``window`` or more behind
+        ``start`` (no row of this or a later step sees them) go back to
+        the heap, then the written entries are mapped: those a later
+        step can see, so a whole prompt admitted at once maps one ring
+        however long it is, and the write's work list drops the rest.
+        Returns whether the table changed."""
+        ps, row = self.page_size, self.table[slot]
+        dead = max(start - self.window + 1, 0) // ps     # entries < dead
+        old = np.nonzero(row[:dead] != self.num_pages)[0]
+        for e in old:
+            heapq.heappush(self._free, int(row[e]))
+            row[e] = self.num_pages
+        self.recycled += len(old)
+        self.floor[slot] = dead
+        changed = bool(len(old))
+        first = max(start // ps, max(end - self.window + 1, 0) // ps)
+        for e in range(first, (end - 1) // ps + 1):
+            if row[e] == self.num_pages:
+                if not self._free:
+                    raise PagePoolExhausted(
+                        f"window page group exhausted: {self.num_pages} "
+                        f"pages all mapped")
+                row[e] = heapq.heappop(self._free)
+                changed = True
+        return changed
+
+    def audit(self, starts, free_slots) -> List[str]:
+        """The ring's part of ``PagedKVPool.consistency_errors``."""
+        errors = []
+        mapped = self.table[self.table != self.num_pages]
+        if len(set(mapped.tolist())) != len(mapped):
+            errors.append("window group: a page is mapped twice")
+        if set(mapped.tolist()) & set(self._free):
+            errors.append("window group: a mapped page is on the free heap")
+        if len(mapped) + len(self._free) != self.num_pages \
+                or len(set(self._free)) != len(self._free):
+            errors.append(
+                f"window group: {len(mapped)} mapped + {len(self._free)} "
+                f"free != {self.num_pages} pages")
+        ps = self.page_size
+        for slot in range(self.table.shape[0]):
+            row, n = self.table[slot], int(starts[slot])
+            if slot in free_slots:
+                if np.any(row != self.num_pages):
+                    errors.append(f"window group: free slot {slot} still "
+                                  f"maps pages")
+                continue
+            lo, hi = max(n - self.window + 1, 0) // ps, -(-n // ps)
+            if np.any(row[lo:hi] == self.num_pages):
+                errors.append(
+                    f"window group: slot {slot} at {n} has unmapped pages "
+                    f"inside its window: row[{lo}:{hi}]="
+                    f"{row[lo:hi].tolist()}")
+            # what had left the window at the slot's last write is gone
+            # (what has left it since goes at its next)
+            if np.any(row[:self.floor[slot]] != self.num_pages):
+                errors.append(
+                    f"window group: slot {slot} at {n} still maps pages "
+                    f"that left its window (entries before "
+                    f"{int(self.floor[slot])})")
+        return errors
+
+
 #: mirror of ``ops.attention.paged_attention.MAX_QUERY_ROWS`` as a local
 #: literal so graftcheck can decide the verify-width gate statically;
 #: ``bind_engine`` asserts the two stay equal
@@ -107,6 +232,17 @@ class PagedKVPool(SlotPool):
                  kernel: str = "auto"):
         if kernel not in ("auto", "on", "off"):
             raise ValueError(f"kernel must be auto|on|off, got {kernel!r}")
+        groups = getattr(spec, "groups", None)
+        if groups is not None:
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache does not compose with a window page "
+                    "group yet: a hit maps pages of the prompt's start, "
+                    "which a ring has recycled (pass paged_kv="
+                    "{'prefix_cache': False}; ROADMAP.md, Reach)")
+            if not groups[0][1]:
+                raise ValueError("a model of sliding-window layers only "
+                                 "has no full page group: not supported")
         capacity = int(spec.max_seq_len)
         page_size = int(page_size)
         if page_size < 1:
@@ -133,6 +269,20 @@ class PagedKVPool(SlotPool):
         self.page_evictions = 0
         self.registry = None              # optional MetricsRegistry
         self.prefix = PrefixCache(page_size) if prefix_cache else None
+        # the window group (None for a model of one layer kind): sized
+        # so that every slot can hold its whole ring
+        self.ring = None
+        if groups is not None:
+            window = groups[1][2]
+            self.ring = WindowRing(
+                num_slots, self.pages_per_slot, page_size, window,
+                num_slots * (-(-window // page_size) + 1))
+        self._table_keys = ("table",) + (("table_win",) if self.ring
+                                         else ())
+        # what the routed FFN counted in each program of the step
+        # (moe.routed_ffn.call_stats, device arrays; the engine reads
+        # them after its sync)
+        self.moe_stats: List[Any] = []
         super().__init__(spec, num_slots, sharding=sharding)
         # engine-bound gather/scatter jits (built on first bind_engine;
         # the copy-page program needs nothing from the engine)
@@ -174,7 +324,9 @@ class PagedKVPool(SlotPool):
         stood twice on chip 0 while it was built (1.5 GB short of
         loading there, chip run of PR 27)."""
         shapes = jax.eval_shape(
-            lambda: self.spec.paged_cache(self.num_pages, self.page_size))
+            lambda: self.spec.paged_cache(self.num_pages, self.page_size)
+            if self.ring is None else self.spec.paged_cache(
+                self.num_pages, self.page_size, self.ring.num_pages))
         cs = {key: jnp.zeros(leaf.shape, leaf.dtype,
                              device=self._leaf_sharding(key, leaf))
               for key, leaf in shapes.items()}
@@ -183,20 +335,50 @@ class PagedKVPool(SlotPool):
         cs["table"] = self._place_leaf(
             "table", jnp.full((self.num_slots, self.pages_per_slot),
                               self.num_pages, jnp.int32))
+        if self.ring is not None:
+            cs["table_win"] = self._place_leaf(
+                "table_win", jnp.full_like(cs["table"],
+                                           self.ring.num_pages))
         return {"cache_store": cs}
 
-    def _table_from_mirror(self):
-        tbl = jnp.array(self.table, copy=True)
+    def _table_from_mirror(self, key: str = "table"):
+        tbl = jnp.array(self.table if key == "table" else self.ring.table,
+                        copy=True)
         if self._sharding is not None:
-            tbl = self._place_leaf("table", tbl)
+            tbl = self._place_leaf(key, tbl)
         return tbl
 
     def _sync_table(self) -> None:
-        """Rebuild the device page table from the host mirror (same
+        """Rebuild the device page tables from the host mirrors (same
         committed-leaf discipline as ``_index_from_mirror``)."""
         cs = dict(self.cache["cache_store"])
-        cs["table"] = self._table_from_mirror()
+        for key in self._table_keys:
+            cs[key] = self._table_from_mirror(key)
         self.cache = {"cache_store": cs}
+
+    @staticmethod
+    def _group_tables(table, win_table=None) -> dict:
+        """One table a layer group, under the keys of a cache container."""
+        return {"table": table} if win_table is None \
+            else {"table": table, "table_win": win_table}
+
+    def _tables(self, cs: dict) -> dict:
+        """The page tables of a cache container, one a layer group."""
+        return {key: cs[key] for key in self._table_keys}
+
+    def _window_rows(self, slots) -> dict:
+        """``win_tables=`` the slots' rows of the window group's table,
+        for the programs that take host-passed rows; nothing for a pool
+        of one group. A slot id out of range (batch padding) gets an
+        all-sentinel row, which writes nothing."""
+        if self.ring is None:
+            return {}
+        slots = np.atleast_1d(np.asarray(slots, np.int64))
+        real = slots < self.num_slots
+        rows = np.full((len(slots), self.pages_per_slot),
+                       self.ring.num_pages, np.int32)
+        rows[real] = self.ring.table[slots[real]]
+        return {"win_tables": jnp.asarray(rows)}
 
     def _inc(self, name: str, amount: float = 1.0) -> None:
         if self.registry is not None:
@@ -264,6 +446,8 @@ class PagedKVPool(SlotPool):
             if pid != sent:
                 self.unref_page(int(pid))
         self.table[slot, :] = sent
+        if self.ring is not None:
+            self.ring.unmap_slot(slot)
 
     def release(self, slot: int) -> None:
         """Free the slot AND unreference its pages: exclusively-owned
@@ -280,6 +464,9 @@ class PagedKVPool(SlotPool):
         heapq.heapify(self._free_pages)
         self._free_page_set = set(self._free_pages)
         self.table[:] = self.num_pages
+        if self.ring is not None:
+            self.ring.reset()
+        self.moe_stats = []
         if self.prefix is not None:
             # the cached pages died with the pool; a fresh trie (not
             # clear()) avoids walking unref_page over freed state
@@ -306,6 +493,12 @@ class PagedKVPool(SlotPool):
         sent = self.num_pages
         ncow = 0
         changed = False
+        if self.ring is not None:
+            recycled = self.ring.recycled
+            changed = self.ring.make_writable(slot, start, end)
+            if self.ring.recycled > recycled:
+                self._inc("paging/window_pages_recycled",
+                          self.ring.recycled - recycled)
         for p in range(start // self.page_size,
                        (end - 1) // self.page_size + 1):
             pid = int(self.table[slot, p])
@@ -416,6 +609,10 @@ class PagedKVPool(SlotPool):
         before the exception propagates (the :meth:`ensure_writable`
         unwind template), so a mid-transfer death leaks nothing on
         either pool."""
+        if self.ring is not None or src_pool.ring is not None:
+            raise ValueError(
+                "cross-pool page transfer does not know a window page "
+                "group yet (a handoff would have to ship the ring too)")
         ids = [int(p) for p in src_page_ids]
         if len(ids) > self.pages_per_slot:
             raise ValueError(
@@ -583,14 +780,20 @@ class PagedKVPool(SlotPool):
         hi = np.clip(first + count, 0, self.capacity)[:, None]
         entry = np.arange(self.pages_per_slot)[None, :] * self.page_size
         spanned = (entry < hi) & (entry + self.page_size > lo)
-        return int(np.sum(spanned
-                          & (self.table[slots] != self.num_pages)))
+        touched = int(np.sum(spanned
+                             & (self.table[slots] != self.num_pages)))
+        if self.ring is not None:       # (a layer of either group)
+            touched += int(np.sum(
+                spanned & (self.ring.table[slots] != self.ring.num_pages)))
+        return touched
 
-    def _write_runs(self, pool: dict, dense: dict, tables, first,
+    def _write_runs(self, pool: dict, dense: dict, tables: dict, first,
                     count: int):
         """Traced: write positions ``[first[r], first[r] + count)`` of
         every row ``r`` of the dense view ((L, B, KV, cd, S), aligned
-        with ``tables`` (B, max_pages_per_slot)) into the page pool —
+        with ``tables`` (a (B, max_pages_per_slot) table a layer group:
+        each group's layers of the dense view go through its table into
+        its leaf) into the page pool —
         the ONE write path into it beside the model's own column write,
         and the same Pallas call (``paged_write``): it takes each
         stacked leaf whole, rewrites the pages its work list names and
@@ -601,25 +804,34 @@ class PagedKVPool(SlotPool):
         out = dict(pool)
         with jax.named_scope("scatter"):
             for key in ("k", "v", "k_scale", "v_scale"):
-                if key in pool:
+                if key not in pool:
+                    continue
+                if self.ring is None:
                     out[key] = paged_write_runs(
-                        pool[key], dense[key], tables, first, count,
+                        pool[key], dense[key], tables["table"], first,
+                        count, page_size=self.page_size)
+                    continue
+                for suffix, layers, _ in self.spec.groups:
+                    out[key + suffix] = paged_write_runs(
+                        pool[key + suffix],
+                        dense[key][np.asarray(layers)],
+                        tables["table" + suffix], first, count,
                         page_size=self.page_size)
         return out
 
     def _paged_admit_rows(self, pool: dict, pre: dict, rows_tables,
-                          slots, lengths):
+                          slots, lengths, win_tables=None):
         """Batched paged admission: write every column of the (full-
         capacity) prefill cache through host-passed per-row tables.
         Padding rows are ALL-sentinel tables (not just a sentinel slot
         id — indexing the device table with a clamped sentinel slot
         would alias a real slot's pages), so they write nothing."""
         nB = rows_tables.shape[0]
-        out = self._write_runs(pool, pre, rows_tables,
+        out = self._write_runs(pool, pre,
+                               self._group_tables(rows_tables, win_tables),
                                jnp.zeros((nB,), jnp.int32), self.capacity)
         out["index"] = pool["index"].at[slots].set(
             jnp.asarray(lengths, jnp.int32), mode="drop")
-        out["table"] = pool["table"]
         return out
 
     def bind_engine(self, engine: Any) -> None:
@@ -646,10 +858,21 @@ class PagedKVPool(SlotPool):
         dequant = engine._dequant
         chunk_gen = getattr(module, "prefill_chunk", None)
         write_runs = self._write_runs
+        tables_of = self._tables
+        grouped = self.ring is not None
+        # a model with a routed FFN reports what it counted through the
+        # "stats" collection (models/transformer_lm.py)
+        want_stats = bool(getattr(getattr(module, "config", None),
+                                  "n_experts", 0))
+        mutable = ["cache", "stats"] if want_stats else ["cache"]
+
+        def gather(vals, tables):
+            return spec.dense_from_pages(
+                vals, tables if grouped else tables["table"])
 
         def dense_cache(cs):
             with jax.named_scope("gather"):
-                dense = spec.dense_from_pages(cs, cs["table"])
+                dense = gather(cs, tables_of(cs))
             dense["index"] = cs["index"]
             return {"cache_store": dense}
 
@@ -657,10 +880,9 @@ class PagedKVPool(SlotPool):
             logits, new = decode_fn(params, dense_cache(cs), token, pos)
             ncs = new["cache_store"]
             # one column written per row
-            out = write_runs(cs, ncs, cs["table"], cs["index"], 1)
+            out = write_runs(cs, ncs, tables_of(cs), cs["index"], 1)
             out["index"] = ncs["index"]
-            out["table"] = cs["table"]
-            return logits, out
+            return logits, out, None
 
         def paged_verify(params, cs, tokens, pos, draft, draft_len, rng,
                          temperature, greedy, top_k, top_p):
@@ -669,33 +891,33 @@ class PagedKVPool(SlotPool):
                 rng, temperature, greedy, top_k, top_p)
             ncs = new["cache_store"]
             # K+1 columns written per row
-            out = write_runs(cs, ncs, cs["table"], cs["index"],
+            out = write_runs(cs, ncs, tables_of(cs), cs["index"],
                              tokens.shape[1])
             out["index"] = ncs["index"]
-            out["table"] = cs["table"]
             return out, out_tok, n_emit
 
         def paged_chunk(params, cs, ids, row_table, slot, start, length,
-                        last_idx):
-            # gather ONE slot's dense row from its pages, run the
+                        last_idx, win_tables=None):
+            # gather ONE slot's dense row from its pages (its table row,
+            # and the window group's where there is one), run the
             # window-masked chunk, scatter back only the chunk window
-            vals = {k: v for k, v in cs.items()
-                    if k not in ("index", "table")}
+            row_tables = self._group_tables(
+                row_table[None], None if win_tables is None
+                else win_tables[:1])
             with jax.named_scope("gather"):
-                dense = spec.dense_from_pages(vals, row_table[None])
+                dense = gather(cs, row_tables)
             dense["index"] = start[None]
             out, vars_ = module.apply(
                 {"params": dequant(params),
                  "cache": {"cache_store": dense}},
                 ids, start[None], last_idx, method=chunk_gen,
-                mutable=["cache"])
+                mutable=mutable)
             new = vars_["cache"]["cache_store"]
-            outcs = write_runs(cs, new, row_table[None], start[None],
+            outcs = write_runs(cs, new, row_tables, start[None],
                                ids.shape[1])
             outcs["index"] = cs["index"].at[slot].set(
                 start + jnp.asarray(length, jnp.int32), mode="drop")
-            outcs["table"] = cs["table"]
-            return out, outcs
+            return out, outcs, vars_["stats"]["moe"] if want_stats else None
 
         self._paged_decode_jit = jax.jit(paged_decode, donate_argnums=(1,))
         self._paged_verify_jit = jax.jit(paged_verify, donate_argnums=(1,),
@@ -721,23 +943,26 @@ class PagedKVPool(SlotPool):
                     f"_KERNEL_MAX_QUERY_ROWS={_KERNEL_MAX_QUERY_ROWS} "
                     f"drifted from kernel MAX_QUERY_ROWS={MAX_QUERY_ROWS}")
 
-            def kernel_decode_fn(params, cache, token, pos):
+            def kernel_apply(params, cache, token, pos):
                 cs = cache["cache_store"]
-                vals = {k: v for k, v in cs.items() if k != "table"}
+                tables = tables_of(cs)
+                vals = {k: v for k, v in cs.items() if k not in tables}
                 logits, vars_ = module.apply(
                     {"params": dequant(params),
                      "cache": {"cache_store": vals}},
-                    token, pos, cs["table"], method=module.decode_paged,
-                    mutable=["cache"])
-                new = dict(vars_["cache"]["cache_store"])
-                new["table"] = cs["table"]
-                return logits, {"cache_store": new}
+                    token, pos, tables if grouped else tables["table"],
+                    method=module.decode_paged, mutable=mutable)
+                new = dict(vars_["cache"]["cache_store"], **tables)
+                return logits, {"cache_store": new}, \
+                    vars_["stats"]["moe"] if want_stats else None
+
+            def kernel_decode_fn(params, cache, token, pos):
+                return kernel_apply(params, cache, token, pos)[:2]
 
             def kernel_decode(params, cs, token, pos):
-                logits, new = kernel_decode_fn(params,
-                                               {"cache_store": cs},
-                                               token, pos)
-                return logits, new["cache_store"]
+                logits, new, stats = kernel_apply(
+                    params, {"cache_store": cs}, token, pos)
+                return logits, new["cache_store"], stats
 
             kernel_verify_body = make_verify_fn(kernel_decode_fn,
                                                 _filter_logits)
@@ -799,13 +1024,15 @@ class PagedKVPool(SlotPool):
         # programs by the attribute the call goes through; each arm
         # rebinds self.cache immediately — its cache operand is donated
         if self._paged_decode_kernel_jit is not None:
-            logits, cs = self._paged_decode_kernel_jit(
+            logits, cs, stats = self._paged_decode_kernel_jit(
                 engine.params, self.cache["cache_store"], tokens, pos)
             self.cache = {"cache_store": cs}
         else:
-            logits, cs = self._paged_decode_jit(
+            logits, cs, stats = self._paged_decode_jit(
                 engine.params, self.cache["cache_store"], tokens, pos)
             self.cache = {"cache_store": cs}
+        if stats is not None:
+            self.moe_stats.append(stats)
         return logits
 
     def run_verify(self, engine: Any, tokens, pos, draft, draft_len, rng,
@@ -843,13 +1070,15 @@ class PagedKVPool(SlotPool):
             raise ValueError("run_prefill_chunk requires a module with "
                              "prefill_chunk(); the TransformerLM family "
                              "has one")
-        logits, cs = self._paged_chunk_jit(
+        logits, cs, stats = self._paged_chunk_jit(
             engine.params, self.cache["cache_store"],
             jnp.asarray(ids, jnp.int32), jnp.asarray(self.table[slot]),
             jnp.asarray(slot, jnp.int32), jnp.asarray(start, jnp.int32),
             jnp.asarray(length, jnp.int32),
-            jnp.asarray(last_idx, jnp.int32))
+            jnp.asarray(last_idx, jnp.int32), **self._window_rows([slot]))
         self.cache = {"cache_store": cs}
+        if stats is not None:
+            self.moe_stats.append(stats)
         return logits
 
     # ------------------------------------------------------------------
@@ -865,7 +1094,8 @@ class PagedKVPool(SlotPool):
         self._sync_table()       # publish ensure_writable's new mappings
         self.cache = {"cache_store": self._admit_rows_jit(
             self.cache["cache_store"], prefill_cache["cache_store"],
-            jnp.asarray(rows), jnp.asarray(slots), jnp.asarray(lengths))}
+            jnp.asarray(rows), jnp.asarray(slots), jnp.asarray(lengths),
+            **self._window_rows(slots))}
         real = slots < self.num_slots
         self.starts[slots[real]] = lengths[real]
 
@@ -904,6 +1134,10 @@ class PagedKVPool(SlotPool):
                  "cow_copies": self.cow_copies,
                  "page_evictions": self.page_evictions,
                  "page_size": self.page_size}
+        if self.ring is not None:
+            stats.update(window_pages_total=self.ring.num_pages,
+                         window_pages_in_use=self.ring.mapped_count,
+                         window_pages_recycled=self.ring.recycled)
         if self.prefix is not None:
             stats.update(
                 prefix_hits=self.prefix.hits,
@@ -920,6 +1154,8 @@ class PagedKVPool(SlotPool):
         pages are exactly the free ones, free slots map nothing, and
         every live slot's ``[0, index)`` columns are page-backed."""
         errors = super().consistency_errors()
+        if self.ring is not None:
+            errors += self.ring.audit(self.starts, self._free_set)
         P, sent = self.num_pages, self.num_pages
         if len(self._free_pages) != len(self._free_page_set):
             errors.append(f"free page heap ({len(self._free_pages)}) and "

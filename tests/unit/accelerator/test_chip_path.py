@@ -202,6 +202,61 @@ def test_pool_kernels_compile_for_a_described_v5e_at_the_served_shape(
         compilation_cache.reset_cache()
 
 
+def test_mellum_kernels_compile_for_a_described_v5e_at_the_served_shape(
+        compiled_kernels, described_v5e):
+    """The kernels PR 30 brought, through Mosaic at the widths of
+    ``perf/configs/mellum2-12b-a2b5-paged.json``: the expert products over
+    the stacked leaves (8 x 64 experts of 2304 x 896: whole (C, F) blocks
+    need the raised VMEM limit, which only this compile checks) for a
+    chunk's 128 rows and a decode step's 64, with no copy of a leaf around
+    them; and the window group's write and read (6 layers, 576 pages of
+    128, a grid that may have no step)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from deepspeed_tpu.moe.routed_ffn import routed_ffn
+    from deepspeed_tpu.ops.attention.paged_attention import (
+        paged_decode_attention, paged_write_columns)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=described_v5e)
+
+    bf16 = jnp.bfloat16
+    L, E, C, F = 8, 64, 2304, 896
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for rows in (128, 64):
+            compiled = jax.jit(lambda h, r, g, u, d, li: routed_ffn(
+                h, r, g, u, d, li, k=8, norm_topk_prob=True)).lower(
+                shape((rows, C), bf16), shape((C, E), jnp.float32),
+                shape((L, E, C, F), bf16), shape((L, E, C, F), bf16),
+                shape((L, E, F, C), bf16), shape((), jnp.int32)).compile()
+            text = compiled.as_text()
+            assert "moe_gate_up" in text and "moe_down" in text
+            # one layer's experts are 0.79 GB: a slice of a leaf would show
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
+        B, H, KV, D, ps, per_slot, P = 64, 32, 4, 128, 128, 64, 576
+        leaf = shape((6, P, KV, D, ps), bf16)
+
+        def step(q, k, v, table, starts, layer, active, cols):
+            k = paged_write_columns(k, layer, cols, table, starts,
+                                    page_size=ps, active=active)
+            return paged_decode_attention(
+                q, k, v, table, starts, layer=layer, page_size=ps,
+                window=1024, active=active), k
+
+        compiled = jax.jit(step, donate_argnums=1).lower(
+            shape((B, 1, H, D), bf16), leaf, leaf,
+            shape((B, per_slot), jnp.int32), shape((B,), jnp.int32),
+            shape((), jnp.int32), shape((), jnp.bool_),
+            shape((B, KV, D, 1), bf16)).compile()
+        assert compiled.as_text().count("tpu_custom_call") >= 2
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 # the lowered programs of a small paged server: what touches a pool leaf
 _LEAF_OPS = ("dynamic_slice", "dynamic_update_slice", "scatter", "transpose",
              "gather", "pad", "concatenate", "convert", "select")

@@ -166,15 +166,17 @@ def test_row_budget_is_enforced():
                                jnp.asarray([5], np.int32))
 
 
-def test_alibi_matches_dense_oracle():
-    """Bitwise for the T=1 row: the paged kernel builds the bias as the
+@pytest.mark.parametrize("KV", [4, 2])
+def test_alibi_matches_dense_oracle(KV):
+    """(With KV 2 the rows of two query heads share one product and each
+    row takes its head's slope.) Bitwise for the T=1 row: the paged kernel builds the bias as the
     dense kernel does for the query at ``start`` (a scalar query
     position) and subtracts each row's own offset, an exact zero on
     row 0. Writing it as ``slope * (pos - (start + row))`` made the CPU
     contract the two kernels' multiply-adds differently (351 of 512
     elements, 3.0e-7)."""
     rng = np.random.default_rng(4)
-    B, H, KV, D, S, ps = 2, 4, 4, 64, 128, 32
+    B, H, D, S, ps = 2, 4, 64, 128, 32
     dense_k, dense_v, k_pages, v_pages, table = _make_paged(
         rng, B, KV, D, S, ps)
     starts = np.asarray([40, 97], np.int32)
