@@ -2094,6 +2094,16 @@ class ServingEngine:
                 self._fail_slot(req, FinishReason.NUMERICAL_ERROR)
         return ok
 
+    def _set_pool_reads(self, sp, rows: int) -> None:
+        """``pool_reads`` / ``read_slots`` on a decode or verify span
+        whose dispatch read the pool through the kernel: the grid steps
+        of its work list for one layer of each page group, and the slots
+        in it (counted like ``pool_writes``: after the dispatch, from
+        the host's mirror)."""
+        work = self.pool.pages_read(rows)
+        if work is not None:
+            sp.set(pool_reads=work[0], read_slots=work[1])
+
     def _decode_step(self, finished: List[Request], t0: float) -> None:
         eng = self.engine
         if self._paged:
@@ -2115,6 +2125,7 @@ class ServingEngine:
                 # work list, the live ones map a page at their index
                 sp.set(pool_writes=self.pool.pages_touched(
                     np.arange(self.pool.num_slots), self.pool.starts, 1))
+                self._set_pool_reads(sp, 1)
             else:
                 logits, cache = eng._jit_decode(eng.params, self.pool.cache,
                                                 tokens, pos)
@@ -2229,6 +2240,7 @@ class ServingEngine:
                 sp.set(pool_writes=self.pool.pages_touched(
                     np.arange(self.pool.num_slots), self.pool.starts,
                     K + 1))
+                self._set_pool_reads(sp, K + 1)
             else:
                 cache, out_dev, n_emit_dev = eng.verify_k(
                     self.pool.cache, tokens,

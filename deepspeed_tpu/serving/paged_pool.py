@@ -83,7 +83,11 @@ gather reads sentinel entries with a clip-mode take (arbitrary real
 page — harmless, a slot's mapped region always covers its live
 ``[0, index)`` columns and attention masks the rest), and a write
 leaves sentinel entries out of its work list, so a dead or padding row
-can never touch a real page.
+can never touch a real page. The kernel read leaves a row that maps
+nothing out of ITS work list too (no grid step: PR 31), so a freed
+slot's rows of a kernel step are not attention output (finite, the
+slot's own query rows); the step computes them on through the head like
+any padding row and the host drops what it samples there.
 """
 
 from __future__ import annotations
@@ -786,6 +790,39 @@ class PagedKVPool(SlotPool):
             touched += int(np.sum(
                 spanned & (self.ring.table[slots] != self.ring.num_pages)))
         return touched
+
+    def pages_read(self, count: int):
+        """``(steps, slots)`` of the kernel read's work list for a
+        dispatch of ``count`` query rows a slot, from the host's mirror
+        of the table and ``starts``: the grid steps of ONE layer of each
+        page group (``live_pages`` / ``_window_pages`` in NumPy), and how
+        many slots have a step at all (``pool_reads`` / ``read_slots`` on
+        the decode and verify spans). A slot whose row maps nothing is
+        not in the list; ``num_slots - slots`` is how many steps a call
+        does not make. ``None`` where the dispatch is the dense
+        composition's (kernel off, or more rows than the kernel takes),
+        which has no work list."""
+        if self._paged_decode_kernel_jit is None \
+                or count > _KERNEL_MAX_QUERY_ROWS:
+            return None
+        per_slot, ps = self.pages_per_slot, self.page_size
+        start = self.positions().astype(np.int64)
+        seen = -(-(start + count) // ps)
+        # the row's leading mapped entries (argmax: its first sentinel)
+        unmapped = np.pad(self.table == self.num_pages, ((0, 0), (0, 1)),
+                          constant_values=True)
+        mapped = np.argmax(unmapped, axis=1)
+        live = np.clip(np.minimum(seen, mapped), np.minimum(mapped, 1),
+                       per_slot)
+        if self.ring is not None:
+            ring = self.ring
+            mapped = np.max(np.where(ring.table != ring.num_pages,
+                                     np.arange(per_slot) + 1, 0), axis=1)
+            first = np.clip((start - ring.window + 1) // ps, 0,
+                            per_slot - 1)
+            live = live + np.clip(np.minimum(seen, mapped) - first,
+                                  np.minimum(mapped, 1), per_slot)
+        return int(live.sum()), int(np.count_nonzero(live))
 
     def _write_runs(self, pool: dict, dense: dict, tables: dict, first,
                     count: int):

@@ -11,6 +11,7 @@ and interpret mode would cost minutes here."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
@@ -44,3 +45,29 @@ def pool(request):
 def make_server(engine, pool, **kw):
     """A ``ServingEngine`` over ``engine`` on the named pool."""
     return ServingEngine(engine, paged_kv=POOLS[pool], **kw)
+
+
+def watch_kernel_reads(srv, device_steps):
+    """Spy on a server's finite guard and on its pool's ``pages_read``.
+    Returns ``(finite_rows, record)``: the guard's (num_slots,) verdict of
+    every guarded step, and for every kernel dispatch ``((steps, slots),
+    device_steps(rows), seated rows that map a page)``, taken AT the
+    dispatch (a step frees slots after it). ``device_steps(rows)`` is the
+    test's own count of the device work list's steps."""
+    pool, finite_rows, record = srv.pool, [], []
+    guard, count = srv._jit_finite, pool.pages_read
+
+    def finite(logits):
+        rows = guard(logits)
+        finite_rows.append(np.asarray(rows))
+        return rows
+
+    def pages_read(rows):
+        work = count(rows)
+        if work is not None:
+            record.append((work, device_steps(rows), int(np.count_nonzero(
+                (pool.table != pool.num_pages).any(axis=1)))))
+        return work
+
+    srv._jit_finite, pool.pages_read = finite, pages_read
+    return finite_rows, record

@@ -14,6 +14,8 @@ import deepspeed_tpu as ds
 from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import PagedKVPool, RequestState, ServingEngine
 
+from .conftest import watch_kernel_reads
+
 TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
             dtype=jnp.float32)
 PS = 8  # page size == prefill chunk for every server in this file
@@ -199,3 +201,69 @@ def test_kernel_churn_never_recompiles_after_warmup(stack):
     assert srv.watchdog.recompiles == 0
     manifest = srv.watchdog.signature_manifest()
     assert "SlotPool._paged_decode_kernel_jit" in manifest
+
+
+# ---------------------------------------------------------------------------
+# the read's work list holds the slots that map a page (ISSUE 31)
+
+
+def _churn(engine, kernel, spec=None):
+    """Admit -> finish -> re-admit on four slots with the finite guard on:
+    seven requests of mixed budgets, so slots stand freed (row all
+    sentinel, index counting on) beside decoding ones for many steps."""
+    from deepspeed_tpu.ops.attention.paged_attention import live_pages
+    from deepspeed_tpu.telemetry import Tracer
+
+    srv = kernel_server(engine, kernel, num_slots=4, guard_numerics=True,
+                        tracer=Tracer(),
+                        **({"spec_decode": dict(spec)} if spec else {}))
+    pool = srv.pool
+    finite_rows, record = watch_kernel_reads(srv, lambda rows: int(
+        live_pages(jnp.asarray(pool.positions()),
+                   pool.cache["cache_store"]["table"], rows, PS,
+                   pool.num_pages)[-1]))
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(0, 64, size=int(n)).astype(np.int32)
+               for n in (5, 19, 9, 3, 12, 7, 21)]
+    budgets = [3, 14, 6, 10, 4, 12, 5]
+    reqs = [srv.submit(p, max_new_tokens=b)
+            for p, b in zip(prompts, budgets)]
+    for _ in range(400):
+        if not (srv.live_count or srv.pending):
+            break
+        srv.step()
+        srv.check_invariants()
+    assert all(r.state == RequestState.FINISHED for r in reqs)
+    # every row of every guarded step, the freed slots' too (the verify
+    # program samples inside and hands the guard no logits)
+    assert bool(finite_rows) == (spec is None)
+    assert all(rows.all() for rows in finite_rows)
+    assert all(r.finish_reason != "numerical_error" for r in reqs)
+    return srv, reqs, record
+
+
+@pytest.mark.parametrize("spec", [None, {"k": 3, "drafter": "ngram"}],
+                         ids=["decode", "verify"])
+def test_freed_slots_are_no_step_under_churn_with_the_finite_guard(
+        stack, spec):
+    """The kernel arm leaves freed slots out of its work list: tokens
+    bitwise the dense arm's for every request, no row of any step
+    non-finite, invariants clean after every step, and the dispatch span
+    says what the list held: ``pool_reads`` the device list's length,
+    ``read_slots`` the seated rows that map a page."""
+    _, _, engine = stack
+    srv, on, record = _churn(engine, "on", spec)
+    srv_off, off, none = _churn(engine, "off", spec)
+    assert not none                 # the dense composition has no list
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a.tokens(), b.tokens())
+    name = "serving/verify_k" if spec else "serving/decode"
+    spans = [e["args"] for e in srv.tracer.events()
+             if e["ph"] == "X" and e["name"] == name]
+    assert len(spans) == len(record) > 10
+    for args, ((reads, slots), total, seated) in zip(spans, record):
+        assert (args["pool_reads"], args["read_slots"]) == (reads, slots)
+        assert reads == total and slots == seated
+    # slots stood freed beside decoding ones, and cost nothing
+    assert any(0 < slots < 4 for (_, slots), _, _ in record)
+    assert srv_off.pool.pages_read(1) is None

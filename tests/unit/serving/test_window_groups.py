@@ -25,6 +25,8 @@ if ROOT not in sys.path:
 
 from perf.reference import mellum as ref  # noqa: E402
 
+from .conftest import watch_kernel_reads  # noqa: E402
+
 WINDOW, PAGE, CTX = 16, 8, 128
 ROPE = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000,
                            "factor": 16,
@@ -280,6 +282,64 @@ def test_server_answers_match_the_reference(stack, paged, chunk):
                      "moe_load_max", "pages_mapped_full",
                      "pages_mapped_window", "window_pages_recycled"):
             assert name in text, name
+
+
+def test_freed_slots_of_both_groups_are_no_step_under_churn(stack):
+    """Admit -> finish -> re-admit on three slots, the finite guard on,
+    through the kernels of both page groups: greedy tokens equal the
+    dense composition's for every request, no row of any decode step is
+    non-finite (a freed slot's rows are routed and counted like any),
+    invariants clean after every step, and the decode span carries the
+    work lists of both groups: ``pool_reads`` the steps of one full and
+    one window layer, ``read_slots`` the seated rows that map a page."""
+    from deepspeed_tpu.ops.attention.paged_attention import live_pages
+    from deepspeed_tpu.telemetry import Tracer
+
+    cfg, model, params, engine, _ = stack
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(1, 128, n).astype(np.int32)
+               for n in (5, 30, 11, 19, 7)]
+    budgets = [3, 12, 5, 9, 4]
+
+    def churn(kernel):
+        srv = ServingEngine(engine, num_slots=3, prefill_chunk=8,
+                            guard_numerics=True, tracer=Tracer(),
+                            paged_kv=dict(PAGED, kernel=kernel))
+        pool = srv.pool
+
+        def device_steps(rows):     # one layer of each group
+            cs, pos = pool.cache["cache_store"], pool.positions()
+            return sum(int(live_pages(
+                jnp.asarray(pos), cs["table" + suffix], rows, PAGE, pages,
+                window or None)[4])
+                for (suffix, _, window), pages in zip(
+                    kv_cache_groups(cfg),
+                    (pool.num_pages, pool.ring.num_pages)))
+
+        finite_rows, record = watch_kernel_reads(srv, device_steps)
+        reqs = [srv.submit(p, max_new_tokens=b)
+                for p, b in zip(prompts, budgets)]
+        for _ in range(300):
+            if not (srv.live_count or srv.pending):
+                break
+            srv.step()
+            srv.check_invariants()
+        assert finite_rows and all(rows.all() for rows in finite_rows)
+        assert [len(r.output_tokens) for r in reqs] == budgets
+        return srv, reqs, record
+
+    srv, on, record = churn("on")
+    _, off, none = churn("off")
+    assert not none                 # the dense composition has no list
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a.tokens(), b.tokens())
+    spans = [e["args"] for e in srv.tracer.events()
+             if e["ph"] == "X" and e["name"] == "serving/decode"]
+    assert len(spans) == len(record) > 5
+    for args, ((reads, slots), total, seated) in zip(spans, record):
+        assert (args["pool_reads"], args["read_slots"]) == (reads, slots)
+        assert reads == total and slots == seated
+    assert any(0 < slots < 3 for (_, slots), _, _ in record)
 
 
 def test_what_does_not_compose_refuses_at_construction(stack):
