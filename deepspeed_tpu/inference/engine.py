@@ -296,6 +296,13 @@ class InferenceEngine:
             return out, vars_["cache"]
 
         chunk_gen = getattr(module, "prefill_chunk", None)
+        has_state = getattr(self.kv_cache_spec(), "state", None) is not None
+        if has_state and self.mp_world_size > 1:
+            raise ValueError(
+                "tensor-parallel inference does not compose with a "
+                "recurrent state yet: the state leaves have no placement on "
+                "the model axis and the retention kernels are not wrapped "
+                "for a mesh (ROADMAP.md, Reach)")
 
         def prefill_chunk_fn(params, cache, ids, slot, start, length,
                              last_idx):
@@ -308,10 +315,27 @@ class InferenceEngine:
             chunk ran at padded width C). Only the target row is ever
             written, so live neighbours can't be clobbered by the
             chunk's C-wide writes, and slot/start/length are traced —
-            ONE compiled program covers every slot at every offset."""
+            ONE compiled program covers every slot at every offset.
+
+            A recurrent state (``KVCacheSpec.state``) is not sliced: the
+            stacked leaves go in whole with the row's number, the chunk
+            kernel rewrites that row's blocks in place and no other row
+            is read or written (a slice would copy one row's state of
+            every layer out and back, 0.27 GB each way at the served
+            size)."""
             cs = cache["cache_store"]
             slot = jnp.asarray(slot, jnp.int32)
             start = jnp.asarray(start, jnp.int32)
+            if has_state:
+                out, vars_ = module.apply(
+                    {"params": dequant(params),
+                     "cache": {"cache_store": dict(cs, index=start[None])}},
+                    ids, start[None], last_idx, slot[None],
+                    method=chunk_gen, mutable=["cache"])
+                new = vars_["cache"]["cache_store"]
+                return out, {"cache_store": dict(
+                    new, index=cs["index"].at[slot].set(
+                        start + jnp.asarray(length, jnp.int32)))}
             row = {k: jax.lax.dynamic_slice_in_dim(v, slot, 1, 1)
                    for k, v in cs.items() if k != "index"}
             row["index"] = start[None]
@@ -332,10 +356,17 @@ class InferenceEngine:
                 start + jnp.asarray(length, jnp.int32))
             return out, {"cache_store": merged}
 
-        def decode_fn(params, cache, token, pos):
+        def decode_fn(params, cache, token, pos, rows=None):
+            # ``rows``: only for a model with a recurrent state, from a
+            # caller some of whose rows do not run (the server: free
+            # slots, slots in mid-prefill). (B,) int32, the cache row of
+            # each entry, out of range for one that does not run: that
+            # row's state comes back bit for bit. A K/V model is never
+            # given it: its program is what it was.
+            more = {} if rows is None else {"rows": rows}
             out, vars_ = module.apply(
                 {"params": dequant(params), "cache": cache}, token, pos,
-                method=module.decode, mutable=["cache"])
+                method=module.decode, mutable=["cache"], **more)
             return out, vars_["cache"]
 
         def sample_fn(logits, rng, temperature, top_k, top_p, greedy):

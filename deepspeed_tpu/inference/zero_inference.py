@@ -53,7 +53,9 @@ class ZeroInferenceEngine:
                 "ZeRO-Inference streams one layer's block parameters at a "
                 "time; the routed FFN's expert leaves are stacked parameters "
                 "of the model and the layer kinds are read off the layer "
-                "scan's counter: neither is streamed yet (ROADMAP.md, Reach)")
+                "scan's counter: neither is streamed yet, nor is a "
+                "power_retention layer's recurrent state threaded through "
+                "the streamed layers (ROADMAP.md, Reach)")
         if int8 and not config.int8_weights:
             # int8 ZeRO-Inference: quantize the Dense kernels host-side
             # (QuantDense layout) so each streamed layer is ~half the

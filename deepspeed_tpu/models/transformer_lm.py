@@ -26,6 +26,12 @@ mellum         rotary    rmsnorm    routed    ``head_dim`` a field, GQA,
                                               window and full layers
                                               (``layer_types``, rotary by
                                               type), top-k of E experts
+brumby         rotary    rmsnorm    swiglu    every layer gated
+                                              power retention of
+                                              degree 2: a norm on q
+                                              and k, a gate a KV
+                                              head, a recurrent
+                                              state in place of K/V
 =============  ========  =========  ========  ===================
 
 Layer kinds that differ (``layer_types``: ``sliding_attention`` or
@@ -37,6 +43,13 @@ expert leaves ``(layers, E, C, F)`` are parameters of the model, not of
 the scanned block, and reach every layer whole (a scanned leaf would be
 sliced, that is copied, a layer). Nothing of this is reached by a
 configuration with ``n_experts == 0`` and no ``layer_types``.
+
+A third kind, ``power_retention`` (:class:`PowerRetention`), keeps no K/V:
+its cache is a state of fixed size a sequence (``KVCacheSpec.state``),
+whose stacked leaf rides the scan's carry whole and is updated in place
+by the kernels of ``ops/attention/power_retention.py``, for the rows the
+caller names (``rows``) and no others. Today every layer of a model is of
+this kind or none is.
 
 KV-cache decoding uses the flax ``cache`` variable collection: ``prefill``
 writes the prompt's K/V at positions [0, T), ``decode`` appends one position
@@ -133,16 +146,38 @@ class TransformerConfig:
     experts_per_token: int = 0
     norm_topk_prob: bool = True         # renormalise the chosen experts'
     # router probabilities to sum to 1
+    qk_norm: bool = False               # RMSNorm over each head of q and k
+    # (a learned weight of head_dim), before the rotary
 
     def __post_init__(self):
         if self.layer_types is not None:
             kinds = set(self.layer_types) - {"sliding_attention",
-                                             "full_attention"}
+                                             "full_attention",
+                                             "power_retention"}
             if kinds or len(self.layer_types) != self.n_layer:
                 raise ValueError(
                     f"layer_types names n_layer={self.n_layer} layers as "
-                    f"sliding_attention | full_attention; got "
-                    f"{len(self.layer_types)} entries, unknown {sorted(kinds)}")
+                    f"sliding_attention | full_attention | power_retention; "
+                    f"got {len(self.layer_types)} entries, unknown "
+                    f"{sorted(kinds)}")
+            if "power_retention" in self.layer_types:
+                if set(self.layer_types) != {"power_retention"}:
+                    raise ValueError(
+                        "power_retention layers beside attention layers "
+                        "need a state group beside the K/V leaves of one "
+                        "cache, which no pool keeps yet: every layer is "
+                        "power_retention or none is (ROADMAP.md, Reach)")
+                if self.kv_cache_quant:
+                    raise ValueError(
+                        "kv_cache_quant quantizes K/V columns; a "
+                        "power_retention layer keeps a float32 state and no "
+                        "column")
+                if self.head_dim % 8 or self.n_head // self.kv_heads \
+                        >= self.head_dim:
+                    raise ValueError(
+                        f"power_retention needs head_dim % 8 == 0 and fewer "
+                        f"query heads a KV head than head_dim; got head_dim="
+                        f"{self.head_dim}, {self.n_head} / {self.kv_heads}")
             if "sliding_attention" in self.layer_types \
                     and not self.sliding_window:
                 raise ValueError("sliding_attention layers need "
@@ -184,6 +219,12 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return self.n_kv_head or self.n_head
 
+    @property
+    def retention(self) -> bool:
+        """Every layer is ``power_retention``: a state, no K/V."""
+        return self.layer_types is not None \
+            and "power_retention" in self.layer_types
+
 
 FAMILY_PRESETS = {
     "gpt2": dict(pos_emb="learned", norm="layernorm", activation="gelu"),
@@ -205,6 +246,14 @@ FAMILY_PRESETS = {
     "mellum": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
                    qkv_bias=False, mlp_bias=False, tie_word_embeddings=False,
                    layer_norm_epsilon=1e-6),
+    # Brumby (Manifest AI): Qwen3's block (llama's with a norm on q and k)
+    # whose every layer is gated power retention (``layer_kind``: one kind
+    # for every layer, which transformer_config spells out as layer_types
+    # once it knows n_layer)
+    "brumby": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
+                   qkv_bias=False, mlp_bias=False, tie_word_embeddings=False,
+                   layer_norm_epsilon=1e-6, qk_norm=True,
+                   layer_kind="power_retention"),
 }
 
 
@@ -224,7 +273,12 @@ def transformer_config(family: str, **overrides) -> TransformerConfig:
         raise ValueError(f"unknown family {family!r}; know {sorted(FAMILY_PRESETS)}")
     overrides = {k: _freeze(v) if k in ("layer_types", "rope_parameters")
                  else v for k, v in overrides.items()}
-    return TransformerConfig(**{**FAMILY_PRESETS[family], **overrides})
+    cfg = {**FAMILY_PRESETS[family], **overrides}
+    kind = cfg.pop("layer_kind", None)
+    if kind is not None:
+        cfg.setdefault("layer_types", (kind,) * cfg.get(
+            "n_layer", TransformerConfig.n_layer))
+    return TransformerConfig(**cfg)
 
 
 def transformer_logical_axes():
@@ -820,6 +874,85 @@ class CachedAttention(nn.Module):
         return o_proj(y), new_cache
 
 
+def _half_life_logit(key, shape, dtype=jnp.float32):
+    """The gate's bias: ``logit(g)`` for ``g = 2 ** (-1 / half-life)`` with
+    half-lives drawn log-uniformly from 16 to 4,096 tokens, one a KV head
+    a layer (the scan splits the key by layer). A zero bias is ``g`` 0.5:
+    the state would forget in two tokens, and nothing that compares outputs
+    could see a wrong carried state."""
+    half_life = 16.0 * 256.0 ** jax.random.uniform(key, shape)
+    g = 2.0 ** (-1.0 / half_life)
+    return (jnp.log(g) - jnp.log1p(-g)).astype(dtype)
+
+
+class PowerRetention(nn.Module):
+    """Gated power retention of degree 2 in the attention's place
+    (``ops/attention/power_retention.py`` has the equations): ``q``, ``k``
+    with their norm and the rotary, ``v``, one gate a KV head
+    (``log g = log sigmoid(W_g x + b_g)``, float32), output projection.
+
+    Modes as :class:`CachedAttention`'s. Without a cache: the attention
+    form in ``jax.numpy``. With one, ``kv_cache`` holds the stacked state
+    leaf ``s`` whole with ``layer``, ``start`` and, from a caller
+    that runs only some rows or maps its batch to other rows, ``rows``
+    (B,) (the cache row of each batch entry, out of range: the entry does
+    not run and its row's state is not touched) and ``valid`` (B,) (tokens
+    from there on are padding and leave the state alone). One token takes
+    ``retention_decode``, more take ``retention_chunk``; an entry whose
+    first position is 0 reads no state."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, *, decode: Union[bool, str] = False,
+                 deterministic: bool = True, kv_cache=None,
+                 block_hint=None, layer=None):
+        from ..ops.attention import power_retention as pr
+
+        cfg = self.config
+        B, T, C = x.shape
+        H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
+        dense = lambda feats, name: _dense(  # noqa: E731
+            cfg, feats, use_bias=cfg.qkv_bias, name=name)
+        q = dense(H * D, "q_proj")(x).reshape(B, T, H, D)
+        k = dense(KV * D, "k_proj")(x).reshape(B, T, KV, D)
+        v = dense(KV * D, "v_proj")(x).reshape(B, T, KV, D)
+        if cfg.qk_norm:
+            q = nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
+                           name="k_norm")(k)
+        log_g = jax.nn.log_sigmoid(nn.Dense(
+            KV, dtype=jnp.float32, bias_init=_half_life_logit,
+            name="g_proj")(x))                                  # (B, T, KV)
+        start = kv_cache["start"] if decode else jnp.zeros((), jnp.int32)
+        if cfg.pos_emb == "rotary":
+            positions = (start[:, None] if jnp.ndim(start) == 1 else start) \
+                + jnp.arange(T)[None, :]
+            rd = int(cfg.rotary_pct * D) // 2 * 2
+            q = apply_rotary(q, positions, rotary_dim=rd,
+                             theta=cfg.rope_theta)
+            k = apply_rotary(k, positions, rotary_dim=rd,
+                             theta=cfg.rope_theta)
+        o_proj = _dense(cfg, C, use_bias=cfg.qkv_bias, name="o_proj")
+        if not decode:
+            y = pr.retention_attention(q, k, v, log_g)
+            return o_proj(y.astype(cfg.dtype).reshape(B, T, H * D)), None
+        rows = kv_cache.get("rows")
+        if rows is None:
+            rows = jnp.arange(B, dtype=jnp.int32)
+        fresh = jnp.broadcast_to(start == 0, (B,))
+        state = (kv_cache["s"], kv_cache["layer"], rows, fresh)
+        if T == 1:
+            y, s = pr.retention_decode(q[:, 0], k[:, 0], v[:, 0],
+                                       log_g[:, 0], *state)
+        else:
+            y, s = pr.retention_prefill(q, k, v, log_g, *state,
+                                        length=kv_cache.get("valid"))
+        y = y.astype(cfg.dtype).reshape(B, T, H * D)
+        return o_proj(y), {"s": s}
+
+
 class TransformerMLP(nn.Module):
     config: TransformerConfig
 
@@ -850,7 +983,8 @@ class TransformerBlock(nn.Module):
                  deterministic: bool = True, kv_cache=None,
                  block_hint=None, layer=None, experts=None):
         cfg = self.config
-        a, new_cache = CachedAttention(cfg, name="attn")(
+        attention = PowerRetention if cfg.retention else CachedAttention
+        a, new_cache = attention(cfg, name="attn")(
             _norm(cfg, "ln_1")(x), decode=decode, deterministic=deterministic,
             kv_cache=kv_cache, block_hint=block_hint, layer=layer)
         stats = ()      # a routed FFN's counts follow (x, cache)
@@ -877,7 +1011,7 @@ class TransformerBlock(nn.Module):
 
 class _ScanBlock(nn.Module):
     """One scanned layer. The carry is ``(x, cache, start, layer_idx)``
-    and the STACKED (L-leading) KV cache rides it. Which of two access
+    and the STACKED (L-leading) KV cache rides it. Which of three access
     patterns a layer uses is read off the carry's contents:
 
     - the contiguous cache (``generate()``, the ``SlotPool``): each
@@ -886,6 +1020,10 @@ class _ScanBlock(nn.Module):
       buffer the quantized cache above ~100 MB through their xs/ys pair
       (PERF.md §8, the carry-DUS lead); the carry-DUS of a
       batch-major dense row did not.
+    - a recurrent state (``"s"`` in the carry, :class:`PowerRetention`):
+      whole like a page pool's leaves, for the same reason and one more:
+      a slice would be one layer's state of EVERY row, 0.5 GB a layer at
+      the served size, read and written for the one row a chunk runs.
     - a page pool (``"table"`` in the carry): the stacked leaves and the
       layer counter go to the block whole and come back whole; the
       block reads and writes them through Pallas calls that index
@@ -918,6 +1056,14 @@ class _ScanBlock(nn.Module):
             x, _, *stats = block(x, decode, deterministic, None, block_hint,
                                  *more)
             return (x, None, start, li + 1 if more else li), tuple(stats)
+        if "s" in cache:
+            # a recurrent state: the stacked leaf goes to the block whole
+            # and comes back whole (its kernels index (layer, row) and
+            # alias it); "rows" / "valid" pass through
+            x, leaves, *stats = block(x, decode, deterministic,
+                                      dict(cache, start=start, layer=li),
+                                      block_hint, *more)
+            return (x, dict(cache, **leaves), start, li + 1), tuple(stats)
         if "table" in cache:
             # the "table*" entries are the POOL-WIDE page tables (slots,
             # pages_per_slot), one a layer group, shared by the group's
@@ -1030,9 +1176,27 @@ class KVCacheSpec:
     groups: Optional[tuple] = None     # kv_cache_groups(cfg): the layers
     # of each group of a page pool; the contiguous containers below keep
     # every layer at full length (a window layer's old columns are masked)
+    state: Optional[tuple] = None      # a model of power_retention layers:
+    # the shape of one KV head's recurrent state
+    # (ops/attention/power_retention.state_shape), float32. Such a cache
+    # holds ``s`` (L, B, KV, *state) and no k / v: its size does not depend
+    # on max_seq_len, which stays the bound on positions
+
+    @property
+    def state_bytes_per_row(self) -> int:
+        """Bytes of one sequence's state over the layers (0: a K/V cache)."""
+        if self.state is None:
+            return 0
+        return 4 * self.n_layer * self.kv_heads * math.prod(self.state)
+
+    def _state_cache(self, lead: tuple) -> dict:
+        return {"s": jnp.zeros(lead + (self.kv_heads,) + self.state,
+                               jnp.float32)}
 
     def layer_cache(self, batch_size: int) -> dict:
         """Zeroed single-layer k/v dict: (B, KV, cache_d, S) [+ scales]."""
+        if self.state is not None:
+            return self._state_cache((batch_size,))
         shape = (batch_size, self.kv_heads, self.cache_d, self.max_seq_len)
         cache = {"k": jnp.zeros(shape, self.dtype),
                  "v": jnp.zeros(shape, self.dtype)}
@@ -1048,6 +1212,9 @@ class KVCacheSpec:
         plus a per-sequence ``index`` (B,) int32 — the vector-start form
         CachedAttention accepts for slot-pooled decode."""
         L = self.n_layer
+        if self.state is not None:
+            return dict(self._state_cache((L, batch_size)),
+                        index=jnp.zeros((batch_size,), jnp.int32))
         shape = (L, batch_size, self.kv_heads, self.cache_d,
                  self.max_seq_len)
         cache = {"k": jnp.zeros(shape, self.dtype),
@@ -1072,6 +1239,8 @@ class KVCacheSpec:
         ``stacked_cache`` layout the attention kernels consume. Same
         dtype/packing tiers as the contiguous container (int8/packed
         cache columns page exactly like full-precision ones)."""
+        if self.state is not None:
+            raise ValueError("a recurrent state has no positions to page")
         lanes = page_lanes(page_size)
         if self.groups is not None:
             # one stacked leaf a group: ``num_pages`` pages for the full
@@ -1147,11 +1316,16 @@ class KVCacheSpec:
 
 def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
     cache_dtype, cache_d, packed = kv_cache_spec(cfg)
+    state = None
+    if cfg.retention:
+        from ..ops.attention.power_retention import state_shape
+
+        state = state_shape(cfg.head_dim)
     return KVCacheSpec(n_layer=cfg.n_layer, kv_heads=cfg.kv_heads,
                        head_dim=cfg.head_dim, cache_d=cache_d,
                        dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
                        quantized=cfg.kv_cache_quant, packed=packed,
-                       groups=kv_cache_groups(cfg))
+                       groups=kv_cache_groups(cfg), state=state)
 
 
 def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:
@@ -1182,6 +1356,20 @@ class _CacheStore(nn.Module):
                  paged=False):
         cfg = self.config
         L, KV = cfg.n_layer, cfg.kv_heads
+        cidx = self.variable("cache", "index",
+                             lambda: jnp.zeros((), jnp.int32))
+        if cfg.retention:
+            # a recurrent state and no k / v (a provided cache passes
+            # through at its own row count, as everywhere here)
+            leaf = self.variable(
+                "cache", "s", jnp.zeros,
+                (L, batch_size, KV) + make_kv_cache_spec(cfg).state,
+                jnp.float32)
+            values = {"s": leaf.value}
+            if new_values is not None:
+                leaf.value = new_values["s"]
+                cidx.value = new_index
+            return values, cidx.value
         cache_dtype, cache_d, _ = kv_cache_spec(cfg)
         shape = (L, batch_size, KV, cache_d, cfg.max_seq_len)
         ck = self.variable("cache", "k", jnp.zeros, shape, cache_dtype)
@@ -1203,8 +1391,6 @@ class _CacheStore(nn.Module):
             cvs = self.variable("cache", "v_scale", jnp.zeros, sshape,
                                 jnp.float32)
             values.update(k_scale=cks.value, v_scale=cvs.value)
-        cidx = self.variable("cache", "index",
-                             lambda: jnp.zeros((), jnp.int32))
         if new_values is not None:
             ck.value = new_values["k"]
             cv.value = new_values["v"]
@@ -1262,7 +1448,8 @@ class TransformerLM(nn.Module):
                                   dtype=jnp.float32, name="lm_head")
 
     def _transform(self, input_ids, positions, decode, deterministic,
-                   block_hint=None, head=True, paged_table=None):
+                   block_hint=None, head=True, paged_table=None,
+                   state_rows=None, valid_len=None):
         cfg = self.config
         B, T = input_ids.shape
         x = self.embed_tokens(input_ids)
@@ -1287,6 +1474,14 @@ class TransformerLM(nn.Module):
                 # writeback; see _ScanBlock)
                 cache = dict(cache, **(paged_table if isinstance(
                     paged_table, dict) else {"table": paged_table}))
+            if cfg.retention:
+                # which cache row each batch entry is and where its real
+                # tokens end ride beside the state (PowerRetention)
+                if state_rows is not None:
+                    cache["rows"] = jnp.asarray(state_rows, jnp.int32)
+                if valid_len is not None:
+                    cache["valid"] = jnp.broadcast_to(
+                        jnp.asarray(valid_len, jnp.int32), (B,))
             carry = (x, cache, start, jnp.zeros((), jnp.int32))
             (x, cache, _, _), stats = self.blocks(
                 carry, decode, deterministic, block_hint, *more)
@@ -1298,7 +1493,8 @@ class TransformerLM(nn.Module):
                 self.sow("stats", "moe", call_stats(stats[0], cfg.n_experts),
                          init_fn=lambda: None, reduce_fn=lambda _, new: new)
             cache = {key: val for key, val in cache.items()
-                     if not key.startswith("table")}
+                     if not key.startswith("table")
+                     and key not in ("rows", "valid")}
             self.cache_store(B, new_values=cache, new_index=start + T,
                              paged=paged)
         else:
@@ -1349,7 +1545,10 @@ class TransformerLM(nn.Module):
         position's hidden state independent of the right padding."""
         B, T = input_ids.shape
         pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-        x = self._transform(input_ids, pos, "prefill", True, head=False)
+        x = self._transform(
+            input_ids, pos, "prefill", True, head=False,
+            valid_len=None if last_pos is None
+            else jnp.asarray(last_pos, jnp.int32) + 1)
         if last_pos is None:
             x = x[:, -1:]
         else:
@@ -1358,7 +1557,7 @@ class TransformerLM(nn.Module):
                 xb, i, 1, 0))(x, idx)
         return self._project_head(x)
 
-    def prefill_chunk(self, input_ids, start_pos, last_idx):
+    def prefill_chunk(self, input_ids, start_pos, last_idx, rows=None):
         """Chunked serving prefill: process a fixed-width (B, C) token
         chunk AGAINST the allocated cache at per-slot offsets and project
         only ``last_idx`` onto the vocabulary, returning (B, 1, V).
@@ -1376,17 +1575,24 @@ class TransformerLM(nn.Module):
         slot's current prefill offset. Right-padding in the final
         partial chunk writes masked garbage past the true length
         (invisible to attention once the caller sets the slot index to
-        the true length, exactly like the bucketed ``prefill_last``)."""
+        the true length, exactly like the bucketed ``prefill_last``).
+
+        A recurrent state has no index to hide padding behind: tokens past
+        ``last_idx`` are padding to it and leave it alone, and ``rows``
+        (B,) names the row of the provided cache each entry of the batch
+        is (a server hands in its whole pool and one row's chunk)."""
         B, T = input_ids.shape
         off = start_pos[:, None] if jnp.ndim(start_pos) == 1 else start_pos
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-        x = self._transform(input_ids, pos, True, True, head=False)
+        x = self._transform(input_ids, pos, True, True, head=False,
+                            state_rows=rows,
+                            valid_len=jnp.asarray(last_idx, jnp.int32) + 1)
         idx = jnp.broadcast_to(jnp.asarray(last_idx, jnp.int32), (B,))
         x = jax.vmap(lambda xb, i: jax.lax.dynamic_slice_in_dim(
             xb, i, 1, 0))(x, idx)
         return self._project_head(x)
 
-    def decode(self, input_ids, start_pos, block_hint=None):
+    def decode(self, input_ids, start_pos, block_hint=None, rows=None):
         """One (or few) token step against the cache; ``start_pos`` is the
         current cache length — scalar for a B-uniform batch, or (B,) for
         slot-pooled decode where every sequence sits at its own offset
@@ -1395,11 +1601,16 @@ class TransformerLM(nn.Module):
         granule — an explicit expert option; engine.generate keeps the
         allocation-based default after a budget-derived hint measured
         net-negative (grid overhead dominates dead-row reads;
-        PERF.md §8, the block_hint lead)."""
+        PERF.md §8, the block_hint lead). ``rows`` (B,), for a model with
+        a recurrent state: the cache row of each entry, out of range for
+        an entry that does not run, whose row's state stays bit for bit
+        (a K/V model hides such an entry's column behind its index and
+        takes no ``rows``)."""
         B, T = input_ids.shape
         off = start_pos[:, None] if jnp.ndim(start_pos) == 1 else start_pos
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-        return self._transform(input_ids, pos, True, True, block_hint)
+        return self._transform(input_ids, pos, True, True, block_hint,
+                               state_rows=rows)
 
     def decode_paged(self, input_ids, start_pos, table):
         """Fused paged-kernel decode step: like :meth:`decode`, but the

@@ -145,7 +145,7 @@ from .flash_attention import LANES, NEG_INF, SUBLANES
 
 __all__ = ["paged_decode_attention", "paged_write_columns",
            "paged_write_runs", "plan_grid", "plan_write", "live_pages",
-           "MAX_QUERY_ROWS"]
+           "kept_first", "MAX_QUERY_ROWS"]
 
 # one kernel serves decode (T=1) and speculative verify (T=K+1): query
 # rows live on the SUBLANES axis of the score tile, so the row budget is
@@ -681,6 +681,17 @@ def paged_write(leaf: jax.Array, src: jax.Array, layer, work,
       shift_of, lo_of, hi_of, leaf, src.astype(leaf.dtype))
 
 
+def kept_first(keep, *values):
+    """A dynamic grid's work list: the entries of each of ``values`` that
+    ``keep`` marks, first and in order, flattened to int32, zeros after
+    them, and last how many they are. A kernel's grid is that long: what
+    follows the kept steps never runs."""
+    order = jnp.argsort(jnp.logical_not(keep).reshape(-1), stable=True)
+    return tuple(jnp.where(keep, x, 0).reshape(-1)[order]
+                 .astype(jnp.int32) for x in values) \
+        + (jnp.sum(keep, dtype=jnp.int32),)
+
+
 def run_work(table: jax.Array, first: jax.Array, count: int, src_col,
              page_size: int, num_pages: int, window: int):
     """The work list of writing positions ``[first[r], first[r] + count)``
@@ -710,11 +721,7 @@ def run_work(table: jax.Array, first: jax.Array, count: int, src_col,
     col = src_col(row, lo_pos)                 # source lane of column lo
     work = (jnp.clip(page, 0, num_pages - 1), row, col // window,
             (lo - col) % window, lo, hi_pos - entry * ps)
-    # the kept steps first, in order; what follows them never runs
-    order = jnp.argsort(jnp.logical_not(keep).reshape(-1), stable=True)
-    return tuple(jnp.where(keep, x, 0).reshape(-1)[order]
-                 .astype(jnp.int32) for x in work) \
-        + (jnp.sum(keep, dtype=jnp.int32),)
+    return kept_first(keep, *work)
 
 
 def paged_write_columns(leaf: jax.Array, layer, cols: jax.Array,

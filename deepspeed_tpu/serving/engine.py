@@ -201,6 +201,32 @@ class ServingEngine:
         # paged_kv: False (contiguous rows), True (paged, defaults), or a
         # dict {"num_pages": int, "page_size": int, "prefix_cache": bool}
         capacity = int(spec.max_seq_len)
+        # a recurrent state in the K/V's place (power_retention layers):
+        # what it does not compose with yet refuses here, by mechanism,
+        # before anything is allocated (ROADMAP.md, Reach)
+        self._state_row_bytes = int(getattr(spec, "state_bytes_per_row", 0))
+        if self._state_row_bytes:
+            mesh = getattr(engine, "mesh", None)
+            refused = [
+                (spec_decode, "spec_decode",
+                 "a rejected draft's tokens are in the state for good: "
+                 "verify_k's rollback moves an index, and a state has none "
+                 "(it would have to keep the state from before the draft)"),
+                (paged_kv, "paged_kv",
+                 "a state has no positions to page, and a prefix hit would "
+                 "need a snapshot of the state at the hit's boundary; a "
+                 "state group beside paged K/V is a later change"),
+                (role != "both", "prefill/decode roles",
+                 "pages are the unit of a handoff and a state has none: the "
+                 "state itself would have to be shipped"),
+                (mesh is not None and mesh.shape.get("model", 1) > 1,
+                 "tensor-parallel serving",
+                 "the state leaves have no placement on the model axis"),
+            ]
+            for given, what, why in refused:
+                if given:
+                    raise ValueError(f"{what} does not compose with a "
+                                     f"recurrent state yet: {why}")
         if paged_kv:
             knobs = dict(paged_kv) if isinstance(paged_kv, dict) else {}
             page_size = knobs.pop("page_size", None)
@@ -365,6 +391,9 @@ class ServingEngine:
         # cost model is attached (the fleet aggregator's fallback)
         self.step_wall_s = 0.0
         self.registry.add_collector(self._collect_telemetry_health)
+        if self._state_row_bytes:
+            self.registry.gauge("serving/state_bytes_resident").set(
+                float(self._state_row_bytes * num_slots))
         if self._paged:
             # pool-internal events (CoW copies, trie evictions) land in
             # the same registry as the engine-side paging/* series
@@ -1043,6 +1072,19 @@ class ServingEngine:
         self._dispatched.update(
             {f"moe_{name}": val for name, val in step.items()})
 
+    def _note_state_rows(self, sp, rows: int) -> None:
+        """``state_rows`` on a dispatch's span, for a model with a
+        recurrent state: the rows whose state the program reads and
+        writes, from the host's own running set (no device read). The
+        step's span gathers them, with the bytes they stand for over the
+        layers (a row's state read once and written once)."""
+        if not self._state_row_bytes:
+            return
+        sp.set(state_rows=rows)
+        d = self._dispatched
+        d["state_rows"] = d.get("state_rows", 0) + rows
+        d["state_bytes"] = 2 * self._state_row_bytes * d["state_rows"]
+
     def _note_admit(self, rows: int, padded_tokens: int) -> None:
         """An admission program of this step: requests seated, and the
         tokens it computes (rows x bucket width, padding included)."""
@@ -1078,6 +1120,7 @@ class ServingEngine:
             self._note_admit(1, width)
             with self.tracer.span("serving/admit", rid=req.request_id,
                                   tokens=T, width=width) as sp:
+                self._note_state_rows(sp, 1)
                 logits, pre_cache = eng._jit_prefill_at(
                     eng.params, jnp.asarray(ids),
                     jnp.asarray(T - 1, jnp.int32))
@@ -1362,6 +1405,7 @@ class ServingEngine:
             self._note_admit(n, nB * width)
             with self.tracer.span("serving/prefill_batch", n=n, width=width,
                                   batch=nB) as sp:
+                self._note_state_rows(sp, n)
                 logits, pre_cache = eng._jit_prefill_at(
                     eng.params, jnp.asarray(ids), jnp.asarray(last_pos))
                 self.pool.admit_rows(pre_cache, slots, lengths)
@@ -1446,6 +1490,7 @@ class ServingEngine:
         self._dispatched["chunk"] = L
         with self.tracer.span("serving/prefill_chunk", rid=req.request_id,
                               pos=pos, len=L) as sp:
+            self._note_state_rows(sp, 1)
             if self._paged:
                 logits = self.pool.run_prefill_chunk(
                     self.engine, ids, slot, pos, L, L - 1)
@@ -2117,7 +2162,19 @@ class ServingEngine:
         tokens = self._cur_dev[:, None]
         pos = jnp.asarray(self.pool.positions())
         self._dispatched["decode"] = len(running)
+        more = ()
+        if self._state_row_bytes:
+            # the rows that run: every other row (free, or seated and
+            # still prefilling) is out of range, no step of the state
+            # kernels, and keeps its state bit for bit. The index
+            # rollback below is what hides such a row's K/V column; it
+            # does nothing for a state.
+            rows = np.full((self.pool.num_slots,), -1, np.int32)
+            for slot, _ in running:
+                rows[slot] = slot
+            more = (self._cur_commit(rows),)
         with self.tracer.span("serving/decode", live=len(running)) as sp:
+            self._note_state_rows(sp, len(running))
             if self._paged:
                 logits = self.pool.run_decode(eng, tokens, pos)
                 # counted after the dispatch, from a mirror the dispatch
@@ -2128,7 +2185,7 @@ class ServingEngine:
                 self._set_pool_reads(sp, 1)
             else:
                 logits, cache = eng._jit_decode(eng.params, self.pool.cache,
-                                                tokens, pos)
+                                                tokens, pos, *more)
         if self.faults is not None:
             logits, _ = self.faults.corrupt_logits(
                 logits, [slot for slot, _ in running])

@@ -16,6 +16,17 @@ row with a dynamic-index update (slot id is a traced operand — one
 compile covers every slot). The prefill cache is allocated at full
 ``max_seq_len`` by ``_CacheStore``, so the row write overwrites ALL of
 the retired occupant's stale state, scales and garbage included.
+
+A model of ``power_retention`` layers keeps a recurrent state in the
+K/V's place (``KVCacheSpec.state``: one leaf ``s`` ``(L, num_slots, KV,
+...)`` float32 and ``index``, no ``k`` / ``v``). The same data movement
+carries it: ``admit`` / ``admit_rows`` write a prefilled state over the
+row. What differs is what hides a row that does not run: a stale K/V
+column is behind the row's ``index``, a state is behind nothing, so the
+decode program is TOLD the rows that run (``ServingEngine._decode_step``)
+and touches no other, and a seated request's first chunk, at position 0,
+reads no state (``ops/attention/power_retention.py``): ``reset_row``
+still moves the index alone.
 """
 
 from __future__ import annotations

@@ -257,6 +257,75 @@ def test_mellum_kernels_compile_for_a_described_v5e_at_the_served_shape(
         compilation_cache.reset_cache()
 
 
+def test_retention_kernels_compile_for_a_described_v5e_at_the_served_shape(
+        compiled_kernels, described_v5e):
+    """The kernels PR 32 brought, through Mosaic at the widths of
+    ``perf/configs/brumby-14b-retention.json``: 16 rows' tokens through
+    ``retention_decode`` and one row's 128-token chunk through
+    ``retention_chunk`` on the pool's stacked leaf (8 layers x 16 slots x 8
+    KV heads of (66, 128, 128) float32, 4.43 GB). A block in and one out,
+    double-buffered, pass the default scoped VMEM limit, and a lane rotation
+    wants whole 128-lane rows: both fail only here. The leaf goes in and
+    comes out in one buffer: no operation of the program copies it. And a
+    leaf small enough for the chip's fast memory stays in HBM."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from deepspeed_tpu.ops.attention import power_retention as pr
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=described_v5e)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    L, R, KV, H, d = 8, 16, 8, 40, 128
+    leaf = shape((L, R, KV) + pr.state_shape(d))
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for name, fn, B, tokens in (("retention_decode", pr.retention_decode,
+                                     R, ()),
+                                    ("retention_chunk", pr.retention_chunk,
+                                     1, (128,))):
+            compiled = jax.jit(fn, donate_argnums=4).lower(
+                shape((B,) + tokens + (H, d), bf16),
+                shape((B,) + tokens + (KV, d), bf16),
+                shape((B,) + tokens + (KV, d), bf16),
+                shape((B,) + tokens + (KV,)), leaf, shape((), i32),
+                shape((B,), i32), shape((B,), jnp.bool_)).compile()
+            text = compiled.as_text()
+            assert name in text and text.count("tpu_custom_call") == 1
+            assert "may-alias" in text
+            # the leaf is 4.43 GB: a copy or a slice of it would show
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
+        # the small leaf that read wrong on the chip (PR 32): one row of one
+        # layer, 34.6 MB, made inside the program and carried through a
+        # layer scan. Left to XLA it gets the fast memory (``S(1)`` on the
+        # custom call's aliased result), where the Mosaic operand read
+        # nothing; the kernels pin it to HBM, whatever its size
+        def scan_layers(q, k, v, log_g, s0, rows, fresh):
+            def layer(carry, li):
+                s, acc = carry
+                o, s = pr.retention_decode(q, k, v, log_g, s, li, rows, fresh)
+                return (s, acc + o), None
+            return jax.lax.scan(layer, (s0 * 0.5, jnp.zeros(q.shape)),
+                                jnp.arange(s0.shape[0]))[0]
+
+        small = shape((1, 1, KV) + pr.state_shape(d))
+        text = jax.jit(scan_layers).lower(
+            shape((1, H, d), bf16), shape((1, KV, d), bf16),
+            shape((1, KV, d), bf16), shape((1, KV)), small,
+            shape((1,), i32), shape((1,), jnp.bool_)).compile().as_text()
+        call, = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and " custom-call(" in line]
+        state_out = call.split("= (")[1].split(", f32[")[0]
+        assert state_out.startswith("f32[1,1,8,66,128,128]"), call[:300]
+        assert "S(1)" not in state_out, state_out
+        assert '"input_memory_space_colors":[{"operand_index":"8",' \
+               '"color":"0"' in call and '"output_memory_colors":["0"' in call
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 # the lowered programs of a small paged server: what touches a pool leaf
 _LEAF_OPS = ("dynamic_slice", "dynamic_update_slice", "scatter", "transpose",
              "gather", "pad", "concatenate", "convert", "select")
