@@ -498,7 +498,8 @@ class CachedAttention(nn.Module):
         return backend.on_tpu()
 
     def _paged_decode_step(self, q, k, v, kv_cache):
-        """Decode/verify step over PAGED storage. ``kv_cache`` holds the
+        """Decode, verify or prefill-chunk step (T = 1, K + 1, the chunk
+        width) over PAGED storage. ``kv_cache`` holds the
         pool's STACKED leaves whole ((L, P, KV, cache_d, lanes), no
         batch axis) with ``layer``, ``start`` and ``table``: this step's
         K/V columns go into this layer's pages through the table
@@ -521,15 +522,10 @@ class CachedAttention(nn.Module):
         B, T, H, D = q.shape
         kv_packed = kv_cache_spec(cfg)[2]
         from ..ops.attention.paged_attention import (
-            MAX_QUERY_ROWS,
             paged_decode_attention,
             paged_write_columns,
         )
 
-        assert T <= MAX_QUERY_ROWS, \
-            (f"paged-kernel decode handles T <= {MAX_QUERY_ROWS} query "
-             f"rows (plain decode and speculative verify); T={T} callers "
-             f"take the dense-composition path")
         start = kv_cache["start"]
         assert jnp.ndim(start) == 1, \
             "paged decode is slot-pooled: start must be (B,)"
@@ -1557,7 +1553,8 @@ class TransformerLM(nn.Module):
                 xb, i, 1, 0))(x, idx)
         return self._project_head(x)
 
-    def prefill_chunk(self, input_ids, start_pos, last_idx, rows=None):
+    def prefill_chunk(self, input_ids, start_pos, last_idx, rows=None,
+                      table=None):
         """Chunked serving prefill: process a fixed-width (B, C) token
         chunk AGAINST the allocated cache at per-slot offsets and project
         only ``last_idx`` onto the vocabulary, returning (B, 1, V).
@@ -1580,12 +1577,19 @@ class TransformerLM(nn.Module):
         A recurrent state has no index to hide padding behind: tokens past
         ``last_idx`` are padding to it and leave it alone, and ``rows``
         (B,) names the row of the provided cache each entry of the batch
-        is (a server hands in its whole pool and one row's chunk)."""
+        is (a server hands in its whole pool and one row's chunk).
+
+        With ``table`` the provided cache is the PAGE POOL and the chunk
+        goes through the pages as a :meth:`decode_paged` step of C rows
+        does: every layer writes the chunk's columns into its pages
+        through the entries' (B, pages_per_slot) table rows (one a layer
+        group, as a dict) and reads them back in place, so no dense row
+        of ``max_seq_len`` positions is built or scored."""
         B, T = input_ids.shape
         off = start_pos[:, None] if jnp.ndim(start_pos) == 1 else start_pos
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))
         x = self._transform(input_ids, pos, True, True, head=False,
-                            state_rows=rows,
+                            paged_table=table, state_rows=rows,
                             valid_len=jnp.asarray(last_idx, jnp.int32) + 1)
         idx = jnp.broadcast_to(jnp.asarray(last_idx, jnp.int32), (B,))
         x = jax.vmap(lambda xb, i: jax.lax.dynamic_slice_in_dim(
@@ -1620,11 +1624,13 @@ class TransformerLM(nn.Module):
         pages_per_slot) int32 page table (sentinel = num_pages). Column
         writes go through the table and attention reads pages in place,
         both inside Pallas calls on the whole leaf — no dense per-slot
-        view and no slice of a leaf is ever materialized. ``start_pos`` must be the per-slot (B,) cache
-        lengths; handles 1 <= T <= MAX_QUERY_ROWS query rows (plain
-        decode and speculative verify). Call with ``mutable=["cache"]``;
-        greedy output is bitwise-identical to the dense-oracle
-        :meth:`decode` over ``dense_from_pages`` of the same pool."""
+        view and no slice of a leaf is ever materialized. ``start_pos``
+        must be the per-slot (B,) cache lengths; T is 1 for plain decode
+        and K + 1 for speculative verify (a prefill chunk's rows go the
+        same way through :meth:`prefill_chunk` with a ``table``). Call
+        with ``mutable=["cache"]``; greedy output is bitwise-identical
+        to the dense-oracle :meth:`decode` over ``dense_from_pages`` of
+        the same pool."""
         B, T = input_ids.shape
         off = start_pos[:, None] if jnp.ndim(start_pos) == 1 else start_pos
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))

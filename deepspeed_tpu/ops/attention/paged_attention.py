@@ -55,15 +55,29 @@ of one layer is contiguous over its heads, so all of them arrive in one
 DMA (16 heads of 128 × 64 bf16: 256 KB, 512 KB in VMEM because a 64-wide
 page fills half of each 128-lane tile — in HBM too). The query and output
 blocks carry the ``kv_group`` KV heads of the slot with the rows of each
-one's ``rep`` query heads stacked (``rep * 8`` rows: GQA's query heads of
-a KV head share its page, so they are one operand of one product), the
-scratch (``acc``, ``m``, ``l``) has the same KV-head axis, and the fold
-runs once a KV head inside the step (a static loop; with ``rep`` 1 a KV
-head is a query head). :func:`plan_grid` picks ``kv_group`` from the shapes against a fixed
-VMEM budget (:data:`VMEM_BUDGET_BYTES`, half the v5e's default scoped
-limit): every KV head when one page of each fits, else the largest divisor
-of ``KV`` that does, and the head-group axis comes back into the grid.
-No option selects any of this.
+one's ``rep`` query heads stacked (``rep * T_pad`` rows, ``T_pad`` the
+call's query rows in whole sublane tiles: GQA's query heads of a KV head
+share its page, so they are one operand of one product), the scratch
+(``acc``, ``m``, ``l``) has the same KV-head axis, and the fold runs once
+a KV head inside the step (a static loop; with ``rep`` 1 a KV head is a
+query head). :func:`plan_grid` picks ``kv_group`` from the shapes against
+a fixed VMEM budget (:data:`VMEM_BUDGET_BYTES`, half the v5e's default
+scoped limit): every KV head when one page of each fits, else the largest
+divisor of ``KV`` that does, and the head-group axis comes back into the
+grid. No option selects any of this.
+
+**Query rows** (PR 33). The row count is a static shape like any other:
+one row a slot for a decode step, K + 1 for a verify step, and a prefill
+chunk's 64 or 128 rows of the one slot it runs, which until PR 33 took a
+gathered dense row of ``max_seq_len`` positions in every layer because
+the kernel stopped at one sublane tile. Everything that held the tile's 8
+holds ``T_pad`` (blocks, scratch, the VMEM plan: 16 KV heads of 64 rows a
+step at Pythia's shape, 2 of 4 KV heads of 8 x 128 = 1,024 rows at
+Mellum's), the per-row causal limit and the window mask are what they
+were, and a call of up to 8 rows compiles to the program it compiled to
+before (``tests/unit/accelerator/test_chip_path.py`` holds the decode
+program's text). Rows that not even one KV head's step can hold go in two
+calls of half the rows each.
 
 **What was measured** (v5e, stand-alone at the served shape B=64, H=KV=16,
 D=128, page 64, 32 table entries a slot, 256 pages; chip runs of PR 24):
@@ -110,10 +124,11 @@ what the XLA scatter's ``mode="drop"`` dropped (a sentinel page, a
 position out of range) is NOT in the list, so it touches nothing. Two
 steps may name one page only if they follow each other (the block is
 then still in VMEM and the second goes on from it). Three shapes of
-write share the kernel: a step's 1-8 columns a slot into one layer
-(:func:`paged_write_columns`, the model's decode and verify), a run of a
-dense cache's columns into every layer (:func:`paged_write_runs`: the
-chunk's window, whole prefilled rows, the kernel-off compositions), and
+write share the kernel: a step's columns a slot into one layer
+(:func:`paged_write_columns`: the model's decode, verify and, since
+PR 33, chunk steps, a window of the source at a time), a run of a dense
+cache's columns into every layer (:func:`paged_write_runs`: whole
+prefilled rows, the kernel-off compositions), and
 the fp32 scale leaves ``(L, P, KV, page_size)`` of the quantized tiers,
 which go through the same call with the heads in the stored dim's place
 (less code than keeping a scatter for them).
@@ -122,8 +137,9 @@ Garbage is masked by length, never by table lookups: sentinel table
 entries (``num_pages`` = unmapped) clip to a real page exactly like the
 dense gather's ``mode="clip"``, and stale columns past the live length
 inside the last live page are masked to ``NEG_INF`` before the softmax,
-so their values never reach the output. Supports 1..SUBLANES query rows
-per slot (plain decode T=1; speculative verify T=K+1) with per-row causal
+so their values never reach the output. Any number of query rows a
+slot (plain decode T=1; speculative verify T=K+1; a prefill chunk's
+width) with per-row causal
 masking, GQA, ALiBi, and the int8/int32-packed quantized cache tiers
 (scales paged alongside, folded into the score/probability rows like the
 dense kernel).
@@ -145,12 +161,7 @@ from .flash_attention import LANES, NEG_INF, SUBLANES
 
 __all__ = ["paged_decode_attention", "paged_write_columns",
            "paged_write_runs", "plan_grid", "plan_write", "live_pages",
-           "kept_first", "MAX_QUERY_ROWS"]
-
-# one kernel serves decode (T=1) and speculative verify (T=K+1): query
-# rows live on the SUBLANES axis of the score tile, so the row budget is
-# the sublane count — pools fall back to the dense composition beyond it
-MAX_QUERY_ROWS = SUBLANES
+           "kept_first"]
 
 # what one grid step may hold in VMEM: half of the v5e's default scoped
 # limit (16 MiB), so the compiler's own temporaries fit beside it and no
@@ -159,38 +170,53 @@ VMEM_BUDGET_BYTES = 8 * 2 ** 20
 
 
 def plan_grid(B: int, H: int, KV: int, D: int, Dc: int, page_size: int,
-              pages_per_slot: int, kv_dtype, q_dtype, quantized: bool):
+              pages_per_slot: int, kv_dtype, q_dtype, quantized: bool,
+              query_rows: int = 1):
     """``(kv_group, pages_per_step, grid)`` for one call, from its static
     shapes alone.
 
     One grid step holds one page of ``kv_group`` KV heads (K and V, and
     the scale rows of a quantized pool, each double-buffered by the
     pipeline) beside the query and output blocks of the group's heads
-    and the fp32 accumulator and softmax statistics. ``kv_group`` is all
-    of ``KV`` when that fits :data:`VMEM_BUDGET_BYTES`, else the largest
-    divisor of ``KV`` that does — the head-group axis then comes back
-    into the grid. ``pages_per_step`` is 1: on the v5e every further
+    (``query_rows`` rows a head, in whole sublane tiles) and the fp32
+    accumulator and softmax statistics of as many rows. ``kv_group`` is
+    all of ``KV`` when that fits :data:`VMEM_BUDGET_BYTES`, else the
+    largest divisor of ``KV`` that does — the head-group axis then comes
+    back into the grid — and 1 where not even one head fits (a call of
+    more than one sublane tile of rows is then made in two halves by
+    :func:`paged_decode_attention`). ``pages_per_step`` is 1: on the v5e every further
     page operand of a step cost more than the grid step it saved (3
     pages a step: 1.02 ms against 0.82 ms a call at the served shape,
     chip run of PR 24). ``grid`` is ``(KV // kv_group, B *
     pages_per_slot)``, the second a bound: the call runs one step for
     each entry of :func:`live_pages`, not for each table entry."""
-    rep = H // KV
-
-    def step_bytes(kv_group: int) -> int:
-        heads = kv_group * rep
-        page = 2 * _vmem_tile_bytes(Dc, page_size, kv_dtype)      # K and V
-        if quantized:
-            page += 2 * _vmem_tile_bytes(1, page_size, jnp.float32)
-        blocks = 2 * heads * _vmem_tile_bytes(SUBLANES, D, q_dtype)
-        scratch = heads * (_vmem_tile_bytes(SUBLANES, D, jnp.float32)
-                           + 2 * _vmem_tile_bytes(SUBLANES, LANES,
-                                                  jnp.float32))
-        return 2 * (kv_group * page + blocks) + scratch
-
+    step_bytes = functools.partial(
+        _step_bytes, rep=H // KV, rows=_row_tiles(query_rows), D=D, Dc=Dc,
+        page_size=page_size, kv_dtype=kv_dtype, q_dtype=q_dtype,
+        quantized=quantized)
     kv_group = max((g for g in range(1, KV + 1) if KV % g == 0
                     and step_bytes(g) <= VMEM_BUDGET_BYTES), default=1)
     return kv_group, 1, (KV // kv_group, B * pages_per_slot)
+
+
+def _row_tiles(query_rows: int) -> int:
+    """``query_rows`` in whole sublane tiles: the rows a query head takes
+    in a block."""
+    return -(-query_rows // SUBLANES) * SUBLANES
+
+
+def _step_bytes(kv_group: int, *, rep: int, rows: int, D: int, Dc: int,
+                page_size: int, kv_dtype, q_dtype, quantized: bool) -> int:
+    """VMEM of one grid step of the read with ``kv_group`` KV heads of
+    ``rep`` query heads, ``rows`` query rows each."""
+    heads = kv_group * rep
+    page = 2 * _vmem_tile_bytes(Dc, page_size, kv_dtype)      # K and V
+    if quantized:
+        page += 2 * _vmem_tile_bytes(1, page_size, jnp.float32)
+    blocks = 2 * heads * _vmem_tile_bytes(rows, D, q_dtype)
+    scratch = heads * (_vmem_tile_bytes(rows, D, jnp.float32)
+                       + 2 * _vmem_tile_bytes(rows, LANES, jnp.float32))
+    return 2 * (kv_group * page + blocks) + scratch
 
 
 def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
@@ -298,7 +324,8 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
     o_ref, acc_ref, m_ref, l_ref = refs
     g, w = pl.program_id(0), pl.program_id(1)
     kv_group = k_ref.shape[2]
-    rows = rep * SUBLANES
+    rows = q_ref.shape[2]           # of one KV head: rep heads of t_pad rows
+    t_pad = rows // rep
     entry = entry_ref[w]
     slot = slot_ref[w]
     start = start_ref[slot]
@@ -313,17 +340,18 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     # GQA: the rep query heads of a KV head share its page, so their
-    # SUBLANES rows each stand in ONE (rep * SUBLANES, D) operand and the
-    # fold runs once a KV head (a fold a query head, 8 rows each, was 32
-    # small products a step at 32 / 4 heads and cost a decode token twice
-    # a prefill token: PERF.md section 6, PR 30). Row r is query row
-    # r % SUBLANES of head r // SUBLANES; with rep == 1 this is the fold
-    # it always was.
+    # t_pad rows each (the call's query rows in whole sublane tiles: 8
+    # for a decode or a narrow verify step, a chunk's 64 or 128) stand
+    # in ONE (rep * t_pad, D) operand and the fold runs once a KV head
+    # (a fold a query head, 8 rows each, was 32 small products a step at
+    # 32 / 4 heads and cost a decode token twice a prefill token:
+    # PERF.md section 6, PR 30). Row r is query row r % t_pad of head
+    # r // t_pad; with rep == 1 this is the fold it always was.
     pos = block_start + jax.lax.broadcasted_iota(
         jnp.int32, (rows, page_size), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
     if rep > 1:
-        head_of, row = row // SUBLANES, row % SUBLANES
+        head_of, row = row // t_pad, row % t_pad
     for c in range(kv_group):
         q = q_ref[0, c]                                   # (rows, D)
         k = k_ref[0, 0, c][:, :page_size]                 # (Dc, page_size)
@@ -394,10 +422,12 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     K/V resolved through a per-slot page table inside the kernel.
 
     Args:
-      q: (B, T, H, D) current-step queries, 1 <= T <= MAX_QUERY_ROWS.
-        Row ``t`` of slot ``b`` attends cache positions
-        ``[0, starts[b] + t]`` (its own column included — the caller has
-        already written this step's T columns into the pages).
+      q: (B, T, H, D) current-step queries: one row a slot for a decode
+        step, K + 1 for a verify step, a prefill chunk's 64 or 128 for
+        the one slot it runs. Row ``t`` of slot ``b`` attends cache
+        positions ``[0, starts[b] + t]`` (its own column included — the
+        caller has already written this step's T columns into the
+        pages).
       k_pages/v_pages: (L, P, KV, Dc, lanes) the pool's STACKED leaf,
         read at ``layer`` through the index map: no slice of it is ever
         made. H % KV == 0 (GQA). May be int8, or int32-packed
@@ -472,8 +502,6 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
     assert ps <= lanes, f"page of {ps} in {lanes} lanes"
     maxP = table.shape[1]
     assert H % KV == 0, f"H={H} not a multiple of KV={KV}"
-    assert 1 <= T <= MAX_QUERY_ROWS, \
-        f"paged kernel handles 1..{MAX_QUERY_ROWS} query rows, got {T}"
     assert (k_scale_pages is None) == (v_scale_pages is None), \
         "provide both k_scale_pages and v_scale_pages or neither"
     quantized = k_scale_pages is not None
@@ -492,6 +520,22 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
         q = q.astype(k_pages.dtype)
     if scale is None:
         scale = 1.0 / math.sqrt(D)
+    if T > SUBLANES and _step_bytes(
+            1, rep=rep, rows=_row_tiles(T), D=D, Dc=Dc, page_size=lanes,
+            kv_dtype=k_pages.dtype, q_dtype=q.dtype,
+            quantized=quantized) > VMEM_BUDGET_BYTES:
+        # not even one KV head's rows fit a step: two calls of half the
+        # query rows each, the second ``half`` positions further on
+        half = _row_tiles(-(-T // 2))
+        part = functools.partial(
+            _paged_decode_attention_local, k_pages=k_pages, v_pages=v_pages,
+            table=table, layer=layer, scale=scale, page_size=ps,
+            alibi_slopes=alibi_slopes, k_scale_pages=k_scale_pages,
+            v_scale_pages=v_scale_pages, window=window, active=active)
+        return jnp.concatenate(
+            [part(q[:, :half], starts=starts),
+             part(q[:, half:], starts=starts + half)],
+            axis=1).astype(out_dtype)
     if alibi_slopes is None:
         slopes = jnp.zeros((H,), jnp.float32)
         alibi = False
@@ -500,19 +544,21 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
         alibi = True
     table = jnp.asarray(table, jnp.int32)
 
-    # query rows ride the SUBLANES axis: pad T up to the full sublane
-    # tile (dead rows compute with a wider causal window and are sliced
-    # off — never all-masked, so no NaN risk)
+    # query rows ride the sublane axis: pad T up to whole sublane tiles
+    # (dead rows compute with a wider causal window and are sliced off —
+    # never all-masked, so no NaN risk)
+    t_pad = _row_tiles(T)
     q4 = q.transpose(0, 2, 1, 3)                          # (B, H, T, D)
-    if T < SUBLANES:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, SUBLANES - T), (0, 0)))
+    if T < t_pad:
+        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, t_pad - T), (0, 0)))
 
-    # a KV head's rep query heads, SUBLANES rows each, as one operand
-    rows = rep * SUBLANES
+    # a KV head's rep query heads, t_pad rows each, as one operand
+    rows = rep * t_pad
     q4 = q4.reshape(B, KV, rows, D)
 
     kv_group, _, (groups, _) = plan_grid(
-        B, H, KV, D, Dc, lanes, maxP, k_pages.dtype, q.dtype, quantized)
+        B, H, KV, D, Dc, lanes, maxP, k_pages.dtype, q.dtype, quantized,
+        query_rows=T)
     slot_of, entry_of, page_of, live, total, *first = live_pages(
         starts, table, T, ps, P, window)
     if active is not None:
@@ -566,7 +612,7 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
         interpret=backend.pallas_interpret(),
     )(slot_of, entry_of, page_of, live, starts, slopes, layer, *first, q4,
       *pools)
-    out = out.reshape(B, H, SUBLANES, D)[:, :, :T]
+    out = out.reshape(B, H, t_pad, D)[:, :, :T]
     return out.transpose(0, 2, 1, 3).astype(out_dtype)
 
 
@@ -728,8 +774,8 @@ def paged_write_columns(leaf: jax.Array, layer, cols: jax.Array,
                         table: jax.Array, starts: jax.Array, *,
                         page_size: Optional[int] = None,
                         active=None) -> jax.Array:
-    """One decode or verify step's new columns into ONE layer of the
-    stacked leaf, in place: ``cols`` (B, KV, Dc, T) goes to positions
+    """One decode, verify or chunk step's new columns into ONE layer of
+    the stacked leaf, in place: ``cols`` (B, KV, Dc, T) goes to positions
     ``starts[b] .. starts[b] + T - 1`` of slot ``b`` through ``table``
     (B, pages_per_slot). A scale leaf (L, P, KV, lanes) takes
     (B, KV, T). ``page_size`` as in :func:`paged_decode_attention`;
@@ -757,12 +803,20 @@ def paged_write_columns(leaf: jax.Array, layer, cols: jax.Array,
 
 def _paged_write_columns_local(leaf, layer, cols, table, starts, *,
                                page_size, active=None):
+    window = math.lcm(page_size, LANES)
+    if cols.shape[-1] > window:
+        # a slot's columns stand inside one window of the source: a run
+        # wider than that goes window by window
+        for j in range(0, cols.shape[-1], window):
+            leaf = _paged_write_columns_local(
+                leaf, layer, cols[..., j:j + window], table, starts + j,
+                page_size=page_size, active=active)
+        return leaf
     scale_leaf = leaf.ndim == 4
     if scale_leaf:            # (L, P, KV, lanes): the heads take Dc's place
         leaf, cols = leaf[:, :, None], cols[:, None]
     L, P, KV, Dc, lanes = leaf.shape
     B, T = cols.shape[0], cols.shape[-1]
-    _, window = plan_write(KV, Dc, page_size, lanes, leaf.dtype)
     # slot b's columns at lanes [b * stride, b * stride + T) of one
     # positions-minor row: a power of two, so that no slot's columns
     # straddle two windows

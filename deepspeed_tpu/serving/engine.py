@@ -1495,6 +1495,7 @@ class ServingEngine:
                 logits = self.pool.run_prefill_chunk(
                     self.engine, ids, slot, pos, L, L - 1)
                 sp.set(pool_writes=self.pool.pages_touched(slot, pos, C))
+                self._set_pool_reads(sp, C, [slot], [pos])
             else:
                 logits, cache = self.engine.prefill_chunk(
                     self.pool.cache, ids, slot, pos, L, L - 1)
@@ -2139,13 +2140,15 @@ class ServingEngine:
                 self._fail_slot(req, FinishReason.NUMERICAL_ERROR)
         return ok
 
-    def _set_pool_reads(self, sp, rows: int) -> None:
-        """``pool_reads`` / ``read_slots`` on a decode or verify span
-        whose dispatch read the pool through the kernel: the grid steps
-        of its work list for one layer of each page group, and the slots
-        in it (counted like ``pool_writes``: after the dispatch, from
-        the host's mirror)."""
-        work = self.pool.pages_read(rows)
+    def _set_pool_reads(self, sp, rows: int, slots=None,
+                        starts=None) -> None:
+        """``pool_reads`` / ``read_slots`` on a decode, verify or chunk
+        span whose dispatch read the pool through the kernel: the grid
+        steps of its work list for one layer of each page group, and the
+        slots in it (counted like ``pool_writes``: after the dispatch,
+        from the host's mirror). A chunk names its one slot and where it
+        starts."""
+        work = self.pool.pages_read(rows, slots, starts)
         if work is not None:
             sp.set(pool_reads=work[0], read_slots=work[1])
 

@@ -24,8 +24,8 @@ Terminology map (for readers coming from the reference systems):
 Shape discipline is identical to the contiguous
 :class:`~deepspeed_tpu.serving.slot_pool.SlotPool`: physical storage is
 ONE statically-shaped pytree — k/v ``(L, num_pages, KV, cache_d,
-lanes)``, a page in the first ``page_size`` of its lanes — and every
-jitted entry (decode, ``verify_k``,
+lanes)``, a page in the first ``page_size`` of its lanes — and with the
+kernel off every jitted entry (decode, ``verify_k``,
 ``prefill_chunk``, batched admission) is a gather → existing traced
 attention program → scatter composition:
 :meth:`KVCacheSpec.dense_from_pages` reassembles the dense ``(L, B, KV,
@@ -37,14 +37,18 @@ preempt/resume are all data movement inside the same buffers: zero
 post-warmup recompiles, watchdog-enforced. The transient dense view is
 scratch the compiler can schedule; the *persistent* HBM footprint is
 the page pool — which is the served-requests-per-GB lever. With the
-kernel active, decode and verify skip the dense view: the model reads
-pages in place (``decode_paged``).
+kernel active, decode, verify and (since PR 33) the prefill chunk skip
+the dense view: the model writes and reads pages in place
+(``decode_paged``; ``prefill_chunk`` with a table), and the dense
+composition is the oracle they are tested against, not a served path.
+Batched admission of short prompts still hands in a full-capacity
+prefill cache (ROADMAP S4).
 
 ONE write path leads into the pool (PR 27): ``paged_write``
 (``ops/attention/paged_attention.py``), a Pallas call that takes a
 stacked leaf whole, rewrites the pages its work list names and returns
-the leaf aliased — from the model's decode step for its own columns,
-and from :meth:`PagedKVPool._write_runs` for the chunk's window,
+the leaf aliased — from the model's decode, verify and chunk steps for
+their own columns, and from :meth:`PagedKVPool._write_runs` for
 admitted rows and the kernel-off compositions. A page is stored in
 whole 128-lane tiles (``models.transformer_lm.page_lanes``: k/v
 ``(L, num_pages, KV, cache_d, lanes)``), the one shape whose device
@@ -212,12 +216,6 @@ class WindowRing:
         return errors
 
 
-#: mirror of ``ops.attention.paged_attention.MAX_QUERY_ROWS`` as a local
-#: literal so graftcheck can decide the verify-width gate statically;
-#: ``bind_engine`` asserts the two stay equal
-_KERNEL_MAX_QUERY_ROWS = 8
-
-
 class PagedKVPool(SlotPool):
     """Drop-in :class:`SlotPool` with paged storage and prefix caching.
 
@@ -299,8 +297,7 @@ class PagedKVPool(SlotPool):
         # "on" forces the in-place page-table kernel (interpret mode
         # off-TPU — the bitwise-parity/CI configuration); "auto" uses
         # the kernel on TPU only. The dense composition remains the
-        # oracle and fallback either way (chunked prefill always uses
-        # it — chunk widths exceed the kernel's query-row limit).
+        # oracle either way.
         self.kernel = kernel
         self._paged_decode_kernel_jit = None
         self._paged_verify_kernel_jit = None
@@ -791,32 +788,48 @@ class PagedKVPool(SlotPool):
                 spanned & (self.ring.table[slots] != self.ring.num_pages)))
         return touched
 
-    def pages_read(self, count: int):
+    def reads_in_place(self, count: int) -> bool:
+        """Whether a dispatch of ``count`` query rows a slot reads and
+        writes the pages in place (``paged_decode`` / ``paged_write``) or
+        is the dense composition's: the kernel is active, and where there
+        is a window group no row's keys have left the ring before the
+        step is over. A ring maps the entries a LATER step can see
+        (:meth:`WindowRing.make_writable`), so a step of ``window`` rows
+        or more writes columns its own later rows need into entries the
+        ring does not hold; the dense row holds them."""
+        return self._paged_decode_kernel_jit is not None and (
+            self.ring is None or count < self.ring.window)
+
+    def pages_read(self, count: int, slots=None, starts=None):
         """``(steps, slots)`` of the kernel read's work list for a
         dispatch of ``count`` query rows a slot, from the host's mirror
         of the table and ``starts``: the grid steps of ONE layer of each
         page group (``live_pages`` / ``_window_pages`` in NumPy), and how
         many slots have a step at all (``pool_reads`` / ``read_slots`` on
-        the decode and verify spans). A slot whose row maps nothing is
-        not in the list; ``num_slots - slots`` is how many steps a call
-        does not make. ``None`` where the dispatch is the dense
-        composition's (kernel off, or more rows than the kernel takes),
-        which has no work list."""
-        if self._paged_decode_kernel_jit is None \
-                or count > _KERNEL_MAX_QUERY_ROWS:
+        the decode, verify and chunk spans). Every slot at its position
+        for a decode or verify step; ``slots`` at ``starts`` for a chunk,
+        which runs one. A slot whose row maps nothing is not in the list;
+        ``num_slots - slots`` is how many steps a call does not make.
+        ``None`` where the dispatch is the dense composition's
+        (:meth:`reads_in_place`), which has no work list."""
+        if not self.reads_in_place(count):
             return None
         per_slot, ps = self.pages_per_slot, self.page_size
-        start = self.positions().astype(np.int64)
+        if slots is None:
+            slots = np.arange(self.num_slots)
+            starts = self.positions()
+        slots = np.atleast_1d(np.asarray(slots, np.int64))
+        start = np.atleast_1d(np.asarray(starts, np.int64))
         seen = -(-(start + count) // ps)
         # the row's leading mapped entries (argmax: its first sentinel)
-        unmapped = np.pad(self.table == self.num_pages, ((0, 0), (0, 1)),
-                          constant_values=True)
+        unmapped = np.pad(self.table[slots] == self.num_pages,
+                          ((0, 0), (0, 1)), constant_values=True)
         mapped = np.argmax(unmapped, axis=1)
         live = np.clip(np.minimum(seen, mapped), np.minimum(mapped, 1),
                        per_slot)
         if self.ring is not None:
             ring = self.ring
-            mapped = np.max(np.where(ring.table != ring.num_pages,
+            mapped = np.max(np.where(ring.table[slots] != ring.num_pages,
                                      np.arange(per_slot) + 1, 0), axis=1)
             first = np.clip((start - ring.window + 1) // ps, 0,
                             per_slot - 1)
@@ -877,7 +890,9 @@ class PagedKVPool(SlotPool):
         the SAME ``decode_fn`` / verify body / ``prefill_chunk`` method
         the contiguous path compiles runs against the gathered dense
         view, which is what makes paged greedy output bitwise identical
-        to the contiguous pool. Idempotent per engine (rebinding would
+        to the contiguous pool; with the kernel active the same methods
+        take the pool's leaves and tables and go through the pages
+        (:meth:`reads_in_place`). Idempotent per engine (rebinding would
         shed the recompile watchdog's wrappers)."""
         if self._engine is engine and self._paged_decode_jit is not None:
             return
@@ -935,23 +950,41 @@ class PagedKVPool(SlotPool):
 
         def paged_chunk(params, cs, ids, row_table, slot, start, length,
                         last_idx, win_tables=None):
-            # gather ONE slot's dense row from its pages (its table row,
-            # and the window group's where there is one), run the
-            # window-masked chunk, scatter back only the chunk window
+            # ONE slot's chunk through its table row (and the window
+            # group's where there is one)
             row_tables = self._group_tables(
                 row_table[None], None if win_tables is None
                 else win_tables[:1])
-            with jax.named_scope("gather"):
-                dense = gather(cs, row_tables)
-            dense["index"] = start[None]
-            out, vars_ = module.apply(
-                {"params": dequant(params),
-                 "cache": {"cache_store": dense}},
-                ids, start[None], last_idx, method=chunk_gen,
-                mutable=mutable)
-            new = vars_["cache"]["cache_store"]
-            outcs = write_runs(cs, new, row_tables, start[None],
-                               ids.shape[1])
+            if self.reads_in_place(ids.shape[1]):
+                # as kernel_apply does for a decode step: the model
+                # takes the stacked leaves whole and a table of one row,
+                # and every layer writes the chunk's columns into its
+                # pages and reads them back in place. No dense row.
+                vals = {k: v for k, v in cs.items()
+                        if k not in row_tables}
+                vals["index"] = start[None]
+                out, vars_ = module.apply(
+                    {"params": dequant(params),
+                     "cache": {"cache_store": vals}},
+                    ids, start[None], last_idx,
+                    table=row_tables if grouped else row_tables["table"],
+                    method=chunk_gen, mutable=mutable)
+                outcs = dict(vars_["cache"]["cache_store"], **tables_of(cs))
+            else:
+                # the dense composition (the oracle): gather the slot's
+                # dense row from its pages, run the window-masked chunk,
+                # scatter back only the chunk window
+                with jax.named_scope("gather"):
+                    dense = gather(cs, row_tables)
+                dense["index"] = start[None]
+                out, vars_ = module.apply(
+                    {"params": dequant(params),
+                     "cache": {"cache_store": dense}},
+                    ids, start[None], last_idx, method=chunk_gen,
+                    mutable=mutable)
+                new = vars_["cache"]["cache_store"]
+                outcs = write_runs(cs, new, row_tables, start[None],
+                                   ids.shape[1])
             outcs["index"] = cs["index"].at[slot].set(
                 start + jnp.asarray(length, jnp.int32), mode="drop")
             return out, outcs, vars_["stats"]["moe"] if want_stats else None
@@ -974,12 +1007,6 @@ class PagedKVPool(SlotPool):
         # block_s=page_size; see ops/attention/paged_attention.py).
         if self.kernel_active \
                 and getattr(module, "decode_paged", None) is not None:
-            from ..ops.attention.paged_attention import MAX_QUERY_ROWS
-            if MAX_QUERY_ROWS != _KERNEL_MAX_QUERY_ROWS:
-                raise RuntimeError(
-                    f"_KERNEL_MAX_QUERY_ROWS={_KERNEL_MAX_QUERY_ROWS} "
-                    f"drifted from kernel MAX_QUERY_ROWS={MAX_QUERY_ROWS}")
-
             def kernel_apply(params, cache, token, pos):
                 cs = cache["cache_store"]
                 tables = tables_of(cs)
@@ -1075,14 +1102,11 @@ class PagedKVPool(SlotPool):
     def run_verify(self, engine: Any, tokens, pos, draft, draft_len, rng,
                    temperature, greedy, top_k: int, top_p: float):
         """Speculative verify over paged storage (same semantics as
-        ``InferenceEngine.verify_k``); returns ``(out, n_emit)``. The
-        fused kernel handles K+1 query rows up to its sublane-tile limit
-        (``_KERNEL_MAX_QUERY_ROWS``); wider verify chunks stay on the
-        dense composition."""
+        ``InferenceEngine.verify_k``); returns ``(out, n_emit)``. With
+        the kernel active the K + 1 query rows a slot read and write the
+        pages in place whatever K is (:meth:`reads_in_place`)."""
         self.bind_engine(engine)
-        use_kernel = self._paged_verify_kernel_jit is not None \
-            and tokens.shape[1] <= _KERNEL_MAX_QUERY_ROWS
-        if use_kernel:
+        if self.reads_in_place(tokens.shape[1]):
             cs, out, n_emit = self._paged_verify_kernel_jit(
                 engine.params, self.cache["cache_store"], tokens, pos,
                 draft, draft_len, rng, temperature, greedy, int(top_k),

@@ -12,6 +12,7 @@
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -349,19 +350,16 @@ def _ops_on(lowered, shapes):
     return found
 
 
-def test_no_program_of_a_serving_step_passes_over_a_pool_leaf(
-        compiled_kernels):
-    """``kernel_decode``, ``paged_chunk`` and the admission program take the
-    stacked K and V leaves and hand them on through custom calls alone: no
-    slice, update-slice, scatter or transpose of a leaf (one layer's or
-    the stacked one). The chunk program's ``dense_from_pages`` gather of
-    one slot's row stays (ROADMAP S2) and is the one reader allowed."""
+def _small_paged_server(pages=6):
+    """A two-layer GPT-NeoX server over a page pool with the kernel on, and
+    the operands of its three step programs. ``max_seq_len`` is 384, a
+    width nothing else in the model has."""
     import deepspeed_tpu as ds
     from deepspeed_tpu.models.transformer_lm import (TransformerLM,
                                                      transformer_config)
     from deepspeed_tpu.serving.paged_pool import PagedKVPool
 
-    cfg = transformer_config("gpt-neox", vocab_size=128, max_seq_len=256,
+    cfg = transformer_config("gpt-neox", vocab_size=128, max_seq_len=384,
                              n_embd=256, n_layer=2, n_head=2,
                              dtype=jnp.bfloat16)
     model = TransformerLM(cfg)
@@ -374,16 +372,13 @@ def test_no_program_of_a_serving_step_passes_over_a_pool_leaf(
     engine = ds.init_inference(model=model, model_parameters=params,
                                config={"dtype": "bf16"})
     engine._ensure_params(jnp.zeros((1, 2), jnp.int32))
-    slots, pages, ps = 2, 6, 64
+    slots, ps = 2, 64
     pool = PagedKVPool(model.kv_cache_spec(), slots, num_pages=pages,
                        page_size=ps, kernel="on")
     pool.bind_engine(engine)
     cs = pool.cache["cache_store"]
-    lanes = cs["k"].shape[-1]
-    assert cs["k"].shape == (2, pages, 2, 128, lanes) and lanes == 128
-    leaves = {f"tensor<2x{pages}x2x128x{lanes}xbf16>",
-              f"tensor<{pages}x2x128x{lanes}xbf16>",
-              f"tensor<1x{pages}x2x128x{lanes}xbf16>"}
+    assert cs["k"].shape == (2, pages, 2, 128, 128)
+    assert pool.pages_per_slot == 6
     i32 = jnp.int32
     pre = dict(model.kv_cache_spec().stacked_cache(2))
     programs = {
@@ -399,18 +394,133 @@ def test_no_program_of_a_serving_step_passes_over_a_pool_leaf(
             cs, pre, jnp.zeros((2, pool.pages_per_slot), i32),
             jnp.zeros((2,), i32), jnp.zeros((2,), i32))),
     }
+    return pool, programs
+
+
+def test_no_program_of_a_serving_step_passes_over_a_pool_leaf(
+        compiled_kernels):
+    """``kernel_decode``, ``paged_chunk`` and the admission program take the
+    stacked K and V leaves and hand them on through custom calls alone: no
+    slice, update-slice, scatter, gather or transpose of a leaf (one
+    layer's or the stacked one). Since PR 33 the chunk program reads
+    through ``paged_decode`` like the decode program: its gather of one
+    slot's dense row is gone, and with it every value of ``max_seq_len``
+    positions."""
+    pool, programs = _small_paged_server()
+    pages, lanes = pool.num_pages, 128
+    leaves = {f"tensor<2x{pages}x2x128x{lanes}xbf16>",
+              f"tensor<{pages}x2x128x{lanes}xbf16>",
+              f"tensor<1x{pages}x2x128x{lanes}xbf16>"}
     for name, (jitted, args) in programs.items():
         lowered = jitted.trace(*args).lower(lowering_platforms=("tpu",))
         text = lowered.as_text()
         assert "paged_write" in text, name
         ops = [op for op in _ops_on(lowered, leaves)
                if op.split(".")[-1] in _LEAF_OPS]
-        allowed = {"stablehlo.gather"} if name == "paged_chunk" else set()
-        assert set(ops) <= allowed, (name, ops)
-    # and the decode program reads through the kernel, on the same leaf
-    lowered = pool._paged_decode_kernel_jit.trace(
-        *programs["kernel_decode"][1]).lower(lowering_platforms=("tpu",))
-    assert "paged_decode" in lowered.as_text()
+        assert not ops, (name, ops)
+        if name != "_paged_admit_rows":
+            # both read through the kernel, on the same leaf, and neither
+            # holds a dense row (the admission program is handed one)
+            assert "paged_decode" in text, name
+            dims = {d for t in re.findall(r"tensor<((?:\d+x)+)", text)
+                    for d in t.split("x")}
+            assert "128" in dims and "384" not in dims, name
+
+
+def _program_text(compiled) -> str:
+    """A compiled program's HLO without what names this checkout: the
+    stack-frame tables and ``metadata`` (files and line numbers), and each
+    Mosaic kernel as its module's text without locations in place of the
+    serialized one."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    def kernel(match):
+        with jax_mlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return '"body":"' + " ".join(module.operation.get_asm(
+                enable_debug_info=False).split()) + '"'
+
+    lines, tables = [], False
+    for line in compiled.as_text().split("\n"):
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            tables = True
+        elif tables and (not line.strip() or re.match(r"\d+ ", line)):
+            continue
+        else:
+            tables = False
+            lines.append(line)
+    text = re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+    return re.sub(r'"body":"([A-Za-z0-9+/=]+)"', kernel, text)
+
+
+# sha256 of _program_text(kernel_decode) of _small_paged_server(4096) on
+# the parent of PR 33 (commit 2a1c43f): the decode program PR 31 measured
+_KERNEL_DECODE_TEXT = (
+    "eccfe7ce1a6812476b6652393a5e16188a87b33f650a3ef2f2261cfc761aaff9")
+
+
+def test_the_chunk_program_goes_through_the_pages_and_decode_is_unchanged(
+        compiled_kernels, described_v5e):
+    """The two step programs of a paged server, compiled for a described
+    v5e with a pool of 4,096 pages (0.5 GB a leaf: nothing the compiler
+    could move to the chip's fast memory). ``paged_chunk`` (PR 33): the
+    leaves go from parameter to custom call to result and no other
+    operation takes or gives one, no value has ``max_seq_len`` positions,
+    and the temporaries are a chunk's activations. ``kernel_decode``: its
+    text is the parent's, kernels included: a call of up to 8 rows
+    compiles to what it compiled to before the row limit went."""
+    import hashlib
+
+    from jax.experimental.compilation_cache import compilation_cache
+    from deepspeed_tpu.parallel import mesh
+
+    pages = 4096
+    pool, programs = _small_paged_server()
+    # the engine made a mesh of this process's CPU devices; the programs
+    # are compiled for one described chip, as a one-chip server's are
+    mesh.reset_mesh()
+    leaf = f"bf16[2,{pages},2,128,128]"
+
+    def described(x):
+        shape = tuple(pages if d == pool.num_pages else d for d in x.shape) \
+            if x.ndim == 5 else x.shape
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=described_v5e)
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = {
+            name: jitted.lower(
+                *jax.tree_util.tree_map(described, args)).compile()
+            for name, (jitted, args) in programs.items()
+            if name != "_paged_admit_rows"}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    chunk = compiled["paged_chunk"]
+    text = chunk.as_text()
+    calls = re.findall(r"%(\w+)\.\d+ = [^\n]*tpu_custom_call", text)
+    assert sorted(calls) == ["paged_decode", "paged_write", "paged_write"]
+    passes = {"parameter", "custom-call", "get-tuple-element", "tuple",
+              "while", "bitcast"}
+    for line in text.split("\n"):
+        op = re.search(r" = \S+ ([\w-]+)\(", line)
+        if op and leaf in line:
+            assert op.group(1) in passes, line[:300]
+    assert not re.search(r"[\[,]384[\],]", text)
+    assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 23
+    digest = hashlib.sha256(
+        _program_text(compiled["kernel_decode"]).encode()).hexdigest()
+    assert digest == _KERNEL_DECODE_TEXT, (
+        "the compiled decode program is not the one this digest was taken "
+        "of: compare _program_text() of both trees, and pin the new digest "
+        "with the chat cell's gap_p90_ms measured on both")
 
 
 def test_dense_decode_lowers_for_tpu(compiled_kernels):

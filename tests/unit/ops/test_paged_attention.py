@@ -16,9 +16,7 @@ from deepspeed_tpu.ops.attention.decode_attention import (
     decode_attention,
     pack_int8_sublanes,
 )
-from deepspeed_tpu.ops.attention.flash_attention import SUBLANES
 from deepspeed_tpu.ops.attention.paged_attention import (
-    MAX_QUERY_ROWS,
     live_pages,
     paged_decode_attention,
     plan_grid,
@@ -117,9 +115,10 @@ def test_garbage_pages_and_sentinels_never_reach_output():
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
 
 
-def _reference_rows(q, dense_k, dense_v, starts):
+def _reference_rows(q, dense_k, dense_v, starts, window=None):
     """Plain fp32 softmax reference with per-row causal limits: row t of
-    slot b attends cache positions [0, starts[b] + t]."""
+    slot b attends cache positions [0, starts[b] + t], with a ``window``
+    the last ``window`` of them."""
     B, T, H, D = q.shape
     _, KV, _, S = dense_k.shape
     rep = H // KV
@@ -130,18 +129,28 @@ def _reference_rows(q, dense_k, dense_v, starts):
     pos = np.arange(S)[None, None, None, :]
     limit = (starts[:, None, None, None]
              + np.arange(T)[None, :, None, None])
-    s = np.where(pos <= limit, s, -np.inf)
+    seen = pos <= limit
+    if window is not None:
+        seen &= pos > limit - window
+    s = np.where(seen, s, -np.inf)
     p = np.exp(s - s.max(axis=-1, keepdims=True))
     p = p / p.sum(axis=-1, keepdims=True)
     return np.einsum("bths,bhds->bthd", p, v.astype(np.float64))
 
 
-@pytest.mark.parametrize("T", [2, 3, MAX_QUERY_ROWS])
+# a verify step's rows (2, 3, 8; 9 and 16 for a wide one) and a prefill
+# chunk's (64 in the Pythia cells, 128 in ide; 61: a width that is no
+# multiple of the sublane tile)
+_ROWS = [2, 3, 8, 9, 16, 61, 64, 128]
+
+
+@pytest.mark.parametrize("T", _ROWS)
 def test_multi_row_verify_matches_reference(T):
-    """T>1 (speculative verify): each query row carries its own causal
-    limit; numerics match a plain-softmax reference."""
+    """T>1 (speculative verify, a prefill chunk): each query row carries
+    its own causal limit; numerics match a plain-softmax reference. The
+    rows of a chunk cross several pages."""
     rng = np.random.default_rng(2)
-    B, H, KV, D, S, ps = 2, 4, 2, 64, 128, 16
+    B, H, KV, D, S, ps = 2, 4, 2, 64, 256, 16
     dense_k, dense_v, k_pages, v_pages, table = _make_paged(
         rng, B, KV, D, S, ps)
     starts = np.asarray([ps - 1, 3 * ps + 2], np.int32)  # straddle pages
@@ -153,17 +162,107 @@ def test_multi_row_verify_matches_reference(T):
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-4, rtol=1e-4)
 
 
-def test_row_budget_is_enforced():
-    assert MAX_QUERY_ROWS == SUBLANES
-    rng = np.random.default_rng(3)
-    B, H, KV, D, S, ps = 1, 2, 2, 64, 64, 16
-    _, _, k_pages, v_pages, table = _make_paged(rng, B, KV, D, S, ps)
-    q = jnp.asarray(
-        rng.standard_normal((B, MAX_QUERY_ROWS + 1, H, D)), jnp.float32)
-    with pytest.raises(AssertionError, match="query rows"):
-        paged_decode_attention(q, jnp.asarray(k_pages),
-                               jnp.asarray(v_pages), jnp.asarray(table),
-                               jnp.asarray([5], np.int32))
+def _quantized_pool(rng, B, KV, D, S, ps, packed):
+    """An int8 (or int32-packed) page pool with per-column scales, and
+    the float dense view it stands for."""
+    per_slot = S // ps
+    P = B * per_slot + 2
+    k8 = rng.integers(-127, 128, (P, KV, D, ps)).astype(np.int8)
+    v8 = rng.integers(-127, 128, (P, KV, D, ps)).astype(np.int8)
+    ks = rng.uniform(0.01, 0.1, (P, KV, ps)).astype(np.float32)
+    vs = rng.uniform(0.01, 0.1, (P, KV, ps)).astype(np.float32)
+    table = rng.permutation(P)[:B * per_slot].reshape(B, per_slot) \
+        .astype(np.int32)
+    dense_k = _gather(k8 * ks[:, :, None, :], table)
+    dense_v = _gather(v8 * vs[:, :, None, :], table)
+    kp, vp = jnp.asarray(k8), jnp.asarray(v8)
+    if packed:
+        kp, vp = pack_int8_sublanes(kp), pack_int8_sublanes(vp)
+    scales = dict(k_scale_pages=jnp.asarray(ks), v_scale_pages=jnp.asarray(vs))
+    return dense_k, dense_v, kp, vp, table, scales
+
+
+@pytest.mark.parametrize("T", [9, 64, 128])
+@pytest.mark.parametrize("variant", ["gqa8", "window", "inactive", "int8",
+                                     "int32-packed", "dead_slot"])
+def test_chunk_rows_match_reference(variant, T):
+    """A wide verify's and a chunk's rows in every form the decode read
+    has: a KV head's eight query heads in one operand (1,024 rows at
+    T = 128), a window shorter than the cached length (the slot's steps
+    begin past entry 0), a call that is not ``active`` (no step: the rows
+    come back as they went in), the quantized tiers, and a slot that maps
+    nothing beside one that does."""
+    rng = np.random.default_rng(330 + T)
+    B, KV, D, S, ps = 2, 2, 64, 256, 16
+    H = KV * (8 if variant == "gqa8" else 2)
+    starts = np.asarray([ps - 1, 5 * ps + 2], np.int32)
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+    scales, window, kwargs = {}, None, {}
+    if variant in ("int8", "int32-packed"):
+        dense_k, dense_v, k_pages, v_pages, table, scales = _quantized_pool(
+            rng, B, KV, D, S, ps, variant == "int32-packed")
+    else:
+        dense_k, dense_v, k_pages, v_pages, table = _make_paged(
+            rng, B, KV, D, S, ps)
+    P = k_pages.shape[0]
+    if variant == "window":
+        window = 2 * ps + 5
+        # what lies a window behind the first row has been recycled
+        first = np.maximum(starts - window + 1, 0) // ps
+        assert first.tolist() == [0, 2]
+        for b in range(B):
+            k_pages[table[b, :first[b]]] = np.nan
+            v_pages[table[b, :first[b]]] = np.nan
+            table[b, :first[b]] = P
+        kwargs = dict(window=window)
+    if variant == "dead_slot":
+        k_pages[table[0]] = v_pages[table[0]] = np.nan
+        table[0] = P                      # freed: its start counts on
+    if variant == "inactive":
+        kwargs = dict(active=jnp.asarray(False))
+    out = np.asarray(paged_decode_attention(
+        q, jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(table),
+        jnp.asarray(starts), **scales, **kwargs))
+    assert out.shape == (B, T, H, D) and np.isfinite(out).all()
+    if variant == "inactive":
+        np.testing.assert_array_equal(out, np.asarray(q))
+        return
+    ref = _reference_rows(np.asarray(q), dense_k, dense_v, starts, window)
+    live = [1] if variant == "dead_slot" else [0, 1]
+    # (the quantized tiers round the probabilities to the compute dtype
+    # before the value product, as the dense quantized kernel does)
+    tol = 2e-2 if scales else 1e-4
+    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+    if variant == "dead_slot":
+        np.testing.assert_array_equal(out[0], np.asarray(q)[0])
+
+
+def test_rows_that_do_not_fit_vmem_go_in_two_calls(monkeypatch):
+    """A budget that one KV head's rows do not fit: the call is cut in two
+    by query rows, the second half further on, and answers the same."""
+    rng = np.random.default_rng(33)
+    B, H, KV, D, S, ps, T = 2, 4, 2, 64, 256, 16, 40
+    dense_k, dense_v, k_pages, v_pages, table = _make_paged(
+        rng, B, KV, D, S, ps)
+    starts = np.asarray([ps - 1, 3 * ps + 2], np.int32)
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+    args = (q, jnp.asarray(k_pages), jnp.asarray(v_pages),
+            jnp.asarray(table), jnp.asarray(starts))
+    whole = paged_decode_attention(*args)
+    # a step of one KV head (two query heads): 408 KB at 40 rows, 296 KB
+    # at 24
+    monkeypatch.setattr(paged_attention, "VMEM_BUDGET_BYTES", 300 * 1024)
+    calls = []
+    call = paged_attention.pl.pallas_call
+    monkeypatch.setattr(
+        paged_attention.pl, "pallas_call",
+        lambda *a, **kw: calls.append(kw["out_shape"].shape) or call(*a, **kw))
+    split = paged_decode_attention(*args)
+    assert calls == [(B, KV, 2 * 24, D), (B, KV, 2 * 16, D)]
+    np.testing.assert_allclose(np.asarray(split), np.asarray(whole),
+                               atol=1e-6)
+    ref = _reference_rows(np.asarray(q), dense_k, dense_v, starts)
+    np.testing.assert_allclose(np.asarray(split), ref, atol=1e-4, rtol=1e-4)
 
 
 @pytest.mark.parametrize("KV", [4, 2])

@@ -50,7 +50,8 @@ def make_server(engine, pool, **kw):
 def watch_kernel_reads(srv, device_steps):
     """Spy on a server's finite guard and on its pool's ``pages_read``.
     Returns ``(finite_rows, record)``: the guard's (num_slots,) verdict of
-    every guarded step, and for every kernel dispatch ``((steps, slots),
+    every guarded step, and for every decode or verify dispatch of the
+    kernel ``((steps, slots),
     device_steps(rows), seated rows that map a page)``, taken AT the
     dispatch (a step frees slots after it). ``device_steps(rows)`` is the
     test's own count of the device work list's steps."""
@@ -62,9 +63,9 @@ def watch_kernel_reads(srv, device_steps):
         finite_rows.append(np.asarray(rows))
         return rows
 
-    def pages_read(rows):
-        work = count(rows)
-        if work is not None:
+    def pages_read(rows, slots=None, starts=None):
+        work = count(rows, slots, starts)
+        if work is not None and slots is None:     # (not a chunk's)
             record.append((work, device_steps(rows), int(np.count_nonzero(
                 (pool.table != pool.num_pages).any(axis=1)))))
         return work

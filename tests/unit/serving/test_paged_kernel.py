@@ -13,6 +13,7 @@ import pytest
 import deepspeed_tpu as ds
 from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import PagedKVPool, RequestState, ServingEngine
+from deepspeed_tpu.telemetry import Tracer
 
 from .conftest import watch_kernel_reads
 
@@ -76,18 +77,6 @@ def test_kernel_knob_validates_and_gates(stack):
         kernel_server(engine, "sometimes")
 
 
-def test_max_query_rows_drift_guard(stack, monkeypatch):
-    """The pool mirrors the kernel's row budget as a local literal (so
-    graftcheck can decide the verify gate statically); binding must
-    refuse to run if the two ever drift."""
-    import deepspeed_tpu.serving.paged_pool as pp
-
-    _, _, engine = stack
-    monkeypatch.setattr(pp, "_KERNEL_MAX_QUERY_ROWS", 4)
-    with pytest.raises(RuntimeError, match="MAX_QUERY_ROWS"):
-        kernel_server(engine, "on")
-
-
 # ---------------------------------------------------------------------------
 # bitwise parity
 
@@ -135,26 +124,31 @@ def test_kernel_spec_verify_parity_with_rollback(stack):
     assert s["spec_drafted"] > 0 and s["spec_accepted"] > 0
 
 
-def test_verify_width_beyond_row_budget_falls_back(stack):
-    """spec_k + 1 rows past MAX_QUERY_ROWS must fall back to the dense
-    verify composition (the kernel's row budget is the sublane count) —
-    with identical tokens, not an error."""
-    from deepspeed_tpu.ops.attention.paged_attention import MAX_QUERY_ROWS
-
+def test_a_verify_of_nine_rows_takes_the_kernel(stack):
+    """spec_k + 1 = 9 rows, one more than a sublane tile: the verify step
+    reads and writes the pages in place like any narrower one (until
+    PR 33 it fell back to the dense composition), tokens equal to the
+    dense arm's."""
     _, _, engine = stack
-    k = MAX_QUERY_ROWS  # verify width k+1 exceeds the kernel budget
     rng = np.random.default_rng(5)
     motif = rng.integers(0, 64, size=4)
     prompts = [np.tile(motif, 5).astype(np.int32)]
     budgets = [10]
-    spec = {"k": k, "drafter": "ngram"}
-    srv_on = kernel_server(engine, "on", spec_decode=dict(spec))
-    assert srv_on.pool._paged_verify_kernel_jit is not None
+    spec = {"k": 8, "drafter": "ngram"}
+    srv_on = kernel_server(engine, "on", spec_decode=dict(spec),
+                           tracer=Tracer())
+    assert srv_on.pool.reads_in_place(9)
     on = run_traffic(srv_on, prompts, budgets)
     off = run_traffic(kernel_server(engine, "off",
                                     spec_decode=dict(spec)),
                       prompts, budgets)
     np.testing.assert_array_equal(on[0].tokens(), off[0].tokens())
+    spans = [e["args"] for e in srv_on.tracer.events()
+             if e["ph"] == "X" and e["name"] == "serving/verify_k"]
+    assert spans and all("pool_reads" in args for args in spans)
+    manifest = srv_on.watchdog.signature_manifest()
+    assert "SlotPool._paged_verify_kernel_jit" in manifest
+    assert "SlotPool._paged_verify_jit" not in manifest
 
 
 def test_kernel_preempt_resume_parity(stack):
@@ -194,13 +188,131 @@ def test_kernel_churn_never_recompiles_after_warmup(stack):
     grow any executable cache."""
     _, _, engine = stack
     prompts, budgets = _mixed_workload(seed=13, n=6)
-    srv = kernel_server(engine, "on")
+    # and prompts of several chunks, the last one short: the chunk
+    # program reads and writes the pages through the same kernels
+    rng = np.random.default_rng(14)
+    prompts += [rng.integers(0, 64, size=n).astype(np.int32)
+                for n in (29, 43)]
+    budgets += [4, 5]
+    srv = kernel_server(engine, "on", tracer=Tracer())
     run_traffic(srv, prompts, budgets)
     srv.end_warmup()
     run_traffic(srv, prompts, budgets)
     assert srv.watchdog.recompiles == 0
     manifest = srv.watchdog.signature_manifest()
     assert "SlotPool._paged_decode_kernel_jit" in manifest
+    assert "SlotPool._paged_chunk_jit" in manifest
+    chunks = [e["args"] for e in srv.tracer.events()
+              if e["ph"] == "X" and e["name"] == "serving/prefill_chunk"]
+    assert len(chunks) >= 2 * (4 + 6)
+    assert all(args["read_slots"] == 1 for args in chunks)
+
+
+# ---------------------------------------------------------------------------
+# the prefill chunk reads and writes its pages in place (ISSUE 33)
+
+CHUNK = 12      # not a multiple of the page: chunks cross page boundaries
+# float32 logits of size ~1 through the pages (one page folded at a time)
+# against the dense row's one softmax over 64 positions
+CHUNK_ATOL = 2e-5
+
+
+def _chunk_server(engine, kernel):
+    """A server of 12-token chunks over pages of 8 whose chunk dispatches
+    are recorded: ``(start, logits, steps of the device's work list)``."""
+    from deepspeed_tpu.ops.attention.paged_attention import live_pages
+
+    srv = kernel_server(engine, kernel, prefill_chunk=CHUNK, tracer=Tracer())
+    pool, calls = srv.pool, []
+    run = pool.run_prefill_chunk
+
+    def run_prefill_chunk(eng, ids, slot, start, length, last_idx):
+        steps = int(live_pages(
+            jnp.asarray([start], jnp.int32), jnp.asarray(pool.table[slot])[None],
+            ids.shape[1], PS, pool.num_pages)[-1])
+        logits = run(eng, ids, slot, start, length, last_idx)
+        calls.append((start, np.asarray(logits), steps))
+        return logits
+
+    pool.run_prefill_chunk = run_prefill_chunk
+    return srv, calls
+
+
+def _drive_chunks(srv, case):
+    rng = np.random.default_rng(33)
+    reqs = []
+    if case == "crosses_pages":
+        # whole chunks and a short last one (6 and 5 tokens of 12), two
+        # prompts prefilling one after the other beside a decoding row
+        for n, budget in ((36, 6), (30, 5), (41, 4)):
+            reqs.append(srv.submit(rng.integers(0, 64, n).astype(np.int32),
+                                   max_new_tokens=budget))
+    elif case == "prefix_hit_mid_page":
+        # a hit of two pages resumes at position 12 (the chunk multiple
+        # under it), in the middle of the hit's second page: that page is
+        # forked before the chunk writes it
+        prompt = rng.integers(0, 64, 24).astype(np.int32)
+        reqs.append(srv.submit(prompt, max_new_tokens=3))
+        srv.run_until_drained(max_steps=100)
+        reqs.append(srv.submit(prompt, max_new_tokens=5))
+        reqs.append(srv.submit(
+            np.concatenate([prompt[:16], rng.integers(0, 64, 13)])
+            .astype(np.int32), max_new_tokens=4))
+    else:
+        assert case == "preempt_mid_prefill"
+        reqs.append(srv.submit(rng.integers(0, 64, 45).astype(np.int32),
+                               max_new_tokens=6))
+        srv.step()
+        srv.step()
+        assert reqs[0].state == RequestState.PREFILLING \
+            and 0 < reqs[0].prefill_pos < 45
+        srv.preempt(reqs[0].request_id)
+        assert reqs[0].preemptions == 1
+    for _ in range(300):
+        if not (srv.live_count or srv.pending):
+            break
+        srv.step()
+        srv.check_invariants()
+    assert all(r.state == RequestState.FINISHED for r in reqs)
+    return reqs
+
+
+@pytest.mark.parametrize("case", ["crosses_pages", "prefix_hit_mid_page",
+                                  "preempt_mid_prefill"])
+def test_chunked_prefill_through_the_pages_matches_the_dense_arm(stack, case):
+    """Every chunk of the kernel arm writes its 12 columns into the pages
+    and reads them back in place; the dense arm gathers the slot's dense
+    row. Greedy tokens equal, every chunk's logits within ``CHUNK_ATOL``,
+    the invariants clean after every step, and the chunk spans say what
+    the read's work list held (and nothing on the dense arm)."""
+    _, _, engine = stack
+    srv_on, on_calls = _chunk_server(engine, "on")
+    srv_off, off_calls = _chunk_server(engine, "off")
+    on, off = _drive_chunks(srv_on, case), _drive_chunks(srv_off, case)
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a.tokens(), b.tokens())
+    assert [c[0] for c in on_calls] == [c[0] for c in off_calls]
+    assert len(on_calls) >= 4
+    for (start, got, _), (_, want, _) in zip(on_calls, off_calls):
+        np.testing.assert_allclose(got, want, atol=CHUNK_ATOL,
+                                   err_msg=f"chunk at {start}")
+    if case == "prefix_hit_mid_page":
+        assert srv_on.pool.cow_copies == srv_off.pool.cow_copies >= 1
+        assert any(start % PS for start, _, _ in on_calls)
+
+    def spans(srv):
+        return [e["args"] for e in srv.tracer.events()
+                if e["ph"] == "X" and e["name"] == "serving/prefill_chunk"]
+
+    assert len(spans(srv_on)) == len(on_calls)
+    for args, (start, _, steps) in zip(spans(srv_on), on_calls):
+        assert args["pos"] == start
+        assert (args["pool_reads"], args["read_slots"]) == (steps, 1)
+        assert args["pool_writes"] >= 1
+    assert spans(srv_off) and not any(
+        "pool_reads" in args or "read_slots" in args
+        for args in spans(srv_off))
+    assert srv_off.pool.pages_read(CHUNK, [0], [0]) is None
 
 
 # ---------------------------------------------------------------------------
@@ -212,7 +324,6 @@ def _churn(engine, kernel, spec=None):
     seven requests of mixed budgets, so slots stand freed (row all
     sentinel, index counting on) beside decoding ones for many steps."""
     from deepspeed_tpu.ops.attention.paged_attention import live_pages
-    from deepspeed_tpu.telemetry import Tracer
 
     srv = kernel_server(engine, kernel, num_slots=4, guard_numerics=True,
                         tracer=Tracer(),

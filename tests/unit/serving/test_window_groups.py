@@ -342,6 +342,52 @@ def test_freed_slots_of_both_groups_are_no_step_under_churn(stack):
     assert any(0 < slots < 3 for (_, slots), _, _ in record)
 
 
+def test_a_chunk_reads_and_writes_both_groups_in_place(stack):
+    """A prompt three windows long, 8 tokens a chunk, kernel on: every
+    chunk goes through the pages of both groups while the ring turns
+    (PR 33; no dense row), its logits the reference's, and the host's
+    count of the read's work list is the device's for one full and one
+    window layer. A chunk as wide as the window stays on the dense
+    composition (a ring maps only what a LATER step can see, and such a
+    chunk's own later rows need more), with the reference's logits too."""
+    from deepspeed_tpu.ops.attention.paged_attention import live_pages
+
+    cfg, model, params, engine, logits_fn = stack
+    seq = np.random.default_rng(33).integers(1, 128, 3 * WINDOW + 5)
+    want = np.asarray(logits_fn(
+        params, np.pad(seq, (0, CTX - len(seq))).astype(np.int32),
+        np.arange(len(seq))))
+    for chunk, in_place in ((8, True), (WINDOW, False)):
+        pool = PagedKVPool(model.kv_cache_spec(), num_slots=2, num_pages=40,
+                           page_size=PAGE, prefix_cache=False, kernel="on")
+        pool.bind_engine(engine)
+        assert pool.reads_in_place(chunk) is in_place
+        slot = pool.alloc()
+        pool.reset_row(slot)
+        for pos in range(0, len(seq), chunk):
+            n = min(chunk, len(seq) - pos)
+            ids = np.zeros((1, chunk), np.int32)
+            ids[0, :n] = seq[pos:pos + n]
+            pool.ensure_writable(slot, pos, pos + n)
+            work = pool.pages_read(chunk, [slot], [pos])
+            if in_place:
+                tables = ((pool.table, pool.num_pages, None),
+                          (pool.ring.table, pool.ring.num_pages, WINDOW))
+                steps = sum(int(live_pages(
+                    jnp.asarray([pos], jnp.int32),
+                    jnp.asarray(table[slot])[None], chunk, PAGE, pages,
+                    window)[4]) for table, pages, window in tables)
+                assert work == (steps, 1)
+            else:
+                assert work is None
+            lg = pool.run_prefill_chunk(engine, ids, slot, pos, n, n - 1)
+            pool.starts[slot] = pos + n
+            np.testing.assert_allclose(np.asarray(lg[0, 0]),
+                                       want[pos + n - 1], atol=ATOL)
+            assert not pool.consistency_errors()
+        assert pool.ring.recycled > 0
+
+
 def test_what_does_not_compose_refuses_at_construction(stack):
     cfg, model, params, engine, _ = stack
     spec = model.kv_cache_spec()
