@@ -678,7 +678,7 @@ class Interp:
         if isinstance(node, ast.BoolOp):
             return self._boolop(node, frame)
         if isinstance(node, (ast.Tuple, ast.List)):
-            return Tup([self.eval(e, frame) for e in node.elts])
+            return Tup(self._eval_items(node.elts, frame))
         if isinstance(node, ast.Dict):
             entries = {}
             placement = COMMITTED
@@ -705,6 +705,22 @@ class Interp:
         if isinstance(node, ast.Starred):
             return self.eval(node.value, frame)
         return Unknown(type(node).__name__)
+
+    def _eval_items(self, nodes: Sequence[ast.expr],
+                    frame: Frame) -> List[AbsValue]:
+        """The items of a display or of a call's positional arguments;
+        ``*seq`` puts the items of a tuple it can see in its place (a
+        dispatch's host-built arguments go to the device in one put and
+        reach the program as ``*args``) and is dropped otherwise."""
+        out: List[AbsValue] = []
+        for node in nodes:
+            if not isinstance(node, ast.Starred):
+                out.append(self.eval(node, frame))
+                continue
+            seq = self.eval(node.value, frame)
+            if isinstance(seq, Tup):
+                out.extend(seq.items)
+        return out
 
     def _load_name(self, name: str, frame: Frame) -> AbsValue:
         if name in frame.locals:
@@ -751,8 +767,7 @@ class Interp:
     def _call(self, node: ast.Call, frame: Frame) -> AbsValue:
         kwargs = {kw.arg: self.eval(kw.value, frame)
                   for kw in node.keywords if kw.arg is not None}
-        args = [self.eval(a, frame) for a in node.args
-                if not isinstance(a, ast.Starred)]
+        args = self._eval_items(node.args, frame)
         f = node.func
         if isinstance(f, ast.Attribute):
             recv = self.eval(f.value, frame)
@@ -917,6 +932,9 @@ class Interp:
                right: AbsValue) -> AbsValue:
         if isinstance(left, (Arr,)) or isinstance(right, (Arr,)):
             return binop(left, right)
+        if isinstance(left, Tup) and isinstance(right, Tup) \
+                and isinstance(op, ast.Add):
+            return Tup(list(left.items) + list(right.items))
         ld = as_dim(left) if isinstance(left, Scalar) else None
         rd = as_dim(right) if isinstance(right, Scalar) else None
         if ld is not None and rd is not None:

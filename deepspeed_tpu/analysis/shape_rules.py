@@ -432,15 +432,32 @@ def _random_categorical(args, kwargs):
 
 
 def _device_put(args, kwargs):
+    """``jax.device_put(x, where)`` commits ``x``; with no ``where`` it is
+    a plain transfer, as ``jnp.asarray`` is: every leaf keeps its
+    placement (a host buffer stays layout-neutral). A tuple of operands
+    is put leaf by leaf."""
     from .absdomain import COMMITTED
     if not args:
         return Unknown("device_put()")
     x = args[0]
+    commits = len(args) > 1 or bool(kwargs)
+    if isinstance(x, Tup):
+        return Tup([_device_put([item] + list(args[1:]), kwargs)
+                    for item in x.items])
     if isinstance(x, Arr):
-        return x.with_placement(COMMITTED)
+        return x.with_placement(COMMITTED) if commits else x
     if isinstance(x, Tree):
-        return Tree(COMMITTED, x.label)
+        return Tree(COMMITTED, x.label) if commits else x
     return Unknown("device_put of unknown operand")
+
+
+def _np_scalar(name: str):
+    """``np.int32(x)`` / ``np.float32(x)``: a host scalar of that type."""
+    convert = _asarray(HOST)
+
+    def rule(args, kwargs):
+        return convert(args[:1], {"dtype": DTypeVal(name)})
+    return rule
 
 
 RULES: Dict[str, Callable[[List[AbsValue], Dict[str, AbsValue]], AbsValue]] = {
@@ -453,6 +470,8 @@ RULES: Dict[str, Callable[[List[AbsValue], Dict[str, AbsValue]], AbsValue]] = {
     "jnp.ones": _constructor(UNCOMMITTED, "float32"),
     "jnp.full": _full(UNCOMMITTED),
     "np.asarray": _asarray(HOST),
+    "np.int32": _np_scalar("int32"),
+    "np.float32": _np_scalar("float32"),
     "np.array": _asarray(HOST),
     "jnp.asarray": _asarray(UNCOMMITTED),
     "jnp.array": _asarray(UNCOMMITTED),

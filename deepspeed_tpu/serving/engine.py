@@ -88,6 +88,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..telemetry import (FlightRecorder, MetricsRegistry, ProgramCostModel,
                          RecompileAfterWarmupError, RecompileWatchdog,
                          SLOTracker, TimelineStore, Tracer, default_tracer)
+from ..telemetry.tracer import NO_SPAN, _Span, gc_ns_total, watch_gc
 from ..utils.logging import log_dist
 from .metrics import ServingMetrics
 from .paged_pool import PagedKVPool, PagePoolExhausted
@@ -118,6 +119,92 @@ _WATCHED_SERVING_JITS = ("_jit_finite", "_jit_cur_scatter", "_jit_spec_cur")
 _WATCHED_DRAFTER_JITS = ("_argmax",)
 
 _MIN_PREFILL_BUCKET = 16
+
+
+class _Enqueue(_Span):
+    """``serving/enqueue`` around ONE call of a jitted program, in the
+    server's tracer (:func:`~deepspeed_tpu.telemetry.tracer.enqueue_span`
+    says which calls get the span). At its close the step's account takes
+    it, from the clock reads the span made: its time under ``enqueue``
+    and, if it is the step's first and the previous step ended in a sync,
+    ``exposed``: from the end of that sync to now, the host time during
+    which the device had nothing queued."""
+
+    __slots__ = ("_srv",)
+
+    def __init__(self, srv: "ServingEngine", program: str):
+        super().__init__(srv.tracer, "serving/enqueue",
+                         {"program": program, "kind": "program"})
+        self._srv = srv
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        srv = self._srv
+        phases = srv._phase_ns
+        phases["enqueue"] = phases.get("enqueue", 0) + self.dur_ns
+        if srv._exposed_from_ns is not None:
+            phases["exposed"] = self.t0_ns + self.dur_ns \
+                - srv._exposed_from_ns
+            srv._exposed_from_ns = None
+        return False
+
+
+class _Phase(_Span):
+    """A span of the step whose time OUTSIDE its ``serving/enqueue``
+    children goes to one phase of the step's account: the phases then
+    add up to no more than the step, whatever device calls a phase
+    holds."""
+
+    __slots__ = ("_srv", "_phase", "_enqueued")
+
+    def __init__(self, srv: "ServingEngine", phase: str, name: str,
+                 attrs: Optional[dict] = None):
+        super().__init__(srv.tracer, name, attrs)
+        self._srv, self._phase, self._enqueued = srv, phase, 0
+
+    def __enter__(self):
+        self._enqueued = self._srv._phase_ns.get("enqueue", 0)
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        phases = self._srv._phase_ns
+        phases[self._phase] = phases.get(self._phase, 0) + self.dur_ns \
+            - (phases.get("enqueue", 0) - self._enqueued)
+        return False
+
+
+class _PagesPhase(_Phase):
+    """``serving/pages``: the scheduler -> KV pool boundary (seating the
+    granted requests, making a dispatch's write columns writable,
+    publishing a prompt's pages), with what it cost the pool: pages
+    ``allocated``, copy-on-write pages ``forked``, requests ``preempted``
+    under page pressure."""
+
+    __slots__ = ("_before",)
+
+    def __init__(self, srv: "ServingEngine"):
+        super().__init__(srv, "pages", "serving/pages")
+        self._before = (0, 0, 0)
+
+    def _counts(self) -> tuple:
+        srv = self._srv
+        if not srv._paged:
+            return (0, 0, srv.metrics.preempted)
+        return (srv.pool.pages_allocated, srv.pool.cow_copies,
+                srv.metrics.preempted)
+
+    def __enter__(self):
+        self._before = self._counts()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        allocated, forked, preempted = self._counts()
+        allocated0, forked0, preempted0 = self._before
+        self.args = {"allocated": allocated - allocated0,
+                     "forked": forked - forked0,
+                     "preempted": preempted - preempted0}
+        return super().__exit__(exc_type, exc, tb)
 
 
 class ServingEngine:
@@ -331,6 +418,8 @@ class ServingEngine:
         if tracer is True:
             tracer = Tracer()
         self.tracer = tracer if tracer is not None else default_tracer()
+        if self.tracer is default_tracer():
+            watch_gc()      # host/gc: a full collection inside a step
         self.registry = registry if registry is not None else MetricsRegistry()
         self.step_id = 0                 # monotonic scheduler-step counter
         self.timelines = TimelineStore(tracer=self.tracer)
@@ -379,11 +468,22 @@ class ServingEngine:
         self._tokens_prev = 0           # snapshot for per-step deltas
         self._after_step_ns = 0         # total of serving/after_step
         # the step in flight, from its spans: when it opened, host
-        # nanoseconds by phase, and the programs it dispatched (the
-        # serving/step span's attributes at its close)
+        # nanoseconds by phase (measured, each from spans that closed:
+        # boundary, grant, pages, prepare, enqueue, sync, replay add up
+        # to the step less its after-step; exposed lies across them),
+        # the calls that handed the device work, and the programs it
+        # dispatched (the serving/step span's attributes at its close)
         self._step_t0_ns = 0
         self._phase_ns: dict = {}
+        self._device_calls = 0
         self._dispatched: dict = {}
+        # when the last sync of the step before ended, until the step in
+        # flight has queued its first program (None: that step had no
+        # sync, or this is the first: the device is not known idle)
+        self._sync_end_ns: Optional[int] = None
+        self._exposed_from_ns: Optional[int] = None
+        # every device call of the pool goes through the same account
+        self.pool.enqueue = self._enqueue
         # fleet identity: assigned by ReplicaRouter at join time, stamped
         # onto every timeline event so cross-replica journeys stitch
         self.replica_id: Optional[int] = None
@@ -1008,7 +1108,24 @@ class ServingEngine:
                 "index", arr if hasattr(arr, "shape") else np.asarray(arr))
         else:
             sh = self._rep_sharding()
-        return jax.device_put(arr, sh)
+        with self._enqueue("cur_commit", "transfer"):
+            return jax.device_put(arr, sh)
+
+    def _enqueue(self, program: str, kind: str = "program"):
+        """Count one device call of the step and, for a jitted program,
+        open the span around it (see :class:`_Enqueue`); the pool makes
+        its calls through this too. A put or an eager operation is
+        counted and nothing else, and so is every call while the tracer's
+        ring is off: no span, no annotation, no clock read."""
+        self._device_calls += 1
+        if kind != "program" or not self.tracer.enabled:
+            return NO_SPAN
+        return _Enqueue(self, program)
+
+    def _phase(self, phase: str, name: str, **attrs) -> _Phase:
+        """``tracer.span(name, **attrs)`` whose time outside its enqueue
+        children the step's account takes under ``phase``."""
+        return _Phase(self, phase, name, attrs or None)
 
     def _sample_dev(self, logits):
         """Dispatch the sampler and return the token *device* array.
@@ -1019,10 +1136,14 @@ class ServingEngine:
         closure in dispatch order. Per-row sampling is independent
         (``categorical``/``argmax`` act row-wise on one split key), so
         batching rows from different call sites cannot change values."""
-        self._rng, sub = jax.random.split(self._rng)
-        return self.engine._jit_sample(
-            logits, sub, jnp.asarray(self.temperature, jnp.float32),
-            int(self.top_k), float(self.top_p), self._greedy)
+        with self._enqueue("rng_split", "transfer"):
+            self._rng, sub = jax.random.split(self._rng)
+        with self._enqueue("sample", "transfer"):
+            temperature = jax.device_put(np.float32(self.temperature))
+        with self._enqueue("sample"):
+            return self.engine._jit_sample(
+                logits, sub, temperature, int(self.top_k),
+                float(self.top_p), self._greedy)
 
     def _defer(self, arrays, callback) -> None:
         """Queue ``callback(*host_values)`` until the end-of-step fetch.
@@ -1047,10 +1168,10 @@ class ServingEngine:
                 # token/flag fetch collapses onto this block
                 jax.block_until_ready(bundle)
             phases["sync"] = phases.get("sync", 0) + sp.dur_ns
-        with self.tracer.span("serving/replay", callbacks=len(pending)) as sp:
+            self._sync_end_ns = sp.t0_ns + sp.dur_ns
+        with self._phase("replay", "serving/replay", callbacks=len(pending)):
             for arrays, callback in pending:
                 callback(*[np.asarray(a) for a in arrays])
-        phases["replay"] = phases.get("replay", 0) + sp.dur_ns
 
     def _note_moe_stats(self) -> None:
         """What the routed FFN counted in this step's programs (each
@@ -1099,8 +1220,25 @@ class ServingEngine:
             b *= 2
         return min(b, cap)
 
-    def _admit(self, req: Request, finished: List[Request]) -> None:
+    def _prefill_at(self, ids, last_pos):
+        """The bucketed prefill program over host-built ``ids`` and the
+        position(s) to project: one put, one call."""
         eng = self.engine
+        with self._enqueue("prefill_at", "transfer"):
+            args = jax.device_put((ids, last_pos))
+        with self._enqueue("prefill_at"):
+            return eng._jit_prefill_at(eng.params, *args)
+
+    def _cur_scatter(self, tokens_dev, slots) -> None:
+        """Write freshly sampled first tokens into the current-token
+        twin at ``slots`` (host ids)."""
+        with self._enqueue("cur_scatter", "transfer"):
+            slots = jax.device_put(np.asarray(slots, np.int32))
+        with self._enqueue("cur_scatter"):
+            self._cur_dev = self._jit_cur_scatter(self._cur_dev, tokens_dev,
+                                                  slots)
+
+    def _admit(self, req: Request, finished: List[Request]) -> None:
         slot = self.pool.alloc()
         # rollback snapshot: a PREEMPTED request arrives carrying its
         # generated-so-far tokens and first-token stamp — a failed
@@ -1118,12 +1256,10 @@ class ServingEngine:
             running_before = self._running_count()
             req.admit_time = self._now()
             self._note_admit(1, width)
-            with self.tracer.span("serving/admit", rid=req.request_id,
-                                  tokens=T, width=width) as sp:
+            with self._phase("prepare", "serving/admit", rid=req.request_id,
+                             tokens=T, width=width) as sp:
                 self._note_state_rows(sp, 1)
-                logits, pre_cache = eng._jit_prefill_at(
-                    eng.params, jnp.asarray(ids),
-                    jnp.asarray(T - 1, jnp.int32))
+                logits, pre_cache = self._prefill_at(ids, np.int32(T - 1))
                 self.pool.admit(pre_cache, slot, T)
                 if self._paged:
                     sp.set(pool_writes=self.pool.pages_touched(
@@ -1132,8 +1268,7 @@ class ServingEngine:
                     # dispatch only; the host value arrives at the
                     # end-of-step fetch
                     tok_dev = self._cur_commit(self._sample_dev(logits))
-                self._cur_dev = self._jit_cur_scatter(
-                    self._cur_dev, tok_dev, jnp.asarray([slot]))
+                self._cur_scatter(tok_dev, [slot])
             now = self._now()
             self.metrics.record_prefill(T, now - req.admit_time,
                                         blocking=running_before > 0)
@@ -1173,7 +1308,8 @@ class ServingEngine:
         if self._use_prefix:
             # publish the freshly-prefilled full prompt pages (refcounted
             # past this slot's lifetime) for the next same-prefix request
-            self.pool.cache_prefix(slot, seed)
+            with _PagesPhase(self):
+                self.pool.cache_prefix(slot, seed)
 
     def _running_count(self) -> int:
         return sum(1 for r in self._slot_req.values()
@@ -1324,12 +1460,12 @@ class ServingEngine:
         self.tracer.flow("s", "req", req.request_id)
         return True
 
-    def _admit_stall_free(self, granted: List[Request],
-                          finished: List[Request]) -> None:
+    def _admit_stall_free(self, granted: List[Request]) -> list:
         """Seat every granted request: long prompts become PREFILLING
-        (their cache rows fill chunk by chunk in later steps), short
-        prompts are grouped by padded bucket width and each group is
-        prefilled + scattered in ONE batched dispatch."""
+        (their cache rows fill chunk by chunk in later steps); short
+        prompts are grouped by padded bucket width and handed back as
+        ``(width, group)`` pairs, each of which the step prefills +
+        scatters in ONE batched dispatch."""
         groups: dict = {}
         for req in granted:
             if self._use_prefix and self._admit_prefix_hit(req):
@@ -1359,15 +1495,7 @@ class ServingEngine:
             else:
                 groups.setdefault(self._bucket(T, self.pool.capacity),
                                   []).append(req)
-        for width in sorted(groups):
-            group = groups[width]
-            if len(group) == 1:
-                # singleton: the per-request path (no sentinel padding,
-                # no scatter program) is strictly cheaper — the batched
-                # dispatch only pays off when it coalesces ≥2 prompts
-                self._admit(group[0], finished)
-            else:
-                self._admit_batch(group, width, finished)
+        return sorted(groups.items())
 
     def _admit_batch(self, group: List[Request], width: int,
                      finished: List[Request]) -> None:
@@ -1377,7 +1505,6 @@ class ServingEngine:
         admit. Compile count: log2(num_slots) batch buckets x
         log2(max_seq_len) width buckets. Padding rows carry the slot
         sentinel ``num_slots`` (scatter drop-mode discards them)."""
-        eng = self.engine
         n = len(group)
         nB = 1
         while nB < n:
@@ -1403,11 +1530,10 @@ class ServingEngine:
                 req.admit_time = self._now()
             t0 = self._now()
             self._note_admit(n, nB * width)
-            with self.tracer.span("serving/prefill_batch", n=n, width=width,
-                                  batch=nB) as sp:
+            with self._phase("prepare", "serving/prefill_batch", n=n,
+                             width=width, batch=nB) as sp:
                 self._note_state_rows(sp, n)
-                logits, pre_cache = eng._jit_prefill_at(
-                    eng.params, jnp.asarray(ids), jnp.asarray(last_pos))
+                logits, pre_cache = self._prefill_at(ids, last_pos)
                 self.pool.admit_rows(pre_cache, slots, lengths)
                 if self._paged:
                     sp.set(pool_writes=self.pool.pages_touched(
@@ -1416,8 +1542,7 @@ class ServingEngine:
                     # dispatch only; host values arrive at the
                     # end-of-step fetch
                     tokens_dev = self._cur_commit(self._sample_dev(logits))
-                self._cur_dev = self._jit_cur_scatter(
-                    self._cur_dev, tokens_dev, jnp.asarray(slots))
+                self._cur_scatter(tokens_dev, slots)
             now = self._now()
             self.metrics.record_prefill(int(lengths.sum()), now - t0,
                                         blocking=running_before > 0)
@@ -1486,10 +1611,11 @@ class ServingEngine:
             # the chunk's write window must land in owned pages BEFORE
             # the dispatch (allocating / CoW-forking under pressure may
             # preempt a victim — host work, so it happens outside jit)
-            self._ensure_pages(slot, pos, pos + L)
+            with _PagesPhase(self):
+                self._ensure_pages(slot, pos, pos + L)
         self._dispatched["chunk"] = L
-        with self.tracer.span("serving/prefill_chunk", rid=req.request_id,
-                              pos=pos, len=L) as sp:
+        with self._phase("prepare", "serving/prefill_chunk",
+                         rid=req.request_id, pos=pos, len=L) as sp:
             self._note_state_rows(sp, 1)
             if self._paged:
                 logits = self.pool.run_prefill_chunk(
@@ -1497,8 +1623,13 @@ class ServingEngine:
                 sp.set(pool_writes=self.pool.pages_touched(slot, pos, C))
                 self._set_pool_reads(sp, C, [slot], [pos])
             else:
-                logits, cache = self.engine.prefill_chunk(
-                    self.pool.cache, ids, slot, pos, L, L - 1)
+                with self._enqueue("chunk", "transfer"):
+                    args = jax.device_put(
+                        (ids, np.int32(slot), np.int32(pos), np.int32(L),
+                         np.int32(L - 1)))
+                with self._enqueue("chunk"):
+                    logits, cache = self.engine.prefill_chunk(
+                        self.pool.cache, *args)
                 self.pool.cache = cache
         self.pool.starts[slot] = pos + L  # device index moved in-program
         req.prefill_pos = pos + L
@@ -1506,19 +1637,19 @@ class ServingEngine:
         self.timelines.record(req.request_id, "prefill_chunk", pos=pos,
                               len=L)
         if req.prefill_pos >= seed_len:
-            with self.tracer.span("serving/sample"):
+            with self._phase("prepare", "serving/sample"):
                 # dispatch only; host value arrives at the end-of-step
                 # fetch
                 tok_dev = self._cur_commit(self._sample_dev(logits))
-            self._cur_dev = self._jit_cur_scatter(
-                self._cur_dev, tok_dev, jnp.asarray([slot]))
+            self._cur_scatter(tok_dev, [slot])
             self.metrics.record_prefill(L, self._now() - t0,
                                         blocking=running_before > 0)
             self._prefill_queue.pop(0)
             req.state = RequestState.RUNNING
             req.last_admit_step = self.step_id
             if self._use_prefix:
-                self.pool.cache_prefix(slot, seed)
+                with _PagesPhase(self):
+                    self.pool.cache_prefix(slot, seed)
 
             def _on_chunk_token(tok, req=req, slot=slot):
                 token = int(tok[0])
@@ -1916,17 +2047,21 @@ class ServingEngine:
         tokens_at_entry = self._tokens_emitted
         self._dispatched = {}
         phases = self._phase_ns = {}
+        self._device_calls = 0
+        # the device is known idle from the previous step's sync on
+        # (serving/enqueue closes the interval: `exposed`)
+        self._exposed_from_ns, self._sync_end_ns = self._sync_end_ns, None
+        gc_ns0 = gc_ns_total()
         with tracer.span("serving/step", step=self.step_id) as sp_step:
             self._step_t0_ns = sp_step.t0_ns
             # boundary work first, outside the abort scope: expiring a
             # deadline or walking the load ladder touches no device
             # state, so a failure here must not FAIL innocent requests
-            with tracer.span("serving/boundary") as sp:
+            with self._phase("boundary", "serving/boundary"):
                 self._expire_deadlines(finished)
                 self._update_load_state()
                 self._auto_preempt()
                 self._burn_preempt()
-            phases["boundary"] = sp.dur_ns
             tracer.counter("serving/occupancy", live=self.live_count,
                            pending=self.scheduler.pending)
             with tracer.span("serving/grant") as sp:
@@ -1948,7 +2083,6 @@ class ServingEngine:
                         self._grantable_slots(),
                         page_budget=page_budget, page_cost=page_cost)
             phases["grant"] = sp.dur_ns
-            t_granted = sp.t0_ns + sp.dur_ns
             try:
                 decoded = False
                 if self._overlap and self._running_count() \
@@ -1965,7 +2099,19 @@ class ServingEngine:
                         self._decode_step(finished, t0)
                     decoded = True
                 if self._stall_free:
-                    self._admit_stall_free(granted, finished)
+                    batches = ()
+                    if granted:
+                        with _PagesPhase(self):
+                            batches = self._admit_stall_free(granted)
+                    for width, group in batches:
+                        if len(group) == 1:
+                            # singleton: the per-request path (no
+                            # sentinel padding, no scatter program) is
+                            # strictly cheaper — the batched dispatch
+                            # only pays off when it coalesces ≥2 prompts
+                            self._admit(group[0], finished)
+                        else:
+                            self._admit_batch(group, width, finished)
                     self._prefill_chunk_step(finished)
                 else:
                     for req in granted:
@@ -1995,18 +2141,21 @@ class ServingEngine:
             # after-step is taken out again, so telemetry_overhead_s
             # (which adds slo.overhead_s) never counts it twice
             slo_ns0 = self.slo.overhead_ns if self.slo is not None else 0
+            self._dispatched["device_calls"] = self._device_calls
             with tracer.span("serving/after_step") as sp:
-                # everything between grant and here that was neither the
-                # sync nor the replay: the dispatches and their host work
-                phases["dispatch"] = sp.t0_ns - t_granted \
-                    - phases.get("sync", 0) - phases.get("replay", 0)
                 wall = self._after_step(t_step, running_at_entry, granted,
                                         finished)
             self._after_step_ns += sp.dur_ns
             if self.slo is not None:
                 self._after_step_ns -= self.slo.overhead_ns - slo_ns0
+            # the step's account, measured: each figure from spans that
+            # closed inside the step (telemetry/tracer.py, PERF.md §3)
+            account = {f"{k}_ns": phases[k] for k in (
+                "enqueue", "prepare", "pages", "exposed") if k in phases}
+            if gc_ns_total() > gc_ns0:
+                account["gc_ns"] = gc_ns_total() - gc_ns0
             sp_step.set(tokens=self._tokens_emitted - tokens_at_entry,
-                        **self._dispatched)
+                        **self._dispatched, **account)
         if running_at_entry:
             # a running request waited through this WHOLE step for its
             # next token — the user-visible inter-token gap, admission
@@ -2064,7 +2213,10 @@ class ServingEngine:
             # up in step_gap p99 and drives the load-state machine
             self.metrics.record_step_overrun(wall, self.step_wall_budget_ms)
             tracer.instant("serving/step_overrun", wall_ms=wall * 1e3,
-                           budget_ms=self.step_wall_budget_ms)
+                           budget_ms=self.step_wall_budget_ms,
+                           device_calls=self._device_calls,
+                           phases_ms={k: v / 1e6
+                                      for k, v in self._phase_ns.items()})
         return wall
 
     def _effective_prefill_budget(self) -> Optional[int]:
@@ -2157,13 +2309,16 @@ class ServingEngine:
         if self._paged:
             # page the write column in BEFORE snapshotting the running
             # set: under pressure this can preempt a victim out of it
-            self._ensure_decode_pages(1)
+            with _PagesPhase(self):
+                self._ensure_decode_pages(1)
         running = [(slot, req) for slot, req in self._slot_req.items()
                    if req.state is RequestState.RUNNING]
-        # device twin of the current-token vector: decode never waits for
-        # the previous step's sampled tokens to round-trip the host
-        tokens = self._cur_dev[:, None]
-        pos = jnp.asarray(self.pool.positions())
+        with self._enqueue("cur_tokens", "transfer"):
+            # device twin of the current-token vector: decode never waits
+            # for the previous step's sampled tokens to round-trip the host
+            tokens = self._cur_dev[:, None]
+        with self._enqueue("decode", "transfer"):
+            pos = jax.device_put(self.pool.positions())
         self._dispatched["decode"] = len(running)
         more = ()
         if self._state_row_bytes:
@@ -2176,7 +2331,8 @@ class ServingEngine:
             for slot, _ in running:
                 rows[slot] = slot
             more = (self._cur_commit(rows),)
-        with self.tracer.span("serving/decode", live=len(running)) as sp:
+        with self._phase("prepare", "serving/decode",
+                         live=len(running)) as sp:
             self._note_state_rows(sp, len(running))
             if self._paged:
                 logits = self.pool.run_decode(eng, tokens, pos)
@@ -2187,14 +2343,17 @@ class ServingEngine:
                     np.arange(self.pool.num_slots), self.pool.starts, 1))
                 self._set_pool_reads(sp, 1)
             else:
-                logits, cache = eng._jit_decode(eng.params, self.pool.cache,
-                                                tokens, pos, *more)
+                with self._enqueue("decode"):
+                    logits, cache = eng._jit_decode(
+                        eng.params, self.pool.cache, tokens, pos, *more)
         if self.faults is not None:
             logits, _ = self.faults.corrupt_logits(
                 logits, [slot for slot, _ in running])
         # dispatch the finite check; the (B,) bool rides the step fetch
-        finite_dev = (self._jit_finite(logits)
-                      if self._jit_finite is not None and running else None)
+        finite_dev = None
+        if self._jit_finite is not None and running:
+            with self._enqueue("finite"):
+                finite_dev = self._jit_finite(logits)
         if not self._paged:
             self.pool.cache = cache
         if self._prefill_queue:
@@ -2208,7 +2367,7 @@ class ServingEngine:
             self.pool.advance(deltas)
         else:
             self.pool.advance(1)
-        with self.tracer.span("serving/sample"):
+        with self._phase("prepare", "serving/sample"):
             nxt_dev = self._sample_dev(logits)
         # full-batch overwrite: every row's next current token IS this
         # decode's sample for that row (non-running rows hold garbage a
@@ -2249,7 +2408,8 @@ class ServingEngine:
             # verify writes K+1 columns past every RUNNING slot's index;
             # page them in first (may preempt under pressure, so it runs
             # before the drafter snapshots the live set)
-            self._ensure_decode_pages(K + 1)
+            with _PagesPhase(self):
+                self._ensure_decode_pages(K + 1)
 
         # PREFILLING slots keep histories[slot] = None: the drafter
         # proposes nothing for them (draft_len 0) and their deltas stay
@@ -2277,41 +2437,46 @@ class ServingEngine:
             for slot, req in self._slot_req.items():
                 if req.state is RequestState.RUNNING:
                     histories[slot] = req.tokens()
-            with self.tracer.span("serving/draft", k=K):
+            with self._phase("prepare", "serving/draft", k=K):
+                # (a model drafter's own programs and its fetch lie in
+                # here, unmarked: no serving/enqueue of theirs)
                 draft, draft_len = self._drafter.propose(histories, K)
             draft = np.asarray(draft, np.int32)
             draft_len = np.clip(np.asarray(draft_len, np.int32), 0, K)
             t_draft = self._now() - t0
 
-        # device twin feeds verify directly — no host round-trip for the
-        # previous step's tokens
-        tokens = jnp.concatenate(
-            [self._cur_dev[:, None], jnp.asarray(draft)], axis=1)
-        self._rng, sub = jax.random.split(self._rng)
+        with self._enqueue("cur_tokens", "transfer"):
+            draft_dev = jax.device_put(draft)
+        with self._enqueue("cur_tokens", "transfer"):
+            # device twin feeds verify directly — no host round-trip for
+            # the previous step's tokens
+            tokens = jnp.concatenate(
+                [self._cur_dev[:, None], draft_dev], axis=1)
+        with self._enqueue("rng_split", "transfer"):
+            self._rng, sub = jax.random.split(self._rng)
         self._dispatched["decode"] = self._running_count()
-        with self.tracer.span("serving/verify_k", k=K) as sp:
-            if self._paged:
-                out_dev, n_emit_dev = self.pool.run_verify(
-                    eng, tokens,
-                    jnp.asarray(self.pool.positions()), jnp.asarray(draft),
-                    jnp.asarray(draft_len), sub,
-                    jnp.asarray(self.temperature, jnp.float32),
+        with self._phase("prepare", "serving/verify_k", k=K) as sp:
+            with self._enqueue("verify_k", "transfer"):
+                pos, lens, temperature = jax.device_put(
+                    (self.pool.positions(), draft_len,
+                     np.float32(self.temperature)))
+            args = (tokens, pos, draft_dev, lens, sub, temperature,
                     self._greedy, int(self.top_k), float(self.top_p))
+            if self._paged:
+                out_dev, n_emit_dev = self.pool.run_verify(eng, *args)
                 sp.set(pool_writes=self.pool.pages_touched(
                     np.arange(self.pool.num_slots), self.pool.starts,
                     K + 1))
                 self._set_pool_reads(sp, K + 1)
             else:
-                cache, out_dev, n_emit_dev = eng.verify_k(
-                    self.pool.cache, tokens,
-                    jnp.asarray(self.pool.positions()), jnp.asarray(draft),
-                    jnp.asarray(draft_len), sub,
-                    jnp.asarray(self.temperature, jnp.float32),
-                    self._greedy, int(self.top_k), float(self.top_p))
+                with self._enqueue("verify_k"):
+                    cache, out_dev, n_emit_dev = eng.verify_k(
+                        self.pool.cache, *args)
                 self.pool.cache = cache
         # next step's current token per row is the last EMITTED one:
         # out[b, n_emit[b]-1] (n_emit >= 1 always for live rows)
-        self._cur_dev = self._jit_spec_cur(out_dev, n_emit_dev)
+        with self._enqueue("spec_cur"):
+            self._cur_dev = self._jit_spec_cur(out_dev, n_emit_dev)
         live = [(slot, req) for slot, req in self._slot_req.items()
                 if req.state is RequestState.RUNNING]
 

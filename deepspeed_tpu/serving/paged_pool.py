@@ -269,6 +269,7 @@ class PagedKVPool(SlotPool):
         self.table = np.full((num_slots, self.pages_per_slot), P, np.int32)
         self.cow_copies = 0
         self.page_evictions = 0
+        self.pages_allocated = 0      # lifetime pops of the free list
         self.registry = None              # optional MetricsRegistry
         self.prefix = PrefixCache(page_size) if prefix_cache else None
         # the window group (None for a model of one layer kind): sized
@@ -353,8 +354,9 @@ class PagedKVPool(SlotPool):
         """Rebuild the device page tables from the host mirrors (same
         committed-leaf discipline as ``_index_from_mirror``)."""
         cs = dict(self.cache["cache_store"])
-        for key in self._table_keys:
-            cs[key] = self._table_from_mirror(key)
+        with self.enqueue("table", "transfer"):
+            for key in self._table_keys:
+                cs[key] = self._table_from_mirror(key)
         self.cache = {"cache_store": cs}
 
     @staticmethod
@@ -367,19 +369,20 @@ class PagedKVPool(SlotPool):
         """The page tables of a cache container, one a layer group."""
         return {key: cs[key] for key in self._table_keys}
 
-    def _window_rows(self, slots) -> dict:
-        """``win_tables=`` the slots' rows of the window group's table,
-        for the programs that take host-passed rows; nothing for a pool
-        of one group. A slot id out of range (batch padding) gets an
-        all-sentinel row, which writes nothing."""
+    def _window_rows(self, slots) -> tuple:
+        """The slots' rows of the window group's table (on the host: the
+        caller puts them on the device with its other arguments), for
+        the programs that take host-passed rows as ``win_tables``;
+        nothing for a pool of one group. A slot id out of range (batch
+        padding) gets an all-sentinel row, which writes nothing."""
         if self.ring is None:
-            return {}
+            return ()
         slots = np.atleast_1d(np.asarray(slots, np.int64))
         real = slots < self.num_slots
         rows = np.full((len(slots), self.pages_per_slot),
                        self.ring.num_pages, np.int32)
         rows[real] = self.ring.table[slots[real]]
-        return {"win_tables": jnp.asarray(rows)}
+        return (rows,)
 
     def _inc(self, name: str, amount: float = 1.0) -> None:
         if self.registry is not None:
@@ -436,6 +439,7 @@ class PagedKVPool(SlotPool):
         pid = heapq.heappop(self._free_pages)
         self._free_page_set.discard(pid)
         self.page_refs[pid] = 1
+        self.pages_allocated += 1
         return pid
 
     # ------------------------------------------------------------------
@@ -509,9 +513,12 @@ class PagedKVPool(SlotPool):
             elif self.page_refs[pid] > 1:
                 fork = self.alloc_page()
                 try:
-                    cs = self._jit_copy_page(self.cache["cache_store"],
-                                             jnp.asarray(pid, jnp.int32),
-                                             jnp.asarray(fork, jnp.int32))
+                    with self.enqueue("copy_page", "transfer"):
+                        pages = jax.device_put((np.int32(pid),
+                                                np.int32(fork)))
+                    with self.enqueue("copy_page"):
+                        cs = self._jit_copy_page(self.cache["cache_store"],
+                                                 *pages)
                 except Exception:
                     # copy dispatch died before the fork was mapped:
                     # return it to the free list (fresh refcount is 1)
@@ -1087,14 +1094,15 @@ class PagedKVPool(SlotPool):
         # fn(...)`): the watchdog and graftcheck identify watched
         # programs by the attribute the call goes through; each arm
         # rebinds self.cache immediately — its cache operand is donated
-        if self._paged_decode_kernel_jit is not None:
-            logits, cs, stats = self._paged_decode_kernel_jit(
-                engine.params, self.cache["cache_store"], tokens, pos)
-            self.cache = {"cache_store": cs}
-        else:
-            logits, cs, stats = self._paged_decode_jit(
-                engine.params, self.cache["cache_store"], tokens, pos)
-            self.cache = {"cache_store": cs}
+        with self.enqueue("decode"):
+            if self._paged_decode_kernel_jit is not None:
+                logits, cs, stats = self._paged_decode_kernel_jit(
+                    engine.params, self.cache["cache_store"], tokens, pos)
+                self.cache = {"cache_store": cs}
+            else:
+                logits, cs, stats = self._paged_decode_jit(
+                    engine.params, self.cache["cache_store"], tokens, pos)
+                self.cache = {"cache_store": cs}
         if stats is not None:
             self.moe_stats.append(stats)
         return logits
@@ -1106,18 +1114,19 @@ class PagedKVPool(SlotPool):
         the kernel active the K + 1 query rows a slot read and write the
         pages in place whatever K is (:meth:`reads_in_place`)."""
         self.bind_engine(engine)
-        if self.reads_in_place(tokens.shape[1]):
-            cs, out, n_emit = self._paged_verify_kernel_jit(
-                engine.params, self.cache["cache_store"], tokens, pos,
-                draft, draft_len, rng, temperature, greedy, int(top_k),
-                float(top_p))
-            self.cache = {"cache_store": cs}
-        else:
-            cs, out, n_emit = self._paged_verify_jit(
-                engine.params, self.cache["cache_store"], tokens, pos,
-                draft, draft_len, rng, temperature, greedy, int(top_k),
-                float(top_p))
-            self.cache = {"cache_store": cs}
+        with self.enqueue("verify_k"):
+            if self.reads_in_place(tokens.shape[1]):
+                cs, out, n_emit = self._paged_verify_kernel_jit(
+                    engine.params, self.cache["cache_store"], tokens, pos,
+                    draft, draft_len, rng, temperature, greedy, int(top_k),
+                    float(top_p))
+                self.cache = {"cache_store": cs}
+            else:
+                cs, out, n_emit = self._paged_verify_jit(
+                    engine.params, self.cache["cache_store"], tokens, pos,
+                    draft, draft_len, rng, temperature, greedy, int(top_k),
+                    float(top_p))
+                self.cache = {"cache_store": cs}
         return out, n_emit
 
     def run_prefill_chunk(self, engine: Any, ids, slot: int, start: int,
@@ -1131,12 +1140,16 @@ class PagedKVPool(SlotPool):
             raise ValueError("run_prefill_chunk requires a module with "
                              "prefill_chunk(); the TransformerLM family "
                              "has one")
-        logits, cs, stats = self._paged_chunk_jit(
-            engine.params, self.cache["cache_store"],
-            jnp.asarray(ids, jnp.int32), jnp.asarray(self.table[slot]),
-            jnp.asarray(slot, jnp.int32), jnp.asarray(start, jnp.int32),
-            jnp.asarray(length, jnp.int32),
-            jnp.asarray(last_idx, jnp.int32), **self._window_rows([slot]))
+        # the chunk's host-built arguments in ONE put: one transfer, one
+        # ``serving/enqueue`` span, whatever the window group adds
+        with self.enqueue("chunk", "transfer"):
+            args = jax.device_put(
+                (np.asarray(ids, np.int32), self.table[slot],
+                 np.int32(slot), np.int32(start), np.int32(length),
+                 np.int32(last_idx)) + self._window_rows([slot]))
+        with self.enqueue("chunk"):
+            logits, cs, stats = self._paged_chunk_jit(
+                engine.params, self.cache["cache_store"], *args)
         self.cache = {"cache_store": cs}
         if stats is not None:
             self.moe_stats.append(stats)
@@ -1153,10 +1166,13 @@ class PagedKVPool(SlotPool):
             if s < self.num_slots:
                 rows[i] = self.table[s]
         self._sync_table()       # publish ensure_writable's new mappings
-        self.cache = {"cache_store": self._admit_rows_jit(
-            self.cache["cache_store"], prefill_cache["cache_store"],
-            jnp.asarray(rows), jnp.asarray(slots), jnp.asarray(lengths),
-            **self._window_rows(slots))}
+        with self.enqueue("admit_rows", "transfer"):
+            args = jax.device_put((rows, slots, lengths)
+                                  + self._window_rows(slots))
+        with self.enqueue("admit_rows"):
+            self.cache = {"cache_store": self._admit_rows_jit(
+                self.cache["cache_store"], prefill_cache["cache_store"],
+                *args)}
         real = slots < self.num_slots
         self.starts[slots[real]] = lengths[real]
 

@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..parallel import mesh as mesh_mod
+from ..telemetry.tracer import enqueue_span
 
 
 class SlotPool:
@@ -60,6 +61,10 @@ class SlotPool:
         if sharding is None and mesh_mod.has_mesh():
             sharding = NamedSharding(mesh_mod.get_mesh(), PartitionSpec())
         self._sharding = sharding
+        # every device call the pool makes goes through this (a program's
+        # gets a ``serving/enqueue`` span); a server hands in its own,
+        # which keeps the step's account too
+        self.enqueue = enqueue_span
         # the flax "cache" collection pytree the engine's decode consumes
         self.cache: Dict[str, Any] = self._fresh_cache()
         # host mirror of the per-slot cache index (device truth lives in
@@ -110,9 +115,10 @@ class SlotPool:
         fork the admit/decode executables on sharding mismatch)."""
         # explicit copy: the CPU backend may zero-copy a numpy buffer,
         # and the mirror is mutated in place by later advance() calls
-        idx = jnp.array(self.starts, copy=True)
-        if self._sharding is not None:
-            idx = self._place_leaf("index", idx)
+        with self.enqueue("index", "transfer"):
+            idx = jnp.array(self.starts, copy=True)
+            if self._sharding is not None:
+                idx = self._place_leaf("index", idx)
         return idx
 
     # ------------------------------------------------------------------
@@ -216,9 +222,12 @@ class SlotPool:
         if np.any(lengths[real] > self.capacity):
             raise ValueError(f"sequence length {int(lengths[real].max())} "
                              f"exceeds slot capacity {self.capacity}")
-        self.cache = {"cache_store": self._admit_rows_jit(
-            self.cache["cache_store"], prefill_cache["cache_store"],
-            jnp.asarray(slots), jnp.asarray(lengths))}
+        with self.enqueue("admit_rows", "transfer"):
+            where = jax.device_put((slots, lengths))
+        with self.enqueue("admit_rows"):
+            self.cache = {"cache_store": self._admit_rows_jit(
+                self.cache["cache_store"], prefill_cache["cache_store"],
+                *where)}
         self.starts[slots[real]] = lengths[real]
 
     def admit(self, prefill_cache: dict, slot: int, length: int) -> None:
@@ -226,9 +235,12 @@ class SlotPool:
         if length > self.capacity:
             raise ValueError(f"sequence length {length} exceeds slot "
                              f"capacity {self.capacity}")
-        self.cache = {"cache_store": self._admit_jit(
-            self.cache["cache_store"], prefill_cache["cache_store"],
-            jnp.asarray(slot, jnp.int32), jnp.asarray(length, jnp.int32))}
+        with self.enqueue("admit_row", "transfer"):
+            where = jax.device_put((np.int32(slot), np.int32(length)))
+        with self.enqueue("admit_row"):
+            self.cache = {"cache_store": self._admit_jit(
+                self.cache["cache_store"], prefill_cache["cache_store"],
+                *where)}
         self.starts[slot] = length
 
     def advance(self, lengths) -> None:

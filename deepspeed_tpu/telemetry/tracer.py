@@ -52,7 +52,9 @@ arrows the router draws at every handoff/transfer/failover boundary.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import gc
 import json
 import threading
 import time
@@ -308,6 +310,70 @@ def default_tracer() -> Tracer:
     """The process-wide tracer, enabled: what an engine records into when
     it is given no ``tracer=``."""
     return _DEFAULT_TRACER
+
+
+# what a device call that gets no span is wrapped in
+NO_SPAN = contextlib.nullcontext()
+
+
+def enqueue_span(program: str, kind: str = "program"):
+    """``serving/enqueue`` around ONE call that hands the device work.
+    ``kind`` ``program`` (a jitted program) gets the span, which ends when
+    the call returns: that is when the work is queued. ``transfer`` (host
+    arrays put on the device, an eager operation on a device array) gets
+    none: a serving step makes four to eight of them, and a span each
+    cost more than the account may (PERF.md §6, PR 34); a server counts
+    them in ``device_calls``. This is what a pool without a server opens,
+    in the process-wide tracer; a ``ServingEngine`` hands its pool its own
+    (``ServingEngine._enqueue``), which opens the same span in the
+    server's tracer and keeps the step's account besides."""
+    if kind != "program":
+        return NO_SPAN
+    return _DEFAULT_TRACER.span("serving/enqueue", program=program,
+                                kind=kind)
+
+
+# ---------------------------------------------------------------------------
+# full garbage collections
+# ---------------------------------------------------------------------------
+class _GcWatch:
+    """The ``gc.callbacks`` entry behind :func:`watch_gc`."""
+
+    def __init__(self):
+        self.total_ns = 0
+        self._span: Optional[_Span] = None
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._span = _DEFAULT_TRACER.span("host/gc", generation=2)
+            self._span.__enter__()
+        elif self._span is not None:
+            sp, self._span = self._span, None
+            sp.set(collected=info["collected"])
+            sp.__exit__(None, None, None)
+            self.total_ns += sp.dur_ns
+
+
+_GC_WATCH = _GcWatch()
+
+
+def watch_gc() -> None:
+    """Leave a ``host/gc`` span (``generation``, ``collected``) in the
+    process-wide tracer for every FULL collection from now on: a
+    generation-2 pass stops the interpreter for tens of milliseconds, and
+    a step that held one is otherwise a stall with no name. Collections
+    of generations 0 and 1 return at once. Installed once a process, by
+    the first engine that records into :func:`default_tracer`."""
+    if _GC_WATCH not in gc.callbacks:
+        gc.callbacks.append(_GC_WATCH)
+
+
+def gc_ns_total() -> int:
+    """Nanoseconds spent in the full collections :func:`watch_gc` has
+    seen: read at both ends of a stretch to know what fell inside."""
+    return _GC_WATCH.total_ns
 
 
 # ---------------------------------------------------------------------------

@@ -55,8 +55,10 @@ class SynchronizedWallClockTimer:
                 pass
 
         def start(self):
+            # no device round trip before the clock read: the bare
+            # synchronize() waits for no enqueued compute (module doc),
+            # and what bounds the interval is stop()
             assert not self.started_, f"{self.name_} timer has already been started"
-            self._sync()
             self.start_time = time.time()
             self.started_ = True
 
@@ -179,12 +181,7 @@ class ThroughputTimer:
         self._init_timer()
         self.started = True
         if self.global_step_count >= self.start_step:
-            from ..accelerator import get_accelerator
-
-            try:
-                get_accelerator().synchronize()
-            except Exception:
-                pass
+            # as Timer.start: stop(block_on=...) bounds the interval
             self.start_time = time.time()
 
     def stop(self, global_step: bool = False, report_speed: bool = True,

@@ -1,7 +1,13 @@
 """The spans the two engines leave in the process-wide tracer by default
 (PR 23): the phases of a serving step and of a train step, in order and
 inside their step span; the request events mirrored into the ring; the
-flight recorder's phase split; the set-up spans of both entry points."""
+flight recorder's phase split; the set-up spans of both entry points.
+Since PR 34 the step's account: a ``serving/enqueue`` around every device
+call, ``serving/pages``, ``host/gc``, and what ``serving/step`` says of
+them at its close, on both K/V pools."""
+
+import gc
+import time
 
 import jax
 import jax.numpy as jnp
@@ -116,10 +122,17 @@ def test_flight_recorder_shows_where_the_step_went(server_parts):
     dump = srv.debug_dump()
     last = dump["steps"][-1]
     phases = last["phases_ms"]
-    assert set(phases) == {"boundary", "grant", "dispatch", "sync", "replay"}
+    # measured, none subtracted: the second step of a server has them all
+    # (`pages` only where a pool has pages: the default one has none)
+    assert set(phases) == {"boundary", "grant", "prepare", "enqueue",
+                           "exposed", "sync", "replay"}
+    assert "dispatch" not in srv._phase_ns
     assert all(v >= 0 for v in phases.values())
-    assert sum(phases.values()) <= last["wall_ms"]
+    # `exposed` lies across the others; they add up to less than the step
+    assert sum(v for k, v in phases.items() if k != "exposed") \
+        <= last["wall_ms"]
     assert last["dispatched"]["decode"] == 1
+    assert last["dispatched"]["device_calls"] >= 5
     # no longer empty by default: the tail of the process-wide ring
     assert any(e["name"] == "serving/step" for e in dump["last_spans"])
     assert dump["telemetry_overhead_s"] > 0.0
@@ -151,10 +164,371 @@ def test_explicit_disabled_tracer_silences_a_server(server_parts):
                max_new_tokens=3)
     srv.run_until_drained(max_steps=50)
     assert quiet.events() == []
-    assert default_tracer().events_total == n0
+    # (a full collection is the process's, not the server's: host/gc)
+    assert [e["name"] for e in _new_events(n0)
+            if e["name"] != "host/gc"] == []
     # the after-step clock still runs: spans time themselves regardless
     assert srv.telemetry_overhead_s > 0.0
     assert srv.debug_dump()["steps"][-1]["wall_ms"] > 0
+
+
+# -- the step's account (PR 34) ---------------------------------------------
+POOLS = {"contiguous": False, "paged": {"kernel": "off"}}
+DISPATCH = ("serving/admit", "serving/prefill_batch",
+            "serving/prefill_chunk", "serving/decode")
+# a plain decode step: the token twin's reshape, positions, the program,
+# the key split, the temperature, the sampler, the commit of its tokens
+DECODE_CALLS = 7
+# a step that only carries a chunk: its arguments in one put + the program
+# (+ the table, republished because the chunk mapped a fresh page)
+CHUNK_CALLS = {"contiguous": 2, "paged": 3}
+
+
+def _kids(evs, parent, name=None):
+    return sorted((e for e in evs if e["ph"] == "X" and e is not parent
+                   and _inside(parent, e)
+                   and (name is None or e["name"] == name)),
+                  key=lambda e: e["ts"])
+
+
+@pytest.fixture(scope="module", params=sorted(POOLS))
+def account(request, server_parts):
+    """One server a pool, driven through a chunked prompt, a batched and a
+    single bucketed admission, plain decode steps, and one step with a
+    forced full collection; every step overruns its (tiny) wall budget."""
+    model, params = server_parts
+    srv = ds.init_serving(model, model_parameters=params,
+                          config={"dtype": "float32"}, num_slots=4,
+                          max_queue_depth=8, prefill_chunk=8,
+                          prefill_token_budget=64,
+                          paged_kv=POOLS[request.param],
+                          step_wall_budget_ms=1e-6)
+    rng = np.random.default_rng(11)
+    n0 = default_tracer().events_total
+
+    def prompt(n):
+        return rng.integers(0, 64, size=n).astype(np.int32)
+
+    srv.submit(prompt(20), max_new_tokens=12)      # three chunks of 8
+    for _ in range(4):
+        srv.step()
+    for n in (5, 6):                               # one bucket: batched
+        srv.submit(prompt(n), max_new_tokens=4)
+    srv.step()
+    srv.submit(prompt(7), max_new_tokens=4)        # alone: serving/admit
+    srv.step()
+    grant = srv.scheduler.grant
+
+    def collecting_grant(*a, **kw):
+        gc.collect()
+        return grant(*a, **kw)
+
+    srv.scheduler.grant = collecting_grant
+    srv.step()
+    srv.scheduler.grant = grant
+    srv.run_until_drained(max_steps=60)
+    evs = _new_events(n0)
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    assert len(steps) == srv.step_id
+    return {"pool": request.param, "srv": srv, "evs": evs, "steps": steps}
+
+
+def _steps_with(account, *names, without=()):
+    out = []
+    for step in account["steps"]:
+        have = {k["name"] for k in _kids(account["evs"], step)}
+        if all(n in have for n in names) \
+                and not any(n in have for n in without):
+            out.append(step)
+    assert out, (names, without)
+    return out
+
+
+@pytest.mark.parametrize("dispatch", DISPATCH)
+def test_every_dispatch_span_holds_its_enqueue(account, dispatch):
+    evs = account["evs"]
+    found = [e for e in evs if e["name"] == dispatch]
+    assert found
+    for sp in found:
+        # the program itself is queued inside the span that names it; the
+        # puts that feed it are counted and leave no span
+        kids = _kids(evs, sp, "serving/enqueue")
+        assert kids and all(k["args"]["program"] for k in kids)
+        assert {k["args"]["kind"] for k in kids} == {"program"}
+
+
+def test_device_calls_counts_every_call_and_the_programs_have_spans(account):
+    evs = account["evs"]
+    for step in account["steps"]:
+        kids = _kids(evs, step, "serving/enqueue")
+        # the enqueue children are the step's programs; its puts and eager
+        # operations are in the count alone
+        assert step["args"]["device_calls"] >= len(kids)
+        assert bool(step["args"]["device_calls"]) == bool(kids)
+        assert step["args"].get("enqueue_ns", 0) == \
+            sum(k["dur"] for k in kids)
+        # a program is queued under a span that says what the host was
+        # doing (pages, a dispatch, the sampling, the replay), or it is one
+        # of the step's own: the finite check and the twin's update
+        named = [p for name in DISPATCH + (
+            "serving/pages", "serving/sample", "serving/replay",
+            "serving/boundary") for p in _kids(evs, step, name)]
+        own = {k["args"]["program"] for k in kids
+               if not any(_inside(p, k) for p in named)}
+        assert own <= {"finite", "cur_scatter"}
+
+
+def test_device_calls_of_a_plain_decode_step_are_pinned(account):
+    plain = _steps_with(account, "serving/decode", without=(
+        "serving/admit", "serving/prefill_batch", "serving/prefill_chunk"))
+    # (a replay that retires a request republishes a paged pool's table:
+    # puts that leave no span, so the quiet steps are the cheapest ones)
+    counts = [s["args"]["device_calls"] for s in plain]
+    assert min(counts) == DECODE_CALLS
+    assert counts.count(DECODE_CALLS) > len(counts) // 2
+    quiet = [s for s in plain if s["args"]["device_calls"] == DECODE_CALLS]
+    programs = [k["args"]["program"] for k in _kids(
+        account["evs"], quiet[0], "serving/enqueue")]
+    assert programs == ["decode", "sample"]
+
+
+def test_device_calls_of_a_chunk_step_are_pinned(account):
+    only = _steps_with(account, "serving/prefill_chunk",
+                       without=("serving/decode", "serving/admit",
+                                "serving/prefill_batch"))
+    # the step that seats the request also resets its row: not that one
+    later = [s for s in only if s["args"]["step"] > 1]
+    assert later
+    assert {s["args"]["device_calls"] for s in later} == \
+        {CHUNK_CALLS[account["pool"]]}
+
+
+def test_exposed_runs_from_the_last_sync_to_the_first_program(account):
+    evs, steps = account["evs"], account["steps"]
+    assert "exposed_ns" not in steps[0]["args"]
+    checked = 0
+    for before, step in zip(steps, steps[1:]):
+        syncs = _kids(evs, before, "serving/sync")
+        first = [k for k in _kids(evs, step, "serving/enqueue")
+                 if k["args"]["kind"] == "program"]
+        if not syncs or not first:
+            # the device was never known idle: the step before ended in
+            # no sync (it only queued a chunk), or this one queued nothing
+            assert "exposed_ns" not in step["args"]
+            continue
+        assert step["args"]["exposed_ns"] == first[0]["ts"] \
+            + first[0]["dur"] - (syncs[-1]["ts"] + syncs[-1]["dur"])
+        assert step["args"]["exposed_ns"] > 0
+        checked += 1
+    assert checked >= 5
+
+
+def test_prepare_is_the_dispatch_spans_outside_their_enqueues(account):
+    evs = account["evs"]
+    for step in account["steps"]:
+        spans = [e for name in DISPATCH + ("serving/sample",)
+                 for e in _kids(evs, step, name)]
+        # (an admission's sampling lies inside its dispatch span)
+        top = [e for e in spans if not any(
+            o is not e and _inside(o, e) for o in spans)]
+        want = sum(e["dur"] - sum(k["dur"] for k in _kids(
+            evs, e, "serving/enqueue")) for e in top)
+        assert step["args"].get("prepare_ns", 0) == want
+
+
+def test_pages_span_says_what_the_pool_did(account):
+    evs, paged = account["evs"], account["pool"] == "paged"
+    pages = [e for e in evs if e["name"] == "serving/pages"]
+    assert pages and all(set(e["args"]) == {"allocated", "forked",
+                                            "preempted"} for e in pages)
+    allocated = sum(e["args"]["allocated"] for e in pages)
+    if paged:
+        # every page the chunks and the decode steps wrote into (a
+        # bucketed admission maps its own inside its dispatch span)
+        assert 0 < allocated <= account["srv"].pool.pages_allocated
+    else:
+        assert allocated == 0
+    for step in account["steps"]:
+        mine = _kids(evs, step, "serving/pages")
+        want = sum(e["dur"] - sum(k["dur"] for k in _kids(
+            evs, e, "serving/enqueue")) for e in mine)
+        assert step["args"].get("pages_ns", 0) == want
+
+
+def test_self_time_of_a_step_is_what_no_span_holds(account):
+    """The account's phases are disjoint: with the after-step and the
+    step's self time (the step less its top-level spans: the puts that
+    leave no span are in it) they add up to the step."""
+    evs = account["evs"]
+    over = [e for e in evs if e["name"] == "serving/step_overrun"]
+    for inst, step in zip(over, account["steps"]):
+        kids = _kids(evs, step)
+        top = [e for e in kids if not any(
+            o is not e and _inside(o, e) for o in kids)]
+        self_ns = step["dur"] - sum(e["dur"] for e in top)
+        assert self_ns >= 0
+        after, = _kids(evs, step, "serving/after_step")
+        collected = sum(g["dur"] for g in _kids(evs, step, "host/gc")
+                        if not any(_inside(p, g) for p in top if p is not g))
+        phases_ns = sum(v * 1e6 for k, v in inst["args"]["phases_ms"].items()
+                        if k != "exposed")
+        assert phases_ns + after["dur"] + collected + self_ns == \
+            pytest.approx(step["dur"], abs=100)
+
+
+def test_a_full_collection_inside_a_step_is_named(account):
+    evs = account["evs"]
+    held = [(s, _kids(evs, s, "host/gc")) for s in account["steps"]]
+    held = [(s, g) for s, g in held if g]
+    assert held
+    for step, collections in held:
+        assert all(g["args"]["generation"] == 2
+                   and g["args"]["collected"] >= 0 for g in collections)
+        assert step["args"]["gc_ns"] == sum(g["dur"] for g in collections)
+    # the forced one fell inside serving/grant of its step
+    assert any(_inside(grant, g) for s, gs in held for g in gs
+               for grant in _kids(evs, s, "serving/grant"))
+    assert all("gc_ns" not in s["args"] for s in account["steps"]
+               if not _kids(evs, s, "host/gc"))
+
+
+def test_an_overrun_names_the_phases_of_its_step(account):
+    evs, srv = account["evs"], account["srv"]
+    over = [e for e in evs if e["name"] == "serving/step_overrun"]
+    assert len(over) == len(account["steps"])
+    for inst, step in zip(over, account["steps"]):
+        phases = inst["args"]["phases_ms"]
+        assert "dispatch" not in phases
+        assert {"boundary", "grant"} <= set(phases)
+        assert inst["args"]["device_calls"] == step["args"]["device_calls"]
+        if step["args"]["device_calls"]:
+            assert phases["enqueue"] * 1e6 == pytest.approx(
+                step["args"]["enqueue_ns"])
+    # the flight recorder's steps carry the same split
+    rec = srv.debug_dump()["steps"][-1]
+    assert "dispatch" not in rec["phases_ms"]
+    assert rec["dispatched"]["device_calls"] == \
+        account["steps"][-1]["args"]["device_calls"]
+
+
+def test_a_dispatch_span_opens_where_it_did(account, server_parts):
+    """The accepted ``step_host_serial_ms_p50`` ends where a step's first
+    dispatch span opens: ``serving/decode`` opens after the positions are
+    read and put, as before the account, and holds the program's call;
+    ``serving/sample`` holds the sampler."""
+    srv = _serve(server_parts)
+    read_at = []
+    positions = srv.pool.positions
+    srv.pool.positions = lambda: read_at.append(
+        time.perf_counter_ns()) or positions()
+    n0 = default_tracer().events_total
+    rng = np.random.default_rng(13)
+    srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
+               max_new_tokens=4)
+    srv.run_until_drained(max_steps=20)
+    new = _new_events(n0)
+    decodes = [e for e in new if e["name"] == "serving/decode"]
+    assert len(read_at) == len(decodes) > 0
+    for t, decode in zip(read_at, decodes):
+        assert t < decode["ts"]
+        assert [(k["args"]["program"], k["args"]["kind"]) for k in _kids(
+            new, decode, "serving/enqueue")] == [("decode", "program")]
+    evs = account["evs"]
+    for step in _steps_with(account, "serving/decode"):
+        sample = _kids(evs, step, "serving/sample")[-1]
+        assert [k["args"]["program"] for k in _kids(
+            evs, sample, "serving/enqueue")] == ["sample"]
+
+
+def test_counter_tracks_sample_every_step(account):
+    """One sample a track a step, as before the account."""
+    evs, paged = account["evs"], account["pool"] == "paged"
+    for name in ("serving/occupancy",) + (("paging/pages",) if paged
+                                          else ()):
+        samples = [e for e in evs if e["ph"] == "C" and e["name"] == name]
+        assert len(samples) == len(account["steps"])
+    # the level a track shows is the server's: the last sample is the end
+    occupancy = [e for e in evs if e["name"] == "serving/occupancy"]
+    assert occupancy[-1]["args"]["pending"] == 0
+
+
+def test_with_the_ring_off_a_device_call_is_counted_and_nothing_else(
+        server_parts):
+    """``Tracer.enabled`` False: no ``serving/enqueue`` object, annotation
+    or clock read a device call, so the cost of the marks can be measured
+    on against off; the step still counts its calls."""
+    from deepspeed_tpu.serving import engine as serving_engine
+
+    srv = _serve(server_parts, tracer=Tracer(enabled=False))
+    assert srv._enqueue("decode") is serving_engine.NO_SPAN
+    rng = np.random.default_rng(12)
+    srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
+               max_new_tokens=4)
+    srv.run_until_drained(max_steps=50)
+    assert srv.tracer.events() == []
+    steps = srv.debug_dump()["steps"]
+    assert any(s["dispatched"]["device_calls"] == DECODE_CALLS
+               for s in steps)
+    assert all("enqueue" not in s["phases_ms"]
+               and "exposed" not in s["phases_ms"] for s in steps)
+
+
+def test_a_pool_without_a_server_still_marks_its_calls(server_parts):
+    """The pool's own default: a program's span alone, in the process-wide
+    ring; a put leaves none."""
+    from deepspeed_tpu.serving.slot_pool import SlotPool
+
+    model, params = server_parts
+    engine = ds.init_inference(model=model, model_parameters=params,
+                               config={"dtype": "float32"})
+    engine._ensure_params(jnp.zeros((1, 2), jnp.int32))
+    pool = SlotPool(engine.kv_cache_spec(), 2)
+    n0 = default_tracer().events_total
+    pool.advance(np.array([1, 0], np.int32))       # the index: a put
+    assert not [e for e in _new_events(n0)
+                if e["name"] == "serving/enqueue"]
+    with pool.enqueue("admit_row"):
+        pass
+    mine = [e for e in _new_events(n0) if e["name"] == "serving/enqueue"]
+    assert [(e["args"]["program"], e["args"]["kind"]) for e in mine] == \
+        [("admit_row", "program")]
+
+
+def test_train_timers_start_without_a_device_round_trip():
+    """One clock in the trainer: nothing between ``train/stack_batch`` and
+    ``train/dispatch`` waits for the device (each timer's start used to
+    drain every local device), and the timers still log a step's wall."""
+    from deepspeed_tpu.utils.timer import TRAIN_BATCH_TIMER
+
+    engine, _, _, _ = ds.initialize(
+        model=SimpleModel(hidden_dim=16),
+        config=dict(base_config(micro=2, gas=2), wall_clock_breakdown=True))
+    batch = random_batch(32)
+    engine.train_batch(batch=batch)        # builds the state, compiles
+    n0 = default_tracer().events_total
+    for _ in range(4):
+        engine.train_batch(batch=batch)
+    evs = _new_events(n0)
+    steps = [e for e in evs if e["name"] == "train/step"]
+    assert len(steps) == 4
+    for step in steps:
+        stack, = _kids(evs, step, "train/stack_batch")
+        dispatch, = _kids(evs, step, "train/dispatch")
+        sync, = _kids(evs, step, "train/sync")
+        gap_ns = dispatch["ts"] - (stack["ts"] + stack["dur"])
+        assert 0 <= gap_ns < 1_000_000
+    timer = engine.timers(TRAIN_BATCH_TIMER)
+    assert not timer.started_
+    # a step's wall, dispatch to the end of the sync, each step recorded
+    recorded = timer.records[-4:]
+    assert len(recorded) == 4
+    for ms, step in zip(recorded, steps):
+        dispatch, = _kids(evs, step, "train/dispatch")
+        sync, = _kids(evs, step, "train/sync")
+        wall_ms = (sync["ts"] + sync["dur"] - dispatch["ts"]) / 1e6
+        assert ms == pytest.approx(wall_ms, abs=2.0)
+    assert engine.tput_timer.global_step_count == 5
+    assert engine.tput_timer.total_elapsed_time > 0.0
 
 
 def test_train_step_leaves_its_phases_in_order():
