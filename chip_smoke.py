@@ -478,8 +478,7 @@ def _run_arm(model, params, size: dict, paged_kv, mesh,
     decode_jit = pool._paged_decode_kernel_jit or pool._paged_decode_jit
     arm.update(_compiled_program_facts(
         decode_jit, srv.engine.params, pool.cache["cache_store"],
-        jnp.zeros((size["num_slots"], 1), jnp.int32),
-        jnp.asarray(pool.positions())))
+        jnp.zeros((size["num_slots"],), jnp.int32)))
     if on_tpu and arm["kernel_active"] and arm["tpu_custom_calls"] == 0:
         failures.append("compiled paged decode step holds no "
                         "tpu_custom_call: the paged kernel was not compiled")

@@ -242,6 +242,33 @@ def _batch_of(a: AbsValue) -> Any:
     return a.shape[0] if isinstance(a, Arr) and a.ndim else Known(1)
 
 
+def _verify_outs(args: List[AbsValue], env: dict) -> List[AbsValue]:
+    """What a verify program gives beside its cache, from ``(params,
+    cache, cur (B,), draft (B, K), ...)``: ``out`` (B, K + 1) with the
+    config's K, ``n_emit`` (B,), and the key it was handed, split."""
+    batch = _batch_of(args[2])
+    return [Arr((batch, Known(int(env.get("spec_k") or 0) + 1)), "int32",
+                COMMITTED),
+            Arr((batch,), "int32", COMMITTED),
+            Arr((Known(2),), "uint32", COMMITTED)]
+
+
+def _pack_chunk_args(args: List[AbsValue]) -> AbsValue:
+    """``inference.engine.pack_chunk_args(ids, slot, start, length,
+    last_idx, *rows)``: ONE host int32 vector of the four scalars, the
+    chunk's ids and every further row."""
+    n = 4
+    for a in [args[0]] + list(args[5:]):
+        if not isinstance(a, Arr) \
+                or not all(isinstance(d, Known) for d in a.shape):
+            return Unknown("pack_chunk_args of an unresolved operand")
+        size = 1
+        for d in a.shape:
+            size *= d.v
+        n += size
+    return Arr((Known(n),), "int32", HOST)
+
+
 WATCHED_MODELS = {
     "_jit_prefill_at": lambda args, kw, env: Tup(
         [_logits(_batch_of(args[1]), env), Tree(COMMITTED, "pre_cache")]),
@@ -249,14 +276,12 @@ WATCHED_MODELS = {
         [_logits(_batch_of(args[2]), env), Tree(COMMITTED, "cache")]),
     "_jit_prefill_chunk": lambda args, kw, env: Tup(
         [_logits(Known(1), env), Tree(COMMITTED, "cache")]),
-    "_jit_sample": lambda args, kw, env: Arr(
-        (_batch_of(args[0]),), "int32", COMMITTED),
+    # (the key it was handed, split; the tokens)
+    "_jit_sample": lambda args, kw, env: Tup(
+        [Arr((Known(2),), "uint32", COMMITTED),
+         Arr((_batch_of(args[0]),), "int32", COMMITTED)]),
     "_jit_verify_k": lambda args, kw, env: Tup(
-        [Tree(COMMITTED, "cache"),
-         Arr((_batch_of(args[2]),
-              args[2].shape[1] if isinstance(args[2], Arr)
-              and args[2].ndim > 1 else Known(1)), "int32", COMMITTED),
-         Arr((_batch_of(args[2]),), "int32", COMMITTED)]),
+        [Tree(COMMITTED, "cache")] + _verify_outs(args, env)),
     "_jit_decode_scan": lambda args, kw, env: Unknown("decode_scan"),
     "_admit_jit": lambda args, kw, env: Tree(COMMITTED, "pool"),
     "_admit_rows_jit": lambda args, kw, env: Tree(COMMITTED, "pool"),
@@ -270,11 +295,7 @@ WATCHED_MODELS = {
     "_paged_chunk_jit": lambda args, kw, env: Tup(
         [_logits(Known(1), env), Tree(COMMITTED, "pool"), Scalar(None)]),
     "_paged_verify_jit": lambda args, kw, env: Tup(
-        [Tree(COMMITTED, "pool"),
-         Arr((_batch_of(args[2]),
-              args[2].shape[1] if isinstance(args[2], Arr)
-              and args[2].ndim > 1 else Known(1)), "int32", COMMITTED),
-         Arr((_batch_of(args[2]),), "int32", COMMITTED)]),
+        [Tree(COMMITTED, "pool")] + _verify_outs(args, env)),
     "_jit_finite": lambda args, kw, env: Arr(
         (_batch_of(args[0]),), "bool", COMMITTED),
     # fused paged-attention kernel arms: same caller-visible contract as
@@ -283,11 +304,7 @@ WATCHED_MODELS = {
         [_logits(_batch_of(args[2]), env), Tree(COMMITTED, "pool"),
          Scalar(None)]),
     "_paged_verify_kernel_jit": lambda args, kw, env: Tup(
-        [Tree(COMMITTED, "pool"),
-         Arr((_batch_of(args[2]),
-              args[2].shape[1] if isinstance(args[2], Arr)
-              and args[2].ndim > 1 else Known(1)), "int32", COMMITTED),
-         Arr((_batch_of(args[2]),), "int32", COMMITTED)]),
+        [Tree(COMMITTED, "pool")] + _verify_outs(args, env)),
     # device current-token twin plumbing: scatter returns the (S,) twin
     # it was handed; spec-cur collapses a (S, K+1) verify output to (S,)
     "_jit_cur_scatter": lambda args, kw, env: args[0]
@@ -855,6 +872,8 @@ class Interp:
             return Unknown("getattr")
         if name == "print":
             return Scalar(None)
+        if name == "pack_chunk_args" and len(args) >= 5:
+            return _pack_chunk_args(args)
         return Unknown(f"builtin {name}")
 
     # ------------------------------------------------------- subscripts
@@ -1306,6 +1325,8 @@ def _pool_obj(env: dict, engine: Obj) -> Obj:
             "page_refs": Arr((P,), "int64", HOST),
             "prefix": Obj("PrefixCache") if env.get("use_prefix")
             else Scalar(None),
+            "ring": Scalar(None),
+            "_table_keys": Tup([Scalar("table")]),
             "cow_copies": Scalar(0),
             "page_evictions": Scalar(0),
             "_jit_copy_page": Obj("jit"),

@@ -60,6 +60,31 @@ def _filter_logits(last, temperature, top_k, top_p):
     return scaled
 
 
+def pack_chunk_args(ids, slot, start, length, last_idx, *rows):
+    """A prefill chunk's host-built arguments as ONE int32 vector, so that
+    they reach the device in one transfer whatever their number:
+    ``[slot, start, length, last_idx]``, the chunk's ``C`` token ids, then
+    any further rows (a paged pool's table rows of the slot). The chunk
+    programs take it apart again with :func:`unpack_chunk_args`."""
+    return np.concatenate(
+        [np.asarray([slot, start, length, last_idx], np.int32),
+         np.asarray(ids, np.int32).reshape(-1)]
+        + [np.asarray(row, np.int32).reshape(-1) for row in rows])
+
+
+def unpack_chunk_args(packed, row_width: int = 0, rows: int = 0):
+    """Traced twin of :func:`pack_chunk_args`: ``(ids (1, C), slot, start,
+    length, last_idx, rows)`` with ``rows`` trailing rows of ``row_width``
+    (static; what is left of the vector is the chunk)."""
+    tail = rows * row_width
+    width = packed.shape[0] - 4 - tail
+    ids = packed[4:4 + width][None]
+    more = tuple(packed[4 + width + i * row_width:
+                        4 + width + (i + 1) * row_width]
+                 for i in range(rows))
+    return (ids, packed[0], packed[1], packed[2], packed[3]) + more
+
+
 class InferenceEngine:
     """Construct via :func:`deepspeed_tpu.init_inference`."""
 
@@ -304,8 +329,7 @@ class InferenceEngine:
                 "the model axis and the retention kernels are not wrapped "
                 "for a mesh (ROADMAP.md, Reach)")
 
-        def prefill_chunk_fn(params, cache, ids, slot, start, length,
-                             last_idx):
+        def prefill_chunk_fn(params, cache, packed):
             """One bounded prefill chunk DIRECTLY into slot ``slot`` of
             the slot-pooled cache: dynamic-slice the target row out
             (batch axis 1 of the (L, B, ...) leaves), run the (1, C)
@@ -315,7 +339,9 @@ class InferenceEngine:
             chunk ran at padded width C). Only the target row is ever
             written, so live neighbours can't be clobbered by the
             chunk's C-wide writes, and slot/start/length are traced —
-            ONE compiled program covers every slot at every offset.
+            ONE compiled program covers every slot at every offset. They
+            arrive with the chunk's ids as one vector
+            (:func:`pack_chunk_args`): one transfer a chunk.
 
             A recurrent state (``KVCacheSpec.state``) is not sliced: the
             stacked leaves go in whole with the row's number, the chunk
@@ -324,8 +350,7 @@ class InferenceEngine:
             every layer out and back, 0.27 GB each way at the served
             size)."""
             cs = cache["cache_store"]
-            slot = jnp.asarray(slot, jnp.int32)
-            start = jnp.asarray(start, jnp.int32)
+            ids, slot, start, length, last_idx = unpack_chunk_args(packed)
             if has_state:
                 out, vars_ = module.apply(
                     {"params": dequant(params),
@@ -334,8 +359,7 @@ class InferenceEngine:
                     method=chunk_gen, mutable=["cache"])
                 new = vars_["cache"]["cache_store"]
                 return out, {"cache_store": dict(
-                    new, index=cs["index"].at[slot].set(
-                        start + jnp.asarray(length, jnp.int32)))}
+                    new, index=cs["index"].at[slot].set(start + length))}
             row = {k: jax.lax.dynamic_slice_in_dim(v, slot, 1, 1)
                    for k, v in cs.items() if k != "index"}
             row["index"] = start[None]
@@ -352,11 +376,17 @@ class InferenceEngine:
                     dst, src.astype(dst.dtype), idx)
 
             merged = {k: write(cs[k], new[k]) for k in cs if k != "index"}
-            merged["index"] = cs["index"].at[slot].set(
-                start + jnp.asarray(length, jnp.int32))
+            merged["index"] = cs["index"].at[slot].set(start + length)
             return out, {"cache_store": merged}
 
-        def decode_fn(params, cache, token, pos, rows=None):
+        capacity = self._declared_kv_capacity()
+
+        def decode_fn(params, cache, token, pos=None, rows=None):
+            # a server hands in what the device already holds and no
+            # more: its (B,) current-token twin as it is (the axis is
+            # added here) and no ``pos``, which is then the cache's own
+            # ``index`` held inside the allocation, as
+            # ``SlotPool.positions`` does it on the host.
             # ``rows``: only for a model with a recurrent state, from a
             # caller some of whose rows do not run (the server: free
             # slots, slots in mid-prefill). (B,) int32, the cache row of
@@ -364,16 +394,27 @@ class InferenceEngine:
             # row's state comes back bit for bit. A K/V model is never
             # given it: its program is what it was.
             more = {} if rows is None else {"rows": rows}
+            if token.ndim == 1:
+                token = token[:, None]
+            if pos is None:
+                pos = cache["cache_store"]["index"]
+                if capacity is not None:
+                    pos = jnp.minimum(pos, capacity - 1)
             out, vars_ = module.apply(
                 {"params": dequant(params), "cache": cache}, token, pos,
                 method=module.decode, mutable=["cache"], **more)
             return out, vars_["cache"]
 
         def sample_fn(logits, rng, temperature, top_k, top_p, greedy):
+            """``(rng', tokens)``: the key is split HERE (the caller
+            keeps what comes back and passes it to its next call), so no
+            caller runs a program of its own for the split."""
+            rng, sub = jax.random.split(rng)
             last = logits[:, -1, :].astype(jnp.float32)
             scaled = _filter_logits(last, temperature, top_k, top_p)
-            sampled = jax.random.categorical(rng, scaled, axis=-1)
-            return jnp.where(greedy, jnp.argmax(last, axis=-1), sampled)
+            sampled = jax.random.categorical(sub, scaled, axis=-1)
+            return rng, jnp.where(greedy, jnp.argmax(last, axis=-1),
+                                  sampled)
 
         def decode_scan_fn(params, cache, token, pos, rng, temperature,
                            greedy, n_steps, top_k, top_p):
@@ -384,10 +425,10 @@ class InferenceEngine:
 
             def body(carry, _):
                 cache, token, pos, rng = carry
-                logits, cache = decode_fn(params, cache, token[:, None], pos)
-                rng, sub = jax.random.split(rng)
-                nxt = sample_fn(logits, sub, temperature, top_k, top_p,
-                                greedy).astype(jnp.int32)
+                logits, cache = decode_fn(params, cache, token, pos)
+                rng, nxt = sample_fn(logits, rng, temperature, top_k, top_p,
+                                     greedy)
+                nxt = nxt.astype(jnp.int32)
                 return (cache, nxt, pos + 1, rng), nxt
 
             (cache, token, pos, rng), toks = jax.lax.scan(
@@ -407,7 +448,12 @@ class InferenceEngine:
                                           donate_argnums=(1,)) \
             if chunk_gen is not None else None
         self._jit_decode = jax.jit(decode_fn, donate_argnums=(1,))
-        self._jit_sample = jax.jit(sample_fn, static_argnums=(3, 4))
+        # a key that a program hands back is pinned to ONE placement,
+        # the one its first holder commits it to (``key_sharding``): left
+        # to the partitioner it differed from program to program on a
+        # mesh, and the next program that took it compiled again
+        self._jit_sample = jax.jit(sample_fn, static_argnums=(3, 4),
+                                   out_shardings=(self.key_sharding, None))
         self._jit_decode_scan = jax.jit(decode_scan_fn,
                                         donate_argnums=(1,),
                                         static_argnums=(7, 8, 9))
@@ -492,6 +538,12 @@ class InferenceEngine:
         self._decode_scan_execs[key] = compiled
         return compiled
 
+    @property
+    def key_sharding(self) -> NamedSharding:
+        """Where a sampling key lives between the programs that split it
+        (the sampler, ``verify_k``): replicated on the engine's mesh."""
+        return NamedSharding(self.mesh, PartitionSpec())
+
     def kv_cache_spec(self):
         """The served module's declared KV-cache contract, or None when it
         doesn't declare one (foreign modules). The serving subsystem sizes
@@ -528,30 +580,32 @@ class InferenceEngine:
                              "last_idx); the unified TransformerLM "
                              "family does")
         return self._jit_prefill_chunk(
-            self.params, cache, jnp.asarray(input_ids, jnp.int32),
-            jnp.asarray(slot, jnp.int32), jnp.asarray(start, jnp.int32),
-            jnp.asarray(length, jnp.int32), jnp.asarray(last_idx, jnp.int32))
+            self.params, cache,
+            pack_chunk_args(input_ids, slot, start, length, last_idx))
 
-    def verify_k(self, cache, tokens, pos, draft, draft_len, rng,
-                 temperature, greedy, top_k: int, top_p: float):
+    def verify_k(self, cache, cur, draft, draft_len, rng, temperature,
+                 greedy, top_k: int, top_p: float):
         """Speculative verification: score K draft positions for every
         row in ONE fixed-shape chunked-decode forward and run acceptance
         in the same compiled program (greedy accept-prefix, or lossless
         rejection sampling under the serving sampler's filtered
         distribution for ``do_sample``).
 
-        ``tokens`` is (B, K+1) int32 — [current_token, draft_0..K-1] per
-        row; ``pos`` (B,) int32 per-slot cache offsets; ``draft`` (B, K);
-        ``draft_len`` (B,) int32 in [0, K] (0 = plain decode for that
-        row: dead or non-speculating slots ride along masked). The cache
+        ``cur`` is (B,) int32, each row's current token (the rows scored
+        are [current_token, draft_0..K-1] at the cache's own per-slot
+        ``index``: nothing about them is sent that the device holds);
+        ``draft`` (B, K); ``draft_len`` (B,) int32 in [0, K] (0 = plain
+        decode for that row: dead or non-speculating slots ride along
+        masked); ``rng`` the caller's key, split inside. The cache
         operand is donated (updated in place in HBM) and comes back with
         all K+1 positions written for every row — the caller rolls back
         rejected positions by per-slot ``index`` masking
         (:meth:`SlotPool.advance`), never a reshape.
 
-        Returns ``(cache, out (B, K+1) int32, n_emit (B,) int32)``: row
-        ``i`` emits ``out[i, :n_emit[i]]`` — the accepted draft prefix
-        plus the bonus/correction token (always >= 1 per step).
+        Returns ``(cache, out (B, K+1) int32, n_emit (B,) int32, rng')``:
+        row ``i`` emits ``out[i, :n_emit[i]]`` — the accepted draft
+        prefix plus the bonus/correction token (always >= 1 per step);
+        ``rng'`` is the key for the caller's next call.
         """
         if self._decode_fn is None:
             raise ValueError("verify_k requires an LM module with a "
@@ -561,10 +615,11 @@ class InferenceEngine:
 
             self._jit_verify_k = jax.jit(
                 make_verify_fn(self._decode_fn, _filter_logits),
-                donate_argnums=(1,), static_argnums=(9, 10))
-        return self._jit_verify_k(self.params, cache, tokens, pos, draft,
-                                  draft_len, rng, temperature, greedy,
-                                  int(top_k), float(top_p))
+                donate_argnums=(1,), static_argnums=(8, 9),
+                out_shardings=(None, None, None, self.key_sharding))
+        return self._jit_verify_k(self.params, cache, cur, draft, draft_len,
+                                  rng, temperature, greedy, int(top_k),
+                                  float(top_p))
 
     def generate(self, input_ids, max_new_tokens: int = 32,
                  do_sample: bool = False, temperature: Optional[float] = None,
@@ -653,15 +708,15 @@ class InferenceEngine:
                 cache_aval, B, bucket, int(top_k), float(top_p))
 
         logits, cache = self._jit_prefill_gen(self.params, input_ids)
-        rng = jax.random.PRNGKey(seed)
-        rng, sub = jax.random.split(rng)
-        token = self._jit_sample(logits, sub, jnp.asarray(temperature, jnp.float32),
-                                 int(top_k), float(top_p), greedy)
+        temperature = jnp.asarray(temperature, jnp.float32)
+        rng, token = self._jit_sample(
+            logits, jax.device_put(jax.random.PRNGKey(seed),
+                                   self.key_sharding),
+            temperature, int(top_k), float(top_p), greedy)
 
         if eos_token_id is None:
             args = (self.params, cache, token.astype(jnp.int32),
-                    jnp.asarray(T, jnp.int32), rng,
-                    jnp.asarray(temperature, jnp.float32), greedy)
+                    jnp.asarray(T, jnp.int32), rng, temperature, greedy)
             rest = None
             if decode_exec is not None:
                 # small args must match the replicated shardings the
@@ -696,10 +751,9 @@ class InferenceEngine:
                 logits, cache = self._jit_decode(
                     self.params, cache, token[:, None],
                     jnp.asarray(pos, jnp.int32))
-                rng, sub = jax.random.split(rng)
-                nxt = self._jit_sample(
-                    logits, sub, jnp.asarray(temperature, jnp.float32),
-                    int(top_k), float(top_p), greedy)
+                rng, nxt = self._jit_sample(
+                    logits, rng, temperature, int(top_k), float(top_p),
+                    greedy)
                 # host sync on the PREVIOUS token while this step runs
                 finished |= np.asarray(token) == eos_token_id
                 if finished.all():

@@ -571,7 +571,15 @@ class ServingEngine:
         self.top_k = cfg.top_k if top_k is None else top_k
         self.top_p = cfg.top_p if top_p is None else top_p
         self._greedy = jnp.asarray(not do_sample)
-        self._rng = jax.random.PRNGKey(seed)
+        # the sampler's key lives on the device: the sampler and verify
+        # programs take it, split it inside and hand the next one back
+        self._rng = jax.device_put(jax.random.PRNGKey(seed),
+                                   engine.key_sharding)
+        # device twins of what the host rarely changes, each beside the
+        # host value it was put from: the temperature, and a state
+        # model's ``rows`` with the running slots it names
+        self._temperature_dev: tuple = (None, None)
+        self._rows_dev: tuple = (None, None)
         self._slot_req: dict = {}                      # slot -> Request
         self._current = np.zeros((num_slots,), np.int32)  # last token per slot
         # device twin of _current: decode/spec dispatch read it so a step
@@ -1127,8 +1135,25 @@ class ServingEngine:
         children the step's account takes under ``phase``."""
         return _Phase(self, phase, name, attrs or None)
 
+    def _temperature(self):
+        """``self.temperature`` as a device scalar, put again only when
+        the attribute has another value than the one it was put from."""
+        value, dev = self._temperature_dev
+        if value != self.temperature:
+            value = self.temperature
+            with self._enqueue("temperature", "transfer"):
+                dev = jax.device_put(np.float32(value))
+            self._temperature_dev = (value, dev)
+        return dev
+
     def _sample_dev(self, logits):
-        """Dispatch the sampler and return the token *device* array.
+        """Dispatch the sampler and return the token *device* array: ONE
+        device call, the program. What it needs beside the logits the
+        device holds already: the key, which the program splits itself
+        and hands back for the next call (the same threefry split the
+        host used to run as a program of its own, so the same sub-keys
+        and tokens), and the temperature's device scalar
+        (:meth:`_temperature`).
 
         No host sync happens here: callers stash the array (plus a
         closure that needs its host value) via :meth:`_defer`, and the
@@ -1136,14 +1161,12 @@ class ServingEngine:
         closure in dispatch order. Per-row sampling is independent
         (``categorical``/``argmax`` act row-wise on one split key), so
         batching rows from different call sites cannot change values."""
-        with self._enqueue("rng_split", "transfer"):
-            self._rng, sub = jax.random.split(self._rng)
-        with self._enqueue("sample", "transfer"):
-            temperature = jax.device_put(np.float32(self.temperature))
+        temperature = self._temperature()
         with self._enqueue("sample"):
-            return self.engine._jit_sample(
-                logits, sub, temperature, int(self.top_k),
+            self._rng, tokens = self.engine._jit_sample(
+                logits, self._rng, temperature, int(self.top_k),
                 float(self.top_p), self._greedy)
+        return tokens
 
     def _defer(self, arrays, callback) -> None:
         """Queue ``callback(*host_values)`` until the end-of-step fetch.
@@ -1374,16 +1397,19 @@ class ServingEngine:
                        // (-(-ring.window // ring.page_size) + 2))
         return free
 
-    def _ensure_pages(self, slot: int, start: int, end: int) -> None:
+    def _ensure_pages(self, slot: int, start: int, end: int,
+                      sync: bool = True) -> None:
         """ensure_writable with the pressure valve: on PagePoolExhausted
         (free list empty AND trie eviction dry), preempt the youngest
         OTHER seated request — its pages come back to the free list —
         and retry. Only when no victim remains does the exhaustion
         propagate (a sizing bug: one request's footprint exceeds the
-        whole pool, which the submit-time page check rejects)."""
+        whole pool, which the submit-time page check rejects). ``sync``
+        is ``ensure_writable``'s: a chunk passes ``False``, its program
+        publishes the row."""
         while True:
             try:
-                self.pool.ensure_writable(slot, start, end)
+                self.pool.ensure_writable(slot, start, end, sync=sync)
                 return
             except PagePoolExhausted:
                 victims = [
@@ -1610,9 +1636,11 @@ class ServingEngine:
         if self._paged:
             # the chunk's write window must land in owned pages BEFORE
             # the dispatch (allocating / CoW-forking under pressure may
-            # preempt a victim — host work, so it happens outside jit)
+            # preempt a victim — host work, so it happens outside jit).
+            # The mappings stay in the host's mirror: the chunk program
+            # writes the slot's row into the device table itself
             with _PagesPhase(self):
-                self._ensure_pages(slot, pos, pos + L)
+                self._ensure_pages(slot, pos, pos + L, sync=False)
         self._dispatched["chunk"] = L
         with self._phase("prepare", "serving/prefill_chunk",
                          rid=req.request_id, pos=pos, len=L) as sp:
@@ -1623,13 +1651,16 @@ class ServingEngine:
                 sp.set(pool_writes=self.pool.pages_touched(slot, pos, C))
                 self._set_pool_reads(sp, C, [slot], [pos])
             else:
-                with self._enqueue("chunk", "transfer"):
-                    args = jax.device_put(
-                        (ids, np.int32(slot), np.int32(pos), np.int32(L),
-                         np.int32(L - 1)))
+                # what only the host knows goes as host values:
+                # ``prefill_chunk`` packs them into ONE vector and hands
+                # it to the jitted call itself, the one transfer of a
+                # chunk (a put of its own before the call cost 0.14 ms
+                # more on the chip's host, a tuple of five arrays 1.05:
+                # PERF.md §6, PR 35)
+                self._enqueue("chunk", "transfer")
                 with self._enqueue("chunk"):
                     logits, cache = self.engine.prefill_chunk(
-                        self.pool.cache, *args)
+                        self.pool.cache, ids, slot, pos, L, L - 1)
                 self.pool.cache = cache
         self.pool.starts[slot] = pos + L  # device index moved in-program
         req.prefill_pos = pos + L
@@ -2048,6 +2079,7 @@ class ServingEngine:
         self._dispatched = {}
         phases = self._phase_ns = {}
         self._device_calls = 0
+        table_puts0 = self.pool.table_puts if self._paged else 0
         # the device is known idle from the previous step's sync on
         # (serving/enqueue closes the interval: `exposed`)
         self._exposed_from_ns, self._sync_end_ns = self._sync_end_ns, None
@@ -2142,6 +2174,12 @@ class ServingEngine:
             # (which adds slo.overhead_s) never counts it twice
             slo_ns0 = self.slo.overhead_ns if self.slo is not None else 0
             self._dispatched["device_calls"] = self._device_calls
+            if self._paged:
+                # whole-table republications (a chunk that only mapped
+                # its own row: 0; a release, a preemption, a decode slot
+                # crossing a page boundary: 1 each)
+                self._dispatched["table_puts"] = \
+                    self.pool.table_puts - table_puts0
             with tracer.span("serving/after_step") as sp:
                 wall = self._after_step(t_step, running_at_entry, granted,
                                         finished)
@@ -2304,7 +2342,34 @@ class ServingEngine:
         if work is not None:
             sp.set(pool_reads=work[0], read_slots=work[1])
 
+    def _state_rows(self, running) -> tuple:
+        """For a model with a recurrent state, the decode program's
+        ``rows``: the rows that run; every other row (free, or seated and
+        still prefilling) is out of range, no step of the state kernels,
+        and keeps its state bit for bit (the index rollback is what hides
+        such a row's K/V column; it does nothing for a state). Put when
+        the running set changes, not every step: the device array stays
+        beside the slots it was built from."""
+        if not self._state_row_bytes:
+            return ()
+        slots = tuple(slot for slot, _ in running)
+        if self._rows_dev[0] != slots:
+            rows = np.full((self.pool.num_slots,), -1, np.int32)
+            rows[list(slots)] = slots
+            self._rows_dev = (slots, self._cur_commit(rows))
+        return (self._rows_dev[1],)
+
     def _decode_step(self, finished: List[Request], t0: float) -> None:
+        """One decode program for every slot. Nothing is sent before it
+        that the device holds: the program takes the (B,) current-token
+        twin as it is and its positions from the cache's own ``index``
+        (equal to the host's ``starts`` whenever a decode is queued: a
+        chunk program sets its slot's entry, and the rollback below
+        republishes it after a decode beside a prefilling slot). The host
+        publishes: the page table when a running slot crosses a page
+        boundary (``_ensure_decode_pages``), a state model's ``rows``
+        when the running set changed, and the index after the program
+        when a prefilling slot rode along."""
         eng = self.engine
         if self._paged:
             # page the write column in BEFORE snapshotting the running
@@ -2313,29 +2378,13 @@ class ServingEngine:
                 self._ensure_decode_pages(1)
         running = [(slot, req) for slot, req in self._slot_req.items()
                    if req.state is RequestState.RUNNING]
-        with self._enqueue("cur_tokens", "transfer"):
-            # device twin of the current-token vector: decode never waits
-            # for the previous step's sampled tokens to round-trip the host
-            tokens = self._cur_dev[:, None]
-        with self._enqueue("decode", "transfer"):
-            pos = jax.device_put(self.pool.positions())
         self._dispatched["decode"] = len(running)
-        more = ()
-        if self._state_row_bytes:
-            # the rows that run: every other row (free, or seated and
-            # still prefilling) is out of range, no step of the state
-            # kernels, and keeps its state bit for bit. The index
-            # rollback below is what hides such a row's K/V column; it
-            # does nothing for a state.
-            rows = np.full((self.pool.num_slots,), -1, np.int32)
-            for slot, _ in running:
-                rows[slot] = slot
-            more = (self._cur_commit(rows),)
+        more = self._state_rows(running)
         with self._phase("prepare", "serving/decode",
                          live=len(running)) as sp:
             self._note_state_rows(sp, len(running))
             if self._paged:
-                logits = self.pool.run_decode(eng, tokens, pos)
+                logits = self.pool.run_decode(eng, self._cur_dev)
                 # counted after the dispatch, from a mirror the dispatch
                 # does not move: every slot's row is in the program's
                 # work list, the live ones map a page at their index
@@ -2345,7 +2394,8 @@ class ServingEngine:
             else:
                 with self._enqueue("decode"):
                     logits, cache = eng._jit_decode(
-                        eng.params, self.pool.cache, tokens, pos, *more)
+                        eng.params, self.pool.cache, self._cur_dev, None,
+                        *more)
         if self.faults is not None:
             logits, _ = self.faults.corrupt_logits(
                 logits, [slot for slot, _ in running])
@@ -2445,32 +2495,27 @@ class ServingEngine:
             draft_len = np.clip(np.asarray(draft_len, np.int32), 0, K)
             t_draft = self._now() - t0
 
-        with self._enqueue("cur_tokens", "transfer"):
-            draft_dev = jax.device_put(draft)
-        with self._enqueue("cur_tokens", "transfer"):
-            # device twin feeds verify directly — no host round-trip for
-            # the previous step's tokens
-            tokens = jnp.concatenate(
-                [self._cur_dev[:, None], draft_dev], axis=1)
-        with self._enqueue("rng_split", "transfer"):
-            self._rng, sub = jax.random.split(self._rng)
         self._dispatched["decode"] = self._running_count()
         with self._phase("prepare", "serving/verify_k", k=K) as sp:
+            # what only the host knows, in one put: the drafts and their
+            # lengths. The program takes the current tokens from the
+            # device twin, the positions from the cache's own index, and
+            # the key, which it splits and hands back (as the sampler)
             with self._enqueue("verify_k", "transfer"):
-                pos, lens, temperature = jax.device_put(
-                    (self.pool.positions(), draft_len,
-                     np.float32(self.temperature)))
-            args = (tokens, pos, draft_dev, lens, sub, temperature,
-                    self._greedy, int(self.top_k), float(self.top_p))
+                draft_dev, lens = jax.device_put((draft, draft_len))
+            args = (self._cur_dev, draft_dev, lens, self._rng,
+                    self._temperature(), self._greedy, int(self.top_k),
+                    float(self.top_p))
             if self._paged:
-                out_dev, n_emit_dev = self.pool.run_verify(eng, *args)
+                out_dev, n_emit_dev, self._rng = self.pool.run_verify(
+                    eng, *args)
                 sp.set(pool_writes=self.pool.pages_touched(
                     np.arange(self.pool.num_slots), self.pool.starts,
                     K + 1))
                 self._set_pool_reads(sp, K + 1)
             else:
                 with self._enqueue("verify_k"):
-                    cache, out_dev, n_emit_dev = eng.verify_k(
+                    cache, out_dev, n_emit_dev, self._rng = eng.verify_k(
                         self.pool.cache, *args)
                 self.pool.cache = cache
         # next step's current token per row is the last EMITTED one:

@@ -103,6 +103,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..inference.engine import pack_chunk_args, unpack_chunk_args
 from ..ops import backend
 from .prefix_cache import PrefixCache
 from .slot_pool import SlotPool
@@ -267,6 +268,10 @@ class PagedKVPool(SlotPool):
         heapq.heapify(self._free_pages)   # smallest page first: deterministic
         self._free_page_set = set(self._free_pages)
         self.table = np.full((num_slots, self.pages_per_slot), P, np.int32)
+        # slots whose rows of the mirrors (this table, the ring's) the
+        # device tables have not seen; whole-table republications so far
+        self._stale_rows: set = set()
+        self.table_puts = 0
         self.cow_copies = 0
         self.page_evictions = 0
         self.pages_allocated = 0      # lifetime pops of the free list
@@ -351,13 +356,33 @@ class PagedKVPool(SlotPool):
         return tbl
 
     def _sync_table(self) -> None:
-        """Rebuild the device page tables from the host mirrors (same
-        committed-leaf discipline as ``_index_from_mirror``)."""
+        """Republish the WHOLE device page tables from the host mirrors
+        (same committed-leaf discipline as ``_index_from_mirror``): one
+        put a layer group, counted in ``table_puts``. Who publishes what:
+        a prefill chunk's program patches its own slot's row from the row
+        it is handed (:meth:`run_prefill_chunk`), so the chunk's
+        ``ensure_writable(..., sync=False)`` publishes nothing; every
+        other change of a mirror (a release, a row reset, an admission's
+        mappings, a decode slot crossing a page boundary) comes through
+        here at once, and a row left stale any other way is published by
+        :meth:`_publish_stale` before the next program that reads the
+        device tables is queued."""
         cs = dict(self.cache["cache_store"])
         with self.enqueue("table", "transfer"):
             for key in self._table_keys:
                 cs[key] = self._table_from_mirror(key)
         self.cache = {"cache_store": cs}
+        self._stale_rows.clear()
+        self.table_puts += 1
+
+    def _publish_stale(self, own: Optional[int] = None) -> None:
+        """Before a program that reads the device tables is queued: the
+        whole tables again if a row the device has not seen is any but
+        ``own`` (the slot whose row the program about to run patches
+        itself)."""
+        stale = self._stale_rows
+        if stale and (len(stale) > 1 or own not in stale):
+            self._sync_table()
 
     @staticmethod
     def _group_tables(table, win_table=None) -> dict:
@@ -451,6 +476,7 @@ class PagedKVPool(SlotPool):
             if pid != sent:
                 self.unref_page(int(pid))
         self.table[slot, :] = sent
+        self._stale_rows.add(slot)
         if self.ring is not None:
             self.ring.unmap_slot(slot)
 
@@ -469,6 +495,7 @@ class PagedKVPool(SlotPool):
         heapq.heapify(self._free_pages)
         self._free_page_set = set(self._free_pages)
         self.table[:] = self.num_pages
+        self._stale_rows.clear()          # (a fresh cache: all sentinel)
         if self.ring is not None:
             self.ring.reset()
         self.moe_stats = []
@@ -491,47 +518,62 @@ class PagedKVPool(SlotPool):
         jitted page copy, table entry swung to the fork, old page
         unref'd. Returns the number of CoW copies performed. May raise
         :class:`PagePoolExhausted` (already-made mappings stay valid;
-        the caller preempts a victim and retries)."""
+        the caller preempts a victim and retries).
+
+        ``sync`` says who tells the device: ``True`` republishes the
+        tables here if a mapping changed (:meth:`_sync_table`; the decode
+        path, one step in ``page_size`` a slot); ``False`` marks the
+        slot's row stale and leaves it to the caller: a prefill chunk,
+        whose program writes the row it is handed into the device table,
+        or an admission, which republishes once for all its rows."""
         if end <= start:
             return 0
         end = min(end, self.capacity)
         sent = self.num_pages
         ncow = 0
         changed = False
-        if self.ring is not None:
-            recycled = self.ring.recycled
-            changed = self.ring.make_writable(slot, start, end)
-            if self.ring.recycled > recycled:
-                self._inc("paging/window_pages_recycled",
-                          self.ring.recycled - recycled)
-        for p in range(start // self.page_size,
-                       (end - 1) // self.page_size + 1):
-            pid = int(self.table[slot, p])
-            if pid == sent:
-                self.table[slot, p] = self.alloc_page()
-                changed = True
-            elif self.page_refs[pid] > 1:
-                fork = self.alloc_page()
-                try:
-                    with self.enqueue("copy_page", "transfer"):
-                        pages = jax.device_put((np.int32(pid),
-                                                np.int32(fork)))
-                    with self.enqueue("copy_page"):
-                        cs = self._jit_copy_page(self.cache["cache_store"],
-                                                 *pages)
-                except Exception:
-                    # copy dispatch died before the fork was mapped:
-                    # return it to the free list (fresh refcount is 1)
-                    # instead of stranding it until the next reset()
-                    self.unref_page(fork)
-                    raise
-                self.cache = {"cache_store": cs}
-                self.table[slot, p] = fork
-                self.unref_page(pid)
-                ncow += 1
-                changed = True
-        if changed and sync:
-            self._sync_table()
+        try:
+            if self.ring is not None:
+                recycled = self.ring.recycled
+                changed = self.ring.make_writable(slot, start, end)
+                if self.ring.recycled > recycled:
+                    self._inc("paging/window_pages_recycled",
+                              self.ring.recycled - recycled)
+            for p in range(start // self.page_size,
+                           (end - 1) // self.page_size + 1):
+                pid = int(self.table[slot, p])
+                if pid == sent:
+                    self.table[slot, p] = self.alloc_page()
+                    changed = True
+                elif self.page_refs[pid] > 1:
+                    fork = self.alloc_page()
+                    try:
+                        with self.enqueue("copy_page", "transfer"):
+                            pages = jax.device_put((np.int32(pid),
+                                                    np.int32(fork)))
+                        with self.enqueue("copy_page"):
+                            cs = self._jit_copy_page(self.cache["cache_store"],
+                                                     *pages)
+                    except Exception:
+                        # copy dispatch died before the fork was mapped:
+                        # return it to the free list (fresh refcount is 1)
+                        # instead of stranding it until the next reset()
+                        self.unref_page(fork)
+                        raise
+                    self.cache = {"cache_store": cs}
+                    self.table[slot, p] = fork
+                    self.unref_page(pid)
+                    ncow += 1
+                    changed = True
+        except PagePoolExhausted:
+            # the mappings made before the pool ran out stay valid, and
+            # the device has not seen them
+            self._stale_rows.add(slot)
+            raise
+        if changed:
+            self._stale_rows.add(slot)
+            if sync:
+                self._sync_table()
         if ncow:
             self.cow_copies += ncow
             self._inc("paging/cow_copies", ncow)
@@ -548,8 +590,10 @@ class PagedKVPool(SlotPool):
                                    f"({slot}, {i})")
             self.ref_page(int(pid))
             self.table[slot, i] = int(pid)
-        if sync and len(page_ids):
-            self._sync_table()
+        if len(page_ids):
+            self._stale_rows.add(slot)
+            if sync:
+                self._sync_table()
 
     def seat_prefix(self, slot: int, page_ids: Sequence[int],
                     prefill_pos: int) -> None:
@@ -564,10 +608,17 @@ class PagedKVPool(SlotPool):
         self.starts[slot] = prefill_pos
         self.ensure_writable(slot, prefill_pos,
                              max(hit_len, prefill_pos + 1), sync=False)
+        self._publish_seat()
+
+    def _publish_seat(self) -> None:
+        """A seating's index and table republished in ONE rebind (a
+        window group composes with neither seating path, so the full
+        group's table is all there is)."""
         cs = dict(self.cache["cache_store"])
         cs["index"] = self._index_from_mirror()
         cs["table"] = self._table_from_mirror()
         self.cache = {"cache_store": cs}
+        self._stale_rows.clear()
 
     def cache_prefix(self, slot: int, tokens) -> int:
         """Publish the slot's freshly-prefilled FULL prompt pages into
@@ -721,10 +772,7 @@ class PagedKVPool(SlotPool):
         for i, pid in enumerate(ids):
             self.table[slot, first_entry + i] = pid
         self.starts[slot] = int(prefill_pos)
-        cs = dict(self.cache["cache_store"])
-        cs["index"] = self._index_from_mirror()
-        cs["table"] = self._table_from_mirror()
-        self.cache = {"cache_store": cs}
+        self._publish_seat()
 
     # ------------------------------------------------------------------
     # jitted gather/scatter programs
@@ -935,33 +983,38 @@ class PagedKVPool(SlotPool):
             dense["index"] = cs["index"]
             return {"cache_store": dense}
 
-        def paged_decode(params, cs, token, pos):
-            logits, new = decode_fn(params, dense_cache(cs), token, pos)
+        # every step program takes what the device already holds from
+        # the device: ``token`` is the server's (B,) current-token twin
+        # as it is and the positions are the cache's own ``index``
+        # (``decode_fn`` adds the axis and holds the index inside the
+        # allocation, as ``positions()`` does on the host)
+        def paged_decode(params, cs, token):
+            logits, new = decode_fn(params, dense_cache(cs), token)
             ncs = new["cache_store"]
             # one column written per row
             out = write_runs(cs, ncs, tables_of(cs), cs["index"], 1)
             out["index"] = ncs["index"]
             return logits, out, None
 
-        def paged_verify(params, cs, tokens, pos, draft, draft_len, rng,
+        def paged_verify(params, cs, cur, draft, draft_len, rng,
                          temperature, greedy, top_k, top_p):
-            new, out_tok, n_emit = verify_body(
-                params, dense_cache(cs), tokens, pos, draft, draft_len,
-                rng, temperature, greedy, top_k, top_p)
+            new, out_tok, n_emit, rng = verify_body(
+                params, dense_cache(cs), cur, draft, draft_len, rng,
+                temperature, greedy, top_k, top_p)
             ncs = new["cache_store"]
             # K+1 columns written per row
             out = write_runs(cs, ncs, tables_of(cs), cs["index"],
-                             tokens.shape[1])
+                             draft.shape[1] + 1)
             out["index"] = ncs["index"]
-            return out, out_tok, n_emit
+            return out, out_tok, n_emit, rng
 
-        def paged_chunk(params, cs, ids, row_table, slot, start, length,
-                        last_idx, win_tables=None):
+        def paged_chunk(params, cs, packed):
             # ONE slot's chunk through its table row (and the window
-            # group's where there is one)
-            row_tables = self._group_tables(
-                row_table[None], None if win_tables is None
-                else win_tables[:1])
+            # group's where there is one), which arrive with the ids and
+            # the scalars as one vector (``pack_chunk_args``)
+            ids, slot, start, length, last_idx, *rows = unpack_chunk_args(
+                packed, self.pages_per_slot, len(self._table_keys))
+            row_tables = self._group_tables(*(row[None] for row in rows))
             if self.reads_in_place(ids.shape[1]):
                 # as kernel_apply does for a decode step: the model
                 # takes the stacked leaves whole and a table of one row,
@@ -976,7 +1029,7 @@ class PagedKVPool(SlotPool):
                     ids, start[None], last_idx,
                     table=row_tables if grouped else row_tables["table"],
                     method=chunk_gen, mutable=mutable)
-                outcs = dict(vars_["cache"]["cache_store"], **tables_of(cs))
+                outcs = dict(vars_["cache"]["cache_store"])
             else:
                 # the dense composition (the oracle): gather the slot's
                 # dense row from its pages, run the window-masked chunk,
@@ -992,13 +1045,21 @@ class PagedKVPool(SlotPool):
                 new = vars_["cache"]["cache_store"]
                 outcs = write_runs(cs, new, row_tables, start[None],
                                    ids.shape[1])
-            outcs["index"] = cs["index"].at[slot].set(
-                start + jnp.asarray(length, jnp.int32), mode="drop")
+            outcs["index"] = cs["index"].at[slot].set(start + length,
+                                                      mode="drop")
+            # the device tables get the row the program was handed: the
+            # host mapped the chunk's fresh pages in its mirror alone
+            for key, row in row_tables.items():
+                outcs[key] = jax.lax.dynamic_update_slice(
+                    cs[key], row, (slot, jnp.zeros((), jnp.int32)))
             return out, outcs, vars_["stats"]["moe"] if want_stats else None
 
         self._paged_decode_jit = jax.jit(paged_decode, donate_argnums=(1,))
+        # (the key comes back where the engine keeps it: key_sharding)
+        verify_out = (None, None, None, engine.key_sharding)
         self._paged_verify_jit = jax.jit(paged_verify, donate_argnums=(1,),
-                                         static_argnums=(9, 10))
+                                         static_argnums=(8, 9),
+                                         out_shardings=verify_out)
         self._paged_chunk_jit = (jax.jit(paged_chunk, donate_argnums=(1,))
                                  if chunk_gen is not None else None)
 
@@ -1014,8 +1075,13 @@ class PagedKVPool(SlotPool):
         # block_s=page_size; see ops/attention/paged_attention.py).
         if self.kernel_active \
                 and getattr(module, "decode_paged", None) is not None:
-            def kernel_apply(params, cache, token, pos):
+            capacity = self.capacity
+
+            def kernel_apply(params, cache, token):
                 cs = cache["cache_store"]
+                if token.ndim == 1:
+                    token = token[:, None]
+                pos = jnp.minimum(cs["index"], capacity - 1)
                 tables = tables_of(cs)
                 vals = {k: v for k, v in cs.items() if k not in tables}
                 logits, vars_ = module.apply(
@@ -1027,28 +1093,29 @@ class PagedKVPool(SlotPool):
                 return logits, {"cache_store": new}, \
                     vars_["stats"]["moe"] if want_stats else None
 
-            def kernel_decode_fn(params, cache, token, pos):
-                return kernel_apply(params, cache, token, pos)[:2]
+            def kernel_decode_fn(params, cache, token):
+                return kernel_apply(params, cache, token)[:2]
 
-            def kernel_decode(params, cs, token, pos):
+            def kernel_decode(params, cs, token):
                 logits, new, stats = kernel_apply(
-                    params, {"cache_store": cs}, token, pos)
+                    params, {"cache_store": cs}, token)
                 return logits, new["cache_store"], stats
 
             kernel_verify_body = make_verify_fn(kernel_decode_fn,
                                                 _filter_logits)
 
-            def kernel_verify(params, cs, tokens, pos, draft, draft_len,
-                              rng, temperature, greedy, top_k, top_p):
-                new, out_tok, n_emit = kernel_verify_body(
-                    params, {"cache_store": cs}, tokens, pos, draft,
-                    draft_len, rng, temperature, greedy, top_k, top_p)
-                return new["cache_store"], out_tok, n_emit
+            def kernel_verify(params, cs, cur, draft, draft_len, rng,
+                              temperature, greedy, top_k, top_p):
+                new, out_tok, n_emit, rng = kernel_verify_body(
+                    params, {"cache_store": cs}, cur, draft, draft_len,
+                    rng, temperature, greedy, top_k, top_p)
+                return new["cache_store"], out_tok, n_emit, rng
 
             self._paged_decode_kernel_jit = jax.jit(kernel_decode,
                                                     donate_argnums=(1,))
             self._paged_verify_kernel_jit = jax.jit(
-                kernel_verify, donate_argnums=(1,), static_argnums=(9, 10))
+                kernel_verify, donate_argnums=(1,), static_argnums=(8, 9),
+                out_shardings=verify_out)
         # pre-compile the CoW copy program with a no-op self-copy: the
         # first real fork can land arbitrarily late (a prefix hit on a
         # page some earlier request published), easily after warmup
@@ -1086,10 +1153,14 @@ class PagedKVPool(SlotPool):
             return True
         return backend.on_tpu()
 
-    def run_decode(self, engine: Any, tokens, pos):
+    def run_decode(self, engine: Any, tokens):
         """One masked decode step for every slot over paged storage;
-        updates the pool state in place and returns the logits."""
+        updates the pool state in place and returns the logits. ``tokens``
+        is the (B,) current-token vector; the positions are the device
+        ``index``, which equals the host's ``starts`` whenever a decode is
+        queued (:meth:`SlotPool.positions`)."""
         self.bind_engine(engine)
+        self._publish_stale()
         # direct attribute dispatch on both arms (not `fn = a or b;
         # fn(...)`): the watchdog and graftcheck identify watched
         # programs by the attribute the call goes through; each arm
@@ -1097,60 +1168,73 @@ class PagedKVPool(SlotPool):
         with self.enqueue("decode"):
             if self._paged_decode_kernel_jit is not None:
                 logits, cs, stats = self._paged_decode_kernel_jit(
-                    engine.params, self.cache["cache_store"], tokens, pos)
+                    engine.params, self.cache["cache_store"], tokens)
                 self.cache = {"cache_store": cs}
             else:
                 logits, cs, stats = self._paged_decode_jit(
-                    engine.params, self.cache["cache_store"], tokens, pos)
+                    engine.params, self.cache["cache_store"], tokens)
                 self.cache = {"cache_store": cs}
         if stats is not None:
             self.moe_stats.append(stats)
         return logits
 
-    def run_verify(self, engine: Any, tokens, pos, draft, draft_len, rng,
+    def run_verify(self, engine: Any, cur, draft, draft_len, rng,
                    temperature, greedy, top_k: int, top_p: float):
         """Speculative verify over paged storage (same semantics as
-        ``InferenceEngine.verify_k``); returns ``(out, n_emit)``. With
-        the kernel active the K + 1 query rows a slot read and write the
-        pages in place whatever K is (:meth:`reads_in_place`)."""
+        ``InferenceEngine.verify_k``); returns ``(out, n_emit, rng')``.
+        With the kernel active the K + 1 query rows a slot read and write
+        the pages in place whatever K is (:meth:`reads_in_place`)."""
         self.bind_engine(engine)
+        self._publish_stale()
         with self.enqueue("verify_k"):
-            if self.reads_in_place(tokens.shape[1]):
-                cs, out, n_emit = self._paged_verify_kernel_jit(
-                    engine.params, self.cache["cache_store"], tokens, pos,
-                    draft, draft_len, rng, temperature, greedy, int(top_k),
+            if self.reads_in_place(draft.shape[1] + 1):
+                cs, out, n_emit, rng = self._paged_verify_kernel_jit(
+                    engine.params, self.cache["cache_store"], cur, draft,
+                    draft_len, rng, temperature, greedy, int(top_k),
                     float(top_p))
                 self.cache = {"cache_store": cs}
             else:
-                cs, out, n_emit = self._paged_verify_jit(
-                    engine.params, self.cache["cache_store"], tokens, pos,
-                    draft, draft_len, rng, temperature, greedy, int(top_k),
+                cs, out, n_emit, rng = self._paged_verify_jit(
+                    engine.params, self.cache["cache_store"], cur, draft,
+                    draft_len, rng, temperature, greedy, int(top_k),
                     float(top_p))
                 self.cache = {"cache_store": cs}
-        return out, n_emit
+        return out, n_emit, rng
 
     def run_prefill_chunk(self, engine: Any, ids, slot: int, start: int,
                           length: int, last_idx: int):
         """One bounded prefill chunk into ``slot``'s pages at offset
         ``start`` (pages covering the window must already be writable —
-        the engine calls :meth:`ensure_writable` first). Returns the
-        chunk's (1, 1, V) logits."""
+        the engine calls :meth:`ensure_writable` first, with
+        ``sync=False``). Returns the chunk's (1, 1, V) logits.
+
+        What only the host knows goes in ONE int32 vector: the ids, the
+        four scalars and the slot's row of each group's table from the
+        host mirror (``pack_chunk_args``), handed to the jitted call as
+        the NumPy array it is: the chunk's one transfer, made by the call
+        (on the chip's host 0.22 ms with the call against 0.36 for a put
+        and the call and 1.27 for a put of the seven arrays: PERF.md §6,
+        PR 35). The program reads and writes
+        the pages through that row and writes it into the device table
+        too, so the pages the chunk mapped are published by the program
+        that fills them and the table is not put again; only a stale row
+        of ANOTHER slot brings the whole table first
+        (:meth:`_publish_stale`)."""
         self.bind_engine(engine)
         if self._paged_chunk_jit is None:
             raise ValueError("run_prefill_chunk requires a module with "
                              "prefill_chunk(); the TransformerLM family "
                              "has one")
-        # the chunk's host-built arguments in ONE put: one transfer, one
-        # ``serving/enqueue`` span, whatever the window group adds
+        self._publish_stale(own=slot)
         with self.enqueue("chunk", "transfer"):
-            args = jax.device_put(
-                (np.asarray(ids, np.int32), self.table[slot],
-                 np.int32(slot), np.int32(start), np.int32(length),
-                 np.int32(last_idx)) + self._window_rows([slot]))
+            packed = pack_chunk_args(
+                ids, slot, start, length, last_idx, self.table[slot],
+                *self._window_rows([slot]))
         with self.enqueue("chunk"):
             logits, cs, stats = self._paged_chunk_jit(
-                engine.params, self.cache["cache_store"], *args)
+                engine.params, self.cache["cache_store"], packed)
         self.cache = {"cache_store": cs}
+        self._stale_rows.discard(slot)
         if stats is not None:
             self.moe_stats.append(stats)
         return logits

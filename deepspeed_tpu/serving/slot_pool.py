@@ -296,5 +296,8 @@ class SlotPool:
     def positions(self) -> np.ndarray:
         """(num_slots,) decode positions, clamped into the allocation so
         long-dead slots can't push position-embedding lookups or cache
-        writes past the last (masked) column."""
+        writes past the last (masked) column. The host's copy: the decode
+        and verify programs make the same vector from the device ``index``
+        (``minimum(index, capacity - 1)``), which equals ``starts`` whenever
+        one is queued, so nothing is put for them."""
         return np.minimum(self.starts, self.capacity - 1).astype(np.int32)

@@ -172,15 +172,13 @@ class SmallModelDrafter(Drafter):
                 lg[:, -1, :].astype(jnp.float32), axis=-1).astype(jnp.int32))
         cur = self._argmax(logits)
         toks = [cur]
-        pos = lens.copy()
         for _ in range(k - 1):
-            logits, cache = eng._jit_decode(eng.params, cache, cur[:, None],
-                                            jnp.asarray(pos))
+            # the (B,) tokens as they are, positions from the cache's own
+            # index (``lens`` and one more a step, inside the capacity by
+            # ``keep``): nothing is put and nothing runs before the program
+            logits, cache = eng._jit_decode(eng.params, cache, cur)
             cur = self._argmax(logits)
             toks.append(cur)
-            # a new array, not ``+=``: the CPU backend may still be reading
-            # the buffer ``jnp.asarray(pos)`` aliases when this line runs
-            pos = pos + 1
         tokens = np.stack([np.asarray(t) for t in toks], axis=1)
         counts = np.where(lens > 0, k, 0).astype(np.int32)
         return tokens.astype(np.int32), counts

@@ -32,21 +32,27 @@ import jax.numpy as jnp
 
 def make_verify_fn(decode_fn, filter_fn):
     """Build the verify body over the engine's traced ``decode_fn``
-    ((params, cache, tokens, pos) -> (logits, cache)) and its sampling
-    ``filter_fn`` ((..., V) logits, temperature, top_k, top_p) — the SAME
-    filter the serving sampler uses, so acceptance probabilities match
-    the distribution plain decode would have sampled from."""
+    ((params, cache, tokens) -> (logits, cache), positions from the
+    cache's own index) and its sampling ``filter_fn`` ((..., V) logits,
+    temperature, top_k, top_p) — the SAME filter the serving sampler
+    uses, so acceptance probabilities match the distribution plain decode
+    would have sampled from."""
 
-    def verify(params, cache, tokens, pos, draft, draft_len, rng,
-               temperature, greedy, top_k, top_p):
-        """tokens: (B, K+1) int32 — [current, draft_0..draft_{K-1}];
-        pos: (B,) int32 decode positions; draft: (B, K) int32;
-        draft_len: (B,) int32 in [0, K] (0 = not speculating / dead).
-        Returns (cache, out (B, K+1) int32, n_emit (B,) int32): row i
-        emits out[i, :n_emit[i]] — accepted prefix + bonus/correction."""
+    def verify(params, cache, cur, draft, draft_len, rng, temperature,
+               greedy, top_k, top_p):
+        """cur: (B,) int32, the server's current-token twin as it is;
+        draft: (B, K) int32; draft_len: (B,) int32 in [0, K] (0 = not
+        speculating / dead); rng: the caller's key, split here. The rows
+        scored are [current, draft_0..draft_{K-1}] at the cache's own
+        per-slot index. Returns (cache, out (B, K+1) int32, n_emit (B,)
+        int32, rng'): row i emits out[i, :n_emit[i]] — accepted prefix +
+        bonus/correction — and the caller keeps ``rng'`` for its next
+        call."""
+        tokens = jnp.concatenate([cur[:, None], draft], axis=1)
         B, T = tokens.shape
         K = T - 1
-        logits, cache = decode_fn(params, cache, tokens, pos)
+        rng, sub = jax.random.split(rng)
+        logits, cache = decode_fn(params, cache, tokens)
         last = logits.astype(jnp.float32)            # (B, K+1, V)
         V = last.shape[-1]
         targets = jnp.argmax(last, axis=-1)          # (B, K+1) greedy next
@@ -60,7 +66,7 @@ def make_verify_fn(decode_fn, filter_fn):
         probs = jax.nn.softmax(filt, axis=-1)
         p_draft = jnp.take_along_axis(probs[:, :K], draft[..., None],
                                       axis=-1)[..., 0]
-        rng_acc, rng_bonus = jax.random.split(rng)
+        rng_acc, rng_bonus = jax.random.split(sub)
         u = jax.random.uniform(rng_acc, (B, K))
         s_accept = (u < p_draft) & in_draft
 
@@ -88,6 +94,6 @@ def make_verify_fn(decode_fn, filter_fn):
         draft_pad = jnp.pad(draft, ((0, 0), (0, 1)))
         out = jnp.where(j < n_acc[:, None], draft_pad,
                         jnp.where(j == n_acc[:, None], bonus[:, None], 0))
-        return cache, out.astype(jnp.int32), n_acc + 1
+        return cache, out.astype(jnp.int32), n_acc + 1, rng
 
     return verify

@@ -357,6 +357,7 @@ def _small_paged_server(pages=6):
     import deepspeed_tpu as ds
     from deepspeed_tpu.models.transformer_lm import (TransformerLM,
                                                      transformer_config)
+    from deepspeed_tpu.inference.engine import pack_chunk_args
     from deepspeed_tpu.serving.paged_pool import PagedKVPool
 
     cfg = transformer_config("gpt-neox", vocab_size=128, max_seq_len=384,
@@ -382,14 +383,13 @@ def _small_paged_server(pages=6):
     i32 = jnp.int32
     pre = dict(model.kv_cache_spec().stacked_cache(2))
     programs = {
+        # (the (B,) token twin; a chunk's arguments as one vector)
         "kernel_decode": (pool._paged_decode_kernel_jit, (
-            engine.params, cs, jnp.zeros((slots, 1), i32),
-            jnp.zeros((slots,), i32))),
+            engine.params, cs, jnp.zeros((slots,), i32))),
         "paged_chunk": (pool._paged_chunk_jit, (
-            engine.params, cs, jnp.zeros((1, 64), i32),
-            jnp.zeros((pool.pages_per_slot,), i32), jnp.asarray(0, i32),
-            jnp.asarray(64, i32), jnp.asarray(64, i32),
-            jnp.asarray(63, i32))),
+            engine.params, cs, jnp.asarray(pack_chunk_args(
+                np.zeros((1, 64), np.int32), 0, 64, 64, 63,
+                np.zeros((pool.pages_per_slot,), np.int32))))),
         "_paged_admit_rows": (pool._admit_rows_jit, (
             cs, pre, jnp.zeros((2, pool.pages_per_slot), i32),
             jnp.zeros((2,), i32), jnp.zeros((2,), i32))),
@@ -458,10 +458,20 @@ def _program_text(compiled) -> str:
     return re.sub(r'"body":"([A-Za-z0-9+/=]+)"', kernel, text)
 
 
-# sha256 of _program_text(kernel_decode) of _small_paged_server(4096) on
-# the parent of PR 33 (commit 2a1c43f): the decode program PR 31 measured
+# sha256 of _program_text(kernel_decode) of _small_paged_server(4096) since
+# PR 35. Until then "eccfe7ce...aaff9", the program PR 31 measured (taken on
+# commit 2a1c43f, still the text of PR 35's parent c3d4d53). PR 35's differs
+# from it, once the numbers XLA gives its instructions are taken out, in 62
+# lines, all of them the token operand: ``s32[2]`` where it was ``s32[2,1]``
+# (the program takes the server's (B,) token twin and adds the axis itself),
+# through the two fusions of the embedding lookup that read it. The positions
+# operand is in neither text: this model's are rotary, made from the cache's
+# index, so XLA had dropped the argument the host still put every step.
+# Measured with it, `serve-pythia-1b4-chat` `gap_p90_ms`, parent / PR 35 at
+# one seed a pair, one v5e chip (PERF.md §6, PR 35, call C1): 9.196 / 8.402
+# and 9.132 / 8.206 ms; `decode_dev_ms_p50` 5.99 / 6.04 (a traced pair).
 _KERNEL_DECODE_TEXT = (
-    "eccfe7ce1a6812476b6652393a5e16188a87b33f650a3ef2f2261cfc761aaff9")
+    "4b94b6257df37087f22d38d8c575a6d3aa0297868123f58b280f53b5c13109c1")
 
 
 def test_the_chunk_program_goes_through_the_pages_and_decode_is_unchanged(
@@ -472,8 +482,8 @@ def test_the_chunk_program_goes_through_the_pages_and_decode_is_unchanged(
     leaves go from parameter to custom call to result and no other
     operation takes or gives one, no value has ``max_seq_len`` positions,
     and the temporaries are a chunk's activations. ``kernel_decode``: its
-    text is the parent's, kernels included: a call of up to 8 rows
-    compiles to what it compiled to before the row limit went."""
+    text is the pinned one, kernels included (what changed it last, and
+    what was measured then, is beside the digest)."""
     import hashlib
 
     from jax.experimental.compilation_cache import compilation_cache

@@ -47,7 +47,7 @@ def test_inventory_finds_the_known_entry_points():
     # paged pool: donated cache arg sits at position 1 (after params),
     # verify carries static draft-shape argnums
     assert by_attr["_paged_decode_jit"]["donate_argnums"] == [1]
-    assert by_attr["_paged_verify_jit"]["static_argnums"] == [9, 10]
+    assert by_attr["_paged_verify_jit"]["static_argnums"] == [8, 9]
     assert by_attr["_paged_chunk_jit"]["donate_argnums"] == [1]
     assert by_attr["_jit_copy_page"]["donate_argnums"] == [0]
     # engine-local guard jit + the drafter's lazily-built argmax (the

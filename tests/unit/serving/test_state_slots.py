@@ -93,6 +93,7 @@ def test_decode_leaves_a_prefilling_rows_state_bitwise_alone(stack):
         return logits, cache
 
     def decode_spy(p, cache, tokens, pos, rows):
+        assert pos is None        # (PR 35: the cache's own index)
         told.append(np.asarray(rows))
         logits, cache = decode(p, cache, tokens, pos, rows)
         if long.state is RequestState.PREFILLING and long.slot in after_chunk:
@@ -189,3 +190,43 @@ def test_tensor_parallel_serving_refuses(stack):
             ServingEngine(engine, num_slots=2, prefill_chunk=CHUNK)
     finally:
         mesh_mod.reset_mesh()
+
+
+def test_the_rows_are_put_again_exactly_when_the_running_set_changes(stack):
+    """The decode program's ``rows`` stay on the device beside the slots
+    they were built from (PR 35): over a drive in which requests join, stream
+    in chunk by chunk and retire, one put of a host array for every change
+    of the running set and none on a step that decodes the same rows."""
+    _, _, _, engine, ids = stack
+    srv = server(engine, 3)
+    eng = srv.engine
+    decode, commit = eng._jit_decode, srv._cur_commit
+    told, puts = [], []
+
+    def decode_spy(p, cache, tokens, pos, rows):
+        told.append((rows, tuple(int(r) for r in np.asarray(rows))))
+        return decode(p, cache, tokens, pos, rows)
+
+    def commit_spy(arr):
+        if isinstance(arr, np.ndarray):       # (sampled tokens are device)
+            puts.append(tuple(int(r) for r in arr))
+        return commit(arr)
+
+    eng._jit_decode, srv._cur_commit = decode_spy, commit_spy
+    try:
+        reqs = [srv.submit(ids[0, :10], max_new_tokens=25),
+                srv.submit(ids[1, :12], max_new_tokens=6)]
+        for _ in range(4):
+            srv.step()
+        reqs.append(srv.submit(ids[2, :60], max_new_tokens=5))
+        srv.run_until_drained(max_steps=300)
+    finally:
+        eng._jit_decode, srv._cur_commit = decode, commit
+    assert all(r.state is RequestState.FINISHED for r in reqs)
+    sets = [rows for _, rows in told]
+    changes = [rows for before, rows in zip([None] + sets, sets)
+               if rows != before]
+    assert len(sets) > 2 * len(changes) > 4
+    assert puts == changes
+    for (a, rows_a), (b, rows_b) in zip(told, told[1:]):
+        assert (a is b) == (rows_a == rows_b)

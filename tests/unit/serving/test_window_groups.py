@@ -123,8 +123,7 @@ def test_chunked_prefill_then_decode_past_the_window_matches_the_reference(
             tokens[s, 0] = seqs[s][length[s]]
             pool.ensure_writable(s, length[s], length[s] + 1)
         most = max(most, mapped())
-        lg = pool.run_decode(engine, jnp.asarray(tokens),
-                             jnp.asarray(pool.positions()))
+        lg = pool.run_decode(engine, jnp.asarray(tokens[:, 0]))
         deltas = np.zeros((3,), np.int32)
         deltas[live] = 1
         pool.advance(deltas)
@@ -173,8 +172,7 @@ def test_a_swapped_window_page_fails_the_reference_by_its_worst_limit(stack):
                                  int(pool.starts[slot]) + 1)
             tokens = np.zeros((2, 1), np.int32)
             tokens[slot, 0] = out[-1]
-            lg = pool.run_decode(engine, jnp.asarray(tokens),
-                                 jnp.asarray(pool.positions()))
+            lg = pool.run_decode(engine, jnp.asarray(tokens[:, 0]))
             pool.advance(np.asarray([1, 0], np.int32))
             out.append(int(np.argmax(np.asarray(lg[slot, 0]))))
         return ref.check_greedy(logits_fn, params, prompt, out, CTX, 24,

@@ -132,7 +132,7 @@ def test_flight_recorder_shows_where_the_step_went(server_parts):
     assert sum(v for k, v in phases.items() if k != "exposed") \
         <= last["wall_ms"]
     assert last["dispatched"]["decode"] == 1
-    assert last["dispatched"]["device_calls"] >= 5
+    assert last["dispatched"]["device_calls"] >= DECODE_CALLS
     # no longer empty by default: the tail of the process-wide ring
     assert any(e["name"] == "serving/step" for e in dump["last_spans"])
     assert dump["telemetry_overhead_s"] > 0.0
@@ -176,12 +176,14 @@ def test_explicit_disabled_tracer_silences_a_server(server_parts):
 POOLS = {"contiguous": False, "paged": {"kernel": "off"}}
 DISPATCH = ("serving/admit", "serving/prefill_batch",
             "serving/prefill_chunk", "serving/decode")
-# a plain decode step: the token twin's reshape, positions, the program,
-# the key split, the temperature, the sampler, the commit of its tokens
-DECODE_CALLS = 7
-# a step that only carries a chunk: its arguments in one put + the program
-# (+ the table, republished because the chunk mapped a fresh page)
-CHUNK_CALLS = {"contiguous": 2, "paged": 3}
+# a plain decode step: the program, the sampler, the commit of its tokens
+# (PR 35: the token twin goes in as it is, the positions are the cache's own
+# index, the key is split in the sampler, the temperature is put once)
+DECODE_CALLS = 3
+# a step that only carries a chunk: its arguments as one vector, the one
+# transfer, + the program (PR 35: which patches the table row it is handed,
+# so the table is not republished for the fresh page the chunk mapped)
+CHUNK_CALLS = {"contiguous": 2, "paged": 2}
 
 
 def _kids(evs, parent, name=None):
@@ -413,14 +415,19 @@ def test_an_overrun_names_the_phases_of_its_step(account):
 
 def test_a_dispatch_span_opens_where_it_did(account, server_parts):
     """The accepted ``step_host_serial_ms_p50`` ends where a step's first
-    dispatch span opens: ``serving/decode`` opens after the positions are
-    read and put, as before the account, and holds the program's call;
-    ``serving/sample`` holds the sampler."""
+    dispatch span opens: ``serving/decode`` opens after the running set is
+    taken, as before the account, and holds the program's call and no
+    other (since PR 35 nothing is read or put for it: the positions are
+    the cache's own index); ``serving/sample`` holds the sampler."""
     srv = _serve(server_parts)
     read_at = []
     positions = srv.pool.positions
     srv.pool.positions = lambda: read_at.append(
         time.perf_counter_ns()) or positions()
+    taken_at = []
+    note_rows = srv._state_rows
+    srv._state_rows = lambda running: taken_at.append(
+        time.perf_counter_ns()) or note_rows(running)
     n0 = default_tracer().events_total
     rng = np.random.default_rng(13)
     srv.submit(rng.integers(0, 64, size=5).astype(np.int32),
@@ -428,8 +435,8 @@ def test_a_dispatch_span_opens_where_it_did(account, server_parts):
     srv.run_until_drained(max_steps=20)
     new = _new_events(n0)
     decodes = [e for e in new if e["name"] == "serving/decode"]
-    assert len(read_at) == len(decodes) > 0
-    for t, decode in zip(read_at, decodes):
+    assert not read_at and len(taken_at) == len(decodes) > 0
+    for t, decode in zip(taken_at, decodes):
         assert t < decode["ts"]
         assert [(k["args"]["program"], k["args"]["kind"]) for k in _kids(
             new, decode, "serving/enqueue")] == [("decode", "program")]
