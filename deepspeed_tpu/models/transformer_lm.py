@@ -32,6 +32,11 @@ brumby         rotary    rmsnorm    swiglu    every layer gated
                                               and k, a gate a KV
                                               head, a recurrent
                                               state in place of K/V
+moonlight      rotary    rmsnorm    routed    latent attention (one
+                                              cached row a token),
+                                              sigmoid router with a
+                                              bias, shared experts,
+                                              leading dense layers
 =============  ========  =========  ========  ===================
 
 Layer kinds that differ (``layer_types``: ``sliding_attention`` or
@@ -51,6 +56,14 @@ by the kernels of ``ops/attention/power_retention.py``, for the rows the
 caller names (``rows``) and no others. Today every layer of a model is of
 this kind or none is.
 
+A fourth, latent attention (:class:`LatentAttention`, ``kv_lora_rank >
+0``), caches ONE row a token a layer, ``[c ; k_r]`` (the normed latent and
+the shared rotary key), that every head reads: ``KVCacheSpec.latent``, one
+leaf ``c`` and no ``k`` / ``v``. The leading ``first_k_dense`` layers of
+such a model may carry a plain gated FFN (``dense_ffn_dim``) before the
+routed layers: they are a scan of their own (``dense_blocks``) in front of
+``blocks``, the layer counter and the cache running through both.
+
 KV-cache decoding uses the flax ``cache`` variable collection: ``prefill``
 writes the prompt's K/V at positions [0, T), ``decode`` appends one position
 via ``lax.dynamic_update_slice`` and attends over the static-shape cache with
@@ -61,6 +74,7 @@ inference_context.h workspace is the moral equivalent).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Tuple, Union
 
@@ -148,6 +162,22 @@ class TransformerConfig:
     # router probabilities to sum to 1
     qk_norm: bool = False               # RMSNorm over each head of q and k
     # (a learned weight of head_dim), before the rotary
+    kv_lora_rank: int = 0               # > 0: latent attention
+    # (LatentAttention): K and V of every head are projections of one
+    # normed latent of this width a token, which is what the cache holds
+    # beside the shared rotary key
+    qk_nope_head_dim: int = 0           # a head's q / k width without rotary
+    qk_rope_head_dim: int = 0           # ... with rotary (k's: one a token)
+    v_head_dim: int = 0
+    scoring_func: str = "softmax"       # the router's: softmax | sigmoid
+    # (sigmoid: the choice is ordered by score + a learned bias, the
+    # weights come from the unbiased scores)
+    routed_scaling_factor: float = 1.0  # multiplies the routed weights
+    n_shared_experts: int = 0           # one gated FFN of this many expert
+    # widths beside the routed sum, for every token
+    first_k_dense: int = 0              # the first layers' FFN is a plain
+    # gated FFN of dense_ffn_dim; the routed FFN starts after them
+    dense_ffn_dim: Optional[int] = None
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -199,6 +229,45 @@ class TransformerConfig:
             if self.activation != "swiglu" or self.mlp_bias:
                 raise ValueError("the routed FFN is gated silu without "
                                  "bias (activation='swiglu', mlp_bias=False)")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func {self.scoring_func!r}: know "
+                             f"softmax | sigmoid")
+        if self.scoring_func == "softmax" and self.routed_scaling_factor != 1:
+            raise ValueError(
+                "routed_scaling_factor multiplies the sigmoid router's "
+                "weights; the softmax router's sum to 1 (norm_topk_prob) "
+                "or are probabilities")
+        if self.first_k_dense:
+            if not self.n_experts or not self.dense_ffn_dim \
+                    or not 0 < self.first_k_dense < self.n_layer:
+                raise ValueError(
+                    f"first_k_dense={self.first_k_dense} names the leading "
+                    f"layers of a routed model (n_experts > 0, fewer than "
+                    f"n_layer={self.n_layer}) whose FFN is plain, of "
+                    f"dense_ffn_dim={self.dense_ffn_dim}")
+        if self.latent:
+            if not (self.qk_nope_head_dim and self.qk_rope_head_dim
+                    and self.v_head_dim) or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention (kv_lora_rank > 0) needs "
+                    "qk_nope_head_dim, an even qk_rope_head_dim and "
+                    "v_head_dim")
+            if self.pos_emb != "rotary" or self.layer_types is not None:
+                raise ValueError(
+                    "latent attention carries its positions in the shared "
+                    "rotary key (pos_emb='rotary') and knows no layer kinds "
+                    "(layer_types) yet (ROADMAP.md, Reach)")
+            if self.kv_cache_quant:
+                raise ValueError(
+                    "kv_cache_quant quantizes K/V columns a head; the "
+                    "latent cache is one row a token that every head reads, "
+                    "and its scales have no leaf yet (ROADMAP.md, Reach)")
+            if self.int8_weights:
+                raise ValueError(
+                    "int8_weights does not reach latent attention: "
+                    "kv_b_proj is read as a matrix (absorbed into the query "
+                    "and the output), not through a Dense (ROADMAP.md, "
+                    "Reach)")
         if (self.layer_types is not None or self.n_experts) \
                 and self.kv_cache_quant:
             raise ValueError(
@@ -218,6 +287,20 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_head or self.n_head
+
+    @property
+    def latent(self) -> int:
+        """Width of the one cached row a token of latent attention
+        (``kv_lora_rank + qk_rope_head_dim``), 0 for K/V a head."""
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            if self.kv_lora_rank else 0
+
+    def dense_layers(self) -> "TransformerConfig":
+        """The configuration of the leading ``first_k_dense`` layers:
+        this one with a plain gated FFN of ``dense_ffn_dim``."""
+        return dataclasses.replace(
+            self, n_experts=0, experts_per_token=0, n_shared_experts=0,
+            first_k_dense=0, ffn_dim=self.dense_ffn_dim)
 
     @property
     def retention(self) -> bool:
@@ -254,6 +337,14 @@ FAMILY_PRESETS = {
                    qkv_bias=False, mlp_bias=False, tie_word_embeddings=False,
                    layer_norm_epsilon=1e-6, qk_norm=True,
                    layer_kind="power_retention"),
+    # Moonlight (Moonshot AI; model_type deepseek_v3): latent attention,
+    # a sigmoid router ordered by score + bias, shared experts, leading
+    # dense layers (``mlp_layer_types``, which transformer_config reads
+    # into first_k_dense). Widths are the caller's.
+    "moonlight": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
+                      qkv_bias=False, mlp_bias=False,
+                      tie_word_embeddings=False, layer_norm_epsilon=1e-5,
+                      scoring_func="sigmoid"),
 }
 
 
@@ -278,6 +369,17 @@ def transformer_config(family: str, **overrides) -> TransformerConfig:
     if kind is not None:
         cfg.setdefault("layer_types", (kind,) * cfg.get(
             "n_layer", TransformerConfig.n_layer))
+    mlp_kinds = cfg.pop("mlp_layer_types", None)
+    if mlp_kinds is not None:
+        # published per layer as "dense" | "sparse"; the program runs the
+        # first n_layer entries: dense layers first, then sparse ones
+        kinds = list(mlp_kinds)[:cfg.get("n_layer", TransformerConfig.n_layer)]
+        k = kinds.index("sparse") if "sparse" in kinds else len(kinds)
+        if set(kinds[:k]) - {"dense"} or set(kinds[k:]) - {"sparse"}:
+            raise ValueError(
+                f"mlp_layer_types names leading dense layers, then sparse "
+                f"ones; got {kinds}")
+        cfg.setdefault("first_k_dense", k)
     return TransformerConfig(**cfg)
 
 
@@ -431,6 +533,18 @@ def alibi_slopes(n_head: int) -> jnp.ndarray:
     base = pow2_slopes(closest)
     extra = pow2_slopes(2 * closest)[0::2][: n_head - closest]
     return jnp.asarray(base + extra, jnp.float32)
+
+
+def _store_columns(buf, new, start):
+    """Write the new positions-minor columns at each row's offset: one
+    DUS for scalar start; per-slot (B,) starts vmap the DUS over the batch
+    (lowers to a scatter — each slot writes at its own cache offset)."""
+    if jnp.ndim(start) == 1:
+        return jax.vmap(
+            lambda c, n, s: jax.lax.dynamic_update_slice(
+                c, n, (0,) * (c.ndim - 1) + (s,)))(buf, new, start)
+    return jax.lax.dynamic_update_slice(
+        buf, new, (0,) * (buf.ndim - 1) + (start,))
 
 
 class CachedAttention(nn.Module):
@@ -698,18 +812,7 @@ class CachedAttention(nn.Module):
             v_rows = v.astype(cfg.dtype).transpose(0, 2, 1, 3)
             new_cache = dict(kv_cache)
 
-            def store(buf, new):
-                """Write the new positions-minor columns at each row's
-                offset: one DUS for scalar start; per-slot (B,) starts
-                vmap the DUS over the batch (lowers to a scatter — each
-                slot writes at its own cache offset)."""
-                if per_slot:
-                    return jax.vmap(
-                        lambda c, n, s: jax.lax.dynamic_update_slice(
-                            c, n, (0,) * (c.ndim - 1) + (s,)))(buf, new,
-                                                               start)
-                return jax.lax.dynamic_update_slice(
-                    buf, new, (0,) * (buf.ndim - 1) + (start,))
+            store = functools.partial(_store_columns, start=start)
 
             if cfg.kv_cache_quant:
                 from ..ops.attention.decode_attention import (
@@ -949,6 +1052,119 @@ class PowerRetention(nn.Module):
         return o_proj(y), {"s": s}
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``kv_lora_rank > 0``): every head's K
+    and V are projections of ONE normed latent ``c`` (``kv_lora_rank``
+    wide) a token, and the rotary part of the key, ``k_r``, is one vector a
+    token that the heads share. With ``h`` the layer's input::
+
+        q_h = [q_n,h ; q_r,h] = W_q h        [c' ; k_r] = W_kva h
+        c = RMSNorm(c')      rotary on q_r,h and on k_r
+        [k_n,h ; v_h] = W_kvb,h c
+        score_h(i, j) = (q_n,h(i) . k_n,h(j) + q_r,h(i) . k_r(j)) / sqrt(dn + dr)
+        o_h = sum_j softmax_j(score_h)(i, j) v_h(j)        out = W_o [o_h]
+
+    Two forms of that one mathematics. **Expanded**: K and V are built from
+    ``c`` and attended to as written (the forward without a cache, and a
+    prompt prefilled whole). **Absorbed**, against a cache: the cache holds
+    ``[c ; k_r]`` a token, after the norm and the rotary, and nothing a
+    head; ``q~_h = W_kvb,h^K^T q_n,h`` scores the cached row itself,
+    ``score_h = (q~_h . c + q_r,h . k_r)``, and the values are read off the
+    same row, ``o_h = W_kvb,h^V (sum_j p_h c_j)``: the K/V of a cached token
+    are never rebuilt. Against a page pool the absorbed read is
+    ``ops/attention/latent_attention.py`` (``mla_decode``: the heads' rows
+    over one page of rows a step); modes as :class:`CachedAttention`'s."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, *, decode: Union[bool, str] = False,
+                 deterministic: bool = True, kv_cache=None,
+                 block_hint=None, layer=None):
+        cfg = self.config
+        B, T, C = x.shape
+        H, R = cfg.n_head, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        q = _dense(cfg, H * (dn + dr), use_bias=False,
+                   name="q_proj")(x).reshape(B, T, H, dn + dr)
+        ckr = _dense(cfg, R + dr, use_bias=False, name="kv_a_proj")(x)
+        c = nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
+                       name="kv_a_norm")(ckr[..., :R])
+        # read as a matrix (its two halves are absorbed into the query and
+        # the output), so a parameter of this module and no Dense
+        w_kvb = self.param("kv_b_proj", nn.initializers.lecun_normal(),
+                           (R, H * (dn + dv))).astype(cfg.dtype)
+        w_kvb = w_kvb.reshape(R, H, dn + dv)
+        o_proj = _dense(cfg, C, use_bias=False, name="o_proj")
+
+        start = kv_cache["start"] if decode else jnp.zeros((), jnp.int32)
+        per_slot = jnp.ndim(start) == 1
+        positions = (start[:, None] if per_slot else start) \
+            + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+        rope = functools.partial(apply_rotary, positions=positions,
+                                 rotary_dim=dr, theta=cfg.rope_theta)
+        q_n, q_r = q[..., :dn], rope(q[..., dn:])
+        k_r = rope(ckr[..., None, R:])[:, :, 0]                 # (B, T, dr)
+        scale = 1.0 / math.sqrt(dn + dr)
+        f32 = jnp.float32
+
+        def expanded():
+            """Causal attention over the fresh tokens, K and V rebuilt."""
+            kv = jnp.einsum("bsr,rhd->bshd", c, w_kvb)
+            att = (jnp.einsum("bthd,bshd->bhts", q_n.astype(f32),
+                              kv[..., :dn].astype(f32))
+                   + jnp.einsum("bthd,bsd->bhts", q_r.astype(f32),
+                                k_r.astype(f32))) * scale
+            att = jnp.where(jnp.tril(jnp.ones((T, T), dtype=bool)), att,
+                            -1e30)
+            return jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(att, -1),
+                              kv[..., dn:].astype(f32))
+
+        if not decode:
+            y = expanded().astype(cfg.dtype).reshape(B, T, H * dv)
+            return o_proj(y), None
+        assert kv_cache is not None, "decode needs the kv_cache slice"
+        # this step's cached rows, positions-minor like every cache here
+        cols = jnp.concatenate([c, k_r], -1).astype(cfg.dtype) \
+            .transpose(0, 2, 1)                               # (B, R + dr, T)
+        # the absorbed query: W_kvb^K^T q_n beside q_r, one (R + dr) row a
+        # head that scores a cached row as it stands
+        q_abs = jnp.concatenate(
+            [jnp.einsum("bthd,rhd->bthr", q_n, w_kvb[..., :dn]), q_r],
+            -1).astype(cfg.dtype)
+        if "table" in kv_cache:
+            from ..ops.attention.latent_attention import latent_attention
+            from ..ops.attention.paged_attention import paged_write_columns
+
+            assert per_slot, "paged decode is slot-pooled: start must be (B,)"
+            table, li = kv_cache["table"], kv_cache["layer"]
+            page_size = cfg.max_seq_len // table.shape[1]
+            leaf = paged_write_columns(kv_cache["c"], li, cols, table, start,
+                                       page_size=page_size)
+            ctx = latent_attention(q_abs, leaf, table, start, layer=li,
+                                   page_size=page_size, rank=R, scale=scale)
+            new_cache = {"c": leaf}
+        else:
+            leaf = _store_columns(kv_cache["c"], cols, start)   # (B, R+dr, S)
+            new_cache = dict(kv_cache, c=leaf)
+            if decode == "prefill" and T > 1:
+                # start == 0: the prompt's causal window IS the fresh rows
+                y = expanded().astype(cfg.dtype).reshape(B, T, H * dv)
+                return o_proj(y), new_cache
+            S = leaf.shape[-1]
+            att = jnp.einsum("bthw,bws->bhts", q_abs.astype(f32),
+                             leaf.astype(f32)) * scale
+            # row t may see cache positions [0, start + t]
+            att = jnp.where((jnp.arange(S) <= positions[..., None])[:, None],
+                            att, -1e30)
+            ctx = jnp.einsum("bhts,brs->bthr", jax.nn.softmax(att, -1),
+                             leaf[:, :R].astype(f32))
+        y = jnp.einsum("bthr,rhd->bthd", ctx.astype(cfg.dtype),
+                       w_kvb[..., dn:])
+        return o_proj(y.astype(cfg.dtype).reshape(B, T, H * dv)), new_cache
+
+
 class TransformerMLP(nn.Module):
     config: TransformerConfig
 
@@ -979,7 +1195,8 @@ class TransformerBlock(nn.Module):
                  deterministic: bool = True, kv_cache=None,
                  block_hint=None, layer=None, experts=None):
         cfg = self.config
-        attention = PowerRetention if cfg.retention else CachedAttention
+        attention = PowerRetention if cfg.retention else \
+            LatentAttention if cfg.latent else CachedAttention
         a, new_cache = attention(cfg, name="attn")(
             _norm(cfg, "ln_1")(x), decode=decode, deterministic=deterministic,
             kv_cache=kv_cache, block_hint=block_hint, layer=layer)
@@ -991,9 +1208,13 @@ class TransformerBlock(nn.Module):
             from ..moe.routed_ffn import RoutedFFN
 
             nonlocal stats
+            # (the expert leaves stack the routed layers only)
             m, layer_stats = RoutedFFN(
                 cfg.n_experts, cfg.experts_per_token, cfg.norm_topk_prob,
-                name="mlp")(h, experts, layer)
+                cfg.scoring_func, cfg.routed_scaling_factor,
+                cfg.n_shared_experts * cfg.ffn_width, cfg.dtype,
+                name="mlp")(h, experts, layer - cfg.first_k_dense
+                             if cfg.first_k_dense else layer)
             stats = (layer_stats,)
             return m
 
@@ -1178,6 +1399,11 @@ class KVCacheSpec:
     # holds ``s`` (L, B, KV, *state) and no k / v: its size does not depend
     # on max_seq_len, which stays the bound on positions
 
+    latent: int = 0                    # latent attention: the width of the
+    # one row a token a layer that every head reads. Such a cache holds
+    # ``c`` (L, B, latent, S) positions-minor and no k / v; a page pool's
+    # leaf is (L, P, latent, lanes), a page one whole-tile block a layer
+
     @property
     def state_bytes_per_row(self) -> int:
         """Bytes of one sequence's state over the layers (0: a K/V cache)."""
@@ -1193,6 +1419,9 @@ class KVCacheSpec:
         """Zeroed single-layer k/v dict: (B, KV, cache_d, S) [+ scales]."""
         if self.state is not None:
             return self._state_cache((batch_size,))
+        if self.latent:
+            return {"c": jnp.zeros((batch_size, self.latent,
+                                    self.max_seq_len), self.dtype)}
         shape = (batch_size, self.kv_heads, self.cache_d, self.max_seq_len)
         cache = {"k": jnp.zeros(shape, self.dtype),
                  "v": jnp.zeros(shape, self.dtype)}
@@ -1211,6 +1440,10 @@ class KVCacheSpec:
         if self.state is not None:
             return dict(self._state_cache((L, batch_size)),
                         index=jnp.zeros((batch_size,), jnp.int32))
+        if self.latent:
+            return {"c": jnp.zeros((L, batch_size, self.latent,
+                                    self.max_seq_len), self.dtype),
+                    "index": jnp.zeros((batch_size,), jnp.int32)}
         shape = (L, batch_size, self.kv_heads, self.cache_d,
                  self.max_seq_len)
         cache = {"k": jnp.zeros(shape, self.dtype),
@@ -1238,6 +1471,9 @@ class KVCacheSpec:
         if self.state is not None:
             raise ValueError("a recurrent state has no positions to page")
         lanes = page_lanes(page_size)
+        if self.latent:
+            return {"c": jnp.zeros((self.n_layer, num_pages, self.latent,
+                                    lanes), self.dtype)}
         if self.groups is not None:
             # one stacked leaf a group: ``num_pages`` pages for the full
             # layers, ``window_pages`` for the window layers
@@ -1292,6 +1528,12 @@ class KVCacheSpec:
         ps = self.max_seq_len // max_pages
         flat = table.reshape(-1)
         out = {}
+        if self.latent:
+            leaf = paged["c"]                       # (L, P, W, lanes)
+            g = jnp.take(leaf, flat, axis=1, mode="clip")[..., :ps]
+            g = g.reshape(leaf.shape[0], B, max_pages, self.latent, ps)
+            return {"c": g.transpose(0, 1, 3, 2, 4).reshape(
+                leaf.shape[0], B, self.latent, max_pages * ps)}
         for key in ("k", "v"):
             leaf = paged[key]                       # (L, P, KV, cd, lanes)
             L, _, KV, cd, _ = leaf.shape
@@ -1321,7 +1563,8 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
                        head_dim=cfg.head_dim, cache_d=cache_d,
                        dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
                        quantized=cfg.kv_cache_quant, packed=packed,
-                       groups=kv_cache_groups(cfg), state=state)
+                       groups=kv_cache_groups(cfg), state=state,
+                       latent=cfg.latent)
 
 
 def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:
@@ -1364,6 +1607,16 @@ class _CacheStore(nn.Module):
             values = {"s": leaf.value}
             if new_values is not None:
                 leaf.value = new_values["s"]
+                cidx.value = new_index
+            return values, cidx.value
+        if cfg.latent:
+            # one row a token that every head reads, and no k / v
+            leaf = self.variable(
+                "cache", "c", jnp.zeros,
+                (L, batch_size, cfg.latent, cfg.max_seq_len), cfg.dtype)
+            values = {"c": leaf.value}
+            if new_values is not None:
+                leaf.value = new_values["c"]
                 cidx.value = new_index
             return values, cidx.value
         cache_dtype, cache_d, _ = kv_cache_spec(cfg)
@@ -1421,20 +1674,28 @@ class TransformerLM(nn.Module):
                                       name="embed_pos")
         if cfg.embed_layernorm:
             self.embed_ln = _norm(cfg, "embed_ln")
-        self.blocks = nn.scan(
-            _ScanBlock,
-            variable_axes={"params": 0},
-            split_rngs={"params": True, "dropout": True},
-            length=cfg.n_layer,
-            in_axes=(nn.broadcast,) * (4 if cfg.n_experts else 3),
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, name="blocks")
+        def scan(config, length, name):
+            return nn.scan(
+                _ScanBlock,
+                variable_axes={"params": 0},
+                split_rngs={"params": True, "dropout": True},
+                length=length,
+                in_axes=(nn.broadcast,) * (4 if config.n_experts else 3),
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )(config, name=name)
+
+        if cfg.first_k_dense:
+            # the leading layers with a plain FFN: a scan of their own in
+            # front, the carry (cache, layer counter) running through both
+            self.dense_blocks = scan(cfg.dense_layers(), cfg.first_k_dense,
+                                     "dense_blocks")
+        self.blocks = scan(cfg, cfg.n_layer - cfg.first_k_dense, "blocks")
         if cfg.n_experts:
             from ..moe.routed_ffn import ExpertLeaves
 
-            self.experts = ExpertLeaves(cfg.n_layer, cfg.n_experts,
-                                        cfg.n_embd, cfg.ffn_width,
-                                        name="experts")
+            self.experts = ExpertLeaves(cfg.n_layer - cfg.first_k_dense,
+                                        cfg.n_experts, cfg.n_embd,
+                                        cfg.ffn_width, name="experts")
         self.cache_store = _CacheStore(cfg, name="cache_store")
         self.ln_f = _norm(cfg, "ln_f")
         if not cfg.tie_word_embeddings:
@@ -1479,6 +1740,9 @@ class TransformerLM(nn.Module):
                     cache["valid"] = jnp.broadcast_to(
                         jnp.asarray(valid_len, jnp.int32), (B,))
             carry = (x, cache, start, jnp.zeros((), jnp.int32))
+            if cfg.first_k_dense:
+                carry, _ = self.dense_blocks(carry, decode, deterministic,
+                                             block_hint)
             (x, cache, _, _), stats = self.blocks(
                 carry, decode, deterministic, block_hint, *more)
             if stats and self.is_mutable_collection("stats"):
@@ -1496,6 +1760,13 @@ class TransformerLM(nn.Module):
         else:
             carry = (x, None, jnp.zeros((), jnp.int32),
                      jnp.zeros((), jnp.int32))
+            if cfg.first_k_dense:
+                # (a scan without a cache counts layers only where its
+                # configuration reads the counter: set it)
+                (x, *_), _ = self.dense_blocks(carry, decode, deterministic,
+                                               block_hint)
+                carry = (x, None, carry[2],
+                         jnp.full((), cfg.first_k_dense, jnp.int32))
             (x, _, _, _), _ = self.blocks(carry, decode, deterministic,
                                           block_hint, *more)
         x = self.ln_f(x)

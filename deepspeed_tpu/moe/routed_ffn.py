@@ -12,6 +12,14 @@ benchmark serves), optionally renormalised over the chosen
     S = top_k(p, k);  w_e = p_e / sum_{e' in S} p_e'      (or p_e)
     y = sum_{e in S} w_e * down_e( silu(gate_e h) * up_e h )
 
+and the sigmoid form (``scoring="sigmoid"``) whose choice is ordered by a
+learned bias that the weights do not see, with a shared expert beside the
+routed sum::
+
+    s = sigmoid(h @ Wr)            float32
+    S = top_k(s + b, k);  w_e = f * s_e / (sum_{e' in S} s_e' + 1e-20)
+    y = sum_{e in S} w_e * down_e( silu(gate_e h) * up_e h ) + shared(h)
+
 **Rows grouped by expert.** The ``N * k`` (token, expert) assignments are
 sorted by expert and laid out in tiles of :data:`ROW_TILE` rows, each
 group padded to whole tiles, so a tile belongs to ONE expert
@@ -35,7 +43,7 @@ double-buffered: 16.5 MB), which is why the calls raise
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -55,13 +63,31 @@ ROW_TILE = 16
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
-def route(h: jax.Array, router: jax.Array, k: int, norm_topk_prob: bool
-          ) -> Tuple[jax.Array, jax.Array]:
+def route(h: jax.Array, router: jax.Array, k: int, norm_topk_prob: bool,
+          scoring: str = "softmax", bias: Optional[jax.Array] = None,
+          scaling: float = 1.0):
     """``(weights (N, k) float32, experts (N, k) int32)`` of ``h`` (N, C)
     under the router matrix (C, E): softmax in float32, the ``k`` largest,
-    renormalised over the chosen when ``norm_topk_prob``."""
+    renormalised over the chosen when ``norm_topk_prob``.
+
+    ``scoring="sigmoid"``: the scores are sigmoids, the chosen are the
+    ``k`` largest of ``score + bias`` (E,), the weights are the UNBIASED
+    scores of the chosen (renormalised likewise, over ``sum + 1e-20``)
+    times ``scaling``; a third value comes back, how many assignments the
+    bias moved: those whose expert is not among the ``k`` largest unbiased
+    scores."""
     logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, e = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, e, axis=-1)
+        if norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        _, unbiased = jax.lax.top_k(s, k)
+        moved = jnp.sum(~jnp.any(e[:, :, None] == unbiased[:, None, :], -1),
+                        dtype=jnp.int32)
+        return w * scaling, e.astype(jnp.int32), moved
     p = jax.nn.softmax(logits, axis=-1)
     w, e = jax.lax.top_k(p, k)
     if norm_topk_prob:
@@ -153,13 +179,16 @@ def _expert_call(kernel, name: str, rows: jax.Array, leaves, layer,
 
 def routed_ffn(h: jax.Array, router: jax.Array, gate: jax.Array,
                up: jax.Array, down: jax.Array, layer, *, k: int,
-               norm_topk_prob: bool, tile: int = ROW_TILE):
+               norm_topk_prob: bool, tile: int = ROW_TILE,
+               **scoring):
     """``(y (N, C), stats (3,) int32)``: the routed FFN of ``h`` (N, C)
     with layer ``layer`` (traced) of the stacked expert leaves ``gate`` /
     ``up`` (L, E, C, F) and ``down`` (L, E, F, C). ``stats`` =
-    (assignments, experts that a row chose, rows of the fullest expert)."""
+    (assignments, experts that a row chose, rows of the fullest expert)
+    and, under sigmoid scoring (``scoring``: :func:`route`'s ``scoring``,
+    ``bias``, ``scaling``), a fourth: assignments the bias moved."""
     E = gate.shape[1]
-    w, experts = route(h, router, k, norm_topk_prob)
+    w, experts, *moved = route(h, router, k, norm_topk_prob, **scoring)
     groups = group_rows(experts, E, tile)
     dtype = gate.dtype
     x = h.astype(dtype)[groups.token_of]                    # (rows, C)
@@ -171,35 +200,37 @@ def routed_ffn(h: jax.Array, router: jax.Array, gate: jax.Array,
     y = jnp.sum(y[groups.row_of] * w[..., None], axis=1)
     stats = jnp.stack([jnp.asarray(experts.size, jnp.int32),
                        jnp.sum(groups.counts > 0, dtype=jnp.int32),
-                       jnp.max(groups.counts)])
+                       jnp.max(groups.counts), *moved])
     return y.astype(h.dtype), stats
 
 
 #: what :func:`call_stats` holds, in order
 CALL_STATS = ("assignments", "experts_touched", "layer_calls", "load_max",
-              "load_max_over_mean")
+              "load_max_over_mean", "bias_reordered")
 
 
 def call_stats(layer_stats: jax.Array, n_experts: int) -> jax.Array:
     """One model call's counts from its layers' ``stats`` (L, 3), as
     float32 in the order of :data:`CALL_STATS`: assignments and experts
     touched summed over the layers, the layers, the rows of the fullest
-    expert of any layer, and those rows over a layer's mean rows an
-    expert (1 is perfectly even)."""
+    expert of any layer, those rows over a layer's mean rows an
+    expert (1 is perfectly even) and, where the layers counted them
+    (sigmoid scoring), the assignments a bias moved."""
     assignments, touched = jnp.sum(layer_stats[:, :2], axis=0)
     load_max = jnp.max(layer_stats[:, 2])
     calls = layer_stats.shape[0]
     mean = jnp.maximum(assignments, 1) / (calls * n_experts)
     return jnp.stack([assignments, touched, calls, load_max,
-                      load_max / mean]).astype(jnp.float32)
+                      load_max / mean, *jnp.sum(layer_stats[:, 3:], axis=0)]
+                     ).astype(jnp.float32)
 
 
 def routed_ffn_reference(h, router, gate, up, down, *, k: int,
-                         norm_topk_prob: bool):
+                         norm_topk_prob: bool, **scoring):
     """The same layer as a plain sum over experts (every expert computed
     for every token, the unchosen weighted 0): what the tests hold the
     kernels to. ``gate`` / ``up`` (E, C, F), ``down`` (E, F, C)."""
-    w, experts = route(h, router, k, norm_topk_prob)
+    w, experts, *_ = route(h, router, k, norm_topk_prob, **scoring)
     E = gate.shape[0]
     dense_w = jnp.zeros((h.shape[0], E), jnp.float32).at[
         jnp.arange(h.shape[0])[:, None], experts].add(w)
@@ -217,6 +248,19 @@ def _expert_init(key, shape, dtype=jnp.float32):
     ``nn.Dense`` draws a kernel; layers and experts are batch dimensions."""
     return jax.nn.initializers.lecun_normal(
         in_axis=-2, out_axis=-1, batch_axis=(0, 1))(key, shape, dtype)
+
+
+#: spread of the seeded ``router_bias``. A trained bias balances the
+#: experts' load and is small beside the scores' spread; a zero one would
+#: leave "ordered by score + bias" equal to "ordered by score", and nothing
+#: that compares outputs could see the rule broken. 0.05 beside sigmoid
+#: scores of a unit-normal logit (spread ~0.2) moves the choice for a few
+#: assignments in a hundred (``moe_bias_reordered``)
+ROUTER_BIAS_STD = 0.05
+
+
+def _bias_init(key, shape, dtype=jnp.float32):
+    return ROUTER_BIAS_STD * jax.random.normal(key, shape, dtype)
 
 
 class ExpertLeaves(nn.Module):
@@ -248,14 +292,36 @@ class RoutedFFN(nn.Module):
     n_experts: int
     experts_per_token: int
     norm_topk_prob: bool
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    shared_width: int = 0       # > 0: a gated FFN of this width for every
+    # token, added to the routed sum
+    dtype: Any = jnp.bfloat16   # the shared FFN's
 
     @nn.compact
     def __call__(self, x, experts, layer):
         B, T, C = x.shape
         router = self.param("router", nn.initializers.lecun_normal(),
                             (C, self.n_experts))
+        scoring = {}
+        if self.scoring_func == "sigmoid":
+            scoring = dict(
+                scoring=self.scoring_func,
+                scaling=self.routed_scaling_factor,
+                bias=self.param("router_bias", _bias_init,
+                                (self.n_experts,)))
         y, stats = routed_ffn(
             x.reshape(B * T, C), router, experts["gate_proj"],
             experts["up_proj"], experts["down_proj"], layer,
-            k=self.experts_per_token, norm_topk_prob=self.norm_topk_prob)
-        return y.reshape(B, T, C), stats
+            k=self.experts_per_token, norm_topk_prob=self.norm_topk_prob,
+            **scoring)
+        y = y.reshape(B, T, C)
+        if self.shared_width:
+            def dense(width, name):
+                return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                                name=name)
+
+            y = y + dense(C, "shared_down_proj")(
+                jax.nn.silu(dense(self.shared_width, "shared_gate_proj")(x))
+                * dense(self.shared_width, "shared_up_proj")(x))
+        return y, stats

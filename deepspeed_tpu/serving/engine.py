@@ -314,6 +314,31 @@ class ServingEngine:
                 if given:
                     raise ValueError(f"{what} does not compose with a "
                                      f"recurrent state yet: {why}")
+        # latent attention (one cached row a token, KVCacheSpec.latent):
+        # the bytes of a token over the layers, and what its cache does
+        # not compose with yet, by mechanism (ROADMAP.md, Reach)
+        self._latent_token_bytes = int(getattr(spec, "latent", 0)) \
+            * np.dtype(spec.dtype).itemsize * int(spec.n_layer)
+        if self._latent_token_bytes:
+            mesh = getattr(engine, "mesh", None)
+            refused = [
+                (spec_decode, "spec_decode",
+                 "the latent read takes one query row a slot or one slot's "
+                 "chunk; a verify step's K + 1 rows of every slot, each "
+                 "with its own causal limit, have no program yet"),
+                (role != "both", "prefill/decode roles",
+                 "a handoff ships K/V pages; a pool of latent pages has "
+                 "not been driven through one"),
+                (mesh is not None and mesh.shape.get("model", 1) > 1,
+                 "tensor-parallel serving",
+                 "every head reads the one cached row, so the latent leaf "
+                 "has no placement on the model axis and the read is not "
+                 "wrapped for a mesh"),
+            ]
+            for given, what, why in refused:
+                if given:
+                    raise ValueError(f"{what} does not compose with latent "
+                                     f"attention's cache yet: {why}")
         if paged_kv:
             knobs = dict(paged_kv) if isinstance(paged_kv, dict) else {}
             page_size = knobs.pop("page_size", None)
@@ -498,6 +523,11 @@ class ServingEngine:
             # pool-internal events (CoW copies, trie evictions) land in
             # the same registry as the engine-side paging/* series
             self.pool.registry = self.registry
+            if self._latent_token_bytes:
+                self.registry.add_collector(
+                    lambda: self.registry.gauge(
+                        "serving/latent_pages_mapped").set(float(
+                            self.pool.num_pages - self.pool.free_page_count)))
         # -- resilience ------------------------------------------------
         if deadline_default_ms is not None and deadline_default_ms <= 0:
             raise ValueError(f"deadline_default_ms must be > 0, got "
@@ -1229,6 +1259,26 @@ class ServingEngine:
         d["state_rows"] = d.get("state_rows", 0) + rows
         d["state_bytes"] = 2 * self._state_row_bytes * d["state_rows"]
 
+    def _note_latent(self, sp, read: int, written: int) -> None:
+        """``latent_tokens_read`` / ``latent_rows_written`` on a dispatch's
+        span, for a model with latent attention: the cached rows that the
+        program's real query rows see (a running slot's, up to and with
+        its own token; a chunk's slot, up to the chunk's last real token)
+        and the rows it adds to the cache, a layer, from the host's own
+        positions (no device read). What rides along (a free slot, one in
+        mid-prefill, a chunk's padding) is not counted: nobody reads its
+        result. The step's span gathers both, with the bytes the read
+        stands for over the layers."""
+        if not self._latent_token_bytes:
+            return
+        sp.set(latent_tokens_read=int(read), latent_rows_written=int(written))
+        d = self._dispatched
+        d["latent_tokens_read"] = d.get("latent_tokens_read", 0) + int(read)
+        d["latent_rows_written"] = d.get("latent_rows_written", 0) \
+            + int(written)
+        d["latent_bytes_read"] = \
+            self._latent_token_bytes * d["latent_tokens_read"]
+
     def _note_admit(self, rows: int, padded_tokens: int) -> None:
         """An admission program of this step: requests seated, and the
         tokens it computes (rows x bucket width, padding included)."""
@@ -1282,6 +1332,7 @@ class ServingEngine:
             with self._phase("prepare", "serving/admit", rid=req.request_id,
                              tokens=T, width=width) as sp:
                 self._note_state_rows(sp, 1)
+                self._note_latent(sp, 0, T)
                 logits, pre_cache = self._prefill_at(ids, np.int32(T - 1))
                 self.pool.admit(pre_cache, slot, T)
                 if self._paged:
@@ -1559,6 +1610,8 @@ class ServingEngine:
             with self._phase("prepare", "serving/prefill_batch", n=n,
                              width=width, batch=nB) as sp:
                 self._note_state_rows(sp, n)
+                # (a prompt admitted whole attends to its own fresh rows)
+                self._note_latent(sp, 0, int(lengths.sum()))
                 logits, pre_cache = self._prefill_at(ids, last_pos)
                 self.pool.admit_rows(pre_cache, slots, lengths)
                 if self._paged:
@@ -1645,6 +1698,7 @@ class ServingEngine:
         with self._phase("prepare", "serving/prefill_chunk",
                          rid=req.request_id, pos=pos, len=L) as sp:
             self._note_state_rows(sp, 1)
+            self._note_latent(sp, pos + L, L)
             if self._paged:
                 logits = self.pool.run_prefill_chunk(
                     self.engine, ids, slot, pos, L, L - 1)
@@ -2383,6 +2437,11 @@ class ServingEngine:
         with self._phase("prepare", "serving/decode",
                          live=len(running)) as sp:
             self._note_state_rows(sp, len(running))
+            if self._latent_token_bytes:
+                slots = [slot for slot, _ in running]
+                self._note_latent(
+                    sp, int(self.pool.starts[slots].sum()) + len(slots),
+                    len(slots))
             if self._paged:
                 logits = self.pool.run_decode(eng, self._cur_dev)
                 # counted after the dispatch, from a mirror the dispatch

@@ -81,6 +81,17 @@ kinds has exactly the single group it always had. What a ring does not
 compose with yet refuses at construction: the prefix cache (what a hit
 means for pages that were recycled), cross-pool page transfer.
 
+Latent pages (PR 38): a model with latent attention
+(``KVCacheSpec.latent``) keeps ONE leaf, ``c`` ``(L, num_pages, W,
+lanes)``: a page is ``W`` stored rows (the normed latent and the shared
+rotary key, 576 at the served size) of ``page_size`` positions, one
+whole-tile block a layer, and there is no ``v``. A latent page is
+position-indexed like a K/V page, so the table, the free heap, the
+refcounts, the prefix trie, copy-on-write, preemption and the audit are
+the ones above; ``paged_write`` writes it in its scale-leaf form (one
+"head" of ``W`` rows) and the model's read is
+``ops/attention/latent_attention.py``.
+
 Sentinel convention: table entry ``num_pages`` means "unmapped" (the
 window group's: its own number of pages). The
 gather reads sentinel entries with a clip-mode take (arbitrary real
@@ -107,6 +118,12 @@ from ..inference.engine import pack_chunk_args, unpack_chunk_args
 from ..ops import backend
 from .prefix_cache import PrefixCache
 from .slot_pool import SlotPool
+
+
+# the leaves of a cache container that hold pages: K/V a head with the
+# scales of a quantized tier, or the one latent row a token (``c``,
+# ``KVCacheSpec.latent``: (L, num_pages, W, lanes), no ``v``)
+PAGE_LEAVES = ("k", "v", "k_scale", "v_scale", "c")
 
 
 class PagePoolExhausted(RuntimeError):
@@ -647,7 +664,7 @@ class PagedKVPool(SlotPool):
         cs = self.cache["cache_store"]
         return sum(int(np.prod(cs[k].shape)) * cs[k].dtype.itemsize
                    // self.num_pages
-                   for k in ("k", "v", "k_scale", "v_scale") if k in cs)
+                   for k in PAGE_LEAVES if k in cs)
 
     def import_pages(self, src_pool: "PagedKVPool",
                      src_page_ids: Sequence[int]) -> List[int]:
@@ -784,7 +801,7 @@ class PagedKVPool(SlotPool):
         page pair."""
         out = dict(cs)
         with jax.named_scope("copy"):
-            for key in ("k", "v", "k_scale", "v_scale"):
+            for key in PAGE_LEAVES:
                 if key not in cs:
                     continue
                 leaf = cs[key]
@@ -805,7 +822,7 @@ class PagedKVPool(SlotPool):
         Runs on the SOURCE pool's devices."""
         with jax.named_scope("gather"):
             return {key: jnp.take(src_cs[key], src_ids, axis=1, mode="clip")
-                    for key in ("k", "v", "k_scale", "v_scale")
+                    for key in PAGE_LEAVES
                     if key in src_cs}
 
     @staticmethod
@@ -816,7 +833,7 @@ class PagedKVPool(SlotPool):
         arrived via :meth:`_land_block`."""
         out = dict(dst_cs)
         with jax.named_scope("scatter"):
-            for key in ("k", "v", "k_scale", "v_scale"):
+            for key in PAGE_LEAVES:
                 if key not in dst_cs:
                     continue
                 out[key] = dst_cs[key].at[:, dst_ids].set(
@@ -908,7 +925,7 @@ class PagedKVPool(SlotPool):
 
         out = dict(pool)
         with jax.named_scope("scatter"):
-            for key in ("k", "v", "k_scale", "v_scale"):
+            for key in PAGE_LEAVES:
                 if key not in pool:
                     continue
                 if self.ring is None:

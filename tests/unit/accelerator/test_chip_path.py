@@ -327,6 +327,54 @@ def test_retention_kernels_compile_for_a_described_v5e_at_the_served_shape(
         compilation_cache.reset_cache()
 
 
+def test_latent_kernels_compile_for_a_described_v5e_at_the_served_shape(
+        compiled_kernels, described_v5e):
+    """The kernels PR 38 brought, through Mosaic at the widths of
+    ``perf/configs/moonlight-16b-a3b-mla.json``: the latent pool's leaf (7
+    layers x 3,072 pages of 576 stored rows x 128 positions, 3.17 GB)
+    written through ``paged_write``'s scale-leaf form and read by
+    ``mla_decode`` (64 slots, 16 heads' rows of one token) and ``mla_chunk``
+    (one slot, a chunk's 2,048 query-head rows in one call: ~22 MB of VMEM,
+    which passes only under the raised limit). The leaf goes in and comes
+    out in one buffer: no operation of the program copies or slices it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from deepspeed_tpu.ops.attention.latent_attention import latent_attention
+    from deepspeed_tpu.ops.attention.paged_attention import \
+        paged_write_columns
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=described_v5e)
+
+    L, P, W, ps, H, per_slot = 7, 3072, 576, 128, 16, 64
+    leaf = shape((L, P, W, ps))
+
+    def step(q, leaf, table, starts, layer, cols):
+        leaf = paged_write_columns(leaf, layer, cols, table, starts,
+                                   page_size=ps)
+        return latent_attention(q, leaf, table, starts, layer=layer,
+                                rank=512, scale=192 ** -0.5,
+                                page_size=ps), leaf
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for name, B, T in (("mla_decode", 64, 1), ("mla_chunk", 1, 128)):
+            compiled = jax.jit(step, donate_argnums=1).lower(
+                shape((B, T, H, W)), leaf, shape((B, per_slot), jnp.int32),
+                shape((B,), jnp.int32), shape((), jnp.int32),
+                shape((B, W, T))).compile()
+            text = compiled.as_text()
+            assert name in text and "paged_write" in text
+            assert text.count("tpu_custom_call") >= 2
+            assert "may-alias" in text
+            # the leaf is 3.17 GB: a copy or a slice of it would show
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 # the lowered programs of a small paged server: what touches a pool leaf
 _LEAF_OPS = ("dynamic_slice", "dynamic_update_slice", "scatter", "transpose",
              "gather", "pad", "concatenate", "convert", "select")
