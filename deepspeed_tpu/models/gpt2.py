@@ -9,7 +9,9 @@ Architecture notes (not a port — reference has no JAX model zoo):
   compiled block body (fast compiles at depth), and under ZeRO-3 the
   per-layer slices of the stacked params are gathered layer-by-layer inside
   the scan, reproducing the reference's module-granular gather/release
-  (stage3.py fetch/release hooks) as a compiler-scheduled pipeline.
+  (stage3.py fetch/release hooks) as a compiler-scheduled pipeline
+  (``_ScanBody`` asks the engine's ZeRO policy for it:
+  ``ZeroShardingPolicy.gather_at_use_site``).
 * ``remat`` enables activation checkpointing around each block
   (≅ runtime/activation_checkpointing/checkpointing.py:708).
 * Tensor-parallel sharding is declared, not coded: ``gpt2_sharding_rules``
@@ -20,6 +22,7 @@ Architecture notes (not a port — reference has no JAX model zoo):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -27,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import backend
-from ..parallel.mesh import MODEL_AXIS
+from ..parallel.mesh import MODEL_AXIS, get_zero_policy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -329,6 +332,18 @@ class _ScanBody(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic):
         block_cls = Block
+        zero = get_zero_policy()
+        if zero is not None and zero.gathers_at_use_site \
+                and not self.is_initializing():
+            # ZeRO-3: this layer's weights are gathered HERE and the
+            # activation stays split over the batch (zero/policy.py says
+            # why it has to be said); inside the remat below, so that the
+            # backward gathers again and no gathered weight is a residual
+            block_cls = nn.map_variables(
+                Block, "params", trans_in_fn=functools.partial(
+                    zero.gather_at_use_site,
+                    path="/".join(self.path + ("block",)),
+                    layers=self.config.n_layer))
         if self.config.remat:
             policy = None
             if self.config.remat_policy == "dots_plain":
@@ -350,7 +365,7 @@ class _ScanBody(nn.Module):
                     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
                     jax.checkpoint_policies.save_only_these_names(
                         *ATTN_SAVE_NAMES, *LN_SAVE_NAMES))
-            block_cls = nn.remat(Block, prevent_cse=False,
+            block_cls = nn.remat(block_cls, prevent_cse=False,
                                  static_argnums=(2,), policy=policy)
         x = block_cls(self.config, name="block")(x, deterministic)
         return x, None
@@ -365,6 +380,10 @@ class GPT2LMHeadModel(nn.Module):
     """
 
     config: GPT2Config
+    # the stacked parameters whose layer slices `_ScanBody` hands to the
+    # ZeRO policy's gather_at_use_site (the engine counts them as it places
+    # the state)
+    use_site_gathered = ("blocks/block",)
 
     def setup(self):
         cfg = self.config

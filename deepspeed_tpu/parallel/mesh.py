@@ -25,6 +25,7 @@ Axis layout (major → minor): ``pipe, data, expert, seq, model``.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import os
@@ -156,6 +157,8 @@ class _GroupsState:
         self.mesh = None
         self.mesh_config: Optional[MeshConfig] = None
         self.topology: Optional["ProcessTopology"] = None
+        # the ZeroShardingPolicy of the engine whose program is being traced
+        self.zero_policy = None
 
 
 _state = _GroupsState()
@@ -204,6 +207,25 @@ def reset_mesh() -> None:
     _state.mesh = None
     _state.mesh_config = None
     _state.topology = None
+    _state.zero_policy = None
+
+
+@contextlib.contextmanager
+def zero_policy_scope(policy):
+    """The engine traces its model under this, so that a model's layer scan
+    can ask the policy to gather a layer's weights where they are used
+    (``ZeroShardingPolicy.gather_at_use_site``). It is set only while a
+    program traces: a model applied with no engine finds none."""
+    previous, _state.zero_policy = _state.zero_policy, policy
+    try:
+        yield
+    finally:
+        _state.zero_policy = previous
+
+
+def get_zero_policy():
+    """The policy of the engine that is tracing, or None."""
+    return _state.zero_policy
 
 
 def _axis_size(axis: str) -> int:

@@ -23,6 +23,7 @@ re-architected TPU-first:
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Any, Callable, Dict, Iterator, Optional, Union
@@ -130,6 +131,9 @@ class DeepSpeedEngine:
         # --- zero policy --------------------------------------------------
         self.zero_config = self._config.zero_optimization
         self.policy = ZeroShardingPolicy(self.zero_config, self.mesh, sharding_rules)
+        # every program of this engine traces the model under its policy, so
+        # a layer scan can gather its weights where they are used (stage 3)
+        self._loss_fn = self._under_policy(self._loss_fn)
 
         # --- optimizer-state offload (ZeRO-Offload / Infinity) ------------
         from .zero.offload_config import OffloadDeviceEnum
@@ -363,6 +367,14 @@ class DeepSpeedEngine:
             return model
         raise ValueError(f"cannot derive a loss function from model {type(model)}")
 
+    def _under_policy(self, loss_fn: LossFn) -> LossFn:
+        @functools.wraps(loss_fn)
+        def traced(*args, **kwargs):
+            with mesh_mod.zero_policy_scope(self.policy):
+                return loss_fn(*args, **kwargs)
+
+        return traced
+
     def _init_params_from_batch(self, batch) -> Any:
         if self._params_host is not None:
             return self._params_host
@@ -392,7 +404,9 @@ class DeepSpeedEngine:
             self._place_state(params_host)
             span.set(parameters=self._num_params, bytes_placed=sum(
                 getattr(x, "nbytes", 0)
-                for x in jax.tree_util.tree_leaves(self.state)))
+                for x in jax.tree_util.tree_leaves(self.state)),
+                use_site_gathers=self.policy.use_site_gathers,
+                use_site_gather_bytes=self.policy.use_site_gather_bytes)
 
     def _place_state(self, params_host) -> None:
         mesh = self.mesh
@@ -506,6 +520,8 @@ class DeepSpeedEngine:
         self._shardings = shardings
         self._num_params = count_parameters(params)
         self._last_grad_norm = None
+        policy.count_use_site_gathers(
+            state["params"], getattr(self.module, "use_site_gathered", ()))
         self._build_jits()
         log_dist(f"engine state built: {self._num_params / 1e6:.1f}M params, "
                  f"{policy.describe()}", ranks=[0])

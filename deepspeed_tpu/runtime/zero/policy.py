@@ -18,12 +18,30 @@ consumed by ``jax.jit``:
   Inside a single fused step this only changes peak memory under gradient
   accumulation — which is precisely its role in the reference.
 * **stage 3** (+parameter partitioning): compute params are *persistently*
-  sharded over the ZeRO axes; XLA all-gathers each param at its use site and
-  frees it after (the gather/release hook pair, parameter_offload.py:370/374),
-  with prefetch overlap handled by XLA's scheduler rather than a recorded
-  trace. Small params stay replicated below
+  sharded over the ZeRO axes and gathered where they are used, a layer at a
+  time, then freed (the gather/release hook pair,
+  parameter_offload.py:370/374), with prefetch overlap handled by XLA's
+  scheduler rather than a recorded trace. Small params stay replicated below
   ``stage3_param_persistence_threshold`` (stage3 persistent-param logic,
   parameter_offload.py:339).
+
+  The gather has to be SAID at the use site. A product ``x @ W`` inside the
+  layer scan meets an activation split over ``data`` (its batch) and a
+  weight split over the SAME axis (a feature dimension), and nothing in the
+  shardings of the step's arguments says which operand gives way: for
+  GPT-2 XL's two MLP matmuls the partitioner resharded the 105 MB
+  activation (a synchronous all-to-all, which depends on the activation and
+  cannot be prefetched) rather than gather a 20 MB weight. So a model's scan
+  body hands its layer's slice to ``gather_at_use_site``, which constrains
+  every ZeRO-sharded leaf to its *use-site* spec: the ZeRO axes taken out,
+  the tensor-parallel axes kept. Inside ``remat`` the backward gathers
+  again and no gathered weight is kept. The constraint's transpose asks for
+  the weight's cotangent in the same layout; the compiler fuses that
+  all-reduce with the slice ``constrain_grads`` takes of it into a
+  reduce-scatter. (A ``custom_vjp`` that constrained the cotangent to the
+  gradient's spec instead compiled to rings of collective-permutes around
+  quartered dW matmuls: more bytes and 5 % slower on the chip, PERF.md §6
+  PR 39.)
 
 Tensor-parallel (model-axis) specs compose: the ZeRO axes shard a dimension
 not already taken by TP.
@@ -31,6 +49,7 @@ not already taken by TP.
 
 from __future__ import annotations
 
+import functools
 import math
 import re
 from typing import Any, Optional, Sequence, Tuple
@@ -153,6 +172,9 @@ class ZeroShardingPolicy:
         self.mesh = mesh
         self.rules = sharding_rules or ShardingRules()
         self.stage = int(zero_config.stage)
+        # what count_use_site_gathers found as the state was placed
+        self.use_site_gathers = 0
+        self.use_site_gather_bytes = 0
 
     # --- per-leaf specs ---------------------------------------------------
     def tp_spec(self, path: str) -> Optional[PartitionSpec]:
@@ -179,8 +201,65 @@ class ZeroShardingPolicy:
     def grad_spec(self, path: str, shape) -> PartitionSpec:
         if self.stage >= ZeroStageEnum.gradients:
             return self.master_spec(path, shape)
+        return self.use_site_spec(path, shape)
+
+    # --- stage 3: a layer's weights gathered where they are used ----------
+    def use_site_spec(self, path: str, shape) -> PartitionSpec:
+        """The leaf's spec where it is computed with: the ZeRO axes taken out
+        of ``param_spec``, the tensor-parallel axes kept."""
         return zero_shard_spec(shape, self.mesh, stage_applies=False,
                                tp_spec=self.tp_spec(path))
+
+    def _layer_use_spec(self, path: str, stacked_shape):
+        """The use-site spec of ONE layer's slice of a stacked leaf, or None
+        where the slice is not ZeRO-sharded (below stage 3, a ZeRO world of
+        1, a leaf under the persistence threshold, a leaf split over its
+        layer dimension): such a leaf is left alone."""
+        use = self.use_site_spec(path, stacked_shape)[1:]
+        if self.param_spec(path, stacked_shape)[1:] == use:
+            return None
+        return PartitionSpec(*use)
+
+    @property
+    def gathers_at_use_site(self) -> bool:
+        return self.stage >= ZeroStageEnum.weights and \
+            _zero_world(self.mesh) > 1
+
+    def gather_at_use_site(self, layer_params, path: str, layers: int):
+        """Called by a model's scan body, inside its ``remat``, on one
+        layer's slice of the stacked parameters at ``path`` (``layers`` is
+        the scan's length, the stacked leaves' leading dimension): each
+        ZeRO-sharded leaf is constrained to its use-site spec. Where nothing
+        is ZeRO-sharded the argument comes back as it is and nothing is
+        traced."""
+        if not self.gathers_at_use_site:
+            return layer_params
+
+        def gather(leaf_path, x):
+            use = self._layer_use_spec(f"{path}/{_path_str(leaf_path)}",
+                                       (layers,) + np.shape(x))
+            if use is None:
+                return x
+            return jax.lax.with_sharding_constraint(
+                x, NamedSharding(self.mesh, use))
+
+        return jax.tree_util.tree_map_with_path(gather, layer_params)
+
+    def count_use_site_gathers(self, params, stacked_paths) -> None:
+        """As the state is placed: the leaves under ``stacked_paths`` (what
+        the model says its scan body hands to ``gather_at_use_site``) that
+        will be gathered, and the bytes one pass over the layers gathers
+        (every layer's slice whole, once)."""
+        self.use_site_gathers = self.use_site_gather_bytes = 0
+        for stacked in stacked_paths:
+            subtree = functools.reduce(lambda t, k: t[k], stacked.split("/"),
+                                       params)
+            for leaf_path, x in jax.tree_util.tree_flatten_with_path(
+                    subtree)[0]:
+                if self._layer_use_spec(f"{stacked}/{_path_str(leaf_path)}",
+                                        np.shape(x)) is not None:
+                    self.use_site_gathers += 1
+                    self.use_site_gather_bytes += int(x.nbytes)
 
     # --- pytree-level shardings ------------------------------------------
     def _tree_shardings(self, tree, spec_fn):
@@ -216,4 +295,6 @@ class ZeroShardingPolicy:
 
     def describe(self) -> str:
         return (f"ZeroShardingPolicy(stage={self.stage}, "
-                f"zero_world={_zero_world(self.mesh)})")
+                f"zero_world={_zero_world(self.mesh)}, "
+                f"use_site_gathers={self.use_site_gathers}, "
+                f"use_site_gather_bytes={self.use_site_gather_bytes})")
