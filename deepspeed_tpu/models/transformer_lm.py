@@ -535,6 +535,43 @@ def alibi_slopes(n_head: int) -> jnp.ndarray:
     return jnp.asarray(base + extra, jnp.float32)
 
 
+def _settled(*projected):
+    """The results of an attention block's input projections, as values
+    the compiler may not look through (``optimization_barrier``).
+
+    What it prevents: the cache write and the read kernels take their
+    operands head_dim-major, and XLA's layout assignment carried that wish
+    back through ``reshape``, the rotary and the projection's dot onto the
+    WEIGHT. It then computed ``W^T x^T``, and to have ``W^T`` it sliced the
+    layer's matrix out of the stacked leaf into a buffer of its own and
+    copied that into the other layout, a layer a projection a step (8 MB
+    twice where the result is 256 KB: ``constant_dynamic-slice_fusion`` and
+    ``copy`` over ``bf16[1, C, C']`` in a decode or chunk program, a
+    quarter of the chat cell's busy device time, ledger PR 39). Behind the
+    barrier the dot is an ordinary one with the slice of the stacked leaf
+    fused into it, as ``o_proj``'s and the MLP's are, and the layout the
+    kernels want is made on the small result. The values are what they
+    were.
+
+    How to see it come back: ``tests/unit/accelerator/test_chip_path.py``
+    ``test_attention_projections_read_the_stacked_leaf`` compiles the step
+    programs for a described v5e and looks for those two instructions."""
+    return jax.lax.optimization_barrier(projected)
+
+
+def _project_qkv(cfg: TransformerConfig, x):
+    """``q_proj``, ``k_proj``, ``v_proj`` of ``x`` (B, T, C), settled, as
+    (B, T, heads, head_dim). Called inside an attention module's
+    ``__call__``: the three ``Dense`` are that module's."""
+    B, T, _ = x.shape
+    H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    q, k, v = _settled(*(
+        _dense(cfg, heads * D, use_bias=cfg.qkv_bias, name=name)(x)
+        for heads, name in ((H, "q_proj"), (KV, "k_proj"), (KV, "v_proj"))))
+    return (q.reshape(B, T, H, D), k.reshape(B, T, KV, D),
+            v.reshape(B, T, KV, D))
+
+
 def _store_columns(buf, new, start):
     """Write the new positions-minor columns at each row's offset: one
     DUS for scalar start; per-slot (B,) starts vmap the DUS over the batch
@@ -742,11 +779,7 @@ class CachedAttention(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
-        dense = lambda feats, name: _dense(  # noqa: E731
-            cfg, feats, use_bias=cfg.qkv_bias, name=name)
-        q = dense(H * D, "q_proj")(x).reshape(B, T, H, D)
-        k = dense(KV * D, "k_proj")(x).reshape(B, T, KV, D)
-        v = dense(KV * D, "v_proj")(x).reshape(B, T, KV, D)
+        q, k, v = _project_qkv(cfg, x)
 
         kv_packed = kv_cache_spec(cfg)[2]
         if decode:
@@ -1011,11 +1044,7 @@ class PowerRetention(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
-        dense = lambda feats, name: _dense(  # noqa: E731
-            cfg, feats, use_bias=cfg.qkv_bias, name=name)
-        q = dense(H * D, "q_proj")(x).reshape(B, T, H, D)
-        k = dense(KV * D, "k_proj")(x).reshape(B, T, KV, D)
-        v = dense(KV * D, "v_proj")(x).reshape(B, T, KV, D)
+        q, k, v = _project_qkv(cfg, x)
         if cfg.qk_norm:
             q = nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
                            name="q_norm")(q)
@@ -1086,8 +1115,9 @@ class LatentAttention(nn.Module):
         H, R = cfg.n_head, cfg.kv_lora_rank
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
-        q = _dense(cfg, H * (dn + dr), use_bias=False,
-                   name="q_proj")(x).reshape(B, T, H, dn + dr)
+        q, = _settled(_dense(cfg, H * (dn + dr), use_bias=False,
+                             name="q_proj")(x))
+        q = q.reshape(B, T, H, dn + dr)
         ckr = _dense(cfg, R + dr, use_bias=False, name="kv_a_proj")(x)
         c = nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
                        name="kv_a_norm")(ckr[..., :R])

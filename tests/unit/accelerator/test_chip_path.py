@@ -10,6 +10,7 @@
     device is what ``chip_smoke.py`` itself establishes on the chip.
 """
 
+import contextlib
 import json
 import os
 import re
@@ -140,6 +141,22 @@ def described_v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def _compile_cache_off():
+    """A compile for a described chip cannot be read back from the
+    persistent cache and warns when it tries: off around such compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 def test_pool_kernels_compile_for_a_described_v5e_at_the_served_shape(
         compiled_kernels, described_v5e):
     """Mosaic itself, which the lowering above does not reach: it refused
@@ -150,7 +167,6 @@ def test_pool_kernels_compile_for_a_described_v5e_at_the_served_shape(
     tier; and XLA around it: stored in whole lane tiles the leaf goes in
     and comes out in one buffer, stored 64 wide it is copied whole to
     row-major and back."""
-    from jax.experimental.compilation_cache import compilation_cache
     from deepspeed_tpu.models.transformer_lm import page_lanes
     from deepspeed_tpu.ops.attention.paged_attention import (
         paged_decode_attention, paged_write_columns, paged_write_runs)
@@ -168,12 +184,7 @@ def test_pool_kernels_compile_for_a_described_v5e_at_the_served_shape(
                        donate_argnums=0).lower(leaf, layer, cols, table,
                                                starts).compile()
 
-    # a compile for a described chip cannot be read back from the
-    # persistent cache and warns when it tries
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compile_cache_off():
         for dtype, Dc in ((jnp.bfloat16, 128), (jnp.int8, 128),
                           (jnp.int32, 32)):
             leaf = shape((L, P, KV, Dc, lanes), dtype)
@@ -198,9 +209,6 @@ def test_pool_kernels_compile_for_a_described_v5e_at_the_served_shape(
         narrow = write(shape((L, P, KV, 128, ps), jnp.bfloat16),
                        shape((B, KV, 128, 8), jnp.bfloat16))
         assert narrow.memory_analysis().temp_size_in_bytes > 2 ** 30
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
 
 
 def test_mellum_kernels_compile_for_a_described_v5e_at_the_served_shape(
@@ -212,7 +220,6 @@ def test_mellum_kernels_compile_for_a_described_v5e_at_the_served_shape(
     chunk's 128 rows and a decode step's 64, with no copy of a leaf around
     them; and the window group's write and read (6 layers, 576 pages of
     128, a grid that may have no step)."""
-    from jax.experimental.compilation_cache import compilation_cache
     from deepspeed_tpu.moe.routed_ffn import routed_ffn
     from deepspeed_tpu.ops.attention.paged_attention import (
         paged_decode_attention, paged_write_columns)
@@ -222,10 +229,7 @@ def test_mellum_kernels_compile_for_a_described_v5e_at_the_served_shape(
 
     bf16 = jnp.bfloat16
     L, E, C, F = 8, 64, 2304, 896
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compile_cache_off():
         for rows in (128, 64):
             compiled = jax.jit(lambda h, r, g, u, d, li: routed_ffn(
                 h, r, g, u, d, li, k=8, norm_topk_prob=True)).lower(
@@ -253,9 +257,6 @@ def test_mellum_kernels_compile_for_a_described_v5e_at_the_served_shape(
             shape((B, KV, D, 1), bf16)).compile()
         assert compiled.as_text().count("tpu_custom_call") >= 2
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
 
 
 def test_retention_kernels_compile_for_a_described_v5e_at_the_served_shape(
@@ -269,7 +270,6 @@ def test_retention_kernels_compile_for_a_described_v5e_at_the_served_shape(
     wants whole 128-lane rows: both fail only here. The leaf goes in and
     comes out in one buffer: no operation of the program copies it. And a
     leaf small enough for the chip's fast memory stays in HBM."""
-    from jax.experimental.compilation_cache import compilation_cache
     from deepspeed_tpu.ops.attention import power_retention as pr
 
     def shape(dims, dtype=jnp.float32):
@@ -278,10 +278,7 @@ def test_retention_kernels_compile_for_a_described_v5e_at_the_served_shape(
     bf16, i32 = jnp.bfloat16, jnp.int32
     L, R, KV, H, d = 8, 16, 8, 40, 128
     leaf = shape((L, R, KV) + pr.state_shape(d))
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compile_cache_off():
         for name, fn, B, tokens in (("retention_decode", pr.retention_decode,
                                      R, ()),
                                     ("retention_chunk", pr.retention_chunk,
@@ -322,9 +319,6 @@ def test_retention_kernels_compile_for_a_described_v5e_at_the_served_shape(
         assert "S(1)" not in state_out, state_out
         assert '"input_memory_space_colors":[{"operand_index":"8",' \
                '"color":"0"' in call and '"output_memory_colors":["0"' in call
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
 
 
 def test_latent_kernels_compile_for_a_described_v5e_at_the_served_shape(
@@ -337,7 +331,6 @@ def test_latent_kernels_compile_for_a_described_v5e_at_the_served_shape(
     (one slot, a chunk's 2,048 query-head rows in one call: ~22 MB of VMEM,
     which passes only under the raised limit). The leaf goes in and comes
     out in one buffer: no operation of the program copies or slices it."""
-    from jax.experimental.compilation_cache import compilation_cache
     from deepspeed_tpu.ops.attention.latent_attention import latent_attention
     from deepspeed_tpu.ops.attention.paged_attention import \
         paged_write_columns
@@ -355,10 +348,7 @@ def test_latent_kernels_compile_for_a_described_v5e_at_the_served_shape(
                                 rank=512, scale=192 ** -0.5,
                                 page_size=ps), leaf
 
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compile_cache_off():
         for name, B, T in (("mla_decode", 64, 1), ("mla_chunk", 1, 128)):
             compiled = jax.jit(step, donate_argnums=1).lower(
                 shape((B, T, H, W)), leaf, shape((B, per_slot), jnp.int32),
@@ -370,9 +360,6 @@ def test_latent_kernels_compile_for_a_described_v5e_at_the_served_shape(
             assert "may-alias" in text
             # the leaf is 3.17 GB: a copy or a slice of it would show
             assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
 
 
 # the lowered programs of a small paged server: what touches a pool leaf
@@ -398,29 +385,36 @@ def _ops_on(lowered, shapes):
     return found
 
 
-def _small_paged_server(pages=6):
-    """A two-layer GPT-NeoX server over a page pool with the kernel on, and
-    the operands of its three step programs. ``max_seq_len`` is 384, a
-    width nothing else in the model has."""
+def _zero_engine(family, **widths):
+    """A bf16 ``TransformerLM`` of ``family`` over a vocabulary of 128 and
+    an inference engine on its parameters, all zero."""
     import deepspeed_tpu as ds
     from deepspeed_tpu.models.transformer_lm import (TransformerLM,
                                                      transformer_config)
-    from deepspeed_tpu.inference.engine import pack_chunk_args
-    from deepspeed_tpu.serving.paged_pool import PagedKVPool
 
-    cfg = transformer_config("gpt-neox", vocab_size=128, max_seq_len=384,
-                             n_embd=256, n_layer=2, n_head=2,
-                             dtype=jnp.bfloat16)
-    model = TransformerLM(cfg)
+    model = TransformerLM(transformer_config(
+        family, vocab_size=128, dtype=jnp.bfloat16, **widths))
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
                            jnp.zeros((1, 8), jnp.int32),
                            method=model.logits)["params"])
-    params = jax.tree_util.tree_map(
-        lambda s: jnp.zeros(s.shape, jnp.bfloat16), params)
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "bf16"})
+    engine = ds.init_inference(
+        model=model, config={"dtype": "bf16"},
+        model_parameters=jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, jnp.bfloat16), params))
     engine._ensure_params(jnp.zeros((1, 2), jnp.int32))
+    return model, engine
+
+
+def _small_paged_server(pages=6):
+    """A two-layer GPT-NeoX server over a page pool with the kernel on, and
+    the operands of its three step programs. ``max_seq_len`` is 384, a
+    width nothing else in the model has."""
+    from deepspeed_tpu.inference.engine import pack_chunk_args
+    from deepspeed_tpu.serving.paged_pool import PagedKVPool
+
+    model, engine = _zero_engine("gpt-neox", max_seq_len=384, n_embd=256,
+                                 n_layer=2, n_head=2)
     slots, ps = 2, 64
     pool = PagedKVPool(model.kv_cache_spec(), slots, num_pages=pages,
                        page_size=ps, kernel="on")
@@ -475,6 +469,134 @@ def test_no_program_of_a_serving_step_passes_over_a_pool_leaf(
             assert "128" in dims and "384" not in dims, name
 
 
+# the served attention kinds at the least widths at which the PARENT of PR 42
+# (ac6b3dd) showed a layer's attention weights sliced out of the stacked leaf
+# and transposed: family, widths, slots, the pool's pages of 128 lanes
+# (None: the contiguous SlotPool), a chunk's tokens
+_ATTENTION_KINDS = {
+    "kv_pages": ("gpt-neox", dict(n_embd=512, n_layer=2, n_head=4), 64,
+                 dict(page_size=64), 64),
+    "state": ("brumby", dict(n_embd=512, n_layer=2, n_head=4, n_kv_head=2,
+                             head_size=128, ffn_dim=1024), 16, None, 128),
+    "latent_pages": ("moonlight", dict(
+        n_embd=512, n_layer=3, n_head=4, n_kv_head=4, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        ffn_dim=128, n_experts=8, experts_per_token=2, dense_ffn_dim=1024,
+        mlp_layer_types=["dense", "sparse", "sparse"]), 64,
+        dict(page_size=128, prefix_cache=False), 128),
+}
+_POOL_PAGES = 4096      # described, as in the test of the chunk program
+
+
+def _attention_step_programs(kind):
+    """The decode and the chunk program of a small server of one attention
+    kind with their operands as shapes, and the per-layer shapes of its
+    stacked attention weights (``bf16[1,in,out]`` of every ``Dense`` under
+    ``attn``)."""
+    from deepspeed_tpu.inference.engine import pack_chunk_args
+    from deepspeed_tpu.serving.paged_pool import PagedKVPool
+
+    family, widths, slots, paged, chunk = _ATTENTION_KINDS[kind]
+    model, engine = _zero_engine(family, max_seq_len=2048, **widths)
+    token = jnp.zeros((slots,), jnp.int32)
+
+    def packed(*rows):
+        return jnp.asarray(pack_chunk_args(
+            np.zeros((1, chunk), np.int32), 0, chunk, chunk, chunk - 1,
+            *rows))
+
+    if paged is None:
+        cache = {"cache_store": jax.eval_shape(
+            lambda: model.kv_cache_spec().stacked_cache(slots))}
+        programs = {
+            "decode": (engine._jit_decode, (
+                engine.params, cache, token, None, token)),
+            "prefill_chunk": (engine._jit_prefill_chunk, (
+                engine.params, cache, packed()))}
+    else:
+        pool = PagedKVPool(model.kv_cache_spec(), slots, num_pages=slots,
+                           kernel="on", **paged)
+        pool.bind_engine(engine)
+        # the pool as a deployment's: nothing the compiler could keep in
+        # the chip's fast memory
+        cs = {key: jax.ShapeDtypeStruct(
+            (leaf.shape[0], _POOL_PAGES) + leaf.shape[2:], leaf.dtype)
+            if leaf.ndim > 2 else leaf
+            for key, leaf in pool.cache["cache_store"].items()}
+        rows = [np.zeros((pool.pages_per_slot,), np.int32)
+                for _ in pool._table_keys]
+        programs = {
+            "kernel_decode": (pool._paged_decode_kernel_jit, (
+                engine.params, cs, token)),
+            "paged_chunk": (pool._paged_chunk_jit, (
+                engine.params, cs, packed(*rows)))}
+    weights = {}
+
+    def note(path, leaf):
+        keys = [getattr(k, "key", None) for k in path]
+        if "attn" in keys and keys[-1] == "kernel" and leaf.ndim == 3:
+            weights.setdefault("bf16[1,%d,%d]" % leaf.shape[1:],
+                               []).append(keys[-2])
+
+    jax.tree_util.tree_map_with_path(note, engine.params)
+    return programs, weights
+
+
+@pytest.mark.parametrize("kind", sorted(_ATTENTION_KINDS))
+def test_attention_projections_read_the_stacked_leaf(
+        compiled_kernels, described_v5e, kind):
+    """PR 42. In the decode and the chunk program of a server, compiled for
+    a described v5e, no instruction of a layer's body gives a value with the
+    per-layer shape of a stacked attention weight: every projection's dot
+    takes its matrix from the stacked leaf, the slice fused into it. The
+    fault this guards (seen at these widths on PR 42's parent, n_embd 512
+    and 4 heads of 128 in every kind, and at the served ones: ``bf16[1,
+    2048,2048]`` x 3 in Pythia's programs, ``[1,2304,4096]`` and ``[1,2304,
+    512]`` x 2 in Mellum's, ``[1,5120,5120]`` and ``[1,5120,1024]`` x 2 in
+    Brumby's, ``[1,2048,3072]`` in Moonlight's): the cache write and the
+    read kernels want head_dim ahead of the rows, layout assignment carried
+    that through the projection onto its weight, and the program made
+    ``%constant_dynamic-slice_fusion = bf16[1,C,C']{2,1,0}`` (the layer's
+    matrix out of the leaf) and ``%copy = bf16[1,C,C']{1,2,0}`` (transposed)
+    a layer a projection a step; ``transformer_lm._settled`` is what stops
+    it. Not looked at: a latent layer's ``kv_b_proj``, a parameter and no
+    ``Dense``, whose slice and copy are the batched product's own (the
+    heads lie in the middle of the stored matrix; the einsum compiled alone
+    makes both); and the asynchronous ``slice-start`` / ``copy-start`` with
+    which the compiler moves a WHOLE parameter of a model this small into
+    the chip's fast memory ahead of the loop."""
+    from deepspeed_tpu.parallel import mesh
+
+    programs, weights = _attention_step_programs(kind)
+    assert {"q_proj", "o_proj"} <= set(sum(weights.values(), [])), weights
+    mesh.reset_mesh()       # (the engine's mesh is of this process's CPUs)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=described_v5e)
+
+    with _compile_cache_off():
+        texts = {name: jitted.lower(*jax.tree_util.tree_map(
+            described, args)).compile().as_text()
+            for name, (jitted, args) in programs.items()}
+    for name, text in texts.items():
+        assert "tpu_custom_call" in text, name
+        found, inside = [], ""
+        for line in text.split("\n"):
+            head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$", line)
+            if head:
+                inside = head.group(1)
+                continue
+            op = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                          r"([\w-]+)\(", line)
+            if op and "fused_computation" not in inside \
+                    and op.group(2) in weights and op.group(3) in (
+                        "fusion", "copy", "dynamic-slice", "slice",
+                        "transpose"):
+                found.append((op.group(1), op.group(2),
+                              weights[op.group(2)]))
+        assert not found, (kind, name, found)
+
+
 def _program_text(compiled) -> str:
     """A compiled program's HLO without what names this checkout: the
     stack-frame tables and ``metadata`` (files and line numbers), and each
@@ -507,19 +629,27 @@ def _program_text(compiled) -> str:
 
 
 # sha256 of _program_text(kernel_decode) of _small_paged_server(4096) since
-# PR 35. Until then "eccfe7ce...aaff9", the program PR 31 measured (taken on
-# commit 2a1c43f, still the text of PR 35's parent c3d4d53). PR 35's differs
-# from it, once the numbers XLA gives its instructions are taken out, in 62
-# lines, all of them the token operand: ``s32[2]`` where it was ``s32[2,1]``
-# (the program takes the server's (B,) token twin and adds the axis itself),
-# through the two fusions of the embedding lookup that read it. The positions
-# operand is in neither text: this model's are rotary, made from the cache's
-# index, so XLA had dropped the argument the host still put every step.
-# Measured with it, `serve-pythia-1b4-chat` `gap_p90_ms`, parent / PR 35 at
-# one seed a pair, one v5e chip (PERF.md §6, PR 35, call C1): 9.196 / 8.402
-# and 9.132 / 8.206 ms; `decode_dev_ms_p50` 5.99 / 6.04 (a traced pair).
+# PR 42. Until then "4b94b625...109c1", pinned by PR 35 (whose text differed
+# from PR 31's "eccfe7ce...aaff9" in the token operand alone: ``s32[2]`` for
+# ``s32[2,1]``; chat `gap_p90_ms` 9.196 / 8.402 then). PR 42's differs from
+# it, once the numbers XLA gives its instructions and their parameters are
+# taken out, in 461 lines (241 of the old text, 220 new), all of them the
+# layer body's q, k and v projections: gone are the three fusions that
+# sliced ``bf16[1,256,256]`` out of the stacked ``bf16[2,256,256]`` leaves
+# into buffers of their own (`constant_dynamic-slice_fusion.15/.16/.17`),
+# the three ``copy`` of them into ``{1,2,0}`` (`copy.100/.106/.107`) and the
+# three products over the transposed ``bf16[2,128,256]`` with the batch as
+# the minor dimension; in their place three products ``bf16[2,1,256]`` that
+# take the stacked leaf as an operand and slice it inside the fusion, as
+# `o_proj`'s did and does (one ``dynamic-slice`` of a ``bf16[1,256,256]``
+# inside a fusion then, four now), and the small copies that give the
+# kernels their layout on the results. Kernels and every other instruction
+# as they were. Measured with it, `serve-pythia-1b4-chat`, parent ac6b3dd /
+# PR 42 at one seed a pair, one v5e chip (PERF.md §6, PR 42, calls A and B):
+# `gap_p90_ms` 8.525 / 7.536, 8.399 / 7.219, 8.313 / 7.053 and 9.446 /
+# 8.226 ms; `decode_dev_ms_p50` 5.953 / 4.940 (the traced pair).
 _KERNEL_DECODE_TEXT = (
-    "4b94b6257df37087f22d38d8c575a6d3aa0297868123f58b280f53b5c13109c1")
+    "766626d4ec018e3b72dbbdd99d9a05480b8cafcc4c18f8558565b183ebab6fd7")
 
 
 def test_the_chunk_program_goes_through_the_pages_and_decode_is_unchanged(
@@ -534,7 +664,6 @@ def test_the_chunk_program_goes_through_the_pages_and_decode_is_unchanged(
     what was measured then, is beside the digest)."""
     import hashlib
 
-    from jax.experimental.compilation_cache import compilation_cache
     from deepspeed_tpu.parallel import mesh
 
     pages = 4096
@@ -549,18 +678,12 @@ def test_the_chunk_program_goes_through_the_pages_and_decode_is_unchanged(
             if x.ndim == 5 else x.shape
         return jax.ShapeDtypeStruct(shape, x.dtype, sharding=described_v5e)
 
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compile_cache_off():
         compiled = {
             name: jitted.lower(
                 *jax.tree_util.tree_map(described, args)).compile()
             for name, (jitted, args) in programs.items()
             if name != "_paged_admit_rows"}
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
     chunk = compiled["paged_chunk"]
     text = chunk.as_text()
     calls = re.findall(r"%(\w+)\.\d+ = [^\n]*tpu_custom_call", text)
