@@ -59,13 +59,15 @@ def init_params(model, init_args: tuple, init_kwargs: dict, seed: int,
     """The parameter tree, made on the device in ONE jitted call from the
     seed. ``cast_to`` casts floating leaves inside the same program (so a
     bf16 server never holds the float32 tree). On a mesh of several chips
-    every leaf is born split (``spread_over``)."""
+    every leaf is born split (``spread_over``). The key and ``init_args``
+    are the program's arguments, not constants of it: one program serves
+    every seed, so a run at a seed the compile cache has not met finds it
+    there all the same."""
     import jax
     import jax.numpy as jnp
 
-    def init():
-        key = jax.random.PRNGKey(seed)
-        tree = model.init({"params": key, "dropout": key}, *init_args,
+    def init(key, *args):
+        tree = model.init({"params": key, "dropout": key}, *args,
                           **init_kwargs)["params"]
         if cast_to is None:
             return tree
@@ -73,10 +75,11 @@ def init_params(model, init_args: tuple, init_kwargs: dict, seed: int,
             lambda x: x.astype(cast_to)
             if jnp.issubdtype(x.dtype, jnp.floating) else x, tree)
 
+    key = jax.random.PRNGKey(seed)
     out_shardings = None
     if mesh is not None and mesh.devices.size > 1:
-        shapes = jax.eval_shape(init)
+        shapes = jax.eval_shape(init, key, *init_args)
         out_shardings = jax.tree_util.tree_map(
             lambda s: spread_over(mesh, s.shape), shapes)
     return jax.block_until_ready(
-        jax.jit(init, out_shardings=out_shardings)())
+        jax.jit(init, out_shardings=out_shardings)(key, *init_args))

@@ -4,7 +4,11 @@ server sustains. Not part of a run; the builder's instrument for the rate in
 the cell's traffic file, kept so a later benchmark issue can find it again.
 
     chiprun --chips 1 --timeout 3000 -- python3 perf/tools/find_knee.py \
-        --workload serve-pythia-1b4-chat --rates 0.5,0.7,0.8,0.9,1.0,1.1
+        --workload serve-pythia-1b4-chat --rates 0.8,2,4,6,8,12,16,24
+
+(go up geometrically until two rates in a row are not sustained, then a
+second call fills in around the edge: ``--light <row of the first call's
+first rate>`` keeps the same reference).
 
 Each rate is one run of the cell in a new process (this file started again
 with ``--one-rate``: ``perf/run.py`` itself takes only the contract's four
@@ -27,7 +31,7 @@ A rate is sustained when
   share of requests inside both.
 
 The knee is the highest sustained rate; the cell runs at 0.8 x it, rounded
-to 0.1 request/s. Every rate that was run is printed and appended to
+to two significant digits. Every rate that was run is printed and appended to
 chiprun_out/knee-<workload>.jsonl, sustained or not."""
 
 import argparse
@@ -45,6 +49,7 @@ ABS_TTFT_MS, ABS_GAP_MS = 500.0, 100.0      # ISSUE 22's limits, reported
 
 def one_rate_here(workload: str, rate: float, seed: int, seconds) -> dict:
     """This process runs the cell once at ``rate`` and returns the row."""
+    from perf.manifest import Manifest
     from perf.run import run_cell
 
     if seconds is None:
@@ -52,19 +57,27 @@ def one_rate_here(workload: str, rate: float, seed: int, seconds) -> dict:
             seconds = json.load(f)["run_seconds"]
     result = run_cell(workload, seed, seconds, False, override={
         "traffic": {"params": {"rate_per_s": rate}}})
-    facts = result["_record"]["facts"]
+    record = result["_record"]
+    facts = record["facts"]
+    chunk_share = Manifest(ROOT).layer_reader("chunk_steps_share")(record)
     whole = facts["whole_window"]
     inside = sum(1 for x in whole.get("ttft_ms", []) if x <= ABS_TTFT_MS) \
         if whole.get("gap_mean_ms", ABS_GAP_MS + 1) <= ABS_GAP_MS else 0
-    return {"rate_per_s": rate, "correct": result["correct"],
+    return {"rate_per_s": rate, "seed": seed,
+            "lead_in_s": record["traffic"]["params"]["lead_in_s"],
+            "correct": result["correct"],
             "attempted": result["attempted"], "failed": result["failed"],
+            "requests_due": whole["requests_due"],
             "no_first_token": facts["no_first_token"],
             "finished_in_window": facts["finished_in_window"],
             "preempted": facts["window_counters"]["preempted"],
             "ttft_p50_ms": whole.get("ttft_p50_ms"),
             "ttft_p90_ms": whole.get("ttft_p90_ms"),
             "gap_mean_ms": whole.get("gap_mean_ms"),
-            "gap_p99_ms": whole.get("gap_p99_ms"),
+            **{f"gap_p{q}_ms": whole.get(f"gap_p{q}_ms")
+               for q in (50, 90, 95, 99)},
+            "steps_in_window": facts["steps_in_window"],
+            "chunk_steps_share": chunk_share,
             "inside_abs_limits_share": inside / max(whole["requests_due"], 1),
             "step_ms_mean": facts["step_ms_mean"],
             "backlog_mid_end": facts["backlog_mid_end"],
@@ -95,6 +108,9 @@ def main() -> int:
                     help="(the tool's own child) run this rate here")
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--light", default=None,
+                    help="ttft_p50_ms,gap_mean_ms of an earlier call's "
+                         "light rate: every rate here is held against it")
     ap.add_argument("--ttft-factor", type=float, default=2.0)
     ap.add_argument("--gap-factor", type=float, default=1.25)
     ap.add_argument("--stop-after-unsustained", type=int, default=2)
@@ -107,7 +123,10 @@ def main() -> int:
 
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
-    table, misses = [], 0
+    table, misses, light = [], 0, None
+    if args.light:
+        ttft, gap = (float(x) for x in args.light.split(","))
+        light = {"ttft_p50_ms": ttft, "gap_mean_ms": gap}
     for rate in [float(r) for r in args.rates.split(",")]:
         cmd = [sys.executable, os.path.abspath(__file__), "--workload",
                args.workload, "--one-rate", str(rate), "--seed",
@@ -121,8 +140,9 @@ def main() -> int:
             raise RuntimeError(f"rate {rate}: exit {proc.returncode}")
         row = json.loads([ln for ln in proc.stdout.splitlines()
                           if ln.strip()][-1])
-        row["sustained"] = sustained(row, table[0] if table else row,
-                                     args.ttft_factor, args.gap_factor)
+        light = light or row
+        row["sustained"] = sustained(row, light, args.ttft_factor,
+                                     args.gap_factor)
         table.append(row)
         print(json.dumps(row), flush=True)
         with open(os.path.join(out_dir, f"knee-{args.workload}.jsonl"),
@@ -135,7 +155,7 @@ def main() -> int:
     knee = max(good) if good else None
     print(json.dumps({"workload": args.workload, "knee_per_s": knee,
                       "cell_rate_per_s": None if knee is None
-                      else round(0.8 * knee, 1)}))
+                      else float(f"{0.8 * knee:.2g}")}))
     return 0
 
 

@@ -1,6 +1,7 @@
 """Traffic generators are pure functions of the seed and keep inside their
 clips; the due-time and percentile arithmetic on hand-made timelines."""
 
+import json
 import os
 import sys
 
@@ -19,9 +20,10 @@ CONTEXT = {"vocab_size": 50304, "context_len": 2048}
 SECONDS = 30.0
 
 
-def _serving_requests(traffic_name, seed):
+def _serving_requests(traffic_name, seed, **params):
     m = Manifest(ROOT)
     traffic = m.traffic(traffic_name)
+    traffic["params"].update(params)
     load = m.generator(traffic["generator"]).generate(
         traffic["params"], seed, SECONDS, CONTEXT)
     if load.closed:
@@ -58,9 +60,9 @@ def test_serving_generators_are_seeded_and_clipped(traffic_name):
         assert r["prompt"].max() < CONTEXT["vocab_size"]
 
 
-def test_open_loop_offers_a_fixed_amount_of_work_whatever_the_seed():
-    params, a = _serving_requests("chat-open", 3)
-    _, b = _serving_requests("chat-open", 4)
+def test_open_loop_offers_a_fixed_amount_of_work_whatever_is_drawn():
+    params, a = _serving_requests("chat-open", 3, schedule_seed=3)
+    _, b = _serving_requests("chat-open", 3, schedule_seed=4)
     due = np.array([r["due_s"] for r in a])
     assert due[0] >= -params["lead_in_s"] and due[0] < 0
     assert due[-1] < SECONDS + params["tail_s"]
@@ -83,6 +85,35 @@ def test_open_loop_offers_a_fixed_amount_of_work_whatever_the_seed():
     lengths = sorted(len(r["prompt"]) for r in window(a))
     assert lengths[len(lengths) // 2] == pytest.approx(
         params["prompt_len"]["median"], rel=0.1)
+
+
+def test_a_schedule_seed_gives_every_run_the_same_schedule():
+    m = Manifest(ROOT)
+    traffic = m.traffic("chat-open")
+    gen = m.generator(traffic["generator"])
+
+    def schedule(params, seed):
+        load = gen.generate(params, seed, SECONDS, CONTEXT)
+        return [(r["due_s"], len(r["prompt"]), r["max_new_tokens"])
+                for r in load.requests], load.requests
+
+    # the cell's own file, as it is
+    assert schedule(traffic["params"], 5)[0] == \
+        schedule(traffic["params"], 6)[0]
+    fixed = dict(traffic["params"], schedule_seed=7)
+    a, reqs_a = schedule(fixed, 3)
+    b, reqs_b = schedule(fixed, 2147485999)
+    # who is due when, with how long a prompt and answer: the same in every
+    # run; the run's seed sets the tokens, and the same seed the same tokens
+    assert a == b
+    assert not any(np.array_equal(x["prompt"], y["prompt"])
+                   for x, y in zip(reqs_a, reqs_b))
+    assert all(np.array_equal(x["prompt"], y["prompt"])
+               for x, y in zip(reqs_a, schedule(fixed, 3)[1]))
+    # another schedule_seed is another schedule of the same work
+    c, _ = schedule(dict(fixed, schedule_seed=8), 3)
+    assert c != a and sorted(x[1:] for x in c) != sorted(x[1:] for x in a) \
+        and sorted(x[1] for x in c) == sorted(x[1] for x in a)
 
 
 def test_closed_loop_sends_the_next_request_when_the_last_is_answered():
@@ -146,7 +177,66 @@ def test_percentile_and_spread():
     assert stats.percentile([], 50) is None
     assert stats.percentile([1, 2, 3, 4], 50) == 2.5
     assert stats.percentile(list(range(101)), 99) == 99.0
-    # quartiles 2 and 4 around a median of 3
-    assert stats.spread([1, 2, 3, 4, 5]) == pytest.approx(2 / 3)
+    # the driver's quartiles, statistics.quantiles(values, n=4): 1.5 and
+    # 4.5 around a median of 3 (numpy's 2 and 4 lie closer together)
+    assert stats.spread([1, 2, 3, 4, 5]) == pytest.approx(1.0)
     summary = stats.summarize_runs([{"a": 1.0}, {"a": 3.0}, {"a": 2.0}])
     assert summary["a"]["median"] == 2.0 and summary["a"]["n"] == 3
+
+
+def test_spread_without_farthest_leaves_out_one_run_and_only_one():
+    steady = [10.0, 10.1, 10.2, 10.3, 10.4]
+    one_off = steady + [14.0]
+    two_off = steady + [14.0, 14.5]
+    assert stats.spread_without_farthest([1.0, 2.0]) is None
+    # one far-off run in a set does no harm: the set reads as without it
+    assert stats.spread_without_farthest(one_off) == \
+        pytest.approx(stats.spread(steady))
+    assert stats.spread(one_off) > 3 * stats.spread(steady)
+    # two do
+    assert stats.spread_without_farthest(two_off) > 3 * stats.spread(steady)
+    # the run is left out only where that narrows the spread: here the
+    # median of the five that stay falls from 11 to 10 and they read wider
+    halves = [10.0, 10.0, 10.0, 12.0, 12.0, 12.0]
+    assert stats.spread(halves[:-1]) > stats.spread(halves)
+    assert stats.spread_without_farthest(halves) == \
+        pytest.approx(stats.spread(halves)) == pytest.approx(2.0 / 11.0)
+
+
+def test_spread_tool_reads_two_sets_as_the_driver_does(tmp_path, monkeypatch,
+                                                       capsys):
+    from perf.manifest import load_module
+
+    tool = load_module(os.path.join(ROOT, "perf", "tools", "spread.py"),
+                       "perf_tool_spread")
+    gaps = {100: 10.0, 101: 10.1, 102: 10.2, 103: 10.3, 104: 10.4, 105: 14.0,
+            106: 10.0, 107: 10.2, 108: 10.4, 109: 10.6, 110: 10.8, 111: 7.0}
+    seen = []
+
+    def run_once(workload, seed, seconds, trace, timeout):
+        seen.append(seed)
+        return {"correct": True, "failed": 0, "attempted": 3, "metrics": {
+            "gap_p90_ms": {"value": gaps[seed], "unit": "ms"},
+            "setup_s": {"value": 40.0 + seed % 2, "unit": "s"}}}, None
+
+    monkeypatch.setattr(tool, "run_once", run_once)
+    monkeypatch.setattr(tool, "ROOT", str(tmp_path))
+    monkeypatch.setattr(sys, "argv", ["spread.py", "--workload", "a-cell",
+                                      "--runs", "6", "--sets", "2"])
+    assert tool.main() == 0
+    assert seen == list(range(100, 112))        # other seeds in the second
+    last = json.loads(capsys.readouterr().out.splitlines()[-1])
+    view = last["as_the_driver_reads_it"]["gap_p90_ms"]
+    first, second = ([gaps[s] for s in range(a, a + 6)] for a in (100, 106))
+    assert view["medians"] == [pytest.approx(10.25), pytest.approx(10.3)]
+    assert view["mean_spread_without_farthest"] == pytest.approx(
+        (stats.spread(first[:5]) + stats.spread(second[:5])) / 2)
+    assert view["widest_spread"] == pytest.approx(
+        max(stats.spread(first), stats.spread(second)))
+    assert len((tmp_path / "chiprun_out" / "spread-a-cell.jsonl")
+               .read_text().splitlines()) == 12
+    # the driver's own two sets have the same seeds
+    seen.clear()
+    monkeypatch.setattr(sys, "argv", sys.argv + ["--same-seeds", "1"])
+    assert tool.main() == 0
+    assert seen == list(range(100, 106)) * 2

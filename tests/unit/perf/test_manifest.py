@@ -22,8 +22,8 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "proj",
-               "head_dim", "expansion", "experts_per_tok")
+WIDTH_WORDS = ("hidden_size", "hidden_dim", "intermediate", "latent",
+               "state", "proj", "head_dim", "expansion", "experts_per_tok")
 
 
 @pytest.fixture(scope="module")
@@ -226,8 +226,9 @@ def _record(**extra):
     m = Manifest(ROOT)
     base = {"failures": [], "attempted": 40, "failed": 0,
             "setup_s": 33.25, "memory_peak_bytes": 13958643712,
-            "end_to_end": {"ttft_p50_ms": 81.5, "gap_p90_ms": 120.25,
-                           "gap_p99_ms": 160.5, "serve_tok_s": 1.0},
+            "end_to_end": {"ttft_p50_ms": 81.5, "gap_p50_ms": 100.125,
+                           "gap_p90_ms": 120.25, "gap_p99_ms": 160.5,
+                           "serve_tok_s": 1.0},
             "counters": {"compiles_in_window": 0, "num_devices": 1,
                          "decode_steps": 10, "slot_steps": 300,
                          "num_pages": 768},
@@ -242,6 +243,60 @@ def _record(**extra):
     return m, base
 
 
+def test_the_weights_come_from_one_program_whatever_the_seed(monkeypatch):
+    """The seed is an argument of the jitted init, not a constant of it: a
+    run at a seed the compile cache has not met finds the program there."""
+    import jax
+    import jax.numpy as jnp
+
+    from perf import build
+
+    m = Manifest(ROOT)
+    config = m.config("pythia-1.4b-paged")
+    model, _ = build.build_model(config["model"], None, True)
+    programs, real_jit = [], jax.jit
+
+    def jit(fn, **kw):
+        jitted = real_jit(fn, **kw)
+
+        def call(*args):
+            programs.append(jitted.lower(*args).as_text())
+            return jitted(*args)
+        return call
+
+    monkeypatch.setattr(jax, "jit", jit)
+
+    def tree(seed):
+        return build.init_params(
+            model, (jnp.zeros((1, 8), jnp.int32),),
+            {"method": getattr(model, config["model"]["init_method"])},
+            seed, cast_to=jnp.bfloat16)
+
+    a, b, c = tree(7), tree(7), tree(3900045001)
+    assert programs[0] == programs[1] == programs[2]
+    leaves = [jax.tree_util.tree_leaves(t) for t in (a, b, c)]
+    assert all(x.dtype == jnp.bfloat16 for x in leaves[0])
+    assert all(bool((x == y).all()) for x, y in zip(leaves[0], leaves[1]))
+    assert any(bool((x != y).any()) for x, y in zip(leaves[0], leaves[2]))
+
+
+@pytest.mark.parametrize("seconds", [20.0, 30.0, 45.0])
+def test_an_open_loop_is_traced_at_the_windows_end_whatever_its_length(
+        seconds):
+    m = Manifest(ROOT)
+    placement = m.entry("serve").trace_placement
+    # chat, the open loop: the window's last 3 s, the profiler stopped after
+    # the loop (its stop stalls the one thread for many seconds)
+    at, length, stop_in_window = placement(
+        m.cell("serve-pythia-1b4-chat"), seconds)
+    assert (at + length, length, stop_in_window) == (seconds, 3.0, False)
+    # a closed loop: from 30 % of the window, stopped there
+    docs = m.cell("serve-pythia-1b4-docs")
+    assert "trace_at" not in docs
+    assert placement(docs, seconds) == (
+        0.3 * seconds, docs.get("trace_seconds", 3.0), True)
+
+
 def test_last_line_untraced_has_exactly_the_contracts_keys():
     from perf.run import assemble_result
 
@@ -252,8 +307,9 @@ def test_last_line_untraced_has_exactly_the_contracts_keys():
     assert line["correct"] is True and line["failed"] == 0
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
+    # chat is judged on its median gap since PR 45 (ISSUE 45 step 4 (b))
     assert line["metrics"] == {
-        "gap_p90_ms": {"value": 120.25, "unit": "ms"},
+        "gap_p50_ms": {"value": 100.125, "unit": "ms"},
         "setup_s": {"value": 33.25, "unit": "s"}}
     json.dumps(line)
 
@@ -283,17 +339,19 @@ def test_last_line_traced_carries_layer_metrics_busy_window_breakdown():
     assert line["metrics"]["live_slots_mean.chat"]["value"] == 30.0
     assert line["metrics"]["serve_step_ms_p50.chat"]["value"] == \
         pytest.approx(40.0)
-    assert line["metrics"]["gen_late_p99_ms"]["value"] == \
+    assert line["metrics"]["gen_late_p99_ms.chat"]["value"] == \
         pytest.approx(1.985)
     # two custom calls of 3 ms in the small trace, each held to the bytes
     # of ~4128 cached tokens' K and V at the v5e's 819 GB/s
     least = 2 * (2 * 4128 * 16 * 128 * 2 + 2 * 16 * 16 * 128 * 2) / 8.19e11
     assert line["metrics"]["pallas_roofline.chat"]["value"] == \
         pytest.approx(100 * least / 0.003, rel=1e-6)
-    assert line["metrics"]["ttft_p50_ms"]["value"] == 81.5
-    assert line["metrics"]["gap_p99_ms"]["value"] == 160.5
+    assert line["metrics"]["ttft_p50_ms.chat"]["value"] == 81.5
+    assert line["metrics"]["gap_p99_ms.chat"]["value"] == 160.5
+    # the 90th gap is a per-layer metric of this cell since PR 45
+    assert line["metrics"]["gap_p90_ms.chat"]["value"] == 120.25
     # no end-to-end metric rides on a traced line
-    assert "gap_p90_ms" not in line["metrics"]
+    assert not {"gap_p50_ms", "gap_p90_ms", "setup_s"} & set(line["metrics"])
     json.dumps(line)
 
 

@@ -237,7 +237,10 @@ def test_train_window_is_the_last_steps_of_the_process(monkeypatch,
 def test_the_twelve_entries_have_readers_and_fit_the_contract(manifest):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    tail = bench["per_layer"][-12:]
+    # found by name: a contiguous run, wherever later PRs have appended to
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW[0])
+    tail = bench["per_layer"][first:first + 12]
     assert [m["name"] for m in tail] == NEW
     end_to_end = {m["name"]: m for m in bench["end_to_end"]}
     cells = [w["name"] for w in bench["workloads"]]
@@ -256,7 +259,7 @@ def test_the_twelve_entries_have_readers_and_fit_the_contract(manifest):
         assert set(NEW[-3:]) <= mine
         assert ("train_host_serial_ms_p50" in mine) == cell.startswith("train")
     # a layer name is one already in use, letter for letter
-    old_layers = {m["layer"] for m in bench["per_layer"][:-12]}
+    old_layers = {m["layer"] for m in bench["per_layer"][:first]}
     assert {m["layer"] for m in tail} <= old_layers
 
 

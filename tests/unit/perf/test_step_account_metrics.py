@@ -20,11 +20,13 @@ from perf.manifest import Manifest  # noqa: E402
 BASES = ["step_exposed_host_ms_p50", "step_enqueue_ms_p50",
          "step_prepare_ms_p50", "step_device_calls_mean",
          "step_idle_unnamed_ms"]
-VARIANTS = {".gap": (["serve-pythia-1b4-chat", "serve-brumby-14b-continue"],
-                     "gap_p90_ms"),
+VARIANTS = {".gap": (["serve-brumby-14b-continue"], "gap_p90_ms"),
             ".tok": (["serve-pythia-1b4-docs", "serve-mellum2-12b-ide"],
-                     "serve_tok_s")}
-NEW = [base + suffix for base in BASES for suffix in VARIANTS]
+                     "serve_tok_s"),
+            # PR 45: chat is judged on its median gap and left the ``.gap``
+            # lists for entries of its own, appended after every other
+            ".chat": (["serve-pythia-1b4-chat"], "gap_p50_ms")}
+NEW = [base + suffix for base in BASES for suffix in (".gap", ".tok")]
 T_OPEN = 2000.0           # the harness's t_open on perf_counter, seconds
 US = 1e-6
 
@@ -294,8 +296,11 @@ def test_no_window_no_reading(manifest, monkeypatch, metric):
 def test_the_ten_entries_fit_the_contract(manifest, metric):
     entry, = [m for m in manifest.data["per_layer"] if m["name"] == metric]
     base, suffix = metric.rsplit(".", 1)
-    cells, moves = VARIANTS["." + suffix]
-    assert entry["workloads"] == cells and entry["moves"] == moves
+    first, moves = VARIANTS["." + suffix]
+    cells = entry["workloads"]
+    # the cells ISSUE 34 entered it for, and whichever later PRs appended
+    assert cells[:len(first)] == first and entry["moves"] == moves
+    assert len(set(cells)) == len(cells)
     assert entry["layer"] == "server step" and entry["better"] == "lower"
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
@@ -308,8 +313,26 @@ def test_the_ten_entries_fit_the_contract(manifest, metric):
     moved, = [m for m in manifest.data["end_to_end"] if m["name"] == moves]
     assert set(cells) <= set(moved["workloads"])
     assert callable(manifest.layer_reader(metric))
-    # appended: the ten are the last entries, in the issue's order
-    assert [m["name"] for m in manifest.data["per_layer"][-10:]] == NEW
+    # found by name: one contiguous run in the issue's order, wherever
+    # later PRs have appended to
+    names = [m["name"] for m in manifest.data["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + 10] == NEW
     for cell in cells:
         assert metric in [m["name"] for m in
                           manifest.metrics_for(cell, "per_layer")]
+
+
+@pytest.mark.parametrize("base", BASES)
+def test_chats_twin_is_the_same_reader_entered_for_another_judge(manifest,
+                                                                 base):
+    """PR 45: chat is judged on its median gap, so it left each ``.gap``
+    list for a ``.chat`` entry that moves ``gap_p50_ms``."""
+    by_name = {m["name"]: m for m in manifest.data["per_layer"]}
+    twin, old = by_name[base + ".chat"], by_name[base + ".gap"]
+    assert (twin["workloads"], twin["moves"]) == VARIANTS[".chat"]
+    assert {k: twin[k] for k in ("unit", "better", "source", "layer")} == \
+        {k: old[k] for k in ("unit", "better", "source", "layer")}
+    mine = [m["name"] for m in manifest.metrics_for(*twin["workloads"],
+                                                    "per_layer")]
+    assert base + ".chat" in mine and base + ".gap" not in mine
