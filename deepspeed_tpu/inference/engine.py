@@ -321,13 +321,12 @@ class InferenceEngine:
             return out, vars_["cache"]
 
         chunk_gen = getattr(module, "prefill_chunk", None)
-        has_state = getattr(self.kv_cache_spec(), "state", None) is not None
-        if has_state and self.mp_world_size > 1:
-            raise ValueError(
-                "tensor-parallel inference does not compose with a "
-                "recurrent state yet: the state leaves have no placement on "
-                "the model axis and the retention kernels are not wrapped "
-                "for a mesh (ROADMAP.md, Reach)")
+        spec = self.kv_cache_spec()
+        has_state = getattr(spec, "state", None) is not None
+        why = spec.refusal("tensor_parallel") \
+            if spec is not None and self.mp_world_size > 1 else None
+        if why:
+            raise ValueError(why)
 
         def prefill_chunk_fn(params, cache, packed):
             """One bounded prefill chunk DIRECTLY into slot ``slot`` of
@@ -673,16 +672,6 @@ class InferenceEngine:
                 f"prompt({T}) + max_new_tokens({max_new_tokens}) exceeds the "
                 f"allocated KV-cache capacity({capacity})")
 
-        # NOTE generate() deliberately does NOT pass a decode block hint:
-        # an A/B that derived the block from the generation budget
-        # (preferred_block_for(T + max_new_tokens), so live 1536 in an 8k
-        # cache took the 1024 block) measured EVERY arm 5-15% slower —
-        # decode at these shapes is grid-overhead bound, not dead-row
-        # bound (the index-map clamp already elides dead-block DMA), so
-        # fewer, larger grid steps win even when the last live block is
-        # mostly dead (PERF.md §8, the block_hint lead). Callers with
-        # measured wins at their own shapes can drive
-        # module.decode(block_hint=...) directly.
         decode_exec = None
         if eos_token_id is None:
             # whole-loop compile (CUDA-graph analog): ONE dispatch for the

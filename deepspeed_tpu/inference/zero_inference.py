@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.transformer_lm import TransformerBlock, TransformerConfig
+from ..models.transformer_lm import (TransformerBlock, TransformerConfig,
+                                      make_kv_cache_spec)
 from ..utils.logging import log_dist
 from ..utils.streaming import LayerWireFormat
 
@@ -48,16 +49,9 @@ class ZeroInferenceEngine:
     def __init__(self, config: TransformerConfig, params_host: Dict,
                  dtype=jnp.bfloat16, prefetch: int = 1, pack: bool = True,
                  int8: bool = False):
-        if config.n_experts or config.layer_types is not None \
-                or config.latent:
-            raise ValueError(
-                "ZeRO-Inference streams one layer's block parameters at a "
-                "time; the routed FFN's expert leaves are stacked parameters "
-                "of the model and the layer kinds are read off the layer "
-                "scan's counter: neither is streamed yet, nor is a "
-                "power_retention layer's recurrent state threaded through "
-                "the streamed layers, nor latent attention's one cached row a "
-                "token (ROADMAP.md, Reach)")
+        why = make_kv_cache_spec(config).refusal("zero_inference")
+        if why:
+            raise ValueError(why)
         if int8 and not config.int8_weights:
             # int8 ZeRO-Inference: quantize the Dense kernels host-side
             # (QuantDense layout) so each streamed layer is ~half the

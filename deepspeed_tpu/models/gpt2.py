@@ -64,17 +64,6 @@ class GPT2Config:
     # The model-level analog of the reference's SparseAttentionUtils
     # module swap (module_inject; docs/_posts/2020-09-09-sparse-attention.md)
     sparse_attention: Optional[Any] = None
-    # fused LayerNorm->matmul Pallas kernel for the ln_1->qkv and ln_2->fc
-    # pairs (ops/transformer/ln_linear.py — the TPU analog of the
-    # reference's fused transformer-block kernel). True | False | "auto".
-    # The parameter tree is identical either way. "auto" currently
-    # resolves to OFF: the round-5 flagship A/B measured the fused kernel
-    # at 0.91x XLA's composition (40.9k -> 37.3k tok/s at 350M/seq1024,
-    # rounds 1-5 runtime, PERF.md §8; XLA's matmul pipelining +
-    # multi-output fusions beat hand fusion at these shapes). Kept as an
-    # explicit option and parity-tested; does not compose with model
-    # parallelism (the Pallas call is not GSPMD-partitionable)
-    fused_ln_linear: Any = "auto"
     # streaming cross-entropy: >0 computes the LM loss in T-chunks of
     # this size without materializing the (B, T, V) logits tensor
     # (ops/transformer/chunked_xent.py). Measured ~free at the flagship
@@ -120,71 +109,15 @@ def gpt2_sharding_rules():
     ]
 
 
-def _use_fused_ln(cfg) -> bool:
-    """Fused ln->matmul gate. "auto" resolves OFF (the measured flagship
-    A/B has XLA's composition 1.10x the fused kernel — GPT2Config note);
-    explicit True demands the kernel and raises under model parallelism
-    (the Pallas call is not GSPMD-partitionable) — silently downgrading a
-    demanded kernel would mis-attribute benchmarks."""
-    if cfg.fused_ln_linear is False:
-        return False
-    from ..parallel.mesh import get_model_parallel_world_size
-
-    if cfg.fused_ln_linear is True:
-        if get_model_parallel_world_size() > 1:
-            raise ValueError(
-                "fused_ln_linear=True does not compose with model "
-                "parallelism (the Pallas call is not GSPMD-partitionable); "
-                "use fused_ln_linear='auto' to fall back automatically")
-        return True
-    # "auto" = off: the measured A/B has XLA's composition 1.10x faster
-    # than the fused kernel at the flagship shape (see GPT2Config note)
-    return False
-
-
-class _LNParams(nn.Module):
-    """LayerNorm parameters only (same names/shapes/init as nn.LayerNorm);
-    the computation itself runs inside the fused ln_linear kernel."""
-
-    @nn.compact
-    def __call__(self, c: int):
-        scale = self.param("scale", nn.initializers.ones, (c,))
-        bias = self.param("bias", nn.initializers.zeros, (c,))
-        return scale, bias
-
-
-class _DenseParams(nn.Module):
-    """nn.Dense parameters only (same names/shapes/init); the matmul runs
-    inside the fused ln_linear kernel."""
-
-    features: int
-
-    @nn.compact
-    def __call__(self, c: int):
-        kernel = self.param("kernel", nn.initializers.lecun_normal(),
-                            (c, self.features))
-        bias = self.param("bias", nn.initializers.zeros, (self.features,))
-        return kernel, bias
-
-
 class CausalSelfAttention(nn.Module):
     config: GPT2Config
 
     @nn.compact
-    def __call__(self, x, deterministic: bool = True, ln=None):
+    def __call__(self, x, deterministic: bool = True):
         cfg = self.config
         B, T, C = x.shape
         H = cfg.n_head
-        if ln is not None:
-            # fused path: x arrives pre-LN; ln_1's params come from the
-            # Block and the LN+qkv matmul run as one Pallas kernel
-            from ..ops.transformer.ln_linear import ln_linear
-
-            kernel, bias = _DenseParams(3 * C, name="qkv")(C)
-            qkv = ln_linear(x, ln[0], ln[1], kernel, bias,
-                            eps=cfg.layer_norm_epsilon)
-        else:
-            qkv = nn.Dense(3 * C, dtype=cfg.dtype, name="qkv")(x)
+        qkv = nn.Dense(3 * C, dtype=cfg.dtype, name="qkv")(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         use_flash = cfg.use_flash_attention
         if use_flash == "auto":
@@ -283,16 +216,9 @@ class MLP(nn.Module):
     config: GPT2Config
 
     @nn.compact
-    def __call__(self, x, deterministic: bool = True, ln=None):
+    def __call__(self, x, deterministic: bool = True):
         cfg = self.config
-        if ln is not None:
-            from ..ops.transformer.ln_linear import ln_linear
-
-            kernel, bias = _DenseParams(4 * cfg.n_embd, name="fc")(cfg.n_embd)
-            h = ln_linear(x, ln[0], ln[1], kernel, bias,
-                          eps=cfg.layer_norm_epsilon)
-        else:
-            h = nn.Dense(4 * cfg.n_embd, dtype=cfg.dtype, name="fc")(x)
+        h = nn.Dense(4 * cfg.n_embd, dtype=cfg.dtype, name="fc")(x)
         h = jax.nn.gelu(h, approximate=True)
         h = nn.Dense(cfg.n_embd, dtype=cfg.dtype, name="proj")(h)
         if cfg.dropout > 0:
@@ -306,15 +232,6 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
         cfg = self.config
-        if _use_fused_ln(cfg):
-            # same parameter tree as the unfused path (_LNParams/_DenseParams
-            # register identical names/shapes/init); LN rides the matmul
-            ln1 = _LNParams(name="ln_1")(cfg.n_embd)
-            x = x + CausalSelfAttention(cfg, name="attn")(
-                x, deterministic, ln=ln1)
-            ln2 = _LNParams(name="ln_2")(cfg.n_embd)
-            x = x + MLP(cfg, name="mlp")(x, deterministic, ln=ln2)
-            return x
         x = x + CausalSelfAttention(cfg, name="attn")(
             nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
                          name="ln_1")(x), deterministic)
@@ -347,7 +264,7 @@ class _ScanBody(nn.Module):
         if self.config.remat:
             policy = None
             if self.config.remat_policy == "dots_plain":
-                # dots WITHOUT the named attention/ln saves — A/B isolation
+                # dots WITHOUT the named attention saves — A/B isolation
                 # for the save-vs-recompute tradeoff (saving out/lse costs
                 # ~20 MB x n_layer of live memory at the flagship shape)
                 policy = jax.checkpoint_policies.\
@@ -359,12 +276,11 @@ class _ScanBody(nn.Module):
                 # re-running the kernel (ATTN_SAVE_NAMES tags in
                 # ops/attention/flash_attention.py)
                 from ..ops.attention.flash_attention import ATTN_SAVE_NAMES
-                from ..ops.transformer.ln_linear import LN_SAVE_NAMES
 
                 policy = jax.checkpoint_policies.save_from_both_policies(
                     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
                     jax.checkpoint_policies.save_only_these_names(
-                        *ATTN_SAVE_NAMES, *LN_SAVE_NAMES))
+                        *ATTN_SAVE_NAMES))
             block_cls = nn.remat(block_cls, prevent_cse=False,
                                  static_argnums=(2,), policy=policy)
         x = block_cls(self.config, name="block")(x, deterministic)

@@ -197,11 +197,6 @@ class TransformerConfig:
                         "need a state group beside the K/V leaves of one "
                         "cache, which no pool keeps yet: every layer is "
                         "power_retention or none is (ROADMAP.md, Reach)")
-                if self.kv_cache_quant:
-                    raise ValueError(
-                        "kv_cache_quant quantizes K/V columns; a "
-                        "power_retention layer keeps a float32 state and no "
-                        "column")
                 if self.head_dim % 8 or self.n_head // self.kv_heads \
                         >= self.head_dim:
                     raise ValueError(
@@ -221,11 +216,6 @@ class TransformerConfig:
                 raise ValueError(
                     f"experts_per_token={self.experts_per_token} of "
                     f"n_experts={self.n_experts}")
-            if self.int8_weights:
-                raise ValueError(
-                    "int8_weights does not reach the routed FFN's expert "
-                    "leaves (ops/quantization quantizes Dense kernels); "
-                    "serve the routed model in bf16")
             if self.activation != "swiglu" or self.mlp_bias:
                 raise ValueError("the routed FFN is gated silu without "
                                  "bias (activation='swiglu', mlp_bias=False)")
@@ -257,24 +247,11 @@ class TransformerConfig:
                     "latent attention carries its positions in the shared "
                     "rotary key (pos_emb='rotary') and knows no layer kinds "
                     "(layer_types) yet (ROADMAP.md, Reach)")
-            if self.kv_cache_quant:
-                raise ValueError(
-                    "kv_cache_quant quantizes K/V columns a head; the "
-                    "latent cache is one row a token that every head reads, "
-                    "and its scales have no leaf yet (ROADMAP.md, Reach)")
-            if self.int8_weights:
-                raise ValueError(
-                    "int8_weights does not reach latent attention: "
-                    "kv_b_proj is read as a matrix (absorbed into the query "
-                    "and the output), not through a Dense (ROADMAP.md, "
-                    "Reach)")
-        if (self.layer_types is not None or self.n_experts) \
-                and self.kv_cache_quant:
-            raise ValueError(
-                "kv_cache_quant does not compose with layer_types or a "
-                "routed FFN yet: the window group's pages and the window "
-                "mask exist for the full-precision tier only (ROADMAP.md, "
-                "Reach)")
+        for feature in ("kv_cache_quant", "int8_weights"):
+            why = getattr(self, feature) \
+                and refusal(cache_kinds(self), feature)
+            if why:
+                raise ValueError(why)
 
     @property
     def head_dim(self) -> int:
@@ -774,8 +751,7 @@ class CachedAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None,
-                 block_hint=None, layer=None):
+                 deterministic: bool = True, kv_cache=None, layer=None):
         cfg = self.config
         B, T, C = x.shape
         H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -880,17 +856,12 @@ class CachedAttention(nn.Module):
                 scales = dict(k_scale=new_cache["k_scale"],
                               v_scale=new_cache["v_scale"]) \
                     if cfg.kv_cache_quant else {}
-                # block_hint (static, from the caller's known generation
-                # budget) shrinks the block granule to the LIVE length
-                # instead of the allocated capacity — cache reads are
-                # block-granular, so this is pure saved bandwidth
                 y = decode_attention(
                     q[:, 0].astype(cfg.dtype), new_cache["k"],
                     new_cache["v"], start + 1, alibi_slopes=slopes,
-                    block_s=pick_block_s(
-                        cfg.max_seq_len,
-                        preferred=(block_hint if block_hint is not None
-                                   else cfg.decode_block)), **scales)
+                    block_s=pick_block_s(cfg.max_seq_len,
+                                         preferred=cfg.decode_block),
+                    **scales)
                 y = y.astype(cfg.dtype).reshape(B, 1, H * D)
                 return o_proj(y), new_cache
             if not fresh:
@@ -1037,8 +1008,7 @@ class PowerRetention(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None,
-                 block_hint=None, layer=None):
+                 deterministic: bool = True, kv_cache=None, layer=None):
         from ..ops.attention import power_retention as pr
 
         cfg = self.config
@@ -1108,8 +1078,7 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None,
-                 block_hint=None, layer=None):
+                 deterministic: bool = True, kv_cache=None, layer=None):
         cfg = self.config
         B, T, C = x.shape
         H, R = cfg.n_head, cfg.kv_lora_rank
@@ -1222,14 +1191,14 @@ class TransformerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None,
-                 block_hint=None, layer=None, experts=None):
+                 deterministic: bool = True, kv_cache=None, layer=None,
+                 experts=None):
         cfg = self.config
         attention = PowerRetention if cfg.retention else \
             LatentAttention if cfg.latent else CachedAttention
         a, new_cache = attention(cfg, name="attn")(
             _norm(cfg, "ln_1")(x), decode=decode, deterministic=deterministic,
-            kv_cache=kv_cache, block_hint=block_hint, layer=layer)
+            kv_cache=kv_cache, layer=layer)
         stats = ()      # a routed FFN's counts follow (x, cache)
 
         def mlp(h):
@@ -1284,14 +1253,12 @@ class _ScanBlock(nn.Module):
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, carry, decode, deterministic, block_hint,
-                 experts=None):
+    def __call__(self, carry, decode, deterministic, experts=None):
         x, cache, start, li = carry
         cfg = self.config
         cls = TransformerBlock
         if cfg.remat:
-            cls = nn.remat(cls, prevent_cse=False,
-                           static_argnums=(2, 3, 5))
+            cls = nn.remat(cls, prevent_cse=False, static_argnums=(2, 3))
         block = cls(cfg, name="block")
         # the scan's counter and the model's expert leaves reach a block
         # only where its configuration reads them
@@ -1300,8 +1267,7 @@ class _ScanBlock(nn.Module):
         # a layer's output beside the carry: what its routed FFN counted
         # (stacked over the layers by the scan), nothing for a dense FFN
         if cache is None:
-            x, _, *stats = block(x, decode, deterministic, None, block_hint,
-                                 *more)
+            x, _, *stats = block(x, decode, deterministic, None, *more)
             return (x, None, start, li + 1 if more else li), tuple(stats)
         if "s" in cache:
             # a recurrent state: the stacked leaf goes to the block whole
@@ -1309,7 +1275,7 @@ class _ScanBlock(nn.Module):
             # alias it); "rows" / "valid" pass through
             x, leaves, *stats = block(x, decode, deterministic,
                                       dict(cache, start=start, layer=li),
-                                      block_hint, *more)
+                                      *more)
             return (x, dict(cache, **leaves), start, li + 1), tuple(stats)
         if "table" in cache:
             # the "table*" entries are the POOL-WIDE page tables (slots,
@@ -1319,14 +1285,14 @@ class _ScanBlock(nn.Module):
                       if key.startswith("table")}
             x, leaves, *stats = block(x, decode, deterministic,
                                       dict(cache, start=start, layer=li),
-                                      block_hint, *more)
+                                      *more)
             return (x, dict(leaves, **tables), start, li + 1), tuple(stats)
         kv_slice = {key: jax.lax.dynamic_index_in_dim(val, li, 0,
                                                       keepdims=False)
                     for key, val in cache.items()}
         kv_slice["start"] = start
         x, new_slice, *stats = block(x, decode, deterministic, kv_slice,
-                                     block_hint, *more)
+                                     *more)
         cache = {key: jax.lax.dynamic_update_slice_in_dim(
             val, new_slice[key][None], li, 0) for key, val in cache.items()}
         return (x, cache, start, li + 1), tuple(stats)
@@ -1402,6 +1368,139 @@ def page_lanes(page_size: int) -> int:
     return -(-page_size // 128) * 128
 
 
+# What a cache kind does not run with yet, in ONE place: (kind, feature) ->
+# the mechanism in its way. Every constructor that turns a feature on asks
+# ``KVCacheSpec.refusal`` (``TransformerConfig``, which is what a spec is
+# made from, asks :func:`refusal` itself) and raises the sentence it gets;
+# none keeps a copy. Lifting a refusal is deleting its row; a new kind adds
+# its rows here and edits no constructor (ROADMAP.md, Reach, has the queue).
+CACHE_KINDS = {     # in the order a refusal is looked up (cache_kinds)
+    "state": "a recurrent state",           # power_retention layers
+    "latent": "latent attention's cache",   # one row a token (kv_lora_rank)
+    "window_only": "sliding-window layers alone",
+    "window": "a window page group",        # sliding beside full layers
+    "layer_types": "layer_types",           # a layer reads its kind off
+    # the scan's counter (a window group is one case)
+    "routed": "a routed FFN",               # the module's, not the cache's
+}
+FEATURES = {        # feature -> how its refusal names it, and who asks
+    "spec_decode": "spec_decode",                           # ServingEngine
+    "paged_kv": "paged_kv",         # ServingEngine, PagedKVPool, paged_cache
+    "prefix_cache": "prefix_cache",                         # PagedKVPool
+    "roles": "prefill/decode roles",    # ServingEngine, import_pages
+    "tensor_parallel": "tensor-parallel inference",         # InferenceEngine
+    "tensor_parallel_serving": "tensor-parallel serving",   # ServingEngine
+    "prefill_chunk_wider_than_window":                      # ServingEngine
+        "a prefill_chunk wider than sliding_window",
+    "zero_inference": "ZeRO-Inference",             # ZeroInferenceEngine
+    "kv_cache_quant": "kv_cache_quant",             # TransformerConfig
+    "int8_weights": "int8_weights",                 # TransformerConfig
+}
+CACHE_REFUSALS = {
+    ("state", "spec_decode"):
+        "a rejected draft's tokens are in the state for good: verify_k's "
+        "rollback moves an index, and a state has none (it would have to "
+        "keep the state from before the draft)",
+    ("state", "paged_kv"):
+        "a state has no positions to page, and a prefix hit would need a "
+        "snapshot of the state at the hit's boundary; a state group beside "
+        "paged K/V is a later change",
+    ("state", "roles"):
+        "pages are the unit of a handoff and a state has none: the state "
+        "itself would have to be shipped",
+    ("state", "tensor_parallel"):
+        "the state leaves have no placement on the model axis and the "
+        "retention kernels are not wrapped for a mesh",
+    ("state", "zero_inference"):
+        "a power_retention layer's recurrent state is not threaded through "
+        "the streamed layers",
+    ("state", "kv_cache_quant"):
+        "kv_cache_quant quantizes K/V columns; a power_retention layer "
+        "keeps a float32 state and no column",
+    ("latent", "spec_decode"):
+        "the latent read takes one query row a slot or one slot's chunk; a "
+        "verify step's K + 1 rows of every slot, each with its own causal "
+        "limit, have no program yet",
+    ("latent", "roles"):
+        "a handoff ships K/V pages; a pool of latent pages has not been "
+        "driven through one",
+    ("latent", "tensor_parallel_serving"):
+        "every head reads the one cached row, so the latent leaf has no "
+        "placement on the model axis and the read is not wrapped for a mesh",
+    ("latent", "zero_inference"):
+        "latent attention's one cached row a token is not threaded through "
+        "the streamed layers",
+    ("latent", "kv_cache_quant"):
+        "kv_cache_quant quantizes K/V columns a head; the latent cache is "
+        "one row a token that every head reads, and its scales have no leaf",
+    ("latent", "int8_weights"):
+        "int8_weights does not reach latent attention: kv_b_proj is read as "
+        "a matrix (absorbed into the query and the output), not through a "
+        "Dense",
+    ("window_only", "paged_kv"):
+        "a model of sliding-window layers only has no full page group",
+    ("window", "spec_decode"):
+        "verify_k's rollback would have to un-recycle window pages",
+    ("window", "prefix_cache"):
+        "a hit maps pages of the prompt's start, which a ring has recycled "
+        "(pass paged_kv={'prefix_cache': False})",
+    ("window", "roles"):
+        "a handoff would have to ship the window ring",
+    ("window", "tensor_parallel_serving"):
+        "the window group's leaves have no placement on the model axis",
+    ("window", "prefill_chunk_wider_than_window"):
+        "a chunk's rows read the pages behind it while its own are mapped, "
+        "more than the ring a slot is granted",
+    ("layer_types", "zero_inference"):
+        "a layer reads its kind off the layer scan's counter, which the "
+        "streamed layers do not carry",
+    ("layer_types", "kv_cache_quant"):
+        "the window group's pages and the window mask exist for the "
+        "full-precision tier only",
+    ("routed", "spec_decode"):
+        "the drafter has no routed FFN",
+    ("routed", "roles"):
+        "a server of a routed model has not been driven through a handoff "
+        "(the one served has a window ring too, which would have to be "
+        "shipped)",
+    ("routed", "tensor_parallel_serving"):
+        "the expert leaves have no placement on the expert axis when served",
+    ("routed", "zero_inference"):
+        "it streams one layer's block parameters at a time; the routed "
+        "FFN's expert leaves are stacked parameters of the model",
+    ("routed", "kv_cache_quant"):
+        "no routed model has run on the int8 cache tier (the one served has "
+        "a window group too, which exists for the full-precision tier only)",
+    ("routed", "int8_weights"):
+        "int8_weights does not reach the routed FFN's expert leaves "
+        "(ops/quantization quantizes Dense kernels); serve the routed "
+        "model in bf16",
+}
+
+
+def cache_kinds(cfg: TransformerConfig) -> tuple:
+    """The kinds of ``CACHE_KINDS`` a configuration is, in its order."""
+    groups = kv_cache_groups(cfg)
+    has = {"state": cfg.retention, "latent": cfg.latent,
+           "window_only": groups is not None and not groups[0][1],
+           "window": groups is not None,
+           "layer_types": cfg.layer_types is not None and not cfg.retention,
+           "routed": cfg.n_experts}
+    return tuple(kind for kind in CACHE_KINDS if has[kind])
+
+
+def refusal(kinds: tuple, feature: str) -> Optional[str]:
+    """Why a model of these ``kinds`` does not run with ``feature`` yet (the
+    first of its kinds that has a row), or None where it does."""
+    name = FEATURES[feature]    # a closed set: an unknown feature is a bug
+    for kind in kinds:
+        why = CACHE_REFUSALS.get((kind, feature))
+        if why is not None:
+            return (f"{name} does not compose with {CACHE_KINDS[kind]} yet: "
+                    f"{why}")
+    return None
+
+
 @dataclasses.dataclass(frozen=True)
 class KVCacheSpec:
     """Module-declared KV-cache allocation contract: everything an engine
@@ -1433,6 +1532,20 @@ class KVCacheSpec:
     # one row a token a layer that every head reads. Such a cache holds
     # ``c`` (L, B, latent, S) positions-minor and no k / v; a page pool's
     # leaf is (L, P, latent, lanes), a page one whole-tile block a layer
+    kinds: tuple = ()                  # cache_kinds(cfg): what refusal() reads
+
+    def refusal(self, feature: str, prefill_chunk: int = 0) -> Optional[str]:
+        """The sentence of ``CACHE_REFUSALS`` for ``feature`` with this
+        cache's model, or None: what the constructor that turns ``feature``
+        on raises. ``prefill_chunk``: the width asked of
+        ``prefill_chunk_wider_than_window``."""
+        why = refusal(self.kinds, feature)
+        if why and feature == "prefill_chunk_wider_than_window":
+            window = self.groups[1][2]
+            return None if prefill_chunk <= window else (
+                f"{why} (prefill_chunk {prefill_chunk}, sliding_window "
+                f"{window})")
+        return why
 
     @property
     def state_bytes_per_row(self) -> int:
@@ -1498,8 +1611,9 @@ class KVCacheSpec:
         ``stacked_cache`` layout the attention kernels consume. Same
         dtype/packing tiers as the contiguous container (int8/packed
         cache columns page exactly like full-precision ones)."""
-        if self.state is not None:
-            raise ValueError("a recurrent state has no positions to page")
+        why = self.refusal("paged_kv")
+        if why:
+            raise ValueError(why)
         lanes = page_lanes(page_size)
         if self.latent:
             return {"c": jnp.zeros((self.n_layer, num_pages, self.latent,
@@ -1594,7 +1708,7 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
                        dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
                        quantized=cfg.kv_cache_quant, packed=packed,
                        groups=kv_cache_groups(cfg), state=state,
-                       latent=cfg.latent)
+                       latent=cfg.latent, kinds=cache_kinds(cfg))
 
 
 def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:
@@ -1710,7 +1824,7 @@ class TransformerLM(nn.Module):
                 variable_axes={"params": 0},
                 split_rngs={"params": True, "dropout": True},
                 length=length,
-                in_axes=(nn.broadcast,) * (4 if config.n_experts else 3),
+                in_axes=(nn.broadcast,) * (3 if config.n_experts else 2),
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(config, name=name)
 
@@ -1735,8 +1849,8 @@ class TransformerLM(nn.Module):
                                   dtype=jnp.float32, name="lm_head")
 
     def _transform(self, input_ids, positions, decode, deterministic,
-                   block_hint=None, head=True, paged_table=None,
-                   state_rows=None, valid_len=None):
+                   head=True, paged_table=None, state_rows=None,
+                   valid_len=None):
         cfg = self.config
         B, T = input_ids.shape
         x = self.embed_tokens(input_ids)
@@ -1771,10 +1885,9 @@ class TransformerLM(nn.Module):
                         jnp.asarray(valid_len, jnp.int32), (B,))
             carry = (x, cache, start, jnp.zeros((), jnp.int32))
             if cfg.first_k_dense:
-                carry, _ = self.dense_blocks(carry, decode, deterministic,
-                                             block_hint)
+                carry, _ = self.dense_blocks(carry, decode, deterministic)
             (x, cache, _, _), stats = self.blocks(
-                carry, decode, deterministic, block_hint, *more)
+                carry, decode, deterministic, *more)
             if stats and self.is_mutable_collection("stats"):
                 # a caller that asks for the "stats" collection gets what
                 # the routed FFN counted in this call, over its layers
@@ -1793,12 +1906,11 @@ class TransformerLM(nn.Module):
             if cfg.first_k_dense:
                 # (a scan without a cache counts layers only where its
                 # configuration reads the counter: set it)
-                (x, *_), _ = self.dense_blocks(carry, decode, deterministic,
-                                               block_hint)
+                (x, *_), _ = self.dense_blocks(carry, decode, deterministic)
                 carry = (x, None, carry[2],
                          jnp.full((), cfg.first_k_dense, jnp.int32))
             (x, _, _, _), _ = self.blocks(carry, decode, deterministic,
-                                          block_hint, *more)
+                                          *more)
         x = self.ln_f(x)
         if not head:
             return x  # pre-projection hidden states (streaming loss path)
@@ -1897,25 +2009,19 @@ class TransformerLM(nn.Module):
             xb, i, 1, 0))(x, idx)
         return self._project_head(x)
 
-    def decode(self, input_ids, start_pos, block_hint=None, rows=None):
+    def decode(self, input_ids, start_pos, rows=None):
         """One (or few) token step against the cache; ``start_pos`` is the
         current cache length — scalar for a B-uniform batch, or (B,) for
         slot-pooled decode where every sequence sits at its own offset
-        (continuous batching). Call with ``mutable=["cache"]``.
-        ``block_hint`` (STATIC int) overrides the fused kernel's block
-        granule — an explicit expert option; engine.generate keeps the
-        allocation-based default after a budget-derived hint measured
-        net-negative (grid overhead dominates dead-row reads;
-        PERF.md §8, the block_hint lead). ``rows`` (B,), for a model with
-        a recurrent state: the cache row of each entry, out of range for
-        an entry that does not run, whose row's state stays bit for bit
-        (a K/V model hides such an entry's column behind its index and
-        takes no ``rows``)."""
+        (continuous batching). Call with ``mutable=["cache"]``. ``rows``
+        (B,), for a model with a recurrent state: the cache row of each
+        entry, out of range for an entry that does not run, whose row's
+        state stays bit for bit (a K/V model hides such an entry's column
+        behind its index and takes no ``rows``)."""
         B, T = input_ids.shape
         off = start_pos[:, None] if jnp.ndim(start_pos) == 1 else start_pos
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-        return self._transform(input_ids, pos, True, True, block_hint,
-                               state_rows=rows)
+        return self._transform(input_ids, pos, True, True, state_rows=rows)
 
     def decode_paged(self, input_ids, start_pos, table):
         """Fused paged-kernel decode step: like :meth:`decode`, but the

@@ -50,20 +50,6 @@ LONG_CACHE_BLOCK_S = 4096  # >= 8k caches: grid overhead, not bandwidth,
 # clamp elides the rest), a sub-ms cost
 
 
-def preferred_block_for(live_len: int) -> int:
-    """Preferred decode block for an EXPECTED LIVE length (prompt +
-    budget), as opposed to the allocated capacity. NOTE the measured
-    e2e A/B came out NEGATIVE for auto-deriving the block from the
-    budget (every arm 5-15% slower at live 1536/4352 in an 8k cache):
-    the index-map clamp already elides dead-block DMA, so decode at
-    these shapes is grid-overhead bound and fewer, larger grid steps
-    win even when the last live block is mostly dead (PERF.md §8,
-    the block_hint lead). engine.generate therefore keeps the
-    allocation-based block; this helper + the ``block_hint`` plumbing
-    remain for callers with measured wins at their own shapes."""
-    return LONG_CACHE_BLOCK_S if live_len >= 8192 else DEFAULT_BLOCK_S
-
-
 def pick_block_s(cache_len: int, preferred: Optional[int] = None) -> int:
     """Largest power-of-two block <= preferred that divides the cache
     length (the kernel requires S % block_s == 0). Returns the largest

@@ -288,57 +288,29 @@ class ServingEngine:
         # paged_kv: False (contiguous rows), True (paged, defaults), or a
         # dict {"num_pages": int, "page_size": int, "prefix_cache": bool}
         capacity = int(spec.max_seq_len)
-        # a recurrent state in the K/V's place (power_retention layers):
-        # what it does not compose with yet refuses here, by mechanism,
-        # before anything is allocated (ROADMAP.md, Reach)
+        # what the cache's kind does not run with yet refuses here, in the
+        # table's words, before anything is allocated
+        # (models/transformer_lm.py, CACHE_REFUSALS); the pool asks for
+        # prefix_cache itself
+        mesh = getattr(engine, "mesh", None)
+        asked = {
+            "spec_decode": spec_decode,
+            "paged_kv": paged_kv,
+            "roles": role != "both",
+            "tensor_parallel_serving":
+                mesh is not None and mesh.shape.get("model", 1) > 1,
+            "prefill_chunk_wider_than_window": paged_kv,
+        }
+        for feature, on in asked.items():
+            why = spec.refusal(feature, prefill_chunk) if on else None
+            if why:
+                raise ValueError(why)
+        # the bytes of a row's recurrent state (power_retention layers) and
+        # of a token's one latent row over the layers (KVCacheSpec.latent),
+        # for the counters; 0 for K/V a head
         self._state_row_bytes = int(getattr(spec, "state_bytes_per_row", 0))
-        if self._state_row_bytes:
-            mesh = getattr(engine, "mesh", None)
-            refused = [
-                (spec_decode, "spec_decode",
-                 "a rejected draft's tokens are in the state for good: "
-                 "verify_k's rollback moves an index, and a state has none "
-                 "(it would have to keep the state from before the draft)"),
-                (paged_kv, "paged_kv",
-                 "a state has no positions to page, and a prefix hit would "
-                 "need a snapshot of the state at the hit's boundary; a "
-                 "state group beside paged K/V is a later change"),
-                (role != "both", "prefill/decode roles",
-                 "pages are the unit of a handoff and a state has none: the "
-                 "state itself would have to be shipped"),
-                (mesh is not None and mesh.shape.get("model", 1) > 1,
-                 "tensor-parallel serving",
-                 "the state leaves have no placement on the model axis"),
-            ]
-            for given, what, why in refused:
-                if given:
-                    raise ValueError(f"{what} does not compose with a "
-                                     f"recurrent state yet: {why}")
-        # latent attention (one cached row a token, KVCacheSpec.latent):
-        # the bytes of a token over the layers, and what its cache does
-        # not compose with yet, by mechanism (ROADMAP.md, Reach)
         self._latent_token_bytes = int(getattr(spec, "latent", 0)) \
             * np.dtype(spec.dtype).itemsize * int(spec.n_layer)
-        if self._latent_token_bytes:
-            mesh = getattr(engine, "mesh", None)
-            refused = [
-                (spec_decode, "spec_decode",
-                 "the latent read takes one query row a slot or one slot's "
-                 "chunk; a verify step's K + 1 rows of every slot, each "
-                 "with its own causal limit, have no program yet"),
-                (role != "both", "prefill/decode roles",
-                 "a handoff ships K/V pages; a pool of latent pages has "
-                 "not been driven through one"),
-                (mesh is not None and mesh.shape.get("model", 1) > 1,
-                 "tensor-parallel serving",
-                 "every head reads the one cached row, so the latent leaf "
-                 "has no placement on the model axis and the read is not "
-                 "wrapped for a mesh"),
-            ]
-            for given, what, why in refused:
-                if given:
-                    raise ValueError(f"{what} does not compose with latent "
-                                     f"attention's cache yet: {why}")
         if paged_kv:
             knobs = dict(paged_kv) if isinstance(paged_kv, dict) else {}
             page_size = knobs.pop("page_size", None)
@@ -368,36 +340,6 @@ class ServingEngine:
             # (a model with sliding-window layers keeps full-length rows
             # here: what left a window is masked, not freed)
             self.pool = SlotPool(spec, num_slots, sharding=rep)
-        # what a window page group or a routed FFN does not compose with
-        # yet refuses here, by mechanism (ROADMAP.md, Reach)
-        grouped = getattr(spec, "groups", None) is not None
-        routed = bool(getattr(getattr(
-            getattr(engine, "_serve_module", None) or engine.module,
-            "config", None), "n_experts", 0))
-        if grouped or routed:
-            what = "sliding-window layers" if grouped else "a routed FFN"
-            if spec_decode:
-                raise ValueError(
-                    f"spec_decode does not compose with {what} yet: "
-                    f"verify_k's rollback would have to un-recycle window "
-                    f"pages, and the drafter has no routed FFN")
-            mesh = getattr(engine, "mesh", None)
-            if mesh is not None and mesh.shape.get("model", 1) > 1:
-                raise ValueError(
-                    f"tensor-parallel serving does not compose with "
-                    f"{what} yet: the expert leaves have no placement on "
-                    f"the expert axis when served and the window group's "
-                    f"leaves none on the model axis")
-            if grouped and paged_kv and prefill_chunk > spec.groups[1][2]:
-                raise ValueError(
-                    f"prefill_chunk ({prefill_chunk}) is wider than "
-                    f"sliding_window ({spec.groups[1][2]}): a chunk's rows "
-                    f"read the pages behind it while its own are mapped, more "
-                    f"than the ring a slot is granted")
-            if role != "both":
-                raise ValueError(
-                    f"prefill/decode roles do not compose with {what} "
-                    f"yet: a handoff would have to ship the window ring")
         self._paged = isinstance(self.pool, PagedKVPool)
         self._spec = None
         self._drafter = None

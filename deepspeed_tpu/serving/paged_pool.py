@@ -78,8 +78,9 @@ not page-aligned is written). The kernels take a group's leaf and table
 and, for the window group, start at the first visible entry and mask
 positions ``<= i - sliding_window`` inside it. A model without layer
 kinds has exactly the single group it always had. What a ring does not
-compose with yet refuses at construction: the prefix cache (what a hit
-means for pages that were recycled), cross-pool page transfer.
+compose with yet refuses at construction, in the words of the one table
+(``models/transformer_lm.py``, ``CACHE_REFUSALS``): the prefix cache (what
+a hit means for pages that were recycled), cross-pool page transfer.
 
 Latent pages (PR 38): a model with latent attention
 (``KVCacheSpec.latent``) keeps ONE leaf, ``c`` ``(L, num_pages, W,
@@ -252,17 +253,11 @@ class PagedKVPool(SlotPool):
                  kernel: str = "auto"):
         if kernel not in ("auto", "on", "off"):
             raise ValueError(f"kernel must be auto|on|off, got {kernel!r}")
-        groups = getattr(spec, "groups", None)
-        if groups is not None:
-            if prefix_cache:
-                raise ValueError(
-                    "prefix_cache does not compose with a window page "
-                    "group yet: a hit maps pages of the prompt's start, "
-                    "which a ring has recycled (pass paged_kv="
-                    "{'prefix_cache': False}; ROADMAP.md, Reach)")
-            if not groups[0][1]:
-                raise ValueError("a model of sliding-window layers only "
-                                 "has no full page group: not supported")
+        why = (prefix_cache and spec.refusal("prefix_cache")) \
+            or spec.refusal("paged_kv")
+        if why:
+            raise ValueError(why)
+        groups = spec.groups
         capacity = int(spec.max_seq_len)
         page_size = int(page_size)
         if page_size < 1:
@@ -685,10 +680,9 @@ class PagedKVPool(SlotPool):
         before the exception propagates (the :meth:`ensure_writable`
         unwind template), so a mid-transfer death leaks nothing on
         either pool."""
-        if self.ring is not None or src_pool.ring is not None:
-            raise ValueError(
-                "cross-pool page transfer does not know a window page "
-                "group yet (a handoff would have to ship the ring too)")
+        why = self.spec.refusal("roles") or src_pool.spec.refusal("roles")
+        if why:
+            raise ValueError(why)
         ids = [int(p) for p in src_page_ids]
         if len(ids) > self.pages_per_slot:
             raise ValueError(

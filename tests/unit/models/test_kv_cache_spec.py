@@ -44,3 +44,40 @@ def test_packed_auto_engages_on_aligned_head_dim():
     off = dataclasses.replace(cfg, kv_cache_packed=False)
     dtype, cache_d, packed = kv_cache_spec(off)
     assert (dtype, cache_d, packed) == (jnp.int8, 16, False)
+
+
+@pytest.mark.parametrize("routed", [False, True])
+def test_remat_hands_a_block_its_layer_and_its_experts(routed):
+    """``_ScanBlock`` names ``TransformerBlock``'s static arguments to
+    ``nn.remat`` by POSITION (decode, deterministic) and passes the scan's
+    counter and the expert leaves after the cache: with ``remat`` on, a
+    model of layer kinds (and a routed FFN) gives the logits and the decode
+    step of the same model without it, to the bit."""
+    import jax
+    import numpy as np
+
+    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
+                                                     transformer_config)
+
+    sizes = dict(vocab_size=128, max_seq_len=64, n_embd=32, n_layer=4,
+                 n_head=4, n_kv_head=2, head_size=8, ffn_dim=16,
+                 layer_types=["sliding_attention", "full_attention"] * 2,
+                 sliding_window=16, dtype=jnp.float32,
+                 **(dict(n_experts=4, experts_per_token=2) if routed else {}))
+    ids = jnp.asarray(np.random.default_rng(0).integers(1, 128, (2, 12)),
+                      jnp.int32)
+    outs = []
+    for remat in (False, True):
+        model = TransformerLM(transformer_config("mellum", remat=remat,
+                                                 **sizes))
+        params = model.init(jax.random.PRNGKey(0), ids,
+                            method=model.logits)["params"]
+        logits = model.apply({"params": params}, ids, method=model.logits)
+        _, cache = model.apply({"params": params}, ids, method=model.prefill,
+                               mutable=["cache"])
+        step, _ = model.apply({"params": params, "cache": cache["cache"]},
+                              ids[:, :1], jnp.asarray(12),
+                              method=model.decode, mutable=["cache"])
+        outs.append((np.asarray(logits), np.asarray(step)))
+    np.testing.assert_array_equal(outs[0][0], outs[1][0])
+    np.testing.assert_array_equal(outs[0][1], outs[1][1])

@@ -91,6 +91,8 @@ def test_pick_block_s():
     assert pick_block_s(16384) == 4096
     assert pick_block_s(4096) == 1024
     assert pick_block_s(97) == 1
+    # a caller's preference (TransformerConfig.decode_block) wins over it
+    assert pick_block_s(16384, preferred=1024) == 1024
 
 
 def test_model_decode_kernel_matches_jnp_path():
@@ -294,49 +296,6 @@ def test_packed_int8_kv_cache_matches_unpacked(B, H, KV, D, S, block):
                                lengths, k_scale=ks, v_scale=vs,
                                block_s=block)
     np.testing.assert_array_equal(np.asarray(out_i32), np.asarray(out_s8))
-
-
-def test_block_hint_changes_block_not_tokens():
-    """An explicit block hint must only change the kernel's block
-    granule, never the outputs (the engine keeps the allocation-based
-    default — the budget-derived hint measured net-negative)."""
-    import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
-    from deepspeed_tpu.ops.attention.decode_attention import (
-        pick_block_s,
-        preferred_block_for,
-    )
-
-    # the hint table: short budgets take the 1024 block, long the 4096
-    assert preferred_block_for(1536) == 1024
-    assert preferred_block_for(9000) == 4096
-    assert pick_block_s(16384, preferred=1024) == 1024
-
-    prompts = np.arange(6, dtype=np.int32)[None] % 32
-    cfg = TransformerConfig(vocab_size=32, max_seq_len=256, n_embd=64,
-                            n_layer=2, n_head=2, dtype=jnp.float32,
-                            decode_kernel="on", kv_cache_quant=True)
-    m = TransformerLM(cfg)
-    eng = ds.init_inference(m, config={"dtype": "fp32"})
-    toks_auto = eng.generate(prompts, max_new_tokens=8)
-
-    # drive decode directly with an explicit tiny block hint: same logits
-    params = eng._params_host
-    _, vars_ = m.apply({"params": params}, prompts, method=m.prefill,
-                       mutable=["cache"])
-    step = jnp.asarray([[7]], jnp.int32)
-    pos = jnp.asarray(prompts.shape[1], jnp.int32)
-    l_default, _ = m.apply({"params": params, "cache": vars_["cache"]},
-                           step, pos, method=m.decode, mutable=["cache"])
-    l_hint, _ = m.apply({"params": params, "cache": vars_["cache"]},
-                        step, pos, method=m.decode, mutable=["cache"],
-                        block_hint=64)
-    np.testing.assert_allclose(np.asarray(l_hint), np.asarray(l_default),
-                               rtol=2e-5, atol=2e-5)
-    assert toks_auto.shape == (1, prompts.shape[1] + 8)
 
 
 def test_prefill_last_matches_full_prefill():

@@ -256,7 +256,7 @@ def test_pipeline_tied_head_shares_params():
 
 def test_pipeline_transformer_block_layerspec():
     """The REAL TransformerBlock — signature (x, decode, deterministic,
-    kv_cache, block_hint), returning (x, new_cache) — must work as a
+    kv_cache, ...), returning (x, new_cache) — must work as a
     LayerSpec block: the executors detect the decode_det call mode and
     unpack the tuple return."""
     from deepspeed_tpu.models.transformer_lm import (TransformerBlock,

@@ -1,0 +1,151 @@
+"""The one table of what a cache kind does not run with yet
+(``models/transformer_lm.py``, ``CACHE_REFUSALS``): for EVERY row, the real
+constructor that turns the feature on, over a tiny model of that kind and no
+other, raises the row's own sentence. A row added without a way to reach it
+here fails (``PRESETS`` / ``ASK`` have no entry for it)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.inference.zero_inference import ZeroInferenceEngine
+from deepspeed_tpu.models.transformer_lm import (CACHE_KINDS, CACHE_REFUSALS,
+                                                 FEATURES, TransformerLM,
+                                                 cache_kinds,
+                                                 transformer_config)
+from deepspeed_tpu.serving import ServingEngine
+
+from .conftest import TINY
+
+WINDOW, PAGE = 16, 8
+_LLAMA = dict(TINY, max_seq_len=128, n_layer=4, head_size=8)
+# a configuration a kind, each of that kind alone (beside what it implies)
+PRESETS = {
+    "state": ("brumby", dict(TINY, n_kv_head=2, head_size=16, ffn_dim=48)),
+    "latent": ("moonlight", dict(
+        TINY, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=8, scoring_func="softmax")),
+    "window_only": ("llama", dict(
+        _LLAMA, layer_types=["sliding_attention"] * 4,
+        sliding_window=WINDOW)),
+    "window": ("llama", dict(
+        _LLAMA, layer_types=["sliding_attention", "full_attention"] * 2,
+        sliding_window=WINDOW)),
+    "layer_types": ("llama", dict(_LLAMA,
+                                  layer_types=["full_attention"] * 4)),
+    "routed": ("llama", dict(TINY, ffn_dim=16, n_experts=4,
+                             experts_per_token=2)),
+}
+IMPLIED = {"window_only": {"window", "layer_types"},
+           "window": {"layer_types"}}
+_OFF = {"page_size": PAGE, "prefix_cache": False, "kernel": "off"}
+
+
+def _config(kind, **over):
+    family, sizes = PRESETS[kind]
+    return transformer_config(family, **{**sizes, **over})
+
+
+def test_each_preset_is_of_its_kind_alone():
+    assert set(PRESETS) == set(CACHE_KINDS)
+    for kind in PRESETS:
+        assert set(cache_kinds(_config(kind))) \
+            == {kind} | IMPLIED.get(kind, set()), kind
+
+
+@pytest.fixture(scope="module")
+def engine_of():
+    """kind -> an inference engine over its tiny model, built on first
+    use."""
+    built = {}
+
+    def get(kind, mesh=None):
+        if (kind, mesh) not in built:
+            model = TransformerLM(_config(kind))
+            params = model.init({"params": jax.random.PRNGKey(0)},
+                                jnp.zeros((1, 8), jnp.int32),
+                                method=model.logits)["params"]
+            built[kind, mesh] = ds.init_inference(
+                model=model, model_parameters=params,
+                config={"dtype": "float32"}, mesh=mesh)
+        return built[kind, mesh]
+
+    return get
+
+
+class _TwoWayModelAxis:
+    shape = {"model": 2, "data": 1}
+
+
+def _serve(**kw):
+    def ask(kind, engine_of, monkeypatch):
+        ServingEngine(engine_of(kind), num_slots=2,
+                      **{"prefill_chunk": PAGE, **kw})
+    return ask
+
+
+def _serve_on_a_model_axis(kind, engine_of, monkeypatch):
+    engine = engine_of(kind)
+    monkeypatch.setattr(engine, "mesh", _TwoWayModelAxis(), raising=False)
+    ServingEngine(engine, num_slots=2, prefill_chunk=PAGE)
+
+
+def _infer_on_a_model_axis(kind, engine_of, monkeypatch):
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+
+    if jax.device_count() < 2:
+        pytest.skip("needs two devices")
+    mesh = mesh_mod.initialize_mesh(model=2, data=jax.device_count() // 2)
+    try:
+        engine_of(kind, mesh)._ensure_params(jnp.zeros((1, 2), jnp.int32))
+    finally:
+        mesh_mod.reset_mesh()
+
+
+def _configure(feature):
+    return lambda kind, engine_of, monkeypatch: _config(kind,
+                                                        **{feature: True})
+
+
+# feature -> the constructor that turns it on (and nothing else the kind
+# refuses)
+ASK = {
+    "spec_decode": _serve(spec_decode={"k": 2}),
+    "paged_kv": _serve(paged_kv=_OFF),
+    "prefix_cache": _serve(paged_kv={"page_size": PAGE, "kernel": "off"}),
+    "roles": _serve(role="decode"),
+    "tensor_parallel": _infer_on_a_model_axis,
+    "tensor_parallel_serving": _serve_on_a_model_axis,
+    "prefill_chunk_wider_than_window": _serve(prefill_chunk=2 * WINDOW,
+                                              paged_kv=_OFF),
+    "zero_inference": lambda kind, engine_of, monkeypatch:
+        ZeroInferenceEngine(_config(kind), {}),
+    "kv_cache_quant": _configure("kv_cache_quant"),
+    "int8_weights": _configure("int8_weights"),
+}
+
+
+@pytest.mark.parametrize("kind,feature", sorted(CACHE_REFUSALS))
+def test_every_row_refuses_at_its_constructor_in_its_own_words(
+        kind, feature, engine_of, monkeypatch):
+    assert set(ASK) == set(FEATURES)
+    with pytest.raises(ValueError) as refusal:
+        ASK[feature](kind, engine_of, monkeypatch)
+    said = str(refusal.value)
+    assert said.startswith(f"{FEATURES[feature]} does not compose with "
+                           f"{CACHE_KINDS[kind]} yet: "), said
+    assert CACHE_REFUSALS[kind, feature] in said
+
+
+def test_a_kind_with_no_row_constructs(engine_of):
+    """The table refuses what it lists and nothing else: the plain model
+    takes every server feature, a window group its pages."""
+    spec = TransformerLM(transformer_config("llama", **TINY)).kv_cache_spec()
+    assert spec.kinds == () and not any(
+        spec.refusal(feature, 10 ** 6) for feature in FEATURES)
+    srv = ServingEngine(engine_of("window"), num_slots=2,
+                        prefill_chunk=PAGE, paged_kv=_OFF)
+    assert srv.pool.ring is not None
+    with pytest.raises(KeyError):
+        spec.refusal("no_such_feature")

@@ -7,6 +7,7 @@ and preemption at the engine level, client-cancellation rollback
 bridge (streaming, cancellation, backpressure, drain-on-shutdown)."""
 
 import asyncio
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -612,21 +613,43 @@ class TestBridge:
 
     def test_rejected_submit_yields_single_terminal_event(self, stack):
         srv = self._srv(stack, num_slots=1, max_queue_depth=1)
+        # the engine thread stands still after its first step, the first
+        # request in the one slot, until the two later submits are both in
+        # its inbox: it then takes them one after the other, no step (and
+        # no finished request) between them
+        in_slot, inbox_full = threading.Event(), threading.Event()
+        real_step = srv.step
+
+        def step_then_stand():
+            real_step()
+            in_slot.set()
+            assert inbox_full.wait(timeout=60)
+
+        srv.step = step_then_stand
+
+        async def release():
+            inbox_full.set()
 
         async def run():
             bridge = AsyncEngineBridge(srv, idle_poll_s=0.005)
             await bridge.start()
             try:
                 # fill slot + queue, then overflow
-                await bridge.submit([1, 2], max_new_tokens=16)
-                await bridge.submit([1, 2], max_new_tokens=16)
-                req, stream = await bridge.submit([1, 2], max_new_tokens=4)
+                first, _ = await bridge.submit([1, 2], max_new_tokens=16)
+                assert await asyncio.get_running_loop().run_in_executor(
+                    None, in_slot.wait, 60)
+                held = first.state
+                _, (req, stream), _ = await asyncio.gather(
+                    bridge.submit([1, 2], max_new_tokens=16),
+                    bridge.submit([1, 2], max_new_tokens=4), release())
                 events = await _collect(stream)
             finally:
+                inbox_full.set()
                 await bridge.stop()
-            return req, events
+            return held, req, events
 
-        req, events = asyncio.run(run())
+        held, req, events = asyncio.run(run())
+        assert held in (RequestState.PREFILLING, RequestState.RUNNING)
         assert req.state is RequestState.REJECTED
         assert len(events) == 1
         assert events[0]["reason"] == "rejected"
