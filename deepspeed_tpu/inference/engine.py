@@ -322,7 +322,7 @@ class InferenceEngine:
 
         chunk_gen = getattr(module, "prefill_chunk", None)
         spec = self.kv_cache_spec()
-        has_state = getattr(spec, "state", None) is not None
+        state_leaves = tuple(getattr(spec, "state_leaves", ()))
         why = spec.refusal("tensor_parallel") \
             if spec is not None and self.mp_world_size > 1 else None
         if why:
@@ -342,29 +342,25 @@ class InferenceEngine:
             arrive with the chunk's ids as one vector
             (:func:`pack_chunk_args`): one transfer a chunk.
 
-            A recurrent state (``KVCacheSpec.state``) is not sliced: the
-            stacked leaves go in whole with the row's number, the chunk
-            kernel rewrites that row's blocks in place and no other row
-            is read or written (a slice would copy one row's state of
-            every layer out and back, 0.27 GB each way at the served
-            size)."""
+            A recurrent state (``KVCacheSpec.state_leaves``) is not
+            sliced: the stacked leaves go in whole with the row's number,
+            the chunk kernel rewrites that row's blocks in place and no
+            other row is read or written (a slice would copy one row's
+            state of every layer out and back, 0.27 GB each way at the
+            served size). K/V leaves beside a state group are sliced as
+            ever."""
             cs = cache["cache_store"]
             ids, slot, start, length, last_idx = unpack_chunk_args(packed)
-            if has_state:
-                out, vars_ = module.apply(
-                    {"params": dequant(params),
-                     "cache": {"cache_store": dict(cs, index=start[None])}},
-                    ids, start[None], last_idx, slot[None],
-                    method=chunk_gen, mutable=["cache"])
-                new = vars_["cache"]["cache_store"]
-                return out, {"cache_store": dict(
-                    new, index=cs["index"].at[slot].set(start + length))}
             row = {k: jax.lax.dynamic_slice_in_dim(v, slot, 1, 1)
-                   for k, v in cs.items() if k != "index"}
-            row["index"] = start[None]
+                   for k, v in cs.items()
+                   if k != "index" and k not in state_leaves}
+            whole = {k: cs[k] for k in state_leaves}
+            rows = (slot[None],) if state_leaves else ()
             out, vars_ = module.apply(
-                {"params": dequant(params), "cache": {"cache_store": row}},
-                ids, start[None], last_idx, method=chunk_gen,
+                {"params": dequant(params),
+                 "cache": {"cache_store": dict(row, **whole,
+                                               index=start[None])}},
+                ids, start[None], last_idx, *rows, method=chunk_gen,
                 mutable=["cache"])
             new = vars_["cache"]["cache_store"]
 
@@ -374,7 +370,8 @@ class InferenceEngine:
                 return jax.lax.dynamic_update_slice(
                     dst, src.astype(dst.dtype), idx)
 
-            merged = {k: write(cs[k], new[k]) for k in cs if k != "index"}
+            merged = {k: write(cs[k], new[k]) for k in row}
+            merged.update({k: new[k] for k in whole})
             merged["index"] = cs["index"].at[slot].set(start + length)
             return out, {"cache_store": merged}
 

@@ -64,6 +64,17 @@ such a model may carry a plain gated FFN (``dense_ffn_dim``) before the
 routed layers: they are a scan of their own (``dense_blocks``) in front of
 ``blocks``, the layer counter and the cache running through both.
 
+A fifth, ``mamba`` (:class:`Mamba2Mixer`), stands BESIDE ``attention``
+layers (full, position-free or rotary) in one model, one attention layer a
+repeating period. The two kinds have different parameter trees, so the
+stack is two stacked leaves (``mamba_blocks``, ``attn_blocks``) run in the
+published order by :meth:`TransformerLM._hybrid_layers`, and the cache is
+two groups: a state group over the mamba layers
+(``KVCacheSpec.state_group``: ``s``, the state, and ``conv``, the
+convolution's last inputs; a row a sequence, no positions) and K/V over the
+attention layers, contiguous or paged. A page pool keeps the state group
+beside its pages.
+
 KV-cache decoding uses the flax ``cache`` variable collection: ``prefill``
 writes the prompt's K/V at positions [0, T), ``decode`` appends one position
 via ``lax.dynamic_update_slice`` and attends over the static-shape cache with
@@ -178,24 +189,46 @@ class TransformerConfig:
     first_k_dense: int = 0              # the first layers' FFN is a plain
     # gated FFN of dense_ffn_dim; the routed FFN starts after them
     dense_ffn_dim: Optional[int] = None
+    # Mamba-2 layers (``layer_types`` "mamba", beside "attention" layers
+    # without a window): heads of mamba_d_head, a state of mamba_d_state
+    # columns a head, B and C shared by the heads (one group), a causal
+    # depthwise convolution of mamba_d_conv taps over [x ; B ; C]
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    embedding_multiplier: float = 1.0   # scales the token embedding
+    embedding_init_std: Optional[float] = None  # a seeded embedding's
+    # spread where it is not flax's 1 / sqrt(n_embd): under a tied head a
+    # position's own input token scores its row's share of the stream
+    attention_multiplier: Optional[float] = None    # the softmax scale
+    # where it is not 1 / sqrt(head_dim)
+    residual_multiplier: float = 1.0    # x + r * a, x + r * ffn
+    logits_scaling: float = 1.0         # the logits are divided by it
 
     def __post_init__(self):
         if self.layer_types is not None:
             kinds = set(self.layer_types) - {"sliding_attention",
                                              "full_attention",
-                                             "power_retention"}
+                                             "power_retention",
+                                             "mamba", "attention"}
             if kinds or len(self.layer_types) != self.n_layer:
                 raise ValueError(
                     f"layer_types names n_layer={self.n_layer} layers as "
-                    f"sliding_attention | full_attention | power_retention; "
+                    f"sliding_attention | full_attention | power_retention "
+                    f"| mamba | attention; "
                     f"got {len(self.layer_types)} entries, unknown "
                     f"{sorted(kinds)}")
+            if {"mamba", "attention"} & set(self.layer_types):
+                self._check_hybrid()
             if "power_retention" in self.layer_types:
                 if set(self.layer_types) != {"power_retention"}:
                     raise ValueError(
-                        "power_retention layers beside attention layers "
-                        "need a state group beside the K/V leaves of one "
-                        "cache, which no pool keeps yet: every layer is "
+                        "power_retention layers beside attention layers: "
+                        "the retention state is one leaf over every layer "
+                        "of the model (KVCacheSpec.state; the state group "
+                        "beside K/V is the mamba layers'): every layer is "
                         "power_retention or none is (ROADMAP.md, Reach)")
                 if self.head_dim % 8 or self.n_head // self.kv_heads \
                         >= self.head_dim:
@@ -253,6 +286,34 @@ class TransformerConfig:
             if why:
                 raise ValueError(why)
 
+    def _check_hybrid(self) -> None:
+        """``mamba`` layers stand beside ``attention`` layers (full, with
+        ``pos_emb`` "rotary" or "none") in a pattern that repeats: one
+        attention layer a period, the same number of mamba layers before
+        and after it in every period (:attr:`hybrid_period`)."""
+        types = self.layer_types
+        n_att = types.count("attention")
+        if set(types) != {"mamba", "attention"} or self.n_layer % n_att \
+                or types != types[:self.n_layer // n_att] * n_att:
+            raise ValueError(
+                f"mamba and attention layers come as a pattern with ONE "
+                f"attention layer that repeats over the layers (the two "
+                f"stacked leaves are run period by period); got "
+                f"{list(types)}")
+        if not (self.mamba_n_heads and self.mamba_d_head
+                and self.mamba_d_state) or self.mamba_n_groups != 1 \
+                or self.mamba_d_conv < 2:
+            raise ValueError(
+                f"mamba layers need mamba_n_heads, mamba_d_head and "
+                f"mamba_d_state, one group (B and C shared by the heads) "
+                f"and a convolution of two taps or more; got "
+                f"{self.mamba_n_heads} x {self.mamba_d_head}, state "
+                f"{self.mamba_d_state}, groups {self.mamba_n_groups}, "
+                f"taps {self.mamba_d_conv}")
+        if self.n_experts or self.parallel_residual:
+            raise ValueError("mamba layers know the sequential residual "
+                             "and a plain FFN (n_experts 0)")
+
     @property
     def head_dim(self) -> int:
         return self.head_size or self.n_embd // self.n_head
@@ -284,6 +345,27 @@ class TransformerConfig:
         """Every layer is ``power_retention``: a state, no K/V."""
         return self.layer_types is not None \
             and "power_retention" in self.layer_types
+
+    @property
+    def mamba(self) -> bool:
+        """``mamba`` layers beside ``attention`` layers: a state group
+        over the former, K/V over the latter."""
+        return self.layer_types is not None and "mamba" in self.layer_types
+
+    @property
+    def hybrid_period(self) -> tuple:
+        """``(before, after, periods)``: the mamba layers before and after
+        a period's one attention layer, and how many periods there are."""
+        periods = self.layer_types.count("attention")
+        period = self.layer_types[:self.n_layer // periods]
+        before = period.index("attention")
+        return before, len(period) - before - 1, periods
+
+    @property
+    def mamba_channels(self) -> int:
+        """The convolution's channels: ``[x ; B ; C]``."""
+        return self.mamba_n_heads * self.mamba_d_head \
+            + 2 * self.mamba_n_groups * self.mamba_d_state
 
 
 FAMILY_PRESETS = {
@@ -322,6 +404,14 @@ FAMILY_PRESETS = {
                       qkv_bias=False, mlp_bias=False,
                       tie_word_embeddings=False, layer_norm_epsilon=1e-5,
                       scoring_func="sigmoid"),
+    # Granite 4.0 hybrid (IBM; model_type granitemoehybrid): Mamba-2 layers
+    # beside position-free GQA layers (``layer_types`` as published), a
+    # SwiGLU in every layer, tied head, four scalars. Widths, the pattern
+    # and the scalars are the caller's.
+    "granite-hybrid": dict(pos_emb="none", norm="rmsnorm",
+                           activation="swiglu", qkv_bias=False,
+                           mlp_bias=False, tie_word_embeddings=True,
+                           layer_norm_epsilon=1e-5),
 }
 
 
@@ -756,6 +846,10 @@ class CachedAttention(nn.Module):
         B, T, C = x.shape
         H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
         q, k, v = _project_qkv(cfg, x)
+        if cfg.attention_multiplier is not None:
+            # every path below (and the kernels) scales by 1 / sqrt(D):
+            # the query carries what the published scale differs by
+            q = q * (cfg.attention_multiplier * math.sqrt(D))
 
         kv_packed = kv_cache_spec(cfg)[2]
         if decode:
@@ -781,12 +875,12 @@ class CachedAttention(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
 
         is_window = None    # traced: this layer is a sliding-window layer
-        if cfg.layer_types is not None:
+        if cfg.layer_types is not None and not cfg.mamba:
             inv_freq, factor, windows = layer_rope_tables(cfg)
             is_window = jnp.asarray(windows)[layer]
         if cfg.pos_emb == "rotary":
             rd = int(cfg.rotary_pct * D) // 2 * 2
-            if cfg.layer_types is not None:
+            if is_window is not None:
                 rope = (jnp.asarray(inv_freq)[layer],
                         jnp.asarray(factor)[layer], rd)
                 q = apply_rotary_table(q, positions, *rope)
@@ -1164,6 +1258,117 @@ class LatentAttention(nn.Module):
         return o_proj(y.astype(cfg.dtype).reshape(B, T, H * dv)), new_cache
 
 
+def _uniform_log(lo: float, hi: float, inverse=None):
+    """An initializer: values drawn log-uniformly from ``lo`` to ``hi``,
+    then through ``inverse`` (what the module applies to the parameter)."""
+    def init(key, shape, dtype=jnp.float32):
+        v = lo * (hi / lo) ** jax.random.uniform(key, shape)
+        return (v if inverse is None else inverse(v)).astype(dtype)
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer in the attention's place
+    (``ops/state_space.py`` has the state's equations). With ``u`` the
+    normed input::
+
+        [z ; xBC ; dt] = W_in u         xBC <- silu(conv(xBC))
+        [x ; B ; C] = xBC               dt <- softplus(dt + dt_bias)
+        H_t = exp(dt_t A) H_{t-1} + dt_t x_t (x) B_t      A = -exp(A_log)
+        y_t = H_t C_t + D x_t
+        out = W_out (w (.) g / rms(g))          g = y (.) silu(z)
+
+    ``conv`` is causal and depthwise over ``mamba_d_conv`` taps with a
+    bias; the gate comes BEFORE the norm. Three forms of that one
+    mathematics. Without a cache: whole sequences from an empty state
+    (``ssm_sequence``). With one, ``kv_cache`` holds the stacked leaves
+    whole, ``s`` (the state, float32) and ``conv`` (the last
+    ``mamba_d_conv - 1`` inputs of the convolution, time-major on the minor
+    axis: ``(L, rows, (taps - 1) * channels)`` in the model's dtype), with
+    ``layer``, ``start`` and, as :class:`PowerRetention`, ``rows`` and
+    ``valid``: one token takes ``ssm_decode``, more take ``ssm_chunk``
+    block by block. A token at or past ``valid`` is padding: it advances
+    neither the state (its ``dt`` is 0) nor the tail (taken at the last
+    real token). An entry whose first position is 0 reads neither. The
+    convolution and the tail's shift are XLA's, under the scope
+    ``ssm_conv``."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, *, decode: Union[bool, str] = False,
+                 deterministic: bool = True, kv_cache=None, layer=None):
+        from ..ops import state_space as ss
+
+        cfg = self.config
+        B, T, C = u.shape
+        H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+        inner, ch, K = H * P, cfg.mamba_channels, cfg.mamba_d_conv
+        f32 = jnp.float32
+        # W_in as two leaves, [z ; xBC] and dt: the published matrix is
+        # 2 * inner + 2 * N + H wide (8,512 at the published widths, no
+        # multiple of 128 lanes), and the chip's client stores such a leaf
+        # with the OTHER dimension minor, which every program then copies
+        # whole before its layer loop (1.25 GB a step there: compiled for
+        # a described v5e, PR 47). z and xBC are whole lane tiles; dt's 64
+        # columns are a leaf of their own, small enough to copy
+        zx = _dense(cfg, inner + ch, use_bias=False, name="in_proj")(u)
+        z, xbc = zx[..., :inner], zx[..., inner:]
+        dt = _dense(cfg, H, use_bias=False, name="dt_proj")(u)
+        bound = 1.0 / math.sqrt(K)      # (torch's Conv1d default)
+        taps = nn.initializers.uniform(2 * bound)
+        conv_w = self.param("conv_w", lambda *a: taps(*a) - bound, (K, ch))
+        conv_b = self.param("conv_b", lambda *a: taps(*a) - bound, (ch,))
+        a = -jnp.exp(self.param("A_log", _uniform_log(1.0, 16.0, jnp.log),
+                                (H,)).astype(f32))
+        skip = self.param("D", nn.initializers.ones, (H,)).astype(f32)
+        dt_bias = self.param(
+            "dt_bias", _uniform_log(1e-3, 1e-1, lambda v: v + jnp.log(
+                -jnp.expm1(-v))), (H,)).astype(f32)
+        gate_norm = self.param("norm", nn.initializers.ones, (inner,))
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)          # (B, T, H)
+
+        tail = jnp.zeros((B, K - 1, ch), xbc.dtype)
+        valid = jnp.full((B,), T, jnp.int32)
+        cached = bool(decode)
+        if cached:
+            start = kv_cache["start"]
+            li = kv_cache["layer"]
+            rows = kv_cache.get("rows")
+            if rows is None:
+                rows = jnp.arange(B, dtype=jnp.int32)
+            R = kv_cache["s"].shape[1]
+            fresh = jnp.broadcast_to(start == 0, (B,))
+            if kv_cache.get("valid") is not None:
+                valid = jnp.minimum(kv_cache["valid"], T)
+            # this layer's tail of each entry's row (an entry that does
+            # not run: row R, read as zeros and dropped when written)
+            at = (li, jnp.where((rows >= 0) & (rows < R), rows, R))
+            tail = jnp.where(fresh[:, None, None], 0, kv_cache["conv"].at[
+                at].get(mode="fill", fill_value=0).reshape(tail.shape))
+        xbc, tail = ss.causal_conv(xbc, tail, conv_w, conv_b, valid)
+        if cached:
+            conv_leaf = kv_cache["conv"].at[at].set(
+                tail.reshape(B, -1).astype(kv_cache["conv"].dtype),
+                mode="drop")
+        x = xbc[..., :inner].reshape(B, T, H, P)
+        b, c = xbc[..., inner:inner + N], xbc[..., inner + N:]
+        if not cached:
+            y = ss.ssm_sequence(x, dt, a, b, c)
+        elif T == 1:
+            y, s = ss.ssm_decode(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0],
+                                 kv_cache["s"], li, rows, fresh)
+            y = y[:, None]
+        else:
+            y, s = ss.ssm_prefill(x, dt, a, b, c, kv_cache["s"], li, rows,
+                                  fresh, length=valid)
+        y = (y + skip[:, None] * x).reshape(B, T, inner)
+        g = ss.gated_norm(y, z, gate_norm, cfg.layer_norm_epsilon)
+        out = _dense(cfg, C, use_bias=False, name="out_proj")(
+            g.astype(cfg.dtype))
+        return out, ({"s": s, "conv": conv_leaf} if cached else None)
+
+
 class TransformerMLP(nn.Module):
     config: TransformerConfig
 
@@ -1188,15 +1393,19 @@ class TransformerMLP(nn.Module):
 
 class TransformerBlock(nn.Module):
     config: TransformerConfig
+    kind: Optional[str] = None      # "mamba" | "attention": the layer's
+    # kind in a model of mamba and attention layers, whose stacked leaves
+    # are one a kind; None: the configuration's one mixer
 
     @nn.compact
     def __call__(self, x, decode: Union[bool, str] = False,
                  deterministic: bool = True, kv_cache=None, layer=None,
                  experts=None):
         cfg = self.config
-        attention = PowerRetention if cfg.retention else \
-            LatentAttention if cfg.latent else CachedAttention
-        a, new_cache = attention(cfg, name="attn")(
+        attention, name = (Mamba2Mixer, "mamba") if self.kind == "mamba" \
+            else (PowerRetention if cfg.retention else
+                  LatentAttention if cfg.latent else CachedAttention, "attn")
+        a, new_cache = attention(cfg, name=name)(
             _norm(cfg, "ln_1")(x), decode=decode, deterministic=deterministic,
             kv_cache=kv_cache, layer=layer)
         stats = ()      # a routed FFN's counts follow (x, cache)
@@ -1217,12 +1426,22 @@ class TransformerBlock(nn.Module):
             stats = (layer_stats,)
             return m
 
+        r = cfg.residual_multiplier
+
+        def branch(h):      # what joins the residual stream, scaled once
+            return h if r == 1.0 else h * jnp.asarray(r, h.dtype)
+
         if cfg.parallel_residual:
-            x = x + a + mlp(_norm(cfg, "ln_2")(x))
+            x = x + branch(a) + branch(mlp(_norm(cfg, "ln_2")(x)))
         else:
-            x = x + a
-            x = x + mlp(_norm(cfg, "ln_2")(x))
+            x = x + branch(a)
+            x = x + branch(mlp(_norm(cfg, "ln_2")(x)))
         return (x, new_cache, *stats)
+
+
+# beside a state's leaves in the carry: which cache row each batch entry
+# is and where its real tokens end (TransformerLM._transform)
+STATE_ROW_KEYS = ("rows", "valid")
 
 
 class _ScanBlock(nn.Module):
@@ -1236,7 +1455,8 @@ class _ScanBlock(nn.Module):
       buffer the quantized cache above ~100 MB through their xs/ys pair
       (PERF.md §8, the carry-DUS lead); the carry-DUS of a
       batch-major dense row did not.
-    - a recurrent state (``"s"`` in the carry, :class:`PowerRetention`):
+    - a recurrent state (``KVCacheSpec.state_leaves``, a layer of
+      :class:`PowerRetention` or :class:`Mamba2Mixer`):
       whole like a page pool's leaves, for the same reason and one more:
       a slice would be one layer's state of EVERY row, 0.5 GB a layer at
       the served size, read and written for the one row a chunk runs.
@@ -1251,6 +1471,8 @@ class _ScanBlock(nn.Module):
       re-layouts (serve-pythia-1b4-chat, ledger, PR 24)."""
 
     config: TransformerConfig
+    kind: Optional[str] = None      # TransformerBlock.kind; the counter of
+    # such a layer counts the layers of its kind (its stacked leaves')
 
     @nn.compact
     def __call__(self, carry, decode, deterministic, experts=None):
@@ -1259,7 +1481,7 @@ class _ScanBlock(nn.Module):
         cls = TransformerBlock
         if cfg.remat:
             cls = nn.remat(cls, prevent_cse=False, static_argnums=(2, 3))
-        block = cls(cfg, name="block")
+        block = cls(cfg, self.kind, name="block")
         # the scan's counter and the model's expert leaves reach a block
         # only where its configuration reads them
         more = (li, experts) if (cfg.layer_types is not None
@@ -1269,32 +1491,30 @@ class _ScanBlock(nn.Module):
         if cache is None:
             x, _, *stats = block(x, decode, deterministic, None, *more)
             return (x, None, start, li + 1 if more else li), tuple(stats)
-        if "s" in cache:
-            # a recurrent state: the stacked leaf goes to the block whole
-            # and comes back whole (its kernels index (layer, row) and
-            # alias it); "rows" / "valid" pass through
+        state_leaves = make_kv_cache_spec(cfg).state_leaves
+        if "table" in cache or (state_leaves and self.kind != "attention"):
+            # a page pool (the "table*" entries are the POOL-WIDE page
+            # tables (slots, pages_per_slot), one a layer group, shared by
+            # the group's layers and never written) or a recurrent state:
+            # the stacked leaves go to the block whole and come back whole
+            # (its kernels index (layer, page) or (layer, row) and alias
+            # them); whatever else rides the carry passes through
             x, leaves, *stats = block(x, decode, deterministic,
                                       dict(cache, start=start, layer=li),
                                       *more)
             return (x, dict(cache, **leaves), start, li + 1), tuple(stats)
-        if "table" in cache:
-            # the "table*" entries are the POOL-WIDE page tables (slots,
-            # pages_per_slot), one a layer group, shared by the group's
-            # layers and never written
-            tables = {key: val for key, val in cache.items()
-                      if key.startswith("table")}
-            x, leaves, *stats = block(x, decode, deterministic,
-                                      dict(cache, start=start, layer=li),
-                                      *more)
-            return (x, dict(leaves, **tables), start, li + 1), tuple(stats)
-        kv_slice = {key: jax.lax.dynamic_index_in_dim(val, li, 0,
+        # (an attention layer beside state layers slices its K/V alone)
+        sliced = [key for key in cache
+                  if key not in state_leaves + STATE_ROW_KEYS]
+        kv_slice = {key: jax.lax.dynamic_index_in_dim(cache[key], li, 0,
                                                       keepdims=False)
-                    for key, val in cache.items()}
+                    for key in sliced}
         kv_slice["start"] = start
         x, new_slice, *stats = block(x, decode, deterministic, kv_slice,
                                      *more)
-        cache = {key: jax.lax.dynamic_update_slice_in_dim(
-            val, new_slice[key][None], li, 0) for key, val in cache.items()}
+        cache = dict(cache, **{
+            key: jax.lax.dynamic_update_slice_in_dim(
+                cache[key], new_slice[key][None], li, 0) for key in sliced})
         return (x, cache, start, li + 1), tuple(stats)
 
 
@@ -1376,6 +1596,7 @@ def page_lanes(page_size: int) -> int:
 # its rows here and edits no constructor (ROADMAP.md, Reach, has the queue).
 CACHE_KINDS = {     # in the order a refusal is looked up (cache_kinds)
     "state": "a recurrent state",           # power_retention layers
+    "ssm": "a state group beside K/V",      # mamba beside attention layers
     "latent": "latent attention's cache",   # one row a token (kv_lora_rank)
     "window_only": "sliding-window layers alone",
     "window": "a window page group",        # sliding beside full layers
@@ -1402,9 +1623,11 @@ CACHE_REFUSALS = {
         "rollback moves an index, and a state has none (it would have to "
         "keep the state from before the draft)",
     ("state", "paged_kv"):
-        "a state has no positions to page, and a prefix hit would need a "
-        "snapshot of the state at the hit's boundary; a state group beside "
-        "paged K/V is a later change",
+        "a state has no positions to page, a model whose every layer keeps "
+        "one has no attention layer whose K/V a page pool would hold, and a "
+        "prefix hit would need a snapshot of the state at the hit's "
+        "boundary (a state group rides beside paged K/V where a model has "
+        "both)",
     ("state", "roles"):
         "pages are the unit of a handoff and a state has none: the state "
         "itself would have to be shipped",
@@ -1417,6 +1640,33 @@ CACHE_REFUSALS = {
     ("state", "kv_cache_quant"):
         "kv_cache_quant quantizes K/V columns; a power_retention layer "
         "keeps a float32 state and no column",
+    ("ssm", "spec_decode"):
+        "a rejected draft's tokens are in the state and the convolution's "
+        "tail for good: verify_k's rollback moves an index, which hides "
+        "K/V columns and nothing of a state",
+    ("ssm", "prefix_cache"):
+        "a hit maps the K/V pages of the prompt's start and would need the "
+        "state as it stood at the hit's boundary, which nothing keeps (a "
+        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
+    ("ssm", "roles"):
+        "pages are the unit of a handoff: the slot's state rows would have "
+        "to be shipped beside them",
+    ("ssm", "tensor_parallel"):
+        "the state leaves have no placement on the model axis and the "
+        "state-space kernels are not wrapped for a mesh",
+    ("ssm", "tensor_parallel_serving"):
+        "the state leaves have no placement on the model axis and the "
+        "state-space kernels are not wrapped for a mesh",
+    ("ssm", "zero_inference"):
+        "it streams one layer's block parameters at a time out of ONE "
+        "stacked tree; mamba and attention layers are two, and the state is "
+        "not threaded through the streamed layers",
+    ("ssm", "kv_cache_quant"):
+        "kv_cache_quant quantizes K/V columns; the state group beside them "
+        "is float32 and the tier has not been run beside it",
+    ("ssm", "int8_weights"):
+        "int8_weights does not reach the mamba layers' convolution, A_log, "
+        "D and dt_bias, which are parameters of the mixer and no Dense",
     ("latent", "spec_decode"):
         "the latent read takes one query row a slot or one slot's chunk; a "
         "verify step's K + 1 rows of every slot, each with its own causal "
@@ -1481,10 +1731,11 @@ CACHE_REFUSALS = {
 def cache_kinds(cfg: TransformerConfig) -> tuple:
     """The kinds of ``CACHE_KINDS`` a configuration is, in its order."""
     groups = kv_cache_groups(cfg)
-    has = {"state": cfg.retention, "latent": cfg.latent,
+    has = {"state": cfg.retention, "ssm": cfg.mamba, "latent": cfg.latent,
            "window_only": groups is not None and not groups[0][1],
            "window": groups is not None,
-           "layer_types": cfg.layer_types is not None and not cfg.retention,
+           "layer_types": cfg.layer_types is not None
+           and not (cfg.retention or cfg.mamba),
            "routed": cfg.n_experts}
     return tuple(kind for kind in CACHE_KINDS if has[kind])
 
@@ -1522,17 +1773,46 @@ class KVCacheSpec:
     groups: Optional[tuple] = None     # kv_cache_groups(cfg): the layers
     # of each group of a page pool; the contiguous containers below keep
     # every layer at full length (a window layer's old columns are masked)
-    state: Optional[tuple] = None      # a model of power_retention layers:
-    # the shape of one KV head's recurrent state
-    # (ops/attention/power_retention.state_shape), float32. Such a cache
-    # holds ``s`` (L, B, KV, *state) and no k / v: its size does not depend
-    # on max_seq_len, which stays the bound on positions
-
     latent: int = 0                    # latent attention: the width of the
     # one row a token a layer that every head reads. Such a cache holds
     # ``c`` (L, B, latent, S) positions-minor and no k / v; a page pool's
     # leaf is (L, P, latent, lanes), a page one whole-tile block a layer
     kinds: tuple = ()                  # cache_kinds(cfg): what refusal() reads
+    state_group: Optional[tuple] = None    # the layers that keep a state a
+    # row (no positions) and their leaves: ``(layers, ((leaf, shape a row
+    # a layer, dtype), ...))``. A leaf of the cache is ``(layers, rows,
+    # *shape)``; a row belongs to a sequence (a slot of a pool: a page pool
+    # keeps the group beside its page leaves, ``num_slots`` rows). A model
+    # of power_retention layers: every layer, one leaf ``s`` ``(KV,
+    # *state)`` float32. A model of mamba layers beside attention layers:
+    # the mamba layers, ``s`` (ops/state_space.state_shape) float32 and
+    # ``conv`` (the convolution's last taps - 1 inputs, time-major) in
+    # ``dtype``; the attention layers keep K/V (:attr:`kv_layers`). A
+    # state's size does not depend on max_seq_len, which stays the bound on
+    # positions
+
+    @property
+    def state_leaves(self) -> tuple:
+        """The leaves that hold a state a row (no positions): what a
+        program is told the running rows for."""
+        return tuple(leaf for leaf, _, _ in self.state_group[1]) \
+            if self.state_group else ()
+
+    @property
+    def state(self) -> Optional[tuple]:
+        """A model with a state in EVERY layer and no k / v
+        (power_retention): the shape of one KV head's state
+        (ops/attention/power_retention.state_shape); None otherwise."""
+        if self.state_group and not self.kv_layers:
+            return self.state_group[1][0][1][1:]
+        return None
+
+    @property
+    def kv_layers(self) -> int:
+        """The layers that keep K/V (or a latent row): the first dimension
+        of those leaves. ``n_layer`` less the state group's."""
+        return self.n_layer - (self.state_group[0] if self.state_group
+                               else 0)
 
     def refusal(self, feature: str, prefill_chunk: int = 0) -> Optional[str]:
         """The sentence of ``CACHE_REFUSALS`` for ``feature`` with this
@@ -1550,18 +1830,24 @@ class KVCacheSpec:
     @property
     def state_bytes_per_row(self) -> int:
         """Bytes of one sequence's state over the layers (0: a K/V cache)."""
-        if self.state is None:
+        if not self.state_group:
             return 0
-        return 4 * self.n_layer * self.kv_heads * math.prod(self.state)
+        layers, leaves = self.state_group
+        return layers * sum(math.prod(shape) * np.dtype(dtype).itemsize
+                            for _, shape, dtype in leaves)
 
-    def _state_cache(self, lead: tuple) -> dict:
-        return {"s": jnp.zeros(lead + (self.kv_heads,) + self.state,
-                               jnp.float32)}
+    def _state_cache(self, rows: int, stacked: bool = True) -> dict:
+        """The zeroed state leaves of ``rows`` sequences: one layer's, or
+        ``stacked`` over the layers that keep a state."""
+        layers, leaves = self.state_group
+        lead = ((layers,) if stacked else ()) + (rows,)
+        return {leaf: jnp.zeros(lead + shape, dtype)
+                for leaf, shape, dtype in leaves}
 
     def layer_cache(self, batch_size: int) -> dict:
         """Zeroed single-layer k/v dict: (B, KV, cache_d, S) [+ scales]."""
-        if self.state is not None:
-            return self._state_cache((batch_size,))
+        if not self.kv_layers:
+            return self._state_cache(batch_size, stacked=False)
         if self.latent:
             return {"c": jnp.zeros((batch_size, self.latent,
                                     self.max_seq_len), self.dtype)}
@@ -1579,9 +1865,9 @@ class KVCacheSpec:
         variables: k/v (L, B, KV, cache_d, S) [+ scales (L, B, KV, S)],
         plus a per-sequence ``index`` (B,) int32 — the vector-start form
         CachedAttention accepts for slot-pooled decode."""
-        L = self.n_layer
-        if self.state is not None:
-            return dict(self._state_cache((L, batch_size)),
+        L = self.kv_layers
+        if not L:
+            return dict(self._state_cache(batch_size),
                         index=jnp.zeros((batch_size,), jnp.int32))
         if self.latent:
             return {"c": jnp.zeros((L, batch_size, self.latent,
@@ -1592,6 +1878,8 @@ class KVCacheSpec:
         cache = {"k": jnp.zeros(shape, self.dtype),
                  "v": jnp.zeros(shape, self.dtype),
                  "index": jnp.zeros((batch_size,), jnp.int32)}
+        if self.state_group:
+            cache.update(self._state_cache(batch_size))
         if self.quantized:
             sshape = (L, batch_size, self.kv_heads, self.max_seq_len)
             cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
@@ -1600,7 +1888,8 @@ class KVCacheSpec:
 
     # -- paged KV (PagedAttention-style block pool) --------------------
     def paged_cache(self, num_pages: int, page_size: int,
-                    window_pages: Optional[int] = None) -> dict:
+                    window_pages: Optional[int] = None,
+                    num_slots: int = 0) -> dict:
         """Zeroed PAGE-POOL k/v arrays: the positions axis is split into
         ``num_pages`` physical pages of ``page_size`` columns each, with
         NO batch axis — k/v (L, P, KV, cache_d, lanes) [+ scales
@@ -1610,13 +1899,14 @@ class KVCacheSpec:
         pages; :meth:`dense_from_pages` reassembles the
         ``stacked_cache`` layout the attention kernels consume. Same
         dtype/packing tiers as the contiguous container (int8/packed
-        cache columns page exactly like full-precision ones)."""
+        cache columns page exactly like full-precision ones). A state
+        group is not paged: ``num_slots`` rows beside the page leaves."""
         why = self.refusal("paged_kv")
         if why:
             raise ValueError(why)
         lanes = page_lanes(page_size)
         if self.latent:
-            return {"c": jnp.zeros((self.n_layer, num_pages, self.latent,
+            return {"c": jnp.zeros((self.kv_layers, num_pages, self.latent,
                                     lanes), self.dtype)}
         if self.groups is not None:
             # one stacked leaf a group: ``num_pages`` pages for the full
@@ -1627,12 +1917,14 @@ class KVCacheSpec:
                     for (suffix, layers, _), pages in zip(
                         self.groups, (num_pages, window_pages))
                     for key in ("k", "v")}
-        shape = (self.n_layer, num_pages, self.kv_heads, self.cache_d,
+        shape = (self.kv_layers, num_pages, self.kv_heads, self.cache_d,
                  lanes)
         cache = {"k": jnp.zeros(shape, self.dtype),
                  "v": jnp.zeros(shape, self.dtype)}
+        if self.state_group:
+            cache.update(self._state_cache(num_slots))
         if self.quantized:
-            sshape = (self.n_layer, num_pages, self.kv_heads, lanes)
+            sshape = (self.kv_layers, num_pages, self.kv_heads, lanes)
             cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
             cache["v_scale"] = jnp.zeros(sshape, jnp.float32)
         return cache
@@ -1671,7 +1963,8 @@ class KVCacheSpec:
         B, max_pages = table.shape
         ps = self.max_seq_len // max_pages
         flat = table.reshape(-1)
-        out = {}
+        # (a state group is rows of the pool already: it rides along)
+        out = {key: paged[key] for key in self.state_leaves}
         if self.latent:
             leaf = paged["c"]                       # (L, P, W, lanes)
             g = jnp.take(leaf, flat, axis=1, mode="clip")[..., :ps]
@@ -1698,17 +1991,27 @@ class KVCacheSpec:
 
 def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
     cache_dtype, cache_d, packed = kv_cache_spec(cfg)
-    state = None
+    group = None
     if cfg.retention:
         from ..ops.attention.power_retention import state_shape
 
-        state = state_shape(cfg.head_dim)
+        group = (cfg.n_layer, (
+            ("s", (cfg.kv_heads,) + state_shape(cfg.head_dim),
+             jnp.float32),))
+    if cfg.mamba:
+        from ..ops.state_space import state_shape
+
+        group = (cfg.layer_types.count("mamba"), (
+            ("s", state_shape(cfg.mamba_n_heads, cfg.mamba_d_head,
+                              cfg.mamba_d_state), jnp.float32),
+            ("conv", ((cfg.mamba_d_conv - 1) * cfg.mamba_channels,),
+             cache_dtype)))
     return KVCacheSpec(n_layer=cfg.n_layer, kv_heads=cfg.kv_heads,
                        head_dim=cfg.head_dim, cache_d=cache_d,
                        dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
                        quantized=cfg.kv_cache_quant, packed=packed,
-                       groups=kv_cache_groups(cfg), state=state,
-                       latent=cfg.latent, kinds=cache_kinds(cfg))
+                       groups=kv_cache_groups(cfg), latent=cfg.latent,
+                       kinds=cache_kinds(cfg), state_group=group)
 
 
 def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:
@@ -1764,10 +2067,25 @@ class _CacheStore(nn.Module):
                 cidx.value = new_index
             return values, cidx.value
         cache_dtype, cache_d, _ = kv_cache_spec(cfg)
+        state = {}
+        if cfg.mamba:
+            # K/V over the attention layers and a state group over the
+            # mamba layers (a provided cache passes through at its own
+            # shapes, as everywhere here)
+            spec = make_kv_cache_spec(cfg)
+            L = spec.kv_layers
+            state = {key: self.variable("cache", key, jnp.zeros, leaf.shape,
+                                        leaf.dtype)
+                     for key, leaf in jax.eval_shape(
+                         lambda: spec._state_cache(batch_size)).items()}
         shape = (L, batch_size, KV, cache_d, cfg.max_seq_len)
         ck = self.variable("cache", "k", jnp.zeros, shape, cache_dtype)
         cv = self.variable("cache", "v", jnp.zeros, shape, cache_dtype)
-        values = {"k": ck.value, "v": cv.value}
+        values = {"k": ck.value, "v": cv.value,
+                  **{key: var.value for key, var in state.items()}}
+        if new_values is not None:
+            for key, var in state.items():
+                var.value = new_values[key]
         if paged and kv_cache_groups(cfg) is not None:
             # a page pool of layer groups hands in a second pair of
             # leaves (always provided: the initializer never runs)
@@ -1811,14 +2129,16 @@ class TransformerLM(nn.Module):
 
     def setup(self):
         cfg = self.config
+        seeded = {} if cfg.embedding_init_std is None else {
+            "embedding_init": nn.initializers.normal(cfg.embedding_init_std)}
         self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype,
-                                     name="embed_tokens")
+                                     name="embed_tokens", **seeded)
         if cfg.pos_emb == "learned":
             self.embed_pos = nn.Embed(cfg.max_seq_len, cfg.n_embd, dtype=cfg.dtype,
                                       name="embed_pos")
         if cfg.embed_layernorm:
             self.embed_ln = _norm(cfg, "embed_ln")
-        def scan(config, length, name):
+        def scan(config, length, name, kind=None):
             return nn.scan(
                 _ScanBlock,
                 variable_axes={"params": 0},
@@ -1826,14 +2146,23 @@ class TransformerLM(nn.Module):
                 length=length,
                 in_axes=(nn.broadcast,) * (3 if config.n_experts else 2),
                 metadata_params={nn.PARTITION_NAME: "layers"},
-            )(config, name=name)
+            )(config, kind, name=name)
 
+        if cfg.mamba:
+            # two stacked leaves, one a layer kind; the scans own them and
+            # make them, :meth:`_hybrid_layers` runs them in the published
+            # order
+            n_att = cfg.layer_types.count("attention")
+            self.mamba_blocks = scan(cfg, cfg.n_layer - n_att,
+                                     "mamba_blocks", "mamba")
+            self.attn_blocks = scan(cfg, n_att, "attn_blocks", "attention")
         if cfg.first_k_dense:
             # the leading layers with a plain FFN: a scan of their own in
             # front, the carry (cache, layer counter) running through both
             self.dense_blocks = scan(cfg.dense_layers(), cfg.first_k_dense,
                                      "dense_blocks")
-        self.blocks = scan(cfg, cfg.n_layer - cfg.first_k_dense, "blocks")
+        if not cfg.mamba:
+            self.blocks = scan(cfg, cfg.n_layer - cfg.first_k_dense, "blocks")
         if cfg.n_experts:
             from ..moe.routed_ffn import ExpertLeaves
 
@@ -1848,12 +2177,64 @@ class TransformerLM(nn.Module):
             self.lm_head = _dense(head_cfg, cfg.vocab_size, use_bias=False,
                                   dtype=jnp.float32, name="lm_head")
 
+    def _hybrid_layers(self, carry, decode, deterministic):
+        """The layers of a model of mamba and attention layers, in the
+        published order, off the two stacked leaves: a scan over the
+        periods whose body scans the mamba layers before the period's
+        attention layer, runs that one, and scans those after it
+        (``[5, attention, 4]`` four times for 40 layers). The compiled
+        body holds the mamba block twice and the attention block once,
+        whatever the depth. A layer takes its slice of its kind's leaf by
+        its index among the layers of that kind, which is also where its
+        cache lies (``_ScanBlock``'s counter). Returns ``(x, cache)``."""
+        cfg = self.config
+        x, cache, start, _ = carry
+        if self.is_initializing():
+            # the scans make their leaves; the order of this one pass over
+            # an empty cache is nobody's
+            (x, *_), _ = self.mamba_blocks((x, None, start, carry[3]),
+                                           False, deterministic)
+            (x, *_), _ = self.attn_blocks((x, None, start, carry[3]),
+                                          False, deterministic)
+            return x, cache
+        before, after, periods = cfg.hybrid_period
+        leaves = {"mamba": self.variables["params"]["mamba_blocks"],
+                  "attention": self.variables["params"]["attn_blocks"]}
+        blocks = {kind: _ScanBlock(cfg, kind, parent=None)
+                  for kind in leaves}
+
+        def layer(kind, state, index):
+            x, cache = state
+            params = jax.tree_util.tree_map(lambda w: w[index], leaves[kind])
+            (x, cache, _, _), _ = blocks[kind].apply(
+                {"params": params}, (x, cache, start, index), decode,
+                deterministic)
+            return x, cache
+
+        def mamba_run(state, first, count):
+            if not count:
+                return state
+            return jax.lax.scan(
+                lambda st, j: (layer("mamba", st, first + j), None),
+                state, jnp.arange(count, dtype=jnp.int32))[0]
+
+        def period(state, p):
+            first = p * (before + after)
+            state = mamba_run(state, first, before)
+            state = layer("attention", state, p)
+            return mamba_run(state, first + before, after), None
+
+        return jax.lax.scan(period, (x, cache),
+                            jnp.arange(periods, dtype=jnp.int32))[0]
+
     def _transform(self, input_ids, positions, decode, deterministic,
                    head=True, paged_table=None, state_rows=None,
                    valid_len=None):
         cfg = self.config
         B, T = input_ids.shape
         x = self.embed_tokens(input_ids)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         if cfg.pos_emb == "learned":
             x = x + self.embed_pos(positions)
         if cfg.embed_layernorm:
@@ -1875,9 +2256,10 @@ class TransformerLM(nn.Module):
                 # writeback; see _ScanBlock)
                 cache = dict(cache, **(paged_table if isinstance(
                     paged_table, dict) else {"table": paged_table}))
-            if cfg.retention:
+            if cfg.retention or cfg.mamba:
                 # which cache row each batch entry is and where its real
-                # tokens end ride beside the state (PowerRetention)
+                # tokens end ride beside the state (PowerRetention,
+                # Mamba2Mixer)
                 if state_rows is not None:
                     cache["rows"] = jnp.asarray(state_rows, jnp.int32)
                 if valid_len is not None:
@@ -1886,8 +2268,12 @@ class TransformerLM(nn.Module):
             carry = (x, cache, start, jnp.zeros((), jnp.int32))
             if cfg.first_k_dense:
                 carry, _ = self.dense_blocks(carry, decode, deterministic)
-            (x, cache, _, _), stats = self.blocks(
-                carry, decode, deterministic, *more)
+            if cfg.mamba:
+                (x, cache), stats = self._hybrid_layers(
+                    carry, decode, deterministic), ()
+            else:
+                (x, cache, _, _), stats = self.blocks(
+                    carry, decode, deterministic, *more)
             if stats and self.is_mutable_collection("stats"):
                 # a caller that asks for the "stats" collection gets what
                 # the routed FFN counted in this call, over its layers
@@ -1897,7 +2283,7 @@ class TransformerLM(nn.Module):
                          init_fn=lambda: None, reduce_fn=lambda _, new: new)
             cache = {key: val for key, val in cache.items()
                      if not key.startswith("table")
-                     and key not in ("rows", "valid")}
+                     and key not in STATE_ROW_KEYS}
             self.cache_store(B, new_values=cache, new_index=start + T,
                              paged=paged)
         else:
@@ -1909,8 +2295,11 @@ class TransformerLM(nn.Module):
                 (x, *_), _ = self.dense_blocks(carry, decode, deterministic)
                 carry = (x, None, carry[2],
                          jnp.full((), cfg.first_k_dense, jnp.int32))
-            (x, _, _, _), _ = self.blocks(carry, decode, deterministic,
-                                          *more)
+            if cfg.mamba:
+                x, _ = self._hybrid_layers(carry, decode, deterministic)
+            else:
+                (x, _, _, _), _ = self.blocks(carry, decode, deterministic,
+                                              *more)
         x = self.ln_f(x)
         if not head:
             return x  # pre-projection hidden states (streaming loss path)
@@ -1920,8 +2309,12 @@ class TransformerLM(nn.Module):
         """The ONE vocabulary-projection path (scoring, generation
         prefill and decode all route here)."""
         if self.config.tie_word_embeddings:
-            return self.embed_tokens.attend(x.astype(jnp.float32))
-        return self.lm_head(x.astype(jnp.float32))
+            logits = self.embed_tokens.attend(x.astype(jnp.float32))
+        else:
+            logits = self.lm_head(x.astype(jnp.float32))
+        if self.config.logits_scaling != 1.0:
+            logits = logits / self.config.logits_scaling
+        return logits
 
     def logits(self, input_ids, deterministic: bool = True):
         B, T = input_ids.shape
@@ -2023,7 +2416,7 @@ class TransformerLM(nn.Module):
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))
         return self._transform(input_ids, pos, True, True, state_rows=rows)
 
-    def decode_paged(self, input_ids, start_pos, table):
+    def decode_paged(self, input_ids, start_pos, table, rows=None):
         """Fused paged-kernel decode step: like :meth:`decode`, but the
         provided ``cache`` collection holds the PAGE POOL arrays
         (``KVCacheSpec.paged_cache`` layout — k/v (L, P, KV, cache_d,
@@ -2034,7 +2427,9 @@ class TransformerLM(nn.Module):
         view and no slice of a leaf is ever materialized. ``start_pos``
         must be the per-slot (B,) cache lengths; T is 1 for plain decode
         and K + 1 for speculative verify (a prefill chunk's rows go the
-        same way through :meth:`prefill_chunk` with a ``table``). Call
+        same way through :meth:`prefill_chunk` with a ``table``).
+        ``rows``, for a pool with a state group beside its pages: as
+        :meth:`decode`'s. Call
         with ``mutable=["cache"]``; greedy output is bitwise-identical
         to the dense-oracle :meth:`decode` over ``dense_from_pages`` of
         the same pool."""
@@ -2042,7 +2437,7 @@ class TransformerLM(nn.Module):
         off = start_pos[:, None] if jnp.ndim(start_pos) == 1 else start_pos
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))
         return self._transform(input_ids, pos, True, True,
-                               paged_table=table)
+                               paged_table=table, state_rows=rows)
 
     def __call__(self, batch, deterministic: bool = False):
         cfg = self.config

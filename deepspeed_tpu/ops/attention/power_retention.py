@@ -28,8 +28,8 @@ first ``d/2 + 1`` rows are ``z``, the same sum without ``v``. A token: ``s
 eps)``; ``q`` and ``k`` come in divided by ``d ** (1/4)``. The stacked leaf
 of a pool is ``(L, rows, KV, d/2 + 2, d, d)``: one operand to alias and one
 block to fetch a step; the plane is 1.5 % more state. The kernels pin the
-leaf to HBM (:func:`_in_hbm`): XLA keeps a buffer that fits the chip's fast
-memory there across the layer scan, and a Mosaic operand aliased to its
+leaf to HBM (``state_rows.in_hbm``): XLA keeps a buffer that fits the chip's
+fast memory there across the layer scan, and a Mosaic operand aliased to its
 result read nothing of it there (``z`` as a 38 MB leaf of its own: every
 denominator wrong on the chip, right in interpret mode).
 
@@ -71,7 +71,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import backend
-from .paged_attention import kept_first
+from ..state_rows import (compiler_params, in_hbm, prefetch_operands,
+                          work_list)
 
 __all__ = ["feature_map", "state_shape", "retention_attention",
            "retention_recurrence", "retention_chunk_plain",
@@ -85,10 +86,6 @@ UNROLL = 5              # rotations a trip of that loop (1: 3.37 ms a layer
 # call of 16 rows at the served shape, 5 or 13: 1.75, the bytes alone 1.35;
 # slabs of 8 to 128 rows all within 3 %: chip runs, PR 32)
 HIGHEST = jax.lax.Precision.HIGHEST
-# a (d/2 + 2, d, d) float32 block in and one out, double-buffered, is
-# 17 MB at d = 128: over the v5e's default scoped limit (16 MiB) and far
-# under its VMEM (128 MiB)
-VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
 def rotations(d: int) -> int:
@@ -187,33 +184,14 @@ def retention_chunk_plain(q, k, v, log_g, s, z):
 
 
 # ---------------------------------------------------------------------------
-# the work list
+# the work list (ops/state_rows.py: shared with the state-space kernels)
 # ---------------------------------------------------------------------------
-def work_list(rows, num_rows: int):
-    """``rows`` (B,) int32 names the pool row of each batch entry; an entry
-    outside ``[0, num_rows)`` does not run. Returns ``(batch, row, total)``:
-    the running entries first, in order, and how many they are."""
-    rows = jnp.asarray(rows, jnp.int32)
-    keep = (rows >= 0) & (rows < num_rows)
-    return kept_first(keep, jnp.arange(rows.shape[0]), rows)
-
-
 def _by_batch(*shape):
     """Block spec of an operand (B, KV, *shape): the batch entry of the
     step's work item (the second operand of the scalar prefetch)."""
     return pl.BlockSpec(
         (1, 1) + shape,
         lambda w, j, layer, batch, *_: (batch[w], j, 0, 0))
-
-
-def _prefetch(layer, rows, fresh, s):
-    """The scalar-prefetch operands ``(layer, batch, row, fresh)`` of a
-    call, how many work items they hold, and which batch entries run."""
-    rows = jnp.asarray(rows, jnp.int32)
-    batch_of, row_of, total = work_list(rows, s.shape[1])
-    return ((jnp.asarray(layer, jnp.int32).reshape(1), batch_of, row_of,
-             jnp.asarray(fresh, jnp.int32)[batch_of]), total,
-            (rows >= 0) & (rows < s.shape[1]))
 
 
 def _state_spec(s):
@@ -234,36 +212,6 @@ def _check(d: int, rep: int, s) -> None:
     if d % 128:
         raise ValueError(f"the retention kernels need head_dim % 128 == 0 "
                          f"on the TPU, got {d}")
-
-
-def _in_hbm(s):
-    """The stacked leaf as the kernels' operand and as their result, both
-    pinned to HBM. Left to itself XLA may keep a buffer that fits the chip's
-    fast memory there across a layer scan (``S(1)`` on the custom call's
-    operand), and a Mosaic operand aliased to its result read nothing of it
-    there (chip runs, PR 32: a 33 MB leaf read wrong, every test in
-    interpret mode right). The constraint is the custom call's own
-    (``input_memory_space_colors`` / ``output_memory_colors``); the blocks
-    still ride through VMEM as their specs say. Interpret mode has no
-    memory spaces, and the constraint has no eager form: on the chip the
-    kernels are called under ``jit``. Every program of the engines donates the pool or makes
-    the leaf inside it. One form is left to the caller: a jitted call that
-    takes such a small leaf as a parameter and does NOT donate it has XLA
-    copy the parameter first, and this libtpu's memory-space assignment
-    aborts on that copy beside the pinned result ("Conflicting pending
-    required assignment", at compile time, on the chip and for a described
-    one alike): donate the leaf."""
-    shape = jax.ShapeDtypeStruct(s.shape, s.dtype)
-    if backend.pallas_interpret():
-        return s, shape
-    return (pltpu.with_memory_space_constraint(s, pltpu.HBM),
-            pltpu.HBM(s.shape, s.dtype))
-
-
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 # ---------------------------------------------------------------------------
@@ -350,7 +298,7 @@ def retention_decode(q, k, v, log_g, s, layer, rows, fresh):
     vt = v.astype(jnp.float32)[..., None]                       # (B,KV,d,1)
     lg = jnp.broadcast_to(log_g.astype(jnp.float32)[:, :, None, None],
                           (B, KV, 1, d))
-    prefetch, total, runs = _prefetch(layer, rows, fresh, s)
+    prefetch, total, runs = prefetch_operands(layer, rows, fresh, s)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(total, KV),
@@ -358,7 +306,7 @@ def retention_decode(q, k, v, log_g, s, layer, rows, fresh):
                   _state_spec(s)],
         out_specs=[_state_spec(s), _by_batch(d, d)],
     )
-    s, s_shape = _in_hbm(s)
+    s, s_shape = in_hbm(s)
     s, o = pl.pallas_call(
         functools.partial(_decode_kernel, rep=rep),
         name="retention_decode",
@@ -366,7 +314,7 @@ def retention_decode(q, k, v, log_g, s, layer, rows, fresh):
         out_shape=[s_shape,
                    jax.ShapeDtypeStruct((B, KV, d, d), jnp.float32)],
         input_output_aliases={7: 0},
-        compiler_params=_params(),
+        compiler_params=compiler_params(),
         interpret=backend.pallas_interpret(),
     )(*prefetch, x, vt, lg, s)
     # (B, KV, e, lane h) -> (B, H, e); the blocks of rows that did not run
@@ -454,7 +402,7 @@ def retention_chunk(q, k, v, log_g, s, layer, rows, fresh):
     v = v.astype(jnp.float32).transpose(0, 2, 1, 3)
     G = jnp.cumsum(log_g.astype(jnp.float32), axis=1).transpose(0, 2, 1)
     g_rows = jnp.tile(G, (1, 1, rep))[..., None]                # (B,KV,rep*T,1)
-    prefetch, total, runs = _prefetch(layer, rows, fresh, s)
+    prefetch, total, runs = prefetch_operands(layer, rows, fresh, s)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(total, KV),
@@ -465,7 +413,7 @@ def retention_chunk(q, k, v, log_g, s, layer, rows, fresh):
         scratch_shapes=[pltpu.VMEM((rep * T, d), jnp.float32),
                         pltpu.VMEM((rep * T, d), jnp.float32)],
     )
-    s, s_shape = _in_hbm(s)
+    s, s_shape = in_hbm(s)
     s, o = pl.pallas_call(
         functools.partial(_chunk_kernel, rep=rep),
         name="retention_chunk",
@@ -473,7 +421,7 @@ def retention_chunk(q, k, v, log_g, s, layer, rows, fresh):
         out_shape=[s_shape,
                    jax.ShapeDtypeStruct((B, KV, rep * T, d), jnp.float32)],
         input_output_aliases={10: 0},
-        compiler_params=_params(),
+        compiler_params=compiler_params(),
         interpret=backend.pallas_interpret(),
     )(*prefetch, q, k, v, v.transpose(0, 1, 3, 2), g_rows, G[:, :, None, :],
       s)

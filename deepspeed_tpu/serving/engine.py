@@ -309,6 +309,7 @@ class ServingEngine:
         # of a token's one latent row over the layers (KVCacheSpec.latent),
         # for the counters; 0 for K/V a head
         self._state_row_bytes = int(getattr(spec, "state_bytes_per_row", 0))
+        self._ssm = "ssm" in getattr(spec, "kinds", ())
         self._latent_token_bytes = int(getattr(spec, "latent", 0)) \
             * np.dtype(spec.dtype).itemsize * int(spec.n_layer)
         if paged_kv:
@@ -1188,18 +1189,24 @@ class ServingEngine:
         self._dispatched.update(
             {f"moe_{name}": val for name, val in step.items()})
 
-    def _note_state_rows(self, sp, rows: int) -> None:
+    def _note_state_rows(self, sp, rows: int, tokens: int = 0) -> None:
         """``state_rows`` on a dispatch's span, for a model with a
         recurrent state: the rows whose state the program reads and
         writes, from the host's own running set (no device read). The
         step's span gathers them, with the bytes they stand for over the
-        layers (a row's state read once and written once)."""
+        layers (a row's state read once and written once). ``tokens``, a
+        prefill dispatch's REAL tokens (the host's own positions): a model
+        of state-space layers runs them through the chunk form
+        (``ssm_chunk_tokens`` on the span, summed on the step's)."""
         if not self._state_row_bytes:
             return
         sp.set(state_rows=rows)
         d = self._dispatched
         d["state_rows"] = d.get("state_rows", 0) + rows
         d["state_bytes"] = 2 * self._state_row_bytes * d["state_rows"]
+        if tokens and self._ssm:
+            sp.set(ssm_chunk_tokens=tokens)
+            d["ssm_chunk_tokens"] = d.get("ssm_chunk_tokens", 0) + tokens
 
     def _note_latent(self, sp, read: int, written: int) -> None:
         """``latent_tokens_read`` / ``latent_rows_written`` on a dispatch's
@@ -1273,7 +1280,7 @@ class ServingEngine:
             self._note_admit(1, width)
             with self._phase("prepare", "serving/admit", rid=req.request_id,
                              tokens=T, width=width) as sp:
-                self._note_state_rows(sp, 1)
+                self._note_state_rows(sp, 1, T)
                 self._note_latent(sp, 0, T)
                 logits, pre_cache = self._prefill_at(ids, np.int32(T - 1))
                 self.pool.admit(pre_cache, slot, T)
@@ -1551,7 +1558,7 @@ class ServingEngine:
             self._note_admit(n, nB * width)
             with self._phase("prepare", "serving/prefill_batch", n=n,
                              width=width, batch=nB) as sp:
-                self._note_state_rows(sp, n)
+                self._note_state_rows(sp, n, int(lengths.sum()))
                 # (a prompt admitted whole attends to its own fresh rows)
                 self._note_latent(sp, 0, int(lengths.sum()))
                 logits, pre_cache = self._prefill_at(ids, last_pos)
@@ -1639,7 +1646,7 @@ class ServingEngine:
         self._dispatched["chunk"] = L
         with self._phase("prepare", "serving/prefill_chunk",
                          rid=req.request_id, pos=pos, len=L) as sp:
-            self._note_state_rows(sp, 1)
+            self._note_state_rows(sp, 1, L)
             self._note_latent(sp, pos + L, L)
             if self._paged:
                 logits = self.pool.run_prefill_chunk(
@@ -2385,7 +2392,7 @@ class ServingEngine:
                     sp, int(self.pool.starts[slots].sum()) + len(slots),
                     len(slots))
             if self._paged:
-                logits = self.pool.run_decode(eng, self._cur_dev)
+                logits = self.pool.run_decode(eng, self._cur_dev, *more)
                 # counted after the dispatch, from a mirror the dispatch
                 # does not move: every slot's row is in the program's
                 # work list, the live ones map a page at their index

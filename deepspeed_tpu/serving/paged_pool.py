@@ -93,6 +93,25 @@ the ones above; ``paged_write`` writes it in its scale-leaf form (one
 "head" of ``W`` rows) and the model's read is
 ``ops/attention/latent_attention.py``.
 
+A state group beside the pages (PR 47): a model of mamba layers beside
+attention layers (``KVCacheSpec.state_group``) keeps K/V pages over its
+attention layers, everything above, and for its mamba layers two leaves
+that are NOT paged: ``s`` and ``conv``, ``num_slots`` rows each, a slot's state
+and the last inputs of its convolution (a state has no positions to
+page). They are allocated from the spec with the pages, go to every
+program whole and come back aliased like the page leaves (the model's
+kernels index (layer, row)), a bucketed admission's rows are scattered
+over the slots' rows, and the decode program is told the rows that run
+(``run_decode(..., rows)``), as the contiguous pool's is. Nothing zeroes
+a row when a slot is seated: a row at position 0 reads neither its state
+nor its tail, so seating costs no device call, and a preempted request
+re-prefills from position 0 into whatever row it is given. The audit
+holds the two leaves to the spec's shapes and dtypes and reads ``s``, the
+float32 leaf, once: the rows that have run hold float32's mantissa
+(``NARROW_STATE_WORDS``). Refused for the kind (the one table): the prefix
+cache (a hit would need the state at its boundary), ``verify_k``, a handoff
+of pages.
+
 Sentinel convention: table entry ``num_pages`` means "unmapped" (the
 window group's: its own number of pages). The
 gather reads sentinel entries with a clip-mode take (arbitrary real
@@ -125,6 +144,32 @@ from .slot_pool import SlotPool
 # scales of a quantized tier, or the one latent row a token (``c``,
 # ``KVCacheSpec.latent``: (L, num_pages, W, lanes), no ``v``)
 PAGE_LEAVES = ("k", "v", "k_scale", "v_scale", "c")
+
+# the audit of a float32 state leaf: of the words a row that has run holds
+# (zeros apart), the share whose low 16 bits are empty, which is to say the
+# values bfloat16's 8 bits of mantissa hold whole. A state updated in
+# float32 reads about 2**-16: 1.7e-5 to 3.4e-5 a row on the chip (64 rows
+# of 18.9 M words, three runs of the served cell, PR 47); one held in
+# bfloat16, or rounded to it as it is written, reads 1.0. A served TOKEN
+# does not tell the two apart (forty bfloat16 layers round as much: the
+# readings are in perf/reference/granite_hybrid.py), so the precision the
+# spec states is held here, where the state lives. 2**-4 is 1,900 x the
+# one reading and 16 x under the other
+NARROW_STATE_WORDS = 2.0 ** -4
+
+
+@jax.jit
+def _narrow_words(leaf):
+    """``(words, narrow)`` a row of a float32 state leaf ``(layers, rows,
+    ...)``: the words that are not zero, and those of them that carry
+    nothing below bfloat16's mantissa. One pass over the leaf, nothing of
+    its size is made."""
+    bits = jax.lax.bitcast_convert_type(leaf, jnp.uint32)
+    axes = (0,) + tuple(range(2, leaf.ndim))
+    some = bits != 0
+    narrow = some & ((bits & 0xFFFF) == 0)
+    return (some.sum(axes, dtype=jnp.int32),
+            narrow.sum(axes, dtype=jnp.int32))
 
 
 class PagePoolExhausted(RuntimeError):
@@ -343,9 +388,10 @@ class PagedKVPool(SlotPool):
         stood twice on chip 0 while it was built (1.5 GB short of
         loading there, chip run of PR 27)."""
         shapes = jax.eval_shape(
-            lambda: self.spec.paged_cache(self.num_pages, self.page_size)
-            if self.ring is None else self.spec.paged_cache(
-                self.num_pages, self.page_size, self.ring.num_pages))
+            lambda: self.spec.paged_cache(
+                self.num_pages, self.page_size,
+                self.ring.num_pages if self.ring is not None else None,
+                num_slots=self.num_slots))
         cs = {key: jnp.zeros(leaf.shape, leaf.dtype,
                              device=self._leaf_sharding(key, leaf))
               for key, leaf in shapes.items()}
@@ -941,11 +987,16 @@ class PagedKVPool(SlotPool):
         capacity) prefill cache through host-passed per-row tables.
         Padding rows are ALL-sentinel tables (not just a sentinel slot
         id — indexing the device table with a clamped sentinel slot
-        would alias a real slot's pages), so they write nothing."""
+        would alias a real slot's pages), so they write nothing. A state
+        group's rows go over the slots' rows, as the contiguous pool
+        writes them (a padding row's slot id is out of range: dropped)."""
         nB = rows_tables.shape[0]
         out = self._write_runs(pool, pre,
                                self._group_tables(rows_tables, win_tables),
                                jnp.zeros((nB,), jnp.int32), self.capacity)
+        for key in self.spec.state_leaves:
+            out[key] = pool[key].at[:, slots].set(
+                pre[key].astype(pool[key].dtype), mode="drop")
         out["index"] = pool["index"].at[slots].set(
             jnp.asarray(lengths, jnp.int32), mode="drop")
         return out
@@ -978,6 +1029,10 @@ class PagedKVPool(SlotPool):
         write_runs = self._write_runs
         tables_of = self._tables
         grouped = self.ring is not None
+        # a state group beside the pages (``KVCacheSpec.state_group``):
+        # rows of the slots that the programs take whole, are told the
+        # running rows for, and hand back beside the page leaves
+        state_leaves = spec.state_leaves
         # a model with a routed FFN reports what it counted through the
         # "stats" collection (models/transformer_lm.py)
         want_stats = bool(getattr(getattr(module, "config", None),
@@ -999,11 +1054,13 @@ class PagedKVPool(SlotPool):
         # as it is and the positions are the cache's own ``index``
         # (``decode_fn`` adds the axis and holds the index inside the
         # allocation, as ``positions()`` does on the host)
-        def paged_decode(params, cs, token):
-            logits, new = decode_fn(params, dense_cache(cs), token)
+        def paged_decode(params, cs, token, rows=None):
+            logits, new = decode_fn(params, dense_cache(cs), token,
+                                    rows=rows)
             ncs = new["cache_store"]
             # one column written per row
             out = write_runs(cs, ncs, tables_of(cs), cs["index"], 1)
+            out.update({key: ncs[key] for key in state_leaves})
             out["index"] = ncs["index"]
             return logits, out, None
 
@@ -1026,6 +1083,7 @@ class PagedKVPool(SlotPool):
             ids, slot, start, length, last_idx, *rows = unpack_chunk_args(
                 packed, self.pages_per_slot, len(self._table_keys))
             row_tables = self._group_tables(*(row[None] for row in rows))
+            state_row = {"rows": slot[None]} if state_leaves else {}
             if self.reads_in_place(ids.shape[1]):
                 # as kernel_apply does for a decode step: the model
                 # takes the stacked leaves whole and a table of one row,
@@ -1039,7 +1097,7 @@ class PagedKVPool(SlotPool):
                      "cache": {"cache_store": vals}},
                     ids, start[None], last_idx,
                     table=row_tables if grouped else row_tables["table"],
-                    method=chunk_gen, mutable=mutable)
+                    method=chunk_gen, mutable=mutable, **state_row)
                 outcs = dict(vars_["cache"]["cache_store"])
             else:
                 # the dense composition (the oracle): gather the slot's
@@ -1052,10 +1110,11 @@ class PagedKVPool(SlotPool):
                     {"params": dequant(params),
                      "cache": {"cache_store": dense}},
                     ids, start[None], last_idx, method=chunk_gen,
-                    mutable=mutable)
+                    mutable=mutable, **state_row)
                 new = vars_["cache"]["cache_store"]
                 outcs = write_runs(cs, new, row_tables, start[None],
                                    ids.shape[1])
+                outcs.update({key: new[key] for key in state_leaves})
             outcs["index"] = cs["index"].at[slot].set(start + length,
                                                       mode="drop")
             # the device tables get the row the program was handed: the
@@ -1088,8 +1147,9 @@ class PagedKVPool(SlotPool):
                 and getattr(module, "decode_paged", None) is not None:
             capacity = self.capacity
 
-            def kernel_apply(params, cache, token):
+            def kernel_apply(params, cache, token, rows=None):
                 cs = cache["cache_store"]
+                more = {} if rows is None else {"rows": rows}
                 if token.ndim == 1:
                     token = token[:, None]
                 pos = jnp.minimum(cs["index"], capacity - 1)
@@ -1099,7 +1159,7 @@ class PagedKVPool(SlotPool):
                     {"params": dequant(params),
                      "cache": {"cache_store": vals}},
                     token, pos, tables if grouped else tables["table"],
-                    method=module.decode_paged, mutable=mutable)
+                    method=module.decode_paged, mutable=mutable, **more)
                 new = dict(vars_["cache"]["cache_store"], **tables)
                 return logits, {"cache_store": new}, \
                     vars_["stats"]["moe"] if want_stats else None
@@ -1107,9 +1167,9 @@ class PagedKVPool(SlotPool):
             def kernel_decode_fn(params, cache, token):
                 return kernel_apply(params, cache, token)[:2]
 
-            def kernel_decode(params, cs, token):
+            def kernel_decode(params, cs, token, rows=None):
                 logits, new, stats = kernel_apply(
-                    params, {"cache_store": cs}, token)
+                    params, {"cache_store": cs}, token, rows)
                 return logits, new["cache_store"], stats
 
             kernel_verify_body = make_verify_fn(kernel_decode_fn,
@@ -1164,12 +1224,14 @@ class PagedKVPool(SlotPool):
             return True
         return backend.on_tpu()
 
-    def run_decode(self, engine: Any, tokens):
+    def run_decode(self, engine: Any, tokens, *rows):
         """One masked decode step for every slot over paged storage;
         updates the pool state in place and returns the logits. ``tokens``
         is the (B,) current-token vector; the positions are the device
         ``index``, which equals the host's ``starts`` whenever a decode is
-        queued (:meth:`SlotPool.positions`)."""
+        queued (:meth:`SlotPool.positions`). ``rows``: for a pool with a
+        state group, the (B,) rows that run (``ServingEngine._state_rows``);
+        a K/V pool is never given it."""
         self.bind_engine(engine)
         self._publish_stale()
         # direct attribute dispatch on both arms (not `fn = a or b;
@@ -1179,11 +1241,11 @@ class PagedKVPool(SlotPool):
         with self.enqueue("decode"):
             if self._paged_decode_kernel_jit is not None:
                 logits, cs, stats = self._paged_decode_kernel_jit(
-                    engine.params, self.cache["cache_store"], tokens)
+                    engine.params, self.cache["cache_store"], tokens, *rows)
                 self.cache = {"cache_store": cs}
             else:
                 logits, cs, stats = self._paged_decode_jit(
-                    engine.params, self.cache["cache_store"], tokens)
+                    engine.params, self.cache["cache_store"], tokens, *rows)
                 self.cache = {"cache_store": cs}
         if stats is not None:
             self.moe_stats.append(stats)
@@ -1319,6 +1381,26 @@ class PagedKVPool(SlotPool):
                 prefix_evictable_pages=self.evictable_page_count())
         return stats
 
+    def _narrow_state_rows(self, key: str, leaf) -> List[str]:
+        """The audit's reading of a float32 state leaf (the one device read
+        of the audit): the rows of seated slots that have run a position
+        hold float32's mantissa (``NARROW_STATE_WORDS``)."""
+        ran = [slot for slot in range(self.num_slots)
+               if slot not in self._free_set and self.starts[slot] > 0]
+        if not ran:
+            return []
+        words, narrow = (np.asarray(n)[ran] for n in _narrow_words(leaf))
+        bad = {slot: float(m) / float(n)
+               for slot, n, m in zip(ran, words, narrow)
+               if m > NARROW_STATE_WORDS * n}
+        if not bad:
+            return []
+        return [f"state leaf {key!r} is float32 and "
+                f"{min(bad.values()):.3f}-{max(bad.values()):.3f} of the "
+                f"words of rows {sorted(bad)[:8]} carry nothing below "
+                f"bfloat16's mantissa (limit {NARROW_STATE_WORDS}): the "
+                f"state is held or rounded narrower than the spec states"]
+
     def consistency_errors(self) -> List[str]:
         """SlotPool's audit plus the page bookkeeping invariants: the
         free-page heap/set mirrors agree, every refcount equals the
@@ -1328,6 +1410,24 @@ class PagedKVPool(SlotPool):
         errors = super().consistency_errors()
         if self.ring is not None:
             errors += self.ring.audit(self.starts, self._free_set)
+        if self.spec.state_leaves:
+            # the state group: a row a slot beside the pages, in the
+            # spec's shapes and dtypes, and a float32 leaf's rows in
+            # float32's precision
+            want = jax.eval_shape(lambda: self.spec.paged_cache(
+                self.num_pages, self.page_size, num_slots=self.num_slots))
+            cs = self.cache["cache_store"]
+            for key in self.spec.state_leaves:
+                got = cs.get(key)
+                if got is None or (got.shape, got.dtype) != (
+                        want[key].shape, want[key].dtype):
+                    errors.append(
+                        f"state leaf {key!r} is "
+                        f"{None if got is None else (got.shape, got.dtype)}"
+                        f", the spec's is "
+                        f"{(want[key].shape, want[key].dtype)}")
+                elif got.dtype == jnp.float32:
+                    errors += self._narrow_state_rows(key, got)
         P, sent = self.num_pages, self.num_pages
         if len(self._free_pages) != len(self._free_page_set):
             errors.append(f"free page heap ({len(self._free_pages)}) and "

@@ -321,6 +321,116 @@ def test_retention_kernels_compile_for_a_described_v5e_at_the_served_shape(
                '"color":"0"' in call and '"output_memory_colors":["0"' in call
 
 
+def test_ssm_kernels_compile_for_a_described_v5e_at_the_served_shape(
+        compiled_kernels, described_v5e):
+    """The kernels PR 47 brought, through Mosaic at the widths of
+    ``perf/configs/granite-4.0-h-micro-hybrid.json``: 64 rows' tokens
+    through ``ssm_decode`` and one row's 128-token chunk through
+    ``ssm_chunk`` on the pool's stacked leaf (36 layers x 64 slots x 32
+    tiles of (128, 128) float32, 4.83 GB). Lane-dense rows, columns one
+    lane wide and blocks of 16 tiles pass Mosaic's tiling only here. The
+    leaf goes in and comes out in one buffer: no operation of the program
+    copies it."""
+    from deepspeed_tpu.ops import state_space as ss
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=described_v5e)
+
+    i32 = jnp.int32
+    L, R, H, P, N = 36, 64, 64, 64, 128
+    leaf = shape((L, R) + ss.state_shape(H, P, N))
+    assert leaf.shape[2:] == (32, 128, 128)
+    with _compile_cache_off():
+        for name, fn, B, tokens in (("ssm_decode", ss.ssm_decode, R, ()),
+                                    ("ssm_chunk", ss.ssm_chunk, 1, (128,))):
+            compiled = jax.jit(fn, donate_argnums=5).lower(
+                shape((B,) + tokens + (H, P)), shape((B,) + tokens + (H,)),
+                shape((H,)), shape((B,) + tokens + (N,)),
+                shape((B,) + tokens + (N,)), leaf, shape((), i32),
+                shape((B,), i32), shape((B,), jnp.bool_)).compile()
+            text = compiled.as_text()
+            assert name in text and text.count("tpu_custom_call") == 1
+            assert "may-alias" in text
+            # the leaf is 4.83 GB: a copy or a slice of it would show
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+
+
+def test_a_state_group_beside_pages_compiles_with_no_copy_of_a_leaf(
+        compiled_kernels, described_v5e):
+    """The decode and the chunk program of a server of mamba and attention
+    layers at the published widths of one period (``[m, m, attention, m]``
+    in place of ``[5, attention, 4]``: the body of the period's scan does
+    not depend on the count), 64 slots, 1,536 pages of 128, compiled for a
+    described v5e. Both hold the five kernels (``ssm_decode`` or
+    ``ssm_chunk`` twice, ``paged_write`` twice, ``paged_decode``); no leaf
+    of the pool and no stacked weight is copied (a program's temporaries
+    stay under 64 MB beside 2 GB of leaves); in particular the input
+    projection, kept as [z ; xBC] and dt, comes in in the layout its
+    product takes (as ONE leaf 8,512 wide, no multiple of 128 lanes, the
+    chip's client stores it transposed and every program copied it whole:
+    627 MB of temporaries at 18 layers)."""
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.inference.engine import pack_chunk_args
+    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
+                                                     transformer_config)
+    from deepspeed_tpu.parallel import mesh
+    from deepspeed_tpu.serving.paged_pool import PagedKVPool
+
+    pattern = ["mamba", "mamba", "attention", "mamba"]
+    model = TransformerLM(transformer_config(
+        "granite-hybrid", vocab_size=128, max_seq_len=16384, n_embd=2048,
+        n_layer=4, n_head=32, n_kv_head=8, ffn_dim=8192,
+        layer_types=pattern, mamba_n_heads=64, mamba_d_head=64,
+        mamba_d_state=128, embedding_multiplier=12.0,
+        attention_multiplier=0.015625, residual_multiplier=0.22,
+        logits_scaling=8.0, dtype=jnp.bfloat16))
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32),
+                           method=model.logits)["params"])
+    engine = ds.init_inference(
+        model=model, config={"dtype": "bf16"},
+        model_parameters=jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, jnp.bfloat16), params))
+    engine._ensure_params(jnp.zeros((1, 2), jnp.int32))
+    spec, slots, chunk = model.kv_cache_spec(), 64, 128
+    pool = PagedKVPool(spec, 2, num_pages=2, kernel="on", page_size=128,
+                       prefix_cache=False)
+    pool.bind_engine(engine)
+    cs = dict(jax.eval_shape(
+        lambda: spec.paged_cache(1536, 128, num_slots=slots)))
+    assert cs["s"].shape == (3, 64, 32, 128, 128)
+    cs["index"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    cs["table"] = jax.ShapeDtypeStruct((slots, pool.pages_per_slot),
+                                       jnp.int32)
+    token = jnp.zeros((slots,), jnp.int32)
+    packed = jnp.asarray(pack_chunk_args(
+        np.zeros((1, chunk), np.int32), 0, chunk, chunk, chunk - 1,
+        np.zeros((pool.pages_per_slot,), np.int32)))
+    programs = {
+        "kernel_decode": (pool._paged_decode_kernel_jit,
+                          (engine.params, cs, token, token), "ssm_decode"),
+        "paged_chunk": (pool._paged_chunk_jit, (engine.params, cs, packed),
+                        "ssm_chunk")}
+    mesh.reset_mesh()       # (the engine's mesh is of this process's CPUs)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=described_v5e)
+
+    with _compile_cache_off():
+        for name, (jitted, args, kernel) in programs.items():
+            compiled = jitted.lower(*jax.tree_util.tree_map(
+                described, args)).compile()
+            text = compiled.as_text()
+            calls = re.findall(
+                r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*tpu_custom_call",
+                text)
+            assert sorted(calls) == sorted(
+                [kernel] * 2 + ["paged_write"] * 2 + ["paged_decode"]), name
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26, \
+                (name, compiled.memory_analysis())
+
+
 def test_latent_kernels_compile_for_a_described_v5e_at_the_served_shape(
         compiled_kernels, described_v5e):
     """The kernels PR 38 brought, through Mosaic at the widths of

@@ -81,3 +81,93 @@ def test_remat_hands_a_block_its_layer_and_its_experts(routed):
         outs.append((np.asarray(logits), np.asarray(step)))
     np.testing.assert_array_equal(outs[0][0], outs[1][0])
     np.testing.assert_array_equal(outs[0][1], outs[1][1])
+
+
+GRANITE_PERIOD = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
+
+
+def test_a_state_group_beside_kv_at_the_published_widths():
+    """Granite-4.0-H-Micro's cache (PR 47), as shapes alone
+    (``jax.eval_shape``: nothing is allocated): a state group over the 36
+    Mamba layers, ``s`` float32 in whole (128, 128) tiles of two heads and
+    ``conv`` bfloat16, the last 3 inputs of 4,352 channels time-major, a
+    row a slot; K/V over the 4 attention layers, paged or contiguous; and
+    a slot's state over the layers to the byte."""
+    import jax
+
+    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
+                                                     transformer_config)
+
+    cfg = transformer_config(
+        "granite-hybrid", vocab_size=100352, max_seq_len=16384, n_embd=2048,
+        n_layer=40, n_head=32, n_kv_head=8, ffn_dim=8192,
+        layer_types=GRANITE_PERIOD * 4, mamba_n_heads=64, mamba_d_head=64,
+        mamba_d_state=128)
+    spec = TransformerLM(cfg).kv_cache_spec()
+    assert spec.n_layer == 40 and spec.kv_layers == 4
+    assert spec.kinds == ("ssm",)
+    assert spec.state_group == (36, (
+        ("s", (32, 128, 128), jnp.float32),
+        ("conv", (3 * 4352,), jnp.bfloat16)))
+    assert spec.state_leaves == ("s", "conv") and spec.state is None
+    assert spec.state_bytes_per_row == 76_437_504 \
+        == 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+    paged = jax.eval_shape(lambda: spec.paged_cache(1536, 128, num_slots=64))
+    shapes = {key: (leaf.shape, leaf.dtype.name)
+              for key, leaf in paged.items()}
+    assert shapes == {
+        "s": ((36, 64, 32, 128, 128), "float32"),
+        "conv": ((36, 64, 13056), "bfloat16"),
+        "k": ((4, 1536, 8, 64, 128), "bfloat16"),
+        "v": ((4, 1536, 8, 64, 128), "bfloat16")}
+    rows = jax.eval_shape(lambda: spec.stacked_cache(2))
+    assert rows["s"].shape == (36, 2, 32, 128, 128)
+    assert rows["conv"].shape == (36, 2, 13056)
+    assert rows["k"].shape == rows["v"].shape == (4, 2, 8, 64, 16384)
+    # a K/V model's spec names no state leaf, a retention model's one
+    plain = TransformerLM(TransformerConfig(
+        vocab_size=64, max_seq_len=16, n_embd=32, n_layer=1, n_head=2))
+    assert plain.kv_cache_spec().state_leaves == ()
+    assert plain.kv_cache_spec().state_bytes_per_row == 0
+    assert plain.kv_cache_spec().kv_layers == 1
+    # the one description of a state group: every layer of a retention
+    # model, one leaf, no layer left to keep K/V
+    kept = TransformerLM(transformer_config(
+        "brumby", vocab_size=128, max_seq_len=128, n_embd=64, n_layer=2,
+        n_head=4, n_kv_head=2, head_size=16, ffn_dim=96)).kv_cache_spec()
+    assert kept.state_group == (2, (("s", (2, 10, 16, 16), jnp.float32),))
+    assert kept.state == (10, 16, 16) and kept.state_leaves == ("s",)
+    assert (kept.n_layer, kept.kv_layers) == (2, 0)
+    assert kept.state_bytes_per_row == 2 * 2 * 10 * 16 * 16 * 4
+
+
+@pytest.mark.parametrize("layer_types,why", [
+    (["mamba"] * 4, "ONE attention layer"),
+    (["mamba", "attention", "attention", "mamba"], "ONE attention layer"),
+    (["attention", "mamba", "mamba", "attention"], "ONE attention layer"),
+    (["mamba", "full_attention"] * 2, "ONE attention layer"),
+    (["attention"] * 4, "ONE attention layer"),
+])
+def test_mamba_and_attention_layers_come_as_a_repeating_pattern(layer_types,
+                                                                why):
+    from deepspeed_tpu.models.transformer_lm import transformer_config
+
+    with pytest.raises(ValueError, match=why):
+        transformer_config(
+            "granite-hybrid", vocab_size=64, max_seq_len=16, n_embd=32,
+            n_layer=4, n_head=2, layer_types=layer_types, mamba_n_heads=4,
+            mamba_d_head=8, mamba_d_state=8)
+
+
+def test_mamba_layers_need_their_widths():
+    from deepspeed_tpu.models.transformer_lm import transformer_config
+
+    with pytest.raises(ValueError, match="mamba_n_heads"):
+        transformer_config(
+            "granite-hybrid", vocab_size=64, max_seq_len=16, n_embd=32,
+            n_layer=2, n_head=2, layer_types=["mamba", "attention"])
+    cfg = transformer_config(
+        "granite-hybrid", vocab_size=64, max_seq_len=16, n_embd=32,
+        n_layer=10, n_head=2, layer_types=GRANITE_PERIOD, mamba_n_heads=4,
+        mamba_d_head=8, mamba_d_state=8)
+    assert cfg.hybrid_period == (5, 4, 1) and cfg.mamba_channels == 48

@@ -23,6 +23,10 @@ _LLAMA = dict(TINY, max_seq_len=128, n_layer=4, head_size=8)
 # a configuration a kind, each of that kind alone (beside what it implies)
 PRESETS = {
     "state": ("brumby", dict(TINY, n_kv_head=2, head_size=16, ffn_dim=48)),
+    "ssm": ("granite-hybrid", dict(
+        TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
+        layer_types=["mamba", "attention"] * 2, mamba_n_heads=4,
+        mamba_d_head=8, mamba_d_state=8)),
     "latent": ("moonlight", dict(
         TINY, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
         v_head_dim=8, scoring_func="softmax")),
@@ -147,5 +151,11 @@ def test_a_kind_with_no_row_constructs(engine_of):
     srv = ServingEngine(engine_of("window"), num_slots=2,
                         prefill_chunk=PAGE, paged_kv=_OFF)
     assert srv.pool.ring is not None
+    # a state group rides beside paged K/V where a model has both (PR 47):
+    # what a model of state layers alone is still refused
+    srv = ServingEngine(engine_of("ssm"), num_slots=2, prefill_chunk=PAGE,
+                        paged_kv=_OFF)
+    assert set(srv.pool.cache["cache_store"]) \
+        == {"s", "conv", "k", "v", "index", "table"}
     with pytest.raises(KeyError):
         spec.refusal("no_such_feature")

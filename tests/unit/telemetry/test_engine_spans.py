@@ -447,6 +447,109 @@ def test_a_dispatch_span_opens_where_it_did(account, server_parts):
             evs, sample, "serving/enqueue")] == ["sample"]
 
 
+@pytest.fixture(scope="module")
+def hybrid_account():
+    """A server of mamba and attention layers over the page pool (a state
+    group beside paged K/V, PR 47), driven as ``account`` is: a chunked
+    prompt, a batched and a single bucketed admission, plain decode steps."""
+    from deepspeed_tpu.models.transformer_lm import transformer_config
+
+    model = TransformerLM(transformer_config(
+        "granite-hybrid", **dict(TINY, n_layer=4, n_kv_head=2, ffn_dim=48),
+        layer_types=["mamba", "attention"] * 2, mamba_n_heads=4,
+        mamba_d_head=8, mamba_d_state=8))
+    params = model.init({"params": jax.random.PRNGKey(1)},
+                        jnp.zeros((1, 8), jnp.int32),
+                        method=model.logits)["params"]
+    srv = ds.init_serving(model, model_parameters=params,
+                          config={"dtype": "float32"}, num_slots=4,
+                          max_queue_depth=8, prefill_chunk=8,
+                          prefill_token_budget=64,
+                          paged_kv={"kernel": "off", "prefix_cache": False})
+    rng = np.random.default_rng(11)
+    n0 = default_tracer().events_total
+
+    def prompt(n):
+        return rng.integers(0, 64, size=n).astype(np.int32)
+
+    srv.submit(prompt(20), max_new_tokens=12)      # chunks of 8, 8 and 4
+    for _ in range(4):
+        srv.step()
+    for n in (5, 6):                               # one bucket: batched
+        srv.submit(prompt(n), max_new_tokens=4)
+    srv.step()
+    srv.submit(prompt(7), max_new_tokens=4)        # alone: serving/admit
+    srv.step()
+    srv.run_until_drained(max_steps=60)
+    srv.check_invariants()
+    evs = _new_events(n0)
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    return {"pool": "paged", "srv": srv, "evs": evs, "steps": steps}
+
+
+def test_a_state_group_beside_pages_counts_its_rows_and_tokens(
+        hybrid_account):
+    """``state_rows`` on every dispatch span, ``ssm_chunk_tokens`` (REAL
+    tokens, from the host's positions) on the three prefill dispatches and
+    both summed on the step with ``state_bytes``; the K/V group's
+    ``pool_writes`` beside them; the resident gauge from the spec."""
+    srv, evs = hybrid_account["srv"], hybrid_account["evs"]
+    by = {name: [e for e in evs if e["name"] == name] for name in DISPATCH}
+    assert all(by.values())
+    assert [e["args"]["ssm_chunk_tokens"]
+            for e in by["serving/prefill_chunk"]] == [8, 8, 4]
+    assert [e["args"]["ssm_chunk_tokens"]
+            for e in by["serving/prefill_batch"]] == [5 + 6]
+    assert [e["args"]["ssm_chunk_tokens"]
+            for e in by["serving/admit"]] == [7]
+    assert [e["args"]["state_rows"] for e in by["serving/prefill_batch"]] \
+        == [2]
+    assert {e["args"]["state_rows"] for e in by["serving/prefill_chunk"]
+            + by["serving/admit"]} == {1}
+    for e in by["serving/decode"]:
+        assert e["args"]["state_rows"] == e["args"]["live"]
+        assert "ssm_chunk_tokens" not in e["args"]
+        assert "pool_writes" in e["args"]       # the 4 attention layers'
+    row_bytes = srv.pool.spec.state_bytes_per_row
+    assert row_bytes == 2 * (4 * 8 * 8 * 4 + 3 * (4 * 8 + 16) * 4)
+    told = 0
+    for step in hybrid_account["steps"]:
+        kids = [k for name in DISPATCH for k in _kids(evs, step, name)]
+        rows = sum(k["args"]["state_rows"] for k in kids)
+        tokens = sum(k["args"].get("ssm_chunk_tokens", 0) for k in kids)
+        assert step["args"].get("state_rows", 0) == rows
+        assert step["args"].get("ssm_chunk_tokens", 0) == tokens
+        assert step["args"].get("state_bytes", 0) == 2 * row_bytes * rows
+        told += tokens
+    assert told == 20 + 5 + 6 + 7
+    assert srv.registry.gauge("serving/state_bytes_resident").value \
+        == 4 * row_bytes
+
+
+def test_a_plain_decode_step_beside_a_state_group_makes_three_calls(
+        hybrid_account):
+    """As the retention state's on the contiguous pool: the decode program,
+    the sampler, the commit; one more, the running rows, in a step whose
+    running set changed. Seating a slot zeroes nothing (a row at position 0
+    reads neither its state nor its tail)."""
+    plain = _steps_with(hybrid_account, "serving/decode", without=(
+        "serving/admit", "serving/prefill_batch", "serving/prefill_chunk"))
+    counts = [s["args"]["device_calls"] for s in plain]
+    # (four short requests: half the plain steps follow a retirement or a
+    # change of the running set, which put the table or the rows again)
+    assert min(counts) == DECODE_CALLS and counts.count(DECODE_CALLS) >= 3
+    quiet = [s for s in plain if s["args"]["device_calls"] == DECODE_CALLS]
+    assert [k["args"]["program"] for k in _kids(
+        hybrid_account["evs"], quiet[0], "serving/enqueue")] \
+        == ["decode", "sample"]
+    only = _steps_with(hybrid_account, "serving/prefill_chunk",
+                       without=("serving/decode", "serving/admit",
+                                "serving/prefill_batch"))
+    later = [s for s in only if s["args"]["step"] > 1]
+    assert later and {s["args"]["device_calls"] for s in later} \
+        == {CHUNK_CALLS["paged"]}
+
+
 def test_counter_tracks_sample_every_step(account):
     """One sample a track a step, as before the account."""
     evs, paged = account["evs"], account["pool"] == "paged"
