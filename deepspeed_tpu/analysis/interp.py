@@ -294,6 +294,10 @@ WATCHED_MODELS = {
          Scalar(None)]),
     "_paged_chunk_jit": lambda args, kw, env: Tup(
         [_logits(Known(1), env), Tree(COMMITTED, "pool"), Scalar(None)]),
+    # (the chunk's logits, the decode rows', pool, the routed FFN's counts)
+    "_paged_chunk_decode_jit": lambda args, kw, env: Tup(
+        [_logits(Known(1), env), _logits(_batch_of(args[3]), env),
+         Tree(COMMITTED, "pool"), Scalar(None)]),
     "_paged_verify_jit": lambda args, kw, env: Tup(
         [Tree(COMMITTED, "pool")] + _verify_outs(args, env)),
     "_jit_finite": lambda args, kw, env: Arr(
@@ -1343,6 +1347,8 @@ def _pool_obj(env: dict, engine: Obj) -> Obj:
             if env.get("paged_kernel_active") else Scalar(None),
             "_paged_verify_kernel_jit": Obj("jit")
             if env.get("paged_kernel_active") else Scalar(None),
+            "_paged_chunk_decode_jit": Obj("jit")
+            if env.get("paged_kernel_active") else Scalar(None),
         })
         return Obj("PagedKVPool", attrs)
     return Obj("SlotPool", attrs)
@@ -1388,7 +1394,20 @@ def _serving_obj(env: dict) -> Obj:
         "step_id": Scalar(0),
         "_tokens_emitted": Scalar(0),
         "_prefill_queue": ListOf(Unknown("queue"), maybe_empty=True),
+        # a chunk left to the decode dispatch (``_fuses_env``): none but
+        # in the driver that hands ``_decode_step`` one
+        "_chunk_beside": Scalar(None),
+        "_fuses_chunks": Scalar(_fuses_env(env)),
     })
+
+
+def _fuses_env(env: dict) -> bool:
+    """``ServingEngine._fuses_chunks`` of a config: a step's chunk goes
+    with its decode rows as one program."""
+    return bool(env.get("paged") and env.get("paged_kernel_active")
+                and env.get("stall_free") and not env.get("spec_k")
+                and not env.get("overlap")
+                and str(env.get("role", "both")) != "prefill")
 
 
 def _request_obj(T: Dim) -> Obj:
@@ -1500,6 +1519,21 @@ def run_drivers(interp: Interp) -> None:
     # 4. the decode step (and the numerics guard, when armed)
     call(srv, "_decode_step", {"finished": finished,
                                "t0": Scalar(0.0)})
+
+    # 4b. the same dispatch with the step's chunk left to it: ONE program
+    #     for the chunk's rows and the decode rows
+    if _fuses_env(env):
+        srv3 = _serving_obj(env)
+        C = int(env["prefill_chunk"])
+        req = _request_obj(IntRange(1, int(env["capacity"]), "seed_len"))
+        req.attrs["slot"] = Scalar(
+            IntRange(0, int(env["num_slots"]) - 1, "slot"))
+        srv3.attrs["_chunk_beside"] = Tup([
+            req, Arr((Known(1), Known(C)), "int32", HOST),
+            Scalar(IntRange(0, int(env["capacity"]) - 1, "pos")),
+            Scalar(C), Scalar(0.0)])
+        call(srv3, "_decode_step", {"finished": finished,
+                                    "t0": Scalar(0.0)})
 
     # 5. speculative verify step
     if env.get("spec_k"):

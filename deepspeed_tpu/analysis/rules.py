@@ -278,6 +278,7 @@ DONATION_FALLBACK: Dict[str, Tuple[int, ...]] = {
     "_paged_decode_jit": (1,),
     "_paged_verify_jit": (1,),
     "_paged_chunk_jit": (1,),
+    "_paged_chunk_decode_jit": (1,),
     "verify_k": (0,),
     "prefill_chunk": (0,),
 }

@@ -651,6 +651,89 @@ def _store_columns(buf, new, start):
         buf, new, (0,) * (buf.ndim - 1) + (start,))
 
 
+@functools.lru_cache(maxsize=None)
+def _traced_once_on(fn, mesh, interpret: bool, static: tuple):
+    return jax.jit(fn, static_argnames=static)
+
+
+def _traced_once(fn, *static, chunk: bool = True):
+    """``fn``, one of the cache kernels' entry points, as a function JAX
+    traces ONCE for each set of operand shapes: an inner ``jit``, inlined
+    where the program is compiled. For a prefill CHUNK's calls alone
+    (``chunk``: several query rows of ONE slot): a layer's body is traced
+    twice by the scan that holds it, and ``paged_chunk`` and the one
+    program of a chunk beside the decode rows trace the same kernels; the
+    kernel bodies were two thirds of a step program's trace time
+    (``paged_chunk`` 2.6 s, ``kernel_decode`` 2.3 s, the two as one
+    program 5.2 s on the chip's host with every compile served from the
+    cache: my chip run, PR 48; all of it ``setup_s``). Keyed by what the
+    kernels' wrappers read while they are traced: the mesh
+    (``ops.backend.shard_kernel``) and the interpret switch.
+
+    Every other call, a decode or a verify step's over all the slots, is
+    ``fn`` itself, so those programs are the parent's. Measured, not
+    supposed: behind an inlined call the decode read's work list
+    (``s32[slots x pages_per_slot]``, the same for every layer) is
+    computed again in every layer instead of once a program, 0.4-0.6 ms a
+    step in docs and ide (my chip runs, PR 48, call 6: ``serve_tok_s``
+    5,199 against 5,423, 6,851 against 7,049); and the plain decode
+    program's compiled text is pinned instruction for instruction
+    (``tests/unit/accelerator/test_chip_path.py``, ``_KERNEL_DECODE_TEXT``),
+    which an inlined call renumbers. (One path for both shapes needs the
+    work list handed to the kernels, a change under ``ops/``: PERF.md
+    section 7.)"""
+    if not chunk:
+        return fn
+    from ..ops import backend
+    from ..parallel import mesh as mesh_mod
+
+    return _traced_once_on(
+        fn, mesh_mod.get_mesh() if mesh_mod.has_mesh() else None,
+        backend.pallas_interpret(), static)
+
+
+def _chunk_shaped(rows) -> bool:
+    """Whether ``rows`` (slots, T, ...) are a prefill chunk's: several of
+    one slot (:func:`_traced_once`)."""
+    return rows.shape[0] == 1 and rows.shape[1] > 1
+
+
+def _chunk_positions(kv_cache, T: int):
+    """Positions (1, T) of the rows of a step that carries a prefill chunk
+    beside its decode rows (``"chunk"`` in the cache a layer is handed,
+    :meth:`TransformerLM.chunk_beside_decode`): the chunk's ``C`` tokens from
+    its start, then each decode row at its own."""
+    start = kv_cache["start"]
+    at = kv_cache["chunk"]["start"][:, None] \
+        + jnp.arange(T - start.shape[0])[None, :]
+    return jnp.concatenate([at, start[None, :]], axis=1)
+
+
+def _by_row_group(kv_cache, step, *rows):
+    """What a mixer does against its cache, ``step(kv_cache, *rows) -> (y,
+    leaves)`` over ``rows`` (B, T, ...), for every group of rows of the
+    call. A call has one group, itself, unless a prefill chunk rides beside
+    the decode rows: then ``rows`` are (1, C + B, ...), the chunk's C rows
+    of ONE slot ahead of B slots' one row each, everything that read a
+    weight has run over all of them at once, and what reads the cache runs
+    a group at a time with the kernels it has: the chunk's rows as (1, C)
+    through the addressing under ``kv_cache["chunk"]`` (its start, its
+    slot's table row(s), its state row), then the decode rows as (B, 1)
+    through the call's own, on the leaves as the chunk left them. That
+    order is the two programs' this replaces: the decode row of the slot in
+    mid-prefill reads what the chunk wrote and writes its dead column
+    behind it."""
+    chunk = kv_cache.get("chunk")
+    if chunk is None:
+        return step(kv_cache, *rows)
+    cache = {key: val for key, val in kv_cache.items() if key != "chunk"}
+    C = rows[0].shape[1] - cache["start"].shape[0]
+    y_chunk, leaves = step(dict(cache, **chunk), *(r[:, :C] for r in rows))
+    y, leaves = step(dict(cache, **leaves),
+                     *(r[0, C:][:, None] for r in rows))
+    return jnp.concatenate([y_chunk, y[:, 0][None]], axis=1), leaves
+
+
 class CachedAttention(nn.Module):
     """Multi-head / grouped-query attention with optional KV cache.
 
@@ -715,9 +798,10 @@ class CachedAttention(nn.Module):
             return True
         return backend.on_tpu()
 
-    def _paged_decode_step(self, q, k, v, kv_cache):
+    def _paged_decode_step(self, kv_cache, q, k, v):
         """Decode, verify or prefill-chunk step (T = 1, K + 1, the chunk
-        width) over PAGED storage. ``kv_cache`` holds the
+        width) over PAGED storage, up to the output projection: ``(y,
+        leaves)``, the leaves it wrote. ``kv_cache`` holds the
         pool's STACKED leaves whole ((L, P, KV, cache_d, lanes), no
         batch axis) with ``layer``, ``start`` and ``table``: this step's
         K/V columns go into this layer's pages through the table
@@ -748,15 +832,16 @@ class CachedAttention(nn.Module):
         assert jnp.ndim(start) == 1, \
             "paged decode is slot-pooled: start must be (B,)"
         if kv_cache_groups(cfg) is not None:
-            return self._grouped_paged_step(q, k, v, kv_cache)
+            return self._grouped_paged_step(kv_cache, q, k, v)
         table = kv_cache["table"]                  # (B, pages_per_slot)
         layer = kv_cache["layer"]
         page_size = cfg.max_seq_len // table.shape[1]
-        new_cache = {key: val for key, val in kv_cache.items()
-                     if key not in ("start", "table", "layer")}
+        new_cache = {}
+        chunk = _chunk_shaped(q)
 
         def write(key, cols):
-            new_cache[key] = paged_write_columns(
+            new_cache[key] = _traced_once(
+                paged_write_columns, "page_size", chunk=chunk)(
                 kv_cache[key], layer, cols, table, start,
                 page_size=page_size)
 
@@ -784,16 +869,14 @@ class CachedAttention(nn.Module):
         write("v", v_cols)
 
         slopes = alibi_slopes(H) if cfg.pos_emb == "alibi" else None
-        y = paged_decode_attention(
+        y = _traced_once(paged_decode_attention, "page_size",
+                         chunk=chunk)(
             q.astype(cfg.dtype), new_cache["k"], new_cache["v"], table,
             start, layer=layer, page_size=page_size, alibi_slopes=slopes,
             **scales)
-        y = y.astype(cfg.dtype).reshape(B, T, H * D)
-        o_proj = _dense(cfg, self.config.n_embd, use_bias=cfg.qkv_bias,
-                        name="o_proj")
-        return o_proj(y), new_cache
+        return y.astype(cfg.dtype).reshape(B, T, H * D), new_cache
 
-    def _grouped_paged_step(self, q, k, v, kv_cache):
+    def _grouped_paged_step(self, kv_cache, q, k, v):
         """:meth:`_paged_decode_step` over a pool of layer GROUPS
         (:func:`kv_cache_groups`): each group has its own stacked leaf
         and table (``k`` / ``table`` for the full layers, ``k_win`` /
@@ -811,12 +894,10 @@ class CachedAttention(nn.Module):
         cfg = self.config
         B, T, H, D = q.shape
         start, layer = kv_cache["start"], kv_cache["layer"]
-        new_cache = {key: val for key, val in kv_cache.items()
-                     if key not in ("start", "layer")
-                     and not key.startswith("table")}
+        new_cache = {}
         k_cols = k.astype(cfg.dtype).transpose(0, 2, 3, 1)    # (B, KV, D, T)
         v_cols = v.astype(cfg.dtype).transpose(0, 2, 3, 1)
-        y = None
+        y, chunk = None, _chunk_shaped(q)
         for suffix, layers, window in kv_cache_groups(cfg):
             place = np.full((cfg.n_layer,), -1, np.int32)
             place[list(layers)] = np.arange(len(layers))
@@ -825,19 +906,18 @@ class CachedAttention(nn.Module):
             table = kv_cache["table" + suffix]
             page_size = cfg.max_seq_len // table.shape[1]
             for key, cols in (("k", k_cols), ("v", v_cols)):
-                new_cache[key + suffix] = paged_write_columns(
+                new_cache[key + suffix] = _traced_once(
+                    paged_write_columns, "page_size", chunk=chunk)(
                     kv_cache[key + suffix], jnp.maximum(index, 0), cols,
                     table, start, page_size=page_size, active=active)
-            y_g = paged_decode_attention(
+            y_g = _traced_once(paged_decode_attention, "page_size",
+                               "window", chunk=chunk)(
                 q.astype(cfg.dtype), new_cache["k" + suffix],
                 new_cache["v" + suffix], table, start,
                 layer=jnp.maximum(index, 0), page_size=page_size,
                 window=window or None, active=active)
             y = y_g if y is None else jnp.where(active, y_g, y)
-        y = y.astype(cfg.dtype).reshape(B, T, H * D)
-        o_proj = _dense(cfg, cfg.n_embd, use_bias=cfg.qkv_bias,
-                        name="o_proj")
-        return o_proj(y), new_cache
+        return y.astype(cfg.dtype).reshape(B, T, H * D), new_cache
 
     @nn.compact
     def __call__(self, x, *, decode: Union[bool, str] = False,
@@ -868,7 +948,9 @@ class CachedAttention(nn.Module):
             # its own cache offset (serving/ continuous batching)
             start = kv_cache["start"]
             per_slot = jnp.ndim(start) == 1
-            positions = (start[:, None] if per_slot else start) \
+            positions = _chunk_positions(kv_cache, T) \
+                if "chunk" in kv_cache \
+                else (start[:, None] if per_slot else start) \
                 + jnp.arange(T)[None, :]
         else:
             start = jnp.zeros((), jnp.int32)
@@ -899,7 +981,10 @@ class CachedAttention(nn.Module):
             # inside Pallas calls — no dense per-slot view and no slice
             # of the leaf is ever materialized
             # (ops/attention/paged_attention.py).
-            return self._paged_decode_step(q, k, v, kv_cache)
+            y, leaves = _by_row_group(kv_cache, self._paged_decode_step,
+                                      q, k, v)
+            o_proj = _dense(cfg, C, use_bias=cfg.qkv_bias, name="o_proj")
+            return o_proj(y), leaves
 
         kv_scales = None  # set on the quantized-cache einsum fallback
         # "fresh" attention = causal over the just-computed k/v. True for
@@ -1193,7 +1278,9 @@ class LatentAttention(nn.Module):
 
         start = kv_cache["start"] if decode else jnp.zeros((), jnp.int32)
         per_slot = jnp.ndim(start) == 1
-        positions = (start[:, None] if per_slot else start) \
+        positions = _chunk_positions(kv_cache, T) \
+            if decode and "chunk" in kv_cache \
+            else (start[:, None] if per_slot else start) \
             + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         rope = functools.partial(apply_rotary, positions=positions,
                                  rotary_dim=dr, theta=cfg.rope_theta)
@@ -1218,9 +1305,9 @@ class LatentAttention(nn.Module):
             y = expanded().astype(cfg.dtype).reshape(B, T, H * dv)
             return o_proj(y), None
         assert kv_cache is not None, "decode needs the kv_cache slice"
-        # this step's cached rows, positions-minor like every cache here
-        cols = jnp.concatenate([c, k_r], -1).astype(cfg.dtype) \
-            .transpose(0, 2, 1)                               # (B, R + dr, T)
+        # this step's cached rows, stored positions-minor like every cache
+        # here
+        rows = jnp.concatenate([c, k_r], -1).astype(cfg.dtype)  # (B, T, R+dr)
         # the absorbed query: W_kvb^K^T q_n beside q_r, one (R + dr) row a
         # head that scores a cached row as it stands
         q_abs = jnp.concatenate(
@@ -1231,15 +1318,25 @@ class LatentAttention(nn.Module):
             from ..ops.attention.paged_attention import paged_write_columns
 
             assert per_slot, "paged decode is slot-pooled: start must be (B,)"
-            table, li = kv_cache["table"], kv_cache["layer"]
-            page_size = cfg.max_seq_len // table.shape[1]
-            leaf = paged_write_columns(kv_cache["c"], li, cols, table, start,
-                                       page_size=page_size)
-            ctx = latent_attention(q_abs, leaf, table, start, layer=li,
-                                   page_size=page_size, rank=R, scale=scale)
-            new_cache = {"c": leaf}
+
+            def paged(cache, q_abs, rows):
+                table, li = cache["table"], cache["layer"]
+                page_size = cfg.max_seq_len // table.shape[1]
+                chunk = _chunk_shaped(q_abs)
+                leaf = _traced_once(paged_write_columns, "page_size",
+                                    chunk=chunk)(
+                    cache["c"], li, rows.transpose(0, 2, 1), table,
+                    cache["start"], page_size=page_size)
+                return _traced_once(
+                    latent_attention, "page_size", "rank", "scale",
+                    chunk=chunk)(
+                    q_abs, leaf, table, cache["start"], layer=li,
+                    page_size=page_size, rank=R, scale=scale), {"c": leaf}
+
+            ctx, new_cache = _by_row_group(kv_cache, paged, q_abs, rows)
         else:
-            leaf = _store_columns(kv_cache["c"], cols, start)   # (B, R+dr, S)
+            leaf = _store_columns(kv_cache["c"], rows.transpose(0, 2, 1),
+                                  start)                        # (B, R+dr, S)
             new_cache = dict(kv_cache, c=leaf)
             if decode == "prefill" and T > 1:
                 # start == 0: the prompt's causal window IS the fresh rows
@@ -1328,45 +1425,57 @@ class Mamba2Mixer(nn.Module):
         gate_norm = self.param("norm", nn.initializers.ones, (inner,))
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias)          # (B, T, H)
 
-        tail = jnp.zeros((B, K - 1, ch), xbc.dtype)
-        valid = jnp.full((B,), T, jnp.int32)
         cached = bool(decode)
-        if cached:
-            start = kv_cache["start"]
-            li = kv_cache["layer"]
-            rows = kv_cache.get("rows")
-            if rows is None:
-                rows = jnp.arange(B, dtype=jnp.int32)
-            R = kv_cache["s"].shape[1]
-            fresh = jnp.broadcast_to(start == 0, (B,))
-            if kv_cache.get("valid") is not None:
-                valid = jnp.minimum(kv_cache["valid"], T)
-            # this layer's tail of each entry's row (an entry that does
-            # not run: row R, read as zeros and dropped when written)
-            at = (li, jnp.where((rows >= 0) & (rows < R), rows, R))
-            tail = jnp.where(fresh[:, None, None], 0, kv_cache["conv"].at[
-                at].get(mode="fill", fill_value=0).reshape(tail.shape))
-        xbc, tail = ss.causal_conv(xbc, tail, conv_w, conv_b, valid)
-        if cached:
-            conv_leaf = kv_cache["conv"].at[at].set(
-                tail.reshape(B, -1).astype(kv_cache["conv"].dtype),
-                mode="drop")
-        x = xbc[..., :inner].reshape(B, T, H, P)
-        b, c = xbc[..., inner:inner + N], xbc[..., inner + N:]
-        if not cached:
-            y = ss.ssm_sequence(x, dt, a, b, c)
-        elif T == 1:
-            y, s = ss.ssm_decode(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0],
-                                 kv_cache["s"], li, rows, fresh)
-            y = y[:, None]
-        else:
-            y, s = ss.ssm_prefill(x, dt, a, b, c, kv_cache["s"], li, rows,
-                                  fresh, length=valid)
-        y = (y + skip[:, None] * x).reshape(B, T, inner)
+
+        def mix(cache, xbc, dt):
+            """The convolution and the state of one group of rows (B, T):
+            ``(y + D x, leaves)``."""
+            B, T = dt.shape[:2]
+            tail = jnp.zeros((B, K - 1, ch), xbc.dtype)
+            valid = jnp.full((B,), T, jnp.int32)
+            if cached:
+                start = cache["start"]
+                li = cache["layer"]
+                rows = cache.get("rows")
+                if rows is None:
+                    rows = jnp.arange(B, dtype=jnp.int32)
+                R = cache["s"].shape[1]
+                fresh = jnp.broadcast_to(start == 0, (B,))
+                if cache.get("valid") is not None:
+                    valid = jnp.minimum(cache["valid"], T)
+                # this layer's tail of each entry's row (an entry that does
+                # not run: row R, read as zeros and dropped when written)
+                at = (li, jnp.where((rows >= 0) & (rows < R), rows, R))
+                tail = jnp.where(fresh[:, None, None], 0, cache["conv"].at[
+                    at].get(mode="fill", fill_value=0).reshape(tail.shape))
+            xbc, tail = ss.causal_conv(xbc, tail, conv_w, conv_b, valid)
+            if cached:
+                conv_leaf = cache["conv"].at[at].set(
+                    tail.reshape(B, -1).astype(cache["conv"].dtype),
+                    mode="drop")
+            x = xbc[..., :inner].reshape(B, T, H, P)
+            b, c = xbc[..., inner:inner + N], xbc[..., inner + N:]
+            if not cached:
+                y, leaves = ss.ssm_sequence(x, dt, a, b, c), None
+            else:
+                if T == 1:
+                    y, s = ss.ssm_decode(x[:, 0], dt[:, 0], a, b[:, 0],
+                                         c[:, 0], cache["s"], li, rows, fresh)
+                    y = y[:, None]
+                else:
+                    y, s = _traced_once(ss.ssm_prefill,
+                                        chunk=_chunk_shaped(x))(
+                        x, dt, a, b, c, cache["s"], li, rows, fresh,
+                        length=valid)
+                leaves = {"s": s, "conv": conv_leaf}
+            return (y + skip[:, None] * x).reshape(B, T, inner), leaves
+
+        y, leaves = _by_row_group(kv_cache, mix, xbc, dt) if cached \
+            else mix(None, xbc, dt)
         g = ss.gated_norm(y, z, gate_norm, cfg.layer_norm_epsilon)
         out = _dense(cfg, C, use_bias=False, name="out_proj")(
             g.astype(cfg.dtype))
-        return out, ({"s": s, "conv": conv_leaf} if cached else None)
+        return out, leaves
 
 
 class TransformerMLP(nn.Module):
@@ -2229,7 +2338,7 @@ class TransformerLM(nn.Module):
 
     def _transform(self, input_ids, positions, decode, deterministic,
                    head=True, paged_table=None, state_rows=None,
-                   valid_len=None):
+                   valid_len=None, chunk=None):
         cfg = self.config
         B, T = input_ids.shape
         x = self.embed_tokens(input_ids)
@@ -2265,6 +2374,12 @@ class TransformerLM(nn.Module):
                 if valid_len is not None:
                     cache["valid"] = jnp.broadcast_to(
                         jnp.asarray(valid_len, jnp.int32), (B,))
+            if chunk is not None:
+                # a prefill chunk's rows ahead of the decode rows
+                # (:meth:`chunk_beside_decode`): how the chunk's rows reach
+                # the cache rides beside the decode rows' own addressing,
+                # and a mixer takes the two apart (``_by_row_group``)
+                cache["chunk"] = chunk
             carry = (x, cache, start, jnp.zeros((), jnp.int32))
             if cfg.first_k_dense:
                 carry, _ = self.dense_blocks(carry, decode, deterministic)
@@ -2283,8 +2398,11 @@ class TransformerLM(nn.Module):
                          init_fn=lambda: None, reduce_fn=lambda _, new: new)
             cache = {key: val for key, val in cache.items()
                      if not key.startswith("table")
-                     and key not in STATE_ROW_KEYS}
-            self.cache_store(B, new_values=cache, new_index=start + T,
+                     and key not in STATE_ROW_KEYS + ("chunk",)}
+            # (beside a chunk every decode row moved one position; the
+            # chunk's slot is the caller's to place)
+            self.cache_store(B, new_values=cache,
+                             new_index=start + (T if chunk is None else 1),
                              paged=paged)
         else:
             carry = (x, None, jnp.zeros((), jnp.int32),
@@ -2438,6 +2556,47 @@ class TransformerLM(nn.Module):
         pos = off + jnp.broadcast_to(jnp.arange(T)[None], (B, T))
         return self._transform(input_ids, pos, True, True,
                                paged_table=table, state_rows=rows)
+
+    def chunk_beside_decode(self, chunk_ids, chunk_start, last_idx,
+                            chunk_table, input_ids, start_pos, table,
+                            rows=None, chunk_row=None):
+        """A :meth:`prefill_chunk` step of ONE slot through its table row(s)
+        and a :meth:`decode_paged` step of every slot in one pass over the
+        layers: ``chunk_ids`` (1, C) at ``chunk_start`` (1,) through
+        ``chunk_table`` (1, pages_per_slot; a dict of one a layer group),
+        ``input_ids`` (B,) at ``start_pos`` (B,) through ``table``. The
+        ``C + B`` rows go through the layers side by side, so whatever
+        reads a weight (the embedding, the norms, the projections, the FFN
+        or the experts, the head) reads it ONCE for both; what reads the
+        cache runs the chunk's rows and then the decode rows, each through
+        the kernels of the separate steps (``_by_row_group``). The provided
+        cache's ``index`` holds the decode rows' positions AS THE CHUNK
+        LEAVES THEM (its slot's entry past the chunk) and comes back
+        advanced by one. ``rows`` as :meth:`decode_paged`'s; ``chunk_row``
+        (1,), for a pool with a state group: the chunk's slot. Returns the
+        logits of the chunk's ``last_idx`` (1, 1, V) and of the decode rows
+        (B, 1, V); call with ``mutable=["cache"]``. What each row computes
+        is what the separate steps compute for it."""
+        C = chunk_ids.shape[1]
+        ids = jnp.concatenate([chunk_ids, input_ids[None, :]], axis=1)
+        pos = jnp.concatenate(
+            [chunk_start[:, None] + jnp.arange(C)[None, :],
+             start_pos[None, :]], axis=1)
+        chunk = dict(chunk_table if isinstance(chunk_table, dict)
+                     else {"table": chunk_table}, start=chunk_start)
+        if chunk_row is not None:
+            chunk.update(
+                rows=jnp.asarray(chunk_row, jnp.int32),
+                valid=jnp.broadcast_to(
+                    jnp.asarray(last_idx, jnp.int32) + 1, (1,)))
+        x = self._transform(ids, pos, True, True, head=False,
+                            paged_table=table, state_rows=rows,
+                            chunk=chunk)[0]
+        picked = jnp.concatenate(
+            [jax.lax.dynamic_slice_in_dim(
+                x, jnp.asarray(last_idx, jnp.int32), 1, 0), x[C:]])
+        logits = self._project_head(picked[:, None])
+        return logits[:1], logits[1:]
 
     def __call__(self, batch, deterministic: bool = False):
         cfg = self.config

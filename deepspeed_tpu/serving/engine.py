@@ -109,7 +109,8 @@ _WATCHED_POOL_JITS = ("_admit_jit", "_admit_rows_jit",
                       "_paged_decode_jit", "_paged_verify_jit",
                       "_paged_decode_kernel_jit",
                       "_paged_verify_kernel_jit",
-                      "_paged_chunk_jit", "_jit_copy_page",
+                      "_paged_chunk_jit", "_paged_chunk_decode_jit",
+                      "_jit_copy_page",
                       "_jit_gather_pages", "_jit_scatter_pages")
 _WATCHED_SERVING_JITS = ("_jit_finite", "_jit_cur_scatter", "_jit_spec_cur")
 # the model drafter jits its own last-token argmax (lazily, on the
@@ -540,6 +541,9 @@ class ServingEngine:
         # FIFO of seated PREFILLING requests whose prompts are still
         # streaming in chunk by chunk; step() advances the head only
         self._prefill_queue: List[Request] = []
+        # the chunk a step has prepared and left to its decode dispatch:
+        # (request, ids, position, length, when its preparation began)
+        self._chunk_beside: Optional[tuple] = None
         self.temperature = cfg.temperature if temperature is None else temperature
         self.top_k = cfg.top_k if top_k is None else top_k
         self.top_p = cfg.top_p if top_p is None else top_p
@@ -662,10 +666,30 @@ class ServingEngine:
             for attr in _WATCHED_DRAFTER_JITS:
                 wd.attach(drafter, attr, name=f"Drafter.{attr}")
 
+    @property
+    def _fuses_chunks(self) -> bool:
+        """Whether a chunk beside running slots goes with the decode rows
+        as ONE program, by what this server is: a paged pool whose chunk
+        reads its pages in place (``PagedKVPool.fuses``), plain decoding,
+        the default order (with ``overlap`` the decode is queued before
+        the chunk is prepared) and a role that decodes."""
+        return (self._paged and self._spec is None and not self._overlap
+                and self.role != "prefill" and self.prefill_chunk > 0
+                and self.pool.fuses(self.prefill_chunk))
+
     def end_warmup(self) -> None:
         """Declare warmup traffic over: from here on, any recompile counts
         against :attr:`watchdog` ``.recompiles`` (and raises in strict
-        mode at the next step boundary)."""
+        mode at the next step boundary). A server that fuses a chunk with
+        the decode rows first brings that program in itself
+        (``PagedKVPool.warm_chunk_decode``): warm-up traffic that drains
+        a request at a time never puts a chunk beside a running slot."""
+        if self._fuses_chunks:
+            rows = (self._cur_commit(np.full((self.pool.num_slots,), -1,
+                                             np.int32)),) \
+                if self._state_row_bytes else ()
+            self.pool.warm_chunk_decode(self.engine, self.prefill_chunk,
+                                        self._cur_dev, *rows)
         self.watchdog.end_warmup()
 
     # -- warmup signature manifest (graftcheck witness) -----------------
@@ -1644,13 +1668,26 @@ class ServingEngine:
             with _PagesPhase(self):
                 self._ensure_pages(slot, pos, pos + L, sync=False)
         self._dispatched["chunk"] = L
+        # a chunk that does not end its prompt, beside running slots, is
+        # queued by this step's decode dispatch, with the decode rows in
+        # ONE program (``_decode_step``); a prompt's last chunk keeps its
+        # own: the slot it finishes decodes in this same step, from the
+        # token this chunk's head has yet to choose. The running slots are
+        # counted AFTER the pages above: paging the chunk in may have
+        # preempted the last of them, and then no decode follows
+        beside = (pos + L < seed_len and self._fuses_chunks
+                  and self._running_count() > 0)
         with self._phase("prepare", "serving/prefill_chunk",
                          rid=req.request_id, pos=pos, len=L) as sp:
             self._note_state_rows(sp, 1, L)
             self._note_latent(sp, pos + L, L)
             if self._paged:
-                logits = self.pool.run_prefill_chunk(
-                    self.engine, ids, slot, pos, L, L - 1)
+                if beside:
+                    self._chunk_beside = (req, ids, pos, L, t0)
+                    logits = None       # (the decode dispatch's to compute)
+                else:
+                    logits = self.pool.run_prefill_chunk(
+                        self.engine, ids, slot, pos, L, L - 1)
                 sp.set(pool_writes=self.pool.pages_touched(slot, pos, C))
                 self._set_pool_reads(sp, C, [slot], [pos])
             else:
@@ -1670,7 +1707,7 @@ class ServingEngine:
         req.chunks += 1
         self.timelines.record(req.request_id, "prefill_chunk", pos=pos,
                               len=L)
-        if req.prefill_pos >= seed_len:
+        if logits is not None and req.prefill_pos >= seed_len:
             with self._phase("prepare", "serving/sample"):
                 # dispatch only; host value arrives at the end-of-step
                 # fetch
@@ -1698,11 +1735,13 @@ class ServingEngine:
                 self._maybe_retire(req, token, finished)
 
             self._defer([tok_dev], _on_chunk_token)
-        else:
+        elif not beside:
             # no sync: the chunk is enqueued and this step's decode
             # dispatch overlaps its host-side latency — the device
             # serializes them anyway, and step_gap captures the real
-            # wall cost. Recorded time is therefore enqueue-side only.
+            # wall cost. Recorded time is therefore enqueue-side only
+            # (a chunk left to the decode dispatch is recorded there,
+            # once its program is enqueued)
             self.metrics.record_prefill(L, self._now() - t0,
                                         blocking=running_before > 0)
 
@@ -2165,6 +2204,10 @@ class ServingEngine:
                         self._spec_decode_step(finished, t0)
                     else:
                         self._decode_step(finished, t0)
+                # a chunk prepared for the decode dispatch went with it
+                # or was dropped by it: its mirrors have moved, so one
+                # left behind would be columns never written
+                assert self._chunk_beside is None, "chunk never dispatched"
                 # the step's ONE device sync: fetch every deferred
                 # token/flag at once, then replay host bookkeeping in
                 # dispatch order
@@ -2372,7 +2415,11 @@ class ServingEngine:
         publishes: the page table when a running slot crosses a page
         boundary (``_ensure_decode_pages``), a state model's ``rows``
         when the running set changed, and the index after the program
-        when a prefilling slot rode along."""
+        when a prefilling slot rode along. Where this step's chunk was
+        left to this dispatch (``_prefill_chunk_step``), the one program
+        is the chunk's and the decode's together
+        (``PagedKVPool.run_chunk_decode``): it leaves the device where the
+        two leave it, so everything after the call is the same."""
         eng = self.engine
         if self._paged:
             # page the write column in BEFORE snapshotting the running
@@ -2383,6 +2430,15 @@ class ServingEngine:
                    if req.state is RequestState.RUNNING]
         self._dispatched["decode"] = len(running)
         more = self._state_rows(running)
+        # the chunk this step left to this dispatch, unless paging the
+        # write column in just preempted its request (the slot is free and
+        # its pages are back in the heap: nothing of it may run)
+        beside = self._chunk_beside
+        self._chunk_beside = None
+        if beside is not None and self._slot_req.get(beside[0].slot) \
+                is not beside[0]:
+            beside = None
+        self._dispatched["fused"] = int(beside is not None)
         with self._phase("prepare", "serving/decode",
                          live=len(running)) as sp:
             self._note_state_rows(sp, len(running))
@@ -2392,7 +2448,16 @@ class ServingEngine:
                     sp, int(self.pool.starts[slots].sum()) + len(slots),
                     len(slots))
             if self._paged:
-                logits = self.pool.run_decode(eng, self._cur_dev, *more)
+                if beside is not None:
+                    req, ids, pos, L, t_chunk = beside
+                    _, logits = self.pool.run_chunk_decode(
+                        eng, ids, req.slot, pos, L, L - 1, self._cur_dev,
+                        *more)
+                    self.registry.counter("serving/fused_steps").inc()
+                    self.metrics.record_prefill(L, self._now() - t_chunk,
+                                                blocking=True)
+                else:
+                    logits = self.pool.run_decode(eng, self._cur_dev, *more)
                 # counted after the dispatch, from a mirror the dispatch
                 # does not move: every slot's row is in the program's
                 # work list, the live ones map a page at their index
@@ -2596,6 +2661,7 @@ class ServingEngine:
                                   reason="step_error")
         self.scheduler.requeue_front(prefilling)
         self._prefill_queue[:] = []
+        self._chunk_beside = None
         for req in self._slot_req.values():
             req.state = RequestState.FAILED
             req.finish_reason = FinishReason.ERROR

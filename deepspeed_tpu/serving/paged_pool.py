@@ -41,6 +41,10 @@ kernel active, decode, verify and (since PR 33) the prefill chunk skip
 the dense view: the model writes and reads pages in place
 (``decode_paged``; ``prefill_chunk`` with a table), and the dense
 composition is the oracle they are tested against, not a served path.
+A chunk that does not end its prompt, beside running slots, goes with
+the decode rows as ONE program (PR 48: :meth:`PagedKVPool.run_chunk_decode`
+over ``TransformerLM.chunk_beside_decode``): the weights are read once
+for both groups of rows, the cache through each group's own kernels.
 Batched admission of short prompts still hands in a full-capacity
 prefill cache (ROADMAP S4).
 
@@ -364,6 +368,9 @@ class PagedKVPool(SlotPool):
         self.kernel = kernel
         self._paged_decode_kernel_jit = None
         self._paged_verify_kernel_jit = None
+        # a prefill chunk beside the decode rows as ONE program (built
+        # with the kernel entries: it goes through the pages in place)
+        self._paged_chunk_decode_jit = None
         self._jit_copy_page = jax.jit(self._copy_page_body,
                                       donate_argnums=(0,))
         # the cross-pool transfer is two programs, not one: replicas
@@ -1076,13 +1083,28 @@ class PagedKVPool(SlotPool):
             out["index"] = ncs["index"]
             return out, out_tok, n_emit, rng
 
+        def chunk_args(packed):
+            # a chunk's host-built vector taken apart (``pack_chunk_args``):
+            # the ids, the scalars and the slot's row of each group's
+            # table as a table of one row
+            ids, slot, start, length, last_idx, *rows = unpack_chunk_args(
+                packed, self.pages_per_slot, len(self._table_keys))
+            return ids, slot, start, length, last_idx, \
+                self._group_tables(*(row[None] for row in rows))
+
+        def patched_tables(cs, row_tables, slot):
+            # the device tables with the row the program was handed: the
+            # host mapped the chunk's fresh pages in its mirror alone
+            return {key: jax.lax.dynamic_update_slice(
+                cs[key], row, (slot, jnp.zeros((), jnp.int32)))
+                for key, row in row_tables.items()}
+
         def paged_chunk(params, cs, packed):
             # ONE slot's chunk through its table row (and the window
             # group's where there is one), which arrive with the ids and
-            # the scalars as one vector (``pack_chunk_args``)
-            ids, slot, start, length, last_idx, *rows = unpack_chunk_args(
-                packed, self.pages_per_slot, len(self._table_keys))
-            row_tables = self._group_tables(*(row[None] for row in rows))
+            # the scalars as one vector
+            ids, slot, start, length, last_idx, row_tables = \
+                chunk_args(packed)
             state_row = {"rows": slot[None]} if state_leaves else {}
             if self.reads_in_place(ids.shape[1]):
                 # as kernel_apply does for a decode step: the model
@@ -1117,11 +1139,7 @@ class PagedKVPool(SlotPool):
                 outcs.update({key: new[key] for key in state_leaves})
             outcs["index"] = cs["index"].at[slot].set(start + length,
                                                       mode="drop")
-            # the device tables get the row the program was handed: the
-            # host mapped the chunk's fresh pages in its mirror alone
-            for key, row in row_tables.items():
-                outcs[key] = jax.lax.dynamic_update_slice(
-                    cs[key], row, (slot, jnp.zeros((), jnp.int32)))
+            outcs.update(patched_tables(cs, row_tables, slot))
             return out, outcs, vars_["stats"]["moe"] if want_stats else None
 
         self._paged_decode_jit = jax.jit(paged_decode, donate_argnums=(1,))
@@ -1182,11 +1200,45 @@ class PagedKVPool(SlotPool):
                     rng, temperature, greedy, top_k, top_p)
                 return new["cache_store"], out_tok, n_emit, rng
 
+            def paged_chunk_beside_decode(params, cs, packed, token,
+                                          rows=None):
+                # ``paged_chunk`` and ``kernel_decode`` of one step as ONE
+                # pass over the layers (``chunk_beside_decode``): a weight
+                # is read once for the chunk's rows and the decode rows.
+                # The decode rows see the device as the chunk program
+                # leaves it, its slot's row in the tables and its index
+                # past the chunk, and the program hands back what the
+                # decode program after it would have
+                ids, slot, start, length, last_idx, row_tables = \
+                    chunk_args(packed)
+                tables = patched_tables(cs, row_tables, slot)
+                vals = {k: v for k, v in cs.items() if k not in tables}
+                vals["index"] = cs["index"].at[slot].set(start + length,
+                                                         mode="drop")
+                more = {} if rows is None else {"rows": rows,
+                                                "chunk_row": slot[None]}
+                (chunk_logits, logits), vars_ = module.apply(
+                    {"params": dequant(params),
+                     "cache": {"cache_store": vals}},
+                    ids, start[None], last_idx,
+                    row_tables if grouped else row_tables["table"],
+                    token, jnp.minimum(vals["index"], capacity - 1),
+                    tables if grouped else tables["table"],
+                    method=module.chunk_beside_decode, mutable=mutable,
+                    **more)
+                new = dict(vars_["cache"]["cache_store"], **tables)
+                return chunk_logits, logits, new, \
+                    vars_["stats"]["moe"] if want_stats else None
+
             self._paged_decode_kernel_jit = jax.jit(kernel_decode,
                                                     donate_argnums=(1,))
             self._paged_verify_kernel_jit = jax.jit(
                 kernel_verify, donate_argnums=(1,), static_argnums=(8, 9),
                 out_shardings=verify_out)
+            if chunk_gen is not None and getattr(
+                    module, "chunk_beside_decode", None) is not None:
+                self._paged_chunk_decode_jit = jax.jit(
+                    paged_chunk_beside_decode, donate_argnums=(1,))
         # pre-compile the CoW copy program with a no-op self-copy: the
         # first real fork can land arbitrarily late (a prefix hit on a
         # page some earlier request published), easily after warmup
@@ -1298,11 +1350,7 @@ class PagedKVPool(SlotPool):
             raise ValueError("run_prefill_chunk requires a module with "
                              "prefill_chunk(); the TransformerLM family "
                              "has one")
-        self._publish_stale(own=slot)
-        with self.enqueue("chunk", "transfer"):
-            packed = pack_chunk_args(
-                ids, slot, start, length, last_idx, self.table[slot],
-                *self._window_rows([slot]))
+        packed = self._chunk_vector(ids, slot, start, length, last_idx)
         with self.enqueue("chunk"):
             logits, cs, stats = self._paged_chunk_jit(
                 engine.params, self.cache["cache_store"], packed)
@@ -1311,6 +1359,75 @@ class PagedKVPool(SlotPool):
         if stats is not None:
             self.moe_stats.append(stats)
         return logits
+
+    def _chunk_vector(self, ids, slot: int, start: int, length: int,
+                      last_idx: int):
+        """A chunk's arguments as its program takes them: the tables
+        published but for the slot's own row (the program patches it),
+        then the ONE vector with that row from the host's mirror."""
+        self._publish_stale(own=slot)
+        with self.enqueue("chunk", "transfer"):
+            return pack_chunk_args(
+                ids, slot, start, length, last_idx, self.table[slot],
+                *self._window_rows([slot]))
+
+    def fuses(self, count: int) -> bool:
+        """Whether a chunk of ``count`` tokens beside a decode step can go
+        as ONE program (:meth:`run_chunk_decode`): the program was built
+        (the kernel is active and the model has the pass) and a chunk of
+        that width reads its pages in place (:meth:`reads_in_place`)."""
+        return self._paged_chunk_decode_jit is not None \
+            and self.reads_in_place(count)
+
+    def run_chunk_decode(self, engine: Any, ids, slot: int, start: int,
+                         length: int, last_idx: int, tokens, *rows):
+        """:meth:`run_prefill_chunk` of ``slot`` and :meth:`run_decode` of
+        every slot as ONE program, one pass over the layers
+        (``TransformerLM.chunk_beside_decode``). Arguments, publication of
+        the tables and what the device holds afterwards are those of the
+        two calls in that order: the chunk's arguments ride in one vector,
+        the token twin and the state ``rows`` are taken as they are, the
+        chunk's slot row is written into the device tables, its index is
+        ``start + length + 1`` and every other slot's is advanced by one.
+        Returns the chunk's (1, 1, V) logits and the decode rows' (the
+        server drops the first: a chunk that ends a prompt keeps the two
+        programs, the second of which decodes from the token the first
+        one's head chose)."""
+        self.bind_engine(engine)
+        packed = self._chunk_vector(ids, slot, start, length, last_idx)
+        with self.enqueue("chunk_decode"):
+            chunk_logits, logits, cs, stats = self._paged_chunk_decode_jit(
+                engine.params, self.cache["cache_store"], packed, tokens,
+                *rows)
+        self.cache = {"cache_store": cs}
+        self._stale_rows.discard(slot)
+        if stats is not None:
+            self.moe_stats.append(stats)
+        return chunk_logits, logits
+
+    def warm_chunk_decode(self, engine: Any, width: int, tokens,
+                          *rows) -> None:
+        """Bring :meth:`run_chunk_decode`'s program in with a call that
+        leaves the pool as it was: the harness's warm-up drains a request a
+        pass, so no step of it carries a chunk beside a running slot, and
+        the first real one would compile inside the measured window. The
+        chunk is of no token through an all-sentinel row (every write of
+        it drops) and the decode rows are a masked step's (``rows``: none
+        runs); the row and the indices it moved are put again from the
+        host's mirrors."""
+        if not self.fuses(width):
+            return
+        nobody = self.num_slots         # (out of range: all-sentinel rows)
+        packed = pack_chunk_args(
+            np.zeros((1, width), np.int32), nobody, 0, 0, 0,
+            np.full((self.pages_per_slot,), self.num_pages, np.int32),
+            *self._window_rows([nobody]))
+        _, _, cs, _ = self._paged_chunk_decode_jit(
+            engine.params, self.cache["cache_store"], packed, tokens, *rows)
+        cs = dict(cs)
+        cs["index"] = self._index_from_mirror()
+        self.cache = {"cache_store": cs}
+        self._sync_table()
 
     # ------------------------------------------------------------------
     # admission (SlotPool API, paged storage)

@@ -357,7 +357,8 @@ def test_ssm_kernels_compile_for_a_described_v5e_at_the_served_shape(
 
 def test_a_state_group_beside_pages_compiles_with_no_copy_of_a_leaf(
         compiled_kernels, described_v5e):
-    """The decode and the chunk program of a server of mamba and attention
+    """The decode, the chunk and (PR 48) the chunk-beside-decode program of
+    a server of mamba and attention
     layers at the published widths of one period (``[m, m, attention, m]``
     in place of ``[5, attention, 4]``: the body of the period's scan does
     not depend on the count), 64 slots, 1,536 pages of 128, compiled for a
@@ -407,26 +408,33 @@ def test_a_state_group_beside_pages_compiles_with_no_copy_of_a_leaf(
     packed = jnp.asarray(pack_chunk_args(
         np.zeros((1, chunk), np.int32), 0, chunk, chunk, chunk - 1,
         np.zeros((pool.pages_per_slot,), np.int32)))
+    step = ["paged_write"] * 2 + ["paged_decode"]
     programs = {
         "kernel_decode": (pool._paged_decode_kernel_jit,
-                          (engine.params, cs, token, token), "ssm_decode"),
+                          (engine.params, cs, token, token),
+                          ["ssm_decode"] * 2 + step),
         "paged_chunk": (pool._paged_chunk_jit, (engine.params, cs, packed),
-                        "ssm_chunk")}
+                        ["ssm_chunk"] * 2 + step),
+        # (PR 48) the chunk's rows and the decode rows in one pass: each
+        # group through its own kernels, the projections over both
+        "paged_chunk_beside_decode": (
+            pool._paged_chunk_decode_jit,
+            (engine.params, cs, packed, token, token),
+            ["ssm_chunk"] * 2 + ["ssm_decode"] * 2 + step * 2)}
     mesh.reset_mesh()       # (the engine's mesh is of this process's CPUs)
 
     def described(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=described_v5e)
 
     with _compile_cache_off():
-        for name, (jitted, args, kernel) in programs.items():
+        for name, (jitted, args, kernels) in programs.items():
             compiled = jitted.lower(*jax.tree_util.tree_map(
                 described, args)).compile()
             text = compiled.as_text()
             calls = re.findall(
                 r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*tpu_custom_call",
                 text)
-            assert sorted(calls) == sorted(
-                [kernel] * 2 + ["paged_write"] * 2 + ["paged_decode"]), name
+            assert sorted(calls) == sorted(kernels), name
             assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26, \
                 (name, compiled.memory_analysis())
 
@@ -546,12 +554,18 @@ def _small_paged_server(pages=6):
             cs, pre, jnp.zeros((2, pool.pages_per_slot), i32),
             jnp.zeros((2,), i32), jnp.zeros((2,), i32))),
     }
+    # (PR 48) a chunk beside the decode rows, ONE program: the chunk's
+    # vector and the token twin
+    programs["paged_chunk_beside_decode"] = (
+        pool._paged_chunk_decode_jit,
+        programs["paged_chunk"][1] + (jnp.zeros((slots,), i32),))
     return pool, programs
 
 
 def test_no_program_of_a_serving_step_passes_over_a_pool_leaf(
         compiled_kernels):
-    """``kernel_decode``, ``paged_chunk`` and the admission program take the
+    """``kernel_decode``, ``paged_chunk``, (PR 48) the two as one program and
+    the admission program take the
     stacked K and V leaves and hand them on through custom calls alone: no
     slice, update-slice, scatter, gather or transpose of a leaf (one
     layer's or the stacked one). Since PR 33 the chunk program reads
@@ -707,6 +721,98 @@ def test_attention_projections_read_the_stacked_leaf(
         assert not found, (kind, name, found)
 
 
+# the paged serve cells' models at the widths of their ``perf/configs/``
+# files, cut where a compile's cost lies and its text does not: depth (the
+# layer scan's body is one), experts 64 -> 8, the vocabulary. Family, widths,
+# the pool (pages as served), a chunk's tokens, the kernels one layer body
+# holds for the chunk's rows and for the decode rows, the stacked weights
+# that must not be copied (bytes of one)
+_SERVED = {
+    "pythia-1.4b-paged": ("gpt-neox", dict(
+        max_seq_len=2048, n_embd=2048, n_layer=2, n_head=16),
+        dict(num_pages=256, page_size=64), 64,
+        ["paged_write"] * 4 + ["paged_decode"] * 2),
+    "mellum2-12b-a2b5-paged": ("mellum", dict(
+        max_seq_len=8192, n_embd=2304, n_layer=4, n_head=32, n_kv_head=4,
+        head_size=128, ffn_dim=896, n_experts=8, experts_per_token=8,
+        norm_topk_prob=True,
+        layer_types=["sliding_attention"] * 3 + ["full_attention"],
+        sliding_window=1024, rope_theta=500000),
+        dict(num_pages=1024, page_size=128, prefix_cache=False), 128,
+        ["paged_write"] * 8 + ["paged_decode"] * 4
+        + ["moe_gate_up", "moe_down"]),
+    "moonlight-16b-a3b-mla": ("moonlight", dict(
+        max_seq_len=8192, n_embd=2048, n_layer=3, n_head=16, n_kv_head=16,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, ffn_dim=1408, n_experts=8, experts_per_token=6,
+        norm_topk_prob=True, scoring_func="sigmoid",
+        routed_scaling_factor=2.446, n_shared_experts=2,
+        mlp_layer_types=["dense", "sparse", "sparse"], dense_ffn_dim=11264,
+        rope_theta=50000),
+        dict(num_pages=3072, page_size=128, prefix_cache=False), 128,
+        # (the dense scan's body and the sparse scan's)
+        (["paged_write"] * 2 + ["mla_chunk", "mla_decode"]) * 2
+        + ["moe_gate_up", "moe_down"]),
+}
+
+
+@pytest.mark.parametrize("config", sorted(_SERVED))
+def test_a_chunk_beside_decode_compiles_for_a_described_v5e_as_served(
+        compiled_kernels, described_v5e, config):
+    """PR 48. The one program of a step that carries a chunk beside running
+    slots, for the three paged serve configurations whose layers are one
+    scan (Granite's, at the published widths of one period, is in
+    ``test_a_state_group_beside_pages_compiles_with_no_copy_of_a_leaf``):
+    64 slots, the pool's pages and the chunk as served. Mosaic takes the
+    chunk's kernels and the decode's in one program; a layer body holds
+    each group's cache kernels and ONE routed FFN (``moe_gate_up`` /
+    ``moe_down`` once, over both groups' rows: the experts are read once);
+    the pool's leaves are aliased through (a copy of one, or of a stacked
+    weight, would be over the temporaries' bound) and the tables come back
+    as leaves of the result."""
+    from deepspeed_tpu.inference.engine import pack_chunk_args
+    from deepspeed_tpu.parallel import mesh
+    from deepspeed_tpu.serving.paged_pool import PagedKVPool
+
+    family, widths, paged, chunk, kernels = _SERVED[config]
+    model, engine = _zero_engine(family, **widths)
+    slots = 64
+    pool = PagedKVPool(model.kv_cache_spec(), 2, kernel="on",
+                       **dict(paged, num_pages=2))
+    pool.bind_engine(engine)
+    assert pool.fuses(chunk)
+    served = jax.eval_shape(lambda: model.kv_cache_spec().paged_cache(
+        paged["num_pages"], paged["page_size"],
+        slots * (-(-1024 // 128) + 1) if pool.ring is not None else None,
+        num_slots=slots))
+    cs = dict(served, index=jax.ShapeDtypeStruct((slots,), jnp.int32))
+    rows = []
+    for key in pool._table_keys:
+        cs[key] = jax.ShapeDtypeStruct((slots, pool.pages_per_slot),
+                                       jnp.int32)
+        rows.append(np.zeros((pool.pages_per_slot,), np.int32))
+    packed = jnp.asarray(pack_chunk_args(
+        np.zeros((1, chunk), np.int32), 0, chunk, chunk, chunk - 1, *rows))
+    args = (engine.params, cs, packed, jnp.zeros((slots,), jnp.int32))
+    mesh.reset_mesh()       # (the engine's mesh is of this process's CPUs)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=described_v5e)
+
+    with _compile_cache_off():
+        compiled = pool._paged_chunk_decode_jit.lower(
+            *jax.tree_util.tree_map(described, args)).compile()
+    text = compiled.as_text()
+    calls = re.findall(
+        r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*tpu_custom_call", text)
+    assert sorted(calls) == sorted(kernels), calls
+    leaf_bytes = min(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                     for key, leaf in served.items())
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < min(leaf_bytes, 2 ** 27), compiled.memory_analysis()
+    assert "may-alias" in text
+
+
 def _program_text(compiled) -> str:
     """A compiled program's HLO without what names this checkout: the
     stack-frame tables and ``metadata`` (files and line numbers), and each
@@ -806,6 +912,17 @@ def test_the_chunk_program_goes_through_the_pages_and_decode_is_unchanged(
             assert op.group(1) in passes, line[:300]
     assert not re.search(r"[\[,]384[\],]", text)
     assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 23
+    # (PR 48) both as ONE program: the two programs' kernels, the leaves
+    # likewise from parameter to custom call to result
+    beside = compiled["paged_chunk_beside_decode"]
+    text = beside.as_text()
+    calls = re.findall(r"%(\w+)\.\d+ = [^\n]*tpu_custom_call", text)
+    assert sorted(calls) == ["paged_decode"] * 2 + ["paged_write"] * 4
+    for line in text.split("\n"):
+        op = re.search(r" = \S+ ([\w-]+)\(", line)
+        if op and leaf in line:
+            assert op.group(1) in passes, line[:300]
+    assert beside.memory_analysis().temp_size_in_bytes < 2 ** 23
     digest = hashlib.sha256(
         _program_text(compiled["kernel_decode"]).encode()).hexdigest()
     assert digest == _KERNEL_DECODE_TEXT, (

@@ -49,6 +49,7 @@ def test_inventory_finds_the_known_entry_points():
     assert by_attr["_paged_decode_jit"]["donate_argnums"] == [1]
     assert by_attr["_paged_verify_jit"]["static_argnums"] == [8, 9]
     assert by_attr["_paged_chunk_jit"]["donate_argnums"] == [1]
+    assert by_attr["_paged_chunk_decode_jit"]["donate_argnums"] == [1]
     assert by_attr["_jit_copy_page"]["donate_argnums"] == [0]
     # engine-local guard jit + the drafter's lazily-built argmax (the
     # escape the inventory originally caught)

@@ -93,7 +93,8 @@ def test_default_envs_enumerate_finitely():
                  "InferenceEngine._jit_sample",
                  "SlotPool._admit_jit", "SlotPool._admit_rows_jit",
                  "SlotPool._paged_decode_jit", "SlotPool._jit_copy_page",
-                 "SlotPool._paged_chunk_jit"):
+                 "SlotPool._paged_chunk_jit",
+                 "SlotPool._paged_chunk_decode_jit"):
         assert progs.get(name), f"missing program {name}"
     # the stall-free row's admission set: singleton width buckets
     # 16..256 plus every (rows x width) group the 1024-token budget

@@ -202,6 +202,9 @@ def test_kernel_churn_never_recompiles_after_warmup(stack):
     manifest = srv.watchdog.signature_manifest()
     assert "SlotPool._paged_decode_kernel_jit" in manifest
     assert "SlotPool._paged_chunk_jit" in manifest
+    # (PR 48) and a chunk beside running slots rode their program
+    assert "SlotPool._paged_chunk_decode_jit" in manifest
+    assert srv.registry.counter("serving/fused_steps").value > 0
     chunks = [e["args"] for e in srv.tracer.events()
               if e["ph"] == "X" and e["name"] == "serving/prefill_chunk"]
     assert len(chunks) >= 2 * (4 + 6)
@@ -224,17 +227,30 @@ def _chunk_server(engine, kernel):
 
     srv = kernel_server(engine, kernel, prefill_chunk=CHUNK, tracer=Tracer())
     pool, calls = srv.pool, []
-    run = pool.run_prefill_chunk
+    run, run_beside = pool.run_prefill_chunk, pool.run_chunk_decode
 
-    def run_prefill_chunk(eng, ids, slot, start, length, last_idx):
-        steps = int(live_pages(
+    def steps_of(ids, slot, start):
+        return int(live_pages(
             jnp.asarray([start], jnp.int32), jnp.asarray(pool.table[slot])[None],
             ids.shape[1], PS, pool.num_pages)[-1])
+
+    def run_prefill_chunk(eng, ids, slot, start, length, last_idx):
+        steps = steps_of(ids, slot, start)
         logits = run(eng, ids, slot, start, length, last_idx)
         calls.append((start, np.asarray(logits), steps))
         return logits
 
+    def run_chunk_decode(eng, ids, slot, start, length, last_idx, *more):
+        # (PR 48) a chunk beside running slots rides the decode rows'
+        # program on the kernel arm: the same chunk, the same logits
+        steps = steps_of(ids, slot, start)
+        logits, decoded = run_beside(eng, ids, slot, start, length,
+                                     last_idx, *more)
+        calls.append((start, np.asarray(logits), steps))
+        return logits, decoded
+
     pool.run_prefill_chunk = run_prefill_chunk
+    pool.run_chunk_decode = run_chunk_decode
     return srv, calls
 
 

@@ -550,6 +550,113 @@ def test_a_plain_decode_step_beside_a_state_group_makes_three_calls(
         == {CHUNK_CALLS["paged"]}
 
 
+# -- a chunk beside running slots as ONE program (PR 48) ---------------------
+# the chunk's vector (the one transfer), the program, the sampler, the commit
+# of its tokens, the index put again from the mirror (the slot in mid-prefill
+# rode the decode rows along): one call under the two programs' six
+BESIDE_CALLS = 5
+# a prompt's LAST chunk beside running slots keeps the two programs (the slot
+# it finishes decodes in the same step from the token its head chose): the
+# vector, the chunk, its sampler, its commit, the twin's scatter (a put and a
+# program), the decode, its sampler, its commit
+LAST_CHUNK_CALLS = 9
+
+
+@pytest.fixture(scope="module")
+def beside_account(server_parts):
+    """A server whose chunks read their pages in place (``kernel: "on"``),
+    warmed the way the benchmark's harness warms one (a request a pass,
+    drained, then ``end_warmup()``: no warm-up step carries a chunk beside a
+    running slot), then given a prompt of three chunks beside a running
+    request, twice."""
+    model, params = server_parts
+    srv = ds.init_serving(model, model_parameters=params,
+                          config={"dtype": "float32"}, num_slots=4,
+                          max_queue_depth=8, prefill_chunk=8,
+                          prefill_token_budget=16, tracer=Tracer(),
+                          paged_kv={"kernel": "on", "page_size": 8,
+                                    "prefix_cache": False})
+    rng = np.random.default_rng(23)
+
+    def prompt(n):
+        return rng.integers(0, 64, size=n).astype(np.int32)
+
+    for n in (5, 20):
+        srv.submit(prompt(n), max_new_tokens=3)
+        srv.run_until_drained(max_steps=50)
+    assert srv.registry.counter("serving/fused_steps").value == 0
+    srv.end_warmup()
+    warm = len(srv.tracer.events())
+    for _ in range(2):
+        # (two tokens: its write column stays inside its first page while
+        # the chunks ride beside it, so no table is put for it)
+        srv.submit(prompt(2), max_new_tokens=10)
+        srv.step()
+        srv.submit(prompt(20), max_new_tokens=3)      # chunks of 8, 8 and 4
+        srv.run_until_drained(max_steps=60)
+    srv.check_invariants()
+    evs = srv.tracer.events()[warm:]
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    return {"srv": srv, "evs": evs, "steps": steps}
+
+
+def test_a_chunk_beside_running_slots_is_one_program(beside_account):
+    """``fused`` on ``serving/step`` and what it stands for: the chunk's
+    span and the decode's both there, the ONE enqueue under the decode's,
+    one device call fewer than the two programs made."""
+    evs, srv = beside_account["evs"], beside_account["srv"]
+    both = _steps_with(beside_account, "serving/prefill_chunk",
+                       "serving/decode")
+    fused = [s for s in both if s["args"]["fused"]]
+    last = [s for s in both if not s["args"]["fused"]]
+    assert len(fused) == 4 and len(last) == 2
+    assert srv.registry.counter("serving/fused_steps").value == 4
+    assert all(s["args"]["fused"] == 0 for s in _steps_with(
+        beside_account, "serving/decode", without=("serving/prefill_chunk",)))
+    for step in fused:
+        assert step["args"]["chunk"] == 8 and step["args"]["decode"] == 1
+        chunk, = _kids(evs, step, "serving/prefill_chunk")
+        decode, = _kids(evs, step, "serving/decode")
+        assert not _kids(evs, chunk, "serving/enqueue")
+        assert [k["args"]["program"] for k in _kids(
+            evs, decode, "serving/enqueue")] == ["chunk_decode"]
+        assert [k["args"]["program"] for k in _kids(
+            evs, step, "serving/enqueue")] == ["chunk_decode", "sample"]
+        # each span says what it said when the programs were two
+        assert {"rid", "pos", "len", "pool_writes", "pool_reads",
+                "read_slots"} <= set(chunk["args"])
+        assert chunk["args"]["len"] == 8 and chunk["args"]["read_slots"] == 1
+        assert {"live", "pool_writes", "pool_reads", "read_slots"} \
+            <= set(decode["args"])
+        assert decode["args"]["live"] == 1
+        # the running slot and the one in mid-prefill both map pages
+        assert decode["args"]["read_slots"] == 2
+    # the step that seats the prompt also resets its row; the one after it
+    # is the quiet one
+    assert [s["args"]["device_calls"] for s in fused[1::2]] \
+        == [BESIDE_CALLS] * 2
+    assert all(s["args"]["device_calls"] > BESIDE_CALLS
+               and s["args"]["table_puts"] for s in fused[0::2])
+    for step in last:
+        assert step["args"]["chunk"] == 4
+        assert [k["args"]["program"] for k in _kids(
+            evs, step, "serving/enqueue")] == [
+                "chunk", "sample", "cur_scatter", "decode", "sample"]
+        assert step["args"]["device_calls"] == LAST_CHUNK_CALLS
+
+
+def test_the_one_program_is_compiled_before_the_warm_up_ends(beside_account):
+    """The harness's warm-up never puts a chunk beside a running slot, so
+    ``end_warmup`` brings the program in itself; mixed traffic after it
+    compiles nothing, and the warm-up call left the pool as it was."""
+    srv = beside_account["srv"]
+    assert srv.watchdog.recompiles == 0
+    manifest = srv.watchdog.signature_manifest()
+    assert len(manifest["SlotPool._paged_chunk_decode_jit"]) == 1
+    np.testing.assert_array_equal(
+        np.asarray(srv.pool.cache["cache_store"]["table"]), srv.pool.table)
+
+
 def test_counter_tracks_sample_every_step(account):
     """One sample a track a step, as before the account."""
     evs, paged = account["evs"], account["pool"] == "paged"
