@@ -183,11 +183,14 @@ def init_serving(model: Any = None, config: Union[str, Dict, None] = None,
     pressure is drained by trie eviction, then automatic preemption.
     ``kernel`` selects the fused Pallas paged-attention decode/verify
     path (``"auto"`` arms it on real TPU hardware only; the dense
-    gather path stays the bitwise-parity oracle). ``overlap`` pipelines
-    ``step()`` — decode dispatches first, host bookkeeping overlaps the
-    in-flight device work, and token fetches collapse onto one
-    end-of-step sync — with outcomes bitwise identical to the serial
-    step.
+    gather path stays the bitwise-parity oracle).
+
+    ``step()`` runs one step ahead of the host: it queues its programs,
+    then settles the step before it (one wait, one fetch, the replay of
+    its tokens) and leaves its own in flight; ``settle()`` brings the host
+    up to date. No option: it is how the server steps (a speculative
+    server and the two roles of a disaggregated pair settle inside the
+    step, because they need the values).
 
     The efficiency/goodput observability keys (all server-global):
     ``cost_model`` (``True``, a :class:`telemetry.ProgramCostModel`

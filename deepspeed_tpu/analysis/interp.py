@@ -1382,8 +1382,11 @@ def _serving_obj(env: dict) -> Obj:
         "_rng": Arr((2,), "uint32", HOST),
         "_current": Arr((S,), "int32", HOST),
         "_cur_dev": Arr((S,), "int32", COMMITTED),
-        "_overlap": Scalar(bool(env.get("overlap"))),
         "_deferred": ListOf(Unknown("deferred fetch"), maybe_empty=True),
+        "_in_flight": Scalar(None),
+        "_unread": Obj("opaque"),
+        "_closing": Obj("opaque"),
+        "_ahead": Scalar(None),
         "timers": Obj("opaque"),
         "_slot_req": Obj("opaque"),
         "tracer": Obj("opaque"),
@@ -1406,7 +1409,6 @@ def _fuses_env(env: dict) -> bool:
     with its decode rows as one program."""
     return bool(env.get("paged") and env.get("paged_kernel_active")
                 and env.get("stall_free") and not env.get("spec_k")
-                and not env.get("overlap")
                 and str(env.get("role", "both")) != "prefill")
 
 
@@ -1480,13 +1482,9 @@ def run_drivers(interp: Interp) -> None:
             args.append(frame_args.get(p, Unknown(f"driver arg {p}")))
         interp.call_function(fn, mod, obj, args, {})
 
-    finished = ListOf(Unknown("finished"), maybe_empty=True)
-
     # 1. singleton bucketed admission (whole-seed prefill at a padded
     #    power-of-two width)
-    call(srv, "_admit", {
-        "req": _request_obj(_singleton_T(env)),
-        "finished": finished})
+    call(srv, "_admit", {"req": _request_obj(_singleton_T(env))})
 
     if env.get("stall_free"):
         # 2. batched bucketed admission, per width (the reachable batch
@@ -1500,8 +1498,7 @@ def run_drivers(interp: Interp) -> None:
                 "group": ListOf(_request_obj(
                     IntRange(1, min(width, _singleton_T(env).hi))),
                     group_n, maybe_empty=False),
-                "width": Scalar(width),
-                "finished": finished})
+                "width": Scalar(width)})
 
         # 3. chunked prefill steps for long prompts
         max_len = int(env.get("max_prompt_len")
@@ -1514,11 +1511,10 @@ def run_drivers(interp: Interp) -> None:
             req.attrs["prefill_pos"] = Scalar(
                 IntRange(0, max_len - 1, "pos"))
             srv2.attrs["_prefill_queue"] = ListOf(req, maybe_empty=False)
-            call(srv2, "_prefill_chunk_step", {"finished": finished})
+            call(srv2, "_prefill_chunk_step", {})
 
     # 4. the decode step (and the numerics guard, when armed)
-    call(srv, "_decode_step", {"finished": finished,
-                               "t0": Scalar(0.0)})
+    call(srv, "_decode_step", {"t0": Scalar(0.0)})
 
     # 4b. the same dispatch with the step's chunk left to it: ONE program
     #     for the chunk's rows and the decode rows
@@ -1532,13 +1528,11 @@ def run_drivers(interp: Interp) -> None:
             req, Arr((Known(1), Known(C)), "int32", HOST),
             Scalar(IntRange(0, int(env["capacity"]) - 1, "pos")),
             Scalar(C), Scalar(0.0)])
-        call(srv3, "_decode_step", {"finished": finished,
-                                    "t0": Scalar(0.0)})
+        call(srv3, "_decode_step", {"t0": Scalar(0.0)})
 
     # 5. speculative verify step
     if env.get("spec_k"):
-        call(srv, "_spec_decode_step", {"finished": finished,
-                                        "t0": Scalar(0.0)})
+        call(srv, "_spec_decode_step", {"t0": Scalar(0.0)})
 
     # 6. paged page management: CoW page copies (also pre-warmed by
     #    bind_engine with a self-copy at runtime)

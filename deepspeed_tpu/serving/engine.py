@@ -48,6 +48,20 @@ Rejected draft positions are rolled back by the per-slot cache ``index``
 (:meth:`SlotPool.advance`), never by reshaping, so speculation adds
 exactly one more compiled program regardless of churn.
 
+The step RUNS ONE AHEAD of what the host has read: ``step()`` number n
+queues its programs and then settles the bundle of step n - 1 (one wait,
+one fetch, the replay of its tokens), leaving its own in flight, so the
+device holds a step queued while the host replays, does its telemetry and
+prepares the next. A token is visible in ``Request.output_tokens`` when
+its step is settled: by the next ``step()``, or by :meth:`settle`. A
+request whose end the host can count (``max_new_tokens``, the slot's
+capacity) takes no row past it; an end only the value tells (EOS, the
+finite guard) costs one dead row. Whatever takes a seated request out by
+another road (preemption, ``cancel``, a deadline, an aborted step, the
+audit, a handoff) settles first. Speculative decoding (the drafter reads
+the host's histories) and the two roles of a disaggregated pair (the
+handoff is a value) settle inside the step.
+
 FAULT TOLERANCE (the :mod:`.resilience` package) hardens the loop
 without ever changing a compiled shape:
 
@@ -128,8 +142,9 @@ class _Enqueue(_Span):
     says which calls get the span). At its close the step's account takes
     it, from the clock reads the span made: its time under ``enqueue``
     and, if it is the step's first and the previous step ended in a sync,
-    ``exposed``: from the end of that sync to now, the host time during
-    which the device had nothing queued."""
+    ``exposed``: from the end of that sync to now, the host's serial
+    stretch between two steps' programs (the device idles through it only
+    where the step before was not left in flight)."""
 
     __slots__ = ("_srv",)
 
@@ -208,6 +223,22 @@ class _PagesPhase(_Phase):
         return super().__exit__(exc_type, exc, tb)
 
 
+class _Bundle:
+    """What one step left on the device for the host to read: the
+    deferred ``(arrays, callback)`` pairs in dispatch order, the routed
+    FFN's counters of its programs, and where the values that only a
+    settled step knows are written (``attrs``: the step's own account
+    while the step is open, the attributes of its ``serving/step`` span
+    once that has closed)."""
+
+    __slots__ = ("step_id", "pending", "moe_stats", "attrs")
+
+    def __init__(self, step_id: int, pending: list, moe_stats: list,
+                 attrs: dict):
+        self.step_id, self.pending = step_id, pending
+        self.moe_stats, self.attrs = moe_stats, attrs
+
+
 class ServingEngine:
     """Continuous-batching server over a built
     :class:`~deepspeed_tpu.inference.engine.InferenceEngine`.
@@ -244,7 +275,6 @@ class ServingEngine:
                  dump_dir: Optional[str] = None,
                  priority: Any = None,
                  clock: Optional[Any] = None,
-                 overlap: bool = False,
                  role: str = "both"):
         self.engine = engine
         # ONE monotonic clock for every time-dependent decision —
@@ -591,7 +621,6 @@ class ServingEngine:
                 out, jnp.maximum(n_emit - 1, 0)[:, None],
                 axis=1)[:, 0].astype(jnp.int32),
             out_shardings=self._cur_sharding)
-        self._overlap = bool(overlap)
         # -- disaggregated prefill/decode role (ISSUE 19) --------------
         # "both" is the classic colocated engine. "prefill" runs
         # admission/chunked prefill only and parks each request once its
@@ -634,9 +663,25 @@ class ServingEngine:
                                         np.int32), rep),
                 jax.device_put(np.ones((num_slots,), np.int32), rep))
         # deferred host work: (device_arrays, callback) pairs queued at
-        # dispatch time and replayed — in dispatch order — after the one
-        # blocking fetch in _drain_deferred at the end of step()
+        # dispatch time and replayed, in dispatch order, when their step
+        # is settled. ``_deferred`` is what the step being queued has
+        # gathered; sealed with the routed FFN's counters it becomes the
+        # bundle ``_in_flight``, which the NEXT step settles after it has
+        # queued its own programs (:meth:`_settle`)
         self._deferred: List[Any] = []
+        self._in_flight: Optional[_Bundle] = None
+        # tokens queued on the device that the host has not read, by
+        # request id, and the requests whose LAST token by the host's own
+        # count (max_new_tokens, the slot's capacity) is among them: such
+        # a request takes no further row (:meth:`_runs`)
+        self._unread: dict = {}
+        self._closing: set = set()
+        # requests that finished since the last step() or settle()
+        # handed its list out
+        self._finished: List[Request] = []
+        # whether the step's first program was queued with the step
+        # before unsettled (None: it has queued none yet)
+        self._ahead: Optional[bool] = None
         self._next_id = 0
         self._ensure_watch()
         log_dist(f"ServingEngine: slots={num_slots} "
@@ -670,12 +715,21 @@ class ServingEngine:
     def _fuses_chunks(self) -> bool:
         """Whether a chunk beside running slots goes with the decode rows
         as ONE program, by what this server is: a paged pool whose chunk
-        reads its pages in place (``PagedKVPool.fuses``), plain decoding,
-        the default order (with ``overlap`` the decode is queued before
-        the chunk is prepared) and a role that decodes."""
-        return (self._paged and self._spec is None and not self._overlap
+        reads its pages in place (``PagedKVPool.fuses``), plain decoding
+        and a role that decodes."""
+        return (self._paged and self._spec is None
                 and self.role != "prefill" and self.prefill_chunk > 0
                 and self.pool.fuses(self.prefill_chunk))
+
+    @property
+    def _runs_ahead(self) -> bool:
+        """Whether a step leaves its bundle in flight for the next to
+        settle, by what this server is: plain decoding (a drafter reads
+        the host's histories, so a speculative step needs its own tokens)
+        on a colocated engine (of a disaggregated pair the prefill side
+        parks a request on its first token's VALUE, and the decode side
+        adopts mid-stream)."""
+        return self._spec is None and self.role == "both"
 
     def end_warmup(self) -> None:
         """Declare warmup traffic over: from here on, any recompile counts
@@ -719,7 +773,6 @@ class ServingEngine:
             "guard_numerics": self._jit_finite is not None,
             "use_prefix": bool(self._use_prefix),
             "stall_free": bool(self._stall_free),
-            "overlap": bool(self._overlap),
             # role never moves a traced shape (same warmups, same
             # programs; a prefill engine just skips the decode
             # dispatch) — recorded for arm attribution like the mesh
@@ -832,8 +885,7 @@ class ServingEngine:
         return total
 
     def _telemetry_step(self, wall: float, running_at_entry: int,
-                        granted: List[Request],
-                        finished: List[Request]) -> None:
+                        granted: List[Request]) -> None:
         """Step-boundary efficiency/SLO/flight-recorder bookkeeping
         (timed by the ``serving/after_step`` span it runs under)."""
         costs, slo, rec = self.costs, self.slo, self.recorder
@@ -851,10 +903,9 @@ class ServingEngine:
                 costs.reconcile_kv(self.pool, monitor=self.metrics.monitor,
                                    step=self.step_id, tracer=self.tracer)
         if rec is not None:
-            rec.record(self._step_record(granted, finished))
+            rec.record(self._step_record(granted))
 
-    def _step_record(self, granted: List[Request],
-                     finished: List[Request]) -> dict:
+    def _step_record(self, granted: List[Request]) -> dict:
         rec = {
             "step_id": self.step_id,
             "t_unix": time.time(),
@@ -872,7 +923,7 @@ class ServingEngine:
             "prefilling": len(self._prefill_queue),
             "free_slots": self.pool.free_count,
             "granted": [r.request_id for r in granted],
-            "finished": [r.request_id for r in finished],
+            "finished": [r.request_id for r in self._finished],
             "tokens_total": self._tokens_emitted,
             "load_state": (self._load.state.name
                            if self._load is not None else None),
@@ -1123,7 +1174,11 @@ class ServingEngine:
         counted and nothing else, and so is every call while the tracer's
         ring is off: no span, no annotation, no clock read."""
         self._device_calls += 1
-        if kind != "program" or not self.tracer.enabled:
+        if kind != "program":
+            return NO_SPAN
+        if self._ahead is None:     # the step's first program
+            self._ahead = self._in_flight is not None
+        if not self.tracer.enabled:
             return NO_SPAN
         return _Enqueue(self, program)
 
@@ -1166,42 +1221,122 @@ class ServingEngine:
         return tokens
 
     def _defer(self, arrays, callback) -> None:
-        """Queue ``callback(*host_values)`` until the end-of-step fetch.
+        """Queue ``callback(*host_values)`` until the step is settled.
 
         ``arrays`` is a list of device arrays; the callback receives the
-        same list with every element converted via ``np.asarray`` after
-        the step's one ``block_until_ready``."""
+        same list as host arrays, out of the bundle's one fetch."""
         self._deferred.append((list(arrays), callback))
 
-    def _drain_deferred(self, *, sync: bool = True) -> None:
-        """The step's single device sync: block on every deferred array
-        at once, then replay the queued host bookkeeping in dispatch
-        order. ``serving/sync`` times exactly the blocking wait."""
-        if not self._deferred:
-            return
-        pending, self._deferred = self._deferred, []
-        bundle = [a for arrays, _ in pending for a in arrays]
-        phases = self._phase_ns
-        if sync:
-            with self.tracer.span("serving/sync", arrays=len(bundle)) as sp:
-                # the step's ONE deliberate sync: every deferred
-                # token/flag fetch collapses onto this block
-                jax.block_until_ready(bundle)
-            phases["sync"] = phases.get("sync", 0) + sp.dur_ns
-            self._sync_end_ns = sp.t0_ns + sp.dur_ns
-        with self._phase("replay", "serving/replay", callbacks=len(pending)):
-            for arrays, callback in pending:
-                callback(*[np.asarray(a) for a in arrays])
+    def _queued_token(self, req: Request) -> None:
+        """A token of ``req`` is queued on the device (its slot's position
+        has moved already). If the host can count that it is the last, by
+        ``max_new_tokens`` or by the slot's capacity, the request is
+        closing: it takes no row after this one, and
+        :meth:`_maybe_retire` finds it counted out when the token is
+        read."""
+        rid = req.request_id
+        unread = self._unread[rid] = self._unread.get(rid, 0) + 1
+        if len(req.output_tokens) + unread >= req.max_new_tokens \
+                or int(self.pool.starts[req.slot]) >= self.pool.capacity:
+            self._closing.add(rid)
 
-    def _note_moe_stats(self) -> None:
-        """What the routed FFN counted in this step's programs (each
-        hands back ``moe.routed_ffn.call_stats``; the arrays are outputs
-        of programs the step's sync has waited for): registry counters,
-        and attributes of the ``serving/step`` span."""
+    def _read_token(self, req: Request) -> None:
+        """The replay has the value of one queued token of ``req``."""
+        rid = req.request_id
+        self._unread[rid] -= 1
+        if not self._unread[rid]:
+            del self._unread[rid]
+
+    def _runs(self, req: Request) -> bool:
+        """Whether a seated request takes a row of the next decode
+        program: it is RUNNING and the host has not counted its end among
+        the tokens in flight."""
+        return req.state is RequestState.RUNNING \
+            and req.request_id not in self._closing
+
+    def _seal(self) -> Optional[_Bundle]:
+        """What the step has queued for the host, as one bundle (None:
+        nothing). The routed FFN's counters ride with the tokens."""
+        moe_stats = []
+        if self._paged and self.pool.moe_stats:
+            moe_stats, self.pool.moe_stats = self.pool.moe_stats, []
+        if not self._deferred and not moe_stats:
+            return None
+        pending, self._deferred = self._deferred, []
+        return _Bundle(self.step_id, pending, moe_stats, self._dispatched)
+
+    def _settle(self, bundle: Optional[_Bundle]) -> None:
+        """Bring the host up to one bundle: ONE wait for its arrays
+        (``serving/sync`` times exactly that), one fetch of them all, the
+        replay of the queued host bookkeeping in dispatch order, and the
+        routed FFN's counters onto the step that ran them."""
+        if bundle is None:
+            return
+        arrays = [a for arrs, _ in bundle.pending for a in arrs]
+        arrays += bundle.moe_stats
+        phases = self._phase_ns
+        with self.tracer.span("serving/sync", arrays=len(arrays),
+                              step=bundle.step_id) as sp:
+            # the step's ONE deliberate sync: every deferred token/flag
+            # fetch collapses onto this block
+            jax.block_until_ready(arrays)
+        phases["sync"] = phases.get("sync", 0) + sp.dur_ns
+        self._sync_end_ns = sp.t0_ns + sp.dur_ns
+        with self._phase("replay", "serving/replay",
+                         callbacks=len(bundle.pending)):
+            host = jax.device_get(arrays)
+            at = 0
+            for arrs, callback in bundle.pending:
+                callback(*host[at:at + len(arrs)])
+                at += len(arrs)
+            if bundle.moe_stats:
+                self._note_moe_stats(host[at:], bundle.attrs)
+
+    def _settle_all(self) -> bool:
+        """Settle the bundle in flight and then what the step being
+        queued has deferred so far (its routed counters stay with the
+        pool, for the step's own bundle). Returns whether there was
+        anything."""
+        if self._in_flight is None and not self._deferred:
+            return False
+        bundle, self._in_flight = self._in_flight, None
+        self._settle(bundle)
+        if self._deferred:
+            pending, self._deferred = self._deferred, []
+            self._settle(_Bundle(self.step_id, pending, [],
+                                 self._dispatched))
+        return True
+
+    def _settle_early(self, reason: str) -> bool:
+        """The forced settle: before a seated request leaves by another
+        road than the steady step (``reason``), the host reads everything
+        the device holds for it. Counted, by reason."""
+        if not self._settle_all():
+            return False
+        self.registry.counter("serving/settled_early").inc()
+        self.registry.counter(f"serving/settled_early/{reason}").inc()
+        return True
+
+    def settle(self) -> List[Request]:
+        """Bring the host up to date with the device: wait for the step in
+        flight and replay its tokens, so that every token queued so far is
+        in its request's ``output_tokens``. Returns the requests that
+        finished since the last ``step()`` or ``settle()`` returned."""
+        self._settle_all()
+        return self._take_finished()
+
+    def _take_finished(self) -> List[Request]:
+        out, self._finished = self._finished, []
+        return out
+
+    def _note_moe_stats(self, stats: list, attrs: dict) -> None:
+        """What the routed FFN counted in one step's programs (each hands
+        back ``moe.routed_ffn.call_stats``; ``stats`` are those arrays out
+        of the bundle's fetch): registry counters, and attributes of the
+        ``serving/step`` span of the step that ran them (``attrs``)."""
         from ..moe.routed_ffn import CALL_STATS
 
-        calls, self.pool.moe_stats = \
-            np.stack([np.asarray(s) for s in self.pool.moe_stats]), []
+        calls = np.stack(stats)
         step = {name: float(col.max() if name.startswith("load_max")
                             else col.sum())
                 for name, col in zip(CALL_STATS, calls.T)}
@@ -1210,8 +1345,7 @@ class ServingEngine:
             reg.counter(f"serving/moe_{name}").inc(step[name])
             step[name] = int(step[name])
         reg.gauge("serving/moe_load_max").set(step["load_max"])
-        self._dispatched.update(
-            {f"moe_{name}": val for name, val in step.items()})
+        attrs.update({f"moe_{name}": val for name, val in step.items()})
 
     def _note_state_rows(self, sp, rows: int, tokens: int = 0) -> None:
         """``state_rows`` on a dispatch's span, for a model with a
@@ -1284,7 +1418,7 @@ class ServingEngine:
             self._cur_dev = self._jit_cur_scatter(self._cur_dev, tokens_dev,
                                                   slots)
 
-    def _admit(self, req: Request, finished: List[Request]) -> None:
+    def _admit(self, req: Request) -> None:
         slot = self.pool.alloc()
         # rollback snapshot: a PREEMPTED request arrives carrying its
         # generated-so-far tokens and first-token stamp — a failed
@@ -1326,8 +1460,10 @@ class ServingEngine:
             self.timelines.record(req.request_id, "admitted", slot=slot,
                                   mode="bucketed")
             self.tracer.flow("s", "req", req.request_id)
+            self._queued_token(req)
 
             def _on_first_token(tok, req=req, slot=slot, n0=n0):
+                self._read_token(req)
                 token = int(tok[0])
                 if req.first_token_time is None:
                     req.first_token_time = self._now()
@@ -1336,7 +1472,7 @@ class ServingEngine:
                 self._current[slot] = token
                 if n0 == 0:
                     self.timelines.record(req.request_id, "first_token")
-                self._maybe_retire(req, token, finished)
+                self._maybe_retire(req, token)
 
             self._defer([tok_dev], _on_first_token)
         except Exception:
@@ -1359,8 +1495,7 @@ class ServingEngine:
                 self.pool.cache_prefix(slot, seed)
 
     def _running_count(self) -> int:
-        return sum(1 for r in self._slot_req.values()
-                   if r.state is RequestState.RUNNING)
+        return sum(1 for r in self._slot_req.values() if self._runs(r))
 
     def _admission_cost(self, req: Request) -> int:
         """Prefill tokens this grant charges against the step budget: the
@@ -1421,21 +1556,30 @@ class ServingEngine:
                        // (-(-ring.window // ring.page_size) + 2))
         return free
 
-    def _ensure_pages(self, slot: int, start: int, end: int,
+    def _ensure_pages(self, req: Request, start: int, end: int,
                       sync: bool = True) -> None:
-        """ensure_writable with the pressure valve: on PagePoolExhausted
-        (free list empty AND trie eviction dry), preempt the youngest
-        OTHER seated request — its pages come back to the free list —
-        and retry. Only when no victim remains does the exhaustion
-        propagate (a sizing bug: one request's footprint exceeds the
-        whole pool, which the submit-time page check rejects). ``sync``
-        is ``ensure_writable``'s: a chunk passes ``False``, its program
+        """ensure_writable of ``req``'s slot with the pressure valve: on
+        PagePoolExhausted (free list empty AND trie eviction dry), settle
+        the step in flight (a request that has ended gives its pages
+        back), then preempt the youngest OTHER seated request — its pages
+        come back to the free list — and retry. Only when no victim
+        remains does the exhaustion propagate (a sizing bug: one request's
+        footprint exceeds the whole pool, which the submit-time page
+        check rejects). The forced settle may end ``req`` itself (its
+        token in flight was an EOS or a poisoned row): its slot is free
+        then and nothing is mapped into it. ``sync`` is
+        ``ensure_writable``'s: a chunk passes ``False``, its program
         publishes the row."""
+        slot = req.slot
         while True:
             try:
                 self.pool.ensure_writable(slot, start, end, sync=sync)
                 return
             except PagePoolExhausted:
+                if self._settle_early("preempt"):
+                    if self._slot_req.get(slot) is not req:
+                        return      # ended in there: free slots map nothing
+                    continue
                 victims = [
                     r for r in select_victims(
                         list(self._slot_req.values()),
@@ -1452,11 +1596,14 @@ class ServingEngine:
         PREFILLING slots are skipped on purpose: their masked garbage
         writes hit unmapped entries (scatter drops them) or pages the
         seating already CoW-forked — allocating for garbage would waste
-        pages under pressure."""
+        pages under pressure. So are closing slots (:meth:`_runs`): what
+        their row writes, nobody reads. (An earlier slot's pages may have
+        settled the step in flight or preempted: a request that left by
+        either is no longer running when its turn comes.)"""
         for slot, req in list(self._slot_req.items()):
-            if req.state is RequestState.RUNNING:
+            if self._runs(req):
                 idx = int(self.pool.starts[slot])
-                self._ensure_pages(slot, idx, idx + width)
+                self._ensure_pages(req, idx, idx + width)
 
     def _admit_prefix_hit(self, req: Request) -> bool:
         """Try to seat ``req`` through the prefix cache: walk the trie,
@@ -1547,8 +1694,7 @@ class ServingEngine:
                                   []).append(req)
         return sorted(groups.items())
 
-    def _admit_batch(self, group: List[Request], width: int,
-                     finished: List[Request]) -> None:
+    def _admit_batch(self, group: List[Request], width: int) -> None:
         """Batched bucketed admission: ``len(group)`` same-bucket prompts
         prefilled in one ``prefill_last`` dispatch at a power-of-two
         batch, then scattered into their slots by one jitted multi-row
@@ -1607,12 +1753,14 @@ class ServingEngine:
                 self.timelines.record(req.request_id, "admitted", slot=slot,
                                       mode="batched")
                 self.tracer.flow("s", "req", req.request_id)
+                self._queued_token(req)
                 if self._use_prefix:
                     self.pool.cache_prefix(slot, req.seed_tokens)
 
             def _on_batch_tokens(tokens, group=group, slots=slots, n0s=n0s):
                 now = self._now()
                 for i, req in enumerate(group):
+                    self._read_token(req)
                     token = int(tokens[i])
                     slot = int(slots[i])
                     if req.first_token_time is None:
@@ -1622,7 +1770,7 @@ class ServingEngine:
                     self._current[slot] = token
                     if n0s[i] == 0:
                         self.timelines.record(req.request_id, "first_token")
-                    self._maybe_retire(req, token, finished)
+                    self._maybe_retire(req, token)
 
             self._defer([tokens_dev], _on_batch_tokens)
         except Exception:
@@ -1634,13 +1782,15 @@ class ServingEngine:
                 if slot < self.pool.num_slots:
                     self._slot_req.pop(slot, None)
                     self.pool.release(slot)
+                self._unread.pop(req.request_id, None)
+                self._closing.discard(req.request_id)
                 req.state = RequestState.QUEUED
                 req.slot = None
                 req.admit_time, req.first_token_time = stamps[i]
                 del req.output_tokens[n0s[i]:]
             raise
 
-    def _prefill_chunk_step(self, finished: List[Request]) -> None:
+    def _prefill_chunk_step(self) -> None:
         """Run AT MOST one bounded prefill chunk — for the head of the
         prefill queue — so per-step latency stays bounded by the token
         budget no matter how long the queued prompts are. The final
@@ -1666,7 +1816,7 @@ class ServingEngine:
             # The mappings stay in the host's mirror: the chunk program
             # writes the slot's row into the device table itself
             with _PagesPhase(self):
-                self._ensure_pages(slot, pos, pos + L, sync=False)
+                self._ensure_pages(req, pos, pos + L, sync=False)
         self._dispatched["chunk"] = L
         # a chunk that does not end its prompt, beside running slots, is
         # queued by this step's decode dispatch, with the decode rows in
@@ -1718,11 +1868,13 @@ class ServingEngine:
             self._prefill_queue.pop(0)
             req.state = RequestState.RUNNING
             req.last_admit_step = self.step_id
+            self._queued_token(req)
             if self._use_prefix:
                 with _PagesPhase(self):
                     self.pool.cache_prefix(slot, seed)
 
             def _on_chunk_token(tok, req=req, slot=slot):
+                self._read_token(req)
                 token = int(tok[0])
                 first = req.first_token_time is None
                 if first:
@@ -1732,7 +1884,7 @@ class ServingEngine:
                 self._current[slot] = token
                 if first:
                     self.timelines.record(req.request_id, "first_token")
-                self._maybe_retire(req, token, finished)
+                self._maybe_retire(req, token)
 
             self._defer([tok_dev], _on_chunk_token)
         elif not beside:
@@ -1745,16 +1897,17 @@ class ServingEngine:
             self.metrics.record_prefill(L, self._now() - t0,
                                         blocking=running_before > 0)
 
-    def _maybe_retire(self, req: Request, token: int,
-                      finished: List[Request]) -> None:
+    def _maybe_retire(self, req: Request, token: int) -> None:
+        rid = req.request_id
         if req.eos_token_id is not None and token == req.eos_token_id:
             req.finish_reason = FinishReason.EOS
         elif len(req.output_tokens) >= req.max_new_tokens:
             req.finish_reason = FinishReason.LENGTH
-        elif req.slot is not None and \
-                int(self.pool.starts[req.slot]) >= self.pool.capacity:
-            # the slot's cache row is full: retire rather than silently
-            # clamp-overwrite the last column on the next decode write
+        elif rid in self._closing and rid not in self._unread:
+            # counted out when this token was queued, and not by its
+            # budget: the slot's cache row was full (``_queued_token``).
+            # Retire rather than silently clamp-overwrite the last column
+            # on the next decode write
             req.finish_reason = FinishReason.LENGTH_CAP
         else:
             if self._handoff_ready is not None and \
@@ -1775,8 +1928,9 @@ class ServingEngine:
         req.finish_time = self._now()
         self.pool.release(req.slot)
         del self._slot_req[req.slot]
+        self._closing.discard(rid)
         self._finish_record(req)
-        finished.append(req)
+        self._finished.append(req)
 
     def _finish_record(self, req: Request) -> None:
         """Shared terminal bookkeeping for every FINISHED retirement
@@ -1838,6 +1992,10 @@ class ServingEngine:
                              "(role 'decode' or 'both')")
         if not self._paged or not getattr(src, "_paged", False):
             raise ValueError("adopt() requires paged KV on both replicas")
+        # both ends read what their devices hold first: the source's last
+        # token is the one decoding resumes from
+        src._settle_early("handoff")
+        self._settle_early("handoff")
         if req.state is not RequestState.RUNNING or req.slot is None:
             raise ValueError(f"adopt() needs a seated RUNNING request; "
                              f"req {req.request_id} is {req.state.value}")
@@ -1913,6 +2071,7 @@ class ServingEngine:
         prompt pages stay warm for the next same-prefix prompt — and
         the request's timeline HERE closes with a terminal hand-off
         event (it finishes on the adopting replica's timeline)."""
+        self._settle_early("handoff")
         if self._slot_req.get(slot) is not req:
             raise ValueError(f"finish_handoff: slot {slot} does not seat "
                              f"req {req.request_id}")
@@ -1937,6 +2096,7 @@ class ServingEngine:
         del self._slot_req[slot]
         self.pool.release(slot)
         req.slot = None
+        self._closing.discard(req.request_id)
         # identity filter, not remove(): value equality on requests would
         # elementwise-compare their numpy prompts
         self._prefill_queue[:] = [r for r in self._prefill_queue
@@ -1947,13 +2107,16 @@ class ServingEngine:
             self._handoff_ready[:] = [r for r in self._handoff_ready
                                       if r is not req]
 
-    def _expire_deadlines(self, finished: List[Request]) -> None:
+    def _expire_deadlines(self) -> None:
         """Retire every request whose deadline has passed: queued ones
-        before they cost a prefill, seated ones via slot eviction. Runs
-        at the step boundary so a mid-step expiry can never interleave
-        with a dispatch."""
+        before they cost a prefill, seated ones via slot eviction (the
+        step in flight is settled first: the request keeps the tokens it
+        was owed, or has ended with them). Runs at the step boundary so a
+        mid-step expiry can never interleave with a dispatch."""
         now = self._now()
         expired = self.scheduler.expire(now)
+        if any(req.expired(now) for req in self._slot_req.values()):
+            self._settle_early("deadline")
         for slot, req in list(self._slot_req.items()):
             if req.expired(now):
                 self._evict_slot(req)
@@ -1963,7 +2126,7 @@ class ServingEngine:
             req.finish_reason = FinishReason.DEADLINE
             req.finish_time = now
             self._finish_record(req)
-            finished.append(req)
+            self._finished.append(req)
 
     def preempt(self, request_id: int) -> Request:
         """Evict a seated (RUNNING or PREFILLING) request and re-queue it
@@ -1971,8 +2134,11 @@ class ServingEngine:
         tokens. Re-admission prefills prompt + outputs through the
         existing bucketed/chunked paths — fixed shapes, zero new
         programs — and greedy output is bitwise identical to never having
-        been preempted (see ``Request.seed_tokens``). Raises
-        ``ValueError`` if the id is not currently seated."""
+        been preempted (see ``Request.seed_tokens``). The step in flight
+        is settled first, so the request carries every token queued for
+        it. Raises ``ValueError`` if the id is not currently seated (or
+        no longer: its last token was in flight)."""
+        self._settle_early("preempt")
         for req in self._slot_req.values():
             if req.request_id == request_id:
                 self._preempt_req(req, auto=False)
@@ -2000,6 +2166,9 @@ class ServingEngine:
                 self.scheduler.queue = type(self.scheduler.queue)(
                     x for x in self.scheduler.queue if x is not r)
                 return self._finish_cancel(r)
+        if any(r.request_id == request_id for r in self._slot_req.values()):
+            # (it may end in there: then the cancel raced the final token)
+            self._settle_early("cancel")
         for r in list(self._slot_req.values()):
             if r.request_id == request_id:
                 slot = r.slot
@@ -2051,19 +2220,38 @@ class ServingEngine:
         if (self.preempt_queue_threshold is None
                 or self.scheduler.pending <= self.preempt_queue_threshold):
             return
-        starved = self.pool.free_count == 0
-        head = self.scheduler.head()
-        if not starved and self._paged and head is not None:
-            starved = (self._page_cost(head)
-                       > self._grant_page_budget())
-        if not starved:
+        self._preempt_one(self.scheduler.head(),
+                          lambda: list(self._slot_req.values()))
+
+    def _preempt_one(self, head: Optional[Request], candidates) -> None:
+        """While ``head`` is starved, evict ONE victim of ``candidates()``
+        (seated requests; ``select_victims`` chooses). The step in flight
+        is settled only once there is a victim to take, so a starved
+        server whose residents are all too young (or too high a class)
+        keeps running ahead; after the settle the question is asked again:
+        a request whose last token was in flight has given its slot back,
+        and the victim may be that one."""
+        def victims():
+            return select_victims(
+                candidates(), n=1, current_step=self.step_id,
+                min_run_steps=self.preempt_min_run_steps,
+                class_rank=self._class_rank)
+
+        if not self._starved(head):
             return
-        victims = select_victims(
-            list(self._slot_req.values()), n=1, current_step=self.step_id,
-            min_run_steps=self.preempt_min_run_steps,
-            class_rank=self._class_rank)
-        for req in victims:
+        chosen = victims()
+        if chosen and self._settle_early("preempt"):
+            chosen = victims() if self._starved(head) else []
+        for req in chosen:
             self._preempt_req(req, auto=True)
+
+    def _starved(self, head: Optional[Request]) -> bool:
+        """No free slot, or (paged) the queue's ``head`` needs more pages
+        than a grant could allocate without a preemption."""
+        if self.pool.free_count == 0:
+            return True
+        return (self._paged and head is not None
+                and self._page_cost(head) > self._grant_page_budget())
 
     def _class_rank(self, req: Request) -> int:
         """Victim-selection key: a request's priority rank (0 = highest)
@@ -2087,31 +2275,26 @@ class ServingEngine:
         head = self.scheduler.head_within(floor)
         if head is None:
             return  # nobody protected is waiting
-        starved = self.pool.free_count == 0
-        if not starved and self._paged:
-            starved = self._page_cost(head) > self._grant_page_budget()
-        if not starved:
-            return  # normal admission will seat the protected head
-        sheddable = [r for r in self._slot_req.values()
-                     if self._class_rank(r) > floor]
-        victims = select_victims(
-            sheddable, n=1, current_step=self.step_id,
-            min_run_steps=self.preempt_min_run_steps,
-            class_rank=self._class_rank)
-        for req in victims:
-            self._preempt_req(req, auto=True)
+        # (not starved: normal admission will seat the protected head)
+        self._preempt_one(head, lambda: [
+            r for r in self._slot_req.values()
+            if self._class_rank(r) > floor])
 
     # ------------------------------------------------------------------
     def step(self) -> List[Request]:
-        """One scheduler iteration: admit into free slots, then one decode
-        (or draft+verify) step for every live slot. Returns the requests
-        that finished.
+        """One scheduler iteration: admit into free slots, queue one decode
+        (or draft+verify) step for every running slot, then settle the
+        step BEFORE this one and leave this one's bundle in flight
+        (:attr:`_runs_ahead`; a server that cannot settles its own too).
+        A step with nothing to queue settles and returns. Returns the
+        requests that finished since the last ``step()`` or ``settle()``
+        returned: a token, and an end, is visible when its step is
+        settled.
 
         Exception-safe: if the engine throws mid-step, no slot leaks —
         granted-but-unadmitted requests go back to the head of the queue,
         requests whose KV state is unrecoverable are FAILED (reason
         ``"error"``), the pool is reset, and the error propagates."""
-        finished: List[Request] = []
         self.step_id += 1
         self._ensure_watch()      # _jit_verify_k materializes lazily
         tracer = self.tracer
@@ -2121,18 +2304,21 @@ class ServingEngine:
         self._dispatched = {}
         phases = self._phase_ns = {}
         self._device_calls = 0
+        self._ahead = None
         table_puts0 = self.pool.table_puts if self._paged else 0
-        # the device is known idle from the previous step's sync on
+        # from the previous step's sync on the host is on its own
         # (serving/enqueue closes the interval: `exposed`)
         self._exposed_from_ns, self._sync_end_ns = self._sync_end_ns, None
         gc_ns0 = gc_ns_total()
-        with tracer.span("serving/step", step=self.step_id) as sp_step:
+        with tracer.span("serving/step", step=self.step_id,
+                         in_flight=int(self._in_flight is not None)
+                         ) as sp_step:
             self._step_t0_ns = sp_step.t0_ns
             # boundary work first, outside the abort scope: expiring a
             # deadline or walking the load ladder touches no device
             # state, so a failure here must not FAIL innocent requests
             with self._phase("boundary", "serving/boundary"):
-                self._expire_deadlines(finished)
+                self._expire_deadlines()
                 self._update_load_state()
                 self._auto_preempt()
                 self._burn_preempt()
@@ -2158,20 +2344,6 @@ class ServingEngine:
                         page_budget=page_budget, page_cost=page_cost)
             phases["grant"] = sp.dur_ns
             try:
-                decoded = False
-                if self._overlap and self._running_count() \
-                        and self.role != "prefill":
-                    # pipelined order: the decode (or draft+verify) for
-                    # the slots ALREADY running is dispatched first, so
-                    # admission/prefill host bookkeeping below overlaps
-                    # the in-flight device step. Slots admitted this
-                    # step join the decode batch next step.
-                    t0 = self._now()
-                    if self._spec is not None:
-                        self._spec_decode_step(finished, t0)
-                    else:
-                        self._decode_step(finished, t0)
-                    decoded = True
                 if self._stall_free:
                     batches = ()
                     if granted:
@@ -2183,13 +2355,13 @@ class ServingEngine:
                             # sentinel padding, no scatter program) is
                             # strictly cheaper — the batched dispatch
                             # only pays off when it coalesces ≥2 prompts
-                            self._admit(group[0], finished)
+                            self._admit(group[0])
                         else:
-                            self._admit_batch(group, width, finished)
-                    self._prefill_chunk_step(finished)
+                            self._admit_batch(group, width)
+                    self._prefill_chunk_step()
                 else:
                     for req in granted:
-                        self._admit(req, finished)
+                        self._admit(req)
                 if self.faults is not None:
                     # the host-exception and slow-dispatch points sit
                     # between admission and decode: requests are seated
@@ -2197,24 +2369,29 @@ class ServingEngine:
                     # state has moved yet
                     self.faults.maybe_sleep("slow_dispatch")
                     self.faults.check("step_host_error")
-                if not decoded and self._running_count() \
-                        and self.role != "prefill":
+                if self._running_count() and self.role != "prefill":
                     t0 = self._now()
                     if self._spec is not None:
-                        self._spec_decode_step(finished, t0)
+                        self._spec_decode_step(t0)
                     else:
-                        self._decode_step(finished, t0)
+                        self._decode_step(t0)
                 # a chunk prepared for the decode dispatch went with it
                 # or was dropped by it: its mirrors have moved, so one
                 # left behind would be columns never written
                 assert self._chunk_beside is None, "chunk never dispatched"
-                # the step's ONE device sync: fetch every deferred
-                # token/flag at once, then replay host bookkeeping in
-                # dispatch order
-                self._drain_deferred()
+                # the step's ONE device sync, for the step BEFORE this
+                # one: its every token/flag/counter in one fetch, then
+                # the host bookkeeping replayed in dispatch order, while
+                # the device runs what this step has just queued
+                before, self._in_flight = self._in_flight, self._seal()
+                self._settle(before)
+                if not self._runs_ahead:
+                    self._settle_all()
             except Exception:
                 self._abort_step(granted)
                 raise
+            if self._ahead:
+                self.registry.counter("serving/steps_run_ahead").inc()
             # the SLO tracker times its own methods: its part of the
             # after-step is taken out again, so telemetry_overhead_s
             # (which adds slo.overhead_s) never counts it twice
@@ -2227,8 +2404,7 @@ class ServingEngine:
                 self._dispatched["table_puts"] = \
                     self.pool.table_puts - table_puts0
             with tracer.span("serving/after_step") as sp:
-                wall = self._after_step(t_step, running_at_entry, granted,
-                                        finished)
+                wall = self._after_step(t_step, running_at_entry, granted)
             self._after_step_ns += sp.dur_ns
             if self.slo is not None:
                 self._after_step_ns -= self.slo.overhead_ns - slo_ns0
@@ -2240,19 +2416,24 @@ class ServingEngine:
                 account["gc_ns"] = gc_ns_total() - gc_ns0
             sp_step.set(tokens=self._tokens_emitted - tokens_at_entry,
                         **self._dispatched, **account)
+            if self._in_flight is not None:
+                # what only the settled step knows (the routed FFN's
+                # counters) is written onto this span when it is: the
+                # step they are reported on is the step that ran them
+                self._in_flight.attrs = sp_step.args
         if running_at_entry:
             # a running request waited through this WHOLE step for its
             # next token — the user-visible inter-token gap, admission
             # work included (what stall-free admission bounds)
             self.metrics.record_step_gap(wall)
-        return finished
+        return self._take_finished()
 
     def _after_step(self, t_step: float, running_at_entry: int,
-                    granted: List[Request],
-                    finished: List[Request]) -> float:
+                    granted: List[Request]) -> float:
         """The serial host work after the step's sync and replay: paging
-        gauges, telemetry, the recompile gate. Returns the step's wall on
-        the injected clock."""
+        gauges, telemetry, the recompile gate; no array of the step in
+        flight is touched. Returns the step's wall on the injected
+        clock."""
         tracer = self.tracer
         if self._paged:
             # per-step paging gauges (Prometheus export + dashboards):
@@ -2273,15 +2454,13 @@ class ServingEngine:
                     float(ring.mapped_count))
                 self._dispatched.update(window_pages=ring.mapped_count,
                                         window_pages_total=ring.num_pages)
-            if self.pool.moe_stats:
-                self._note_moe_stats()
         if self.faults is not None and self.faults.fires("state_corruption"):
             # chaos: corrupt our own slot bookkeeping at the boundary so
             # check_invariants + the flight recorder face REAL damage
             self._chaos_corrupt_state()
         wall = self._now() - t_step
         self.step_wall_s += wall
-        self._telemetry_step(wall, running_at_entry, granted, finished)
+        self._telemetry_step(wall, running_at_entry, granted)
         # strict-mode recompile gate sits at the step boundary: raising
         # mid-step would trigger _abort_step and FAIL innocent in-flight
         # requests, when the state is actually perfectly consistent
@@ -2405,7 +2584,7 @@ class ServingEngine:
             self._rows_dev = (slots, self._cur_commit(rows))
         return (self._rows_dev[1],)
 
-    def _decode_step(self, finished: List[Request], t0: float) -> None:
+    def _decode_step(self, t0: float) -> None:
         """One decode program for every slot. Nothing is sent before it
         that the device holds: the program takes the (B,) current-token
         twin as it is and its positions from the cache's own ``index``
@@ -2427,7 +2606,7 @@ class ServingEngine:
             with _PagesPhase(self):
                 self._ensure_decode_pages(1)
         running = [(slot, req) for slot, req in self._slot_req.items()
-                   if req.state is RequestState.RUNNING]
+                   if self._runs(req)]
         self._dispatched["decode"] = len(running)
         more = self._state_rows(running)
         # the chunk this step left to this dispatch, unless paging the
@@ -2498,21 +2677,27 @@ class ServingEngine:
         # scatter overwrites them); re-committed to the canonical slots
         # placement — a free transfer when GSPMD already chose it
         self._cur_dev = self._cur_commit(nxt_dev)
+        for _, req in running:
+            self._queued_token(req)
 
         def _on_decode(nxt, finite=None, running=running):
+            for _, req in running:
+                self._read_token(req)
             live = self._guard_rows(finite, running)
             emitted = 0
             for slot, req in live:
                 if req.state is not RequestState.RUNNING:
-                    # retired by an earlier replay in this same drain
-                    # (e.g. an admission token hit EOS); its decode row
-                    # was masked padding
+                    # ended by a token read before this one (an EOS or a
+                    # poisoned row of the step before, whose value the
+                    # host had not read when this row was queued): the
+                    # one dead row such an end costs. It wrote a column
+                    # the slot owned
                     continue
                 token = int(nxt[slot])
                 req.output_tokens.append(token)
                 self._current[slot] = token
                 emitted += 1
-                self._maybe_retire(req, token, finished)
+                self._maybe_retire(req, token)
             self._tokens_emitted += emitted
             self.metrics.record_decode_step(emitted, len(running),
                                             step_s=self._now() - t0)
@@ -2520,7 +2705,7 @@ class ServingEngine:
         self._defer([nxt_dev] if finite_dev is None
                     else [nxt_dev, finite_dev], _on_decode)
 
-    def _spec_decode_step(self, finished: List[Request], t0: float) -> None:
+    def _spec_decode_step(self, t0: float) -> None:
         """Draft K tokens per live slot, verify them all in ONE fixed-shape
         (num_slots, K+1) forward, keep each slot's accepted prefix + bonus
         token, and roll back rejected KV via the per-slot index."""
@@ -2548,17 +2733,14 @@ class ServingEngine:
             draft_len = np.zeros((B,), np.int32)
             t_draft = 0.0
         else:
-            if self._deferred:
-                # admissions sampled first tokens earlier THIS step (the
-                # serial-order path): the drafter's host-side histories
-                # need them, so settle the queue now. Steady-state decode
-                # steps — and overlap mode, which dispatches spec before
-                # admissions — never take this early drain, keeping the
-                # hot loop at exactly one sync per step.
-                self._drain_deferred()
+            # admissions sampled first tokens earlier THIS step: the
+            # drafter's host-side histories need them, so settle them
+            # now. Steady-state decode steps never take this early
+            # settle, keeping the hot loop at exactly one sync per step.
+            self._settle_all()
             histories: List[Optional[np.ndarray]] = [None] * B
             for slot, req in self._slot_req.items():
-                if req.state is RequestState.RUNNING:
+                if self._runs(req):
                     histories[slot] = req.tokens()
             with self._phase("prepare", "serving/draft", k=K):
                 # (a model drafter's own programs and its fetch lie in
@@ -2596,7 +2778,7 @@ class ServingEngine:
         with self._enqueue("spec_cur"):
             self._cur_dev = self._jit_spec_cur(out_dev, n_emit_dev)
         live = [(slot, req) for slot, req in self._slot_req.items()
-                if req.state is RequestState.RUNNING]
+                if self._runs(req)]
 
         def _on_verify(out, n_emit, live=live, draft_len=draft_len):
             deltas = np.zeros((B,), np.int32)
@@ -2607,6 +2789,10 @@ class ServingEngine:
                     # its verify row was masked padding
                     continue
                 e = int(n_emit[slot])
+                if int(self.pool.starts[slot]) >= self.pool.capacity:
+                    # (how many tokens a verify emits is a value, so the
+                    # full row is found here and not when it was queued)
+                    self._closing.add(req.request_id)
                 # the cache row holds e new positions regardless of how
                 # many tokens the request actually consumes below: if
                 # eos/budget truncates the emission, the request retires
@@ -2621,7 +2807,7 @@ class ServingEngine:
                     req.output_tokens.append(token)
                     self._current[slot] = token
                     emitted += 1
-                    self._maybe_retire(req, token, finished)
+                    self._maybe_retire(req, token)
                     if req.state is not RequestState.RUNNING:
                         break
             self.pool.advance(deltas)      # per-slot KV rollback
@@ -2639,7 +2825,15 @@ class ServingEngine:
         rebuilt from the prompt, so they are scrubbed and re-queued too
         (ahead of the granted ones — they are older); running requests
         lose their (possibly donated-away) KV state and are FAILED; the
-        pool restarts from a fresh cache."""
+        pool restarts from a fresh cache. The step BEFORE the failed one,
+        if it is still in flight, is settled first: its tokens are owed,
+        and a request that ended with them has ended."""
+        before, self._in_flight = self._in_flight, None
+        if before is not None and before.step_id < self.step_id:
+            try:
+                self._settle(before)
+            except Exception:   # (the device is what failed: the step's
+                pass            #  own error is the one to propagate)
         requeued = [r for r in granted if r.state is RequestState.QUEUED]
         self.scheduler.requeue_front(requeued)
         for req in requeued:
@@ -2677,6 +2871,8 @@ class ServingEngine:
         # belong to the aborted step's state, and its requests are now
         # FAILED/requeued either way
         self._deferred.clear()
+        self._unread.clear()
+        self._closing.clear()
         self._cur_dev = jax.device_put(
             np.zeros((self.pool.num_slots,), np.int32),
             self._cur_sharding)
@@ -2692,7 +2888,8 @@ class ServingEngine:
         only mean a livelock (scheduler bug, budget deadlock, preemption
         thrash). Rather than hang forever, that raises
         :class:`~deepspeed_tpu.serving.resilience.ServingStalledError`
-        carrying a dump of every stuck request's state."""
+        carrying a dump of every stuck request's state. The host is up
+        to date when this returns (:meth:`settle`), at ``max_steps`` too."""
         out: List[Request] = []
         steps = 0
         last_sig = None
@@ -2719,6 +2916,8 @@ class ServingEngine:
             else:
                 still = 0
                 last_sig = sig
+        # (an EOS leaves its one dead row in flight behind an empty server)
+        out.extend(self.settle())
         return out
 
     def _progress_signature(self) -> tuple:
@@ -2750,8 +2949,14 @@ class ServingEngine:
         listing every violation (never just the first) if any state is
         inconsistent. The chaos suite calls this after every injected
         fault — the contract is that NO fault, wherever injected, may
-        leak a slot or strand a request."""
+        leak a slot or strand a request. The step in flight is settled
+        first: the audit judges a host that has read what it queued."""
+        self._settle_early("audit")
         errors = list(self.pool.consistency_errors())
+        if self._unread or self._closing:
+            errors.append(f"tokens counted in flight with nothing in "
+                          f"flight: unread {self._unread}, closing "
+                          f"{sorted(self._closing)}")
         seated = set(self._slot_req.keys())
         free = set(self.pool._free_set)
         overlap = seated & free

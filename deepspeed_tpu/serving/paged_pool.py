@@ -414,8 +414,9 @@ class PagedKVPool(SlotPool):
         return {"cache_store": cs}
 
     def _table_from_mirror(self, key: str = "table"):
-        tbl = jnp.array(self.table if key == "table" else self.ring.table,
-                        copy=True)
+        # (a host copy, as ``SlotPool._index_from_mirror`` says why)
+        tbl = jnp.asarray(np.array(
+            self.table if key == "table" else self.ring.table))
         if self._sharding is not None:
             tbl = self._place_leaf(key, tbl)
         return tbl

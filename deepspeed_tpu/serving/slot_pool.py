@@ -113,10 +113,14 @@ class SlotPool:
         every other pool leaf (see :meth:`_fresh_cache` — a bare
         ``jnp.asarray`` would flip the leaf back to uncommitted and
         fork the admit/decode executables on sharding mismatch)."""
-        # explicit copy: the CPU backend may zero-copy a numpy buffer,
-        # and the mirror is mutated in place by later advance() calls
+        # a copy made HERE, on the host: the device array may alias the
+        # buffer it is put from or read it only when the transfer runs,
+        # and the mirror is mutated in place by later advance() calls,
+        # which a step that runs ahead makes while the program that takes
+        # this leaf is still queued (``jnp.array(copy=True)`` copies on
+        # the device, after the put: it read the next step's mirror)
         with self.enqueue("index", "transfer"):
-            idx = jnp.array(self.starts, copy=True)
+            idx = jnp.asarray(np.array(self.starts))
             if self._sharding is not None:
                 idx = self._place_leaf("index", idx)
         return idx

@@ -468,6 +468,7 @@ class TestCancellation:
         survivor = srv.submit(_prompt(rng), max_new_tokens=8)
         srv.step()
         srv.step()
+        srv.settle()
         assert r.state is RequestState.RUNNING and r.output_tokens
         n = len(r.output_tokens)
         assert srv.cancel(r.request_id) is r

@@ -261,6 +261,7 @@ class TestDeadlines:
                          max_new_tokens=32, deadline_ms=60_000.0)
         srv.step()
         srv.step()
+        srv.settle()
         assert req.state is RequestState.RUNNING
         got = len(req.output_tokens)
         assert got >= 1
@@ -298,6 +299,7 @@ class TestPreemption:
         req = srv.submit(prompt, max_new_tokens=budget)
         for _ in range(4):
             srv.step()
+        srv.settle()
         assert req.state is RequestState.RUNNING
         mid = len(req.output_tokens)
         assert 0 < mid < budget

@@ -76,7 +76,8 @@ def test_staggered_admission_and_slot_reuse(stack, pool):
                     max_new_tokens=2)
     r2 = srv.submit(rng.integers(0, 64, size=10).astype(np.int32),
                     max_new_tokens=12)
-    done = srv.step()  # admit both; r1 (budget 2) finishes on this step
+    # admit both; r1 (budget 2) finishes with this step's tokens
+    done = srv.step() + srv.settle()
     assert r1 in done and r1.state == RequestState.FINISHED
     assert r2.state == RequestState.RUNNING
 

@@ -69,6 +69,7 @@ def test_long_prompt_does_not_stall_running_slot(stack, pool):
     short = srv.submit(rng.integers(1, 64, size=6).astype(np.int32),
                        max_new_tokens=20)
     srv.step()
+    srv.settle()
     assert short.state == RequestState.RUNNING
 
     long = srv.submit(rng.integers(1, 64, size=48).astype(np.int32),
@@ -76,6 +77,7 @@ def test_long_prompt_does_not_stall_running_slot(stack, pool):
     while long.state in (RequestState.QUEUED, RequestState.PREFILLING):
         before = len(short.output_tokens)
         srv.step()
+        srv.settle()        # (a token is visible when its step is settled)
         if long.state == RequestState.PREFILLING:
             # a mid-prefill step still ran the decode for the live slot
             assert len(short.output_tokens) == before + 1

@@ -62,10 +62,18 @@ def _serve(engine, pool, do_sample, **kw):
                       **kw)
     prompts = _prompts()
     reqs = [srv.submit(p, max_new_tokens=10) for p in prompts[:2]]
-    srv.step()
-    srv.step()
+    # every step settled before the next, the parents' order: a slot is
+    # granted again in the step after its request's last, so the sampler's
+    # calls, and with them the keys, fall on the same tokens (a loop that
+    # runs ahead seats the fourth request one step later: the same key
+    # sequence under other tokens; greedy streams do not move)
+    for _ in range(2):
+        srv.step()
+        srv.settle()
     reqs += [srv.submit(p, max_new_tokens=10) for p in prompts[2:]]
-    srv.run_until_drained(max_steps=400)
+    while srv.pending or srv.live_count:
+        srv.step()
+        srv.settle()
     return srv, [list(map(int, r.output_tokens)) for r in reqs]
 
 
