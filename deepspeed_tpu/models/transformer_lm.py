@@ -37,6 +37,11 @@ moonlight      rotary    rmsnorm    routed    latent attention (one
                                               sigmoid router with a
                                               bias, shared experts,
                                               leading dense layers
+granite-hybrid none      rmsnorm    swiglu    Mamba-2 layers beside
+                                              GQA layers, tied head
+kimi_linear    none      rmsnorm    routed    KDA layers beside latent
+                                              attention, moonlight's
+                                              FFN, experts held
 =============  ========  =========  ========  ===================
 
 Layer kinds that differ (``layer_types``: ``sliding_attention`` or
@@ -74,6 +79,17 @@ two groups: a state group over the mamba layers
 convolution's last inputs; a row a sequence, no positions) and K/V over the
 attention layers, contiguous or paged. A page pool keeps the state group
 beside its pages.
+
+A sixth, ``kda`` (:class:`KDAMixer`, Kimi Linear's gated delta rule with a
+decay a channel), stands beside ``attention`` layers the same way (the
+state layers' stacked leaf is ``kda_blocks``; ``s`` a head's (K, V) matrix,
+``conv`` the last inputs of the three convolutions). The attention layers
+beside state layers may be latent (``kv_lora_rank > 0``: the cache is the
+state group beside the one leaf ``c``), with ``pos_emb`` "none" they rotate
+nothing, and the FFN may be routed, behind ``first_k_dense`` leading layers
+with a plain one inside the first period (``dense_blocks``, of the leading
+layers' kind). A routed FFN may hold a share of its experts
+(``experts_held``).
 
 KV-cache decoding uses the flax ``cache`` variable collection: ``prefill``
 writes the prompt's K/V at positions [0, T), ``decode`` appends one position
@@ -198,6 +214,18 @@ class TransformerConfig:
     mamba_d_state: int = 0
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
+    # KDA layers (``layer_types`` "kda", beside "attention" layers): heads
+    # of kda_d_head key channels and as many value channels, a causal
+    # depthwise convolution of kda_d_conv taps over each of q, k and v, the
+    # decay and the output gate through a low rank of kda_d_head
+    # (ops/kda.py has the state's equations)
+    kda_n_heads: int = 0
+    kda_d_head: int = 0
+    kda_d_conv: int = 4
+    experts_held: Optional[int] = None  # the routed FFN holds experts
+    # [0, experts_held) of n_experts (one chip's share of a layer that
+    # several divide): the router and the top-k run over all n_experts, the
+    # held experts' part of the sum goes on; None: all
     embedding_multiplier: float = 1.0   # scales the token embedding
     embedding_init_std: Optional[float] = None  # a seeded embedding's
     # spread where it is not flax's 1 / sqrt(n_embd): under a tied head a
@@ -212,15 +240,15 @@ class TransformerConfig:
             kinds = set(self.layer_types) - {"sliding_attention",
                                              "full_attention",
                                              "power_retention",
-                                             "mamba", "attention"}
+                                             "mamba", "kda", "attention"}
             if kinds or len(self.layer_types) != self.n_layer:
                 raise ValueError(
                     f"layer_types names n_layer={self.n_layer} layers as "
                     f"sliding_attention | full_attention | power_retention "
-                    f"| mamba | attention; "
+                    f"| mamba | kda | attention; "
                     f"got {len(self.layer_types)} entries, unknown "
                     f"{sorted(kinds)}")
-            if {"mamba", "attention"} & set(self.layer_types):
+            if {"mamba", "kda", "attention"} & set(self.layer_types):
                 self._check_hybrid()
             if "power_retention" in self.layer_types:
                 if set(self.layer_types) != {"power_retention"}:
@@ -249,6 +277,11 @@ class TransformerConfig:
                 raise ValueError(
                     f"experts_per_token={self.experts_per_token} of "
                     f"n_experts={self.n_experts}")
+            if self.experts_held is not None \
+                    and not 0 < self.experts_held <= self.n_experts:
+                raise ValueError(
+                    f"experts_held={self.experts_held} of "
+                    f"n_experts={self.n_experts}")
             if self.activation != "swiglu" or self.mlp_bias:
                 raise ValueError("the routed FFN is gated silu without "
                                  "bias (activation='swiglu', mlp_bias=False)")
@@ -275,11 +308,14 @@ class TransformerConfig:
                     "latent attention (kv_lora_rank > 0) needs "
                     "qk_nope_head_dim, an even qk_rope_head_dim and "
                     "v_head_dim")
-            if self.pos_emb != "rotary" or self.layer_types is not None:
+            if self.pos_emb not in ("rotary", "none") or (
+                    self.layer_types is not None and not self.hybrid):
                 raise ValueError(
                     "latent attention carries its positions in the shared "
-                    "rotary key (pos_emb='rotary') and knows no layer kinds "
-                    "(layer_types) yet (ROADMAP.md, Reach)")
+                    "rotary key (pos_emb='rotary') or none at all ('none'), "
+                    "and knows no layer kinds (layer_types) but the state "
+                    "layers beside it: no window and no retention yet "
+                    "(ROADMAP.md, Reach)")
         for feature in ("kv_cache_quant", "int8_weights"):
             why = getattr(self, feature) \
                 and refusal(cache_kinds(self), feature)
@@ -287,22 +323,28 @@ class TransformerConfig:
                 raise ValueError(why)
 
     def _check_hybrid(self) -> None:
-        """``mamba`` layers stand beside ``attention`` layers (full, with
-        ``pos_emb`` "rotary" or "none") in a pattern that repeats: one
-        attention layer a period, the same number of mamba layers before
-        and after it in every period (:attr:`hybrid_period`)."""
+        """State layers of ONE kind, ``mamba`` or ``kda``, stand beside
+        ``attention`` layers (full, K/V a head or latent, with ``pos_emb``
+        "rotary" or "none") in a pattern that repeats: one attention layer
+        a period, the same number of state layers before and after it in
+        every period (:attr:`hybrid_period`). The FFN may be routed; the
+        ``first_k_dense`` layers with a plain one are state layers at the
+        head of the first period."""
         types = self.layer_types
         n_att = types.count("attention")
-        if set(types) != {"mamba", "attention"} or self.n_layer % n_att \
+        state = set(types) - {"attention"}
+        if len(state) != 1 or not state < {"mamba", "kda"} or not n_att \
+                or self.n_layer % n_att \
                 or types != types[:self.n_layer // n_att] * n_att:
             raise ValueError(
-                f"mamba and attention layers come as a pattern with ONE "
-                f"attention layer that repeats over the layers (the two "
-                f"stacked leaves are run period by period); got "
+                f"mamba or kda layers and attention layers come as a pattern "
+                f"with ONE attention layer that repeats over the layers (the "
+                f"two stacked leaves are run period by period); got "
                 f"{list(types)}")
-        if not (self.mamba_n_heads and self.mamba_d_head
-                and self.mamba_d_state) or self.mamba_n_groups != 1 \
-                or self.mamba_d_conv < 2:
+        if self.mamba and (
+                not (self.mamba_n_heads and self.mamba_d_head
+                     and self.mamba_d_state) or self.mamba_n_groups != 1
+                or self.mamba_d_conv < 2):
             raise ValueError(
                 f"mamba layers need mamba_n_heads, mamba_d_head and "
                 f"mamba_d_state, one group (B and C shared by the heads) "
@@ -310,9 +352,20 @@ class TransformerConfig:
                 f"{self.mamba_n_heads} x {self.mamba_d_head}, state "
                 f"{self.mamba_d_state}, groups {self.mamba_n_groups}, "
                 f"taps {self.mamba_d_conv}")
-        if self.n_experts or self.parallel_residual:
-            raise ValueError("mamba layers know the sequential residual "
-                             "and a plain FFN (n_experts 0)")
+        if self.kda and (not (self.kda_n_heads and self.kda_d_head)
+                         or self.kda_d_conv < 2):
+            raise ValueError(
+                f"kda layers need kda_n_heads and kda_d_head and a "
+                f"convolution of two taps or more; got {self.kda_n_heads} x "
+                f"{self.kda_d_head}, taps {self.kda_d_conv}")
+        if self.parallel_residual:
+            raise ValueError("state layers know the sequential residual")
+        if self.first_k_dense > self.hybrid_period[0]:
+            raise ValueError(
+                f"first_k_dense={self.first_k_dense} leading layers with a "
+                f"plain FFN are state layers at the head of the first "
+                f"period, which has {self.hybrid_period[0]} before its "
+                f"attention layer")
 
     @property
     def head_dim(self) -> int:
@@ -338,7 +391,7 @@ class TransformerConfig:
         this one with a plain gated FFN of ``dense_ffn_dim``."""
         return dataclasses.replace(
             self, n_experts=0, experts_per_token=0, n_shared_experts=0,
-            first_k_dense=0, ffn_dim=self.dense_ffn_dim)
+            experts_held=None, first_k_dense=0, ffn_dim=self.dense_ffn_dim)
 
     @property
     def retention(self) -> bool:
@@ -353,8 +406,25 @@ class TransformerConfig:
         return self.layer_types is not None and "mamba" in self.layer_types
 
     @property
+    def kda(self) -> bool:
+        """``kda`` layers beside ``attention`` layers: a state group over
+        the former, K/V or a latent row over the latter."""
+        return self.layer_types is not None and "kda" in self.layer_types
+
+    @property
+    def hybrid(self) -> Optional[str]:
+        """The kind of the state layers that stand beside ``attention``
+        layers, ``"mamba"`` or ``"kda"``; None for a model of one stack."""
+        return "mamba" if self.mamba else "kda" if self.kda else None
+
+    @property
+    def kda_width(self) -> int:
+        """``kda_n_heads * kda_d_head``: the width of q, k and v each."""
+        return self.kda_n_heads * self.kda_d_head
+
+    @property
     def hybrid_period(self) -> tuple:
-        """``(before, after, periods)``: the mamba layers before and after
+        """``(before, after, periods)``: the state layers before and after
         a period's one attention layer, and how many periods there are."""
         periods = self.layer_types.count("attention")
         period = self.layer_types[:self.n_layer // periods]
@@ -412,6 +482,14 @@ FAMILY_PRESETS = {
                            activation="swiglu", qkv_bias=False,
                            mlp_bias=False, tie_word_embeddings=True,
                            layer_norm_epsilon=1e-5),
+    # Kimi Linear (Moonshot AI; model_type kimi_linear): KDA layers beside
+    # latent attention layers that rotate nothing (``layer_types`` "kda" |
+    # "attention"), Moonlight's router and shared expert behind one leading
+    # dense layer, an untied head. Widths and the pattern are the caller's.
+    "kimi_linear": dict(pos_emb="none", norm="rmsnorm", activation="swiglu",
+                        qkv_bias=False, mlp_bias=False,
+                        tie_word_embeddings=False, layer_norm_epsilon=1e-5,
+                        scoring_func="sigmoid"),
 }
 
 
@@ -957,7 +1035,7 @@ class CachedAttention(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
 
         is_window = None    # traced: this layer is a sliding-window layer
-        if cfg.layer_types is not None and not cfg.mamba:
+        if cfg.layer_types is not None and not cfg.hybrid:
             inv_freq, factor, windows = layer_rope_tables(cfg)
             is_window = jnp.asarray(windows)[layer]
         if cfg.pos_emb == "rotary":
@@ -1282,8 +1360,11 @@ class LatentAttention(nn.Module):
             if decode and "chunk" in kv_cache \
             else (start[:, None] if per_slot else start) \
             + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+        # (pos_emb "none": the "rope" columns are there and nothing
+        # rotates them)
         rope = functools.partial(apply_rotary, positions=positions,
-                                 rotary_dim=dr, theta=cfg.rope_theta)
+                                 rotary_dim=dr, theta=cfg.rope_theta) \
+            if cfg.pos_emb == "rotary" else (lambda x: x)
         q_n, q_r = q[..., :dn], rope(q[..., dn:])
         k_r = rope(ckr[..., None, R:])[:, :, 0]                 # (B, T, dr)
         scale = 1.0 / math.sqrt(dn + dr)
@@ -1364,6 +1445,86 @@ def _uniform_log(lo: float, hi: float, inverse=None):
     return init
 
 
+def _state_rows(cache, B: int, T: int):
+    """What a state layer's mixer reads off the cache it is handed for a
+    group of rows (B, T): ``(layer, rows, fresh, valid)``: the layer's
+    index among those that keep a state, the cache row of each entry (its
+    own place where the caller names none), whether an entry stands at
+    position 0 (it reads neither its state nor its tail), and how many of
+    its T tokens are real."""
+    rows = cache.get("rows")
+    if rows is None:
+        rows = jnp.arange(B, dtype=jnp.int32)
+    valid = jnp.full((B,), T, jnp.int32)
+    if cache.get("valid") is not None:
+        valid = jnp.minimum(cache["valid"], T)
+    return (cache["layer"], rows,
+            jnp.broadcast_to(cache["start"] == 0, (B,)), valid)
+
+
+def _conv_after_tail(cache, x, w, b):
+    """A state layer's causal convolution of one group of rows ``x`` (B, T,
+    C) after the tail its cache carries (``ops/state_space.causal_conv``):
+    ``(conv(x), where, conv leaf)``. ``cache`` None: whole sequences from
+    nothing, no ``where`` and no leaf. Else ``where`` is
+    :func:`_state_rows`'s, the tail is read from the leaf ``conv`` and the
+    tail at the last REAL token is written back to it."""
+    from ..ops.state_space import causal_conv
+
+    B, T = x.shape[:2]
+    tail = jnp.zeros((B, w.shape[0] - 1, x.shape[-1]), x.dtype)
+    if cache is None:
+        return causal_conv(x, tail, w, b, jnp.full((B,), T, jnp.int32))[0], \
+            None, None
+    where = layer, rows, fresh, valid = _state_rows(cache, B, T)
+    tail = _read_rows(cache["conv"], layer, rows, fresh, tail.shape[1:])
+    x, tail = causal_conv(x, tail, w, b, valid)
+    return x, where, _write_rows(cache["conv"], layer, rows,
+                                 tail.reshape(B, -1))
+
+
+def _read_rows(leaf, layer, rows, fresh, shape):
+    """Rows ``rows`` (B,) of layer ``layer`` of ``leaf`` (L, R, W), each as
+    ``shape``: zeros for an entry that is ``fresh`` or out of ``[0, R)``
+    (one that does not run)."""
+    R = leaf.shape[1]
+    at = (layer, jnp.where((rows >= 0) & (rows < R), rows, R))
+    return jnp.where(fresh[:, None, None], 0, leaf.at[at].get(
+        mode="fill", fill_value=0).reshape((rows.shape[0],) + shape))
+
+
+def _write_rows(leaf, layer, rows, values):
+    """``leaf`` (L, R, W) with ``values`` (B, W) written to rows ``rows``
+    (B,) of layer ``layer``; an entry out of ``[0, R)`` writes nothing.
+    A few entries are a scatter of their rows. From ``_SLAB_FROM`` on they
+    go through the layer's whole slab, each row taking the entry that names
+    it (a select and ONE update of (R, W)): XLA expands a scatter of B rows
+    into a loop of B single-row updates, 5.6 ms a step of 128 rows over 9
+    KDA layers where the slabs' bytes are 0.2 ms (my traced run, PR 50,
+    call 3: ``dynamic-update-slice`` over ``bf16[9,128,36864]`` with its
+    bounds check, 17 % of the busy device; the mamba layers' tail of 64
+    rows x 36 layers went the same way). The slab costs the same at every
+    B and more than a scatter of two: a layer of (64, 13056) 11.9 us
+    against 4.9 at B = 2, 9.3 at 8, 17.3 at 16, 55.7 at 64; a layer of
+    (128, 36864) 63 us against 18 at B = 2, 66 at 8, 130 at 16, 973 at
+    128 (my chip run, PR 50, call 94: each form alone, every layer once)."""
+    B, R = rows.shape[0], leaf.shape[1]
+    values = values.astype(leaf.dtype)
+    if B < _SLAB_FROM:
+        at = (layer, jnp.where((rows >= 0) & (rows < R), rows, R))
+        return leaf.at[at].set(values, mode="drop")
+    named = rows[None, :] == jnp.arange(R, dtype=rows.dtype)[:, None]  # R, B
+    slab = jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+    slab = jnp.where(jnp.any(named, axis=1)[:, None],
+                     values[jnp.argmax(named, axis=1)], slab)
+    return jax.lax.dynamic_update_slice_in_dim(leaf, slab[None], layer, 0)
+
+
+# entries from which _write_rows takes the slab (where the two forms met
+# on both cells' leaves, see there)
+_SLAB_FROM = 8
+
+
 class Mamba2Mixer(nn.Module):
     """The Mamba-2 mixer in the attention's place
     (``ops/state_space.py`` has the state's equations). With ``u`` the
@@ -1431,33 +1592,14 @@ class Mamba2Mixer(nn.Module):
             """The convolution and the state of one group of rows (B, T):
             ``(y + D x, leaves)``."""
             B, T = dt.shape[:2]
-            tail = jnp.zeros((B, K - 1, ch), xbc.dtype)
-            valid = jnp.full((B,), T, jnp.int32)
-            if cached:
-                start = cache["start"]
-                li = cache["layer"]
-                rows = cache.get("rows")
-                if rows is None:
-                    rows = jnp.arange(B, dtype=jnp.int32)
-                R = cache["s"].shape[1]
-                fresh = jnp.broadcast_to(start == 0, (B,))
-                if cache.get("valid") is not None:
-                    valid = jnp.minimum(cache["valid"], T)
-                # this layer's tail of each entry's row (an entry that does
-                # not run: row R, read as zeros and dropped when written)
-                at = (li, jnp.where((rows >= 0) & (rows < R), rows, R))
-                tail = jnp.where(fresh[:, None, None], 0, cache["conv"].at[
-                    at].get(mode="fill", fill_value=0).reshape(tail.shape))
-            xbc, tail = ss.causal_conv(xbc, tail, conv_w, conv_b, valid)
-            if cached:
-                conv_leaf = cache["conv"].at[at].set(
-                    tail.reshape(B, -1).astype(cache["conv"].dtype),
-                    mode="drop")
+            xbc, where, conv_leaf = _conv_after_tail(cache, xbc, conv_w,
+                                                     conv_b)
             x = xbc[..., :inner].reshape(B, T, H, P)
             b, c = xbc[..., inner:inner + N], xbc[..., inner + N:]
             if not cached:
                 y, leaves = ss.ssm_sequence(x, dt, a, b, c), None
             else:
+                li, rows, fresh, valid = where
                 if T == 1:
                     y, s = ss.ssm_decode(x[:, 0], dt[:, 0], a, b[:, 0],
                                          c[:, 0], cache["s"], li, rows, fresh)
@@ -1476,6 +1618,102 @@ class Mamba2Mixer(nn.Module):
         out = _dense(cfg, C, use_bias=False, name="out_proj")(
             g.astype(cfg.dtype))
         return out, leaves
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention in the attention's place (``ops/kda.py`` has
+    the state's equations). With ``x`` the normed input, a head ``h`` of
+    ``d = kda_d_head`` channels::
+
+        [q ; k ; v] = silu(conv(W_qkv x))       (one convolution a channel)
+        q_h <- l2norm(q_h) / sqrt(d)            k_h <- l2norm(k_h)
+        g_h = -exp(A_log_h) softplus(W_f^up W_f^down x + dt_bias)_h
+        beta_h = sigmoid(W_beta x)_h
+        o_h = the gated delta rule over (q_h, k_h, v_h, g_h, beta_h)
+        out = W_o [rmsnorm_d(o_h; w) (.) sigmoid(W_g^up W_g^down x)_h]
+
+    ``conv`` is causal and depthwise over ``kda_d_conv`` taps, no bias; the
+    decay ``g`` is a channel's, through a low rank of ``d``, and so is the
+    output gate. Three forms, as :class:`Mamba2Mixer`: without a
+    cache whole sequences from an empty state (``kda_sequence``); with one,
+    ``kv_cache`` holds the stacked leaves whole, ``s`` (float32, (L, rows,
+    H, d, d)) and ``conv`` (the last ``kda_d_conv - 1`` inputs of the
+    convolution over [q ; k ; v], time-major on the minor axis), with
+    ``layer``, ``start``, ``rows`` and ``valid``: one token takes
+    ``kda_decode``, more take ``kda_chunk`` block by block. A token at or
+    past ``valid`` is padding: it advances neither the state (its ``g``
+    and ``beta`` are 0) nor the tail. An entry whose first position is 0
+    reads neither. The convolution and the tail's shift are XLA's
+    (``ops/state_space.causal_conv``)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, *, decode: Union[bool, str] = False,
+                 deterministic: bool = True, kv_cache=None, layer=None):
+        from ..ops import kda
+
+        cfg = self.config
+        B, T, C = x.shape
+        H, D, taps = cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_d_conv
+        inner, rank = cfg.kda_width, D      # (the low rank: a head's width)
+        f32 = jnp.float32
+
+        def dense(width, name):
+            return _dense(cfg, width, use_bias=False, name=name)
+
+        qkv = dense(3 * inner, "qkv_proj")(x)
+        bound = 1.0 / math.sqrt(taps)       # (torch's Conv1d default)
+        draw = nn.initializers.uniform(2 * bound)
+        conv_w = self.param("conv_w", lambda *a: draw(*a) - bound,
+                            (taps, 3 * inner))
+        a = jnp.exp(self.param("A_log", _uniform_log(1.0, 16.0, jnp.log),
+                               (H,)).astype(f32))
+        dt_bias = self.param(
+            "dt_bias", _uniform_log(1e-3, 1e-1, lambda v: v + jnp.log(
+                -jnp.expm1(-v))), (inner,)).astype(f32)
+        f = dense(inner, "f_b_proj")(dense(rank, "f_a_proj")(x))
+        g = -a[:, None] * jax.nn.softplus(
+            f.astype(f32) + dt_bias).reshape(B, T, H, D)
+        beta = jax.nn.sigmoid(dense(H, "b_proj")(x).astype(f32))
+        gate = dense(inner, "g_b_proj")(dense(rank, "g_a_proj")(x))
+        o_norm = self.param("o_norm", nn.initializers.ones, (D,))
+
+        cached = bool(decode)
+
+        def unit(v):
+            return v * jax.lax.rsqrt(
+                jnp.sum(v * v, axis=-1, keepdims=True) + 1e-6)
+
+        def mix(cache, qkv, g, beta):
+            """The convolution and the state of one group of rows (B, T):
+            ``(o, leaves)``."""
+            B, T = beta.shape[:2]
+            qkv, where, conv_leaf = _conv_after_tail(cache, qkv, conv_w,
+                                                     None)
+            q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(
+                B, T, H, D) for i in range(3))
+            q, k = unit(q) * (1.0 / math.sqrt(D)), unit(k)
+            if not cached:
+                return kda.kda_sequence(q, k, v, g, beta), None
+            li, rows, fresh, valid = where
+            if T == 1:
+                o, s = kda.kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                      beta[:, 0], cache["s"], li, rows, fresh)
+                o = o[:, None]
+            else:
+                o, s = _traced_once(kda.kda_prefill,
+                                    chunk=_chunk_shaped(q))(
+                    q, k, v, g, beta, cache["s"], li, rows, fresh,
+                    length=valid)
+            return o, {"s": s, "conv": conv_leaf}
+
+        o, leaves = _by_row_group(kv_cache, mix, qkv, g, beta) if cached \
+            else mix(None, qkv, g, beta)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + cfg.layer_norm_epsilon) * o_norm.astype(f32)
+        y = o.reshape(B, T, inner) * jax.nn.sigmoid(gate.astype(f32))
+        return dense(C, "o_proj")(y.astype(cfg.dtype)), leaves
 
 
 class TransformerMLP(nn.Module):
@@ -1502,16 +1740,17 @@ class TransformerMLP(nn.Module):
 
 class TransformerBlock(nn.Module):
     config: TransformerConfig
-    kind: Optional[str] = None      # "mamba" | "attention": the layer's
-    # kind in a model of mamba and attention layers, whose stacked leaves
-    # are one a kind; None: the configuration's one mixer
+    kind: Optional[str] = None      # "mamba" | "kda" | "attention": the
+    # layer's kind in a model of state and attention layers, whose stacked
+    # leaves are one a kind; None: the configuration's one mixer
 
     @nn.compact
     def __call__(self, x, decode: Union[bool, str] = False,
                  deterministic: bool = True, kv_cache=None, layer=None,
-                 experts=None):
+                 experts=None, ffn_layer=None):
         cfg = self.config
         attention, name = (Mamba2Mixer, "mamba") if self.kind == "mamba" \
+            else (KDAMixer, "kda") if self.kind == "kda" \
             else (PowerRetention if cfg.retention else
                   LatentAttention if cfg.latent else CachedAttention, "attn")
         a, new_cache = attention(cfg, name=name)(
@@ -1525,12 +1764,15 @@ class TransformerBlock(nn.Module):
             from ..moe.routed_ffn import RoutedFFN
 
             nonlocal stats
-            # (the expert leaves stack the routed layers only)
+            # (the expert leaves stack the routed layers only; ``layer``
+            # counts a kind's layers where the stack is one a kind, and
+            # ``ffn_layer`` then says which routed layer this is)
             m, layer_stats = RoutedFFN(
                 cfg.n_experts, cfg.experts_per_token, cfg.norm_topk_prob,
                 cfg.scoring_func, cfg.routed_scaling_factor,
                 cfg.n_shared_experts * cfg.ffn_width, cfg.dtype,
-                name="mlp")(h, experts, layer - cfg.first_k_dense
+                name="mlp")(h, experts, ffn_layer if ffn_layer is not None
+                             else layer - cfg.first_k_dense
                              if cfg.first_k_dense else layer)
             stats = (layer_stats,)
             return m
@@ -1565,7 +1807,7 @@ class _ScanBlock(nn.Module):
       (PERF.md §8, the carry-DUS lead); the carry-DUS of a
       batch-major dense row did not.
     - a recurrent state (``KVCacheSpec.state_leaves``, a layer of
-      :class:`PowerRetention` or :class:`Mamba2Mixer`):
+      :class:`PowerRetention`, :class:`Mamba2Mixer` or :class:`KDAMixer`):
       whole like a page pool's leaves, for the same reason and one more:
       a slice would be one layer's state of EVERY row, 0.5 GB a layer at
       the served size, read and written for the one row a chunk runs.
@@ -1584,7 +1826,8 @@ class _ScanBlock(nn.Module):
     # such a layer counts the layers of its kind (its stacked leaves')
 
     @nn.compact
-    def __call__(self, carry, decode, deterministic, experts=None):
+    def __call__(self, carry, decode, deterministic, experts=None,
+                 ffn_layer=None):
         x, cache, start, li = carry
         cfg = self.config
         cls = TransformerBlock
@@ -1595,6 +1838,8 @@ class _ScanBlock(nn.Module):
         # only where its configuration reads them
         more = (li, experts) if (cfg.layer_types is not None
                                  or cfg.n_experts) else ()
+        if ffn_layer is not None:
+            more += (ffn_layer,)
         # a layer's output beside the carry: what its routed FFN counted
         # (stacked over the layers by the scan), nothing for a dense FFN
         if cache is None:
@@ -1706,6 +1951,7 @@ def page_lanes(page_size: int) -> int:
 CACHE_KINDS = {     # in the order a refusal is looked up (cache_kinds)
     "state": "a recurrent state",           # power_retention layers
     "ssm": "a state group beside K/V",      # mamba beside attention layers
+    "kda": "a KDA state group",             # kda beside attention layers
     "latent": "latent attention's cache",   # one row a token (kv_lora_rank)
     "window_only": "sliding-window layers alone",
     "window": "a window page group",        # sliding beside full layers
@@ -1776,6 +2022,34 @@ CACHE_REFUSALS = {
     ("ssm", "int8_weights"):
         "int8_weights does not reach the mamba layers' convolution, A_log, "
         "D and dt_bias, which are parameters of the mixer and no Dense",
+    ("kda", "spec_decode"):
+        "a rejected draft's tokens are in the delta-rule state and the "
+        "convolutions' tail for good: verify_k's rollback moves an index, "
+        "which hides cached columns and nothing of a state",
+    ("kda", "prefix_cache"):
+        "a hit maps the pages of the prompt's start and would need the "
+        "state as it stood at the hit's boundary, which nothing keeps (a "
+        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
+    ("kda", "roles"):
+        "pages are the unit of a handoff: the slot's state rows would have "
+        "to be shipped beside them",
+    ("kda", "tensor_parallel"):
+        "the state leaves have no placement on the model axis and the KDA "
+        "kernels are not wrapped for a mesh",
+    ("kda", "tensor_parallel_serving"):
+        "the state leaves have no placement on the model axis and the KDA "
+        "kernels are not wrapped for a mesh",
+    ("kda", "zero_inference"):
+        "it streams one layer's block parameters at a time out of ONE "
+        "stacked tree; kda and attention layers are two, and the state is "
+        "not threaded through the streamed layers",
+    ("kda", "kv_cache_quant"):
+        "kv_cache_quant quantizes K/V columns; the state group beside them "
+        "is float32 and the tier has not been run beside it",
+    ("kda", "int8_weights"):
+        "int8_weights does not reach the kda layers' convolution, A_log, "
+        "dt_bias and output norm, which are parameters of the mixer and no "
+        "Dense",
     ("latent", "spec_decode"):
         "the latent read takes one query row a slot or one slot's chunk; a "
         "verify step's K + 1 rows of every slot, each with its own causal "
@@ -1840,11 +2114,12 @@ CACHE_REFUSALS = {
 def cache_kinds(cfg: TransformerConfig) -> tuple:
     """The kinds of ``CACHE_KINDS`` a configuration is, in its order."""
     groups = kv_cache_groups(cfg)
-    has = {"state": cfg.retention, "ssm": cfg.mamba, "latent": cfg.latent,
+    has = {"state": cfg.retention, "ssm": cfg.mamba, "kda": cfg.kda,
+           "latent": cfg.latent,
            "window_only": groups is not None and not groups[0][1],
            "window": groups is not None,
            "layer_types": cfg.layer_types is not None
-           and not (cfg.retention or cfg.mamba),
+           and not (cfg.retention or cfg.hybrid),
            "routed": cfg.n_experts}
     return tuple(kind for kind in CACHE_KINDS if has[kind])
 
@@ -1896,9 +2171,11 @@ class KVCacheSpec:
     # *state)`` float32. A model of mamba layers beside attention layers:
     # the mamba layers, ``s`` (ops/state_space.state_shape) float32 and
     # ``conv`` (the convolution's last taps - 1 inputs, time-major) in
-    # ``dtype``; the attention layers keep K/V (:attr:`kv_layers`). A
-    # state's size does not depend on max_seq_len, which stays the bound on
-    # positions
+    # ``dtype``; the attention layers keep K/V (:attr:`kv_layers`). A model
+    # of kda layers beside attention layers: the kda layers, ``s`` (heads,
+    # d, d) float32 and ``conv`` likewise; the attention layers keep K/V or
+    # the latent row. A state's size does not depend on max_seq_len, which
+    # stays the bound on positions
 
     @property
     def state_leaves(self) -> tuple:
@@ -1981,7 +2258,9 @@ class KVCacheSpec:
         if self.latent:
             return {"c": jnp.zeros((L, batch_size, self.latent,
                                     self.max_seq_len), self.dtype),
-                    "index": jnp.zeros((batch_size,), jnp.int32)}
+                    "index": jnp.zeros((batch_size,), jnp.int32),
+                    **(self._state_cache(batch_size) if self.state_group
+                       else {})}
         shape = (L, batch_size, self.kv_heads, self.cache_d,
                  self.max_seq_len)
         cache = {"k": jnp.zeros(shape, self.dtype),
@@ -2016,7 +2295,9 @@ class KVCacheSpec:
         lanes = page_lanes(page_size)
         if self.latent:
             return {"c": jnp.zeros((self.kv_layers, num_pages, self.latent,
-                                    lanes), self.dtype)}
+                                    lanes), self.dtype),
+                    **(self._state_cache(num_slots) if self.state_group
+                       else {})}
         if self.groups is not None:
             # one stacked leaf a group: ``num_pages`` pages for the full
             # layers, ``window_pages`` for the window layers
@@ -2078,8 +2359,8 @@ class KVCacheSpec:
             leaf = paged["c"]                       # (L, P, W, lanes)
             g = jnp.take(leaf, flat, axis=1, mode="clip")[..., :ps]
             g = g.reshape(leaf.shape[0], B, max_pages, self.latent, ps)
-            return {"c": g.transpose(0, 1, 3, 2, 4).reshape(
-                leaf.shape[0], B, self.latent, max_pages * ps)}
+            return dict(out, c=g.transpose(0, 1, 3, 2, 4).reshape(
+                leaf.shape[0], B, self.latent, max_pages * ps))
         for key in ("k", "v"):
             leaf = paged[key]                       # (L, P, KV, cd, lanes)
             L, _, KV, cd, _ = leaf.shape
@@ -2114,6 +2395,12 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
             ("s", state_shape(cfg.mamba_n_heads, cfg.mamba_d_head,
                               cfg.mamba_d_state), jnp.float32),
             ("conv", ((cfg.mamba_d_conv - 1) * cfg.mamba_channels,),
+             cache_dtype)))
+    if cfg.kda:
+        group = (cfg.layer_types.count("kda"), (
+            ("s", (cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_d_head),
+             jnp.float32),
+            ("conv", ((cfg.kda_d_conv - 1) * 3 * cfg.kda_width,),
              cache_dtype)))
     return KVCacheSpec(n_layer=cfg.n_layer, kv_heads=cfg.kv_heads,
                        head_dim=cfg.head_dim, cache_d=cache_d,
@@ -2165,28 +2452,31 @@ class _CacheStore(nn.Module):
                 leaf.value = new_values["s"]
                 cidx.value = new_index
             return values, cidx.value
-        if cfg.latent:
-            # one row a token that every head reads, and no k / v
-            leaf = self.variable(
-                "cache", "c", jnp.zeros,
-                (L, batch_size, cfg.latent, cfg.max_seq_len), cfg.dtype)
-            values = {"c": leaf.value}
-            if new_values is not None:
-                leaf.value = new_values["c"]
-                cidx.value = new_index
-            return values, cidx.value
-        cache_dtype, cache_d, _ = kv_cache_spec(cfg)
         state = {}
-        if cfg.mamba:
-            # K/V over the attention layers and a state group over the
-            # mamba layers (a provided cache passes through at its own
-            # shapes, as everywhere here)
+        if cfg.hybrid:
+            # K/V (or the latent row) over the attention layers and a
+            # state group over the state layers (a provided cache passes
+            # through at its own shapes, as everywhere here)
             spec = make_kv_cache_spec(cfg)
             L = spec.kv_layers
             state = {key: self.variable("cache", key, jnp.zeros, leaf.shape,
                                         leaf.dtype)
                      for key, leaf in jax.eval_shape(
                          lambda: spec._state_cache(batch_size)).items()}
+        if cfg.latent:
+            # one row a token that every head reads, and no k / v
+            leaf = self.variable(
+                "cache", "c", jnp.zeros,
+                (L, batch_size, cfg.latent, cfg.max_seq_len), cfg.dtype)
+            values = {"c": leaf.value,
+                      **{key: var.value for key, var in state.items()}}
+            if new_values is not None:
+                leaf.value = new_values["c"]
+                for key, var in state.items():
+                    var.value = new_values[key]
+                cidx.value = new_index
+            return values, cidx.value
+        cache_dtype, cache_d, _ = kv_cache_spec(cfg)
         shape = (L, batch_size, KV, cache_d, cfg.max_seq_len)
         ck = self.variable("cache", "k", jnp.zeros, shape, cache_dtype)
         cv = self.variable("cache", "v", jnp.zeros, shape, cache_dtype)
@@ -2257,27 +2547,31 @@ class TransformerLM(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(config, kind, name=name)
 
-        if cfg.mamba:
+        state_kind = cfg.hybrid
+        if state_kind:
             # two stacked leaves, one a layer kind; the scans own them and
             # make them, :meth:`_hybrid_layers` runs them in the published
-            # order
+            # order (the leading layers with a plain FFN are state layers
+            # and a third leaf, ``dense_blocks``)
             n_att = cfg.layer_types.count("attention")
-            self.mamba_blocks = scan(cfg, cfg.n_layer - n_att,
-                                     "mamba_blocks", "mamba")
+            self.state_blocks = scan(
+                cfg, cfg.n_layer - n_att - cfg.first_k_dense,
+                f"{state_kind}_blocks", state_kind)
             self.attn_blocks = scan(cfg, n_att, "attn_blocks", "attention")
         if cfg.first_k_dense:
             # the leading layers with a plain FFN: a scan of their own in
             # front, the carry (cache, layer counter) running through both
             self.dense_blocks = scan(cfg.dense_layers(), cfg.first_k_dense,
-                                     "dense_blocks")
-        if not cfg.mamba:
+                                     "dense_blocks", state_kind)
+        if not state_kind:
             self.blocks = scan(cfg, cfg.n_layer - cfg.first_k_dense, "blocks")
         if cfg.n_experts:
             from ..moe.routed_ffn import ExpertLeaves
 
             self.experts = ExpertLeaves(cfg.n_layer - cfg.first_k_dense,
-                                        cfg.n_experts, cfg.n_embd,
-                                        cfg.ffn_width, name="experts")
+                                        cfg.experts_held or cfg.n_experts,
+                                        cfg.n_embd, cfg.ffn_width,
+                                        name="experts")
         self.cache_store = _CacheStore(cfg, name="cache_store")
         self.ln_f = _norm(cfg, "ln_f")
         if not cfg.tie_word_embeddings:
@@ -2286,55 +2580,97 @@ class TransformerLM(nn.Module):
             self.lm_head = _dense(head_cfg, cfg.vocab_size, use_bias=False,
                                   dtype=jnp.float32, name="lm_head")
 
-    def _hybrid_layers(self, carry, decode, deterministic):
-        """The layers of a model of mamba and attention layers, in the
-        published order, off the two stacked leaves: a scan over the
-        periods whose body scans the mamba layers before the period's
+    def _hybrid_layers(self, carry, decode, deterministic, experts=()):
+        """The layers of a model of state (mamba or kda) and attention
+        layers, in the published order, off the stacked leaves: a scan over
+        the periods whose body scans the state layers before the period's
         attention layer, runs that one, and scans those after it
         (``[5, attention, 4]`` four times for 40 layers). The compiled
-        body holds the mamba block twice and the attention block once,
+        body holds the state block twice and the attention block once,
         whatever the depth. A layer takes its slice of its kind's leaf by
         its index among the layers of that kind, which is also where its
-        cache lies (``_ScanBlock``'s counter). Returns ``(x, cache)``."""
+        cache lies (``_ScanBlock``'s counter); where the FFN is routed
+        (``experts``: the model's expert leaves) it is told its index among
+        the routed layers too. ``first_k_dense`` leading state layers with
+        a plain FFN are a leaf of their own: the first period then runs
+        out of the scan, behind them. Returns ``(x, cache)`` and what the
+        routed layers counted, stacked in their order (or ``()``)."""
         cfg = self.config
         x, cache, start, _ = carry
+        kind, dense = cfg.hybrid, cfg.first_k_dense
         if self.is_initializing():
             # the scans make their leaves; the order of this one pass over
             # an empty cache is nobody's
-            (x, *_), _ = self.mamba_blocks((x, None, start, carry[3]),
-                                           False, deterministic)
-            (x, *_), _ = self.attn_blocks((x, None, start, carry[3]),
-                                          False, deterministic)
-            return x, cache
+            empty = (x, None, start, carry[3] + dense)
+            if dense:
+                (x, *_), _ = self.dense_blocks(empty, False, deterministic)
+            (x, *_), _ = self.state_blocks((x,) + empty[1:], False,
+                                           deterministic, *experts)
+            (x, *_), _ = self.attn_blocks((x,) + empty[1:], False,
+                                          deterministic, *experts)
+            return (x, cache), ()
         before, after, periods = cfg.hybrid_period
-        leaves = {"mamba": self.variables["params"]["mamba_blocks"],
-                  "attention": self.variables["params"]["attn_blocks"]}
-        blocks = {kind: _ScanBlock(cfg, kind, parent=None)
-                  for kind in leaves}
+        params = self.variables["params"]
+        leaves = {kind: params[f"{kind}_blocks"],
+                  "attention": params["attn_blocks"]}
+        blocks = {k: _ScanBlock(cfg, k, parent=None) for k in leaves}
 
-        def layer(kind, state, index):
+        def layer(of, state, index, ffn_layer):
+            """Layer ``index`` of kind ``of`` (the state layers' leaf starts
+            behind the dense ones); ``ffn_layer``: its place among the
+            routed layers. Returns the state and what its FFN counted."""
             x, cache = state
-            params = jax.tree_util.tree_map(lambda w: w[index], leaves[kind])
-            (x, cache, _, _), _ = blocks[kind].apply(
-                {"params": params}, (x, cache, start, index), decode,
-                deterministic)
-            return x, cache
+            at = index - dense if of == kind else index
+            sliced = jax.tree_util.tree_map(lambda w: w[at], leaves[of])
+            (x, cache, _, _), stats = blocks[of].apply(
+                {"params": sliced}, (x, cache, start, index), decode,
+                deterministic, *experts,
+                *((ffn_layer,) if experts else ()))
+            return (x, cache), stats
 
-        def mamba_run(state, first, count):
+        def state_run(state, first, count, ffn_first):
+            """``count`` state layers from the ``first``: the state, and
+            their counts stacked (None for no layer)."""
             if not count:
-                return state
+                return state, None
             return jax.lax.scan(
-                lambda st, j: (layer("mamba", st, first + j), None),
-                state, jnp.arange(count, dtype=jnp.int32))[0]
+                lambda st, j: layer(kind, st, first + j, ffn_first + j),
+                state, jnp.arange(count, dtype=jnp.int32))
 
-        def period(state, p):
-            first = p * (before + after)
-            state = mamba_run(state, first, before)
-            state = layer("attention", state, p)
-            return mamba_run(state, first + before, after), None
+        def joined(runs):
+            """The counts of runs of layers, each a tuple of (layers, ...)
+            arrays (of none without a routed FFN), as one run's."""
+            return tuple(jnp.concatenate(parts) for parts in zip(
+                *(run for run in runs if run is not None)))
 
-        return jax.lax.scan(period, (x, cache),
-                            jnp.arange(periods, dtype=jnp.int32))[0]
+        def period(state, p, lead=0):
+            """Period ``p`` from its state layer ``lead`` on."""
+            first, routed = p * (before + after), p * (before + after + 1) \
+                - dense
+            state, s0 = state_run(state, first + lead, before - lead,
+                                  routed + lead)
+            state, s1 = layer("attention", state, p, routed + before)
+            state, s2 = state_run(state, first + before, after,
+                                  routed + before + 1)
+            return state, joined([s0, tuple(s[None] for s in s1), s2])
+
+        state, stats = (x, cache), []
+        first_period = 0
+        if dense:
+            # the leading layers with a plain FFN, then the rest of their
+            # period: what the scan over the periods cannot hold
+            (x, cache, _, _), _ = self.dense_blocks(
+                (x, cache, start, jnp.zeros((), jnp.int32)), decode,
+                deterministic)
+            state, s = period((x, cache), 0, lead=dense)
+            stats.append(s)
+            first_period = 1
+        if periods > first_period:
+            state, s = jax.lax.scan(
+                period, state,
+                jnp.arange(first_period, periods, dtype=jnp.int32))
+            stats.append(tuple(a.reshape((-1,) + a.shape[2:]) for a in s))
+        return state, joined(stats)
 
     def _transform(self, input_ids, positions, decode, deterministic,
                    head=True, paged_table=None, state_rows=None,
@@ -2365,10 +2701,10 @@ class TransformerLM(nn.Module):
                 # writeback; see _ScanBlock)
                 cache = dict(cache, **(paged_table if isinstance(
                     paged_table, dict) else {"table": paged_table}))
-            if cfg.retention or cfg.mamba:
+            if cfg.retention or cfg.hybrid:
                 # which cache row each batch entry is and where its real
                 # tokens end ride beside the state (PowerRetention,
-                # Mamba2Mixer)
+                # Mamba2Mixer, KDAMixer)
                 if state_rows is not None:
                     cache["rows"] = jnp.asarray(state_rows, jnp.int32)
                 if valid_len is not None:
@@ -2381,12 +2717,13 @@ class TransformerLM(nn.Module):
                 # and a mixer takes the two apart (``_by_row_group``)
                 cache["chunk"] = chunk
             carry = (x, cache, start, jnp.zeros((), jnp.int32))
-            if cfg.first_k_dense:
-                carry, _ = self.dense_blocks(carry, decode, deterministic)
-            if cfg.mamba:
+            if cfg.hybrid:
                 (x, cache), stats = self._hybrid_layers(
-                    carry, decode, deterministic), ()
+                    carry, decode, deterministic, more)
             else:
+                if cfg.first_k_dense:
+                    carry, _ = self.dense_blocks(carry, decode,
+                                                 deterministic)
                 (x, cache, _, _), stats = self.blocks(
                     carry, decode, deterministic, *more)
             if stats and self.is_mutable_collection("stats"):
@@ -2394,8 +2731,9 @@ class TransformerLM(nn.Module):
                 # the routed FFN counted in this call, over its layers
                 from ..moe.routed_ffn import call_stats
 
-                self.sow("stats", "moe", call_stats(stats[0], cfg.n_experts),
-                         init_fn=lambda: None, reduce_fn=lambda _, new: new)
+                self.sow("stats", "moe", call_stats(
+                    stats[0], cfg.experts_held or cfg.n_experts),
+                    init_fn=lambda: None, reduce_fn=lambda _, new: new)
             cache = {key: val for key, val in cache.items()
                      if not key.startswith("table")
                      and key not in STATE_ROW_KEYS + ("chunk",)}
@@ -2407,14 +2745,15 @@ class TransformerLM(nn.Module):
         else:
             carry = (x, None, jnp.zeros((), jnp.int32),
                      jnp.zeros((), jnp.int32))
-            if cfg.first_k_dense:
+            if cfg.first_k_dense and not cfg.hybrid:
                 # (a scan without a cache counts layers only where its
                 # configuration reads the counter: set it)
                 (x, *_), _ = self.dense_blocks(carry, decode, deterministic)
                 carry = (x, None, carry[2],
                          jnp.full((), cfg.first_k_dense, jnp.int32))
-            if cfg.mamba:
-                x, _ = self._hybrid_layers(carry, decode, deterministic)
+            if cfg.hybrid:
+                (x, _), _ = self._hybrid_layers(carry, decode, deterministic,
+                                                more)
             else:
                 (x, _, _, _), _ = self.blocks(carry, decode, deterministic,
                                               *more)

@@ -105,27 +105,41 @@ class RowGroups(NamedTuple):
     counts: jax.Array       # (E,) assignments an expert
 
 
-def group_rows(experts: jax.Array, num_experts: int, tile: int = ROW_TILE
-               ) -> RowGroups:
+def group_rows(experts: jax.Array, num_experts: int, tile: int = ROW_TILE,
+               held: Optional[int] = None) -> RowGroups:
     """Lay the ``N * k`` assignments out expert by expert, each expert's
     rows padded to whole tiles of ``tile``. ``ceil(N k / tile) + E`` tiles
     bound the layout whatever the router does (one expert taking every
-    token fills ``N k / tile`` of them; every expert taking one row, ``E``)."""
+    token fills ``N k / tile`` of them; every expert taking one row, ``E``).
+
+    ``held``: experts ``[0, held)`` of ``num_experts`` are here
+    (:func:`routed_ffn`). An assignment to any other makes no row: the
+    layout is over ``held`` experts, an unheld assignment is counted
+    nowhere, sorts behind the last row and its ``row_of`` is the layout's
+    end, which no tile holds."""
     N, k = experts.shape
     A = N * k
     flat = experts.reshape(A)
+    drop = {}       # (how an update out of range is said to be dropped)
+    if held is not None:
+        num_experts, drop = held, {"mode": "drop"}
+        flat = jnp.where(flat < held, flat, held)   # (behind every held one)
     tiles = -(-A // tile) + num_experts
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    counts = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    counts = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1, **drop)
     padded = -(-counts // tile) * tile
     ends_p = jnp.cumsum(padded)
     begin, begin_p = jnp.cumsum(counts) - counts, ends_p - padded
     sorted_e = flat[order]
     rank = jnp.arange(A, dtype=jnp.int32)
     row_sorted = begin_p[sorted_e] + rank - begin[sorted_e]
+    if held is not None:
+        # (the gathers above read an unheld assignment's place off the
+        # last held expert: name it here)
+        row_sorted = jnp.where(sorted_e < held, row_sorted, tiles * tile)
     row_of = jnp.zeros((A,), jnp.int32).at[order].set(row_sorted)
     token_of = jnp.zeros((tiles * tile,), jnp.int32).at[row_sorted].set(
-        order // k)
+        order // k, **drop)
     first_row = jnp.arange(tiles, dtype=jnp.int32) * tile
     tile_expert = jnp.minimum(
         jnp.searchsorted(ends_p, first_row, side="right"),
@@ -186,10 +200,21 @@ def routed_ffn(h: jax.Array, router: jax.Array, gate: jax.Array,
     ``up`` (L, E, C, F) and ``down`` (L, E, F, C). ``stats`` =
     (assignments, experts that a row chose, rows of the fullest expert)
     and, under sigmoid scoring (``scoring``: :func:`route`'s ``scoring``,
-    ``bias``, ``scaling``), a fourth: assignments the bias moved."""
+    ``bias``, ``scaling``), a fourth: assignments the bias moved.
+
+    **Experts held.** The leaves may hold fewer experts than the router
+    is wide (``E < router.shape[1]``): experts ``[0, E)``, one chip's share
+    of a layer that several chips divide. The router and the top-k run
+    over all of them; only assignments to held experts are laid out (the
+    others make no row, no tile and no read), and ``y`` is the held
+    experts' part of the sum, which is what goes on. ``stats`` then count
+    what the kernels ran (assignments, experts and the fullest expert
+    among the HELD), always carry the fourth (0 under softmax) and a
+    fifth: all ``N k`` assignments the router made."""
     E = gate.shape[1]
+    held = E if E < router.shape[1] else None
     w, experts, *moved = route(h, router, k, norm_topk_prob, **scoring)
-    groups = group_rows(experts, E, tile)
+    groups = group_rows(experts, router.shape[1], tile, held)
     dtype = gate.dtype
     x = h.astype(dtype)[groups.token_of]                    # (rows, C)
     act = _expert_call(_gate_up_kernel, "moe_gate_up", x, (gate, up), layer,
@@ -197,6 +222,19 @@ def routed_ffn(h: jax.Array, router: jax.Array, gate: jax.Array,
     y = _expert_call(_down_kernel, "moe_down", act, (down,), layer, groups,
                      down.shape[3], jnp.float32, tile)
     # each token gathers the rows of its k choices: no scatter, one order
+    if held is not None:
+        # an unheld assignment's row is past the layout: it adds nothing
+        # (a select: rows no tile wrote are whatever the buffer held)
+        there = (experts < held)[..., None]
+        y = jnp.sum(jnp.where(
+            there, y.at[groups.row_of].get(mode="clip") * w[..., None], 0.0),
+            axis=1)
+        stats = jnp.stack([jnp.sum(groups.counts),
+                           jnp.sum(groups.counts > 0, dtype=jnp.int32),
+                           jnp.max(groups.counts),
+                           *(moved or [jnp.zeros((), jnp.int32)]),
+                           jnp.asarray(experts.size, jnp.int32)])
+        return y.astype(h.dtype), stats
     y = jnp.sum(y[groups.row_of] * w[..., None], axis=1)
     stats = jnp.stack([jnp.asarray(experts.size, jnp.int32),
                        jnp.sum(groups.counts > 0, dtype=jnp.int32),
@@ -206,7 +244,7 @@ def routed_ffn(h: jax.Array, router: jax.Array, gate: jax.Array,
 
 #: what :func:`call_stats` holds, in order
 CALL_STATS = ("assignments", "experts_touched", "layer_calls", "load_max",
-              "load_max_over_mean", "bias_reordered")
+              "load_max_over_mean", "bias_reordered", "routed_assignments")
 
 
 def call_stats(layer_stats: jax.Array, n_experts: int) -> jax.Array:
@@ -215,7 +253,10 @@ def call_stats(layer_stats: jax.Array, n_experts: int) -> jax.Array:
     touched summed over the layers, the layers, the rows of the fullest
     expert of any layer, those rows over a layer's mean rows an
     expert (1 is perfectly even) and, where the layers counted them
-    (sigmoid scoring), the assignments a bias moved."""
+    (sigmoid scoring), the assignments a bias moved; where a layer holds
+    a share of its experts (``n_experts``: those held), all of these are of
+    the held experts and a last one counts every assignment the router
+    made."""
     assignments, touched = jnp.sum(layer_stats[:, :2], axis=0)
     load_max = jnp.max(layer_stats[:, 2])
     calls = layer_stats.shape[0]
@@ -271,7 +312,7 @@ class ExpertLeaves(nn.Module):
     handed to a custom call is a copy."""
 
     n_layer: int
-    n_experts: int
+    n_experts: int      # the experts held here (all, or this chip's share)
     n_embd: int
     width: int
 
@@ -287,7 +328,10 @@ class ExpertLeaves(nn.Module):
 
 class RoutedFFN(nn.Module):
     """One layer's routed FFN inside the block: owns the router matrix
-    (C, E), takes the model's expert leaves and the layer's index."""
+    (C, E), takes the model's expert leaves and the layer's index. The
+    leaves may hold experts ``[0, held)`` of the ``E`` the router knows
+    (:func:`routed_ffn`): the shared FFN, which every chip computes alike,
+    is added to the held experts' part."""
 
     n_experts: int
     experts_per_token: int

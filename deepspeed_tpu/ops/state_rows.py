@@ -8,6 +8,8 @@ one layer and of the rows that run.
   bit for bit);
 * :func:`prefetch_operands`: the scalar-prefetch operands ``(layer, batch,
   row, fresh)`` a kernel finds its block by;
+* :func:`state_spec`, :func:`by_batch`: the block specs of the leaf and
+  of an operand a batch entry, found from those operands;
 * :func:`in_hbm`: the leaf pinned to HBM as operand and as result;
 * :func:`compiler_params`: two sequential grid axes and the raised VMEM
   limit."""
@@ -16,13 +18,14 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import backend
 from .attention.paged_attention import kept_first
 
-__all__ = ["work_list", "prefetch_operands", "in_hbm", "compiler_params",
-           "VMEM_LIMIT_BYTES"]
+__all__ = ["work_list", "prefetch_operands", "state_spec", "by_batch",
+           "in_hbm", "compiler_params", "VMEM_LIMIT_BYTES"]
 
 # a retention state's (d/2 + 2, d, d) float32 block in and one out,
 # double-buffered, is 17 MB at d = 128: over the v5e's default scoped limit
@@ -47,6 +50,27 @@ def prefetch_operands(layer, rows, fresh, s):
     return ((jnp.asarray(layer, jnp.int32).reshape(1), batch_of, row_of,
              jnp.asarray(fresh, jnp.int32)[batch_of]), total,
             (rows >= 0) & (rows < s.shape[1]))
+
+
+def state_spec(s, count: int):
+    """Block spec of the stacked leaf (L, R, n, ...): ``count`` of the
+    third dimension's entries (tiles, heads) of one (layer, row) a step,
+    the row from the work list, the block of ``count`` from the grid's
+    second axis."""
+    return pl.BlockSpec(
+        (1, 1, count) + s.shape[3:],
+        lambda w, j, layer, batch, row, *_: (layer[0], row[w], j)
+        + (0,) * (s.ndim - 3))
+
+
+def by_batch(block: tuple, stepped: bool):
+    """Block spec of an operand (B, ...): the batch entry of the step's
+    work item; ``stepped``: its second dimension follows the grid's second
+    axis (the step's block of tiles, its head)."""
+    rest = (0,) * (len(block) - 2)
+    return pl.BlockSpec(
+        block, lambda w, j, layer, batch, *_: (
+            batch[w], j if stepped else 0) + rest)
 
 
 def in_hbm(s):

@@ -66,7 +66,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import backend
-from .state_rows import compiler_params, in_hbm, prefetch_operands
+from .state_rows import (by_batch as _by_batch, compiler_params, in_hbm,
+                         prefetch_operands, state_spec as _state_spec)
 
 __all__ = ["state_shape", "to_tiles", "from_tiles", "causal_conv",
            "gated_norm", "ssm_recurrence",
@@ -117,7 +118,8 @@ def causal_conv(xbc, tail, w, b, valid):
     """The causal depthwise convolution with its bias and the silu, after a
     carried tail. ``xbc`` (B, T, C), ``tail`` (B, K - 1, C): the K - 1
     inputs before the first token (zeros before a sequence), ``w`` (K, C),
-    ``b`` (C,), ``valid`` (B,): how many of the T tokens are real. Returns
+    ``b`` (C,) or None (no bias), ``valid`` (B,): how many of the T tokens
+    are real. Returns
     ``(silu(conv) (B, T, C) float32, tail')``: the last K - 1 inputs up to
     the last REAL token, which is what the next call continues from
     (padding shifts nothing in)."""
@@ -125,8 +127,10 @@ def causal_conv(xbc, tail, w, b, valid):
         K, T = w.shape[0], xbc.shape[1]
         f32 = jnp.float32
         seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-        out = b.astype(f32) + sum(
+        out = sum(
             w[j].astype(f32) * seq[:, j:j + T].astype(f32) for j in range(K))
+        if b is not None:
+            out = b.astype(f32) + out
         tail = jax.vmap(lambda sq, n: jax.lax.dynamic_slice_in_dim(
             sq, n, K - 1, 0))(seq, jnp.asarray(valid, jnp.int32))
         return jax.nn.silu(out), tail
@@ -210,24 +214,6 @@ def ssm_sequence(x, dt, a, b, c, block: int = CHUNK):
 # ---------------------------------------------------------------------------
 # the kernels
 # ---------------------------------------------------------------------------
-def _by_batch(block: tuple, tiled: bool):
-    """Block spec of an operand (B, ...): the batch entry of the step's
-    work item; ``tiled``: its second dimension follows the step's block
-    of tiles."""
-    rest = (0,) * (len(block) - 2)
-    return pl.BlockSpec(
-        block, lambda w, j, layer, batch, *_: (batch[w], j if tiled else 0)
-        + rest)
-
-
-def _state_spec(s, tiles: int):
-    """Block spec of the stacked leaf (L, R, tiles, N, lanes): ``tiles``
-    tiles of one (layer, row) a step, the row from the work list."""
-    return pl.BlockSpec(
-        (1, 1, tiles) + s.shape[3:],
-        lambda w, j, layer, batch, row, *_: (layer[0], row[w], j, 0, 0))
-
-
 def _head_rows(v, d_head: int, tiles: int):
     """A head's scalar (..., H) on each of its lanes: (..., tiles, lanes)."""
     v = jnp.repeat(v, d_head, axis=-1)

@@ -340,9 +340,14 @@ class ServingEngine:
         # of a token's one latent row over the layers (KVCacheSpec.latent),
         # for the counters; 0 for K/V a head
         self._state_row_bytes = int(getattr(spec, "state_bytes_per_row", 0))
-        self._ssm = "ssm" in getattr(spec, "kinds", ())
+        # (a state group's prefill runs its real tokens through the chunk
+        # form of its kind's scan: the span attribute that counts them)
+        self._chunk_tokens_key = next(
+            (f"{kind}_chunk_tokens" for kind in ("ssm", "kda")
+             if kind in getattr(spec, "kinds", ())), None)
         self._latent_token_bytes = int(getattr(spec, "latent", 0)) \
-            * np.dtype(spec.dtype).itemsize * int(spec.n_layer)
+            * np.dtype(spec.dtype).itemsize \
+            * int(getattr(spec, "kv_layers", spec.n_layer))
         if paged_kv:
             knobs = dict(paged_kv) if isinstance(paged_kv, dict) else {}
             page_size = knobs.pop("page_size", None)
@@ -1341,9 +1346,11 @@ class ServingEngine:
                             else col.sum())
                 for name, col in zip(CALL_STATS, calls.T)}
         reg = self.registry
-        for name in ("assignments", "experts_touched", "layer_calls"):
-            reg.counter(f"serving/moe_{name}").inc(step[name])
-            step[name] = int(step[name])
+        for name in ("assignments", "experts_touched", "layer_calls",
+                     "routed_assignments"):
+            if name in step:    # (the last: a layer that holds a share)
+                reg.counter(f"serving/moe_{name}").inc(step[name])
+                step[name] = int(step[name])
         reg.gauge("serving/moe_load_max").set(step["load_max"])
         attrs.update({f"moe_{name}": val for name, val in step.items()})
 
@@ -1354,17 +1361,19 @@ class ServingEngine:
         step's span gathers them, with the bytes they stand for over the
         layers (a row's state read once and written once). ``tokens``, a
         prefill dispatch's REAL tokens (the host's own positions): a model
-        of state-space layers runs them through the chunk form
-        (``ssm_chunk_tokens`` on the span, summed on the step's)."""
+        of state-space or KDA layers runs them through the chunk form
+        (``ssm_chunk_tokens`` / ``kda_chunk_tokens`` on the span, summed on
+        the step's)."""
         if not self._state_row_bytes:
             return
         sp.set(state_rows=rows)
         d = self._dispatched
         d["state_rows"] = d.get("state_rows", 0) + rows
         d["state_bytes"] = 2 * self._state_row_bytes * d["state_rows"]
-        if tokens and self._ssm:
-            sp.set(ssm_chunk_tokens=tokens)
-            d["ssm_chunk_tokens"] = d.get("ssm_chunk_tokens", 0) + tokens
+        key = self._chunk_tokens_key
+        if tokens and key:
+            sp.set(**{key: tokens})
+            d[key] = d.get(key, 0) + tokens
 
     def _note_latent(self, sp, read: int, written: int) -> None:
         """``latent_tokens_read`` / ``latent_rows_written`` on a dispatch's

@@ -355,6 +355,103 @@ def test_ssm_kernels_compile_for_a_described_v5e_at_the_served_shape(
             assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
 
 
+def test_kda_kernels_compile_for_a_described_v5e_at_the_served_shape(
+        compiled_kernels, described_v5e):
+    """The kernels PR 50 brought, through Mosaic at the widths of
+    ``perf/configs/kimi-linear-48b-a3b-ep8.json``: 128 rows' tokens through
+    ``kda_decode`` (one grid step a row with its 32 heads; the channels'
+    vectors as columns ONE lane wide of a (128, 128) block, which pass
+    Mosaic's tiling only here) and one row's 128-token chunk through
+    ``kda_chunk`` on the pool's stacked leaf (9 layers x 128 slots x 32
+    heads of (128, 128) float32, 2.4 GB). The leaf goes in and comes out in
+    one buffer: no operation of the program copies it."""
+    from deepspeed_tpu.ops import kda
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=described_v5e)
+
+    i32 = jnp.int32
+    L, R, H, K = 9, 128, 32, 128
+    leaf = shape((L, R, H, K, K))
+    with _compile_cache_off():
+        for name, fn, B, tokens in (("kda_decode", kda.kda_decode, R, ()),
+                                    ("kda_chunk", kda.kda_chunk, 1, (128,))):
+            vectors = shape((B,) + tokens + (H, K))
+            compiled = jax.jit(fn, donate_argnums=5).lower(
+                vectors, vectors, vectors, vectors,
+                shape((B,) + tokens + (H,)), leaf, shape((), i32),
+                shape((B,), i32), shape((B,), jnp.bool_)).compile()
+            text = compiled.as_text()
+            assert name in text and text.count("tpu_custom_call") == 1
+            assert "may-alias" in text
+            # the leaf is 2.4 GB: a copy or a slice of it would show
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+
+
+def test_a_kda_state_group_beside_latent_pages_compiles_with_no_copy_of_a_leaf(
+        compiled_kernels, described_v5e):
+    """PR 50. The one program of a step that carries a chunk beside running
+    slots, for a server of kda and latent attention layers at the published
+    widths of one period (``[kda (dense FFN), kda, kda, attention]``), 32
+    of 256 experts held, 128 slots, 8,192 pages of 128, compiled for a
+    described v5e. It holds each group's cache kernels a layer body (the
+    dense layer's, the state layers' scan's, the attention layer's) and
+    ONE routed FFN a routed body over both groups' rows; no leaf of the
+    pool and no stacked weight is copied (temporaries under 128 MB beside
+    1.7 GB of leaves and 0.9 GB of weights), the 32-wide ``b_proj`` and the
+    conv tail's rows written through the layer's slab among them."""
+    from deepspeed_tpu.inference.engine import pack_chunk_args
+    from deepspeed_tpu.parallel import mesh
+    from deepspeed_tpu.serving.paged_pool import PagedKVPool
+
+    model, engine = _zero_engine(
+        "kimi_linear", max_seq_len=8192, n_embd=2304, n_layer=4, n_head=32,
+        n_kv_head=32, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        layer_types=["kda", "kda", "kda", "attention"], kda_n_heads=32,
+        kda_d_head=128, ffn_dim=1024, n_experts=256, experts_held=32,
+        experts_per_token=8, routed_scaling_factor=2.446,
+        n_shared_experts=1, dense_ffn_dim=9216,
+        mlp_layer_types=["dense", "sparse", "sparse", "sparse"])
+    spec, slots, chunk = model.kv_cache_spec(), 128, 128
+    pool = PagedKVPool(spec, 2, num_pages=2, kernel="on", page_size=128,
+                       prefix_cache=False)
+    pool.bind_engine(engine)
+    assert pool.fuses(chunk)
+    cs = dict(jax.eval_shape(
+        lambda: spec.paged_cache(8192, 128, num_slots=slots)))
+    assert cs["s"].shape == (3, 128, 32, 128, 128)
+    assert cs["c"].shape == (1, 8192, 576, 128)
+    cs["index"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    cs["table"] = jax.ShapeDtypeStruct((slots, pool.pages_per_slot),
+                                       jnp.int32)
+    token = jnp.zeros((slots,), jnp.int32)
+    packed = jnp.asarray(pack_chunk_args(
+        np.zeros((1, chunk), np.int32), 0, chunk, chunk, chunk - 1,
+        np.zeros((pool.pages_per_slot,), np.int32)))
+    mesh.reset_mesh()       # (the engine's mesh is of this process's CPUs)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=described_v5e)
+
+    with _compile_cache_off():
+        compiled = pool._paged_chunk_decode_jit.lower(
+            *jax.tree_util.tree_map(
+                described, (engine.params, cs, packed, token, token))
+        ).compile()
+    text = compiled.as_text()
+    calls = re.findall(
+        r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*tpu_custom_call", text)
+    assert sorted(calls) == sorted(
+        ["kda_chunk", "kda_decode"] * 2
+        # (a chunk's 128 x 32 query-head rows are two calls of MAX_ROWS)
+        + ["paged_write"] * 2 + ["mla_chunk"] * 2 + ["mla_decode"]
+        + ["moe_gate_up", "moe_down"] * 2), calls
+    assert "may-alias" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27, \
+        compiled.memory_analysis()
+
+
 def test_a_state_group_beside_pages_compiles_with_no_copy_of_a_leaf(
         compiled_kernels, described_v5e):
     """The decode, the chunk and (PR 48) the chunk-beside-decode program of
@@ -811,6 +908,21 @@ def test_a_chunk_beside_decode_compiles_for_a_described_v5e_as_served(
     assert compiled.memory_analysis().temp_size_in_bytes \
         < min(leaf_bytes, 2 ** 27), compiled.memory_analysis()
     assert "may-alias" in text
+    # (PR 50) the routed FFN learned to hold a share of its experts; with
+    # every expert held, as these configurations have them, the program is
+    # the one PR 49 compiled, text for text (sha256 of _program_text, the
+    # first 16 digits; taken of commit 4378523 and of PR 50's tree alike)
+    import hashlib
+
+    pinned = {"mellum2-12b-a2b5-paged": "4a3978ddd2f56143",
+              "moonlight-16b-a3b-mla": "d12581f2ad0b359a"}
+    if config in pinned:
+        digest = hashlib.sha256(_program_text(compiled).encode()).hexdigest()
+        assert digest[:16] == pinned[config], (
+            "the compiled chunk-beside-decode program of an all-held "
+            "configuration is not the one this digest was taken of: "
+            "compare _program_text() of both trees, and pin the new digest "
+            "with the cell measured on both")
 
 
 def _program_text(compiled) -> str:

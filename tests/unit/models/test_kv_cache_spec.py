@@ -171,3 +171,47 @@ def test_mamba_layers_need_their_widths():
         n_layer=10, n_head=2, layer_types=GRANITE_PERIOD, mamba_n_heads=4,
         mamba_d_head=8, mamba_d_state=8)
     assert cfg.hybrid_period == (5, 4, 1) and cfg.mamba_channels == 48
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("rows", [
+    [3],                        # one entry: the scatter itself
+    [5, 0],                     # a bucket of two
+    [2, -1, 6, 7],              # one that does not run (-1)
+    [8, 1, 9, 4],               # out of [0, R) above: 8 is R, 9 past it
+    [7, 6, 5, 4, 3, 2, 1, 0],   # every row, as a decode step names them
+    [2, -1, 6, 8, 7, 9, -1, 0, 4, 1],   # the slab's form, with rows that
+                                        # do not run (-1, R, past R)
+    [-1, 8, -1],                # none runs: the leaf comes back as it was
+    [-1, 8, -1, 9, -1, 8, -1, 9],       # and in the slab's form
+])
+def test_a_state_layers_tail_write_is_the_scatter_it_replaced(rows, dtype):
+    """``_write_rows`` with ``_SLAB_FROM`` entries or more goes through the
+    layer's whole slab (a select and one update); until PR 50
+    ``Mamba2Mixer`` wrote its tail with ``leaf.at[layer, rows].set(values,
+    mode="drop")``. Same leaf, bit for bit, rows that do not run
+    included."""
+    import jax
+    import numpy as np
+
+    from deepspeed_tpu.models.transformer_lm import _SLAB_FROM, _write_rows
+
+    assert _SLAB_FROM == 8      # (the cases above stand on both sides of it)
+    L, R, W = 3, 8, 24
+    k1, k2 = jax.random.split(jax.random.PRNGKey(len(rows)))
+    leaf = jax.random.normal(k1, (L, R, W), jnp.float32).astype(dtype)
+    values = jax.random.normal(k2, (len(rows), W), jnp.float32)
+    rows = jnp.asarray(rows, jnp.int32)
+    for layer in (0, L - 1):
+        at = (layer, jnp.where((rows >= 0) & (rows < R), rows, R))
+        scatter = leaf.at[at].set(values.astype(dtype), mode="drop")
+        got = jax.jit(_write_rows)(leaf, jnp.int32(layer), rows, values)
+        assert got.dtype == leaf.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(scatter, np.float32))
+        ran = [int(r) for r in rows if 0 <= r < R]
+        untouched = np.ones((L, R), bool)
+        untouched[layer, ran] = False
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32)[untouched],
+            np.asarray(leaf, np.float32)[untouched])

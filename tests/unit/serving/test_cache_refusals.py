@@ -27,6 +27,10 @@ PRESETS = {
         TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
         layer_types=["mamba", "attention"] * 2, mamba_n_heads=4,
         mamba_d_head=8, mamba_d_state=8)),
+    "kda": ("granite-hybrid", dict(
+        TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
+        layer_types=["kda", "attention"] * 2, kda_n_heads=2,
+        kda_d_head=8)),
     "latent": ("moonlight", dict(
         TINY, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
         v_head_dim=8, scoring_func="softmax")),
@@ -157,5 +161,55 @@ def test_a_kind_with_no_row_constructs(engine_of):
                         paged_kv=_OFF)
     assert set(srv.pool.cache["cache_store"]) \
         == {"s", "conv", "k", "v", "index", "table"}
+    srv = ServingEngine(engine_of("kda"), num_slots=2, prefill_chunk=PAGE,
+                        paged_kv=_OFF)
+    assert set(srv.pool.cache["cache_store"]) \
+        == {"s", "conv", "k", "v", "index", "table"}
     with pytest.raises(KeyError):
         spec.refusal("no_such_feature")
+
+
+def test_a_kda_state_group_beside_latent_pages_refuses_in_the_groups_words():
+    """PR 50's pairing (kda layers, latent attention, a routed FFN that
+    holds a share): the first of its kinds that has a row answers, which is
+    the state group's for everything a state is in the way of, and it
+    serves on the page pool with the prefix cache off."""
+    cfg = transformer_config(
+        "kimi_linear", **dict(TINY, n_layer=8, kv_lora_rank=16,
+                              qk_nope_head_dim=8, qk_rope_head_dim=8,
+                              v_head_dim=8, ffn_dim=16, n_experts=8,
+                              experts_per_token=2, experts_held=2,
+                              dense_ffn_dim=48, kda_n_heads=2, kda_d_head=8),
+        layer_types=["kda", "kda", "kda", "attention"] * 2,
+        mlp_layer_types=["dense"] + ["sparse"] * 7)
+    assert cache_kinds(cfg) == ("kda", "latent", "routed")
+    spec = TransformerLM(cfg).kv_cache_spec()
+    for feature in ("spec_decode", "prefix_cache", "roles", "tensor_parallel",
+                    "tensor_parallel_serving", "zero_inference"):
+        said = spec.refusal(feature)
+        assert said.startswith(f"{FEATURES[feature]} does not compose with "
+                               f"a KDA state group yet: "), said
+        assert CACHE_REFUSALS["kda", feature] in said
+    assert spec.refusal("paged_kv") is None
+    cache = spec.paged_cache(4, PAGE, num_slots=2)
+    assert set(cache) == {"c", "s", "conv"}
+    # latent attention beside state layers, and without positions: what the
+    # configuration refused before; a window beside it is still refused
+    with pytest.raises(ValueError, match="no window and no retention"):
+        transformer_config(
+            "moonlight", **dict(TINY, n_layer=2, kv_lora_rank=16,
+                                qk_nope_head_dim=8, qk_rope_head_dim=8,
+                                v_head_dim=8, scoring_func="softmax"),
+            layer_types=["sliding_attention", "full_attention"],
+            sliding_window=WINDOW)
+    with pytest.raises(ValueError, match="ONE attention layer that repeats"):
+        transformer_config(
+            "kimi_linear", **dict(TINY, n_layer=3, kda_n_heads=2,
+                                  kda_d_head=8),
+            layer_types=["kda", "mamba", "attention"])
+    with pytest.raises(ValueError, match="head of the first period"):
+        transformer_config(
+            "kimi_linear", **dict(TINY, n_layer=4, ffn_dim=16, n_experts=4,
+                                  experts_per_token=2, dense_ffn_dim=48,
+                                  kda_n_heads=2, kda_d_head=8),
+            layer_types=["kda", "attention"] * 2, first_k_dense=2)

@@ -580,6 +580,90 @@ LAST_CHUNK_CALLS = 9
 
 
 @pytest.fixture(scope="module")
+def kimi_account():
+    """A server of kda and latent attention layers with a routed FFN that
+    holds 2 of its 8 experts (PR 50: a state group beside latent pages),
+    driven as ``hybrid_account`` is."""
+    from deepspeed_tpu.models.transformer_lm import transformer_config
+
+    model = TransformerLM(transformer_config(
+        "kimi_linear", **dict(TINY, n_layer=4, kv_lora_rank=16,
+                              qk_nope_head_dim=8, qk_rope_head_dim=8,
+                              v_head_dim=8, ffn_dim=16, n_experts=8,
+                              experts_per_token=2, experts_held=2,
+                              n_shared_experts=1, dense_ffn_dim=48,
+                              kda_n_heads=2, kda_d_head=8),
+        layer_types=["kda", "kda", "kda", "attention"],
+        mlp_layer_types=["dense", "sparse", "sparse", "sparse"]))
+    params = model.init({"params": jax.random.PRNGKey(1)},
+                        jnp.zeros((1, 8), jnp.int32),
+                        method=model.logits)["params"]
+    srv = ds.init_serving(model, model_parameters=params,
+                          config={"dtype": "float32"}, num_slots=4,
+                          max_queue_depth=8, prefill_chunk=8,
+                          prefill_token_budget=64,
+                          paged_kv={"kernel": "off", "prefix_cache": False})
+    rng = np.random.default_rng(11)
+    n0 = default_tracer().events_total
+    srv.submit(rng.integers(0, 64, size=20).astype(np.int32),
+               max_new_tokens=12)                  # chunks of 8, 8 and 4
+    for _ in range(4):
+        srv.step()
+    srv.submit(rng.integers(0, 64, size=7).astype(np.int32),
+               max_new_tokens=4)                   # alone: serving/admit
+    srv.run_until_drained(max_steps=60)
+    srv.check_invariants()
+    evs = _new_events(n0)
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    return {"pool": "paged", "srv": srv, "evs": evs, "steps": steps}
+
+
+def test_a_kda_state_group_beside_latent_pages_counts_what_it_ran(
+        kimi_account):
+    """``state_rows`` on every dispatch span and ``kda_chunk_tokens`` (REAL
+    tokens) on the prefill dispatches, as the mamba layers'
+    ``ssm_chunk_tokens`` and never both; ``latent_tokens_read`` over the
+    ONE latent layer's bytes; on ``serving/step`` the routed FFN's counts of
+    the HELD experts (what the kernels ran) beside every assignment the
+    router made, ``rows x k`` a routed layer a call. A plain decode step
+    makes the three device calls it makes beside any state group."""
+    srv, evs = kimi_account["srv"], kimi_account["evs"]
+    chunks = [e for e in evs if e["name"] == "serving/prefill_chunk"]
+    assert [e["args"]["kda_chunk_tokens"] for e in chunks] == [8, 8, 4]
+    admits = [e for e in evs if e["name"] == "serving/admit"]
+    assert [e["args"]["kda_chunk_tokens"] for e in admits] == [7]
+    assert not any("ssm_chunk_tokens" in (e.get("args") or {}) for e in evs)
+    decodes = [e for e in evs if e["name"] == "serving/decode"]
+    for e in decodes:
+        assert e["args"]["state_rows"] == e["args"]["live"]
+        assert e["args"]["latent_tokens_read"] > 0
+    spec = srv.pool.spec
+    assert spec.state_bytes_per_row == 3 * (2 * 8 * 8 * 4 + 3 * 48 * 4)
+    assert srv._latent_token_bytes == 1 * (16 + 8) * 4    # (one layer's)
+    counted = [s["args"] for s in kimi_account["steps"]
+               if s["args"].get("moe_layer_calls")]
+    assert counted
+    for args in counted:
+        calls = args["moe_layer_calls"]
+        assert calls % 3 == 0                   # three routed layers a call
+        assert 0 <= args["moe_assignments"] <= args["moe_routed_assignments"]
+        assert args["moe_experts_touched"] <= 2 * calls
+        assert isinstance(args["moe_routed_assignments"], int)
+    # a plain decode step of n rows: 3 layers x n x 2 assignments made
+    plain = _steps_with(kimi_account, "serving/decode", without=(
+        "serving/admit", "serving/prefill_batch", "serving/prefill_chunk"))
+    for step in plain:
+        if step["args"].get("moe_layer_calls") == 3:
+            assert step["args"]["moe_routed_assignments"] == 3 * 4 * 2
+    assert sum(a["moe_assignments"] for a in counted) \
+        < sum(a["moe_routed_assignments"] for a in counted)
+    assert srv.registry.counter("serving/moe_routed_assignments").value \
+        == sum(a["moe_routed_assignments"] for a in counted)
+    counts = [s["args"]["device_calls"] for s in plain]
+    assert min(counts) == DECODE_CALLS
+
+
+@pytest.fixture(scope="module")
 def beside_account(server_parts):
     """A server whose chunks read their pages in place (``kernel: "on"``),
     warmed the way the benchmark's harness warms one (a request a pass,
