@@ -2161,6 +2161,8 @@ class KVCacheSpec:
     # one row a token a layer that every head reads. Such a cache holds
     # ``c`` (L, B, latent, S) positions-minor and no k / v; a page pool's
     # leaf is (L, P, latent, lanes), a page one whole-tile block a layer
+    latent_rank: int = 0               # of which the values: the row's
+    # leading ``kv_lora_rank`` stored rows
     kinds: tuple = ()                  # cache_kinds(cfg): what refusal() reads
     state_group: Optional[tuple] = None    # the layers that keep a state a
     # row (no positions) and their leaves: ``(layers, ((leaf, shape a row
@@ -2407,6 +2409,7 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
                        dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
                        quantized=cfg.kv_cache_quant, packed=packed,
                        groups=kv_cache_groups(cfg), latent=cfg.latent,
+                       latent_rank=cfg.kv_lora_rank if cfg.latent else 0,
                        kinds=cache_kinds(cfg), state_group=group)
 
 

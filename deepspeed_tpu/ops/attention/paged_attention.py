@@ -89,7 +89,11 @@ live page. Several page operands a step (the table row cut in blocks) were
 slower with every operand added (3: 1.02 / 0.65 ms, 8: 1.15 / 0.65 ms), and
 Mosaic refuses the kernel's own ``make_async_copy`` of a 64-wide page
 ("Slice shape along dimension 3 must be aligned to tiling (128)"), so
-neither is here.
+neither is here. The copy form lives in ``latent_attention.py`` (PR 51),
+whose page is whole 128-lane tiles: blocks of pages by the kernel's own
+copies, a block's scores, statistics and values each in one run (there
+the page's three parts waiting on each other were the cost, not the
+step); it is the pattern a 128-wide K/V page would take.
 
 Parity contract (the "dense oracle" discipline): for each head the
 per-page fold is op-for-op the dense decode kernel's

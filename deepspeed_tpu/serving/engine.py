@@ -2566,15 +2566,17 @@ class ServingEngine:
 
     def _set_pool_reads(self, sp, rows: int, slots=None,
                         starts=None) -> None:
-        """``pool_reads`` / ``read_slots`` on a decode, verify or chunk
-        span whose dispatch read the pool through the kernel: the grid
-        steps of its work list for one layer of each page group, and the
-        slots in it (counted like ``pool_writes``: after the dispatch,
-        from the host's mirror). A chunk names its one slot and where it
-        starts."""
+        """``pool_reads`` / ``read_slots`` / ``pool_read_pages`` on a
+        decode, verify or chunk span whose dispatch read the pool through
+        the kernel: the grid steps of its work list for one layer of each
+        page group, the slots in it and the pages the steps fold (more
+        than the steps where a step is a block: the latent read; counted
+        like ``pool_writes``: after the dispatch, from the host's
+        mirror). A chunk names its one slot and where it starts."""
         work = self.pool.pages_read(rows, slots, starts)
         if work is not None:
-            sp.set(pool_reads=work[0], read_slots=work[1])
+            sp.set(pool_reads=work[0], read_slots=work[1],
+                   pool_read_pages=work[2])
 
     def _state_rows(self, running) -> tuple:
         """For a model with a recurrent state, the decode program's

@@ -920,13 +920,32 @@ class PagedKVPool(SlotPool):
         return self._paged_decode_kernel_jit is not None and (
             self.ring is None or count < self.ring.window)
 
+    def pages_a_read_step(self, count: int) -> int:
+        """The pages ONE grid step of the kernel read folds for a
+        dispatch of ``count`` query rows a slot: a block of the latent
+        read (``latent_attention.pages_a_step``, from the same shapes the
+        call gives it), one page of K/V."""
+        if not self.spec.latent:
+            return 1
+        from ..models.transformer_lm import page_lanes
+        from ..ops.attention.latent_attention import call_rows, pages_a_step
+
+        return pages_a_step(
+            call_rows(count, self.spec.kv_heads), self.spec.latent,
+            self.spec.latent_rank, page_lanes(self.page_size),
+            self.spec.dtype)
+
     def pages_read(self, count: int, slots=None, starts=None):
-        """``(steps, slots)`` of the kernel read's work list for a
+        """``(steps, slots, pages)`` of the kernel read's work list for a
         dispatch of ``count`` query rows a slot, from the host's mirror
         of the table and ``starts``: the grid steps of ONE layer of each
-        page group (``live_pages`` / ``_window_pages`` in NumPy), and how
-        many slots have a step at all (``pool_reads`` / ``read_slots`` on
-        the decode, verify and chunk spans). Every slot at its position
+        page group (``live_pages`` / ``_window_pages`` in NumPy; the
+        latent read's steps are blocks, ``page_blocks``), how many slots
+        have a step at all, and the pages the steps fold (``pool_reads``
+        / ``read_slots`` / ``pool_read_pages`` on the decode, verify and
+        chunk spans; pages over steps x :meth:`pages_a_read_step` is how
+        full a block is, and a step is a page where that is 1). Every
+        slot at its position
         for a decode or verify step; ``slots`` at ``starts`` for a chunk,
         which runs one. A slot whose row maps nothing is not in the list;
         ``num_slots - slots`` is how many steps a call does not make.
@@ -955,7 +974,10 @@ class PagedKVPool(SlotPool):
                             per_slot - 1)
             live = live + np.clip(np.minimum(seen, mapped) - first,
                                   np.minimum(mapped, 1), per_slot)
-        return int(live.sum()), int(np.count_nonzero(live))
+        # (a block is one page wherever there is a ring)
+        steps = -(-live // self.pages_a_read_step(count))
+        return int(steps.sum()), int(np.count_nonzero(live)), \
+            int(live.sum())
 
     def _write_runs(self, pool: dict, dense: dict, tables: dict, first,
                     count: int):

@@ -20,17 +20,17 @@ PS, PER_SLOT, P = 16, 8, 24
 ATOL = 2e-5
 
 
-def _case(seed, B, T, lengths):
+def _case(seed, B, T, lengths, per_slot=PER_SLOT, P=P):
     """Random ``c``, ``k_r`` rows in pages through a shuffled table, and
     queries ``q_n``, ``q_r``: ``lengths[b]`` cached positions before this
     step's ``T`` (whose rows are already in the pages)."""
     rng = np.random.default_rng(seed)
-    rows = rng.standard_normal((B, PER_SLOT * PS, W)).astype(np.float32)
+    rows = rng.standard_normal((B, per_slot * PS, W)).astype(np.float32)
     w_kvb = (rng.standard_normal((R, H, DN + DV)) / math.sqrt(R)
              ).astype(np.float32)
     q_n = rng.standard_normal((B, T, H, DN)).astype(np.float32)
     q_r = rng.standard_normal((B, T, H, DR)).astype(np.float32)
-    table = np.full((B, PER_SLOT), P, np.int32)
+    table = np.full((B, per_slot), P, np.int32)
     pages = rng.standard_normal((2, P, W, PS)).astype(np.float32)  # garbage
     free = list(rng.permutation(P))
     for b, n in enumerate(lengths):
@@ -91,6 +91,63 @@ def test_absorbed_equals_expanded(T, lengths):
             assert not np.asarray(ctx[b]).any()
         else:
             np.testing.assert_allclose(got[b], want[b], atol=ATOL)
+
+
+@pytest.mark.parametrize("T", [1, 3])
+@pytest.mark.parametrize("G", [1, 2, 4, 8])
+def test_a_step_of_several_pages_folds_them_as_one_page_a_step_does(
+        monkeypatch, G, T):
+    """Blocks of ``G`` pages a grid step against one page a step: the same
+    pages in the same order through the same update, so the same BITS in
+    interpret mode. A slot of exactly ``G`` pages (one whole block), of
+    ``G + 1`` with a partial last page and with a whole one (a block of
+    one page behind a whole block), of one partial page, and a slot that
+    maps nothing between two that do."""
+    from deepspeed_tpu.ops.attention import latent_attention as la
+
+    lengths = [G * PS - T, -1, G * PS + 5 - T, 3, (G + 1) * PS - T]
+    case = _case(G * 10 + T, len(lengths), T, lengths, per_slot=10, P=32)
+    rows, w_kvb, q_n, q_r, table, pages = case
+    before = pages.copy()
+    got = {}
+    for g in (G, 1):
+        monkeypatch.setattr(la, "pages_a_step", lambda *shapes, g=g: g)
+        got[g] = _absorbed(rows, w_kvb, q_n, q_r, table, pages,
+                           np.asarray(lengths))
+    np.testing.assert_array_equal(np.asarray(got[G][0]),
+                                  np.asarray(got[1][0]))
+    np.testing.assert_array_equal(pages, before)
+    want = _expanded(rows, w_kvb, q_n, q_r, lengths, T)
+    for b, n in enumerate(lengths):
+        if n < 0:
+            assert not np.asarray(got[G][0][b]).any()
+        else:
+            np.testing.assert_allclose(got[G][1][b], want[b], atol=ATOL)
+
+
+@pytest.mark.parametrize("G", [1, 2, 4, 8])
+def test_the_work_list_in_blocks_against_numpy(G):
+    """``page_blocks``: ``ceil(live / G)`` steps a slot, the slot's pages
+    in table order, none past ``live``, no step for a slot of none."""
+    from deepspeed_tpu.ops.attention.latent_attention import page_blocks
+
+    per_slot = 11
+    for live in ([0, 0, 0], [G, 0, G + 1, 1, per_slot, 0],
+                 list(np.random.default_rng(G).integers(0, per_slot + 1, 9))):
+        live = np.asarray(live, np.int32)
+        slot_of, entry_of, total = map(np.asarray, page_blocks(
+            jnp.asarray(live), G, per_slot))
+        assert len(slot_of) == len(entry_of) == len(live) * -(-per_slot // G)
+        assert total == np.sum(-(-live // G))
+        pages = [(b, e) for b, e0 in zip(slot_of[:total], entry_of[:total])
+                 for e in range(e0, min(e0 + G, live[b]))]
+        assert pages == [(b, e) for b, n in enumerate(live)
+                         for e in range(n)]
+        assert all(e % G == 0 for e in entry_of[:total])
+        # what never runs is in range all the same
+        assert slot_of.max(initial=0) < len(live)
+        assert 0 <= entry_of.min(initial=0) and \
+            entry_of.max(initial=0) < per_slot
 
 
 def test_more_rows_than_a_call_holds_go_in_several(monkeypatch):

@@ -66,7 +66,8 @@ def watch_kernel_reads(srv, device_steps):
     def pages_read(rows, slots=None, starts=None):
         work = count(rows, slots, starts)
         if work is not None and slots is None:     # (not a chunk's)
-            record.append((work, device_steps(rows), int(np.count_nonzero(
+            assert work[2] == work[0]      # a step of K/V is a page
+            record.append((work[:2], device_steps(rows), int(np.count_nonzero(
                 (pool.table != pool.num_pages).any(axis=1)))))
         return work
 

@@ -182,6 +182,53 @@ def test_the_dispatch_spans_count_latent_rows(stack):
     srv.cancel(hold.request_id)
 
 
+def test_the_read_spans_count_blocks_and_the_pages_in_them(stack):
+    """``pool_reads`` on a latent pool's decode and chunk spans is the
+    read's grid steps, which are BLOCKS of ``G`` pages (``G`` from the
+    dispatch's rows), and ``pool_read_pages`` the pages in them: both as
+    the device's own work list has them (``live_pages`` /
+    ``page_blocks``) at the dispatch, pages over steps x ``G`` the fill."""
+    from deepspeed_tpu.ops.attention.latent_attention import (
+        call_rows, page_blocks, pages_a_step)
+    from deepspeed_tpu.ops.attention.paged_attention import live_pages
+    from deepspeed_tpu.telemetry.tracer import Tracer
+
+    cfg, _, _, engine, ids = stack
+    tracer = Tracer()
+    srv = server(engine, "kernel", tracer=tracer)
+    pool, record = srv.pool, []
+    count = pool.pages_read
+
+    def pages_read(rows, slots=None, starts=None):
+        work = count(rows, slots, starts)
+        G = pages_a_step(call_rows(rows, cfg.n_head), cfg.latent,
+                         cfg.kv_lora_rank, 128, jnp.float32)
+        assert G == pool.pages_a_read_step(rows)
+        if slots is None:
+            slots, starts = np.arange(pool.num_slots), pool.positions()
+        live = live_pages(jnp.asarray(starts, jnp.int32),
+                          jnp.asarray(pool.table[np.asarray(slots)]), rows,
+                          16, pool.num_pages)[3]
+        blocks = int(page_blocks(live, G, pool.pages_per_slot)[2])
+        assert work == (blocks, int(np.count_nonzero(live)), int(live.sum()))
+        record.append((work, G))
+        return work
+
+    pool.pages_read = pages_read
+    drained(srv, [ids[1, :77], ids[0, :9]])
+    spans = [e["args"] for e in tracer.events()
+             if e.get("ph") == "X" and "pool_reads" in (e.get("args") or {})
+             and e["name"] in ("serving/decode", "serving/prefill_chunk")]
+    assert len(spans) == len(record) > 8
+    assert sorted((a["pool_reads"], a["read_slots"], a["pool_read_pages"])
+                  for a in spans) == sorted(work for work, _ in record)
+    for (steps, slots, pages), G in record:
+        assert slots <= steps <= pages <= steps * G
+    # a slot's several pages were one step, and a slot's pages two steps
+    assert any(steps < pages for (steps, _, pages), _ in record)
+    assert any(steps > slots for (steps, slots, _), _ in record)
+
+
 # ---------------------------------------------------------------------------
 # what does not compose yet refuses at construction, by mechanism
 # ---------------------------------------------------------------------------

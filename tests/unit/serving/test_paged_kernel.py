@@ -324,6 +324,7 @@ def test_chunked_prefill_through_the_pages_matches_the_dense_arm(stack, case):
     for args, (start, _, steps) in zip(spans(srv_on), on_calls):
         assert args["pos"] == start
         assert (args["pool_reads"], args["read_slots"]) == (steps, 1)
+        assert args["pool_read_pages"] == steps     # a step of K/V: a page
         assert args["pool_writes"] >= 1
     assert spans(srv_off) and not any(
         "pool_reads" in args or "read_slots" in args
@@ -390,7 +391,7 @@ def test_freed_slots_are_no_step_under_churn_with_the_finite_guard(
     assert len(spans) == len(record) > 10
     for args, ((reads, slots), total, seated) in zip(spans, record):
         assert (args["pool_reads"], args["read_slots"]) == (reads, slots)
-        assert reads == total and slots == seated
+        assert args["pool_read_pages"] == reads == total and slots == seated
     # slots stood freed beside decoding ones, and cost nothing
     assert any(0 < slots < 4 for (_, slots), _, _ in record)
     assert srv_off.pool.pages_read(1) is None

@@ -375,7 +375,7 @@ def test_a_chunk_reads_and_writes_both_groups_in_place(stack):
                     jnp.asarray([pos], jnp.int32),
                     jnp.asarray(table[slot])[None], chunk, PAGE, pages,
                     window)[4]) for table, pages, window in tables)
-                assert work == (steps, 1)
+                assert work == (steps, 1, steps)
             else:
                 assert work is None
             lg = pool.run_prefill_chunk(engine, ids, slot, pos, n, n - 1)
