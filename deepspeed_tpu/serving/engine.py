@@ -102,7 +102,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..telemetry import (FlightRecorder, MetricsRegistry, ProgramCostModel,
                          RecompileAfterWarmupError, RecompileWatchdog,
                          SLOTracker, TimelineStore, Tracer, default_tracer)
-from ..telemetry.tracer import NO_SPAN, _Span, gc_ns_total, watch_gc
+from ..telemetry.tracer import (NO_SPAN, _Span, _StepSpan, gc_ns_total,
+                                watch_gc)
 from ..utils.logging import log_dist
 from .metrics import ServingMetrics
 from .paged_pool import PagedKVPool, PagePoolExhausted
@@ -143,8 +144,11 @@ class _Enqueue(_Span):
     it, from the clock reads the span made: its time under ``enqueue``
     and, if it is the step's first and the previous step ended in a sync,
     ``exposed``: from the end of that sync to now, the host's serial
-    stretch between two steps' programs (the device idles through it only
-    where the step before was not left in flight)."""
+    stretch between two steps' programs. With the step run one ahead the
+    device works through that stretch on the step before; it is idle time
+    of the chip only on a step marked ``dry`` (:meth:`ServingEngine.
+    _enqueue`: the bundle in flight was ready before this program was
+    called), and on one with nothing in flight."""
 
     __slots__ = ("_srv",)
 
@@ -237,6 +241,14 @@ class _Bundle:
                  attrs: dict):
         self.step_id, self.pending = step_id, pending
         self.moe_stats, self.attrs = moe_stats, attrs
+
+    def ready(self) -> bool:
+        """Whether the device has finished the step: its last-queued
+        array is ready (the device runs a step's programs in order). No
+        wait and no device call."""
+        last = self.pending[-1][0][-1] if self.pending \
+            else self.moe_stats[-1]
+        return last.is_ready()
 
 
 class ServingEngine:
@@ -417,7 +429,9 @@ class ServingEngine:
             if priority else None
         # -- telemetry -------------------------------------------------
         # given none, the server records into the process-wide tracer,
-        # which is ON (~2 us a span, a dozen spans a step); an explicit
+        # which is ON (an event ~0.4 us over the ~1 us its span takes to
+        # time itself, 11 events a plain decode step; the ring holds
+        # 131,072: telemetry/tracer.py); an explicit
         # Tracer(enabled=False) silences the ring
         if tracer is True:
             tracer = Tracer()
@@ -685,8 +699,12 @@ class ServingEngine:
         # handed its list out
         self._finished: List[Request] = []
         # whether the step's first program was queued with the step
-        # before unsettled (None: it has queued none yet)
+        # before unsettled (None: it has queued none yet), and whether
+        # that step was done by then: the device had run dry
         self._ahead: Optional[bool] = None
+        self._dry = False
+        # a counter track's last recorded sample, by name
+        self._track_last: dict = {}
         self._next_id = 0
         self._ensure_watch()
         log_dist(f"ServingEngine: slots={num_slots} "
@@ -837,6 +855,7 @@ class ServingEngine:
         """Swap the tracer in post-construction (e.g. a traced replay on
         an already-warmed server)."""
         self.tracer = tracer
+        self._track_last = {}       # its counter tracks start afresh
         self.timelines.tracer = tracer
         self.watchdog.tracer = tracer
         if self.slo is not None:
@@ -903,7 +922,7 @@ class ServingEngine:
                 slo.observe_gap(wall)
             slo.on_step(self.step_id)
         if costs is not None:
-            costs.step_update(wall, tokens=tokens, tracer=self.tracer)
+            costs.step_update(wall, tokens=tokens)
             if self.step_id % costs.kv_every == 0:
                 costs.reconcile_kv(self.pool, monitor=self.metrics.monitor,
                                    step=self.step_id, tracer=self.tracer)
@@ -922,6 +941,10 @@ class ServingEngine:
             # serving/step opened, and the phases closed so far
             "wall_ms": (time.perf_counter_ns() - self._step_t0_ns) / 1e6,
             "phases_ms": {k: v / 1e6 for k, v in self._phase_ns.items()},
+            # whether the device had finished the step before when this
+            # one's first program was called: `exposed` was the chip's
+            # idle time on this step
+            "dry": int(self._dry),
             "dispatched": dict(self._dispatched),
             "live": len(self._slot_req),
             "pending": self.scheduler.pending,
@@ -1182,10 +1205,28 @@ class ServingEngine:
         if kind != "program":
             return NO_SPAN
         if self._ahead is None:     # the step's first program
-            self._ahead = self._in_flight is not None
+            bundle = self._in_flight
+            self._ahead = bundle is not None
+            # the step before is done already and its successor not yet
+            # called: the chip idles until this call lands. One
+            # non-blocking query of the bundle's last-queued array (a
+            # step with nothing in flight is dry by construction, and
+            # says so through `in_flight`)
+            self._dry = bundle is not None and bundle.ready()
         if not self.tracer.enabled:
             return NO_SPAN
         return _Enqueue(self, program)
+
+    def _track(self, name: str, **values) -> None:
+        """A counter track's sample, recorded when a level differs from
+        the track's last recorded one and every 256th step besides (so an
+        exported ring that has wrapped still shows the level): the
+        staircase Perfetto draws is the one a sample a step drew."""
+        if not self.tracer.enabled:
+            return
+        if self._track_last.get(name) != values or not self.step_id % 256:
+            self._track_last[name] = values
+            self.tracer.counter(name, **values)
 
     def _phase(self, phase: str, name: str, **attrs) -> _Phase:
         """``tracer.span(name, **attrs)`` whose time outside its enqueue
@@ -2313,15 +2354,15 @@ class ServingEngine:
         self._dispatched = {}
         phases = self._phase_ns = {}
         self._device_calls = 0
-        self._ahead = None
+        self._ahead, self._dry = None, False
         table_puts0 = self.pool.table_puts if self._paged else 0
         # from the previous step's sync on the host is on its own
         # (serving/enqueue closes the interval: `exposed`)
         self._exposed_from_ns, self._sync_end_ns = self._sync_end_ns, None
         gc_ns0 = gc_ns_total()
-        with tracer.span("serving/step", step=self.step_id,
-                         in_flight=int(self._in_flight is not None)
-                         ) as sp_step:
+        with _StepSpan(tracer, "serving/step", {
+                "step": self.step_id,
+                "in_flight": int(self._in_flight is not None)}) as sp_step:
             self._step_t0_ns = sp_step.t0_ns
             # boundary work first, outside the abort scope: expiring a
             # deadline or walking the load ladder touches no device
@@ -2331,8 +2372,8 @@ class ServingEngine:
                 self._update_load_state()
                 self._auto_preempt()
                 self._burn_preempt()
-            tracer.counter("serving/occupancy", live=self.live_count,
-                           pending=self.scheduler.pending)
+            self._track("serving/occupancy", live=self.live_count,
+                        pending=self.scheduler.pending)
             with tracer.span("serving/grant") as sp:
                 page_budget = self._grant_page_budget() if self._paged \
                     else None
@@ -2401,6 +2442,8 @@ class ServingEngine:
                 raise
             if self._ahead:
                 self.registry.counter("serving/steps_run_ahead").inc()
+            if self._dry:
+                self.registry.counter("serving/steps_device_dry").inc()
             # the SLO tracker times its own methods: its part of the
             # after-step is taken out again, so telemetry_overhead_s
             # (which adds slo.overhead_s) never counts it twice
@@ -2423,7 +2466,8 @@ class ServingEngine:
                 "enqueue", "prepare", "pages", "exposed") if k in phases}
             if gc_ns_total() > gc_ns0:
                 account["gc_ns"] = gc_ns_total() - gc_ns0
-            sp_step.set(tokens=self._tokens_emitted - tokens_at_entry,
+            sp_step.set(dry=int(self._dry),
+                        tokens=self._tokens_emitted - tokens_at_entry,
                         **self._dispatched, **account)
             if self._in_flight is not None:
                 # what only the settled step knows (the routed FFN's
@@ -2453,8 +2497,8 @@ class ServingEngine:
             self.registry.gauge("paging/pages_in_use").set(
                 float(self.pool.num_pages - free))
             self.registry.gauge("paging/refcounted_pages").set(float(shared))
-            tracer.counter("paging/pages", free=free,
-                           in_use=self.pool.num_pages - free, shared=shared)
+            self._track("paging/pages", free=free,
+                        in_use=self.pool.num_pages - free, shared=shared)
             if self.pool.ring is not None:
                 ring = self.pool.ring
                 self.registry.gauge("paging/pages_mapped_full").set(
@@ -2487,6 +2531,7 @@ class ServingEngine:
             tracer.instant("serving/step_overrun", wall_ms=wall * 1e3,
                            budget_ms=self.step_wall_budget_ms,
                            device_calls=self._device_calls,
+                           dry=int(self._dry),
                            phases_ms={k: v / 1e6
                                       for k, v in self._phase_ns.items()})
         return wall
@@ -2523,8 +2568,7 @@ class ServingEngine:
             # pages — not slots — are the scarce resource
             pending = max(pending, cfg.queue_pressured)
         moved = self._load.update(pending, p99, step=self.step_id)
-        self.tracer.counter("serving/load_state",
-                            level=int(self._load.state))
+        self._track("serving/load_state", level=int(self._load.state))
         if moved is not None:
             old, new = moved
             self.metrics.record_load_state(old, new)

@@ -69,8 +69,20 @@ DeepSpeed's observability stack, mapped feature-for-feature:
 What is on by default: :func:`default_tracer`, the process-wide
 :class:`Tracer`, enabled. Both engines record their step phases, request
 events and set-up (``setup/import``, ``setup/build``, one
-``setup/compile`` per compile) into it when no ``tracer=`` is passed; a
-span costs ~2.5 us. What a profiler session adds: every span is also a
+``setup/compile`` per compile) into it when no ``tracer=`` is passed. A
+span takes ~1 us to time itself and open its annotation, and its event in
+the ring ~0.4 us more (a flat record, no lock; ``events()`` builds the
+dicts when it is read). The ring holds 131,072 events: ~11,900 plain
+decode steps of a server (11 events each), a window of 30 s while a step
+takes 2.5 ms or more; ``dropped`` / ``events_total`` say when it wrapped
+(the gauges ``telemetry/tracer_dropped`` / ``telemetry/tracer_events_total``
+in a server's registry). A server's counter tracks
+(``serving/occupancy``, ``paging/pages``, ``serving/load_state``) take a
+sample when a level changes and at every 256th step. ``serving/step``
+carries ``dry`` = 1 where the step found the device done with the step
+before when it called its first program (``serving/steps_device_dry`` in
+the registry): only there is the step's ``exposed`` phase idle time of
+the chip. What a profiler session adds: every span is also a
 ``jax.profiler.TraceAnnotation``, so under ``jax.profiler.start_trace``
 (xprof, ``chip_smoke.py``, the benchmark's ``--trace 1``) the same spans
 lie in the xplane's ``/host:CPU`` plane on the profiler's clock, beside

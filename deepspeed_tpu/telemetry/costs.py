@@ -256,8 +256,7 @@ class ProgramCostModel:
         return cost
 
     # -- per-step gauges -----------------------------------------------
-    def step_update(self, wall_s: float, tokens: int = 0,
-                    tracer=None) -> None:
+    def step_update(self, wall_s: float, tokens: int = 0) -> None:
         """Fold the window's flops/bytes into gauges against ``wall_s``
         (the step's span duration). Called once per serving step."""
         f, b = self._win_flops, self._win_bytes
@@ -287,9 +286,6 @@ class ProgramCostModel:
             h[2].set(self.tokens_per_gflop)
             h[3].inc(f)
             h[4].inc(b)
-        if tracer is not None:
-            tracer.counter("telemetry/efficiency", mfu=self.mfu,
-                           bandwidth_util=self.bandwidth_util)
 
     # -- KV HBM reconciliation -----------------------------------------
     def reconcile_kv(self, pool, monitor=None, step: int = 0,

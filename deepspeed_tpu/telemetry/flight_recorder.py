@@ -84,7 +84,7 @@ class FlightRecorder:
         spans: List[Dict[str, Any]] = []
         if tracer is not None and getattr(tracer, "enabled", False):
             try:
-                spans = tracer.events()[-self.last_spans:]
+                spans = tracer.tail(self.last_spans)
             except Exception:
                 pass
         return {

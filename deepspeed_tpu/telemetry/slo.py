@@ -27,7 +27,7 @@ SLO*, not raw throughput. The sensors for that live here:
   burn-rate-driven shedding/preemption consumes.
 
 Everything exports through the existing sinks: registry gauges (hence
-Prometheus), Perfetto counter tracks, and monitor events on alert
+Prometheus), and a tracer instant plus a monitor event on alert
 transitions.
 """
 
@@ -424,7 +424,10 @@ class SLOTracker:
 
     def on_step(self, step: int = 0) -> None:
         """Once per serving step: window rotation, burn-rate/alert
-        recompute, gauge + Perfetto counter export."""
+        recompute, gauges; a change of the alert leaves a
+        ``slo/alert_change`` instant in the tracer (the per-step Perfetto
+        counter samples went with PR 52: nothing read them, the gauges
+        carry the same levels)."""
         t0 = time.perf_counter_ns()
         self._steps_in_window += 1
         if self._steps_in_window >= self.config.window_steps:
@@ -451,10 +454,6 @@ class SLOTracker:
                 h[4].set(pc["ttft_p99_ms"])
                 h[5].set(pc["gap_p99_ms"])
                 h[6].set(pc["e2e_p99_ms"])
-        if self.tracer is not None:
-            self.tracer.counter("slo/goodput", goodput=gp,
-                                burn_short=self.burn_short)
-            self.tracer.counter("slo/alert", level=level)
         if self.alert_state != prev_state:
             if self.tracer is not None:
                 self.tracer.instant("slo/alert_change",

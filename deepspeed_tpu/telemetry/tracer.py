@@ -8,18 +8,43 @@ span has three sinks:
   benchmark's ``--trace 1``) the span lies in the xplane's ``/host:CPU``
   plane on the profiler's clock, beside the device's operations. With no
   session the annotation costs ~0.4 us;
-* an event in a bounded, lock-protected ring buffer on
-  ``time.perf_counter_ns`` (monotonic, ns resolution): the clock of
-  ``Request.*_time``, of :class:`TimelineStore` and of a harness that
-  reads ``time.perf_counter``. Each event notes under ``profiled``
-  whether a profiler session was active, so a reader can pick the
-  traced stretch;
+* an event in a bounded ring buffer on ``time.perf_counter_ns``
+  (monotonic, ns resolution): the clock of ``Request.*_time``, of
+  :class:`TimelineStore` and of a harness that reads
+  ``time.perf_counter``. Each event notes under ``profiled`` whether a
+  profiler session was active, so a reader can pick the traced stretch;
 * Chrome trace-event / Perfetto export of that ring.
 
-The tracer is deliberately dumb: every event is a small dict and
-nesting is never tracked explicitly — Chrome's trace viewer infers
-nesting of complete ("X") events from ts/dur containment per thread
-track, so a span stack on the host would only add overhead.
+The tracer is deliberately dumb: nesting is never tracked explicitly —
+Chrome's trace viewer infers nesting of complete ("X") events from
+ts/dur containment per thread track, so a span stack on the host would
+only add overhead.
+
+What an event costs (PR 52). The ring holds one flat tuple an event
+(:func:`_as_dict` names its fields), appended to a
+``collections.deque(maxlen=capacity)``: the append is atomic under the
+interpreter's lock, so the record path takes no lock, builds no dict,
+and shares one ``tid`` object a thread. The dicts that
+:meth:`Tracer.events`, :meth:`Tracer.to_chrome`, :func:`merge_chrome`
+and the flight recorder hand out are built when they are READ, key for
+key what the ring used to store; an event's ``args`` is the caller's own
+dict, held by reference, so what is written onto it after the span
+closed (the routed FFN's ``moe_*`` on a ``serving/step``, a step late)
+is in every later export. Measured on the chip's host (``PERF.md`` §6,
+PR 52): an event's ring part is 0.4 us inside a ``serving/step`` (which
+asks the profiler once for all its events, :class:`_StepSpan`) and
+0.5-0.7 us outside one, where the dict, the lock and the calls it
+replaces took 1.2; the span that times it (two clock reads and the
+annotation) takes ~1.2 us with the ring on or off. A record holds 172 B
+where the dict took 381 B before its ``args``: with the ``args`` of a
+server's plain decode step a full default ring of 131,072 holds 43.5 MB
+where the 65,536 dicts held 35.3 MB, 3.6 KB a step of history where it
+was 7.0. A plain decode step of a server leaves 11 events, so the
+default ring holds ~11,900 of them: a window of 30 s fits while a step
+takes 2.5 ms or more. A ring that wrapped says so: ``dropped`` here and
+in every export, ``events_total`` beside it (a server's registry carries
+both as the gauges ``telemetry/tracer_dropped`` /
+``telemetry/tracer_events_total``).
 
 :func:`default_tracer` is the process-wide tracer, ENABLED: engines use
 it when no ``tracer=`` is passed. An explicit ``Tracer(enabled=False)``
@@ -55,9 +80,12 @@ from __future__ import annotations
 import contextlib
 import functools
 import gc
+import itertools
 import json
-import threading
 import time
+from collections import deque
+from threading import get_ident
+from time import perf_counter_ns
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 
@@ -74,6 +102,33 @@ _SETUP_CAPACITY = 4096
 def profiler_active() -> bool:
     """Whether a profiler session is recording TraceAnnotations now."""
     return _Annotation is not None and bool(_Annotation.is_enabled())
+
+
+def _as_dict(rec: tuple) -> Dict[str, Any]:
+    """The dict an event is read as: the keys of its kind, in the order
+    the ring stored them when it stored dicts. One event of the ring is a
+    flat record: ``(name, ph, ts, dur, tid, args, profiled)``, and ``cat``,
+    ``id`` after them on an async or flow event. ``dur`` is None on all but
+    a complete span; what a kind always carries (an instant's ``s``, a flow
+    finish's ``bp``) is added here."""
+    name, ph, ts, dur, tid, args, profiled = rec[:7]
+    if ph == "X":
+        return {"name": name, "ph": ph, "ts": ts, "dur": dur, "tid": tid,
+                "args": args, "profiled": profiled}
+    if ph == "C":
+        return {"name": name, "ph": ph, "ts": ts, "tid": tid,
+                "args": args, "profiled": profiled}
+    if ph == "i":
+        return {"name": name, "ph": ph, "ts": ts, "tid": tid, "s": "t",
+                "args": args, "profiled": profiled}
+    ev = {"name": name, "ph": ph, "cat": rec[7], "id": rec[8], "ts": ts,
+          "tid": tid}
+    if ph == "f":
+        ev["bp"] = "e"  # bind to enclosing slice
+    elif ph != "s":     # (a flow carries no args)
+        ev["args"] = args
+    ev["profiled"] = profiled
+    return ev
 
 
 class _Span:
@@ -103,11 +158,11 @@ class _Span:
         if _Annotation is not None:
             self._ann = _Annotation(self.name)
             self._ann.__enter__()
-        self.t0_ns = time.perf_counter_ns()
+        self.t0_ns = perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self.dur_ns = time.perf_counter_ns() - self.t0_ns
+        self.dur_ns = perf_counter_ns() - self.t0_ns
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         if self._tracer.enabled:
@@ -115,12 +170,29 @@ class _Span:
             if exc_type is not None:
                 args = dict(args) if args else {}
                 args["error"] = exc_type.__name__
-            self._tracer._record({
-                "name": self.name, "ph": "X", "ts": self.t0_ns,
-                "dur": self.dur_ns,
-                "tid": threading.get_ident(), "args": args,
-            })
+            self._tracer._record(self.name, "X", self.t0_ns, self.dur_ns,
+                                 args)
         return False
+
+
+class _StepSpan(_Span):
+    """A span that many events close inside (``serving/step``): it asks
+    ONCE, at its opening, whether a profiler session is recording, and the
+    tracer marks every event up to its close with that answer instead of
+    asking an event. A tracer used outside such a span keeps asking."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        if self._tracer.enabled:
+            self._tracer._profiled = profiler_active()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        try:
+            return super().__exit__(exc_type, exc, tb)
+        finally:
+            self._tracer._profiled = None
 
 
 class Tracer:
@@ -128,43 +200,61 @@ class Tracer:
 
     ``capacity`` bounds host memory: once full, the oldest events are
     overwritten (ring buffer). ``events_total`` keeps counting, so
-    ``events_total > capacity`` tells you the window wrapped.
+    ``events_total > capacity`` (``dropped`` > 0) tells you the window
+    wrapped. The default holds ~11,900 plain decode steps of a server
+    (11 events each): 30 s of steps of 2.5 ms.
     """
 
-    def __init__(self, capacity: int = 65536, enabled: bool = True,
+    def __init__(self, capacity: int = 131072, enabled: bool = True,
                  process_name: str = "deepspeed_tpu"):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity
         self.enabled = enabled
         self.process_name = process_name
-        self._lock = threading.Lock()
-        self._buf: List[Dict[str, Any]] = []
-        self._pos = 0  # next overwrite index once the buffer is full
-        self._setup: List[Dict[str, Any]] = []  # setup/* events, kept
-        self.events_total = 0
+        # flat records (_as_dict), oldest first; the append is atomic
+        # under the interpreter's lock, so recording takes none
+        self._ring: "deque[tuple]" = deque(maxlen=capacity)
+        self._setup: List[tuple] = []   # setup/* events, kept
+        # thread ident -> [the one int object its events share, how many
+        # it has recorded]: each thread writes its own entry alone
+        self._threads: Dict[int, list] = {}
+        # what a step's owner knows of the profiler for the step's events
+        # (ServingEngine.step asks once a step); None: ask an event
+        self._profiled: Optional[bool] = None
         # wall-clock anchor so exports can be correlated across files
-        self.epoch_ns = time.perf_counter_ns()
+        self.epoch_ns = perf_counter_ns()
         self.epoch_unix = time.time()
 
     # ------------------------------------------------------------------
     # recording
     # ------------------------------------------------------------------
-    def _record(self, ev: Dict[str, Any]) -> None:
+    def _record(self, name: str, ph: str, ts: int, dur: Optional[int],
+                args: Optional[Dict[str, Any]], *ids) -> None:
         if not self.enabled:
             return
-        ev["profiled"] = profiler_active()
-        with self._lock:
-            if ev["name"].startswith(SETUP_PREFIX):
-                if len(self._setup) < _SETUP_CAPACITY:
-                    self._setup.append(ev)
-                    return
-            if len(self._buf) < self.capacity:
-                self._buf.append(ev)
-            else:
-                self._buf[self._pos] = ev
-                self._pos = (self._pos + 1) % self.capacity
-            self.events_total += 1
+        profiled = self._profiled
+        if profiled is None:
+            profiled = profiler_active()
+        ident = get_ident()
+        mine = self._threads.get(ident)
+        if mine is None:
+            mine = self._threads.setdefault(ident, [ident, 0])
+        rec = (name, ph, ts, dur, mine[0], args, profiled)
+        if ids:
+            rec += ids
+        if name.startswith(SETUP_PREFIX) \
+                and len(self._setup) < _SETUP_CAPACITY:
+            self._setup.append(rec)
+            return
+        self._ring.append(rec)
+        mine[1] += 1
+
+    @property
+    def events_total(self) -> int:
+        """Events the ring has taken since it was made or cleared, the
+        overwritten ones included (the kept ``setup/*`` aside)."""
+        return sum(n for _, n in list(self._threads.values()))
 
     def span(self, name: str, **args):
         """Context manager timing a block: ``with tracer.span("x"): ...``"""
@@ -175,9 +265,7 @@ class Tracer:
         ``perf_counter_ns``): work a listener hears of only at its end,
         such as a compile, or that began before this module existed,
         such as the package's import."""
-        self._record({"name": name, "ph": "X", "ts": int(t0_ns),
-                      "dur": int(dur_ns), "tid": threading.get_ident(),
-                      "args": args or None})
+        self._record(name, "X", int(t0_ns), int(dur_ns), args or None)
 
     def trace(self, name: Optional[str] = None):
         """Decorator form of :meth:`span`."""
@@ -192,20 +280,13 @@ class Tracer:
         return deco
 
     def instant(self, name: str, **args) -> None:
-        if not self.enabled:
-            return
-        self._record({"name": name, "ph": "i",
-                      "ts": time.perf_counter_ns(),
-                      "tid": threading.get_ident(),
-                      "s": "t", "args": args or None})
+        if self.enabled:
+            self._record(name, "i", perf_counter_ns(), None, args or None)
 
     def counter(self, name: str, **values) -> None:
         """Counter track sample, e.g. ``counter("slots", live=3)``."""
-        if not self.enabled:
-            return
-        self._record({"name": name, "ph": "C",
-                      "ts": time.perf_counter_ns(),
-                      "tid": threading.get_ident(), "args": values})
+        if self.enabled:
+            self._record(name, "C", perf_counter_ns(), None, values)
 
     # --- async (per-request) tracks -----------------------------------
     def async_begin(self, cat: str, name: str, aid, **args) -> None:
@@ -219,22 +300,14 @@ class Tracer:
 
     def _async(self, ph: str, cat: str, name: str, aid,
                args: Dict[str, Any]) -> None:
-        if not self.enabled:
-            return
-        self._record({"name": name, "ph": ph, "cat": cat,
-                      "id": aid, "ts": time.perf_counter_ns(),
-                      "tid": threading.get_ident(),
-                      "args": args or None})
+        if self.enabled:
+            self._record(name, ph, perf_counter_ns(), None, args or None,
+                         cat, aid)
 
     def flow(self, ph: str, name: str, fid, cat: str = "flow") -> None:
         """Flow event: ``ph`` is ``"s"`` (start) or ``"f"`` (finish)."""
-        if not self.enabled:
-            return
-        ev = {"name": name, "ph": ph, "cat": cat, "id": fid,
-              "ts": time.perf_counter_ns(), "tid": threading.get_ident()}
-        if ph == "f":
-            ev["bp"] = "e"  # bind to enclosing slice
-        self._record(ev)
+        if self.enabled:
+            self._record(name, ph, perf_counter_ns(), None, None, cat, fid)
 
     # ------------------------------------------------------------------
     # inspection / export
@@ -244,18 +317,46 @@ class Tracer:
         """Events overwritten by ring wrap-around (never exported)."""
         return max(0, self.events_total - self.capacity)
 
+    def _snapshot(self, last: Optional[int] = None) -> List[tuple]:
+        """The ring's records oldest first, or its ``last`` newest. A
+        thread that appends meanwhile makes the deque's iterator raise:
+        read again."""
+        while True:
+            try:
+                if last is None:
+                    return list(self._ring)
+                return list(itertools.islice(reversed(self._ring),
+                                             last))[::-1]
+            except RuntimeError:
+                continue
+
     def events(self) -> List[Dict[str, Any]]:
         """Snapshot of the kept ``setup/*`` events, then of the buffered
-        events, each oldest first."""
-        with self._lock:
-            return self._setup + self._buf[self._pos:] + self._buf[:self._pos]
+        events, each oldest first. The dicts are built here; an event's
+        ``args`` is the recorder's own object. The collector is paused
+        meanwhile: none of a full ring's dicts is garbage, and a full
+        collection set off by the read would leave its ``host/gc`` span
+        in the ring being read."""
+        paused = gc.isenabled()
+        gc.disable()
+        try:
+            return [_as_dict(r) for r in itertools.chain(
+                list(self._setup), self._snapshot())]
+        finally:
+            if paused:
+                gc.enable()
+
+    def tail(self, n: int) -> List[Dict[str, Any]]:
+        """``events()[-n:]`` without reading the ring's other events."""
+        recs = self._snapshot(n) if n > 0 else []
+        if len(recs) < n:
+            recs = list(self._setup)[len(recs) - n:] + recs
+        return [_as_dict(r) for r in recs]
 
     def clear(self) -> None:
-        with self._lock:
-            self._buf = []
-            self._pos = 0
-            self._setup = []
-            self.events_total = 0
+        self._ring.clear()
+        self._setup = []
+        self._threads = {}
 
     def to_chrome(self) -> Dict[str, Any]:
         """Render the buffer as a Chrome trace-event JSON object.

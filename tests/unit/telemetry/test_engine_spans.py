@@ -31,8 +31,17 @@ def _new_events(n0):
     """Events the default tracer took since its ``events_total`` was n0
     (the ring is process-wide: other tests wrote before us)."""
     tr = default_tracer()
-    evs = [e for e in tr.events() if not e["name"].startswith("setup/")]
-    return evs[len(evs) - (tr.events_total - n0):]
+    # (the count and the events are read with the collector paused: a full
+    # collection between the two would leave its host/gc span in one only)
+    paused = gc.isenabled()
+    gc.disable()
+    try:
+        total, evs = tr.events_total, tr.events()
+    finally:
+        if paused:
+            gc.enable()
+    evs = [e for e in evs if not e["name"].startswith("setup/")]
+    return evs[len(evs) - (total - n0):]
 
 
 def _inside(step, ev):
@@ -221,6 +230,9 @@ def account(request, server_parts):
                           step_wall_budget_ms=1e-6)
     rng = np.random.default_rng(11)
     n0 = default_tracer().events_total
+    levels, track = [], srv._track
+    srv._track = lambda name, **values: (
+        levels.append((srv.step_id, name, values)), track(name, **values))
 
     def prompt(n):
         return rng.integers(0, 64, size=n).astype(np.int32)
@@ -246,7 +258,8 @@ def account(request, server_parts):
     evs = _new_events(n0)
     steps = [e for e in evs if e["name"] == "serving/step"]
     assert len(steps) == srv.step_id
-    return {"pool": request.param, "srv": srv, "evs": evs, "steps": steps}
+    return {"pool": request.param, "srv": srv, "evs": evs, "steps": steps,
+            "levels": levels}
 
 
 def _steps_with(account, *names, without=()):
@@ -758,16 +771,208 @@ def test_the_one_program_is_compiled_before_the_warm_up_ends(beside_account):
         np.asarray(srv.pool.cache["cache_store"]["table"]), srv.pool.table)
 
 
-def test_counter_tracks_sample_every_step(account):
-    """One sample a track a step, as before the account."""
+def test_counter_tracks_sample_every_step(account, server_parts):
+    """Since PR 52 a track takes a sample where a level CHANGED (and at
+    every 256th step besides): the staircase is the one a sample a step
+    drew, so the level a track shows at any step is the server's."""
     evs, paged = account["evs"], account["pool"] == "paged"
-    for name in ("serving/occupancy",) + (("paging/pages",) if paged
-                                          else ()):
+    names = ("serving/occupancy",) + (("paging/pages",) if paged else ())
+    assert {name for _, name, _ in account["levels"]} == set(names)
+    for name in names:
         samples = [e for e in evs if e["ph"] == "C" and e["name"] == name]
-        assert len(samples) == len(account["steps"])
+        true = [(step, values) for step, n, values in account["levels"]
+                if n == name]
+        # the server offered the track its level once a step
+        assert [step for step, _ in true] == \
+            [s["args"]["step"] for s in account["steps"]]
+        # a sample wherever the level differs from the one before it
+        moved = [values for k, (_, values) in enumerate(true)
+                 if k == 0 or values != true[k - 1][1]]
+        assert [e["args"] for e in samples] == moved
+        assert 1 < len(samples) < len(account["steps"])
+        # and the newest sample at or before a step's own is its level
+        at = 0
+        for step, (_, values) in zip(account["steps"], true):
+            while at + 1 < len(samples) and samples[at + 1]["ts"] \
+                    <= step["ts"] + step["dur"]:
+                at += 1
+            assert samples[at]["args"] == values
     # the level a track shows is the server's: the last sample is the end
     occupancy = [e for e in evs if e["name"] == "serving/occupancy"]
     assert occupancy[-1]["args"]["pending"] == 0
+    # an idle server's level never moves: one sample, then the 256th step's
+    srv = _serve(server_parts, tracer=Tracer())
+    for _ in range(3):
+        srv.step()
+    srv.step_id = 254
+    srv.step()
+    assert len(srv.tracer.events()) == 3 * 4 + 4 + 1
+    srv.step()
+    assert srv.step_id == 256
+    samples = [e for e in srv.tracer.events() if e["ph"] == "C"]
+    assert [e["args"] for e in samples] == [{"live": 0, "pending": 0}] * 2
+    # another tracer's tracks start afresh
+    srv.set_tracer(Tracer())
+    srv.step()
+    assert [e["name"] for e in srv.tracer.events() if e["ph"] == "C"] == \
+        ["serving/occupancy"]
+
+
+PLAIN_STEP_EVENTS = {"contiguous": 10, "paged": 11}     # (+ serving/pages)
+
+
+def test_a_plain_decode_step_leaves_eleven_events(account):
+    """``serving/step``, ``boundary``, ``grant``, ``pages`` on a paged pool,
+    ``decode``, ``sample``, two ``enqueue``, ``sync``, ``replay``,
+    ``after_step``: what the default ring is sized by (131,072 // 11 plain
+    steps). A counter track adds a sample only where its level moved."""
+    evs = account["evs"]
+    plain = [s for s in _steps_with(account, "serving/decode", without=(
+        "serving/admit", "serving/prefill_batch", "serving/prefill_chunk"))
+        if s["args"]["device_calls"] == DECODE_CALLS]
+    counts, sampled = [], []
+    for step in plain:
+        inside = [e for e in evs if step["ts"] <= e["ts"]
+                  and e["ts"] + e.get("dur", 0) <= step["ts"] + step["dur"]]
+        # (every step of this server overruns its tiny wall budget)
+        inside = [e for e in inside if e["name"] != "serving/step_overrun"]
+        if any(e["ph"] not in ("X", "C") for e in inside):
+            continue                # a request came or went: its events
+        if any(e["name"] == "host/gc" for e in inside):
+            continue
+        tracks = [e for e in inside if e["ph"] == "C"]
+        counts.append(len(inside) - len(tracks))
+        sampled.append(len(tracks))
+    assert counts and set(counts) == {PLAIN_STEP_EVENTS[account["pool"]]}
+    assert max(counts) <= 11 and sampled.count(0) > len(sampled) // 2
+    assert Tracer().capacity // 11 >= 11_000
+
+
+@pytest.fixture
+def ready(monkeypatch):
+    """A stand-in for the readiness of the bundle in flight."""
+    from deepspeed_tpu.serving import engine as serving_engine
+
+    answer = {"ready": True, "asked": 0}
+
+    def stand_in(bundle):
+        answer["asked"] += 1
+        return answer["ready"]
+
+    monkeypatch.setattr(serving_engine._Bundle, "ready", stand_in)
+    return answer
+
+
+@pytest.mark.parametrize("is_ready", [True, False])
+def test_a_step_that_found_the_device_dry_says_so(server_parts, ready,
+                                                  is_ready):
+    """``dry``: the bundle in flight was READY before the step's first
+    program was called, so the chip idles until that call lands. On
+    ``serving/step`` beside ``in_flight``, counted in the registry, in the
+    flight recorder's step and on ``serving/step_overrun``. One query a
+    step that queues a program with a bundle in flight; no device call."""
+    ready["ready"] = is_ready
+    srv = _serve(server_parts, tracer=Tracer(), step_wall_budget_ms=1e-6)
+    rng = np.random.default_rng(21)
+    srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
+               max_new_tokens=5)
+    srv.run_until_drained(max_steps=50)
+    evs = srv.tracer.events()
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    queued = [int(any(k in s["args"] for k in ("decode", "admit", "chunk")))
+              for s in steps]
+    ahead = [s["args"]["in_flight"] & q for s, q in zip(steps, queued)]
+    assert sum(ahead) >= 3 and ready["asked"] == sum(ahead)
+    want = [a if is_ready else 0 for a in ahead]
+    assert [s["args"]["dry"] for s in steps] == want
+    # the first step has nothing in flight: dry by construction, which
+    # `in_flight` 0 says; the last queues nothing
+    assert (steps[0]["args"]["in_flight"], want[0], want[-1]) == (0, 0, 0)
+    snap = srv.registry.snapshot()
+    assert snap.get("serving/steps_device_dry", 0) == sum(want)
+    assert snap["serving/steps_run_ahead"] == sum(ahead)
+    recorded = srv.debug_dump()["steps"]
+    assert [r["dry"] for r in recorded] == want
+    over = [e for e in evs if e["name"] == "serving/step_overrun"]
+    assert [e["args"]["dry"] for e in over] == want
+    # the query is no device call: a plain step's count is the pinned one
+    assert min(s["args"]["device_calls"] for s in steps
+               if "decode" in s["args"]) == DECODE_CALLS
+
+
+def test_with_the_ring_off_a_dry_step_is_still_counted(server_parts, ready):
+    srv = _serve(server_parts, tracer=Tracer(enabled=False))
+    rng = np.random.default_rng(22)
+    srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
+               max_new_tokens=5)
+    srv.run_until_drained(max_steps=50)
+    assert srv.tracer.events() == []
+    snap = srv.registry.snapshot()
+    assert snap["serving/steps_device_dry"] == ready["asked"] >= 3
+    assert snap["serving/steps_device_dry"] == snap["serving/steps_run_ahead"]
+    assert [r["dry"] for r in srv.debug_dump()["steps"]].count(1) == \
+        ready["asked"]
+
+
+def test_a_bundles_readiness_is_its_last_queued_arrays(account):
+    """The real query: ``jax.Array.is_ready`` of the array the step queued
+    last (a step's programs run in order), without a wait."""
+    from deepspeed_tpu.serving.engine import _Bundle
+
+    class _Arr:
+        def __init__(self, done):
+            self.done, self.asked = done, 0
+
+        def is_ready(self):
+            self.asked += 1
+            return self.done
+
+    first, last, stats = _Arr(True), _Arr(False), _Arr(True)
+    bundle = _Bundle(1, [([first], None), ([first, last], None)], [stats], {})
+    assert bundle.ready() is False
+    assert (first.asked, last.asked, stats.asked) == (0, 1, 0)
+    assert _Bundle(1, [], [stats], {}).ready() is True
+    done = jax.block_until_ready(jnp.ones(4) + 1)
+    assert _Bundle(1, [([done], None)], [], {}).ready() is True
+    # a real server's steps ask it and stay whole
+    steps, srv = account["steps"], account["srv"]
+    assert all(s["args"]["dry"] in (0, 1) for s in steps)
+    assert srv.registry.snapshot().get("serving/steps_device_dry", 0) == \
+        sum(s["args"]["dry"] for s in steps)
+    assert all(s["args"]["dry"] <= s["args"]["in_flight"] for s in steps)
+
+
+def test_every_event_of_a_step_under_a_profiler_session_says_so(
+        server_parts, monkeypatch):
+    """The step asks once, at its opening, whether a session is recording;
+    every event up to its close carries the answer (``perf/step_account.py``
+    finds the traced stretch by the ``serving/step`` events' ``profiled``)."""
+    from deepspeed_tpu.telemetry import tracer as tracer_mod
+
+    srv = _serve(server_parts, tracer=Tracer())
+    rng = np.random.default_rng(24)
+    srv.submit(rng.integers(0, 64, size=6).astype(np.int32),
+               max_new_tokens=4)
+    srv.step()
+    srv.step()
+    before = len(srv.tracer.events())
+    assert not any(e["profiled"] for e in srv.tracer.events())
+    asked = []
+    monkeypatch.setattr(tracer_mod, "profiler_active",
+                        lambda: asked.append(1) or True)
+    srv.step()
+    srv.step()
+    assert len(asked) == 2
+    monkeypatch.undo()
+    srv.run_until_drained(max_steps=50)
+    evs = srv.tracer.events()
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    traced = [s for s in steps if s["profiled"]]
+    assert [s["args"]["step"] for s in traced] == [3, 4]
+    for ev in evs[before:]:
+        inside = any(_inside(s, dict(ev, dur=ev.get("dur", 0)))
+                     for s in traced)
+        assert ev["profiled"] == inside, ev
 
 
 def test_with_the_ring_off_a_device_call_is_counted_and_nothing_else(
