@@ -66,6 +66,23 @@ scoped limit): every KV head when one page of each fits, else the largest
 divisor of ``KV`` that does, and the head-group axis comes back into the
 grid. No option selects any of this.
 
+**The order inside a step** (PR 53). The step's KV heads are independent
+of each other, and a head's update is a chain: scores (a product), the
+statistics (row maximum, two exponentials, row sum), values (a second
+product into the accumulator). Mosaic keeps the MXU's products in the
+order they are written, so written head after head every link waited for
+the one before, sixteen times a page at Pythia's shape. The kernel
+writes the same update a RUN of heads at a time: every head's scores,
+then the statistics head after head, then every head's values
+(``_paged_kernel.fold``). Each value's arithmetic is in the order it
+was, so the result is bit for bit the fold of one head after another
+(the parity contract below holds as it did; on the chip max |delta| 0.0
+against the kernel of PR 52 at every served shape). :func:`plan_grid`
+gives the run from the call's static shapes (a power of two, at most
+``kv_group``: the heads whose scores take a quarter of the vector
+registers, four while theirs take no more than half); a chunk of 512 or
+1,024 rows a KV head folds one head at a time, as before.
+
 **Query rows** (PR 33). The row count is a static shape like any other:
 one row a slot for a decode step, K + 1 for a verify step, and a prefill
 chunk's 64 or 128 rows of the one slot it runs, which until PR 33 took a
@@ -94,6 +111,23 @@ whose page is whole 128-lane tiles: blocks of pages by the kernel's own
 copies, a block's scores, statistics and values each in one run (there
 the page's three parts waiting on each other were the cost, not the
 step); it is the pattern a 128-wide K/V page would take.
+
+The same chain stood in this kernel's step, head after head (chip runs
+of PR 53, microseconds a FURTHER live page at the served shape, 12 to 64
+live pages): as it was 2.02; the scores' product, the statistics or the
+values' product removed 1.56 / 1.41 / 1.39; all three removed, the page
+still fetched 1.36 (the padded DMA of K and V, 1 MB); the fold kept and
+the page index held constant 1.94; neither 0.25. Any ONE link removed
+gave the DMA's time, so the order was the cost and not the work. In runs
+of 2 / 4 / 8 / 16 heads: 1.73 / 1.66 / 1.39 / 1.37-1.42, against the
+parent's 2.02 beside them; what is left is the padded DMA. Mellum's
+decode rows (64 a KV head, 4 heads, pages of 128) 0.97 -> 0.88 in runs
+of 4 (the statistics of 64 rows are most of its page: 0.56 without
+them), Granite's (32 rows, 8 heads of 64) 0.98 -> 0.88 in runs of 4 and
+1.29 in ONE run of 8; a 64-row chunk at Pythia's shape reads the same
+at every run (3.1-3.3: its statistics, 1.28 without them), and a chunk
+of 512 or 1,024 rows a KV head keeps the fold of one head after another
+(8.1 and 7.5 a page either way).
 
 Parity contract (the "dense oracle" discipline): for each head the
 per-page fold is op-for-op the dense decode kernel's
@@ -171,13 +205,18 @@ __all__ = ["paged_decode_attention", "paged_write_columns",
 # limit (16 MiB), so the compiler's own temporaries fit beside it and no
 # call needs a raised ``vmem_limit_bytes``
 VMEM_BUDGET_BYTES = 8 * 2 ** 20
+# a run of KV heads (plan_grid): the query rows whose scores fill a
+# quarter of the vector registers (16 of 64, a row of 128 lanes of fp32
+# an eighth of one), and the heads that go one to each of the chip's MXUs
+RUN_ROWS = 128
+RUN_HEADS = 4
 
 
 def plan_grid(B: int, H: int, KV: int, D: int, Dc: int, page_size: int,
               pages_per_slot: int, kv_dtype, q_dtype, quantized: bool,
               query_rows: int = 1):
-    """``(kv_group, pages_per_step, grid)`` for one call, from its static
-    shapes alone.
+    """``(kv_group, run, grid)`` for one call, from its static shapes
+    alone.
 
     One grid step holds one page of ``kv_group`` KV heads (K and V, and
     the scale rows of a quantized pool, each double-buffered by the
@@ -188,19 +227,39 @@ def plan_grid(B: int, H: int, KV: int, D: int, Dc: int, page_size: int,
     largest divisor of ``KV`` that does — the head-group axis then comes
     back into the grid — and 1 where not even one head fits (a call of
     more than one sublane tile of rows is then made in two halves by
-    :func:`paged_decode_attention`). ``pages_per_step`` is 1: on the v5e every further
-    page operand of a step cost more than the grid step it saved (3
-    pages a step: 1.02 ms against 0.82 ms a call at the served shape,
-    chip run of PR 24). ``grid`` is ``(KV // kv_group, B *
-    pages_per_slot)``, the second a bound: the call runs one step for
-    each entry of :func:`live_pages`, not for each table entry."""
+    :func:`paged_decode_attention`). A step is always ONE page (every
+    further page operand of a step cost more than the grid step it
+    saved: 3 pages a step 1.02 ms against 0.82 ms a call at the served
+    shape, chip run of PR 24).
+
+    ``run`` is how many of the step's KV heads the kernel folds at a
+    time (every head's scores, then the statistics, then every head's
+    values: the module docstring's "The order inside a step"): a power
+    of two, at most ``kv_group``; as many heads as :data:`RUN_ROWS`
+    ``// rows`` for the ``rows`` query rows of a KV head, so that a
+    run's scores take a quarter of the vector registers, but
+    :data:`RUN_HEADS` (one for each MXU) while those four's scores take
+    no more than half: all 16 heads of Pythia's decode rows (8 rows a
+    head), 4 of Mellum's and of Granite's (64 and 32 rows; Granite's 8
+    in one run read a third SLOWER than head after head on the chip),
+    4 of a 64-row chunk, and 1, the fold of one head after another,
+    from 128 rows a KV head on (a chunk of 512 or 1,024 at Mellum's and
+    Granite's shapes). PERF.md section 6, PR 53, has the sweep.
+
+    ``grid`` is ``(KV // kv_group, B * pages_per_slot)``, the second a
+    bound: the call runs one step for each entry of :func:`live_pages`,
+    not for each table entry."""
     step_bytes = functools.partial(
         _step_bytes, rep=H // KV, rows=_row_tiles(query_rows), D=D, Dc=Dc,
         page_size=page_size, kv_dtype=kv_dtype, q_dtype=q_dtype,
         quantized=quantized)
     kv_group = max((g for g in range(1, KV + 1) if KV % g == 0
                     and step_bytes(g) <= VMEM_BUDGET_BYTES), default=1)
-    return kv_group, 1, (KV // kv_group, B * pages_per_slot)
+    rows = H // KV * _row_tiles(query_rows)
+    run = max(RUN_ROWS // rows,
+              RUN_HEADS if RUN_HEADS * rows <= 2 * RUN_ROWS else 1)
+    run = 1 << (min(run, kv_group).bit_length() - 1)
+    return kv_group, run, (KV // kv_group, B * pages_per_slot)
 
 
 def _row_tiles(query_rows: int) -> int:
@@ -306,7 +365,7 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
                   slope_ref, layer_ref, *refs,
                   page_size: int, scale: float, rep: int, alibi: bool,
                   quantized: bool, packed: bool, compute_dtype,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, run: int):
     # the first seven are scalar-prefetch SMEM arrays: the work list of
     # live_pages, (B,) starts, (H,) slopes and the (1,) layer of the
     # stacked leaf (page_ref and layer_ref are read by the index maps
@@ -356,51 +415,68 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
     if rep > 1:
         head_of, row = row // t_pad, row % t_pad
-    for c in range(kv_group):
-        q = q_ref[0, c]                                   # (rows, D)
-        k = k_ref[0, 0, c][:, :page_size]                 # (Dc, page_size)
-        v = v_ref[0, 0, c][:, :page_size]
-        if quantized:
-            if packed:
-                k = pltpu.bitcast(k, jnp.int8).astype(compute_dtype)
-                v = pltpu.bitcast(v, jnp.int8).astype(compute_dtype)
-            else:
-                k = k.astype(compute_dtype)
-                v = v.astype(compute_dtype)
-        s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if quantized:
-            s = s * k_scale_ref[0, 0, c][:, :page_size]    # (1, page)
-        if alibi:
-            # the dense kernel's bias for the query at ``start``, then
-            # row t's own offset: slope * (pos - (start + t)). Row 0
-            # subtracts an exact zero, which keeps a T=1 call bitwise
-            # equal to the dense kernel's scalar expression
-            slope = slope_ref[(g * kv_group + c) * rep]
-            for j in range(1, rep):
-                slope = jnp.where(
-                    head_of == j, slope_ref[(g * kv_group + c) * rep + j],
-                    slope)
-            s = s + slope * (pos - start).astype(jnp.float32) \
-                - slope * row.astype(jnp.float32)
-        seen = pos <= start + row
-        if window is not None:
-            seen = jnp.logical_and(seen, pos > start + row - window)
-        s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_ref[c, :, :1]
-        l_prev = l_ref[c, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[c] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-            l_ref.shape[1:])
-        if quantized:
-            p = p * v_scale_ref[0, 0, c][:, :page_size]    # (1, page)
-        acc_ref[c] = acc_ref[c] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[c] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+    def stored(ref, c):
+        """Head ``c``'s (D, page_size) rows of the page as the products
+        take them: a quantized tier's widened to the compute dtype."""
+        x = ref[0, 0, c][:, :page_size]                   # (Dc, page_size)
+        if packed:
+            x = pltpu.bitcast(x, jnp.int8)
+        return x.astype(compute_dtype) if quantized else x
+
+    def fold(heads):
+        """The online-softmax update of one page for the KV heads
+        ``heads`` of the step: every head's scores, then the statistics
+        head after head, then every head's values. Written head by head
+        the three parts of a head waited on each other, head after head
+        (Mosaic keeps the MXU's products in the order they are written:
+        PERF.md section 6, PR 53); each value's arithmetic is in the
+        order it was, so the bits are."""
+        scores = []
+        for c in heads:
+            q = q_ref[0, c]                               # (rows, D)
+            s = jax.lax.dot_general(
+                q, stored(k_ref, c), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quantized:
+                s = s * k_scale_ref[0, 0, c][:, :page_size]    # (1, page)
+            if alibi:
+                # the dense kernel's bias for the query at ``start``, then
+                # row t's own offset: slope * (pos - (start + t)). Row 0
+                # subtracts an exact zero, which keeps a T=1 call bitwise
+                # equal to the dense kernel's scalar expression
+                slope = slope_ref[(g * kv_group + c) * rep]
+                for j in range(1, rep):
+                    slope = jnp.where(
+                        head_of == j,
+                        slope_ref[(g * kv_group + c) * rep + j], slope)
+                s = s + slope * (pos - start).astype(jnp.float32) \
+                    - slope * row.astype(jnp.float32)
+            seen = pos <= start + row
+            if window is not None:
+                seen = jnp.logical_and(seen, pos > start + row - window)
+            scores.append(jnp.where(seen, s, NEG_INF))
+        weights = []
+        for c, s in zip(heads, scores):
+            m_prev = m_ref[c, :, :1]
+            l_prev = l_ref[c, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[c] = jnp.broadcast_to(
+                alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
+                l_ref.shape[1:])
+            weights.append((p, alpha, m_new))
+        for c, (p, alpha, m_new) in zip(heads, weights):
+            v = stored(v_ref, c)
+            if quantized:
+                p = p * v_scale_ref[0, 0, c][:, :page_size]    # (1, page)
+            acc_ref[c] = acc_ref[c] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[c] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+
+    for c in range(0, kv_group, run):
+        fold(range(c, min(c + run, kv_group)))
 
     last = live_ref[slot] - 1
     if first_ref is not None:
@@ -560,7 +636,7 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
     rows = rep * t_pad
     q4 = q4.reshape(B, KV, rows, D)
 
-    kv_group, _, (groups, _) = plan_grid(
+    kv_group, run, (groups, _) = plan_grid(
         B, H, KV, D, Dc, lanes, maxP, k_pages.dtype, q.dtype, quantized,
         query_rows=T)
     slot_of, entry_of, page_of, live, total, *first = live_pages(
@@ -602,7 +678,8 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
         functools.partial(_paged_kernel, page_size=ps, scale=scale, rep=rep,
                           alibi=alibi,
                           quantized=quantized, packed=packed,
-                          compute_dtype=compute_dtype, window=window),
+                          compute_dtype=compute_dtype, window=window,
+                          run=run),
         name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, rows, D), q.dtype),

@@ -917,12 +917,15 @@ def test_a_chunk_beside_decode_compiles_for_a_described_v5e_as_served(
     # every expert held, as these configurations have them, the program is
     # the one PR 49 compiled, text for text (sha256 of _program_text, the
     # first 16 digits; Mellum's taken of commit 4378523 and of PR 50's tree
-    # alike and unchanged since; Moonlight's re-taken at PR 51, whose
-    # latent read takes a block of pages a grid step: d12581f2ad0b359a
-    # before, reason measured on both, PERF.md section 6)
+    # alike, and re-taken at PR 53, whose K/V read folds a step's KV heads
+    # a run at a time: 4a3978ddd2f56143 before, the two paged_decode
+    # bodies alone differ, ide measured on both, PERF.md section 6;
+    # Moonlight's re-taken at PR 51, whose latent read takes a block of
+    # pages a grid step: d12581f2ad0b359a before, reason measured on both,
+    # and unchanged by PR 53)
     import hashlib
 
-    pinned = {"mellum2-12b-a2b5-paged": "4a3978ddd2f56143",
+    pinned = {"mellum2-12b-a2b5-paged": "f43b131370936a4f",
               "moonlight-16b-a3b-mla": "1830eba84bdfb003"}
     if config in pinned:
         digest = hashlib.sha256(_program_text(compiled).encode()).hexdigest()
@@ -984,8 +987,16 @@ def _program_text(compiled) -> str:
 # PR 42 at one seed a pair, one v5e chip (PERF.md §6, PR 42, calls A and B):
 # `gap_p90_ms` 8.525 / 7.536, 8.399 / 7.219, 8.313 / 7.053 and 9.446 /
 # 8.226 ms; `decode_dev_ms_p50` 5.953 / 4.940 (the traced pair).
+# Re-taken at PR 53 ("766626d4...b6fd7" until then): the body of the
+# `paged_decode` kernel alone differs (the step's KV heads folded a run at
+# a time: every head's scores, the statistics, every head's values), every
+# instruction of XLA's as it was. Measured with it, `serve-pythia-1b4-chat`,
+# parent b162d6f / PR 53 at one seed a pair, one v5e chip (PERF.md §6,
+# PR 53, call 2): `gap_p50_ms` 5.403 / 5.135 and 5.426 / 5.147 ms;
+# `decode_dev_ms_p50.chat` 5.036 / 4.814, `gap_p90_ms.chat` 6.232 / 5.789
+# (the traced pair).
 _KERNEL_DECODE_TEXT = (
-    "766626d4ec018e3b72dbbdd99d9a05480b8cafcc4c18f8558565b183ebab6fd7")
+    "a21fb47b36e01cd8f5ee202825e9a420f2779fa3360208f5be9240a345344eec")
 
 
 def test_the_chunk_program_goes_through_the_pages_and_decode_is_unchanged(

@@ -469,6 +469,104 @@ def test_heads_that_do_not_fit_vmem_split_into_groups(monkeypatch):
                                   np.asarray(oracle))
 
 
+# PR 53: a grid step folds its KV heads a RUN at a time (every head's
+# scores, then the statistics, then every head's values). name: H, KV, D,
+# page size, lanes of the stored page, query rows, what else the call
+# carries, and the runs tried. The served shapes: Pythia (MHA 16, a
+# 64-wide page in 128 lanes), Mellum (4 x 8, pages of 128, a full and a
+# window group), Granite (8 x 4, heads of 64)
+_RUN_CASES = {
+    "pythia-decode": (16, 16, 128, 64, 128, 1, None, (2, 4, 8, 16)),
+    "pythia-chunk64": (16, 16, 128, 64, 128, 64, None, (2, 4)),
+    "pythia-int8": (16, 16, 128, 64, 64, 1, "int8", (16,)),
+    "pythia-int32-packed": (16, 16, 128, 64, 64, 5, "int32-packed", (2, 16)),
+    "mellum-decode": (32, 4, 128, 128, 128, 1, None, (2, 4)),
+    "mellum-window": (32, 4, 128, 128, 128, 1, "window", (4,)),
+    "mellum-verify-window": (32, 4, 128, 128, 128, 5, "window", (2, 4)),
+    "granite-decode": (32, 8, 64, 128, 128, 1, None, (2, 4, 8)),
+    "granite-alibi": (32, 8, 64, 128, 128, 5, "alibi", (4,)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RUN_CASES))
+def test_fold_in_runs_is_the_head_after_head_fold_bit_for_bit(
+        case, monkeypatch):
+    """Every value's arithmetic is in the order it was: a call whose
+    steps fold their KV heads in runs of 2, 4, ... (between the cases
+    every run the rule can return, the shape's own among them) answers bit
+    for bit what the fold of one head after another answers (a run of 1:
+    the kernel of PR 52)."""
+    H, KV, D, ps, lanes, T, extra, runs = _RUN_CASES[case]
+    rng = np.random.default_rng(53)
+    B, per_slot = 2, 3
+    S = per_slot * ps
+    starts = np.asarray([2 * ps + ps // 2, 0], np.int32)
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.bfloat16)
+    kwargs = {}
+    if extra in ("int8", "int32-packed"):
+        _, _, k_pages, v_pages, table, kwargs = _quantized_pool(
+            rng, B, KV, D, S, ps, extra == "int32-packed")
+    else:
+        _, _, k_pages, v_pages, table = _make_paged(rng, B, KV, D, S, ps)
+        k_pages, v_pages = (
+            jnp.pad(jnp.asarray(x, jnp.bfloat16),
+                    ((0, 0),) * 3 + ((0, lanes - ps),), constant_values=3.0)
+            for x in (k_pages, v_pages))
+    if extra == "window":
+        kwargs = dict(window=ps + ps // 4)
+        table[0, 0] = k_pages.shape[0]      # behind the window: recycled
+    if extra == "alibi":
+        kwargs = dict(alibi_slopes=jnp.asarray(
+            2.0 ** -np.linspace(1, 8, H), jnp.float32))
+    real = paged_attention.plan_grid
+    shape = (B, H, KV, D, k_pages.shape[2], lanes, per_slot, k_pages.dtype,
+             jnp.bfloat16, extra in ("int8", "int32-packed"))
+    kv_group, rule, _ = real(*shape, query_rows=T)
+    assert kv_group == KV
+
+    def call(run):
+        def planned(*args, **kw):
+            kv_group, _, grid = real(*args, **kw)
+            return kv_group, run, grid
+        monkeypatch.setattr(paged_attention, "plan_grid", planned)
+        return np.asarray(paged_decode_attention(
+            q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(starts),
+            page_size=ps, **kwargs).astype(jnp.float32))
+
+    one = call(1)
+    assert np.isfinite(one).all()
+    assert rule in runs
+    for run in runs:
+        np.testing.assert_array_equal(call(run), one, err_msg=f"run {run}")
+
+
+@pytest.mark.parametrize("shape,T,rows,kv_group,run", [
+    # (B, H, KV, D, lanes, entries a slot), query rows of the call -> the
+    # rows of one KV head, the heads of a step and of a run
+    ((64, 16, 16, 128, 128, 32), 1, 8, 16, 16),       # Pythia decode
+    ((64, 16, 16, 128, 128, 32), 5, 8, 16, 16),       # ... verify
+    ((1, 16, 16, 128, 128, 32), 64, 64, 16, 4),       # docs' chunk
+    ((64, 16, 16, 128, 128, 32), 16, 16, 16, 8),
+    ((1, 16, 16, 128, 128, 32), 128, 128, 16, 1),
+    ((64, 32, 4, 128, 128, 64), 1, 64, 4, 4),         # Mellum decode
+    ((64, 32, 8, 64, 128, 128), 1, 32, 8, 4),         # Granite decode
+    ((64, 8, 2, 64, 128, 16), 1, 32, 2, 2),           # fewer heads than 4
+    ((1, 32, 8, 64, 128, 128), 128, 512, 4, 1),       # Granite's chunk
+    ((1, 32, 4, 128, 128, 64), 128, 1024, 2, 1),      # Mellum's chunk
+])
+def test_the_run_follows_the_rows_of_a_kv_head(shape, T, rows, kv_group,
+                                               run):
+    """``plan_grid``'s second return, from static shapes alone: a power of
+    two, at most the step's heads, ``RUN_ROWS // rows`` but ``RUN_HEADS``
+    up to 64 rows; a chunk of 512 or 1,024 rows a KV head folds head
+    after head as it always did."""
+    B, H, KV, D, lanes, per_slot = shape
+    assert H // KV * paged_attention._row_tiles(T) == rows
+    got = plan_grid(B, H, KV, D, D, lanes, per_slot, jnp.bfloat16,
+                    jnp.bfloat16, False, query_rows=T)
+    assert got[:2] == (kv_group, run)
+
+
 def test_grid_follows_live_pages_not_table_entries():
     """The mechanism itself, so that a return to a per-head or per-entry
     grid fails here: at the served shape a grid step holds every head of
@@ -477,10 +575,10 @@ def test_grid_follows_live_pages_not_table_entries():
     for dtype, quantized, Dc in ((jnp.bfloat16, False, D),
                                  (jnp.int8, True, D),
                                  (jnp.int32, True, D // 4)):
-        kv_group, pages_per_step, grid = plan_grid(
+        kv_group, _, grid = plan_grid(
             B, H, KV, D, Dc, ps, per_slot, dtype, jnp.bfloat16, quantized)
         assert kv_group == KV
-        assert int(np.prod(grid)) <= B * -(-per_slot // pages_per_step)
+        assert int(np.prod(grid)) <= B * per_slot        # one page a step
     # a much wider model: the heads of one page no longer fit
     kv_group, _, grid = plan_grid(4, 64, 64, 256, 256, 128, 8, jnp.bfloat16,
                                   jnp.bfloat16, False)
