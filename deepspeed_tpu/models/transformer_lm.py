@@ -42,6 +42,11 @@ granite-hybrid none      rmsnorm    swiglu    Mamba-2 layers beside
 kimi_linear    none      rmsnorm    routed    KDA layers beside latent
                                               attention, moonlight's
                                               FFN, experts held
+lfm2_moe       rotary    rmsnorm    routed    gated short-convolution
+                                              layers beside QK-normed
+                                              GQA, sigmoid router with
+                                              a bias and no shared
+                                              expert, tied head
 =============  ========  =========  ========  ===================
 
 Layer kinds that differ (``layer_types``: ``sliding_attention`` or
@@ -90,6 +95,12 @@ nothing, and the FFN may be routed, behind ``first_k_dense`` leading layers
 with a plain one inside the first period (``dense_blocks``, of the leading
 layers' kind). A routed FFN may hold a share of its experts
 (``experts_held``).
+
+A seventh, ``conv`` (:class:`ShortConvMixer`, LFM2's gated short
+convolution), is a state layer with NO matrix state: its state group is the
+one leaf ``conv``, the convolution's last ``conv_taps - 1`` inputs (the
+stacked leaf is ``conv_blocks``). The attention layers beside it may rotate
+(``pos_emb`` "rotary") and norm each head of q and k (``qk_norm``).
 
 KV-cache decoding uses the flax ``cache`` variable collection: ``prefill``
 writes the prompt's K/V at positions [0, T), ``decode`` appends one position
@@ -200,6 +211,8 @@ class TransformerConfig:
     # (sigmoid: the choice is ordered by score + a learned bias, the
     # weights come from the unbiased scores)
     routed_scaling_factor: float = 1.0  # multiplies the routed weights
+    topk_norm_eps: float = 1e-20        # the sigmoid router's: joins the
+    # chosen scores' sum before they are divided by it (norm_topk_prob)
     n_shared_experts: int = 0           # one gated FFN of this many expert
     # widths beside the routed sum, for every token
     first_k_dense: int = 0              # the first layers' FFN is a plain
@@ -222,6 +235,11 @@ class TransformerConfig:
     kda_n_heads: int = 0
     kda_d_head: int = 0
     kda_d_conv: int = 4
+    # gated short-convolution layers (``layer_types`` "conv", beside
+    # "attention" layers): a causal depthwise convolution of conv_taps taps
+    # over n_embd channels between two gates, no bias, no activation
+    # (ShortConvMixer has the equations); the state is the convolution's tail
+    conv_taps: int = 3
     experts_held: Optional[int] = None  # the routed FFN holds experts
     # [0, experts_held) of n_experts (one chip's share of a layer that
     # several divide): the router and the top-k run over all n_experts, the
@@ -240,15 +258,16 @@ class TransformerConfig:
             kinds = set(self.layer_types) - {"sliding_attention",
                                              "full_attention",
                                              "power_retention",
-                                             "mamba", "kda", "attention"}
+                                             "mamba", "kda", "conv",
+                                             "attention"}
             if kinds or len(self.layer_types) != self.n_layer:
                 raise ValueError(
                     f"layer_types names n_layer={self.n_layer} layers as "
                     f"sliding_attention | full_attention | power_retention "
-                    f"| mamba | kda | attention; "
+                    f"| mamba | kda | conv | attention; "
                     f"got {len(self.layer_types)} entries, unknown "
                     f"{sorted(kinds)}")
-            if {"mamba", "kda", "attention"} & set(self.layer_types):
+            if set(STATE_KINDS + ("attention",)) & set(self.layer_types):
                 self._check_hybrid()
             if "power_retention" in self.layer_types:
                 if set(self.layer_types) != {"power_retention"}:
@@ -323,9 +342,9 @@ class TransformerConfig:
                 raise ValueError(why)
 
     def _check_hybrid(self) -> None:
-        """State layers of ONE kind, ``mamba`` or ``kda``, stand beside
-        ``attention`` layers (full, K/V a head or latent, with ``pos_emb``
-        "rotary" or "none") in a pattern that repeats: one attention layer
+        """State layers of ONE kind, ``mamba``, ``kda`` or ``conv``, stand
+        beside ``attention`` layers (full, K/V a head or latent, with
+        ``pos_emb`` "rotary" or "none") in a pattern that repeats: one attention layer
         a period, the same number of state layers before and after it in
         every period (:attr:`hybrid_period`). The FFN may be routed; the
         ``first_k_dense`` layers with a plain one are state layers at the
@@ -333,11 +352,12 @@ class TransformerConfig:
         types = self.layer_types
         n_att = types.count("attention")
         state = set(types) - {"attention"}
-        if len(state) != 1 or not state < {"mamba", "kda"} or not n_att \
+        if len(state) != 1 or not state < set(STATE_KINDS) or not n_att \
                 or self.n_layer % n_att \
                 or types != types[:self.n_layer // n_att] * n_att:
             raise ValueError(
-                f"mamba or kda layers and attention layers come as a pattern "
+                f"mamba, kda or conv layers and attention layers come as a "
+                f"pattern "
                 f"with ONE attention layer that repeats over the layers (the "
                 f"two stacked leaves are run period by period); got "
                 f"{list(types)}")
@@ -358,6 +378,10 @@ class TransformerConfig:
                 f"kda layers need kda_n_heads and kda_d_head and a "
                 f"convolution of two taps or more; got {self.kda_n_heads} x "
                 f"{self.kda_d_head}, taps {self.kda_d_conv}")
+        if self.conv and self.conv_taps < 2:
+            raise ValueError(
+                f"conv layers need a convolution of two taps or more (the "
+                f"state is its tail); got conv_taps={self.conv_taps}")
         if self.parallel_residual:
             raise ValueError("state layers know the sequential residual")
         if self.first_k_dense > self.hybrid_period[0]:
@@ -412,10 +436,18 @@ class TransformerConfig:
         return self.layer_types is not None and "kda" in self.layer_types
 
     @property
+    def conv(self) -> bool:
+        """``conv`` layers beside ``attention`` layers: a state group of
+        the convolution's tail alone over the former, K/V over the latter."""
+        return self.layer_types is not None and "conv" in self.layer_types
+
+    @property
     def hybrid(self) -> Optional[str]:
         """The kind of the state layers that stand beside ``attention``
-        layers, ``"mamba"`` or ``"kda"``; None for a model of one stack."""
-        return "mamba" if self.mamba else "kda" if self.kda else None
+        layers, one of :data:`STATE_KINDS`; None for a model of one stack."""
+        return next((kind for kind in STATE_KINDS
+                     if self.layer_types is not None
+                     and kind in self.layer_types), None)
 
     @property
     def kda_width(self) -> int:
@@ -437,6 +469,9 @@ class TransformerConfig:
         return self.mamba_n_heads * self.mamba_d_head \
             + 2 * self.mamba_n_groups * self.mamba_d_state
 
+
+# the kinds of state layer that stand beside ``attention`` layers
+STATE_KINDS = ("mamba", "kda", "conv")
 
 FAMILY_PRESETS = {
     "gpt2": dict(pos_emb="learned", norm="layernorm", activation="gelu"),
@@ -490,6 +525,17 @@ FAMILY_PRESETS = {
                         qkv_bias=False, mlp_bias=False,
                         tie_word_embeddings=False, layer_norm_epsilon=1e-5,
                         scoring_func="sigmoid"),
+    # LFM2 MoE (Liquid AI; model_type lfm2_moe): gated short-convolution
+    # layers beside rotary GQA layers with a norm on each head of q and k
+    # (``layer_types`` as published, "conv" | "full_attention"), a sigmoid
+    # router ordered by score + bias over ``sum + 1e-6`` with no shared
+    # expert, behind leading dense layers (``first_k_dense``); the head is
+    # tied. Widths and the pattern are the caller's.
+    "lfm2_moe": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
+                     qkv_bias=False, mlp_bias=False,
+                     tie_word_embeddings=True, layer_norm_epsilon=1e-5,
+                     qk_norm=True, scoring_func="sigmoid",
+                     topk_norm_eps=1e-6),
 }
 
 
@@ -510,6 +556,12 @@ def transformer_config(family: str, **overrides) -> TransformerConfig:
     overrides = {k: _freeze(v) if k in ("layer_types", "rope_parameters")
                  else v for k, v in overrides.items()}
     cfg = {**FAMILY_PRESETS[family], **overrides}
+    if "conv" in (cfg.get("layer_types") or ()):
+        # (published beside "conv" as "full_attention": the one attention
+        # kind of a stack of state and attention layers)
+        cfg["layer_types"] = tuple(
+            "attention" if kind == "full_attention" else kind
+            for kind in cfg["layer_types"])
     kind = cfg.pop("layer_kind", None)
     if kind is not None:
         cfg.setdefault("layer_types", (kind,) * cfg.get(
@@ -715,6 +767,15 @@ def _project_qkv(cfg: TransformerConfig, x):
         for heads, name in ((H, "q_proj"), (KV, "k_proj"), (KV, "v_proj"))))
     return (q.reshape(B, T, H, D), k.reshape(B, T, KV, D),
             v.reshape(B, T, KV, D))
+
+
+def _norm_qk(cfg: TransformerConfig, q, k):
+    """``qk_norm``: RMSNorm over each head of ``q`` and of ``k`` (one
+    learned weight of ``head_dim`` each), before the rotary. Called inside
+    an attention module's ``__call__``: the two norms are that module's."""
+    return tuple(nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
+                            name=name)(x)
+                 for name, x in (("q_norm", q), ("k_norm", k)))
 
 
 def _store_columns(buf, new, start):
@@ -1004,6 +1065,8 @@ class CachedAttention(nn.Module):
         B, T, C = x.shape
         H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
         q, k, v = _project_qkv(cfg, x)
+        if cfg.qk_norm:
+            q, k = _norm_qk(cfg, q, k)
         if cfg.attention_multiplier is not None:
             # every path below (and the kernels) scales by 1 / sqrt(D):
             # the query carries what the published scale differs by
@@ -1273,10 +1336,7 @@ class PowerRetention(nn.Module):
         H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
         q, k, v = _project_qkv(cfg, x)
         if cfg.qk_norm:
-            q = nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
-                           name="q_norm")(q)
-            k = nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
-                           name="k_norm")(k)
+            q, k = _norm_qk(cfg, q, k)
         log_g = jax.nn.log_sigmoid(nn.Dense(
             KV, dtype=jnp.float32, bias_init=_half_life_logit,
             name="g_proj")(x))                                  # (B, T, KV)
@@ -1462,23 +1522,26 @@ def _state_rows(cache, B: int, T: int):
             jnp.broadcast_to(cache["start"] == 0, (B,)), valid)
 
 
-def _conv_after_tail(cache, x, w, b):
+def _conv_after_tail(cache, x, w, b, silu: bool = True):
     """A state layer's causal convolution of one group of rows ``x`` (B, T,
-    C) after the tail its cache carries (``ops/state_space.causal_conv``):
-    ``(conv(x), where, conv leaf)``. ``cache`` None: whole sequences from
-    nothing, no ``where`` and no leaf. Else ``where`` is
-    :func:`_state_rows`'s, the tail is read from the leaf ``conv`` and the
-    tail at the last REAL token is written back to it."""
-    from ..ops.state_space import causal_conv
+    C) after the tail its cache carries (``ops/state_space.causal_conv``;
+    ``silu`` False: without its activation): ``(conv(x), where, conv
+    leaf)``. ``cache`` None: whole sequences from nothing, no ``where`` and
+    no leaf. Else ``where`` is :func:`_state_rows`'s, the tail is read from
+    the leaf ``conv`` and the tail at the last REAL token is written back to
+    it."""
+    from ..ops import state_space
 
+    conv = state_space.causal_conv if silu else functools.partial(
+        state_space.causal_conv, silu=False)
     B, T = x.shape[:2]
     tail = jnp.zeros((B, w.shape[0] - 1, x.shape[-1]), x.dtype)
     if cache is None:
-        return causal_conv(x, tail, w, b, jnp.full((B,), T, jnp.int32))[0], \
+        return conv(x, tail, w, b, jnp.full((B,), T, jnp.int32))[0], \
             None, None
     where = layer, rows, fresh, valid = _state_rows(cache, B, T)
     tail = _read_rows(cache["conv"], layer, rows, fresh, tail.shape[1:])
-    x, tail = causal_conv(x, tail, w, b, valid)
+    x, tail = conv(x, tail, w, b, valid)
     return x, where, _write_rows(cache["conv"], layer, rows,
                                  tail.reshape(B, -1))
 
@@ -1716,6 +1779,54 @@ class KDAMixer(nn.Module):
         return dense(C, "o_proj")(y.astype(cfg.dtype)), leaves
 
 
+class ShortConvMixer(nn.Module):
+    """LFM2's gated short convolution in the attention's place. With ``u``
+    the normed input (C wide)::
+
+        [B ; C ; z] = W_in u            (three gates of C channels, this order)
+        v = B (.) z
+        c_t = sum_j w_j (.) v_{t - (K - 1) + j}     j = 0 .. K - 1
+        out = W_out (C (.) c)
+
+    The convolution is causal and depthwise over ``conv_taps`` = K taps, no
+    bias and NO activation. There is no matrix state: what a sequence
+    carries is the convolution's tail, the last K - 1 rows of ``v``, the
+    one leaf ``conv`` of the state group (``(L, rows, (K - 1) * C)`` in the
+    model's dtype, time-major on the minor axis), read and written through
+    :func:`_conv_after_tail` with ``layer``, ``start``, ``rows`` and
+    ``valid`` as :class:`Mamba2Mixer`: a decode row, a chunk and a whole
+    sequence are one code path, a token at or past ``valid`` shifts nothing
+    into the tail, an entry whose first position is 0 reads none, and a
+    row that does not run keeps its tail. All of it is XLA's, under the
+    scope ``short_conv``."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, *, decode: Union[bool, str] = False,
+                 deterministic: bool = True, kv_cache=None, layer=None):
+        cfg = self.config
+        C, K = u.shape[-1], cfg.conv_taps
+        bcz = _dense(cfg, 3 * C, use_bias=False, name="in_proj")(u)
+        bound = 1.0 / math.sqrt(K)      # (torch's Conv1d default)
+        taps = nn.initializers.uniform(2 * bound)
+        conv_w = self.param("conv_w", lambda *a: taps(*a) - bound, (K, C))
+
+        def mix(cache, bcz):
+            """The gates and the convolution of one group of rows (B, T):
+            ``(C (.) c, leaves)``."""
+            with jax.named_scope("short_conv"):
+                b, c, z = (bcz[..., i * C:(i + 1) * C] for i in range(3))
+                conv, _, leaf = _conv_after_tail(cache, b * z, conv_w, None,
+                                                 silu=False)
+                y = (c.astype(jnp.float32) * conv).astype(cfg.dtype)
+            return y, None if cache is None else {"conv": leaf}
+
+        y, leaves = _by_row_group(kv_cache, mix, bcz) if decode \
+            else mix(None, bcz)
+        return _dense(cfg, C, use_bias=False, name="out_proj")(y), leaves
+
+
 class TransformerMLP(nn.Module):
     config: TransformerConfig
 
@@ -1740,9 +1851,9 @@ class TransformerMLP(nn.Module):
 
 class TransformerBlock(nn.Module):
     config: TransformerConfig
-    kind: Optional[str] = None      # "mamba" | "kda" | "attention": the
-    # layer's kind in a model of state and attention layers, whose stacked
-    # leaves are one a kind; None: the configuration's one mixer
+    kind: Optional[str] = None      # "mamba" | "kda" | "conv" | "attention":
+    # the layer's kind in a model of state and attention layers, whose
+    # stacked leaves are one a kind; None: the configuration's one mixer
 
     @nn.compact
     def __call__(self, x, decode: Union[bool, str] = False,
@@ -1751,6 +1862,7 @@ class TransformerBlock(nn.Module):
         cfg = self.config
         attention, name = (Mamba2Mixer, "mamba") if self.kind == "mamba" \
             else (KDAMixer, "kda") if self.kind == "kda" \
+            else (ShortConvMixer, "conv") if self.kind == "conv" \
             else (PowerRetention if cfg.retention else
                   LatentAttention if cfg.latent else CachedAttention, "attn")
         a, new_cache = attention(cfg, name=name)(
@@ -1771,9 +1883,10 @@ class TransformerBlock(nn.Module):
                 cfg.n_experts, cfg.experts_per_token, cfg.norm_topk_prob,
                 cfg.scoring_func, cfg.routed_scaling_factor,
                 cfg.n_shared_experts * cfg.ffn_width, cfg.dtype,
-                name="mlp")(h, experts, ffn_layer if ffn_layer is not None
-                             else layer - cfg.first_k_dense
-                             if cfg.first_k_dense else layer)
+                cfg.topk_norm_eps, name="mlp")(
+                    h, experts, ffn_layer if ffn_layer is not None
+                    else layer - cfg.first_k_dense
+                    if cfg.first_k_dense else layer)
             stats = (layer_stats,)
             return m
 
@@ -1807,7 +1920,8 @@ class _ScanBlock(nn.Module):
       (PERF.md §8, the carry-DUS lead); the carry-DUS of a
       batch-major dense row did not.
     - a recurrent state (``KVCacheSpec.state_leaves``, a layer of
-      :class:`PowerRetention`, :class:`Mamba2Mixer` or :class:`KDAMixer`):
+      :class:`PowerRetention`, :class:`Mamba2Mixer`, :class:`KDAMixer` or
+      :class:`ShortConvMixer`):
       whole like a page pool's leaves, for the same reason and one more:
       a slice would be one layer's state of EVERY row, 0.5 GB a layer at
       the served size, read and written for the one row a chunk runs.
@@ -1952,6 +2066,7 @@ CACHE_KINDS = {     # in the order a refusal is looked up (cache_kinds)
     "state": "a recurrent state",           # power_retention layers
     "ssm": "a state group beside K/V",      # mamba beside attention layers
     "kda": "a KDA state group",             # kda beside attention layers
+    "conv": "a convolution-tail state group",   # conv beside attention layers
     "latent": "latent attention's cache",   # one row a token (kv_lora_rank)
     "window_only": "sliding-window layers alone",
     "window": "a window page group",        # sliding beside full layers
@@ -2050,6 +2165,31 @@ CACHE_REFUSALS = {
         "int8_weights does not reach the kda layers' convolution, A_log, "
         "dt_bias and output norm, which are parameters of the mixer and no "
         "Dense",
+    ("conv", "spec_decode"):
+        "a rejected draft's tokens are in the convolution's tail for good: "
+        "verify_k's rollback moves an index, which hides K/V columns and "
+        "nothing of a state",
+    ("conv", "prefix_cache"):
+        "a hit maps the K/V pages of the prompt's start and would need the "
+        "tail as it stood at the hit's boundary, which nothing keeps (a "
+        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
+    ("conv", "roles"):
+        "pages are the unit of a handoff: the slot's state rows would have "
+        "to be shipped beside them",
+    ("conv", "tensor_parallel"):
+        "the state leaf has no placement on the model axis",
+    ("conv", "tensor_parallel_serving"):
+        "the state leaf has no placement on the model axis",
+    ("conv", "zero_inference"):
+        "it streams one layer's block parameters at a time out of ONE "
+        "stacked tree; conv and attention layers are two, and the tail is "
+        "not threaded through the streamed layers",
+    ("conv", "kv_cache_quant"):
+        "kv_cache_quant quantizes K/V columns; the tail beside them is in "
+        "the model's dtype and the tier has not been run beside it",
+    ("conv", "int8_weights"):
+        "int8_weights does not reach the conv layers' taps, which are a "
+        "parameter of the mixer and no Dense",
     ("latent", "spec_decode"):
         "the latent read takes one query row a slot or one slot's chunk; a "
         "verify step's K + 1 rows of every slot, each with its own causal "
@@ -2115,7 +2255,7 @@ def cache_kinds(cfg: TransformerConfig) -> tuple:
     """The kinds of ``CACHE_KINDS`` a configuration is, in its order."""
     groups = kv_cache_groups(cfg)
     has = {"state": cfg.retention, "ssm": cfg.mamba, "kda": cfg.kda,
-           "latent": cfg.latent,
+           "conv": cfg.conv, "latent": cfg.latent,
            "window_only": groups is not None and not groups[0][1],
            "window": groups is not None,
            "layer_types": cfg.layer_types is not None
@@ -2176,8 +2316,10 @@ class KVCacheSpec:
     # ``dtype``; the attention layers keep K/V (:attr:`kv_layers`). A model
     # of kda layers beside attention layers: the kda layers, ``s`` (heads,
     # d, d) float32 and ``conv`` likewise; the attention layers keep K/V or
-    # the latent row. A state's size does not depend on max_seq_len, which
-    # stays the bound on positions
+    # the latent row. A model of conv layers beside attention layers: the
+    # conv layers, ONE leaf ``conv`` (the convolution's last taps - 1
+    # inputs) and no ``s``. A state's size does not depend on max_seq_len,
+    # which stays the bound on positions
 
     @property
     def state_leaves(self) -> tuple:
@@ -2404,6 +2546,9 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
              jnp.float32),
             ("conv", ((cfg.kda_d_conv - 1) * 3 * cfg.kda_width,),
              cache_dtype)))
+    if cfg.conv:
+        group = (cfg.layer_types.count("conv"), (
+            ("conv", ((cfg.conv_taps - 1) * cfg.n_embd,), cache_dtype),))
     return KVCacheSpec(n_layer=cfg.n_layer, kv_heads=cfg.kv_heads,
                        head_dim=cfg.head_dim, cache_d=cache_d,
                        dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
@@ -2584,7 +2729,7 @@ class TransformerLM(nn.Module):
                                   dtype=jnp.float32, name="lm_head")
 
     def _hybrid_layers(self, carry, decode, deterministic, experts=()):
-        """The layers of a model of state (mamba or kda) and attention
+        """The layers of a model of state (mamba, kda or conv) and attention
         layers, in the published order, off the stacked leaves: a scan over
         the periods whose body scans the state layers before the period's
         attention layer, runs that one, and scans those after it
@@ -2706,8 +2851,8 @@ class TransformerLM(nn.Module):
                     paged_table, dict) else {"table": paged_table}))
             if cfg.retention or cfg.hybrid:
                 # which cache row each batch entry is and where its real
-                # tokens end ride beside the state (PowerRetention,
-                # Mamba2Mixer, KDAMixer)
+                # tokens end ride beside the state (PowerRetention and the
+                # mixers of the state layers)
                 if state_rows is not None:
                     cache["rows"] = jnp.asarray(state_rows, jnp.int32)
                 if valid_len is not None:

@@ -65,15 +65,16 @@ VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 def route(h: jax.Array, router: jax.Array, k: int, norm_topk_prob: bool,
           scoring: str = "softmax", bias: Optional[jax.Array] = None,
-          scaling: float = 1.0):
+          scaling: float = 1.0, eps: float = 1e-20):
     """``(weights (N, k) float32, experts (N, k) int32)`` of ``h`` (N, C)
     under the router matrix (C, E): softmax in float32, the ``k`` largest,
     renormalised over the chosen when ``norm_topk_prob``.
 
     ``scoring="sigmoid"``: the scores are sigmoids, the chosen are the
     ``k`` largest of ``score + bias`` (E,), the weights are the UNBIASED
-    scores of the chosen (renormalised likewise, over ``sum + 1e-20``)
-    times ``scaling``; a third value comes back, how many assignments the
+    scores of the chosen (renormalised likewise, over ``sum + eps``: 1e-20
+    as Moonlight and Kimi Linear publish it, 1e-6 for LFM2) times
+    ``scaling``; a third value comes back, how many assignments the
     bias moved: those whose expert is not among the ``k`` largest unbiased
     scores."""
     logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
@@ -83,7 +84,7 @@ def route(h: jax.Array, router: jax.Array, k: int, norm_topk_prob: bool,
         _, e = jax.lax.top_k(s + bias.astype(jnp.float32), k)
         w = jnp.take_along_axis(s, e, axis=-1)
         if norm_topk_prob:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
         _, unbiased = jax.lax.top_k(s, k)
         moved = jnp.sum(~jnp.any(e[:, :, None] == unbiased[:, None, :], -1),
                         dtype=jnp.int32)
@@ -341,6 +342,8 @@ class RoutedFFN(nn.Module):
     shared_width: int = 0       # > 0: a gated FFN of this width for every
     # token, added to the routed sum
     dtype: Any = jnp.bfloat16   # the shared FFN's
+    norm_eps: float = 1e-20     # the sigmoid router's: what joins the chosen
+    # scores' sum before they are divided by it
 
     @nn.compact
     def __call__(self, x, experts, layer):
@@ -351,7 +354,7 @@ class RoutedFFN(nn.Module):
         if self.scoring_func == "sigmoid":
             scoring = dict(
                 scoring=self.scoring_func,
-                scaling=self.routed_scaling_factor,
+                scaling=self.routed_scaling_factor, eps=self.norm_eps,
                 bias=self.param("router_bias", _bias_init,
                                 (self.n_experts,)))
         y, stats = routed_ffn(

@@ -114,10 +114,11 @@ def from_tiles(s, d_head: int):
 # ---------------------------------------------------------------------------
 # what stays XLA's beside the kernels: the convolution and the gated norm
 # ---------------------------------------------------------------------------
-def causal_conv(xbc, tail, w, b, valid):
-    """The causal depthwise convolution with its bias and the silu, after a
-    carried tail. ``xbc`` (B, T, C), ``tail`` (B, K - 1, C): the K - 1
-    inputs before the first token (zeros before a sequence), ``w`` (K, C),
+def causal_conv(xbc, tail, w, b, valid, silu: bool = True):
+    """The causal depthwise convolution with its bias and the silu
+    (``silu`` False: the convolution alone), after a carried tail. ``xbc``
+    (B, T, C), ``tail`` (B, K - 1, C): the K - 1 inputs before the first
+    token (zeros before a sequence), ``w`` (K, C),
     ``b`` (C,) or None (no bias), ``valid`` (B,): how many of the T tokens
     are real. Returns
     ``(silu(conv) (B, T, C) float32, tail')``: the last K - 1 inputs up to
@@ -133,7 +134,7 @@ def causal_conv(xbc, tail, w, b, valid):
             out = b.astype(f32) + out
         tail = jax.vmap(lambda sq, n: jax.lax.dynamic_slice_in_dim(
             sq, n, K - 1, 0))(seq, jnp.asarray(valid, jnp.int32))
-        return jax.nn.silu(out), tail
+        return (jax.nn.silu(out) if silu else out), tail
 
 
 def gated_norm(y, z, weight, eps: float):

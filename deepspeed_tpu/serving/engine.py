@@ -355,7 +355,7 @@ class ServingEngine:
         # (a state group's prefill runs its real tokens through the chunk
         # form of its kind's scan: the span attribute that counts them)
         self._chunk_tokens_key = next(
-            (f"{kind}_chunk_tokens" for kind in ("ssm", "kda")
+            (f"{kind}_chunk_tokens" for kind in ("ssm", "kda", "conv")
              if kind in getattr(spec, "kinds", ())), None)
         self._latent_token_bytes = int(getattr(spec, "latent", 0)) \
             * np.dtype(spec.dtype).itemsize \
@@ -1402,9 +1402,9 @@ class ServingEngine:
         step's span gathers them, with the bytes they stand for over the
         layers (a row's state read once and written once). ``tokens``, a
         prefill dispatch's REAL tokens (the host's own positions): a model
-        of state-space or KDA layers runs them through the chunk form
-        (``ssm_chunk_tokens`` / ``kda_chunk_tokens`` on the span, summed on
-        the step's)."""
+        of state-space, KDA or short-convolution layers runs them through
+        the chunk form (``ssm_chunk_tokens`` / ``kda_chunk_tokens`` /
+        ``conv_chunk_tokens`` on the span, summed on the step's)."""
         if not self._state_row_bytes:
             return
         sp.set(state_rows=rows)

@@ -536,6 +536,78 @@ def test_a_state_group_beside_pages_compiles_with_no_copy_of_a_leaf(
                 (name, compiled.memory_analysis())
 
 
+def test_a_conv_tail_beside_pages_compiles_with_no_copy_of_a_leaf(
+        compiled_kernels, described_v5e):
+    """PR 54. The decode, the chunk and the chunk-beside-decode program of
+    a server of gated short-convolution and QK-normed rotary attention
+    layers at every published width of ``perf/configs/lfm2-24b-a2b-conv
+    .json``, cut as PR 42's recipe cuts (one period ``[conv, conv,
+    attention, conv]`` whose two leading conv layers carry the dense FFN of
+    11,776 and whose other two a routed one, 8 of 64 experts, a vocabulary
+    of 128), 256 slots, 8,192 pages of 128, compiled for a described v5e.
+    The mixer brings no kernel: a program holds the attention layer's
+    ``paged_write`` twice and ``paged_decode`` for each group of rows, and
+    ONE routed FFN a routed layer body over both groups' rows. No leaf of
+    the pool (2.1 GB of pages; the tail's (3, 256, 4096) written through a
+    layer's slab) and no stacked weight is copied."""
+    from deepspeed_tpu.inference.engine import pack_chunk_args
+    from deepspeed_tpu.parallel import mesh
+    from deepspeed_tpu.serving.paged_pool import PagedKVPool
+
+    model, engine = _zero_engine(
+        "lfm2_moe", max_seq_len=4096, n_embd=2048, n_layer=4, n_head=32,
+        n_kv_head=8, rope_theta=1000000.0,
+        layer_types=["conv", "conv", "full_attention", "conv"],
+        ffn_dim=1536, n_experts=8, experts_per_token=4, first_k_dense=2,
+        dense_ffn_dim=11776)
+    assert model.config.head_dim == 64 and model.config.qk_norm
+    spec, slots, chunk = model.kv_cache_spec(), 256, 128
+    pool = PagedKVPool(spec, 2, num_pages=2, kernel="on", page_size=128,
+                       prefix_cache=False)
+    pool.bind_engine(engine)
+    assert pool.fuses(chunk)
+    cs = dict(jax.eval_shape(
+        lambda: spec.paged_cache(8192, 128, num_slots=slots)))
+    assert set(cs) == {"k", "v", "conv"}
+    assert cs["conv"].shape == (3, 256, 4096)
+    assert cs["k"].shape == (1, 8192, 8, 64, 128)
+    cs["index"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    cs["table"] = jax.ShapeDtypeStruct((slots, pool.pages_per_slot),
+                                       jnp.int32)
+    token = jnp.zeros((slots,), jnp.int32)
+    packed = jnp.asarray(pack_chunk_args(
+        np.zeros((1, chunk), np.int32), 0, chunk, chunk, chunk - 1,
+        np.zeros((pool.pages_per_slot,), np.int32)))
+    step = ["paged_write"] * 2 + ["paged_decode"]
+    routed = ["moe_gate_up", "moe_down"] * 2    # the attention layer's and
+    #                                             the conv layer's behind it
+    programs = {
+        "kernel_decode": (pool._paged_decode_kernel_jit,
+                          (engine.params, cs, token, token), step + routed),
+        "paged_chunk": (pool._paged_chunk_jit, (engine.params, cs, packed),
+                        step + routed),
+        "paged_chunk_beside_decode": (
+            pool._paged_chunk_decode_jit,
+            (engine.params, cs, packed, token, token), step * 2 + routed)}
+    mesh.reset_mesh()       # (the engine's mesh is of this process's CPUs)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=described_v5e)
+
+    with _compile_cache_off():
+        for name, (jitted, args, kernels) in programs.items():
+            compiled = jitted.lower(*jax.tree_util.tree_map(
+                described, args)).compile()
+            text = compiled.as_text()
+            calls = re.findall(
+                r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*tpu_custom_call",
+                text)
+            assert sorted(calls) == sorted(kernels), (name, calls)
+            assert "may-alias" in text
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27, \
+                (name, compiled.memory_analysis())
+
+
 def test_latent_kernels_compile_for_a_described_v5e_at_the_served_shape(
         compiled_kernels, described_v5e):
     """The kernels PR 38 brought, through Mosaic at the widths of

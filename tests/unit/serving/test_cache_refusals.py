@@ -31,6 +31,9 @@ PRESETS = {
         TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
         layer_types=["kda", "attention"] * 2, kda_n_heads=2,
         kda_d_head=8)),
+    "conv": ("granite-hybrid", dict(
+        TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
+        layer_types=["conv", "attention"] * 2)),
     "latent": ("moonlight", dict(
         TINY, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
         v_head_dim=8, scoring_func="softmax")),
@@ -165,6 +168,11 @@ def test_a_kind_with_no_row_constructs(engine_of):
                         paged_kv=_OFF)
     assert set(srv.pool.cache["cache_store"]) \
         == {"s", "conv", "k", "v", "index", "table"}
+    # (PR 54) a state group of ONE leaf, the convolution's tail
+    srv = ServingEngine(engine_of("conv"), num_slots=2, prefill_chunk=PAGE,
+                        paged_kv=_OFF)
+    assert set(srv.pool.cache["cache_store"]) \
+        == {"conv", "k", "v", "index", "table"}
     with pytest.raises(KeyError):
         spec.refusal("no_such_feature")
 
@@ -213,3 +221,35 @@ def test_a_kda_state_group_beside_latent_pages_refuses_in_the_groups_words():
                                   experts_per_token=2, dense_ffn_dim=48,
                                   kda_n_heads=2, kda_d_head=8),
             layer_types=["kda", "attention"] * 2, first_k_dense=2)
+
+
+def test_a_conv_tail_beside_rotary_pages_refuses_in_the_groups_words():
+    """PR 54's pairing (conv layers, QK-normed rotary attention, a routed
+    FFN behind two dense layers that are a period's whole head): the state
+    group answers for everything a state is in the way of, and it serves on
+    the page pool with the prefix cache off."""
+    sizes = dict(TINY, n_layer=8, n_kv_head=2, ffn_dim=16, n_experts=8,
+                 experts_per_token=2, dense_ffn_dim=48)
+    published = ["conv", "conv", "full_attention", "conv"] * 2
+    cfg = transformer_config("lfm2_moe", **sizes, layer_types=published,
+                             first_k_dense=2)
+    assert cache_kinds(cfg) == ("conv", "routed")
+    assert cfg.pos_emb == "rotary" and cfg.qk_norm
+    spec = TransformerLM(cfg).kv_cache_spec()
+    for feature in ("spec_decode", "prefix_cache", "roles", "tensor_parallel",
+                    "tensor_parallel_serving", "zero_inference"):
+        said = spec.refusal(feature)
+        assert said.startswith(f"{FEATURES[feature]} does not compose with "
+                               f"a convolution-tail state group yet: "), said
+        assert CACHE_REFUSALS["conv", feature] in said
+    assert spec.refusal("paged_kv") is None
+    assert set(spec.paged_cache(4, PAGE, num_slots=2)) == {"k", "v", "conv"}
+    with pytest.raises(ValueError, match="head of the first period"):
+        transformer_config("lfm2_moe", **sizes, layer_types=published,
+                           first_k_dense=3)
+    with pytest.raises(ValueError, match="two taps or more"):
+        transformer_config("lfm2_moe", **sizes, layer_types=published,
+                           first_k_dense=2, conv_taps=1)
+    with pytest.raises(ValueError, match="ONE attention layer that repeats"):
+        transformer_config("lfm2_moe", **dict(sizes, n_layer=3),
+                           layer_types=["conv", "mamba", "full_attention"])

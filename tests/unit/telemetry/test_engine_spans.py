@@ -677,6 +677,94 @@ def test_a_kda_state_group_beside_latent_pages_counts_what_it_ran(
 
 
 @pytest.fixture(scope="module")
+def lfm2_account():
+    """A server of gated short-convolution layers beside QK-normed rotary
+    attention with a routed FFN behind two dense layers (PR 54: a state
+    group of ONE leaf, the convolution's tail, beside paged K/V), driven as
+    ``kimi_account`` is."""
+    from deepspeed_tpu.models.transformer_lm import transformer_config
+
+    model = TransformerLM(transformer_config(
+        "lfm2_moe", **dict(TINY, n_layer=4, n_kv_head=2, ffn_dim=16,
+                           n_experts=8, experts_per_token=2,
+                           first_k_dense=2, dense_ffn_dim=48),
+        layer_types=["conv", "conv", "full_attention", "conv"]))
+    params = model.init({"params": jax.random.PRNGKey(1)},
+                        jnp.zeros((1, 8), jnp.int32),
+                        method=model.logits)["params"]
+    srv = ds.init_serving(model, model_parameters=params,
+                          config={"dtype": "float32"}, num_slots=4,
+                          max_queue_depth=8, prefill_chunk=8,
+                          prefill_token_budget=64,
+                          paged_kv={"kernel": "off", "prefix_cache": False})
+    rng = np.random.default_rng(11)
+    n0 = default_tracer().events_total
+    srv.submit(rng.integers(0, 64, size=20).astype(np.int32),
+               max_new_tokens=12)                  # chunks of 8, 8 and 4
+    for _ in range(4):
+        srv.step()
+    srv.submit(rng.integers(0, 64, size=7).astype(np.int32),
+               max_new_tokens=4)                   # alone: serving/admit
+    srv.run_until_drained(max_steps=60)
+    srv.check_invariants()
+    evs = _new_events(n0)
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    return {"pool": "paged", "srv": srv, "evs": evs, "steps": steps}
+
+
+def test_a_conv_tail_beside_pages_counts_what_it_ran(lfm2_account):
+    """``state_rows`` on every dispatch span and ``conv_chunk_tokens`` (REAL
+    tokens) on the prefill dispatches, as the mamba layers'
+    ``ssm_chunk_tokens`` and the kda layers' ``kda_chunk_tokens`` and never
+    two of them; the state's bytes are the tail's alone (two rows of the
+    hidden width a conv layer); on ``serving/step`` the routed FFN's five
+    counts as for Moonlight (every expert held: no ``routed_assignments``
+    beside ``assignments``). A plain decode step makes the three device
+    calls it makes beside any state group."""
+    srv, evs = lfm2_account["srv"], lfm2_account["evs"]
+    chunks = [e for e in evs if e["name"] == "serving/prefill_chunk"]
+    assert [e["args"]["conv_chunk_tokens"] for e in chunks] == [8, 8, 4]
+    assert all(e["args"]["state_rows"] == 1 for e in chunks)
+    admits = [e for e in evs if e["name"] == "serving/admit"]
+    assert [e["args"]["conv_chunk_tokens"] for e in admits] == [7]
+    assert not any(key in (e.get("args") or {}) for e in evs
+                   for key in ("ssm_chunk_tokens", "kda_chunk_tokens",
+                               "latent_tokens_read"))
+    decodes = [e for e in evs if e["name"] == "serving/decode"]
+    assert decodes
+    for e in decodes:
+        assert e["args"]["state_rows"] == e["args"]["live"]
+    spec = srv.pool.spec
+    assert spec.state_leaves == ("conv",)
+    assert spec.state_bytes_per_row == 3 * (2 * 32 * 4)    # three conv layers
+    assert srv._latent_token_bytes == 0
+    summed = [s["args"] for s in lfm2_account["steps"]
+              if s["args"].get("state_rows")]
+    assert summed and all(
+        a["state_bytes"] == 2 * spec.state_bytes_per_row * a["state_rows"]
+        for a in summed)
+    counted = [s["args"] for s in lfm2_account["steps"]
+               if s["args"].get("moe_layer_calls")]
+    assert counted
+    for args in counted:
+        calls = args["moe_layer_calls"]
+        assert calls % 2 == 0                   # two routed layers a call
+        assert {"moe_assignments", "moe_experts_touched", "moe_load_max",
+                "moe_load_max_over_mean", "moe_bias_reordered"} <= set(args)
+        assert "moe_routed_assignments" not in args
+        assert args["moe_experts_touched"] <= 8 * calls
+    # a plain decode step of n rows: 2 layers x n x 2 assignments
+    plain = _steps_with(lfm2_account, "serving/decode", without=(
+        "serving/admit", "serving/prefill_batch", "serving/prefill_chunk"))
+    for step in plain:
+        if step["args"].get("moe_layer_calls") == 2:
+            assert step["args"]["moe_assignments"] \
+                == 2 * step["args"]["decode"] * 2
+    counts = [s["args"]["device_calls"] for s in plain]
+    assert min(counts) == DECODE_CALLS
+
+
+@pytest.fixture(scope="module")
 def beside_account(server_parts):
     """A server whose chunks read their pages in place (``kernel: "on"``),
     warmed the way the benchmark's harness warms one (a request a pass,
