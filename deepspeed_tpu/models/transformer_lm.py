@@ -2304,6 +2304,8 @@ class KVCacheSpec:
     latent_rank: int = 0               # of which the values: the row's
     # leading ``kv_lora_rank`` stored rows
     kinds: tuple = ()                  # cache_kinds(cfg): what refusal() reads
+    rep: int = 1                       # query heads that share a KV head
+    # (GQA): the rows of the page read's block (paged_attention.block_rows)
     state_group: Optional[tuple] = None    # the layers that keep a state a
     # row (no positions) and their leaves: ``(layers, ((leaf, shape a row
     # a layer, dtype), ...))``. A leaf of the cache is ``(layers, rows,
@@ -2555,7 +2557,8 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
                        quantized=cfg.kv_cache_quant, packed=packed,
                        groups=kv_cache_groups(cfg), latent=cfg.latent,
                        latent_rank=cfg.kv_lora_rank if cfg.latent else 0,
-                       kinds=cache_kinds(cfg), state_group=group)
+                       kinds=cache_kinds(cfg),
+                       rep=cfg.n_head // cfg.kv_heads, state_group=group)
 
 
 def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:

@@ -54,13 +54,17 @@ ride scalar prefetch.
 of one layer is contiguous over its heads, so all of them arrive in one
 DMA (16 heads of 128 × 64 bf16: 256 KB, 512 KB in VMEM because a 64-wide
 page fills half of each 128-lane tile — in HBM too). The query and output
-blocks carry the ``kv_group`` KV heads of the slot with the rows of each
-one's ``rep`` query heads stacked (``rep * T_pad`` rows, ``T_pad`` the
-call's query rows in whole sublane tiles: GQA's query heads of a KV head
-share its page, so they are one operand of one product), the scratch
-(``acc``, ``m``, ``l``) has the same KV-head axis, and the fold runs once
-a KV head inside the step (a static loop; with ``rep`` 1 a KV head is a
-query head). :func:`plan_grid` picks ``kv_group`` from the shapes against
+blocks carry the ``kv_group`` KV heads of the slot, and a KV head's rows
+are those of its ``rep`` query heads one head after another, ``T`` rows
+each, padded ONCE to whole sublane tiles: ``_row_tiles(rep * T)`` rows
+(:func:`block_rows`; GQA's query heads of a KV head share its page, so
+they are one operand of one product; until PR 55 each head's rows were
+padded to a tile before the heads were stacked, ``rep * _row_tiles(T)``,
+and a decode step of 4 or 8 query heads a KV head folded 32 or 64 rows
+of which 4 or 8 are read). The scratch (``acc``, ``m``, ``l``) has the
+same KV-head axis, and the fold runs once a KV head inside the step (a
+static loop; with ``rep`` 1 a KV head is a query head).
+:func:`plan_grid` picks ``kv_group`` from the shapes against
 a fixed VMEM budget (:data:`VMEM_BUDGET_BYTES`, half the v5e's default
 scoped limit): every KV head when one page of each fits, else the largest
 divisor of ``KV`` that does, and the head-group axis comes back into the
@@ -83,18 +87,23 @@ gives the run from the call's static shapes (a power of two, at most
 registers, four while theirs take no more than half); a chunk of 512 or
 1,024 rows a KV head folds one head at a time, as before.
 
-**Query rows** (PR 33). The row count is a static shape like any other:
-one row a slot for a decode step, K + 1 for a verify step, and a prefill
-chunk's 64 or 128 rows of the one slot it runs, which until PR 33 took a
-gathered dense row of ``max_seq_len`` positions in every layer because
-the kernel stopped at one sublane tile. Everything that held the tile's 8
-holds ``T_pad`` (blocks, scratch, the VMEM plan: 16 KV heads of 64 rows a
-step at Pythia's shape, 2 of 4 KV heads of 8 x 128 = 1,024 rows at
-Mellum's), the per-row causal limit and the window mask are what they
-were, and a call of up to 8 rows compiles to the program it compiled to
-before (``tests/unit/accelerator/test_chip_path.py`` holds the decode
-program's text). Rows that not even one KV head's step can hold go in two
-calls of half the rows each.
+**Query rows** (PR 33; PR 55). The row count is a static shape like any
+other: one row a slot for a decode step, K + 1 for a verify step, and a
+prefill chunk's 64 or 128 rows of the one slot it runs, which until PR 33
+took a gathered dense row of ``max_seq_len`` positions in every layer
+because the kernel stopped at one sublane tile. Everything that held the
+tile's 8 holds ``_row_tiles(rep * T)`` (blocks, scratch, the VMEM plan:
+16 KV heads of 64 rows a step at Pythia's shape, 2 of 4 KV heads of
+8 x 128 = 1,024 rows at Mellum's; 8 rows a KV head for a decode step of
+Mellum's, Granite's or LFM2's, 24 for a 5-row verify step at ``rep`` 4).
+Row ``r`` of a KV head's block is query row ``r % T`` of its head
+``r // T``: the per-row causal limit, the window mask and a head's ALiBi
+slope read those two, and the rows from ``rep * T`` on are padding. With
+``rep`` 1, or ``T`` in whole tiles, the layout and the compiled program
+are what they were (``tests/unit/accelerator/test_chip_path.py`` holds
+the decode program's text, ``tests/unit/ops/test_paged_attention.py`` the
+layout's jaxpr). Rows that not even one KV head's step can hold go in
+two calls of half the rows each.
 
 **What was measured** (v5e, stand-alone at the served shape B=64, H=KV=16,
 D=128, page 64, 32 table entries a slot, 256 pages; chip runs of PR 24):
@@ -128,6 +137,20 @@ them), Granite's (32 rows, 8 heads of 64) 0.98 -> 0.88 in runs of 4 and
 at every run (3.1-3.3: its statistics, 1.28 without them), and a chunk
 of 512 or 1,024 rows a KV head keeps the fold of one head after another
 (8.1 and 7.5 a page either way).
+
+Those 64 and 32 rows were a tile of 8 for each query head's ONE decode
+row. With a KV head's rows packed into whole tiles (PR 55; chip runs of
+PR 55, the same method, the parent's kernel beside it, max |delta| 0.0
+at every shape): Granite's and LFM2's decode rows (8 a KV head now, 8
+heads, ONE run of 8) 0.88 -> 0.65 a further page (64 slots x 15 pages,
+and 256 slots x 11: 2.59 -> 1.91 ms a call), in runs of 2 / 4 / 8 0.84 /
+0.76 / 0.65: at 8 rows the one run that read a third slower at 32 rows
+reads best, as at Pythia's 8 rows; Mellum's (8 rows, 4 heads, runs of 4)
+0.89 -> 0.63 full group, 0.85 -> 0.61 window group, in runs of 2 0.64; a
+5-row verify step at ``rep`` 4 (24 rows for 32) 0.88 -> 0.83 in runs of
+4 (2: 1.01, 8: 0.90), at ``rep`` 8 (40 for 64) 0.89 -> 0.67 (runs of 2:
+0.63-0.66). What is left at these shapes is the step itself: one 256 KB page
+a grid step, 0.32 us of bytes.
 
 Parity contract (the "dense oracle" discipline): for each head the
 per-page fold is op-for-op the dense decode kernel's
@@ -198,8 +221,8 @@ from .. import backend
 from .flash_attention import LANES, NEG_INF, SUBLANES
 
 __all__ = ["paged_decode_attention", "paged_write_columns",
-           "paged_write_runs", "plan_grid", "plan_write", "live_pages",
-           "kept_first"]
+           "paged_write_runs", "plan_grid", "block_rows", "plan_write",
+           "live_pages", "kept_first"]
 
 # what one grid step may hold in VMEM: half of the v5e's default scoped
 # limit (16 MiB), so the compiler's own temporaries fit beside it and no
@@ -240,46 +263,62 @@ def plan_grid(B: int, H: int, KV: int, D: int, Dc: int, page_size: int,
     run's scores take a quarter of the vector registers, but
     :data:`RUN_HEADS` (one for each MXU) while those four's scores take
     no more than half: all 16 heads of Pythia's decode rows (8 rows a
-    head), 4 of Mellum's and of Granite's (64 and 32 rows; Granite's 8
-    in one run read a third SLOWER than head after head on the chip),
-    4 of a 64-row chunk, and 1, the fold of one head after another,
-    from 128 rows a KV head on (a chunk of 512 or 1,024 at Mellum's and
-    Granite's shapes). PERF.md section 6, PR 53, has the sweep.
+    head), and since PR 55 packed a GQA decode step's rows all 8 of
+    Granite's and LFM2's and all 4 of Mellum's likewise (8 rows a KV
+    head: 0.65 and 0.63 us a further page, 0.76 in runs of 4 and 0.64
+    in runs of 2); 4 heads at 24 to 64 rows (a 5-row verify step at
+    ``rep`` 4 or 8; at 32 rows Granite's 8 in one run read a third
+    SLOWER than head after head on the chip), 4 of a 64-row chunk, and
+    1, the fold of one head after another, from 128 rows a KV head on (a
+    chunk of 512 or 1,024 at Mellum's and Granite's shapes). PERF.md
+    section 6, PR 53 and PR 55, has the sweeps.
 
     ``grid`` is ``(KV // kv_group, B * pages_per_slot)``, the second a
     bound: the call runs one step for each entry of :func:`live_pages`,
     not for each table entry."""
+    rows = block_rows(H // KV, query_rows)
     step_bytes = functools.partial(
-        _step_bytes, rep=H // KV, rows=_row_tiles(query_rows), D=D, Dc=Dc,
-        page_size=page_size, kv_dtype=kv_dtype, q_dtype=q_dtype,
-        quantized=quantized)
+        _step_bytes, rows=rows, D=D, Dc=Dc, page_size=page_size,
+        kv_dtype=kv_dtype, q_dtype=q_dtype, quantized=quantized)
     kv_group = max((g for g in range(1, KV + 1) if KV % g == 0
                     and step_bytes(g) <= VMEM_BUDGET_BYTES), default=1)
-    rows = H // KV * _row_tiles(query_rows)
     run = max(RUN_ROWS // rows,
               RUN_HEADS if RUN_HEADS * rows <= 2 * RUN_ROWS else 1)
     run = 1 << (min(run, kv_group).bit_length() - 1)
     return kv_group, run, (KV // kv_group, B * pages_per_slot)
 
 
-def _row_tiles(query_rows: int) -> int:
-    """``query_rows`` in whole sublane tiles: the rows a query head takes
-    in a block."""
-    return -(-query_rows // SUBLANES) * SUBLANES
+def block_rows(rep: int, query_rows: int) -> int:
+    """The rows of ONE KV head in the query and output blocks of a call
+    of ``query_rows`` rows a slot: its ``rep`` query heads' rows one
+    after another, ``rep * query_rows`` of them, in whole sublane tiles
+    (8 for a decode step of up to 8 query heads a KV head, whatever
+    ``rep``; ``rep`` times a chunk's 64 or 128)."""
+    return _row_tiles(rep * query_rows)
 
 
-def _step_bytes(kv_group: int, *, rep: int, rows: int, D: int, Dc: int,
+def _row_tiles(rows: int) -> int:
+    """``rows`` in whole sublane tiles."""
+    return -(-rows // SUBLANES) * SUBLANES
+
+
+def _step_bytes(kv_group: int, *, rows: int, D: int, Dc: int,
                 page_size: int, kv_dtype, q_dtype, quantized: bool) -> int:
-    """VMEM of one grid step of the read with ``kv_group`` KV heads of
-    ``rep`` query heads, ``rows`` query rows each."""
-    heads = kv_group * rep
+    """VMEM of one grid step of the read with ``kv_group`` KV heads,
+    ``rows`` query rows each (:func:`block_rows`). A KV head's block is
+    rounded to the VMEM tile as the ONE (rows, D) array it is, where the
+    reckoning before PR 55 rounded each of its ``rep`` heads: at ``rep``
+    > 1 with 8 or 24 bf16 rows a head (half a 16-row tile each) this
+    figure is the smaller and the truer one; no served shape's
+    ``kv_group`` moved by it (all far under the budget; ``rep`` 4 at 8
+    rows is pinned in the tests)."""
     page = 2 * _vmem_tile_bytes(Dc, page_size, kv_dtype)      # K and V
     if quantized:
         page += 2 * _vmem_tile_bytes(1, page_size, jnp.float32)
-    blocks = 2 * heads * _vmem_tile_bytes(rows, D, q_dtype)
-    scratch = heads * (_vmem_tile_bytes(rows, D, jnp.float32)
-                       + 2 * _vmem_tile_bytes(rows, LANES, jnp.float32))
-    return 2 * (kv_group * page + blocks) + scratch
+    blocks = 2 * _vmem_tile_bytes(rows, D, q_dtype)
+    scratch = _vmem_tile_bytes(rows, D, jnp.float32) \
+        + 2 * _vmem_tile_bytes(rows, LANES, jnp.float32)
+    return kv_group * (2 * (page + blocks) + scratch)
 
 
 def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
@@ -363,8 +402,8 @@ def _window_pages(starts, table, num_rows: int, page_size: int,
 
 def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
                   slope_ref, layer_ref, *refs,
-                  page_size: int, scale: float, rep: int, alibi: bool,
-                  quantized: bool, packed: bool, compute_dtype,
+                  page_size: int, scale: float, rep: int, query_rows: int,
+                  alibi: bool, quantized: bool, packed: bool, compute_dtype,
                   window: Optional[int] = None, run: int):
     # the first seven are scalar-prefetch SMEM arrays: the work list of
     # live_pages, (B,) starts, (H,) slopes and the (1,) layer of the
@@ -387,8 +426,7 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
     o_ref, acc_ref, m_ref, l_ref = refs
     g, w = pl.program_id(0), pl.program_id(1)
     kv_group = k_ref.shape[2]
-    rows = q_ref.shape[2]           # of one KV head: rep heads of t_pad rows
-    t_pad = rows // rep
+    rows = q_ref.shape[2]           # of one KV head: block_rows(rep, T)
     entry = entry_ref[w]
     slot = slot_ref[w]
     start = start_ref[slot]
@@ -402,19 +440,27 @@ def _paged_kernel(slot_ref, entry_ref, page_ref, live_ref, start_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # GQA: the rep query heads of a KV head share its page, so their
-    # t_pad rows each (the call's query rows in whole sublane tiles: 8
-    # for a decode or a narrow verify step, a chunk's 64 or 128) stand
-    # in ONE (rep * t_pad, D) operand and the fold runs once a KV head
-    # (a fold a query head, 8 rows each, was 32 small products a step at
-    # 32 / 4 heads and cost a decode token twice a prefill token:
-    # PERF.md section 6, PR 30). Row r is query row r % t_pad of head
-    # r // t_pad; with rep == 1 this is the fold it always was.
+    # GQA: the rep query heads of a KV head share its page, so their T
+    # (= query_rows) rows each stand one head after another in ONE
+    # (rows, D) operand and the fold runs once a KV head (a fold a query
+    # head, 8 rows each, was 32 small products a step at 32 / 4 heads
+    # and cost a decode token twice a prefill token: PERF.md section 6,
+    # PR 30). Row r is query row r % T of head r // T, and the rows from
+    # rep * T on are padding: the operand is padded to whole sublane
+    # tiles ONCE (PR 55; until then each head's T rows were, and a
+    # decode step's statistics ran over 32 or 64 rows of which 4 or 8
+    # are read). A padding row stands past the last head (it takes the
+    # first head's slope) and is never read; with rep == 1, or T in
+    # whole tiles, this is the fold it always was.
     pos = block_start + jax.lax.broadcasted_iota(
         jnp.int32, (rows, page_size), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
     if rep > 1:
-        head_of, row = row // t_pad, row % t_pad
+        # (a decode step's T is 1: the row is the head and its query row
+        # 0, which Mosaic folds; written out as constants the CPU's
+        # compiler contracted the ALiBi multiply-adds another way than
+        # the dense kernel's and the bitwise test caught it)
+        head_of, row = row // query_rows, row % query_rows
     def stored(ref, c):
         """Head ``c``'s (D, page_size) rows of the page as the products
         take them: a quantized tier's widened to the compute dtype."""
@@ -601,7 +647,7 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     if T > SUBLANES and _step_bytes(
-            1, rep=rep, rows=_row_tiles(T), D=D, Dc=Dc, page_size=lanes,
+            1, rows=block_rows(rep, T), D=D, Dc=Dc, page_size=lanes,
             kv_dtype=k_pages.dtype, q_dtype=q.dtype,
             quantized=quantized) > VMEM_BUDGET_BYTES:
         # not even one KV head's rows fit a step: two calls of half the
@@ -624,17 +670,8 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
         alibi = True
     table = jnp.asarray(table, jnp.int32)
 
-    # query rows ride the sublane axis: pad T up to whole sublane tiles
-    # (dead rows compute with a wider causal window and are sliced off —
-    # never all-masked, so no NaN risk)
-    t_pad = _row_tiles(T)
-    q4 = q.transpose(0, 2, 1, 3)                          # (B, H, T, D)
-    if T < t_pad:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, t_pad - T), (0, 0)))
-
-    # a KV head's rep query heads, t_pad rows each, as one operand
-    rows = rep * t_pad
-    q4 = q4.reshape(B, KV, rows, D)
+    q4 = _pack_rows(q, KV)
+    rows = q4.shape[2]
 
     kv_group, run, (groups, _) = plan_grid(
         B, H, KV, D, Dc, lanes, maxP, k_pages.dtype, q.dtype, quantized,
@@ -676,7 +713,7 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page_size=ps, scale=scale, rep=rep,
-                          alibi=alibi,
+                          query_rows=T, alibi=alibi,
                           quantized=quantized, packed=packed,
                           compute_dtype=compute_dtype, window=window,
                           run=run),
@@ -693,8 +730,29 @@ def _paged_decode_attention_local(q, k_pages, v_pages, table, starts, layer,
         interpret=backend.pallas_interpret(),
     )(slot_of, entry_of, page_of, live, starts, slopes, layer, *first, q4,
       *pools)
-    out = out.reshape(B, H, t_pad, D)[:, :, :T]
-    return out.transpose(0, 2, 1, 3).astype(out_dtype)
+    return _unpack_rows(out, T, H).astype(out_dtype)
+
+
+def _pack_rows(q: jax.Array, KV: int) -> jax.Array:
+    """``(B, T, H, D)`` queries as the read's operand ``(B, KV, rows,
+    D)``. Query rows ride the sublane axis: a KV head's ``rep`` query
+    heads, ``T`` rows each, stand one head after another (row ``r`` is
+    query row ``r % T`` of head ``r // T``) and are padded ONCE up to
+    :func:`block_rows` whole sublane tiles (dead rows see position 0 at
+    least and are sliced off: never all-masked, so no NaN risk)."""
+    B, T, H, D = q.shape
+    rep = H // KV
+    live, rows = rep * T, block_rows(rep, T)
+    q4 = q.transpose(0, 2, 1, 3).reshape(B, KV, live, D)
+    if live < rows:
+        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, rows - live), (0, 0)))
+    return q4
+
+
+def _unpack_rows(out: jax.Array, T: int, H: int) -> jax.Array:
+    """:func:`_pack_rows` back: ``(B, KV, rows, D)`` to ``(B, T, H, D)``."""
+    B, KV, _, D = out.shape
+    return out[:, :, :H // KV * T].reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------

@@ -2616,11 +2616,19 @@ class ServingEngine:
         page group, the slots in it and the pages the steps fold (more
         than the steps where a step is a block: the latent read; counted
         like ``pool_writes``: after the dispatch, from the host's
-        mirror). A chunk names its one slot and where it starts."""
+        mirror). A chunk names its one slot and where it starts. Beside
+        them, for K/V pages, ``read_rows`` / ``read_rows_live``: the rows
+        of a KV head's block that every page is folded into, and those
+        of them that are somebody's (``PagedKVPool.rows_a_read_block``)."""
         work = self.pool.pages_read(rows, slots, starts)
-        if work is not None:
-            sp.set(pool_reads=work[0], read_slots=work[1],
-                   pool_read_pages=work[2])
+        if work is None:
+            return
+        attrs = dict(pool_reads=work[0], read_slots=work[1],
+                     pool_read_pages=work[2])
+        block = self.pool.rows_a_read_block(rows)
+        if block is not None:
+            attrs.update(read_rows=block[0], read_rows_live=block[1])
+        sp.set(**attrs)
 
     def _state_rows(self, running) -> tuple:
         """For a model with a recurrent state, the decode program's

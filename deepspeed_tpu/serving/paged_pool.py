@@ -935,6 +935,20 @@ class PagedKVPool(SlotPool):
             self.spec.latent_rank, page_lanes(self.page_size),
             self.spec.dtype)
 
+    def rows_a_read_block(self, count: int):
+        """``(rows, live)`` of ONE KV head's block in the K/V read of a
+        dispatch of ``count`` query rows a slot: the rows the kernel
+        folds a page (``paged_attention.block_rows``: whole sublane
+        tiles) and those of them somebody reads, ``rep x count``
+        (``read_rows`` / ``read_rows_live`` on the decode, verify and
+        chunk spans: how full the read's tiles are). ``None`` for the
+        latent read, whose block is every head's rows."""
+        if self.spec.latent:
+            return None
+        from ..ops.attention.paged_attention import block_rows
+
+        return block_rows(self.spec.rep, count), self.spec.rep * count
+
     def pages_read(self, count: int, slots=None, starts=None):
         """``(steps, slots, pages)`` of the kernel read's work list for a
         dispatch of ``count`` query rows a slot, from the host's mirror

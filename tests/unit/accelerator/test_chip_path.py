@@ -991,13 +991,20 @@ def test_a_chunk_beside_decode_compiles_for_a_described_v5e_as_served(
     # first 16 digits; Mellum's taken of commit 4378523 and of PR 50's tree
     # alike, and re-taken at PR 53, whose K/V read folds a step's KV heads
     # a run at a time: 4a3978ddd2f56143 before, the two paged_decode
-    # bodies alone differ, ide measured on both, PERF.md section 6;
+    # bodies alone differ, ide measured on both, PERF.md section 6; and at
+    # PR 55, whose K/V read packs a KV head's query rows into whole tiles:
+    # f43b131370936a4f before; what differs is the DECODE rows' read, the
+    # two paged_decode bodies of bf16[64,4,64,128], now [64,4,8,128], and
+    # XLA's small fusions that lay those rows out; the chunk's two
+    # paged_decode bodies bf16[1,4,1024,128], the eight paged_write and
+    # the routed FFN's two are the text they were; ide measured on both,
+    # PERF.md section 6, PR 55;
     # Moonlight's re-taken at PR 51, whose latent read takes a block of
     # pages a grid step: d12581f2ad0b359a before, reason measured on both,
-    # and unchanged by PR 53)
+    # and unchanged by PR 53 and PR 55)
     import hashlib
 
-    pinned = {"mellum2-12b-a2b5-paged": "f43b131370936a4f",
+    pinned = {"mellum2-12b-a2b5-paged": "9d4509a5d64249a7",
               "moonlight-16b-a3b-mla": "1830eba84bdfb003"}
     if config in pinned:
         digest = hashlib.sha256(_program_text(compiled).encode()).hexdigest()

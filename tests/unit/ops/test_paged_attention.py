@@ -23,6 +23,11 @@ from deepspeed_tpu.ops.attention.paged_attention import (
 )
 
 
+# the dense oracle, compiled once for the rows of a test that asks it row
+# after row (called eagerly, every row lowers and compiles the kernel anew)
+_dense_rows = jax.jit(decode_attention, static_argnames=("block_s",))
+
+
 def _make_paged(rng, B, KV, D, S, ps, n_free=2, dtype=np.float32):
     """Random dense positions-minor cache (B, KV, D, S) cut into pages at
     a random physical placement. Returns (dense_k, dense_v, k_pages,
@@ -115,10 +120,11 @@ def test_garbage_pages_and_sentinels_never_reach_output():
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
 
 
-def _reference_rows(q, dense_k, dense_v, starts, window=None):
+def _reference_rows(q, dense_k, dense_v, starts, window=None, slopes=None):
     """Plain fp32 softmax reference with per-row causal limits: row t of
     slot b attends cache positions [0, starts[b] + t], with a ``window``
-    the last ``window`` of them."""
+    the last ``window`` of them, with ``slopes`` under head h's ALiBi
+    bias ``slopes[h] * (pos - (starts[b] + t))``."""
     B, T, H, D = q.shape
     _, KV, _, S = dense_k.shape
     rep = H // KV
@@ -130,6 +136,9 @@ def _reference_rows(q, dense_k, dense_v, starts, window=None):
     limit = (starts[:, None, None, None]
              + np.arange(T)[None, :, None, None])
     seen = pos <= limit
+    if slopes is not None:
+        s = s + np.asarray(slopes, np.float64)[None, None, :, None] \
+            * (pos - limit)
     if window is not None:
         seen &= pos > limit - window
     s = np.where(seen, s, -np.inf)
@@ -434,12 +443,172 @@ def test_rows_and_cache_tiers_match_dense_oracle(T, tier):
                                  jnp.asarray(starts), **scales)
     assert out.shape == (B, T, H, D) and out.dtype == q.dtype
     for t in range(T):
-        oracle = decode_attention(
+        oracle = _dense_rows(
             q[:, t], dense_k, dense_v, jnp.asarray(starts + t + 1),
             block_s=ps, **(dense_scales if scales else {}))
         np.testing.assert_array_equal(
             np.asarray(out[:, t].astype(jnp.float32)),
             np.asarray(oracle.astype(jnp.float32)), err_msg=f"row {t}")
+
+
+# PR 55: a KV head's rep query heads, T rows each, stand one head after
+# another and are padded to whole sublane tiles ONCE. (rep, T, what else
+# the call carries): every packed layout (rep * T short of a tile's
+# multiple) under every mask and tier, the layouts that are what they
+# were (rep 1; T in whole tiles) beside them, and the packed tier at
+# three of them
+_PACKED_CASES = [
+    (rep, T, extra) for rep in (1, 4, 8) for T in (1, 3, 5, 8, 16)
+    for extra in ("bf16", "window", "alibi", "int8")
+] + [(1, 5, "int32-packed"), (4, 5, "int32-packed"),
+     (8, 3, "int32-packed")]
+
+
+@pytest.mark.parametrize("rep,T,extra", _PACKED_CASES)
+def test_packed_rows_and_cache_tiers_match_dense_oracle(rep, T, extra):
+    """The parity contract, row by row and head by head: row t of a
+    T-row call is the dense kernel's answer for a query at ``start + t``
+    (the call's columns are already in the pages), bitwise, for each K/V
+    tier and any number of query heads a KV head: a row that stood at
+    the wrong ``r // T`` or ``r % T`` is another head's or another
+    position's answer. Where the dense kernel has no twin (a window; an
+    ALiBi row past the first, whose bias it writes another way) the
+    oracle is the same read with EVERY query head given a KV head of its
+    own (its KV head's pages repeated): ``rep`` 1, the layout that never
+    changed, which the plain reference holds in its turn. A block of 64
+    rows and more (``T`` in whole tiles: the program of before PR 55) is
+    held to one unit in bf16's last place in at most one value of a
+    thousand: the CPU's compiler orders such a block's sums another way
+    than the oracle's few rows, and the parent's kernel differs there
+    from the same oracle in the same value."""
+    rng = np.random.default_rng(55 + 16 * rep + T)
+    B, KV, D, ps, per_slot = 2, 2, 64, 16, 4
+    H, P = KV * rep, B * per_slot + 1
+    table = rng.permutation(P)[:B * per_slot].reshape(B, per_slot) \
+        .astype(np.int32)
+    starts = np.asarray([ps - 2, 3 * ps - T], np.int32)   # rows cross a page
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.bfloat16)
+    kwargs, dense_kwargs = {}, {}
+    if extra in ("int8", "int32-packed"):
+        k8 = rng.integers(-127, 128, (P, KV, D, ps)).astype(np.int8)
+        v8 = rng.integers(-127, 128, (P, KV, D, ps)).astype(np.int8)
+        ks = rng.uniform(0.01, 0.1, (P, KV, ps)).astype(np.float32)
+        vs = rng.uniform(0.01, 0.1, (P, KV, ps)).astype(np.float32)
+        k_pages, v_pages = jnp.asarray(k8), jnp.asarray(v8)
+        dense_k, dense_v = _gather(k8, table), _gather(v8, table)
+        if extra == "int32-packed":
+            k_pages, v_pages, dense_k, dense_v = (
+                pack_int8_sublanes(jnp.asarray(x))
+                for x in (k_pages, v_pages, dense_k, dense_v))
+        kwargs = dict(k_scale_pages=jnp.asarray(ks),
+                      v_scale_pages=jnp.asarray(vs))
+        dense_kwargs = dict(k_scale=_gather(ks, table),
+                            v_scale=_gather(vs, table))
+    else:
+        k_pages, v_pages = (
+            jnp.asarray(rng.standard_normal((P, KV, D, ps)), jnp.bfloat16)
+            for _ in range(2))
+        dense_k, dense_v = (_gather(np.asarray(x), table)
+                            for x in (k_pages, v_pages))
+    window = slopes = None
+    if extra == "window":
+        window = ps + ps // 2
+        kwargs = dict(window=window)
+        if starts[1] - window + 1 >= ps:    # behind every row's window:
+            table[1, 0] = P                 # recycled
+    if extra == "alibi":
+        slopes = 2.0 ** -np.linspace(1, 8, H)
+        kwargs = dict(alibi_slopes=jnp.asarray(slopes, jnp.float32))
+    out = paged_decode_attention(q, k_pages, v_pages, jnp.asarray(table),
+                                 jnp.asarray(starts), **kwargs)
+    assert out.shape == (B, T, H, D) and out.dtype == q.dtype
+    out = np.asarray(out.astype(jnp.float32))
+    assert np.isfinite(out).all()
+
+    def same(oracle):
+        oracle = np.asarray(oracle.astype(jnp.float32)).reshape(out.shape)
+        if rep * T < 64:
+            np.testing.assert_array_equal(out, oracle)
+            return
+        assert rep == 1 or T % 8 == 0
+        np.testing.assert_allclose(out, oracle, rtol=2.0 ** -7, atol=0)
+        assert (out != oracle).mean() <= 1e-3
+
+    if extra != "window" and (extra != "alibi" or T == 1):
+        # every row at once: row t of slot b as a sequence of its own
+        def rows(x):
+            return jnp.asarray(np.repeat(np.asarray(x), T, axis=0))
+        oracle = decode_attention(
+            q.reshape(B * T, H, D), rows(dense_k), rows(dense_v),
+            jnp.asarray((starts[:, None] + np.arange(T) + 1).reshape(-1)),
+            block_s=ps, **{k: rows(v) for k, v in dense_kwargs.items()},
+            **(kwargs if extra == "alibi" else {}))
+        same(oracle)
+        return
+    ref = _reference_rows(
+        np.asarray(q.astype(jnp.float32)), dense_k.astype(np.float32),
+        dense_v.astype(np.float32), starts, window=window, slopes=slopes)
+    np.testing.assert_allclose(out, ref, atol=3e-2, rtol=3e-2)
+    if rep > 1:
+        own = paged_decode_attention(
+            q, jnp.repeat(k_pages, rep, axis=1),
+            jnp.repeat(v_pages, rep, axis=1), jnp.asarray(table),
+            jnp.asarray(starts), **kwargs)
+        same(own)
+
+
+def _tiles_then_heads(q, KV):
+    """The operand as it was until PR 55: each query head's T rows padded
+    to whole sublane tiles, THEN a KV head's rep heads stacked."""
+    B, T, H, D = q.shape
+    t_pad = -(-T // 8) * 8
+    q4 = q.transpose(0, 2, 1, 3)
+    if T < t_pad:
+        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, t_pad - T), (0, 0)))
+    return q4.reshape(B, KV, H // KV * t_pad, D)
+
+
+def _heads_then_tiles_back(out, T, H):
+    B, KV, rows, D = out.shape
+    out = out.reshape(B, H, rows * KV // H, D)[:, :, :T]
+    return out.transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("T", [1, 3, 5, 8, 16])
+@pytest.mark.parametrize("rep", [1, 4, 8])
+def test_a_kv_heads_rows_are_packed_once_into_whole_tiles(rep, T):
+    """The wrapper's half of the layout, without a kernel: row ``r`` of a
+    KV head's block is query row ``r % T`` of its head ``r // T``, the
+    block is ``rep * T`` rows in whole tiles (not ``rep`` heads of one
+    tile each: a decode step of Granite's, LFM2's or Mellum's folds the
+    8 rows it reads), and the way back is its inverse. With ``rep`` 1,
+    or ``T`` in whole tiles, the program is the one it was: the same
+    jaxpr as the former layout's."""
+    B, KV, D = 2, 2, 8
+    H = KV * rep
+    q = np.arange(B * T * H * D, dtype=np.float32).reshape(B, T, H, D)
+    packed = np.asarray(paged_attention._pack_rows(jnp.asarray(q), KV))
+    rows = -(-rep * T // 8) * 8
+    assert packed.shape == (B, KV, rows, D)
+    assert paged_attention.block_rows(rep, T) == rows
+    for r in range(rep * T):
+        np.testing.assert_array_equal(
+            packed[:, :, r], q[:, r % T].reshape(B, KV, rep, D)[:, :, r // T])
+    assert not packed[:, :, rep * T:].any()
+    np.testing.assert_array_equal(
+        np.asarray(paged_attention._unpack_rows(jnp.asarray(packed), T, H)),
+        q)
+    was = _tiles_then_heads(jnp.asarray(q), KV)
+    same = rep == 1 or T % 8 == 0
+    assert (was.shape == packed.shape) == same
+    if same:
+        assert str(jax.make_jaxpr(
+            lambda x: paged_attention._pack_rows(x, KV))(q)) \
+            == str(jax.make_jaxpr(lambda x: _tiles_then_heads(x, KV))(q))
+        assert str(jax.make_jaxpr(
+            lambda x: paged_attention._unpack_rows(x, T, H))(packed)) \
+            == str(jax.make_jaxpr(
+                lambda x: _heads_then_tiles_back(x, T, H))(packed))
 
 
 def test_heads_that_do_not_fit_vmem_split_into_groups(monkeypatch):
@@ -542,15 +711,20 @@ def test_fold_in_runs_is_the_head_after_head_fold_bit_for_bit(
 
 @pytest.mark.parametrize("shape,T,rows,kv_group,run", [
     # (B, H, KV, D, lanes, entries a slot), query rows of the call -> the
-    # rows of one KV head, the heads of a step and of a run
+    # rows of one KV head (its rep heads' rows packed, PR 55: in brackets
+    # what rep heads of one tile each took), the heads of a step and of a
+    # run
     ((64, 16, 16, 128, 128, 32), 1, 8, 16, 16),       # Pythia decode
     ((64, 16, 16, 128, 128, 32), 5, 8, 16, 16),       # ... verify
     ((1, 16, 16, 128, 128, 32), 64, 64, 16, 4),       # docs' chunk
     ((64, 16, 16, 128, 128, 32), 16, 16, 16, 8),
     ((1, 16, 16, 128, 128, 32), 128, 128, 16, 1),
-    ((64, 32, 4, 128, 128, 64), 1, 64, 4, 4),         # Mellum decode
-    ((64, 32, 8, 64, 128, 128), 1, 32, 8, 4),         # Granite decode
-    ((64, 8, 2, 64, 128, 16), 1, 32, 2, 2),           # fewer heads than 4
+    ((64, 32, 4, 128, 128, 64), 1, 8, 4, 4),          # Mellum decode [64]
+    ((64, 32, 8, 64, 128, 128), 1, 8, 8, 8),          # Granite decode [32]
+    ((256, 32, 8, 64, 128, 32), 1, 8, 8, 8),          # LFM2 decode [32]
+    ((64, 32, 8, 64, 128, 128), 5, 24, 8, 4),         # ... verify [32]
+    ((64, 32, 8, 64, 128, 128), 8, 32, 8, 4),         # ... 8 rows a head
+    ((64, 8, 2, 64, 128, 16), 1, 8, 2, 2),            # fewer heads than 4
     ((1, 32, 8, 64, 128, 128), 128, 512, 4, 1),       # Granite's chunk
     ((1, 32, 4, 128, 128, 64), 128, 1024, 2, 1),      # Mellum's chunk
 ])
@@ -559,9 +733,11 @@ def test_the_run_follows_the_rows_of_a_kv_head(shape, T, rows, kv_group,
     """``plan_grid``'s second return, from static shapes alone: a power of
     two, at most the step's heads, ``RUN_ROWS // rows`` but ``RUN_HEADS``
     up to 64 rows; a chunk of 512 or 1,024 rows a KV head folds head
-    after head as it always did."""
+    after head as it always did. The rows are a KV head's ``rep * T`` in
+    whole tiles (``block_rows``), so a GQA decode step runs as Pythia's
+    does."""
     B, H, KV, D, lanes, per_slot = shape
-    assert H // KV * paged_attention._row_tiles(T) == rows
+    assert paged_attention.block_rows(H // KV, T) == rows
     got = plan_grid(B, H, KV, D, D, lanes, per_slot, jnp.bfloat16,
                     jnp.bfloat16, False, query_rows=T)
     assert got[:2] == (kv_group, run)
@@ -724,9 +900,9 @@ def test_dead_slots_between_live_ones_are_no_step_and_change_nothing(
     dense_k = jnp.asarray(_gather(np.asarray(clean_k), table))
     dense_v = jnp.asarray(_gather(np.asarray(clean_v), table))
     for t in range(T):
-        oracle = decode_attention(q[:, t], dense_k, dense_v,
-                                  jnp.asarray(starts + t + 1), block_s=ps,
-                                  **dense_scales)
+        oracle = _dense_rows(q[:, t], dense_k, dense_v,
+                             jnp.asarray(starts + t + 1), block_s=ps,
+                             **dense_scales)
         np.testing.assert_array_equal(out[alive, t].astype(np.float32),
                                       np.asarray(oracle, np.float32)[alive],
                                       err_msg=f"row {t}")

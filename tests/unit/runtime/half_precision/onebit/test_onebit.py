@@ -119,6 +119,9 @@ class TestCompressedAllreduce:
         xs = rng.standard_normal((8, n)).astype(np.float32)
         true_mean = xs.mean(axis=0)
 
+        # (jitted: called eagerly, the 40 rounds below re-ran the
+        # shard_map's dispatch op by op, 270 s of tier-1's 1,470)
+        @jax.jit
         @functools.partial(
             shard_map, mesh=mesh,
             in_specs=(P("dp"), P("dp"), P("dp")),

@@ -325,6 +325,10 @@ def test_chunked_prefill_through_the_pages_matches_the_dense_arm(stack, case):
         assert args["pos"] == start
         assert (args["pool_reads"], args["read_slots"]) == (steps, 1)
         assert args["pool_read_pages"] == steps     # a step of K/V: a page
+        # a KV head's block: its rep heads' rows of the chunk, whole tiles
+        live = srv_on.pool.spec.rep * srv_on.prefill_chunk
+        assert (args["read_rows"], args["read_rows_live"]) \
+            == (-(-live // 8) * 8, live)
         assert args["pool_writes"] >= 1
     assert spans(srv_off) and not any(
         "pool_reads" in args or "read_slots" in args
@@ -392,6 +396,11 @@ def test_freed_slots_are_no_step_under_churn_with_the_finite_guard(
     for args, ((reads, slots), total, seated) in zip(spans, record):
         assert (args["pool_reads"], args["read_slots"]) == (reads, slots)
         assert args["pool_read_pages"] == reads == total and slots == seated
+        # the rows of a KV head's block: its rep heads' rows packed into
+        # whole sublane tiles, and those of them somebody reads
+        live = srv.pool.spec.rep * (spec["k"] + 1 if spec else 1)
+        assert (args["read_rows"], args["read_rows_live"]) \
+            == (-(-live // 8) * 8, live)
     # slots stood freed beside decoding ones, and cost nothing
     assert any(0 < slots < 4 for (_, slots), _, _ in record)
     assert srv_off.pool.pages_read(1) is None

@@ -337,6 +337,8 @@ def test_freed_slots_of_both_groups_are_no_step_under_churn(stack):
     for args, ((reads, slots), total, seated) in zip(spans, record):
         assert (args["pool_reads"], args["read_slots"]) == (reads, slots)
         assert reads == total and slots == seated
+        # a KV head's two query heads' decode rows share one tile
+        assert (args["read_rows"], args["read_rows_live"]) == (8, 2)
     assert any(0 < slots < 3 for (_, slots), _, _ in record)
 
 
