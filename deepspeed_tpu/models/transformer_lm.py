@@ -240,6 +240,19 @@ class TransformerConfig:
     # over n_embd channels between two gates, no bias, no activation
     # (ShortConvMixer has the equations); the state is the convolution's tail
     conv_taps: int = 3
+    # Lightning layers (``layer_types`` "lightning", beside "attention"
+    # layers, in ANY order: the stack is run as a list of runs where it
+    # does not repeat): n_head heads of head_dim with their own q, k and v,
+    # a float32 state of head_dim x head_dim a head under one constant decay
+    # a head (models/lightning_sparse.py, ops/lightning.py); the layer
+    # rotates its own q and k whatever pos_emb says of the attention layers
+    # learned block-sparse attention in the attention layers' place
+    # (ops/attention/sparse_index.py has the equations), as frozen by
+    # transformer_config from {"kernel_size", "kernel_stride", "block_size",
+    # "init_blocks", "window_size", "topk", "dense_len"}; None: every key
+    sparse_attention: Optional[tuple] = None
+    attn_output_gate: bool = False      # o (.) sigmoid(W_z x) before o_proj
+    # (the sparse attention layers')
     experts_held: Optional[int] = None  # the routed FFN holds experts
     # [0, experts_held) of n_experts (one chip's share of a layer that
     # several divide): the router and the top-k run over all n_experts, the
@@ -259,12 +272,12 @@ class TransformerConfig:
                                              "full_attention",
                                              "power_retention",
                                              "mamba", "kda", "conv",
-                                             "attention"}
+                                             "lightning", "attention"}
             if kinds or len(self.layer_types) != self.n_layer:
                 raise ValueError(
                     f"layer_types names n_layer={self.n_layer} layers as "
                     f"sliding_attention | full_attention | power_retention "
-                    f"| mamba | kda | conv | attention; "
+                    f"| mamba | kda | conv | lightning | attention; "
                     f"got {len(self.layer_types)} entries, unknown "
                     f"{sorted(kinds)}")
             if set(STATE_KINDS + ("attention",)) & set(self.layer_types):
@@ -335,6 +348,17 @@ class TransformerConfig:
                     "and knows no layer kinds (layer_types) but the state "
                     "layers beside it: no window and no retention yet "
                     "(ROADMAP.md, Reach)")
+        if self.sparse_attention is not None:
+            self.sparse.check()
+            if not self.hybrid or self.latent or self.pos_emb != "none":
+                raise ValueError(
+                    "sparse_attention is the attention layers' of a stack "
+                    "of state and attention layers (layer_types), K/V a "
+                    "head, without positions (pos_emb='none'): the index "
+                    "scores keys that carry none (ROADMAP.md, Reach)")
+        if self.attn_output_gate and self.sparse_attention is None:
+            raise ValueError("attn_output_gate is the sparse attention "
+                             "layers' (sparse_attention)")
         for feature in ("kv_cache_quant", "int8_weights"):
             why = getattr(self, feature) \
                 and refusal(cache_kinds(self), feature)
@@ -342,25 +366,33 @@ class TransformerConfig:
                 raise ValueError(why)
 
     def _check_hybrid(self) -> None:
-        """State layers of ONE kind, ``mamba``, ``kda`` or ``conv``, stand
-        beside ``attention`` layers (full, K/V a head or latent, with
-        ``pos_emb`` "rotary" or "none") in a pattern that repeats: one attention layer
-        a period, the same number of state layers before and after it in
-        every period (:attr:`hybrid_period`). The FFN may be routed; the
+        """State layers of ONE kind (:data:`STATE_KINDS`) stand beside
+        ``attention`` layers (full, K/V a head or latent, with ``pos_emb``
+        "rotary" or "none"; sparse under ``sparse_attention``). In a
+        pattern that repeats, one attention layer a period and the same
+        number of state layers before and after it in every period
+        (:attr:`hybrid_period`), the FFN may be routed, and the
         ``first_k_dense`` layers with a plain one are state layers at the
-        head of the first period."""
+        head of the first period. Any other order (adjacent attention
+        layers, runs of unequal length) is run as a list of runs
+        (:attr:`hybrid_runs`) with a plain FFN in every layer."""
         types = self.layer_types
         n_att = types.count("attention")
         state = set(types) - {"attention"}
-        if len(state) != 1 or not state < set(STATE_KINDS) or not n_att \
-                or self.n_layer % n_att \
-                or types != types[:self.n_layer // n_att] * n_att:
+        if len(state) != 1 or not state < set(STATE_KINDS) or not n_att:
             raise ValueError(
-                f"mamba, kda or conv layers and attention layers come as a "
-                f"pattern "
-                f"with ONE attention layer that repeats over the layers (the "
-                f"two stacked leaves are run period by period); got "
-                f"{list(types)}")
+                f"state layers of ONE kind ({' | '.join(STATE_KINDS)}) "
+                f"stand beside attention layers, as a pattern with ONE "
+                f"attention layer that repeats over the layers or as any "
+                f"list of runs of the two; got {list(types)}")
+        if not self.hybrid_repeats and (self.n_experts
+                                        or self.first_k_dense):
+            raise ValueError(
+                f"a routed FFN or leading dense layers count the layers "
+                f"period by period: a pattern with ONE attention layer "
+                f"that repeats over the layers; got {list(types)} (a "
+                f"pattern without a period is run as a list of runs, "
+                f"hybrid_runs, with a plain FFN in every layer)")
         if self.mamba and (
                 not (self.mamba_n_heads and self.mamba_d_head
                      and self.mamba_d_state) or self.mamba_n_groups != 1
@@ -384,7 +416,7 @@ class TransformerConfig:
                 f"state is its tail); got conv_taps={self.conv_taps}")
         if self.parallel_residual:
             raise ValueError("state layers know the sequential residual")
-        if self.first_k_dense > self.hybrid_period[0]:
+        if self.first_k_dense and self.first_k_dense > self.hybrid_period[0]:
             raise ValueError(
                 f"first_k_dense={self.first_k_dense} leading layers with a "
                 f"plain FFN are state layers at the head of the first "
@@ -442,6 +474,25 @@ class TransformerConfig:
         return self.layer_types is not None and "conv" in self.layer_types
 
     @property
+    def lightning(self) -> bool:
+        """``lightning`` layers beside ``attention`` layers: a state group
+        of the linear attention's state over the former, K/V (and the
+        index's group means, where the attention is sparse) over the
+        latter."""
+        return self.layer_types is not None \
+            and "lightning" in self.layer_types
+
+    @property
+    def sparse(self):
+        """``sparse_attention`` as ``ops.attention.sparse_index.SparseSizes``,
+        or None."""
+        if self.sparse_attention is None:
+            return None
+        from ..ops.attention.sparse_index import SparseSizes
+
+        return SparseSizes(**dict(self.sparse_attention))
+
+    @property
     def hybrid(self) -> Optional[str]:
         """The kind of the state layers that stand beside ``attention``
         layers, one of :data:`STATE_KINDS`; None for a model of one stack."""
@@ -453,6 +504,30 @@ class TransformerConfig:
     def kda_width(self) -> int:
         """``kda_n_heads * kda_d_head``: the width of q, k and v each."""
         return self.kda_n_heads * self.kda_d_head
+
+    @property
+    def hybrid_repeats(self) -> bool:
+        """Whether the state and attention layers come as a pattern with
+        ONE attention layer that repeats over the layers
+        (:attr:`hybrid_period`); else they are :attr:`hybrid_runs`."""
+        n_att = self.layer_types.count("attention")
+        return not self.n_layer % n_att and self.layer_types \
+            == self.layer_types[:self.n_layer // n_att] * n_att
+
+    @property
+    def hybrid_runs(self) -> tuple:
+        """The stack as a list of runs, ``((kind, first, count), ...)``:
+        ``count`` layers of one kind side by side, the ``first`` of them
+        counted among the layers of that kind (where its slice of the
+        kind's stacked leaves and its cache lie)."""
+        runs, seen = [], {}
+        for kind in self.layer_types:
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, seen.get(kind, 0), 1])
+            seen[kind] = seen.get(kind, 0) + 1
+        return tuple(tuple(run) for run in runs)
 
     @property
     def hybrid_period(self) -> tuple:
@@ -471,7 +546,7 @@ class TransformerConfig:
 
 
 # the kinds of state layer that stand beside ``attention`` layers
-STATE_KINDS = ("mamba", "kda", "conv")
+STATE_KINDS = ("mamba", "kda", "conv", "lightning")
 
 FAMILY_PRESETS = {
     "gpt2": dict(pos_emb="learned", norm="layernorm", activation="gelu"),
@@ -536,6 +611,20 @@ FAMILY_PRESETS = {
                      tie_word_embeddings=True, layer_norm_epsilon=1e-5,
                      qk_norm=True, scoring_func="sigmoid",
                      topk_norm_eps=1e-6),
+    # MiniCPM-SALA (OpenBMB; model_type minicpm_sala): Lightning
+    # linear-attention layers beside learned block-sparse GQA layers in the
+    # published, irregular order (``mixer_types`` "lightning-attn" |
+    # "minicpm4", or ``layer_types`` "lightning" | "sparse_attention"), a
+    # norm on each head of q and k in both, an output gate on both (the
+    # sparse layers' where ``sparse_attention`` is given), no
+    # positions in the attention layers and a rotary in the Lightning
+    # layers, MiniCPM's three scalars (``embedding_multiplier``,
+    # ``residual_multiplier``, ``logits_scaling``), an untied head. Widths,
+    # the pattern, the scalars and ``sparse_attention`` are the caller's.
+    "minicpm_sala": dict(pos_emb="none", norm="rmsnorm",
+                         activation="swiglu", qkv_bias=False,
+                         mlp_bias=False, tie_word_embeddings=False,
+                         layer_norm_epsilon=1e-6, qk_norm=True),
 }
 
 
@@ -553,9 +642,21 @@ def transformer_config(family: str, **overrides) -> TransformerConfig:
     reference module_inject/replace_policy.py)."""
     if family not in FAMILY_PRESETS:
         raise ValueError(f"unknown family {family!r}; know {sorted(FAMILY_PRESETS)}")
-    overrides = {k: _freeze(v) if k in ("layer_types", "rope_parameters")
+    overrides = {k: _freeze(v) if k in ("layer_types", "rope_parameters",
+                                        "sparse_attention")
                  else v for k, v in overrides.items()}
     cfg = {**FAMILY_PRESETS[family], **overrides}
+    mixers = cfg.pop("mixer_types", None)
+    if mixers is not None:
+        # published a layer as "lightning-attn" | "minicpm4"
+        cfg.setdefault("layer_types", tuple(mixers))
+    if {"lightning", "lightning-attn"} & set(cfg.get("layer_types") or ()):
+        # (beside Lightning layers the sparse layers are the stack's
+        # ``attention``: what makes them sparse is ``sparse_attention``)
+        names = {"lightning-attn": "lightning", "minicpm4": "attention",
+                 "sparse_attention": "attention"}
+        cfg["layer_types"] = tuple(names.get(kind, kind)
+                                   for kind in cfg["layer_types"])
     if "conv" in (cfg.get("layer_types") or ()):
         # (published beside "conv" as "full_attention": the one attention
         # kind of a stack of state and attention layers)
@@ -1851,7 +1952,7 @@ class TransformerMLP(nn.Module):
 
 class TransformerBlock(nn.Module):
     config: TransformerConfig
-    kind: Optional[str] = None      # "mamba" | "kda" | "conv" | "attention":
+    kind: Optional[str] = None      # a STATE_KINDS entry | "attention":
     # the layer's kind in a model of state and attention layers, whose
     # stacked leaves are one a kind; None: the configuration's one mixer
 
@@ -1860,11 +1961,16 @@ class TransformerBlock(nn.Module):
                  deterministic: bool = True, kv_cache=None, layer=None,
                  experts=None, ffn_layer=None):
         cfg = self.config
+        if self.kind == "lightning" or cfg.sparse_attention is not None:
+            from .lightning_sparse import LightningMixer, SparseAttention
         attention, name = (Mamba2Mixer, "mamba") if self.kind == "mamba" \
             else (KDAMixer, "kda") if self.kind == "kda" \
             else (ShortConvMixer, "conv") if self.kind == "conv" \
+            else (LightningMixer, "lightning") if self.kind == "lightning" \
             else (PowerRetention if cfg.retention else
-                  LatentAttention if cfg.latent else CachedAttention, "attn")
+                  LatentAttention if cfg.latent else
+                  SparseAttention if cfg.sparse_attention is not None
+                  else CachedAttention, "attn")
         a, new_cache = attention(cfg, name=name)(
             _norm(cfg, "ln_1")(x), decode=decode, deterministic=deterministic,
             kv_cache=kv_cache, layer=layer)
@@ -2067,6 +2173,10 @@ CACHE_KINDS = {     # in the order a refusal is looked up (cache_kinds)
     "ssm": "a state group beside K/V",      # mamba beside attention layers
     "kda": "a KDA state group",             # kda beside attention layers
     "conv": "a convolution-tail state group",   # conv beside attention layers
+    "sparse": "the index of learned sparse attention",  # sparse_attention:
+    # group means of the keys beside the K/V pages, a choice of blocks (it
+    # stands beside lightning layers and is asked first)
+    "lightning": "a Lightning state group",     # lightning beside attention
     "latent": "latent attention's cache",   # one row a token (kv_lora_rank)
     "window_only": "sliding-window layers alone",
     "window": "a window page group",        # sliding beside full layers
@@ -2190,6 +2300,55 @@ CACHE_REFUSALS = {
     ("conv", "int8_weights"):
         "int8_weights does not reach the conv layers' taps, which are a "
         "parameter of the mixer and no Dense",
+    ("lightning", "spec_decode"):
+        "a rejected draft's tokens are in the linear attention's state for "
+        "good: verify_k's rollback moves an index, which hides K/V columns "
+        "and nothing of a state",
+    ("lightning", "prefix_cache"):
+        "a hit maps the K/V pages of the prompt's start and would need the "
+        "state as it stood at the hit's boundary, which nothing keeps (a "
+        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
+    ("lightning", "roles"):
+        "pages are the unit of a handoff: the slot's state rows would have "
+        "to be shipped beside them",
+    ("lightning", "tensor_parallel"):
+        "the state leaf has no placement on the model axis and the "
+        "Lightning kernels are not wrapped for a mesh",
+    ("lightning", "tensor_parallel_serving"):
+        "the state leaf has no placement on the model axis and the "
+        "Lightning kernels are not wrapped for a mesh",
+    ("lightning", "zero_inference"):
+        "it streams one layer's block parameters at a time out of ONE "
+        "stacked tree; lightning and attention layers are two, and the "
+        "state is not threaded through the streamed layers",
+    ("lightning", "kv_cache_quant"):
+        "kv_cache_quant quantizes K/V columns; the state group beside them "
+        "is float32 and the tier has not been run beside it",
+    ("lightning", "int8_weights"):
+        "int8_weights has not been run through the lightning layers (their "
+        "norms on q, k and the output are parameters of the mixer and no "
+        "Dense)",
+    ("sparse", "spec_decode"):
+        "a verify step's K + 1 rows of every slot would each choose their "
+        "own blocks, and a rejected draft's keys are in the index's group "
+        "means for good: the read of a chosen page list takes one row a "
+        "slot or one slot's chunk",
+    ("sparse", "prefix_cache"):
+        "a page shared by a hit would have to share its group means, and a "
+        "copy-on-write fork copies K/V pages while a group is still filling",
+    ("sparse", "roles"):
+        "a handoff ships K/V pages; the index's group means beside them "
+        "have not been driven through one",
+    ("sparse", "tensor_parallel_serving"):
+        "the choice sums the query heads of a KV head and the chosen page "
+        "list is one device's: the read has not been wrapped for a mesh",
+    ("sparse", "zero_inference"):
+        "the choice of blocks reads the whole row's keys, which the "
+        "streamed layers' one-layer cache does not hand it",
+    ("sparse", "kv_cache_quant"):
+        "kv_cache_quant quantizes K/V columns; the index's group means are "
+        "float32 means of the keys as written and the chosen-pages read "
+        "takes no scales",
     ("latent", "spec_decode"):
         "the latent read takes one query row a slot or one slot's chunk; a "
         "verify step's K + 1 rows of every slot, each with its own causal "
@@ -2255,7 +2414,9 @@ def cache_kinds(cfg: TransformerConfig) -> tuple:
     """The kinds of ``CACHE_KINDS`` a configuration is, in its order."""
     groups = kv_cache_groups(cfg)
     has = {"state": cfg.retention, "ssm": cfg.mamba, "kda": cfg.kda,
-           "conv": cfg.conv, "latent": cfg.latent,
+           "conv": cfg.conv, "lightning": cfg.lightning,
+           "sparse": cfg.sparse_attention is not None,
+           "latent": cfg.latent,
            "window_only": groups is not None and not groups[0][1],
            "window": groups is not None,
            "layer_types": cfg.layer_types is not None
@@ -2306,6 +2467,13 @@ class KVCacheSpec:
     kinds: tuple = ()                  # cache_kinds(cfg): what refusal() reads
     rep: int = 1                       # query heads that share a KV head
     # (GQA): the rows of the page read's block (paged_attention.block_rows)
+    sparse: Optional[tuple] = None     # learned sparse attention's sizes
+    # (ops/attention/sparse_index.SparseSizes). A page pool then keeps,
+    # beside k / v and under the same table, the leaf ``kc`` (L, P, KV,
+    # page_size // kernel_stride, head_dim) float32: the means of each
+    # group of ``kernel_stride`` consecutive keys, which the choice of
+    # blocks is made against. A contiguous cache keeps none: its keys lie
+    # in one piece
     state_group: Optional[tuple] = None    # the layers that keep a state a
     # row (no positions) and their leaves: ``(layers, ((leaf, shape a row
     # a layer, dtype), ...))``. A leaf of the cache is ``(layers, rows,
@@ -2322,6 +2490,11 @@ class KVCacheSpec:
     # conv layers, ONE leaf ``conv`` (the convolution's last taps - 1
     # inputs) and no ``s``. A state's size does not depend on max_seq_len,
     # which stays the bound on positions
+
+    @property
+    def index_stride(self) -> int:
+        """The keys a group of the index's leaf ``kc`` averages; 0: none."""
+        return self.sparse.kernel_stride if self.sparse else 0
 
     @property
     def state_leaves(self) -> tuple:
@@ -2457,6 +2630,14 @@ class KVCacheSpec:
                  lanes)
         cache = {"k": jnp.zeros(shape, self.dtype),
                  "v": jnp.zeros(shape, self.dtype)}
+        if self.index_stride:
+            if page_size % self.index_stride:
+                raise ValueError(
+                    f"a page holds whole groups of the index's "
+                    f"{self.index_stride} keys; got page_size {page_size}")
+            cache["kc"] = jnp.zeros(
+                (self.kv_layers, num_pages, self.kv_heads,
+                 page_size // self.index_stride, self.head_dim), jnp.float32)
         if self.state_group:
             cache.update(self._state_cache(num_slots))
         if self.quantized:
@@ -2551,6 +2732,11 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
     if cfg.conv:
         group = (cfg.layer_types.count("conv"), (
             ("conv", ((cfg.conv_taps - 1) * cfg.n_embd,), cache_dtype),))
+    if cfg.lightning:
+        from ..ops.lightning import state_shape
+
+        group = (cfg.layer_types.count("lightning"), (
+            ("s", state_shape(cfg.n_head, cfg.head_dim), jnp.float32),))
     return KVCacheSpec(n_layer=cfg.n_layer, kv_heads=cfg.kv_heads,
                        head_dim=cfg.head_dim, cache_d=cache_d,
                        dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
@@ -2558,7 +2744,8 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
                        groups=kv_cache_groups(cfg), latent=cfg.latent,
                        latent_rank=cfg.kv_lora_rank if cfg.latent else 0,
                        kinds=cache_kinds(cfg),
-                       rep=cfg.n_head // cfg.kv_heads, state_group=group)
+                       rep=cfg.n_head // cfg.kv_heads, state_group=group,
+                       sparse=cfg.sparse)
 
 
 def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:
@@ -2636,6 +2823,13 @@ class _CacheStore(nn.Module):
         if new_values is not None:
             for key, var in state.items():
                 var.value = new_values[key]
+        if paged and cfg.sparse_attention is not None:
+            # a page pool hands in the index's group means beside k / v
+            # (always provided: the initializer never runs)
+            var = self.variable("cache", "kc", jnp.zeros, (0,), jnp.float32)
+            values["kc"] = var.value
+            if new_values is not None:
+                var.value = new_values["kc"]
         if paged and kv_cache_groups(cfg) is not None:
             # a page pool of layer groups hands in a second pair of
             # leaves (always provided: the initializer never runs)
@@ -2760,11 +2954,34 @@ class TransformerLM(nn.Module):
             (x, *_), _ = self.attn_blocks((x,) + empty[1:], False,
                                           deterministic, *experts)
             return (x, cache), ()
-        before, after, periods = cfg.hybrid_period
         params = self.variables["params"]
         leaves = {kind: params[f"{kind}_blocks"],
                   "attention": params["attn_blocks"]}
         blocks = {k: _ScanBlock(cfg, k, parent=None) for k in leaves}
+        if not cfg.hybrid_repeats:
+            # a pattern without a period (adjacent attention layers, runs
+            # of unequal length): run after run in the published order, a
+            # run of several layers a scan over its slice of the kind's
+            # leaf. The compiled program holds a block once a run.
+            def one(of, state, index):
+                x, cache = state
+                sliced = jax.tree_util.tree_map(lambda w: w[index],
+                                                leaves[of])
+                (x, cache, _, _), _ = blocks[of].apply(
+                    {"params": sliced}, (x, cache, start, index), decode,
+                    deterministic)
+                return x, cache
+
+            state = (x, cache)
+            for of, first, count in cfg.hybrid_runs:
+                if count == 1:
+                    state = one(of, state, jnp.asarray(first, jnp.int32))
+                else:
+                    state, _ = jax.lax.scan(
+                        lambda st, j, of=of: (one(of, st, j), None), state,
+                        first + jnp.arange(count, dtype=jnp.int32))
+            return state, ()
+        before, after, periods = cfg.hybrid_period
 
         def layer(of, state, index, ffn_layer):
             """Layer ``index`` of kind ``of`` (the state layers' leaf starts

@@ -173,21 +173,27 @@ def _dual(dt, a, b, c):
     causal = jnp.tril(jnp.ones((T, T), bool))
     decay = jnp.exp(jnp.where(causal, Lh[..., :, None] - Lh[..., None, :],
                               -jnp.inf))
+    if b.ndim == 4:     # a head's own B and C: (B, T, H, N)
+        return L, jnp.einsum("bthn,bshn->bhts", c, b,
+                             precision=HIGHEST) * decay
     cb = jnp.einsum("btn,bsn->bts", c, b, precision=HIGHEST)
     return L, cb[:, None] * decay
 
 
 def ssm_chunk_plain(x, dt, a, b, c, h0):
     """The chunk form in ``jax.numpy``: ``x`` (B, T, H, P), ``dt`` (B, T,
-    H), ``b``, ``c`` (B, T, N), ``h0`` (B, H, P, N). Returns ``(y, h_T)``."""
+    H), ``b``, ``c`` (B, T, N) or, a head's own, (B, T, H, N), ``h0`` (B, H,
+    P, N). Returns ``(y, h_T)``."""
     L, gm = _dual(dt, a, b, c)
     xd = dt[..., None] * x
-    y = jnp.exp(L)[..., None] * jnp.einsum("btn,bhpn->bthp", c, h0,
+    tn = "bthn" if b.ndim == 4 else "btn"   # (a head's own B and C)
+    y = jnp.exp(L)[..., None] * jnp.einsum(f"{tn},bhpn->bthp", c, h0,
                                            precision=HIGHEST) \
         + jnp.einsum("bhts,bshp->bthp", gm, xd, precision=HIGHEST)
     w = jnp.exp(L[:, -1:] - L)                                  # (B, T, H)
     h = jnp.exp(L[:, -1])[..., None, None] * h0 \
-        + jnp.einsum("bsh,bshp,bsn->bhpn", w, xd, b, precision=HIGHEST)
+        + jnp.einsum(f"bsh,bshp,{tn.replace('t', 's')}->bhpn", w, xd, b,
+                     precision=HIGHEST)
     return y, h
 
 
@@ -245,12 +251,46 @@ def _decode_kernel(layer_ref, batch_ref, row_ref, fresh_ref,
     jax.lax.fori_loop(0, tiles, body, 0)
 
 
-def ssm_decode(x, dt, a, b, c, s, layer, rows, fresh):
+def _decode_kernel_by_head(layer_ref, batch_ref, row_ref, fresh_ref,
+                           da_ref, dtx_ref, b_ref, c_ref, s_ref, so_ref,
+                           y_ref):
+    """:func:`_decode_kernel` where every head has its own ``B`` and ``C``:
+    they arrive as lane-dense rows ``(heads of the tile, N)`` and both
+    products are the MXU's (``B^T (dt x)``, a product over the tile's
+    heads with each head's lanes kept, and ``C H``)."""
+    w = pl.program_id(0)
+    fresh = fresh_ref[w] != 0
+    tiles, N, lanes = s_ref.shape[2:]
+    heads = b_ref.shape[2]
+    width = lanes // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 0)
+    mine = lane // width == head                    # (heads, lanes)
+    dot = functools.partial(jax.lax.dot_general, precision=HIGHEST,
+                            preferred_element_type=jnp.float32)
+
+    def body(j, carry):
+        tile = jnp.where(fresh, 0.0, s_ref[0, 0, j])
+        dtx = jnp.where(mine, dtx_ref[0, pl.ds(j, 1), :], 0.0)
+        tile = da_ref[0, pl.ds(j, 1), :] * tile \
+            + dot(b_ref[0, j], dtx, (((0,), (0,)), ((), ())))
+        so_ref[0, 0, j] = tile
+        y = dot(c_ref[0, j], tile, (((1,), (0,)), ((), ())))
+        y_ref[0, pl.ds(j, 1), :] = jnp.sum(jnp.where(mine, y, 0.0), axis=0,
+                                           keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, tiles, body, 0)
+
+
+def ssm_decode(x, dt, a, b, c, s, layer, rows, fresh, name="ssm_decode"):
     """One token a running row, state updated in place.
 
     Args:
       x: (B, H, P), after the convolution; dt: (B, H), after the softplus;
-        a: (H,), negative; b, c: (B, N).
+        a: (H,), negative; b, c: (B, N), or (B, H, N) where every head has
+        its own (a linear-attention layer's key and query: ``name`` is then
+        the caller's, ``ops/lightning.py``).
       s: the stacked leaf (L, R, tiles, N, lanes), aliased to the result.
       layer: int32 scalar (traced). rows: (B,) int32, the pool row of each
         batch entry, out of range for an entry that does not run (its
@@ -267,33 +307,40 @@ def ssm_decode(x, dt, a, b, c, s, layer, rows, fresh):
     dtx = _lane_rows(dt[..., None] * x, tiles)
     step = DECODE_TILES if tiles % DECODE_TILES == 0 else tiles
     prefetch, total, runs = prefetch_operands(layer, rows, fresh, s)
+    by_head = b.ndim == 3
+    if by_head:     # (B, H, N) as lane-dense rows (B, tiles, heads, N)
+        b, c = (v.astype(f32).reshape(B, tiles, H // tiles, N)
+                for v in (b, c))
+        bc_spec = _by_batch((1, step, H // tiles, N), True)
+    else:
+        b, c = b.astype(f32)[..., None], c.astype(f32)[..., None]
+        bc_spec = _by_batch((1, N, 1), False)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(total, tiles // step),
         in_specs=[_by_batch((1, step, lanes), True),
                   _by_batch((1, step, lanes), True),
-                  _by_batch((1, N, 1), False), _by_batch((1, N, 1), False),
+                  bc_spec, bc_spec,
                   _state_spec(s, step)],
         out_specs=[_state_spec(s, step), _by_batch((1, step, lanes), True)],
     )
     s, s_shape = in_hbm(s)
     s, y = pl.pallas_call(
-        _decode_kernel,
-        name="ssm_decode",
+        _decode_kernel_by_head if by_head else _decode_kernel,
+        name=name,
         grid_spec=grid_spec,
         out_shape=[s_shape, jax.ShapeDtypeStruct((B, tiles, lanes), f32)],
         input_output_aliases={8: 0},
         compiler_params=compiler_params(),
         interpret=backend.pallas_interpret(),
-    )(*prefetch, da, dtx, b.astype(f32)[..., None], c.astype(f32)[..., None],
-      s)
+    )(*prefetch, da, dtx, b, c, s)
     # the blocks of rows that did not run were never written
     return jnp.where(runs[:, None, None], y, 0.0).reshape(B, H, P), s
 
 
 def _chunk_kernel(layer_ref, batch_ref, row_ref, fresh_ref,
                   gm_ref, xd_ref, e_ref, c_ref, bt_ref, w_ref, s_ref,
-                  so_ref, y_ref, *, heads: int):
+                  so_ref, y_ref, *, heads: int, by_head: bool = False):
     w = pl.program_id(0)
     fresh = fresh_ref[w] != 0
     T, lanes = xd_ref.shape[2:]
@@ -304,20 +351,25 @@ def _chunk_kernel(layer_ref, batch_ref, row_ref, fresh_ref,
     h0 = jnp.where(fresh, 0.0, s_ref[0, 0, 0])                  # (N, lanes)
     xd, e = xd_ref[0, 0], e_ref[0, 0]                           # (T, lanes)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
-    y = e * dot(c_ref[0], h0)                   # the carried part
+    # the carried part (``by_head``: a head's own C and B, its lanes kept)
+    y = 0.0 if by_head else e * dot(c_ref[0], h0)
     upd = jnp.zeros(h0.shape, jnp.float32)
     for g in range(heads):      # a head's decay over the tile, its lanes kept
         mine = (lane >= g * width) & (lane < (g + 1) * width)
         y = y + jnp.where(mine, dot(gm_ref[0, g], xd), 0.0)
-        upd = upd + jnp.where(mine, dot(bt_ref[0] * w_ref[0, g], xd), 0.0)
+        if by_head:
+            y = y + jnp.where(mine, e * dot(c_ref[0, g], h0), 0.0)
+        upd = upd + jnp.where(mine, dot(
+            (bt_ref[0, g] if by_head else bt_ref[0]) * w_ref[0, g], xd), 0.0)
     y_ref[0, 0] = y
     so_ref[0, 0, 0] = e[T - 1:T, :] * h0 + upd
 
 
-def ssm_chunk(x, dt, a, b, c, s, layer, rows, fresh):
+def ssm_chunk(x, dt, a, b, c, s, layer, rows, fresh, name="ssm_chunk"):
     """``T`` tokens of every running row after its carried state (the
     chunk form), state updated in place. ``x`` (B, T, H, P), ``dt`` (B, T,
-    H), ``b``, ``c`` (B, T, N), ``T`` a multiple of 8 and at most
+    H), ``b``, ``c`` (B, T, N), or (B, T, H, N) where every head has its
+    own (``name`` is then the caller's), ``T`` a multiple of 8 and at most
     :data:`CHUNK`; the rest as :func:`ssm_decode`. A padding token comes
     with ``dt`` zero. Returns ``(y (B, T, H, P) float32, s)``."""
     B, T, H, P = x.shape
@@ -326,30 +378,39 @@ def ssm_chunk(x, dt, a, b, c, s, layer, rows, fresh):
     assert (tiles, N, lanes) == state_shape(H, P, N), (x.shape, s.shape)
     f32 = jnp.float32
     heads = H // tiles
-    with jax.named_scope("ssm_chunk_prep"):
+    by_head = b.ndim == 4
+    with jax.named_scope(name + "_prep"):
         x, dt, b, c = (v.astype(f32) for v in (x, dt, b, c))
         L, gm = _dual(dt, a, b, c)
         # (B, T, tiles, lanes) -> (B, tiles, T, lanes)
         xd = jnp.moveaxis(_lane_rows(dt[..., None] * x, tiles), 1, 2)
         e = jnp.moveaxis(_head_rows(jnp.exp(L), P, tiles), 1, 2)
         w = jnp.exp(L[:, -1:] - L).transpose(0, 2, 1)[:, :, None]   # (B,H,1,T)
-        bt = b.transpose(0, 2, 1)                                   # (B, N, T)
+        if by_head:
+            c = c.transpose(0, 2, 1, 3)                         # (B, H, T, N)
+            bt = b.transpose(0, 2, 3, 1)                        # (B, H, N, T)
+        else:
+            bt = b.transpose(0, 2, 1)                           # (B, N, T)
     prefetch, total, runs = prefetch_operands(layer, rows, fresh, s)
+    c_spec, bt_spec = (_by_batch((1, heads, T, N), True),
+                       _by_batch((1, heads, N, T), True)) if by_head else \
+        (_by_batch((1, T, N), False), _by_batch((1, N, T), False))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(total, tiles),
         in_specs=[_by_batch((1, heads, T, T), True),
                   _by_batch((1, 1, T, lanes), True),
                   _by_batch((1, 1, T, lanes), True),
-                  _by_batch((1, T, N), False), _by_batch((1, N, T), False),
+                  c_spec, bt_spec,
                   _by_batch((1, heads, 1, T), True),
                   _state_spec(s, 1)],
         out_specs=[_state_spec(s, 1), _by_batch((1, 1, T, lanes), True)],
     )
     s, s_shape = in_hbm(s)
     s, y = pl.pallas_call(
-        functools.partial(_chunk_kernel, heads=heads),
-        name="ssm_chunk",
+        functools.partial(_chunk_kernel, heads=heads, by_head=True)
+        if by_head else functools.partial(_chunk_kernel, heads=heads),
+        name=name,
         grid_spec=grid_spec,
         out_shape=[s_shape,
                    jax.ShapeDtypeStruct((B, tiles, T, lanes), f32)],
@@ -362,7 +423,7 @@ def ssm_chunk(x, dt, a, b, c, s, layer, rows, fresh):
 
 
 def ssm_prefill(x, dt, a, b, c, s, layer, rows, fresh, length=None,
-                block: int = CHUNK):
+                block: int = CHUNK, name="ssm_chunk"):
     """:func:`ssm_chunk` over a sequence of any length: tokens at or past
     ``length`` (B,) are padding (their ``dt`` is zeroed here), the sequence
     is cut into blocks of at most ``block`` tokens and the state rides from
@@ -379,8 +440,12 @@ def ssm_prefill(x, dt, a, b, c, s, layer, rows, fresh, length=None,
                        for v in (x, dt, b, c))
     blocks = (T + pad) // Q
     fresh = jnp.asarray(fresh, bool)
+    # (found by its module name at the call, under its own name as it was:
+    # the builder's tools wrap ``ssm_chunk`` from outside)
+    chunk = ssm_chunk if name == "ssm_chunk" else functools.partial(
+        ssm_chunk, name=name)
     if blocks == 1:
-        y, s = ssm_chunk(x, dt, a, b, c, s, layer, rows, fresh)
+        y, s = chunk(x, dt, a, b, c, s, layer, rows, fresh)
         return y[:, :T], s
 
     def cut(v):     # (B, blocks * Q, ...) -> (blocks, B, Q, ...)
@@ -388,8 +453,8 @@ def ssm_prefill(x, dt, a, b, c, s, layer, rows, fresh, length=None,
 
     def step(carry, xs):
         s, first = carry
-        y, s = ssm_chunk(xs[0], xs[1], a, xs[2], xs[3], s, layer, rows,
-                         fresh & first)
+        y, s = chunk(xs[0], xs[1], a, xs[2], xs[3], s, layer, rows,
+                     fresh & first)
         return (s, jnp.zeros((), bool)), y
 
     (s, _), y = jax.lax.scan(step, (s, jnp.ones((), bool)),

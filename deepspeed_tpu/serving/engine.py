@@ -99,6 +99,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ..ops.attention.sparse_index import index_rows, tokens_read
 from ..telemetry import (FlightRecorder, MetricsRegistry, ProgramCostModel,
                          RecompileAfterWarmupError, RecompileWatchdog,
                          SLOTracker, TimelineStore, Tracer, default_tracer)
@@ -355,8 +356,12 @@ class ServingEngine:
         # (a state group's prefill runs its real tokens through the chunk
         # form of its kind's scan: the span attribute that counts them)
         self._chunk_tokens_key = next(
-            (f"{kind}_chunk_tokens" for kind in ("ssm", "kda", "conv")
+            (f"{kind}_chunk_tokens"
+             for kind in ("ssm", "kda", "conv", "lightning")
              if kind in getattr(spec, "kinds", ())), None)
+        # learned sparse attention's sizes (``KVCacheSpec.sparse``): what
+        # the equations read for a query at a position, for the counters
+        self._sparse = getattr(spec, "sparse", None)
         self._latent_token_bytes = int(getattr(spec, "latent", 0)) \
             * np.dtype(spec.dtype).itemsize \
             * int(getattr(spec, "kv_layers", spec.n_layer))
@@ -1436,6 +1441,28 @@ class ServingEngine:
         d["latent_bytes_read"] = \
             self._latent_token_bytes * d["latent_tokens_read"]
 
+    def _note_sparse(self, sp, positions) -> None:
+        """``sparse_rows`` / ``sparse_tokens_read`` / ``sparse_index_rows``
+        on a dispatch's span, for a model with learned sparse attention:
+        its real query rows, the tokens the EQUATIONS read for them (a KV
+        head a layer: all of a context under ``dense_len``, else the
+        window's and the chosen blocks') and the compressed keys visible to
+        them, from the host's own positions (no device read: which blocks
+        were chosen is the device's to know). The step's span gathers
+        them."""
+        if self._sparse is None:
+            return
+        positions = np.asarray(positions, np.int64).reshape(-1)
+        new = {"sparse_rows": int(positions.size),
+               "sparse_tokens_read": int(
+                   tokens_read(positions, self._sparse).sum()),
+               "sparse_index_rows": int(
+                   index_rows(positions, self._sparse).sum())}
+        sp.set(**new)
+        d = self._dispatched
+        for key, val in new.items():
+            d[key] = d.get(key, 0) + val
+
     def _note_admit(self, rows: int, padded_tokens: int) -> None:
         """An admission program of this step: requests seated, and the
         tokens it computes (rows x bucket width, padding included)."""
@@ -1881,6 +1908,7 @@ class ServingEngine:
                          rid=req.request_id, pos=pos, len=L) as sp:
             self._note_state_rows(sp, 1, L)
             self._note_latent(sp, pos + L, L)
+            self._note_sparse(sp, pos + np.arange(L))
             if self._paged:
                 if beside:
                     self._chunk_beside = (req, ids, pos, L, t0)
@@ -2684,6 +2712,8 @@ class ServingEngine:
         with self._phase("prepare", "serving/decode",
                          live=len(running)) as sp:
             self._note_state_rows(sp, len(running))
+            self._note_sparse(sp, self.pool.starts[
+                [slot for slot, _ in running]])
             if self._latent_token_bytes:
                 slots = [slot for slot, _ in running]
                 self._note_latent(

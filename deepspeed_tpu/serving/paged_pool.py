@@ -148,6 +148,12 @@ from .slot_pool import SlotPool
 # scales of a quantized tier, or the one latent row a token (``c``,
 # ``KVCacheSpec.latent``: (L, num_pages, W, lanes), no ``v``)
 PAGE_LEAVES = ("k", "v", "k_scale", "v_scale", "c")
+# ... and the index of learned sparse attention beside K/V under the same
+# table (``KVCacheSpec.sparse``: (L, num_pages, KV, groups a page, D)
+# float32 group means of the keys): a page's copy, transfer and audit take
+# it along; the columns' write path does not (a dense row holds no means:
+# ``_paged_admit_rows`` makes them from the row's keys)
+INDEX_LEAF = "kc"
 
 # the audit of a float32 state leaf: of the words a row that has run holds
 # (zeros apart), the share whose low 16 bits are empty, which is to say the
@@ -713,7 +719,7 @@ class PagedKVPool(SlotPool):
         cs = self.cache["cache_store"]
         return sum(int(np.prod(cs[k].shape)) * cs[k].dtype.itemsize
                    // self.num_pages
-                   for k in PAGE_LEAVES if k in cs)
+                   for k in PAGE_LEAVES + (INDEX_LEAF,) if k in cs)
 
     def import_pages(self, src_pool: "PagedKVPool",
                      src_page_ids: Sequence[int]) -> List[int]:
@@ -849,7 +855,7 @@ class PagedKVPool(SlotPool):
         page pair."""
         out = dict(cs)
         with jax.named_scope("copy"):
-            for key in PAGE_LEAVES:
+            for key in PAGE_LEAVES + (INDEX_LEAF,):
                 if key not in cs:
                     continue
                 leaf = cs[key]
@@ -870,7 +876,7 @@ class PagedKVPool(SlotPool):
         Runs on the SOURCE pool's devices."""
         with jax.named_scope("gather"):
             return {key: jnp.take(src_cs[key], src_ids, axis=1, mode="clip")
-                    for key in PAGE_LEAVES
+                    for key in PAGE_LEAVES + (INDEX_LEAF,)
                     if key in src_cs}
 
     @staticmethod
@@ -881,7 +887,7 @@ class PagedKVPool(SlotPool):
         arrived via :meth:`_land_block`."""
         out = dict(dst_cs)
         with jax.named_scope("scatter"):
-            for key in PAGE_LEAVES:
+            for key in PAGE_LEAVES + (INDEX_LEAF,):
                 if key not in dst_cs:
                     continue
                 out[key] = dst_cs[key].at[:, dst_ids].set(
@@ -1041,6 +1047,21 @@ class PagedKVPool(SlotPool):
         for key in self.spec.state_leaves:
             out[key] = pool[key].at[:, slots].set(
                 pre[key].astype(pool[key].dtype), mode="drop")
+        if INDEX_LEAF in pool:
+            # the index's group means of the rows' REAL keys (a group that
+            # a row's length cuts holds the part that has arrived, as the
+            # steps that follow go on from it): (L, B, KV, D, S) ->
+            # (L, B, entries, KV, groups a page, D) through the tables
+            leaf, k = pool[INDEX_LEAF], pre["k"]
+            L, B, KV, D, S = k.shape
+            st, G = self.spec.index_stride, leaf.shape[3]
+            real = jnp.arange(S)[None, :] < jnp.asarray(lengths)[:, None]
+            means = jnp.where(real[None, :, None, None, :],
+                              k.astype(jnp.float32), 0.0).reshape(
+                L, B, KV, D, S // st, st).sum(-1) / st
+            means = means.reshape(L, B, KV, D, S // st // G, G).transpose(
+                0, 1, 4, 2, 5, 3)
+            out[INDEX_LEAF] = leaf.at[:, rows_tables].set(means, mode="drop")
         out["index"] = pool["index"].at[slots].set(
             jnp.asarray(lengths, jnp.int32), mode="drop")
         return out
@@ -1582,6 +1603,18 @@ class PagedKVPool(SlotPool):
                         f"{(want[key].shape, want[key].dtype)}")
                 elif got.dtype == jnp.float32:
                     errors += self._narrow_state_rows(key, got)
+        if self.spec.index_stride:
+            # the index beside the pages: a leaf of group means a page, in
+            # the spec's shape and float32 (the choice is made against it)
+            want = (self.spec.kv_layers, self.num_pages, self.spec.kv_heads,
+                    self.page_size // self.spec.index_stride,
+                    self.spec.head_dim)
+            got = self.cache["cache_store"].get(INDEX_LEAF)
+            if got is None or (got.shape, got.dtype) != (want, jnp.float32):
+                errors.append(
+                    f"index leaf {INDEX_LEAF!r} is "
+                    f"{None if got is None else (got.shape, got.dtype)}, "
+                    f"the spec's is {(want, jnp.dtype(jnp.float32))}")
         P, sent = self.num_pages, self.num_pages
         if len(self._free_pages) != len(self._free_page_set):
             errors.append(f"free page heap ({len(self._free_pages)}) and "

@@ -22,6 +22,35 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
+# Files that one worker holds for minutes, by their seconds in one process
+# (ROADMAP D8; `--durations`). Under ``--dist loadfile`` xdist hands files
+# out by their NUMBER of tests, most first, so a file of one long test runs
+# last and five workers idle behind it (~50-100 s of a Tier-1 run that
+# stands two minutes from its clock). The order below is xdist's own, by
+# number of tests, with these files ahead of it by their cost; the tests a
+# worker runs, and their order inside a file, are what they were.
+_LONG_FILES = {
+    "tests/unit/ops/test_paged_attention.py": 350,
+    "tests/unit/accelerator/test_chip_path.py": 200,
+    "tests/unit/launcher/test_elastic_e2e.py": 119,
+    "tests/model/test_realtext_convergence.py": 110,
+}
+
+
+def pytest_configure(config):
+    # (xdist's reordering by count is replaced by the one below)
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(config, items):
+    by_file = {}
+    for item in items:
+        by_file.setdefault(item.nodeid.split("::", 1)[0], []).append(item)
+    order = sorted(by_file, key=lambda name: (
+        -_LONG_FILES.get(name, 0), -len(by_file[name])))
+    items[:] = [item for name in order for item in by_file[name]]
+
 
 @pytest.fixture(autouse=True)
 def _reset_global_mesh():

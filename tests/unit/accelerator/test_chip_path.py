@@ -11,6 +11,7 @@
 """
 
 import contextlib
+import functools
 import json
 import os
 import re
@@ -353,6 +354,68 @@ def test_ssm_kernels_compile_for_a_described_v5e_at_the_served_shape(
             assert "may-alias" in text
             # the leaf is 4.83 GB: a copy or a slice of it would show
             assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+
+
+def test_lightning_and_sparse_kernels_compile_for_a_described_v5e_as_served(
+        compiled_kernels, described_v5e):
+    """The kernels PR 56 brought, through Mosaic at the widths of
+    ``perf/configs/minicpm-sala-9b-sparse.json``: 32 rows' tokens through
+    ``lightning_decode`` and one row's 128-token block through
+    ``lightning_chunk`` (the state-space kernels with a head's own B and C:
+    lane-dense rows a head, both products of a decode step on the MXU) on
+    the pool's stacked leaf (9 layers x 32 slots x 32 tiles of (128, 128)
+    float32); and the sparse layers' page read (``ops/attention/
+    sparse_read.py``) with its plan, the call's queries and their
+    accumulators whole in VMEM: 32 decode rows, a (row, KV head) a step's
+    one entry of 16 query heads over its own pages (``sparse_read``), and a
+    chunk's 512 queries, 16 of them a step, under the blocks they chose and
+    over their window (``sparse_read_chunk``), pages of 128. The leaves
+    go in and come out in one buffer: no operation copies them."""
+    from deepspeed_tpu.ops import lightning
+    from deepspeed_tpu.ops.attention import sparse_read as sr
+    from deepspeed_tpu.ops.attention.sparse_index import SparseSizes
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=described_v5e)
+
+    i32, bf16 = jnp.int32, jnp.bfloat16
+    L, R, H, D = 9, 32, 32, 128
+    leaf = shape((L, R) + lightning.state_shape(H, D))
+    assert leaf.shape[2:] == (32, 128, 128)
+    with _compile_cache_off():
+        for name, fn, B, tokens, more in (
+                ("lightning_decode", lightning.lightning_decode, R, (), ()),
+                ("lightning_chunk", lightning.lightning_prefill, 1, (128,),
+                 (shape((1,), i32),))):
+            qkv = shape((B,) + tokens + (H, D))
+            compiled = jax.jit(fn, donate_argnums=3).lower(
+                qkv, qkv, qkv, leaf, shape((), i32), shape((B,), i32),
+                shape((B,), jnp.bool_), *more).compile()
+            text = compiled.as_text()
+            assert name in text and text.count("tpu_custom_call") == 1
+            assert "may-alias" in text
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+        pages, per_slot, KV = 10240, 320, 2
+        k = shape((3, pages, KV, D, 128), bf16)
+        sizes = SparseSizes(32, 16, 64, 1, 2048, 64, 8192)
+        for name, fn, rows, table, limit in (
+                ("sparse_read", sr.read_rows, 32, (32, per_slot), 2 ** 24),
+                ("sparse_read_chunk", sr.read_chunk, 512, (per_slot,),
+                 2 ** 24)):
+            compiled = jax.jit(functools.partial(
+                fn, sizes=sizes, page_size=128, scale=D ** -0.5,
+                name=name)).lower(
+                shape((rows, H, D), bf16), k, k, shape((), i32),
+                shape(table, i32), shape((rows,), i32),
+                shape((rows, KV, per_slot * 2), jnp.bool_)).compile()
+            text = compiled.as_text()
+            # (the target, not the bare word: the text's table of source
+            # frames names this file's ``_tpu_custom_calls`` too)
+            assert name in text and text.count(
+                'custom_call_target="tpu_custom_call"') == 1
+            # the K/V leaves are 4 GB: a copy of one would show, and so
+            # would a gather of the queries or of partial results
+            assert compiled.memory_analysis().temp_size_in_bytes < limit
 
 
 def test_kda_kernels_compile_for_a_described_v5e_at_the_served_shape(

@@ -143,20 +143,33 @@ def test_a_state_group_beside_kv_at_the_published_widths():
 
 @pytest.mark.parametrize("layer_types,why", [
     (["mamba"] * 4, "ONE attention layer"),
-    (["mamba", "attention", "attention", "mamba"], "ONE attention layer"),
-    (["attention", "mamba", "mamba", "attention"], "ONE attention layer"),
+    # (PR 56) a pattern without a period is a list of runs
+    (["mamba", "attention", "attention", "mamba"],
+     (("mamba", 0, 1), ("attention", 0, 2), ("mamba", 1, 1))),
+    (["attention", "mamba", "mamba", "attention"],
+     (("attention", 0, 1), ("mamba", 0, 2), ("attention", 1, 1))),
     (["mamba", "full_attention"] * 2, "ONE attention layer"),
     (["attention"] * 4, "ONE attention layer"),
 ])
-def test_mamba_and_attention_layers_come_as_a_repeating_pattern(layer_types,
-                                                                why):
+def test_mamba_and_attention_layers_come_as_a_pattern_or_as_runs(layer_types,
+                                                                 why):
     from deepspeed_tpu.models.transformer_lm import transformer_config
 
-    with pytest.raises(ValueError, match=why):
-        transformer_config(
+    def build(**more):
+        return transformer_config(
             "granite-hybrid", vocab_size=64, max_seq_len=16, n_embd=32,
             n_layer=4, n_head=2, layer_types=layer_types, mamba_n_heads=4,
-            mamba_d_head=8, mamba_d_state=8)
+            mamba_d_head=8, mamba_d_state=8, **more)
+
+    if isinstance(why, str):
+        with pytest.raises(ValueError, match=why):
+            build()
+        return
+    cfg = build()
+    assert not cfg.hybrid_repeats and cfg.hybrid_runs == why
+    # a routed FFN counts its layers period by period
+    with pytest.raises(ValueError, match="period by period"):
+        build(ffn_dim=8, n_experts=4, experts_per_token=2)
 
 
 def test_mamba_layers_need_their_widths():

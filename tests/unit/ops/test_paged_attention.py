@@ -6,6 +6,8 @@ change — and garbage pages (unmapped sentinels, stale contents past the
 live length) can never reach the output. Pallas runs in interpreter mode
 on CPU."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,9 +20,45 @@ from deepspeed_tpu.ops.attention.decode_attention import (
 )
 from deepspeed_tpu.ops.attention.paged_attention import (
     live_pages,
-    paged_decode_attention,
     plan_grid,
 )
+
+# Called eagerly, every call of a kernel's entry point lowers and compiles
+# its kernel anew (a ``pallas_call`` bound outside ``jit`` carries a fresh
+# jaxpr each time: nothing to find in a cache). The tests below call the
+# entry points through ``_once``: ONE ``jax.jit`` for each (entry point,
+# static arguments, and the module's attributes a test may have patched),
+# so the same shapes compile once a file (PR 56; 191 cases took 327-334 s
+# in one process before).
+_JITS = {}
+_STATIC = (int, float, bool, str, type(None))
+
+
+def _once(name):
+    def call(*args, **kw):
+        fn = getattr(paged_attention, name)
+        fixed = tuple((i, a) for i, a in enumerate(args)
+                      if isinstance(a, _STATIC))
+        named = tuple(sorted((k, v) for k, v in kw.items()
+                             if isinstance(v, _STATIC)))
+        key = (fn, fixed, named, paged_attention.VMEM_BUDGET_BYTES,
+               paged_attention.plan_grid, paged_attention.pl.pallas_call)
+        if key not in _JITS:
+            at = dict(fixed)
+
+            def traced(*arrays, **more):
+                given = iter(arrays)
+                return fn(*(at[i] if i in at else next(given)
+                            for i in range(len(args))),
+                          **dict(named), **more)
+            _JITS[key] = jax.jit(traced)
+        return _JITS[key](
+            *(a for i, a in enumerate(args) if not isinstance(a, _STATIC)),
+            **{k: v for k, v in kw.items() if not isinstance(v, _STATIC)})
+    return functools.wraps(getattr(paged_attention, name))(call)
+
+
+paged_decode_attention = _once("paged_decode_attention")
 
 
 # the dense oracle, compiled once for the rows of a test that asks it row
@@ -346,8 +384,8 @@ def test_jit_and_eager_agree():
     args = (q, jnp.asarray(k_pages), jnp.asarray(v_pages),
             jnp.asarray(table), jnp.asarray(starts))
     np.testing.assert_array_equal(
-        np.asarray(jax.jit(paged_decode_attention)(*args)),
-        np.asarray(paged_decode_attention(*args)))
+        np.asarray(paged_decode_attention(*args)),      # (under jit)
+        np.asarray(paged_attention.paged_decode_attention(*args)))
 
 
 # ---------------------------------------------------------------------------
@@ -912,10 +950,11 @@ def test_dead_slots_between_live_ones_are_no_step_and_change_nothing(
 # the write: columns into the stacked leaf, in place (ISSUE 27)
 # ---------------------------------------------------------------------------
 from deepspeed_tpu.ops.attention.paged_attention import (  # noqa: E402
-    paged_write_columns,
-    paged_write_runs,
     plan_write,
 )
+
+paged_write_columns = _once("paged_write_columns")
+paged_write_runs = _once("paged_write_runs")
 
 # dtype and stored head dim of a 128-wide head in each K/V tier
 _WRITE_TIERS = {"bf16": (jnp.bfloat16, 128), "int8": (jnp.int8, 128),
@@ -1161,3 +1200,128 @@ def test_pages_stored_in_whole_lane_tiles_read_and_write_the_same(tier):
                                     jnp.asarray(starts), layer=1,
                                     page_size=ps, **wide_scales)
     _same(padded, narrow)
+
+
+# ---------------------------------------------------------------------------
+# a page list that is a choice (PR 56): learned sparse attention reads the
+# blocks each (query, KV head) chose and its window (ops/attention/
+# sparse_read.py: one kernel over (query tile, KV head, page) steps)
+# ---------------------------------------------------------------------------
+def _sparse_case(rng, B, KV, D, S, ps, sizes, qpos):
+    """Pages, a table and a random choice as ``choose_blocks`` hands it:
+    (N, KV, nb) blocks each query reads whole (never one that meets its
+    window; every block under ``dense_len``), and the float64 softmax over
+    what the equations let each query see."""
+    from deepspeed_tpu.ops.attention import sparse_index as si
+
+    dense_k, dense_v, k_pages, v_pages, table = _make_paged(
+        rng, B, KV, D, S, ps)
+    nb = S // sizes.block_size
+    b = np.arange(nb)
+    meets = (b + 1) * sizes.block_size - 1 >= qpos[:, None] \
+        - sizes.window_size + 1
+    # the topk best of random scores, the first blocks before all others
+    score = rng.random((len(qpos), KV, nb))
+    score[..., :sizes.init_blocks] = 2.0
+    score = np.where(meets[:, None, :], -1.0, score)
+    kth = np.sort(score, axis=-1)[..., -sizes.topk][..., None]
+    blocks = (score >= kth) & (score >= 0)
+    blocks |= (qpos + 1 < sizes.dense_len)[:, None, None]
+    may = np.asarray(si.token_mask(jnp.asarray(blocks)[None],
+                                   jnp.asarray(qpos)[None], sizes, S))[0]
+    seen = may & (np.arange(S) <= qpos[:, None])[None]     # (KV, N, S)
+    return (dense_k, dense_v, jnp.asarray(k_pages)[None],
+            jnp.asarray(v_pages)[None], table, blocks, seen)
+
+
+def _seen_softmax(q, k, v, seen):
+    """``q`` (N, H, D) over one slot's ``k``, ``v`` (KV, D, S) where
+    ``seen`` (KV, N, S)."""
+    N, H, D = q.shape
+    KV = k.shape[0]
+    qg = q.astype(np.float64).reshape(N, KV, H // KV, D)
+    s = np.einsum("nkrd,kds->knrs", qg, k.astype(np.float64)) / np.sqrt(D)
+    s = np.where(seen[:, :, None, :], s, -np.inf)
+    p = np.exp(s - s.max(axis=-1, keepdims=True))
+    return np.einsum("knrs,kds->nkrd", p / p.sum(-1, keepdims=True),
+                     v.astype(np.float64)).reshape(N, H, D)
+
+
+@pytest.mark.parametrize("rep,ps,bk", [(8, 16, 8), (2, 128, 64)])
+def test_decode_rows_read_their_own_choice_and_their_window(rep, ps, bk):
+    """A (row, KV head)'s steps are the pages that hold a block IT chose
+    or meet ITS window, nobody else's, and a page's unchosen block stays
+    unseen (a bit a block, no mask): a row under ``dense_len`` reads all
+    before it, a row that does not run reads nothing and comes back zero.
+    The numerics are the plain softmax's over the equations' tokens."""
+    from deepspeed_tpu.ops.attention import sparse_index as si
+    from deepspeed_tpu.ops.attention import sparse_read as sr
+
+    rng = np.random.default_rng(56 + rep)
+    B, KV, D, S = 4, 2, 64, 16 * ps
+    sizes = si.SparseSizes(2 * bk, bk, bk, 1, 3 * bk, 3, 5 * ps)
+    qpos = np.asarray([S - 1, 9 * ps + 3, 2 * ps + 1, 7 * ps], np.int32)
+    dense_k, dense_v, k_pages, v_pages, table, blocks, seen = _sparse_case(
+        rng, B, KV, D, S, ps, sizes, qpos)
+    q = rng.standard_normal((B, KV * rep, D)).astype(np.float32)
+    running = np.asarray([True, True, True, False])
+    read = jax.jit(functools.partial(
+        sr.read_rows, sizes=sizes, page_size=ps, scale=1 / np.sqrt(D)))
+    y, pages = read(jnp.asarray(q), k_pages, v_pages, None,
+                    jnp.asarray(table), jnp.asarray(qpos),
+                    jnp.asarray(blocks), running=jnp.asarray(running))
+    for r in range(3):
+        np.testing.assert_allclose(
+            np.asarray(y)[r], _seen_softmax(
+                q[r:r + 1], dense_k[r], dense_v[r], seen[:, r:r + 1])[0],
+            atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(y)[3], 0.0)
+    # the list: a page a (row, KV head) that holds something it sees
+    own = seen.reshape(KV, B, S // ps, ps).any(-1)[:, :3]
+    assert int(pages) == own.sum()
+    steps, total = sr.rows_plan(
+        jnp.asarray(blocks), jnp.asarray(qpos), jnp.asarray(table), sizes,
+        ps, k_pages.shape[1], jnp.asarray(running))
+    page, kv, entry, _, base, tile = (
+        np.asarray(x)[:int(total)] for x in steps)
+    for r in range(B * KV):
+        mine = entry[tile[:, 0] == r]
+        assert (np.diff(mine) > 0).all()
+        assert mine.tolist() == (np.flatnonzero(
+            own[r % KV, r // KV]).tolist() if r // KV < 3 else [])
+        assert (page[tile[:, 0] == r] == table[r // KV, mine]).all()
+        assert (kv[tile[:, 0] == r] == r % KV).all()
+        assert (base[tile[:, 0] == r] == qpos[r // KV]).all()
+
+
+@pytest.mark.parametrize("p0", [8, 40])
+def test_a_chunks_queries_each_read_their_own_choice(p0):
+    """A chunk's queries stand as the rows of the blocks they chose, a
+    block's tiles one step each, and every query's partial results join
+    its window's: the plain softmax over the equations' tokens, for a
+    chunk that crosses ``dense_len`` (48) and one past it, with blocks
+    nobody chose, blocks everybody chose, more queries than a tile and a
+    last tile that is not full."""
+    from deepspeed_tpu.ops.attention import sparse_index as si
+    from deepspeed_tpu.ops.attention import sparse_read as sr
+
+    rng = np.random.default_rng(57 + p0)
+    KV, rep, D, ps, bk, T = 2, 4, 64, 16, 8, 40
+    S = 8 * ps
+    sizes = si.SparseSizes(2 * bk, bk, bk, 1, 2 * bk, 3, 48)
+    qpos = (p0 + np.arange(T)).astype(np.int32)
+    dense_k, dense_v, k_pages, v_pages, table, blocks, seen = _sparse_case(
+        rng, 1, KV, D, S, ps, sizes, qpos)
+    q = rng.standard_normal((T, KV * rep, D)).astype(np.float32)
+    y, tiles = jax.jit(functools.partial(
+        sr.read_chunk, sizes=sizes, page_size=ps, scale=1 / np.sqrt(D)))(
+        jnp.asarray(q), k_pages, v_pages, None, jnp.asarray(table[0]),
+        jnp.asarray(qpos), jnp.asarray(blocks))
+    np.testing.assert_allclose(
+        np.asarray(y), _seen_softmax(q, dense_k[0], dense_v[0], seen),
+        atol=1e-4, rtol=1e-4)
+    # a block's steps: the queries that read it whole, a tile at a time
+    far = blocks & (np.arange(S // bk)[None, :]
+                    < ((qpos - sizes.window_size + 1) // bk)[:, None])[
+                        :, None, :]
+    assert int(tiles) == (-(-far.sum(0) // sr.CHUNK_TILE)).sum()

@@ -34,6 +34,15 @@ PRESETS = {
     "conv": ("granite-hybrid", dict(
         TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
         layer_types=["conv", "attention"] * 2)),
+    "sparse": ("minicpm_sala", dict(
+        TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
+        layer_types=["lightning", "sparse_attention"] * 2,
+        sparse_attention=dict(kernel_size=2, kernel_stride=1, block_size=4,
+                              init_blocks=1, window_size=8, topk=2,
+                              dense_len=16))),
+    "lightning": ("minicpm_sala", dict(
+        TINY, n_layer=4, n_kv_head=2, ffn_dim=48, attn_output_gate=False,
+        layer_types=["lightning", "attention"] * 2)),
     "latent": ("moonlight", dict(
         TINY, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
         v_head_dim=8, scoring_func="softmax")),
@@ -49,7 +58,10 @@ PRESETS = {
                              experts_per_token=2)),
 }
 IMPLIED = {"window_only": {"window", "layer_types"},
-           "window": {"layer_types"}}
+           "window": {"layer_types"},
+           # (sparse attention stands beside state layers: its rows come
+           # first in the table's order)
+           "sparse": {"lightning"}}
 _OFF = {"page_size": PAGE, "prefix_cache": False, "kernel": "off"}
 
 
@@ -173,6 +185,13 @@ def test_a_kind_with_no_row_constructs(engine_of):
                         paged_kv=_OFF)
     assert set(srv.pool.cache["cache_store"]) \
         == {"conv", "k", "v", "index", "table"}
+    # (PR 56) the index's group means beside K/V under the same table, and
+    # the Lightning state a slot
+    srv = ServingEngine(engine_of("sparse"), num_slots=2, prefill_chunk=PAGE,
+                        paged_kv=_OFF)
+    assert set(srv.pool.cache["cache_store"]) \
+        == {"s", "k", "v", "kc", "index", "table"}
+    srv.check_invariants()
     with pytest.raises(KeyError):
         spec.refusal("no_such_feature")
 

@@ -765,6 +765,86 @@ def test_a_conv_tail_beside_pages_counts_what_it_ran(lfm2_account):
 
 
 @pytest.fixture(scope="module")
+def sala_account():
+    """A server of Lightning layers beside learned sparse attention in an
+    irregular stack (PR 56), its chunks and decode rows reading the pages
+    in place (the sparse read is the kernel path's), driven as
+    ``lfm2_account`` is: a prompt of three chunks past the toy
+    ``dense_len``, then one admitted whole."""
+    from deepspeed_tpu.models.transformer_lm import transformer_config
+
+    model = TransformerLM(transformer_config(
+        "minicpm_sala", **dict(TINY, n_layer=4, n_kv_head=2, ffn_dim=16),
+        mixer_types=["minicpm4", "lightning-attn", "minicpm4", "minicpm4"],
+        sparse_attention=dict(kernel_size=2, kernel_stride=1, block_size=4,
+                              init_blocks=1, window_size=8, topk=2,
+                              dense_len=16)))
+    params = model.init({"params": jax.random.PRNGKey(1)},
+                        jnp.zeros((1, 8), jnp.int32),
+                        method=model.logits)["params"]
+    srv = ds.init_serving(model, model_parameters=params,
+                          config={"dtype": "float32"}, num_slots=4,
+                          max_queue_depth=8, prefill_chunk=8,
+                          prefill_token_budget=64,
+                          paged_kv={"kernel": "on", "page_size": 8,
+                                    "prefix_cache": False})
+    rng = np.random.default_rng(11)
+    n0 = default_tracer().events_total
+    srv.submit(rng.integers(0, 64, size=20).astype(np.int32),
+               max_new_tokens=12)                  # chunks of 8, 8 and 4
+    for _ in range(4):
+        srv.step()
+    srv.submit(rng.integers(0, 64, size=7).astype(np.int32),
+               max_new_tokens=4)                   # alone: serving/admit
+    srv.run_until_drained(max_steps=60)
+    srv.check_invariants()
+    evs = _new_events(n0)
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    return {"pool": "paged", "srv": srv, "evs": evs, "steps": steps}
+
+
+def test_sparse_attention_beside_a_lightning_state_counts_what_it_ran(
+        sala_account):
+    """``sparse_rows`` / ``sparse_tokens_read`` / ``sparse_index_rows`` on
+    the chunk and decode spans, from the host's positions by the EQUATIONS
+    (a row under ``dense_len`` 16 reads its whole context, one past it its
+    window of 8 and 2 blocks of 4; a compressed key a position once 2 have
+    arrived), ``lightning_chunk_tokens`` (REAL tokens) on the prefill
+    dispatches and ``state_rows`` everywhere; the step's span sums them."""
+    srv, evs = sala_account["srv"], sala_account["evs"]
+    sizes = srv.pool.spec.sparse
+    assert sizes.dense_len == 16 and srv._sparse == sizes
+    chunks = [e["args"] for e in evs if e["name"] == "serving/prefill_chunk"]
+    assert [a["lightning_chunk_tokens"] for a in chunks] == [8, 8, 4]
+    assert [a["sparse_rows"] for a in chunks] == [8, 8, 4]
+    # positions 0-7, 8-15 (the last one past dense_len) and 16-19
+    assert [a["sparse_tokens_read"] for a in chunks] == [
+        sum(range(1, 9)), sum(range(9, 16)) + 16, 4 * 16]
+    assert [a["sparse_index_rows"] for a in chunks] == [
+        sum(range(0, 8)), sum(range(8, 16)), sum(range(16, 20))]
+    admits = [e["args"] for e in evs if e["name"] == "serving/admit"]
+    assert [a["lightning_chunk_tokens"] for a in admits] == [7]
+    decodes = [e["args"] for e in evs if e["name"] == "serving/decode"]
+    assert decodes
+    for a in decodes:
+        assert a["state_rows"] == a["sparse_rows"] == a["live"]
+        assert a["sparse_tokens_read"] <= 16 * a["live"]
+    # the long request's decode rows are past dense_len: 16 tokens a row
+    assert any(a["sparse_tokens_read"] == 16 * a["live"] for a in decodes)
+    assert not any(key in (e.get("args") or {}) for e in evs
+                   for key in ("ssm_chunk_tokens", "kda_chunk_tokens",
+                               "conv_chunk_tokens", "latent_tokens_read"))
+    spec = srv.pool.spec
+    assert spec.state_leaves == ("s",) and spec.kv_layers == 3
+    assert set(srv.pool.cache["cache_store"]) \
+        == {"s", "k", "v", "kc", "index", "table"}
+    summed = [s["args"] for s in sala_account["steps"]
+              if s["args"].get("sparse_rows")]
+    assert summed and all(a["sparse_tokens_read"] >= a["sparse_rows"]
+                          for a in summed)
+
+
+@pytest.fixture(scope="module")
 def beside_account(server_parts):
     """A server whose chunks read their pages in place (``kernel: "on"``),
     warmed the way the benchmark's harness warms one (a request a pass,
