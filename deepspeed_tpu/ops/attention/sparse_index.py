@@ -58,7 +58,7 @@ import numpy as np
 
 __all__ = ["SparseSizes", "group_means", "group_sums_write", "choose_blocks",
            "token_mask", "sparse_attention_dense",
-           "tokens_read", "index_rows"]
+           "tokens_read", "index_rows", "pages_most"]
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG_INF = -1e30
@@ -232,3 +232,17 @@ def index_rows(pos, sizes: SparseSizes):
     np_ = jnp if isinstance(pos, jax.Array) else np
     return np_.maximum(pos - sizes.kernel_size + 1, -1) \
         // sizes.kernel_stride + 1
+
+
+def pages_most(pos, sizes: SparseSizes, page_size: int):
+    """The most pages a (decode row, KV head) at position ``pos`` can list
+    (``sparse_read.rows_plan``'s ``count``): every page up to ``pos`` under
+    ``dense_len`` (exact), else its window's pages and a page each for as
+    many chosen blocks as lie before them (a bound: two chosen blocks may
+    share a page, and one may lie in the window's first)."""
+    np_ = jnp if isinstance(pos, jax.Array) else np
+    first = np_.maximum(pos - sizes.window_size + 1, 0)
+    far = np_.minimum(np_.minimum(first // sizes.block_size, sizes.topk),
+                      first // page_size)
+    sparse = pos // page_size - first // page_size + 1 + far
+    return np_.where(pos + 1 < sizes.dense_len, pos // page_size + 1, sparse)

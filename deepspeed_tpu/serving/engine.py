@@ -99,7 +99,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..ops.attention.sparse_index import index_rows, tokens_read
+from ..ops.attention.sparse_index import (index_rows, pages_most,
+                                            tokens_read)
 from ..telemetry import (FlightRecorder, MetricsRegistry, ProgramCostModel,
                          RecompileAfterWarmupError, RecompileWatchdog,
                          SLOTracker, TimelineStore, Tracer, default_tracer)
@@ -1441,15 +1442,19 @@ class ServingEngine:
         d["latent_bytes_read"] = \
             self._latent_token_bytes * d["latent_tokens_read"]
 
-    def _note_sparse(self, sp, positions) -> None:
+    def _note_sparse(self, sp, positions, decode: bool = False) -> None:
         """``sparse_rows`` / ``sparse_tokens_read`` / ``sparse_index_rows``
         on a dispatch's span, for a model with learned sparse attention:
         its real query rows, the tokens the EQUATIONS read for them (a KV
         head a layer: all of a context under ``dense_len``, else the
         window's and the chosen blocks') and the compressed keys visible to
         them, from the host's own positions (no device read: which blocks
-        were chosen is the device's to know). The step's span gathers
-        them."""
+        were chosen is the device's to know). The ``decode`` rows of a page
+        pool also say how their read's blocks engage, a layer:
+        ``sparse_pages_most``, the most pages their (row, KV head)s can
+        list, and ``sparse_blocks_most``, the grid steps of ``sparse_read.
+        pages_a_step`` pages those make (exact under ``dense_len``, bounds
+        past it). The step's span gathers them."""
         if self._sparse is None:
             return
         positions = np.asarray(positions, np.int64).reshape(-1)
@@ -1458,6 +1463,17 @@ class ServingEngine:
                    tokens_read(positions, self._sparse).sum()),
                "sparse_index_rows": int(
                    index_rows(positions, self._sparse).sum())}
+        if decode and self._paged:
+            from ..models.transformer_lm import page_lanes
+            from ..ops.attention.sparse_read import pages_a_step
+
+            spec, page_size = self.pool.spec, self.pool.page_size
+            most = pages_most(positions, self._sparse, page_size)
+            G = pages_a_step(spec.rep, spec.cache_d, page_lanes(page_size),
+                             spec.dtype)
+            new["sparse_pages_most"] = int(spec.kv_heads * most.sum())
+            new["sparse_blocks_most"] = int(
+                spec.kv_heads * (-(-most // G)).sum())
         sp.set(**new)
         d = self._dispatched
         for key, val in new.items():
@@ -2713,7 +2729,7 @@ class ServingEngine:
                          live=len(running)) as sp:
             self._note_state_rows(sp, len(running))
             self._note_sparse(sp, self.pool.starts[
-                [slot for slot, _ in running]])
+                [slot for slot, _ in running]], decode=True)
             if self._latent_token_bytes:
                 slots = [slot for slot, _ in running]
                 self._note_latent(

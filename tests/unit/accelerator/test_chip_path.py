@@ -365,12 +365,13 @@ def test_lightning_and_sparse_kernels_compile_for_a_described_v5e_as_served(
     lane-dense rows a head, both products of a decode step on the MXU) on
     the pool's stacked leaf (9 layers x 32 slots x 32 tiles of (128, 128)
     float32); and the sparse layers' page read (``ops/attention/
-    sparse_read.py``) with its plan, the call's queries and their
-    accumulators whole in VMEM: 32 decode rows, a (row, KV head) a step's
-    one entry of 16 query heads over its own pages (``sparse_read``), and a
-    chunk's 512 queries, 16 of them a step, under the blocks they chose and
-    over their window (``sparse_read_chunk``), pages of 128. The leaves
-    go in and come out in one buffer: no operation copies them."""
+    sparse_read.py``) with its plan: 32 decode rows, a (row, KV head) one
+    entry of 16 query heads, a grid step a block of 16 of its own pages
+    that the kernel fetches from the leaves in HBM by its own copies (PR
+    57; ``sparse_read``), and a chunk's 512 queries, they and their
+    accumulators whole in VMEM, 16 of them a step, under the blocks they
+    chose and over their window (``sparse_read_chunk``), pages of 128. The
+    leaves go in and come out in one buffer: no operation copies them."""
     from deepspeed_tpu.ops import lightning
     from deepspeed_tpu.ops.attention import sparse_read as sr
     from deepspeed_tpu.ops.attention.sparse_index import SparseSizes

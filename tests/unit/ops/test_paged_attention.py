@@ -1279,19 +1279,20 @@ def test_decode_rows_read_their_own_choice_and_their_window(rep, ps, bk):
     # the list: a page a (row, KV head) that holds something it sees
     own = seen.reshape(KV, B, S // ps, ps).any(-1)[:, :3]
     assert int(pages) == own.sum()
-    steps, total = sr.rows_plan(
+    (page, entry, _), count = sr.rows_plan(
         jnp.asarray(blocks), jnp.asarray(qpos), jnp.asarray(table), sizes,
         ps, k_pages.shape[1], jnp.asarray(running))
-    page, kv, entry, _, base, tile = (
-        np.asarray(x)[:int(total)] for x in steps)
+    page, entry, count = (np.asarray(x) for x in (page, entry, count))
+    assert count.sum() == own.sum()
+    assert count.max() <= len(page) // (B * KV)
+    first = np.cumsum(count) - count
     for r in range(B * KV):
-        mine = entry[tile[:, 0] == r]
+        mine = entry[first[r]:first[r] + count[r]]
         assert (np.diff(mine) > 0).all()
         assert mine.tolist() == (np.flatnonzero(
             own[r % KV, r // KV]).tolist() if r // KV < 3 else [])
-        assert (page[tile[:, 0] == r] == table[r // KV, mine]).all()
-        assert (kv[tile[:, 0] == r] == r % KV).all()
-        assert (base[tile[:, 0] == r] == qpos[r // KV]).all()
+        assert (page[first[r]:first[r] + count[r]]
+                == table[r // KV, mine]).all()
 
 
 @pytest.mark.parametrize("p0", [8, 40])

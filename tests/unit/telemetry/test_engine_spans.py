@@ -806,7 +806,8 @@ def sala_account():
 def test_sparse_attention_beside_a_lightning_state_counts_what_it_ran(
         sala_account):
     """``sparse_rows`` / ``sparse_tokens_read`` / ``sparse_index_rows`` on
-    the chunk and decode spans, from the host's positions by the EQUATIONS
+    the chunk and decode spans (``sparse_pages_most`` / ``sparse_blocks_most``
+    on the decode spans alone), from the host's positions by the EQUATIONS
     (a row under ``dense_len`` 16 reads its whole context, one past it its
     window of 8 and 2 blocks of 4; a compressed key a position once 2 have
     arrived), ``lightning_chunk_tokens`` (REAL tokens) on the prefill
@@ -831,6 +832,12 @@ def test_sparse_attention_beside_a_lightning_state_counts_what_it_ran(
         assert a["sparse_tokens_read"] <= 16 * a["live"]
     # the long request's decode rows are past dense_len: 16 tokens a row
     assert any(a["sparse_tokens_read"] == 16 * a["live"] for a in decodes)
+    # how the decode rows' read engages (PR 57): the pages their (row, KV
+    # head)s can list and the blocks those make, a block each at this size
+    for a in decodes:
+        assert a["sparse_blocks_most"] == 2 * a["live"] \
+            <= a["sparse_pages_most"] <= 2 * 4 * a["live"]
+    assert not any("sparse_pages_most" in a for a in chunks + admits)
     assert not any(key in (e.get("args") or {}) for e in evs
                    for key in ("ssm_chunk_tokens", "kda_chunk_tokens",
                                "conv_chunk_tokens", "latent_tokens_read"))
