@@ -576,8 +576,8 @@ def phase_serve(rehearsal: bool, plant: bool) -> dict:
 
     compile_requests = _CompileRequests()
 
-    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                     transformer_config)
+    from deepspeed_tpu.models.lm_config import transformer_config
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
     from deepspeed_tpu.parallel import initialize_mesh
 
     if plant:

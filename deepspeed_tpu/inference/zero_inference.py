@@ -28,8 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.transformer_lm import (TransformerBlock, TransformerConfig,
-                                      make_kv_cache_spec)
+from ..models.kv_cache_spec import make_kv_cache_spec
+from ..models.lm_config import TransformerConfig
+from ..models.transformer_lm import TransformerBlock
 from ..utils.logging import log_dist
 from ..utils.streaming import LayerWireFormat
 
@@ -127,7 +128,7 @@ class ZeroInferenceEngine:
         # without donation held ~1.5 GB/s.
         self._jit_block = jax.jit(block_fn)
 
-        from ..models.transformer_lm import make_layer_kv_cache
+        from ..models.kv_cache_spec import make_layer_kv_cache
 
         def cached_block_init_fn(layer_params, x):
             # first (prefill) pass: build this layer's zeroed cache and
@@ -163,7 +164,7 @@ class ZeroInferenceEngine:
         self._jit_cached_block = jax.jit(cached_block_fn,
                                          donate_argnums=(1,))
 
-        from ..models.transformer_lm import _norm
+        from ..models.lm_parts import _norm
 
         def embed_fn(emb, pos_emb, emb_ln, ids, start):
             B, T = ids.shape

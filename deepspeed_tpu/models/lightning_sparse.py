@@ -17,10 +17,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .transformer_lm import (TransformerConfig, _by_row_group, _chunk_positions,
-                             _chunk_shaped, _dense, _norm_qk, _project_qkv,
-                             _state_rows, _store_columns, _traced_once,
-                             apply_rotary)
+from .lm_config import TransformerConfig
+from .lm_parts import (_by_row_group, _chunk_positions, _chunk_shaped, _dense,
+                       _norm_qk, _project_qkv, _store_columns, _traced_once,
+                       apply_rotary)
+from .state_layers import _state_rows
 
 
 def _positions(kv_cache, decode, B: int, T: int):

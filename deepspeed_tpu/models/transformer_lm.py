@@ -9,45 +9,16 @@ collapse into ONE parameterized flax module: every family is a point in a
 small feature space (position encoding × norm × activation × residual
 topology × GQA), and XLA fuses what the reference hand-fused in CUDA.
 
-Families are presets of :class:`TransformerConfig` (see ``FAMILY_PRESETS``):
-
-=============  ========  =========  ========  ===================
-family         pos_emb   norm       act       notes
-=============  ========  =========  ========  ===================
-gpt2           learned   layernorm  gelu      tied head, qkv bias
-gpt-neo        learned   layernorm  gelu      local attn ignored
-gptj           rotary    layernorm  gelu      parallel residual
-gpt-neox       rotary    layernorm  gelu      parallel residual, rotary_pct
-llama          rotary    rmsnorm    swiglu    no biases, untied head, GQA
-opt            learned   layernorm  relu      tied head
-bloom          alibi     layernorm  gelu      embedding layernorm
-megatron-gpt   learned   layernorm  gelu
-mellum         rotary    rmsnorm    routed    ``head_dim`` a field, GQA,
-                                              window and full layers
-                                              (``layer_types``, rotary by
-                                              type), top-k of E experts
-brumby         rotary    rmsnorm    swiglu    every layer gated
-                                              power retention of
-                                              degree 2: a norm on q
-                                              and k, a gate a KV
-                                              head, a recurrent
-                                              state in place of K/V
-moonlight      rotary    rmsnorm    routed    latent attention (one
-                                              cached row a token),
-                                              sigmoid router with a
-                                              bias, shared experts,
-                                              leading dense layers
-granite-hybrid none      rmsnorm    swiglu    Mamba-2 layers beside
-                                              GQA layers, tied head
-kimi_linear    none      rmsnorm    routed    KDA layers beside latent
-                                              attention, moonlight's
-                                              FFN, experts held
-lfm2_moe       rotary    rmsnorm    routed    gated short-convolution
-                                              layers beside QK-normed
-                                              GQA, sigmoid router with
-                                              a bias and no shared
-                                              expert, tied head
-=============  ========  =========  ========  ===================
+Families are presets of ``TransformerConfig`` (``models/lm_config.py`` has
+the table of them). This file is the block, the layer scan, the cache's
+variables and the model. What a block mixes with lives below it, and none
+of it imports this file: ``attention_layers.py`` (K/V a head),
+``state_layers.py`` (retention, mamba, KDA, the short convolution) and
+``lightning_sparse.py`` (Lightning and learned block-sparse attention), on
+the helpers of ``lm_parts.py``; the cache's container is
+``kv_cache_spec.py``'s and what a kind refuses ``cache_kinds.py``'s. A new
+layer kind is a class in a file of its own or one of those, its rows in
+``cache_kinds.py`` and a branch of :class:`TransformerBlock`.
 
 Layer kinds that differ (``layer_types``: ``sliding_attention`` or
 ``full_attention``, each with its own rotary table) run inside the ONE
@@ -59,54 +30,30 @@ the scanned block, and reach every layer whole (a scanned leaf would be
 sliced, that is copied, a layer). Nothing of this is reached by a
 configuration with ``n_experts == 0`` and no ``layer_types``.
 
-A third kind, ``power_retention`` (:class:`PowerRetention`), keeps no K/V:
-its cache is a state of fixed size a sequence (``KVCacheSpec.state``),
-whose stacked leaf rides the scan's carry whole and is updated in place
-by the kernels of ``ops/attention/power_retention.py``, for the rows the
-caller names (``rows``) and no others. Today every layer of a model is of
-this kind or none is.
+A model of ``power_retention`` layers keeps no K/V: the state's stacked
+leaf (``KVCacheSpec.state``) rides the scan's carry whole and is updated
+in place, for the rows the caller names (``rows``) and no others.
 
-A fourth, latent attention (:class:`LatentAttention`, ``kv_lora_rank >
-0``), caches ONE row a token a layer, ``[c ; k_r]`` (the normed latent and
-the shared rotary key), that every head reads: ``KVCacheSpec.latent``, one
-leaf ``c`` and no ``k`` / ``v``. The leading ``first_k_dense`` layers of
-such a model may carry a plain gated FFN (``dense_ffn_dim``) before the
-routed layers: they are a scan of their own (``dense_blocks``) in front of
-``blocks``, the layer counter and the cache running through both.
+Latent attention (:class:`LatentAttention`, ``kv_lora_rank > 0``) caches
+ONE row a token a layer, ``[c ; k_r]`` (the normed latent and the shared
+rotary key), that every head reads: ``KVCacheSpec.latent``, one leaf ``c``
+and no ``k`` / ``v``. The leading ``first_k_dense`` layers of such a model
+may carry a plain gated FFN (``dense_ffn_dim``) before the routed layers:
+they are a scan of their own (``dense_blocks``) in front of ``blocks``, the
+layer counter and the cache running through both.
 
-A fifth, ``mamba`` (:class:`Mamba2Mixer`), stands BESIDE ``attention``
-layers (full, position-free or rotary) in one model, one attention layer a
-repeating period. The two kinds have different parameter trees, so the
-stack is two stacked leaves (``mamba_blocks``, ``attn_blocks``) run in the
-published order by :meth:`TransformerLM._hybrid_layers`, and the cache is
-two groups: a state group over the mamba layers
-(``KVCacheSpec.state_group``: ``s``, the state, and ``conv``, the
-convolution's last inputs; a row a sequence, no positions) and K/V over the
-attention layers, contiguous or paged. A page pool keeps the state group
-beside its pages.
-
-A sixth, ``kda`` (:class:`KDAMixer`, Kimi Linear's gated delta rule with a
-decay a channel), stands beside ``attention`` layers the same way (the
-state layers' stacked leaf is ``kda_blocks``; ``s`` a head's (K, V) matrix,
-``conv`` the last inputs of the three convolutions). The attention layers
-beside state layers may be latent (``kv_lora_rank > 0``: the cache is the
-state group beside the one leaf ``c``), with ``pos_emb`` "none" they rotate
-nothing, and the FFN may be routed, behind ``first_k_dense`` leading layers
-with a plain one inside the first period (``dense_blocks``, of the leading
-layers' kind). A routed FFN may hold a share of its experts
-(``experts_held``).
-
-A seventh, ``conv`` (:class:`ShortConvMixer`, LFM2's gated short
-convolution), is a state layer with NO matrix state: its state group is the
-one leaf ``conv``, the convolution's last ``conv_taps - 1`` inputs (the
-stacked leaf is ``conv_blocks``). The attention layers beside it may rotate
-(``pos_emb`` "rotary") and norm each head of q and k (``qk_norm``).
-
-KV-cache decoding uses the flax ``cache`` variable collection: ``prefill``
-writes the prompt's K/V at positions [0, T), ``decode`` appends one position
-via ``lax.dynamic_update_slice`` and attends over the static-shape cache with
-a validity mask — static shapes keep XLA happy (the reference's
-inference_context.h workspace is the moral equivalent).
+State layers of one kind (``STATE_KINDS``: ``mamba``, ``kda``, ``conv``,
+``lightning``) stand BESIDE ``attention`` layers (full, position-free or
+rotary, K/V a head or latent, sparse under ``sparse_attention``) in one
+model. The two kinds have different parameter trees, so the stack is two
+stacked leaves (``<kind>_blocks``, ``attn_blocks``) run in the published
+order by :meth:`TransformerLM._hybrid_layers`, and the cache is two
+groups: a state group over the state layers (``KVCacheSpec.state_group``)
+and K/V, or the one leaf ``c``, over the attention layers, contiguous or
+paged. A page pool keeps the state group beside its pages. The FFN may be
+routed, behind ``first_k_dense`` leading layers with a plain one inside
+the first period (``dense_blocks``, of the leading layers' kind), and may
+hold a share of its experts (``experts_held``).
 """
 
 from __future__ import annotations
@@ -114,1361 +61,33 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Optional, Tuple, Union
+from typing import Optional, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from ..ops import backend
-
-
-@dataclasses.dataclass(frozen=True)
-class TransformerConfig:
-    vocab_size: int = 50257
-    max_seq_len: int = 2048
-    n_embd: int = 768
-    n_layer: int = 12
-    n_head: int = 12
-    n_kv_head: Optional[int] = None     # < n_head ⇒ grouped-query attention
-    pos_emb: str = "learned"            # learned | rotary | alibi | none
-    rotary_pct: float = 1.0             # fraction of head_dim rotated (neox)
-    rope_theta: float = 10000.0
-    norm: str = "layernorm"             # layernorm | rmsnorm
-    activation: str = "gelu"            # gelu | relu | swiglu
-    mlp_ratio: float = 4.0
-    parallel_residual: bool = False     # gptj/neox: x + attn(ln1 x) + mlp(ln2 x)
-    qkv_bias: bool = True
-    mlp_bias: bool = True
-    embed_layernorm: bool = False       # bloom
-    tie_word_embeddings: bool = True
-    layer_norm_epsilon: float = 1e-5
-    dropout: float = 0.0
-    dtype: Any = jnp.bfloat16
-    use_flash_attention: Any = "auto"   # True | False | "auto" (Pallas flash
-    # for the full-context forward on TPU from the tuned crossover length;
-    # alibi and train-mode attention dropout stay on the einsum path)
-    remat: bool = False
-    decode_kernel: str = "auto"         # auto | on | off (fused Pallas decode)
-    decode_block: Optional[int] = None  # pin the fused decode kernel's block
-    # granule (STATIC int). The paged-attention kernel's position block is
-    # one page, so a dense arm pinned to decode_block=page_size runs the
-    # SAME online-softmax blocking — the bitwise-parity oracle for the
-    # paged kernel (ops/attention/paged_attention.py). None keeps the
-    # allocation-based default (pick_block_s).
-    kv_cache_quant: bool = False        # int8 KV cache (per-row scales):
-    # halves the cache's HBM traffic — the resource decode is bound by —
-    # and halves KV memory, doubling the servable context per chip
-    kv_cache_packed: Optional[bool] = None  # store the int8 cache in an
-    # int32 container (pack_int8_sublanes: 4 head-dim rows per word, the
-    # TPU's own sublane byte order, so the kernel unpacks with a free
-    # pltpu.bitcast). Same bytes in a natively-tiled dtype — insurance
-    # against Mosaic's (4,1)-packed s8 layout-conversion copies (the
-    # round-4/5 capacity killer; the positions-minor layout + carry-DUS
-    # scan fixed the measured cases, and packed/plain now measure equal —
-    # PERF.md §8). Only meaningful with
-    # kv_cache_quant; requires head_dim % 4 == 0. Tri-state: None (auto,
-    # the default) packs when head_dim allows and warns once when it
-    # can't; True requires a packable head_dim (raises otherwise);
-    # False keeps the plain int8 container.
-    int8_weights: bool = False          # serve with int8-at-rest Dense kernels
-    int8_kernel: str = "auto"           # auto | on | off (Pallas dequant-GEMM)
-    int8_head: bool = False             # quantize lm_head too (off: the vocab
-    # projection — the largest single accuracy lever — stays full precision,
-    # matching the ZeRO-Inference streamed tier and reference practice)
-    loss_chunk: int = 0                 # streaming cross-entropy: >0 computes
-    # the LM loss in T-chunks of this size without materializing the
-    # (B, T, V) logits (ops/transformer/chunked_xent.py); 0 = dense loss
-    head_size: Optional[int] = None     # per-head width where it is not
-    # n_embd // n_head (read it as ``head_dim``)
-    ffn_dim: Optional[int] = None       # FFN width where it is not
-    # mlp_ratio * n_embd; with experts, the width of ONE expert
-    layer_types: Optional[Tuple[str, ...]] = None   # per layer,
-    # "sliding_attention" | "full_attention"; None: every layer full
-    sliding_window: Optional[int] = None    # a sliding layer's query i sees
-    # key j iff 0 <= i - j < sliding_window
-    rope_parameters: Optional[tuple] = None     # rotary by layer type, as
-    # frozen by transformer_config from {"<layer type>": {"rope_type":
-    # "default" | "yarn", "rope_theta", "factor",
-    # "original_max_position_embeddings", "beta_fast", "beta_slow",
-    # "attention_factor"}}; None: rope_theta for every layer
-    n_experts: int = 0                  # > 0: the FFN of every layer is
-    # routed (deepspeed_tpu/moe/routed_ffn.py), ffn_dim an expert's width
-    experts_per_token: int = 0
-    norm_topk_prob: bool = True         # renormalise the chosen experts'
-    # router probabilities to sum to 1
-    qk_norm: bool = False               # RMSNorm over each head of q and k
-    # (a learned weight of head_dim), before the rotary
-    kv_lora_rank: int = 0               # > 0: latent attention
-    # (LatentAttention): K and V of every head are projections of one
-    # normed latent of this width a token, which is what the cache holds
-    # beside the shared rotary key
-    qk_nope_head_dim: int = 0           # a head's q / k width without rotary
-    qk_rope_head_dim: int = 0           # ... with rotary (k's: one a token)
-    v_head_dim: int = 0
-    scoring_func: str = "softmax"       # the router's: softmax | sigmoid
-    # (sigmoid: the choice is ordered by score + a learned bias, the
-    # weights come from the unbiased scores)
-    routed_scaling_factor: float = 1.0  # multiplies the routed weights
-    topk_norm_eps: float = 1e-20        # the sigmoid router's: joins the
-    # chosen scores' sum before they are divided by it (norm_topk_prob)
-    n_shared_experts: int = 0           # one gated FFN of this many expert
-    # widths beside the routed sum, for every token
-    first_k_dense: int = 0              # the first layers' FFN is a plain
-    # gated FFN of dense_ffn_dim; the routed FFN starts after them
-    dense_ffn_dim: Optional[int] = None
-    # Mamba-2 layers (``layer_types`` "mamba", beside "attention" layers
-    # without a window): heads of mamba_d_head, a state of mamba_d_state
-    # columns a head, B and C shared by the heads (one group), a causal
-    # depthwise convolution of mamba_d_conv taps over [x ; B ; C]
-    mamba_n_heads: int = 0
-    mamba_d_head: int = 0
-    mamba_d_state: int = 0
-    mamba_n_groups: int = 1
-    mamba_d_conv: int = 4
-    # KDA layers (``layer_types`` "kda", beside "attention" layers): heads
-    # of kda_d_head key channels and as many value channels, a causal
-    # depthwise convolution of kda_d_conv taps over each of q, k and v, the
-    # decay and the output gate through a low rank of kda_d_head
-    # (ops/kda.py has the state's equations)
-    kda_n_heads: int = 0
-    kda_d_head: int = 0
-    kda_d_conv: int = 4
-    # gated short-convolution layers (``layer_types`` "conv", beside
-    # "attention" layers): a causal depthwise convolution of conv_taps taps
-    # over n_embd channels between two gates, no bias, no activation
-    # (ShortConvMixer has the equations); the state is the convolution's tail
-    conv_taps: int = 3
-    # Lightning layers (``layer_types`` "lightning", beside "attention"
-    # layers, in ANY order: the stack is run as a list of runs where it
-    # does not repeat): n_head heads of head_dim with their own q, k and v,
-    # a float32 state of head_dim x head_dim a head under one constant decay
-    # a head (models/lightning_sparse.py, ops/lightning.py); the layer
-    # rotates its own q and k whatever pos_emb says of the attention layers
-    # learned block-sparse attention in the attention layers' place
-    # (ops/attention/sparse_index.py has the equations), as frozen by
-    # transformer_config from {"kernel_size", "kernel_stride", "block_size",
-    # "init_blocks", "window_size", "topk", "dense_len"}; None: every key
-    sparse_attention: Optional[tuple] = None
-    attn_output_gate: bool = False      # o (.) sigmoid(W_z x) before o_proj
-    # (the sparse attention layers')
-    experts_held: Optional[int] = None  # the routed FFN holds experts
-    # [0, experts_held) of n_experts (one chip's share of a layer that
-    # several divide): the router and the top-k run over all n_experts, the
-    # held experts' part of the sum goes on; None: all
-    embedding_multiplier: float = 1.0   # scales the token embedding
-    embedding_init_std: Optional[float] = None  # a seeded embedding's
-    # spread where it is not flax's 1 / sqrt(n_embd): under a tied head a
-    # position's own input token scores its row's share of the stream
-    attention_multiplier: Optional[float] = None    # the softmax scale
-    # where it is not 1 / sqrt(head_dim)
-    residual_multiplier: float = 1.0    # x + r * a, x + r * ffn
-    logits_scaling: float = 1.0         # the logits are divided by it
-
-    def __post_init__(self):
-        if self.layer_types is not None:
-            kinds = set(self.layer_types) - {"sliding_attention",
-                                             "full_attention",
-                                             "power_retention",
-                                             "mamba", "kda", "conv",
-                                             "lightning", "attention"}
-            if kinds or len(self.layer_types) != self.n_layer:
-                raise ValueError(
-                    f"layer_types names n_layer={self.n_layer} layers as "
-                    f"sliding_attention | full_attention | power_retention "
-                    f"| mamba | kda | conv | lightning | attention; "
-                    f"got {len(self.layer_types)} entries, unknown "
-                    f"{sorted(kinds)}")
-            if set(STATE_KINDS + ("attention",)) & set(self.layer_types):
-                self._check_hybrid()
-            if "power_retention" in self.layer_types:
-                if set(self.layer_types) != {"power_retention"}:
-                    raise ValueError(
-                        "power_retention layers beside attention layers: "
-                        "the retention state is one leaf over every layer "
-                        "of the model (KVCacheSpec.state; the state group "
-                        "beside K/V is the mamba layers'): every layer is "
-                        "power_retention or none is (ROADMAP.md, Reach)")
-                if self.head_dim % 8 or self.n_head // self.kv_heads \
-                        >= self.head_dim:
-                    raise ValueError(
-                        f"power_retention needs head_dim % 8 == 0 and fewer "
-                        f"query heads a KV head than head_dim; got head_dim="
-                        f"{self.head_dim}, {self.n_head} / {self.kv_heads}")
-            if "sliding_attention" in self.layer_types \
-                    and not self.sliding_window:
-                raise ValueError("sliding_attention layers need "
-                                 "sliding_window")
-            if self.pos_emb not in ("rotary", "none"):
-                raise ValueError(
-                    f"layer_types composes with rotary or no positions, "
-                    f"not pos_emb={self.pos_emb!r}")
-        if self.n_experts:
-            if not 0 < self.experts_per_token <= self.n_experts:
-                raise ValueError(
-                    f"experts_per_token={self.experts_per_token} of "
-                    f"n_experts={self.n_experts}")
-            if self.experts_held is not None \
-                    and not 0 < self.experts_held <= self.n_experts:
-                raise ValueError(
-                    f"experts_held={self.experts_held} of "
-                    f"n_experts={self.n_experts}")
-            if self.activation != "swiglu" or self.mlp_bias:
-                raise ValueError("the routed FFN is gated silu without "
-                                 "bias (activation='swiglu', mlp_bias=False)")
-        if self.scoring_func not in ("softmax", "sigmoid"):
-            raise ValueError(f"scoring_func {self.scoring_func!r}: know "
-                             f"softmax | sigmoid")
-        if self.scoring_func == "softmax" and self.routed_scaling_factor != 1:
-            raise ValueError(
-                "routed_scaling_factor multiplies the sigmoid router's "
-                "weights; the softmax router's sum to 1 (norm_topk_prob) "
-                "or are probabilities")
-        if self.first_k_dense:
-            if not self.n_experts or not self.dense_ffn_dim \
-                    or not 0 < self.first_k_dense < self.n_layer:
-                raise ValueError(
-                    f"first_k_dense={self.first_k_dense} names the leading "
-                    f"layers of a routed model (n_experts > 0, fewer than "
-                    f"n_layer={self.n_layer}) whose FFN is plain, of "
-                    f"dense_ffn_dim={self.dense_ffn_dim}")
-        if self.latent:
-            if not (self.qk_nope_head_dim and self.qk_rope_head_dim
-                    and self.v_head_dim) or self.qk_rope_head_dim % 2:
-                raise ValueError(
-                    "latent attention (kv_lora_rank > 0) needs "
-                    "qk_nope_head_dim, an even qk_rope_head_dim and "
-                    "v_head_dim")
-            if self.pos_emb not in ("rotary", "none") or (
-                    self.layer_types is not None and not self.hybrid):
-                raise ValueError(
-                    "latent attention carries its positions in the shared "
-                    "rotary key (pos_emb='rotary') or none at all ('none'), "
-                    "and knows no layer kinds (layer_types) but the state "
-                    "layers beside it: no window and no retention yet "
-                    "(ROADMAP.md, Reach)")
-        if self.sparse_attention is not None:
-            self.sparse.check()
-            if not self.hybrid or self.latent or self.pos_emb != "none":
-                raise ValueError(
-                    "sparse_attention is the attention layers' of a stack "
-                    "of state and attention layers (layer_types), K/V a "
-                    "head, without positions (pos_emb='none'): the index "
-                    "scores keys that carry none (ROADMAP.md, Reach)")
-        if self.attn_output_gate and self.sparse_attention is None:
-            raise ValueError("attn_output_gate is the sparse attention "
-                             "layers' (sparse_attention)")
-        for feature in ("kv_cache_quant", "int8_weights"):
-            why = getattr(self, feature) \
-                and refusal(cache_kinds(self), feature)
-            if why:
-                raise ValueError(why)
-
-    def _check_hybrid(self) -> None:
-        """State layers of ONE kind (:data:`STATE_KINDS`) stand beside
-        ``attention`` layers (full, K/V a head or latent, with ``pos_emb``
-        "rotary" or "none"; sparse under ``sparse_attention``). In a
-        pattern that repeats, one attention layer a period and the same
-        number of state layers before and after it in every period
-        (:attr:`hybrid_period`), the FFN may be routed, and the
-        ``first_k_dense`` layers with a plain one are state layers at the
-        head of the first period. Any other order (adjacent attention
-        layers, runs of unequal length) is run as a list of runs
-        (:attr:`hybrid_runs`) with a plain FFN in every layer."""
-        types = self.layer_types
-        n_att = types.count("attention")
-        state = set(types) - {"attention"}
-        if len(state) != 1 or not state < set(STATE_KINDS) or not n_att:
-            raise ValueError(
-                f"state layers of ONE kind ({' | '.join(STATE_KINDS)}) "
-                f"stand beside attention layers, as a pattern with ONE "
-                f"attention layer that repeats over the layers or as any "
-                f"list of runs of the two; got {list(types)}")
-        if not self.hybrid_repeats and (self.n_experts
-                                        or self.first_k_dense):
-            raise ValueError(
-                f"a routed FFN or leading dense layers count the layers "
-                f"period by period: a pattern with ONE attention layer "
-                f"that repeats over the layers; got {list(types)} (a "
-                f"pattern without a period is run as a list of runs, "
-                f"hybrid_runs, with a plain FFN in every layer)")
-        if self.mamba and (
-                not (self.mamba_n_heads and self.mamba_d_head
-                     and self.mamba_d_state) or self.mamba_n_groups != 1
-                or self.mamba_d_conv < 2):
-            raise ValueError(
-                f"mamba layers need mamba_n_heads, mamba_d_head and "
-                f"mamba_d_state, one group (B and C shared by the heads) "
-                f"and a convolution of two taps or more; got "
-                f"{self.mamba_n_heads} x {self.mamba_d_head}, state "
-                f"{self.mamba_d_state}, groups {self.mamba_n_groups}, "
-                f"taps {self.mamba_d_conv}")
-        if self.kda and (not (self.kda_n_heads and self.kda_d_head)
-                         or self.kda_d_conv < 2):
-            raise ValueError(
-                f"kda layers need kda_n_heads and kda_d_head and a "
-                f"convolution of two taps or more; got {self.kda_n_heads} x "
-                f"{self.kda_d_head}, taps {self.kda_d_conv}")
-        if self.conv and self.conv_taps < 2:
-            raise ValueError(
-                f"conv layers need a convolution of two taps or more (the "
-                f"state is its tail); got conv_taps={self.conv_taps}")
-        if self.parallel_residual:
-            raise ValueError("state layers know the sequential residual")
-        if self.first_k_dense and self.first_k_dense > self.hybrid_period[0]:
-            raise ValueError(
-                f"first_k_dense={self.first_k_dense} leading layers with a "
-                f"plain FFN are state layers at the head of the first "
-                f"period, which has {self.hybrid_period[0]} before its "
-                f"attention layer")
-
-    @property
-    def head_dim(self) -> int:
-        return self.head_size or self.n_embd // self.n_head
-
-    @property
-    def ffn_width(self) -> int:
-        return self.ffn_dim or int(self.mlp_ratio * self.n_embd)
-
-    @property
-    def kv_heads(self) -> int:
-        return self.n_kv_head or self.n_head
-
-    @property
-    def latent(self) -> int:
-        """Width of the one cached row a token of latent attention
-        (``kv_lora_rank + qk_rope_head_dim``), 0 for K/V a head."""
-        return self.kv_lora_rank + self.qk_rope_head_dim \
-            if self.kv_lora_rank else 0
-
-    def dense_layers(self) -> "TransformerConfig":
-        """The configuration of the leading ``first_k_dense`` layers:
-        this one with a plain gated FFN of ``dense_ffn_dim``."""
-        return dataclasses.replace(
-            self, n_experts=0, experts_per_token=0, n_shared_experts=0,
-            experts_held=None, first_k_dense=0, ffn_dim=self.dense_ffn_dim)
-
-    @property
-    def retention(self) -> bool:
-        """Every layer is ``power_retention``: a state, no K/V."""
-        return self.layer_types is not None \
-            and "power_retention" in self.layer_types
-
-    @property
-    def mamba(self) -> bool:
-        """``mamba`` layers beside ``attention`` layers: a state group
-        over the former, K/V over the latter."""
-        return self.layer_types is not None and "mamba" in self.layer_types
-
-    @property
-    def kda(self) -> bool:
-        """``kda`` layers beside ``attention`` layers: a state group over
-        the former, K/V or a latent row over the latter."""
-        return self.layer_types is not None and "kda" in self.layer_types
-
-    @property
-    def conv(self) -> bool:
-        """``conv`` layers beside ``attention`` layers: a state group of
-        the convolution's tail alone over the former, K/V over the latter."""
-        return self.layer_types is not None and "conv" in self.layer_types
-
-    @property
-    def lightning(self) -> bool:
-        """``lightning`` layers beside ``attention`` layers: a state group
-        of the linear attention's state over the former, K/V (and the
-        index's group means, where the attention is sparse) over the
-        latter."""
-        return self.layer_types is not None \
-            and "lightning" in self.layer_types
-
-    @property
-    def sparse(self):
-        """``sparse_attention`` as ``ops.attention.sparse_index.SparseSizes``,
-        or None."""
-        if self.sparse_attention is None:
-            return None
-        from ..ops.attention.sparse_index import SparseSizes
-
-        return SparseSizes(**dict(self.sparse_attention))
-
-    @property
-    def hybrid(self) -> Optional[str]:
-        """The kind of the state layers that stand beside ``attention``
-        layers, one of :data:`STATE_KINDS`; None for a model of one stack."""
-        return next((kind for kind in STATE_KINDS
-                     if self.layer_types is not None
-                     and kind in self.layer_types), None)
-
-    @property
-    def kda_width(self) -> int:
-        """``kda_n_heads * kda_d_head``: the width of q, k and v each."""
-        return self.kda_n_heads * self.kda_d_head
-
-    @property
-    def hybrid_repeats(self) -> bool:
-        """Whether the state and attention layers come as a pattern with
-        ONE attention layer that repeats over the layers
-        (:attr:`hybrid_period`); else they are :attr:`hybrid_runs`."""
-        n_att = self.layer_types.count("attention")
-        return not self.n_layer % n_att and self.layer_types \
-            == self.layer_types[:self.n_layer // n_att] * n_att
-
-    @property
-    def hybrid_runs(self) -> tuple:
-        """The stack as a list of runs, ``((kind, first, count), ...)``:
-        ``count`` layers of one kind side by side, the ``first`` of them
-        counted among the layers of that kind (where its slice of the
-        kind's stacked leaves and its cache lie)."""
-        runs, seen = [], {}
-        for kind in self.layer_types:
-            if runs and runs[-1][0] == kind:
-                runs[-1][2] += 1
-            else:
-                runs.append([kind, seen.get(kind, 0), 1])
-            seen[kind] = seen.get(kind, 0) + 1
-        return tuple(tuple(run) for run in runs)
-
-    @property
-    def hybrid_period(self) -> tuple:
-        """``(before, after, periods)``: the state layers before and after
-        a period's one attention layer, and how many periods there are."""
-        periods = self.layer_types.count("attention")
-        period = self.layer_types[:self.n_layer // periods]
-        before = period.index("attention")
-        return before, len(period) - before - 1, periods
-
-    @property
-    def mamba_channels(self) -> int:
-        """The convolution's channels: ``[x ; B ; C]``."""
-        return self.mamba_n_heads * self.mamba_d_head \
-            + 2 * self.mamba_n_groups * self.mamba_d_state
-
-
-# the kinds of state layer that stand beside ``attention`` layers
-STATE_KINDS = ("mamba", "kda", "conv", "lightning")
-
-FAMILY_PRESETS = {
-    "gpt2": dict(pos_emb="learned", norm="layernorm", activation="gelu"),
-    "gpt-neo": dict(pos_emb="learned", norm="layernorm", activation="gelu"),
-    "gptj": dict(pos_emb="rotary", norm="layernorm", activation="gelu",
-                 parallel_residual=True, tie_word_embeddings=False),
-    "gpt-neox": dict(pos_emb="rotary", rotary_pct=0.25, norm="layernorm",
-                     activation="gelu", parallel_residual=True,
-                     tie_word_embeddings=False),
-    "llama": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
-                  qkv_bias=False, mlp_bias=False, tie_word_embeddings=False,
-                  layer_norm_epsilon=1e-6),
-    "opt": dict(pos_emb="learned", norm="layernorm", activation="relu"),
-    "bloom": dict(pos_emb="alibi", norm="layernorm", activation="gelu",
-                  embed_layernorm=True),
-    "megatron-gpt": dict(pos_emb="learned", norm="layernorm", activation="gelu"),
-    # Mellum 2 (JetBrains): llama's block with head_dim, FFN width, layer
-    # kinds, rotary by kind and the routed FFN given by the caller
-    "mellum": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
-                   qkv_bias=False, mlp_bias=False, tie_word_embeddings=False,
-                   layer_norm_epsilon=1e-6),
-    # Brumby (Manifest AI): Qwen3's block (llama's with a norm on q and k)
-    # whose every layer is gated power retention (``layer_kind``: one kind
-    # for every layer, which transformer_config spells out as layer_types
-    # once it knows n_layer)
-    "brumby": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
-                   qkv_bias=False, mlp_bias=False, tie_word_embeddings=False,
-                   layer_norm_epsilon=1e-6, qk_norm=True,
-                   layer_kind="power_retention"),
-    # Moonlight (Moonshot AI; model_type deepseek_v3): latent attention,
-    # a sigmoid router ordered by score + bias, shared experts, leading
-    # dense layers (``mlp_layer_types``, which transformer_config reads
-    # into first_k_dense). Widths are the caller's.
-    "moonlight": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
-                      qkv_bias=False, mlp_bias=False,
-                      tie_word_embeddings=False, layer_norm_epsilon=1e-5,
-                      scoring_func="sigmoid"),
-    # Granite 4.0 hybrid (IBM; model_type granitemoehybrid): Mamba-2 layers
-    # beside position-free GQA layers (``layer_types`` as published), a
-    # SwiGLU in every layer, tied head, four scalars. Widths, the pattern
-    # and the scalars are the caller's.
-    "granite-hybrid": dict(pos_emb="none", norm="rmsnorm",
-                           activation="swiglu", qkv_bias=False,
-                           mlp_bias=False, tie_word_embeddings=True,
-                           layer_norm_epsilon=1e-5),
-    # Kimi Linear (Moonshot AI; model_type kimi_linear): KDA layers beside
-    # latent attention layers that rotate nothing (``layer_types`` "kda" |
-    # "attention"), Moonlight's router and shared expert behind one leading
-    # dense layer, an untied head. Widths and the pattern are the caller's.
-    "kimi_linear": dict(pos_emb="none", norm="rmsnorm", activation="swiglu",
-                        qkv_bias=False, mlp_bias=False,
-                        tie_word_embeddings=False, layer_norm_epsilon=1e-5,
-                        scoring_func="sigmoid"),
-    # LFM2 MoE (Liquid AI; model_type lfm2_moe): gated short-convolution
-    # layers beside rotary GQA layers with a norm on each head of q and k
-    # (``layer_types`` as published, "conv" | "full_attention"), a sigmoid
-    # router ordered by score + bias over ``sum + 1e-6`` with no shared
-    # expert, behind leading dense layers (``first_k_dense``); the head is
-    # tied. Widths and the pattern are the caller's.
-    "lfm2_moe": dict(pos_emb="rotary", norm="rmsnorm", activation="swiglu",
-                     qkv_bias=False, mlp_bias=False,
-                     tie_word_embeddings=True, layer_norm_epsilon=1e-5,
-                     qk_norm=True, scoring_func="sigmoid",
-                     topk_norm_eps=1e-6),
-    # MiniCPM-SALA (OpenBMB; model_type minicpm_sala): Lightning
-    # linear-attention layers beside learned block-sparse GQA layers in the
-    # published, irregular order (``mixer_types`` "lightning-attn" |
-    # "minicpm4", or ``layer_types`` "lightning" | "sparse_attention"), a
-    # norm on each head of q and k in both, an output gate on both (the
-    # sparse layers' where ``sparse_attention`` is given), no
-    # positions in the attention layers and a rotary in the Lightning
-    # layers, MiniCPM's three scalars (``embedding_multiplier``,
-    # ``residual_multiplier``, ``logits_scaling``), an untied head. Widths,
-    # the pattern, the scalars and ``sparse_attention`` are the caller's.
-    "minicpm_sala": dict(pos_emb="none", norm="rmsnorm",
-                         activation="swiglu", qkv_bias=False,
-                         mlp_bias=False, tie_word_embeddings=False,
-                         layer_norm_epsilon=1e-6, qk_norm=True),
-}
-
-
-def _freeze(value):
-    """JSON-shaped ``value`` as something a frozen dataclass can hash."""
-    if isinstance(value, dict):
-        return tuple(sorted((k, _freeze(v)) for k, v in value.items()))
-    if isinstance(value, (list, tuple)):
-        return tuple(_freeze(v) for v in value)
-    return value
-
-
-def transformer_config(family: str, **overrides) -> TransformerConfig:
-    """Build a config from a family preset (≅ picking an injection policy,
-    reference module_inject/replace_policy.py)."""
-    if family not in FAMILY_PRESETS:
-        raise ValueError(f"unknown family {family!r}; know {sorted(FAMILY_PRESETS)}")
-    overrides = {k: _freeze(v) if k in ("layer_types", "rope_parameters",
-                                        "sparse_attention")
-                 else v for k, v in overrides.items()}
-    cfg = {**FAMILY_PRESETS[family], **overrides}
-    mixers = cfg.pop("mixer_types", None)
-    if mixers is not None:
-        # published a layer as "lightning-attn" | "minicpm4"
-        cfg.setdefault("layer_types", tuple(mixers))
-    if {"lightning", "lightning-attn"} & set(cfg.get("layer_types") or ()):
-        # (beside Lightning layers the sparse layers are the stack's
-        # ``attention``: what makes them sparse is ``sparse_attention``)
-        names = {"lightning-attn": "lightning", "minicpm4": "attention",
-                 "sparse_attention": "attention"}
-        cfg["layer_types"] = tuple(names.get(kind, kind)
-                                   for kind in cfg["layer_types"])
-    if "conv" in (cfg.get("layer_types") or ()):
-        # (published beside "conv" as "full_attention": the one attention
-        # kind of a stack of state and attention layers)
-        cfg["layer_types"] = tuple(
-            "attention" if kind == "full_attention" else kind
-            for kind in cfg["layer_types"])
-    kind = cfg.pop("layer_kind", None)
-    if kind is not None:
-        cfg.setdefault("layer_types", (kind,) * cfg.get(
-            "n_layer", TransformerConfig.n_layer))
-    mlp_kinds = cfg.pop("mlp_layer_types", None)
-    if mlp_kinds is not None:
-        # published per layer as "dense" | "sparse"; the program runs the
-        # first n_layer entries: dense layers first, then sparse ones
-        kinds = list(mlp_kinds)[:cfg.get("n_layer", TransformerConfig.n_layer)]
-        k = kinds.index("sparse") if "sparse" in kinds else len(kinds)
-        if set(kinds[:k]) - {"dense"} or set(kinds[k:]) - {"sparse"}:
-            raise ValueError(
-                f"mlp_layer_types names leading dense layers, then sparse "
-                f"ones; got {kinds}")
-        cfg.setdefault("first_k_dense", k)
-    return TransformerConfig(**cfg)
-
-
-def transformer_logical_axes():
-    """LOGICAL axis annotations for this module's parameter paths (≅ t5x
-    ``param_with_axes`` metadata, expressed as path patterns so the flax
-    modules stay annotation-free). Works for every family preset (paths
-    are family-invariant). Scanned blocks carry a leading ``layers`` dim;
-    ``heads`` is the fused heads*head_dim projection width and ``ffn``
-    the MLP hidden width."""
-    return [
-        (r"embed_tokens/embedding", ("vocab", "embed")),
-        (r"embed_pos/embedding", ("positions", "embed")),
-        (r"attn/(q_proj|k_proj|v_proj)/kernel", ("layers", "embed", "heads")),
-        (r"attn/o_proj/kernel", ("layers", "heads", "embed")),
-        (r"attn/(q_proj|k_proj|v_proj)/bias", ("layers", "heads")),
-        (r"mlp/(up_proj|gate_proj)/kernel", ("layers", "embed", "ffn")),
-        (r"mlp/(up_proj|gate_proj)/bias", ("layers", "ffn")),
-        (r"mlp/down_proj/kernel", ("layers", "ffn", "embed")),
-        (r"lm_head/kernel", ("embed", "vocab")),
-    ]
-
-
-def transformer_sharding_rules(rules=None):
-    """Megatron-style TP rules for this module's parameter paths — the
-    AutoTP analog (reference module_inject/auto_tp.py:13): column-parallel
-    up-projections, row-parallel down-projections, vocab-parallel
-    embedding. Derived by resolving :func:`transformer_logical_axes`
-    through the ``parallel/`` axis-rules table (``rules`` overrides the
-    default) so one table swap re-partitions the module; the default
-    table reproduces the historical hard-coded placement exactly
-    (pinned by tests/unit/parallel/test_axis_rules.py)."""
-    from ..parallel.axis_rules import default_axis_rules
-
-    rules = rules if rules is not None else default_axis_rules()
-    return [(pat, rules.spec_entries(axes))
-            for pat, axes in transformer_logical_axes()]
-
-
-def _dense(cfg: TransformerConfig, features: int, *, use_bias: bool,
-           name: str, dtype=None):
-    """nn.Dense, or its int8-at-rest serving twin when ``cfg.int8_weights``
-    — params become int8 kernel + f32 per-channel scale consumed by the
-    Pallas dequant-GEMM (ops/quantization); the inference engine's
-    quantization tier builds that tree from a bf16 checkpoint."""
-    if cfg.int8_weights:
-        from ..ops.quantization import QuantDense
-
-        return QuantDense(features, use_bias=use_bias, dtype=dtype or cfg.dtype,
-                          kernel_mode=cfg.int8_kernel, name=name)
-    return nn.Dense(features, use_bias=use_bias, dtype=dtype or cfg.dtype,
-                    name=name)
-
-
-def _norm(cfg: TransformerConfig, name: str):
-    if cfg.norm == "rmsnorm":
-        return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, name=name)
-    return nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, name=name)
-
-
-def _rotate_half(x):
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([-x2, x1], axis=-1)
-
-
-def apply_rotary(x, positions, *, rotary_dim: int, theta: float):
-    """NeoX-style rotary embedding on the first ``rotary_dim`` channels.
-    x: (B, T, H, D); positions: (B, T) absolute token positions."""
-    rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
-                                / rotary_dim))
-    angles = positions[..., None].astype(jnp.float32) * inv_freq  # (B,T,rd/2)
-    angles = jnp.concatenate([angles, angles], axis=-1)[:, :, None, :]  # (B,T,1,rd)
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    rot32 = rot.astype(jnp.float32)
-    out = rot32 * cos + _rotate_half(rot32) * sin
-    return jnp.concatenate([out.astype(x.dtype), rest], axis=-1)
-
-
-def rope_inv_freq(rotary_dim: int, rope: dict):
-    """``(inv_freq (rotary_dim // 2,), factor)`` of one ``rope_parameters``
-    section: ``default`` is ``theta ** (-2i / d)`` with factor 1; ``yarn``
-    is the static YaRN of the ``transformers`` library (``truncate`` at its
-    default): frequencies under ``low`` keep ``f_i``, over ``high`` take
-    ``f_i / s``, a linear ramp between, and cos and sin are multiplied by
-    ``attention_factor`` (``0.1 ln s + 1`` where the section gives none)."""
-    d = rotary_dim
-    theta = float(rope.get("rope_theta", 10000.0))
-    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    kind = rope.get("rope_type", "default")
-    if kind == "default":
-        return f.astype(np.float32), 1.0
-    if kind != "yarn":
-        raise ValueError(f"rope_type {kind!r}: know default | yarn")
-    s = float(rope["factor"])
-    L0 = float(rope["original_max_position_embeddings"])
-
-    def corr(beta):
-        return d * math.log(L0 / (2 * math.pi * beta)) / (2 * math.log(theta))
-
-    low = max(math.floor(corr(float(rope.get("beta_fast", 32.0)))), 0)
-    high = min(math.ceil(corr(float(rope.get("beta_slow", 1.0)))), d - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
-                   / (high - low), 0.0, 1.0)
-    factor = rope.get("attention_factor")
-    if factor is None:
-        factor = 0.1 * math.log(s) + 1.0
-    return (f / s * ramp + f * (1 - ramp)).astype(np.float32), float(factor)
-
-
-def layer_rope_tables(cfg: "TransformerConfig"):
-    """Per layer of a ``layer_types`` configuration: ``(inv_freq (L, rd/2),
-    factor (L,), window (L,) bool)`` as constants the scanned layer indexes
-    by the scan's counter."""
-    rd = int(cfg.rotary_pct * cfg.head_dim) // 2 * 2
-    sections = {k: dict(v) for k, v in (cfg.rope_parameters or ())}
-    rows, factors = [], []
-    for kind in cfg.layer_types:
-        inv, factor = rope_inv_freq(
-            rd, sections.get(kind, {"rope_theta": cfg.rope_theta}))
-        rows.append(inv)
-        factors.append(factor)
-    return (np.stack(rows), np.asarray(factors, np.float32),
-            np.asarray([k == "sliding_attention" for k in cfg.layer_types]))
-
-
-def apply_rotary_table(x, positions, inv_freq, factor, rotary_dim: int):
-    """:func:`apply_rotary` with the frequencies given (a layer's row of
-    :func:`layer_rope_tables`) and cos, sin scaled by ``factor``."""
-    rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
-    angles = positions[..., None].astype(jnp.float32) * inv_freq
-    angles = jnp.concatenate([angles, angles], axis=-1)[:, :, None, :]
-    cos, sin = jnp.cos(angles) * factor, jnp.sin(angles) * factor
-    rot32 = rot.astype(jnp.float32)
-    out = rot32 * cos + _rotate_half(rot32) * sin
-    return jnp.concatenate([out.astype(x.dtype), rest], axis=-1)
-
-
-def alibi_slopes(n_head: int) -> jnp.ndarray:
-    """Per-head ALiBi slopes (Press et al.), matching the reference's alibi
-    computation used for bloom (csrc attention alibi path)."""
-    def pow2_slopes(n):
-        start = 2.0 ** (-(2.0 ** -(math.log2(n) - 3)))
-        return [start * (start ** i) for i in range(n)]
-
-    if math.log2(n_head).is_integer():
-        return jnp.asarray(pow2_slopes(n_head), jnp.float32)
-    closest = 2 ** math.floor(math.log2(n_head))
-    base = pow2_slopes(closest)
-    extra = pow2_slopes(2 * closest)[0::2][: n_head - closest]
-    return jnp.asarray(base + extra, jnp.float32)
-
-
-def _settled(*projected):
-    """The results of an attention block's input projections, as values
-    the compiler may not look through (``optimization_barrier``).
-
-    What it prevents: the cache write and the read kernels take their
-    operands head_dim-major, and XLA's layout assignment carried that wish
-    back through ``reshape``, the rotary and the projection's dot onto the
-    WEIGHT. It then computed ``W^T x^T``, and to have ``W^T`` it sliced the
-    layer's matrix out of the stacked leaf into a buffer of its own and
-    copied that into the other layout, a layer a projection a step (8 MB
-    twice where the result is 256 KB: ``constant_dynamic-slice_fusion`` and
-    ``copy`` over ``bf16[1, C, C']`` in a decode or chunk program, a
-    quarter of the chat cell's busy device time, ledger PR 39). Behind the
-    barrier the dot is an ordinary one with the slice of the stacked leaf
-    fused into it, as ``o_proj``'s and the MLP's are, and the layout the
-    kernels want is made on the small result. The values are what they
-    were.
-
-    How to see it come back: ``tests/unit/accelerator/test_chip_path.py``
-    ``test_attention_projections_read_the_stacked_leaf`` compiles the step
-    programs for a described v5e and looks for those two instructions."""
-    return jax.lax.optimization_barrier(projected)
-
-
-def _project_qkv(cfg: TransformerConfig, x):
-    """``q_proj``, ``k_proj``, ``v_proj`` of ``x`` (B, T, C), settled, as
-    (B, T, heads, head_dim). Called inside an attention module's
-    ``__call__``: the three ``Dense`` are that module's."""
-    B, T, _ = x.shape
-    H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
-    q, k, v = _settled(*(
-        _dense(cfg, heads * D, use_bias=cfg.qkv_bias, name=name)(x)
-        for heads, name in ((H, "q_proj"), (KV, "k_proj"), (KV, "v_proj"))))
-    return (q.reshape(B, T, H, D), k.reshape(B, T, KV, D),
-            v.reshape(B, T, KV, D))
-
-
-def _norm_qk(cfg: TransformerConfig, q, k):
-    """``qk_norm``: RMSNorm over each head of ``q`` and of ``k`` (one
-    learned weight of ``head_dim`` each), before the rotary. Called inside
-    an attention module's ``__call__``: the two norms are that module's."""
-    return tuple(nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
-                            name=name)(x)
-                 for name, x in (("q_norm", q), ("k_norm", k)))
-
-
-def _store_columns(buf, new, start):
-    """Write the new positions-minor columns at each row's offset: one
-    DUS for scalar start; per-slot (B,) starts vmap the DUS over the batch
-    (lowers to a scatter — each slot writes at its own cache offset)."""
-    if jnp.ndim(start) == 1:
-        return jax.vmap(
-            lambda c, n, s: jax.lax.dynamic_update_slice(
-                c, n, (0,) * (c.ndim - 1) + (s,)))(buf, new, start)
-    return jax.lax.dynamic_update_slice(
-        buf, new, (0,) * (buf.ndim - 1) + (start,))
-
-
-@functools.lru_cache(maxsize=None)
-def _traced_once_on(fn, mesh, interpret: bool, static: tuple):
-    return jax.jit(fn, static_argnames=static)
-
-
-def _traced_once(fn, *static, chunk: bool = True):
-    """``fn``, one of the cache kernels' entry points, as a function JAX
-    traces ONCE for each set of operand shapes: an inner ``jit``, inlined
-    where the program is compiled. For a prefill CHUNK's calls alone
-    (``chunk``: several query rows of ONE slot): a layer's body is traced
-    twice by the scan that holds it, and ``paged_chunk`` and the one
-    program of a chunk beside the decode rows trace the same kernels; the
-    kernel bodies were two thirds of a step program's trace time
-    (``paged_chunk`` 2.6 s, ``kernel_decode`` 2.3 s, the two as one
-    program 5.2 s on the chip's host with every compile served from the
-    cache: my chip run, PR 48; all of it ``setup_s``). Keyed by what the
-    kernels' wrappers read while they are traced: the mesh
-    (``ops.backend.shard_kernel``) and the interpret switch.
-
-    Every other call, a decode or a verify step's over all the slots, is
-    ``fn`` itself, so those programs are the parent's. Measured, not
-    supposed: behind an inlined call the decode read's work list
-    (``s32[slots x pages_per_slot]``, the same for every layer) is
-    computed again in every layer instead of once a program, 0.4-0.6 ms a
-    step in docs and ide (my chip runs, PR 48, call 6: ``serve_tok_s``
-    5,199 against 5,423, 6,851 against 7,049); and the plain decode
-    program's compiled text is pinned instruction for instruction
-    (``tests/unit/accelerator/test_chip_path.py``, ``_KERNEL_DECODE_TEXT``),
-    which an inlined call renumbers. (One path for both shapes needs the
-    work list handed to the kernels, a change under ``ops/``: PERF.md
-    section 7.)"""
-    if not chunk:
-        return fn
-    from ..ops import backend
-    from ..parallel import mesh as mesh_mod
-
-    return _traced_once_on(
-        fn, mesh_mod.get_mesh() if mesh_mod.has_mesh() else None,
-        backend.pallas_interpret(), static)
-
-
-def _chunk_shaped(rows) -> bool:
-    """Whether ``rows`` (slots, T, ...) are a prefill chunk's: several of
-    one slot (:func:`_traced_once`)."""
-    return rows.shape[0] == 1 and rows.shape[1] > 1
-
-
-def _chunk_positions(kv_cache, T: int):
-    """Positions (1, T) of the rows of a step that carries a prefill chunk
-    beside its decode rows (``"chunk"`` in the cache a layer is handed,
-    :meth:`TransformerLM.chunk_beside_decode`): the chunk's ``C`` tokens from
-    its start, then each decode row at its own."""
-    start = kv_cache["start"]
-    at = kv_cache["chunk"]["start"][:, None] \
-        + jnp.arange(T - start.shape[0])[None, :]
-    return jnp.concatenate([at, start[None, :]], axis=1)
-
-
-def _by_row_group(kv_cache, step, *rows):
-    """What a mixer does against its cache, ``step(kv_cache, *rows) -> (y,
-    leaves)`` over ``rows`` (B, T, ...), for every group of rows of the
-    call. A call has one group, itself, unless a prefill chunk rides beside
-    the decode rows: then ``rows`` are (1, C + B, ...), the chunk's C rows
-    of ONE slot ahead of B slots' one row each, everything that read a
-    weight has run over all of them at once, and what reads the cache runs
-    a group at a time with the kernels it has: the chunk's rows as (1, C)
-    through the addressing under ``kv_cache["chunk"]`` (its start, its
-    slot's table row(s), its state row), then the decode rows as (B, 1)
-    through the call's own, on the leaves as the chunk left them. That
-    order is the two programs' this replaces: the decode row of the slot in
-    mid-prefill reads what the chunk wrote and writes its dead column
-    behind it."""
-    chunk = kv_cache.get("chunk")
-    if chunk is None:
-        return step(kv_cache, *rows)
-    cache = {key: val for key, val in kv_cache.items() if key != "chunk"}
-    C = rows[0].shape[1] - cache["start"].shape[0]
-    y_chunk, leaves = step(dict(cache, **chunk), *(r[:, :C] for r in rows))
-    y, leaves = step(dict(cache, **leaves),
-                     *(r[0, C:][:, None] for r in rows))
-    return jnp.concatenate([y_chunk, y[:, 0][None]], axis=1), leaves
-
-
-class CachedAttention(nn.Module):
-    """Multi-head / grouped-query attention with optional KV cache.
-
-    Modes (``decode`` is a static tri-state):
-      - ``False`` — training / no-cache forward: full causal
-        self-attention.
-      - ``"prefill"`` — writes the prompt's k/v into the ``cache``
-        collection (k, v, cache_index) and attends over the FRESH
-        prompt k/v (start == 0 contract): O(T) attention memory, never
-        the (B, H, T, max_seq_len) allocated-cache tensor. Use for the
-        first multi-token call.
-      - ``True`` — reads+updates the cache; 1-token decode takes the
-        fused Pallas kernel, multi-token (chunked decode at unknown
-        start) takes the window-masked einsum over the cache.
-    """
-
-    config: TransformerConfig
-
-    def _use_flash(self, seq_len: int, deterministic: bool) -> bool:
-        """Route the full-context (non-decode) forward through the Pallas
-        flash kernel. ``auto``: on TPU from the tuned crossover length;
-        ``True`` forces it (interpret mode off-TPU — for tests). ALiBi has
-        no flash bias hook and attention-probability dropout has no kernel
-        equivalent — those stay on the einsum path (forcing raises)."""
-        cfg = self.config
-        use = cfg.use_flash_attention
-        if use is False or use == "off" or cfg.layer_types is not None:
-            # (a window inside the flash kernels is not written yet: layer
-            # kinds take the masked einsum in the full-context forward)
-            return False
-        alibi_ok = cfg.pos_emb != "alibi"
-        drop_ok = cfg.dropout == 0 or deterministic
-        if use == "auto":
-            from ..ops.attention.flash_attention import use_flash_by_default
-
-            return use_flash_by_default(seq_len) and alibi_ok and drop_ok
-        if not alibi_ok:
-            raise ValueError("use_flash_attention=True does not compose with "
-                             "pos_emb='alibi' (no bias hook in the kernel)")
-        if not drop_ok:
-            raise ValueError("use_flash_attention=True does not support "
-                             "attention-probability dropout in train mode")
-        return True
-
-    def _use_decode_kernel(self, cache_len: int,
-                           deterministic: bool = True) -> bool:
-        """Route 1-token decode through the fused Pallas kernel. ``auto``:
-        on TPU with a kernel-compatible cache length; ``on`` forces it
-        (interpret mode off-TPU — for tests); ``off`` keeps the jnp path.
-        Attention-probability dropout (train-mode decode) has no kernel
-        equivalent — that combination stays on the jnp path."""
-        from ..ops.attention.decode_attention import pick_block_s
-
-        cfg = self.config
-        if cfg.decode_kernel == "off" or cfg.layer_types is not None:
-            return False    # (the dense decode kernel knows no window)
-        if cfg.dropout > 0 and not deterministic:
-            return False
-        if pick_block_s(cache_len) < 8:
-            return False
-        if cfg.decode_kernel == "on":
-            return True
-        return backend.on_tpu()
-
-    def _paged_decode_step(self, kv_cache, q, k, v):
-        """Decode, verify or prefill-chunk step (T = 1, K + 1, the chunk
-        width) over PAGED storage, up to the output projection: ``(y,
-        leaves)``, the leaves it wrote. ``kv_cache`` holds the
-        pool's STACKED leaves whole ((L, P, KV, cache_d, lanes), no
-        batch axis) with ``layer``, ``start`` and ``table``: this step's
-        K/V columns go into this layer's pages through the table
-        (``paged_write``: a Pallas call that takes the whole leaf,
-        rewrites the pages it names and returns the leaf aliased;
-        sentinel entries and positions out of range are not in its work
-        list, so they touch nothing) and the fused paged kernel attends
-        over the same leaf at the same layer. No slice, re-layout or
-        copy of a leaf is made on the way: the XLA scatter this replaced
-        (``buf.at[pages, :, :, offs].set`` on one layer's slice, on the
-        first and the minor dimension of a positions-minor page) cost
-        five passes over a 67 MB slice a layer with the slicing around
-        it, 74 % of a busy chip (ledger, PR 24). The value bytes
-        written and the attention math match the dense path exactly
-        (same quantize/pack pipeline; for each head the kernel folds one
-        page at a time in table order, op-for-op the dense decode kernel
-        at a block of one page), which is what keeps paged-kernel greedy
-        output bitwise-identical to the dense oracle."""
-        cfg = self.config
-        B, T, H, D = q.shape
-        kv_packed = kv_cache_spec(cfg)[2]
-        from ..ops.attention.paged_attention import (
-            paged_decode_attention,
-            paged_write_columns,
-        )
-
-        start = kv_cache["start"]
-        assert jnp.ndim(start) == 1, \
-            "paged decode is slot-pooled: start must be (B,)"
-        if kv_cache_groups(cfg) is not None:
-            return self._grouped_paged_step(kv_cache, q, k, v)
-        table = kv_cache["table"]                  # (B, pages_per_slot)
-        layer = kv_cache["layer"]
-        page_size = cfg.max_seq_len // table.shape[1]
-        new_cache = {}
-        chunk = _chunk_shaped(q)
-
-        def write(key, cols):
-            new_cache[key] = _traced_once(
-                paged_write_columns, "page_size", chunk=chunk)(
-                kv_cache[key], layer, cols, table, start,
-                page_size=page_size)
-
-        k_rows = k.astype(cfg.dtype).transpose(0, 2, 1, 3)  # (B, KV, T, D)
-        v_rows = v.astype(cfg.dtype).transpose(0, 2, 1, 3)
-        scales = {}
-        if cfg.kv_cache_quant:
-            from ..ops.attention.decode_attention import (
-                pack_int8_sublanes,
-                quantize_kv_rows,
-            )
-
-            k_rows, k_sc = quantize_kv_rows(k_rows)       # scales (B,KV,T)
-            v_rows, v_sc = quantize_kv_rows(v_rows)
-            write("k_scale", k_sc)
-            write("v_scale", v_sc)
-            scales = dict(k_scale_pages=new_cache["k_scale"],
-                          v_scale_pages=new_cache["v_scale"])
-        k_cols = k_rows.transpose(0, 1, 3, 2)             # (B, KV, D, T)
-        v_cols = v_rows.transpose(0, 1, 3, 2)
-        if kv_packed:
-            k_cols = pack_int8_sublanes(k_cols)           # (B, KV, D//4, T)
-            v_cols = pack_int8_sublanes(v_cols)
-        write("k", k_cols)
-        write("v", v_cols)
-
-        slopes = alibi_slopes(H) if cfg.pos_emb == "alibi" else None
-        y = _traced_once(paged_decode_attention, "page_size",
-                         chunk=chunk)(
-            q.astype(cfg.dtype), new_cache["k"], new_cache["v"], table,
-            start, layer=layer, page_size=page_size, alibi_slopes=slopes,
-            **scales)
-        return y.astype(cfg.dtype).reshape(B, T, H * D), new_cache
-
-    def _grouped_paged_step(self, kv_cache, q, k, v):
-        """:meth:`_paged_decode_step` over a pool of layer GROUPS
-        (:func:`kv_cache_groups`): each group has its own stacked leaf
-        and table (``k`` / ``table`` for the full layers, ``k_win`` /
-        ``table_win`` for the window layers). The layer's kind is a
-        traced value inside the scan, so the step makes the write and the
-        read of EVERY group and gives the groups the layer is not in an
-        empty work list (``active``: a grid of no step, a leaf returned
-        as it came); a ``lax.cond`` over the leaves would copy the
-        branch's pass-through operands."""
-        from ..ops.attention.paged_attention import (
-            paged_decode_attention,
-            paged_write_columns,
-        )
-
-        cfg = self.config
-        B, T, H, D = q.shape
-        start, layer = kv_cache["start"], kv_cache["layer"]
-        new_cache = {}
-        k_cols = k.astype(cfg.dtype).transpose(0, 2, 3, 1)    # (B, KV, D, T)
-        v_cols = v.astype(cfg.dtype).transpose(0, 2, 3, 1)
-        y, chunk = None, _chunk_shaped(q)
-        for suffix, layers, window in kv_cache_groups(cfg):
-            place = np.full((cfg.n_layer,), -1, np.int32)
-            place[list(layers)] = np.arange(len(layers))
-            index = jnp.asarray(place)[layer]    # the layer within its group
-            active = index >= 0
-            table = kv_cache["table" + suffix]
-            page_size = cfg.max_seq_len // table.shape[1]
-            for key, cols in (("k", k_cols), ("v", v_cols)):
-                new_cache[key + suffix] = _traced_once(
-                    paged_write_columns, "page_size", chunk=chunk)(
-                    kv_cache[key + suffix], jnp.maximum(index, 0), cols,
-                    table, start, page_size=page_size, active=active)
-            y_g = _traced_once(paged_decode_attention, "page_size",
-                               "window", chunk=chunk)(
-                q.astype(cfg.dtype), new_cache["k" + suffix],
-                new_cache["v" + suffix], table, start,
-                layer=jnp.maximum(index, 0), page_size=page_size,
-                window=window or None, active=active)
-            y = y_g if y is None else jnp.where(active, y_g, y)
-        return y.astype(cfg.dtype).reshape(B, T, H * D), new_cache
-
-    @nn.compact
-    def __call__(self, x, *, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None, layer=None):
-        cfg = self.config
-        B, T, C = x.shape
-        H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
-        q, k, v = _project_qkv(cfg, x)
-        if cfg.qk_norm:
-            q, k = _norm_qk(cfg, q, k)
-        if cfg.attention_multiplier is not None:
-            # every path below (and the kernels) scales by 1 / sqrt(D):
-            # the query carries what the published scale differs by
-            q = q * (cfg.attention_multiplier * math.sqrt(D))
-
-        kv_packed = kv_cache_spec(cfg)[2]
-        if decode:
-            # This layer's KV cache arrives as an ARGUMENT (dict with
-            # k/v [+ scales] and the shared ``start``) and the updated
-            # one is RETURNED: the stacked cache rides the layer scan's
-            # carry (_ScanBlock), as one layer's slice for the
-            # contiguous cache and whole for a page pool. (The previous
-            # design — per-layer flax cache variables, nn.scan
-            # variable_axes — lowers to a scan whose xs/ys pair
-            # double-buffers the quantized cache above ~100 MB:
-            # PERF.md §8, the carry-DUS lead.)
-            assert kv_cache is not None, "decode needs the kv_cache slice"
-            # ``start`` is scalar () for batch-uniform decode (generate),
-            # or (B,) for slot-pooled decode where every sequence sits at
-            # its own cache offset (serving/ continuous batching)
-            start = kv_cache["start"]
-            per_slot = jnp.ndim(start) == 1
-            positions = _chunk_positions(kv_cache, T) \
-                if "chunk" in kv_cache \
-                else (start[:, None] if per_slot else start) \
-                + jnp.arange(T)[None, :]
-        else:
-            start = jnp.zeros((), jnp.int32)
-            positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-
-        is_window = None    # traced: this layer is a sliding-window layer
-        if cfg.layer_types is not None and not cfg.hybrid:
-            inv_freq, factor, windows = layer_rope_tables(cfg)
-            is_window = jnp.asarray(windows)[layer]
-        if cfg.pos_emb == "rotary":
-            rd = int(cfg.rotary_pct * D) // 2 * 2
-            if is_window is not None:
-                rope = (jnp.asarray(inv_freq)[layer],
-                        jnp.asarray(factor)[layer], rd)
-                q = apply_rotary_table(q, positions, *rope)
-                k = apply_rotary_table(k, positions, *rope)
-            else:
-                q = apply_rotary(q, positions, rotary_dim=rd,
-                                 theta=cfg.rope_theta)
-                k = apply_rotary(k, positions, rotary_dim=rd,
-                                 theta=cfg.rope_theta)
-
-        if decode and kv_cache is not None and "table" in kv_cache:
-            # Paged decode: K/V live in the PAGE POOL ((L, P, KV,
-            # cache_d, lanes), no batch axis, every layer in one
-            # leaf) and both the column writes and the attention read
-            # resolve (layer, position) through the per-slot page table
-            # inside Pallas calls — no dense per-slot view and no slice
-            # of the leaf is ever materialized
-            # (ops/attention/paged_attention.py).
-            y, leaves = _by_row_group(kv_cache, self._paged_decode_step,
-                                      q, k, v)
-            o_proj = _dense(cfg, C, use_bias=cfg.qkv_bias, name="o_proj")
-            return o_proj(y), leaves
-
-        kv_scales = None  # set on the quantized-cache einsum fallback
-        # "fresh" attention = causal over the just-computed k/v. True for
-        # the training forward AND for prefill (start == 0 contract): the
-        # prompt's causal window IS the fresh k/v, so prefill must NOT
-        # attend over the allocated cache — the (B, H, T, S) score tensor
-        # that implies OOM-crashed the worker at T=4096 / S=8192.
-        fresh = (not decode) or (decode == "prefill" and T > 1)
-        new_cache = None
-        o_proj = _dense(cfg, C, use_bias=cfg.qkv_bias, name="o_proj")
-        if decode:
-            k_rows = k.astype(cfg.dtype).transpose(0, 2, 1, 3)  # (B,KV,T,D)
-            v_rows = v.astype(cfg.dtype).transpose(0, 2, 1, 3)
-            new_cache = dict(kv_cache)
-
-            store = functools.partial(_store_columns, start=start)
-
-            if cfg.kv_cache_quant:
-                from ..ops.attention.decode_attention import (
-                    pack_int8_sublanes,
-                    quantize_kv_rows,
-                )
-
-                k_rows, k_sc = quantize_kv_rows(k_rows)
-                v_rows, v_sc = quantize_kv_rows(v_rows)
-                new_cache["k_scale"] = store(kv_cache["k_scale"], k_sc)
-                new_cache["v_scale"] = store(kv_cache["v_scale"], v_sc)
-            # positions-minor store: new rows become (B, KV, D, T) columns
-            k_cols = k_rows.transpose(0, 1, 3, 2)
-            v_cols = v_rows.transpose(0, 1, 3, 2)
-            if kv_packed:
-                k_cols = pack_int8_sublanes(k_cols)  # (B, KV, D//4, T)
-                v_cols = pack_int8_sublanes(v_cols)
-            new_cache["k"] = store(kv_cache["k"], k_cols)
-            new_cache["v"] = store(kv_cache["v"], v_cols)
-            if T == 1 and self._use_decode_kernel(cfg.max_seq_len,
-                                                  deterministic):
-                # fused Pallas decode attention (reference softmax_context,
-                # pt_binding.cpp:1910-1975): length masking + softmax +
-                # value reduction in one pass over the cache; int8 caches
-                # pass their per-row scales straight through
-                from ..ops.attention.decode_attention import (
-                    decode_attention,
-                    pick_block_s,
-                )
-
-                slopes = alibi_slopes(H) if cfg.pos_emb == "alibi" else None
-                scales = dict(k_scale=new_cache["k_scale"],
-                              v_scale=new_cache["v_scale"]) \
-                    if cfg.kv_cache_quant else {}
-                y = decode_attention(
-                    q[:, 0].astype(cfg.dtype), new_cache["k"],
-                    new_cache["v"], start + 1, alibi_slopes=slopes,
-                    block_s=pick_block_s(cfg.max_seq_len,
-                                         preferred=cfg.decode_block),
-                    **scales)
-                y = y.astype(cfg.dtype).reshape(B, 1, H * D)
-                return o_proj(y), new_cache
-            if not fresh:
-                # chunked decode (decode=True, T > 1, start unknown):
-                # attend over the allocated cache with a window mask
-                k_all, v_all = new_cache["k"], new_cache["v"]
-                S = cfg.max_seq_len
-                if kv_packed:
-                    from ..ops.attention.decode_attention import \
-                        unpack_int8_sublanes
-
-                    k_all = unpack_int8_sublanes(k_all)
-                    v_all = unpack_int8_sublanes(v_all)
-                # the shared einsum below expects (B, KV, S, D)
-                k_all = k_all.transpose(0, 1, 3, 2)
-                v_all = v_all.transpose(0, 1, 3, 2)
-                if cfg.kv_cache_quant:
-                    # do NOT dequantize the cache (a full-size bf16 copy —
-                    # multiple GB at long S); fold the per-row scales into
-                    # the score and probability tensors, as the kernel does
-                    kv_scales = (new_cache["k_scale"], new_cache["v_scale"])
-                # row t may see cache slots [0, start+t]; per-slot starts
-                # make the mask batch-dependent: (B, T, S) instead of (T, S)
-                if per_slot:
-                    mask = (jnp.arange(S)[None, None, :]
-                            <= (start[:, None]
-                                + jnp.arange(T)[None, :])[:, :, None])
-                else:
-                    mask = (jnp.arange(S)[None, :]
-                            <= (start + jnp.arange(T))[:, None])
-                if is_window is not None:
-                    # a sliding layer's row at position p sees keys in
-                    # (p - sliding_window, p]
-                    qpos = (start[:, None] if per_slot else start) \
-                        + jnp.arange(T)
-                    mask = mask & jnp.logical_or(
-                        ~is_window, jnp.arange(S) > qpos[..., None]
-                        - cfg.sliding_window)
-        if fresh:
-            if self._use_flash(T, deterministic):
-                # fused Pallas flash attention for the full-context forward
-                # (and, via its custom_vjp, the streamed/resident backward) —
-                # O(T) memory instead of the (B, H, T, T) logits tensor
-                from ..ops.attention.flash_attention import flash_attention
-
-                k_f, v_f = k, v
-                if KV != H:
-                    k_f = jnp.repeat(k, H // KV, axis=2)
-                    v_f = jnp.repeat(v, H // KV, axis=2)
-                y = flash_attention(q.astype(cfg.dtype),
-                                    k_f.astype(cfg.dtype),
-                                    v_f.astype(cfg.dtype), causal=True)
-                y = y.astype(cfg.dtype).reshape(B, T, H * D)
-                return o_proj(y), new_cache
-            k_all = k.transpose(0, 2, 1, 3)  # (B, KV, T, D)
-            v_all = v.transpose(0, 2, 1, 3)
-            S = T
-            mask = jnp.tril(jnp.ones((T, T), dtype=bool))
-            if is_window is not None:
-                mask = mask & jnp.logical_or(
-                    ~is_window, jnp.arange(T)[None, :]
-                    > jnp.arange(T)[:, None] - cfg.sliding_window)
-
-        if KV != H:
-            rep = H // KV
-            k_all = jnp.repeat(k_all, rep, axis=1)
-            v_all = jnp.repeat(v_all, rep, axis=1)
-            if kv_scales is not None:
-                kv_scales = tuple(jnp.repeat(s, rep, axis=1)
-                                  for s in kv_scales)
-
-        scale = 1.0 / math.sqrt(D)
-        # int8 cache: the s8->f32 cast does NOT fuse into the dot on TPU
-        # (rounds 1-5: full fp32 cache copies appeared), so the quantized path casts to the compute dtype
-        # instead — int8 is exact in bf16, the copy is half the bytes,
-        # and the dot still accumulates in f32. The per-row scales apply
-        # to the (B,H,T,S) score/probability tensors.
-        if kv_scales is not None:
-            att = jnp.einsum("bthd,bhsd->bhts", q.astype(cfg.dtype),
-                             k_all.astype(cfg.dtype),
-                             preferred_element_type=jnp.float32) * scale
-            att = att * kv_scales[0][:, :, None, :]
-        else:
-            att = jnp.einsum("bthd,bhsd->bhts", q.astype(jnp.float32),
-                             k_all.astype(jnp.float32)) * scale
-        if cfg.pos_emb == "alibi":
-            slopes = alibi_slopes(H)  # (H,)
-            if decode and jnp.ndim(start) == 1:
-                # per-slot decode: relative key offsets differ per batch row
-                rel = (jnp.arange(S)[None, None, :]
-                       - (start[:, None] + jnp.arange(T)[None, :])[:, :, None])
-                att = att + slopes[None, :, None, None] * rel[:, None]
-            else:
-                kpos = jnp.arange(S)[None, :]
-                qpos = (start + jnp.arange(T))[:, None]
-                att = att + slopes[None, :, None, None] \
-                    * (kpos - qpos)[None, None]
-        att = jnp.where(mask[None, None] if mask.ndim == 2 else mask[:, None],
-                        att, -1e30)
-        att = jax.nn.softmax(att, axis=-1)
-        if cfg.dropout > 0:
-            att = nn.Dropout(cfg.dropout)(att, deterministic=deterministic)
-        if kv_scales is not None:
-            att = att * kv_scales[1][:, :, None, :]
-            y = jnp.einsum("bhts,bhsd->bthd", att.astype(cfg.dtype),
-                           v_all.astype(cfg.dtype),
-                           preferred_element_type=jnp.float32)
-        else:
-            y = jnp.einsum("bhts,bhsd->bthd", att,
-                           v_all.astype(jnp.float32))
-        y = y.astype(cfg.dtype)
-        y = y.reshape(B, T, H * D)
-        return o_proj(y), new_cache
-
-
-def _half_life_logit(key, shape, dtype=jnp.float32):
-    """The gate's bias: ``logit(g)`` for ``g = 2 ** (-1 / half-life)`` with
-    half-lives drawn log-uniformly from 16 to 4,096 tokens, one a KV head
-    a layer (the scan splits the key by layer). A zero bias is ``g`` 0.5:
-    the state would forget in two tokens, and nothing that compares outputs
-    could see a wrong carried state."""
-    half_life = 16.0 * 256.0 ** jax.random.uniform(key, shape)
-    g = 2.0 ** (-1.0 / half_life)
-    return (jnp.log(g) - jnp.log1p(-g)).astype(dtype)
-
-
-class PowerRetention(nn.Module):
-    """Gated power retention of degree 2 in the attention's place
-    (``ops/attention/power_retention.py`` has the equations): ``q``, ``k``
-    with their norm and the rotary, ``v``, one gate a KV head
-    (``log g = log sigmoid(W_g x + b_g)``, float32), output projection.
-
-    Modes as :class:`CachedAttention`'s. Without a cache: the attention
-    form in ``jax.numpy``. With one, ``kv_cache`` holds the stacked state
-    leaf ``s`` whole with ``layer``, ``start`` and, from a caller
-    that runs only some rows or maps its batch to other rows, ``rows``
-    (B,) (the cache row of each batch entry, out of range: the entry does
-    not run and its row's state is not touched) and ``valid`` (B,) (tokens
-    from there on are padding and leave the state alone). One token takes
-    ``retention_decode``, more take ``retention_chunk``; an entry whose
-    first position is 0 reads no state."""
-
-    config: TransformerConfig
-
-    @nn.compact
-    def __call__(self, x, *, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None, layer=None):
-        from ..ops.attention import power_retention as pr
-
-        cfg = self.config
-        B, T, C = x.shape
-        H, KV, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
-        q, k, v = _project_qkv(cfg, x)
-        if cfg.qk_norm:
-            q, k = _norm_qk(cfg, q, k)
-        log_g = jax.nn.log_sigmoid(nn.Dense(
-            KV, dtype=jnp.float32, bias_init=_half_life_logit,
-            name="g_proj")(x))                                  # (B, T, KV)
-        start = kv_cache["start"] if decode else jnp.zeros((), jnp.int32)
-        if cfg.pos_emb == "rotary":
-            positions = (start[:, None] if jnp.ndim(start) == 1 else start) \
-                + jnp.arange(T)[None, :]
-            rd = int(cfg.rotary_pct * D) // 2 * 2
-            q = apply_rotary(q, positions, rotary_dim=rd,
-                             theta=cfg.rope_theta)
-            k = apply_rotary(k, positions, rotary_dim=rd,
-                             theta=cfg.rope_theta)
-        o_proj = _dense(cfg, C, use_bias=cfg.qkv_bias, name="o_proj")
-        if not decode:
-            y = pr.retention_attention(q, k, v, log_g)
-            return o_proj(y.astype(cfg.dtype).reshape(B, T, H * D)), None
-        rows = kv_cache.get("rows")
-        if rows is None:
-            rows = jnp.arange(B, dtype=jnp.int32)
-        fresh = jnp.broadcast_to(start == 0, (B,))
-        state = (kv_cache["s"], kv_cache["layer"], rows, fresh)
-        if T == 1:
-            y, s = pr.retention_decode(q[:, 0], k[:, 0], v[:, 0],
-                                       log_g[:, 0], *state)
-        else:
-            y, s = pr.retention_prefill(q, k, v, log_g, *state,
-                                        length=kv_cache.get("valid"))
-        y = y.astype(cfg.dtype).reshape(B, T, H * D)
-        return o_proj(y), {"s": s}
-
-
+from .attention_layers import CachedAttention
+from .kv_cache_spec import (KVCacheSpec, kv_cache_groups, kv_cache_spec,
+                            make_kv_cache_spec)
+from .lightning_sparse import LightningMixer, SparseAttention
+from .lm_config import TransformerConfig
+from .lm_parts import (_by_row_group, _chunk_positions, _chunk_shaped, _dense,
+                       _norm, _settled, _store_columns, _traced_once,
+                       apply_rotary)
+from .state_layers import (KDAMixer, Mamba2Mixer, PowerRetention,
+                           ShortConvMixer)
+
+# not used here: perf/configs/*.json and tests/unit/perf/ (a benchmark PR's
+# files) name the two by this module
+from .lm_config import transformer_config  # noqa: F401
+from .lm_parts import rope_inv_freq  # noqa: F401
+
+
+# LatentAttention is attention_layers.py's by kind. It stays here, where it
+# looks ``apply_rotary`` up, until tests/unit/perf/test_reference_moonlight.py
+# (a benchmark PR's file) plants its shifted-position fault somewhere else
+# than ``transformer_lm.apply_rotary`` (ROADMAP.md, D14).
 class LatentAttention(nn.Module):
     """Multi-head latent attention (``kv_lora_rank > 0``): every head's K
     and V are projections of ONE normed latent ``c`` (``kv_lora_rank``
@@ -1595,337 +214,6 @@ class LatentAttention(nn.Module):
         y = jnp.einsum("bthr,rhd->bthd", ctx.astype(cfg.dtype),
                        w_kvb[..., dn:])
         return o_proj(y.astype(cfg.dtype).reshape(B, T, H * dv)), new_cache
-
-
-def _uniform_log(lo: float, hi: float, inverse=None):
-    """An initializer: values drawn log-uniformly from ``lo`` to ``hi``,
-    then through ``inverse`` (what the module applies to the parameter)."""
-    def init(key, shape, dtype=jnp.float32):
-        v = lo * (hi / lo) ** jax.random.uniform(key, shape)
-        return (v if inverse is None else inverse(v)).astype(dtype)
-    return init
-
-
-def _state_rows(cache, B: int, T: int):
-    """What a state layer's mixer reads off the cache it is handed for a
-    group of rows (B, T): ``(layer, rows, fresh, valid)``: the layer's
-    index among those that keep a state, the cache row of each entry (its
-    own place where the caller names none), whether an entry stands at
-    position 0 (it reads neither its state nor its tail), and how many of
-    its T tokens are real."""
-    rows = cache.get("rows")
-    if rows is None:
-        rows = jnp.arange(B, dtype=jnp.int32)
-    valid = jnp.full((B,), T, jnp.int32)
-    if cache.get("valid") is not None:
-        valid = jnp.minimum(cache["valid"], T)
-    return (cache["layer"], rows,
-            jnp.broadcast_to(cache["start"] == 0, (B,)), valid)
-
-
-def _conv_after_tail(cache, x, w, b, silu: bool = True):
-    """A state layer's causal convolution of one group of rows ``x`` (B, T,
-    C) after the tail its cache carries (``ops/state_space.causal_conv``;
-    ``silu`` False: without its activation): ``(conv(x), where, conv
-    leaf)``. ``cache`` None: whole sequences from nothing, no ``where`` and
-    no leaf. Else ``where`` is :func:`_state_rows`'s, the tail is read from
-    the leaf ``conv`` and the tail at the last REAL token is written back to
-    it."""
-    from ..ops import state_space
-
-    conv = state_space.causal_conv if silu else functools.partial(
-        state_space.causal_conv, silu=False)
-    B, T = x.shape[:2]
-    tail = jnp.zeros((B, w.shape[0] - 1, x.shape[-1]), x.dtype)
-    if cache is None:
-        return conv(x, tail, w, b, jnp.full((B,), T, jnp.int32))[0], \
-            None, None
-    where = layer, rows, fresh, valid = _state_rows(cache, B, T)
-    tail = _read_rows(cache["conv"], layer, rows, fresh, tail.shape[1:])
-    x, tail = conv(x, tail, w, b, valid)
-    return x, where, _write_rows(cache["conv"], layer, rows,
-                                 tail.reshape(B, -1))
-
-
-def _read_rows(leaf, layer, rows, fresh, shape):
-    """Rows ``rows`` (B,) of layer ``layer`` of ``leaf`` (L, R, W), each as
-    ``shape``: zeros for an entry that is ``fresh`` or out of ``[0, R)``
-    (one that does not run)."""
-    R = leaf.shape[1]
-    at = (layer, jnp.where((rows >= 0) & (rows < R), rows, R))
-    return jnp.where(fresh[:, None, None], 0, leaf.at[at].get(
-        mode="fill", fill_value=0).reshape((rows.shape[0],) + shape))
-
-
-def _write_rows(leaf, layer, rows, values):
-    """``leaf`` (L, R, W) with ``values`` (B, W) written to rows ``rows``
-    (B,) of layer ``layer``; an entry out of ``[0, R)`` writes nothing.
-    A few entries are a scatter of their rows. From ``_SLAB_FROM`` on they
-    go through the layer's whole slab, each row taking the entry that names
-    it (a select and ONE update of (R, W)): XLA expands a scatter of B rows
-    into a loop of B single-row updates, 5.6 ms a step of 128 rows over 9
-    KDA layers where the slabs' bytes are 0.2 ms (my traced run, PR 50,
-    call 3: ``dynamic-update-slice`` over ``bf16[9,128,36864]`` with its
-    bounds check, 17 % of the busy device; the mamba layers' tail of 64
-    rows x 36 layers went the same way). The slab costs the same at every
-    B and more than a scatter of two: a layer of (64, 13056) 11.9 us
-    against 4.9 at B = 2, 9.3 at 8, 17.3 at 16, 55.7 at 64; a layer of
-    (128, 36864) 63 us against 18 at B = 2, 66 at 8, 130 at 16, 973 at
-    128 (my chip run, PR 50, call 94: each form alone, every layer once)."""
-    B, R = rows.shape[0], leaf.shape[1]
-    values = values.astype(leaf.dtype)
-    if B < _SLAB_FROM:
-        at = (layer, jnp.where((rows >= 0) & (rows < R), rows, R))
-        return leaf.at[at].set(values, mode="drop")
-    named = rows[None, :] == jnp.arange(R, dtype=rows.dtype)[:, None]  # R, B
-    slab = jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
-    slab = jnp.where(jnp.any(named, axis=1)[:, None],
-                     values[jnp.argmax(named, axis=1)], slab)
-    return jax.lax.dynamic_update_slice_in_dim(leaf, slab[None], layer, 0)
-
-
-# entries from which _write_rows takes the slab (where the two forms met
-# on both cells' leaves, see there)
-_SLAB_FROM = 8
-
-
-class Mamba2Mixer(nn.Module):
-    """The Mamba-2 mixer in the attention's place
-    (``ops/state_space.py`` has the state's equations). With ``u`` the
-    normed input::
-
-        [z ; xBC ; dt] = W_in u         xBC <- silu(conv(xBC))
-        [x ; B ; C] = xBC               dt <- softplus(dt + dt_bias)
-        H_t = exp(dt_t A) H_{t-1} + dt_t x_t (x) B_t      A = -exp(A_log)
-        y_t = H_t C_t + D x_t
-        out = W_out (w (.) g / rms(g))          g = y (.) silu(z)
-
-    ``conv`` is causal and depthwise over ``mamba_d_conv`` taps with a
-    bias; the gate comes BEFORE the norm. Three forms of that one
-    mathematics. Without a cache: whole sequences from an empty state
-    (``ssm_sequence``). With one, ``kv_cache`` holds the stacked leaves
-    whole, ``s`` (the state, float32) and ``conv`` (the last
-    ``mamba_d_conv - 1`` inputs of the convolution, time-major on the minor
-    axis: ``(L, rows, (taps - 1) * channels)`` in the model's dtype), with
-    ``layer``, ``start`` and, as :class:`PowerRetention`, ``rows`` and
-    ``valid``: one token takes ``ssm_decode``, more take ``ssm_chunk``
-    block by block. A token at or past ``valid`` is padding: it advances
-    neither the state (its ``dt`` is 0) nor the tail (taken at the last
-    real token). An entry whose first position is 0 reads neither. The
-    convolution and the tail's shift are XLA's, under the scope
-    ``ssm_conv``."""
-
-    config: TransformerConfig
-
-    @nn.compact
-    def __call__(self, u, *, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None, layer=None):
-        from ..ops import state_space as ss
-
-        cfg = self.config
-        B, T, C = u.shape
-        H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
-        inner, ch, K = H * P, cfg.mamba_channels, cfg.mamba_d_conv
-        f32 = jnp.float32
-        # W_in as two leaves, [z ; xBC] and dt: the published matrix is
-        # 2 * inner + 2 * N + H wide (8,512 at the published widths, no
-        # multiple of 128 lanes), and the chip's client stores such a leaf
-        # with the OTHER dimension minor, which every program then copies
-        # whole before its layer loop (1.25 GB a step there: compiled for
-        # a described v5e, PR 47). z and xBC are whole lane tiles; dt's 64
-        # columns are a leaf of their own, small enough to copy
-        zx = _dense(cfg, inner + ch, use_bias=False, name="in_proj")(u)
-        z, xbc = zx[..., :inner], zx[..., inner:]
-        dt = _dense(cfg, H, use_bias=False, name="dt_proj")(u)
-        bound = 1.0 / math.sqrt(K)      # (torch's Conv1d default)
-        taps = nn.initializers.uniform(2 * bound)
-        conv_w = self.param("conv_w", lambda *a: taps(*a) - bound, (K, ch))
-        conv_b = self.param("conv_b", lambda *a: taps(*a) - bound, (ch,))
-        a = -jnp.exp(self.param("A_log", _uniform_log(1.0, 16.0, jnp.log),
-                                (H,)).astype(f32))
-        skip = self.param("D", nn.initializers.ones, (H,)).astype(f32)
-        dt_bias = self.param(
-            "dt_bias", _uniform_log(1e-3, 1e-1, lambda v: v + jnp.log(
-                -jnp.expm1(-v))), (H,)).astype(f32)
-        gate_norm = self.param("norm", nn.initializers.ones, (inner,))
-        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)          # (B, T, H)
-
-        cached = bool(decode)
-
-        def mix(cache, xbc, dt):
-            """The convolution and the state of one group of rows (B, T):
-            ``(y + D x, leaves)``."""
-            B, T = dt.shape[:2]
-            xbc, where, conv_leaf = _conv_after_tail(cache, xbc, conv_w,
-                                                     conv_b)
-            x = xbc[..., :inner].reshape(B, T, H, P)
-            b, c = xbc[..., inner:inner + N], xbc[..., inner + N:]
-            if not cached:
-                y, leaves = ss.ssm_sequence(x, dt, a, b, c), None
-            else:
-                li, rows, fresh, valid = where
-                if T == 1:
-                    y, s = ss.ssm_decode(x[:, 0], dt[:, 0], a, b[:, 0],
-                                         c[:, 0], cache["s"], li, rows, fresh)
-                    y = y[:, None]
-                else:
-                    y, s = _traced_once(ss.ssm_prefill,
-                                        chunk=_chunk_shaped(x))(
-                        x, dt, a, b, c, cache["s"], li, rows, fresh,
-                        length=valid)
-                leaves = {"s": s, "conv": conv_leaf}
-            return (y + skip[:, None] * x).reshape(B, T, inner), leaves
-
-        y, leaves = _by_row_group(kv_cache, mix, xbc, dt) if cached \
-            else mix(None, xbc, dt)
-        g = ss.gated_norm(y, z, gate_norm, cfg.layer_norm_epsilon)
-        out = _dense(cfg, C, use_bias=False, name="out_proj")(
-            g.astype(cfg.dtype))
-        return out, leaves
-
-
-class KDAMixer(nn.Module):
-    """Kimi Delta Attention in the attention's place (``ops/kda.py`` has
-    the state's equations). With ``x`` the normed input, a head ``h`` of
-    ``d = kda_d_head`` channels::
-
-        [q ; k ; v] = silu(conv(W_qkv x))       (one convolution a channel)
-        q_h <- l2norm(q_h) / sqrt(d)            k_h <- l2norm(k_h)
-        g_h = -exp(A_log_h) softplus(W_f^up W_f^down x + dt_bias)_h
-        beta_h = sigmoid(W_beta x)_h
-        o_h = the gated delta rule over (q_h, k_h, v_h, g_h, beta_h)
-        out = W_o [rmsnorm_d(o_h; w) (.) sigmoid(W_g^up W_g^down x)_h]
-
-    ``conv`` is causal and depthwise over ``kda_d_conv`` taps, no bias; the
-    decay ``g`` is a channel's, through a low rank of ``d``, and so is the
-    output gate. Three forms, as :class:`Mamba2Mixer`: without a
-    cache whole sequences from an empty state (``kda_sequence``); with one,
-    ``kv_cache`` holds the stacked leaves whole, ``s`` (float32, (L, rows,
-    H, d, d)) and ``conv`` (the last ``kda_d_conv - 1`` inputs of the
-    convolution over [q ; k ; v], time-major on the minor axis), with
-    ``layer``, ``start``, ``rows`` and ``valid``: one token takes
-    ``kda_decode``, more take ``kda_chunk`` block by block. A token at or
-    past ``valid`` is padding: it advances neither the state (its ``g``
-    and ``beta`` are 0) nor the tail. An entry whose first position is 0
-    reads neither. The convolution and the tail's shift are XLA's
-    (``ops/state_space.causal_conv``)."""
-
-    config: TransformerConfig
-
-    @nn.compact
-    def __call__(self, x, *, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None, layer=None):
-        from ..ops import kda
-
-        cfg = self.config
-        B, T, C = x.shape
-        H, D, taps = cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_d_conv
-        inner, rank = cfg.kda_width, D      # (the low rank: a head's width)
-        f32 = jnp.float32
-
-        def dense(width, name):
-            return _dense(cfg, width, use_bias=False, name=name)
-
-        qkv = dense(3 * inner, "qkv_proj")(x)
-        bound = 1.0 / math.sqrt(taps)       # (torch's Conv1d default)
-        draw = nn.initializers.uniform(2 * bound)
-        conv_w = self.param("conv_w", lambda *a: draw(*a) - bound,
-                            (taps, 3 * inner))
-        a = jnp.exp(self.param("A_log", _uniform_log(1.0, 16.0, jnp.log),
-                               (H,)).astype(f32))
-        dt_bias = self.param(
-            "dt_bias", _uniform_log(1e-3, 1e-1, lambda v: v + jnp.log(
-                -jnp.expm1(-v))), (inner,)).astype(f32)
-        f = dense(inner, "f_b_proj")(dense(rank, "f_a_proj")(x))
-        g = -a[:, None] * jax.nn.softplus(
-            f.astype(f32) + dt_bias).reshape(B, T, H, D)
-        beta = jax.nn.sigmoid(dense(H, "b_proj")(x).astype(f32))
-        gate = dense(inner, "g_b_proj")(dense(rank, "g_a_proj")(x))
-        o_norm = self.param("o_norm", nn.initializers.ones, (D,))
-
-        cached = bool(decode)
-
-        def unit(v):
-            return v * jax.lax.rsqrt(
-                jnp.sum(v * v, axis=-1, keepdims=True) + 1e-6)
-
-        def mix(cache, qkv, g, beta):
-            """The convolution and the state of one group of rows (B, T):
-            ``(o, leaves)``."""
-            B, T = beta.shape[:2]
-            qkv, where, conv_leaf = _conv_after_tail(cache, qkv, conv_w,
-                                                     None)
-            q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(
-                B, T, H, D) for i in range(3))
-            q, k = unit(q) * (1.0 / math.sqrt(D)), unit(k)
-            if not cached:
-                return kda.kda_sequence(q, k, v, g, beta), None
-            li, rows, fresh, valid = where
-            if T == 1:
-                o, s = kda.kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                      beta[:, 0], cache["s"], li, rows, fresh)
-                o = o[:, None]
-            else:
-                o, s = _traced_once(kda.kda_prefill,
-                                    chunk=_chunk_shaped(q))(
-                    q, k, v, g, beta, cache["s"], li, rows, fresh,
-                    length=valid)
-            return o, {"s": s, "conv": conv_leaf}
-
-        o, leaves = _by_row_group(kv_cache, mix, qkv, g, beta) if cached \
-            else mix(None, qkv, g, beta)
-        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                              + cfg.layer_norm_epsilon) * o_norm.astype(f32)
-        y = o.reshape(B, T, inner) * jax.nn.sigmoid(gate.astype(f32))
-        return dense(C, "o_proj")(y.astype(cfg.dtype)), leaves
-
-
-class ShortConvMixer(nn.Module):
-    """LFM2's gated short convolution in the attention's place. With ``u``
-    the normed input (C wide)::
-
-        [B ; C ; z] = W_in u            (three gates of C channels, this order)
-        v = B (.) z
-        c_t = sum_j w_j (.) v_{t - (K - 1) + j}     j = 0 .. K - 1
-        out = W_out (C (.) c)
-
-    The convolution is causal and depthwise over ``conv_taps`` = K taps, no
-    bias and NO activation. There is no matrix state: what a sequence
-    carries is the convolution's tail, the last K - 1 rows of ``v``, the
-    one leaf ``conv`` of the state group (``(L, rows, (K - 1) * C)`` in the
-    model's dtype, time-major on the minor axis), read and written through
-    :func:`_conv_after_tail` with ``layer``, ``start``, ``rows`` and
-    ``valid`` as :class:`Mamba2Mixer`: a decode row, a chunk and a whole
-    sequence are one code path, a token at or past ``valid`` shifts nothing
-    into the tail, an entry whose first position is 0 reads none, and a
-    row that does not run keeps its tail. All of it is XLA's, under the
-    scope ``short_conv``."""
-
-    config: TransformerConfig
-
-    @nn.compact
-    def __call__(self, u, *, decode: Union[bool, str] = False,
-                 deterministic: bool = True, kv_cache=None, layer=None):
-        cfg = self.config
-        C, K = u.shape[-1], cfg.conv_taps
-        bcz = _dense(cfg, 3 * C, use_bias=False, name="in_proj")(u)
-        bound = 1.0 / math.sqrt(K)      # (torch's Conv1d default)
-        taps = nn.initializers.uniform(2 * bound)
-        conv_w = self.param("conv_w", lambda *a: taps(*a) - bound, (K, C))
-
-        def mix(cache, bcz):
-            """The gates and the convolution of one group of rows (B, T):
-            ``(C (.) c, leaves)``."""
-            with jax.named_scope("short_conv"):
-                b, c, z = (bcz[..., i * C:(i + 1) * C] for i in range(3))
-                conv, _, leaf = _conv_after_tail(cache, b * z, conv_w, None,
-                                                 silu=False)
-                y = (c.astype(jnp.float32) * conv).astype(cfg.dtype)
-            return y, None if cache is None else {"conv": leaf}
-
-        y, leaves = _by_row_group(kv_cache, mix, bcz) if decode \
-            else mix(None, bcz)
-        return _dense(cfg, C, use_bias=False, name="out_proj")(y), leaves
 
 
 class TransformerMLP(nn.Module):
@@ -2090,670 +378,6 @@ class _ScanBlock(nn.Module):
             key: jax.lax.dynamic_update_slice_in_dim(
                 cache[key], new_slice[key][None], li, 0) for key in sliced})
         return (x, cache, start, li + 1), tuple(stats)
-
-
-_PACK_DISABLED_WARNED: set = set()
-
-
-def kv_cache_spec(cfg: TransformerConfig):
-    """The single source of truth for the KV-cache container: returns
-    ``(cache_dtype, cache_d, kv_packed)`` — the per-layer k/v arrays are
-    (B, KV, cache_d, max_seq_len). Used by CachedAttention (reads/
-    writes), _CacheStore (allocation) and make_layer_kv_cache
-    (ZeRO-Inference allocation) so the layout can never drift apart."""
-    D = cfg.head_dim
-    if cfg.kv_cache_quant and cfg.kv_cache_packed is not False and D % 4 != 0:
-        if cfg.kv_cache_packed is True:
-            raise ValueError(
-                f"kv_cache_packed=True requires head_dim % 4 == 0 (the int32 "
-                f"container packs 4 head-dim rows per word); head_dim={D}. "
-                f"Use kv_cache_packed=None (auto) or False, or pad n_embd.")
-        if D not in _PACK_DISABLED_WARNED:  # auto: warn once per head_dim
-            _PACK_DISABLED_WARNED.add(D)
-            from ..utils.logging import logger
-
-            logger.warning(
-                f"int32 KV-cache packing disabled: head_dim={D} is not a "
-                f"multiple of 4; falling back to the plain int8 container "
-                f"(risk: Mosaic's (4,1)-packed s8 carry layout — see "
-                f"kv_cache_packed in TransformerConfig)")
-    kv_packed = (cfg.kv_cache_quant and cfg.kv_cache_packed is not False
-                 and D % 4 == 0)
-    if kv_packed:
-        return jnp.int32, D // 4, True
-    if cfg.kv_cache_quant:
-        return jnp.int8, D, False
-    return cfg.dtype, D, False
-
-
-def kv_cache_groups(cfg: TransformerConfig):
-    """The layer groups of the cache, or None for the single group every
-    model had before ``layer_types``: ``((suffix, layers, window), ...)``,
-    ``""`` the full-attention layers (every position kept) and ``"_win"``
-    the sliding layers (the last ``window`` positions visible). A page
-    pool keeps one stacked leaf (``k<suffix>``, ``v<suffix>``) and one
-    page table (``table<suffix>``) a group."""
-    if cfg.layer_types is None \
-            or "sliding_attention" not in cfg.layer_types:
-        return None
-    by_kind = {kind: tuple(i for i, k in enumerate(cfg.layer_types)
-                           if k == kind)
-               for kind in ("full_attention", "sliding_attention")}
-    return (("", by_kind["full_attention"], 0),
-            ("_win", by_kind["sliding_attention"], int(cfg.sliding_window)))
-
-
-def page_lanes(page_size: int) -> int:
-    """The minor dimension a page of ``page_size`` columns is stored
-    with: whole 128-lane tiles. A Mosaic kernel takes its operands
-    row-major, where a 64-wide page fills half of each tile anyway; the
-    TPU client, left to store a ``(..., 128, 64)`` leaf as it likes,
-    puts the head dim minor instead, and XLA then wraps every kernel
-    call in a copy of the whole leaf to row-major and back (``copy.107
-    / .110`` of the decode program before PR 27; four copies of the
-    stacked leaf a step, 8.5 ms each, once the kernels took it whole:
-    chip runs of PR 27). A leaf whose minor dimension IS the lane tile
-    has one layout everybody agrees on, at the bytes the row-major
-    layout takes in any case: twice a page's at ``page_size`` 64, none
-    extra at 128. (Pinning the layout of a 64-wide leaf with
-    ``jax.experimental.layout`` works within one process and breaks the
-    persistent compile cache: a deserialized executable reports default
-    layouts for its row-major operands; chip run of PR 27.)"""
-    return -(-page_size // 128) * 128
-
-
-# What a cache kind does not run with yet, in ONE place: (kind, feature) ->
-# the mechanism in its way. Every constructor that turns a feature on asks
-# ``KVCacheSpec.refusal`` (``TransformerConfig``, which is what a spec is
-# made from, asks :func:`refusal` itself) and raises the sentence it gets;
-# none keeps a copy. Lifting a refusal is deleting its row; a new kind adds
-# its rows here and edits no constructor (ROADMAP.md, Reach, has the queue).
-CACHE_KINDS = {     # in the order a refusal is looked up (cache_kinds)
-    "state": "a recurrent state",           # power_retention layers
-    "ssm": "a state group beside K/V",      # mamba beside attention layers
-    "kda": "a KDA state group",             # kda beside attention layers
-    "conv": "a convolution-tail state group",   # conv beside attention layers
-    "sparse": "the index of learned sparse attention",  # sparse_attention:
-    # group means of the keys beside the K/V pages, a choice of blocks (it
-    # stands beside lightning layers and is asked first)
-    "lightning": "a Lightning state group",     # lightning beside attention
-    "latent": "latent attention's cache",   # one row a token (kv_lora_rank)
-    "window_only": "sliding-window layers alone",
-    "window": "a window page group",        # sliding beside full layers
-    "layer_types": "layer_types",           # a layer reads its kind off
-    # the scan's counter (a window group is one case)
-    "routed": "a routed FFN",               # the module's, not the cache's
-}
-FEATURES = {        # feature -> how its refusal names it, and who asks
-    "spec_decode": "spec_decode",                           # ServingEngine
-    "paged_kv": "paged_kv",         # ServingEngine, PagedKVPool, paged_cache
-    "prefix_cache": "prefix_cache",                         # PagedKVPool
-    "roles": "prefill/decode roles",    # ServingEngine, import_pages
-    "tensor_parallel": "tensor-parallel inference",         # InferenceEngine
-    "tensor_parallel_serving": "tensor-parallel serving",   # ServingEngine
-    "prefill_chunk_wider_than_window":                      # ServingEngine
-        "a prefill_chunk wider than sliding_window",
-    "zero_inference": "ZeRO-Inference",             # ZeroInferenceEngine
-    "kv_cache_quant": "kv_cache_quant",             # TransformerConfig
-    "int8_weights": "int8_weights",                 # TransformerConfig
-}
-CACHE_REFUSALS = {
-    ("state", "spec_decode"):
-        "a rejected draft's tokens are in the state for good: verify_k's "
-        "rollback moves an index, and a state has none (it would have to "
-        "keep the state from before the draft)",
-    ("state", "paged_kv"):
-        "a state has no positions to page, a model whose every layer keeps "
-        "one has no attention layer whose K/V a page pool would hold, and a "
-        "prefix hit would need a snapshot of the state at the hit's "
-        "boundary (a state group rides beside paged K/V where a model has "
-        "both)",
-    ("state", "roles"):
-        "pages are the unit of a handoff and a state has none: the state "
-        "itself would have to be shipped",
-    ("state", "tensor_parallel"):
-        "the state leaves have no placement on the model axis and the "
-        "retention kernels are not wrapped for a mesh",
-    ("state", "zero_inference"):
-        "a power_retention layer's recurrent state is not threaded through "
-        "the streamed layers",
-    ("state", "kv_cache_quant"):
-        "kv_cache_quant quantizes K/V columns; a power_retention layer "
-        "keeps a float32 state and no column",
-    ("ssm", "spec_decode"):
-        "a rejected draft's tokens are in the state and the convolution's "
-        "tail for good: verify_k's rollback moves an index, which hides "
-        "K/V columns and nothing of a state",
-    ("ssm", "prefix_cache"):
-        "a hit maps the K/V pages of the prompt's start and would need the "
-        "state as it stood at the hit's boundary, which nothing keeps (a "
-        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
-    ("ssm", "roles"):
-        "pages are the unit of a handoff: the slot's state rows would have "
-        "to be shipped beside them",
-    ("ssm", "tensor_parallel"):
-        "the state leaves have no placement on the model axis and the "
-        "state-space kernels are not wrapped for a mesh",
-    ("ssm", "tensor_parallel_serving"):
-        "the state leaves have no placement on the model axis and the "
-        "state-space kernels are not wrapped for a mesh",
-    ("ssm", "zero_inference"):
-        "it streams one layer's block parameters at a time out of ONE "
-        "stacked tree; mamba and attention layers are two, and the state is "
-        "not threaded through the streamed layers",
-    ("ssm", "kv_cache_quant"):
-        "kv_cache_quant quantizes K/V columns; the state group beside them "
-        "is float32 and the tier has not been run beside it",
-    ("ssm", "int8_weights"):
-        "int8_weights does not reach the mamba layers' convolution, A_log, "
-        "D and dt_bias, which are parameters of the mixer and no Dense",
-    ("kda", "spec_decode"):
-        "a rejected draft's tokens are in the delta-rule state and the "
-        "convolutions' tail for good: verify_k's rollback moves an index, "
-        "which hides cached columns and nothing of a state",
-    ("kda", "prefix_cache"):
-        "a hit maps the pages of the prompt's start and would need the "
-        "state as it stood at the hit's boundary, which nothing keeps (a "
-        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
-    ("kda", "roles"):
-        "pages are the unit of a handoff: the slot's state rows would have "
-        "to be shipped beside them",
-    ("kda", "tensor_parallel"):
-        "the state leaves have no placement on the model axis and the KDA "
-        "kernels are not wrapped for a mesh",
-    ("kda", "tensor_parallel_serving"):
-        "the state leaves have no placement on the model axis and the KDA "
-        "kernels are not wrapped for a mesh",
-    ("kda", "zero_inference"):
-        "it streams one layer's block parameters at a time out of ONE "
-        "stacked tree; kda and attention layers are two, and the state is "
-        "not threaded through the streamed layers",
-    ("kda", "kv_cache_quant"):
-        "kv_cache_quant quantizes K/V columns; the state group beside them "
-        "is float32 and the tier has not been run beside it",
-    ("kda", "int8_weights"):
-        "int8_weights does not reach the kda layers' convolution, A_log, "
-        "dt_bias and output norm, which are parameters of the mixer and no "
-        "Dense",
-    ("conv", "spec_decode"):
-        "a rejected draft's tokens are in the convolution's tail for good: "
-        "verify_k's rollback moves an index, which hides K/V columns and "
-        "nothing of a state",
-    ("conv", "prefix_cache"):
-        "a hit maps the K/V pages of the prompt's start and would need the "
-        "tail as it stood at the hit's boundary, which nothing keeps (a "
-        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
-    ("conv", "roles"):
-        "pages are the unit of a handoff: the slot's state rows would have "
-        "to be shipped beside them",
-    ("conv", "tensor_parallel"):
-        "the state leaf has no placement on the model axis",
-    ("conv", "tensor_parallel_serving"):
-        "the state leaf has no placement on the model axis",
-    ("conv", "zero_inference"):
-        "it streams one layer's block parameters at a time out of ONE "
-        "stacked tree; conv and attention layers are two, and the tail is "
-        "not threaded through the streamed layers",
-    ("conv", "kv_cache_quant"):
-        "kv_cache_quant quantizes K/V columns; the tail beside them is in "
-        "the model's dtype and the tier has not been run beside it",
-    ("conv", "int8_weights"):
-        "int8_weights does not reach the conv layers' taps, which are a "
-        "parameter of the mixer and no Dense",
-    ("lightning", "spec_decode"):
-        "a rejected draft's tokens are in the linear attention's state for "
-        "good: verify_k's rollback moves an index, which hides K/V columns "
-        "and nothing of a state",
-    ("lightning", "prefix_cache"):
-        "a hit maps the K/V pages of the prompt's start and would need the "
-        "state as it stood at the hit's boundary, which nothing keeps (a "
-        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
-    ("lightning", "roles"):
-        "pages are the unit of a handoff: the slot's state rows would have "
-        "to be shipped beside them",
-    ("lightning", "tensor_parallel"):
-        "the state leaf has no placement on the model axis and the "
-        "Lightning kernels are not wrapped for a mesh",
-    ("lightning", "tensor_parallel_serving"):
-        "the state leaf has no placement on the model axis and the "
-        "Lightning kernels are not wrapped for a mesh",
-    ("lightning", "zero_inference"):
-        "it streams one layer's block parameters at a time out of ONE "
-        "stacked tree; lightning and attention layers are two, and the "
-        "state is not threaded through the streamed layers",
-    ("lightning", "kv_cache_quant"):
-        "kv_cache_quant quantizes K/V columns; the state group beside them "
-        "is float32 and the tier has not been run beside it",
-    ("lightning", "int8_weights"):
-        "int8_weights has not been run through the lightning layers (their "
-        "norms on q, k and the output are parameters of the mixer and no "
-        "Dense)",
-    ("sparse", "spec_decode"):
-        "a verify step's K + 1 rows of every slot would each choose their "
-        "own blocks, and a rejected draft's keys are in the index's group "
-        "means for good: the read of a chosen page list takes one row a "
-        "slot or one slot's chunk",
-    ("sparse", "prefix_cache"):
-        "a page shared by a hit would have to share its group means, and a "
-        "copy-on-write fork copies K/V pages while a group is still filling",
-    ("sparse", "roles"):
-        "a handoff ships K/V pages; the index's group means beside them "
-        "have not been driven through one",
-    ("sparse", "tensor_parallel_serving"):
-        "the choice sums the query heads of a KV head and the chosen page "
-        "list is one device's: the read has not been wrapped for a mesh",
-    ("sparse", "zero_inference"):
-        "the choice of blocks reads the whole row's keys, which the "
-        "streamed layers' one-layer cache does not hand it",
-    ("sparse", "kv_cache_quant"):
-        "kv_cache_quant quantizes K/V columns; the index's group means are "
-        "float32 means of the keys as written and the chosen-pages read "
-        "takes no scales",
-    ("latent", "spec_decode"):
-        "the latent read takes one query row a slot or one slot's chunk; a "
-        "verify step's K + 1 rows of every slot, each with its own causal "
-        "limit, have no program yet",
-    ("latent", "roles"):
-        "a handoff ships K/V pages; a pool of latent pages has not been "
-        "driven through one",
-    ("latent", "tensor_parallel_serving"):
-        "every head reads the one cached row, so the latent leaf has no "
-        "placement on the model axis and the read is not wrapped for a mesh",
-    ("latent", "zero_inference"):
-        "latent attention's one cached row a token is not threaded through "
-        "the streamed layers",
-    ("latent", "kv_cache_quant"):
-        "kv_cache_quant quantizes K/V columns a head; the latent cache is "
-        "one row a token that every head reads, and its scales have no leaf",
-    ("latent", "int8_weights"):
-        "int8_weights does not reach latent attention: kv_b_proj is read as "
-        "a matrix (absorbed into the query and the output), not through a "
-        "Dense",
-    ("window_only", "paged_kv"):
-        "a model of sliding-window layers only has no full page group",
-    ("window", "spec_decode"):
-        "verify_k's rollback would have to un-recycle window pages",
-    ("window", "prefix_cache"):
-        "a hit maps pages of the prompt's start, which a ring has recycled "
-        "(pass paged_kv={'prefix_cache': False})",
-    ("window", "roles"):
-        "a handoff would have to ship the window ring",
-    ("window", "tensor_parallel_serving"):
-        "the window group's leaves have no placement on the model axis",
-    ("window", "prefill_chunk_wider_than_window"):
-        "a chunk's rows read the pages behind it while its own are mapped, "
-        "more than the ring a slot is granted",
-    ("layer_types", "zero_inference"):
-        "a layer reads its kind off the layer scan's counter, which the "
-        "streamed layers do not carry",
-    ("layer_types", "kv_cache_quant"):
-        "the window group's pages and the window mask exist for the "
-        "full-precision tier only",
-    ("routed", "spec_decode"):
-        "the drafter has no routed FFN",
-    ("routed", "roles"):
-        "a server of a routed model has not been driven through a handoff "
-        "(the one served has a window ring too, which would have to be "
-        "shipped)",
-    ("routed", "tensor_parallel_serving"):
-        "the expert leaves have no placement on the expert axis when served",
-    ("routed", "zero_inference"):
-        "it streams one layer's block parameters at a time; the routed "
-        "FFN's expert leaves are stacked parameters of the model",
-    ("routed", "kv_cache_quant"):
-        "no routed model has run on the int8 cache tier (the one served has "
-        "a window group too, which exists for the full-precision tier only)",
-    ("routed", "int8_weights"):
-        "int8_weights does not reach the routed FFN's expert leaves "
-        "(ops/quantization quantizes Dense kernels); serve the routed "
-        "model in bf16",
-}
-
-
-def cache_kinds(cfg: TransformerConfig) -> tuple:
-    """The kinds of ``CACHE_KINDS`` a configuration is, in its order."""
-    groups = kv_cache_groups(cfg)
-    has = {"state": cfg.retention, "ssm": cfg.mamba, "kda": cfg.kda,
-           "conv": cfg.conv, "lightning": cfg.lightning,
-           "sparse": cfg.sparse_attention is not None,
-           "latent": cfg.latent,
-           "window_only": groups is not None and not groups[0][1],
-           "window": groups is not None,
-           "layer_types": cfg.layer_types is not None
-           and not (cfg.retention or cfg.hybrid),
-           "routed": cfg.n_experts}
-    return tuple(kind for kind in CACHE_KINDS if has[kind])
-
-
-def refusal(kinds: tuple, feature: str) -> Optional[str]:
-    """Why a model of these ``kinds`` does not run with ``feature`` yet (the
-    first of its kinds that has a row), or None where it does."""
-    name = FEATURES[feature]    # a closed set: an unknown feature is a bug
-    for kind in kinds:
-        why = CACHE_REFUSALS.get((kind, feature))
-        if why is not None:
-            return (f"{name} does not compose with {CACHE_KINDS[kind]} yet: "
-                    f"{why}")
-    return None
-
-
-@dataclasses.dataclass(frozen=True)
-class KVCacheSpec:
-    """Module-declared KV-cache allocation contract: everything an engine
-    needs to size, allocate and bound a cache WITHOUT inferring layout
-    from pytree leaf shapes. ``stacked_cache``/``layer_cache``
-    build zeroed containers in the exact layout CachedAttention reads and
-    writes; the serving slot pool allocates through this (batch dim =
-    slots) and ``InferenceEngine.generate`` takes ``max_seq_len`` as the
-    authoritative capacity."""
-
-    n_layer: int
-    kv_heads: int
-    head_dim: int          # logical per-head width
-    cache_d: int           # stored sublane dim (head_dim, or //4 packed)
-    dtype: Any
-    max_seq_len: int
-    quantized: bool
-    packed: bool
-    groups: Optional[tuple] = None     # kv_cache_groups(cfg): the layers
-    # of each group of a page pool; the contiguous containers below keep
-    # every layer at full length (a window layer's old columns are masked)
-    latent: int = 0                    # latent attention: the width of the
-    # one row a token a layer that every head reads. Such a cache holds
-    # ``c`` (L, B, latent, S) positions-minor and no k / v; a page pool's
-    # leaf is (L, P, latent, lanes), a page one whole-tile block a layer
-    latent_rank: int = 0               # of which the values: the row's
-    # leading ``kv_lora_rank`` stored rows
-    kinds: tuple = ()                  # cache_kinds(cfg): what refusal() reads
-    rep: int = 1                       # query heads that share a KV head
-    # (GQA): the rows of the page read's block (paged_attention.block_rows)
-    sparse: Optional[tuple] = None     # learned sparse attention's sizes
-    # (ops/attention/sparse_index.SparseSizes). A page pool then keeps,
-    # beside k / v and under the same table, the leaf ``kc`` (L, P, KV,
-    # page_size // kernel_stride, head_dim) float32: the means of each
-    # group of ``kernel_stride`` consecutive keys, which the choice of
-    # blocks is made against. A contiguous cache keeps none: its keys lie
-    # in one piece
-    state_group: Optional[tuple] = None    # the layers that keep a state a
-    # row (no positions) and their leaves: ``(layers, ((leaf, shape a row
-    # a layer, dtype), ...))``. A leaf of the cache is ``(layers, rows,
-    # *shape)``; a row belongs to a sequence (a slot of a pool: a page pool
-    # keeps the group beside its page leaves, ``num_slots`` rows). A model
-    # of power_retention layers: every layer, one leaf ``s`` ``(KV,
-    # *state)`` float32. A model of mamba layers beside attention layers:
-    # the mamba layers, ``s`` (ops/state_space.state_shape) float32 and
-    # ``conv`` (the convolution's last taps - 1 inputs, time-major) in
-    # ``dtype``; the attention layers keep K/V (:attr:`kv_layers`). A model
-    # of kda layers beside attention layers: the kda layers, ``s`` (heads,
-    # d, d) float32 and ``conv`` likewise; the attention layers keep K/V or
-    # the latent row. A model of conv layers beside attention layers: the
-    # conv layers, ONE leaf ``conv`` (the convolution's last taps - 1
-    # inputs) and no ``s``. A state's size does not depend on max_seq_len,
-    # which stays the bound on positions
-
-    @property
-    def index_stride(self) -> int:
-        """The keys a group of the index's leaf ``kc`` averages; 0: none."""
-        return self.sparse.kernel_stride if self.sparse else 0
-
-    @property
-    def state_leaves(self) -> tuple:
-        """The leaves that hold a state a row (no positions): what a
-        program is told the running rows for."""
-        return tuple(leaf for leaf, _, _ in self.state_group[1]) \
-            if self.state_group else ()
-
-    @property
-    def state(self) -> Optional[tuple]:
-        """A model with a state in EVERY layer and no k / v
-        (power_retention): the shape of one KV head's state
-        (ops/attention/power_retention.state_shape); None otherwise."""
-        if self.state_group and not self.kv_layers:
-            return self.state_group[1][0][1][1:]
-        return None
-
-    @property
-    def kv_layers(self) -> int:
-        """The layers that keep K/V (or a latent row): the first dimension
-        of those leaves. ``n_layer`` less the state group's."""
-        return self.n_layer - (self.state_group[0] if self.state_group
-                               else 0)
-
-    def refusal(self, feature: str, prefill_chunk: int = 0) -> Optional[str]:
-        """The sentence of ``CACHE_REFUSALS`` for ``feature`` with this
-        cache's model, or None: what the constructor that turns ``feature``
-        on raises. ``prefill_chunk``: the width asked of
-        ``prefill_chunk_wider_than_window``."""
-        why = refusal(self.kinds, feature)
-        if why and feature == "prefill_chunk_wider_than_window":
-            window = self.groups[1][2]
-            return None if prefill_chunk <= window else (
-                f"{why} (prefill_chunk {prefill_chunk}, sliding_window "
-                f"{window})")
-        return why
-
-    @property
-    def state_bytes_per_row(self) -> int:
-        """Bytes of one sequence's state over the layers (0: a K/V cache)."""
-        if not self.state_group:
-            return 0
-        layers, leaves = self.state_group
-        return layers * sum(math.prod(shape) * np.dtype(dtype).itemsize
-                            for _, shape, dtype in leaves)
-
-    def _state_cache(self, rows: int, stacked: bool = True) -> dict:
-        """The zeroed state leaves of ``rows`` sequences: one layer's, or
-        ``stacked`` over the layers that keep a state."""
-        layers, leaves = self.state_group
-        lead = ((layers,) if stacked else ()) + (rows,)
-        return {leaf: jnp.zeros(lead + shape, dtype)
-                for leaf, shape, dtype in leaves}
-
-    def layer_cache(self, batch_size: int) -> dict:
-        """Zeroed single-layer k/v dict: (B, KV, cache_d, S) [+ scales]."""
-        if not self.kv_layers:
-            return self._state_cache(batch_size, stacked=False)
-        if self.latent:
-            return {"c": jnp.zeros((batch_size, self.latent,
-                                    self.max_seq_len), self.dtype)}
-        shape = (batch_size, self.kv_heads, self.cache_d, self.max_seq_len)
-        cache = {"k": jnp.zeros(shape, self.dtype),
-                 "v": jnp.zeros(shape, self.dtype)}
-        if self.quantized:
-            sshape = (batch_size, self.kv_heads, self.max_seq_len)
-            cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
-            cache["v_scale"] = jnp.zeros(sshape, jnp.float32)
-        return cache
-
-    def stacked_cache(self, batch_size: int) -> dict:
-        """Zeroed L-stacked cache dict matching the ``cache_store`` flax
-        variables: k/v (L, B, KV, cache_d, S) [+ scales (L, B, KV, S)],
-        plus a per-sequence ``index`` (B,) int32 — the vector-start form
-        CachedAttention accepts for slot-pooled decode."""
-        L = self.kv_layers
-        if not L:
-            return dict(self._state_cache(batch_size),
-                        index=jnp.zeros((batch_size,), jnp.int32))
-        if self.latent:
-            return {"c": jnp.zeros((L, batch_size, self.latent,
-                                    self.max_seq_len), self.dtype),
-                    "index": jnp.zeros((batch_size,), jnp.int32),
-                    **(self._state_cache(batch_size) if self.state_group
-                       else {})}
-        shape = (L, batch_size, self.kv_heads, self.cache_d,
-                 self.max_seq_len)
-        cache = {"k": jnp.zeros(shape, self.dtype),
-                 "v": jnp.zeros(shape, self.dtype),
-                 "index": jnp.zeros((batch_size,), jnp.int32)}
-        if self.state_group:
-            cache.update(self._state_cache(batch_size))
-        if self.quantized:
-            sshape = (L, batch_size, self.kv_heads, self.max_seq_len)
-            cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
-            cache["v_scale"] = jnp.zeros(sshape, jnp.float32)
-        return cache
-
-    # -- paged KV (PagedAttention-style block pool) --------------------
-    def paged_cache(self, num_pages: int, page_size: int,
-                    window_pages: Optional[int] = None,
-                    num_slots: int = 0) -> dict:
-        """Zeroed PAGE-POOL k/v arrays: the positions axis is split into
-        ``num_pages`` physical pages of ``page_size`` columns each, with
-        NO batch axis — k/v (L, P, KV, cache_d, lanes) [+ scales
-        (L, P, KV, lanes)], ``lanes = page_lanes(page_size)``: a page's
-        columns stand in its first ``page_size`` lanes and the rest is
-        never read. A per-slot page table maps logical positions to
-        pages; :meth:`dense_from_pages` reassembles the
-        ``stacked_cache`` layout the attention kernels consume. Same
-        dtype/packing tiers as the contiguous container (int8/packed
-        cache columns page exactly like full-precision ones). A state
-        group is not paged: ``num_slots`` rows beside the page leaves."""
-        why = self.refusal("paged_kv")
-        if why:
-            raise ValueError(why)
-        lanes = page_lanes(page_size)
-        if self.latent:
-            return {"c": jnp.zeros((self.kv_layers, num_pages, self.latent,
-                                    lanes), self.dtype),
-                    **(self._state_cache(num_slots) if self.state_group
-                       else {})}
-        if self.groups is not None:
-            # one stacked leaf a group: ``num_pages`` pages for the full
-            # layers, ``window_pages`` for the window layers
-            return {key + suffix: jnp.zeros(
-                        (len(layers), pages, self.kv_heads, self.cache_d,
-                         lanes), self.dtype)
-                    for (suffix, layers, _), pages in zip(
-                        self.groups, (num_pages, window_pages))
-                    for key in ("k", "v")}
-        shape = (self.kv_layers, num_pages, self.kv_heads, self.cache_d,
-                 lanes)
-        cache = {"k": jnp.zeros(shape, self.dtype),
-                 "v": jnp.zeros(shape, self.dtype)}
-        if self.index_stride:
-            if page_size % self.index_stride:
-                raise ValueError(
-                    f"a page holds whole groups of the index's "
-                    f"{self.index_stride} keys; got page_size {page_size}")
-            cache["kc"] = jnp.zeros(
-                (self.kv_layers, num_pages, self.kv_heads,
-                 page_size // self.index_stride, self.head_dim), jnp.float32)
-        if self.state_group:
-            cache.update(self._state_cache(num_slots))
-        if self.quantized:
-            sshape = (self.kv_layers, num_pages, self.kv_heads, lanes)
-            cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
-            cache["v_scale"] = jnp.zeros(sshape, jnp.float32)
-        return cache
-
-    def dense_from_pages(self, paged: dict, table) -> dict:
-        """Traced paged-attention GATHER: reassemble the dense
-        ``(L, B, KV, cache_d, max_seq_len)`` view of a page pool from a
-        ``(B, max_pages_per_slot)`` int32 page table, so the existing
-        attention programs (decode / verify / chunked prefill) run
-        UNCHANGED over paged storage — bitwise-identical math, static
-        shapes, zero new attention kernels. Unmapped entries carry the
-        sentinel ``num_pages``; the clip-mode gather reads an arbitrary
-        real page there, which is safe because a slot's mapped region
-        always covers its live ``[0, index)`` columns and attention
-        masks everything beyond (the same alive-masking that makes dead
-        slots free). ``table`` rows must span exactly
-        ``max_seq_len // page_size`` pages.
-
-        With layer ``groups``, ``paged`` holds a leaf and ``table`` (a
-        dict) a table a group; each group is gathered through its own
-        table and the layers come back in model order, so the dense
-        programs see the one ``(L, ...)`` stack they always saw. A window
-        group's recycled entries are sentinels like any other: what they
-        read is behind the window mask."""
-        if self.groups is not None:
-            single = dataclasses.replace(self, groups=None)
-            parts = [single.dense_from_pages(
-                        {key: paged[key + suffix] for key in ("k", "v")},
-                        table["table" + suffix])
-                     for suffix, _, _ in self.groups]
-            order = np.argsort(np.concatenate(
-                [np.asarray(layers, np.int64)
-                 for _, layers, _ in self.groups]))
-            return {key: jnp.concatenate([p[key] for p in parts])[order]
-                    for key in ("k", "v")}
-        B, max_pages = table.shape
-        ps = self.max_seq_len // max_pages
-        flat = table.reshape(-1)
-        # (a state group is rows of the pool already: it rides along)
-        out = {key: paged[key] for key in self.state_leaves}
-        if self.latent:
-            leaf = paged["c"]                       # (L, P, W, lanes)
-            g = jnp.take(leaf, flat, axis=1, mode="clip")[..., :ps]
-            g = g.reshape(leaf.shape[0], B, max_pages, self.latent, ps)
-            return dict(out, c=g.transpose(0, 1, 3, 2, 4).reshape(
-                leaf.shape[0], B, self.latent, max_pages * ps))
-        for key in ("k", "v"):
-            leaf = paged[key]                       # (L, P, KV, cd, lanes)
-            L, _, KV, cd, _ = leaf.shape
-            g = jnp.take(leaf, flat, axis=1, mode="clip")[..., :ps]
-            g = g.reshape(L, B, max_pages, KV, cd, ps)
-            out[key] = g.transpose(0, 1, 3, 4, 2, 5).reshape(
-                L, B, KV, cd, max_pages * ps)
-        if self.quantized:
-            for key in ("k_scale", "v_scale"):
-                leaf = paged[key]                   # (L, P, KV, lanes)
-                L, _, KV, _ = leaf.shape
-                g = jnp.take(leaf, flat, axis=1, mode="clip")[..., :ps]
-                g = g.reshape(L, B, max_pages, KV, ps)
-                out[key] = g.transpose(0, 1, 3, 2, 4).reshape(
-                    L, B, KV, max_pages * ps)
-        return out
-
-
-def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
-    cache_dtype, cache_d, packed = kv_cache_spec(cfg)
-    group = None
-    if cfg.retention:
-        from ..ops.attention.power_retention import state_shape
-
-        group = (cfg.n_layer, (
-            ("s", (cfg.kv_heads,) + state_shape(cfg.head_dim),
-             jnp.float32),))
-    if cfg.mamba:
-        from ..ops.state_space import state_shape
-
-        group = (cfg.layer_types.count("mamba"), (
-            ("s", state_shape(cfg.mamba_n_heads, cfg.mamba_d_head,
-                              cfg.mamba_d_state), jnp.float32),
-            ("conv", ((cfg.mamba_d_conv - 1) * cfg.mamba_channels,),
-             cache_dtype)))
-    if cfg.kda:
-        group = (cfg.layer_types.count("kda"), (
-            ("s", (cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_d_head),
-             jnp.float32),
-            ("conv", ((cfg.kda_d_conv - 1) * 3 * cfg.kda_width,),
-             cache_dtype)))
-    if cfg.conv:
-        group = (cfg.layer_types.count("conv"), (
-            ("conv", ((cfg.conv_taps - 1) * cfg.n_embd,), cache_dtype),))
-    if cfg.lightning:
-        from ..ops.lightning import state_shape
-
-        group = (cfg.layer_types.count("lightning"), (
-            ("s", state_shape(cfg.n_head, cfg.head_dim), jnp.float32),))
-    return KVCacheSpec(n_layer=cfg.n_layer, kv_heads=cfg.kv_heads,
-                       head_dim=cfg.head_dim, cache_d=cache_d,
-                       dtype=cache_dtype, max_seq_len=cfg.max_seq_len,
-                       quantized=cfg.kv_cache_quant, packed=packed,
-                       groups=kv_cache_groups(cfg), latent=cfg.latent,
-                       latent_rank=cfg.kv_lora_rank if cfg.latent else 0,
-                       kinds=cache_kinds(cfg),
-                       rep=cfg.n_head // cfg.kv_heads, state_group=group,
-                       sparse=cfg.sparse)
-
-
-def make_layer_kv_cache(cfg: TransformerConfig, batch_size: int) -> dict:
-    """Zeroed SINGLE-LAYER KV cache dict — the explicit functional form
-    of one _CacheStore slice, for callers that stream layers one at a
-    time (ZeRO-Inference) and thread the cache themselves. Add a
-    ``start`` scalar before passing to TransformerBlock."""
-    return make_kv_cache_spec(cfg).layer_cache(batch_size)
 
 
 class _CacheStore(nn.Module):

@@ -80,7 +80,8 @@ def get_policy_rules(model: Any) -> Optional[ShardingRules]:
     """Explicit per-family rules when the model type is known (≅ the policy/
     container path, reference module_inject/replace_policy.py)."""
     from ..models.gpt2 import GPT2LMHeadModel, gpt2_sharding_rules
-    from ..models.transformer_lm import TransformerLM, transformer_sharding_rules
+    from ..models.lm_config import transformer_sharding_rules
+    from ..models.transformer_lm import TransformerLM
 
     if isinstance(model, TransformerLM):
         return ShardingRules(transformer_sharding_rules())

@@ -61,7 +61,7 @@ DEFAULT_AXIS_RULES: Tuple[Tuple[str, Optional[str]], ...] = (
 )
 
 #: logical layouts of the serving cache containers (KVCacheSpec
-#: layouts; models/transformer_lm.py is the shape source of truth)
+#: layouts; models/kv_cache_spec.py is the shape source of truth)
 STACKED_CACHE_AXES: Dict[str, Tuple[Optional[str], ...]] = {
     "k": ("layers", "slots", "kv_heads", "head_dim", "positions"),
     "v": ("layers", "slots", "kv_heads", "head_dim", "positions"),

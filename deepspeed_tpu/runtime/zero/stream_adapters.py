@@ -84,7 +84,7 @@ class TransformerLMAdapter(StreamedModelAdapter):
         self._block = TransformerBlock(self.cfg)
 
     def embed_apply(self, resident, batch):
-        from ...models.transformer_lm import _norm
+        from ...models.lm_parts import _norm
 
         cfg = self.cfg
         ids = batch["input_ids"]
@@ -111,7 +111,7 @@ class TransformerLMAdapter(StreamedModelAdapter):
                                  deterministic, rngs=rngs)[0]
 
     def head_loss(self, resident, xL, batch):
-        from ...models.transformer_lm import _norm
+        from ...models.lm_parts import _norm
 
         cfg = self.cfg
         # EXACTLY TransformerLM.__call__'s tail (shift + masked xent).

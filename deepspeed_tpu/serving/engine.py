@@ -99,6 +99,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ..models.kv_cache_spec import page_lanes
 from ..ops.attention.sparse_index import (index_rows, pages_most,
                                             tokens_read)
 from ..telemetry import (FlightRecorder, MetricsRegistry, ProgramCostModel,
@@ -335,7 +336,7 @@ class ServingEngine:
         capacity = int(spec.max_seq_len)
         # what the cache's kind does not run with yet refuses here, in the
         # table's words, before anything is allocated
-        # (models/transformer_lm.py, CACHE_REFUSALS); the pool asks for
+        # (models/cache_kinds.py, CACHE_REFUSALS); the pool asks for
         # prefix_cache itself
         mesh = getattr(engine, "mesh", None)
         asked = {
@@ -1464,7 +1465,6 @@ class ServingEngine:
                "sparse_index_rows": int(
                    index_rows(positions, self._sparse).sum())}
         if decode and self._paged:
-            from ..models.transformer_lm import page_lanes
             from ..ops.attention.sparse_read import pages_a_step
 
             spec, page_size = self.pool.spec, self.pool.page_size
@@ -2660,19 +2660,12 @@ class ServingEngine:
         page group, the slots in it and the pages the steps fold (more
         than the steps where a step is a block: the latent read; counted
         like ``pool_writes``: after the dispatch, from the host's
-        mirror). A chunk names its one slot and where it starts. Beside
-        them, for K/V pages, ``read_rows`` / ``read_rows_live``: the rows
-        of a KV head's block that every page is folded into, and those
-        of them that are somebody's (``PagedKVPool.rows_a_read_block``)."""
+        mirror). A chunk names its one slot and where it starts."""
         work = self.pool.pages_read(rows, slots, starts)
         if work is None:
             return
-        attrs = dict(pool_reads=work[0], read_slots=work[1],
-                     pool_read_pages=work[2])
-        block = self.pool.rows_a_read_block(rows)
-        if block is not None:
-            attrs.update(read_rows=block[0], read_rows_live=block[1])
-        sp.set(**attrs)
+        sp.set(pool_reads=work[0], read_slots=work[1],
+               pool_read_pages=work[2])
 
     def _state_rows(self, running) -> tuple:
         """For a model with a recurrent state, the decode program's

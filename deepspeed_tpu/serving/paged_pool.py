@@ -54,7 +54,7 @@ stacked leaf whole, rewrites the pages its work list names and returns
 the leaf aliased — from the model's decode, verify and chunk steps for
 their own columns, and from :meth:`PagedKVPool._write_runs` for
 admitted rows and the kernel-off compositions. A page is stored in
-whole 128-lane tiles (``models.transformer_lm.page_lanes``: k/v
+whole 128-lane tiles (``models.kv_cache_spec.page_lanes``: k/v
 ``(L, num_pages, KV, cache_d, lanes)``), the one shape whose device
 layout XLA and a Mosaic operand agree on; no program of a serving step
 passes over a whole leaf.
@@ -83,7 +83,7 @@ and, for the window group, start at the first visible entry and mask
 positions ``<= i - sliding_window`` inside it. A model without layer
 kinds has exactly the single group it always had. What a ring does not
 compose with yet refuses at construction, in the words of the one table
-(``models/transformer_lm.py``, ``CACHE_REFUSALS``): the prefix cache (what
+(``models/cache_kinds.py``, ``CACHE_REFUSALS``): the prefix cache (what
 a hit means for pages that were recycled), cross-pool page transfer.
 
 Latent pages (PR 38): a model with latent attention
@@ -139,6 +139,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..inference.engine import pack_chunk_args, unpack_chunk_args
+from ..models.kv_cache_spec import page_lanes
 from ..ops import backend
 from .prefix_cache import PrefixCache
 from .slot_pool import SlotPool
@@ -933,27 +934,12 @@ class PagedKVPool(SlotPool):
         call gives it), one page of K/V."""
         if not self.spec.latent:
             return 1
-        from ..models.transformer_lm import page_lanes
         from ..ops.attention.latent_attention import call_rows, pages_a_step
 
         return pages_a_step(
             call_rows(count, self.spec.kv_heads), self.spec.latent,
             self.spec.latent_rank, page_lanes(self.page_size),
             self.spec.dtype)
-
-    def rows_a_read_block(self, count: int):
-        """``(rows, live)`` of ONE KV head's block in the K/V read of a
-        dispatch of ``count`` query rows a slot: the rows the kernel
-        folds a page (``paged_attention.block_rows``: whole sublane
-        tiles) and those of them somebody reads, ``rep x count``
-        (``read_rows`` / ``read_rows_live`` on the decode, verify and
-        chunk spans: how full the read's tiles are). ``None`` for the
-        latent read, whose block is every head's rows."""
-        if self.spec.latent:
-            return None
-        from ..ops.attention.paged_attention import block_rows
-
-        return block_rows(self.spec.rep, count), self.spec.rep * count
 
     def pages_read(self, count: int, slots=None, starts=None):
         """``(steps, slots, pages)`` of the kernel read's work list for a

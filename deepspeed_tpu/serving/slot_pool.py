@@ -3,7 +3,7 @@
 The pool owns ONE statically-shaped cache pytree in the exact layout the
 model's flax ``cache`` collection uses (``{"cache_store": {...}}`` with
 k/v ``(L, num_slots, KV, cache_d, max_seq_len)``), allocated through the
-module-declared :class:`~deepspeed_tpu.models.transformer_lm.KVCacheSpec`
+module-declared :class:`~deepspeed_tpu.models.kv_cache_spec.KVCacheSpec`
 — batch dimension = slots. Continuous batching then never changes a
 shape: admitting, retiring and reusing slots are all data movement
 inside the same buffers, so the jitted decode step compiles once and is

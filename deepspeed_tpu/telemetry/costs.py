@@ -389,7 +389,7 @@ def kv_hbm_report(pool) -> Dict[str, float]:
         per_token += spec.n_layer * spec.kv_heads * 2 * 4  # f32 scales
     paged = hasattr(pool, "num_pages")
     if paged:
-        from ..models.transformer_lm import page_lanes
+        from ..models.kv_cache_spec import page_lanes
 
         tokens = pool.num_pages * pool.page_size
         page_bytes = per_token * page_lanes(pool.page_size)

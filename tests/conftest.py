@@ -15,25 +15,52 @@ _flag = "--xla_force_host_platform_device_count=8"
 if _flag not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " " + _flag
 os.environ["JAX_PLATFORMS"] = "cpu"
+# The suite's seconds are compiles: ~30,000 programs, most of them run
+# once, and three fifths of a test's seconds inside XLA:CPU's compile (a
+# listener on jax's monitoring events over 1,050 cases: 14,957 compiles,
+# 2,033 of 3,451 s; tracing 13 %, lowering 17 %; ROADMAP D8). This process
+# compiles them without LLVM's optimisation passes and with the elemental
+# emitters in place of the MLIR fusion pipeline: a one-op program in 16 ms
+# where it took 33, a kernel in interpret mode a fifth sooner.
+# Whole runs of this tree on one machine (six workers, PR 58): 1,127 s
+# without the two flags, 820, 762 and 731 s with them (the parent 1,293 s).
+# XLA reads XLA_FLAGS once, at the first compile below; the two flags then
+# leave the environment, so what a test LAUNCHES (the launcher's workers,
+# the rehearsals of ``perf/tools/``, chip_smoke.py) compiles as a user's
+# process does and runs at full speed inside its timed windows.
+_LIGHT_COMPILES = {"xla_backend_optimization_level": 0,
+                   "xla_cpu_use_fusion_emitters": False}
+_USERS_COMPILES = {"xla_backend_optimization_level": 3,     # XLA's defaults
+                   "xla_cpu_use_fusion_emitters": True}
+_LIGHT_FLAGS = " ".join(f"--{name}={str(value).lower()}"
+                        for name, value in _LIGHT_COMPILES.items())
+_inherited = os.environ["XLA_FLAGS"]
+os.environ["XLA_FLAGS"] = _inherited + " " + _LIGHT_FLAGS
 
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+jax.jit(lambda: 0)()
+os.environ["XLA_FLAGS"] = _inherited
 
 import pytest  # noqa: E402
 
-# Files that one worker holds for minutes, by their seconds in one process
-# (ROADMAP D8; `--durations`). Under ``--dist loadfile`` xdist hands files
-# out by their NUMBER of tests, most first, so a file of one long test runs
-# last and five workers idle behind it (~50-100 s of a Tier-1 run that
-# stands two minutes from its clock). The order below is xdist's own, by
-# number of tests, with these files ahead of it by their cost; the tests a
-# worker runs, and their order inside a file, are what they were.
+# Files that one worker holds for minutes, by their seconds in a whole run
+# of PR 58's tree (six workers; ROADMAP D8; the run's junit file). Under
+# ``--dist loadfile`` xdist hands files out by their NUMBER of tests, most
+# first, so a file of one long test runs last and five workers idle behind
+# it. The order below is xdist's own, by number of tests, with these files
+# ahead of it by their cost; the tests a worker runs, and their order inside
+# a file, are what they were. Only these four: with every file over 150 s
+# listed (fifteen, before the light compiles), the heaviest files all ran
+# at once, each stretched by a quarter to a third (compile-heavy files use
+# several cores each), and the run took 1,251 s where this order took 1,226
+# (PR 58, two whole runs).
 _LONG_FILES = {
-    "tests/unit/ops/test_paged_attention.py": 350,
-    "tests/unit/accelerator/test_chip_path.py": 200,
-    "tests/unit/launcher/test_elastic_e2e.py": 119,
-    "tests/model/test_realtext_convergence.py": 110,
+    "tests/unit/ops/test_paged_attention.py": 298,
+    "tests/unit/accelerator/test_chip_path.py": 188,
+    "tests/model/test_realtext_convergence.py": 126,
+    "tests/unit/launcher/test_elastic_e2e.py": 101,
 }
 
 
@@ -73,6 +100,23 @@ def _bound_jax_compile_cache():
     as the price of bounding native compiler state."""
     yield
     jax.clear_caches()
+
+
+@pytest.fixture
+def light_compiles():
+    """``XLA_FLAGS`` for the workers of a test that launches processes of
+    its own and times nothing in them: they compile as this one does."""
+    return _LIGHT_FLAGS
+
+
+@pytest.fixture
+def users_compiles():
+    """``compiler_options`` under which one program compiles as a user's
+    process compiles it: for the few tests that hold the ROUNDINGS of two
+    different programs to each other (a tolerance of 1e-6; a parameter whose
+    gradient is zero but for rounding, through Adam), which the light
+    compiles above do not keep."""
+    return dict(_USERS_COMPILES)
 
 
 @pytest.fixture

@@ -7,11 +7,13 @@ track the stage-0 fp32 curve within tolerance and reach a clearly lower
 final loss than initial.
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.parallel import mesh as mesh_mod
+from tests.unit.kinds import engine_weights
 
 STEPS = 30
 SEQ = 32
@@ -47,7 +49,11 @@ def _run(config_overrides, seed=0):
         "seed": 1234,
     }
     config.update(config_overrides)
-    engine, _, _, _ = ds.initialize(model=GPT2LMHeadModel(cfg),
+    model = GPT2LMHeadModel(cfg)
+    # (every variant and the baseline start from the same weights)
+    params = engine_weights(
+        model, {"input_ids": jnp.zeros((2, SEQ), jnp.int32)})
+    engine, _, _, _ = ds.initialize(model=model, model_parameters=params,
                                     config=config)
     losses = []
     for batch in _data(engine.train_batch_size(), STEPS, seed):

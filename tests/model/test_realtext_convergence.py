@@ -25,10 +25,8 @@ import numpy as np
 import jax.numpy as jnp
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import (
-    TransformerLM,
-    transformer_config,
-)
+from deepspeed_tpu.models.lm_config import transformer_config
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.parallel import reset_mesh
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")

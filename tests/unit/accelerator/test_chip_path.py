@@ -168,7 +168,7 @@ def test_pool_kernels_compile_for_a_described_v5e_at_the_served_shape(
     tier; and XLA around it: stored in whole lane tiles the leaf goes in
     and comes out in one buffer, stored 64 wide it is copied whole to
     row-major and back."""
-    from deepspeed_tpu.models.transformer_lm import page_lanes
+    from deepspeed_tpu.models.kv_cache_spec import page_lanes
     from deepspeed_tpu.ops.attention.paged_attention import (
         paged_decode_attention, paged_write_columns, paged_write_runs)
 
@@ -533,8 +533,8 @@ def test_a_state_group_beside_pages_compiles_with_no_copy_of_a_leaf(
     627 MB of temporaries at 18 layers)."""
     import deepspeed_tpu as ds
     from deepspeed_tpu.inference.engine import pack_chunk_args
-    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                     transformer_config)
+    from deepspeed_tpu.models.lm_config import transformer_config
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
     from deepspeed_tpu.parallel import mesh
     from deepspeed_tpu.serving.paged_pool import PagedKVPool
 
@@ -745,8 +745,8 @@ def _zero_engine(family, **widths):
     """A bf16 ``TransformerLM`` of ``family`` over a vocabulary of 128 and
     an inference engine on its parameters, all zero."""
     import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                     transformer_config)
+    from deepspeed_tpu.models.lm_config import transformer_config
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     model = TransformerLM(transformer_config(
         family, vocab_size=128, dtype=jnp.bfloat16, **widths))
@@ -920,7 +920,7 @@ def test_attention_projections_read_the_stacked_leaf(
     that through the projection onto its weight, and the program made
     ``%constant_dynamic-slice_fusion = bf16[1,C,C']{2,1,0}`` (the layer's
     matrix out of the leaf) and ``%copy = bf16[1,C,C']{1,2,0}`` (transposed)
-    a layer a projection a step; ``transformer_lm._settled`` is what stops
+    a layer a projection a step; ``lm_parts._settled`` is what stops
     it. Not looked at: a latent layer's ``kv_b_proj``, a parameter and no
     ``Dense``, whose slice and copy are the batched product's own (the
     heads lie in the middle of the stored matrix; the einsum compiled alone

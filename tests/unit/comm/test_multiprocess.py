@@ -60,10 +60,8 @@ def _engine_worker(rank, world):
     import numpy as np
 
     import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     model = TransformerLM(TransformerConfig(
         vocab_size=64, n_embd=32, n_layer=2, n_head=4, max_seq_len=32))
@@ -96,10 +94,8 @@ def _checkpoint_worker(rank, world, ckpt_dir):
     import numpy as np
 
     import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     config = {"train_micro_batch_size_per_gpu": 2,
               "gradient_accumulation_steps": 1,
@@ -140,17 +136,20 @@ def _checkpoint_worker(rank, world, ckpt_dir):
 # ---------------------------------------------------------------------------
 # tests
 # ---------------------------------------------------------------------------
-def test_multiprocess_collectives():
-    run_distributed(_collectives_worker, world_size=2)
+def test_multiprocess_collectives(light_compiles):
+    run_distributed(_collectives_worker, world_size=2,
+                    env={"XLA_FLAGS": light_compiles})
 
 
-def test_multiprocess_engine_train():
-    run_distributed(_engine_worker, world_size=2)
+def test_multiprocess_engine_train(light_compiles):
+    run_distributed(_engine_worker, world_size=2,
+                    env={"XLA_FLAGS": light_compiles})
 
 
-def test_multiprocess_checkpoint_resume():
+def test_multiprocess_checkpoint_resume(light_compiles):
     with tempfile.TemporaryDirectory() as d:
-        run_distributed(_checkpoint_worker, world_size=2, payload=d)
+        run_distributed(_checkpoint_worker, world_size=2, payload=d,
+                        env={"XLA_FLAGS": light_compiles})
 
 
 def _onebit_wire_worker(rank, world):
@@ -160,10 +159,8 @@ def _onebit_wire_worker(rank, world):
     import numpy as np
 
     import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     model = TransformerLM(TransformerConfig(
         vocab_size=64, n_embd=32, n_layer=2, n_head=4, max_seq_len=32))
@@ -192,8 +189,9 @@ def _onebit_wire_worker(rank, world):
         {"wire_losses": [round(l, 5) for l in losses]}, "onebit wire losses")
 
 
-def test_multiprocess_onebit_compressed_wire():
-    run_distributed(_onebit_wire_worker, world_size=2)
+def test_multiprocess_onebit_compressed_wire(light_compiles):
+    run_distributed(_onebit_wire_worker, world_size=2,
+                    env={"XLA_FLAGS": light_compiles})
 
 
 def _param_offload_worker(rank, world):
@@ -204,10 +202,8 @@ def _param_offload_worker(rank, world):
     import numpy as np
 
     import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     model = TransformerLM(TransformerConfig(
         vocab_size=64, n_embd=32, n_layer=2, n_head=4, max_seq_len=32))
@@ -240,5 +236,6 @@ def _param_offload_worker(rank, world):
                                   "streamed param digest")
 
 
-def test_multiprocess_param_offload():
-    run_distributed(_param_offload_worker, world_size=2)
+def test_multiprocess_param_offload(light_compiles):
+    run_distributed(_param_offload_worker, world_size=2,
+                    env={"XLA_FLAGS": light_compiles})

@@ -10,10 +10,8 @@ import deepspeed_tpu as ds
 
 
 def _make_hybrid_engine():
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     cfg = TransformerConfig(vocab_size=128, n_layer=2, n_head=2, n_embd=32,
                             max_seq_len=64)

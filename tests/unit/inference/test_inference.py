@@ -14,11 +14,8 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import (
-    FAMILY_PRESETS,
-    TransformerLM,
-    transformer_config,
-)
+from deepspeed_tpu.models.lm_config import FAMILY_PRESETS, transformer_config
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.parallel import initialize_mesh
 
 TINY = dict(vocab_size=64, max_seq_len=48, n_embd=32, n_layer=2, n_head=4,
@@ -32,8 +29,10 @@ def _model(family, **kw):
 
 def _init(model, B=2, T=8, seed=0):
     ids = jax.random.randint(jax.random.PRNGKey(seed), (B, T), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
+    # (inside one jit: op by op a model's init is a hundred small compiles)
+    params = jax.jit(lambda: model.init(
+        {"params": jax.random.PRNGKey(1)}, ids,
+        method=model.logits))()["params"]
     return params, ids
 
 
@@ -43,19 +42,23 @@ def test_kv_cache_decode_matches_recompute(family):
     model, cfg = _model(family, **kw)
     params, ids = _init(model)
 
-    # full-context logits (no cache)
-    full = model.apply({"params": params}, ids, method=model.logits)
+    # full-context logits (no cache); each program compiled once
+    full = jax.jit(lambda p: model.apply({"params": p}, ids,
+                                         method=model.logits))(params)
 
     # prefill on the first 5 tokens, then decode the rest one by one
-    pre, vars_ = model.apply({"params": params}, ids[:, :5],
-                             method=model.prefill, mutable=["cache"])
+    pre, vars_ = jax.jit(lambda p: model.apply(
+        {"params": p}, ids[:, :5], method=model.prefill,
+        mutable=["cache"]))(params)
     np.testing.assert_allclose(np.asarray(pre), np.asarray(full[:, :5]),
                                rtol=2e-4, atol=2e-4)
     cache = vars_["cache"]
+    decode = jax.jit(lambda p, cache, token, t: model.apply(
+        {"params": p, "cache": cache}, token, t, method=model.decode,
+        mutable=["cache"]))
     for t in range(5, ids.shape[1]):
-        step, vars_ = model.apply(
-            {"params": params, "cache": cache}, ids[:, t:t + 1],
-            jnp.asarray(t, jnp.int32), method=model.decode, mutable=["cache"])
+        step, vars_ = decode(params, cache, ids[:, t:t + 1],
+                             jnp.asarray(t, jnp.int32))
         cache = vars_["cache"]
         np.testing.assert_allclose(np.asarray(step[:, 0]),
                                    np.asarray(full[:, t]),

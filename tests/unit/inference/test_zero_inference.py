@@ -9,11 +9,9 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.zero_inference import ZeroInferenceEngine
-from deepspeed_tpu.models.transformer_lm import (
-    TransformerConfig,
-    TransformerLM,
-    transformer_config,
-)
+from deepspeed_tpu.models.lm_config import (TransformerConfig,
+                                            transformer_config)
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 
 
 def _model_and_params(family="gpt2", n_layer=3):

@@ -45,10 +45,8 @@ import deepspeed_tpu as ds
 
 ds.init_distributed()
 
-from deepspeed_tpu.models.transformer_lm import (
-    TransformerConfig,
-    TransformerLM,
-)
+from deepspeed_tpu.models.lm_config import TransformerConfig
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 
 GLOBAL_BATCH = 4
 ckpt = os.path.join(work, "ckpt")
@@ -66,9 +64,9 @@ def probe_loss(engine):
     rng = np.random.default_rng(7)
     batch = {"input_ids": rng.integers(0, 64, (GLOBAL_BATCH, 32)).astype(np.int32)}
     params = jax.device_get(engine.state["params"])
-    return float(engine.module.apply(
-        {"params": params}, {"input_ids": np.asarray(batch["input_ids"])},
-        deterministic=True))
+    # (one program: op by op the scan's body is ~100 small compiles)
+    return float(jax.jit(lambda p, b: engine.module.apply(
+        {"params": p}, b, deterministic=True))(params, batch))
 
 
 def record(payload):
@@ -79,8 +77,14 @@ def record(payload):
 
 model = TransformerLM(TransformerConfig(
     vocab_size=64, n_embd=32, n_layer=2, n_head=4, max_seq_len=32))
+# the same weights on every rank, made inside one jit (left to the engine,
+# 6 s of the 17 a worker lives; three workers' lives, one after another,
+# are the test's seconds)
+from tests.unit.kinds import engine_weights
+
+params = engine_weights(model, make_batch(0))
 engine, _, _, _ = ds.initialize(
-    model=model,
+    model=model, model_parameters=params,
     config={"train_micro_batch_size_per_gpu": GLOBAL_BATCH // world,
             "gradient_accumulation_steps": 1,
             "zero_optimization": {"stage": 1},
@@ -135,20 +139,21 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _launch(script, work, mode, total, kill_at, nprocs, port, elastic=False):
+def _launch(script, work, mode, total, kill_at, nprocs, port, flags,
+            elastic=False):
     cmd = [sys.executable, "-u", "-m", "deepspeed_tpu.launcher.runner",
            "--num_gpus", str(nprocs), "--master_port", str(port)]
     if elastic:
         cmd += ["--elastic_training", "--max_elastic_restarts", "2"]
     cmd += [script, work, mode, str(total), str(kill_at)]
     env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)  # no virtual-mesh leak into real procs
+    env["XLA_FLAGS"] = flags    # (no virtual-mesh leak into real procs)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.run(cmd, capture_output=True, text=True, timeout=900,
                           cwd=REPO_ROOT, env=env)
 
 
-def test_elastic_loop_end_to_end(tmp_path):
+def test_elastic_loop_end_to_end(tmp_path, light_compiles):
     script = tmp_path / "elastic_train.py"
     script.write_text(textwrap.dedent(_TRAIN_SCRIPT))
     work = str(tmp_path)
@@ -156,7 +161,7 @@ def test_elastic_loop_end_to_end(tmp_path):
     # Phase A: 2 workers, elastic agent on; rank 1 dies after step 2 on the
     # first attempt; the agent restarts and training resumes to step 4.
     proc = _launch(str(script), work, "train", 4, 2, nprocs=2, port=_free_port(),
-                   elastic=True)
+                   flags=light_compiles, elastic=True)
     assert proc.returncode == 0, proc.stderr[-4000:]
 
     starts0 = int((tmp_path / "starts_rank0").read_text())
@@ -177,7 +182,7 @@ def test_elastic_loop_end_to_end(tmp_path):
 
     # Phase B: relaunch at world size 1 from the universal checkpoint.
     proc = _launch(str(script), work, "resume_universal", 6, -1, nprocs=1,
-                   port=_free_port())
+                   port=_free_port(), flags=light_compiles)
     assert proc.returncode == 0, proc.stderr[-4000:]
 
     probe_b = json.loads((tmp_path / "probe_after_remesh.json").read_text())

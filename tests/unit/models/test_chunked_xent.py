@@ -12,8 +12,14 @@ def _loss_and_grads(model, params, batch):
     def f(p):
         return model.apply({"params": p}, batch)
 
-    loss, grads = jax.value_and_grad(f)(params)
+    # (one program: op by op the layers' backward is hundreds of compiles)
+    loss, grads = jax.jit(jax.value_and_grad(f))(params)
     return float(loss), grads
+
+
+def _init(model, batch):
+    return jax.jit(lambda: model.init({"params": jax.random.PRNGKey(0)},
+                                      batch))()["params"]
 
 
 def _assert_tree_close(a, b, rtol, atol):
@@ -40,7 +46,7 @@ def test_gpt2_chunked_matches_dense(chunk):
     batch = {"input_ids": jnp.asarray(ids), "labels": jnp.asarray(labels)}
 
     dense = GPT2LMHeadModel(cfg)
-    params = dense.init({"params": jax.random.PRNGKey(0)}, batch)["params"]
+    params = _init(dense, batch)
     l_dense, g_dense = _loss_and_grads(dense, params, batch)
 
     chunked = GPT2LMHeadModel(dataclasses.replace(cfg, loss_chunk=chunk))
@@ -54,10 +60,8 @@ def test_gpt2_chunked_matches_dense(chunk):
 def test_transformer_lm_chunked_matches_dense(tied):
     import dataclasses
 
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     cfg = TransformerConfig(vocab_size=97, max_seq_len=16, n_embd=32,
                             n_layer=2, n_head=2, dtype=jnp.float32,
@@ -67,15 +71,14 @@ def test_transformer_lm_chunked_matches_dense(tied):
     batch = {"input_ids": jnp.asarray(ids)}
 
     dense = TransformerLM(cfg)
-    params = dense.init({"params": jax.random.PRNGKey(0)}, batch)["params"]
+    params = _init(dense, batch)
     l_dense, g_dense = _loss_and_grads(dense, params, batch)
 
     chunked = TransformerLM(dataclasses.replace(cfg, loss_chunk=7))
     # from-scratch init of the CHUNKED model must create the full param
     # tree (incl. the untied lm_head the streaming path reads without
     # calling) — same structure as the dense init
-    params_c = chunked.init({"params": jax.random.PRNGKey(0)},
-                            batch)["params"]
+    params_c = jax.eval_shape(lambda: _init(chunked, batch))
     assert (jax.tree_util.tree_structure(params_c)
             == jax.tree_util.tree_structure(params))
     l_chunk, g_chunk = _loss_and_grads(chunked, params, batch)
@@ -90,10 +93,8 @@ def test_chunked_int8_guard_is_untied_only():
     can't read); tied embeddings are never quantized and must pass."""
     import dataclasses
 
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     base = TransformerConfig(vocab_size=64, max_seq_len=16, n_embd=32,
                              n_layer=1, n_head=2, dtype=jnp.float32,

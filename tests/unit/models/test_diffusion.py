@@ -71,13 +71,13 @@ def test_unet_denoise_step(clip_cfg):
     t = jnp.asarray([1, 500])
     context = jnp.asarray(np.random.default_rng(1)
                           .standard_normal((2, 8, 32)), jnp.float32)
-    params = unet.init(jax.random.PRNGKey(0), latents, t, context)
-    out = jax.jit(lambda p, l, tt, c: unet.apply(p, l, tt, c))(
-        params, latents, t, context)
+    params = jax.jit(unet.init)(jax.random.PRNGKey(0), latents, t, context)
+    denoise = jax.jit(lambda p, l, tt, c: unet.apply(p, l, tt, c))
+    out = denoise(params, latents, t, context)
     assert out.shape == latents.shape
     assert np.isfinite(np.asarray(out)).all()
     # conditioning matters: different context -> different noise prediction
-    out2 = unet.apply(params, latents, t, context + 1.0)
+    out2 = denoise(params, latents, t, context + 1.0)
     assert not np.allclose(np.asarray(out), np.asarray(out2))
 
 

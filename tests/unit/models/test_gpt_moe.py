@@ -38,8 +38,10 @@ def test_moe_gpt_trains():
 def test_moe_blocks_alternate():
     model = GPTMoEModel(_tiny(moe_every=2))
     b = {"input_ids": jnp.ones((2, 8), jnp.int32)}
-    params = model.init({"params": jax.random.PRNGKey(0),
-                         "gating": jax.random.PRNGKey(1)}, b)["params"]
+    # (the tree alone: shapes, nothing computed)
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0),
+         "gating": jax.random.PRNGKey(1)}, b))["params"]
     # blocks 1 and 3 are MoE, 0 and 2 dense
     assert "moe" in params["block_1"] and "moe" in params["block_3"]
     assert "mlp_fc" in params["block_0"] and "mlp_fc" in params["block_2"]
@@ -48,8 +50,9 @@ def test_moe_blocks_alternate():
 def test_pyramid_experts():
     model = GPTMoEModel(_tiny(num_experts=[2, 4]))
     b = {"input_ids": jnp.ones((2, 8), jnp.int32)}
-    params = model.init({"params": jax.random.PRNGKey(0),
-                         "gating": jax.random.PRNGKey(1)}, b)["params"]
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0),
+         "gating": jax.random.PRNGKey(1)}, b))["params"]
     g1 = params["block_1"]["moe"]["gate"]["kernel"]
     g3 = params["block_3"]["moe"]["gate"]["kernel"]
     assert g1.shape[-1] == 2 and g3.shape[-1] == 4

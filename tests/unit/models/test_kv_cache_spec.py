@@ -8,11 +8,9 @@ import dataclasses
 import jax.numpy as jnp
 import pytest
 
-from deepspeed_tpu.models.transformer_lm import (
-    _PACK_DISABLED_WARNED,
-    TransformerConfig,
-    kv_cache_spec,
-)
+from deepspeed_tpu.models.kv_cache_spec import (_PACK_DISABLED_WARNED,
+                                                kv_cache_spec)
+from deepspeed_tpu.models.lm_config import TransformerConfig
 
 # n_embd=30 / n_head=2 -> head_dim=15, not a multiple of 4
 ODD = dict(vocab_size=64, max_seq_len=16, n_embd=30, n_layer=1, n_head=2,
@@ -56,8 +54,8 @@ def test_remat_hands_a_block_its_layer_and_its_experts(routed):
     import jax
     import numpy as np
 
-    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                     transformer_config)
+    from deepspeed_tpu.models.lm_config import transformer_config
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     sizes = dict(vocab_size=128, max_seq_len=64, n_embd=32, n_layer=4,
                  n_head=4, n_kv_head=2, head_size=8, ffn_dim=16,
@@ -70,14 +68,19 @@ def test_remat_hands_a_block_its_layer_and_its_experts(routed):
     for remat in (False, True):
         model = TransformerLM(transformer_config("mellum", remat=remat,
                                                  **sizes))
-        params = model.init(jax.random.PRNGKey(0), ids,
-                            method=model.logits)["params"]
-        logits = model.apply({"params": params}, ids, method=model.logits)
-        _, cache = model.apply({"params": params}, ids, method=model.prefill,
-                               mutable=["cache"])
-        step, _ = model.apply({"params": params, "cache": cache["cache"]},
-                              ids[:, :1], jnp.asarray(12),
-                              method=model.decode, mutable=["cache"])
+        def run(model=model):
+            params = model.init(jax.random.PRNGKey(0), ids,
+                                method=model.logits)["params"]
+            logits = model.apply({"params": params}, ids,
+                                 method=model.logits)
+            _, cache = model.apply({"params": params}, ids,
+                                   method=model.prefill, mutable=["cache"])
+            step, _ = model.apply(
+                {"params": params, "cache": cache["cache"]}, ids[:, :1],
+                jnp.asarray(12), method=model.decode, mutable=["cache"])
+            return logits, step
+
+        logits, step = jax.jit(run)()    # (one program, not op by op)
         outs.append((np.asarray(logits), np.asarray(step)))
     np.testing.assert_array_equal(outs[0][0], outs[1][0])
     np.testing.assert_array_equal(outs[0][1], outs[1][1])
@@ -95,8 +98,8 @@ def test_a_state_group_beside_kv_at_the_published_widths():
     a slot's state over the layers to the byte."""
     import jax
 
-    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                     transformer_config)
+    from deepspeed_tpu.models.lm_config import transformer_config
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     cfg = transformer_config(
         "granite-hybrid", vocab_size=100352, max_seq_len=16384, n_embd=2048,
@@ -153,7 +156,7 @@ def test_a_state_group_beside_kv_at_the_published_widths():
 ])
 def test_mamba_and_attention_layers_come_as_a_pattern_or_as_runs(layer_types,
                                                                  why):
-    from deepspeed_tpu.models.transformer_lm import transformer_config
+    from deepspeed_tpu.models.lm_config import transformer_config
 
     def build(**more):
         return transformer_config(
@@ -173,7 +176,7 @@ def test_mamba_and_attention_layers_come_as_a_pattern_or_as_runs(layer_types,
 
 
 def test_mamba_layers_need_their_widths():
-    from deepspeed_tpu.models.transformer_lm import transformer_config
+    from deepspeed_tpu.models.lm_config import transformer_config
 
     with pytest.raises(ValueError, match="mamba_n_heads"):
         transformer_config(
@@ -207,7 +210,7 @@ def test_a_state_layers_tail_write_is_the_scatter_it_replaced(rows, dtype):
     import jax
     import numpy as np
 
-    from deepspeed_tpu.models.transformer_lm import _SLAB_FROM, _write_rows
+    from deepspeed_tpu.models.state_layers import _SLAB_FROM, _write_rows
 
     assert _SLAB_FROM == 8      # (the cases above stand on both sides of it)
     L, R, W = 3, 8, 24
@@ -228,3 +231,44 @@ def test_a_state_layers_tail_write_is_the_scatter_it_replaced(rows, dtype):
         np.testing.assert_array_equal(
             np.asarray(got, np.float32)[untouched],
             np.asarray(leaf, np.float32)[untouched])
+
+
+# the modules a layer kind is written in stand BELOW transformer_lm.py: none
+# imports it, at its top or on the way (ROADMAP.md, D14)
+BELOW = ["lm_config", "cache_kinds", "kv_cache_spec", "lm_parts",
+         "attention_layers", "state_layers", "lightning_sparse"]
+
+
+@pytest.fixture(scope="module")
+def imported_with():
+    """``{module: the deepspeed_tpu.models modules its import loaded}``,
+    taken in ONE fresh interpreter (whose cost is importing jax and flax):
+    the package's models are forgotten between one import and the next."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import importlib, json, sys\n"
+        "out = {}\n"
+        f"for name in {BELOW!r}:\n"
+        "    importlib.import_module('deepspeed_tpu.models.' + name)\n"
+        "    mine = [m for m in sys.modules\n"
+        "            if m.startswith('deepspeed_tpu.models.')]\n"
+        "    out[name] = [m.rpartition('.')[2] for m in mine]\n"
+        "    for m in mine:\n"
+        "        del sys.modules[m]\n"
+        "print(json.dumps(out))\n")
+    root = os.path.join(os.path.dirname(__file__), "..", "..", "..")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=300, cwd=root)
+    assert proc.returncode == 0, proc.stderr
+    return json.loads(proc.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("module", BELOW)
+def test_a_layer_kinds_module_does_not_import_transformer_lm(imported_with,
+                                                             module):
+    loaded = imported_with[module]
+    assert module in loaded and "transformer_lm" not in loaded, loaded

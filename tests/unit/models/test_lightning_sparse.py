@@ -21,8 +21,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.models.transformer_lm import (TransformerLM,  # noqa: E402
-                                                 transformer_config)
+from deepspeed_tpu.models.lm_config import transformer_config
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.ops import lightning  # noqa: E402
 from deepspeed_tpu.ops import state_space as ss  # noqa: E402
 from deepspeed_tpu.ops.attention import sparse_index as si  # noqa: E402
@@ -50,8 +50,8 @@ def toy():
     cfg = _config()
     model = TransformerLM(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(1), (1, T), 0, 97)
-    params = model.init(jax.random.PRNGKey(0), {"input_ids": ids[:, :8]})[
-        "params"]
+    params = jax.jit(lambda: model.init(
+        jax.random.PRNGKey(0), {"input_ids": ids[:, :8]}))()["params"]
     flat, tree = jax.tree_util.tree_flatten_with_path(params)
     keys = jax.random.split(jax.random.PRNGKey(2), len(flat))
     params = jax.tree_util.tree_unflatten(tree, [

@@ -9,12 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models.transformer_lm import (CachedAttention,
-                                                 ShortConvMixer,
-                                                 TransformerLM,
-                                                 _conv_after_tail,
-                                                 apply_rotary,
-                                                 transformer_config)
+from deepspeed_tpu.models.attention_layers import CachedAttention
+from deepspeed_tpu.models.lm_config import transformer_config
+from deepspeed_tpu.models.lm_parts import apply_rotary
+from deepspeed_tpu.models.state_layers import ShortConvMixer, _conv_after_tail
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 
 C, K, T = 16, 3, 11
 
@@ -153,18 +152,24 @@ def test_a_model_decodes_what_its_full_forward_computes():
     model = TransformerLM(cfg)
     ids = jnp.asarray(np.random.default_rng(0).integers(1, 64, (2, 14)),
                       jnp.int32)
-    params = model.init(jax.random.PRNGKey(1), ids[:, :8],
-                        method=model.logits)["params"]
-    want = model.apply({"params": params}, ids, method=model.logits)
-    logits, vars_ = model.apply({"params": params}, ids[:, :9],
-                                method=model.prefill, mutable=["cache"])
+    # (each program traced and compiled once: op by op the four layers are
+    # hundreds of small compiles)
+    params = jax.jit(lambda: model.init(
+        jax.random.PRNGKey(1), ids[:, :8], method=model.logits))()["params"]
+    want = jax.jit(lambda p: model.apply({"params": p}, ids,
+                                         method=model.logits))(params)
+    logits, vars_ = jax.jit(lambda p: model.apply(
+        {"params": p}, ids[:, :9], method=model.prefill,
+        mutable=["cache"]))(params)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want[:, :9]),
                                atol=2e-5)
     assert set(vars_["cache"]["cache_store"]) == {"conv", "k", "v", "index"}
+    decode = jax.jit(lambda p, cache, token, t: model.apply(
+        {"params": p, "cache": cache}, token, t, method=model.decode,
+        mutable=["cache"]))
     for t in range(9, 14):
-        logits, vars_ = model.apply(
-            {"params": params, "cache": vars_["cache"]}, ids[:, t:t + 1],
-            jnp.int32(t), method=model.decode, mutable=["cache"])
+        logits, vars_ = decode(params, vars_["cache"], ids[:, t:t + 1],
+                               jnp.int32(t))
         np.testing.assert_allclose(np.asarray(logits[:, 0]),
                                    np.asarray(want[:, t]), atol=2e-5)
 

@@ -14,10 +14,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.transformer_lm import (
-    TransformerLM,
-    transformer_config,
-)
+from deepspeed_tpu.models.lm_config import transformer_config
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 
 _TINY = dict(vocab_size=64, n_embd=32, n_layer=1, n_head=2,
              max_seq_len=32, dtype=jnp.float32)
@@ -40,12 +38,11 @@ def test_flash_forward_and_grads_match_einsum():
     params = m_e.init({"params": jax.random.PRNGKey(0)}, ids,
                       method=m_e.logits)["params"]
 
-    l_e = float(_loss(m_e, params, ids))
-    l_f = float(_loss(m_f, params, ids))
-    assert abs(l_e - l_f) < 5e-3, (l_e, l_f)
-
-    g_e = jax.grad(lambda p: _loss(m_e, p, ids))(params)
-    g_f = jax.grad(lambda p: _loss(m_f, p, ids))(params)
+    # (loss and grads as one program a model, not op by op)
+    (l_e, g_e), (l_f, g_f) = (
+        jax.jit(jax.value_and_grad(lambda p, m=m: _loss(m, p, ids)))(params)
+        for m in (m_e, m_f))
+    assert abs(float(l_e) - float(l_f)) < 5e-3, (l_e, l_f)
     diffs = jax.tree_util.tree_map(
         lambda a, b: float(jnp.abs(a - b).max()), g_e, g_f)
     assert max(jax.tree_util.tree_leaves(diffs)) < 5e-3

@@ -132,8 +132,8 @@ def test_the_model_holds_its_share_and_counts_what_ran():
     """``experts_held`` through ``TransformerLM``: the expert leaves are
     (routed layers, held, ...), the router keeps its width, and a call's
     counts are of the held experts beside every assignment made."""
-    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                     transformer_config)
+    from deepspeed_tpu.models.lm_config import transformer_config
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     sizes = dict(vocab_size=64, max_seq_len=32, n_embd=32, n_layer=3,
                  n_head=4, ffn_dim=16, n_experts=16, experts_per_token=4,

@@ -98,10 +98,8 @@ def test_pick_block_s():
 def test_model_decode_kernel_matches_jnp_path():
     """CachedAttention with decode_kernel on vs off: same generation."""
     import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     prompts = np.arange(6, dtype=np.int32)[None] % 32
 
@@ -211,10 +209,8 @@ def test_model_int8_kv_cache_generates_same_tokens(kernel_mode, packed):
     noise below the argmax margin) on both the fused-kernel and einsum
     decode paths."""
     import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     prompts = np.arange(6, dtype=np.int32)[None] % 32
 
@@ -304,10 +300,8 @@ def test_prefill_last_matches_full_prefill():
     prefill and logits equal to its last row — sampling sees no
     difference, only the (B, T, V) prompt-logits allocation disappears."""
     import deepspeed_tpu as ds
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     prompts = jnp.asarray(np.arange(7, dtype=np.int32)[None] % 32)
     cfg = TransformerConfig(vocab_size=32, max_seq_len=64, n_embd=64,
@@ -336,10 +330,8 @@ def test_packed_chunked_decode_matches_unpacked():
     logits must match the plain-int8 cache bit for bit (same quantized
     rows, the fallback unpacks the container)."""
     import deepspeed_tpu  # noqa: F401  (path setup)
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     prompts = jnp.asarray(np.arange(7, dtype=np.int32)[None] % 32)
     chunk = jnp.asarray([[3, 1, 4]], jnp.int32)

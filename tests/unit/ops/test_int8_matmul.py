@@ -148,10 +148,8 @@ def test_engine_int8_compute_tier():
     """dtype=int8 on a TransformerLM swaps Dense -> QuantDense: int8
     kernels in the engine param tree, logits tracking the bf16 engine."""
     import deepspeed_tpu
-    from deepspeed_tpu.models.transformer_lm import (
-        TransformerLM,
-        transformer_config,
-    )
+    from deepspeed_tpu.models.lm_config import transformer_config
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     cfg = transformer_config("llama", vocab_size=256, n_embd=128, n_layer=2,
                              n_head=4, max_seq_len=64)

@@ -184,8 +184,8 @@ def test_latent_module_absorbed_equals_expanded_through_a_dense_cache():
     """``LatentAttention`` itself: the no-cache forward (expanded) against
     a prefill of 9 tokens (expanded, rows stored) and 7 decode steps
     (absorbed against the stored rows), float32."""
-    from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                     transformer_config)
+    from deepspeed_tpu.models.lm_config import transformer_config
+    from deepspeed_tpu.models.transformer_lm import TransformerLM
 
     cfg = transformer_config(
         "moonlight", vocab_size=64, max_seq_len=32, n_embd=32, n_layer=2,
@@ -195,17 +195,22 @@ def test_latent_module_absorbed_equals_expanded_through_a_dense_cache():
         dtype=jnp.float32)
     model = TransformerLM(cfg)
     ids = jnp.asarray(np.random.default_rng(1).integers(1, 64, (2, 16)))
-    params = model.init(jax.random.PRNGKey(0), ids, method=model.logits)[
-        "params"]
-    full = model.apply({"params": params}, ids, method=model.logits)
-    out, vars_ = model.apply({"params": params}, ids[:, :9],
-                             method=model.prefill, mutable=["cache"])
+    # (each program traced and compiled once: op by op the two layers'
+    # scans are hundreds of small compiles)
+    params = jax.jit(lambda: model.init(
+        jax.random.PRNGKey(0), ids, method=model.logits))()["params"]
+    full = jax.jit(lambda p: model.apply({"params": p}, ids,
+                                         method=model.logits))(params)
+    out, vars_ = jax.jit(lambda p: model.apply(
+        {"params": p}, ids[:, :9], method=model.prefill,
+        mutable=["cache"]))(params)
     np.testing.assert_allclose(out, full[:, :9], atol=ATOL)
     cache = vars_["cache"]
     assert set(cache["cache_store"]) == {"c", "index"}
+    decode = jax.jit(lambda p, cache, token, t: model.apply(
+        {"params": p, "cache": cache}, token, t, method=model.decode,
+        mutable=["cache"]))
     for t in range(9, 16):
-        out, vars_ = model.apply(
-            {"params": params, "cache": cache}, ids[:, t:t + 1],
-            jnp.asarray(t), method=model.decode, mutable=["cache"])
+        out, vars_ = decode(params, cache, ids[:, t:t + 1], jnp.asarray(t))
         cache = vars_["cache"]
         np.testing.assert_allclose(out[:, 0], full[:, t], atol=ATOL)

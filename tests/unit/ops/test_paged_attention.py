@@ -1161,7 +1161,7 @@ def test_pages_stored_in_whole_lane_tiles_read_and_write_the_same(tier):
     (``page_lanes``) and tells the kernels the page size: the write
     touches those lanes alone and the read answers bit for bit what it
     answers on the unpadded leaf."""
-    from deepspeed_tpu.models.transformer_lm import page_lanes
+    from deepspeed_tpu.models.kv_cache_spec import page_lanes
 
     dtype, Dc = _WRITE_TIERS[tier]
     rng = np.random.default_rng(34)

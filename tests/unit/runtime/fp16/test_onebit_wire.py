@@ -19,7 +19,9 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
+from deepspeed_tpu.models.lm_config import TransformerConfig
+from deepspeed_tpu.models.transformer_lm import TransformerLM
+from tests.unit.kinds import engine_weights
 
 WORLD = 8
 FREEZE = 3
@@ -46,6 +48,15 @@ def _model():
         vocab_size=128, n_embd=32, n_layer=2, n_head=4, max_seq_len=32))
 
 
+def _initialize(**kw):
+    """``ds.initialize`` over ``_model()``, its weights made inside one jit
+    (``engine_weights``; every case builds one to four engines)."""
+    model = _model()
+    params = engine_weights(
+        model, {"input_ids": jnp.zeros((8, 32), jnp.int32)})
+    return ds.initialize(model=model, model_parameters=params, **kw)
+
+
 def _batches(n, seed=0):
     rng = np.random.default_rng(seed)
     return [{"input_ids": rng.integers(0, 128, (16, 32)).astype(np.int32)}
@@ -57,8 +68,7 @@ def _batches(n, seed=0):
     ("int8", 3.0, 5.0),     # fallback: one sign per byte
 ])
 def test_comm_bytes_drop_at_freeze_boundary(packing, lo, hi):
-    engine, _, _, _ = ds.initialize(model=_model(),
-                                    config=_config(packing=packing))
+    engine, _, _, _ = _initialize(config=_config(packing=packing))
     dense, compressed = [], []
     for i, b in enumerate(_batches(6)):
         engine.train_batch(batch=b)
@@ -73,8 +83,7 @@ def test_comm_bytes_drop_at_freeze_boundary(packing, lo, hi):
 @pytest.mark.parametrize("packing,dtype_tag", [("1bit", "u8"),
                                                ("int8", "s8")])
 def test_compiled_step_contains_packed_collectives(packing, dtype_tag):
-    engine, _, _, _ = ds.initialize(model=_model(),
-                                    config=_config(packing=packing))
+    engine, _, _, _ = _initialize(config=_config(packing=packing))
     b = _batches(1)[0]
     stacked = engine._stack_micro_batches(b)
     if engine.state is None:
@@ -96,8 +105,8 @@ def test_convergence_through_freeze_boundary():
     batches = _batches(24, seed=1)
 
     def run(backend):
-        engine, _, _, _ = ds.initialize(
-            model=_model(), config=_config(freeze_step=6, backend=backend))
+        engine, _, _, _ = _initialize(
+            config=_config(freeze_step=6, backend=backend))
         return [float(engine.train_batch(batch=b)) for b in batches]
 
     wired = run("compressed")
@@ -111,7 +120,7 @@ def test_convergence_through_freeze_boundary():
 
 
 def test_state_has_per_rank_error_buffers():
-    engine, _, _, _ = ds.initialize(model=_model(), config=_config())
+    engine, _, _, _ = _initialize(config=_config())
     engine.train_batch(batch=_batches(1)[0])
     ob = engine.state["onebit"]
     n_pad = ob["m"].shape[0]
@@ -123,9 +132,9 @@ def test_state_has_per_rank_error_buffers():
 
 def test_rejected_configs():
     with pytest.raises(ValueError, match="ZeRO stage"):
-        ds.initialize(model=_model(), config=_config(stage=2))
+        _initialize(config=_config(stage=2))
     with pytest.raises(ValueError, match="onebit_packing"):
-        ds.initialize(model=_model(), config=_config(packing="2bit"))
+        _initialize(config=_config(packing="2bit"))
 
 
 def test_zero_stage1_sharded_state_and_convergence():
@@ -135,8 +144,8 @@ def test_zero_stage1_sharded_state_and_convergence():
     batches = _batches(12, seed=3)
 
     def run(stage):
-        engine, _, _, _ = ds.initialize(
-            model=_model(), config=_config(freeze_step=4, stage=stage))
+        engine, _, _, _ = _initialize(
+            config=_config(freeze_step=4, stage=stage))
         losses = [float(engine.train_batch(batch=b)) for b in batches]
         return losses, engine
 
@@ -163,8 +172,7 @@ def test_zero_stage1_sharded_state_and_convergence():
 def test_onebit_checkpoint_roundtrip(tmp_path):
     """Momentum + error buffers (and the stage-1 sharded master) survive
     save/load — a resume must not silently re-zero the exchange."""
-    engine, _, _, _ = ds.initialize(model=_model(),
-                                    config=_config(stage=1))
+    engine, _, _, _ = _initialize(config=_config(stage=1))
     batches = _batches(FREEZE + 2, seed=5)
     for b in batches:
         engine.train_batch(batch=b)
@@ -172,7 +180,7 @@ def test_onebit_checkpoint_roundtrip(tmp_path):
     m_before = np.asarray(engine.state["onebit"]["m"])
     l_next = float(engine.train_batch(batch=batches[0]))
 
-    eng2, _, _, _ = ds.initialize(model=_model(), config=_config(stage=1))
+    eng2, _, _, _ = _initialize(config=_config(stage=1))
     eng2.train_batch(batch=batches[0])  # build state
     eng2.load_checkpoint(str(tmp_path))
     np.testing.assert_allclose(np.asarray(eng2.state["onebit"]["m"]),
@@ -183,7 +191,7 @@ def test_onebit_checkpoint_roundtrip(tmp_path):
     # PARTIAL restore (no optimizer states): the stage-1 sharded master
     # must be re-seeded from the loaded weights — a stale init-time
     # master would silently reset the model on the next step
-    eng3, _, _, _ = ds.initialize(model=_model(), config=_config(stage=1))
+    eng3, _, _, _ = _initialize(config=_config(stage=1))
     eng3.train_batch(batch=batches[0])  # build state
     eng3.load_checkpoint(str(tmp_path), load_optimizer_states=False)
     # the step loss is computed on the PRE-update params, so a correct
@@ -202,14 +210,14 @@ def test_wire_composes_with_tensor_parallelism(stage):
     and the packed collectives must still be in the HLO."""
     from deepspeed_tpu.parallel import initialize_mesh
     from deepspeed_tpu.parallel import mesh as mesh_mod
-    from deepspeed_tpu.models.transformer_lm import transformer_sharding_rules
+    from deepspeed_tpu.models.lm_config import transformer_sharding_rules
     from deepspeed_tpu.runtime.zero.policy import ShardingRules
 
     batches = _batches(10, seed=7)
 
     def run(mesh, rules=None):
-        engine, _, _, _ = ds.initialize(
-            model=_model(), config=_config(freeze_step=4, stage=stage),
+        engine, _, _, _ = _initialize(
+            config=_config(freeze_step=4, stage=stage),
             sharding_rules=rules, mesh=mesh)
         losses = [float(engine.train_batch(batch=b)) for b in batches]
         return losses, engine
@@ -242,7 +250,7 @@ def test_wire_composes_with_tensor_parallelism(stage):
 
 def test_compression_stage_actually_compresses():
     """After freeze, worker error becomes non-zero (compression residual)."""
-    engine, _, _, _ = ds.initialize(model=_model(), config=_config())
+    engine, _, _, _ = _initialize(config=_config())
     for b in _batches(FREEZE + 2):
         engine.train_batch(batch=b)
     we = np.asarray(engine.state["onebit"]["we"])

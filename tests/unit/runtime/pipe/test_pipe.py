@@ -259,8 +259,8 @@ def test_pipeline_transformer_block_layerspec():
     kv_cache, ...), returning (x, new_cache) — must work as a
     LayerSpec block: the executors detect the decode_det call mode and
     unpack the tuple return."""
-    from deepspeed_tpu.models.transformer_lm import (TransformerBlock,
-                                                     TransformerConfig)
+    from deepspeed_tpu.models.lm_config import TransformerConfig
+    from deepspeed_tpu.models.transformer_lm import TransformerBlock
 
     cfg = TransformerConfig(vocab_size=64, max_seq_len=16, n_embd=32,
                             n_layer=4, n_head=4, dtype=jnp.float32)

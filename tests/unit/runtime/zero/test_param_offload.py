@@ -21,14 +21,19 @@ import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import (
-    TransformerLM,
-    transformer_config,
-)
+from deepspeed_tpu.models.lm_config import transformer_config
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.parallel import reset_mesh
+from tests.unit.kinds import engine_weights
 
 _MODEL = dict(vocab_size=128, n_embd=32, n_layer=3, n_head=4,
               max_seq_len=32, dtype=jnp.float32)
+
+
+def _weights(model, seed=1234):
+    """(every engine of a comparison starts from the same ones either way)"""
+    return engine_weights(model, {"input_ids": jnp.zeros((8, 32), jnp.int32)},
+                          seed, ("params", "dropout", "gating"))
 
 
 def _run(zero, steps=4, family="gpt2", gas=2, model_kw=None, conf_extra=None):
@@ -41,7 +46,10 @@ def _run(zero, steps=4, family="gpt2", gas=2, model_kw=None, conf_extra=None):
                           "params": {"lr": 1e-3, "weight_decay": 0.01}},
             "gradient_clipping": 1.0, "steps_per_print": 10 ** 9}
     conf.update(conf_extra or {})
-    engine, _, _, _ = ds.initialize(model=TransformerLM(cfg), config=conf)
+    model = TransformerLM(cfg)
+    engine, _, _, _ = ds.initialize(
+        model=model, model_parameters=_weights(model, conf.get("seed", 1234)),
+        config=conf)
     rng = np.random.default_rng(0)
     losses = []
     for _ in range(steps):
@@ -199,7 +207,9 @@ def _run_gpt2(zero, steps=4, gas=2, dropout=0.0, seed=1234):
                           "params": {"lr": 1e-3, "weight_decay": 0.01}},
             "gradient_clipping": 1.0, "steps_per_print": 10 ** 9,
             "seed": seed}
-    engine, _, _, _ = ds.initialize(model=GPT2LMHeadModel(cfg), config=conf)
+    model = GPT2LMHeadModel(cfg)
+    engine, _, _, _ = ds.initialize(
+        model=model, model_parameters=_weights(model, seed), config=conf)
     rng = np.random.default_rng(0)
     losses = []
     for _ in range(steps):
@@ -309,7 +319,10 @@ def _run_moe(zero, steps=4, gas=2, dropout=0.0, use_rts=False, k=1,
             "optimizer": {"type": "AdamW",
                           "params": {"lr": 1e-3, "weight_decay": 0.01}},
             "gradient_clipping": 1.0, "steps_per_print": 10 ** 9}
-    engine, _, _, _ = ds.initialize(model=GPTMoEModel(cfg), config=conf)
+    model = GPTMoEModel(cfg)
+    engine, _, _, _ = ds.initialize(
+        model=model, model_parameters=_weights(model, conf.get("seed", 1234)),
+        config=conf)
     rng = np.random.default_rng(0)
     losses = []
     fixed = {"input_ids": rng.integers(

@@ -1,5 +1,5 @@
 """The one table of what a cache kind does not run with yet
-(``models/transformer_lm.py``, ``CACHE_REFUSALS``): for EVERY row, the real
+(``models/cache_kinds.py``, ``CACHE_REFUSALS``): for EVERY row, the real
 constructor that turns the feature on, over a tiny model of that kind and no
 other, raises the row's own sentence. A row added without a way to reach it
 here fails (``PRESETS`` / ``ASK`` have no entry for it)."""
@@ -10,19 +10,21 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.inference.zero_inference import ZeroInferenceEngine
-from deepspeed_tpu.models.transformer_lm import (CACHE_KINDS, CACHE_REFUSALS,
-                                                 FEATURES, TransformerLM,
-                                                 cache_kinds,
-                                                 transformer_config)
+from deepspeed_tpu.models.cache_kinds import (CACHE_KINDS, CACHE_REFUSALS,
+                                              FEATURES, cache_kinds)
+from deepspeed_tpu.models.lm_config import transformer_config
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.serving import ServingEngine
 
-from .conftest import TINY
+from tests.unit.kinds import KINDS, SPARSE, TINY, init_params, kind_widths
 
 WINDOW, PAGE = 16, 8
 _LLAMA = dict(TINY, max_seq_len=128, n_layer=4, head_size=8)
-# a configuration a kind, each of that kind alone (beside what it implies)
+# a configuration a kind, each of that kind ALONE (beside what it implies):
+# the rows of tests/unit/kinds.py are the kinds as they are served, most of
+# them two or three of these at once
 PRESETS = {
-    "state": ("brumby", dict(TINY, n_kv_head=2, head_size=16, ffn_dim=48)),
+    "state": (KINDS["retention"][0], kind_widths("retention")),
     "ssm": ("granite-hybrid", dict(
         TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
         layer_types=["mamba", "attention"] * 2, mamba_n_heads=4,
@@ -37,9 +39,7 @@ PRESETS = {
     "sparse": ("minicpm_sala", dict(
         TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
         layer_types=["lightning", "sparse_attention"] * 2,
-        sparse_attention=dict(kernel_size=2, kernel_stride=1, block_size=4,
-                              init_blocks=1, window_size=8, topk=2,
-                              dense_len=16))),
+        sparse_attention=SPARSE)),
     "lightning": ("minicpm_sala", dict(
         TINY, n_layer=4, n_kv_head=2, ffn_dim=48, attn_output_gate=False,
         layer_types=["lightning", "attention"] * 2)),
@@ -86,11 +86,8 @@ def engine_of():
     def get(kind, mesh=None):
         if (kind, mesh) not in built:
             model = TransformerLM(_config(kind))
-            params = model.init({"params": jax.random.PRNGKey(0)},
-                                jnp.zeros((1, 8), jnp.int32),
-                                method=model.logits)["params"]
             built[kind, mesh] = ds.init_inference(
-                model=model, model_parameters=params,
+                model=model, model_parameters=init_params(model, seed=0),
                 config={"dtype": "float32"}, mesh=mesh)
         return built[kind, mesh]
 

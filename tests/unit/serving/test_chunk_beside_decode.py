@@ -22,61 +22,39 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                 transformer_config)
 from deepspeed_tpu.serving import ServingEngine
 from deepspeed_tpu.serving.request import RequestState
 from deepspeed_tpu.telemetry import Tracer
+from tests.unit.kinds import kind_stack
+
+from .conftest import traced_once
 
 # chunks of 4 in pages of 8: a chunk ends inside a page and on a page
 # boundary in turn
 PAGE, CHUNK, SLOTS, CTX = 8, 4, 3, 64
-KINDS = {
-    "kv_pages": ("gpt-neox", dict(n_embd=32, n_layer=2, n_head=4)),
-    "window_group_routed_ffn": ("mellum", dict(
-        n_embd=32, n_layer=2, n_head=4, n_kv_head=2, head_size=16,
-        ffn_dim=16, layer_types=["sliding_attention", "full_attention"],
-        sliding_window=16, n_experts=4, experts_per_token=2)),
-    "latent_pages_dense_first": ("moonlight", dict(
-        n_embd=32, n_layer=2, n_head=4, kv_lora_rank=16, qk_nope_head_dim=8,
-        qk_rope_head_dim=8, v_head_dim=8, ffn_dim=16, n_experts=4,
-        experts_per_token=2, n_shared_experts=1, first_k_dense=1,
-        dense_ffn_dim=48, routed_scaling_factor=2.446)),
-    "state_group": ("granite-hybrid", dict(
-        n_embd=32, n_layer=2, n_head=4, n_kv_head=2, ffn_dim=48,
-        layer_types=["mamba", "attention"], mamba_n_heads=4,
-        mamba_d_head=8, mamba_d_state=8)),
-}
+# the case's name -> its row of tests/unit/kinds.py
+KINDS = {"kv_pages": "plain", "window_group_routed_ffn": "window_routed",
+         "latent_pages_dense_first": "latent_routed",
+         "state_group": "state_group"}
 # compiled float32 against compiled float32 of another program text
 ATOL = {"kv_pages": 0.0}
 PROMPTS = (5, 14, 9)        # bucketed; chunks of 4, 4, 4, 2; of 4, 4, 1
 FUSED_STEPS = 3 + 2         # every chunk but a prompt's last
 
 
-def _model(kind):
-    family, widths = KINDS[kind]
-    model = TransformerLM(transformer_config(
-        family, vocab_size=64, max_seq_len=CTX, dtype=jnp.float32, **widths))
-    params = jax.jit(lambda: model.init(
-        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32),
-        method=model.logits))()["params"]
-    return model, params
-
-
-def _server(engine, fused: bool, **paged):
-    srv = ServingEngine(engine, num_slots=SLOTS, prefill_chunk=CHUNK,
-                        prefill_token_budget=2 * CHUNK, max_queue_depth=8,
-                        paged_kv={"kernel": "on", "page_size": PAGE,
-                                  "prefix_cache": False, **paged},
-                        tracer=Tracer())
-    assert srv._fuses_chunks
-    if not fused:
-        # the same server without the one program: every chunk step runs
-        # the two it replaces
-        srv.pool._paged_chunk_decode_jit = None
-        assert not srv._fuses_chunks
-    return srv
+def _servers(engine, **paged):
+    """The fused server, and the same server without the one program:
+    every chunk step of it runs the two it replaces, which are the fused
+    server's own two (a prompt's last chunk and a plain decode step run
+    them there too), traced once."""
+    fused, two = (traced_once(ServingEngine(
+        engine, num_slots=SLOTS, prefill_chunk=CHUNK,
+        prefill_token_budget=2 * CHUNK, max_queue_depth=8,
+        paged_kv={"kernel": "on", "page_size": PAGE, "prefix_cache": False,
+                  **paged}, tracer=Tracer())) for _ in range(2))
+    two.pool._paged_chunk_decode_jit = None
+    assert fused._fuses_chunks and not two._fuses_chunks
+    return {True: fused, False: two}
 
 
 def _pool_state(srv):
@@ -115,12 +93,10 @@ def served(request):
     pool after every step, what every ``serving/step`` said, and a chunk's
     columns as the step that wrote them left them."""
     kind = request.param
-    model, params = _model(kind)
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
+    engine = kind_stack(KINDS[kind])[2]
     rng = np.random.default_rng(17)
     prompts = [rng.integers(1, 64, size=n).astype(np.int32) for n in PROMPTS]
-    servers = {True: _server(engine, True), False: _server(engine, False)}
+    servers = _servers(engine)
     reqs, states, written = {}, [], []
     for fused, srv in servers.items():
         reqs[fused] = [srv.submit(prompts[0], max_new_tokens=14)]
@@ -265,10 +241,8 @@ PRESSED = {
 def pressed():
     """Two servers on a pool of seven pages, fused and held to two
     programs; every scenario drains them, so the next finds them empty."""
-    model, params = _model("kv_pages")
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return {f: _server(engine, f, num_pages=7) for f in (True, False)}
+    engine = kind_stack(KINDS["kv_pages"])[2]
+    return _servers(engine, num_pages=7)
 
 
 @pytest.mark.parametrize("name", sorted(PRESSED))
@@ -319,7 +293,8 @@ def test_a_chunk_under_page_pressure_is_the_two_programs(pressed, name):
 
 # (plain K/V pages are held to the bit at the default level, above)
 @pytest.mark.parametrize("kind", sorted(k for k in KINDS if k not in ATOL))
-def test_as_written_one_pass_gives_each_row_the_bits_of_two(kind):
+def test_as_written_one_pass_gives_each_row_the_bits_of_two(
+        kind, users_compiles):
     """``chunk_beside_decode`` against ``prefill_chunk`` (through a table
     row) then ``decode_paged`` on the same pool, compiled with the
     arithmetic left as written: the decode rows' logits and every leaf of
@@ -327,7 +302,7 @@ def test_as_written_one_pass_gives_each_row_the_bits_of_two(kind):
     over one row is a matrix-vector product, over B + 1 rows a matrix
     product, two routines.) Slot 1 is in mid-prefill (8 of its tokens in, a
     chunk of 4 to go: it ends inside a page), slots 0 and 2 run."""
-    model, params = _model(kind)
+    model, params, _ = kind_stack(KINDS[kind])
     spec = model.kv_cache_spec()
     pages, per_slot = 12, CTX // PAGE
     ring = 3 * (16 // PAGE + 1) if spec.groups is not None else None
@@ -380,7 +355,7 @@ def test_as_written_one_pass_gives_each_row_the_bits_of_two(kind):
             **({"chunk_row": jnp.asarray([slot])} if state else {}))
         return chunk, logits, new
 
-    as_written = {"xla_backend_optimization_level": 0}
+    as_written = dict(users_compiles, xla_backend_optimization_level=0)
     (chunk2, logits2, cs2), (chunk1, logits1, cs1) = (
         jax.jit(f).lower(cs).compile(compiler_options=as_written)(cs)
         for f in (two, one))

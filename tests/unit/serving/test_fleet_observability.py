@@ -14,36 +14,18 @@ throughout, pinning the zero-new-jitted-programs acceptance bar.
 import json
 import os
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import RequestState, ServingEngine
 from deepspeed_tpu.serving.router import ReplicaRouter
 from deepspeed_tpu.telemetry import (FLEET_POST_MORTEM_KEYS,
                                      QuantileDigest, Tracer)
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
 PS = 8
 
 LENGTHS = [5, 9, 12, 5, 17, 12]
 BUDGETS = [6, 4, 8, 3, 7, 5]
-
-
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
 
 
 def paged_server(engine, role="both", **kw):

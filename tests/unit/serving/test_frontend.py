@@ -9,38 +9,20 @@ bridge (streaming, cancellation, backpressure, drain-on-shutdown)."""
 import asyncio
 import threading
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import (FIFOScheduler, FinishReason,
                                    PriorityConfig, PriorityScheduler,
                                    RejectReason, Request, RequestState,
                                    ServingEngine, TenantPolicy)
 from deepspeed_tpu.serving.frontend import AsyncEngineBridge
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
 
 # relaxed SLO for engine tests: the first step's jit compile lands in
 # TTFT, which would trip the default 500 ms target and turn burn-rate
 # shedding ON mid-test (that behavior gets its own deterministic tests)
 LENIENT_SLO = {"ttft_ms": 6e5, "gap_ms": 6e5}
-
-
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
 
 
 class FakeClock:

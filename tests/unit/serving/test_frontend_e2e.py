@@ -7,34 +7,16 @@ timeline completes, invariants hold, no slot leaks."""
 import asyncio
 import json
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import (FinishReason, ServingEngine,
                                    ServingFrontend)
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
 # compile time lands in the first TTFT; keep burn shedding out of the
 # basic e2e flows (the shed path is asserted separately with the SLO
 # tracker driven directly)
 LENIENT_SLO = {"ttft_ms": 6e5, "gap_ms": 6e5}
-
-
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
 
 
 # ---------------------------------------------------------------------------

@@ -7,22 +7,25 @@ and what does not compose refuses at construction, by mechanism."""
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import (TransformerConfig,
-                                                 TransformerLM,
-                                                 transformer_config)
+from deepspeed_tpu.models.lm_config import (TransformerConfig,
+                                            transformer_config)
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.serving import RequestState, ServingEngine
+from deepspeed_tpu.telemetry.tracer import Tracer
+from tests.unit.kinds import init_params, kind_stack, kind_widths
 
-SMALL = dict(vocab_size=96, max_seq_len=128, n_embd=32, n_layer=3, n_head=4,
-             kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
-             v_head_dim=8, ffn_dim=16, n_experts=4, experts_per_token=2,
-             n_shared_experts=1, first_k_dense=1, dense_ffn_dim=48,
-             routed_scaling_factor=2.446, dtype=jnp.float32)
+from .conftest import Servers, spans_since, traced_once
+
+# the latent row of tests/unit/kinds.py with two routed layers behind the
+# dense one, and room for a prompt of five chunks of 16 over a vocabulary
+# of 96
+OVER = dict(vocab_size=96, max_seq_len=128, n_layer=3)
+SMALL = kind_widths("latent_routed", **OVER)
 CHUNK = 16
 POOLS = {"contiguous": False,
          "paged": {"kernel": "off", "page_size": 16, "prefix_cache": False},
@@ -31,20 +34,19 @@ POOLS = {"contiguous": False,
 
 @pytest.fixture(scope="module")
 def stack():
-    cfg = transformer_config("moonlight", **SMALL)
-    model = TransformerLM(cfg)
+    model, params, engine = kind_stack("latent_routed", **OVER)
     ids = np.random.default_rng(3).integers(1, 96, (3, 80)).astype(np.int32)
-    params = model.init({"params": jax.random.PRNGKey(2)},
-                        jnp.asarray(ids[:1, :8]),
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return cfg, model, params, engine, ids
+    return model.config, model, params, engine, ids
 
 
-def server(engine, pool="kernel", slots=3, **kw):
-    return ServingEngine(engine, num_slots=slots, prefill_chunk=CHUNK,
-                         paged_kv=POOLS[pool], **kw)
+@pytest.fixture(scope="module")
+def server(stack):
+    """``server(pool)``: the module's one server on that pool (with a
+    tracer of its own), emptied between the cases that take it."""
+    engine = stack[3]
+    return Servers(lambda pool: ServingEngine(
+        engine, num_slots=3, prefill_chunk=CHUNK, paged_kv=POOLS[pool],
+        tracer=Tracer()))
 
 
 def drained(srv, prompts, new_tokens=8):
@@ -60,14 +62,13 @@ def prompts_of(ids):
     return [ids[0, :9], ids[1, :30], ids[2, 3:31], ids[0, :50], ids[1, :77]]
 
 
-def test_every_pool_serves_the_same_tokens(stack):
+def test_every_pool_serves_the_same_tokens(stack, server):
     """Bucketed admission, chunked prefill and decode on the contiguous
     pool (it takes the latent row like any leaf), the page pool's dense
     composition and its kernels: one greedy answer, which is the no-cache
     forward's."""
-    _, model, params, engine, ids = stack
-    outs = {pool: drained(server(engine, pool), prompts_of(ids))
-            for pool in POOLS}
+    _, model, params, _, ids = stack
+    outs = {pool: drained(server(pool), prompts_of(ids)) for pool in POOLS}
     assert outs["contiguous"] == outs["paged"] == outs["kernel"]
     for prompt, out in zip(prompts_of(ids), outs["kernel"]):
         seq = jnp.asarray([list(prompt) + out])
@@ -76,22 +77,22 @@ def test_every_pool_serves_the_same_tokens(stack):
         assert out == want.tolist()
 
 
-def test_the_pool_holds_one_leaf_of_whole_pages(stack):
-    _, _, _, engine, _ = stack
-    srv = server(engine, "kernel")
+def test_the_pool_holds_one_leaf_of_whole_pages(server):
+    srv = server("kernel")
     cs = srv.pool.cache["cache_store"]
     assert set(cs) == {"c", "index", "table"}            # no k, no v
     assert cs["c"].shape == (3, srv.pool.num_pages, 24, 128)
     assert srv.pool.page_nbytes == 3 * 24 * 128 * 4
-    assert set(server(engine, "contiguous").pool.cache["cache_store"]) \
+    assert set(server("contiguous").pool.cache["cache_store"]) \
         == {"c", "index"}
 
 
 @pytest.mark.parametrize("pool", ["paged", "kernel"])
-def test_a_preempted_request_re_prefills_into_latent_pages(stack, pool):
-    _, _, _, engine, ids = stack
-    want = drained(server(engine, pool), [ids[0, :40]], 12)[0]
-    srv = server(engine, pool)
+def test_a_preempted_request_re_prefills_into_latent_pages(stack, server,
+                                                           pool):
+    ids = stack[4]
+    want = drained(server(pool), [ids[0, :40]], 12)[0]
+    srv = server(pool)
     req = srv.submit(ids[0, :40], max_new_tokens=12)
     other = srv.submit(ids[1, :20], max_new_tokens=12)
     while len(req.output_tokens) < 5:
@@ -110,17 +111,17 @@ def test_a_preempted_request_re_prefills_into_latent_pages(stack, pool):
     assert not srv.pool.consistency_errors()
 
 
-def test_a_prefix_hit_maps_latent_pages(stack):
+def test_a_prefix_hit_maps_latent_pages(stack, server):
     """A latent page is position-indexed like a K/V page: the trie hands
     the second request the first's pages, and its answer is what a server
     without a trie gives."""
     _, _, _, engine, ids = stack
     shared = ids[0, :48]
     second = np.concatenate([shared, ids[1, :10]])
-    want = drained(server(engine, "kernel"), [second])[0]
-    srv = ServingEngine(engine, num_slots=2, prefill_chunk=CHUNK,
-                        paged_kv={"kernel": "on", "page_size": 16,
-                                  "prefix_cache": True})
+    want = drained(server("kernel"), [second])[0]
+    srv = traced_once(ServingEngine(
+        engine, num_slots=2, prefill_chunk=CHUNK,
+        paged_kv={"kernel": "on", "page_size": 16, "prefix_cache": True}))
     drained(srv, [np.concatenate([shared, ids[2, :7]])])
     req = srv.submit(second, max_new_tokens=8)
     srv.run_until_drained(max_steps=200)
@@ -129,22 +130,19 @@ def test_a_prefix_hit_maps_latent_pages(stack):
     srv.check_invariants()
 
 
-def test_the_dispatch_spans_count_latent_rows(stack):
+def test_the_dispatch_spans_count_latent_rows(stack, server):
     """``latent_tokens_read`` / ``latent_rows_written`` on the decode,
     chunk and admission spans, the step's totals with the bytes they stand
     for, and the gauge of mapped pages."""
-    from deepspeed_tpu.telemetry.tracer import Tracer
-
-    _, _, _, engine, ids = stack
-    tracer = Tracer()
-    srv = server(engine, "kernel", tracer=tracer)
+    ids = stack[4]
+    srv = server("kernel")
+    n0 = srv.tracer.events_total
     a = srv.submit(ids[0, :40], max_new_tokens=4)      # chunked: 16, 16, 8
     b = srv.submit(ids[1, :9], max_new_tokens=4)       # a bucket's
     srv.run_until_drained(max_steps=100)
-    spans = {}
-    for e in tracer.events():
-        if e.get("ph") == "X" and "latent_tokens_read" in (e.get("args")
-                                                           or {}):
+    spans, events = {}, spans_since(srv, n0)
+    for e in events:
+        if "latent_tokens_read" in (e.get("args") or {}):
             spans.setdefault(e["name"], []).append(e["args"])
     chunks = spans["serving/prefill_chunk"]
     assert [(c["latent_tokens_read"], c["latent_rows_written"])
@@ -165,8 +163,7 @@ def test_the_dispatch_spans_count_latent_rows(stack):
         for x, y in steady)
     # the first decode after the prompt of 9: its rows and its own token
     assert decodes[0]["live"] == 1 and decodes[0]["latent_tokens_read"] == 10
-    steps = [e["args"] for e in tracer.events()
-             if e.get("ph") == "X" and e["name"] == "serving/step"
+    steps = [e["args"] for e in events if e["name"] == "serving/step"
              and "latent_tokens_read" in (e.get("args") or {})]
     assert steps and all(
         s["latent_bytes_read"] == s["latent_tokens_read"] * 24 * 4 * 3
@@ -182,7 +179,8 @@ def test_the_dispatch_spans_count_latent_rows(stack):
     srv.cancel(hold.request_id)
 
 
-def test_the_read_spans_count_blocks_and_the_pages_in_them(stack):
+def test_the_read_spans_count_blocks_and_the_pages_in_them(stack, server,
+                                                           monkeypatch):
     """``pool_reads`` on a latent pool's decode and chunk spans is the
     read's grid steps, which are BLOCKS of ``G`` pages (``G`` from the
     dispatch's rows), and ``pool_read_pages`` the pages in them: both as
@@ -191,11 +189,10 @@ def test_the_read_spans_count_blocks_and_the_pages_in_them(stack):
     from deepspeed_tpu.ops.attention.latent_attention import (
         call_rows, page_blocks, pages_a_step)
     from deepspeed_tpu.ops.attention.paged_attention import live_pages
-    from deepspeed_tpu.telemetry.tracer import Tracer
 
-    cfg, _, _, engine, ids = stack
-    tracer = Tracer()
-    srv = server(engine, "kernel", tracer=tracer)
+    cfg, _, _, _, ids = stack
+    srv = server("kernel")
+    n0 = srv.tracer.events_total
     pool, record = srv.pool, []
     count = pool.pages_read
 
@@ -214,10 +211,10 @@ def test_the_read_spans_count_blocks_and_the_pages_in_them(stack):
         record.append((work, G))
         return work
 
-    pool.pages_read = pages_read
+    monkeypatch.setattr(pool, "pages_read", pages_read)
     drained(srv, [ids[1, :77], ids[0, :9]])
-    spans = [e["args"] for e in tracer.events()
-             if e.get("ph") == "X" and "pool_reads" in (e.get("args") or {})
+    spans = [e["args"] for e in spans_since(srv, n0)
+             if "pool_reads" in (e.get("args") or {})
              and e["name"] in ("serving/decode", "serving/prefill_chunk")]
     assert len(spans) == len(record) > 8
     assert sorted((a["pool_reads"], a["read_slots"], a["pool_read_pages"])
@@ -278,9 +275,7 @@ def test_mlp_layer_types_are_dense_layers_then_sparse_ones():
 @pytest.fixture(scope="module")
 def latent_engine():
     model = TransformerLM(TransformerConfig(**_latent_only()))
-    params = model.init({"params": jax.random.PRNGKey(0)},
-                        jnp.zeros((1, 8), jnp.int32),
-                        method=model.logits)["params"]
+    params = init_params(model, seed=0)
     return ds.init_inference(model=model, model_parameters=params,
                              config={"dtype": "float32"})
 
@@ -319,8 +314,8 @@ def test_a_latent_model_without_experts_serves(latent_engine):
     """The cache is the attention's, not the router's: a plain FFN behind
     latent attention serves on latent pages too."""
     ids = np.random.default_rng(0).integers(1, 96, (30,)).astype(np.int32)
-    outs = [drained(ServingEngine(latent_engine, num_slots=2,
-                                  prefill_chunk=CHUNK, paged_kv=paged),
+    outs = [drained(traced_once(ServingEngine(
+        latent_engine, num_slots=2, prefill_chunk=CHUNK, paged_kv=paged)),
                     [ids, ids[:7]], 6)
             for paged in (False, {"kernel": "on", "page_size": 16})]
     assert outs[0] == outs[1]

@@ -10,34 +10,28 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import PagedKVPool, RequestState, ServingEngine
 from deepspeed_tpu.telemetry import Tracer
 
-from .conftest import watch_kernel_reads
+from .conftest import (Servers, emptied, spans_since, traced_once,
+                       watch_kernel_reads)
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
 PS = 8  # page size == prefill chunk for every server in this file
 
 
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
-
-
-def kernel_server(engine, kernel="on", num_slots=2, **kw):
+def kernel_server(engine, kernel="on", num_slots=2, own_programs=False,
+                  **kw):
     kw.setdefault("prefill_chunk", PS)
-    return ServingEngine(engine, num_slots=num_slots, max_queue_depth=32,
-                         paged_kv={"page_size": PS, "kernel": kernel}, **kw)
+    srv = ServingEngine(engine, num_slots=num_slots, max_queue_depth=32,
+                        paged_kv={"page_size": PS, "kernel": kernel}, **kw)
+    return srv if own_programs else traced_once(srv)
+
+
+@pytest.fixture(scope="module")
+def servers(stack):
+    """``servers(kernel)``: the module's one plain ``kernel_server`` of two
+    slots on that arm, emptied between the cases that drive it."""
+    return Servers(lambda kernel: kernel_server(stack[2], kernel))
 
 
 def run_traffic(srv, prompts, budgets, max_steps=400):
@@ -81,14 +75,14 @@ def test_kernel_knob_validates_and_gates(stack):
 # bitwise parity
 
 
-def test_kernel_tokens_bitwise_match_dense_and_generate(stack):
+def test_kernel_tokens_bitwise_match_dense_and_generate(stack, servers):
     """Multi-wave slot churn through the fused kernel: per-request tokens
     must equal the dense-oracle server's AND static-batch generate()'s,
     bit for bit (greedy)."""
     _, _, engine = stack
     prompts, budgets = _mixed_workload()
-    on = run_traffic(kernel_server(engine, "on"), prompts, budgets)
-    off = run_traffic(kernel_server(engine, "off"), prompts, budgets)
+    on = run_traffic(servers("on"), prompts, budgets)
+    off = run_traffic(servers("off"), prompts, budgets)
     for a, b, p, budget in zip(on, off, prompts, budgets):
         assert a.state == RequestState.FINISHED, a.finish_reason
         np.testing.assert_array_equal(a.tokens(), b.tokens())
@@ -151,7 +145,7 @@ def test_a_verify_of_nine_rows_takes_the_kernel(stack):
     assert "SlotPool._paged_verify_jit" not in manifest
 
 
-def test_kernel_preempt_resume_parity(stack):
+def test_kernel_preempt_resume_parity(stack, servers):
     """Preempt mid-decode, resume through the kernel arm: the rebuilt
     page table must feed the kernel exactly the tokens the dense arm
     (and an unpreempted generate()) sees."""
@@ -160,7 +154,7 @@ def test_kernel_preempt_resume_parity(stack):
     prompt = rng.integers(0, 64, size=18).astype(np.int32)
 
     def run(kernel):
-        srv = kernel_server(engine, kernel, num_slots=2)
+        srv = servers(kernel)
         req = srv.submit(prompt, max_new_tokens=12)
         for _ in range(4):                       # partway through decode
             srv.step()
@@ -194,7 +188,7 @@ def test_kernel_churn_never_recompiles_after_warmup(stack):
     prompts += [rng.integers(0, 64, size=n).astype(np.int32)
                 for n in (29, 43)]
     budgets += [4, 5]
-    srv = kernel_server(engine, "on", tracer=Tracer())
+    srv = kernel_server(engine, "on", own_programs=True, tracer=Tracer())
     run_traffic(srv, prompts, budgets)
     srv.end_warmup()
     run_traffic(srv, prompts, budgets)
@@ -254,6 +248,15 @@ def _chunk_server(engine, kernel):
     return srv, calls
 
 
+@pytest.fixture(scope="module")
+def chunk_servers(stack):
+    """``{kernel: (server, calls)}``, one pair a module: a case takes each
+    :func:`emptied`, its record cleared, and reads the spans and the
+    copies-on-write it added."""
+    return {kernel: _chunk_server(stack[2], kernel)
+            for kernel in ("on", "off")}
+
+
 def _drive_chunks(srv, case):
     rng = np.random.default_rng(33)
     reqs = []
@@ -295,15 +298,20 @@ def _drive_chunks(srv, case):
 
 @pytest.mark.parametrize("case", ["crosses_pages", "prefix_hit_mid_page",
                                   "preempt_mid_prefill"])
-def test_chunked_prefill_through_the_pages_matches_the_dense_arm(stack, case):
+def test_chunked_prefill_through_the_pages_matches_the_dense_arm(
+        chunk_servers, case):
     """Every chunk of the kernel arm writes its 12 columns into the pages
     and reads them back in place; the dense arm gathers the slot's dense
     row. Greedy tokens equal, every chunk's logits within ``CHUNK_ATOL``,
     the invariants clean after every step, and the chunk spans say what
     the read's work list held (and nothing on the dense arm)."""
-    _, _, engine = stack
-    srv_on, on_calls = _chunk_server(engine, "on")
-    srv_off, off_calls = _chunk_server(engine, "off")
+    (srv_on, on_calls), (srv_off, off_calls) = (
+        chunk_servers[kernel] for kernel in ("on", "off"))
+    before = {}
+    for srv, calls in chunk_servers.values():
+        emptied(srv)
+        calls.clear()
+        before[srv] = (srv.tracer.events_total, srv.pool.cow_copies)
     on, off = _drive_chunks(srv_on, case), _drive_chunks(srv_off, case)
     for a, b in zip(on, off):
         np.testing.assert_array_equal(a.tokens(), b.tokens())
@@ -313,22 +321,19 @@ def test_chunked_prefill_through_the_pages_matches_the_dense_arm(stack, case):
         np.testing.assert_allclose(got, want, atol=CHUNK_ATOL,
                                    err_msg=f"chunk at {start}")
     if case == "prefix_hit_mid_page":
-        assert srv_on.pool.cow_copies == srv_off.pool.cow_copies >= 1
+        assert srv_on.pool.cow_copies - before[srv_on][1] \
+            == srv_off.pool.cow_copies - before[srv_off][1] >= 1
         assert any(start % PS for start, _, _ in on_calls)
 
     def spans(srv):
-        return [e["args"] for e in srv.tracer.events()
-                if e["ph"] == "X" and e["name"] == "serving/prefill_chunk"]
+        return [e["args"] for e in spans_since(srv, before[srv][0])
+                if e["name"] == "serving/prefill_chunk"]
 
     assert len(spans(srv_on)) == len(on_calls)
     for args, (start, _, steps) in zip(spans(srv_on), on_calls):
         assert args["pos"] == start
         assert (args["pool_reads"], args["read_slots"]) == (steps, 1)
         assert args["pool_read_pages"] == steps     # a step of K/V: a page
-        # a KV head's block: its rep heads' rows of the chunk, whole tiles
-        live = srv_on.pool.spec.rep * srv_on.prefill_chunk
-        assert (args["read_rows"], args["read_rows_live"]) \
-            == (-(-live // 8) * 8, live)
         assert args["pool_writes"] >= 1
     assert spans(srv_off) and not any(
         "pool_reads" in args or "read_slots" in args
@@ -396,11 +401,6 @@ def test_freed_slots_are_no_step_under_churn_with_the_finite_guard(
     for args, ((reads, slots), total, seated) in zip(spans, record):
         assert (args["pool_reads"], args["read_slots"]) == (reads, slots)
         assert args["pool_read_pages"] == reads == total and slots == seated
-        # the rows of a KV head's block: its rep heads' rows packed into
-        # whole sublane tiles, and those of them somebody reads
-        live = srv.pool.spec.rep * (spec["k"] + 1 if spec else 1)
-        assert (args["read_rows"], args["read_rows_live"]) \
-            == (-(-live // 8) * 8, live)
     # slots stood freed beside decoding ones, and cost nothing
     assert any(0 < slots < 4 for (_, slots), _, _ in record)
     assert srv_off.pool.pages_read(1) is None

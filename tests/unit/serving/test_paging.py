@@ -5,39 +5,33 @@ rollback across page boundaries, and preempt/resume; page churn never
 recompiles; refcount bookkeeping survives the invariant audit; admission is
 page-denominated."""
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
 from deepspeed_tpu.serving import (PagedKVPool, PagePoolExhausted, PrefixCache,
                                    RejectReason, RequestState, ServingEngine)
 from deepspeed_tpu.serving.resilience import InvariantViolation
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
+from .conftest import Servers, traced_once
+
 PS = 8  # page size == prefill chunk for every server in this file
 
 
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
-
-
-def paged_server(engine, num_slots=2, num_pages=None, **kw):
+def paged_server(engine, num_slots=2, num_pages=None, own_programs=False,
+                 **kw):
     kw.setdefault("prefill_chunk", PS)
-    return ServingEngine(engine, num_slots=num_slots, max_queue_depth=32,
-                         paged_kv={"page_size": PS, "num_pages": num_pages},
-                         **kw)
+    srv = ServingEngine(engine, num_slots=num_slots, max_queue_depth=32,
+                        paged_kv={"page_size": PS, "num_pages": num_pages},
+                        **kw)
+    return srv if own_programs else traced_once(srv)
+
+
+@pytest.fixture(scope="module")
+def shared(stack):
+    """``shared()``: the module's one ``paged_server`` of two slots, for the
+    cases that assert on nothing a server counts over its life; handed out
+    :func:`emptied` (every page free, an empty trie)."""
+    return Servers(lambda: paged_server(stack[2]))
 
 
 def run_traffic(srv, prompts, budgets):
@@ -59,7 +53,7 @@ def assert_matches_generate(engine, reqs, prompts, budgets):
 # bitwise parity
 
 
-def test_paged_tokens_bitwise_match_generate(stack):
+def test_paged_tokens_bitwise_match_generate(stack, shared):
     """Multi-wave slot reuse through the paged pool must produce EXACTLY
     the tokens static-batch generate() produces — page tables are an
     addressing change, never a numerics change (greedy)."""
@@ -68,14 +62,14 @@ def test_paged_tokens_bitwise_match_generate(stack):
     lengths = [5, 9, 12, 5, 17, 12]
     budgets = [6, 4, 8, 3, 7, 5]
     prompts = [rng.integers(0, 64, size=n).astype(np.int32) for n in lengths]
-    srv = paged_server(engine)
+    srv = shared()
     assert isinstance(srv.pool, PagedKVPool)
     reqs = run_traffic(srv, prompts, budgets)
     assert_matches_generate(engine, reqs, prompts, budgets)
     srv.check_invariants()
 
 
-def test_paged_matches_contiguous_pool(stack):
+def test_paged_matches_contiguous_pool(stack, shared):
     """The same staggered traffic through a paged and a contiguous server
     yields identical per-request tokens — pinning paged-vs-SlotPool parity
     directly, not just both-against-generate."""
@@ -84,7 +78,7 @@ def test_paged_matches_contiguous_pool(stack):
     prompts = [rng.integers(0, 64, size=n).astype(np.int32)
                for n in (6, 11, 24, 9, 6)]
     budgets = [5, 7, 4, 6, 8]
-    paged = run_traffic(paged_server(engine), prompts, budgets)
+    paged = run_traffic(shared(), prompts, budgets)
     dense = run_traffic(
         ServingEngine(engine, num_slots=2, max_queue_depth=32,
                       prefill_chunk=PS), prompts, budgets)
@@ -202,7 +196,7 @@ def test_spec_decode_paged_parity_across_page_boundary(stack):
     srv.check_invariants()
 
 
-def test_preempt_resume_with_cached_prefix(stack):
+def test_preempt_resume_with_cached_prefix(stack, shared):
     """Preempt mid-decode, resume through the paged pool: the re-prefill
     walks the prefix cache (the preempted prompt's own full pages are
     trie-cached) and the final tokens are bitwise what an unpreempted run
@@ -210,7 +204,7 @@ def test_preempt_resume_with_cached_prefix(stack):
     _, _, engine = stack
     rng = np.random.default_rng(31)
     prompt = rng.integers(0, 64, size=18).astype(np.int32)
-    srv = paged_server(engine, num_slots=2)
+    srv = shared()
     req = srv.submit(prompt, max_new_tokens=12)
     for _ in range(4):                           # partway through decode
         srv.step()
@@ -231,7 +225,7 @@ def test_no_recompile_after_warmup_page_churn(stack):
     prefix hits, and a CoW fork, page churn (new tables, eviction,
     oversubscription pressure) must never recompile a paged program."""
     _, _, engine = stack
-    srv = paged_server(engine, num_slots=4, num_pages=12,
+    srv = paged_server(engine, num_slots=4, num_pages=12, own_programs=True,
                        preempt_queue_threshold=2, strict_recompile=True)
     base = list(range(1, 25))
     for i in range(3):
@@ -298,11 +292,10 @@ def test_page_denominated_admission_rejects(stack):
 # bookkeeping integrity
 
 
-def test_invariant_audit_catches_refcount_corruption(stack):
+def test_invariant_audit_catches_refcount_corruption(shared):
     """The page audit must detect a refcount that no held reference
     explains — the chaos-suite contract extended to page bookkeeping."""
-    _, _, engine = stack
-    srv = paged_server(engine, num_slots=2)
+    srv = shared()
     srv.submit(np.arange(1, 20, dtype=np.int32), max_new_tokens=4)
     srv.run_until_drained(max_steps=100)
     srv.check_invariants()                       # clean before corruption
@@ -316,11 +309,10 @@ def test_invariant_audit_catches_refcount_corruption(stack):
     srv.check_invariants()
 
 
-def test_paging_telemetry_gauges_and_stats(stack):
+def test_paging_telemetry_gauges_and_stats(shared):
     """stats() carries the paging panel and the registry exports the
     paging/* gauges every step."""
-    _, _, engine = stack
-    srv = paged_server(engine, num_slots=2)
+    srv = shared()
     srv.submit(np.arange(1, 15, dtype=np.int32), max_new_tokens=3)
     srv.run_until_drained(max_steps=100)
     snap = srv.stats()

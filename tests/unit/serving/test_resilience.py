@@ -556,8 +556,9 @@ class TestChaos:
         _, _, engine = stack
         rng = np.random.default_rng(14)
         fi = FaultInjector(seed=0)   # empty schedule through warmup
-        srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
-                          guard_numerics=True, fault_injector=fi)
+        srv = make_server(engine, pool, own_programs=True, num_slots=2,
+                          max_queue_depth=16, guard_numerics=True,
+                          fault_injector=fi)
         for count in (1, 2):         # cover single + batched admission
             for _ in range(count):
                 srv.submit(rng.integers(0, 64, size=6).astype(np.int32),

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
+from deepspeed_tpu.models.lm_config import TransformerConfig
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.serving import (ID_STRIDE, FinishReason,
                                    NoLiveReplicaError, ReplicaRouter,
                                    RequestState, ServingEngine)

@@ -9,83 +9,46 @@ state group, latent pages, a routed FFN and speculative decoding (which
 settles inside the step), including preempt / resume, ``cancel`` and a
 deadline with a bundle in flight, and an EOS, the one end that costs a dead
 row. A request ended by its budget gets exactly ``max_new_tokens`` and
-takes no row after its last."""
+takes no row after its last.
 
-import jax
+The two arms of a case run on ONE server, which traces its programs once:
+each arm starts from ``emptied(srv)`` (nothing seated, queued or in flight,
+the audit clean, the pool reset to a new server's) and reads the counters it
+asserts on as differences."""
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import (TransformerConfig,
-                                                 TransformerLM,
-                                                 transformer_config)
 from deepspeed_tpu.serving import FinishReason, RequestState, ServingEngine
 from deepspeed_tpu.telemetry import Tracer
+from tests.unit.kinds import TINY, kind_stack
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
+from .conftest import emptied, traced_once
+
 PS = 8
-# the cache kinds the page pool serves, at test sizes, the kernels in
-# interpret mode (tests/unit/serving/test_chunk_beside_decode.py's): chunks
-# of 4 in pages of 8, so prompts stream in beside running slots and every
-# chunk but a prompt's last goes with the decode rows as ONE program
-KINDS = {
-    "fused-chunk": ("gpt-neox", dict(n_embd=32, n_layer=2, n_head=4)),
-    "routed-ffn": ("mellum", dict(
-        n_embd=32, n_layer=2, n_head=4, n_kv_head=2, head_size=16,
-        ffn_dim=16, layer_types=["sliding_attention", "full_attention"],
-        sliding_window=16, n_experts=4, experts_per_token=2)),
-    "latent-pages": ("moonlight", dict(
-        n_embd=32, n_layer=2, n_head=4, kv_lora_rank=16, qk_nope_head_dim=8,
-        qk_rope_head_dim=8, v_head_dim=8, ffn_dim=16, n_experts=4,
-        experts_per_token=2, n_shared_experts=1, first_k_dense=1,
-        dense_ffn_dim=48, routed_scaling_factor=2.446)),
-    "state-group": ("granite-hybrid", dict(
-        n_embd=32, n_layer=2, n_head=4, n_kv_head=2, ffn_dim=48,
-        layer_types=["mamba", "attention"], mamba_n_heads=4,
-        mamba_d_head=8, mamba_d_state=8)),
-}
+# the cache kinds the page pool serves (rows of tests/unit/kinds.py), the
+# kernels in interpret mode, as tests/unit/serving/test_chunk_beside_decode.py
+# serves them: chunks of 4 in pages of 8, so prompts stream in beside
+# running slots and every chunk but a prompt's last goes with the decode
+# rows as ONE program
+KINDS = {"fused-chunk": "plain", "routed-ffn": "window_routed",
+         "latent-pages": "latent_routed", "state-group": "state_group"}
 KIND_SERVER = dict(num_slots=3, prefill_chunk=4, prefill_token_budget=8,
                    paged_kv={"kernel": "on", "page_size": PS,
                              "prefix_cache": False})
 
 
-@pytest.fixture(scope="module")
-def stack():
-    cfg = TransformerConfig(**TINY)
-    model = TransformerLM(cfg)
-    ids = jax.random.randint(jax.random.PRNGKey(0), (1, 8), 0, 64)
-    params = model.init({"params": jax.random.PRNGKey(1)}, ids,
-                        method=model.logits)["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
-    return model, params, engine
-
-
-_KIND_ENGINES = {}
-
-
 def kind_engine(kind):
-    if kind not in _KIND_ENGINES:
-        family, widths = KINDS[kind]
-        model = TransformerLM(transformer_config(
-            family, vocab_size=64, max_seq_len=64, dtype=jnp.float32,
-            **widths))
-        params = jax.jit(lambda: model.init(
-            jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32),
-            method=model.logits))()["params"]
-        _KIND_ENGINES[kind] = ds.init_inference(
-            model=model, model_parameters=params,
-            config={"dtype": "float32"})
-    return _KIND_ENGINES[kind]
+    return kind_stack(KINDS[kind])[2]
 
 
 def make_srv(engine, num_slots=3, **kw):
     kw.setdefault("prefill_chunk", PS)
     kw.setdefault("tracer", Tracer())
-    return ServingEngine(engine, num_slots=num_slots, max_queue_depth=32,
-                         **kw)
+    return traced_once(ServingEngine(
+        engine, num_slots=num_slots, max_queue_depth=32, **kw))
 
 
 def _workload(seed=11, n=8):
@@ -125,25 +88,39 @@ def counter(srv, name):
     return int(srv.registry.counter(name).value)
 
 
-def same_outcomes(srv_a, reqs_a, srv_b, reqs_b):
+def counting(srv, name):
+    """``counter(srv, name)`` from now on: the two arms of a case run on
+    one server, each from :func:`emptied`, and read their own counts."""
+    base = counter(srv, name)
+    return lambda: counter(srv, name) - base
+
+
+def same_outcomes(srv, reqs_a, reqs_b):
     for a, b in zip(reqs_a, reqs_b):
         assert a.state == b.state == RequestState.FINISHED, a.finish_reason
         assert a.finish_reason == b.finish_reason
         np.testing.assert_array_equal(a.tokens(), b.tokens())
-        ev_a = srv_a.timelines.events_of(a.request_id)
-        ev_b = srv_b.timelines.events_of(b.request_id)
+        ev_a = srv.timelines.events_of(a.request_id)
+        ev_b = srv.timelines.events_of(b.request_id)
         assert ev_a[0] == ev_b[0] and ev_a[-1] == ev_b[-1]
 
 
-def _servers(stack, case):
-    """Two servers of one kind: the one that runs ahead, the one driven
-    serially."""
+def _server(stack, case):
     if case in KINDS:
-        return [make_srv(kind_engine(case), **KIND_SERVER) for _ in "ab"]
+        return make_srv(kind_engine(case), **KIND_SERVER)
     extra = {"plain": {},
              "paged-kernel": {"paged_kv": {"page_size": PS, "kernel": "on"}},
              "spec": {"spec_decode": {"k": 3, "drafter": "ngram"}}}[case]
-    return [make_srv(stack[2], **extra) for _ in "ab"]
+    return make_srv(stack[2], **extra)
+
+
+def tally(srv):
+    """The counters the parity case reads, as they stand: it drives ONE
+    server twice (its programs are traced once), so an arm's count is a
+    difference of two of these."""
+    said = {name: counter(srv, "serving/" + name) for name in (
+        "fused_steps", "steps_run_ahead", "settled_early")}
+    return dict(said, slot_steps=srv.metrics.slot_steps)
 
 
 @pytest.mark.parametrize("case", ["plain", "paged-kernel", "spec",
@@ -154,24 +131,27 @@ def test_run_ahead_outcome_parity(stack, case):
     reason and first/terminal timeline events; ended by its budget, with
     exactly ``max_new_tokens``, and no slot takes a row after its last."""
     prompts, budgets = _workload()
-    ahead, serial = _servers(stack, case)
-    assert ahead._runs_ahead == (case != "spec")
-    got = run_traffic(ahead, prompts, budgets, serial=False)
-    want = run_traffic(serial, prompts, budgets, serial=True)
-    same_outcomes(ahead, got, serial, want)
+    srv = _server(stack, case)
+    assert srv._runs_ahead == (case != "spec")
+    got = run_traffic(srv, prompts, budgets, serial=False)
+    ahead = tally(srv)
+    # the serial arm: the same server, its pool as a new server has it
+    want = run_traffic(emptied(srv), prompts, budgets, serial=True)
+    serial = {name: n - ahead[name] for name, n in tally(srv).items()}
+    same_outcomes(srv, got, want)
     for req, budget in zip(got, budgets):
         assert req.finish_reason == FinishReason.LENGTH
         assert len(req.output_tokens) == budget
     if case in KINDS:
-        assert counter(ahead, "serving/fused_steps") > 0
+        assert ahead["fused_steps"] > 0
     if case != "spec":
         # every row of every decode program gave a token that was kept: a
         # request's first comes from its admission, the others one a row
-        for srv in (ahead, serial):
-            assert srv.metrics.slot_steps == sum(budgets) - len(budgets)
-        assert counter(ahead, "serving/steps_run_ahead") > len(prompts)
-        assert counter(serial, "serving/steps_run_ahead") == 0
-    assert counter(ahead, "serving/settled_early") == 0
+        for arm in (ahead, serial):
+            assert arm["slot_steps"] == sum(budgets) - len(budgets)
+        assert ahead["steps_run_ahead"] > len(prompts)
+        assert serial["steps_run_ahead"] == 0
+    assert ahead["settled_early"] == 0
 
 
 def test_run_ahead_matches_generate(stack):
@@ -194,8 +174,10 @@ def test_run_ahead_preempt_resume_parity(stack):
     rng = np.random.default_rng(23)
     prompt = rng.integers(0, 64, size=14).astype(np.int32)
 
+    srv = make_srv(engine, num_slots=2)
+
     def run(serial):
-        srv = make_srv(engine, num_slots=2)
+        settled = counting(emptied(srv), "serving/settled_early/preempt")
         req = srv.submit(prompt, max_new_tokens=10)
         for _ in range(4):
             srv.step()
@@ -206,8 +188,7 @@ def test_run_ahead_preempt_resume_parity(stack):
         assert srv._in_flight is None and not srv._unread
         assert req.preemptions == 1 and req.state == RequestState.QUEUED
         carried = list(req.output_tokens)
-        assert counter(srv, "serving/settled_early/preempt") == \
-            (0 if serial else 1)
+        assert settled() == (0 if serial else 1)
         drive(srv, serial)
         srv.check_invariants()
         return req, carried
@@ -231,21 +212,24 @@ def test_a_starved_server_settles_early_only_for_a_victim(stack):
     prompts = [rng.integers(0, 64, size=n).astype(np.int32)
                for n in (5, 7, 6, 4)]
 
+    srv = make_srv(engine, num_slots=2, preempt_queue_threshold=1,
+                   preempt_min_run_steps=5)
+
     def run(serial):
-        srv = make_srv(engine, num_slots=2, preempt_queue_threshold=1,
-                       preempt_min_run_steps=5)
+        emptied(srv)
+        settled, ran_ahead, evicted = (counting(srv, name) for name in (
+            "serving/settled_early", "serving/steps_run_ahead",
+            "serving/settled_early/preempt"))
         reqs = [srv.submit(p, max_new_tokens=12) for p in prompts]
         for _ in range(5):      # residents seated in step 1: too young
             srv.step()
             if serial:
                 srv.settle()
         assert srv.pending == 2 and srv.live_count == 2
-        assert counter(srv, "serving/settled_early") == 0
-        assert counter(srv, "serving/steps_run_ahead") == \
-            (0 if serial else 4)
+        assert settled() == 0
+        assert ran_ahead() == (0 if serial else 4)
         srv.step()              # held five steps: one is evicted
-        assert counter(srv, "serving/settled_early/preempt") == \
-            (0 if serial else 1)
+        assert evicted() == (0 if serial else 1)
         assert sum(r.preemptions for r in reqs) == 1
         drive(srv, serial)
         srv.settle()
@@ -332,8 +316,10 @@ def test_run_ahead_cancel_midflight(stack):
     keep_p = rng.integers(0, 64, size=9).astype(np.int32)
     kill_p = rng.integers(0, 64, size=12).astype(np.int32)
 
+    srv = make_srv(engine, num_slots=2)
+
     def run(serial):
-        srv = make_srv(engine, num_slots=2)
+        settled = counting(emptied(srv), "serving/settled_early/cancel")
         keep = srv.submit(keep_p, max_new_tokens=6)
         kill = srv.submit(kill_p, max_new_tokens=20)
         for _ in range(6):
@@ -343,8 +329,7 @@ def test_run_ahead_cancel_midflight(stack):
         assert (srv._in_flight is None) == serial
         assert srv.cancel(kill.request_id) is kill
         assert srv._in_flight is None
-        assert counter(srv, "serving/settled_early/cancel") == \
-            (0 if serial else 1)
+        assert settled() == (0 if serial else 1)
         drive(srv, serial)
         srv.check_invariants()
         assert keep.state == RequestState.FINISHED
@@ -403,28 +388,31 @@ def test_an_eos_costs_one_dead_row(stack, paged):
     rng = np.random.default_rng(37)
     prompt = rng.integers(0, 64, size=11).astype(np.int32)
     other = rng.integers(0, 64, size=6).astype(np.int32)
-    free = run_traffic(make_srv(engine, paged_kv=paged), [prompt], [12],
-                       serial=True)[0].output_tokens
+    srv = make_srv(engine, paged_kv=paged)
+    free = run_traffic(srv, [prompt], [12], serial=True)[0].output_tokens
     at = next(i for i in range(3, 12) if free[i] not in free[:i])
 
     def run(serial):
-        srv = make_srv(engine, paged_kv=paged)
+        metrics = emptied(srv).metrics
+        rows, tokens = metrics.slot_steps, metrics.decode_tokens
         a = srv.submit(prompt, max_new_tokens=12, eos_token_id=free[at])
         b = srv.submit(other, max_new_tokens=9)
         drive(srv, serial)
         srv.settle()
         srv.check_invariants()
         assert not srv._unread and not srv._closing
-        return srv, a, b
+        return (metrics.slot_steps - rows, metrics.decode_tokens - tokens,
+                a, b)
 
-    (ahead, a, b), (serial, a_s, b_s) = run(False), run(True)
+    (ahead, tokens, a, b), (serial, tokens_s, a_s, b_s) = \
+        run(False), run(True)
     assert a.finish_reason == a_s.finish_reason == FinishReason.EOS
     assert a.output_tokens == a_s.output_tokens == free[:at + 1]
     assert b.output_tokens == b_s.output_tokens and len(b.output_tokens) == 9
     rows = at + 8                 # a's decode tokens + b's
-    assert serial.metrics.slot_steps == rows
-    assert ahead.metrics.slot_steps == rows + 1
-    assert ahead.metrics.decode_tokens == serial.metrics.decode_tokens
+    assert serial == rows
+    assert ahead == rows + 1
+    assert tokens == tokens_s
 
 
 def test_an_eos_in_flight_under_page_pressure_maps_nothing(stack):
@@ -451,11 +439,15 @@ def test_an_eos_in_flight_under_page_pressure_maps_nothing(stack):
     else:
         pytest.fail("no prompt here ends on a token it has not given yet")
 
+    srv = make_srv(engine, num_slots=2, prefill_chunk=16,
+                   paged_kv=dict(paged, num_pages=3))
+
     def run(serial):
         # a holds one page, b two: the three there are. a's column 8 is
         # paged in with its EOS in flight; b's column 16 comes later
-        srv = make_srv(engine, num_slots=2, prefill_chunk=16,
-                       paged_kv=dict(paged, num_pages=3))
+        emptied(srv)
+        evicted, settled = (counting(srv, name) for name in (
+            "serving/settled_early/preempt", "serving/settled_early"))
         a = srv.submit(prompt, max_new_tokens=8, eos_token_id=free[at])
         b = srv.submit(other, max_new_tokens=6)
         steps = 0
@@ -472,14 +464,14 @@ def test_an_eos_in_flight_under_page_pressure_maps_nothing(stack):
         srv.check_invariants()
         assert srv.pool.free_page_count == 3
         assert a.preemptions == b.preemptions == 0
-        return srv, a, b
+        return evicted(), settled(), a, b
 
-    (ahead, a, b), (serial, a_s, b_s) = run(False), run(True)
+    (ahead, _, a, b), (_, serial, a_s, b_s) = run(False), run(True)
     assert a.finish_reason == a_s.finish_reason == FinishReason.EOS
     assert a.output_tokens == a_s.output_tokens == free
     assert b.output_tokens == b_s.output_tokens and len(b.output_tokens) == 6
-    assert counter(ahead, "serving/settled_early/preempt") == 1
-    assert counter(serial, "serving/settled_early") == 0
+    assert ahead == 1       # settled_early/preempt where it ran ahead
+    assert serial == 0      # settled_early, of any cause, where it did not
 
 
 @pytest.mark.parametrize("paged", [False, {"page_size": PS, "kernel": "off"}],
@@ -508,10 +500,11 @@ def test_the_capacity_edge_ends_on_the_decode_that_fills_the_row(
         ids = np.append(ids, np.int32(jnp.argmax(logits[0, -1])))
     want = ids[len(prompt):]
 
+    srv = make_srv(engine, num_slots=2, paged_kv=paged)
+    srv.scheduler.capacity = srv.scheduler.num_pages = None
+
     def run(serial):
-        srv = make_srv(engine, num_slots=2, paged_kv=paged)
-        srv.scheduler.capacity = srv.scheduler.num_pages = None
-        req = srv.submit(prompt, max_new_tokens=8)
+        req = emptied(srv).submit(prompt, max_new_tokens=8)
         drive(srv, serial)
         srv.settle()
         srv.check_invariants()

@@ -103,7 +103,8 @@ def test_slot_reuse_does_not_recompile(stack, pool):
     masked padding, not shape changes."""
     _, _, engine = stack
     rng = np.random.default_rng(5)
-    srv = make_server(engine, pool, num_slots=2, max_queue_depth=16)
+    srv = make_server(engine, pool, own_programs=True, num_slots=2,
+                      max_queue_depth=16)
     # wave A: compile everything once — 3 requests over 2 slots so both
     # admission batch buckets (nB=2 full step, nB=1 single refill) warm up
     for _ in range(3):

@@ -115,8 +115,8 @@ def test_spec_churn_does_not_recompile(stack, pool):
     rng = np.random.default_rng(31)
 
     def wave(n):
-        srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
-                          spec_decode=_spec(k=4))
+        srv = make_server(engine, pool, own_programs=True, num_slots=2,
+                          max_queue_depth=16, spec_decode=_spec(k=4))
         for p in _mixed_prompts(rng, n):
             srv.submit(p, max_new_tokens=5)
         srv.run_until_drained(max_steps=200)

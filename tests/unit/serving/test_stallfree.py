@@ -17,7 +17,7 @@ def _prompts(rng, lengths):
     return [rng.integers(1, 64, size=n).astype(np.int32) for n in lengths]
 
 
-def test_chunked_prefill_parity_with_generate(stack, pool):
+def test_chunked_prefill_parity_with_generate(stack, servers, pool):
     """Prompts longer than the chunk width stream in chunk by chunk; the
     resulting greedy tokens must bitwise-match whole-prompt generate()."""
     _, _, engine = stack
@@ -25,8 +25,7 @@ def test_chunked_prefill_parity_with_generate(stack, pool):
     lengths = [40, 33, 17]          # 3 chunks, 3 chunks (odd tail), 2 chunks
     budgets = [6, 5, 4]
     prompts = _prompts(rng, lengths)
-    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
-                      prefill_chunk=16)
+    srv = servers(pool, num_slots=2, max_queue_depth=8, prefill_chunk=16)
     assert srv._stall_free and srv.prefill_chunk == 16
     reqs = [srv.submit(p, max_new_tokens=b) for p, b in zip(prompts, budgets)]
     srv.run_until_drained(max_steps=300)
@@ -37,7 +36,7 @@ def test_chunked_prefill_parity_with_generate(stack, pool):
                                       err_msg=f"req {req.request_id}")
 
 
-def test_bucket_boundary_prompt_lengths(stack, pool):
+def test_bucket_boundary_prompt_lengths(stack, servers, pool):
     """Power-of-two bucket edges (15/16/17, 31/32/33) and a prompt that
     exactly fills its slot with its budget (60 + 4 = capacity 64) must
     all admit, finish, and match generate() bitwise."""
@@ -46,8 +45,7 @@ def test_bucket_boundary_prompt_lengths(stack, pool):
     lengths = [15, 16, 17, 31, 32, 33, 60]
     budgets = [3, 3, 3, 3, 3, 3, 4]
     prompts = _prompts(rng, lengths)
-    srv = make_server(engine, pool, num_slots=2, max_queue_depth=8,
-                      prefill_chunk=16)
+    srv = servers(pool, num_slots=2, max_queue_depth=8, prefill_chunk=16)
     reqs = [srv.submit(p, max_new_tokens=b) for p, b in zip(prompts, budgets)]
     srv.run_until_drained(max_steps=400)
     for req, prompt, budget in zip(reqs, prompts, budgets):
@@ -226,8 +224,8 @@ def test_no_recompile_across_chunked_and_batched_churn(stack, pool):
     compiled program."""
     _, _, engine = stack
     rng = np.random.default_rng(53)
-    srv = make_server(engine, pool, num_slots=2, max_queue_depth=16,
-                      prefill_chunk=16)
+    srv = make_server(engine, pool, own_programs=True, num_slots=2,
+                      max_queue_depth=16, prefill_chunk=16)
     # warmup: two shorts together (nB=2), a straggler short (nB=1 refill),
     # and a long prompt (chunk program at several offsets)
     for n, b in [(6, 3), (9, 3), (7, 3), (40, 3)]:

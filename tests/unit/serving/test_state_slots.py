@@ -10,8 +10,11 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerLM, transformer_config
+from deepspeed_tpu.models.lm_config import transformer_config
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.serving import RequestState, ServingEngine
+
+from .conftest import traced_once
 
 SMALL = dict(vocab_size=96, max_seq_len=128, n_embd=32, n_layer=2, n_head=4,
              n_kv_head=2, head_size=16, ffn_dim=48, dtype=jnp.float32)
@@ -32,7 +35,8 @@ def stack():
 
 
 def server(engine, slots, **kw):
-    return ServingEngine(engine, num_slots=slots, prefill_chunk=CHUNK, **kw)
+    return traced_once(ServingEngine(engine, num_slots=slots,
+                                     prefill_chunk=CHUNK, **kw))
 
 
 def record_logits(srv):

@@ -12,13 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
 from deepspeed_tpu.inference.engine import (pack_chunk_args,
                                             unpack_chunk_args)
-from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                 transformer_config)
 from deepspeed_tpu.serving import RequestState, ServingEngine
 from deepspeed_tpu.serving.resilience import FaultInjector, InjectedFault
+from tests.unit.kinds import MELLUM_PERIOD, kind_stack
 
 from .conftest import make_server
 
@@ -57,9 +55,9 @@ def _prompts():
 
 
 def _serve(engine, pool, do_sample, **kw):
-    srv = make_server(engine, pool, num_slots=3, prefill_chunk=8,
-                      do_sample=do_sample, temperature=0.8, top_k=20, seed=7,
-                      **kw)
+    srv = make_server(engine, pool, own_programs=True, num_slots=3,
+                      prefill_chunk=8, do_sample=do_sample, temperature=0.8,
+                      top_k=20, seed=7, **kw)
     prompts = _prompts()
     reqs = [srv.submit(p, max_new_tokens=10) for p in prompts[:2]]
     # every step settled before the next, the parents' order: a slot is
@@ -244,29 +242,13 @@ def test_a_chunks_arguments_pack_into_one_vector_and_back(rows):
 
 # ---------------------------------------------------------------- (a)
 WINDOW, PAGE = 16, 8
-ROPE = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000,
-                           "factor": 16,
-                           "original_max_position_embeddings": 8192,
-                           "attention_factor": 1.2772588722239782},
-        "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
-GROUPED = dict(vocab_size=128, max_seq_len=128, n_embd=64, n_layer=4,
-               n_head=4, n_kv_head=2, head_size=32, ffn_dim=32,
-               layer_types=["sliding_attention"] * 3 + ["full_attention"],
-               sliding_window=WINDOW, rope_theta=500000,
-               rope_parameters=ROPE, n_experts=8, experts_per_token=2,
-               dtype=jnp.float32)
 
 
 @pytest.fixture(scope="module")
 def grouped_engine():
     """A small ``mellum``: three window layers to one full one, so a paged
     pool keeps two page groups (``table`` and ``table_win``)."""
-    model = TransformerLM(transformer_config("mellum", **GROUPED))
-    params = jax.jit(lambda: model.init(
-        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32),
-        method=model.logits))()["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
+    engine = kind_stack("window_routed", **MELLUM_PERIOD)[2]
     engine._ensure_params(jnp.zeros((1, 2), jnp.int32))
     return engine
 

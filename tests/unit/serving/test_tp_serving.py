@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
+from deepspeed_tpu.models.lm_config import TransformerConfig
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.serving import RequestState, ServingEngine
 
 TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,

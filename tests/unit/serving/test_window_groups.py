@@ -12,9 +12,9 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import (TransformerLM,
-                                                 kv_cache_groups,
-                                                 transformer_config)
+from deepspeed_tpu.models.cache_kinds import kv_cache_groups
+from deepspeed_tpu.models.lm_config import transformer_config
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.serving import ServingEngine
 from deepspeed_tpu.serving.paged_pool import PagedKVPool, PagePoolExhausted
 
@@ -25,19 +25,16 @@ if ROOT not in sys.path:
 
 from perf.reference import mellum as ref  # noqa: E402
 
-from .conftest import watch_kernel_reads  # noqa: E402
+from tests.unit.kinds import (MELLUM_PERIOD, kind_stack,  # noqa: E402
+                              kind_widths)
+
+from .conftest import traced_once, watch_kernel_reads  # noqa: E402
 
 WINDOW, PAGE, CTX = 16, 8, 128
-ROPE = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000,
-                           "factor": 16,
-                           "original_max_position_embeddings": 8192,
-                           "attention_factor": 1.2772588722239782},
-        "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
-SMALL = dict(vocab_size=128, max_seq_len=CTX, n_embd=64, n_layer=8, n_head=4,
-             n_kv_head=2, head_size=32, ffn_dim=32,
-             layer_types=(["sliding_attention"] * 3 + ["full_attention"]) * 2,
-             sliding_window=WINDOW, rope_theta=500000, rope_parameters=ROPE,
-             n_experts=8, experts_per_token=2, dtype=jnp.float32)
+# two periods of tests/unit/kinds.py's
+OVER = dict(MELLUM_PERIOD, n_layer=8,
+            layer_types=MELLUM_PERIOD["layer_types"] * 2)
+SMALL = kind_widths("window_routed", **OVER)
 # float32 at "highest" against float32 through pages, chunks and kernels:
 # logits of size ~3 agree to ~1e-5. A key one position outside the window,
 # a stale page or the other rotary table moves them by ~1e-1
@@ -46,13 +43,9 @@ ATOL = 2e-4
 
 @pytest.fixture(scope="module")
 def stack():
-    cfg = transformer_config("mellum", **SMALL)
-    model = TransformerLM(cfg)
-    params = jax.jit(lambda: model.init(
-        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32),
-        method=model.logits))()["params"]
-    engine = ds.init_inference(model=model, model_parameters=params,
-                               config={"dtype": "float32"})
+    model, params, engine = kind_stack("window_routed", **OVER)
+    cfg = model.config
+    assert (cfg.max_seq_len, cfg.sliding_window) == (CTX, WINDOW)
     engine._ensure_params(jnp.zeros((1, 2), jnp.int32))
     logits_fn = ref.make_forward(
         n_head=cfg.n_head, n_kv_head=cfg.n_kv_head, head_dim=cfg.head_dim,
@@ -60,6 +53,16 @@ def stack():
         rope_parameters=cfg.rope_parameters,
         experts_per_token=cfg.experts_per_token)
     return cfg, model, params, engine, logits_fn
+
+
+def _pool(stack, kernel):
+    """A new pool of three slots and forty pages, bound; the programs of
+    every such pool of the module (the churn servers' among them) are
+    traced once (``traced_once``)."""
+    pool = PagedKVPool(stack[1].kv_cache_spec(), num_slots=3, num_pages=40,
+                       page_size=PAGE, prefix_cache=False, kernel=kernel)
+    pool.bind_engine(stack[3])
+    return traced_once(pool)
 
 
 def test_groups_of_the_spec(stack):
@@ -85,9 +88,7 @@ def test_chunked_prefill_then_decode_past_the_window_matches_the_reference(
     than window / page + 1 pages of a slot."""
     cfg, model, params, engine, logits_fn = stack
     chunk = 8
-    pool = PagedKVPool(model.kv_cache_spec(), num_slots=3, num_pages=40,
-                       page_size=PAGE, prefix_cache=False, kernel=kernel)
-    pool.bind_engine(engine)
+    pool = _pool(stack, kernel)
     rng = np.random.default_rng(0)
     seqs = {0: rng.integers(1, 128, 4 * WINDOW + 9),
             1: rng.integers(1, 128, 4 * WINDOW + 30)}
@@ -150,10 +151,11 @@ def test_a_swapped_window_page_fails_the_reference_by_its_worst_limit(stack):
     cfg, model, params, engine, logits_fn = stack
     prompt = np.random.default_rng(3).integers(1, 128, 40).astype(np.int32)
 
+    # one pool for both decodes, reset between them to what a new pool is
+    pool = _pool(stack, "on")
+
     def decode(swap):
-        pool = PagedKVPool(model.kv_cache_spec(), num_slots=2, num_pages=40,
-                           page_size=PAGE, prefix_cache=False, kernel="on")
-        pool.bind_engine(engine)
+        pool.reset()
         slot = pool.alloc()
         pool.reset_row(slot)
         for pos in range(0, 40, 8):
@@ -170,10 +172,10 @@ def test_a_swapped_window_page_fails_the_reference_by_its_worst_limit(stack):
         for _ in range(23):
             pool.ensure_writable(slot, int(pool.starts[slot]),
                                  int(pool.starts[slot]) + 1)
-            tokens = np.zeros((2, 1), np.int32)
+            tokens = np.zeros((3, 1), np.int32)
             tokens[slot, 0] = out[-1]
             lg = pool.run_decode(engine, jnp.asarray(tokens[:, 0]))
-            pool.advance(np.asarray([1, 0], np.int32))
+            pool.advance(np.asarray([1, 0, 0], np.int32))
             out.append(int(np.argmax(np.asarray(lg[slot, 0]))))
         return ref.check_greedy(logits_fn, params, prompt, out, CTX, 24,
                                 2.0 ** -5)
@@ -300,9 +302,9 @@ def test_freed_slots_of_both_groups_are_no_step_under_churn(stack):
     budgets = [3, 12, 5, 9, 4]
 
     def churn(kernel):
-        srv = ServingEngine(engine, num_slots=3, prefill_chunk=8,
-                            guard_numerics=True, tracer=Tracer(),
-                            paged_kv=dict(PAGED, kernel=kernel))
+        srv = traced_once(ServingEngine(
+            engine, num_slots=3, prefill_chunk=8, guard_numerics=True,
+            tracer=Tracer(), paged_kv=dict(PAGED, kernel=kernel)))
         pool = srv.pool
 
         def device_steps(rows):     # one layer of each group
@@ -337,8 +339,6 @@ def test_freed_slots_of_both_groups_are_no_step_under_churn(stack):
     for args, ((reads, slots), total, seated) in zip(spans, record):
         assert (args["pool_reads"], args["read_slots"]) == (reads, slots)
         assert reads == total and slots == seated
-        # a KV head's two query heads' decode rows share one tile
-        assert (args["read_rows"], args["read_rows_live"]) == (8, 2)
     assert any(0 < slots < 3 for (_, slots), _, _ in record)
 
 
@@ -358,9 +358,7 @@ def test_a_chunk_reads_and_writes_both_groups_in_place(stack):
         params, np.pad(seq, (0, CTX - len(seq))).astype(np.int32),
         np.arange(len(seq))))
     for chunk, in_place in ((8, True), (WINDOW, False)):
-        pool = PagedKVPool(model.kv_cache_spec(), num_slots=2, num_pages=40,
-                           page_size=PAGE, prefix_cache=False, kernel="on")
-        pool.bind_engine(engine)
+        pool = _pool(stack, "on")
         assert pool.reads_in_place(chunk) is in_place
         slot = pool.alloc()
         pool.reset_row(slot)

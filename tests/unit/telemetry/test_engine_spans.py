@@ -15,12 +15,12 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models.transformer_lm import TransformerConfig, TransformerLM
+from deepspeed_tpu.models.lm_config import TransformerConfig
+from deepspeed_tpu.models.transformer_lm import TransformerLM
 from deepspeed_tpu.telemetry import Tracer, default_tracer
+from tests.unit.kinds import TINY, kind_stack
 from tests.unit.simple_model import SimpleModel, base_config, random_batch
 
-TINY = dict(vocab_size=64, max_seq_len=64, n_embd=32, n_layer=2, n_head=4,
-            dtype=jnp.float32)
 SERVING_PHASES = ["serving/boundary", "serving/grant", "serving/sync",
                   "serving/replay", "serving/after_step"]
 TRAIN_PHASES = ["train/stack_batch", "train/dispatch", "train/sync",
@@ -482,15 +482,9 @@ def hybrid_account():
     """A server of mamba and attention layers over the page pool (a state
     group beside paged K/V, PR 47), driven as ``account`` is: a chunked
     prompt, a batched and a single bucketed admission, plain decode steps."""
-    from deepspeed_tpu.models.transformer_lm import transformer_config
-
-    model = TransformerLM(transformer_config(
-        "granite-hybrid", **dict(TINY, n_layer=4, n_kv_head=2, ffn_dim=48),
-        layer_types=["mamba", "attention"] * 2, mamba_n_heads=4,
-        mamba_d_head=8, mamba_d_state=8))
-    params = model.init({"params": jax.random.PRNGKey(1)},
-                        jnp.zeros((1, 8), jnp.int32),
-                        method=model.logits)["params"]
+    # (two periods: two state layers and two of K/V)
+    model, params, _ = kind_stack(
+        "state_group", n_layer=4, layer_types=["mamba", "attention"] * 2)
     srv = ds.init_serving(model, model_parameters=params,
                           config={"dtype": "float32"}, num_slots=4,
                           max_queue_depth=8, prefill_chunk=8,
@@ -597,20 +591,7 @@ def kimi_account():
     """A server of kda and latent attention layers with a routed FFN that
     holds 2 of its 8 experts (PR 50: a state group beside latent pages),
     driven as ``hybrid_account`` is."""
-    from deepspeed_tpu.models.transformer_lm import transformer_config
-
-    model = TransformerLM(transformer_config(
-        "kimi_linear", **dict(TINY, n_layer=4, kv_lora_rank=16,
-                              qk_nope_head_dim=8, qk_rope_head_dim=8,
-                              v_head_dim=8, ffn_dim=16, n_experts=8,
-                              experts_per_token=2, experts_held=2,
-                              n_shared_experts=1, dense_ffn_dim=48,
-                              kda_n_heads=2, kda_d_head=8),
-        layer_types=["kda", "kda", "kda", "attention"],
-        mlp_layer_types=["dense", "sparse", "sparse", "sparse"]))
-    params = model.init({"params": jax.random.PRNGKey(1)},
-                        jnp.zeros((1, 8), jnp.int32),
-                        method=model.logits)["params"]
+    model, params, _ = kind_stack("kda_latent")
     srv = ds.init_serving(model, model_parameters=params,
                           config={"dtype": "float32"}, num_slots=4,
                           max_queue_depth=8, prefill_chunk=8,
@@ -682,16 +663,7 @@ def lfm2_account():
     attention with a routed FFN behind two dense layers (PR 54: a state
     group of ONE leaf, the convolution's tail, beside paged K/V), driven as
     ``kimi_account`` is."""
-    from deepspeed_tpu.models.transformer_lm import transformer_config
-
-    model = TransformerLM(transformer_config(
-        "lfm2_moe", **dict(TINY, n_layer=4, n_kv_head=2, ffn_dim=16,
-                           n_experts=8, experts_per_token=2,
-                           first_k_dense=2, dense_ffn_dim=48),
-        layer_types=["conv", "conv", "full_attention", "conv"]))
-    params = model.init({"params": jax.random.PRNGKey(1)},
-                        jnp.zeros((1, 8), jnp.int32),
-                        method=model.logits)["params"]
+    model, params, _ = kind_stack("conv_tail")
     srv = ds.init_serving(model, model_parameters=params,
                           config={"dtype": "float32"}, num_slots=4,
                           max_queue_depth=8, prefill_chunk=8,
@@ -771,17 +743,7 @@ def sala_account():
     in place (the sparse read is the kernel path's), driven as
     ``lfm2_account`` is: a prompt of three chunks past the toy
     ``dense_len``, then one admitted whole."""
-    from deepspeed_tpu.models.transformer_lm import transformer_config
-
-    model = TransformerLM(transformer_config(
-        "minicpm_sala", **dict(TINY, n_layer=4, n_kv_head=2, ffn_dim=16),
-        mixer_types=["minicpm4", "lightning-attn", "minicpm4", "minicpm4"],
-        sparse_attention=dict(kernel_size=2, kernel_stride=1, block_size=4,
-                              init_blocks=1, window_size=8, topk=2,
-                              dense_len=16)))
-    params = model.init({"params": jax.random.PRNGKey(1)},
-                        jnp.zeros((1, 8), jnp.int32),
-                        method=model.logits)["params"]
+    model, params, _ = kind_stack("sparse_lightning")
     srv = ds.init_serving(model, model_parameters=params,
                           config={"dtype": "float32"}, num_slots=4,
                           max_queue_depth=8, prefill_chunk=8,
