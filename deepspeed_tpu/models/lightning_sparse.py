@@ -57,9 +57,10 @@ class LightningMixer(nn.Module):
 
     which is ``ops/state_space``'s recurrence ``H <- a H + x (x) B``, ``y =
     H C`` with ``x = v``, ``B = k``, ``C = q / sqrt(head_dim)`` a head's own,
-    ``dt = 1`` and ``A_h = log l_h``: the kernels are that module's, under
-    the names ``lightning_decode`` / ``lightning_chunk``
-    (``ops/lightning.py``). Without a cache: whole sequences from an empty
+    ``dt = 1`` and ``A_h = log l_h``: the leaf and the chunk kernel are
+    that module's, the decode kernel its own, under the names
+    ``lightning_decode`` / ``lightning_chunk`` (``ops/lightning.py``).
+    Without a cache: whole sequences from an empty
     state. With one, ``kv_cache`` holds the stacked leaf ``s`` whole with
     ``layer``, ``start``, ``rows`` and ``valid`` (as ``Mamba2Mixer``): a
     token past ``valid`` is padding and leaves the state alone, an entry at

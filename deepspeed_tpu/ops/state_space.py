@@ -36,6 +36,9 @@ product).
 * ``ssm_decode``: one token a row. A step holds :data:`DECODE_TILES` tiles
   of the row: each is decayed, takes ``B (x) dt x`` and is summed against
   ``C``, on the VPU. 2 x ``4 tiles N lanes`` bytes of state a row a layer.
+  ONE group: ``B`` and ``C`` are the same two columns under every tile. A
+  layer whose heads have their own (Lightning's key and query) keeps this
+  leaf and brings its own decode kernel, ``ops/lightning.py``.
 * ``ssm_chunk``: ``T <= CHUNK`` tokens of a row after its carried state,
   in the chunked (state-space-dual) form, one tile a step. With ``L_t`` the
   running sum of ``dt A`` of a head: ``y_t = exp(L_t) C_t H_0 + sum_{s <=
@@ -51,7 +54,8 @@ product).
   tokens (the published ``mamba_chunk_size`` 256 is a blocking of the same
   sums, not mathematics; 128 is the server's ``prefill_chunk`` and one MXU
   tile); longer sequences go block by block with the state carried in
-  place (:func:`ssm_prefill`).
+  place (:func:`ssm_prefill`). ``b``, ``c`` (B, T, H, N), a head's own,
+  are taken too (``lightning_chunk``: the caller's ``name``).
 
 float32 throughout, products at ``Precision.HIGHEST``. Interpret mode off
 the TPU, as the other kernels."""
@@ -251,46 +255,14 @@ def _decode_kernel(layer_ref, batch_ref, row_ref, fresh_ref,
     jax.lax.fori_loop(0, tiles, body, 0)
 
 
-def _decode_kernel_by_head(layer_ref, batch_ref, row_ref, fresh_ref,
-                           da_ref, dtx_ref, b_ref, c_ref, s_ref, so_ref,
-                           y_ref):
-    """:func:`_decode_kernel` where every head has its own ``B`` and ``C``:
-    they arrive as lane-dense rows ``(heads of the tile, N)`` and both
-    products are the MXU's (``B^T (dt x)``, a product over the tile's
-    heads with each head's lanes kept, and ``C H``)."""
-    w = pl.program_id(0)
-    fresh = fresh_ref[w] != 0
-    tiles, N, lanes = s_ref.shape[2:]
-    heads = b_ref.shape[2]
-    width = lanes // heads
-    lane = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 0)
-    mine = lane // width == head                    # (heads, lanes)
-    dot = functools.partial(jax.lax.dot_general, precision=HIGHEST,
-                            preferred_element_type=jnp.float32)
-
-    def body(j, carry):
-        tile = jnp.where(fresh, 0.0, s_ref[0, 0, j])
-        dtx = jnp.where(mine, dtx_ref[0, pl.ds(j, 1), :], 0.0)
-        tile = da_ref[0, pl.ds(j, 1), :] * tile \
-            + dot(b_ref[0, j], dtx, (((0,), (0,)), ((), ())))
-        so_ref[0, 0, j] = tile
-        y = dot(c_ref[0, j], tile, (((1,), (0,)), ((), ())))
-        y_ref[0, pl.ds(j, 1), :] = jnp.sum(jnp.where(mine, y, 0.0), axis=0,
-                                           keepdims=True)
-        return carry
-
-    jax.lax.fori_loop(0, tiles, body, 0)
-
-
-def ssm_decode(x, dt, a, b, c, s, layer, rows, fresh, name="ssm_decode"):
+def ssm_decode(x, dt, a, b, c, s, layer, rows, fresh):
     """One token a running row, state updated in place.
 
     Args:
       x: (B, H, P), after the convolution; dt: (B, H), after the softplus;
-        a: (H,), negative; b, c: (B, N), or (B, H, N) where every head has
-        its own (a linear-attention layer's key and query: ``name`` is then
-        the caller's, ``ops/lightning.py``).
+        a: (H,), negative; b, c: (B, N), one group: shared by the heads (a
+        head's own key and query, a linear-attention layer's, have a kernel
+        of their own: ``ops/lightning.py``).
       s: the stacked leaf (L, R, tiles, N, lanes), aliased to the result.
       layer: int32 scalar (traced). rows: (B,) int32, the pool row of each
         batch entry, out of range for an entry that does not run (its
@@ -307,33 +279,26 @@ def ssm_decode(x, dt, a, b, c, s, layer, rows, fresh, name="ssm_decode"):
     dtx = _lane_rows(dt[..., None] * x, tiles)
     step = DECODE_TILES if tiles % DECODE_TILES == 0 else tiles
     prefetch, total, runs = prefetch_operands(layer, rows, fresh, s)
-    by_head = b.ndim == 3
-    if by_head:     # (B, H, N) as lane-dense rows (B, tiles, heads, N)
-        b, c = (v.astype(f32).reshape(B, tiles, H // tiles, N)
-                for v in (b, c))
-        bc_spec = _by_batch((1, step, H // tiles, N), True)
-    else:
-        b, c = b.astype(f32)[..., None], c.astype(f32)[..., None]
-        bc_spec = _by_batch((1, N, 1), False)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(total, tiles // step),
         in_specs=[_by_batch((1, step, lanes), True),
                   _by_batch((1, step, lanes), True),
-                  bc_spec, bc_spec,
+                  _by_batch((1, N, 1), False), _by_batch((1, N, 1), False),
                   _state_spec(s, step)],
         out_specs=[_state_spec(s, step), _by_batch((1, step, lanes), True)],
     )
     s, s_shape = in_hbm(s)
     s, y = pl.pallas_call(
-        _decode_kernel_by_head if by_head else _decode_kernel,
-        name=name,
+        _decode_kernel,
+        name="ssm_decode",
         grid_spec=grid_spec,
         out_shape=[s_shape, jax.ShapeDtypeStruct((B, tiles, lanes), f32)],
         input_output_aliases={8: 0},
         compiler_params=compiler_params(),
         interpret=backend.pallas_interpret(),
-    )(*prefetch, da, dtx, b, c, s)
+    )(*prefetch, da, dtx, b.astype(f32)[..., None], c.astype(f32)[..., None],
+      s)
     # the blocks of rows that did not run were never written
     return jnp.where(runs[:, None, None], y, 0.0).reshape(B, H, P), s
 

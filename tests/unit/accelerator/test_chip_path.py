@@ -360,9 +360,12 @@ def test_lightning_and_sparse_kernels_compile_for_a_described_v5e_as_served(
         compiled_kernels, described_v5e):
     """The kernels PR 56 brought, through Mosaic at the widths of
     ``perf/configs/minicpm-sala-9b-sparse.json``: 32 rows' tokens through
-    ``lightning_decode`` and one row's 128-token block through
-    ``lightning_chunk`` (the state-space kernels with a head's own B and C:
-    lane-dense rows a head, both products of a decode step on the MXU) on
+    ``lightning_decode`` (PR 59: on the vector unit, a row's 32 tiles a
+    grid step, a head's k and q as columns ONE lane wide beside its tile,
+    as ``kda_decode``'s, turned from their lane-dense rows by a transpose
+    of (64, 128) inside the kernel, which passes Mosaic only here) and one
+    row's 128-token block through ``lightning_chunk`` (the state-space
+    chunk kernel with a head's own B and C, its products on the MXU) on
     the pool's stacked leaf (9 layers x 32 slots x 32 tiles of (128, 128)
     float32); and the sparse layers' page read (``ops/attention/
     sparse_read.py``) with its plan: 32 decode rows, a (row, KV head) one

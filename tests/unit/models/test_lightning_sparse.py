@@ -277,15 +277,6 @@ def test_a_bfloat16_state_is_told_apart(toy, reference_logits):
     assert np.abs(coarse - reference_logits).max() > 20 * 5e-5
 
 
-@pytest.fixture(scope="module")
-def state_ops():
-    H, P, B, T = 4, 16, 3, 16
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    q, k, v = (jax.random.normal(key, (B, T, H, P)) for key in keys[:3])
-    leaf = jax.random.normal(keys[3], (2, 4) + lightning.state_shape(H, P))
-    return q, k, v, leaf
-
-
 def _by_definition(q, k, v, s0):
     """One sequence (T, H, d) after the state ``s0`` (H, d, d), position
     by position."""
@@ -297,22 +288,37 @@ def _by_definition(q, k, v, s0):
     return np.stack(out), s
 
 
-@pytest.mark.parametrize("form", ["chunk", "decode", "sequence"])
-def test_the_lightning_kernels_are_the_recurrence(state_ops, form):
-    """A head's own q and k through the state-space kernels (their
-    one-group rule lifted): the chunk form and a decode step against the
-    definition; an entry out of range does not run (its state bit for
-    bit), a fresh entry reads none, no other layer's state moves."""
-    q, k, v, leaf = (np.asarray(x) for x in state_ops)
-    rows, fresh = jnp.asarray([2, -1, 0]), jnp.asarray([False, False, True])
-    # the leaf holds S transposed: the key's channel on the sublanes
-    carried = np.asarray(ss.from_tiles(leaf[1], 16)).transpose(0, 1, 3, 2)
+@pytest.mark.parametrize("form, H, P", [
+    ("chunk", 4, 16), ("decode", 4, 16), ("sequence", 4, 16),
+    ("decode", 2, 128),     # ONE head a tile: the width it is served at
+    ("decode", 4, 64),      # two heads a tile, two tiles
+    ("decode", 2, 64),      # two heads, one tile
+    ("decode", 3, 32),      # heads that do not fill a tile's 128 lanes
+])
+def test_the_lightning_kernels_are_the_recurrence(form, H, P):
+    """A head's own q and k through the state-space leaf: the chunk form
+    (``ssm_chunk``'s one-group rule lifted) and a decode step (a head's k
+    and q as columns beside its tile, on that head's lanes where a tile
+    holds several) against the definition; an entry out of range does not
+    run (its state bit for bit, its output 0), a fresh entry reads none (a
+    NaN planted in its old state does not come through), no other layer's
+    state moves."""
+    B, T = 3, 16
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v = (np.asarray(jax.random.normal(key, (B, T, H, P)))
+               for key in keys[:3])
     if form == "sequence":
         got = np.asarray(jax.jit(lightning.lightning_sequence)(q, k, v))
         for b in range(3):
-            want, _ = _by_definition(q[b], k[b], v[b], np.zeros((4, 16, 16)))
+            want, _ = _by_definition(q[b], k[b], v[b], np.zeros((H, P, P)))
             np.testing.assert_allclose(got[b], want, atol=2e-5)
         return
+    leaf = np.array(jax.random.normal(
+        keys[3], (2, 4) + lightning.state_shape(H, P)))
+    leaf[1, 0] = np.nan     # the fresh entry's row: what it held is unread
+    rows, fresh = jnp.asarray([2, -1, 0]), jnp.asarray([False, False, True])
+    # the leaf holds S transposed: the key's channel on the sublanes
+    carried = np.asarray(ss.from_tiles(leaf[1], P)).transpose(0, 1, 3, 2)
     if form == "chunk":
         out, new = jax.jit(lightning.lightning_prefill)(
             q, k, v, leaf, 1, rows, fresh, jnp.asarray([16, 16, 11]))
@@ -320,17 +326,19 @@ def test_the_lightning_kernels_are_the_recurrence(state_ops, form):
     else:
         out, new = jax.jit(lightning.lightning_decode)(
             q[:, 0], k[:, 0], v[:, 0], leaf, 1, rows, fresh)
-        out, spans = np.asarray(out)[:, None], (1, 1, 1)
-    after = np.asarray(ss.from_tiles(new[1], 16)).transpose(0, 1, 3, 2)
+        out, spans = out[:, None], (1, 1, 1)
+    out, new = np.asarray(out), np.asarray(new)
+    after = np.asarray(ss.from_tiles(new[1], P)).transpose(0, 1, 3, 2)
     for b, row in ((0, 2), (2, 0)):
         n = spans[b]
         want, s = _by_definition(
             q[b, :n], k[b, :n], v[b, :n],
-            np.zeros((4, 16, 16)) if row == 0 else carried[row])
-        np.testing.assert_allclose(np.asarray(out)[b, :n], want, atol=2e-5)
+            np.zeros((H, P, P)) if row == 0 else carried[row])
+        np.testing.assert_allclose(out[b, :n], want, atol=2e-5)
         np.testing.assert_allclose(after[row], s, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(new[0]), leaf[0])
-    np.testing.assert_array_equal(np.asarray(new[1, [1, 3]]), leaf[1, [1, 3]])
+    assert (out[1] == 0).all()
+    np.testing.assert_array_equal(new[0], leaf[0])
+    np.testing.assert_array_equal(new[1, [1, 3]], leaf[1, [1, 3]])
 
 
 def test_keys_join_their_groups_as_they_arrive():
