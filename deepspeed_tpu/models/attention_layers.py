@@ -26,7 +26,8 @@ from ..ops import backend
 from .kv_cache_spec import kv_cache_groups, kv_cache_spec
 from .lm_config import TransformerConfig
 from .lm_parts import (_by_row_group, _chunk_positions, _chunk_shaped, _dense,
-                       _norm_qk, _project_qkv, _store_columns, _traced_once,
+                       _gated, _norm_qk, _project_qkv, _store_columns,
+                       _traced_once,
                        alibi_slopes, apply_rotary, apply_rotary_table,
                        layer_rope_tables)
 
@@ -255,6 +256,11 @@ class CachedAttention(nn.Module):
             start = jnp.zeros((), jnp.int32)
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
 
+        o_dense = _dense(cfg, C, use_bias=cfg.qkv_bias, name="o_proj")
+
+        def o_proj(y):      # behind the output gate, where there is one
+            return o_dense(_gated(cfg, y, x) if cfg.attn_output_gate else y)
+
         is_window = None    # traced: this layer is a sliding-window layer
         if cfg.layer_types is not None and not cfg.hybrid:
             inv_freq, factor, windows = layer_rope_tables(cfg)
@@ -282,7 +288,6 @@ class CachedAttention(nn.Module):
             # (ops/attention/paged_attention.py).
             y, leaves = _by_row_group(kv_cache, self._paged_decode_step,
                                       q, k, v)
-            o_proj = _dense(cfg, C, use_bias=cfg.qkv_bias, name="o_proj")
             return o_proj(y), leaves
 
         kv_scales = None  # set on the quantized-cache einsum fallback
@@ -293,7 +298,6 @@ class CachedAttention(nn.Module):
         # that implies OOM-crashed the worker at T=4096 / S=8192.
         fresh = (not decode) or (decode == "prefill" and T > 1)
         new_cache = None
-        o_proj = _dense(cfg, C, use_bias=cfg.qkv_bias, name="o_proj")
         if decode:
             k_rows = k.astype(cfg.dtype).transpose(0, 2, 1, 3)  # (B,KV,T,D)
             v_rows = v.astype(cfg.dtype).transpose(0, 2, 1, 3)

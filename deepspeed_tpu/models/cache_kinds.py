@@ -41,6 +41,7 @@ CACHE_KINDS = {     # in the order a refusal is looked up (cache_kinds)
     "state": "a recurrent state",           # power_retention layers
     "ssm": "a state group beside K/V",      # mamba beside attention layers
     "kda": "a KDA state group",             # kda beside attention layers
+    "gdn": "a Gated DeltaNet state group",  # gdn beside attention layers
     "conv": "a convolution-tail state group",   # conv beside attention layers
     "sparse": "the index of learned sparse attention",  # sparse_attention:
     # group means of the keys beside the K/V pages, a choice of blocks (it
@@ -142,6 +143,34 @@ CACHE_REFUSALS = {
         "is float32 and the tier has not been run beside it",
     ("kda", "int8_weights"):
         "int8_weights does not reach the kda layers' convolution, A_log, "
+        "dt_bias and output norm, which are parameters of the mixer and no "
+        "Dense",
+    ("gdn", "spec_decode"):
+        "a rejected draft's tokens are in the delta-rule state and the "
+        "convolution's tail for good: verify_k's rollback moves an index, "
+        "which hides K/V columns and nothing of a state",
+    ("gdn", "prefix_cache"):
+        "a hit maps the K/V pages of the prompt's start and would need the "
+        "state as it stood at the hit's boundary, which nothing keeps (a "
+        "snapshot a page boundary; pass paged_kv={'prefix_cache': False})",
+    ("gdn", "roles"):
+        "pages are the unit of a handoff: the slot's state rows would have "
+        "to be shipped beside them",
+    ("gdn", "tensor_parallel"):
+        "the state leaves have no placement on the model axis and the "
+        "delta-rule kernels are not wrapped for a mesh",
+    ("gdn", "tensor_parallel_serving"):
+        "the state leaves have no placement on the model axis and the "
+        "delta-rule kernels are not wrapped for a mesh",
+    ("gdn", "zero_inference"):
+        "it streams one layer's block parameters at a time out of ONE "
+        "stacked tree; gdn and attention layers are two, and the state is "
+        "not threaded through the streamed layers",
+    ("gdn", "kv_cache_quant"):
+        "kv_cache_quant quantizes K/V columns; the state group beside them "
+        "is float32 and the tier has not been run beside it",
+    ("gdn", "int8_weights"):
+        "int8_weights does not reach the gdn layers' convolution, A_log, "
         "dt_bias and output norm, which are parameters of the mixer and no "
         "Dense",
     ("conv", "spec_decode"):
@@ -283,7 +312,7 @@ def cache_kinds(cfg: TransformerConfig) -> tuple:
     """The kinds of ``CACHE_KINDS`` a configuration is, in its order."""
     groups = kv_cache_groups(cfg)
     has = {"state": cfg.retention, "ssm": cfg.mamba, "kda": cfg.kda,
-           "conv": cfg.conv, "lightning": cfg.lightning,
+           "gdn": cfg.gdn, "conv": cfg.conv, "lightning": cfg.lightning,
            "sparse": cfg.sparse_attention is not None,
            "latent": cfg.latent,
            "window_only": groups is not None and not groups[0][1],

@@ -119,7 +119,11 @@ class KVCacheSpec:
     # ``dtype``; the attention layers keep K/V (:attr:`kv_layers`). A model
     # of kda layers beside attention layers: the kda layers, ``s`` (heads,
     # d, d) float32 and ``conv`` likewise; the attention layers keep K/V or
-    # the latent row. A model of conv layers beside attention layers: the
+    # the latent row. A model of gdn layers beside attention layers: the
+    # gdn layers, ``s`` (value heads, d, d) float32 and ``conv`` (the last
+    # taps - 1 inputs of the one convolution over [q ; k ; v]); the
+    # attention layers keep K/V. A model of conv layers beside attention
+    # layers: the
     # conv layers, ONE leaf ``conv`` (the convolution's last taps - 1
     # inputs) and no ``s``. A state's size does not depend on max_seq_len,
     # which stays the bound on positions
@@ -361,6 +365,13 @@ def make_kv_cache_spec(cfg: TransformerConfig) -> KVCacheSpec:
             ("s", (cfg.kda_n_heads, cfg.kda_d_head, cfg.kda_d_head),
              jnp.float32),
             ("conv", ((cfg.kda_d_conv - 1) * 3 * cfg.kda_width,),
+             cache_dtype)))
+    if cfg.gdn:
+        # (KDA's leaf at the value heads: the kernels are ops/kda.py's)
+        group = (cfg.layer_types.count("gdn"), (
+            ("s", (cfg.gdn_n_value_heads, cfg.gdn_d_head, cfg.gdn_d_head),
+             jnp.float32),
+            ("conv", ((cfg.gdn_d_conv - 1) * cfg.gdn_channels,),
              cache_dtype)))
     if cfg.conv:
         group = (cfg.layer_types.count("conv"), (

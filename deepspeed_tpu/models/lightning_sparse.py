@@ -19,8 +19,8 @@ import jax.numpy as jnp
 
 from .lm_config import TransformerConfig
 from .lm_parts import (_by_row_group, _chunk_positions, _chunk_shaped, _dense,
-                       _norm_qk, _project_qkv, _store_columns, _traced_once,
-                       apply_rotary)
+                       _gated, _norm_qk, _project_qkv, _store_columns,
+                       _traced_once, apply_rotary)
 from .state_layers import _state_rows
 
 
@@ -35,14 +35,6 @@ def _positions(kv_cache, decode, B: int, T: int):
     return jnp.broadcast_to(
         (start[:, None] if jnp.ndim(start) == 1 else start)
         + jnp.arange(T)[None, :], (B, T))
-
-
-def _gated(cfg: TransformerConfig, y, u):
-    """``y (.) sigmoid(W_z u)``: the gate on a mixer's output, a projection
-    of the mixer's own input (called inside the mixer: ``z_proj`` is its)."""
-    z = _dense(cfg, y.shape[-1], use_bias=False, name="z_proj")(u)
-    return (y.astype(jnp.float32)
-            * jax.nn.sigmoid(z.astype(jnp.float32))).astype(cfg.dtype)
 
 
 class LightningMixer(nn.Module):

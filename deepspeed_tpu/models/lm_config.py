@@ -40,6 +40,12 @@ lfm2_moe       rotary    rmsnorm    routed    gated short-convolution
                                               GQA, sigmoid router with
                                               a bias and no shared
                                               expert, tied head
+qwen3_next     rotary    rmsnorm1p  routed    Gated DeltaNet layers
+                                              beside gated QK-normed
+                                              GQA (rotary_pct 0.25),
+                                              softmax router, a
+                                              sigmoid-gated shared
+                                              expert, experts held
 =============  ========  =========  ========  ===================
 """
 
@@ -64,7 +70,9 @@ class TransformerConfig:
     pos_emb: str = "learned"            # learned | rotary | alibi | none
     rotary_pct: float = 1.0             # fraction of head_dim rotated (neox)
     rope_theta: float = 10000.0
-    norm: str = "layernorm"             # layernorm | rmsnorm
+    norm: str = "layernorm"             # layernorm | rmsnorm | rmsnorm1p
+    # (rmsnorm1p: the zero-centred RMSNorm, x / rms(x) * (1 + w) in
+    # float32, for the layer norms, the final norm and the qk_norm)
     activation: str = "gelu"            # gelu | relu | swiglu
     mlp_ratio: float = 4.0
     parallel_residual: bool = False     # gptj/neox: x + attn(ln1 x) + mlp(ln2 x)
@@ -144,6 +152,8 @@ class TransformerConfig:
     # chosen scores' sum before they are divided by it (norm_topk_prob)
     n_shared_experts: int = 0           # one gated FFN of this many expert
     # widths beside the routed sum, for every token
+    shared_expert_gate: bool = False    # the shared expert's output is
+    # scaled by sigmoid(w_s . x) a token (w_s: n_embd -> 1)
     first_k_dense: int = 0              # the first layers' FFN is a plain
     # gated FFN of dense_ffn_dim; the routed FFN starts after them
     dense_ffn_dim: Optional[int] = None
@@ -169,6 +179,17 @@ class TransformerConfig:
     # over n_embd channels between two gates, no bias, no activation
     # (ShortConvMixer has the equations); the state is the convolution's tail
     conv_taps: int = 3
+    # Gated DeltaNet layers (``layer_types`` "gdn", beside "attention"
+    # layers): gdn_n_value_heads heads of gdn_d_head value channels over
+    # gdn_n_key_heads heads of as many key channels (a key head serves
+    # value heads // key heads value heads), ONE log decay a value head, a
+    # causal depthwise convolution of gdn_d_conv taps over [q ; k ; v], a
+    # SiLU-gated norm on the output (models/gdn_layers.py; ops/kda.py has
+    # the state's equations and kernels, in their scalar-decay form)
+    gdn_n_key_heads: int = 0
+    gdn_n_value_heads: int = 0
+    gdn_d_head: int = 0
+    gdn_d_conv: int = 4
     # Lightning layers (``layer_types`` "lightning", beside "attention"
     # layers, in ANY order: the stack is run as a list of runs where it
     # does not repeat): n_head heads of head_dim with their own q, k and v,
@@ -181,7 +202,7 @@ class TransformerConfig:
     # "init_blocks", "window_size", "topk", "dense_len"}; None: every key
     sparse_attention: Optional[tuple] = None
     attn_output_gate: bool = False      # o (.) sigmoid(W_z x) before o_proj
-    # (the sparse attention layers')
+    # (the attention layers' that cache K/V a head, sparse or not)
     experts_held: Optional[int] = None  # the routed FFN holds experts
     # [0, experts_held) of n_experts (one chip's share of a layer that
     # several divide): the router and the top-k run over all n_experts, the
@@ -200,13 +221,13 @@ class TransformerConfig:
             kinds = set(self.layer_types) - {"sliding_attention",
                                              "full_attention",
                                              "power_retention",
-                                             "mamba", "kda", "conv",
+                                             "mamba", "kda", "gdn", "conv",
                                              "lightning", "attention"}
             if kinds or len(self.layer_types) != self.n_layer:
                 raise ValueError(
                     f"layer_types names n_layer={self.n_layer} layers as "
                     f"sliding_attention | full_attention | power_retention "
-                    f"| mamba | kda | conv | lightning | attention; "
+                    f"| mamba | kda | gdn | conv | lightning | attention; "
                     f"got {len(self.layer_types)} entries, unknown "
                     f"{sorted(kinds)}")
             if set(STATE_KINDS + ("attention",)) & set(self.layer_types):
@@ -285,9 +306,17 @@ class TransformerConfig:
                     "of state and attention layers (layer_types), K/V a "
                     "head, without positions (pos_emb='none'): the index "
                     "scores keys that carry none (ROADMAP.md, Reach)")
-        if self.attn_output_gate and self.sparse_attention is None:
-            raise ValueError("attn_output_gate is the sparse attention "
-                             "layers' (sparse_attention)")
+        if self.attn_output_gate and (self.latent or self.retention):
+            raise ValueError("attn_output_gate is the gate of attention "
+                             "layers that cache K/V a head (CachedAttention, "
+                             "SparseAttention): latent attention and "
+                             "power_retention have none")
+        if self.shared_expert_gate and not self.n_shared_experts:
+            raise ValueError("shared_expert_gate scales the shared expert "
+                             "(n_shared_experts > 0)")
+        if self.norm not in ("layernorm", "rmsnorm", "rmsnorm1p"):
+            raise ValueError(f"norm {self.norm!r}: know layernorm | rmsnorm "
+                             f"| rmsnorm1p")
         for feature in ("kv_cache_quant", "int8_weights"):
             why = getattr(self, feature) \
                 and refusal(cache_kinds(self), feature)
@@ -339,6 +368,17 @@ class TransformerConfig:
                 f"kda layers need kda_n_heads and kda_d_head and a "
                 f"convolution of two taps or more; got {self.kda_n_heads} x "
                 f"{self.kda_d_head}, taps {self.kda_d_conv}")
+        if self.gdn and (
+                not (self.gdn_n_key_heads and self.gdn_n_value_heads
+                     and self.gdn_d_head)
+                or self.gdn_n_value_heads % self.gdn_n_key_heads
+                or self.gdn_d_conv < 2):
+            raise ValueError(
+                f"gdn layers need gdn_n_key_heads, a multiple of them as "
+                f"gdn_n_value_heads, gdn_d_head and a convolution of two "
+                f"taps or more; got {self.gdn_n_key_heads} key and "
+                f"{self.gdn_n_value_heads} value heads of "
+                f"{self.gdn_d_head}, taps {self.gdn_d_conv}")
         if self.conv and self.conv_taps < 2:
             raise ValueError(
                 f"conv layers need a convolution of two taps or more (the "
@@ -376,7 +416,8 @@ class TransformerConfig:
         this one with a plain gated FFN of ``dense_ffn_dim``."""
         return dataclasses.replace(
             self, n_experts=0, experts_per_token=0, n_shared_experts=0,
-            experts_held=None, first_k_dense=0, ffn_dim=self.dense_ffn_dim)
+            shared_expert_gate=False, experts_held=None, first_k_dense=0,
+            ffn_dim=self.dense_ffn_dim)
 
     @property
     def retention(self) -> bool:
@@ -395,6 +436,18 @@ class TransformerConfig:
         """``kda`` layers beside ``attention`` layers: a state group over
         the former, K/V or a latent row over the latter."""
         return self.layer_types is not None and "kda" in self.layer_types
+
+    @property
+    def gdn(self) -> bool:
+        """``gdn`` layers beside ``attention`` layers: a state group over
+        the former (KDA's leaf at the value heads), K/V over the latter."""
+        return self.layer_types is not None and "gdn" in self.layer_types
+
+    @property
+    def gdn_channels(self) -> int:
+        """The convolution's channels: ``[q ; k ; v]``."""
+        return (2 * self.gdn_n_key_heads + self.gdn_n_value_heads) \
+            * self.gdn_d_head
 
     @property
     def conv(self) -> bool:
@@ -475,7 +528,7 @@ class TransformerConfig:
 
 
 # the kinds of state layer that stand beside ``attention`` layers
-STATE_KINDS = ("mamba", "kda", "conv", "lightning")
+STATE_KINDS = ("mamba", "kda", "gdn", "conv", "lightning")
 
 FAMILY_PRESETS = {
     "gpt2": dict(pos_emb="learned", norm="layernorm", activation="gelu"),
@@ -554,6 +607,21 @@ FAMILY_PRESETS = {
                          activation="swiglu", qkv_bias=False,
                          mlp_bias=False, tie_word_embeddings=False,
                          layer_norm_epsilon=1e-6, qk_norm=True),
+    # Qwen3-Next (Qwen; model_type qwen3_next): Gated DeltaNet layers
+    # beside gated softmax GQA layers (``layer_types`` as published,
+    # "linear_attention" | "full_attention", or "gdn" | "attention"), the
+    # zero-centred RMSNorm everywhere but the DeltaNet output norm, a norm
+    # on each head of q and k and a rotary over the first quarter of a
+    # head, the output gate ``o * sigmoid(W_g x)``, a softmax router whose
+    # chosen weights are renormalised, a shared expert scaled by a sigmoid
+    # gate a token, an untied head. Widths, the pattern and the experts held
+    # are the caller's.
+    "qwen3_next": dict(pos_emb="rotary", rotary_pct=0.25, norm="rmsnorm1p",
+                       activation="swiglu", qkv_bias=False, mlp_bias=False,
+                       tie_word_embeddings=False, layer_norm_epsilon=1e-6,
+                       qk_norm=True, attn_output_gate=True,
+                       scoring_func="softmax", norm_topk_prob=True,
+                       n_shared_experts=1, shared_expert_gate=True),
 }
 
 
@@ -584,6 +652,11 @@ def transformer_config(family: str, **overrides) -> TransformerConfig:
         # ``attention``: what makes them sparse is ``sparse_attention``)
         names = {"lightning-attn": "lightning", "minicpm4": "attention",
                  "sparse_attention": "attention"}
+        cfg["layer_types"] = tuple(names.get(kind, kind)
+                                   for kind in cfg["layer_types"])
+    if {"gdn", "linear_attention"} & set(cfg.get("layer_types") or ()):
+        # (published as "linear_attention" | "full_attention")
+        names = {"linear_attention": "gdn", "full_attention": "attention"}
         cfg["layer_types"] = tuple(names.get(kind, kind)
                                    for kind in cfg["layer_types"])
     if "conv" in (cfg.get("layer_types") or ()):

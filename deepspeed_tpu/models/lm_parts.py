@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -32,9 +33,35 @@ def _dense(cfg: TransformerConfig, features: int, *, use_bias: bool,
                     name=name)
 
 
+class ZeroCentredRMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * (1 + w)`` over the last axis, in
+    float32, cast to ``dtype`` (``TransformerConfig.norm`` "rmsnorm1p":
+    Qwen3-Next's norm). A trained ``w`` starts at 0; a SEEDED one is drawn
+    normal with spread 0.1, so that ``1 + w`` differs from ``w`` and from 1
+    for whatever compares outputs."""
+
+    epsilon: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.normal(0.1), (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                              + self.epsilon)
+        return (x * (1.0 + w.astype(jnp.float32))).astype(self.dtype)
+
+
+def _rms_norm(cfg: TransformerConfig, name: str):
+    """The configuration's RMSNorm: the zero-centred one under
+    ``norm="rmsnorm1p"``, flax's otherwise."""
+    cls = ZeroCentredRMSNorm if cfg.norm == "rmsnorm1p" else nn.RMSNorm
+    return cls(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, name=name)
+
+
 def _norm(cfg: TransformerConfig, name: str):
-    if cfg.norm == "rmsnorm":
-        return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, name=name)
+    if cfg.norm in ("rmsnorm", "rmsnorm1p"):
+        return _rms_norm(cfg, name)
     return nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, name=name)
 
 
@@ -172,11 +199,19 @@ def _project_qkv(cfg: TransformerConfig, x):
 
 def _norm_qk(cfg: TransformerConfig, q, k):
     """``qk_norm``: RMSNorm over each head of ``q`` and of ``k`` (one
-    learned weight of ``head_dim`` each), before the rotary. Called inside
-    an attention module's ``__call__``: the two norms are that module's."""
-    return tuple(nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
-                            name=name)(x)
+    learned weight of ``head_dim`` each; the zero-centred norm where the
+    configuration's is), before the rotary. Called inside an attention
+    module's ``__call__``: the two norms are that module's."""
+    return tuple(_rms_norm(cfg, name)(x)
                  for name, x in (("q_norm", q), ("k_norm", k)))
+
+
+def _gated(cfg: TransformerConfig, y, u):
+    """``y (.) sigmoid(W_z u)``: the gate on a mixer's output, a projection
+    of the mixer's own input (called inside the mixer: ``z_proj`` is its)."""
+    z = _dense(cfg, y.shape[-1], use_bias=False, name="z_proj")(u)
+    return (y.astype(jnp.float32)
+            * jax.nn.sigmoid(z.astype(jnp.float32))).astype(cfg.dtype)
 
 
 def _store_columns(buf, new, start):

@@ -13,8 +13,9 @@ Families are presets of ``TransformerConfig`` (``models/lm_config.py`` has
 the table of them). This file is the block, the layer scan, the cache's
 variables and the model. What a block mixes with lives below it, and none
 of it imports this file: ``attention_layers.py`` (K/V a head),
-``state_layers.py`` (retention, mamba, KDA, the short convolution) and
-``lightning_sparse.py`` (Lightning and learned block-sparse attention), on
+``state_layers.py`` (retention, mamba, KDA, the short convolution),
+``gdn_layers.py`` (Gated DeltaNet) and ``lightning_sparse.py`` (Lightning
+and learned block-sparse attention), on
 the helpers of ``lm_parts.py``; the cache's container is
 ``kv_cache_spec.py``'s and what a kind refuses ``cache_kinds.py``'s. A new
 layer kind is a class in a file of its own or one of those, its rows in
@@ -42,8 +43,8 @@ may carry a plain gated FFN (``dense_ffn_dim``) before the routed layers:
 they are a scan of their own (``dense_blocks``) in front of ``blocks``, the
 layer counter and the cache running through both.
 
-State layers of one kind (``STATE_KINDS``: ``mamba``, ``kda``, ``conv``,
-``lightning``) stand BESIDE ``attention`` layers (full, position-free or
+State layers of one kind (``STATE_KINDS``: ``mamba``, ``kda``, ``gdn``,
+``conv``, ``lightning``) stand BESIDE ``attention`` layers (full, position-free or
 rotary, K/V a head or latent, sparse under ``sparse_attention``) in one
 model. The two kinds have different parameter trees, so the stack is two
 stacked leaves (``<kind>_blocks``, ``attn_blocks``) run in the published
@@ -68,6 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from .attention_layers import CachedAttention
+from .gdn_layers import GatedDeltaNetMixer
 from .kv_cache_spec import (KVCacheSpec, kv_cache_groups, kv_cache_spec,
                             make_kv_cache_spec)
 from .lightning_sparse import LightningMixer, SparseAttention
@@ -253,6 +255,7 @@ class TransformerBlock(nn.Module):
             from .lightning_sparse import LightningMixer, SparseAttention
         attention, name = (Mamba2Mixer, "mamba") if self.kind == "mamba" \
             else (KDAMixer, "kda") if self.kind == "kda" \
+            else (GatedDeltaNetMixer, "gdn") if self.kind == "gdn" \
             else (ShortConvMixer, "conv") if self.kind == "conv" \
             else (LightningMixer, "lightning") if self.kind == "lightning" \
             else (PowerRetention if cfg.retention else
@@ -277,7 +280,7 @@ class TransformerBlock(nn.Module):
                 cfg.n_experts, cfg.experts_per_token, cfg.norm_topk_prob,
                 cfg.scoring_func, cfg.routed_scaling_factor,
                 cfg.n_shared_experts * cfg.ffn_width, cfg.dtype,
-                cfg.topk_norm_eps, name="mlp")(
+                cfg.topk_norm_eps, cfg.shared_expert_gate, name="mlp")(
                     h, experts, ffn_layer if ffn_layer is not None
                     else layer - cfg.first_k_dense
                     if cfg.first_k_dense else layer)
@@ -550,7 +553,7 @@ class TransformerLM(nn.Module):
                                   dtype=jnp.float32, name="lm_head")
 
     def _hybrid_layers(self, carry, decode, deterministic, experts=()):
-        """The layers of a model of state (mamba, kda or conv) and attention
+        """The layers of a model of state (mamba, kda, gdn or conv) and attention
         layers, in the published order, off the stacked leaves: a scan over
         the periods whose body scans the state layers before the period's
         attention layer, runs that one, and scans those after it

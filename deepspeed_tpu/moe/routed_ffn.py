@@ -20,6 +20,9 @@ routed sum::
     S = top_k(s + b, k);  w_e = f * s_e / (sum_{e' in S} s_e' + 1e-20)
     y = sum_{e in S} w_e * down_e( silu(gate_e h) * up_e h ) + shared(h)
 
+(under ``RoutedFFN.shared_gate`` the shared expert, beside either router,
+is scaled a token: ``sigmoid(w_s . h) * shared(h)``).
+
 **Rows grouped by expert.** The ``N * k`` (token, expert) assignments are
 sorted by expert and laid out in tiles of :data:`ROW_TILE` rows, each
 group padded to whole tiles, so a tile belongs to ONE expert
@@ -344,6 +347,8 @@ class RoutedFFN(nn.Module):
     dtype: Any = jnp.bfloat16   # the shared FFN's
     norm_eps: float = 1e-20     # the sigmoid router's: what joins the chosen
     # scores' sum before they are divided by it
+    shared_gate: bool = False   # the shared FFN's output is scaled by
+    # sigmoid(w_s . x) a token (``shared_gate_w`` (C,); Qwen3-Next)
 
     @nn.compact
     def __call__(self, x, experts, layer):
@@ -368,7 +373,17 @@ class RoutedFFN(nn.Module):
                 return nn.Dense(width, use_bias=False, dtype=self.dtype,
                                 name=name)
 
-            y = y + dense(C, "shared_down_proj")(
+            shared = dense(C, "shared_down_proj")(
                 jax.nn.silu(dense(self.shared_width, "shared_gate_proj")(x))
                 * dense(self.shared_width, "shared_up_proj")(x))
+            if self.shared_gate:
+                w_s = self.param("shared_gate_w",
+                                 nn.initializers.normal(C ** -0.5), (C,))
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "btc,c->bt", x.astype(jnp.float32),
+                    w_s.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST))
+                shared = (shared.astype(jnp.float32)
+                          * gate[..., None]).astype(shared.dtype)
+            y = y + shared
         return y, stats

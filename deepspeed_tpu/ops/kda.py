@@ -59,6 +59,20 @@ bit, and a row whose first position is 0 reads no state (a select).
   sequences go block by block with the state carried in place
   (:func:`kda_prefill`).
 
+**One log decay a head** (Gated DeltaNet; Qwen3-Next): ``g_t`` a number a
+head, ``(T, H)`` where KDA's is ``(T, H, K)``. Broadcast over the channels
+it is the rule above, and every function here takes either: the decode
+kernel is handed the broadcast column, and the chunk form's preparation is
+the cheaper one (:func:`_scalar_decay_operands`): the decay leaves the sum
+over the channels, so the pairs of a chunk are ONE product ``a b^T`` times
+the ``(T, T)`` mask ``exp(G_r - G_i)``, every exponent <= 0 as before, and
+no sum a channel at a time. ``q`` and ``k`` may then come with fewer heads
+than ``v`` (``H = rep x Hk``: key head ``j`` serves value heads ``rep j ..
+rep j + rep - 1``); the products are made once a key head and what the
+kernels are handed, which carries a value head's decay, a value head. The
+calls take their ``name`` from the caller (``gdn_decode``, ``gdn_chunk``:
+the v5e trace attributes by name alone); the kernels are the same.
+
 Interpret mode off the TPU, as the other kernels."""
 
 from __future__ import annotations
@@ -87,10 +101,26 @@ _einsum = functools.partial(jnp.einsum, precision=HIGHEST)
 # the plain forms (jax.numpy): the forward without a cache, and what the
 # kernels are tested against
 # ---------------------------------------------------------------------------
+def _a_value_head(q, k, g, heads: int, axis: int):
+    """``q``, ``k`` repeated to ``heads`` along ``axis`` where they come a
+    key head, and a decay a head (one dimension short of ``k``) broadcast
+    over the channels: the operands as the rule with a decay a channel
+    reads them."""
+    rep = heads // q.shape[axis]
+    if rep > 1:
+        q, k = (jnp.repeat(x, rep, axis=axis) for x in (q, k))
+    if g.ndim < k.ndim:
+        g = jnp.broadcast_to(g[..., None], k.shape)
+    return q, k, g
+
+
 def kda_recurrence(q, k, v, g, beta, s0):
     """The recurrence, token by token (a ``lax.scan``), ONE sequence: ``q``,
     ``k``, ``g`` (T, H, K), ``v`` (T, H, V), ``beta`` (T, H), ``s0`` (H, K,
-    V). Returns ``(o (T, H, V), s_T)``."""
+    V); or a decay a head, ``g`` (T, H), and ``q``, ``k`` a key head.
+    Returns ``(o (T, H, V), s_T)``."""
+    q, k, g = _a_value_head(q, k, g, v.shape[1], 1)
+
     def step(s, xs):
         q_t, k_t, v_t, g_t, b_t = xs
         s = jnp.exp(g_t)[..., None] * s
@@ -160,7 +190,10 @@ def _chunk_operands(q, k, v, g, beta, sub: int = SUB):
     K), ``v`` (B, H, T, V), ``beta`` (B, H, T); ``T`` a multiple of
     ``sub``. Returns ``(inverse (T, T), pairs P (T, T), beta k e^G, q e^G
     (T, K), (k e^{G_T - G})^T (K, T), beta v (T, V), e^{G_T} (1, K))``,
-    each after the leading ``(B, H)``."""
+    each after the leading ``(B, H)``. A decay a head, ``g`` (B, H, T):
+    :func:`_scalar_decay_operands`."""
+    if g.ndim == beta.ndim:
+        return _scalar_decay_operands(q, k, v, g, beta, sub)
     G = jnp.cumsum(g, axis=2)
     both = _pairs(jnp.stack([k, q]), k[None], G[None], sub)
     strict = jnp.tril(jnp.ones(both.shape[-2:], bool), -1)
@@ -170,6 +203,41 @@ def _chunk_operands(q, k, v, g, beta, sub: int = SUB):
     return (_unit_lower_inverse(a, sub), both[1], beta[..., None] * k * e,
             q * e, jnp.swapaxes(k * jnp.exp(last - G), -1, -2),
             beta[..., None] * v, jnp.exp(last))
+
+
+def _scalar_decay_operands(q, k, v, g, beta, sub: int = SUB):
+    """:func:`_chunk_operands` under ONE log decay a head: ``g``, ``beta``
+    (B, H, T), ``v`` (B, H, T, V), ``q``, ``k`` (B, Hk, T, K) with ``H`` a
+    multiple of ``Hk``. The decay does not depend on the channel, so it
+    leaves the sum over them: ``<a_r, b_i>_G = (a_r . b_i) exp(G_r - G_i)``,
+    one product a KEY head and a ``(T, T)`` mask a value head (the exponent
+    of a pair ``i <= r`` is <= 0; above the diagonal the mask is 0)."""
+    B, H, T = g.shape
+    Hk, K = q.shape[1], q.shape[-1]
+    G = jnp.cumsum(g, axis=2)
+    low = jnp.tril(jnp.ones((T, T), bool))
+    mask = jnp.exp(jnp.where(low, G[..., :, None] - G[..., None, :],
+                             -jnp.inf))                     # (B, H, T, T)
+
+    def heads(x):       # (B, Hk, ...) seen a value head: (B, Hk, rep, ...)
+        return x.reshape((B, Hk, 1) + x.shape[2:])
+
+    def value(x):       # (B, Hk, rep, ...) -> (B, H, ...)
+        return x.reshape((B, H) + x.shape[3:])
+
+    by_key = mask.reshape(B, Hk, H // Hk, T, T)
+    both = [value(heads(_einsum("bhrc,bhic->bhri", lhs, k)) * by_key)
+            for lhs in (k, q)]
+    a = jnp.where(jnp.tril(low, -1), beta[..., None] * both[0], 0.0)
+
+    def scaled(x, by):  # x (B, Hk, T, K) times a value head's by (B, H, T)
+        return value(heads(x) * by.reshape(B, Hk, H // Hk, T, 1))
+
+    e, last = jnp.exp(G), G[:, :, -1:]
+    return (_unit_lower_inverse(a, sub), both[1], scaled(k, beta * e),
+            scaled(q, e), jnp.swapaxes(scaled(k, jnp.exp(last - G)), -1, -2),
+            beta[..., None] * v,
+            jnp.broadcast_to(jnp.exp(last)[..., None], (B, H, 1, K)))
 
 
 def _chunk_apply(inv, p, bkg, qg, kt, bv, eg, s0, dot):
@@ -189,7 +257,8 @@ def _chunk_apply(inv, p, bkg, qg, kt, bv, eg, s0, dot):
 def kda_chunk_plain(q, k, v, g, beta, s0, sub: int = SUB):
     """The chunk form in ``jax.numpy``: ``q``, ``k``, ``g`` (B, T, H, K),
     ``v`` (B, T, H, V), ``beta`` (B, T, H), ``s0`` (B, H, K, V); ``T`` a
-    multiple of ``sub``. Returns ``(o (B, T, H, V), s_T)``."""
+    multiple of ``sub``; or a decay a head, ``g`` (B, T, H), and ``q``,
+    ``k`` a key head. Returns ``(o (B, T, H, V), s_T)``."""
     heads = [jnp.swapaxes(x, 1, 2) for x in (q, k, v, g)]
     ops = _chunk_operands(*heads, jnp.swapaxes(beta, 1, 2), sub)
     o, s = _chunk_apply(*ops, s0, functools.partial(
@@ -213,7 +282,8 @@ def kda_sequence(q, k, v, g, beta, block: int = CHUNK):
     """Whole sequences from an empty state, block by block through
     :func:`kda_chunk_plain` (the forward without a cache). Returns ``o``
     (B, T, H, V)."""
-    B, T, H, K = q.shape
+    B, T, _, K = q.shape
+    H = v.shape[2]
     Q, pad = _blocks(T, block, SUB)
     ops = _pad_tokens((q, k, v, g, beta), pad)      # (g, beta 0: padding)
     cut = [jnp.moveaxis(x.reshape((B, -1, Q) + x.shape[2:]), 1, 0)
@@ -259,23 +329,29 @@ def _decode_kernel(layer_ref, batch_ref, row_ref, fresh_ref,
         o_ref[0, h:h + 1, :] = jnp.sum(s * column(2), axis=0, keepdims=True)
 
 
-def kda_decode(q, k, v, g, beta, s, layer, rows, fresh):
+def kda_decode(q, k, v, g, beta, s, layer, rows, fresh,
+               name: str = "kda_decode"):
     """One token a running row, state updated in place.
 
     Args:
-      q, k, g: (B, H, K); v: (B, H, V); beta: (B, H) (see the module text).
+      q, k, g: (B, H, K); v: (B, H, V); beta: (B, H) (see the module text);
+        or a decay a head, g: (B, H), and q, k a key head, (B, Hk, K): the
+        kernel is handed the columns of every value head all the same (64 KB
+        a row beside 2 MB of state).
       s: the stacked leaf (L, R, H, K, V) float32, aliased to the result.
       layer: int32 scalar (traced). rows: (B,) int32, the pool row of each
         batch entry, out of range for an entry that does not run (its
         output is 0 and its state untouched). fresh: (B,) bool, the entry
         stands at position 0 and reads no state.
+      name: the Pallas call's (what a trace attributes its time by).
 
     Returns ``(o (B, H, V) float32, s)``."""
-    B, H, K = q.shape
-    V = v.shape[-1]
+    B, H, V = v.shape
+    K = q.shape[-1]
     assert s.shape[2:] == (H, K, V), (q.shape, v.shape, s.shape)
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    q, k, g = _a_value_head(q, k, g, H, 1)
     cols = _columns(jnp.exp(g), k, q)
     lane_rows = jnp.concatenate(
         [beta[..., None] * v, jnp.broadcast_to(beta[..., None], v.shape)],
@@ -291,7 +367,7 @@ def kda_decode(q, k, v, g, beta, s, layer, rows, fresh):
     s, s_shape = in_hbm(s)
     s, o = pl.pallas_call(
         functools.partial(_decode_kernel, heads=H),
-        name="kda_decode",
+        name=name,
         grid_spec=grid_spec,
         out_shape=[s_shape, jax.ShapeDtypeStruct((B, H, V), f32)],
         input_output_aliases={6: 0},
@@ -317,19 +393,22 @@ def _chunk_kernel(layer_ref, batch_ref, row_ref, fresh_ref, inv_ref, p_ref,
     so_ref[0, 0, 0] = s
 
 
-def kda_chunk(q, k, v, g, beta, s, layer, rows, fresh):
+def kda_chunk(q, k, v, g, beta, s, layer, rows, fresh,
+              name: str = "kda_chunk"):
     """``T`` tokens of every running row after its carried state (the chunk
     form), state updated in place. ``q``, ``k``, ``g`` (B, T, H, K), ``v``
     (B, T, H, V), ``beta`` (B, T, H), ``T`` a multiple of :data:`SUB` and
-    at most :data:`CHUNK`; the rest as :func:`kda_decode`. A padding token
-    comes with ``g`` and ``beta`` zero. Returns ``(o (B, T, H, V) float32,
-    s)``."""
-    B, T, H, K = q.shape
-    V = v.shape[-1]
+    at most :data:`CHUNK`; or a decay a head, ``g`` (B, T, H), and ``q``,
+    ``k`` a key head (the preparation is then the scalar-decay one); the
+    rest as :func:`kda_decode`. A padding token comes with ``g`` and
+    ``beta`` zero. What XLA prepares lies under the scope ``<name>_prep``.
+    Returns ``(o (B, T, H, V) float32, s)``."""
+    B, T, H, V = v.shape
+    K = q.shape[-1]
     assert T % SUB == 0 and T <= CHUNK, T
     assert s.shape[2:] == (H, K, V), (q.shape, v.shape, s.shape)
     f32 = jnp.float32
-    with jax.named_scope("kda_chunk_prep"):
+    with jax.named_scope(f"{name}_prep"):
         heads = [jnp.swapaxes(x.astype(f32), 1, 2) for x in (q, k, v, g)]
         ops = _chunk_operands(*heads,
                               jnp.swapaxes(beta.astype(f32), 1, 2))
@@ -344,7 +423,7 @@ def kda_chunk(q, k, v, g, beta, s, layer, rows, fresh):
     s, s_shape = in_hbm(s)
     s, o = pl.pallas_call(
         _chunk_kernel,
-        name="kda_chunk",
+        name=name,
         grid_spec=grid_spec,
         out_shape=[s_shape, jax.ShapeDtypeStruct((B, H, T, V), f32)],
         input_output_aliases={4 + len(ops): 0},
@@ -356,7 +435,7 @@ def kda_chunk(q, k, v, g, beta, s, layer, rows, fresh):
 
 
 def kda_prefill(q, k, v, g, beta, s, layer, rows, fresh, length=None,
-                block: int = CHUNK):
+                block: int = CHUNK, name: str = "kda_chunk"):
     """:func:`kda_chunk` over a sequence of any length: tokens at or past
     ``length`` (B,) are padding (their ``g`` and ``beta`` are zeroed here),
     the sequence is cut into blocks of at most ``block`` tokens and the
@@ -365,14 +444,18 @@ def kda_prefill(q, k, v, g, beta, s, layer, rows, fresh, length=None,
     B, T = q.shape[:2]
     if length is not None:
         real = jnp.arange(T)[None, :] < jnp.asarray(length)[:, None]
-        g = jnp.where(real[..., None, None], g, 0)
+        g = jnp.where(real[(...,) + (None,) * (g.ndim - 2)], g, 0)
         beta = jnp.where(real[..., None], beta, 0)
     Q, pad = _blocks(T, block, SUB)
     ops = _pad_tokens((q, k, v, g, beta), pad)
     blocks = (T + pad) // Q
     fresh = jnp.asarray(fresh, bool)
+    # (looked up by its module name at every call, and called as it always
+    # was under its own name: perf/tools/kimi_limits.py wraps it)
+    chunk = kda_chunk if name == "kda_chunk" \
+        else functools.partial(kda_chunk, name=name)
     if blocks == 1:
-        o, s = kda_chunk(*ops, s, layer, rows, fresh)
+        o, s = chunk(*ops, s, layer, rows, fresh)
         return o[:, :T], s
 
     def cut(x):     # (B, blocks * Q, ...) -> (blocks, B, Q, ...)
@@ -380,7 +463,7 @@ def kda_prefill(q, k, v, g, beta, s, layer, rows, fresh, length=None,
 
     def step(carry, xs):
         s, first = carry
-        o, s = kda_chunk(*xs, s, layer, rows, fresh & first)
+        o, s = chunk(*xs, s, layer, rows, fresh & first)
         return (s, jnp.zeros((), bool)), o
 
     (s, _), o = jax.lax.scan(step, (s, jnp.ones((), bool)),

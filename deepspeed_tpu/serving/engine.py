@@ -359,7 +359,7 @@ class ServingEngine:
         # form of its kind's scan: the span attribute that counts them)
         self._chunk_tokens_key = next(
             (f"{kind}_chunk_tokens"
-             for kind in ("ssm", "kda", "conv", "lightning")
+             for kind in ("ssm", "kda", "gdn", "conv", "lightning")
              if kind in getattr(spec, "kinds", ())), None)
         # learned sparse attention's sizes (``KVCacheSpec.sparse``): what
         # the equations read for a query at a position, for the counters
@@ -1411,7 +1411,8 @@ class ServingEngine:
         prefill dispatch's REAL tokens (the host's own positions): a model
         of state-space, KDA or short-convolution layers runs them through
         the chunk form (``ssm_chunk_tokens`` / ``kda_chunk_tokens`` /
-        ``conv_chunk_tokens`` on the span, summed on the step's)."""
+        ``gdn_chunk_tokens`` / ``conv_chunk_tokens`` on the span, summed on
+        the step's)."""
         if not self._state_row_bytes:
             return
         sp.set(state_rows=rows)

@@ -467,10 +467,6 @@ def test_a_kda_state_group_beside_latent_pages_compiles_with_no_copy_of_a_leaf(
     pool and no stacked weight is copied (temporaries under 128 MB beside
     1.7 GB of leaves and 0.9 GB of weights), the 32-wide ``b_proj`` and the
     conv tail's rows written through the layer's slab among them."""
-    from deepspeed_tpu.inference.engine import pack_chunk_args
-    from deepspeed_tpu.parallel import mesh
-    from deepspeed_tpu.serving.paged_pool import PagedKVPool
-
     model, engine = _zero_engine(
         "kimi_linear", max_seq_len=8192, n_embd=2304, n_layer=4, n_head=32,
         n_kv_head=32, kv_lora_rank=512, qk_nope_head_dim=128,
@@ -480,18 +476,87 @@ def test_a_kda_state_group_beside_latent_pages_compiles_with_no_copy_of_a_leaf(
         experts_per_token=8, routed_scaling_factor=2.446,
         n_shared_experts=1, dense_ffn_dim=9216,
         mlp_layer_types=["dense", "sparse", "sparse", "sparse"])
-    spec, slots, chunk = model.kv_cache_spec(), 128, 128
+    compiled, served = _chunk_beside_decode_of(
+        model, engine, described_v5e, slots=128, chunk=128, num_pages=8192)
+    assert served["s"].shape == (3, 128, 32, 128, 128)
+    assert served["c"].shape == (1, 8192, 576, 128)
+    text = compiled.as_text()
+    calls = re.findall(
+        r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*tpu_custom_call", text)
+    assert sorted(calls) == sorted(
+        ["kda_chunk", "kda_decode"] * 2
+        # (a chunk's 128 x 32 query-head rows are two calls of MAX_ROWS)
+        + ["paged_write"] * 2 + ["mla_chunk"] * 2 + ["mla_decode"]
+        + ["moe_gate_up", "moe_down"] * 2), calls
+    assert "may-alias" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27, \
+        compiled.memory_analysis()
+
+
+def test_gdn_kernels_compile_for_a_described_v5e_at_the_served_shape(
+        compiled_kernels, described_v5e):
+    """PR 60. ``ops/kda.py``'s two kernels in their scalar-decay form, under
+    the names a Gated DeltaNet layer gives them, through Mosaic at the
+    widths of ``perf/configs/qwen3-next-80b-a3b-ep4.json``: 96 rows' tokens
+    through ``gdn_decode`` (32 value heads over 16 key heads, one log decay
+    a head) and one row's 128-token block through ``gdn_chunk`` on the
+    pool's stacked leaf (6 layers x 96 slots x 32 heads of (128, 128)
+    float32, 1.2 GB), which goes in and comes out in one buffer."""
+    from deepspeed_tpu.ops import kda
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=described_v5e)
+
+    i32 = jnp.int32
+    L, R, Hk, H, K = 6, 96, 16, 32, 128
+    leaf = shape((L, R, H, K, K))
+    with _compile_cache_off():
+        for name, fn, B, tokens in (("gdn_decode", kda.kda_decode, R, ()),
+                                    ("gdn_chunk", kda.kda_chunk, 1, (128,))):
+            keys = shape((B,) + tokens + (Hk, K))
+            heads = shape((B,) + tokens + (H,))
+            compiled = jax.jit(functools.partial(fn, name=name),
+                               donate_argnums=5).lower(
+                keys, keys, shape((B,) + tokens + (H, K)), heads, heads,
+                leaf, shape((), i32), shape((B,), i32),
+                shape((B,), jnp.bool_)).compile()
+            text = compiled.as_text()
+            assert re.findall(r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*"
+                              r"tpu_custom_call", text) == [name]
+            assert "may-alias" in text
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+
+
+def _qwen3_next_period(held: int):
+    """One period of Qwen3-Next at its published widths (``[gdn, gdn, gdn,
+    attention]``), ``held`` of the router's 512 experts here."""
+    return _zero_engine(
+        "qwen3_next", max_seq_len=8192, n_embd=2048, n_layer=4, n_head=16,
+        n_kv_head=2, head_size=256, rope_theta=10000000,
+        layer_types=["linear_attention"] * 3 + ["full_attention"],
+        gdn_n_key_heads=16, gdn_n_value_heads=32, gdn_d_head=128,
+        ffn_dim=512, n_experts=512, experts_held=held, experts_per_token=10)
+
+
+def _chunk_beside_decode_of(model, engine, described_v5e, slots, chunk,
+                            num_pages):
+    """The one program of a step that carries a ``chunk`` beside ``slots``
+    running rows over a pool of ``num_pages`` pages of 128, compiled for a
+    described v5e: ``(compiled, the pool's leaves as shapes)``."""
+    from deepspeed_tpu.inference.engine import pack_chunk_args
+    from deepspeed_tpu.parallel import mesh
+    from deepspeed_tpu.serving.paged_pool import PagedKVPool
+
+    spec = model.kv_cache_spec()
     pool = PagedKVPool(spec, 2, num_pages=2, kernel="on", page_size=128,
                        prefix_cache=False)
     pool.bind_engine(engine)
     assert pool.fuses(chunk)
-    cs = dict(jax.eval_shape(
-        lambda: spec.paged_cache(8192, 128, num_slots=slots)))
-    assert cs["s"].shape == (3, 128, 32, 128, 128)
-    assert cs["c"].shape == (1, 8192, 576, 128)
-    cs["index"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
-    cs["table"] = jax.ShapeDtypeStruct((slots, pool.pages_per_slot),
-                                       jnp.int32)
+    served = jax.eval_shape(
+        lambda: spec.paged_cache(num_pages, 128, num_slots=slots))
+    cs = dict(served, index=jax.ShapeDtypeStruct((slots,), jnp.int32),
+              table=jax.ShapeDtypeStruct((slots, pool.pages_per_slot),
+                                         jnp.int32))
     token = jnp.zeros((slots,), jnp.int32)
     packed = jnp.asarray(pack_chunk_args(
         np.zeros((1, chunk), np.int32), 0, chunk, chunk, chunk - 1,
@@ -502,17 +567,40 @@ def test_a_kda_state_group_beside_latent_pages_compiles_with_no_copy_of_a_leaf(
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=described_v5e)
 
     with _compile_cache_off():
-        compiled = pool._paged_chunk_decode_jit.lower(
+        return pool._paged_chunk_decode_jit.lower(
             *jax.tree_util.tree_map(
                 described, (engine.params, cs, packed, token, token))
-        ).compile()
+        ).compile(), served
+
+
+def test_a_gdn_state_group_beside_wide_pages_compiles_with_no_copy_of_a_leaf(
+        compiled_kernels, described_v5e):
+    """PR 60. The one program of a step that carries a chunk of 512 beside
+    96 running slots, for a server of Gated DeltaNet and gated GQA layers
+    at Qwen3-Next's published widths of one period, 16 of 512 experts held
+    (128 as served: the tile bound and the leaf grow, the text does not),
+    5,632 pages of 128 at head_dim 256, compiled for a described v5e. A
+    state layer's body holds the conv tail's slab write and the two forms
+    of the delta rule under their own names; the attention layer's the
+    256-wide page write (a chunk's 512 columns a window of 128 at a time,
+    K and V: eight calls, and the decode rows' two), and the read of a
+    chunk's 512 x 8 query rows a KV head as FOUR calls of 128 positions
+    (``_step_bytes``: two (1,024, 256) blocks and their float32 accumulator
+    are 4.5 MB of the 8 MB a step may hold; 256 positions would be 8.7)
+    beside the decode rows' one; ONE routed FFN a body over both groups'
+    rows. No leaf of the pool and no stacked weight is copied."""
+    model, engine = _qwen3_next_period(16)
+    compiled, served = _chunk_beside_decode_of(
+        model, engine, described_v5e, slots=96, chunk=512, num_pages=5632)
+    assert served["s"].shape == (3, 96, 32, 128, 128)
+    assert served["conv"].shape == (3, 96, 3 * 8192)
+    assert served["k"].shape == (1, 5632, 2, 256, 128)
     text = compiled.as_text()
     calls = re.findall(
         r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*tpu_custom_call", text)
     assert sorted(calls) == sorted(
-        ["kda_chunk", "kda_decode"] * 2
-        # (a chunk's 128 x 32 query-head rows are two calls of MAX_ROWS)
-        + ["paged_write"] * 2 + ["mla_chunk"] * 2 + ["mla_decode"]
+        ["gdn_chunk", "gdn_decode"]
+        + ["paged_write"] * 10 + ["paged_decode"] * 5
         + ["moe_gate_up", "moe_down"] * 2), calls
     assert "may-alias" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27, \

@@ -73,6 +73,14 @@ KINDS = {
         kda_n_heads=2, kda_d_head=8,
         layer_types=["kda", "kda", "kda", "attention"],
         mlp_layer_types=["dense", "sparse", "sparse", "sparse"])),
+    # a Gated DeltaNet state group (two value heads a key head) beside gated
+    # QK-normed K/V pages under a quarter-head rotary, the zero-centred norm,
+    # the routed FFN holding 2 of its 8 experts beside a gated shared expert
+    "gdn_gated": ("qwen3_next", dict(
+        n_layer=4, n_kv_head=2, head_size=16, ffn_dim=16, n_experts=8,
+        experts_per_token=2, experts_held=2, gdn_n_key_heads=2,
+        gdn_n_value_heads=4, gdn_d_head=8,
+        layer_types=["linear_attention"] * 3 + ["full_attention"])),
     # a state group of the convolution's tail alone beside QK-normed rotary
     # K/V, the routed FFN behind two dense layers
     "conv_tail": ("lfm2_moe", dict(

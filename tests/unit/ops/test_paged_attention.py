@@ -312,6 +312,67 @@ def test_rows_that_do_not_fit_vmem_go_in_two_calls(monkeypatch):
     np.testing.assert_allclose(np.asarray(split), ref, atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("T", [1, 24])
+def test_heads_of_256_read_and_write_through_the_pages(T):
+    """(PR 60) ``head_dim`` 256, eight query heads a K/V head (Qwen3-Next's
+    gated attention): a decode row and a chunk's rows through the write
+    (the stacked leaf bitwise the XLA scatter's) and through the read of
+    what was written, against the plain softmax."""
+    rng = np.random.default_rng(256 + T)
+    B, KV, D, S, ps, rep = 2, 2, 256, 128, 16, 8
+    dense_k, dense_v, k_pages, v_pages, table = _make_paged(
+        rng, B, KV, D, S, ps)
+    starts = np.asarray([ps - 1, 5 * ps + 2], np.int32)
+    leaves = [jnp.asarray(x)[None] for x in (k_pages, v_pages)]
+    new = [jnp.asarray(rng.standard_normal((B, KV, D, T)), jnp.float32)
+           for _ in leaves]
+    wrote = [jax.jit(paged_write_columns)(
+        leaf, jnp.asarray(0, jnp.int32), cols, jnp.asarray(table),
+        jnp.asarray(starts)) for leaf, cols in zip(leaves, new)]
+    for leaf, cols, out in zip(leaves, new, wrote):
+        _same(out, _scatter_columns(leaf, 0, cols, table, starts))
+    for dense, cols in zip((dense_k, dense_v), new):
+        for b in range(B):
+            dense[b, :, :, starts[b]:starts[b] + T] = np.asarray(cols[b])
+    q = jnp.asarray(rng.standard_normal((B, T, KV * rep, D)), jnp.float32)
+    out = np.asarray(paged_decode_attention(
+        q, wrote[0][0], wrote[1][0], jnp.asarray(table),
+        jnp.asarray(starts)))
+    np.testing.assert_allclose(
+        out, _reference_rows(np.asarray(q), dense_k, dense_v, starts),
+        atol=1e-4, rtol=1e-4)
+
+
+def test_a_chunk_of_512_at_head_dim_256_is_read_in_four_calls():
+    """(PR 60) At the served shape (16 query heads over 2 K/V heads of 256,
+    pages of 128, bfloat16) a chunk's 512 x 8 rows a K/V head do not fit a
+    step, nor do 256 x 8 (two (2,048, 256) blocks and their float32
+    accumulator: 8.25 MiB of the 8 a step may hold): the call halves twice,
+    four calls of 128 positions each 128 further on. Traced, not run."""
+    calls = []
+    call = paged_attention.pl.pallas_call
+
+    def noted(*a, **kw):
+        calls.append(kw["out_shape"].shape)
+        return call(*a, **kw)
+
+    bf16 = jnp.bfloat16
+    shape = jax.ShapeDtypeStruct
+    try:
+        paged_attention.pl.pallas_call = noted
+        out = jax.eval_shape(
+            functools.partial(paged_attention.paged_decode_attention,
+                              page_size=128),
+            shape((1, 512, 16, 256), bf16),
+            shape((2, 64, 2, 256, 128), bf16),
+            shape((2, 64, 2, 256, 128), bf16), shape((1, 64), jnp.int32),
+            shape((1,), jnp.int32), layer=shape((), jnp.int32))
+    finally:
+        paged_attention.pl.pallas_call = call
+    assert out.shape == (1, 512, 16, 256)
+    assert calls == [(1, 2, 8 * 128, 256)] * 4
+
+
 @pytest.mark.parametrize("KV", [4, 2])
 def test_alibi_matches_dense_oracle(KV):
     """(With KV 2 the rows of two query heads share one product and each
@@ -765,6 +826,9 @@ def test_fold_in_runs_is_the_head_after_head_fold_bit_for_bit(
     ((64, 8, 2, 64, 128, 16), 1, 8, 2, 2),            # fewer heads than 4
     ((1, 32, 8, 64, 128, 128), 128, 512, 4, 1),       # Granite's chunk
     ((1, 32, 4, 128, 128, 64), 128, 1024, 2, 1),      # Mellum's chunk
+    ((96, 16, 2, 256, 128, 64), 1, 8, 2, 2),          # Qwen3-Next decode
+    ((1, 16, 2, 256, 128, 64), 128, 1024, 1, 1),      # ... a chunk's quarter
+    ((1, 16, 2, 256, 128, 64), 64, 512, 2, 1),
 ])
 def test_the_run_follows_the_rows_of_a_kv_head(shape, T, rows, kv_group,
                                                run):
@@ -1143,6 +1207,8 @@ def test_write_group_follows_the_vmem_budget(monkeypatch):
     split call writes the same bits."""
     assert plan_write(16, 128, 64, 128, jnp.bfloat16) == (16, 128)
     assert plan_write(16, 32, 128, 128, jnp.int32) == (16, 128)
+    # (PR 60) two K/V heads of 256: 384 KB a step, both heads in one
+    assert plan_write(2, 256, 128, 128, jnp.bfloat16) == (2, 128)
     rng = np.random.default_rng(33)
     L, P, KV, Dc, ps = 2, 4, 4, 128, 64
     leaf = _values(rng, (L, P, KV, Dc, ps), jnp.bfloat16)

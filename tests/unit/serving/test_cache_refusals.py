@@ -33,6 +33,10 @@ PRESETS = {
         TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
         layer_types=["kda", "attention"] * 2, kda_n_heads=2,
         kda_d_head=8)),
+    "gdn": ("granite-hybrid", dict(
+        TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
+        layer_types=["gdn", "attention"] * 2, gdn_n_key_heads=2,
+        gdn_n_value_heads=4, gdn_d_head=8)),
     "conv": ("granite-hybrid", dict(
         TINY, n_layer=4, n_kv_head=2, ffn_dim=48,
         layer_types=["conv", "attention"] * 2)),
@@ -237,6 +241,64 @@ def test_a_kda_state_group_beside_latent_pages_refuses_in_the_groups_words():
                                   experts_per_token=2, dense_ffn_dim=48,
                                   kda_n_heads=2, kda_d_head=8),
             layer_types=["kda", "attention"] * 2, first_k_dense=2)
+
+
+def test_a_gdn_state_group_beside_gated_pages_refuses_in_the_groups_words():
+    """PR 60's pairing (Gated DeltaNet layers, gated QK-normed rotary
+    attention, a routed FFN that holds a share beside a gated shared
+    expert): the state group answers for everything a state is in the way
+    of, in the rows ``kda`` has, and it serves on the page pool with the
+    prefix cache off."""
+    sizes = dict(TINY, n_layer=8, n_kv_head=2, head_size=16, ffn_dim=16,
+                 n_experts=8, experts_per_token=2, experts_held=2,
+                 gdn_n_key_heads=2, gdn_n_value_heads=4, gdn_d_head=8)
+    cfg = transformer_config(
+        "qwen3_next", **sizes,
+        layer_types=["linear_attention"] * 3 + ["full_attention"]
+        + ["gdn"] * 3 + ["attention"])
+    assert cfg.layer_types == ("gdn", "gdn", "gdn", "attention") * 2
+    assert cache_kinds(cfg) == ("gdn", "routed")
+    assert {f for k, f in CACHE_REFUSALS if k == "gdn"} \
+        == {f for k, f in CACHE_REFUSALS if k == "kda"}
+    spec = TransformerLM(cfg).kv_cache_spec()
+    for feature in ("spec_decode", "prefix_cache", "roles", "tensor_parallel",
+                    "tensor_parallel_serving", "zero_inference"):
+        said = spec.refusal(feature)
+        assert said.startswith(f"{FEATURES[feature]} does not compose with "
+                               f"a Gated DeltaNet state group yet: "), said
+        assert CACHE_REFUSALS["gdn", feature] in said
+    assert spec.refusal("paged_kv") is None
+    cache = spec.paged_cache(4, PAGE, num_slots=2)
+    assert set(cache) == {"k", "v", "s", "conv"}
+    assert cache["s"].shape == (6, 2, 4, 8, 8)
+    assert cache["conv"].shape == (6, 2, 3 * (2 * 2 + 4) * 8)
+    for feature in ("kv_cache_quant", "int8_weights"):
+        with pytest.raises(ValueError,
+                           match="a Gated DeltaNet state group yet"):
+            transformer_config(
+                "qwen3_next", **sizes, **{feature: True},
+                layer_types=["gdn", "gdn", "gdn", "attention"] * 2)
+    # value heads a multiple of the key heads; the gates and the norm are
+    # refused where nothing reads them
+    with pytest.raises(ValueError, match="a multiple of them as "
+                                         "gdn_n_value_heads"):
+        transformer_config(
+            "qwen3_next", **dict(sizes, gdn_n_value_heads=3),
+            layer_types=["gdn", "gdn", "gdn", "attention"] * 2)
+    with pytest.raises(ValueError, match="shared_expert_gate scales the "
+                                         "shared expert"):
+        transformer_config(
+            "qwen3_next", **dict(sizes, n_shared_experts=0),
+            layer_types=["gdn", "gdn", "gdn", "attention"] * 2)
+    with pytest.raises(ValueError, match="attn_output_gate is the gate of "
+                                         "attention layers that cache K/V"):
+        transformer_config(
+            "moonlight", **dict(TINY, kv_lora_rank=16, qk_nope_head_dim=8,
+                                qk_rope_head_dim=8, v_head_dim=8,
+                                scoring_func="softmax"),
+            attn_output_gate=True)
+    with pytest.raises(ValueError, match="know layernorm | rmsnorm"):
+        transformer_config("llama", **dict(TINY, norm="rmsnorm2p"))
 
 
 def test_a_conv_tail_beside_rotary_pages_refuses_in_the_groups_words():

@@ -737,6 +737,84 @@ def test_a_conv_tail_beside_pages_counts_what_it_ran(lfm2_account):
 
 
 @pytest.fixture(scope="module")
+def qwen_account():
+    """A server of Gated DeltaNet layers beside gated QK-normed rotary
+    attention with a routed FFN that holds 2 of its 8 experts beside a
+    gated shared expert (PR 60: KDA's state leaf under one decay a head),
+    driven as ``kimi_account`` is."""
+    model, params, _ = kind_stack("gdn_gated")
+    srv = ds.init_serving(model, model_parameters=params,
+                          config={"dtype": "float32"}, num_slots=4,
+                          max_queue_depth=8, prefill_chunk=8,
+                          prefill_token_budget=64,
+                          paged_kv={"kernel": "off", "prefix_cache": False})
+    rng = np.random.default_rng(11)
+    n0 = default_tracer().events_total
+    srv.submit(rng.integers(0, 64, size=20).astype(np.int32),
+               max_new_tokens=12)                  # chunks of 8, 8 and 4
+    for _ in range(4):
+        srv.step()
+    srv.submit(rng.integers(0, 64, size=7).astype(np.int32),
+               max_new_tokens=4)                   # alone: serving/admit
+    srv.run_until_drained(max_steps=60)
+    srv.check_invariants()
+    evs = _new_events(n0)
+    steps = [e for e in evs if e["name"] == "serving/step"]
+    return {"pool": "paged", "srv": srv, "evs": evs, "steps": steps}
+
+
+def test_a_gdn_state_group_beside_pages_counts_what_it_ran(qwen_account):
+    """``state_rows`` on every dispatch span and ``gdn_chunk_tokens`` (REAL
+    tokens) on the prefill dispatches, as the kda layers'
+    ``kda_chunk_tokens`` and never two of them; the state's bytes are a
+    value head's matrices and the one convolution's tail; on
+    ``serving/step`` the routed FFN's counts of the HELD experts (what the
+    kernels ran) beside every assignment the router made, ``rows x k`` a
+    layer a call, every one of the four layers routed."""
+    srv, evs = qwen_account["srv"], qwen_account["evs"]
+    chunks = [e for e in evs if e["name"] == "serving/prefill_chunk"]
+    assert [e["args"]["gdn_chunk_tokens"] for e in chunks] == [8, 8, 4]
+    assert all(e["args"]["state_rows"] == 1 for e in chunks)
+    admits = [e for e in evs if e["name"] == "serving/admit"]
+    assert [e["args"]["gdn_chunk_tokens"] for e in admits] == [7]
+    assert not any(key in (e.get("args") or {}) for e in evs
+                   for key in ("ssm_chunk_tokens", "kda_chunk_tokens",
+                               "conv_chunk_tokens", "latent_tokens_read"))
+    decodes = [e for e in evs if e["name"] == "serving/decode"]
+    assert decodes
+    for e in decodes:
+        assert e["args"]["state_rows"] == e["args"]["live"]
+    spec = srv.pool.spec
+    assert spec.state_leaves == ("s", "conv")
+    # three DeltaNet layers: 4 value heads of (8, 8) float32 and a tail of
+    # 3 x (2 x 2 + 4) x 8 channels
+    assert spec.state_bytes_per_row == 3 * (4 * 8 * 8 * 4 + 3 * 64 * 4)
+    summed = [s["args"] for s in qwen_account["steps"]
+              if s["args"].get("state_rows")]
+    assert summed and all(
+        a["state_bytes"] == 2 * spec.state_bytes_per_row * a["state_rows"]
+        for a in summed)
+    counted = [s["args"] for s in qwen_account["steps"]
+               if s["args"].get("moe_layer_calls")]
+    assert counted
+    for args in counted:
+        calls = args["moe_layer_calls"]
+        assert calls % 4 == 0                   # four routed layers a call
+        assert 0 <= args["moe_assignments"] <= args["moe_routed_assignments"]
+        assert args["moe_experts_touched"] <= 2 * calls
+        assert args["moe_bias_reordered"] == 0  # (a softmax router)
+    plain = _steps_with(qwen_account, "serving/decode", without=(
+        "serving/admit", "serving/prefill_batch", "serving/prefill_chunk"))
+    for step in plain:
+        if step["args"].get("moe_layer_calls") == 4:
+            assert step["args"]["moe_routed_assignments"] == 4 * 4 * 2
+    assert sum(a["moe_assignments"] for a in counted) \
+        < sum(a["moe_routed_assignments"] for a in counted)
+    counts = [s["args"]["device_calls"] for s in plain]
+    assert min(counts) == DECODE_CALLS
+
+
+@pytest.fixture(scope="module")
 def sala_account():
     """A server of Lightning layers beside learned sparse attention in an
     irregular stack (PR 56), its chunks and decode rows reading the pages
