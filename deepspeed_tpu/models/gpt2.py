@@ -44,8 +44,10 @@ class GPT2Config:
     layer_norm_epsilon: float = 1e-5
     dtype: Any = jnp.bfloat16  # activation/compute dtype
     remat: bool = False  # activation checkpointing per block
-    # remat policy: "full" recomputes everything; "dots" saves matmul
-    # outputs and recomputes only elementwise ops (cheaper recompute,
+    # remat policy (ops/attention/flash_attention.py REMAT_POLICIES): "full"
+    # keeps a block's input and the attention kernel's (out, lse) and
+    # recomputes every XLA operation; "dots" keeps matmul outputs too and
+    # recomputes only elementwise ops (cheaper recompute,
     # jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     remat_policy: str = "full"
     # Pallas flash kernel: True | False | "auto" (on-TPU when seq >= the
@@ -262,27 +264,13 @@ class _ScanBody(nn.Module):
                     path="/".join(self.path + ("block",)),
                     layers=self.config.n_layer))
         if self.config.remat:
-            policy = None
-            if self.config.remat_policy == "dots_plain":
-                # dots WITHOUT the named attention saves — A/B isolation
-                # for the save-vs-recompute tradeoff (saving out/lse costs
-                # ~20 MB x n_layer of live memory at the flagship shape)
-                policy = jax.checkpoint_policies.\
-                    dots_with_no_batch_dims_saveable
-            elif self.config.remat_policy == "dots":
-                # dots policy + named attention-kernel outputs: saves matmul
-                # outputs AND the flash/sparse kernel's (out, lse), so the
-                # backward pass reuses the attention forward instead of
-                # re-running the kernel (ATTN_SAVE_NAMES tags in
-                # ops/attention/flash_attention.py)
-                from ..ops.attention.flash_attention import ATTN_SAVE_NAMES
+            # whatever the policy, the attention kernel's named (out, lse)
+            # are kept: the backward reads them and runs no forward kernel
+            from ..ops.attention.flash_attention import REMAT_POLICIES
 
-                policy = jax.checkpoint_policies.save_from_both_policies(
-                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                    jax.checkpoint_policies.save_only_these_names(
-                        *ATTN_SAVE_NAMES))
-            block_cls = nn.remat(block_cls, prevent_cse=False,
-                                 static_argnums=(2,), policy=policy)
+            block_cls = nn.remat(
+                block_cls, prevent_cse=False, static_argnums=(2,),
+                policy=REMAT_POLICIES[self.config.remat_policy])
         x = block_cls(self.config, name="block")(x, deterministic)
         return x, None
 

@@ -50,10 +50,25 @@ DEFAULT_BLOCK_K = 128
 ROWS = 256
 ROWS_DKV = 128
 NEG_INF = -1e30
-# checkpoint_name tags on attention-kernel outputs (see _flash_attention_fwd);
-# remat policies compose save_only_these_names(*ATTN_SAVE_NAMES) so the
-# backward pass reuses the forward kernel's (out, lse) instead of re-running it
+# checkpoint_name tags on attention-kernel outputs (see _flash_attention_fwd):
+# every remat policy but the oracle keeps them, so the backward pass reads
+# the forward kernel's (out, lse) instead of running it a second time
 ATTN_SAVE_NAMES = ("flash_out", "flash_lse")
+_DOTS = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+_KERNEL_OUT = jax.checkpoint_policies.save_only_these_names(*ATTN_SAVE_NAMES)
+# remat_policy -> what a rematerialised block keeps besides its arguments.
+# The name chooses among XLA's values; the kernel's residuals are not part
+# of that choice: a ninth of what "dots" keeps, for the one operation of a
+# block that runs at a third of its roofline (PERF.md section 6, PR 61).
+REMAT_POLICIES = {
+    # the kernel's (out, lse) alone: norms, matmuls and GELU run again
+    "full": _KERNEL_OUT,
+    # matmul outputs too: only elementwise operations run again
+    "dots": jax.checkpoint_policies.save_from_both_policies(_DOTS, _KERNEL_OUT),
+    # matmul outputs WITHOUT the kernel's: the arm in which the forward
+    # kernel runs twice, kept as the tests' oracle and chosen by no cell
+    "dots_plain": _DOTS,
+}
 # TPU vector layout: fp32 tiles are (8 sublanes, 128 lanes). Row statistics
 # (lse, delta) are carried replicated across a size-8 sublane dim so their
 # blocks satisfy the (8, 128) tiling rule; stats scratch is lane-width.
@@ -437,17 +452,18 @@ def _flash_attention(q, k, v, causal, scale, block_q, block_k):
 def _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k):
     out, lse = _flash_fwd(q, k, v, causal=causal, scale=scale, block_q=block_q,
                           block_k=block_k)
-    # Name the kernel outputs so activation-checkpoint policies can save
-    # them: under the "dots" policy alone a rematerialized block re-runs the
-    # whole forward kernel in the backward pass (pallas_call outputs are not
-    # dot_general outputs). remat_policy="dots" composes
-    # save_only_these_names(*ATTN_SAVE_NAMES) on top, which keeps (out, lse)
-    # and skips the recompute; q/k/v re-derive cheaply from the saved qkv
-    # projection dot.
+    # Name the kernel outputs so that a remat policy can keep them (a
+    # pallas_call's outputs are no dot_general's, so "dots" alone runs the
+    # whole forward kernel again in the backward pass): REMAT_POLICIES' "full"
+    # and "dots" do, and q/k/v are re-derived by the qkv projection.
     from jax.ad_checkpoint import checkpoint_name
 
     out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    # what is kept of lse is its one distinct row (the kernel writes it over
+    # SUBLANES, the backward kernels read sublane 0): 1/8 of the bytes a
+    # layer, which is what lets GPT-2 large's step keep them on one chip
+    # without XLA's own rematerialisation setting in (PERF.md section 6, PR 61)
+    lse = jnp.broadcast_to(checkpoint_name(lse[:, :1], "flash_lse"), lse.shape)
     return out, (q, k, v, out, lse)
 
 

@@ -135,6 +135,11 @@ def _get(tree, path):
     return tree
 
 
+# "full" keeps the attention kernel's named (out, lse) since PR 61: two
+# activations of a chip's own sequences and no weight, so what is counted
+# here (gathers inside the loop, no gathered weight kept, no activation
+# moved) is unchanged; the rehearsal's SEQ runs XLA's attention on the CPU,
+# which names nothing, so this program is the one of before.
 @pytest.mark.parametrize("remat", ["off", "full"])
 def test_the_step_gathers_weights_and_moves_no_activation(remat):
     engine = _engine(3, remat=remat)
